@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig, DtypeEnum
+from deepspeed_tpu.models.transformer import TransformerLM
 from deepspeed_tpu.parallel.mesh import get_topology
 from deepspeed_tpu.profiling.compile_telemetry import CompileTelemetry
 from deepspeed_tpu.profiling.tracer import MetricsRegistry, ObservabilityHub, Tracer
@@ -59,7 +60,9 @@ class InferenceEngine:
         self._jit_forward = None
         self._cached_tp_rules = None
         self._rng = jax.random.PRNGKey(0)
-        self._ds_config = None  # TransformerConfig when kernel-injected
+        # TransformerConfig of the KV-cached decode / paged serving path: an
+        # injected HF model's converted config, or a native TransformerLM's own
+        self._ds_config = None
         # ZeRO-Inference (reference engine.py:1499-1520: stage-3 offload
         # without an optimizer): params live in host DRAM / on NVMe and
         # stream through HBM per layer — capacity over latency
@@ -114,6 +117,8 @@ class InferenceEngine:
             injected = True
         else:
             self.module = wrap_module(model)
+            if isinstance(model, TransformerLM):
+                self._ds_config = model.config
         # checkpoint handed to init_inference (reference engine.py:406):
         # a path string — engine-format dir, or an mp-checkpoint manifest
         ckpt = self._config.checkpoint
@@ -521,6 +526,11 @@ class InferenceEngine:
         the bucketed oracle, ≤1 compile per shape bucket and one
         ``paged_decode_*`` dispatch per decode step."""
         return self._telemetry.stats()
+
+    def program_text(self, name: str) -> str:
+        """Lowered (StableHLO) text of one dispatched ``compile_stats()``
+        program: which kernels and collectives it really contains."""
+        return self._telemetry.lowered_text(name)
 
     def analysis_report(self, programs=None, passes=None):
         """Static-analysis report over every dispatched inference program

@@ -89,7 +89,7 @@ def _sharding_key(sharding):
     the unsharded pools)."""
     if sharding is None:
         return None
-    from deepspeed_tpu.utils.jax_compat import mesh_fingerprint
+    from deepspeed_tpu.parallel.mesh import mesh_fingerprint
 
     return (str(sharding.spec), mesh_fingerprint(sharding.mesh))
 
@@ -259,31 +259,21 @@ class PagePool:
         return self.used_pages() * self.page_size * self.cache.bytes_per_token
 
     def memory_report(self) -> dict:
-        """Static residency accounting for the analysis HBM ledger: total
-        device bytes of the page pools, the per-chip share under the pool's
-        kv-head sharding (``total / tp`` when sharded — the tensor-parallel
-        serving contract), and the host-side scheduling structures (page
-        table, sequence lengths, refcounts, ownership) that stay replicated
-        host RAM, never HBM."""
-        total = self.cache.hbm_bytes()
-        per_chip = total
-        devices = 1
-        if self.kv_sharding is not None:
-            try:
-                devices = int(self.kv_sharding.num_devices)
-                shard = self.kv_sharding.shard_shape(
-                    tuple(self.cache.k_pages.shape)
-                )
-                n = 1
-                for d in shard:
-                    n *= int(d)
-                per_chip = 2 * n * self.cache.k_pages.dtype.itemsize
-            except Exception:
-                per_chip = total
+        """Residency accounting for the analysis HBM ledger, read from the
+        live page arrays' shards (where the bytes are, not where a sharding
+        was declared to put them): total device bytes of the page pools,
+        the largest per-chip share (``total / tp`` under the kv-head
+        sharding — the tensor-parallel serving contract), and the host-side
+        scheduling structures (page table, sequence lengths, refcounts,
+        ownership) that stay replicated host RAM, never HBM."""
+        per_device: dict = {}
+        for pages in (self.cache.k_pages, self.cache.v_pages):
+            for shard in pages.addressable_shards:
+                per_device[shard.device] = per_device.get(shard.device, 0) + shard.data.nbytes
         return {
-            "kv_total_bytes": total,
-            "kv_bytes_per_chip": per_chip,
-            "kv_devices": devices,
+            "kv_total_bytes": self.cache.hbm_bytes(),
+            "kv_bytes_per_chip": max(per_device.values()),
+            "kv_devices": len(per_device),
             "live_kv_bytes": self.live_hbm_bytes(),
             "page_table_location": "host",
             "host_table_bytes": int(

@@ -48,10 +48,11 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.compression.int8 import QuantizedTensor, qmatmul, slice_out_channels
-from deepspeed_tpu.utils.jax_compat import mesh_fingerprint, shard_map
+from deepspeed_tpu.parallel.mesh import mesh_fingerprint
 
 # serving-layout classification (models/transformer.py param names; the
 # AutoTP walk in module_inject/auto_tp.py generalizes the same policy)

@@ -52,7 +52,7 @@ class TransformerConfig:
     remat: bool = True  # jax.checkpoint each layer
     remat_policy: str = "nothing_saveable"
     scan_layers: bool = True  # lax.scan over stacked layer params
-    flash_attention: bool = True  # use the Pallas fused-attention kernel when available (falls back to einsum)
+    flash_attention: bool = True  # Pallas fused-attention kernel for causal attention (einsum for alibi / attention dropout)
     sequence_parallel: bool = False  # sequence parallelism over the 'sequence' axis
     sequence_parallel_mode: str = "ulysses"  # ulysses (all-to-all) | ring (ppermute)
 

@@ -14,8 +14,9 @@ Engineering choices for the MXU/HBM:
 * ``jax.checkpoint`` (remat) wraps the scanned body with a configurable
   policy — the activation-checkpointing subsystem of the reference
   (``deepspeed/runtime/activation_checkpointing``).
-* attention is einsum-based (MXU-shaped); the Pallas flash-attention kernel
-  swaps in via ``config.flash_attention`` when available.
+* attention is the Pallas flash-attention kernel under
+  ``config.flash_attention`` (causal, no alibi, no attention dropout) and
+  einsum-based (MXU-shaped) otherwise.
 * weights carry Megatron-style TP specs over the ``model`` axis
   (``tp_partition_rules``), composed with ZeRO sharding by the partitioner.
 """
@@ -33,15 +34,6 @@ from deepspeed_tpu.models.config import TransformerConfig
 from deepspeed_tpu.runtime.module import DSModule
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
-
-
-def _flash_attention_available() -> bool:
-    try:
-        from deepspeed_tpu.ops.transformer.flash_attention import flash_attention  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
 
 
 def _maybe_quantize_activation(x, site: str):
@@ -292,16 +284,13 @@ class TransformerLM(DSModule):
         NH, NKV = q.shape[2], k.shape[2]
         if (
             cfg.flash_attention
-            and _flash_attention_available()
             and cfg.position != "alibi"
             and cfg.causal
             and (not train or cfg.attn_dropout == 0)  # no dropout inside the fused kernel
         ):
-            from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
-
             if NKV != NH:
                 k, v = _expand_gqa(q, k, v)  # kernel contract: equal head counts
-            return flash_attention(q, k, v, causal=True, scale=scale)
+            return _flash_on_mesh(q, k, v, scale)
         if NKV != NH:
             # grouped GQA: heads stay [NKV, G]-factored through both einsums
             B, T, _, D = q.shape
@@ -832,6 +821,38 @@ class TransformerLM(DSModule):
             # (the reference adds l_aux only in training client code).
             loss = loss + aux
         return loss
+
+
+def _flash_on_mesh(q, k, v, scale):
+    """The fused causal kernel over [B, T, N, D] on the live topology.
+
+    GSPMD cannot partition a Mosaic kernel (the TPU compiler refuses the
+    whole step: "Mosaic kernels cannot be automatically partitioned"), so on
+    more than one device the call is wrapped in ``shard_map`` and every chip
+    runs the kernel on its own batch rows and heads — attention is
+    independent per (row, head), so nothing is exchanged. A dim the mesh
+    axes do not divide stays whole on every chip, as GSPMD would have
+    replicated it. Inside a caller's own ``shard_map`` the operands are
+    already per-chip and the kernel is entered directly."""
+    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+    from deepspeed_tpu.parallel.mesh import get_topology
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=scale)
+
+    topo = get_topology()
+    if topo.mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
+        return kernel(q, k, v)
+    batch_axes = topo.dense_batch_axes()
+    names = (batch_axes,) if isinstance(batch_axes, str) else tuple(batch_axes or ())
+    if q.shape[0] % int(np.prod([topo.axis_size(a) for a in names])):
+        batch_axes = None
+    tp = topo.axis_size("model")
+    head_axes = "model" if tp > 1 and q.shape[2] % tp == 0 else None
+    spec = P(batch_axes, None, head_axes, None)
+    return jax.shard_map(
+        kernel, mesh=topo.mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+    )(q, k, v)
 
 
 def _expand_gqa(q, k, v):

@@ -48,7 +48,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.moe import sharded_moe
@@ -224,7 +224,7 @@ def ep_gate_dispatch(
         P(tok_e, None),
     )
     return shard_map(
-        body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs, check_rep=False
+        body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs, check_vma=False
     )(*args)
 
 
@@ -245,5 +245,5 @@ def ep_combine(expert_out, combine_w, topo, *, quantized: bool = False):
         mesh=mesh,
         in_specs=(P(EXPERT_AXIS, rest_e, None), P(tok_e, None, None)),
         out_specs=P(tok_e, None),
-        check_rep=False,
+        check_vma=False,
     )(expert_out, combine_w)

@@ -58,9 +58,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.accelerator import on_tpu
 from deepspeed_tpu.ops.transformer.decode_attention import (
     NEG_INF,
-    _on_tpu,
     paged_decode_attention as _pallas_paged_decode,
     ragged_paged_attention as _pallas_ragged_paged,
 )
@@ -126,7 +126,7 @@ def paged_decode_attention(
     kernel on TPU and the XLA gather fallback elsewhere; ``pallas`` / ``xla``
     force one (``pallas`` off-TPU runs in interpret mode — tests only)."""
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "xla"
+        impl = "pallas" if on_tpu() else "xla"
     if impl == "pallas":
         return _pallas_paged_decode(q, k_pages, v_pages, page_table, kv_len, scale=scale)
     if impl == "xla":
@@ -155,7 +155,7 @@ def ragged_paged_attention(
     ``kv_lens == 0`` return exact zeros; window slots past ``q_lens``
     return garbage the caller ignores."""
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "xla"
+        impl = "pallas" if on_tpu() else "xla"
     if impl == "pallas":
         return _pallas_ragged_paged(
             q, k_pages, v_pages, page_table, kv_lens, q_lens, scale=scale

@@ -25,7 +25,6 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from deepspeed_tpu.runtime.config import MeshConfig
-from deepspeed_tpu.utils.logging import logger
 
 # canonical axis order, outermost first
 AXIS_ORDER: Tuple[str, ...] = ("pipe", "data_outer", "data", "expert", "sequence", "model")
@@ -124,6 +123,20 @@ class Topology:
         return axes
 
 
+def mesh_fingerprint(mesh) -> tuple:
+    """Hashable identity of a Mesh — axis names, shape, and the flat
+    device ids. The ONE definition shared by every cache that must not
+    serve an executable (or an out_shardings contract) built for one mesh
+    to arrays living on another: the paged-program cache key
+    (``inference/tp.py:TPServing.cache_key``) and the pool's CoW copier
+    cache (``inference/kv_pool.py``)."""
+    return (
+        tuple(mesh.axis_names),
+        tuple(int(s) for s in mesh.devices.shape),
+        tuple(d.id for d in mesh.devices.flat),
+    )
+
+
 def build_mesh(
     mesh_config: MeshConfig,
     devices: Optional[List] = None,
@@ -131,7 +144,9 @@ def build_mesh(
     """Create the global Mesh from resolved axis sizes.
 
     Uses ``mesh_utils.create_device_mesh`` so the logical axes map onto the
-    physical ICI torus (innermost logical axis → nearest neighbors).
+    physical ICI torus (innermost logical axis → nearest neighbors); for
+    CPU devices that call is itself a row-major reshape. A shape it cannot
+    map onto a TPU's topology raises.
     """
     import jax
     from jax.experimental import mesh_utils
@@ -149,11 +164,7 @@ def build_mesh(
         resolved.sequence,
         resolved.model,
     )
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception as e:  # fallback: row-major reshape (CPU meshes, odd shapes)
-        logger.debug(f"create_device_mesh failed ({e}); falling back to reshape")
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     mesh = Mesh(dev_array, AXIS_ORDER)
     return Topology(mesh, resolved)
 

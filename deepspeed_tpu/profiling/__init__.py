@@ -8,7 +8,7 @@ from deepspeed_tpu.profiling.compile_telemetry import (  # noqa: F401
     CompileTelemetry,
     InstrumentedFunction,
     ProgramStats,
-    configure_persistent_cache,
+    use_compile_cache,
 )
 from deepspeed_tpu.profiling.tracer import (  # noqa: F401
     NULL_TRACER,

@@ -85,10 +85,7 @@ def host_snapshot(tree: Any) -> Any:
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     for leaf in leaves:
         if isinstance(leaf, jax.Array):
-            try:
-                leaf.copy_to_host_async()
-            except Exception:
-                pass  # older jax / committed host arrays: device_get below
+            leaf.copy_to_host_async()
     host = [
         np.asarray(jax.device_get(leaf)) if isinstance(leaf, jax.Array) else leaf
         for leaf in leaves

@@ -176,15 +176,11 @@ class CompileConfig(DeepSpeedConfigModel):
     through ``train_batch``; the per-microbatch forward/backward/step
     protocol keeps the unfused programs). ``multi_step`` goes one level
     further and fuses N whole optimizer steps into one dispatch (see
-    :class:`MultiStepTrainConfig`). ``cache_dir`` opts into JAX's
-    persistent compilation cache so repeated runs skip cold compiles;
-    ``cache_min_compile_secs`` is the write threshold (0 caches everything).
+    :class:`MultiStepTrainConfig`).
     """
 
     fuse_grad_accum: bool = False
     multi_step: MultiStepTrainConfig = Field(default_factory=MultiStepTrainConfig)
-    cache_dir: Optional[str] = None
-    cache_min_compile_secs: float = 0.0
 
 
 class AnalysisConfig(DeepSpeedConfigModel):
@@ -200,8 +196,9 @@ class AnalysisConfig(DeepSpeedConfigModel):
     tiny buffers on some backends). ``collective_budget_bytes`` turns the
     collective extractor into a gate: any single program whose static
     per-device collective payload exceeds the budget is a violation.
-    Verification re-traces and re-compiles each program once — pair it with
-    ``compile.cache_dir`` to make the second compile a cache hit.
+    Verification re-traces and re-compiles each program once; with JAX's
+    persistent compilation cache on (``JAX_COMPILATION_CACHE_DIR``) the
+    second compile is a cache hit.
     """
 
     verify: str = "off"  # off | warn | raise

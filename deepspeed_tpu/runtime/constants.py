@@ -153,8 +153,6 @@ GRAD_ACCUM_DTYPE_DEFAULT = None
 COMPILE = "compile"
 FUSE_GRAD_ACCUM = "fuse_grad_accum"
 FUSE_GRAD_ACCUM_DEFAULT = False
-COMPILE_CACHE_DIR = "cache_dir"
-COMPILE_CACHE_DIR_DEFAULT = None
 
 #############################################
 # Eigenvalue (MoQ)

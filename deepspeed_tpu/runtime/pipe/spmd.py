@@ -41,9 +41,8 @@ from typing import Any, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 from deepspeed_tpu.runtime.module import DSModule
 from deepspeed_tpu.utils.logging import log_dist

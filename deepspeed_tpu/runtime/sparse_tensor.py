@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 class SparseTensor:

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.ops.quantizer import quantize
 from deepspeed_tpu.parallel.mesh import Topology

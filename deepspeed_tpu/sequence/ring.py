@@ -117,7 +117,5 @@ def ring_attention(
 
         mesh = get_topology().mesh
     spec = P(batch_axes, axis_name, head_axes, None)
-    from deepspeed_tpu.utils.jax_compat import shard_map as _shard_map_fn
-
-    smap = partial(_shard_map_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    smap = partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return smap(body)(q, k, v)

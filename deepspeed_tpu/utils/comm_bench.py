@@ -14,7 +14,7 @@ import time
 
 import numpy as np
 
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _bw_gb(op: str, size_bytes: int, seconds: float, n: int) -> float:

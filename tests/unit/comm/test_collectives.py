@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.comm import collectives
 from deepspeed_tpu import comm as dist
@@ -20,7 +20,7 @@ def mesh(eight_devices):
 
 
 def _smap(mesh, fn, in_specs, out_specs):
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs, check_rep=False, out_specs=out_specs))
+    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs, check_vma=False, out_specs=out_specs))
 
 
 def test_psum(mesh):

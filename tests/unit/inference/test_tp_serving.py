@@ -46,7 +46,7 @@ from deepspeed_tpu.inference.tp import TPServing, quantized_all_reduce, serving_
 from deepspeed_tpu.models import TransformerLM
 from deepspeed_tpu.models.config import TransformerConfig
 from deepspeed_tpu.profiling.compile_telemetry import CompileTelemetry
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 # MHA config: head axes divide by 4 so the same weights serve tp ∈ {1,2,4}
 CFG = dict(

@@ -1,0 +1,123 @@
+"""The main path's Pallas kernels, compiled by the TPU compiler for a v5e.
+
+The chip is described, not attached (``on-chip-measurement`` guide §2.3):
+nothing runs, but the compiler refuses here what it would refuse on the
+chip — a slice not aligned to the tiling, too much VMEM — which interpret
+mode never shows. Every kernel is entered with ``interpret=False``: left to
+itself it reads ``jax.default_backend()`` (the CPU here) and would lower the
+interpreter instead of the kernel. Shapes are the ones ``chip_smoke.py``
+runs: GPT-2 125M training (B8 T1024 N12 D64) and llama-1b serving (32 q
+heads over 4 kv heads, D64, page 64, 8 slots, windows 1 and 128).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.sparse_attention.pallas_block_sparse import pallas_block_sparse_attention
+from deepspeed_tpu.ops.sparse_attention.sparsity_config import BSLongformerSparsityConfig
+from deepspeed_tpu.ops.transformer.decode_attention import (
+    decode_attention,
+    paged_decode_attention,
+    ragged_paged_attention,
+)
+from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+
+BF16 = jnp.bfloat16
+I32 = jnp.int32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device. A compile for it is written to the
+    persistent cache but can never be read back without a chip, so the
+    cache is off for this module (the guide's advice)."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _bwd(fwd):
+    """dq, dk, dv of a scalar of ``fwd``: the kernel's backward programs."""
+
+    def bwd(q, k, v):
+        loss = lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return bwd
+
+
+def _ragged(q, k_pages, v_pages, table, kv_lens, q_lens):
+    return ragged_paged_attention(q, k_pages, v_pages, table, kv_lens, q_lens, interpret=False)
+
+
+def _paged(q, k_pages, v_pages, table, kv_lens):
+    return paged_decode_attention(q, k_pages, v_pages, table, kv_lens, interpret=False)
+
+
+def _dense_decode(q, k_cache, v_cache, kv_lens):
+    return decode_attention(q, k_cache, v_cache, kv_lens, interpret=False)
+
+
+_SPARSE_T, _SPARSE_BLOCK = 8192, 64  # tests/perf/block_sparse_bench.py's shape
+
+
+def _sparse_layout():
+    cfg = BSLongformerSparsityConfig(num_heads=8, block=_SPARSE_BLOCK)
+    return np.asarray(cfg.make_layout(_SPARSE_T))[:1]
+
+
+def _sparse_fwd(q, k, v):
+    return pallas_block_sparse_attention(
+        q, k, v, _sparse_layout(), _SPARSE_BLOCK, causal=True, interpret=False
+    )
+
+
+# the server's pool: 8 slots x (2048 / 64) pages + the trash page
+_PAGES = ((257, 4, 64, 64), BF16)
+_TABLE = ((8, 32), I32)
+_LENS = ((8,), I32)
+_TRAIN_QKV = [((8, 1024, 12, 64), BF16)] * 3
+_SPARSE_QKV = [((1, 8, _SPARSE_T, 64), BF16)] * 3
+
+CASES = {
+    "flash_fwd_gpt2_125m": (_flash_fwd, _TRAIN_QKV),
+    "flash_bwd_gpt2_125m": (_bwd(_flash_fwd), _TRAIN_QKV),
+    "ragged_w1_llama_1b": (_ragged, [((8, 1, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS, _LENS]),
+    "ragged_w128_llama_1b": (_ragged, [((8, 128, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS, _LENS]),
+    "paged_decode_llama_1b": (_paged, [((8, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS]),
+    "dense_decode_llama_1b": (
+        _dense_decode,
+        [((8, 32, 64), BF16), ((8, 2048, 4, 64), BF16), ((8, 2048, 4, 64), BF16), _LENS],
+    ),
+    "block_sparse_fwd_8k": (_sparse_fwd, _SPARSE_QKV),
+    "block_sparse_bwd_8k": (_bwd(_sparse_fwd), _SPARSE_QKV),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, name):
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel is not in the program"

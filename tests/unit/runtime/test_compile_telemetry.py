@@ -10,11 +10,16 @@ counters, and the ``invalidate_compiled_step`` test pins the
 executable-release fix for the PERF.md mid-suite wedge.
 """
 
+import os
+import subprocess
+import sys
+
 import jax
 import numpy as np
 
 import deepspeed_tpu as ds
 import deepspeed_tpu.parallel.mesh as mesh_mod
+from deepspeed_tpu.profiling import use_compile_cache
 from tests.unit.simple_model import SimpleModel, step_batch, train_steps_micro
 
 
@@ -143,18 +148,44 @@ def test_micro_batch_resize_bounded_executables(eight_devices):
     assert engine._jit_fused_step.cache_size() == 0
 
 
-def test_persistent_cache_opt_in(eight_devices, tmp_path):
-    """compile.cache_dir routes jitted programs through JAX's persistent
-    compilation cache."""
-    cache_dir = str(tmp_path / "xla_cache")
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+
+
+def test_compile_cache_yields_to_environment(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, whoever runs the program
+    places the cache: the helper sets nothing in code."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed_outside"))
+    before = jax.config.jax_compilation_cache_dir
+    assert use_compile_cache() == str(tmp_path / "placed_outside")
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_one_fixed_path(monkeypatch):
+    """Unset, the cache is <checkout>/.jax_cache: the same path on every
+    call and in every process, or the next run never finds this one's
+    programs."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    expected = os.path.join(_REPO, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
     try:
-        engine = _engine(compile={"cache_dir": cache_dir})
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-        train_steps_micro(engine, step_batch(batch_size=8), 1)
+        assert use_compile_cache() == expected
+        assert use_compile_cache() == expected
+        assert jax.config.jax_compilation_cache_dir == expected
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-
-
+        jax.config.update("jax_compilation_cache_dir", before)
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    child = subprocess.run(
+        [
+            sys.executable,
+            "-c",
+            "import jax\n"
+            "from deepspeed_tpu.profiling import use_compile_cache\n"
+            "use_compile_cache()\n"
+            "print(jax.config.jax_compilation_cache_dir)",
+        ],
+        env=env, cwd=_REPO, capture_output=True, text=True, timeout=120, check=True,
+    )
+    assert child.stdout.strip().splitlines()[-1] == expected
 def test_monitor_receives_compile_counters(eight_devices, tmp_path):
     """The monitor stream carries the compile counters (wired through
     _write_monitor)."""

@@ -307,7 +307,7 @@ def test_overlap_pass_red_serialized_schedule(eight_devices):
 
     from deepspeed_tpu.analysis import analyze_program
     from deepspeed_tpu.profiling.compile_telemetry import CompileTelemetry
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("x",))
     # a stacked, ZeRO-sharded layer stack: the per-iteration slice makes the

@@ -9,13 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-# `from jax import shard_map` only exists on jax >= 0.5; the repo's compat
-# shim (utils/jax_compat.py) presents the modern signature on every
-# supported jax — importing it here is what lets this module COLLECT on
-# 0.4.x instead of erroring out of tier-1
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 from deepspeed_tpu.parallel.mesh import initialize_topology
 from deepspeed_tpu.runtime.config import MeshConfig

@@ -1,14 +1,29 @@
 """Accelerator abstraction tests (reference: tests/accelerator/)."""
 
+import jax
 import jax.numpy as jnp
+import pytest
 
-from deepspeed_tpu.accelerator import get_accelerator
+from deepspeed_tpu.accelerator import get_accelerator, real_accelerator
 
 
 def test_singleton_and_name():
     acc = get_accelerator()
     assert acc is get_accelerator()
     assert acc._name in ("tpu", "cpu")
+
+
+def test_backend_error_is_not_reported_as_cpu(monkeypatch):
+    """A backend that fails to initialise must fail the caller: answering
+    "cpu" would silently run every kernel interpreted."""
+
+    def broken_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.delenv("DS_ACCELERATOR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", broken_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        real_accelerator._detect_platform()
 
 
 def test_device_api():
