@@ -116,15 +116,16 @@ def _ffn_body(cfg: TransformerConfig, p, x, norm_scale, norm_bias, tp=None):
     """norm → ffn, NO residual — callers place the residual per architecture."""
     from deepspeed_tpu.moe.experts import apply_dense_ffn
 
-    h = _norm(x, norm_scale, norm_bias, cfg.norm, cfg.norm_eps)
-    if "moe" in p:
-        if tp is not None:
-            raise NotImplementedError(
-                "tensor-parallel MoE serving is not supported: expert "
-                "placement is the 'expert' mesh axis, not a TP weight split"
-            )
-        return _moe_ffn(cfg, p["moe"], h)
-    return apply_dense_ffn(p, h, cfg.activation, tp=tp)
+    with jax.named_scope("mlp"):
+        h = _norm(x, norm_scale, norm_bias, cfg.norm, cfg.norm_eps)
+        if "moe" in p:
+            if tp is not None:
+                raise NotImplementedError(
+                    "tensor-parallel MoE serving is not supported: expert "
+                    "placement is the 'expert' mesh axis, not a TP weight split"
+                )
+            return _moe_ffn(cfg, p["moe"], h)
+        return apply_dense_ffn(p, h, cfg.activation, tp=tp)
 
 
 def _layer_mlp(cfg: TransformerConfig, p, x, tp=None):
@@ -148,11 +149,12 @@ def _post_attention(cfg, p, x, attn, tp=None):
     optionally quantized) all-reduce, and the bias — replicated — is
     added exactly once, after the reduce."""
     B, T = x.shape[:2]
-    a = attn.reshape(B, T, cfg.num_heads * cfg.head_dim)
-    attn = (tp.row_matmul(a, p["wo"]) if tp is not None else qmatmul(a, p["wo"]))
-    attn = attn.astype(x.dtype)
-    if cfg.use_bias:
-        attn = attn + p["bo"].astype(x.dtype)
+    with jax.named_scope("attention"):
+        a = attn.reshape(B, T, cfg.num_heads * cfg.head_dim)
+        attn = (tp.row_matmul(a, p["wo"]) if tp is not None else qmatmul(a, p["wo"]))
+        attn = attn.astype(x.dtype)
+        if cfg.use_bias:
+            attn = attn + p["bo"].astype(x.dtype)
     if cfg.parallel_residual:
         # GPT-J/NeoX: mlp branch reads x (shared ln_1 or its own norm),
         # not the attn-updated residual
@@ -251,15 +253,16 @@ def _final_logits(cfg, params, x):
     head the returned logits are each chip's LOCAL vocab slice — the
     builders resolve greedy tokens through ``tp.argmax`` (global-first-max
     semantics), so full logits never gather."""
-    x = _norm(
-        x, params["final_norm_scale"], params.get("final_norm_bias"), cfg.norm, cfg.norm_eps
-    )
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["tokens"].astype(x.dtype).T
-    else:
-        logits = qmatmul(x, params["lm_head"])
-        if cfg.lm_head_bias:
-            logits = logits + params["lm_head_bias"].astype(logits.dtype)
+    with jax.named_scope("head_sample"):
+        x = _norm(
+            x, params["final_norm_scale"], params.get("final_norm_bias"), cfg.norm, cfg.norm_eps
+        )
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"]["tokens"].astype(x.dtype).T
+        else:
+            logits = qmatmul(x, params["lm_head"])
+            if cfg.lm_head_bias:
+                logits = logits + params["lm_head_bias"].astype(logits.dtype)
     return logits
 
 
@@ -656,6 +659,19 @@ def _paged_program_key(name, cfg, page_size, attn_impl, telemetry, tp=None) -> T
     )
 
 
+def ragged_program_name(rows: int, width: int, tp=None) -> str:
+    """The ``compile_stats()`` key of ``build_ragged_step(cfg, rows, width,
+    ..., tp=tp)`` and, with ``jit_`` in front, its XLA module's name: what
+    the scheduler's ``serve.pack`` / ``serve.dispatch`` spans carry as
+    ``program``."""
+    return _program_name("ragged", rows, width) + _tp_suffix(tp)
+
+
+def multistep_program_name(rows: int, width: int, horizon: int, tp=None) -> str:
+    """Same, for ``build_ragged_multistep``."""
+    return f"{_program_name('multistep', rows, width)}_n{int(horizon)}" + _tp_suffix(tp)
+
+
 def _tp_suffix(tp) -> str:
     """Program-name suffix for tensor-parallel builds: a shard_map-wrapped
     program is a different executable from the single-chip one even at the
@@ -739,32 +755,39 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
         x = x + params["embed"]["pos"].astype(dtype)[positions_b]
     scale = _softmax_scale(cfg, cfg.head_dim)
 
+    # named scopes (here, in ``_post_attention``, ``_ffn_body`` and
+    # ``_final_logits``) put the region into every op's name stack, where a
+    # profiler trace reads it: ``kv_write``, ``attention``, ``mlp``,
+    # ``head_sample``. Names only, nothing computed differently.
     def layer_step(x, per_layer):
         p, kp_l, vp_l = per_layer
-        q, k_new, v_new = _layer_project_qkv(cfg, p, x)
-        if cfg.position == "rope":
-            q = _rope(q, positions_b, cfg.rope_theta, cfg.rope_dim)
-            k_new = _rope(k_new, positions_b, cfg.rope_theta, cfg.rope_dim)
-        kp_l = _scatter_pages(kp_l, k_new.astype(dtype), page_table, positions_b, P,
-                              valid=write_valid)
-        vp_l = _scatter_pages(vp_l, v_new.astype(dtype), page_table, positions_b, P,
-                              valid=write_valid)
+        with jax.named_scope("attention"):
+            q, k_new, v_new = _layer_project_qkv(cfg, p, x)
+            if cfg.position == "rope":
+                q = _rope(q, positions_b, cfg.rope_theta, cfg.rope_dim)
+                k_new = _rope(k_new, positions_b, cfg.rope_theta, cfg.rope_dim)
+        with jax.named_scope("kv_write"):
+            kp_l = _scatter_pages(kp_l, k_new.astype(dtype), page_table, positions_b, P,
+                                  valid=write_valid)
+            vp_l = _scatter_pages(vp_l, v_new.astype(dtype), page_table, positions_b, P,
+                                  valid=write_valid)
         # attn_lens discriminates decode from prefill: a prefill_chunk=1
         # program also has T == 1 but must take the causal-mask path
-        if ragged_q_lens is not None:
-            attn = ragged_paged_attention(
-                q, kp_l, vp_l, page_table, prefill_kv_lens, ragged_q_lens,
-                scale=scale, impl=attn_impl,
-            )
-        elif T == 1 and attn_lens is not None:
-            attn = paged_decode_attention(
-                q[:, 0], kp_l, vp_l, page_table, attn_lens, scale=scale, impl=attn_impl
-            )[:, None]
-        else:
-            attn = paged_prefill_attention(
-                q, kp_l, vp_l, page_table, positions_b, scale=scale,
-                kv_lens=prefill_kv_lens,
-            )
+        with jax.named_scope("attention"):
+            if ragged_q_lens is not None:
+                attn = ragged_paged_attention(
+                    q, kp_l, vp_l, page_table, prefill_kv_lens, ragged_q_lens,
+                    scale=scale, impl=attn_impl,
+                )
+            elif T == 1 and attn_lens is not None:
+                attn = paged_decode_attention(
+                    q[:, 0], kp_l, vp_l, page_table, attn_lens, scale=scale, impl=attn_impl
+                )[:, None]
+            else:
+                attn = paged_prefill_attention(
+                    q, kp_l, vp_l, page_table, positions_b, scale=scale,
+                    kv_lens=prefill_kv_lens,
+                )
         x = _post_attention(cfg, p, x, attn, tp=tp)
         return x, (kp_l, vp_l)
 
@@ -952,7 +975,7 @@ def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: 
             f"multi-step window needs rows >= 1 and horizon >= 2, got "
             f"{rows} rows x horizon {horizon}"
         )
-    name = f"{_program_name('multistep', rows, width)}_n{int(horizon)}" + _tp_suffix(tp)
+    name = multistep_program_name(rows, width, horizon, tp)
     key = _paged_program_key(name, cfg, page_size, attn_impl, telemetry, tp)
     fn = _paged_program_cache.get(key)
     if fn is not None:
@@ -1049,7 +1072,7 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
         raise NotImplementedError("paged serving does not support alibi attention biases")
     if rows < 1 or width < 1:
         raise ValueError(f"ragged step needs rows >= 1 and width >= 1, got {rows}x{width}")
-    name = _program_name("ragged", rows, width) + _tp_suffix(tp)
+    name = ragged_program_name(rows, width, tp)
     key = _paged_program_key(name, cfg, page_size, attn_impl, telemetry, tp)
     fn = _paged_program_cache.get(key)
     if fn is not None:
@@ -1067,14 +1090,15 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
             None, attn_impl, write_valid=valid, prefill_kv_lens=kv_lens,
             ragged_q_lens=q_lens, tp=tp,
         )
-        greedy = (
-            tp.argmax(logits) if tp is not None
-            else jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        )  # [R, W]
-        # verify resolution (inert elsewhere: decode rows have no drafts and
-        # prefill rows' accepted count is ignored by the host)
-        accepted = _accepted_prefix(tokens, greedy, q_lens - 1)
-        packed = jnp.concatenate([accepted[:, None].astype(jnp.int32), greedy], axis=1)
+        with jax.named_scope("head_sample"):
+            greedy = (
+                tp.argmax(logits) if tp is not None
+                else jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            )  # [R, W]
+            # verify resolution (inert elsewhere: decode rows have no drafts and
+            # prefill rows' accepted count is ignored by the host)
+            accepted = _accepted_prefix(tokens, greedy, q_lens - 1)
+            packed = jnp.concatenate([accepted[:, None].astype(jnp.int32), greedy], axis=1)
         return packed, new_k, new_v
 
     body = _step if tp is None else tp.shard_program(_step, n_args=7)

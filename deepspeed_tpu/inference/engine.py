@@ -81,6 +81,9 @@ class InferenceEngine:
         # observability() merges it with compile/analysis/serve stats
         tcfg = self._config.tracing
         self.tracer = Tracer(max_spans=tcfg.max_spans, enabled=tcfg.enabled)
+        # every span() is also an event of the profiler's own trace, on the
+        # device ops' clock (tracer.py itself may not import jax)
+        self.tracer.sink = jax.profiler.TraceAnnotation
         self.metrics = MetricsRegistry()
         self._obs_hub = ObservabilityHub(self.tracer, self.metrics)
         self._obs_hub.add_source("compile", self.compile_stats)

@@ -82,6 +82,8 @@ from deepspeed_tpu.inference.decode import (
     build_paged_verify_step,
     build_ragged_multistep,
     build_ragged_step,
+    multistep_program_name,
+    ragged_program_name,
 )
 from deepspeed_tpu.inference.journal import JournaledRequest, RequestJournal
 from deepspeed_tpu.inference.kv_pool import PagePool
@@ -282,12 +284,19 @@ class PagedServer:
             params = tp.shard_params(cfg, params)
         self.params = params
         # unified tracing (profiling/tracer.py): per-step phase spans
-        # (admit / pack / dispatch / emit / journal_sync) and per-request
+        # (admit / pack / dispatch / emit > fetch, settle / journal_sync,
+        # each also an event of the profiler's trace) and per-request
         # lifecycle spans (submit → admit → first_token → finish, with
         # tenant / prefix-hit / spec-accept attributes). Host-side only —
         # the step's device work stays one enqueue + one budgeted fetch.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics if metrics is not None else MetricsRegistry()
+        # the scheduler's state at the top of each step: the same three
+        # readings go on the ``serve.step`` span and out through
+        # ``monitor_events`` (the registry's gauges)
+        self._g_waiting = self.metrics.gauge("serve.waiting")
+        self._g_running = self.metrics.gauge("serve.running")
+        self._g_pages = self.metrics.gauge("serve.kv_pages_in_use")
         self.prefill_chunk = int(prefill_chunk)
         self.attn_impl = attn_impl
         self.telemetry = telemetry
@@ -765,9 +774,19 @@ class PagedServer:
         of ``horizon`` plain-decode rounds; in bucketed mode one prefill
         dispatch per chunk followed by one decode/verify dispatch over the
         running set."""
-        with self.tracer.span("serve.step"):
-            with self.tracer.span("serve.admit"):
+        waiting, running = len(self._queue), len(self._active)
+        pages_in_use = self.pool.used_pages()
+        self._g_waiting.set(waiting)
+        self._g_running.set(running)
+        self._g_pages.set(pages_in_use)
+        with self.tracer.span(
+            "serve.step", waiting=waiting, running=running,
+            pages_in_use=pages_in_use, pages_total=self.pool.num_pages - 1,
+        ):
+            with self.tracer.span("serve.admit") as admit_span:
+                admitted = self.stats["admitted"]
                 self._admit()
+                admit_span.set(admitted=self.stats["admitted"] - admitted)
             if self.ragged:
                 if not (self.ms_enable and self._ragged_window()):
                     self._ragged_step(drafts=self._take_predrafts())
@@ -853,6 +872,7 @@ class PagedServer:
             self.tracer.instant_async(
                 "request", req.uid, "admit",
                 slot=slot, prefix_cached=cached, admissions=req.admissions,
+                queue_wait_ms=round((self.clock() - req.t_submit) * 1e3, 3),
             )
             self.policy.on_admit(req, self)
 
@@ -977,10 +997,11 @@ class PagedServer:
                     tokens[i, 0] = r.pending
                     tokens[i, 1 : 1 + d.size] = d
                     q_lens[i] = 1 + d.size
-            pack_span.set(rows=len(rows), width=W)
+            program = ragged_program_name(R, W, self.tp)
+            pack_span.set(rows=len(rows), width=W, program=program)
         # dispatch = build + ENQUEUE only (jit returns futures; the fetch
         # below is where device time surfaces)
-        with self.tracer.span("serve.dispatch", rows=len(rows), width=W):
+        with self.tracer.span("serve.dispatch", rows=len(rows), width=W, program=program):
             step_fn = build_ragged_step(
                 self.cfg, R, W, self.pool.page_size, attn_impl=self.attn_impl,
                 telemetry=self.telemetry, tp=self.tp,
@@ -999,8 +1020,16 @@ class PagedServer:
         """Post-dispatch accounting for one ragged step: the budgeted host
         fetch, then per-row advance/emit/publish."""
         # the step's single host fetch: [R, W+1] = accepted counts + the
-        # greedy token after each position
-        out = np.asarray(out)  # lint: allow(DS-R005)
+        # greedy token after each position. ``serve.fetch`` is the wait for
+        # the device, ``serve.settle`` the host's own work after it
+        with self.tracer.span("serve.fetch"):
+            out = np.asarray(out)  # lint: allow(DS-R005)
+        with self.tracer.span("serve.settle") as settle_span:
+            emitted = self.stats["emitted_tokens"]
+            self._settle_fetched_rows(rows, out, chunk_len, q_lens)
+            settle_span.set(tokens=self.stats["emitted_tokens"] - emitted)
+
+    def _settle_fetched_rows(self, rows, out, chunk_len, q_lens) -> None:
         had_decode = had_spec = False
         for i, r in enumerate(rows):
             if r.pending is None:
@@ -1103,9 +1132,10 @@ class PagedServer:
                     if r.eos_token_id is not None:
                         eos_ids[i] = r.eos_token_id
                     budgets[i] = r.max_new_tokens - len(r.generated)  # >= 1
-                pack_span.set(rows=len(rows), horizon=H)
+                program = multistep_program_name(R, 1, H, self.tp)
+                pack_span.set(rows=len(rows), horizon=H, program=program)
             with self.tracer.span("serve.dispatch", rows=len(rows), width=1,
-                                  horizon=H):
+                                  horizon=H, program=program):
                 window_fn = build_ragged_multistep(
                     self.cfg, R, 1, H, self.pool.page_size,
                     attn_impl=self.attn_impl, telemetry=self.telemetry,
@@ -1135,7 +1165,14 @@ class PagedServer:
         row. Rows that froze before the horizon name the window's break
         reason (EOS vs budget); surplus reserved pages go back to the
         pool so a parked reservation never starves the next admission."""
-        out = np.asarray(out)  # lint: allow(DS-R005) — the window's one fetch
+        with self.tracer.span("serve.fetch"):
+            out = np.asarray(out)  # lint: allow(DS-R005) — the window's one fetch
+        with self.tracer.span("serve.settle") as settle_span:
+            emitted = self.stats["emitted_tokens"]
+            self._settle_fetched_window(rows, out, horizon)
+            settle_span.set(tokens=self.stats["emitted_tokens"] - emitted)
+
+    def _settle_fetched_window(self, rows, out, horizon: int) -> None:
         eos_broke = budget_broke = False
         for i, r in enumerate(rows):
             n = int(out[i, 0])

@@ -405,33 +405,37 @@ class TransformerLM(DSModule):
         B, T, H = x.shape
         NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-        # pre-LN (GPT/Llama): norm feeds the block, residual stays unnormed.
-        # post-LN (BERT family): the block reads the residual stream raw and
-        # the norm is applied AFTER adding the residual.
-        if cfg.prenorm:
-            h = _norm(x, p["attn_norm_scale"], p.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
-        else:
-            h = x
-        h = _maybe_quantize_activation(h, "layers/attn_input")
-        q = h @ p["wq"].astype(h.dtype)
-        k = h @ p["wk"].astype(h.dtype)
-        v = h @ p["wv"].astype(h.dtype)
-        if cfg.qkv_bias:
-            q, k, v = q + p["bq"].astype(h.dtype), k + p["bk"].astype(h.dtype), v + p["bv"].astype(h.dtype)
-        q = q.reshape(B, T, NH, D)
-        k = k.reshape(B, T, NKV, D)
-        v = v.reshape(B, T, NKV, D)
-        if cfg.position == "rope":
-            q = _rope(q, positions, cfg.rope_theta, cfg.rope_dim)
-            k = _rope(k, positions, cfg.rope_theta, cfg.rope_dim)
-        rng, r_attn, r_hid, r_mlp = jax.random.split(rng, 4) if rng is not None else (None, None, None, None)
-        attn = self._attention(q, k, v, positions, r_attn, train)
-        attn = attn.reshape(B, T, NH * D) @ p["wo"].astype(h.dtype)
-        if cfg.use_bias:
-            attn = attn + p["bo"].astype(h.dtype)
-        if train and cfg.hidden_dropout > 0 and r_hid is not None:
-            keep = jax.random.bernoulli(r_hid, 1 - cfg.hidden_dropout, attn.shape)
-            attn = attn * keep / (1 - cfg.hidden_dropout)
+        # the two blocks are named scopes (``attention``, ``mlp``, inside the
+        # caller's ``layers``): names on the ops for a profiler trace to
+        # read, nothing computed differently
+        with jax.named_scope("attention"):
+            # pre-LN (GPT/Llama): norm feeds the block, residual stays unnormed.
+            # post-LN (BERT family): the block reads the residual stream raw and
+            # the norm is applied AFTER adding the residual.
+            if cfg.prenorm:
+                h = _norm(x, p["attn_norm_scale"], p.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
+            else:
+                h = x
+            h = _maybe_quantize_activation(h, "layers/attn_input")
+            q = h @ p["wq"].astype(h.dtype)
+            k = h @ p["wk"].astype(h.dtype)
+            v = h @ p["wv"].astype(h.dtype)
+            if cfg.qkv_bias:
+                q, k, v = q + p["bq"].astype(h.dtype), k + p["bk"].astype(h.dtype), v + p["bv"].astype(h.dtype)
+            q = q.reshape(B, T, NH, D)
+            k = k.reshape(B, T, NKV, D)
+            v = v.reshape(B, T, NKV, D)
+            if cfg.position == "rope":
+                q = _rope(q, positions, cfg.rope_theta, cfg.rope_dim)
+                k = _rope(k, positions, cfg.rope_theta, cfg.rope_dim)
+            rng, r_attn, r_hid, r_mlp = jax.random.split(rng, 4) if rng is not None else (None, None, None, None)
+            attn = self._attention(q, k, v, positions, r_attn, train)
+            attn = attn.reshape(B, T, NH * D) @ p["wo"].astype(h.dtype)
+            if cfg.use_bias:
+                attn = attn + p["bo"].astype(h.dtype)
+            if train and cfg.hidden_dropout > 0 and r_hid is not None:
+                keep = jax.random.bernoulli(r_hid, 1 - cfg.hidden_dropout, attn.shape)
+                attn = attn * keep / (1 - cfg.hidden_dropout)
         if cfg.parallel_residual:
             # GPT-J/NeoX: both branches read x — attn already consumed
             # norm1(x) as h; the mlp branch reads the SAME h (GPT-J shared
@@ -441,18 +445,20 @@ class TransformerLM(DSModule):
                 if cfg.shared_parallel_norm
                 else _norm(x, p["mlp_norm_scale"], p.get("mlp_norm_bias"), cfg.norm, cfg.norm_eps)
             )
-            out, aux = self._mlp(p, h_mlp, r_mlp, train)
+            with jax.named_scope("mlp"):
+                out, aux = self._mlp(p, h_mlp, r_mlp, train)
             return x + attn + out, aux
-        if cfg.prenorm:
-            x = x + attn
-            h = _norm(x, p["mlp_norm_scale"], p.get("mlp_norm_bias"), cfg.norm, cfg.norm_eps)
-        else:
-            x = _norm(x + attn, p["attn_norm_scale"], p.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
-            h = x
-        out, aux = self._mlp(p, h, r_mlp, train)
-        if cfg.prenorm:
-            return x + out, aux
-        return _norm(x + out, p["mlp_norm_scale"], p.get("mlp_norm_bias"), cfg.norm, cfg.norm_eps), aux
+        with jax.named_scope("mlp"):
+            if cfg.prenorm:
+                x = x + attn
+                h = _norm(x, p["mlp_norm_scale"], p.get("mlp_norm_bias"), cfg.norm, cfg.norm_eps)
+            else:
+                x = _norm(x + attn, p["attn_norm_scale"], p.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
+                h = x
+            out, aux = self._mlp(p, h, r_mlp, train)
+            if cfg.prenorm:
+                return x + out, aux
+            return _norm(x + out, p["mlp_norm_scale"], p.get("mlp_norm_bias"), cfg.norm, cfg.norm_eps), aux
 
     def _activation_constraint(self, x):
         """Pin [B, T, H] activations to (batch-axes, sequence, None): one
@@ -521,22 +527,27 @@ class TransformerLM(DSModule):
         cfg = self.config
         tokens = jnp.asarray(tokens)
         B, T = tokens.shape
-        if cfg.sparse_embedding_grads:
-            x = self._sparse_embed(params, tokens)
-        else:
-            x = params["embed"]["tokens"].astype(self.dtype)[tokens]
-        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-        if cfg.position == "learned":
-            x = x + params["embed"]["pos"].astype(self.dtype)[positions[0]][None]
-        if cfg.embed_norm:
-            x = _norm(
-                x,
-                params["embed"]["norm_scale"],
-                params["embed"].get("norm_bias"),
-                cfg.norm,
-                cfg.norm_eps,
-            )
-        x = self._activation_constraint(x)
+        # the step's regions are named scopes (``embed``, ``layers`` with
+        # ``attention`` / ``mlp`` inside, ``head_loss``): the name lands in
+        # every op's name stack, forward and backward, where a profiler trace
+        # reads it (``benchmark/op_scopes.py``)
+        with jax.named_scope("embed"):
+            if cfg.sparse_embedding_grads:
+                x = self._sparse_embed(params, tokens)
+            else:
+                x = params["embed"]["tokens"].astype(self.dtype)[tokens]
+            positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
+            if cfg.position == "learned":
+                x = x + params["embed"]["pos"].astype(self.dtype)[positions[0]][None]
+            if cfg.embed_norm:
+                x = _norm(
+                    x,
+                    params["embed"]["norm_scale"],
+                    params["embed"].get("norm_bias"),
+                    cfg.norm,
+                    cfg.norm_eps,
+                )
+            x = self._activation_constraint(x)
 
         base_rng = (rngs or {}).get("dropout") if isinstance(rngs, dict) else rngs
         L = cfg.num_layers
@@ -634,65 +645,67 @@ class TransformerLM(DSModule):
             ltd_body = jax.checkpoint(ltd_body, policy=policy, prevent_cse=False)
 
         aux_total = jnp.zeros((), jnp.float32)
-        if ltd_active:
-            # layer 0 full → LTD layers 1..1+n_ltd on subsets → rest full
-            def run_full(x, rng, aux_total, lo, hi):
-                if hi <= lo:
+        with jax.named_scope("layers"):
+            if ltd_active:
+                # layer 0 full → LTD layers 1..1+n_ltd on subsets → rest full
+                def run_full(x, rng, aux_total, lo, hi):
+                    if hi <= lo:
+                        return x, rng, aux_total
+                    if cfg.scan_layers:
+                        sub = jax.tree_util.tree_map(lambda a: a[lo:hi], params["layers"])
+                        (x, rng), aux = jax.lax.scan(body, (x, rng), sub)
+                        return x, rng, aux_total + jnp.sum(aux)
+                    for i in range(lo, hi):
+                        (x, rng), aux = body((x, rng), self._layer_params(params, i))
+                        aux_total = aux_total + aux
                     return x, rng, aux_total
+
+                x, base_rng, aux_total = run_full(x, base_rng, aux_total, 0, 1)
                 if cfg.scan_layers:
-                    sub = jax.tree_util.tree_map(lambda a: a[lo:hi], params["layers"])
-                    (x, rng), aux = jax.lax.scan(body, (x, rng), sub)
-                    return x, rng, aux_total + jnp.sum(aux)
-                for i in range(lo, hi):
-                    (x, rng), aux = body((x, rng), self._layer_params(params, i))
-                    aux_total = aux_total + aux
-                return x, rng, aux_total
-
-            x, base_rng, aux_total = run_full(x, base_rng, aux_total, 0, 1)
-            if cfg.scan_layers:
-                mid = jax.tree_util.tree_map(
-                    lambda a: a[1 : 1 + n_ltd], params["layers"]
-                )
-                (x, base_rng), aux = jax.lax.scan(ltd_body, (x, base_rng), (mid, ltd_idx))
-                aux_total = aux_total + jnp.sum(aux)
-            else:
-                for j in range(n_ltd):
-                    (x, base_rng), aux = ltd_body(
-                        (x, base_rng), (self._layer_params(params, 1 + j), ltd_idx[j])
+                    mid = jax.tree_util.tree_map(
+                        lambda a: a[1 : 1 + n_ltd], params["layers"]
                     )
+                    (x, base_rng), aux = jax.lax.scan(ltd_body, (x, base_rng), (mid, ltd_idx))
+                    aux_total = aux_total + jnp.sum(aux)
+                else:
+                    for j in range(n_ltd):
+                        (x, base_rng), aux = ltd_body(
+                            (x, base_rng), (self._layer_params(params, 1 + j), ltd_idx[j])
+                        )
+                        aux_total = aux_total + aux
+                x, base_rng, aux_total = run_full(x, base_rng, aux_total, 1 + n_ltd, L)
+            elif cfg.scan_layers and (
+                overlap_plan is not None
+                and overlap_plan.prefetch_enabled
+                and not pld_active
+            ):
+                x, aux_total = self._pipelined_layer_scan(
+                    overlap_plan, params["layers"], x, base_rng, positions, train
+                )
+            elif cfg.scan_layers:
+                xs = (
+                    (params["layers"], jnp.arange(L, dtype=jnp.int32))
+                    if pld_active
+                    else params["layers"]
+                )
+                (x, _), aux_per_layer = jax.lax.scan(body, (x, base_rng), xs)
+                aux_total = jnp.sum(aux_per_layer)
+            else:
+                for i in range(L):
+                    per = self._layer_params(params, i)
+                    scanned = (per, jnp.int32(i)) if pld_active else per
+                    (x, base_rng), aux = body((x, base_rng), scanned)
                     aux_total = aux_total + aux
-            x, base_rng, aux_total = run_full(x, base_rng, aux_total, 1 + n_ltd, L)
-        elif cfg.scan_layers and (
-            overlap_plan is not None
-            and overlap_plan.prefetch_enabled
-            and not pld_active
-        ):
-            x, aux_total = self._pipelined_layer_scan(
-                overlap_plan, params["layers"], x, base_rng, positions, train
-            )
-        elif cfg.scan_layers:
-            xs = (
-                (params["layers"], jnp.arange(L, dtype=jnp.int32))
-                if pld_active
-                else params["layers"]
-            )
-            (x, _), aux_per_layer = jax.lax.scan(body, (x, base_rng), xs)
-            aux_total = jnp.sum(aux_per_layer)
-        else:
-            for i in range(L):
-                per = self._layer_params(params, i)
-                scanned = (per, jnp.int32(i)) if pld_active else per
-                (x, base_rng), aux = body((x, base_rng), scanned)
-                aux_total = aux_total + aux
 
-        if cfg.prenorm:
-            x = _norm(x, params["final_norm_scale"], params.get("final_norm_bias"), cfg.norm, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"]["tokens"].astype(self.dtype).T
-        else:
-            logits = x @ params["lm_head"].astype(self.dtype)
-            if cfg.lm_head_bias:
-                logits = logits + params["lm_head_bias"].astype(logits.dtype)
+        with jax.named_scope("head_loss"):
+            if cfg.prenorm:
+                x = _norm(x, params["final_norm_scale"], params.get("final_norm_bias"), cfg.norm, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = x @ params["embed"]["tokens"].astype(self.dtype).T
+            else:
+                logits = x @ params["lm_head"].astype(self.dtype)
+                if cfg.lm_head_bias:
+                    logits = logits + params["lm_head_bias"].astype(logits.dtype)
         return logits, aux_total
 
     def _scan_layer_step(self, x, per_layer, positions, rng, train):
@@ -814,7 +827,8 @@ class TransformerLM(DSModule):
         )
         if labels is None:
             return logits
-        loss = cross_entropy_loss(logits, labels)
+        with jax.named_scope("head_loss"):
+            loss = cross_entropy_loss(logits, labels)
         if train:
             # aux is the (already coefficient-scaled) MoE load-balance loss;
             # zero for dense families. Train-only, so eval loss stays pure CE

@@ -16,6 +16,10 @@ attention the reference approximates with masking).
 The serving layer reaches the page-table variant (``paged_decode_attention``
 below) through ``ops/transformer/paged_attention.py``, which fronts it with
 an XLA gather fallback and the chunk-prefill attention.
+
+Each ``pallas_call`` carries its entry point's name (``decode_attention``,
+``paged_decode_attention``, ``ragged_paged_attention``): a profiler trace
+finds the kernel by it (``benchmark/op_scopes.py``).
 """
 
 from __future__ import annotations
@@ -196,6 +200,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NKV, Hg, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
         **params,
     )(jnp.asarray(page_table, jnp.int32), lens, qg, k_pages, v_pages)
     return o.reshape(B, NH, D)
@@ -310,6 +315,7 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, NKV, W * Hg, D), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
         **params,
     )(jnp.asarray(page_table, jnp.int32), lens, qlens, qg, k_pages, v_pages)
     return o.reshape(R, NKV, W, Hg, D).transpose(0, 2, 1, 3, 4).reshape(R, W, NH, D)
@@ -355,6 +361,7 @@ def _grouped_decode(q, k_cache, v_cache, lens, scale_f, blk, nk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * NKV, Hg, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
         **params,
     )(lens_g, qg, kg, vg)
     return o.reshape(B, NKV, Hg, D).reshape(B, NH, D)

@@ -21,6 +21,11 @@ layout jax's own TPU flash kernels use for l/m residuals): Mosaic requires
 the last dim to tile to 128, so the broadcast buys tileability at T*512B of
 HBM per (b, n) row per residual — real but small next to activations, and
 only alive between fwd and bwd of one layer.
+
+The three ``pallas_call``s are named (``flash_fwd``, ``flash_bwd_dq``,
+``flash_bwd_dkv``): the name is the custom call's instruction name in the
+compiled HLO and a component of its op name stack, which is how a profiler
+trace finds each kernel (``benchmark/op_scopes.py``).
 """
 
 from __future__ import annotations
@@ -149,6 +154,7 @@ def _flash_fwd(q, k, v, scale, causal, blk_q, blk_k, interpret):
             pltpu.VMEM((blk_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
         **params,
     )(q, k, v)
     return o, lse[:, :, 0]
@@ -255,6 +261,7 @@ def _flash_bwd(res, g, scale, causal, blk_q, blk_k, interpret):
         out_shape=jax.ShapeDtypeStruct((BN, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
         **params,
     )(q, k, v, do, lse, delta)
 
@@ -283,6 +290,7 @@ def _flash_bwd(res, g, scale, causal, blk_q, blk_k, interpret):
             pltpu.VMEM((blk_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **params,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -142,7 +142,10 @@ class InstrumentedFunction:
             stats.traces += 1
             return fn(*args, **kwargs)
 
-        traced.__name__ = getattr(fn, "__name__", stats.name)
+        # the telemetry name is the program's name everywhere: the
+        # compile_stats() key, the XLA module (``jit_<name>``) and, through
+        # it, the module line and the op name stacks of a profiler trace
+        traced.__name__ = traced.__qualname__ = stats.name
         self._jitted = jax.jit(traced, **jit_kwargs)
 
     def __call__(self, *args, **kwargs):

@@ -17,6 +17,15 @@ is the shared timeline + metrics substrate underneath all of them:
   concurrently. The hard hot-path contract (enforced by the
   telemetry-is-free tests): tracing performs **zero host↔device transfers
   and compiles zero new programs** — nothing in this file imports jax.
+  ``Tracer.sink`` is the spans' second destination: the engines (which do
+  import jax) set it to ``jax.profiler.TraceAnnotation``, so that every
+  ``span()`` is also an event of the profiler's own trace, **on the clock
+  the device ops are on**, with its attributes as the event's stats. The
+  ring buffer is what the flight recorder, ``phase_summary`` and the
+  Chrome export read; the profiler's trace is what ``benchmark/`` reads.
+  ``add_span`` (explicit stamps: ``timer.*``, ``comm.*``, ``infer.*``),
+  instants and the asynchronous request lifecycles cannot be annotations
+  (an annotation is a scope on one thread) and stay ring-buffer only.
 * :class:`MetricsRegistry` — named counters / gauges / fixed-bucket
   histograms (p50/p99 via bucket interpolation), thread-safe, cheap enough
   for per-step observation.
@@ -119,7 +128,7 @@ class _Span:
     tracks nesting depth through the tracer's per-thread stack, and appends
     one completed record to the ring buffer on exit."""
 
-    __slots__ = ("_tr", "name", "attrs", "t0", "t1", "depth")
+    __slots__ = ("_tr", "name", "attrs", "t0", "t1", "depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[Dict]):
         self._tr = tracer
@@ -128,18 +137,27 @@ class _Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.depth = 0
+        self._ann = None  # the sink's scope for this span, while it is open
 
     def __enter__(self) -> "_Span":
         tr = self._tr
         stack = tr._stack()
         self.depth = len(stack)
         stack.append(self)  # the stack IS the open-span registry (no lock)
+        sink = tr.sink
+        if sink is not None:
+            # outside a profiler session this is one inactive-TraceMe check
+            ann = self._ann = sink(self.name, **self.attrs) if self.attrs else sink(self.name)
+            ann.__enter__()
         self.t0 = tr.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tr
         self.t1 = tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -165,6 +183,8 @@ class _Span:
             self.attrs = attrs
         else:
             self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     @property
@@ -178,7 +198,11 @@ class Tracer:
     ``enabled=False`` makes every recording call a near-free no-op (the
     shared :data:`_NULL_SPAN` / an early return); flipping ``enabled`` at
     runtime is safe (the bench uses it to measure tracing overhead).
-    ``clock`` is injectable for tests; it must be monotonic.
+    ``clock`` is injectable for tests; it must be monotonic. ``sink``
+    (attribute, default None) is called as ``sink(name, **attrs)`` for every
+    ``span()`` and must return a context manager with
+    ``set_metadata(**attrs)``: the engines set it to
+    ``jax.profiler.TraceAnnotation``, which this module may not import.
     """
 
     def __init__(
@@ -191,6 +215,7 @@ class Tracer:
             raise ValueError(f"max_spans must be >= 1, got {max_spans}")
         self.enabled = bool(enabled)
         self.clock = clock
+        self.sink: Optional[Callable[..., Any]] = None
         self.max_spans = int(max_spans)
         self._buf: deque = deque(maxlen=self.max_spans)
         self._total = 0
@@ -228,7 +253,9 @@ class Tracer:
 
     def add_span(self, name: str, t0: float, t1: float, **attrs) -> None:
         """Record a span from explicit clock() stamps (the timer module and
-        the comm wrappers route through this — they own their own timing)."""
+        the comm wrappers route through this — they own their own timing).
+        Ring buffer only: a scope that has already ended cannot be handed to
+        ``sink``."""
         if not self.enabled:
             return
         self._append(
@@ -364,6 +391,12 @@ class Tracer:
     ) -> str:
         """Write the ring buffer as Trace Event Format JSON (the format
         ``chrome://tracing`` and https://ui.perfetto.dev load directly).
+        This timeline is the host's ``perf_counter`` and holds **no device
+        operation**: it is the one view of the request lifecycles (``b`` /
+        ``n`` / ``e`` events), of ``add_span`` records and of the pure-host
+        fleet router. Where the question is what the device did under a
+        span, take a ``jax.profiler`` trace instead: ``span()`` also lands
+        there (``Tracer.sink``), on the device's clock.
         Span times become microsecond offsets from the tracer's anchor;
         the wall-clock anchor and an optional metrics snapshot ride in
         ``otherData``. Returns the written path. The write is
@@ -460,10 +493,6 @@ class Counter:
         with self._lock:
             self._v += n
 
-    @property
-    def value(self) -> float:
-        return self._v
-
     def snapshot(self) -> float:
         return self._v
 
@@ -481,10 +510,6 @@ class Gauge:
     def set(self, v: float) -> None:
         with self._lock:
             self._v = float(v)
-
-    @property
-    def value(self) -> float:
-        return self._v
 
     def snapshot(self) -> float:
         return self._v
@@ -530,10 +555,6 @@ class Histogram:
                 self._min = v
             if v > self._max:
                 self._max = v
-
-    @property
-    def count(self) -> int:
-        return self._count
 
     def percentile(self, p: float) -> float:
         """Interpolated percentile in [0, 100]; 0.0 when empty."""
@@ -624,10 +645,6 @@ class MetricsRegistry:
             else:
                 out["histograms"][name] = m.snapshot()
         return out
-
-    def reset(self) -> None:
-        with self._lock:
-            self._metrics.clear()
 
 
 # ---------------------------------------------------------------------------

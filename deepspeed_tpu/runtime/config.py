@@ -241,7 +241,12 @@ class TracingConfig(DeepSpeedConfigModel):
     device transfers and zero compiled programs, and measures under 2%
     of a bench step) records step-phase spans and engine metrics into a
     ``max_spans``-deep ring buffer, readable via ``engine.observability()``
-    and exportable as a Perfetto/Chrome trace. ``flight_recorder`` arms the
+    and exportable as a Perfetto/Chrome trace. That export is on the host's
+    ``perf_counter`` and holds no device operation; while a
+    ``jax.profiler`` session is active every span is ALSO an event of the
+    profiler's trace, on the device ops' clock, with its attributes (no
+    extra key: the sink is on whenever the tracer is, and costs one inactive
+    ``TraceMe`` check per span outside a session). ``flight_recorder`` arms the
     crash postmortem: on interpreter exit and on every ``utils/chaos.py``
     fault injection the last ``flight_recorder_spans`` spans + a metrics
     snapshot are dumped to ``flight_recorder_dir`` (required when armed)."""

@@ -253,6 +253,9 @@ class DeepSpeedEngine:
         # transfers, zero compiled programs (guarded by tests).
         tcfg = self._config.tracing_config
         self.tracer = Tracer(max_spans=tcfg.max_spans, enabled=tcfg.enabled)
+        # every span() is also an event of the profiler's own trace, on the
+        # device ops' clock (tracer.py itself may not import jax)
+        self.tracer.sink = jax.profiler.TraceAnnotation
         self.metrics = MetricsRegistry()
         self._obs_hub = ObservabilityHub(self.tracer, self.metrics)
         self._obs_hub.add_source("compile", self.compile_stats)
@@ -1202,14 +1205,16 @@ class DeepSpeedEngine:
                 return loss_of(p, batch, rng, model_kwargs) * scale.astype(jnp.float32)
 
             loss_scaled, grads = jax.value_and_grad(scaled_loss)(params)
-            # accumulate in the buffer's dtype (grad_accum_dtype; fp32 default)
-            new_acc = jax.tree_util.tree_map(
-                lambda a, g, s: jax.lax.with_sharding_constraint(a + g.astype(a.dtype), NamedSharding(mesh, s)),
-                grad_acc,
-                grads,
-                grad_specs,
-                is_leaf=lambda x: isinstance(x, PartitionSpec),
-            )
+            # accumulate in the buffer's dtype (grad_accum_dtype; fp32 default);
+            # the pin to the grad layout is where the reduction lands
+            with jax.named_scope("grad_reduce"):
+                new_acc = jax.tree_util.tree_map(
+                    lambda a, g, s: jax.lax.with_sharding_constraint(a + g.astype(a.dtype), NamedSharding(mesh, s)),
+                    grad_acc,
+                    grads,
+                    grad_specs,
+                    is_leaf=lambda x: isinstance(x, PartitionSpec),
+                )
             return loss_scaled / scale.astype(jnp.float32), new_acc
 
         if qgz:
@@ -1262,30 +1267,33 @@ class DeepSpeedEngine:
             Overflow check, global-norm clip, optimizer apply, overflow-revert
             (a ``where``, not a host sync), compute-dtype re-cast, loss-scale
             update. Used by both the standalone step and the fused micro-step
-            so the update math lives in exactly one place."""
-            overflow = has_inf_or_nan(grads32) if fp16 else jnp.zeros((), jnp.bool_)
-            # global grad norm: full reductions over sharded leaves are global
-            sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree_util.tree_leaves(grads32))
-            grad_norm = jnp.sqrt(sq)
-            if clip > 0:
-                coef = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                grads32 = jax.tree_util.tree_map(lambda g: g * coef, grads32)
-            new_master, new_opt = optimizer.apply(grads32, opt_state, master, jnp.float32(lr))
-            new_master = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(overflow, o, n), new_master, master
-            )
-            new_opt = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(overflow, o, n), new_opt, opt_state
-            )
-            if mixed:
-                # re-cast to each param's stored dtype (keep_fp32_params leaves
-                # stay fp32; everything else is the compute dtype)
-                new_params = jax.tree_util.tree_map(
-                    lambda m, p: jnp.where(overflow, p, m.astype(p.dtype)), new_master, params
+            so the update math lives in exactly one place. All of it is the
+            named scope ``optimizer`` (a name on the ops, for a profiler
+            trace)."""
+            with jax.named_scope("optimizer"):
+                overflow = has_inf_or_nan(grads32) if fp16 else jnp.zeros((), jnp.bool_)
+                # global grad norm: full reductions over sharded leaves are global
+                sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree_util.tree_leaves(grads32))
+                grad_norm = jnp.sqrt(sq)
+                if clip > 0:
+                    coef = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
+                    grads32 = jax.tree_util.tree_map(lambda g: g * coef, grads32)
+                new_master, new_opt = optimizer.apply(grads32, opt_state, master, jnp.float32(lr))
+                new_master = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(overflow, o, n), new_master, master
                 )
-            else:
-                new_params = new_master
-            new_scale_state = scaler.update(scale_state, overflow)
+                new_opt = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(overflow, o, n), new_opt, opt_state
+                )
+                if mixed:
+                    # re-cast to each param's stored dtype (keep_fp32_params leaves
+                    # stay fp32; everything else is the compute dtype)
+                    new_params = jax.tree_util.tree_map(
+                        lambda m, p: jnp.where(overflow, p, m.astype(p.dtype)), new_master, params
+                    )
+                else:
+                    new_params = new_master
+                new_scale_state = scaler.update(scale_state, overflow)
             return new_params, new_master, new_opt, new_scale_state, grad_norm, overflow
 
         def step_fn(params_or_none, master, opt_state, grad_acc, scale_state, lr):
@@ -1348,15 +1356,16 @@ class DeepSpeedEngine:
                         return loss_of(p, mb, r, model_kwargs) * scale.astype(jnp.float32)
 
                     loss_scaled, g = jax.value_and_grad(scaled_loss)(params)
-                    acc = jax.tree_util.tree_map(
-                        lambda a, gg, s: jax.lax.with_sharding_constraint(
-                            a + gg.astype(a.dtype), NamedSharding(mesh, s)
-                        ),
-                        acc,
-                        g,
-                        grad_specs,
-                        is_leaf=lambda x: isinstance(x, PartitionSpec),
-                    )
+                    with jax.named_scope("grad_reduce"):
+                        acc = jax.tree_util.tree_map(
+                            lambda a, gg, s: jax.lax.with_sharding_constraint(
+                                a + gg.astype(a.dtype), NamedSharding(mesh, s)
+                            ),
+                            acc,
+                            g,
+                            grad_specs,
+                            is_leaf=lambda x: isinstance(x, PartitionSpec),
+                        )
                     return acc, loss_scaled / scale.astype(jnp.float32)
 
                 zero_acc = jax.tree_util.tree_map(
@@ -1913,7 +1922,7 @@ class DeepSpeedEngine:
                 self._profile_fn = self._jit_fused_step
             # dispatch ENQUEUE only: jit returns futures; device time shows
             # up at the next blocking fetch, never as a sync here
-            with self.tracer.span("train.dispatch", program="fused_step"):
+            with self.tracer.span("train.dispatch", program="fused_step", step=self.global_steps):
                 out = self._jit_fused_step(*fwd_args)
             # the inputs were donated — adopt the new state immediately so the
             # engine never holds references to deleted buffers
@@ -2752,9 +2761,18 @@ class DeepSpeedEngine:
         window's mean loss as a host scalar (same contract as the unfused
         loop)."""
         gas = self.gradient_accumulation_steps()
+        # the whole fused optimizer step, host-side wall clock (the loss
+        # fetch closes it — the one sanctioned blocking read, so this
+        # includes the device time the dispatch hid)
+        with self.tracer.span("train.step", gas=gas, fused=True) as step_span:
+            val = self._fused_train_step(micro, gas)
+        if self.tracer.enabled:
+            self.metrics.histogram("train.step_ms").observe(step_span.duration_ms)
+        return val
+
+    def _fused_train_step(self, micro, gas: int):
         self.tput_timer.start()
         self.timers(FORWARD_GLOBAL_TIMER).start()
-        t_step0 = self.tracer.clock()
         if self.curriculum_scheduler is not None:
             seqlen = self.curriculum_scheduler.update_difficulty(self.global_steps + 1)
             micro = [_truncate_seq(b, seqlen) for b in micro]
@@ -2763,7 +2781,9 @@ class DeepSpeedEngine:
         model_kwargs = self._model_kwargs()  # pld theta; random-LTD is gated off
         parent_rng = self._rng
         lr = self.optimizer.param_groups[0]["lr"]
-        dispatch_span = self.tracer.span("train.dispatch", program="fused_accum_step")
+        dispatch_span = self.tracer.span(
+            "train.dispatch", program="fused_accum_step", step=self.global_steps
+        )
         if self.mixed_precision:
             with dispatch_span:
                 out = self._jit_fused_accum_step(
@@ -2824,15 +2844,7 @@ class DeepSpeedEngine:
         self.tput_timer.stop(global_step=True)
         with self.tracer.span("train.loss_fetch"):
             _enqueue_host_copies((loss,))
-            val = jax.device_get(loss)
-        if self.tracer.enabled:
-            # the whole fused optimizer step, host-side wall clock (the
-            # loss fetch closes the window — the one sanctioned blocking
-            # read, so this includes the device time the dispatch hid)
-            t_now = self.tracer.clock()
-            self.tracer.add_span("train.step", t_step0, t_now, gas=gas, fused=True)
-            self.metrics.histogram("train.step_ms").observe((t_now - t_step0) * 1e3)
-        return val
+            return jax.device_get(loss)
 
     def _split_step_batch(self, batch, gas: int):
         """Slice a full-step batch into gas microbatches along the leading dim."""
@@ -3009,7 +3021,7 @@ class DeepSpeedEngine:
                     exp_avg_sq=ho.unflatten(g_vs),
                 )
             window_name = f"fused_window_step_n{H}"
-            with self.tracer.span("train.dispatch", program=window_name):
+            with self.tracer.span("train.dispatch", program=window_name, step=self.global_steps):
                 if self.mixed_precision:
                     (
                         self._params,

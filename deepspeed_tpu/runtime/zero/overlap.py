@@ -222,7 +222,8 @@ class OverlapPlan:
             return tree, None
 
         def _bwd(_, g):
-            return (self._coalesce_cotangent(g),)
+            with jax.named_scope("grad_reduce"):
+                return (self._coalesce_cotangent(g),)
 
         _reduce_boundary.defvjp(_fwd, _bwd)
         return _reduce_boundary(per_layer)

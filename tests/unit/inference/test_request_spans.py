@@ -104,6 +104,60 @@ def test_request_lifecycle_submit_admit_first_token_finish(model_and_params):
     assert m.snapshot()["histograms"]["serve.ttft_ms"]["count"] == 3
 
 
+def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_params, tmp_path):
+    """With the sink the engines install (``jax.profiler.TraceAnnotation``),
+    a serving run under a profiler session leaves the scheduler's phases in
+    the host plane of the ``.xplane.pb``, nested as the program nests them
+    and carrying the step's counts; the same three readings are the
+    registry's gauges; the request's admit instant carries its queue wait."""
+    from jax.profiler import ProfileData
+
+    cfg, _, params = model_and_params
+    tr, m = Tracer(), MetricsRegistry()
+    tr.sink = jax.profiler.TraceAnnotation
+    server = _server(cfg, params, tracer=tr, metrics=m)
+    server.serve(_prompts(1, seed=11), max_new_tokens=2)  # compile outside the session
+    warm_steps = tr.phase_summary()["serve.step"]["count"]
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        uids = [server.submit(p, max_new_tokens=3) for p in _prompts(3, seed=12)]
+        server.step()
+        waiting_then = m.snapshot()["gauges"]["serve.waiting"]
+        server.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+        for plane in ProfileData.from_file(str(path)).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events if ev.name.startswith("serve.")
+    ]
+    steps = [e for e in events if e[0] == "serve.step"]
+    assert len(steps) == tr.phase_summary()["serve.step"]["count"] - warm_steps  # every traced step, no other
+
+    def inside(outer, name):
+        return [e for e in events if e[0] == name and outer[1] <= e[1] and e[2] <= outer[2]]
+
+    first = steps[0]
+    assert first[3] == {"waiting": 3, "running": 0, "pages_in_use": 0, "pages_total": server.pool.num_pages - 1}
+    assert waiting_then == 3.0 and m.snapshot()["gauges"]["serve.running"] > 0
+    (admit,), (pack,), (dispatch,), (emit,) = (inside(first, n) for n in ("serve.admit", "serve.pack", "serve.dispatch", "serve.emit"))
+    assert admit[3] == {"admitted": 3}
+    program = f"paged_ragged_r{server.pool.max_slots}_w8"
+    assert pack[3] == dispatch[3] == {"rows": 3, "width": 8, "program": program}
+    assert admit[2] <= pack[1] and pack[2] <= dispatch[1] and dispatch[2] <= emit[1]
+    (fetch,), (settle,) = inside(emit, "serve.fetch"), inside(emit, "serve.settle")
+    assert fetch[2] <= settle[1]
+    emitted = sum(e[3]["tokens"] for e in events if e[0] == "serve.settle")
+    assert emitted == 9 == sum(len(server.take_result(u)) for u in uids) - sum(p.size for p in _prompts(3, seed=12))
+    # a later step sees the running set and its pages
+    assert steps[-1][3]["running"] >= 1 and steps[-1][3]["pages_in_use"] >= 1
+    admits = [r for r in tr.spans() if r["ph"] == "n" and r["name"] == "admit" and r.get("id") in uids]
+    assert len(admits) == 3 and all(r["attrs"]["queue_wait_ms"] >= 0.0 for r in admits)
+
+
 def test_preemption_leaves_preempt_instant_and_readmission(model_and_params):
     """A pool sized to force recompute-preemption: the victim's span trail
     shows preempt → admit again, and its finish attrs count both
@@ -128,9 +182,12 @@ def test_preemption_leaves_preempt_instant_and_readmission(model_and_params):
 
 
 def test_spec_decode_attrs_on_finish(model_and_params):
-    """With the n-gram drafter engaged on a motif prompt, the request's
-    finish span reports how many drafts it sent and how many were
-    accepted (the per-request speculation story)."""
+    """With the n-gram drafter engaged, the request's finish span reports
+    how many drafts it sent and how many were accepted (the per-request
+    speculation story). The drafter proposes only where the context's own
+    suffix occurred before, and this model does not continue the prompt's
+    motif: its greedy stream enters a cycle of seven tokens from its third
+    token on, so the budget has to reach the cycle's second turn."""
     cfg, _, params = model_and_params
     tr = Tracer()
     server = _server(
@@ -139,9 +196,10 @@ def test_spec_decode_attrs_on_finish(model_and_params):
     )
     server.tracer = tr
     motif = np.array([5, 9, 5, 9, 5, 9, 5, 9, 5, 9], np.int32)
-    uid = server.submit(motif, max_new_tokens=8)
+    uid = server.submit(motif, max_new_tokens=24)
     server.run()
     assert server.stats["spec_drafted"] > 0  # the drafter engaged
+    assert server.stats["spec_accepted"] > 0  # and the cycle made it right
     end = [r for r in tr.spans() if r["ph"] == "e" and r.get("id") == uid][0]
     assert end["attrs"]["spec_drafted"] == server.stats["spec_drafted"]
     assert end["attrs"]["spec_accepted"] == server.stats["spec_accepted"]
@@ -197,6 +255,12 @@ def test_engine_observability_merged_report_and_trace(model_and_params, tmp_path
     assert rep["timeline"]["phases"]["serve.step"]["count"] >= 1
     assert rep["serve"]["finished"] == 3
     assert any(n.startswith("paged_") for n in rep["compile"])
+    # a program's XLA module carries its compile_stats() key: what the
+    # profiler's module line and the serve.dispatch span's `program` agree on
+    for name in rep["compile"]:
+        if name.startswith("paged_ragged"):
+            assert f"module @jit_{name} " in engine._telemetry.lowered_text(name)[:200]
+    assert engine.tracer.sink is jax.profiler.TraceAnnotation
     # the analysis merge is the real report (violations counted), not a stub
     assert rep["analysis"]["totals"]["violations"] == 0
     # Perfetto trace for a serving run

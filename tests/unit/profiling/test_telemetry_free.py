@@ -24,6 +24,7 @@ from __future__ import annotations
 import json
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -184,6 +185,20 @@ def test_fused_accum_step_phase_breakdown(eight_devices):
         assert phases[name]["count"] == 2, (name, phases.get(name))
     hist = engine.metrics.snapshot()["histograms"]["train.step_ms"]
     assert hist["count"] == 2 and hist["p50"] > 0
+    # train.step is a span that was open around its phases (so that the sink,
+    # jax.profiler.TraceAnnotation, sees it too), not a record stamped after
+    recs = [r for r in engine.tracer.spans() if r["ph"] == "X"]
+    steps = [r for r in recs if r["name"] == "train.step"]
+    dispatches = [r for r in recs if r["name"] == "train.dispatch"]
+    assert [r["depth"] for r in steps] == [0, 0] and [r["depth"] for r in dispatches] == [1, 1]
+    assert all(s["t0"] <= d["t0"] and d["t1"] <= s["t1"] for s, d in zip(steps, dispatches))
+    assert [d["attrs"] for d in dispatches] == [
+        {"program": "fused_accum_step", "step": 0}, {"program": "fused_accum_step", "step": 1}
+    ]
+    assert engine.tracer.sink is jax.profiler.TraceAnnotation
+    # the program's XLA module is named after its compile_stats() key
+    assert "fused_accum_step" in engine.compile_stats()
+    assert "module @jit_fused_accum_step " in engine._telemetry.lowered_text("fused_accum_step")[:200]
 
 
 def test_ckpt_d2h_stall_span_and_writer_spans(eight_devices, tmp_path):
