@@ -340,7 +340,8 @@ def generate_reference(engine, prompts, budgets):
 
 def attention_parity(sz, seed: int) -> dict:
     """``ragged_paged_attention`` Pallas vs XLA on seeded inputs at the
-    server's two window shapes, live rows and slots only."""
+    server's two window shapes: the outputs of live rows and slots, and the
+    bytes both leave in every page but the trash page (layer 1 of two)."""
     from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
 
     cfg, paged = sz.serve_model, sz.paged
@@ -348,28 +349,35 @@ def attention_parity(sz, seed: int) -> dict:
     maxp = cfg.max_seq_len // page
     n_pages = rows * maxp + 1
     rs = np.random.RandomState(seed + 2)
-    shape = (n_pages, cfg.num_kv_heads, page, cfg.head_dim)
+    shape = (2, n_pages, cfg.num_kv_heads, page, cfg.head_dim)
     k_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
     v_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
     table = jnp.asarray(1 + rs.permutation(rows * maxp).reshape(rows, maxp), jnp.int32)
     worst = {}
     for width in (1, paged["prefill_chunk"]):
         q = jnp.asarray(rs.randn(rows, width, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
+        k_new, v_new = (
+            jnp.asarray(rs.randn(rows, width, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+            for _ in range(2)
+        )
         q_lens = rs.randint(1, width + 1, (rows,)).astype(np.int32)
         q_lens[-1] = 0  # a dead row
         kv_lens = np.where(
             q_lens > 0, q_lens + rs.randint(0, cfg.max_seq_len - width, (rows,)), 0
         ).astype(np.int32)
-        out = {
-            impl: np.asarray(
+        got = {
+            impl: jax.device_get(
                 ragged_paged_attention(
-                    q, k_pages, v_pages, table, jnp.asarray(kv_lens), jnp.asarray(q_lens), impl=impl
-                ).astype(jnp.float32)
+                    q, k_new, v_new, k_pages, v_pages, 1, table, jnp.asarray(kv_lens),
+                    jnp.asarray(q_lens), impl=impl,
+                )
             )
             for impl in ("pallas", "xla")
         }
+        for pool in (1, 2):  # the same bytes in every page but the trash page, in both layers
+            assert np.array_equal(got["pallas"][pool][:, 1:], got["xla"][pool][:, 1:])
         live = np.arange(width)[None, :] < q_lens[:, None]
-        a, b = out["pallas"][live], out["xla"][live]
+        a, b = (got[impl][0].astype(np.float32)[live] for impl in ("pallas", "xla"))
         assert np.isfinite(a).all() and np.isfinite(b).all()
         np.testing.assert_allclose(a, b, atol=BF16_ATTN_TOL, rtol=BF16_ATTN_TOL)
         worst[f"w{width}"] = float(np.abs(a - b).max())

@@ -701,55 +701,44 @@ def _accepted_prefix(tokens, greedy, n_drafts):
     return jnp.sum(jnp.cumprod(matches.astype(jnp.int32), axis=1), axis=1)
 
 
-def _scatter_pages(pages_l, vals, page_table, positions, page_size, valid=None):
-    """Write [B, T, NKV, D] new k/v rows into one layer's page pool
-    [NP, NKV, P, D] at absolute ``positions`` [B, T] through the page table
-    [B, MAXP]. Sentinel table entries (< 0, i.e. unallocated/dead rows)
-    clamp onto the reserved trash page 0, so padded bucket rows and prompt
-    pad tails write garbage only where nothing lives. ``valid`` (bool
-    [B, T], optional) force-redirects masked positions onto the trash page
-    regardless of the table: the verify program's pad draft slots sit past
-    a row's ensured pages, where ``positions // page_size`` could alias a
-    LIVE page after the maxp clamp."""
-    NP = pages_l.shape[0]
-    maxp = page_table.shape[1]
-    slot = jnp.clip(positions // page_size, 0, maxp - 1)
-    pid = jnp.clip(jnp.take_along_axis(page_table, slot, axis=1), 0, NP - 1)
-    if valid is not None:
-        pid = jnp.where(valid, pid, 0)  # page 0 = the reserved trash page
-    off = positions % page_size
-    # advanced-index scatter: (pid, off) broadcast to [B, T] and land first,
-    # giving the [B, T, NKV, D] update window vals fills exactly
-    return pages_l.at[pid, :, off, :].set(vals)
-
-
 def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
                    attn_lens, attn_impl, write_valid=None, prefill_kv_lens=None,
                    ragged_q_lens=None, tp=None):
-    """Forward [B, T] tokens against the paged cache: scatter each token's
+    """Forward [B, T] tokens against the paged cache: write each token's
     k/v into its page, then attend — single-token rows (T == 1) through the
     paged decode kernel with live lengths ``attn_lens``, chunks through the
     causal prefill attention (mask from ``positions_b``). ``write_valid``
     ([B, T] bool) redirects masked positions' k/v writes to the trash page;
     ``prefill_kv_lens`` ([B]) additionally bounds the causal attention to
     each row's live kv prefix (the verify program's pad-slot safety).
-    ``ragged_q_lens`` ([B]) switches the attention to the unified ragged
-    entry (mixed prefill/decode/verify rows, per-row metadata — the
-    one-program serving step). ``tp`` (a ``inference/tp.py:TPServing``)
-    marks the body as running INSIDE shard_map on a tensor-parallel mesh:
-    ``cfg`` is then the local per-shard view (heads and kv pages sliced on
-    the head axes), the row-parallel projections all-reduce through the
-    context, and the returned logits may be the local vocab slice.
+    ``ragged_q_lens`` ([B]) switches to the unified ragged entry (mixed
+    prefill/decode/verify rows, per-row metadata — the one-program serving
+    step), which writes and attends in one call: row b's tokens sit at
+    ``prefill_kv_lens[b] - ragged_q_lens[b] ..``, slots past
+    ``ragged_q_lens[b]`` reach no live page. ``tp`` (a
+    ``inference/tp.py:TPServing``) marks the body as running INSIDE
+    shard_map on a tensor-parallel mesh: ``cfg`` is then the local per-shard
+    view (heads and kv pages sliced on the head axes), the row-parallel
+    projections all-reduce through the context, and the returned logits may
+    be the local vocab slice.
+
+    The stacked pools ``[L, NP, NKV, P, D]`` ride in the layer scan's CARRY
+    beside the layer index, and every write and read reaches its layer
+    through that index: the pools are never sliced into per-layer ``xs`` nor
+    restacked from ``ys``, so the donated buffers are the ones returned. On
+    the Pallas ragged path the fused kernel is the only operation applied
+    to them (aliased in → out), which also leaves their layout to nobody
+    but the kernel.
     Returns (logits [B, T, V], new_k_pages, new_v_pages)."""
     from deepspeed_tpu.ops.transformer.paged_attention import (
         paged_decode_attention,
         paged_prefill_attention,
         ragged_paged_attention,
+        scatter_pages,
     )
 
-    B, T = tokens.shape
+    T = tokens.shape[1]
     dtype = k_pages.dtype
-    P = k_pages.shape[3]
     x = params["embed"]["tokens"].astype(dtype)[tokens]
     if cfg.position == "learned":
         x = x + params["embed"]["pos"].astype(dtype)[positions_b]
@@ -759,39 +748,42 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
     # ``_final_logits``) put the region into every op's name stack, where a
     # profiler trace reads it: ``kv_write``, ``attention``, ``mlp``,
     # ``head_sample``. Names only, nothing computed differently.
-    def layer_step(x, per_layer):
-        p, kp_l, vp_l = per_layer
+    def layer_step(carry, p):
+        x, kp, vp, layer = carry
         with jax.named_scope("attention"):
             q, k_new, v_new = _layer_project_qkv(cfg, p, x)
             if cfg.position == "rope":
                 q = _rope(q, positions_b, cfg.rope_theta, cfg.rope_dim)
                 k_new = _rope(k_new, positions_b, cfg.rope_theta, cfg.rope_dim)
-        with jax.named_scope("kv_write"):
-            kp_l = _scatter_pages(kp_l, k_new.astype(dtype), page_table, positions_b, P,
-                                  valid=write_valid)
-            vp_l = _scatter_pages(vp_l, v_new.astype(dtype), page_table, positions_b, P,
-                                  valid=write_valid)
-        # attn_lens discriminates decode from prefill: a prefill_chunk=1
-        # program also has T == 1 but must take the causal-mask path
-        with jax.named_scope("attention"):
-            if ragged_q_lens is not None:
-                attn = ragged_paged_attention(
-                    q, kp_l, vp_l, page_table, prefill_kv_lens, ragged_q_lens,
-                    scale=scale, impl=attn_impl,
+        if ragged_q_lens is not None:
+            with jax.named_scope("attention"):
+                attn, kp, vp = ragged_paged_attention(
+                    q, k_new, v_new, kp, vp, layer, page_table, prefill_kv_lens,
+                    ragged_q_lens, scale=scale, impl=attn_impl,
                 )
-            elif T == 1 and attn_lens is not None:
-                attn = paged_decode_attention(
-                    q[:, 0], kp_l, vp_l, page_table, attn_lens, scale=scale, impl=attn_impl
-                )[:, None]
-            else:
-                attn = paged_prefill_attention(
-                    q, kp_l, vp_l, page_table, positions_b, scale=scale,
-                    kv_lens=prefill_kv_lens,
-                )
+        else:
+            with jax.named_scope("kv_write"):
+                kp = scatter_pages(kp, layer, k_new, page_table, positions_b, write_valid)
+                vp = scatter_pages(vp, layer, v_new, page_table, positions_b, write_valid)
+            # attn_lens discriminates decode from prefill: a prefill_chunk=1
+            # program also has T == 1 but must take the causal-mask path
+            with jax.named_scope("attention"):
+                if T == 1 and attn_lens is not None:
+                    attn = paged_decode_attention(
+                        q[:, 0], kp, vp, layer, page_table, attn_lens, scale=scale,
+                        impl=attn_impl,
+                    )[:, None]
+                else:
+                    attn = paged_prefill_attention(
+                        q, kp, vp, layer, page_table, positions_b, scale=scale,
+                        kv_lens=prefill_kv_lens,
+                    )
         x = _post_attention(cfg, p, x, attn, tp=tp)
-        return x, (kp_l, vp_l)
+        return (x, kp, vp, layer + 1), None
 
-    x, (new_k, new_v) = jax.lax.scan(layer_step, x, (params["layers"], k_pages, v_pages))
+    (x, new_k, new_v, _), _ = jax.lax.scan(
+        layer_step, (x, k_pages, v_pages, jnp.int32(0)), params["layers"]
+    )
     return _final_logits(cfg, params, x), new_k, new_v
 
 
@@ -944,7 +936,7 @@ def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: 
     (per-row ``(kv_len, q_len)`` metadata with ``q_len ∈ {0, 1}``), takes
     the greedy argmax in-program, and advances the carry. Stopping is pure
     in-program data: a row FREEZES — its ``q_len`` drops to 0, so further
-    writes redirect to the trash page and its length stops — the round it
+    writes reach no live page and its length stops — the round it
     emits its ``eos_ids[r]`` token (−1 = no EOS) or its ``budgets[r]``-th
     window token. A frozen row is indistinguishable from a dead padding
     row to every other row, which is what makes the window byte-identical
@@ -991,8 +983,7 @@ def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: 
             kv_lens = jnp.where(alive, lens + 1, 0)
             logits, kp, vp = _paged_forward(
                 run_cfg, params, tok[:, None], kp, vp, page_table, lens[:, None],
-                None, attn_impl, write_valid=alive[:, None],
-                prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
+                None, attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
             )
             nxt = (
                 tp.argmax(logits[:, -1, :]) if tp is not None
@@ -1043,9 +1034,9 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
     * a **dead** padding row has ``q_lens[r] == 0`` (sentinel table,
       trash-page writes, zero attention).
 
-    The program scatters k/v for every real position (window slots past
-    ``q_lens[r]`` redirect to the trash page), attends through ONE ragged
-    paged-attention call driven by the per-row ``(kv_len, q_len)``
+    The program writes k/v for every real position (window slots past
+    ``q_lens[r]`` reach no live page) and attends in ONE ragged
+    paged-attention call a layer, driven by the per-row ``(kv_len, q_len)``
     metadata, and resolves every mode in-program: ``out[r, 1 + j]`` is the
     greedy token after position j (decode rows read ``out[r, 1]``, a
     finishing prefill chunk reads ``out[r, q_lens[r]]``), and ``out[r, 0]``
@@ -1083,12 +1074,10 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
     def _step(params, tokens, k_pages, v_pages, page_table, lengths, q_lens):
         offs = jnp.arange(W, dtype=jnp.int32)
         positions_b = lengths[:, None] + offs[None, :]
-        valid = offs[None, :] < q_lens[:, None]
         kv_lens = jnp.where(q_lens > 0, lengths + q_lens, 0)
         logits, new_k, new_v = _paged_forward(
             run_cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
-            None, attn_impl, write_valid=valid, prefill_kv_lens=kv_lens,
-            ragged_q_lens=q_lens, tp=tp,
+            None, attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
         )
         with jax.named_scope("head_sample"):
             greedy = (

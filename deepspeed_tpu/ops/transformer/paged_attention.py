@@ -2,9 +2,12 @@
 
 The serving layer (``inference/kv_pool.py`` + ``inference/scheduler.py``)
 stores every sequence's KV cache as fixed-size pages in one shared pool
-``[num_pages, NKV, page_size, D]`` per layer, addressed through per-sequence
-page tables. This module is the single attention entry point for that
-layout:
+``[L, num_pages, NKV, page_size, D]`` for all layers, addressed through
+per-sequence page tables. This module is the single attention entry point
+for that layout. Every entry takes the whole stack and a ``layer`` index and
+reaches the layer's pages through it (``pool[layer, ids]``, the kernels'
+index maps): the serving step carries the stack through its layer loop, and
+a slice of it would be a copy of one layer's pool.
 
 * ``paged_decode_attention`` — one generated token per sequence attends over
   its live pages. Dispatches to the Pallas kernel
@@ -24,10 +27,14 @@ layout:
   serving step dispatches (``decode.py:build_ragged_step``): mixed
   prefill-chunk / decode / verify rows in one ``[R, W]`` window, driven
   entirely by per-row ``(kv_len, q_len)`` metadata arrays so the mix
-  never retraces. Pallas kernel on TPU
-  (``decode_attention.ragged_paged_attention``: kv grid walks the page
-  table via scalar prefetch, causal in-window mask, pages past a row's
-  live length skipped), XLA gather fallback elsewhere.
+  never retraces. It WRITES the window's k/v into the pool and attends over
+  it, and returns the pools. Pallas kernel on TPU
+  (``decode_attention.ragged_paged_attention``: one fused kernel on the
+  aliased stacks — kv grid walks the page table via scalar prefetch, merges
+  the new rows into the pages that receive them, causal in-window mask,
+  pages past a row's live length skipped), XLA scatter + gather elsewhere.
+* ``scatter_pages`` — the XLA write of a token slab's k/v into the pool,
+  for the two read-only entries' callers and the ragged entry's XLA path.
 
 GQA is handled by grouping — queries reshape to ``[B, NKV, G, D]`` and each
 kv head's rows are read once — so no path here (kernel or fallback) ever
@@ -70,20 +77,43 @@ def _scale_or_default(scale: Optional[float], head_dim: int) -> float:
     return float(scale) if scale is not None else 1.0 / float(np.sqrt(head_dim))
 
 
-def _gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """[NP, NKV, P, D] pool + [B, MAXP] table -> [B, MAXP*P, NKV, D] linear
-    view (kv position s lives in table slot s // P at offset s % P)."""
-    NP, NKV, P, D = pages.shape
+def _gather_pages(pages: jnp.ndarray, layer, page_table: jnp.ndarray) -> jnp.ndarray:
+    """[L, NP, NKV, P, D] pool + layer + [B, MAXP] table -> [B, MAXP*P, NKV, D]
+    linear view (kv position s lives in table slot s // P at offset s % P)."""
+    _, NP, NKV, P, D = pages.shape
     B, maxp = page_table.shape
     pt = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, NP - 1)
     # [B, MAXP, NKV, P, D] -> [B, MAXP, P, NKV, D] -> [B, S, NKV, D]
-    return pages[pt].transpose(0, 1, 3, 2, 4).reshape(B, maxp * P, NKV, D)
+    return pages[layer, pt].transpose(0, 1, 3, 2, 4).reshape(B, maxp * P, NKV, D)
+
+
+def scatter_pages(pages, layer, vals, page_table, positions, valid=None):
+    """Write [B, T, NKV, D] new k/v rows into ``layer`` of the page pool
+    [L, NP, NKV, P, D] at absolute ``positions`` [B, T] through the page table
+    [B, MAXP]. Sentinel table entries (< 0, i.e. unallocated/dead rows)
+    clamp onto the reserved trash page 0, so padded bucket rows and prompt
+    pad tails write garbage only where nothing lives. ``valid`` (bool
+    [B, T], optional) force-redirects masked positions onto the trash page
+    regardless of the table: the verify program's pad draft slots sit past
+    a row's ensured pages, where ``positions // page_size`` could alias a
+    LIVE page after the maxp clamp."""
+    _, NP, _, P, _ = pages.shape
+    maxp = page_table.shape[1]
+    slot = jnp.clip(positions // P, 0, maxp - 1)
+    pid = jnp.clip(jnp.take_along_axis(page_table, slot, axis=1), 0, NP - 1)
+    if valid is not None:
+        pid = jnp.where(valid, pid, 0)  # page 0 = the reserved trash page
+    off = positions % P
+    # advanced-index scatter: (layer, pid, off) broadcast to [B, T] and land
+    # first, giving the [B, T, NKV, D] update window vals fills exactly
+    return pages.at[layer, pid, :, off, :].set(vals.astype(pages.dtype))
 
 
 def paged_decode_attention_xla(
     q: jnp.ndarray,  # [B, NH, D]
-    k_pages: jnp.ndarray,  # [NP, NKV, P, D]
+    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D]
     v_pages: jnp.ndarray,
+    layer,  # int32 scalar
     page_table: jnp.ndarray,  # [B, MAXP] int32
     kv_len,  # [B] int32 live lengths (or scalar)
     scale: Optional[float] = None,
@@ -92,15 +122,15 @@ def paged_decode_attention_xla(
     grouped-GQA masked attention. Rows with length 0 return exact zeros
     (matching the Pallas kernel's empty-accumulator output)."""
     B, NH, D = q.shape
-    NP, NKV, P, _ = k_pages.shape
+    _, NP, NKV, P, _ = k_pages.shape
     assert v_pages.shape == k_pages.shape
     if NH % NKV:
         raise ValueError(f"query heads {NH} not a multiple of kv heads {NKV}")
     G = NH // NKV
     S = page_table.shape[1] * P
     scale_f = _scale_or_default(scale, D)
-    k = _gather_pages(k_pages, page_table)  # [B, S, NKV, D]
-    v = _gather_pages(v_pages, page_table)
+    k = _gather_pages(k_pages, layer, page_table)  # [B, S, NKV, D]
+    v = _gather_pages(v_pages, layer, page_table)
     lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     qg = q.reshape(B, NKV, G, D)
     scores = jnp.einsum("bkgd,bskd->bkgs", qg, k).astype(jnp.float32) * scale_f
@@ -115,8 +145,9 @@ def paged_decode_attention_xla(
 
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, NH, D]
-    k_pages: jnp.ndarray,  # [NP, NKV, P, D]
+    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D]
     v_pages: jnp.ndarray,
+    layer,  # int32 scalar
     page_table: jnp.ndarray,  # [B, MAXP] int32
     kv_len,  # [B] int32 live lengths
     scale: Optional[float] = None,
@@ -128,37 +159,46 @@ def paged_decode_attention(
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
     if impl == "pallas":
-        return _pallas_paged_decode(q, k_pages, v_pages, page_table, kv_len, scale=scale)
+        return _pallas_paged_decode(q, k_pages, v_pages, layer, page_table, kv_len, scale=scale)
     if impl == "xla":
-        return paged_decode_attention_xla(q, k_pages, v_pages, page_table, kv_len, scale=scale)
+        return paged_decode_attention_xla(
+            q, k_pages, v_pages, layer, page_table, kv_len, scale=scale
+        )
     raise ValueError(f"unknown paged attention impl {impl!r}; expected auto|pallas|xla")
 
 
 def ragged_paged_attention(
     q: jnp.ndarray,  # [R, W, NH, D] — per-row padded token windows
-    k_pages: jnp.ndarray,  # [NP, NKV, P, D]
+    k_new: jnp.ndarray,  # [R, W, NKV, D] — the windows' keys, not yet in the pool
+    v_new: jnp.ndarray,
+    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D]
     v_pages: jnp.ndarray,
+    layer,  # int32 scalar
     page_table: jnp.ndarray,  # [R, MAXP] int32
     kv_lens: jnp.ndarray,  # [R] live kv length INCLUDING this step's tokens
     q_lens: jnp.ndarray,  # [R] real tokens in the window (0 = dead row)
     scale: Optional[float] = None,
     impl: str = "auto",
-) -> jnp.ndarray:
-    """Unified mixed-row attention for the one-program ragged serving step
-    (arXiv 2604.15464): every row attends causally over its own pages with
-    per-row ``(kv_len, q_len)`` metadata riding in as arrays — a decode row
-    (q_len 1), a verify row (q_len K+1), and a prefill chunk (q_len C) all
-    take the same code path, so shifting the mix never changes the program.
-    ``impl``: ``auto`` picks the Pallas ragged kernel on TPU and the XLA
-    gather fallback elsewhere; ``pallas`` / ``xla`` force one (``pallas``
-    off-TPU runs in interpret mode — tests only). Rows with
-    ``kv_lens == 0`` return exact zeros; window slots past ``q_lens``
-    return garbage the caller ignores."""
+):
+    """Unified mixed-row write-and-attend for the one-program ragged serving
+    step (arXiv 2604.15464): row r's ``q_lens[r]`` new keys and values go
+    into its pages at positions ``kv_lens[r] - q_lens[r] ..`` (window slots
+    past ``q_lens[r]`` to the trash page), then the row attends causally
+    over its own pages. The per-row ``(kv_len, q_len)`` metadata rides in as
+    arrays — a decode row (q_len 1), a verify row (q_len K+1), and a prefill
+    chunk (q_len C) all take the same code path, so shifting the mix never
+    changes the program. ``impl``: ``auto`` picks the fused Pallas kernel on
+    TPU and XLA's scatter + gather elsewhere; ``pallas`` / ``xla`` force one
+    (``pallas`` off-TPU runs in interpret mode — tests only). Both leave the
+    same bytes in every page but the trash page. Returns
+    ``(out [R, W, NH, D], k_pages, v_pages)``: rows with ``kv_lens == 0``
+    are exact zeros; window slots past ``q_lens`` are garbage the caller
+    ignores."""
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
     if impl == "pallas":
         return _pallas_ragged_paged(
-            q, k_pages, v_pages, page_table, kv_lens, q_lens, scale=scale
+            q, k_new, v_new, k_pages, v_pages, layer, page_table, kv_lens, q_lens, scale=scale
         )
     if impl != "xla":
         raise ValueError(f"unknown ragged attention impl {impl!r}; expected auto|pallas|xla")
@@ -168,16 +208,22 @@ def ragged_paged_attention(
     # absolute query positions: the row's write base (kv_len - q_len) plus
     # the in-window offset — the causal mask then bounds every real slot,
     # and the kv_lens cap silences pad slots' reads above the live prefix
-    q_positions = (lens - qlens)[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-    return paged_prefill_attention(
-        q, k_pages, v_pages, page_table, q_positions, scale=scale, kv_lens=lens
+    offs = jnp.arange(W, dtype=jnp.int32)[None, :]
+    q_positions = (lens - qlens)[:, None] + offs
+    valid = offs < qlens[:, None]
+    k_pages = scatter_pages(k_pages, layer, k_new, page_table, q_positions, valid)
+    v_pages = scatter_pages(v_pages, layer, v_new, page_table, q_positions, valid)
+    out = paged_prefill_attention(
+        q, k_pages, v_pages, layer, page_table, q_positions, scale=scale, kv_lens=lens
     )
+    return out, k_pages, v_pages
 
 
 def paged_prefill_attention(
     q: jnp.ndarray,  # [B, T, NH, D] — a prompt chunk's queries
-    k_pages: jnp.ndarray,  # [NP, NKV, P, D]
+    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D]
     v_pages: jnp.ndarray,
+    layer,  # int32 scalar
     page_table: jnp.ndarray,  # [B, MAXP] int32
     q_positions: jnp.ndarray,  # [B, T] absolute positions of the chunk tokens
     scale: Optional[float] = None,
@@ -194,14 +240,14 @@ def paged_prefill_attention(
     them read unwritten pages); rows with ``kv_lens == 0`` (dead bucket
     padding) return exact zeros."""
     B, T, NH, D = q.shape
-    NP, NKV, P, _ = k_pages.shape
+    _, NP, NKV, P, _ = k_pages.shape
     if NH % NKV:
         raise ValueError(f"query heads {NH} not a multiple of kv heads {NKV}")
     G = NH // NKV
     S = page_table.shape[1] * P
     scale_f = _scale_or_default(scale, D)
-    k = _gather_pages(k_pages, page_table)  # [B, S, NKV, D]
-    v = _gather_pages(v_pages, page_table)
+    k = _gather_pages(k_pages, layer, page_table)  # [B, S, NKV, D]
+    v = _gather_pages(v_pages, layer, page_table)
     qg = q.reshape(B, T, NKV, G, D)
     scores = jnp.einsum("btkgd,bskd->bkgts", qg, k).astype(jnp.float32) * scale_f
     kv_pos = jnp.arange(S, dtype=jnp.int32)

@@ -122,6 +122,16 @@ class TestDeepSpeedTransformerLayer:
         assert shapes.shape == (4, 4)
 
 
+def _paged(q, pool_k, pool_v, table, lens):
+    """``paged_decode_attention`` on a per-layer pool: the kernel takes every
+    layer's pool and a layer index, so the pool rides as layer 1 under a
+    layer of garbage that a wrong index would read."""
+    from deepspeed_tpu.ops.transformer.decode_attention import paged_decode_attention
+
+    stack = lambda pool: jnp.stack([jnp.full(pool.shape, 1e6, pool.dtype), jnp.asarray(pool)])
+    return paged_decode_attention(jnp.asarray(q), stack(pool_k), stack(pool_v), 1, table, lens)
+
+
 class TestPagedDecodeAttention:
     def _pages_from_contiguous(self, k, v, page):
         """Scatter a contiguous [B,S,NKV,D] cache into a shared page pool
@@ -142,10 +152,6 @@ class TestPagedDecodeAttention:
 
     @pytest.mark.parametrize("nkv", [4, 2])
     def test_matches_contiguous_kernel(self, nkv):
-        from deepspeed_tpu.ops.transformer.decode_attention import (
-            paged_decode_attention,
-        )
-
         B, NH, D, S, page = 2, 4, 32, 512, 128
         rs = np.random.RandomState(0)
         q = rs.randn(B, NH, D).astype(np.float32)
@@ -153,19 +159,13 @@ class TestPagedDecodeAttention:
         v = rs.randn(B, S, nkv, D).astype(np.float32)
         lens = np.array([130, 512], np.int32)
         pool_k, pool_v, table = self._pages_from_contiguous(k, v, page)
-        out = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v), table, lens
-        )
+        out = _paged(q, pool_k, pool_v, table, lens)
         ref = _dense_ref(q, k, v, lens, 1.0 / np.sqrt(D))
         np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
     def test_shared_prefix_pages(self):
         """Two sequences sharing their first page (prefix sharing — the
         memory win paging exists for) must read identical prefix content."""
-        from deepspeed_tpu.ops.transformer.decode_attention import (
-            paged_decode_attention,
-        )
-
         NH, D, page = 4, 32, 128
         rs = np.random.RandomState(1)
         pool_k = rs.randn(4, NH, page, D).astype(np.float32)
@@ -174,9 +174,7 @@ class TestPagedDecodeAttention:
         # both sequences point at page 1 first, then diverge (2 vs 3)
         table = np.array([[1, 2], [1, 3]], np.int32)
         lens = np.array([256, 256], np.int32)
-        out = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v), table, lens
-        )
+        out = _paged(q, pool_k, pool_v, table, lens)
         # dense reference: reconstruct each sequence's contiguous cache
         for b in range(2):
             kb = np.concatenate(
@@ -189,35 +187,23 @@ class TestPagedDecodeAttention:
             np.testing.assert_allclose(np.asarray(out)[b : b + 1], ref, rtol=2e-5, atol=2e-5)
 
     def test_unused_pool_pages_ignored(self):
-        from deepspeed_tpu.ops.transformer.decode_attention import (
-            paged_decode_attention,
-        )
-
         NH, D, page = 2, 32, 128
         rs = np.random.RandomState(2)
         pool_k = rs.randn(3, NH, page, D).astype(np.float32)
         pool_v = rs.randn(3, NH, page, D).astype(np.float32)
         q = rs.randn(1, NH, D).astype(np.float32)
         table = np.array([[1, 2]], np.int32)
-        out1 = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v), table, np.array([200])
-        )
+        out1 = _paged(q, pool_k, pool_v, table, np.array([200]))
         pool_k2 = pool_k.copy()
         pool_k2[0] = 1e6  # garbage in the unused page
         # and garbage past len inside the last live page's tail
-        out2 = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pool_k2), jnp.asarray(pool_v), table, np.array([200])
-        )
+        out2 = _paged(q, pool_k2, pool_v, table, np.array([200]))
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
 
     def test_padding_slots_with_sentinel_ids(self):
         """Serving stacks pad page tables with -1 (or ids >= NP) past the
         live length; the index map must clamp those fetches in-range rather
         than read out of bounds, and their scores are masked anyway."""
-        from deepspeed_tpu.ops.transformer.decode_attention import (
-            paged_decode_attention,
-        )
-
         NH, D, page = 2, 32, 128
         rs = np.random.RandomState(3)
         pool_k = rs.randn(3, NH, page, D).astype(np.float32)
@@ -226,12 +212,8 @@ class TestPagedDecodeAttention:
         lens = np.array([130, 256], np.int32)
         valid = np.array([[1, 2, 0, 0], [2, 0, 0, 0]], np.int32)
         padded = np.array([[1, 2, -1, 99], [2, 0, -1, -1]], np.int32)
-        out_valid = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v), valid, lens
-        )
-        out_padded = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v), padded, lens
-        )
+        out_valid = _paged(q, pool_k, pool_v, valid, lens)
+        out_padded = _paged(q, pool_k, pool_v, padded, lens)
         np.testing.assert_allclose(
             np.asarray(out_valid), np.asarray(out_padded), rtol=1e-6
         )

@@ -27,6 +27,40 @@ def _rand_pool(rs, NP, NKV, P, D):
     return jnp.asarray(k), jnp.asarray(v)
 
 
+def _stack(pool):
+    """A per-layer pool [NP, NKV, P, D] as layer 1 of the two-layer stack the
+    entries take, under a layer of garbage that a wrong index would read."""
+    return jnp.stack([jnp.full(pool.shape, 1e6, pool.dtype), pool])
+
+
+def _window_rows(pool, page_table, kv_lens, q_lens, W):
+    """[R, W, NKV, D]: what the pool holds at each row's window positions
+    ``kv_len - q_len ..`` (zeros in the slots past ``q_len``)."""
+    pool, pt = np.asarray(pool), np.asarray(page_table)
+    _, NKV, P, D = pool.shape
+    rows = np.zeros((len(pt), W, NKV, D), np.float32)
+    for r, (kv, ql) in enumerate(zip(np.asarray(kv_lens), np.asarray(q_lens))):
+        for w in range(ql):
+            pos = kv - ql + w
+            rows[r, w] = pool[pt[r, pos // P], :, pos % P]
+    return jnp.asarray(rows)
+
+
+def _ragged(q, kp, vp, pt, kv_lens, q_lens, impl):
+    """``ragged_paged_attention`` on a pool that already holds the windows'
+    keys and values: the entry writes them again, which must change no page
+    but the trash page, and attends."""
+    W = q.shape[1]
+    out, k2, v2 = ragged_paged_attention(
+        q, _window_rows(kp, pt, kv_lens, q_lens, W), _window_rows(vp, pt, kv_lens, q_lens, W),
+        _stack(kp), _stack(vp), 1, pt, kv_lens, q_lens, impl=impl,
+    )
+    for new, old in ((k2, kp), (v2, vp)):
+        np.testing.assert_array_equal(np.asarray(new[1, 1:]), np.asarray(old[1:]))
+        assert (np.asarray(new[0]) == 1e6).all()
+    return np.asarray(out)
+
+
 def _dense_from_pages(k_pages, page_table, P):
     """[B, S, NKV, D] linear cache equivalent of a page table (numpy ref)."""
     kp = np.asarray(k_pages)
@@ -71,7 +105,7 @@ def test_xla_fallback_matches_reference(nkv):
     pt[1, :1] = [5]
     pt[2, :4] = [2, 9, 4, 8]
     lens = np.array([20, 8, 32], np.int32)
-    out = paged_decode_attention_xla(jnp.asarray(q), kp, vp, jnp.asarray(pt), lens)
+    out = paged_decode_attention_xla(jnp.asarray(q), _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens)
     ref = _ref_decode(
         q, _dense_from_pages(kp, pt, P), _dense_from_pages(vp, pt, P),
         lens, 1.0 / np.sqrt(D),
@@ -88,8 +122,8 @@ def test_xla_matches_pallas_interpret():
     pt[0, :2] = [4, 2]
     pt[1, :3] = [7, 1, 9]
     lens = np.array([13, 24], np.int32)
-    out_x = paged_decode_attention(q, kp, vp, jnp.asarray(pt), lens, impl="xla")
-    out_p = paged_decode_attention(q, kp, vp, jnp.asarray(pt), lens, impl="pallas")
+    out_x = paged_decode_attention(q, _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens, impl="xla")
+    out_p = paged_decode_attention(q, _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens, impl="pallas")
     np.testing.assert_allclose(np.asarray(out_x), np.asarray(out_p), rtol=2e-5, atol=2e-5)
 
 
@@ -100,12 +134,12 @@ def test_zero_length_rows_and_garbage_pages_are_inert():
     kp, vp = _rand_pool(rs, NP, nkv, P, D)
     pt = np.array([[3, -1], [-1, -1]], np.int32)
     lens = np.array([4, 0], np.int32)
-    out = np.asarray(paged_decode_attention_xla(q, kp, vp, jnp.asarray(pt), lens))
+    out = np.asarray(paged_decode_attention_xla(q, _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens))
     assert (out[1] == 0).all()  # dead row: exact zeros (kernel contract)
     # garbage in pages past the live length must not move the output
     kp2 = kp.at[5].set(1e6)
     vp2 = vp.at[5].set(-1e6)
-    out2 = np.asarray(paged_decode_attention_xla(q, kp2, vp2, jnp.asarray(pt), lens))
+    out2 = np.asarray(paged_decode_attention_xla(q, _stack(kp2), _stack(vp2), 1, jnp.asarray(pt), lens))
     np.testing.assert_allclose(out, out2, rtol=1e-6)
 
 
@@ -118,7 +152,7 @@ def test_prefill_chunk_matches_causal_reference():
     start = 3  # chunk positions 3..8: prefix 0..2 already in the pages
     q_pos = np.arange(start, start + T, dtype=np.int32)[None]
     out = paged_prefill_attention(
-        jnp.asarray(q), kp, vp, jnp.asarray(pt), jnp.asarray(q_pos)
+        jnp.asarray(q), _stack(kp), _stack(vp), 1, jnp.asarray(pt), jnp.asarray(q_pos)
     )
     k_lin = _dense_from_pages(kp, pt, P)
     v_lin = _dense_from_pages(vp, pt, P)
@@ -155,7 +189,7 @@ def test_ragged_matches_per_mode_reference():
     rs = np.random.RandomState(4)
     q, kp, vp, pt, kv_lens, q_lens = _ragged_fixture(rs)
     W, D, P = q.shape[1], q.shape[3], kp.shape[2]
-    out = np.asarray(ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens, impl="xla"))
+    out = _ragged(q, kp, vp, pt, kv_lens, q_lens, "xla")
     k_lin = _dense_from_pages(kp, pt, P)
     v_lin = _dense_from_pages(vp, pt, P)
     scale = 1.0 / np.sqrt(D)
@@ -178,8 +212,8 @@ def test_ragged_xla_matches_pallas_interpret():
     LIVE window slot; dead rows are zeros in both."""
     rs = np.random.RandomState(5)
     q, kp, vp, pt, kv_lens, q_lens = _ragged_fixture(rs)
-    out_x = np.asarray(ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens, impl="xla"))
-    out_p = np.asarray(ragged_paged_attention(q, kp, vp, pt, kv_lens, q_lens, impl="pallas"))
+    out_x = _ragged(q, kp, vp, pt, kv_lens, q_lens, "xla")
+    out_p = _ragged(q, kp, vp, pt, kv_lens, q_lens, "pallas")
     for r, ql in enumerate(np.asarray(q_lens)):
         np.testing.assert_allclose(
             out_x[r, :ql], out_p[r, :ql], rtol=2e-5, atol=2e-5, err_msg=f"row {r}"
@@ -199,10 +233,7 @@ def test_ragged_mid_sequence_verify_row():
     start, ql = 5, 3  # tokens at positions 5, 6, 7; slot 3 is pad garbage
     kv_lens = np.array([start + ql], np.int32)
     q_lens = np.array([ql], np.int32)
-    out = np.asarray(ragged_paged_attention(
-        q, kp, vp, jnp.asarray(pt), jnp.asarray(kv_lens), jnp.asarray(q_lens),
-        impl="xla",
-    ))
+    out = _ragged(q, kp, vp, jnp.asarray(pt), jnp.asarray(kv_lens), jnp.asarray(q_lens), "xla")
     k_lin = _dense_from_pages(kp, pt, P)
     v_lin = _dense_from_pages(vp, pt, P)
     for t in range(ql):
@@ -214,11 +245,82 @@ def test_ragged_mid_sequence_verify_row():
     # positions 8..11, all >= kv_len 8) never leak in
     kp2 = kp.at[1].set(1e6)
     vp2 = vp.at[1].set(-1e6)
-    out2 = np.asarray(ragged_paged_attention(
-        q, kp2, vp2, jnp.asarray(pt), jnp.asarray(kv_lens), jnp.asarray(q_lens),
-        impl="xla",
-    ))
+    out2 = _ragged(q, kp2, vp2, jnp.asarray(pt), jnp.asarray(kv_lens), jnp.asarray(q_lens), "xla")
     np.testing.assert_allclose(out[:, :ql], out2[:, :ql], rtol=1e-6)
+
+
+# --- the fused write-and-attend kernel against XLA's scatter + gather --------
+# rows as (kv length before the step, new tokens, page-table row); pages of 8,
+# windows of 5 (a verify row of K = 4 drafts fills one)
+_FUSED_CASES = {
+    "decode_row": [(10, 1, [3, 7, -1, -1])],
+    "chunk_crossing_a_page_boundary": [(6, 5, [2, 9, -1, -1])],
+    "first_token_of_a_fresh_page": [(8, 1, [4, 6, -1, -1])],
+    "verify_row": [(13, 5, [5, 1, 8, -1])],
+    "dead_row": [(0, 0, [-1, -1, -1, -1])],
+    "mixed_window": [
+        (10, 1, [3, 7, -1, -1]), (6, 5, [2, 9, -1, -1]), (0, 0, [-1, -1, -1, -1]),
+        (13, 5, [5, 1, 8, -1]), (0, 3, [10, -1, -1, -1]),
+    ],
+}
+
+
+_GROUP4_NKV2, _GROUP1 = (8, 2), (2, 2)  # (query heads, kv heads); 2 kv heads is also a TP shard's view
+
+
+@pytest.mark.parametrize(
+    "case, heads, layer, D",
+    [(case, heads, 1, 16) for case in sorted(_FUSED_CASES) for heads in (_GROUP4_NKV2, _GROUP1)]
+    + [("mixed_window", _GROUP4_NKV2, 0, 16), ("mixed_window", _GROUP4_NKV2, 2, 16)]
+    # a head of whole lanes: the written pages leave by DMA, not through out-blocks
+    + [(case, _GROUP4_NKV2, 1, 128) for case in sorted(_FUSED_CASES)],
+)
+def test_fused_ragged_kernel_matches_xla_scatter_then_gather(case, heads, layer, D):
+    """The Pallas kernel (interpret mode) merges the window's keys and values
+    into the pages that receive them as it reads them: every page but the
+    trash page must hold the bytes XLA's scatter leaves there, in the written
+    layer and in the two others, and no page that receives nothing may change;
+    the output must be bit for bit what the same kernel gives on the scattered
+    pool, and the XLA gather's within float32 rounding; a dead row is exact
+    zeros."""
+    (NH, NKV), L, NP, P, W = heads, 3, 12, 8, 5
+    rows = _FUSED_CASES[case]
+    rs = np.random.RandomState(7)
+    k0 = jnp.asarray(rs.randn(L, NP, NKV, P, D).astype(np.float32))
+    v0 = jnp.asarray(rs.randn(L, NP, NKV, P, D).astype(np.float32))
+    q = jnp.asarray(rs.randn(len(rows), W, NH, D).astype(np.float32))
+    k_new = jnp.asarray(rs.randn(len(rows), W, NKV, D).astype(np.float32))
+    v_new = jnp.asarray(rs.randn(len(rows), W, NKV, D).astype(np.float32))
+    q_lens = jnp.asarray([n for _, n, _ in rows], jnp.int32)
+    kv_lens = jnp.asarray([before + n if n else 0 for before, n, _ in rows], jnp.int32)
+    pt = jnp.asarray([table for _, _, table in rows], jnp.int32)
+
+    def run(k_pages, v_pages, impl):
+        return ragged_paged_attention(
+            q, k_new, v_new, k_pages, v_pages, layer, pt, kv_lens, q_lens, impl=impl
+        )
+
+    out_x, k_x, v_x = run(k0, v0, "xla")
+    out_p, k_p, v_p = run(k0, v0, "pallas")
+    for fused, scattered, before in ((k_p, k_x, k0), (v_p, v_x, v0)):
+        np.testing.assert_array_equal(np.asarray(fused[:, 1:]), np.asarray(scattered[:, 1:]))
+        others = [l for l in range(L) if l != layer]
+        np.testing.assert_array_equal(np.asarray(fused)[others], np.asarray(before)[others])
+        assert np.isfinite(np.asarray(fused[:, 0])).all()  # the trash page
+        written = (np.asarray(fused[layer, 1:]) != np.asarray(before[layer, 1:])).any(axis=(1, 2, 3))
+        receiving = {
+            table[pos // P] for before_len, n, table in rows for pos in range(before_len, before_len + n)
+        }
+        assert set(1 + np.flatnonzero(written)) == receiving
+    out_again, _, _ = run(k_x, v_x, "pallas")  # XLA's scatter, then the kernel
+    np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_again))
+    for r, (_, n, _) in enumerate(rows):
+        np.testing.assert_allclose(
+            np.asarray(out_p)[r, :n], np.asarray(out_x)[r, :n], rtol=2e-5, atol=2e-5,
+            err_msg=f"row {r}",
+        )
+        if n == 0:
+            assert (np.asarray(out_p)[r] == 0).all()
 
 
 def test_gqa_grouped_equals_repeat_expansion():
@@ -229,7 +331,7 @@ def test_gqa_grouped_equals_repeat_expansion():
     kp, vp = _rand_pool(rs, NP, nkv, P, D)
     pt = np.array([[1, 4], [6, -1]], np.int32)
     lens = np.array([12, 5], np.int32)
-    out = paged_decode_attention_xla(jnp.asarray(q), kp, vp, jnp.asarray(pt), lens)
+    out = paged_decode_attention_xla(jnp.asarray(q), _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens)
     # reference: expand kv to NH heads, per-head attention
     k_lin = _dense_from_pages(kp, pt, P).repeat(NH // nkv, axis=2)
     v_lin = _dense_from_pages(vp, pt, P).repeat(NH // nkv, axis=2)

@@ -8,9 +8,16 @@ itself it reads ``jax.default_backend()`` (the CPU here) and would lower the
 interpreter instead of the kernel. Shapes are the ones ``chip_smoke.py``
 runs: GPT-2 125M training (B8 T1024 N12 D64) and llama-1b serving (32 q
 heads over 4 kv heads, D64, page 64, 8 slots, windows 1 and 128).
+
+The last test compiles the whole ragged serving step at the shapes of the
+benchmark's Mistral cells and reads what the compiler made of the KV pool.
 """
 
+import json
 import os
+import pathlib
+import re
+import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -21,6 +28,10 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from deepspeed_tpu.analysis.hlo import parse_computations, parse_input_output_aliases
+from deepspeed_tpu.inference import decode
+from deepspeed_tpu.models import TransformerLM
+from deepspeed_tpu.models.config import TransformerConfig
 from deepspeed_tpu.ops.sparse_attention.pallas_block_sparse import pallas_block_sparse_attention
 from deepspeed_tpu.ops.sparse_attention.sparsity_config import BSLongformerSparsityConfig
 from deepspeed_tpu.ops.transformer.decode_attention import (
@@ -67,12 +78,14 @@ def _bwd(fwd):
     return bwd
 
 
-def _ragged(q, k_pages, v_pages, table, kv_lens, q_lens):
-    return ragged_paged_attention(q, k_pages, v_pages, table, kv_lens, q_lens, interpret=False)
+def _ragged(q, k_new, v_new, k_pages, v_pages, table, kv_lens, q_lens):
+    return ragged_paged_attention(
+        q, k_new, v_new, k_pages, v_pages, 1, table, kv_lens, q_lens, interpret=False
+    )
 
 
 def _paged(q, k_pages, v_pages, table, kv_lens):
-    return paged_decode_attention(q, k_pages, v_pages, table, kv_lens, interpret=False)
+    return paged_decode_attention(q, k_pages, v_pages, 1, table, kv_lens, interpret=False)
 
 
 def _dense_decode(q, k_cache, v_cache, kv_lens):
@@ -93,8 +106,8 @@ def _sparse_fwd(q, k, v):
     )
 
 
-# the server's pool: 8 slots x (2048 / 64) pages + the trash page
-_PAGES = ((257, 4, 64, 64), BF16)
+# the server's pool: two layers of 8 slots x (2048 / 64) pages + the trash page
+_PAGES = ((2, 257, 4, 64, 64), BF16)
 _TABLE = ((8, 32), I32)
 _LENS = ((8,), I32)
 _TRAIN_QKV = [((8, 1024, 12, 64), BF16)] * 3
@@ -103,8 +116,14 @@ _SPARSE_QKV = [((1, 8, _SPARSE_T, 64), BF16)] * 3
 CASES = {
     "flash_fwd_gpt2_125m": (_flash_fwd, _TRAIN_QKV),
     "flash_bwd_gpt2_125m": (_bwd(_flash_fwd), _TRAIN_QKV),
-    "ragged_w1_llama_1b": (_ragged, [((8, 1, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS, _LENS]),
-    "ragged_w128_llama_1b": (_ragged, [((8, 128, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS, _LENS]),
+    "ragged_w1_llama_1b": (
+        _ragged,
+        [((8, 1, 32, 64), BF16)] + [((8, 1, 4, 64), BF16)] * 2 + [_PAGES, _PAGES, _TABLE, _LENS, _LENS],
+    ),
+    "ragged_w128_llama_1b": (
+        _ragged,
+        [((8, 128, 32, 64), BF16)] + [((8, 128, 4, 64), BF16)] * 2 + [_PAGES, _PAGES, _TABLE, _LENS, _LENS],
+    ),
     "paged_decode_llama_1b": (_paged, [((8, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS]),
     "dense_decode_llama_1b": (
         _dense_decode,
@@ -121,3 +140,66 @@ def test_kernel_compiles_for_v5e(v5e, name):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel is not in the program"
+
+
+_MISTRAL_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mistral-7b-v0.3-l16.json"
+_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while", "bitcast"}  # no bytes move
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_ragged_step_keeps_the_pool_in_one_buffer(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the Mistral cells' shapes (16 rows, 609 pages
+    of 64, the narrow and the mixed width): the donated pools are aliased to
+    the outputs, nothing but parameters, tuple plumbing, bitcasts and the
+    ``ragged_paged_attention`` kernel has a result of the pool's shape, of
+    one layer's slice of it or of any other view of that many pages, and the
+    program's temporaries are smaller than one pool. The layer loop carries the pools and the fused kernel is the
+    only operation on them; a slice, a scatter or a layout copy of the pool
+    would show here."""
+    monkeypatch.setattr(
+        sys.modules["deepspeed_tpu.ops.transformer.decode_attention"], "on_tpu", lambda: True
+    )
+    conf = json.loads(_MISTRAL_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = TransformerConfig(**{**conf["model"]["kwargs"], "max_seq_len": paged["max_seq_len"]})
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    n_pages = rows * maxp + 1
+    assert (n_pages, width in (1, paged["prefill_chunk"])) == (609, True)
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(
+        lambda: TransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))
+    )
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
+    pool = on_v5e((cfg.num_layers, n_pages, cfg.num_kv_heads, page, cfg.head_dim), BF16)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), pool, pool, on_v5e((rows, maxp), I32),
+        on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+
+    first_pool = len(jax.tree_util.tree_leaves(params)) + 1  # after the params and the tokens
+    assert {first_pool, first_pool + 1} <= parse_input_output_aliases(text)
+    # [..., NKV, P, D] with a layer's pages or more in front: the stack, a layer of it, a view
+    pages_of = re.compile(rf"\[([\d,]+),{cfg.num_kv_heads},{page},{cfg.head_dim}\]")
+    held = {
+        instr.name: instr.op
+        for computation in parse_computations(text)[0].values() for instr in computation
+        if any(
+            np.prod([int(d) for d in dims.split(",")]) >= n_pages
+            for dims in pages_of.findall(instr.shape_str)
+        )
+    }
+    assert any(name.startswith("ragged_paged_attention") for name in held), held
+    strangers = {
+        name: opcode for name, opcode in held.items()
+        if opcode not in _PLUMBING
+        and not (opcode == "custom-call" and name.startswith("ragged_paged_attention"))
+    }
+    assert not strangers, f"pool-shaped results outside the kernel: {strangers}"
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
