@@ -18,6 +18,7 @@ the training forward — guarded by the decode-vs-full-forward parity test
 
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from typing import Any, Dict, NamedTuple, Tuple
 
@@ -68,6 +69,11 @@ def _layer_project_qkv(cfg: TransformerConfig, p, h):
         q = q + p["bq"].astype(hn.dtype)
         k = k + p["bk"].astype(hn.dtype)
         v = v + p["bv"].astype(hn.dtype)
+    if getattr(cfg, "qk_norm", None) == "projection":
+        # over the whole projection, before the head split and RoPE: what is
+        # cached is the normed, rotated k
+        q = _norm(q, p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, p["k_norm_scale"], None, "rmsnorm", cfg.norm_eps)
     return (
         q.reshape(B, T, NH, D),
         k.reshape(B, T, NKV, D),
@@ -75,45 +81,46 @@ def _layer_project_qkv(cfg: TransformerConfig, p, h):
     )
 
 
-def _moe_ffn(cfg, p, h):
-    """Eval-mode MoE routing for a normed [B, T, H] slab (ISSUE 20 serving
-    tentpole): in-program top-k gate + capacity-bucketed expert einsum, the
-    exact inference semantics of ``moe/layer.py`` ``MoE.apply(train=False)``
-    — eval capacity factor, no gate noise, no RNG (deterministic drops).
-    Capacity is a Python int from the static token count, so shifting
-    expert-routing mixes are pure data: the paged programs never retrace.
-    Expert weights may be int8 (``quantize_params_int8`` stacks scales as
-    ``[E, 1, I]``); ``apply_expert_ffn`` fuses the dequantization."""
-    from deepspeed_tpu.moe import sharded_moe
-    from deepspeed_tpu.moe.experts import apply_dense_ffn, apply_expert_ffn
+def _moe_ffn(cfg, p, h, live=None, experts=None, group_offset=0):
+    """Eval-mode MoE for a normed [B, T, H] slab: the router's logits in
+    float32, then ``moe/layer.py::routed_experts``, the same function
+    ``MoE.apply(train=False)`` runs (eval capacity factor, no gate noise, no
+    RNG). ``moe_drop_tokens=False`` takes the sorted, grouped path, any k;
+    with drops, the capacity einsums (k of 1 or 2). ``live`` ([B, T] bool)
+    marks the window's real tokens: a dead slot is not routed, costs no
+    expert work and takes no live token's capacity. Group sizes and capacity
+    slots are data, so a shifting routing mix never retraces. Expert weights
+    may be int8 (``quantize_params_int8``). ``experts`` (default
+    ``p["experts"]``) may be a longer stack in which this layer's begin at
+    ``group_offset`` (``_paged_forward``). Returns (out [B, T, H], the
+    per-expert assignment counts [E])."""
+    from deepspeed_tpu.moe.layer import residual_mix, routed_experts
 
     B, T, H = h.shape
     tokens = h.reshape(-1, H)
-    logits = tokens.astype(jnp.float32) @ p["gate"]["wg"]
-    _l_aux, combine_w, dispatch_m, _counts = sharded_moe.topkgating(
+    logits = tokens.astype(jnp.float32) @ p["gate"]["wg"].astype(jnp.float32)
+    out, _l_aux, counts = routed_experts(
+        p["experts"] if experts is None else experts,
+        tokens,
         logits,
-        cfg.moe_top_k,
-        cfg.eval_capacity_factor,
-        cfg.min_capacity,
+        k=cfg.moe_top_k,
+        activation=cfg.activation,
         drop_tokens=cfg.moe_drop_tokens,
-        rng=None,
-        noisy_gate_policy=None,
+        norm_topk_prob=cfg.moe_norm_topk_prob,
+        capacity_factor=cfg.eval_capacity_factor,
+        min_capacity=cfg.min_capacity,
         use_rts=cfg.moe_use_rts,
+        live=None if live is None else live.reshape(-1),
+        group_offset=group_offset,
     )
-    dispatched = sharded_moe.dispatch(tokens, dispatch_m)
-    expert_out = apply_expert_ffn(p["experts"], dispatched, cfg.activation)
-    out = sharded_moe.combine(expert_out, combine_w)
-    if "mlp" in p:
-        # PR-MoE residual branch: dense MLP in parallel, learned 2-way mix
-        mlp_out = apply_dense_ffn(p["mlp"], tokens, cfg.activation)
-        coef = tokens.astype(jnp.float32) @ p["coefficient"]["w"] + p["coefficient"]["b"]
-        coef = jax.nn.softmax(coef, axis=-1).astype(out.dtype)
-        out = out * coef[..., 0:1] + mlp_out * coef[..., 1:2]
-    return out.reshape(B, T, H)
+    return residual_mix(p, tokens, out, cfg.activation).reshape(B, T, H), counts
 
 
-def _ffn_body(cfg: TransformerConfig, p, x, norm_scale, norm_bias, tp=None):
-    """norm → ffn, NO residual — callers place the residual per architecture."""
+def _ffn_body(cfg: TransformerConfig, p, x, norm_scale, norm_bias, tp=None, moe_ffn=_moe_ffn):
+    """norm → ffn, NO residual — callers place the residual per architecture.
+    ``moe_ffn`` is what an MoE layer runs (``_moe_ffn``, which a caller may
+    have bound to its live tokens and expert stacks).
+    Returns (out, an MoE layer's per-expert assignment counts or None)."""
     from deepspeed_tpu.moe.experts import apply_dense_ffn
 
     with jax.named_scope("mlp"):
@@ -124,12 +131,13 @@ def _ffn_body(cfg: TransformerConfig, p, x, norm_scale, norm_bias, tp=None):
                     "tensor-parallel MoE serving is not supported: expert "
                     "placement is the 'expert' mesh axis, not a TP weight split"
                 )
-            return _moe_ffn(cfg, p["moe"], h)
-        return apply_dense_ffn(p, h, cfg.activation, tp=tp)
+            return moe_ffn(cfg, p["moe"], h)
+        return apply_dense_ffn(p, h, cfg.activation, tp=tp), None
 
 
-def _layer_mlp(cfg: TransformerConfig, p, x, tp=None):
-    return x + _ffn_body(cfg, p, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"), tp=tp)
+def _layer_mlp(cfg: TransformerConfig, p, x, tp=None, moe_ffn=_moe_ffn):
+    out, moe_counts = _ffn_body(cfg, p, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"), tp=tp, moe_ffn=moe_ffn)
+    return x + out, moe_counts
 
 
 def _softmax_scale(cfg, head_dim: int) -> float:
@@ -140,14 +148,15 @@ def _softmax_scale(cfg, head_dim: int) -> float:
     )
 
 
-def _post_attention(cfg, p, x, attn, tp=None):
+def _post_attention(cfg, p, x, attn, tp=None, moe_ffn=_moe_ffn):
     """Output projection + residual placement + mlp — shared tail of every
     cached-attention layer (dense and paged), so the two decode paths can
     never drift on the residual architecture. Under TP serving the output
     projection is row-parallel: each chip holds its heads' slice of
     ``wo``, the partial sums meet in ``tp.row_matmul``'s (chunked,
     optionally quantized) all-reduce, and the bias — replicated — is
-    added exactly once, after the reduce."""
+    added exactly once, after the reduce. ``moe_ffn`` is for an MoE layer
+    (``_ffn_body``). Returns (x, that layer's expert counts or None)."""
     B, T = x.shape[:2]
     with jax.named_scope("attention"):
         a = attn.reshape(B, T, cfg.num_heads * cfg.head_dim)
@@ -162,9 +171,10 @@ def _post_attention(cfg, p, x, attn, tp=None):
         norm_bias = (
             p.get("attn_norm_bias") if cfg.shared_parallel_norm else p.get("mlp_norm_bias")
         )
-        return x + attn + _ffn_body(cfg, p, x, norm_scale, norm_bias, tp=tp)
+        out, moe_counts = _ffn_body(cfg, p, x, norm_scale, norm_bias, tp=tp, moe_ffn=moe_ffn)
+        return x + attn + out, moe_counts
     x = x + attn
-    return _layer_mlp(cfg, p, x, tp=tp)
+    return _layer_mlp(cfg, p, x, tp=tp, moe_ffn=moe_ffn)
 
 
 def _cached_attention(cfg, q, k_cache, v_cache, q_positions, kv_len_mask, kv_len=None):
@@ -238,7 +248,7 @@ def _forward_with_cache(cfg, params, tokens, cache: KVCache, start_pos):
         attn = _cached_attention(
             cfg, q, k_cache_l, v_cache_l, positions_b, kv_len_mask, kv_len=start_pos + T
         )
-        x = _post_attention(cfg, p, x, attn)
+        x, _ = _post_attention(cfg, p, x, attn)
         return x, (k_cache_l, v_cache_l)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -729,7 +739,11 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
     the Pallas ragged path the fused kernel is the only operation applied
     to them (aliased in → out), which also leaves their layout to nobody
     but the kernel.
-    Returns (logits [B, T, V], new_k_pages, new_v_pages)."""
+    An MoE model routes only the window's live tokens (``ragged_q_lens``,
+    else ``write_valid``, else all of them) and hands back its per-layer,
+    per-expert assignment counts.
+    Returns (logits [B, T, V], new_k_pages, new_v_pages, moe_counts [L, E]
+    or None for a dense model)."""
     from deepspeed_tpu.ops.transformer.paged_attention import (
         paged_decode_attention,
         paged_prefill_attention,
@@ -743,6 +757,17 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
     if cfg.position == "learned":
         x = x + params["embed"]["pos"].astype(dtype)[positions_b]
     scale = _softmax_scale(cfg, cfg.head_dim)
+    live = write_valid
+    if ragged_q_lens is not None:
+        live = jnp.arange(T, dtype=jnp.int32)[None, :] < ragged_q_lens[:, None]
+    # a dropless MoE model's expert stacks stay out of the scanned per-layer
+    # weights, like the pools: seen as one stack of L x E experts, the grouped
+    # matmul reaches its layer's through an offset, and nothing copies a
+    # layer's experts out of the stack first
+    layers, expert_stacks = params["layers"], None
+    if "moe" in layers and not cfg.moe_drop_tokens:
+        expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), layers["moe"]["experts"])
+        layers = {**layers, "moe": {k: v for k, v in layers["moe"].items() if k != "experts"}}
 
     # named scopes (here, in ``_post_attention``, ``_ffn_body`` and
     # ``_final_logits``) put the region into every op's name stack, where a
@@ -778,13 +803,16 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
                         q, kp, vp, layer, page_table, positions_b, scale=scale,
                         kv_lens=prefill_kv_lens,
                     )
-        x = _post_attention(cfg, p, x, attn, tp=tp)
-        return (x, kp, vp, layer + 1), None
+        moe_ffn = functools.partial(_moe_ffn, live=live)
+        if expert_stacks is not None:
+            moe_ffn = functools.partial(moe_ffn, experts=expert_stacks, group_offset=layer * cfg.num_experts)
+        x, moe_counts = _post_attention(cfg, p, x, attn, tp=tp, moe_ffn=moe_ffn)
+        return (x, kp, vp, layer + 1), moe_counts
 
-    (x, new_k, new_v, _), _ = jax.lax.scan(
-        layer_step, (x, k_pages, v_pages, jnp.int32(0)), params["layers"]
+    (x, new_k, new_v, _), moe_counts = jax.lax.scan(
+        layer_step, (x, k_pages, v_pages, jnp.int32(0)), layers
     )
-    return _final_logits(cfg, params, x), new_k, new_v
+    return _final_logits(cfg, params, x), new_k, new_v, moe_counts
 
 
 def build_paged_decode_step(cfg, bucket: int, page_size: int, attn_impl: str = "auto",
@@ -807,7 +835,7 @@ def build_paged_decode_step(cfg, bucket: int, page_size: int, attn_impl: str = "
         return fn
 
     def _decode(params, tokens, k_pages, v_pages, page_table, lengths):
-        logits, new_k, new_v = _paged_forward(
+        logits, new_k, new_v, _ = _paged_forward(
             cfg, params, tokens[:, None], k_pages, v_pages, page_table,
             lengths[:, None], lengths + 1, attn_impl,
         )
@@ -844,7 +872,7 @@ def build_paged_prefill(cfg, chunk: int, page_size: int, attn_impl: str = "auto"
         offs = jnp.arange(T, dtype=jnp.int32)
         positions_b = start[:, None] + offs[None, :]
         valid = (offs <= last_idx)[None, :]  # pad tail -> trash page
-        logits, new_k, new_v = _paged_forward(
+        logits, new_k, new_v, _ = _paged_forward(
             cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
             None, attn_impl, write_valid=valid,
         )
@@ -905,7 +933,7 @@ def build_paged_verify_step(cfg, bucket: int, K: int, page_size: int,
         # trash page and their kv rows are masked out of the attention
         valid = offs[None, :] <= draft_lens[:, None]
         kv_lens = jnp.where(lengths > 0, lengths + draft_lens + 1, 0)
-        logits, new_k, new_v = _paged_forward(
+        logits, new_k, new_v, _ = _paged_forward(
             cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
             None, attn_impl, write_valid=valid, prefill_kv_lens=kv_lens,
         )
@@ -981,7 +1009,7 @@ def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: 
             tok, kp, vp, lens, alive, emitted = carry
             q_lens = alive.astype(jnp.int32)  # [R]: 1 live, 0 frozen/dead
             kv_lens = jnp.where(alive, lens + 1, 0)
-            logits, kp, vp = _paged_forward(
+            logits, kp, vp, _ = _paged_forward(
                 run_cfg, params, tok[:, None], kp, vp, page_table, lens[:, None],
                 None, attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
             )
@@ -1014,6 +1042,19 @@ def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: 
     return fn
 
 
+MOE_STAT_ROWS = 3
+
+
+def _moe_stat_rows(moe_counts, width: int):
+    """A step's routing counts, as rows for the packed result of an MoE
+    model's ragged step: column 0 of three rows beneath the ``rows`` real
+    ones holds the live (token, expert) assignments over all layers, the
+    experts hit (with at least one live token; summed over layers) and the
+    largest number of assignments any one expert of any layer received."""
+    stats = jnp.stack([jnp.sum(moe_counts), jnp.sum(moe_counts > 0), jnp.max(moe_counts)]).astype(jnp.int32)
+    return jnp.zeros((MOE_STAT_ROWS, width), jnp.int32).at[:, 0].set(stats)
+
+
 def build_ragged_step(cfg, rows: int, width: int, page_size: int,
                       attn_impl: str = "auto", telemetry=None, tp=None):
     """THE one serving program: a ``rows × width`` ragged step that handles
@@ -1043,7 +1084,9 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
     is the verify rows' accepted-prefix length (count of leading drafts
     matching the model's own greedy argmax — byte-identical to sequential
     decode; 0 wherever nothing was drafted). Pages are donated; the packed
-    [R, W+1] fetch is the step's only host traffic.
+    [R, W+1] fetch is the step's only host traffic. An MoE model's result has
+    ``MOE_STAT_ROWS`` more rows, whose column 0 holds the step's routing
+    counts (``_moe_stat_rows``): the same one fetch.
 
     Because slot count, chunk progress, spec-K, and the mode mix all ride
     in as array contents, shifting traffic NEVER retraces: the scheduler
@@ -1075,7 +1118,7 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
         offs = jnp.arange(W, dtype=jnp.int32)
         positions_b = lengths[:, None] + offs[None, :]
         kv_lens = jnp.where(q_lens > 0, lengths + q_lens, 0)
-        logits, new_k, new_v = _paged_forward(
+        logits, new_k, new_v, moe_counts = _paged_forward(
             run_cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
             None, attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
         )
@@ -1088,6 +1131,8 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
             # prefill rows' accepted count is ignored by the host)
             accepted = _accepted_prefix(tokens, greedy, q_lens - 1)
             packed = jnp.concatenate([accepted[:, None].astype(jnp.int32), greedy], axis=1)
+            if moe_counts is not None:
+                packed = jnp.concatenate([packed, _moe_stat_rows(moe_counts, W + 1)], axis=0)
         return packed, new_k, new_v
 
     body = _step if tp is None else tp.shard_program(_step, n_args=7)

@@ -77,6 +77,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from deepspeed_tpu.inference.decode import (
+    MOE_STAT_ROWS,
     build_paged_decode_step,
     build_paged_prefill,
     build_paged_verify_step,
@@ -265,14 +266,20 @@ class PagedServer:
         if is_moe and "moe_layers" in params:
             raise NotImplementedError(
                 "paged serving supports MoE only with moe_layer_freq == 1 "
-                "(a scanned [L, E, ...] expert stack); interleaved "
+                "(a scanned [L, E, ...] expert stack, routed by capacity or, "
+                "with moe_drop_tokens=False, dropless); interleaved "
                 "dense/MoE stacks keep experts outside the layer scan"
             )
         if is_moe and tp is not None and tp.degree > 1:
             raise NotImplementedError(
-                "tensor-parallel MoE serving is not supported: expert "
-                "placement is the 'expert' mesh axis, not a TP weight split"
+                "tensor-parallel MoE serving is not supported (with or without "
+                "moe_drop_tokens): expert placement is the 'expert' mesh axis, "
+                "not a TP weight split"
             )
+        # an MoE model's ragged step appends its routing counts to the
+        # step's one result (decode.py:_moe_stat_rows); a dense model's
+        # result, stats keys and spans are as they were
+        self._moe_slots = cfg.num_layers * cfg.num_experts if is_moe else 0
         if tp is not None:
             if not ragged:
                 raise ValueError(
@@ -453,6 +460,12 @@ class PagedServer:
             # pairs whose accepted prefix was exactly n drafts long
             "spec_accept_hist": [0] * (self._draft_cap + 1),
         }
+        if self._moe_slots:
+            # ragged steps only: live (token, expert) assignments over all
+            # layers; experts hit (>= 1 live token), summed over layers; the
+            # largest load any one expert of any layer took in one step
+            self.stats.update(moe_assignments=0, moe_experts_hit=0, moe_max_expert_load=0)
+            self._g_moe_hit = self.metrics.gauge("serve.moe_experts_hit_share")
 
     # --- request intake -------------------------------------------------
     def _tenant(self, name: str) -> Dict:
@@ -1026,6 +1039,14 @@ class PagedServer:
             out = np.asarray(out)  # lint: allow(DS-R005)
         with self.tracer.span("serve.settle") as settle_span:
             emitted = self.stats["emitted_tokens"]
+            if self._moe_slots:
+                out, moe = out[:-MOE_STAT_ROWS], out[-MOE_STAT_ROWS:, 0]
+                assignments, hit, max_load = (int(v) for v in moe)
+                self.stats["moe_assignments"] += assignments
+                self.stats["moe_experts_hit"] += hit
+                self.stats["moe_max_expert_load"] = max(self.stats["moe_max_expert_load"], max_load)
+                self._g_moe_hit.set(hit / self._moe_slots)
+                settle_span.set(moe_assignments=assignments, moe_experts_hit=hit, moe_max_expert_load=max_load)
             self._settle_fetched_rows(rows, out, chunk_len, q_lens)
             settle_span.set(tokens=self.stats["emitted_tokens"] - emitted)
 

@@ -166,6 +166,11 @@ class TPServing:
 
     # --- config & weights ------------------------------------------------
     def validate_cfg(self, cfg) -> None:
+        if getattr(cfg, "qk_norm", None) is not None:
+            raise NotImplementedError(
+                "tensor-parallel serving does not support qk_norm: the norm runs over the "
+                "whole q / k projection, which the head split cuts across chips"
+            )
         if cfg.num_heads % self.degree or cfg.num_kv_heads % self.degree:
             raise ValueError(
                 f"tensor-parallel serving shards the head axes: num_heads="

@@ -4,6 +4,7 @@ from deepspeed_tpu.models.moe_transformer import (
     MoETransformerLM,
     mixtral_config,
     moe_llama_config,
+    olmoe_config,
 )
 from deepspeed_tpu.models.transformer import TransformerLM, cross_entropy_loss
 from deepspeed_tpu.models.unet import (

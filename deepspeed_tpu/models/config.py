@@ -41,6 +41,10 @@ class TransformerConfig:
     hidden_dropout: float = 0.0
     use_bias: bool = True  # linear biases (gpt2 yes, llama no)
     qkv_bias: Optional[bool] = None  # override for qkv projs
+    # QK-norm. "projection": RMSNorm (``norm_eps``, learned scale) over the WHOLE
+    # q projection [NH*D] and the whole k projection [NKV*D], before the split
+    # into heads and RoPE (the OLMoE family), so the cached k is the normed one
+    qk_norm: Optional[str] = None
     dtype: str = "bfloat16"  # computation dtype for activations
 
     # sparse embedding gradients (reference engine.py:2398: DP-reduce the
@@ -69,6 +73,8 @@ class TransformerConfig:
                 self.intermediate_size = 4 * self.hidden_size
         if self.qkv_bias is None:
             self.qkv_bias = self.use_bias
+        if self.qk_norm not in (None, "projection"):
+            raise ValueError(f"unknown qk_norm {self.qk_norm!r}; expected None or 'projection'")
         if self.sequence_parallel_mode not in ("ulysses", "ring"):
             raise ValueError(
                 f"unknown sequence_parallel_mode {self.sequence_parallel_mode!r}; "

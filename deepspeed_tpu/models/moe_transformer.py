@@ -37,7 +37,14 @@ class MoETransformerConfig(TransformerConfig):
     min_capacity: int = 4
     use_residual: bool = False  # PR-MoE
     noisy_gate_policy: Optional[str] = None  # None | 'RSample' | 'Jitter'
+    # True: capacity routing (top-1 / top-2 only; tokens over an expert's
+    # capacity are dropped). False: dropless, any k: tokens sorted by expert
+    # and multiplied group by group (moe/routed_ffn.py); not expert-parallel yet
     moe_drop_tokens: bool = True
+    # dropless routing: renormalise the k chosen gates to sum to one. None: as
+    # the capacity gates do (top-1 keeps the plain gate, k > 1 renormalises;
+    # moe/routed_ffn.py::route). OLMoE: False
+    moe_norm_topk_prob: Optional[bool] = None
     moe_use_rts: bool = True
     moe_aux_loss_coef: float = 0.01
     expert_intermediate_size: Optional[int] = None
@@ -49,6 +56,11 @@ class MoETransformerConfig(TransformerConfig):
         super().__post_init__()
         if self.expert_intermediate_size is None:
             self.expert_intermediate_size = self.intermediate_size
+        if self.moe_top_k > 2 and self.moe_drop_tokens:
+            raise ValueError(
+                f"moe_top_k={self.moe_top_k} needs moe_drop_tokens=False: capacity routing "
+                "(moe_drop_tokens=True) supports top-1 and top-2 only"
+            )
         if self.moe_layer_freq > 1:
             # mixed dense/MoE stacks can't share one scanned param stack
             self.scan_layers = False
@@ -74,6 +86,7 @@ class MoETransformerLM(TransformerLM):
             use_bias=cfg.use_bias,
             out_std=0.02 / np.sqrt(2 * cfg.num_layers),
             quantized_a2a=cfg.moe_quantized_a2a,
+            norm_topk_prob=cfg.moe_norm_topk_prob,
         )
         moe_layers = [i for i in range(cfg.num_layers) if self._is_moe_layer(i)]
         dense_layers = [i for i in range(cfg.num_layers) if not self._is_moe_layer(i)]
@@ -179,6 +192,10 @@ class MoETransformerLM(TransformerLM):
         return super()._mlp(p, h, rng, train)
 
 def moe_llama_config(size: str = "tiny", **overrides) -> MoETransformerConfig:
+    """Llama body with 8 experts in every layer. Routing as the defaults give
+    it: top-1 through the capacity path (``moe_drop_tokens=True``, tokens
+    over capacity dropped); ``moe_drop_tokens=False`` takes the sorted,
+    grouped path instead."""
     presets = {
         "tiny": dict(hidden_size=256, num_layers=4, num_heads=8, vocab_size=32000, max_seq_len=512),
         "1b-8e": dict(hidden_size=2048, num_layers=22, num_heads=32, num_kv_heads=4, vocab_size=32000),
@@ -197,7 +214,11 @@ def moe_llama_config(size: str = "tiny", **overrides) -> MoETransformerConfig:
 
 def mixtral_config(size: str = "8x7b", **overrides) -> MoETransformerConfig:
     """Mixtral presets (BASELINE config 5's model family): GQA llama body,
-    8 experts, top-2 routing, every layer MoE."""
+    8 experts, top-2 routing with the two gates renormalised, every layer
+    MoE. As configured here it routes through the capacity path
+    (``moe_drop_tokens=True``: tokens over ``capacity_factor`` are dropped,
+    which the published model does not do); ``moe_drop_tokens=False`` gives
+    the published dropless routing by the sorted, grouped path."""
     presets = {
         "tiny": dict(hidden_size=256, num_layers=4, num_heads=8, num_kv_heads=2, vocab_size=32000, max_seq_len=512),
         "8x7b": dict(
@@ -220,6 +241,38 @@ def mixtral_config(size: str = "8x7b", **overrides) -> MoETransformerConfig:
         num_experts=8,
         moe_top_k=2,
         moe_layer_freq=1,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    return MoETransformerConfig(**base)
+
+
+def olmoe_config(size: str = "1b-7b", **overrides) -> MoETransformerConfig:
+    """OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct ``config.json``,
+    ``model_type: olmoe``): 16 layers of MHA (16 heads of 128, RoPE 1e4,
+    RMSNorm 1e-5) with QK-norm over the whole q and k projections, and in
+    every layer 64 SwiGLU experts of width 1,024, 8 a token, no shared
+    expert, softmax over all 64 and the top 8 NOT renormalised, no drops:
+    the sorted, grouped path (``moe/routed_ffn.py``). Vocabulary 50,304,
+    head untied, 4,096 positions. 6.92 B parameters, 1.3 B active."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=2, num_heads=4, intermediate_size=32, vocab_size=512,
+                     max_seq_len=256, num_experts=8, moe_top_k=3),
+        "1b-7b": dict(hidden_size=2048, num_layers=16, num_heads=16, num_kv_heads=16, intermediate_size=1024,
+                      vocab_size=50304, max_seq_len=4096, num_experts=64, moe_top_k=8),
+    }
+    base = dict(
+        norm="rmsnorm",
+        norm_eps=1e-5,
+        position="rope",
+        rope_theta=10000.0,
+        activation="swiglu",
+        use_bias=False,
+        tie_embeddings=False,
+        qk_norm="projection",
+        moe_layer_freq=1,
+        moe_drop_tokens=False,
+        moe_norm_topk_prob=False,
     )
     base.update(presets[size])
     base.update(overrides)

@@ -188,6 +188,9 @@ class TransformerLM(DSModule):
         if cfg.norm == "layernorm":
             layer["attn_norm_bias"] = jnp.zeros((L, H))
             layer["mlp_norm_bias"] = jnp.zeros((L, H))
+        if cfg.qk_norm == "projection":
+            layer["q_norm_scale"] = jnp.ones((L, NH * D))
+            layer["k_norm_scale"] = jnp.ones((L, NKV * D))
         if cfg.qkv_bias:
             layer["bq"] = jnp.zeros((L, NH * D))
             layer["bk"] = jnp.zeros((L, NKV * D))
@@ -422,6 +425,9 @@ class TransformerLM(DSModule):
             v = h @ p["wv"].astype(h.dtype)
             if cfg.qkv_bias:
                 q, k, v = q + p["bq"].astype(h.dtype), k + p["bk"].astype(h.dtype), v + p["bv"].astype(h.dtype)
+            if cfg.qk_norm == "projection":
+                q = _norm(q, p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+                k = _norm(k, p["k_norm_scale"], None, "rmsnorm", cfg.norm_eps)
             q = q.reshape(B, T, NH, D)
             k = k.reshape(B, T, NKV, D)
             v = v.reshape(B, T, NKV, D)

@@ -2,7 +2,9 @@
 
 TPU-native counterpart of ``deepspeed/moe/``: top-1/top-2 gating with
 capacity + load-balance loss, expert dispatch over the ``expert`` mesh axis
-(GSPMD all-to-all), stacked-expert FFNs, PR-MoE residual.
+(GSPMD all-to-all), stacked-expert FFNs, PR-MoE residual; and dropless top-k
+routing for any k, tokens sorted by expert and multiplied group by group
+(``moe/routed_ffn.py``, ``moe/grouped_matmul.py``), which is what ``drop_tokens=False`` means.
 """
 
 from deepspeed_tpu.moe.layer import MoE

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.moe import a2a, sharded_moe
+from deepspeed_tpu.moe import a2a, routed_ffn, sharded_moe
 from deepspeed_tpu.moe.experts import (
     apply_dense_ffn,
     apply_expert_ffn,
@@ -31,6 +31,73 @@ from deepspeed_tpu.moe.experts import (
     init_dense_ffn,
     init_expert_ffn,
 )
+
+
+def routed_experts(
+    experts: Dict[str, Any],
+    tokens: jnp.ndarray,
+    logits: jnp.ndarray,
+    *,
+    k: int,
+    activation: str,
+    drop_tokens: bool,
+    norm_topk_prob: Optional[bool],
+    capacity_factor: float,
+    min_capacity: int,
+    use_rts: bool = True,
+    live: Optional[jnp.ndarray] = None,
+    rng: Optional[jax.Array] = None,
+    noisy_gate_policy: Optional[str] = None,
+    constrain=lambda x, spec: x,
+    group_offset=0,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The routed FFN on one expert group: ``tokens`` [S, H] with router
+    ``logits`` [S, E] (float32) through the stacked ``experts``; ``live`` [S]
+    marks the tokens that are routed at all (a serving window's dead slots
+    are not). The ONE routing function of training (``MoE.apply``) and of
+    serving (``inference/decode.py``); only ``MoE.apply``'s explicit
+    all-to-all fast path over an ``expert`` mesh axis goes round it.
+
+    ``drop_tokens=False``: the sorted, grouped path, any k
+    (``moe/routed_ffn.py``). ``drop_tokens=True``: the capacity gates and
+    their dispatch / combine einsums, k of 1 or 2 (``sharded_moe.py``).
+    ``group_offset`` (dropless only): where the E experts begin in a longer
+    stack (``routed_ffn``).
+    Returns ``(out [S, H], l_aux, counts [E])``."""
+    if not drop_tokens:
+        noisy = None
+        if noisy_gate_policy == "RSample" and rng is not None:
+            noisy = logits + sharded_moe.gumbel_rsample(logits.shape, rng)
+        out, counts, gates = routed_ffn.routed_ffn(
+            experts, tokens, logits, k=k, activation=activation,
+            norm_topk_prob=norm_topk_prob, live=live, select_logits=noisy, group_offset=group_offset,
+        )
+        return out, routed_ffn.load_balance_loss(gates, counts, k, live), counts
+    l_aux, combine_w, dispatch_m, counts = sharded_moe.topkgating(
+        logits,
+        k,
+        capacity_factor,
+        min_capacity,
+        drop_tokens=True,
+        rng=rng,
+        noisy_gate_policy=noisy_gate_policy,
+        use_rts=use_rts,
+        used_token_mask=live,
+    )
+    dispatched = constrain(sharded_moe.dispatch(tokens, dispatch_m), P("expert", None, None))
+    expert_out = constrain(apply_expert_ffn(experts, dispatched, activation), P("expert", None, None))
+    return sharded_moe.combine(expert_out, combine_w), l_aux, counts
+
+
+def residual_mix(params: Dict[str, Any], tokens: jnp.ndarray, out: jnp.ndarray, activation: str) -> jnp.ndarray:
+    """PR-MoE: a dense MLP beside the experts, mixed by a learned 2-way
+    coefficient; ``out`` unchanged for a layer without one."""
+    if "mlp" not in params:
+        return out
+    mlp_out = apply_dense_ffn(params["mlp"], tokens, activation)
+    coef = tokens.astype(jnp.float32) @ params["coefficient"]["w"] + params["coefficient"]["b"]
+    coef = jax.nn.softmax(coef, axis=-1).astype(out.dtype)
+    return out * coef[..., 0:1] + mlp_out * coef[..., 1:2]
 
 
 class MoE:
@@ -59,6 +126,7 @@ class MoE:
         use_bias: bool = True,
         out_std: Optional[float] = None,
         quantized_a2a: bool = False,
+        norm_topk_prob: Optional[bool] = None,
     ):
         self.hidden_size = hidden_size
         self.num_experts = num_experts
@@ -81,6 +149,9 @@ class MoE:
         # int8 dispatch/combine wire format (EQuARX-style); an active
         # OverlapPlan's a2a stage overrides this layer-local default
         self.quantized_a2a = quantized_a2a
+        # dropless routing: whether the k chosen gates are renormalised to sum
+        # to one. None: as the capacity gates do (routed_ffn.route); OLMoE: False
+        self.norm_topk_prob = norm_topk_prob
 
     # --- params ---------------------------------------------------------
     def init(self, rng) -> Dict[str, Any]:
@@ -154,7 +225,12 @@ class MoE:
         cf = self.capacity_factor if train else self.eval_capacity_factor
         from deepspeed_tpu.parallel.mesh import _TOPOLOGY
 
-        if a2a.ep_fast_path(_TOPOLOGY, self.num_experts, tokens.shape[0]):
+        if not self.drop_tokens and _TOPOLOGY is not None and _TOPOLOGY.config.expert > 1:
+            raise NotImplementedError(
+                "dropless routing (drop_tokens=False) is not expert-parallel yet: the mesh's "
+                f"'expert' axis is {_TOPOLOGY.config.expert}; use expert=1 or drop_tokens=True"
+            )
+        if self.drop_tokens and a2a.ep_fast_path(_TOPOLOGY, self.num_experts, tokens.shape[0]):
             # expert-parallel fast path: per-shard gating + explicit
             # dispatch/combine all-to-alls (moe/a2a.py). The dispatch a2a is
             # emitted before the residual/shared-dense branch and the combine
@@ -194,28 +270,23 @@ class MoE:
             l_aux = jnp.mean(l_aux_shards)
             exp_counts = jnp.sum(count_shards, axis=0)
         else:
-            l_aux, combine_w, dispatch_m, exp_counts = sharded_moe.topkgating(
+            live = None if used_token_mask is None else used_token_mask.reshape(-1).astype(bool)
+            out, l_aux, exp_counts = routed_experts(
+                params["experts"],
+                tokens,
                 logits,
-                self.k,
-                cf,
-                self.min_capacity,
+                k=self.k,
+                activation=self.activation,
                 drop_tokens=self.drop_tokens,
+                norm_topk_prob=self.norm_topk_prob,
+                capacity_factor=cf,
+                min_capacity=self.min_capacity,
+                use_rts=self.use_rts,
+                live=live,
                 rng=rng if train else None,
                 noisy_gate_policy=self.noisy_gate_policy if train else None,
-                use_rts=self.use_rts,
-                used_token_mask=used_token_mask,
+                constrain=self._constrain,
             )
 
-            dispatched = sharded_moe.dispatch(tokens, dispatch_m)
-            dispatched = self._constrain(dispatched, P("expert", None, None))
-            expert_out = apply_expert_ffn(params["experts"], dispatched, self.activation)
-            expert_out = self._constrain(expert_out, P("expert", None, None))
-            out = sharded_moe.combine(expert_out, combine_w)
-
-        if self.use_residual:
-            mlp_out = apply_dense_ffn(params["mlp"], tokens, self.activation)
-            coef = tokens.astype(jnp.float32) @ params["coefficient"]["w"] + params["coefficient"]["b"]
-            coef = jax.nn.softmax(coef, axis=-1).astype(out.dtype)
-            out = out * coef[..., 0:1] + mlp_out * coef[..., 1:2]
-
+        out = residual_mix(params, tokens, out, self.activation)
         return out.reshape(orig_shape), l_aux, exp_counts

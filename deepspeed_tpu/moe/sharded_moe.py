@@ -23,8 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-uniform_map = None  # parity marker (reference caches torch.distributions here)
-
 
 def _capacity(num_tokens: int, num_experts: int, capacity_factor: float, min_capacity: int) -> int:
     """Static tokens-per-expert capacity (reference sharded_moe.py:85)."""
@@ -222,7 +220,10 @@ def topkgating(
             used_token_mask=used_token_mask,
             top2_2nd_expert_sampling=rng is not None,
         )
-    raise ValueError(f"Only top-1 and top-2 gating are supported (got k={k})")
+    raise ValueError(
+        f"capacity routing (drop_tokens=True) supports top-1 and top-2 only (got k={k}); "
+        "any k runs dropless: drop_tokens=False (moe_drop_tokens in the model config), moe/routed_ffn.py"
+    )
 
 
 def dispatch(tokens: jnp.ndarray, dispatch_mask: jnp.ndarray) -> jnp.ndarray:

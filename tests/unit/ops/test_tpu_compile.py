@@ -30,8 +30,10 @@ from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.analysis.hlo import parse_computations, parse_input_output_aliases
 from deepspeed_tpu.inference import decode
-from deepspeed_tpu.models import TransformerLM
+from deepspeed_tpu.models import MoETransformerLM, TransformerLM
 from deepspeed_tpu.models.config import TransformerConfig
+from deepspeed_tpu.models.moe_transformer import MoETransformerConfig
+from deepspeed_tpu.moe.grouped_matmul import grouped_matmul
 from deepspeed_tpu.ops.sparse_attention.pallas_block_sparse import pallas_block_sparse_attention
 from deepspeed_tpu.ops.sparse_attention.sparsity_config import BSLongformerSparsityConfig
 from deepspeed_tpu.ops.transformer.decode_attention import (
@@ -113,6 +115,10 @@ _LENS = ((8,), I32)
 _TRAIN_QKV = [((8, 1024, 12, 64), BF16)] * 3
 _SPARSE_QKV = [((1, 8, _SPARSE_T, 64), BF16)] * 3
 
+def _grouped(x, w, sizes):
+    return grouped_matmul(x, w, sizes, out_dtype=jnp.float32, impl="pallas")
+
+
 CASES = {
     "flash_fwd_gpt2_125m": (_flash_fwd, _TRAIN_QKV),
     "flash_bwd_gpt2_125m": (_bwd(_flash_fwd), _TRAIN_QKV),
@@ -129,6 +135,10 @@ CASES = {
         _dense_decode,
         [((8, 32, 64), BF16), ((8, 2048, 4, 64), BF16), ((8, 2048, 4, 64), BF16), _LENS],
     ),
+    # OLMoE's expert matmuls: 64 experts of 2048 x 1024, a narrow step's 128
+    # assignments and a mixed step's 16,384
+    "moe_grouped_up_narrow": (_grouped, [((128, 2048), BF16), ((64, 2048, 1024), BF16), ((64,), I32)]),
+    "moe_grouped_down_mixed": (_grouped, [((16384, 1024), BF16), ((64, 1024, 2048), BF16), ((64,), I32)]),
     "block_sparse_fwd_8k": (_sparse_fwd, _SPARSE_QKV),
     "block_sparse_bwd_8k": (_bwd(_sparse_fwd), _SPARSE_QKV),
 }
@@ -203,3 +213,61 @@ def test_ragged_step_keeps_the_pool_in_one_buffer(v5e, monkeypatch, width):
     assert not strangers, f"pool-shaped results outside the kernel: {strangers}"
     pool_bytes = int(np.prod(pool.shape)) * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+_OLMOE_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/olmoe-1b-7b-0125-l12.json"
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_olmoe_ragged_step_fits_and_names_its_kernels(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the OLMoE cell's shapes (12 layers, 64 experts
+    of 1,024, top-8, 16 rows, 385 pages of 64): it compiles for a v5e, the
+    pools stay aliased, weights + pools + temporaries fit the chip, and the
+    expert matmuls are the ``moe_grouped_matmul`` kernel. Only the ragged
+    attention kernel may open with three ``s32`` operands: the benchmark's
+    accepted readers tell it by that signature
+    (``benchmark/kernels/ragged_paged_attention.py``), and ``jax.lax.ragged_dot``'s
+    own lowering, which opens with five, would be counted as one."""
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul"):
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = json.loads(_OLMOE_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = MoETransformerConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    n_pages = rows * maxp + 1
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: MoETransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32)))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
+    pool = on_v5e((cfg.num_layers, n_pages, cfg.num_kv_heads, page, cfg.head_dim), BF16)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), pool, pool, on_v5e((rows, maxp), I32),
+        on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+    first_pool = len(jax.tree_util.tree_leaves(params)) + 1
+    assert {first_pool, first_pool + 1} <= parse_input_output_aliases(text)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
+    # the compiled text names a call's operands without their types: look them up
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+)", text, flags=re.M))
+    kernels = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*? custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text, flags=re.M))
+    opens_with_three_s32 = [
+        name for name, operands in kernels.items()
+        if all(shape_of[o].startswith("s32[") for o in re.findall(r"%([\w.-]+)", operands)[:3])
+    ]
+    assert len(opens_with_three_s32) == 1 and opens_with_three_s32[0].startswith("ragged_paged_attention"), kernels
+    assert sum(name.startswith("moe_grouped_matmul") for name in kernels) == 3, kernels
+    # the expert stacks reach the kernel as they lie (every layer's, seen as one stack), not as a layer's copy:
+    # the kernel's weight operand has L x E matrices and the temporaries hold no 268 MB matrix stack of one layer
+    for name, operands in kernels.items():
+        if name.startswith("moe_grouped_matmul"):
+            weights = shape_of[re.findall(r"%([\w.-]+)", operands)[2]]
+            assert weights.startswith(f"bf16[{cfg.num_layers * cfg.num_experts},"), (name, weights)
+    assert memory.temp_size_in_bytes < 0.5e9
+    # the step's one result: 16 rows of width + 1, and the three rows of routing counts
+    assert re.search(rf"s32\[{rows + decode.MOE_STAT_ROWS},{width + 1}\]", text)
