@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import gc
+import itertools
 import json
 import os
 import re
@@ -341,7 +342,10 @@ def generate_reference(engine, prompts, budgets):
 def attention_parity(sz, seed: int) -> dict:
     """``ragged_paged_attention`` Pallas vs XLA on seeded inputs at the
     server's two window shapes: the outputs of live rows and slots, and the
-    bytes both leave in every page but the trash page (layer 1 of two)."""
+    bytes both leave in every page but the trash page (layer 1 of two). Once
+    with the server's heads and once with the same widths cut into heads of
+    128: a head of whole lanes takes the kernel that walks a row's live
+    pages, a narrower one the grid over the page table."""
     from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
 
     cfg, paged = sz.serve_model, sz.paged
@@ -349,16 +353,19 @@ def attention_parity(sz, seed: int) -> dict:
     maxp = cfg.max_seq_len // page
     n_pages = rows * maxp + 1
     rs = np.random.RandomState(seed + 2)
-    shape = (2, n_pages, cfg.num_kv_heads, page, cfg.head_dim)
-    k_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
-    v_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
     table = jnp.asarray(1 + rs.permutation(rows * maxp).reshape(rows, maxp), jnp.int32)
     worst = {}
-    for width in (1, paged["prefill_chunk"]):
-        q = jnp.asarray(rs.randn(rows, width, cfg.num_heads, cfg.head_dim), jnp.bfloat16)
+    whole_lanes = max(1, 128 // cfg.head_dim)  # heads that make one head of 128
+    layouts = {(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)}
+    if cfg.num_kv_heads % whole_lanes == 0:
+        layouts.add((cfg.num_heads // whole_lanes, cfg.num_kv_heads // whole_lanes, cfg.head_dim * whole_lanes))
+    for (heads, kv_heads, head_dim), width in itertools.product(sorted(layouts), (1, paged["prefill_chunk"])):
+        shape = (2, n_pages, kv_heads, page, head_dim)
+        k_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
+        v_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
+        q = jnp.asarray(rs.randn(rows, width, heads, head_dim), jnp.bfloat16)
         k_new, v_new = (
-            jnp.asarray(rs.randn(rows, width, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
-            for _ in range(2)
+            jnp.asarray(rs.randn(rows, width, kv_heads, head_dim), jnp.bfloat16) for _ in range(2)
         )
         q_lens = rs.randint(1, width + 1, (rows,)).astype(np.int32)
         q_lens[-1] = 0  # a dead row
@@ -380,7 +387,7 @@ def attention_parity(sz, seed: int) -> dict:
         a, b = (got[impl][0].astype(np.float32)[live] for impl in ("pallas", "xla"))
         assert np.isfinite(a).all() and np.isfinite(b).all()
         np.testing.assert_allclose(a, b, atol=BF16_ATTN_TOL, rtol=BF16_ATTN_TOL)
-        worst[f"w{width}"] = float(np.abs(a - b).max())
+        worst[f"d{head_dim}_w{width}"] = float(np.abs(a - b).max())
     return worst
 
 

@@ -1011,7 +1011,12 @@ class PagedServer:
                     tokens[i, 1 : 1 + d.size] = d
                     q_lens[i] = 1 + d.size
             program = ragged_program_name(R, W, self.tp)
-            pack_span.set(rows=len(rows), width=W, program=program)
+            # pages the ragged kernel walks a layer (the live rows' own) of the
+            # table slots a grid over the whole table would visit
+            kv_pages = int((-(-(lengths + q_lens)[q_lens > 0] // self.pool.page_size)).sum())
+            pack_span.set(
+                rows=len(rows), width=W, program=program, kv_pages=kv_pages, table_pages=page_table.size
+            )
         # dispatch = build + ENQUEUE only (jit returns futures; the fetch
         # below is where device time surfaces)
         with self.tracer.span("serve.dispatch", rows=len(rows), width=W, program=program):

@@ -30,6 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -227,27 +228,17 @@ def _page_receives(ki, page, kv_len, start):
     return (ki * page < kv_len) & ((ki + 1) * page > start) & (start < kv_len)
 
 
-def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
-                   m_s, l_s, acc_s, *page_bufs, scale, page, maxp, Hg, W):
-    """``page_bufs``: ``(kbuf, vbuf, sem)`` where a written page leaves by DMA
-    (``ko_ref`` / ``vo_ref`` are then the whole pools, in place), nothing
-    where it leaves through the out-blocks ``ko_ref`` / ``vo_ref``."""
+def _ragged_grid_kernel(pt_ref, len_ref, qlen_ref, x_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
+                        m_s, l_s, acc_s, *, scale, page, maxp, Hg, W):
+    """The kernel for heads that are no whole lanes (``D % 128 != 0``): one
+    grid step a (row, kv head, table slot), a written page leaves through the
+    out-blocks ``ko_ref`` / ``vo_ref``."""
     b = pl.program_id(0)
-    g = pl.program_id(1)
     ki = pl.program_id(2)
     kv_len = len_ref[b]
     start = kv_len - qlen_ref[b]  # the row's write base
     receives = _page_receives(ki, page, kv_len, start)
-    by_dma = bool(page_bufs)
-    merged = page_bufs[:2] if by_dma else (ko_ref.at[0, 0], vo_ref.at[0, 0])
-
-    def write_back(slot):
-        """The two copies of the merged pages to the page of table slot
-        ``slot``, to start and later to wait for."""
-        return [
-            pltpu.make_async_copy(buf, pool.at[pt_ref[b, slot], g], page_bufs[2].at[i])
-            for i, (buf, pool) in enumerate(zip(merged, (ko_ref, vo_ref)))
-        ]
+    merged = (ko_ref.at[0, 0], vo_ref.at[0, 0])
 
     @pl.when(ki == 0)
     def _init():
@@ -286,14 +277,7 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, k_ref, v_ref, o_ref, ko_ref
     @pl.when(receives)
     def _merge_attend_write():
         # the page as the step leaves it: the window's rows where they land,
-        # what was read everywhere else; attended from there and written back
-        if by_dma:
-
-            @pl.when(ki * page > start)
-            def _buffers_free():  # the row's page before this one is on its way
-                for copy in write_back(ki - 1):
-                    copy.wait()
-
+        # what was read everywhere else; attended from there
         pos = ki * page + jax.lax.broadcasted_iota(jnp.int32, (page, W), 0)
         w = jax.lax.broadcasted_iota(jnp.int32, (page, W), 1)
         sel = pos == start + w  # [page, W] one-hot: window slot w lands on page row p
@@ -308,113 +292,31 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, k_ref, v_ref, o_ref, ko_ref
                     preferred_element_type=jnp.float32,
                 ).astype(new.dtype)
             out[...] = jnp.where(hit, new, in_ref[0, 0])
-        if by_dma:
-            for copy in write_back(ki):
-                copy.start()
         attend(merged[0][...], merged[1][...])
 
-    if not by_dma:
-        # a fresh out-block is not loaded, so every step writes its own: where
-        # nothing is received it sits on the trash page and takes what was
-        # read, so the trash page only ever holds finite page contents
-        @pl.when(jnp.logical_not(receives))
-        def _keep():
-            merged[0][...] = k_ref[0, 0]
-            merged[1][...] = v_ref[0, 0]
+    # a fresh out-block is not loaded, so every step writes its own: where
+    # nothing is received it sits on the trash page and takes what was
+    # read, so the trash page only ever holds finite page contents
+    @pl.when(jnp.logical_not(receives))
+    def _keep():
+        merged[0][...] = k_ref[0, 0]
+        merged[1][...] = v_ref[0, 0]
 
     @pl.when(ki == maxp - 1)
     def _finish():
         l = l_s[:, :1]
         safe_l = jnp.where(l == 0, 1.0, l)
         o_ref[0, 0] = (acc_s[...] / safe_l).astype(o_ref.dtype)
-        if by_dma:
-
-            @pl.when(start < kv_len)
-            def _written():  # the buffers are the next (row, kv head)'s from here
-                for copy in write_back((kv_len - 1) // page):
-                    copy.wait()
 
 
-def ragged_paged_attention(
-    q: jnp.ndarray,  # [R, W, NH, D] — each row's padded token window
-    k_new: jnp.ndarray,  # [R, W, NKV, D] — the window's keys, not yet in the pool
-    v_new: jnp.ndarray,
-    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D] — every layer's shared page pool
-    v_pages: jnp.ndarray,
-    layer,  # int32 scalar: the layer whose pool this call writes and reads
-    page_table: jnp.ndarray,  # [R, MAXP] int32 page ids per row
-    kv_lens,  # [R] int32 live kv length INCLUDING this step's tokens
-    q_lens,  # [R] int32 real tokens in the row's window (0 = dead row)
-    scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
-):
-    """One ragged kernel for mixed prefill-chunk / decode / verify rows that
-    writes the step's keys and values into the pool and attends over it.
-
-    The per-row ``(kv_len, q_len)`` metadata rides in as scalar-prefetch
-    arrays (the Ragged Paged Attention design, arXiv 2604.15464): row r's
-    window holds ``q_lens[r]`` real tokens at absolute positions
-    ``kv_lens[r] - q_lens[r] ..`` — a decode row is q_len 1, a verify row
-    q_len K+1, a prefill chunk q_len C — and the kv grid walks the row's
-    page table, skipping pages past ``kv_lens[r]`` entirely, so changing
-    the prefill/decode/verify mix only changes ARRAY CONTENTS, never the
-    program. Queries ride the sublane dim W-major over the GQA group
-    (``[W*Hg, D] x [D, page]`` per block) with a causal in-window mask on
-    top of the length mask.
-
-    The pools are the whole ``[L, NP, NKV, P, D]`` stacks, aliased in → out
-    and seen as ``L * NP`` pages (a view), with ``layer`` folded into the page
-    table: the kernel is the only operation the program ever applies to
-    them, so they stay in one buffer and in the default layout through a
-    layer loop that carries them. A page that holds some of the positions
-    the row writes (``_page_receives``) is merged with the window's rows as
-    it is read — a one-hot product, exact — attended from there and written
-    back; the window rides behind the queries in one operand. A written page
-    belongs to one row (the pool's copy-on-write) and is visited once a kv
-    head, so no grid step reads what another writes.
-
-    How a written page leaves: where a ``[P, D]`` page is whole lanes
-    (``D % 128 == 0``) it is merged in a VMEM buffer and copied to the pool
-    by a DMA that is waited for when the row's next page needs the buffer
-    or its kv head is done, and a grid step that writes nothing moves and
-    computes what it did when the write was XLA's (measured on a v5e at the
-    Mistral cell's shapes, the kernel alone: +2-3% a call; through
-    out-blocks +28%). Mosaic
-    refuses that DMA for a narrower head (the pool's last dimension is
-    padded to 128 lanes and a page is no aligned slice of it), so there the
-    pools have out-blocks: on the page where it receives, on the trash page
-    0 everywhere else.
-
-    Returns ``(out [R, W, NH, D], k_pages, v_pages)``. Window slots past
-    ``q_lens[r]`` are not written and produce garbage rows the caller ignores
-    (finite: masked softmax over the live prefix); rows with
-    ``kv_lens[r] == 0`` return exact zeros."""
-    R, W, NH, D = q.shape
-    L, NP, NKV, P, Dk = k_pages.shape
-    assert Dk == D and v_pages.shape == k_pages.shape and v_pages.dtype == k_pages.dtype
-    assert k_new.shape == v_new.shape == (R, W, NKV, D)
-    if NH % NKV:
-        raise ValueError(f"query heads {NH} not a multiple of kv heads {NKV}")
-    maxp = page_table.shape[1]
-    scale_f = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
-    if interpret is None:
-        interpret = not on_tpu()
-    Hg = NH // NKV
-    # one operand a (row, kv head): the queries W-major (slot w of group head h
-    # at sublane w*Hg + h), then the window's keys, then its values — rounded
-    # to the pool's dtype first, in a dtype that holds both exactly
-    xdtype = jnp.promote_types(q.dtype, k_pages.dtype)
-    x = jnp.concatenate(
-        [q.reshape(R, W, NKV, Hg, D).transpose(0, 2, 1, 3, 4).reshape(R, NKV, W * Hg, D)]
-        + [new.astype(k_pages.dtype).transpose(0, 2, 1, 3) for new in (k_new, v_new)],
-        axis=2, dtype=xdtype,
-    )
-    lens = jnp.broadcast_to(jnp.asarray(kv_lens, jnp.int32), (R,))
-    qlens = jnp.broadcast_to(jnp.asarray(q_lens, jnp.int32), (R,))
-    # the trash page once more in a last column, for the out-blocks of grid
-    # steps that write nothing
-    pages = _pages_in_stack(layer, jnp.pad(page_table, ((0, 0), (0, 1)), constant_values=-1), NP)
-    kernel = functools.partial(_ragged_kernel, scale=scale_f, page=P, maxp=maxp, Hg=Hg, W=W)
+def _ragged_by_grid(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dtype, interpret):
+    """``_ragged_grid_kernel`` over ``R x NKV x MAXP`` steps; ``pages`` has the
+    trash page once more in a last column, for the out-blocks of grid steps
+    that write nothing."""
+    R, NKV, _, D = x.shape
+    P = pools[0].shape[2]
+    maxp = pages.shape[1] - 1
+    kernel = functools.partial(_ragged_grid_kernel, scale=scale, page=P, maxp=maxp, Hg=Hg, W=W)
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
@@ -431,15 +333,6 @@ def ragged_paged_attention(
         receives = _page_receives(ki, P, ln[b], ln[b] - ql[b])
         return (pt[b, jnp.where(receives, ki, maxp)], g, 0, 0)
 
-    if D % 128 == 0:  # a page is a slab a DMA can address
-        pool_out = pl.BlockSpec(memory_space=pl.ANY)
-        page_bufs = [
-            pltpu.VMEM((P, D), k_pages.dtype),
-            pltpu.VMEM((P, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-    else:
-        pool_out, page_bufs = pl.BlockSpec((1, 1, P, D), page_written), []
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(R, NKV, maxp),
@@ -448,31 +341,403 @@ def ragged_paged_attention(
             pl.BlockSpec((1, 1, P, D), page_read),
             pl.BlockSpec((1, 1, P, D), page_read),
         ],
-        out_specs=[pl.BlockSpec((1, 1, W * Hg, D), row_block), pool_out, pool_out],
+        out_specs=[
+            pl.BlockSpec((1, 1, W * Hg, D), row_block),
+            pl.BlockSpec((1, 1, P, D), page_written),
+            pl.BlockSpec((1, 1, P, D), page_written),
+        ],
         scratch_shapes=[
             pltpu.VMEM((W * Hg, 128), jnp.float32),
             pltpu.VMEM((W * Hg, 128), jnp.float32),
             pltpu.VMEM((W * Hg, D), jnp.float32),
-            *page_bufs,
         ],
     )
-    o, new_k, new_v = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((R, NKV, W * Hg, D), q.dtype),
-            jax.ShapeDtypeStruct((L * NP, NKV, P, D), k_pages.dtype),
-            jax.ShapeDtypeStruct((L * NP, NKV, P, D), v_pages.dtype),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((R, NKV, W * Hg, D), out_dtype)]
+        + [jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in pools],
         # operands count from the scalars: the pools are the 5th and 6th
         input_output_aliases={4: 1, 5: 2},
         interpret=interpret,
         name="ragged_paged_attention",
         **params,
-    )(
-        pages, lens, qlens, x,
-        k_pages.reshape(L * NP, NKV, P, D), v_pages.reshape(L * NP, NKV, P, D),
+    )(pages, lens, qlens, x, *pools)
+
+
+# The ragged kernel's tile sizes, from the shapes alone (read on a v5e at the
+# two serving cells' head layouts with ``tools/ragged_kernel_bench.py``;
+# ``PERF.md`` section 6, PR 27): bytes of keys (and again of values) a half of
+# the double buffer holds, keys a score tile spans at most, float32 scores a
+# tile holds (16 vregs), query rows a tile of a wide window holds.
+_KV_HALF_BYTES = 1 << 19
+_TILE_KEYS = 512
+_TILE_SCORES = 16 * 1024
+_TILE_ROWS = 128
+
+
+def _largest_divisor(n: int, limit: int, multiple_of: int = 1) -> int:
+    """The largest divisor of ``n`` that is at most ``limit`` and a multiple
+    of ``multiple_of``; ``n`` itself where there is none."""
+    for d in range(min(n, limit), 0, -1):
+        if n % d == 0 and d % multiple_of == 0:
+            return d
+    return n
+
+
+def _ragged_tiles(NKV, Hg, W, P, D, maxp, itemsize, pages_per_buffer=None):
+    """``(C, CK, TQ, HB)``: pages a half-buffer holds, pages a key tile spans,
+    query rows and kv heads a score tile holds. A narrow window (decode,
+    verify) is bound by its fetches and takes short halves, which waste least
+    on a row's last pages; a wide one is bound by its tiles and takes halves
+    of a whole key tile at least."""
+    rows = W * Hg
+    TQ = rows if rows <= _TILE_ROWS else _largest_divisor(rows, _TILE_ROWS, 8)
+    if pages_per_buffer is None:
+        pages_per_buffer = max(1, _KV_HALF_BYTES // (NKV * P * D * itemsize))
+        if rows >= _TILE_ROWS:
+            pages_per_buffer = max(pages_per_buffer, _TILE_KEYS // P)
+        pages_per_buffer = min(pages_per_buffer, maxp)
+    CK = max(1, min(_TILE_KEYS // P, pages_per_buffer))
+    C = pages_per_buffer // CK * CK
+    HB = _largest_divisor(NKV, max(1, _TILE_SCORES // ((-(-TQ // 8) * 8) * CK * P)))
+    return C, CK, TQ, HB
+
+
+def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool, v_pool,
+                   kbuf, vbuf, m_s, l_s, acc_s, fetch_sem, write_sem, slot_s,
+                   *, scale, P, C, CK, TQ, HB, Hg, W):
+    """Grid step ``g`` attends row ``g - 1`` and starts the fetch of row
+    ``g``'s first pages (step 0 only fetches). The row's live pages, all kv
+    heads of a page at a time, come by DMA into one half of ``kbuf`` / ``vbuf``
+    (``[2, NKV, C * P, D]``: a kv head's keys lie together) while the other
+    half is attended. ``k_pool`` / ``v_pool`` are the whole pools, read and
+    written in place (the aliased inputs ``_k_in`` / ``_v_in`` are the same
+    memory).
+
+    The scalar arithmetic is written in ``lax`` primitives: a ``jnp`` function
+    or an operator on a traced value is a nested ``jit`` trace, five times the
+    price, and this body is traced for every serving program of every process
+    (``PERF.md`` section 6, PR 26: what no compilation cache holds)."""
+    add, sub, mul, div, lt, gt = lax.add, lax.sub, lax.mul, lax.div, lax.lt, lax.gt
+    g = pl.program_id(0)
+    R = pl.num_programs(0) - 1
+    NKV, rows, D = o_ref.shape
+    TK = CK * P
+    pools = ((k_pool, kbuf), (v_pool, vbuf))  # a pool and the double buffer its pages come into
+
+    def pages_of(row, there):  # what the kernel walks of a row: nothing of a dead one
+        walked = lax.bitwise_and(there, gt(qlen_ref[row], 0))
+        return lax.select(walked, div(add(len_ref[row], P - 1), P), 0)
+
+    r, nxt = lax.max(sub(g, 1), 0), lax.min(g, R - 1)
+    kv_len = len_ref[r]
+    start = sub(kv_len, qlen_ref[r])  # the row's write base
+    n_pages = pages_of(r, gt(g, 0))
+    n_buf = div(add(n_pages, C - 1), C)
+    next_pages = pages_of(nxt, lt(g, R))
+    n_live = div(add(mul(qlen_ref[r], Hg), TQ - 1), TQ)  # query tiles that hold a real token
+
+    def page_rows(c):
+        return pl.ds(pl.multiple_of(mul(c, P), P), P)
+
+    def fetch(row, first, slot, count, wait=False):
+        """The copies of ``count`` pages, from table slot ``first`` of ``row``
+        on, into half ``slot``: started, or waited for."""
+
+        def page(c, _):
+            for i, (pool, buf) in enumerate(pools):
+                copy = pltpu.make_async_copy(
+                    pool.at[pt_ref[row, add(first, c)]], buf.at[slot, :, page_rows(c), :],
+                    fetch_sem.at[slot, i],
+                )
+                copy.wait() if wait else copy.start()
+            return _
+
+        lax.fori_loop(0, count, page, None)
+
+    @pl.when(g == 0)
+    def _first_step():
+        # what a half holds past a row's live pages is masked, and so must be finite
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_s[0] = 0
+
+    @pl.when(lt(mul(n_live, TQ), rows))
+    def _dead_slots():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    slot0 = slot_s[0]
+    n_halves = lax.max(n_buf, 1)  # a step that attends nothing still fetches for the next
+
+    def half(b, _):
+        slot = lax.bitwise_and(add(slot0, b), 1)
+        first = mul(b, C)  # the half's first table slot
+        base = mul(first, P)  # and its first key's position
+        # the next half's pages, the row's own or else the next row's first, are
+        # asked for before this half's are waited for: two halves in flight
+        own = lt(add(b, 1), n_buf)
+        fetch(
+            lax.select(own, r, nxt), lax.select(own, add(first, C), 0), sub(1, slot),
+            lax.min(lax.select(own, sub(n_pages, add(first, C)), next_pages), C),
+        )
+        fetch(r, first, slot, lax.min(sub(n_pages, first), C), wait=True)
+
+        # pages of this half that receive the row's new positions
+        # ``start .. kv_len - 1``: merged here with the window's rows (one-hot,
+        # exact), attended from here, written back from here
+        c_lo = lax.clamp(0, sub(div(start, P), first), C)
+        c_hi = lax.clamp(0, sub(n_pages, first), C)
+
+        def write_back(c, wait=False):
+            for i, (pool, buf) in enumerate(pools):
+                copy = pltpu.make_async_copy(
+                    buf.at[slot, :, page_rows(c), :], pool.at[pt_ref[r, add(first, c)]], write_sem.at[i]
+                )
+                copy.wait() if wait else copy.start()
+
+        def merge(c, _):
+            pos = add(lax.broadcasted_iota(jnp.int32, (P, W), 0), add(base, mul(c, P)))
+            w = lax.broadcasted_iota(jnp.int32, (P, W), 1)
+            sel = lax.eq(pos, add(w, start))  # [P, W] one-hot: window slot w lands on page row p
+            hit = (pos[:, :1] >= start) & (pos[:, :1] < kv_len)
+            for n, buf in enumerate((kbuf, vbuf)):
+                new = x_ref[:, W * (Hg + n) : W * (Hg + n + 1), :].astype(buf.dtype)  # [NKV, W, D]
+                if W > 1:
+                    # one product term a row at most, so exact in the pool's dtype
+                    new = lax.dot_general(
+                        jnp.broadcast_to(sel.astype(new.dtype), (NKV, P, W)), new,
+                        (((2,), (1,)), ((0,), (0,))),
+                        precision=lax.Precision.HIGHEST if new.dtype == jnp.float32 else None,
+                        preferred_element_type=jnp.float32,
+                    ).astype(buf.dtype)
+                buf[slot, :, page_rows(c), :] = jnp.where(hit, new, buf[slot, :, page_rows(c), :])
+            write_back(c)
+            return _
+
+        lax.fori_loop(c_lo, c_hi, merge, None)
+
+        def head_block(hb, _):
+            heads = pl.ds(pl.multiple_of(mul(hb, HB), HB), HB)
+
+            def query_tile(t, _):
+                row0 = mul(t, TQ)
+                tile = pl.ds(0 if TQ == rows else pl.multiple_of(row0, TQ), TQ)
+                q = x_ref[heads, tile, :]  # [HB, TQ, D]: slot w of group head h at row w*Hg + h
+                q_pos = add(div(add(lax.broadcasted_iota(jnp.int32, (TQ, TK), 0), row0), Hg), start)
+                # keys the tile's last query sees, counted from the half's first
+                seen = sub(lax.min(kv_len, add(add(div(add(row0, TQ - 1), Hg), 1), start)), base)
+
+                def key_tile(kt, carry):
+                    m, l, acc = carry
+                    key0 = mul(kt, TK)
+                    keys = pl.ds(pl.multiple_of(key0, TK), TK)
+                    s = lax.dot_general(
+                        q, kbuf[slot, heads, keys, :], (((2,), (2,)), ((0,), (0,))),
+                        preferred_element_type=jnp.float32,
+                    )  # [HB, TQ, TK]
+                    kv_pos = add(lax.broadcasted_iota(jnp.int32, (TQ, TK), 1), add(base, key0))
+                    live = lax.bitwise_and(lax.le(kv_pos, q_pos), lt(kv_pos, kv_len))
+                    s = jnp.where(live, mul(s, scale), NEG_INF)
+                    m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+                    corr = jnp.exp(m - m_new)
+                    p = jnp.exp(s - m_new)
+                    l = corr * l + jnp.sum(p, axis=2, keepdims=True)
+                    acc = acc * corr + lax.dot_general(
+                        p, vbuf[slot, heads, keys, :].astype(jnp.float32),
+                        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32,
+                    )
+                    return m_new, l, acc
+
+                first_half = b == 0
+                m, l, acc = lax.fori_loop(
+                    0, lax.clamp(0, div(add(seen, TK - 1), TK), C // CK), key_tile,
+                    (
+                        jnp.where(first_half, NEG_INF, m_s[heads, tile, :1]),
+                        jnp.where(first_half, 0.0, l_s[heads, tile, :1]),
+                        jnp.where(first_half, 0.0, acc_s[heads, tile, :]),
+                    ),
+                )
+                m_s[heads, tile, :] = jnp.broadcast_to(m, (HB, TQ, 128))
+                l_s[heads, tile, :] = jnp.broadcast_to(l, (HB, TQ, 128))
+                acc_s[heads, tile, :] = acc
+
+                @pl.when(b == n_buf - 1)
+                def _finish():
+                    o_ref[heads, tile, :] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
+
+                return _
+
+            if TQ == rows:  # one tile, whatever its height: a static slice
+                return query_tile(0, _)
+            return lax.fori_loop(0, n_live, query_tile, _)
+
+        lax.fori_loop(0, lax.select(gt(n_pages, 0), NKV // HB, 0), head_block, None)
+        # the written pages are on their way since the merge: the half is the
+        # next fetch's only once they have left
+        lax.fori_loop(c_lo, c_hi, lambda c, _: write_back(c, wait=True), None)
+        return _
+
+    lax.fori_loop(0, n_halves, half, None)
+    slot_s[0] = lax.bitwise_and(add(slot0, n_halves), 1)  # where the next step finds its first pages
+
+
+def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dtype, interpret,
+                          pages_per_buffer=None):
+    """``_ragged_kernel`` over ``R + 1`` steps, the pools left where they are."""
+    R, NKV, _, D = x.shape
+    P, maxp = pools[0].shape[2], pages.shape[1]
+    itemsize = jnp.dtype(pools[0].dtype).itemsize
+    C, CK, TQ, HB = _ragged_tiles(NKV, Hg, W, P, D, maxp, itemsize, pages_per_buffer)
+    kernel = functools.partial(
+        _ragged_kernel, scale=scale, P=P, C=C, CK=CK, TQ=TQ, HB=HB, Hg=Hg, W=W
     )
+    half = (2, NKV, C * P, D)
+    stats = (NKV, W * Hg, 128)
+    params = {}
+    if not interpret:
+        held = (
+            2 * 2 * NKV * C * P * D * itemsize  # the two double buffers
+            + 2 * NKV * W * (2 * Hg + 2) * D * x.dtype.itemsize  # x and o, twice
+            + 4 * NKV * W * Hg * (2 * 128 + D)  # m, l, acc
+        )
+        params["compiler_params"] = pltpu.CompilerParams(
+            # a row's first pages are fetched by the step before its own
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=held + (24 << 20),
+        )
+
+    def row_block(g, pt, ln, ql):  # step g attends row g - 1; step 0 only fetches
+        return (lax.max(g - 1, 0), 0, 0, 0)
+
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(R + 1,),
+        in_specs=[pl.BlockSpec((None, NKV, W * (Hg + 2), D), row_block), pool, pool],
+        out_specs=[pl.BlockSpec((None, NKV, W * Hg, D), row_block), pool, pool],
+        scratch_shapes=[
+            pltpu.VMEM(half, pools[0].dtype),
+            pltpu.VMEM(half, pools[1].dtype),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM((NKV, W * Hg, D), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),  # fetches: a half, keys or values
+            pltpu.SemaphoreType.DMA((2,)),  # write-backs: keys or values
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((R, NKV, W * Hg, D), out_dtype)]
+        + [jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in pools],
+        # operands count from the scalars: the pools are the 5th and 6th
+        input_output_aliases={4: 1, 5: 2},
+        interpret=interpret,
+        name="ragged_paged_attention",
+        **params,
+    )(pages, lens, qlens, x, *pools)
+
+
+def ragged_paged_attention(
+    q: jnp.ndarray,  # [R, W, NH, D] — each row's padded token window
+    k_new: jnp.ndarray,  # [R, W, NKV, D] — the window's keys, not yet in the pool
+    v_new: jnp.ndarray,
+    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D] — every layer's shared page pool
+    v_pages: jnp.ndarray,
+    layer,  # int32 scalar: the layer whose pool this call writes and reads
+    page_table: jnp.ndarray,  # [R, MAXP] int32 page ids per row
+    kv_lens,  # [R] int32 live kv length INCLUDING this step's tokens
+    q_lens,  # [R] int32 real tokens in the row's window (0 = dead row)
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
+    pages_per_buffer: Optional[int] = None,
+):
+    """One ragged kernel for mixed prefill-chunk / decode / verify rows that
+    writes the step's keys and values into the pool and attends over it.
+
+    The per-row ``(kv_len, q_len)`` metadata rides in as scalar-prefetch
+    arrays (the Ragged Paged Attention design, arXiv 2604.15464): row r's
+    window holds ``q_lens[r]`` real tokens at absolute positions
+    ``kv_lens[r] - q_lens[r] ..`` — a decode row is q_len 1, a verify row
+    q_len K+1, a prefill chunk q_len C — so changing the
+    prefill/decode/verify mix only changes ARRAY CONTENTS, never the
+    program. Queries ride the sublane dim W-major over the GQA group
+    (``[W*Hg, D] x [D, keys]`` a kv head) with a causal in-window mask on
+    top of the length mask.
+
+    The work follows the row's live pages, not the page table's width: a
+    grid step is a row, and its body walks ``ceil(kv_len / P)`` pages only,
+    so a dead row and the dead tail of a table cost a few scalar reads. The
+    pools are the whole ``[L, NP, NKV, P, D]`` stacks, left where they are
+    (``pl.ANY``), aliased in → out and seen as ``L * NP`` pages (a view), with
+    ``layer`` folded into the page table: the kernel is the only operation
+    the program ever applies to them, so they stay in one buffer and in the
+    default layout through a layer loop that carries them. The body fetches
+    all kv heads of several pages at a time by its own DMAs into one half of
+    a double buffer in VMEM (about a megabyte of keys and one of values)
+    while it attends the other half; a row's first pages are fetched while
+    the row before it is attended. The walks over halves, pages, kv heads,
+    query tiles and key tiles are rolled loops and the kv heads of a tile one
+    batched product, so the body's jaxpr is as long for 2 pages a half as for
+    16, for 2 kv heads as for 16.
+
+    A page that holds some of the positions the row writes is merged in the
+    half with the window's rows — a one-hot product, exact — attended from
+    there and written back by DMA, waited for before the half is fetched
+    into again; the window rides behind the queries in one operand. A
+    written page belongs to one row (the pool's copy-on-write), so no row
+    reads what another writes.
+
+    The dots take q, k and v as they are stored and accumulate in float32
+    (a bfloat16 product is exact there); the softmax statistics and the
+    accumulator are float32, and so is ``p`` in ``p · v``.
+
+    A head that is no whole lanes (``D % 128 != 0``) keeps the kernel this
+    one replaced, a grid of ``R x NKV x MAXP`` steps with out-blocks on the
+    pools (``_ragged_by_grid``): such a pool's last dimension is padded to 128
+    lanes, and Mosaic refuses a DMA of a page of it, whole or in part ("Slice
+    shape along dimension 3 must be aligned to tiling (128), but is 64").
+
+    Returns ``(out [R, W, NH, D], k_pages, v_pages)``. Window slots past
+    ``q_lens[r]`` are not written and produce garbage rows the caller ignores
+    (finite: masked softmax over the live prefix, or zeros); rows with
+    ``q_lens[r] == 0`` return exact zeros. ``pages_per_buffer`` overrides the
+    size of a half (tests, ``tools/ragged_kernel_bench.py``)."""
+    R, W, NH, D = q.shape
+    L, NP, NKV, P, Dk = k_pages.shape
+    assert Dk == D and v_pages.shape == k_pages.shape and v_pages.dtype == k_pages.dtype
+    assert k_new.shape == v_new.shape == (R, W, NKV, D)
+    if NH % NKV:
+        raise ValueError(f"query heads {NH} not a multiple of kv heads {NKV}")
+    maxp = page_table.shape[1]
+    scale_f = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
+    if interpret is None:
+        interpret = not on_tpu()
+    Hg = NH // NKV
+    # one operand a row: the queries W-major (slot w of group head h at
+    # sublane w*Hg + h), then the window's keys, then its values — rounded
+    # to the pool's dtype first, in a dtype that holds both exactly
+    xdtype = jnp.promote_types(q.dtype, k_pages.dtype)
+    x = jnp.concatenate(
+        [q.reshape(R, W, NKV, Hg, D).transpose(0, 2, 1, 3, 4).reshape(R, NKV, W * Hg, D)]
+        + [new.astype(k_pages.dtype).transpose(0, 2, 1, 3) for new in (k_new, v_new)],
+        axis=2, dtype=xdtype,
+    )
+    lens = jnp.broadcast_to(jnp.asarray(kv_lens, jnp.int32), (R,))
+    qlens = jnp.broadcast_to(jnp.asarray(q_lens, jnp.int32), (R,))
+    pools = [k_pages.reshape(L * NP, NKV, P, D), v_pages.reshape(L * NP, NKV, P, D)]
+    shared = dict(scale=scale_f, Hg=Hg, W=W, out_dtype=q.dtype, interpret=interpret)
+    if D % 128 == 0:  # a page of all kv heads is a slab a DMA can address
+        o, new_k, new_v = _ragged_by_live_pages(
+            x, _pages_in_stack(layer, page_table, NP), lens, qlens, pools,
+            pages_per_buffer=pages_per_buffer, **shared,
+        )
+    else:
+        trash_last = jnp.pad(page_table, ((0, 0), (0, 1)), constant_values=-1)
+        o, new_k, new_v = _ragged_by_grid(
+            x, _pages_in_stack(layer, trash_last, NP), lens, qlens, pools, **shared
+        )
     o = o.reshape(R, NKV, W, Hg, D).transpose(0, 2, 1, 3, 4).reshape(R, W, NH, D)
     return o, new_k.reshape(k_pages.shape), new_v.reshape(v_pages.shape)
 

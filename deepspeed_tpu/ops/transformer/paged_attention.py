@@ -30,9 +30,10 @@ a slice of it would be a copy of one layer's pool.
   never retraces. It WRITES the window's k/v into the pool and attends over
   it, and returns the pools. Pallas kernel on TPU
   (``decode_attention.ragged_paged_attention``: one fused kernel on the
-  aliased stacks — kv grid walks the page table via scalar prefetch, merges
-  the new rows into the pages that receive them, causal in-window mask,
-  pages past a row's live length skipped), XLA scatter + gather elsewhere.
+  aliased stacks — a grid step a row walks the row's live pages only,
+  fetched by the kernel's own DMAs, merges the new rows into the pages that
+  receive them, causal in-window mask; a dead row and the dead tail of a
+  table cost a few scalar reads), XLA scatter + gather elsewhere.
 * ``scatter_pages`` — the XLA write of a token slab's k/v into the pool,
   for the two read-only entries' callers and the ragged entry's XLA path.
 

@@ -146,7 +146,10 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
     (admit,), (pack,), (dispatch,), (emit,) = (inside(first, n) for n in ("serve.admit", "serve.pack", "serve.dispatch", "serve.emit"))
     assert admit[3] == {"admitted": 3}
     program = f"paged_ragged_r{server.pool.max_slots}_w8"
-    assert pack[3] == dispatch[3] == {"rows": 3, "width": 8, "program": program}
+    assert dispatch[3] == {"rows": 3, "width": 8, "program": program}
+    # three first chunks of at most a page each, of a table of max_slots x max_pages_per_slot slots
+    table_pages = server.pool.max_slots * server.pool.max_pages_per_slot
+    assert pack[3] == {**dispatch[3], "kv_pages": 3, "table_pages": table_pages}
     assert admit[2] <= pack[1] and pack[2] <= dispatch[1] and dispatch[2] <= emit[1]
     (fetch,), (settle,) = inside(emit, "serve.fetch"), inside(emit, "serve.settle")
     assert fetch[2] <= settle[1]

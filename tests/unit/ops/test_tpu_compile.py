@@ -7,7 +7,9 @@ mode never shows. Every kernel is entered with ``interpret=False``: left to
 itself it reads ``jax.default_backend()`` (the CPU here) and would lower the
 interpreter instead of the kernel. Shapes are the ones ``chip_smoke.py``
 runs: GPT-2 125M training (B8 T1024 N12 D64) and llama-1b serving (32 q
-heads over 4 kv heads, D64, page 64, 8 slots, windows 1 and 128).
+heads over 4 kv heads, D64, page 64, 8 slots, windows 1 and 128); the ragged
+kernel also at the benchmark's serving cells' (heads of 128: the kernel that
+walks a row's live pages).
 
 The last test compiles the whole ragged serving step at the shapes of the
 benchmark's Mistral cells and reads what the compiler made of the KV pool.
@@ -130,6 +132,19 @@ CASES = {
         _ragged,
         [((8, 128, 32, 64), BF16)] + [((8, 128, 4, 64), BF16)] * 2 + [_PAGES, _PAGES, _TABLE, _LENS, _LENS],
     ),
+    **{
+        # the benchmark's serving cells: 16 rows over the Mistral pool (32 query heads on 8 kv heads) and the
+        # OLMoE pool (16 on 16), and a tensor-parallel shard of Mistral's (8 rows, 8 on 2), both widths
+        f"ragged_w{width}_{cell}": (
+            _ragged,
+            [((rows, width, heads, 128), BF16)] + [((rows, width, kv_heads, 128), BF16)] * 2
+            + [((layers, rows * maxp + 1, kv_heads, 64, 128), BF16)] * 2 + [((rows, maxp), I32)] + [((rows,), I32)] * 2,
+        )
+        for cell, (rows, heads, kv_heads, layers, maxp) in {
+            "mistral7b": (16, 32, 8, 16, 38), "olmoe": (16, 16, 16, 12, 24), "mistral7b_tp4_shard": (8, 8, 2, 16, 38),
+        }.items()
+        for width in (1, 128)
+    },
     "paged_decode_llama_1b": (_paged, [((8, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS]),
     "dense_decode_llama_1b": (
         _dense_decode,
