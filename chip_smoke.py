@@ -68,7 +68,6 @@ def sizes(rehearse: bool) -> SimpleNamespace:
             budgets=[4, 6, 8, 10, 12, 14, 16, 5],
         )
     return SimpleNamespace(
-        # bench.py's config 1, the one configuration with an older chip number
         train_name="gpt2 125m",
         train_model=lambda **kw: gpt2_config("125m", max_seq_len=1024, remat=False, **kw),
         global_batch=8,

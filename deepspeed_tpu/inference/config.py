@@ -76,7 +76,7 @@ class MultiStepConfig(DeepSpeedConfigModel):
     dispatches/token → ``1/horizon``. Any scheduling event falls back to
     the single-step ragged path (``serve_stats()['window_break_reasons']``
     counts why), so greedy streams stay byte-identical to single-step —
-    and to bucketed and dense — serving. One horizon is armed at a time,
+    and to dense — serving. One horizon is armed at a time,
     adding at most ONE compiled serving program (≤ 4 total with the
     narrow + mixed ragged widths)."""
 
@@ -146,23 +146,17 @@ class PagedKVConfig(DeepSpeedConfigModel):
     (``max_slots × ceil(max_seq_len / page_size) + 1``, preemption-free);
     set it lower to oversubscribe and trade HBM for recompute preemptions.
 
-    ``ragged`` (default ON) serves every step as ONE dispatch of the
-    unified ragged program (``decode.py:build_ragged_step``): mixed
-    prefill-chunk, decode, and verify rows ride together, driven by
-    per-row ``(kv_len, q_len)`` metadata arrays, so shifting traffic never
-    retraces and total compiled serving programs is ≤ 2 (the narrow
-    decode/verify width plus the mixed width covering prefill chunks) —
-    chunked prefill shares the dispatch with decoders instead of stealing
-    whole steps, and spec-K varies freely per request. With
-    ``ragged = False`` the bucketed per-shape programs are kept as the
-    token-exactness oracle: compiled-program count is then
-    ``len(slot_buckets) + 1`` (one decode program per bucket, one prefill
-    program per chunk size) plus ``len(slot_buckets) × len(spec_lens)``
-    verify programs when ``spec_decode.enable`` is set. Greedy streams
-    are byte-identical across the two paths.
+    Every step is ONE dispatch of the unified ragged program
+    (``decode.py:build_ragged_step``): mixed prefill-chunk, decode, and
+    verify rows ride together, driven by per-row ``(kv_len, q_len)``
+    metadata arrays, so shifting traffic never retraces and total compiled
+    serving programs is ≤ 2 (the narrow decode/verify width plus the mixed
+    width covering prefill chunks) — chunked prefill shares the dispatch
+    with decoders instead of stealing whole steps, and spec-K varies freely
+    per request.
 
     ``multi_step`` (see :class:`MultiStepConfig`) arms fused windows of N
-    plain-decode rounds per dispatch on top of the ragged path — the host
+    plain-decode rounds per dispatch on top of that step — the host
     dispatch gap amortizes to 1/N in steady state, streams stay
     byte-identical, and any scheduling event falls back to single-step.
 
@@ -178,33 +172,16 @@ class PagedKVConfig(DeepSpeedConfigModel):
     page_size: int = 16
     num_pages: int = 0  # 0 = worst-case auto-size (no preemption possible)
     max_slots: int = 8  # concurrent sequences (rows of the decode batch)
-    slot_buckets: list = Field(default_factory=list)  # [] = powers of 2 up to max_slots
     max_seq_len: int = 0  # 0 = the model config's max_seq_len
     prefill_chunk: int = 32  # prompt tokens per interleaved prefill dispatch
     attn_impl: str = "auto"  # auto | pallas | xla (decode attention backend)
     prefix_cache: bool = True  # page-level prefix sharing (hash-of-block + CoW)
-    ragged: bool = True  # one ragged program per step (False = bucketed oracle)
     # multi-step windows: N decode rounds fused into one dispatch when the
-    # running set is stable (requires the ragged path)
+    # running set is stable
     multi_step: MultiStepConfig = Field(default_factory=MultiStepConfig)
-    # multi-chip tensor-parallel serving (requires the ragged path):
-    # sharded weights + kv-head-sharded pages + quantized comms knobs
+    # multi-chip tensor-parallel serving: sharded weights +
+    # kv-head-sharded pages + quantized comms knobs
     sharded: ShardedServingConfig = Field(default_factory=ShardedServingConfig)
-
-    @model_validator(mode="after")
-    def _check_multi_step(self):
-        if self.multi_step.enable and not self.ragged:
-            raise ValueError(
-                "paged_kv.multi_step runs over the ragged serving path: "
-                "enable paged_kv.ragged (or disable multi_step)"
-            )
-        if self.sharded.tp_degree > 1 and not self.ragged:
-            raise ValueError(
-                "paged_kv.sharded tensor-parallel serving runs over the "
-                "ragged serving path: enable paged_kv.ragged (or set "
-                "sharded.tp_degree <= 1)"
-            )
-        return self
 
 
 class TenantConfig(DeepSpeedConfigModel):
@@ -262,17 +239,14 @@ class SpecDecodeConfig(DeepSpeedConfigModel):
     Each speculative round drafts up to ``max_draft`` tokens per request
     host-side (``inference/spec_decode.py``: model-free n-gram /
     prompt-lookup of order ``ngram_order``) and verifies them in ONE
-    device dispatch; greedy outputs stay byte-identical to
-    speculation-off serving. ``spec_lens`` are the compiled verify widths
-    K (a round uses the smallest K covering its longest draft); program
-    count is bounded by ``len(slot_buckets) × len(spec_lens)``. With
-    ``spec_lens = []`` the single width ``max_draft`` is compiled.
+    device dispatch — the step's own, whose decode/verify width is
+    ``max_draft + 1``; greedy outputs stay byte-identical to
+    speculation-off serving.
     """
 
     enable: bool = False
     max_draft: int = 4  # drafted tokens per request per round (the K cap)
     ngram_order: int = 3  # longest suffix n-gram the drafter looks up
-    spec_lens: list = Field(default_factory=list)  # [] = [max_draft]
 
 
 class DeepSpeedInferenceConfig(DeepSpeedConfigModel):

@@ -632,28 +632,22 @@ def beam_generate(
 
 # --- paged (block-table) serving programs ----------------------------------
 # The continuous-batching scheduler (inference/scheduler.py) drives these.
-# Ragged mode (the default): ONE `build_ragged_step` program per step
-# handles mixed prefill-chunk, decode, and verify rows together, driven by
-# per-row (kv_len, q_len) metadata arrays — total compiled serving programs
-# ≤ 2 (a narrow decode/verify width plus the mixed width covering prefill
-# chunks). Multi-step windows (`build_ragged_multistep`, armed via
+# ONE `build_ragged_step` program per step handles mixed prefill-chunk,
+# decode, and verify rows together, driven by per-row (kv_len, q_len)
+# metadata arrays — total compiled serving programs ≤ 2 (a narrow
+# decode/verify width plus the mixed width covering prefill chunks).
+# Multi-step windows (`build_ragged_multistep`, armed via
 # `paged_kv.multi_step`) add at most ONE more program per horizon: a
 # lax.scan of N plain-decode rounds dispatched when the running set is
-# stable, amortizing the host gap to 1/N. Bucketed mode (the token-exactness oracle): per decode step ONE
-# dispatch of a slot-bucket-sized program (or, with speculation, ONE
-# dispatch of a (bucket, K)-shaped verify program); per prompt chunk one
-# dispatch of a fixed-chunk prefill program — programs bounded by (slot
-# buckets × spec lengths + slot buckets + chunk sizes). Neither is ever
-# bounded by traffic.
+# stable, amortizing the host gap to 1/N. The count is never bounded by
+# traffic.
 
 
 def _program_name(kind: str, rows: int, width: int) -> str:
-    """Unified serving-program name ``paged_<kind>_r<rows>_w<width>``: one
-    scheme across the decode / prefill / verify / ragged builders (decode
-    was keyed ``b<bucket>``, prefill ``c<chunk>``, verify
-    ``b<bucket>_k<K>`` before), so compile telemetry counts serving
-    programs consistently — the ragged ≤2-compile gate and the bench's
-    ``compiled_programs`` field both count ``paged_*`` entries."""
+    """Serving-program name ``paged_<kind>_r<rows>_w<width>`` (``kind``:
+    ``ragged`` or ``multistep``), so compile telemetry counts serving
+    programs consistently — the ragged ≤2-compile gate and the benchmark's
+    compile counters both count ``paged_*`` entries."""
     return f"paged_{kind}_r{int(rows)}_w{int(width)}"
 
 
@@ -702,8 +696,7 @@ def _accepted_prefix(tokens, greedy, n_drafts):
     """Per-row count of leading drafts (``tokens[:, 1:]``) that match the
     model's own greedy argmax for their positions, bounded by ``n_drafts``
     — THE acceptance rule (argmax-compare ⇒ greedy outputs byte-identical
-    to sequential decode), shared by the bucketed verify program and the
-    ragged step so the oracle and the default path cannot drift."""
+    to sequential decode)."""
     n_slots = tokens.shape[1] - 1
     matches = (tokens[:, 1:] == greedy[:, :-1]) & (
         jnp.arange(n_slots, dtype=jnp.int32)[None, :] < n_drafts[:, None]
@@ -712,20 +705,16 @@ def _accepted_prefix(tokens, greedy, n_drafts):
 
 
 def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
-                   attn_lens, attn_impl, write_valid=None, prefill_kv_lens=None,
-                   ragged_q_lens=None, tp=None):
-    """Forward [B, T] tokens against the paged cache: write each token's
-    k/v into its page, then attend — single-token rows (T == 1) through the
-    paged decode kernel with live lengths ``attn_lens``, chunks through the
-    causal prefill attention (mask from ``positions_b``). ``write_valid``
-    ([B, T] bool) redirects masked positions' k/v writes to the trash page;
-    ``prefill_kv_lens`` ([B]) additionally bounds the causal attention to
-    each row's live kv prefix (the verify program's pad-slot safety).
-    ``ragged_q_lens`` ([B]) switches to the unified ragged entry (mixed
-    prefill/decode/verify rows, per-row metadata — the one-program serving
-    step), which writes and attends in one call: row b's tokens sit at
-    ``prefill_kv_lens[b] - ragged_q_lens[b] ..``, slots past
-    ``ragged_q_lens[b]`` reach no live page. ``tp`` (a
+                   _unused, attn_impl, *, prefill_kv_lens, ragged_q_lens, tp=None):
+    """Forward a ragged ``[B, T]`` window of tokens against the paged cache
+    (mixed prefill/decode/verify rows, per-row metadata — the one-program
+    serving step): each layer writes the window's k/v into its pages and
+    attends in ONE call of the ragged entry. Row b's tokens sit at
+    ``prefill_kv_lens[b] - ragged_q_lens[b] ..`` (``positions_b``, for the
+    embeddings and RoPE), slots past ``ragged_q_lens[b]`` reach no live
+    page, a row with ``ragged_q_lens[b] == 0`` is dead. ``_unused`` takes
+    no part: ``benchmark/tools/olmoe_logits_check.py`` passes ``None`` in
+    that place (ROADMAP Queue 3 item 11). ``tp`` (a
     ``inference/tp.py:TPServing``) marks the body as running INSIDE
     shard_map on a tensor-parallel mesh: ``cfg`` is then the local per-shard
     view (heads and kv pages sliced on the head axes), the row-parallel
@@ -736,20 +725,14 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
     beside the layer index, and every write and read reaches its layer
     through that index: the pools are never sliced into per-layer ``xs`` nor
     restacked from ``ys``, so the donated buffers are the ones returned. On
-    the Pallas ragged path the fused kernel is the only operation applied
-    to them (aliased in → out), which also leaves their layout to nobody
-    but the kernel.
-    An MoE model routes only the window's live tokens (``ragged_q_lens``,
-    else ``write_valid``, else all of them) and hands back its per-layer,
-    per-expert assignment counts.
+    the Pallas path the fused kernel is the only operation applied to them
+    (aliased in → out), which also leaves their layout to nobody but the
+    kernel.
+    An MoE model routes only the window's live tokens and hands back its
+    per-layer, per-expert assignment counts.
     Returns (logits [B, T, V], new_k_pages, new_v_pages, moe_counts [L, E]
     or None for a dense model)."""
-    from deepspeed_tpu.ops.transformer.paged_attention import (
-        paged_decode_attention,
-        paged_prefill_attention,
-        ragged_paged_attention,
-        scatter_pages,
-    )
+    from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
 
     T = tokens.shape[1]
     dtype = k_pages.dtype
@@ -757,9 +740,7 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
     if cfg.position == "learned":
         x = x + params["embed"]["pos"].astype(dtype)[positions_b]
     scale = _softmax_scale(cfg, cfg.head_dim)
-    live = write_valid
-    if ragged_q_lens is not None:
-        live = jnp.arange(T, dtype=jnp.int32)[None, :] < ragged_q_lens[:, None]
+    live = jnp.arange(T, dtype=jnp.int32)[None, :] < ragged_q_lens[:, None]
     # a dropless MoE model's expert stacks stay out of the scanned per-layer
     # weights, like the pools: seen as one stack of L x E experts, the grouped
     # matmul reaches its layer's through an offset, and nothing copies a
@@ -771,8 +752,8 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
 
     # named scopes (here, in ``_post_attention``, ``_ffn_body`` and
     # ``_final_logits``) put the region into every op's name stack, where a
-    # profiler trace reads it: ``kv_write``, ``attention``, ``mlp``,
-    # ``head_sample``. Names only, nothing computed differently.
+    # profiler trace reads it: ``attention``, ``mlp``, ``head_sample``.
+    # Names only, nothing computed differently.
     def layer_step(carry, p):
         x, kp, vp, layer = carry
         with jax.named_scope("attention"):
@@ -780,29 +761,10 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
             if cfg.position == "rope":
                 q = _rope(q, positions_b, cfg.rope_theta, cfg.rope_dim)
                 k_new = _rope(k_new, positions_b, cfg.rope_theta, cfg.rope_dim)
-        if ragged_q_lens is not None:
-            with jax.named_scope("attention"):
-                attn, kp, vp = ragged_paged_attention(
-                    q, k_new, v_new, kp, vp, layer, page_table, prefill_kv_lens,
-                    ragged_q_lens, scale=scale, impl=attn_impl,
-                )
-        else:
-            with jax.named_scope("kv_write"):
-                kp = scatter_pages(kp, layer, k_new, page_table, positions_b, write_valid)
-                vp = scatter_pages(vp, layer, v_new, page_table, positions_b, write_valid)
-            # attn_lens discriminates decode from prefill: a prefill_chunk=1
-            # program also has T == 1 but must take the causal-mask path
-            with jax.named_scope("attention"):
-                if T == 1 and attn_lens is not None:
-                    attn = paged_decode_attention(
-                        q[:, 0], kp, vp, layer, page_table, attn_lens, scale=scale,
-                        impl=attn_impl,
-                    )[:, None]
-                else:
-                    attn = paged_prefill_attention(
-                        q, kp, vp, layer, page_table, positions_b, scale=scale,
-                        kv_lens=prefill_kv_lens,
-                    )
+            attn, kp, vp = ragged_paged_attention(
+                q, k_new, v_new, kp, vp, layer, page_table, prefill_kv_lens,
+                ragged_q_lens, scale=scale, impl=attn_impl,
+            )
         moe_ffn = functools.partial(_moe_ffn, live=live)
         if expert_stacks is not None:
             moe_ffn = functools.partial(moe_ffn, experts=expert_stacks, group_offset=layer * cfg.num_experts)
@@ -813,138 +775,6 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
         layer_step, (x, k_pages, v_pages, jnp.int32(0)), layers
     )
     return _final_logits(cfg, params, x), new_k, new_v, moe_counts
-
-
-def build_paged_decode_step(cfg, bucket: int, page_size: int, attn_impl: str = "auto",
-                            telemetry=None):
-    """One-dispatch decode step for a ``bucket``-row slot batch.
-
-    ``decode_step(params, tokens [B], k_pages, v_pages, page_table [B, MAXP],
-    lengths [B]) -> (next_tokens [B], k_pages, v_pages)``: writes each row's
-    pending token at position ``lengths[b]``, attends over ``lengths[b]+1``
-    live positions, returns the greedy next token (argmax runs in-program —
-    the only host traffic per step is the [B] token fetch). Pages donated.
-    Compiled once per bucket size; MAXP rides in from the table shape.
-    """
-    if cfg.position == "alibi":
-        raise NotImplementedError("paged serving does not support alibi attention biases")
-    name = _program_name("decode", bucket, 1)
-    key = _paged_program_key(name, cfg, page_size, attn_impl, telemetry)
-    fn = _paged_program_cache.get(key)
-    if fn is not None:
-        return fn
-
-    def _decode(params, tokens, k_pages, v_pages, page_table, lengths):
-        logits, new_k, new_v, _ = _paged_forward(
-            cfg, params, tokens[:, None], k_pages, v_pages, page_table,
-            lengths[:, None], lengths + 1, attn_impl,
-        )
-        return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), new_k, new_v
-
-    fn = _jit(_decode, telemetry, name, donate_argnums=(2, 3))
-    _paged_program_cache[key] = fn
-    return fn
-
-
-def build_paged_prefill(cfg, chunk: int, page_size: int, attn_impl: str = "auto",
-                        telemetry=None):
-    """Fixed-size prompt-chunk program (one compile per chunk size).
-
-    ``prefill(params, tokens [1, C], k_pages, v_pages, page_table [1, MAXP],
-    start [1], last_idx) -> (next_token [1], k_pages, v_pages)``: scatters
-    the chunk's k/v at ``start..start+C-1``, attends causally, and returns
-    the greedy token after position ``last_idx`` (traced, so ragged final
-    chunks never retrace). Short final chunks arrive padded; pad slots
-    (index > ``last_idx``) redirect their writes to the trash page — a pad
-    position past the table width would otherwise clamp onto the LAST live
-    column and overwrite real prompt k/v — and are causally invisible to
-    every real token."""
-    if cfg.position == "alibi":
-        raise NotImplementedError("paged serving does not support alibi attention biases")
-    name = _program_name("prefill", 1, chunk)
-    key = _paged_program_key(name, cfg, page_size, attn_impl, telemetry)
-    fn = _paged_program_cache.get(key)
-    if fn is not None:
-        return fn
-
-    def _prefill(params, tokens, k_pages, v_pages, page_table, start, last_idx):
-        T = tokens.shape[1]
-        offs = jnp.arange(T, dtype=jnp.int32)
-        positions_b = start[:, None] + offs[None, :]
-        valid = (offs <= last_idx)[None, :]  # pad tail -> trash page
-        logits, new_k, new_v, _ = _paged_forward(
-            cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
-            None, attn_impl, write_valid=valid,
-        )
-        last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1, keepdims=False)
-        return jnp.argmax(last, axis=-1).astype(jnp.int32), new_k, new_v
-
-    fn = _jit(_prefill, telemetry, name, donate_argnums=(2, 3))
-    _paged_program_cache[key] = fn
-    return fn
-
-
-def build_paged_verify_step(cfg, bucket: int, K: int, page_size: int,
-                            attn_impl: str = "auto", telemetry=None):
-    """One-dispatch speculative draft-and-verify step for a ``bucket``-row
-    slot batch and draft width ``K``.
-
-    ``verify(params, tokens [B, K+1], k_pages, v_pages, page_table [B, MAXP],
-    lengths [B], draft_lens [B]) -> (out [B, K+2], k_pages, v_pages)``.
-    Row b's ``tokens`` are its pending token followed by up to K host-drafted
-    tokens (garbage past ``draft_lens[b]``). The program scatters k/v for
-    every position ``lengths[b] + j`` (pad slots redirect to the trash page),
-    scores all K+1 positions in ONE causal chunk-prefill attention pass over
-    the row's pages, and resolves the speculation in-program:
-    ``out[:, 0]`` is the accepted-prefix length ``n`` — the count of leading
-    drafts that equal the model's own greedy argmax, bounded by
-    ``draft_lens`` — and ``out[:, 1:]`` the greedy token after each prefix,
-    so the round emits ``out[b, 1 : n+2]`` (n accepted drafts + the
-    bonus/correction token), byte-identical to n+1 sequential decode steps.
-    The host rolls the rejected tail's pages back via ``PagePool.rollback``.
-
-    Pages are donated; the packed [B, K+2] fetch is the round's only host
-    traffic. Compiled once per (bucket, K); the scheduler bounds total
-    verify programs by ``len(slot_buckets) × len(spec_lens)``.
-
-    Exactness caveat: verify scores through the XLA chunk attention, so
-    byte-identical spec-on/spec-off streams are guaranteed when the plain
-    decode steps use the same backend (``attn_impl="xla"``, the tested
-    config). Under ``"auto"`` on TPU the plain steps run the Pallas decode
-    kernel — mathematically the same scores, but an argmax near-tie could
-    in principle resolve differently across the two lowerings.
-    """
-    if cfg.position == "alibi":
-        raise NotImplementedError("paged serving does not support alibi attention biases")
-    if K < 1:
-        raise ValueError(f"speculative verify needs K >= 1 drafted slots, got {K}")
-    name = _program_name("verify", bucket, K + 1)
-    key = _paged_program_key(name, cfg, page_size, attn_impl, telemetry)
-    fn = _paged_program_cache.get(key)
-    if fn is not None:
-        return fn
-
-    def _verify(params, tokens, k_pages, v_pages, page_table, lengths, draft_lens):
-        T = K + 1
-        offs = jnp.arange(T, dtype=jnp.int32)
-        positions_b = lengths[:, None] + offs[None, :]
-        # pad slots (j > draft_lens[b]) hold garbage tokens whose positions
-        # may reach past the row's ensured pages — their writes go to the
-        # trash page and their kv rows are masked out of the attention
-        valid = offs[None, :] <= draft_lens[:, None]
-        kv_lens = jnp.where(lengths > 0, lengths + draft_lens + 1, 0)
-        logits, new_k, new_v, _ = _paged_forward(
-            cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
-            None, attn_impl, write_valid=valid, prefill_kv_lens=kv_lens,
-        )
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, K+1]
-        accepted = _accepted_prefix(tokens, greedy, draft_lens)
-        packed = jnp.concatenate([accepted[:, None].astype(jnp.int32), greedy], axis=1)
-        return packed, new_k, new_v
-
-    fn = _jit(_verify, telemetry, name, donate_argnums=(2, 3))
-    _paged_program_cache[key] = fn
-    return fn
 
 
 def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: int,

@@ -521,13 +521,12 @@ class InferenceEngine:
         counterpart of the training engine's ``compile_stats()``: for each
         jitted program (``forward``, ``kv_prefill`` / ``kv_decode_loop`` /
         ``kv_beam_loop``, ``full_fwd_gen_step``, and the serving programs —
-        ``paged_<kind>_r<rows>_w<width>`` across the ragged / decode /
-        prefill / verify builders) the trace, compile, and dispatch
-        counters. The serving contract under ``paged_kv.ragged`` (default):
-        ≤ 2 compiled ``paged_*`` programs for a whole mixed serve and
-        exactly one ``paged_ragged_*`` dispatch per scheduler step; under
-        the bucketed oracle, ≤1 compile per shape bucket and one
-        ``paged_decode_*`` dispatch per decode step."""
+        ``paged_ragged_r<rows>_w<width>`` and, with windows armed,
+        ``paged_multistep_r<rows>_w<width>_n<horizon>``) the trace, compile,
+        and dispatch counters. The serving contract: ≤ 2 compiled
+        ``paged_*`` programs for a whole mixed serve (one more per window
+        horizon) and exactly one ``paged_ragged_*`` dispatch per
+        single-step scheduler step."""
         return self._telemetry.stats()
 
     def program_text(self, name: str) -> str:
@@ -542,7 +541,7 @@ class InferenceEngine:
         host-transfer, and collective-schedule pass results per program,
         retrace-cause diffs, and aggregate totals (``donation_verified``,
         static collective bytes). The serving invariants become checkable
-        properties: every ``paged_decode_*`` / ``paged_prefill_*`` program
+        properties: every ``paged_ragged_*`` / ``paged_multistep_*`` program
         must alias its donated page buffers and contain no host callback."""
         from deepspeed_tpu.analysis import engine_analysis_report
 
@@ -679,21 +678,6 @@ class InferenceEngine:
         tp_degree = int(scfg.tp_degree or self._config.tensor_parallel.tp_size or 1)
         tp_ctx = None
         params = self._params
-        if tp_degree > 1 and not pcfg.ragged and scfg.tp_degree == 0:
-            # FOLLOW mode (sharded.tp_degree=0 defers to tensor_parallel):
-            # tp_size also drives the dense AutoTP forward/generate path,
-            # and tp_size>1 + the bucketed oracle was a valid combination
-            # before sharded serving existed — the bucketed path simply
-            # stays single-chip. (An EXPLICIT sharded.tp_degree>1 with
-            # ragged=False is a contradiction and fails config validation.)
-            log_dist(
-                "paged_kv.ragged=False: bucketed serving stays single-chip "
-                f"(tensor_parallel.tp_size={tp_degree} keeps driving the "
-                "dense generate path; enable ragged or set "
-                "paged_kv.sharded.tp_degree to shard serving)",
-                ranks=[0],
-            )
-            tp_degree = 1
         if tp_degree > 1:
             from deepspeed_tpu.inference.tp import TPServing, serving_mesh
 
@@ -714,7 +698,6 @@ class InferenceEngine:
             page_size=pcfg.page_size,
             num_pages=pcfg.num_pages,
             max_slots=pcfg.max_slots,
-            slot_buckets=pcfg.slot_buckets or None,
             max_seq_len=pcfg.max_seq_len,
             prefill_chunk=pcfg.prefill_chunk,
             attn_impl=pcfg.attn_impl,
@@ -722,7 +705,6 @@ class InferenceEngine:
             telemetry=self._telemetry,
             spec_decode=self._config.spec_decode,
             prefix_cache=pcfg.prefix_cache,
-            ragged=pcfg.ragged,
             multi_step=pcfg.multi_step,
             journal=journal,
             tracer=self.tracer,
@@ -758,10 +740,8 @@ class InferenceEngine:
         requests are admitted/evicted every step, prompts prefill in chunks
         riding the SAME dispatch as in-flight decoders, and each step is
         ONE dispatch of the unified ragged program
-        (``inference/scheduler.py``; ``paged_kv.ragged=False`` falls back
-        to the bucketed per-shape programs, byte-identical streams) — or,
-        with ``paged_kv.multi_step`` armed and the running set stable, ONE
-        fused window of up to ``horizon`` decode rounds (host dispatch gap
+        (``inference/scheduler.py``) — or, with ``paged_kv.multi_step``
+        armed and the running set stable, ONE fused window of up to ``horizon`` decode rounds (host dispatch gap
         amortized to 1/N, still byte-identical). With
         ``inference.spec_decode.enable`` host-side n-gram drafts verify
         inside the same per-step dispatch (per-request spec-K), token-exact

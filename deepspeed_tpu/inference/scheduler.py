@@ -15,11 +15,11 @@ vLLM style):
   continuation token-exact, so preemption is invisible in the output;
 * with **speculative decoding** enabled, each round first asks a host-side
   ``Drafter`` (``inference/spec_decode.py``) for up to K plausible next
-  tokens per running request, then verifies drafts + bonus token in ONE
-  dispatch of a (bucket, K)-shaped program — the accepted prefix advances
+  tokens per running request, then verifies drafts + bonus token inside
+  the step's ONE dispatch — the accepted prefix advances
   ``mean accepted + 1`` tokens per dispatch, the rejected tail's pages roll
   back to the free list, and greedy outputs stay byte-identical to
-  speculation-off serving (the verify program argmax-compares in-program);
+  speculation-off serving (the step argmax-compares in-program);
 * with **prefix caching** enabled the pool's hash-of-block index
   (``inference/kv_pool.py``) is consulted at admission: the longest cached
   full-page prefix of the request's context attaches by reference (its KV
@@ -27,15 +27,18 @@ vLLM style):
   chunk grid (so every position is computed by the same (chunk, row)
   geometry — byte-identical streams), and each newly filled full page is
   published back to the index;
-* in **ragged** mode (the default) every scheduler step is ONE dispatch
-  of the unified ``build_ragged_step`` program: prefill chunks, pending
+* every scheduler step is ONE dispatch of the unified
+  ``build_ragged_step`` program: prefill chunks, pending
   decode tokens, and drafted verify rows pack into a single
   ``[max_slots, W]`` window whose per-row ``(kv_len, q_len)`` metadata
   ride in as arrays (Ragged Paged Attention, arXiv 2604.15464) — so
   chunked prefill COEXISTS with decoding instead of stealing steps,
   spec-K varies per request, and shifting the mix never retraces. Total
   compiled serving programs is ≤ 2 (the narrow decode/verify width plus
-  the chunk-covering mixed width), vs the bucketed matrix's dozens;
+  the chunk-covering mixed width), and steady state is one dispatch per
+  step — enforced by the serving tests via the engine's compile
+  telemetry. Prefix sharing adds zero dispatches and zero programs:
+  attach/register are host-side table and hash work;
 * with **multi-step windows** armed (``inference.paged_kv.multi_step``)
   a step whose running set is STABLE — nothing queued, nothing
   prefilling, no drafts, no preemption pressure — dispatches ONE fused
@@ -47,16 +50,6 @@ vLLM style):
   window instead of once per token (dispatches/token → 1/horizon). Any
   scheduling event breaks back to the single-step path — streams stay
   byte-identical, and ``window_break_reasons`` names every break;
-* in **bucketed** mode (``ragged=False`` — kept as the token-exactness
-  oracle) compiled-program count is bounded by the **slot-count buckets**
-  (× the **spec lengths** when speculating): each round dispatches ONE
-  program shaped to the smallest bucket covering the running set, and
-  each prompt chunk one fixed-chunk prefill program. Steady state is one
-  dispatch per round, ≤1 compile per (bucket[, spec length]) — enforced
-  by the serving tests via the engine's compile telemetry. Greedy streams
-  are byte-identical across the two modes. Prefix sharing adds zero
-  dispatches and zero programs in either: attach/register are host-side
-  table and hash work;
 * admission order and preemption victims are delegated to a
   ``SchedulingPolicy`` (default: FIFO admission, youngest-first
   preemption — the original behavior). ``inference/traffic.py`` layers
@@ -78,9 +71,6 @@ import numpy as np
 
 from deepspeed_tpu.inference.decode import (
     MOE_STAT_ROWS,
-    build_paged_decode_step,
-    build_paged_prefill,
-    build_paged_verify_step,
     build_ragged_multistep,
     build_ragged_step,
     multistep_program_name,
@@ -109,11 +99,10 @@ def _spec_knob(spec, name, default):
 
 def compiled_serving_programs(compile_stats: Dict) -> int:
     """Count the serving programs a telemetry snapshot saw compile: every
-    ``paged_*`` entry (the unified ``paged_<kind>_r<rows>_w<width>`` naming
-    across the decode/prefill/verify/ragged/multistep builders) with at
-    least one cold dispatch. The ragged compile-budget gate asserts this
-    ≤ 2 for a full mixed serve — ≤ 4 with a multi-step window horizon
-    armed; ``bench.py`` records it as ``compiled_programs``."""
+    ``paged_*`` entry (the ``paged_<kind>_r<rows>_w<width>`` naming of the
+    ragged and multistep builders) with at least one cold dispatch. The
+    compile-budget gate asserts this ≤ 2 for a full mixed serve — ≤ 4 with
+    a multi-step window horizon armed."""
     return sum(
         1
         for name, rec in compile_stats.items()
@@ -207,19 +196,8 @@ class Request:
         return self.context().copy()
 
 
-def _default_buckets(max_slots: int) -> List[int]:
-    """Powers of two up to and including max_slots."""
-    buckets, b = [], 1
-    while b < max_slots:
-        buckets.append(b)
-        b *= 2
-    buckets.append(max_slots)
-    return sorted(set(buckets))
-
-
 class PagedServer:
-    """Owns the page pool, the per-bucket compiled programs, and the
-    admit → prefill-chunk → decode-step loop."""
+    """Owns the page pool and the admit → ragged-step (or window) loop."""
 
     def __init__(
         self,
@@ -228,7 +206,6 @@ class PagedServer:
         page_size: int = 16,
         num_pages: int = 0,
         max_slots: int = 8,
-        slot_buckets: Optional[Sequence[int]] = None,
         max_seq_len: int = 0,
         prefill_chunk: int = 32,
         attn_impl: str = "auto",
@@ -239,7 +216,6 @@ class PagedServer:
         prefix_cache: bool = False,
         policy: Optional[SchedulingPolicy] = None,
         clock=None,
-        ragged: bool = True,
         multi_step=None,
         journal: Optional[RequestJournal] = None,
         tracer=None,
@@ -251,8 +227,7 @@ class PagedServer:
         # ragged programs run under shard_map on the mesh — weights
         # column/row-parallel, kv pages sharded on the kv-head axis, page
         # TABLES (and every other host structure: queues, prefix index,
-        # journal, fleet routing) replicated and untouched. Requires the
-        # ragged path: the bucketed oracle stays single-chip by contract.
+        # journal, fleet routing) replicated and untouched.
         self.tp = tp
         # MoE serving (ISSUE 20): the per-layer "moe" subtree routes inside
         # the same paged programs (decode.py:_moe_ffn) — but only when the
@@ -281,11 +256,6 @@ class PagedServer:
         # result, stats keys and spans are as they were
         self._moe_slots = cfg.num_layers * cfg.num_experts if is_moe else 0
         if tp is not None:
-            if not ragged:
-                raise ValueError(
-                    "tensor-parallel serving runs the ragged path: enable "
-                    "paged_kv.ragged (the bucketed oracle is single-chip)"
-                )
             if tp.degree > 1:
                 tp.validate_cfg(cfg)
             params = tp.shard_params(cfg, params)
@@ -308,12 +278,6 @@ class PagedServer:
         self.attn_impl = attn_impl
         self.telemetry = telemetry
         self.prefix_cache = bool(prefix_cache)
-        # ragged (default): every step is ONE dispatch of the unified
-        # build_ragged_step program — mixed prefill/decode/verify rows,
-        # per-row (kv_len, q_len) metadata, ≤2 compiled programs total.
-        # ragged=False keeps the bucketed per-shape programs as the
-        # token-exactness oracle.
-        self.ragged = bool(ragged)
         # multi-step windows (inference.paged_kv.multi_step): when the
         # running set is STABLE — nothing queued, nothing prefilling, no
         # drafts, no preemption pressure — a step dispatches ONE fused
@@ -325,11 +289,6 @@ class PagedServer:
         # ride unchanged and streams stay byte-identical.
         self.ms_enable = bool(_spec_knob(multi_step, "enable", False))
         self.ms_horizon = int(_spec_knob(multi_step, "horizon", 8))
-        if self.ms_enable and not self.ragged:
-            raise ValueError(
-                "multi_step windows run over the ragged serving path: "
-                "enable paged_kv.ragged (or disable paged_kv.multi_step)"
-            )
         if self.ms_enable and self.ms_horizon < 2:
             raise ValueError(
                 f"multi_step.horizon must be >= 2 (1 is the single-step "
@@ -351,40 +310,18 @@ class PagedServer:
         # speculation: a SpecDecodeConfig / dict of knobs, or an explicit
         # Drafter instance (tests inject oracles this way) — either enables
         self.max_draft = int(_spec_knob(spec_decode, "max_draft", 4))
-        lens = [int(l) for l in (_spec_knob(spec_decode, "spec_lens", None) or [])]
-        self.spec_lens = sorted(set(lens)) or [self.max_draft]
         if drafter is None and _spec_knob(spec_decode, "enable", False):
             drafter = NGramDrafter(
                 ngram_order=int(_spec_knob(spec_decode, "ngram_order", 3))
             )
         self.drafter = drafter
-        if self.drafter is not None and (
-            self.max_draft < 1 or any(l < 1 for l in self.spec_lens)
-        ):
-            raise ValueError(
-                f"speculation needs max_draft >= 1 and spec_lens >= 1, got "
-                f"max_draft={self.max_draft} spec_lens={self.spec_lens}"
-            )
-        if self.drafter is not None and attn_impl == "auto":
-            from deepspeed_tpu.utils.logging import logger
-
-            # byte-identical spec-on/spec-off streams are guaranteed when
-            # decode and verify score through one backend; "auto" on TPU
-            # mixes the Pallas decode kernel with XLA verify scoring, where
-            # an argmax near-tie could in principle resolve differently
-            logger.warning(
-                "speculative serving with attn_impl='auto': greedy streams "
-                "are exact per attention backend; pin attn_impl='xla' for a "
-                "strict byte-identical guarantee vs speculation-off serving"
-            )
-        # drafts are clamped to the widest compiled verify program
-        # (bucketed) / the decode-row window width (ragged)
-        self._draft_cap = min(self.max_draft, self.spec_lens[-1])
-        # the two ragged widths: decode/verify rows need 1 + draft_cap
+        if self.drafter is not None and self.max_draft < 1:
+            raise ValueError(f"speculation needs max_draft >= 1, got max_draft={self.max_draft}")
+        # the two ragged widths: decode/verify rows need 1 + max_draft
         # slots, prefill chunks need prefill_chunk — a step dispatches the
         # narrow program unless it carries a chunk row, so total compiled
         # serving programs is ≤ 2 regardless of traffic
-        self._ragged_w_decode = (self._draft_cap + 1) if self.drafter is not None else 1
+        self._ragged_w_decode = (self.max_draft + 1) if self.drafter is not None else 1
         self._ragged_w_mixed = max(self.prefill_chunk, self._ragged_w_decode)
         max_seq = int(max_seq_len or cfg.max_seq_len)
         if num_pages <= 0:
@@ -397,12 +334,6 @@ class PagedServer:
             max_seq_len=max_seq, dtype=dtype,
             kv_sharding=None if tp is None else tp.kv_sharding,
         )
-        buckets = sorted(set(int(b) for b in (slot_buckets or _default_buckets(max_slots))))
-        if buckets[-1] < max_slots:
-            buckets.append(max_slots)
-        if any(b < 1 for b in buckets):
-            raise ValueError(f"slot buckets must be >= 1, got {buckets}")
-        self.buckets = buckets
         self._queue: deque[Request] = deque()
         self._active: List[Request] = []  # admission order (oldest first)
         self._results: Dict[int, np.ndarray] = {}
@@ -430,15 +361,14 @@ class PagedServer:
             "journal_compactions": 0,  # full-state rewrites (amortized)
             "prefix_cached_tokens": 0,  # context tokens attached, not prefilled
             "prefill_chunks": 0,
-            # ragged mode: every scheduler step is ONE ragged dispatch;
-            # decode_steps / spec_rounds then count the dispatches that
-            # carried plain-decode / drafted rows (a mixed dispatch can
-            # count as both)
+            # every scheduler step is ONE ragged dispatch; decode_steps /
+            # spec_rounds count the dispatches that carried plain-decode /
+            # drafted rows (a mixed dispatch can count as both)
             "ragged_steps": 0,
             # multi-step windows: one fused horizon-round dispatch each;
-            # `dispatches` counts EVERY serving dispatch (windows, ragged
-            # steps, bucketed prefill/decode/verify) and `emitted_tokens`
-            # every generated token, so dispatches_per_token is derivable
+            # `dispatches` counts EVERY serving dispatch (windows and
+            # ragged steps) and `emitted_tokens` every generated token, so
+            # dispatches_per_token is derivable
             "window_steps": 0,
             "dispatches": 0,
             "emitted_tokens": 0,
@@ -452,18 +382,19 @@ class PagedServer:
                 "admission": 0, "prefill": 0, "draft": 0, "eos": 0,
                 "budget": 0, "pool": 0,
             },
-            "decode_steps": 0,  # plain (non-speculative) decode dispatches
-            "spec_rounds": 0,  # verify dispatches (one per speculative round)
+            "decode_steps": 0,  # dispatches that carried a plain decode row
+            "spec_rounds": 0,  # dispatches that carried a drafted row
             "spec_drafted": 0,  # draft tokens sent to verification
             "spec_accepted": 0,  # draft tokens accepted
             # draft-hit histogram: accept_hist[n] counts (request, round)
             # pairs whose accepted prefix was exactly n drafts long
-            "spec_accept_hist": [0] * (self._draft_cap + 1),
+            "spec_accept_hist": [0] * (self.max_draft + 1),
         }
         if self._moe_slots:
-            # ragged steps only: live (token, expert) assignments over all
-            # layers; experts hit (>= 1 live token), summed over layers; the
-            # largest load any one expert of any layer took in one step
+            # single steps only (windows carry none): live (token, expert)
+            # assignments over all layers; experts hit (>= 1 live token),
+            # summed over layers; the largest load any one expert of any
+            # layer took in one step
             self.stats.update(moe_assignments=0, moe_experts_hit=0, moe_max_expert_load=0)
             self._g_moe_hit = self.metrics.gauge("serve.moe_experts_hit_share")
 
@@ -780,13 +711,11 @@ class PagedServer:
 
     # --- one scheduler iteration ---------------------------------------
     def step(self) -> None:
-        """Admit what fits, then run the round's device work: in ragged
-        mode ONE dispatch covering every active row's next tokens (prefill
-        chunks, pending decodes, and drafted verifies together) — or, with
-        ``multi_step`` armed and the running set stable, ONE fused window
-        of ``horizon`` plain-decode rounds; in bucketed mode one prefill
-        dispatch per chunk followed by one decode/verify dispatch over the
-        running set."""
+        """Admit what fits, then run the round's device work: ONE dispatch
+        covering every active row's next tokens (prefill chunks, pending
+        decodes, and drafted verifies together) — or, with ``multi_step``
+        armed and the running set stable, ONE fused window of ``horizon``
+        plain-decode rounds."""
         waiting, running = len(self._queue), len(self._active)
         pages_in_use = self.pool.used_pages()
         self._g_waiting.set(waiting)
@@ -800,14 +729,8 @@ class PagedServer:
                 admitted = self.stats["admitted"]
                 self._admit()
                 admit_span.set(admitted=self.stats["admitted"] - admitted)
-            if self.ragged:
-                if not (self.ms_enable and self._ragged_window()):
-                    self._ragged_step(drafts=self._take_predrafts())
-            else:
-                with self.tracer.span("serve.prefill"):
-                    self._prefill_step()
-                with self.tracer.span("serve.decode"):
-                    self._decode_step()
+            if not (self.ms_enable and self._ragged_window()):
+                self._ragged_step(drafts=self._take_predrafts())
             # the round's device work and emissions happened; the chaos
             # point models dying BEFORE the journal flush — the un-synced
             # tokens are re-derived identically on recovery (greedy
@@ -902,54 +825,6 @@ class PagedServer:
             real = min(real, C - start % C)
         return real
 
-    def _prefill_step(self) -> None:
-        C = self.prefill_chunk
-        prefill = build_paged_prefill(
-            self.cfg, C, self.pool.page_size, attn_impl=self.attn_impl,
-            telemetry=self.telemetry,
-        )
-        for req in [r for r in self._active if r.pending is None and not r.done]:
-            ctx = req.context()
-            start = req.consumed
-            real = self._next_chunk_len(req, ctx.size)
-            if not self.pool.prepare_write(req.slot, start + real):
-                # unreachable: admission pre-reserved the whole context and
-                # prefill never writes into attached (shared) pages
-                raise RuntimeError(
-                    f"prefill write barrier failed for slot {req.slot} "
-                    f"({start}..{start + real})"
-                )
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :real] = ctx[start : start + real]
-            pt, _ = self.pool.rows([req.slot])
-            tok, new_k, new_v = prefill(
-                self.params, chunk, self.pool.cache.k_pages, self.pool.cache.v_pages,
-                pt, np.asarray([start], np.int32), np.int32(real - 1),
-            )
-            self.pool.set_cache(new_k, new_v)
-            self.stats["dispatches"] += 1
-            self.pool.advance(req.slot, real)
-            req.consumed = start + real
-            if self.prefix_cache:
-                self.pool.register_prefix(req.slot, ctx, req.consumed)
-            self.stats["prefill_chunks"] += 1
-            if req.consumed == ctx.size:
-                # the chunk's single host fetch: the first generated token
-                self._emit(req, int(np.asarray(tok)[0]))  # lint: allow(DS-R005)
-
-    def _decode_step(self) -> None:
-        running = [r for r in self._active if r.pending is not None and not r.done]
-        if not running:
-            return
-        if self.drafter is not None:
-            drafts = self._propose_drafts(running)
-            if any(d.size for d in drafts.values()):
-                self._verify_round(running, drafts)
-                return
-            # nothing drafted anywhere: a verify dispatch would only carry
-            # dead slots — fall through to the plain one-token program
-        self._plain_decode_step(running)
-
     # --- the ragged one-program step -------------------------------------
     def _take_predrafts(self) -> Optional[Dict[int, np.ndarray]]:
         """Drafts a failed window probe already proposed this step (the
@@ -997,7 +872,8 @@ class PagedServer:
             )
             # pad to the single fixed row budget — never re-bucketed; lengths
             # == consumed for prefill rows, so one write base serves every mode
-            R, page_table, lengths = self._dispatch_rows(rows, pad_to=self.pool.max_slots)
+            R = self.pool.max_slots
+            page_table, lengths = self._dispatch_rows(rows, R)
             tokens = np.zeros((R, W), np.int32)
             q_lens = np.zeros(R, np.int32)
             for i, r in enumerate(rows):
@@ -1145,9 +1021,8 @@ class PagedServer:
         self._predrafts = None
         with self.tracer.span("serve.window", rows=len(rows), horizon=H):
             with self.tracer.span("serve.pack") as pack_span:
-                R, page_table, lengths = self._dispatch_rows(
-                    rows, pad_to=self.pool.max_slots
-                )
+                R = self.pool.max_slots
+                page_table, lengths = self._dispatch_rows(rows, R)
                 tokens = np.zeros(R, np.int32)
                 live = np.zeros(R, np.int32)
                 eos_ids = np.full(R, -1, np.int32)
@@ -1271,28 +1146,24 @@ class PagedServer:
             idx += 1
         return running
 
-    def _dispatch_rows(self, running: List[Request], pad_to: Optional[int] = None):
-        """(rows, page_table, lengths) padded to ``pad_to`` rows (default:
-        the smallest slot bucket covering the set; the ragged step passes
-        its fixed row budget) — rows past ``len(running)`` are dead padding
-        (-1 tables / length 0: trash-page semantics make them always
-        safe)."""
-        rows = pad_to or min(b for b in self.buckets if b >= len(running))
-        page_table = np.full((rows, self.pool.max_pages_per_slot), -1, np.int32)
-        lengths = np.zeros(rows, np.int32)
+    def _dispatch_rows(self, running: List[Request], pad_to: int):
+        """(page_table, lengths) padded to ``pad_to`` rows (the fixed row
+        budget) — rows past ``len(running)`` are dead padding (-1 tables /
+        length 0: trash-page semantics make them always safe)."""
+        page_table = np.full((pad_to, self.pool.max_pages_per_slot), -1, np.int32)
+        lengths = np.zeros(pad_to, np.int32)
         rows_pt, rows_len = self.pool.rows([r.slot for r in running])
         n = len(running)
         page_table[:n] = rows_pt
         lengths[:n] = rows_len
-        return rows, page_table, lengths
+        return page_table, lengths
 
     def _settle_spec_row(self, req: Request, d: int, acc: int, out_row) -> None:
         """Post-dispatch accounting for one decode/verify row — advance all
         ``d + 1`` written positions, roll the rejected tail's pages back,
         update the speculation stats, emit the accepted prefix + bonus/
         correction token (stopping at EOS / budget), and republish the
-        prefix. Shared verbatim by the bucketed verify round and the ragged
-        step so the oracle and the default path cannot drift."""
+        prefix."""
         self.pool.advance(req.slot, d + 1)
         self.pool.rollback(req.slot, d - acc)
         self.stats["spec_drafted"] += d
@@ -1312,46 +1183,16 @@ class PagedServer:
                 req.slot, req.context(), int(self.pool.seq_lens[req.slot])
             )
 
-    def _plain_decode_step(self, running: List[Request]) -> None:
-        running = self._reserve_for_growth(running, {})
-        if not running:
-            return
-        bucket, page_table, lengths = self._dispatch_rows(running)
-        tokens = np.zeros(bucket, np.int32)
-        tokens[: len(running)] = [r.pending for r in running]
-        decode = build_paged_decode_step(
-            self.cfg, bucket, self.pool.page_size, attn_impl=self.attn_impl,
-            telemetry=self.telemetry,
-        )
-        out, new_k, new_v = decode(
-            self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
-            page_table, lengths,
-        )
-        self.pool.set_cache(new_k, new_v)
-        self.stats["decode_steps"] += 1
-        self.stats["dispatches"] += 1
-        # the step's single host fetch: [bucket] tokens
-        out = np.asarray(out)  # lint: allow(DS-R005)
-        for i, req in enumerate(running):
-            self.pool.advance(req.slot, 1)
-            self._emit(req, int(out[i]))
-            if self.prefix_cache and not req.done:
-                # publish any page this write just filled (incremental: one
-                # hash per P decode steps per request)
-                self.pool.register_prefix(
-                    req.slot, req.context(), int(self.pool.seq_lens[req.slot])
-                )
-
     # --- speculative rounds ---------------------------------------------
     def _propose_drafts(self, running: List[Request]) -> Dict[int, np.ndarray]:
-        """Host-side drafting: up to ``_draft_cap`` tokens per request,
+        """Host-side drafting: up to ``max_draft`` tokens per request,
         clamped so drafts never outrun the request's remaining budget (the
         bonus token always needs one slot) — which also keeps every write
         inside ``max_seq_len``."""
         drafts: Dict[int, np.ndarray] = {}
         for req in running:
             budget = req.max_new_tokens - len(req.generated)  # >= 1 while running
-            k = min(self._draft_cap, budget - 1)
+            k = min(self.max_draft, budget - 1)
             d = np.zeros(0, np.int32)
             if k > 0:
                 d = np.asarray(
@@ -1359,47 +1200,6 @@ class PagedServer:
                 ).reshape(-1)[:k]
             drafts[req.uid] = d
         return drafts
-
-    def _verify_round(self, running: List[Request], drafts: Dict[int, np.ndarray]) -> None:
-        """One speculative round: reserve pages for every row's drafts +
-        bonus slot, dispatch ONE (bucket, K) verify program, emit each
-        row's accepted prefix + bonus/correction token, and roll the
-        rejected tail's pages back to the free list."""
-        need = {uid: d.size + 1 for uid, d in drafts.items()}
-        running = self._reserve_for_growth(running, need)
-        if not running:
-            return
-        d_max = max(drafts[r.uid].size for r in running)
-        # the smallest compiled width covering this round's longest draft
-        # (preemption may have evicted every drafting row — any width works)
-        K = next((l for l in self.spec_lens if l >= d_max), self.spec_lens[-1])
-        bucket, page_table, lengths = self._dispatch_rows(running)
-        tokens = np.zeros((bucket, K + 1), np.int32)
-        draft_lens = np.zeros(bucket, np.int32)
-        for i, req in enumerate(running):
-            d = drafts[req.uid]
-            tokens[i, 0] = req.pending
-            tokens[i, 1 : 1 + d.size] = d
-            draft_lens[i] = d.size
-        verify = build_paged_verify_step(
-            self.cfg, bucket, K, self.pool.page_size, attn_impl=self.attn_impl,
-            telemetry=self.telemetry,
-        )
-        out, new_k, new_v = verify(
-            self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
-            page_table, lengths, draft_lens,
-        )
-        self.pool.set_cache(new_k, new_v)
-        self.stats["spec_rounds"] += 1
-        self.stats["dispatches"] += 1
-        # the round's single host fetch: [bucket, K+2] = accept count + the
-        # greedy token after each prefix
-        out = np.asarray(out)  # lint: allow(DS-R005)
-        for i, req in enumerate(running):
-            # acc (out[i, 0]) is bounded by draft_lens in-program; all d+1
-            # written positions advance first, then the rejected tail rolls
-            # back — net advance is the accepted prefix + bonus token
-            self._settle_spec_row(req, int(draft_lens[i]), int(out[i, 0]), out[i])
 
     # --- bookkeeping ----------------------------------------------------
     def _emit(self, req: Request, token: int) -> None:
@@ -1476,7 +1276,7 @@ class PagedServer:
 
     def serve_stats(self) -> Dict:
         """Scheduler counters (incl. ``ragged_steps`` — one per unified
-        dispatch on the default path — and the multi-step window block:
+        dispatch — and the multi-step window block:
         ``window_steps`` fused dispatches, ``window_horizon``,
         ``dispatches_per_token`` over every serving dispatch and emitted
         token, and ``window_break_reasons`` naming why windows could not
@@ -1486,8 +1286,7 @@ class PagedServer:
         (hit rate, CoW copies, cached pages), and latency SLOs — aggregate
         and per-tenant p50/p99 TTFT (submit → first token, queue wait
         included) and TPOT (per generated token after the first) — the
-        payload ``InferenceEngine.serve_stats()`` surfaces and ``bench.py``
-        records per serving config."""
+        payload ``InferenceEngine.serve_stats()`` surfaces."""
         s = dict(self.stats)
         s["spec_accept_hist"] = list(self.stats["spec_accept_hist"])
         s["window_break_reasons"] = dict(self.stats["window_break_reasons"])
@@ -1505,7 +1304,7 @@ class PagedServer:
         )
         # tensor-parallel serving: the sharding degree this server runs at
         # (1 = single-chip) and whether the row-parallel all-reduces are
-        # EQuARX-quantized — bench and fleet observability key on these
+        # EQuARX-quantized — fleet observability keys on these
         s["tp_degree"] = self.tp.degree if self.tp is not None else 1
         s["tp_quantized_allreduce"] = (
             bool(self.tp.quantized_allreduce) if self.tp is not None else False

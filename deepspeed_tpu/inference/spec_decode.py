@@ -2,9 +2,9 @@
 
 Speculative decoding splits every serving round into host-side *drafting*
 and one device *verify* dispatch: a drafter proposes up to K plausible next
-tokens per running request, and ``build_paged_verify_step``
-(``inference/decode.py``) scores all K+1 positions (drafts + the bonus
-slot) in a single program, accepting the longest prefix that matches the
+tokens per running request, and the step's one program
+(``inference/decode.py:build_ragged_step``) scores all K+1 positions
+(drafts + the bonus slot) of every such row, accepting the longest prefix that matches the
 model's own greedy argmax — so the output stream is byte-identical to
 non-speculative decode while each accepted draft turns a whole
 model-streaming dispatch (plus its per-dispatch host cost) into one extra

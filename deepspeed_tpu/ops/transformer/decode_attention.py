@@ -13,13 +13,13 @@ cache capacity. Per-batch lengths arrive via scalar prefetch, making the
 kernel ragged — each batch row stops at its own length (the paged/ragged
 attention the reference approximates with masking).
 
-The serving layer reaches the page-table variant (``paged_decode_attention``
+The serving layer reaches the page-table kernel (``ragged_paged_attention``
 below) through ``ops/transformer/paged_attention.py``, which fronts it with
-an XLA gather fallback and the chunk-prefill attention.
+an XLA scatter + gather fallback.
 
 Each ``pallas_call`` carries its entry point's name (``decode_attention``,
-``paged_decode_attention``, ``ragged_paged_attention``): a profiler trace
-finds the kernel by it (``benchmark/op_scopes.py``).
+``ragged_paged_attention``): a profiler trace finds the kernel by it
+(``benchmark/op_scopes.py``).
 """
 
 from __future__ import annotations
@@ -115,110 +115,6 @@ def _pages_in_stack(layer, page_table, NP):
     return jnp.asarray(layer, jnp.int32) * NP + jnp.clip(
         jnp.asarray(page_table, jnp.int32), 0, NP - 1
     )
-
-
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, scale, page, maxp):
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    @pl.when(ki * page < len_ref[b])
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [Hg, D]
-        k = k_ref[0, 0].astype(jnp.float32)  # [page, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        pos = ki * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < len_ref[b], s, NEG_INF)
-        m_prev = m_s[:, :1]
-        l_prev = l_s[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * corr + jax.lax.dot(p, v, preferred_element_type=jnp.float32)
-        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
-
-    @pl.when(ki == maxp - 1)
-    def _finish():
-        l = l_s[:, :1]
-        safe_l = jnp.where(l == 0, 1.0, l)
-        o_ref[0, 0] = (acc_s[...] / safe_l).astype(o_ref.dtype)
-
-
-def paged_decode_attention(
-    q: jnp.ndarray,  # [B, NH, D]
-    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D] — every layer's shared page pool
-    v_pages: jnp.ndarray,
-    layer,  # int32 scalar: the layer whose pool is read
-    page_table: jnp.ndarray,  # [B, MAXP] int32 page ids per sequence
-    kv_len,  # [B] int32 live lengths
-    scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """Paged (block-table) decode attention — the vLLM-style serving layout
-    the reference approximates with contiguous per-sequence workspaces: each
-    sequence's cache is a list of pages in a shared pool, so prefixes can be
-    shared and memory allocates page-granular. The kernel's kv grid walks
-    the page table via scalar prefetch (k/v BlockSpecs jump straight to the
-    page; the stacked pools are seen as ``L * NP`` pages and ``layer`` is
-    folded into the table, so no slice of the stack is ever made). Compute
-    for table slots past the live length is skipped, but the
-    block FETCH is not (pl.when gates the body, not the BlockSpec), so the
-    table's ids are clamped into [0, NP): tables padded with -1 or sentinel
-    ids >= NP read a valid page whose scores are then masked out."""
-    B, NH, D = q.shape
-    L, NP, NKV, P, Dk = k_pages.shape
-    assert Dk == D and v_pages.shape == k_pages.shape
-    if NH % NKV:
-        raise ValueError(f"query heads {NH} not a multiple of kv heads {NKV}")
-    maxp = page_table.shape[1]
-    scale_f = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
-    if interpret is None:
-        interpret = not on_tpu()
-    Hg = NH // NKV
-    qg = q.reshape(B, NKV, Hg, D)
-    lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
-    kernel = functools.partial(_paged_kernel, scale=scale_f, page=P, maxp=maxp)
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    pages = _pages_in_stack(layer, page_table, NP)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NKV, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, Hg, D), lambda b, g, ki, pt, ln: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, P, D), lambda b, g, ki, pt, ln: (pt[b, ki], g, 0, 0)),
-            pl.BlockSpec((1, 1, P, D), lambda b, g, ki, pt, ln: (pt[b, ki], g, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Hg, D), lambda b, g, ki, pt, ln: (b, g, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hg, 128), jnp.float32),
-            pltpu.VMEM((Hg, 128), jnp.float32),
-            pltpu.VMEM((Hg, D), jnp.float32),
-        ],
-    )
-    o = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, NKV, Hg, D), q.dtype),
-        interpret=interpret,
-        name="paged_decode_attention",
-        **params,
-    )(pages, lens, qg, k_pages.reshape(L * NP, NKV, P, D), v_pages.reshape(L * NP, NKV, P, D))
-    return o.reshape(B, NH, D)
 
 
 def _page_receives(ki, page, kv_len, start):

@@ -1,4 +1,4 @@
-"""Ragged paged decode attention — serving-layer front end.
+"""Ragged paged attention — serving-layer front end.
 
 The serving layer (``inference/kv_pool.py`` + ``inference/scheduler.py``)
 stores every sequence's KV cache as fixed-size pages in one shared pool
@@ -9,22 +9,8 @@ reaches the layer's pages through it (``pool[layer, ids]``, the kernels'
 index maps): the serving step carries the stack through its layer loop, and
 a slice of it would be a copy of one layer's pool.
 
-* ``paged_decode_attention`` — one generated token per sequence attends over
-  its live pages. Dispatches to the Pallas kernel
-  (``decode_attention._pallas_paged_decode``: the kv grid walks the page
-  table via scalar prefetch, online softmax, GQA groups ride the sublane
-  dim) on TPU, and to a gather-based XLA implementation everywhere else —
-  interpret-mode Pallas inside a per-step serving program would dominate
-  CPU-mesh test time.
-* ``paged_prefill_attention`` — a token slab ``[B, T]`` attends causally
-  over each row's own pages (prefix + the slab itself, already scattered
-  in). Pure XLA: the slab paths are matmul-bound. Two callers: chunked
-  prompt prefill (B = 1, T = chunk) and the speculative verify program
-  (B = slot bucket, T = K+1 draft-and-bonus slots), which also passes
-  per-row ``kv_lens`` so pad draft slots past a row's live prefix are
-  masked out of every score.
-* ``ragged_paged_attention`` — the unified entry the one-program ragged
-  serving step dispatches (``decode.py:build_ragged_step``): mixed
+* ``ragged_paged_attention`` — the entry the one-program ragged serving
+  step dispatches (``decode.py:build_ragged_step``): mixed
   prefill-chunk / decode / verify rows in one ``[R, W]`` window, driven
   entirely by per-row ``(kv_len, q_len)`` metadata arrays so the mix
   never retraces. It WRITES the window's k/v into the pool and attends over
@@ -33,9 +19,14 @@ a slice of it would be a copy of one layer's pool.
   aliased stacks — a grid step a row walks the row's live pages only,
   fetched by the kernel's own DMAs, merges the new rows into the pages that
   receive them, causal in-window mask; a dead row and the dead tail of a
-  table cost a few scalar reads), XLA scatter + gather elsewhere.
-* ``scatter_pages`` — the XLA write of a token slab's k/v into the pool,
-  for the two read-only entries' callers and the ragged entry's XLA path.
+  table cost a few scalar reads), XLA scatter + gather elsewhere —
+  interpret-mode Pallas inside a per-step serving program would dominate
+  CPU-mesh test time.
+* ``scatter_pages`` and ``paged_prefill_attention`` — the entry's XLA form,
+  and the reference the kernel's tests compare with: the write of a token
+  slab's k/v into the pool, then the slab ``[B, T]`` attending causally over
+  each row's own pages (prefix + the slab itself), per-row ``kv_lens``
+  masking the pad slots past a row's live prefix out of every score.
 
 GQA is handled by grouping — queries reshape to ``[B, NKV, G, D]`` and each
 kv head's rows are read once — so no path here (kernel or fallback) ever
@@ -69,7 +60,6 @@ import numpy as np
 from deepspeed_tpu.accelerator import on_tpu
 from deepspeed_tpu.ops.transformer.decode_attention import (
     NEG_INF,
-    paged_decode_attention as _pallas_paged_decode,
     ragged_paged_attention as _pallas_ragged_paged,
 )
 
@@ -88,84 +78,25 @@ def _gather_pages(pages: jnp.ndarray, layer, page_table: jnp.ndarray) -> jnp.nda
     return pages[layer, pt].transpose(0, 1, 3, 2, 4).reshape(B, maxp * P, NKV, D)
 
 
-def scatter_pages(pages, layer, vals, page_table, positions, valid=None):
+def scatter_pages(pages, layer, vals, page_table, positions, valid):
     """Write [B, T, NKV, D] new k/v rows into ``layer`` of the page pool
     [L, NP, NKV, P, D] at absolute ``positions`` [B, T] through the page table
     [B, MAXP]. Sentinel table entries (< 0, i.e. unallocated/dead rows)
-    clamp onto the reserved trash page 0, so padded bucket rows and prompt
-    pad tails write garbage only where nothing lives. ``valid`` (bool
-    [B, T], optional) force-redirects masked positions onto the trash page
-    regardless of the table: the verify program's pad draft slots sit past
-    a row's ensured pages, where ``positions // page_size`` could alias a
-    LIVE page after the maxp clamp."""
+    clamp onto the reserved trash page 0, so dead padding rows write garbage
+    only where nothing lives. ``valid`` (bool [B, T])
+    force-redirects masked positions onto the trash page regardless of the
+    table: a window's slots past the row's real tokens sit past its ensured
+    pages, where ``positions // page_size`` could alias a LIVE page after
+    the maxp clamp."""
     _, NP, _, P, _ = pages.shape
     maxp = page_table.shape[1]
     slot = jnp.clip(positions // P, 0, maxp - 1)
     pid = jnp.clip(jnp.take_along_axis(page_table, slot, axis=1), 0, NP - 1)
-    if valid is not None:
-        pid = jnp.where(valid, pid, 0)  # page 0 = the reserved trash page
+    pid = jnp.where(valid, pid, 0)  # page 0 = the reserved trash page
     off = positions % P
     # advanced-index scatter: (layer, pid, off) broadcast to [B, T] and land
     # first, giving the [B, T, NKV, D] update window vals fills exactly
     return pages.at[layer, pid, :, off, :].set(vals.astype(pages.dtype))
-
-
-def paged_decode_attention_xla(
-    q: jnp.ndarray,  # [B, NH, D]
-    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D]
-    v_pages: jnp.ndarray,
-    layer,  # int32 scalar
-    page_table: jnp.ndarray,  # [B, MAXP] int32
-    kv_len,  # [B] int32 live lengths (or scalar)
-    scale: Optional[float] = None,
-) -> jnp.ndarray:
-    """Gather-based reference/fallback: linearize each row's pages and run
-    grouped-GQA masked attention. Rows with length 0 return exact zeros
-    (matching the Pallas kernel's empty-accumulator output)."""
-    B, NH, D = q.shape
-    _, NP, NKV, P, _ = k_pages.shape
-    assert v_pages.shape == k_pages.shape
-    if NH % NKV:
-        raise ValueError(f"query heads {NH} not a multiple of kv heads {NKV}")
-    G = NH // NKV
-    S = page_table.shape[1] * P
-    scale_f = _scale_or_default(scale, D)
-    k = _gather_pages(k_pages, layer, page_table)  # [B, S, NKV, D]
-    v = _gather_pages(v_pages, layer, page_table)
-    lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
-    qg = q.reshape(B, NKV, G, D)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg, k).astype(jnp.float32) * scale_f
-    kv_pos = jnp.arange(S, dtype=jnp.int32)
-    live = kv_pos[None, None, None, :] < lens[:, None, None, None]
-    scores = jnp.where(live, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v)
-    out = jnp.where((lens > 0)[:, None, None, None], out, 0)
-    return out.reshape(B, NH, D)
-
-
-def paged_decode_attention(
-    q: jnp.ndarray,  # [B, NH, D]
-    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D]
-    v_pages: jnp.ndarray,
-    layer,  # int32 scalar
-    page_table: jnp.ndarray,  # [B, MAXP] int32
-    kv_len,  # [B] int32 live lengths
-    scale: Optional[float] = None,
-    impl: str = "auto",
-) -> jnp.ndarray:
-    """Single-token paged attention. ``impl``: ``auto`` picks the Pallas
-    kernel on TPU and the XLA gather fallback elsewhere; ``pallas`` / ``xla``
-    force one (``pallas`` off-TPU runs in interpret mode — tests only)."""
-    if impl == "auto":
-        impl = "pallas" if on_tpu() else "xla"
-    if impl == "pallas":
-        return _pallas_paged_decode(q, k_pages, v_pages, layer, page_table, kv_len, scale=scale)
-    if impl == "xla":
-        return paged_decode_attention_xla(
-            q, k_pages, v_pages, layer, page_table, kv_len, scale=scale
-        )
-    raise ValueError(f"unknown paged attention impl {impl!r}; expected auto|pallas|xla")
 
 
 def ragged_paged_attention(
@@ -215,7 +146,7 @@ def ragged_paged_attention(
     k_pages = scatter_pages(k_pages, layer, k_new, page_table, q_positions, valid)
     v_pages = scatter_pages(v_pages, layer, v_new, page_table, q_positions, valid)
     out = paged_prefill_attention(
-        q, k_pages, v_pages, layer, page_table, q_positions, scale=scale, kv_lens=lens
+        q, k_pages, v_pages, layer, page_table, q_positions, lens, scale=scale
     )
     return out, k_pages, v_pages
 
@@ -227,8 +158,8 @@ def paged_prefill_attention(
     layer,  # int32 scalar
     page_table: jnp.ndarray,  # [B, MAXP] int32
     q_positions: jnp.ndarray,  # [B, T] absolute positions of the chunk tokens
+    kv_lens: jnp.ndarray,  # [B] live kv bound (incl. the slab)
     scale: Optional[float] = None,
-    kv_lens: Optional[jnp.ndarray] = None,  # [B] live kv bound (incl. the slab)
 ) -> jnp.ndarray:
     """Causal slab attention over each sequence's own pages: query at
     absolute position p sees kv positions <= p (the slab's k/v have already
@@ -236,10 +167,10 @@ def paged_prefill_attention(
     Positions past a slab's real end (pad tail) produce garbage rows the
     caller ignores — their writes land on the trash page and their reads are
     causally bounded, so they never contaminate live positions. ``kv_lens``
-    additionally caps every row's visible kv range (the verify program's
-    pad slots sit ABOVE live positions, where causality alone would let
-    them read unwritten pages); rows with ``kv_lens == 0`` (dead bucket
-    padding) return exact zeros."""
+    also caps every row's visible kv range (a window's pad slots
+    sit ABOVE live positions, where causality alone would let them read
+    unwritten pages); rows with ``kv_lens == 0`` (dead padding) return
+    exact zeros."""
     B, T, NH, D = q.shape
     _, NP, NKV, P, _ = k_pages.shape
     if NH % NKV:
@@ -252,14 +183,11 @@ def paged_prefill_attention(
     qg = q.reshape(B, T, NKV, G, D)
     scores = jnp.einsum("btkgd,bskd->bkgts", qg, k).astype(jnp.float32) * scale_f
     kv_pos = jnp.arange(S, dtype=jnp.int32)
+    lens = jnp.asarray(kv_lens, jnp.int32)
     mask = q_positions[:, None, None, :, None] >= kv_pos[None, None, None, None, :]
-    if kv_lens is not None:
-        lens = jnp.asarray(kv_lens, jnp.int32)
-        mask = mask & (kv_pos[None, None, None, None, :] < lens[:, None, None, None, None])
+    mask = mask & (kv_pos[None, None, None, None, :] < lens[:, None, None, None, None])
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
     out = out.reshape(B, T, NH, D)
-    if kv_lens is not None:
-        out = jnp.where((lens > 0)[:, None, None, None], out, 0)
-    return out
+    return jnp.where((lens > 0)[:, None, None, None], out, 0)

@@ -14,7 +14,7 @@ wedged whole test sessions (PERF.md round 5). This module makes both visible:
   record, so a retrace-regression guard can assert "≤1 compile across N
   steps" without caring when the engine rebuilt its callables.
 * ``use_compile_cache`` places JAX's on-disk compilation cache for the
-  checkout's entry scripts (``chip_smoke.py``, ``bench.py`` children,
+  checkout's entry scripts (``chip_smoke.py``, ``benchmark/run.py``,
   ``tools/``) so repeated runs skip cold compiles.
 
 The wrapper forwards ``lower``/``eval_shape``/``clear_cache`` to the

@@ -469,10 +469,10 @@ def test_overlap_loop_membership_is_transitive():
 # green sweep: speculative verify programs (ISSUE 4)
 # ---------------------------------------------------------------------------
 def test_green_spec_verify_programs():
-    """The speculative serving programs (paged_verify per (bucket, K), next
-    to decode/prefill) verify clean under every pass: donated page buffers
-    aliased, zero host transfers, zero upcast-compute sites, zero
-    violations overall."""
+    """The serving programs of a server whose every decode round drafts (the
+    narrow ragged width carries verify rows, the mixed one the chunks)
+    verify clean under every pass: donated page buffers aliased, zero host
+    transfers, zero upcast-compute sites, zero violations overall."""
     from deepspeed_tpu.analysis import run_program_passes
     from deepspeed_tpu.inference.scheduler import PagedServer
     from deepspeed_tpu.inference.spec_decode import Drafter
@@ -480,7 +480,7 @@ def test_green_spec_verify_programs():
     from deepspeed_tpu.models.config import TransformerConfig
 
     class TwoTokenDrafter(Drafter):
-        # always drafts something: every round is a verify dispatch
+        # always drafts something: every decode row is a verify row
         def propose(self, uid, context, k):
             return np.asarray([0, 1][: max(k, 0)], np.int32)
 
@@ -498,15 +498,14 @@ def test_green_spec_verify_programs():
         cfg, params, page_size=8, max_slots=4, prefill_chunk=8,
         attn_impl="xla", dtype=jnp.float32, telemetry=tel,
         spec_decode={"max_draft": 2}, drafter=TwoTokenDrafter(),
-        ragged=False,  # the bucketed oracle's verify programs
     )
     rs = np.random.RandomState(0)
     prompts = [rs.randint(0, 128, (7,)).astype(np.int32) for _ in range(3)]
     server.serve(prompts, max_new_tokens=4)
-    assert server.stats["spec_rounds"] >= 1
+    assert server.stats["spec_rounds"] >= 1 and server.stats["decode_steps"] <= 1
     rep = run_program_passes(tel)
     names = set(rep["programs"])
-    assert any(n.startswith("paged_verify_") for n in names), names
+    assert names == {"paged_ragged_r4_w3", "paged_ragged_r4_w8"}, names
     t = rep["totals"]
     assert t["analysis_failures"] == 0 and t["violations"] == 0, rep
     assert t["donation_verified"] is True
@@ -525,8 +524,8 @@ def test_green_traffic_serving_programs():
     """Serving through the traffic layer (prefix-cached pool + SLA tenant
     scheduler) dispatches only the existing paged programs — donation
     aliased, zero host transfers, zero violations — and sharing adds no
-    dispatches: decode dispatches == decode steps, prefill dispatches ==
-    prefill chunks, even with prefix attaches happening."""
+    dispatches: one ragged dispatch a scheduler step, even with prefix
+    attaches happening."""
     from deepspeed_tpu.analysis import run_program_passes
     from deepspeed_tpu.inference.scheduler import PagedServer
     from deepspeed_tpu.inference.traffic import MultiTenantServer, TenantSpec
@@ -547,7 +546,7 @@ def test_green_traffic_serving_programs():
         PagedServer(
             cfg, params, page_size=8, max_slots=4, prefill_chunk=8,
             attn_impl="xla", dtype=jnp.float32, telemetry=tel,
-            prefix_cache=True, ragged=False,  # the bucketed oracle's programs
+            prefix_cache=True,
         ),
         tenants=[TenantSpec(name="a", weight=2.0), TenantSpec(name="b")],
     )
@@ -562,17 +561,9 @@ def test_green_traffic_serving_programs():
     server.serve(prompts[1:], max_new_tokens=4, tenant=["b", "a", "b"])
     assert server.pool.stats["prefix_hit_pages"] > 0  # sharing engaged
     stats = tel.stats()
-    decode_dispatches = sum(
-        rec["dispatches"] for name, rec in stats.items()
-        if name.startswith("paged_decode_")
-    )
-    prefill_dispatches = sum(
-        rec["dispatches"] for name, rec in stats.items()
-        if name.startswith("paged_prefill_")
-    )
-    assert decode_dispatches == server.stats["decode_steps"]
-    assert prefill_dispatches == server.stats["prefill_chunks"]
-    assert all(n.startswith("paged_") for n in stats), stats.keys()
+    assert sum(rec["dispatches"] for rec in stats.values()) == server.stats["ragged_steps"]
+    assert server.stats["decode_steps"] >= 1 and server.stats["prefill_chunks"] >= 4
+    assert all(n.startswith("paged_ragged_") for n in stats), stats.keys()
     rep = run_program_passes(tel)
     t = rep["totals"]
     assert t["analysis_failures"] == 0 and t["violations"] == 0, rep
@@ -623,7 +614,6 @@ def test_green_ragged_serving_program_and_compile_gate():
         attn_impl="xla", dtype=jnp.float32, telemetry=tel,
         spec_decode={"max_draft": 2}, drafter=MixDrafter(), prefix_cache=True,
     )
-    assert server.ragged  # the default path is the one under the gate
     rs = np.random.RandomState(0)
     # 3 waves of shifting mixes: short prompts (single chunk), long prompts
     # (multi-chunk, so chunks ride WITH in-flight decoders), varying counts

@@ -105,9 +105,9 @@ def test_green_fp32_single_buffer_step(eight_devices):
 
 
 def test_green_paged_serving_programs():
-    """The serving programs (paged decode per bucket, chunked prefill)
-    verify clean: donated page buffers aliased, no host callback, no
-    upcast compute."""
+    """The serving programs (the ragged step at its narrow decode width and
+    at the mixed width that carries the prefill chunks) verify clean: donated
+    page buffers aliased, no host callback, no upcast compute."""
     from deepspeed_tpu.inference.scheduler import PagedServer
     from deepspeed_tpu.models import TransformerLM
     from deepspeed_tpu.models.config import TransformerConfig
@@ -125,15 +125,13 @@ def test_green_paged_serving_programs():
     server = PagedServer(
         cfg, params, page_size=8, max_slots=4, prefill_chunk=8,
         attn_impl="xla", dtype=jnp.float32, telemetry=tel,
-        ragged=False,  # the bucketed oracle's decode/prefill programs
     )
     rs = np.random.RandomState(0)
     prompts = [rs.randint(0, 128, (7,)).astype(np.int32) for _ in range(3)]
     server.serve(prompts, max_new_tokens=4)
     rep = run_program_passes(tel)
     names = set(rep["programs"])
-    assert any(n.startswith("paged_decode_") for n in names), names
-    assert any(n.startswith("paged_prefill_") for n in names), names
+    assert names == {"paged_ragged_r4_w1", "paged_ragged_r4_w8"}, names
     _assert_clean(rep, sorted(names))
 
 

@@ -45,6 +45,21 @@ class TestConfigModel:
         with pytest.raises(ValidationError):
             DeepSpeedInferenceConfig(definitely_not_a_key=1)
 
+    @pytest.mark.parametrize("through", ["config_model", "init_inference"])
+    @pytest.mark.parametrize(
+        "section, key, value",
+        [("paged_kv", "ragged", True), ("paged_kv", "slot_buckets", [1, 2, 4]), ("spec_decode", "spec_lens", [2, 4])],
+    )
+    def test_removed_serving_knobs_refused_by_name(self, section, key, value, through):
+        """The bucketed serving path's three options went with it: a config
+        that still sets one is refused by the key's name, not read past,
+        whatever the value, and before ``init_inference`` builds anything."""
+        with pytest.raises(ValidationError, match=rf"{section}\.{key}\b"):
+            if through == "config_model":
+                DeepSpeedInferenceConfig(**{section: {key: value}})
+            else:
+                ds.init_inference(object(), config={section: {key: value}})
+
 
 class TestInitInference:
     def _model(self):

@@ -1,11 +1,9 @@
 """One ragged serving program (ISSUE 8): unified prefill+decode+verify
 dispatch that never retraces.
 
-Load-bearing checks: with ``ragged=True`` (the default) every scheduler
-step is ONE dispatch of the unified ``build_ragged_step`` program and the
-greedy output streams are BYTE-IDENTICAL to the bucketed per-shape path
-(``ragged=False``, the token-exactness oracle) and to the dense lockstep
-``decode.generate`` — across mid-stream admission, preemption+resume on
+Load-bearing checks: every scheduler step is ONE dispatch of the unified
+``build_ragged_step`` program and the greedy output streams are
+BYTE-IDENTICAL to the dense lockstep ``decode.generate`` — across mid-stream admission, preemption+resume on
 the chunk grid, prefix-cache attach, a per-request spec-K mix, and EOS
 landing inside an accepted draft run. Compile telemetry must show ≤ 2
 compiled serving programs for a full mixed serve and 1 dispatch per step
@@ -20,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu as ds
 from deepspeed_tpu.inference import decode
 from deepspeed_tpu.inference.scheduler import PagedServer, compiled_serving_programs
 from deepspeed_tpu.inference.spec_decode import Drafter
@@ -66,20 +63,19 @@ def _dense(cfg, params, prompt, n, eos=None):
     return np.asarray(decode.generate(cfg, params, prompt[None], n, eos_token_id=eos))[0]
 
 
-def _server(cfg, params, ragged=True, **kw):
+def _server(cfg, params, **kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("max_slots", 4)
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("attn_impl", "xla")
     kw.setdefault("dtype", jnp.float32)
-    return PagedServer(cfg, params, ragged=ragged, **kw)
+    return PagedServer(cfg, params, **kw)
 
 
 class MixKDrafter(Drafter):
     """Per-request spec-K mix: request uid drafts its precomputed greedy
     future, but only ``uid % (cap+1)`` tokens of it — every ragged round
-    carries rows with DIFFERENT draft counts (incl. zero) at once, the
-    shape the bucketed path could only serve by freezing K per program."""
+    carries rows with DIFFERENT draft counts (incl. zero) at once."""
 
     def __init__(self, futures, cap=3):
         self.futures = futures
@@ -90,22 +86,19 @@ class MixKDrafter(Drafter):
         return self.futures[uid][context.size : context.size + k].astype(np.int32)
 
 
-# --- token exactness: ragged vs bucketed vs dense ---------------------------
-def test_ragged_matches_bucketed_and_dense_mixed_serve(model_and_params):
-    """The core exactness oracle: same ragged request mix through both
-    paths, byte-identical streams, pool drained."""
+# --- token exactness: ragged vs dense -----------------------------------------
+def test_ragged_matches_dense_mixed_serve(model_and_params):
+    """The core exactness check: a ragged request mix, every stream
+    byte-identical to its own dense decode, pool drained."""
     cfg, _, params = model_and_params
     prompts = _prompts(6, seed=2)
     budgets = [10, 3, 7, 12, 1, 5]
-    ragged = _server(cfg, params, ragged=True)
+    ragged = _server(cfg, params)
     outs = ragged.serve(prompts, max_new_tokens=budgets)
-    bucketed = _server(cfg, params, ragged=False)
-    oracle = bucketed.serve(prompts, max_new_tokens=budgets)
-    for p, n, a, b in zip(prompts, budgets, outs, oracle):
+    for p, n, a in zip(prompts, budgets, outs):
         np.testing.assert_array_equal(a, _dense(cfg, params, p, n))
-        np.testing.assert_array_equal(a, b)
     assert ragged.stats["finished"] == 6
-    assert ragged.stats["ragged_steps"] >= 1 and bucketed.stats["ragged_steps"] == 0
+    assert ragged.stats["ragged_steps"] >= 1
     assert ragged.pool.used_pages() == 0 and ragged.pool.live_tokens() == 0
 
 
@@ -132,8 +125,8 @@ def test_ragged_admission_mid_stream(model_and_params):
 def test_ragged_prefill_coexists_with_decode(model_and_params):
     """A long multi-chunk prompt admitted next to a short one: once the
     short request starts decoding, the long one's remaining chunks share
-    its dispatches — total dispatches stay well under the bucketed path's
-    chunks + decode steps."""
+    its dispatches — total dispatches stay well under chunks + decode
+    steps."""
     cfg, _, params = model_and_params
     rs = np.random.RandomState(9)
     short = rs.randint(0, 128, (4,)).astype(np.int32)
@@ -146,26 +139,23 @@ def test_ragged_prefill_coexists_with_decode(model_and_params):
     np.testing.assert_array_equal(results[uids[1]], _dense(cfg, params, long, 4))
     st = server.stats
     # 40-token prompt = 5 chunks; the short request decodes through 4+ of
-    # those same dispatches — strictly fewer total dispatches than the
-    # bucketed schedule's (chunks + decode steps)
+    # those same dispatches — strictly fewer total dispatches than
+    # chunks + decode steps
     assert st["prefill_chunks"] >= 6
     assert st["ragged_steps"] < st["prefill_chunks"] + st["decode_steps"]
 
 
 def test_ragged_preemption_resume_on_chunk_grid(model_and_params):
     """An undersized pool forces preemption mid-stream; the resumed prefill
-    realigns to the chunk grid and the recomputed continuation is exact —
-    in BOTH paths, and identical between them."""
+    realigns to the chunk grid and the recomputed continuation is exact."""
     cfg, _, params = model_and_params
     kw = dict(page_size=4, num_pages=14, max_slots=3, prefill_chunk=8)
     prompts = _prompts(4, seed=4, lo=6, hi=14)
-    ragged = _server(cfg, params, ragged=True, **kw)
+    ragged = _server(cfg, params, **kw)
     outs = ragged.serve(prompts, max_new_tokens=12)
     assert ragged.stats["preempted"] >= 1, "pool was sized to force preemption"
-    oracle = _server(cfg, params, ragged=False, **kw).serve(prompts, max_new_tokens=12)
-    for p, a, b in zip(prompts, outs, oracle):
+    for p, a in zip(prompts, outs):
         np.testing.assert_array_equal(a, _dense(cfg, params, p, 12))
-        np.testing.assert_array_equal(a, b)
     assert ragged.pool.used_pages() == 0
 
 
@@ -192,10 +182,9 @@ def test_ragged_prefix_cache_attach(model_and_params):
 
 
 def test_ragged_per_request_spec_k_mix(model_and_params):
-    """Per-request spec-K inside one dispatch — the shape the bucketed
-    path cannot express (its verify programs freeze K): rows drafting 0,
-    1, 2, and 3 tokens verify together, streams stay byte-identical to
-    spec-off serving and dense."""
+    """Per-request spec-K inside one dispatch: rows drafting 0, 1, 2, and
+    3 tokens verify together, streams stay byte-identical to spec-off
+    serving and dense."""
     cfg, _, params = model_and_params
     prompts = _prompts(4, seed=5)
     futures = {i: _dense(cfg, params, p, 12) for i, p in enumerate(prompts)}
@@ -237,7 +226,7 @@ def test_ragged_eos_in_accepted_run(model_and_params):
 def test_ragged_compile_budget_and_one_dispatch_per_step(model_and_params):
     """3-wave shifting mix through one telemetry: ≤ 2 compiled serving
     programs TOTAL (warmup aside, no wave adds a compile), exactly one
-    ragged dispatch per scheduler step, and ZERO bucketed programs."""
+    ragged dispatch per scheduler step, and no other serving program."""
     cfg, _, params = model_and_params
     telemetry = CompileTelemetry()
     server = _server(cfg, params, telemetry=telemetry)
@@ -255,28 +244,3 @@ def test_ragged_compile_budget_and_one_dispatch_per_step(model_and_params):
     assert sum(r["dispatches"] for r in stats.values()) == server.stats["ragged_steps"]
 
 
-def test_ragged_knob_through_engine(model_and_params):
-    """paged_kv.ragged=False routes the engine's serve() to the bucketed
-    oracle; the default routes to the ragged program. Outputs identical."""
-    cfg, model, params = model_and_params
-    outs = {}
-    for ragged in (True, False):
-        engine = ds.init_inference(
-            model,
-            dtype="fp32",
-            paged_kv={"page_size": 8, "max_slots": 4, "prefill_chunk": 8,
-                      "attn_impl": "xla", "ragged": ragged},
-        )
-        engine.set_params(params)
-        engine._ds_config = cfg  # converted-family contract
-        prompts = _prompts(3, seed=11)
-        outs[ragged] = engine.serve(prompts, max_new_tokens=5)
-        names = list(engine.compile_stats())
-        if ragged:
-            assert any(n.startswith("paged_ragged_") for n in names), names
-            assert engine.serve_stats()["ragged_steps"] >= 1
-        else:
-            assert any(n.startswith("paged_decode_") for n in names), names
-            assert not any(n.startswith("paged_ragged_") for n in names)
-    for a, b in zip(outs[True], outs[False]):
-        np.testing.assert_array_equal(a, b)

@@ -222,56 +222,43 @@ def test_tp_multistep_window_parity(model_and_params):
 
 
 # --- config / validation red tests ------------------------------------------
-def test_tp_requires_ragged_and_divisibility(model_and_params):
+def test_tp_divisibility_and_weight_bits_validation(model_and_params):
     cfg, _, params = model_and_params
-    with pytest.raises(ValueError, match="ragged"):
-        _server(cfg, params, tp=_tp(2), ragged=False)
     bad = TransformerConfig(**{**CFG, "num_heads": 6, "num_kv_heads": 3})
     with pytest.raises(ValueError, match="divide"):
         _server(bad, params, tp=_tp(4))
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 
-    with pytest.raises(Exception, match="ragged"):
-        DeepSpeedInferenceConfig(
-            paged_kv={"ragged": False, "sharded": {"tp_degree": 2}}
-        )
-    # the FOLLOW mode (sharded.tp_degree=0 defers to tensor_parallel) with
-    # the bucketed oracle stays VALID — tp_size also drives the dense
-    # generate path, and pre-sharded-serving configs used exactly this
-    # combination. The engine falls back to single-chip bucketed serving.
-    follow = DeepSpeedInferenceConfig(
-        tensor_parallel={"tp_size": 2}, paged_kv={"ragged": False}
-    )
-    assert follow.paged_kv.sharded.tp_degree == 0
-    engine = ds.init_inference(
-        TransformerLM(cfg), dtype="fp32", tensor_parallel={"tp_size": 2},
-        paged_kv={"ragged": False, "page_size": 8, "max_slots": 4,
-                  "prefill_chunk": 8, "attn_impl": "xla"},
-    )
-    engine.set_params(params)
-    engine._ds_config = cfg
-    assert engine._build_paged_server().tp is None  # single-chip fallback
     with pytest.raises(Exception, match="weight_quant_bits"):
         DeepSpeedInferenceConfig(paged_kv={"sharded": {"weight_quant_bits": 4}})
 
 
-def test_tp_engine_knob_routing(model_and_params):
-    """`paged_kv.sharded.tp_degree` routes through the engine: the built
+@pytest.mark.parametrize(
+    "sharded, engine_kw",
+    [
+        ({"tp_degree": 2}, {}),
+        # the FOLLOW mode: no ``sharded`` block, so sharded.tp_degree stays 0
+        # and defers to the engine-level degree
+        (None, {"tensor_parallel": {"tp_size": 2}}),
+    ],
+    ids=["sharded_tp_degree", "follows_tensor_parallel"],
+)
+def test_tp_engine_knob_routing(model_and_params, sharded, engine_kw):
+    """The degree, given either way, routes through the engine: the built
     server runs the sharded programs and reports its degree."""
     cfg, _, params = model_and_params
-    engine = ds.init_inference(
-        TransformerLM(cfg),
-        dtype="fp32",
-        paged_kv={
-            "page_size": 8, "max_slots": 4, "prefill_chunk": 8,
-            "attn_impl": "xla", "sharded": {"tp_degree": 2},
-        },
-    )
+    paged_kv = {"page_size": 8, "max_slots": 4, "prefill_chunk": 8, "attn_impl": "xla"}
+    if sharded:
+        paged_kv["sharded"] = sharded
+    engine = ds.init_inference(TransformerLM(cfg), dtype="fp32", paged_kv=paged_kv, **engine_kw)
+    assert engine._config.paged_kv.sharded.tp_degree == (2 if sharded else 0)
     engine.set_params(params)
     engine._ds_config = cfg
     prompts = _prompts(2, seed=8)
     outs = engine.serve(prompts, max_new_tokens=4)
     assert all(o is not None for o in outs)
+    assert engine._paged_server.tp.degree == 2
+    assert any(n.endswith("_tp2") for n in engine.compile_stats() if n.startswith("paged_ragged_"))
     st = engine.serve_stats()
     assert st["tp_degree"] == 2 and st["finished"] == 2
     ref = _server(cfg, params).serve(prompts, max_new_tokens=4)

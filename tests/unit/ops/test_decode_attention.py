@@ -123,16 +123,33 @@ class TestDeepSpeedTransformerLayer:
 
 
 def _paged(q, pool_k, pool_v, table, lens):
-    """``paged_decode_attention`` on a per-layer pool: the kernel takes every
-    layer's pool and a layer index, so the pool rides as layer 1 under a
-    layer of garbage that a wrong index would read."""
-    from deepspeed_tpu.ops.transformer.decode_attention import paged_decode_attention
+    """One token a row (``q_lens == 1``) through the ragged page-table kernel
+    on a per-layer pool: the kernel takes every layer's pool and a layer
+    index, so the pool rides as layer 1 under a layer of garbage that a wrong
+    index would read. The kernel also writes each row's newest key and value
+    (position ``len - 1``); it is handed the ones the pool holds there, so
+    no page but the trash page 0 may change."""
+    from deepspeed_tpu.ops.transformer.decode_attention import ragged_paged_attention
 
+    pool_k, pool_v, table = np.asarray(pool_k), np.asarray(pool_v), np.asarray(table)
+    lens = np.broadcast_to(np.asarray(lens, np.int32), (len(q),))
+    last, rows = lens - 1, np.arange(len(q))
+    pid = table[rows, last // pool_k.shape[2]]
+    newest = lambda pool: jnp.asarray(pool[pid, :, last % pool.shape[2]][:, None])  # [B, 1, NKV, D]
     stack = lambda pool: jnp.stack([jnp.full(pool.shape, 1e6, pool.dtype), jnp.asarray(pool)])
-    return paged_decode_attention(jnp.asarray(q), stack(pool_k), stack(pool_v), 1, table, lens)
+    out, k2, v2 = ragged_paged_attention(
+        jnp.asarray(q)[:, None], newest(pool_k), newest(pool_v), stack(pool_k), stack(pool_v), 1,
+        jnp.asarray(table), jnp.asarray(lens), jnp.ones_like(lens), interpret=True,
+    )
+    for new, old in ((k2, pool_k), (v2, pool_v)):
+        np.testing.assert_array_equal(np.asarray(new[1, 1:]), old[1:])
+        assert (np.asarray(new[0]) == 1e6).all()
+    return out[:, 0]
 
 
 class TestPagedDecodeAttention:
+    """Decode rows of the ragged kernel (one new token on a live prefix)."""
+
     def _pages_from_contiguous(self, k, v, page):
         """Scatter a contiguous [B,S,NKV,D] cache into a shared page pool
         with a per-sequence page table."""
@@ -206,12 +223,12 @@ class TestPagedDecodeAttention:
         than read out of bounds, and their scores are masked anyway."""
         NH, D, page = 2, 32, 128
         rs = np.random.RandomState(3)
-        pool_k = rs.randn(3, NH, page, D).astype(np.float32)
-        pool_v = rs.randn(3, NH, page, D).astype(np.float32)
+        pool_k = rs.randn(5, NH, page, D).astype(np.float32)
+        pool_v = rs.randn(5, NH, page, D).astype(np.float32)
         q = rs.randn(2, NH, D).astype(np.float32)
         lens = np.array([130, 256], np.int32)
-        valid = np.array([[1, 2, 0, 0], [2, 0, 0, 0]], np.int32)
-        padded = np.array([[1, 2, -1, 99], [2, 0, -1, -1]], np.int32)
+        valid = np.array([[1, 2, 0, 0], [3, 4, 0, 0]], np.int32)
+        padded = np.array([[1, 2, -1, 99], [3, 4, -1, -1]], np.int32)
         out_valid = _paged(q, pool_k, pool_v, valid, lens)
         out_padded = _paged(q, pool_k, pool_v, padded, lens)
         np.testing.assert_allclose(

@@ -1,7 +1,7 @@
 """Paged attention front-end tests (``ops/transformer/paged_attention.py``).
 
-The serving layer depends on three invariants: the XLA gather fallback and
-the Pallas page-table kernel agree, sentinel/garbage table entries past the
+The serving layer depends on three invariants: the XLA scatter + gather form
+and the Pallas page-table kernel agree, sentinel/garbage table entries past the
 live length never leak into outputs, and GQA is computed by grouping —
 never by materializing an NH-wide cache copy.
 """
@@ -14,8 +14,6 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.transformer.paged_attention import (
-    paged_decode_attention,
-    paged_decode_attention_xla,
     paged_prefill_attention,
     ragged_paged_attention,
 )
@@ -59,6 +57,14 @@ def _ragged(q, kp, vp, pt, kv_lens, q_lens, impl):
         np.testing.assert_array_equal(np.asarray(new[1, 1:]), np.asarray(old[1:]))
         assert (np.asarray(new[0]) == 1e6).all()
     return np.asarray(out)
+
+
+def _decode(q, kp, vp, pt, lens, impl):
+    """Decode rows of the ragged entry: one token ``q`` [B, NH, D] a row,
+    the newest of the row's ``lens`` keys (``q_lens == 1``; a row of length
+    0 is dead)."""
+    lens = jnp.asarray(lens, jnp.int32)
+    return _ragged(jnp.asarray(q)[:, None], kp, vp, jnp.asarray(pt), lens, (lens > 0).astype(jnp.int32), impl)[:, 0]
 
 
 def _dense_from_pages(k_pages, page_table, P):
@@ -105,7 +111,7 @@ def test_xla_fallback_matches_reference(nkv):
     pt[1, :1] = [5]
     pt[2, :4] = [2, 9, 4, 8]
     lens = np.array([20, 8, 32], np.int32)
-    out = paged_decode_attention_xla(jnp.asarray(q), _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens)
+    out = _decode(q, kp, vp, pt, lens, "xla")
     ref = _ref_decode(
         q, _dense_from_pages(kp, pt, P), _dense_from_pages(vp, pt, P),
         lens, 1.0 / np.sqrt(D),
@@ -122,9 +128,9 @@ def test_xla_matches_pallas_interpret():
     pt[0, :2] = [4, 2]
     pt[1, :3] = [7, 1, 9]
     lens = np.array([13, 24], np.int32)
-    out_x = paged_decode_attention(q, _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens, impl="xla")
-    out_p = paged_decode_attention(q, _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens, impl="pallas")
-    np.testing.assert_allclose(np.asarray(out_x), np.asarray(out_p), rtol=2e-5, atol=2e-5)
+    out_x = _decode(q, kp, vp, pt, lens, "xla")
+    out_p = _decode(q, kp, vp, pt, lens, "pallas")
+    np.testing.assert_allclose(out_x, out_p, rtol=2e-5, atol=2e-5)
 
 
 def test_zero_length_rows_and_garbage_pages_are_inert():
@@ -134,12 +140,12 @@ def test_zero_length_rows_and_garbage_pages_are_inert():
     kp, vp = _rand_pool(rs, NP, nkv, P, D)
     pt = np.array([[3, -1], [-1, -1]], np.int32)
     lens = np.array([4, 0], np.int32)
-    out = np.asarray(paged_decode_attention_xla(q, _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens))
+    out = _decode(q, kp, vp, pt, lens, "xla")
     assert (out[1] == 0).all()  # dead row: exact zeros (kernel contract)
     # garbage in pages past the live length must not move the output
     kp2 = kp.at[5].set(1e6)
     vp2 = vp.at[5].set(-1e6)
-    out2 = np.asarray(paged_decode_attention_xla(q, _stack(kp2), _stack(vp2), 1, jnp.asarray(pt), lens))
+    out2 = _decode(q, kp2, vp2, pt, lens, "xla")
     np.testing.assert_allclose(out, out2, rtol=1e-6)
 
 
@@ -152,7 +158,7 @@ def test_prefill_chunk_matches_causal_reference():
     start = 3  # chunk positions 3..8: prefix 0..2 already in the pages
     q_pos = np.arange(start, start + T, dtype=np.int32)[None]
     out = paged_prefill_attention(
-        jnp.asarray(q), _stack(kp), _stack(vp), 1, jnp.asarray(pt), jnp.asarray(q_pos)
+        jnp.asarray(q), _stack(kp), _stack(vp), 1, jnp.asarray(pt), jnp.asarray(q_pos), jnp.asarray([start + T])
     )
     k_lin = _dense_from_pages(kp, pt, P)
     v_lin = _dense_from_pages(vp, pt, P)
@@ -392,7 +398,7 @@ def test_gqa_grouped_equals_repeat_expansion():
     kp, vp = _rand_pool(rs, NP, nkv, P, D)
     pt = np.array([[1, 4], [6, -1]], np.int32)
     lens = np.array([12, 5], np.int32)
-    out = paged_decode_attention_xla(jnp.asarray(q), _stack(kp), _stack(vp), 1, jnp.asarray(pt), lens)
+    out = _decode(q, kp, vp, pt, lens, "xla")
     # reference: expand kv to NH heads, per-head attention
     k_lin = _dense_from_pages(kp, pt, P).repeat(NH // nkv, axis=2)
     v_lin = _dense_from_pages(vp, pt, P).repeat(NH // nkv, axis=2)

@@ -40,7 +40,6 @@ from deepspeed_tpu.ops.sparse_attention.pallas_block_sparse import pallas_block_
 from deepspeed_tpu.ops.sparse_attention.sparsity_config import BSLongformerSparsityConfig
 from deepspeed_tpu.ops.transformer.decode_attention import (
     decode_attention,
-    paged_decode_attention,
     ragged_paged_attention,
 )
 from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
@@ -86,10 +85,6 @@ def _ragged(q, k_new, v_new, k_pages, v_pages, table, kv_lens, q_lens):
     return ragged_paged_attention(
         q, k_new, v_new, k_pages, v_pages, 1, table, kv_lens, q_lens, interpret=False
     )
-
-
-def _paged(q, k_pages, v_pages, table, kv_lens):
-    return paged_decode_attention(q, k_pages, v_pages, 1, table, kv_lens, interpret=False)
 
 
 def _dense_decode(q, k_cache, v_cache, kv_lens):
@@ -145,7 +140,6 @@ CASES = {
         }.items()
         for width in (1, 128)
     },
-    "paged_decode_llama_1b": (_paged, [((8, 32, 64), BF16), _PAGES, _PAGES, _TABLE, _LENS]),
     "dense_decode_llama_1b": (
         _dense_decode,
         [((8, 32, 64), BF16), ((8, 2048, 4, 64), BF16), ((8, 2048, 4, 64), BF16), _LENS],

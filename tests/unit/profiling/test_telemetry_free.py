@@ -13,7 +13,6 @@ bench-like step. Checks here:
   imports jax — but the pass proves the programs themselves are unchanged);
 * overhead guard — the measured per-span cost times a generous
   spans-per-step budget is under 2% of a measured bench-like step (the
-  wall-clock A/B rides in ``bench.py`` as ``trace_overhead_pct``; here the
   bound is computed from stable minima so the fast tier never flakes);
 * the merged ``observability()`` report + Perfetto trace for a training
   run (the serving-run counterparts live in test_request_spans.py).

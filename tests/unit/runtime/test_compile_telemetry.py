@@ -104,7 +104,7 @@ def test_retrace_guard_unfused_five_steps(eight_devices):
 
 def test_compile_stats_surface(eight_devices):
     """compile_stats() exposes every instrumented program with the counter
-    fields bench.py and the monitor consume."""
+    fields the monitor consumes."""
     engine = _engine()
     train_steps_micro(engine, step_batch(batch_size=8), 1)
     stats = engine.compile_stats()

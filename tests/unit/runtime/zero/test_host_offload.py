@@ -450,25 +450,3 @@ def test_red_multistep_rejects_legacy_offload_and_offload_param(eight_devices):
     mesh_mod.reset_topology()
     with pytest.raises(ValueError, match="offload_param"):
         ds.initialize(model=SimpleModel(), config=cfg)
-
-
-# ---------------------------------------------------------------------------
-# the bench probe's pure bisection helper
-# ---------------------------------------------------------------------------
-def test_max_params_under_budget_bisection():
-    import sys
-
-    sys.path.insert(0, str(__import__("pathlib").Path(__file__).parents[4]))
-    from bench import _max_params_under_budget
-
-    calls = []
-
-    def fits(i):
-        calls.append(i)
-        return i <= 11
-
-    assert _max_params_under_budget(fits, 0, 31) == 11
-    assert len(calls) <= 7  # log2(32) + the lo probe: bisection, not a sweep
-    assert _max_params_under_budget(lambda i: True, 0, 9) == 9
-    assert _max_params_under_budget(lambda i: False, 0, 9) == -1
-    assert _max_params_under_budget(lambda i: i == 0, 0, 0) == 0

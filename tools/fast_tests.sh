@@ -30,7 +30,7 @@
 # (2 tenants, shared prefix, flood-vs-trickle on a virtual clock),
 # admission control, DS-R007 lint, traffic green sweep.
 # +ragged serving 2026-08-04 (test_ragged_serving.py + extended
-# test_paged_attention.py + analysis compile gate): ragged-vs-bucketed
+# test_paged_attention.py + analysis compile gate): ragged-vs-dense
 # byte-identical streams across admission/preemption/prefix/spec-K-mix/
 # EOS, ≤2-compiled-programs + 1-dispatch-per-step + 3-wave retrace
 # guards, ragged attention kernel parity (XLA fallback + Pallas
@@ -55,7 +55,7 @@
 # DS-R009 lint.
 # +multi-step windows 2026-08-04 (test_multistep_serving.py + extended
 # test_journal_recovery.py + analysis window gate): N-decode-rounds-per-
-# dispatch fused windows — window vs single-step vs bucketed vs dense
+# dispatch fused windows — window vs single-step vs dense
 # byte-identical across EOS-in-window/window-edge/admission-break/
 # preemption/prefix-attach/spec-handoff, steady-state dispatches/token
 # ≤ 1/horizon via telemetry, ≤4-compiled-programs + retrace guards,
@@ -106,8 +106,7 @@
 # knobs on / red overlap verdict with pipeline_write off, host-resident
 # checkpoint snapshot roundtrip + streamed/legacy format guards,
 # train.mid_offload_stream chaos kill → auto_resume bit-identical,
-# legacy cpu_offload* config-routing red tests, bench bisection-probe
-# unit.
+# legacy cpu_offload* config-routing red tests.
 # +static HBM ledger 2026-08-07 (test_memory.py + test_passes.py::
 # test_green_memory_ledger_{offload,tp_serving} ride the lint.sh analysis
 # suite; DS-R011/DS-R012 lint + the --json/--rule CLI ride

@@ -4,7 +4,7 @@
 Usage::
 
     python tools/lint.py                      # lint deepspeed_tpu + tests
-    python tools/lint.py deepspeed_tpu bench.py --format json
+    python tools/lint.py deepspeed_tpu chip_smoke.py --format json
     python tools/lint.py --json               # shorthand for --format json
     python tools/lint.py --rule DS-R011       # only the named rule(s)
 

@@ -7,6 +7,6 @@
 # Wired into tools/fast_tests.sh; also runnable standalone.
 cd "$(dirname "$0")/.." || exit 1
 echo "== tools/lint.sh: repo AST lint =="
-python tools/lint.py deepspeed_tpu tests bench.py || exit 1
+python tools/lint.py deepspeed_tpu tests || exit 1
 echo "== tools/lint.sh: analysis pass suite =="
 python -m pytest -q tests/unit/analysis -p no:cacheprovider || exit 1
