@@ -53,14 +53,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None) -> 
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
-def _layer_project_qkv(cfg: TransformerConfig, p, h):
+def _project_qkv(cfg: TransformerConfig, p, h):
     """Norm + qkv projection for a [B, T, H] slab (same ops as
-    models/transformer.py _layer). Column-parallel under TP serving: the
+    models/transformer.py _layer), the heads not yet split: ``[B, T, NH D]``
+    and twice ``[B, T, NKV D]``. Column-parallel under TP serving: the
     weights arrive pre-sliced by shard_map (cfg is then the LOCAL view),
     and ``qmatmul`` fuses int8 dequantization when the weights are
     quantized (``compression/int8.py``)."""
-    B, T, _ = h.shape
-    NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     hn = _norm(h, p["attn_norm_scale"], p.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
     q = qmatmul(hn, p["wq"])
     k = qmatmul(hn, p["wk"])
@@ -74,11 +73,19 @@ def _layer_project_qkv(cfg: TransformerConfig, p, h):
         # cached is the normed, rotated k
         q = _norm(q, p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps)
         k = _norm(k, p["k_norm_scale"], None, "rmsnorm", cfg.norm_eps)
-    return (
-        q.reshape(B, T, NH, D),
-        k.reshape(B, T, NKV, D),
-        v.reshape(B, T, NKV, D),
-    )
+    return q, k, v
+
+
+def _split_heads(cfg: TransformerConfig, q, k, v):
+    B, T, _ = q.shape
+    NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    return q.reshape(B, T, NH, D), k.reshape(B, T, NKV, D), v.reshape(B, T, NKV, D)
+
+
+def _layer_project_qkv(cfg: TransformerConfig, p, h):
+    """``_project_qkv`` with the heads split: ``[B, T, NH, D]`` and twice
+    ``[B, T, NKV, D]``."""
+    return _split_heads(cfg, *_project_qkv(cfg, p, h))
 
 
 def _moe_ffn(cfg, p, h, live=None, experts=None, group_offset=0):
@@ -704,6 +711,246 @@ def _accepted_prefix(tokens, greedy, n_drafts):
     return jnp.sum(jnp.cumprod(matches.astype(jnp.int32), axis=1), axis=1)
 
 
+DENSE_TOKEN_TILE = 512
+MOE_ROWS_PER_EXPERT = 128
+
+
+def token_tile(cfg) -> int:
+    """Packed tokens in one tile of the ragged step's token-wise work, from
+    the layer's shapes alone; 0 for a model whose step is never tiled.
+
+    A wide window (``rows x width`` slots above this number) is compacted and
+    everything that is a function of one token runs tile by tile over the
+    live tokens only (``_paged_layers``). Every tile streams the layer's
+    weights again, so the tile is the smallest whose arithmetic hides that
+    stream with room to spare: a v5e multiplies 197e12 / 819e9 = 240 rows in
+    the time it takes to stream the matrix they meet, and at twice that, 512,
+    a full window's four tiles cost what its slab does (+4%, measured:
+    ``tools/mixed_step_bench.py``, PERF.md PR 30), where 256 rows neither hide
+    the stream nor are hidden by it (a tile a fifth cheaper, a full window
+    +11%). A dropless routed FFN spreads a tile's ``tile x k`` assignments
+    over E experts, and an expert's matrices are streamed for however few rows
+    reach it, so its tile is the one that brings each expert a whole row tile
+    of the grouped matmul on average (``128 E / k``: 1,024 tokens for OLMoE's 8
+    of 64; at 512 a full window reads every expert six times for the slab's
+    three), never below the dense tile. A capacity-routed MoE
+    (``moe_drop_tokens``) is not tiled: an expert's capacity is a function of
+    the window's slot count, so a tile would drop other tokens than the window
+    does."""
+    if getattr(cfg, "num_experts", 0) and getattr(cfg, "moe_top_k", 0):
+        if cfg.moe_drop_tokens:
+            return 0
+        per_expert = -(-MOE_ROWS_PER_EXPERT * cfg.num_experts // cfg.moe_top_k)
+        return max(DENSE_TOKEN_TILE, -(-per_expert // DENSE_TOKEN_TILE) * DENSE_TOKEN_TILE)
+    return DENSE_TOKEN_TILE
+
+
+def token_tiles(cfg, rows: int, width: int, live_tokens: int) -> int:
+    """Tiles the ``rows x width`` ragged program runs for ``live_tokens``
+    live tokens: 0 where the window is at most one tile (such a program
+    computes its whole slab), else ``ceil(live_tokens / token_tile(cfg))``.
+    The scheduler's ``serve.pack`` counts with it."""
+    tile = token_tile(cfg)
+    if not tile or rows * width <= tile:
+        return 0
+    return -(-int(live_tokens) // tile)
+
+
+class _Packed(NamedTuple):
+    """A ragged window's live tokens, row after row, at the front of a
+    buffer of whole tiles."""
+
+    tile: int
+    n_tiles: jax.Array  # int32 scalar: ceil(live tokens / tile)
+    slot: jax.Array  # [NP] int32: packed index -> flat slab slot (any slot past the live tokens)
+    index: jax.Array  # [B, T] int32: slab slot -> packed index (any index for a dead slot)
+    live: jax.Array  # [NP] bool
+
+    def tiles(self, body, init):
+        """``body(start, carry) -> carry`` over the live tiles' first packed
+        indices: the trip count is data, the body is traced once."""
+        return jax.lax.fori_loop(
+            0, self.n_tiles, lambda t, carry: body(jax.lax.mul(t, jnp.int32(self.tile)), carry), init
+        )
+
+    def take(self, packed, start):
+        return jax.lax.dynamic_slice_in_dim(packed, start, self.tile, axis=0)
+
+    def expand(self, packed):
+        """[NP, ...] -> [B, T, ...]: every live slot's own packed row."""
+        return jnp.take(packed, self.index, axis=0, mode="clip")
+
+
+def _pack_window(q_lens, B: int, T: int, tile: int) -> _Packed:
+    """From ``q_lens`` alone: slot (r, j), ``j < q_lens[r]``, is packed token
+    ``cumsum(q_lens)[r] - q_lens[r] + j``."""
+    n_packed = -(-B * T // tile) * tile
+    q = jnp.asarray(q_lens, jnp.int32)
+    ends = jnp.cumsum(q)
+    starts = ends - q
+    i = jnp.arange(n_packed, dtype=jnp.int32)
+    row = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), B - 1)
+    slot = jnp.clip(row * T + i - starts[row], 0, B * T - 1)
+    index = jnp.minimum(starts[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :], n_packed - 1)
+    return _Packed(tile, (ends[-1] + (tile - 1)) // tile, slot, index, i < ends[-1])
+
+
+def _split_expert_stacks(cfg, layers):
+    """A dropless MoE model's expert stacks stay out of the per-layer
+    weights, like the pools: seen as one stack of L x E experts, the grouped
+    matmul reaches its layer's through an offset, and nothing copies a
+    layer's experts out of the stack first. Returns (layers without them,
+    the stacks or None)."""
+    if "moe" not in layers or cfg.moe_drop_tokens:
+        return layers, None
+    stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), layers["moe"]["experts"])
+    return {**layers, "moe": {k: v for k, v in layers["moe"].items() if k != "experts"}}, stacks
+
+
+def _bound_moe_ffn(cfg, live, expert_stacks, layer):
+    moe_ffn = functools.partial(_moe_ffn, live=live)
+    if expert_stacks is not None:
+        moe_ffn = functools.partial(moe_ffn, experts=expert_stacks, group_offset=layer * cfg.num_experts)
+    return moe_ffn
+
+
+def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b, attn_impl,
+                  *, prefill_kv_lens, ragged_q_lens, tp=None):
+    """Embedding and layers of the ragged step (``_paged_forward`` has the
+    contract). A window of at most one token tile (``token_tile``; a test of
+    shapes: the width-1 program, a verify width, every multi-step window) is
+    computed as the ``[B, T]`` slab it is. A wider one is computed over its
+    LIVE tokens only, inside the one program:
+
+    * the live tokens and their positions are packed to the front of a
+      ``[B T, H]`` buffer (``_pack_window``: a few int32 ops and one gather of
+      the embedding);
+    * whatever is a function of one token runs in ``fori_loop``s over
+      ``ceil(live / tile)`` tiles of packed tokens, each body traced once at
+      ``[1, tile, ...]``: norm, q/k/v projection, QK-norm and RoPE before the
+      attention call; output projection, residuals and the MLP or the routed
+      FFN after it. A tile without a live token is never run; the last tile's
+      tail is masked as dead slots are (``live`` for the router, no page for
+      k/v);
+    * ``ragged_paged_attention`` keeps its ``[R, W, ...]`` windows and is
+      called once a layer, outside the loops: the packed q, k, v are expanded
+      to their slots by a gather (a live slot reads its own packed row; what a
+      dead slot reads is never attended nor kept), and the post-attention loop
+      gathers its tile's rows of the kernel's output.
+
+    The per-layer weights are indexed out of their stacks INSIDE the tile
+    bodies (the layer scan carries an index, not slices): a slice made in
+    the scan's body would be copied to be handed to the inner loop, a layer's
+    weights read and written once more a layer.
+    Returns ``(x, new_k, new_v, moe_counts, packed)``: ``x`` is the slab
+    ``[B, T, H]`` and ``packed`` None, or ``x`` is the packed ``[NP, H]`` and
+    ``packed`` says where its rows belong."""
+    from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
+
+    B, T = tokens.shape
+    dtype = k_pages.dtype
+    scale = _softmax_scale(cfg, cfg.head_dim)
+    tile = token_tile(cfg)
+
+    def embed(tokens, positions):
+        x = params["embed"]["tokens"].astype(dtype)[tokens]
+        if cfg.position == "learned":
+            x = x + params["embed"]["pos"].astype(dtype)[positions]
+        return x
+
+    def project(p, x, positions, keep_apart=False):
+        q, k_new, v_new = _project_qkv(cfg, p, x)
+        if keep_apart:
+            # or the compiler folds the head split into the matmul, wants the
+            # weights transposed for it, and copies their whole STACK to that
+            # layout once a layer on its way into the tile loop
+            q, k_new, v_new = jax.lax.optimization_barrier((q, k_new, v_new))
+        q, k_new, v_new = _split_heads(cfg, q, k_new, v_new)
+        if cfg.position == "rope":
+            q = _rope(q, positions, cfg.rope_theta, cfg.rope_dim)
+            k_new = _rope(k_new, positions, cfg.rope_theta, cfg.rope_dim)
+        return q, k_new, v_new
+
+    def attend(q, k_new, v_new, kp, vp, layer):
+        return ragged_paged_attention(
+            q, k_new, v_new, kp, vp, layer, page_table, prefill_kv_lens,
+            ragged_q_lens, scale=scale, impl=attn_impl,
+        )
+
+    # named scopes (here, in ``_post_attention``, ``_ffn_body`` and
+    # ``_final_logits``) put the region into every op's name stack, where a
+    # profiler trace reads it: ``attention``, ``mlp``, ``head_sample``.
+    # Names only, nothing computed differently.
+    if not tile or B * T <= tile:
+        x = embed(tokens, positions_b)
+        live = jnp.arange(T, dtype=jnp.int32)[None, :] < ragged_q_lens[:, None]
+        layers, expert_stacks = _split_expert_stacks(cfg, params["layers"])
+
+        def layer_step(carry, p):
+            x, kp, vp, layer = carry
+            with jax.named_scope("attention"):
+                q, k_new, v_new = project(p, x, positions_b)
+                attn, kp, vp = attend(q, k_new, v_new, kp, vp, layer)
+            x, moe_counts = _post_attention(
+                cfg, p, x, attn, tp=tp, moe_ffn=_bound_moe_ffn(cfg, live, expert_stacks, layer)
+            )
+            return (x, kp, vp, layer + 1), moe_counts
+
+        (x, new_k, new_v, _), moe_counts = jax.lax.scan(
+            layer_step, (x, k_pages, v_pages, jnp.int32(0)), layers
+        )
+        return x, new_k, new_v, moe_counts, None
+
+    packed = _pack_window(ragged_q_lens, B, T, tile)
+    positions = jnp.take(positions_b.reshape(-1), packed.slot, mode="clip")
+    x = embed(jnp.take(tokens.reshape(-1), packed.slot, mode="clip"), positions)
+    layers, expert_stacks = _split_expert_stacks(cfg, params["layers"])
+    NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+
+    def layer_step(carry, layer):
+        x, kp, vp = carry
+
+        def weights(start):
+            # tied to the tile, or the compiler hoists the slices out of the tile loop
+            stacks, _ = jax.lax.optimization_barrier((layers, start))
+            return jax.tree_util.tree_map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False), stacks)
+
+        def before(start, qkv):
+            q, k_new, v_new = project(
+                weights(start), packed.take(x, start)[None], packed.take(positions, start)[None], keep_apart=True
+            )
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(buf, new[0].astype(dtype).reshape(packed.tile, -1), start, axis=0)
+                for buf, new in zip(qkv, (q, k_new, v_new))
+            )
+
+        with jax.named_scope("attention"):
+            qkv = packed.tiles(before, tuple(jnp.zeros((x.shape[0], n * D), dtype) for n in (NH, NKV, NKV)))
+            attn, kp, vp = attend(
+                *(packed.expand(a).reshape(B, T, n, D) for a, n in zip(qkv, (NH, NKV, NKV))), kp, vp, layer
+            )
+            attn = attn.reshape(B * T, NH, D)
+
+        def after(start, carry):
+            x, counts = carry
+            with jax.named_scope("attention"):
+                attn_tile = jnp.take(attn, packed.take(packed.slot, start), axis=0, mode="clip")[None]
+            moe_ffn = _bound_moe_ffn(cfg, packed.take(packed.live, start)[None], expert_stacks, layer)
+            x_tile, tile_counts = _post_attention(
+                cfg, weights(start), packed.take(x, start)[None], attn_tile, tp=tp, moe_ffn=moe_ffn
+            )
+            x = jax.lax.dynamic_update_slice_in_dim(x, x_tile[0], start, axis=0)
+            return x, (None if tile_counts is None else counts + tile_counts)
+
+        x, moe_counts = packed.tiles(after, (x, jnp.zeros((cfg.num_experts,), jnp.int32) if "moe" in layers else None))
+        return (x, kp, vp), moe_counts
+
+    (x, new_k, new_v), moe_counts = jax.lax.scan(
+        layer_step, (x, k_pages, v_pages), jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    )
+    return x, new_k, new_v, moe_counts, packed
+
+
 def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
                    _unused, attn_impl, *, prefill_kv_lens, ragged_q_lens, tp=None):
     """Forward a ragged ``[B, T]`` window of tokens against the paged cache
@@ -728,53 +975,40 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
     the Pallas path the fused kernel is the only operation applied to them
     (aliased in → out), which also leaves their layout to nobody but the
     kernel.
+    Only the attention kernel sees every slot of the window: a window wider
+    than one token tile has its token-wise work done over its live tokens
+    only (``_paged_layers``), and this entry lays the result back on the
+    slab for the head (a dead slot's logits are then some live token's: the
+    caller ignores them). ``build_ragged_step`` takes its arg-max on the
+    packed tiles instead.
     An MoE model routes only the window's live tokens and hands back its
     per-layer, per-expert assignment counts.
     Returns (logits [B, T, V], new_k_pages, new_v_pages, moe_counts [L, E]
     or None for a dense model)."""
-    from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
-
-    T = tokens.shape[1]
-    dtype = k_pages.dtype
-    x = params["embed"]["tokens"].astype(dtype)[tokens]
-    if cfg.position == "learned":
-        x = x + params["embed"]["pos"].astype(dtype)[positions_b]
-    scale = _softmax_scale(cfg, cfg.head_dim)
-    live = jnp.arange(T, dtype=jnp.int32)[None, :] < ragged_q_lens[:, None]
-    # a dropless MoE model's expert stacks stay out of the scanned per-layer
-    # weights, like the pools: seen as one stack of L x E experts, the grouped
-    # matmul reaches its layer's through an offset, and nothing copies a
-    # layer's experts out of the stack first
-    layers, expert_stacks = params["layers"], None
-    if "moe" in layers and not cfg.moe_drop_tokens:
-        expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), layers["moe"]["experts"])
-        layers = {**layers, "moe": {k: v for k, v in layers["moe"].items() if k != "experts"}}
-
-    # named scopes (here, in ``_post_attention``, ``_ffn_body`` and
-    # ``_final_logits``) put the region into every op's name stack, where a
-    # profiler trace reads it: ``attention``, ``mlp``, ``head_sample``.
-    # Names only, nothing computed differently.
-    def layer_step(carry, p):
-        x, kp, vp, layer = carry
-        with jax.named_scope("attention"):
-            q, k_new, v_new = _layer_project_qkv(cfg, p, x)
-            if cfg.position == "rope":
-                q = _rope(q, positions_b, cfg.rope_theta, cfg.rope_dim)
-                k_new = _rope(k_new, positions_b, cfg.rope_theta, cfg.rope_dim)
-            attn, kp, vp = ragged_paged_attention(
-                q, k_new, v_new, kp, vp, layer, page_table, prefill_kv_lens,
-                ragged_q_lens, scale=scale, impl=attn_impl,
-            )
-        moe_ffn = functools.partial(_moe_ffn, live=live)
-        if expert_stacks is not None:
-            moe_ffn = functools.partial(moe_ffn, experts=expert_stacks, group_offset=layer * cfg.num_experts)
-        x, moe_counts = _post_attention(cfg, p, x, attn, tp=tp, moe_ffn=moe_ffn)
-        return (x, kp, vp, layer + 1), moe_counts
-
-    (x, new_k, new_v, _), moe_counts = jax.lax.scan(
-        layer_step, (x, k_pages, v_pages, jnp.int32(0)), layers
+    x, new_k, new_v, moe_counts, packed = _paged_layers(
+        cfg, params, tokens, k_pages, v_pages, page_table, positions_b, attn_impl,
+        prefill_kv_lens=prefill_kv_lens, ragged_q_lens=ragged_q_lens, tp=tp,
     )
+    if packed is not None:
+        x = packed.expand(x)
     return _final_logits(cfg, params, x), new_k, new_v, moe_counts
+
+
+def _argmax(logits, tp):
+    return tp.argmax(logits) if tp is not None else jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _packed_greedy(cfg, params, x, packed: _Packed, tp=None):
+    """Final norm, head and arg-max of the packed ``x`` [NP, H], tile by live
+    tile, laid back on the slab: ``[B, T]`` int32. ``[B, T, V]`` logits never
+    exist; a dead slot reads any token."""
+
+    def head(start, greedy):
+        logits = _final_logits(cfg, params, packed.take(x, start)[None])
+        with jax.named_scope("head_sample"):
+            return jax.lax.dynamic_update_slice_in_dim(greedy, _argmax(logits, tp)[0], start, axis=0)
+
+    return packed.expand(packed.tiles(head, jnp.zeros((x.shape[0],), jnp.int32)))
 
 
 def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: int,
@@ -843,10 +1077,7 @@ def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: 
                 run_cfg, params, tok[:, None], kp, vp, page_table, lens[:, None],
                 None, attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
             )
-            nxt = (
-                tp.argmax(logits[:, -1, :]) if tp is not None
-                else jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            )
+            nxt = _argmax(logits[:, -1, :], tp)
             out_tok = jnp.where(alive, nxt, -1)
             emitted = emitted + q_lens
             lens = lens + q_lens
@@ -923,6 +1154,13 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
     compiles at most two widths of this program (decode/verify width and
     the mixed width covering prefill chunks) for an entire serve.
 
+    ``width`` sets the window the kernel sees, not the step's cost: a window
+    wider than one token tile (``token_tile``) has everything but attention
+    computed over its live tokens only, in tiles whose count is data
+    (``_paged_layers``), the arg-max taken on the packed tiles
+    (``_packed_greedy``) and 2,048 int32 laid back on the slab: ``[R, W, V]``
+    logits never exist. A narrower window is computed as the slab it is.
+
     With ``tp`` (a ``inference/tp.py:TPServing``) the SAME body runs under
     ``shard_map`` on the tensor-parallel mesh: weights and kv pages ride
     in sharded (column/row-parallel projections, kv-head-sliced pools),
@@ -948,15 +1186,17 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
         offs = jnp.arange(W, dtype=jnp.int32)
         positions_b = lengths[:, None] + offs[None, :]
         kv_lens = jnp.where(q_lens > 0, lengths + q_lens, 0)
-        logits, new_k, new_v, moe_counts = _paged_forward(
+        x, new_k, new_v, moe_counts, tiles = _paged_layers(
             run_cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
-            None, attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
+            attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
         )
+        if tiles is None:
+            logits = _final_logits(run_cfg, params, x)
+            with jax.named_scope("head_sample"):
+                greedy = _argmax(logits, tp)  # [R, W]
+        else:
+            greedy = _packed_greedy(run_cfg, params, x, tiles, tp)
         with jax.named_scope("head_sample"):
-            greedy = (
-                tp.argmax(logits) if tp is not None
-                else jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            )  # [R, W]
             # verify resolution (inert elsewhere: decode rows have no drafts and
             # prefill rows' accepted count is ignored by the host)
             accepted = _accepted_prefix(tokens, greedy, q_lens - 1)

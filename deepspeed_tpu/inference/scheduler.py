@@ -75,6 +75,7 @@ from deepspeed_tpu.inference.decode import (
     build_ragged_step,
     multistep_program_name,
     ragged_program_name,
+    token_tiles,
 )
 from deepspeed_tpu.inference.journal import JournaledRequest, RequestJournal
 from deepspeed_tpu.inference.kv_pool import PagePool
@@ -382,6 +383,11 @@ class PagedServer:
                 "admission": 0, "prefill": 0, "draft": 0, "eos": 0,
                 "budget": 0, "pool": 0,
             },
+            # the mixed-width dispatches, the live tokens they carried and the
+            # token tiles the program ran for them (decode.token_tiles)
+            "mixed_steps": 0,
+            "mixed_live_tokens": 0,
+            "mixed_token_tiles": 0,
             "decode_steps": 0,  # dispatches that carried a plain decode row
             "spec_rounds": 0,  # dispatches that carried a drafted row
             "spec_drafted": 0,  # draft tokens sent to verification
@@ -840,7 +846,11 @@ class PagedServer:
         ride in as arrays. A chunk row no longer steals a step from
         decoders (they share the dispatch), spec-K varies freely per row,
         and only the WIDTH can differ between steps (narrow decode/verify
-        vs chunk-covering mixed), bounding compiled programs at 2."""
+        vs chunk-covering mixed), bounding compiled programs at 2. The
+        width is the window the attention kernel sees; what the wide
+        program computes besides follows the step's live tokens, in token
+        tiles (``decode.token_tiles``: ``serve.pack``'s ``live_tokens`` and
+        ``token_tiles``, summed over mixed steps in ``stats``)."""
         rows = [r for r in self._active if not r.done]
         if not rows:
             return
@@ -865,11 +875,8 @@ class PagedServer:
             rows = self._reserve_for_growth(rows, need)
             if not rows:
                 return
-            W = (
-                self._ragged_w_mixed
-                if any(r.pending is None for r in rows)
-                else self._ragged_w_decode
-            )
+            mixed = any(r.pending is None for r in rows)
+            W = self._ragged_w_mixed if mixed else self._ragged_w_decode
             # pad to the single fixed row budget — never re-bucketed; lengths
             # == consumed for prefill rows, so one write base serves every mode
             R = self.pool.max_slots
@@ -890,9 +897,18 @@ class PagedServer:
             # pages the ragged kernel walks a layer (the live rows' own) of the
             # table slots a grid over the whole table would visit
             kv_pages = int((-(-(lengths + q_lens)[q_lens > 0] // self.pool.page_size)).sum())
+            # what the program computes: its live tokens, in that many token
+            # tiles (0: a window of one tile at most, computed whole)
+            live_tokens = int(q_lens.sum())
+            tiles = token_tiles(self.cfg, R, W, live_tokens)
             pack_span.set(
-                rows=len(rows), width=W, program=program, kv_pages=kv_pages, table_pages=page_table.size
+                rows=len(rows), width=W, program=program, kv_pages=kv_pages, table_pages=page_table.size,
+                live_tokens=live_tokens, token_tiles=tiles,
             )
+            if mixed:
+                self.stats["mixed_steps"] += 1
+                self.stats["mixed_live_tokens"] += live_tokens
+                self.stats["mixed_token_tiles"] += tiles
         # dispatch = build + ENQUEUE only (jit returns futures; the fetch
         # below is where device time surfaces)
         with self.tracer.span("serve.dispatch", rows=len(rows), width=W, program=program):
@@ -1302,6 +1318,11 @@ class PagedServer:
         s["dispatches_per_token"] = (
             s["dispatches"] / s["emitted_tokens"] if s["emitted_tokens"] else 0.0
         )
+        # a mixed step's live tokens and token tiles, on average: 1.0 tiles
+        # means every mixed step cost one tile of the weights
+        mixed = max(s["mixed_steps"], 1)
+        s["mixed_tokens_per_step"] = s["mixed_live_tokens"] / mixed
+        s["mixed_tiles_per_step"] = s["mixed_token_tiles"] / mixed
         # tensor-parallel serving: the sharding degree this server runs at
         # (1 = single-chip) and whether the row-parallel all-reduces are
         # EQuARX-quantized — fleet observability keys on these

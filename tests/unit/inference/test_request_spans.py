@@ -149,7 +149,9 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
     assert dispatch[3] == {"rows": 3, "width": 8, "program": program}
     # three first chunks of at most a page each, of a table of max_slots x max_pages_per_slot slots
     table_pages = server.pool.max_slots * server.pool.max_pages_per_slot
-    assert pack[3] == {**dispatch[3], "kv_pages": 3, "table_pages": table_pages}
+    # and what the program computes: the chunks' tokens, in no token tile (a window of one tile at most)
+    live_tokens = sum(min(p.size, 8) for p in _prompts(3, seed=12))
+    assert pack[3] == {**dispatch[3], "kv_pages": 3, "table_pages": table_pages, "live_tokens": live_tokens, "token_tiles": 0}
     assert admit[2] <= pack[1] and pack[2] <= dispatch[1] and dispatch[2] <= emit[1]
     (fetch,), (settle,) = inside(emit, "serve.fetch"), inside(emit, "serve.settle")
     assert fetch[2] <= settle[1]

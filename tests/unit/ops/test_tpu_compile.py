@@ -165,6 +165,35 @@ _MISTRAL_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mistral-7
 _PLUMBING = {"parameter", "tuple", "get-tuple-element", "while", "bitcast"}  # no bytes move
 
 
+def _compiled_mistral_step(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the Mistral cells' shapes (16 rows, 609 pages
+    of 64), compiled for the described chip: (config, the cell's ``paged_kv``,
+    the abstract params and pool it was lowered with, the executable)."""
+    monkeypatch.setattr(
+        sys.modules["deepspeed_tpu.ops.transformer.decode_attention"], "on_tpu", lambda: True
+    )
+    conf = json.loads(_MISTRAL_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = TransformerConfig(**{**conf["model"]["kwargs"], "max_seq_len": paged["max_seq_len"]})
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(
+        lambda: TransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))
+    )
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
+    pool = on_v5e((cfg.num_layers, rows * maxp + 1, cfg.num_kv_heads, page, cfg.head_dim), BF16)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), pool, pool, on_v5e((rows, maxp), I32),
+        on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    return cfg, paged, params, pool, compiled
+
+
 @pytest.mark.parametrize("width", [1, 128])
 def test_ragged_step_keeps_the_pool_in_one_buffer(v5e, monkeypatch, width):
     """``build_ragged_step`` at the Mistral cells' shapes (16 rows, 609 pages
@@ -175,30 +204,9 @@ def test_ragged_step_keeps_the_pool_in_one_buffer(v5e, monkeypatch, width):
     program's temporaries are smaller than one pool. The layer loop carries the pools and the fused kernel is the
     only operation on them; a slice, a scatter or a layout copy of the pool
     would show here."""
-    monkeypatch.setattr(
-        sys.modules["deepspeed_tpu.ops.transformer.decode_attention"], "on_tpu", lambda: True
-    )
-    conf = json.loads(_MISTRAL_CELL.read_text())
-    paged = conf["engine"]["init_inference"]["paged_kv"]
-    cfg = TransformerConfig(**{**conf["model"]["kwargs"], "max_seq_len": paged["max_seq_len"]})
-    rows, page = paged["max_slots"], paged["page_size"]
-    maxp = paged["max_seq_len"] // page
-    n_pages = rows * maxp + 1
+    cfg, paged, params, pool, compiled = _compiled_mistral_step(v5e, monkeypatch, width)
+    page, n_pages = paged["page_size"], pool.shape[1]
     assert (n_pages, width in (1, paged["prefill_chunk"])) == (609, True)
-
-    def on_v5e(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    params = jax.eval_shape(
-        lambda: TransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))
-    )
-    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
-    pool = on_v5e((cfg.num_layers, n_pages, cfg.num_kv_heads, page, cfg.head_dim), BF16)
-    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
-    compiled = step.lower(
-        params, on_v5e((rows, width), I32), pool, pool, on_v5e((rows, maxp), I32),
-        on_v5e((rows,), I32), on_v5e((rows,), I32),
-    ).compile()
     text = compiled.as_text()
 
     first_pool = len(jax.tree_util.tree_leaves(params)) + 1  # after the params and the tokens
@@ -222,6 +230,27 @@ def test_ragged_step_keeps_the_pool_in_one_buffer(v5e, monkeypatch, width):
     assert not strangers, f"pool-shaped results outside the kernel: {strangers}"
     pool_bytes = int(np.prod(pool.shape)) * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+def test_mixed_step_computes_token_tiles_not_the_slab(v5e, monkeypatch):
+    """``paged_ragged_r16_w128`` at the Mistral cells' shapes computes its
+    token-wise work in tiles of ``decode.token_tile`` packed tokens: the
+    compiled program holds one ``tpu_custom_call`` (the layer loop's one
+    ragged kernel), no instruction whose result is the slab's MLP
+    intermediate (``[16,128,14336]`` or ``[2048,14336]``) but the tile's, and
+    its temporaries are smaller than one weight matrix: neither a layer's
+    slices nor a stack in another layout are copied on their way into the tile
+    loops (both were, in this PR's first drafts: 470 MB and 768 MB a layer)."""
+    cfg, paged, _, _, compiled = _compiled_mistral_step(v5e, monkeypatch, 128)
+    rows, width, inner = paged["max_slots"], paged["prefill_chunk"], cfg.intermediate_size
+    tile = decode.token_tile(cfg)
+    assert (width, rows * width > tile) == (128, True)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"[{tile},{inner}]" in text, "the MLP is not computed a tile at a time"
+    for slab in (f"[{rows},{width},{inner}]", f"[{rows * width},{inner}]"):
+        assert slab not in text, f"an instruction of the whole slab's shape {slab}"
+    assert compiled.memory_analysis().temp_size_in_bytes < cfg.hidden_size * inner * 2
 
 
 _OLMOE_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/olmoe-1b-7b-0125-l12.json"
