@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.compression.int8 import qmatmul
-from deepspeed_tpu.models.config import TransformerConfig
+from deepspeed_tpu.models.config import TransformerConfig, has_state_layers
 from deepspeed_tpu.models.transformer import _norm, _rope
 
 NEG_INF_F = -1e30  # additive mask for dead beams (finite: keeps fp math NaN-free)
@@ -45,6 +45,7 @@ class KVCache(NamedTuple):
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None) -> KVCache:
+    _refuse_state_layers(cfg, "generate() / beam_generate() (the dense KVCache)")
     if dtype is None:
         dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}[
             cfg.dtype
@@ -304,6 +305,18 @@ def _cfg_key(cfg) -> Tuple:
 _decoder_cache: Dict[Tuple, Tuple] = {}
 
 
+def _refuse_state_layers(cfg, what: str) -> None:
+    """Keys and values are the only state ``what`` knows of. A model with
+    recurrent-state layers (``layer_types`` naming ``linear``) is refused
+    where it is built, with the missing piece named."""
+    if has_state_layers(cfg):
+        raise NotImplementedError(
+            f"{what} does not support a model with recurrent-state (linear-attention) layers: it would need "
+            "a snapshot of each row's recurrent state and convolution tail beside its keys and values, "
+            "which only the paged server's per-slot state store keeps (serve through init_inference(...).serve())"
+        )
+
+
 def _jit(fn, telemetry, name, **jit_kwargs):
     """jax.jit, counted under ``name`` when a CompileTelemetry is given —
     the engines' compile_stats() path (profiling/compile_telemetry.py)."""
@@ -325,6 +338,7 @@ def build_decoder(cfg: TransformerConfig, telemetry=None) -> Tuple[Any, Any]:
     ``decode_step(params, token, cache, pos)`` appends one token [B].
     Both donate the cache buffer (in-place workspace update).
     """
+    _refuse_state_layers(cfg, "generate() / beam_generate() (the dense KVCache)")
     key = (_cfg_key(cfg), _telemetry_uid(telemetry))
     if key in _decoder_cache:
         return _decoder_cache[key]
@@ -736,10 +750,18 @@ def token_tile(cfg) -> int:
     three), never below the dense tile. A capacity-routed MoE
     (``moe_drop_tokens``) is not tiled: an expert's capacity is a function of
     the window's slot count, so a tile would drop other tokens than the window
-    does."""
+    does. A model that holds a SHARE of the experts its router chooses from
+    (``moe_router_experts`` above ``num_experts``) takes the dense tile: of a
+    tile's ``tile x k`` assignments only the held share arrives, so the tile
+    that would bring a held expert a whole row tile is that of the uncut layer
+    (5,120 tokens for 8 of 320), 25 times the ~200 live tokens of a steady mixed
+    step, whose dense work it would multiply: 1,746 tokens/s at 5,120, 2,505 at
+    1,024, 2,707 at 512 (v5e, 40 of 320 experts held, PERF.md PR 31)."""
     if getattr(cfg, "num_experts", 0) and getattr(cfg, "moe_top_k", 0):
         if cfg.moe_drop_tokens:
             return 0
+        if (getattr(cfg, "moe_router_experts", None) or cfg.num_experts) > cfg.num_experts:
+            return DENSE_TOKEN_TILE
         per_expert = -(-MOE_ROWS_PER_EXPERT * cfg.num_experts // cfg.moe_top_k)
         return max(DENSE_TOKEN_TILE, -(-per_expert // DENSE_TOKEN_TILE) * DENSE_TOKEN_TILE)
     return DENSE_TOKEN_TILE
@@ -1052,6 +1074,7 @@ def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: 
     """
     if cfg.position == "alibi":
         raise NotImplementedError("paged serving does not support alibi attention biases")
+    _refuse_state_layers(cfg, "a multi-step window (paged_kv.multi_step)")
     if width != 1:
         raise ValueError(f"multi-step windows run plain decode only (width 1), got {width}")
     if rows < 1 or horizon < 2:
@@ -1180,6 +1203,15 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
     if fn is not None:
         return fn
     W = int(width)
+    if getattr(cfg, "layer_types", None):
+        # layers of more than one kind: another program, with the state store's
+        # buffers beside the pages. Chosen here, when the program is built: a
+        # uniform model's step below is traced as it always was
+        if tp is not None:
+            raise NotImplementedError("tensor-parallel serving of a hybrid (multi-kind) layer stack is not supported")
+        from deepspeed_tpu.inference.hybrid_decode import build_hybrid_ragged_step
+
+        return build_hybrid_ragged_step(cfg, rows, W, page_size, attn_impl, telemetry, name, key)
     run_cfg = cfg if tp is None else tp.local_cfg(cfg)
 
     def _step(params, tokens, k_pages, v_pages, page_table, lengths, q_lens):

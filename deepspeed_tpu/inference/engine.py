@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig, DtypeEnum
+from deepspeed_tpu.models.config import has_state_layers
 from deepspeed_tpu.models.transformer import TransformerLM
 from deepspeed_tpu.parallel.mesh import get_topology
 from deepspeed_tpu.profiling.compile_telemetry import CompileTelemetry
@@ -615,6 +616,16 @@ class InferenceEngine:
                     kind="kv_pool",
                     detail=rep,
                 )
+                if "state_total_bytes" in rep:
+                    # a hybrid model's second cache: recurrent states and
+                    # convolution tails, one entry a slot (kv_pool.StateStore)
+                    ledger.add_persistent(
+                        "recurrent_state",
+                        per_chip_bytes=rep["state_total_bytes"],
+                        global_bytes=rep["state_total_bytes"],
+                        kind="kv_pool",
+                        detail={k: v for k, v in rep.items() if k.startswith("state_")},
+                    )
                 ledger.add_persistent(
                     "kv_page_tables",
                     per_chip_bytes=rep["host_table_bytes"],
@@ -692,6 +703,13 @@ class InferenceEngine:
             from deepspeed_tpu.compression.int8 import quantize_params_int8
 
             params = quantize_params_int8(params)
+        prefix_cache = pcfg.prefix_cache
+        if has_state_layers(self._ds_config) and "prefix_cache" not in pcfg.model_fields_set:
+            # the default is on; a model with recurrent-state layers cannot
+            # attach a cached prefix (no state snapshot at that position), so
+            # the default is off for it. Asked for by name, it is refused
+            log_dist("paged_kv.prefix_cache defaults to off for a model with recurrent-state layers", ranks=[0])
+            prefix_cache = False
         server = PagedServer(
             self._ds_config,
             params,
@@ -704,7 +722,7 @@ class InferenceEngine:
             dtype=self.dtype,
             telemetry=self._telemetry,
             spec_decode=self._config.spec_decode,
-            prefix_cache=pcfg.prefix_cache,
+            prefix_cache=prefix_cache,
             multi_step=pcfg.multi_step,
             journal=journal,
             tracer=self.tracer,

@@ -148,7 +148,9 @@ def init_paged_cache(
     so per-chip KV HBM is ``hbm_bytes() / tp``."""
     if dtype is None:
         dtype = _DTYPES[cfg.dtype]
-    shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size, cfg.head_dim)
+    # a model with layers of more than one kind pages its softmax layers only
+    layers = cfg.layers_of("softmax") if getattr(cfg, "layer_types", None) else cfg.num_layers
+    shape = (layers, num_pages, cfg.num_kv_heads, page_size, cfg.head_dim)
     if sharding is not None:
         # allocate DIRECTLY sharded: a full-size zeros + device_put would
         # transiently commit the whole pool to one chip — tp× the
@@ -162,6 +164,31 @@ def init_paged_cache(
     else:
         k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
     return PagedKVCache(k_pages=k, v_pages=v)
+
+
+class StateStore(NamedTuple):
+    """The second kind of cache: what a model's recurrent-state layers keep of
+    a row, whatever its length. One entry a SLOT (the pool's slots are the
+    index; entry ``max_slots`` belongs to nobody and takes dead rows' writes),
+    donated into every serving program beside the pages and returned in
+    place. Nothing here is allocated or freed: a slot's entry is simply
+    restarted from zero by the program when a row's window begins at position
+    0 (``inference/hybrid_decode.py``)."""
+
+    state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32
+    conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3 NH D]
+
+    def hbm_bytes(self) -> int:
+        return self.state.nbytes + self.conv.nbytes
+
+
+def _refuse_with_state(states, what: str) -> None:
+    if states is not None and states.state.size:
+        raise NotImplementedError(
+            f"{what} is not supported for a model with recurrent-state layers: keys and values can be shared, "
+            "copied or rolled back a page at a time, the recurrent state of a row cannot without a snapshot of "
+            "it at that position, which the state store does not keep"
+        )
 
 
 class PagePool:
@@ -205,6 +232,17 @@ class PagePool:
         self.cache = init_paged_cache(
             cfg, num_pages, page_size, dtype=dtype, sharding=kv_sharding
         )
+        # a model with layers of more than one kind: the per-slot store of its
+        # recurrent-state layers, sized by max_slots (no such layer: empty arrays,
+        # the hybrid step's arguments all the same)
+        self.states: Optional[StateStore] = None
+        if getattr(cfg, "layer_types", None):
+            from deepspeed_tpu.inference.hybrid_decode import state_shapes
+
+            shapes = state_shapes(cfg, self.max_slots)
+            self.states = StateStore(
+                jnp.zeros(shapes.state, jnp.float32), jnp.zeros(shapes.conv, self.cache.k_pages.dtype)
+            )
         # LIFO free list keeps hot pages hot; page 0 stays out of circulation
         self._free = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._free_slots = list(range(max_slots - 1, -1, -1))
@@ -270,7 +308,15 @@ class PagePool:
         for pages in (self.cache.k_pages, self.cache.v_pages):
             for shard in pages.addressable_shards:
                 per_device[shard.device] = per_device.get(shard.device, 0) + shard.data.nbytes
+        state = {}
+        if self.states is not None:
+            state = {
+                "state_total_bytes": self.states.hbm_bytes(),
+                "state_slots": self.max_slots,
+                "state_slots_in_use": self.max_slots - len(self._free_slots),
+            }
         return {
+            **state,
             "kv_total_bytes": self.cache.hbm_bytes(),
             "kv_bytes_per_chip": max(per_device.values()),
             "kv_devices": len(per_device),
@@ -295,6 +341,10 @@ class PagePool:
         """Install the page arrays a serving program returned (the donated
         buffers aliased in place). The one sanctioned external write."""
         self.cache = PagedKVCache(k_pages=new_k, v_pages=new_v)
+
+    def set_states(self, state: jax.Array, conv: jax.Array) -> None:
+        """Same, for the state store's buffers."""
+        self.states = StateStore(state, conv)
 
     # --- page acquisition / release -------------------------------------
     def _acquire_page(self) -> Optional[int]:
@@ -413,6 +463,7 @@ class PagePool:
             return None
         matched: List[Tuple[int, int]] = []
         if prefix_tokens is not None:
+            _refuse_with_state(self.states, "attaching a cached prefix")
             matched = self.match_prefix(prefix_tokens)
         # attached cached pages leave the reclaimable set, so discount them
         fresh = self.pages_for(want) - len(matched)
@@ -542,6 +593,8 @@ class PagePool:
         those positions overwrites it (through the write barrier). Returns
         how many pages this slot released."""
         n_tokens = int(n_tokens)
+        if n_tokens:
+            _refuse_with_state(self.states, "rolling back written tokens (speculative decode's rejected tail)")
         new_len = int(self.seq_lens[slot]) - n_tokens
         if n_tokens < 0 or new_len < 0:
             raise ValueError(

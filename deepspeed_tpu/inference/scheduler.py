@@ -71,6 +71,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.decode import (
     MOE_STAT_ROWS,
+    _refuse_state_layers,
     build_ragged_multistep,
     build_ragged_step,
     multistep_program_name,
@@ -237,7 +238,7 @@ class PagedServer:
         # which the scanned serving body cannot see; and expert placement is
         # the 'expert' mesh axis, not a TP weight split.
         is_moe = isinstance(params, dict) and (
-            "moe" in params.get("layers", {}) or "moe_layers" in params
+            "moe" in params.get("layers", {}) or "moe_layers" in params or "periods" in params
         )
         if is_moe and "moe_layers" in params:
             raise NotImplementedError(
@@ -256,6 +257,12 @@ class PagedServer:
         # step's one result (decode.py:_moe_stat_rows); a dense model's
         # result, stats keys and spans are as they were
         self._moe_slots = cfg.num_layers * cfg.num_experts if is_moe else 0
+        # a chip that holds a share of its router's experts (models/hybrid_moe.py)
+        # counts the held assignments in the program; every live token routes
+        # moe_top_k a layer, so all routed assignments are counted here
+        self._moe_routed_per_token = (
+            cfg.num_layers * cfg.moe_top_k if is_moe and getattr(cfg, "moe_router_experts", None) else 0
+        )
         if tp is not None:
             if tp.degree > 1:
                 tp.validate_cfg(cfg)
@@ -299,6 +306,16 @@ class PagedServer:
         # single-step fallback, so a (possibly stateful) Drafter is asked
         # at most once per scheduler step
         self._predrafts: Optional[Dict[int, np.ndarray]] = None
+        # recurrent-state layers: a row's state exists at its newest position
+        # only, so whatever re-enters a sequence part-way is refused here
+        refused = {
+            "paged_kv.prefix_cache (and copy-on-write forks of shared pages)": self.prefix_cache,
+            "spec_decode (verify rows roll their rejected tail back)": drafter is not None or bool(_spec_knob(spec_decode, "enable", False)),
+            "paged_kv.multi_step windows": self.ms_enable,
+        }
+        for what, asked in refused.items():
+            if asked:
+                _refuse_state_layers(cfg, what)
         self.policy = policy or YoungestFirstPolicy()
         # crash-recovery journal (inference/journal.py): admissions and
         # emitted tokens are appended per event and made durable ONCE per
@@ -403,6 +420,10 @@ class PagedServer:
             # layer took in one step
             self.stats.update(moe_assignments=0, moe_experts_hit=0, moe_max_expert_load=0)
             self._g_moe_hit = self.metrics.gauge("serve.moe_experts_hit_share")
+            if self._moe_routed_per_token:
+                self.stats["moe_routed_assignments"] = 0  # held or not; moe_assignments: the held
+        if self.pool.states is not None:
+            self._g_state_slots = self.metrics.gauge("serve.state_slots_in_use")
 
     # --- request intake -------------------------------------------------
     def _tenant(self, name: str) -> Dict:
@@ -905,6 +926,13 @@ class PagedServer:
                 rows=len(rows), width=W, program=program, kv_pages=kv_pages, table_pages=page_table.size,
                 live_tokens=live_tokens, token_tiles=tiles,
             )
+            states = self.pool.states
+            if states is not None:
+                # the rows' entries of the state store; dead rows go to the spare one
+                slots = np.full(R, self.pool.max_slots, np.int32)
+                slots[: len(rows)] = [r.slot for r in rows]
+                self._g_state_slots.set(len(rows))
+                pack_span.set(state_slots=len(rows), state_bytes=states.hbm_bytes())
             if mixed:
                 self.stats["mixed_steps"] += 1
                 self.stats["mixed_live_tokens"] += live_tokens
@@ -916,10 +944,17 @@ class PagedServer:
                 self.cfg, R, W, self.pool.page_size, attn_impl=self.attn_impl,
                 telemetry=self.telemetry, tp=self.tp,
             )
-            out, new_k, new_v = step_fn(
-                self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
-                page_table, lengths, q_lens,
-            )
+            if states is not None:
+                out, new_k, new_v, new_state, new_conv = step_fn(
+                    self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
+                    states.state, states.conv, page_table, lengths, q_lens, slots,
+                )
+                self.pool.set_states(new_state, new_conv)
+            else:
+                out, new_k, new_v = step_fn(
+                    self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
+                    page_table, lengths, q_lens,
+                )
             self.pool.set_cache(new_k, new_v)
         self.stats["ragged_steps"] += 1
         self.stats["dispatches"] += 1
@@ -944,6 +979,10 @@ class PagedServer:
                 self.stats["moe_max_expert_load"] = max(self.stats["moe_max_expert_load"], max_load)
                 self._g_moe_hit.set(hit / self._moe_slots)
                 settle_span.set(moe_assignments=assignments, moe_experts_hit=hit, moe_max_expert_load=max_load)
+                if self._moe_routed_per_token:
+                    routed = int(q_lens.sum()) * self._moe_routed_per_token
+                    self.stats["moe_routed_assignments"] += routed
+                    settle_span.set(moe_routed_assignments=routed)
             self._settle_fetched_rows(rows, out, chunk_len, q_lens)
             settle_span.set(tokens=self.stats["emitted_tokens"] - emitted)
 

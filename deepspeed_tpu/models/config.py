@@ -97,6 +97,12 @@ class TransformerConfig:
             )
 
 
+def has_state_layers(cfg) -> bool:
+    """Whether a model keeps recurrent state beside its keys and values
+    (``models/hybrid_moe.py``: a ``layer_types`` that names ``linear``)."""
+    return "linear" in (getattr(cfg, "layer_types", None) or ())
+
+
 def gpt2_config(size: str = "125m", **overrides) -> TransformerConfig:
     presets = {
         "tiny": dict(hidden_size=256, num_layers=4, num_heads=8, vocab_size=1024, max_seq_len=512),

@@ -1,0 +1,359 @@
+"""A decoder whose layers are of more than one kind: softmax attention in
+some, gated delta-rule linear attention (``ops/transformer/linear_attention.py``)
+in the others, every layer with a routed FFN that has a shared expert and may
+hold only this chip's share of the experts its router chooses from.
+
+``HybridMoEConfig.layer_types`` says what each layer is (``softmax`` /
+``linear``); the list repeats with a period (one softmax layer and three
+linear ones, say). Parameters are stacked by KIND inside a period and by
+period in front::
+
+    params["periods"]["softmax"]  leaves [periods, softmax layers a period, ...]
+    params["periods"]["linear"]   leaves [periods, linear layers a period, ...]
+    params["periods"]["moe"]      leaves [periods, layers a period, ...]
+
+so one ``lax.scan`` over periods runs the model, its body holding the
+period's layers in order. The functions below are the layer's mathematics,
+shared by ``HybridMoETransformerLM.apply`` (a whole sequence, no cache: what
+the parity tests use; training this family is not supported) and by the paged
+serving step (``inference/hybrid_decode.py``).
+
+The softmax layer: ``q k v = h Wq, h Wk, h Wv``, no positional term at all
+(``position="none"``), causal softmax over ``num_kv_heads`` grouped heads,
+``o = (attn * sigmoid(h Wg)) Wo`` (``attn_output_gate``). The linear layer:
+``q~ k~ v~ = h Wq, h Wk, h Wv``, each through a depthwise causal convolution
+of ``linear_conv_kernel`` taps and SiLU; per head ``q = l2norm(q~) / sqrt(Dk)``,
+``k = l2norm(k~)``; decay ``a = exp(-exp(A_log) softplus(Wf_up (Wf_down h) +
+dt_bias))`` a key channel; ``b = 2 sigmoid(h w_b)`` a head (``1 x`` without
+``linear_allow_neg_eigval``); the delta rule; output
+``(RMSNorm_head(o) * sigmoid(Wg_up (Wg_down h))) Wo``. The FFN: ``moe_scoring``
+over ``moe_router_experts`` outputs, the ``moe_top_k`` largest of score +
+selection bias, gates normalised over the chosen (``moe_norm_topk_prob``) and
+scaled by ``moe_routed_scaling``; of those, the experts this chip holds
+(``moe_expert_share = (index, of)``: experts ``index * num_experts ..``), plus
+the shared expert, once.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.compression.int8 import qmatmul
+from deepspeed_tpu.models.moe_transformer import MoETransformerConfig, MoETransformerLM
+from deepspeed_tpu.models.transformer import _norm
+
+LAYER_KINDS = ("softmax", "linear")
+
+
+@dataclasses.dataclass
+class HybridMoEConfig(MoETransformerConfig):
+    # what each layer is; None: every layer ``softmax``
+    layer_types: Optional[Sequence[str]] = None
+    attn_output_gate: bool = False  # softmax layers: attn * sigmoid(h Wg) before Wo
+    linear_num_heads: int = 0  # 0: num_heads
+    linear_head_dim: int = 0  # 0: head_dim (keys and values alike)
+    linear_conv_kernel: int = 4
+    linear_gate_rank: int = 0  # the decay's and the output gate's low rank; 0: linear_head_dim
+    linear_allow_neg_eigval: bool = True
+    # the router
+    moe_scoring: str = "softmax"  # softmax | sigmoid
+    moe_select_bias: bool = False  # a learned bias added to the scores for the choice alone
+    moe_router_experts: Optional[int] = None  # the router's width; None: num_experts (all held)
+    moe_expert_share: Tuple[int, int] = (0, 1)  # (index, of): which share of the router's experts is held
+    moe_shared_experts: int = 0  # shared experts, run as one FFN of that many expert widths
+    moe_routed_scaling: float = 1.0
+
+    def __post_init__(self):
+        super().__post_init__()
+        self.layer_types = tuple(self.layer_types or ("softmax",) * self.num_layers)
+        if len(self.layer_types) != self.num_layers or set(self.layer_types) - set(LAYER_KINDS):
+            raise ValueError(f"layer_types must name {self.num_layers} layers of {LAYER_KINDS}, got {self.layer_types}")
+        self.linear_num_heads = self.linear_num_heads or self.num_heads
+        self.linear_head_dim = self.linear_head_dim or self.head_dim
+        self.linear_gate_rank = self.linear_gate_rank or self.linear_head_dim
+        self.moe_expert_share = tuple(self.moe_expert_share)
+        index, of = self.moe_expert_share
+        if self.moe_router_experts is None:
+            self.moe_router_experts = self.num_experts * of
+        if self.num_experts * of != self.moe_router_experts or not 0 <= index < of:
+            raise ValueError(
+                f"share {index} of {of} of a router over {self.moe_router_experts} experts holds "
+                f"{self.moe_router_experts // of}, not num_experts={self.num_experts}"
+            )
+        if self.moe_layer_freq != 1 or self.moe_drop_tokens or self.activation != "swiglu":
+            raise ValueError("a hybrid model routes every layer droplessly through SwiGLU experts: "
+                             "moe_layer_freq=1, moe_drop_tokens=False, activation='swiglu'")
+        if self.position != "none" or self.use_bias or self.norm != "rmsnorm":
+            raise ValueError("a hybrid model is pre-norm RMSNorm without biases and without a positional term (position='none')")
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The shortest prefix of ``layer_types`` that the list repeats."""
+        L = self.num_layers
+        for n in range(1, L + 1):
+            if L % n == 0 and all(self.layer_types[i] == self.layer_types[i % n] for i in range(L)):
+                return tuple(self.layer_types[:n])
+        raise AssertionError
+
+    @property
+    def num_periods(self) -> int:
+        return self.num_layers // len(self.period)
+
+    def layers_of(self, kind: str) -> int:
+        return sum(t == kind for t in self.layer_types)
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(the first held expert's index at the router, how many are held)."""
+        return self.moe_expert_share[0] * self.num_experts, self.num_experts
+
+
+# --- the layers' mathematics -------------------------------------------------------
+
+
+def softmax_gate(p, h, attn):
+    """``attn`` [..., NH * D] times the element-wise output gate, where the layer has one."""
+    return attn * jax.nn.sigmoid(qmatmul(h, p["wg"])).astype(attn.dtype) if "wg" in p else attn
+
+
+def linear_inputs(cfg: HybridMoEConfig, p, h):
+    """What a linear layer computes of one token before its recurrence, from
+    the normed ``h`` [..., H]: the pre-convolution ``q~ k~ v~`` side by side
+    [..., 3 NH D] in h's type, the log decay [..., NH D] and ``b`` [..., NH] in
+    float32."""
+    NH, D = cfg.linear_num_heads, cfg.linear_head_dim
+    qkv = jnp.concatenate([qmatmul(h, p["wq"]), qmatmul(h, p["wk"]), qmatmul(h, p["wv"])], axis=-1)
+    f = qmatmul(qmatmul(h, p["wf_down"]), p["wf_up"]).astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)
+    rate = jnp.repeat(jnp.exp(p["A_log"].astype(jnp.float32)), D)  # a head's rate, on each of its channels
+    log_a = -rate * jax.nn.softplus(f)
+    beta = jax.nn.sigmoid(qmatmul(h, p["wb"]).astype(jnp.float32))
+    return qkv, log_a, (2.0 * beta if cfg.linear_allow_neg_eigval else beta)
+
+
+def short_conv(p, tails, qkv):
+    """The three depthwise causal convolutions and SiLU, as one over the
+    channels side by side: ``qkv`` [B, T, 3C] after the ``K - 1`` inputs that
+    came before it (``tails`` [B, K - 1, 3C]). Float32 [B, T, 3C]."""
+    w = jnp.concatenate([p["conv_q"], p["conv_k"], p["conv_v"]], axis=-1).astype(jnp.float32)  # [K, 3C]
+    T = qkv.shape[1]
+    ext = jnp.concatenate([tails, qkv], axis=1).astype(jnp.float32)
+    return jax.nn.silu(sum(w[j] * ext[:, j : j + T] for j in range(w.shape[0])))
+
+
+def linear_qkv(cfg: HybridMoEConfig, y):
+    """The convolved ``y`` [..., 3 NH D] float32, split and normed: ``q k v``
+    [..., NH, D]."""
+    NH, D = cfg.linear_num_heads, cfg.linear_head_dim
+    q, k, v = (a.reshape(a.shape[:-1] + (NH, D)) for a in jnp.split(y, 3, axis=-1))
+    unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+    return unit(q) * (D ** -0.5), unit(k), v
+
+
+def linear_output(cfg: HybridMoEConfig, p, h, o):
+    """``o`` [..., NH, D] of the recurrence: the head-wise RMSNorm, the
+    low-rank output gate from ``h``, the output projection. [..., H]."""
+    gate = jax.nn.sigmoid(qmatmul(qmatmul(h, p["wg_down"]), p["wg_up"]).astype(jnp.float32))
+    o = _norm(o.astype(jnp.float32), p["o_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+    return qmatmul((o.reshape(gate.shape) * gate).astype(h.dtype), p["wo"])
+
+
+def moe_ffn(cfg: HybridMoEConfig, p, h, live=None, experts=None, group_offset=0):
+    """The routed FFN of one layer for a normed slab ``h`` [B, T, H]: the
+    router over its whole width in float32, the held experts' part
+    (``moe/routed_ffn.py``, ``held``), the shared expert once. ``live``,
+    ``experts`` and ``group_offset`` as ``inference/decode.py::_moe_ffn``.
+    Returns (out [B, T, H], the held experts' assignment counts [E])."""
+    from deepspeed_tpu.moe.experts import apply_dense_ffn
+    from deepspeed_tpu.moe.routed_ffn import routed_ffn
+
+    B, T, H = h.shape
+    tokens = h.reshape(-1, H)
+    logits = tokens.astype(jnp.float32) @ p["gate"]["wg"].astype(jnp.float32)
+    out, counts, _ = routed_ffn(
+        p["experts"] if experts is None else experts, tokens, logits, k=cfg.moe_top_k, activation=cfg.activation,
+        norm_topk_prob=cfg.moe_norm_topk_prob, live=None if live is None else live.reshape(-1),
+        group_offset=group_offset, scoring=cfg.moe_scoring, select_bias=p["gate"].get("bias"),
+        held=cfg.held_experts,
+    )
+    if cfg.moe_routed_scaling != 1.0:
+        out = out * jnp.asarray(cfg.moe_routed_scaling, out.dtype)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            out = out + apply_dense_ffn(p["shared"], tokens, cfg.activation)
+    return out.reshape(B, T, H), counts
+
+
+class HybridMoETransformerLM(MoETransformerLM):
+    """``init`` and a cache-free ``apply`` (logits of a whole sequence; with
+    labels, the loss). Serving goes through ``init_inference`` and the paged
+    server (``inference/hybrid_decode.py``)."""
+
+    def stream_fns(self):
+        raise NotImplementedError("offload_param layer streaming does not support hybrid (multi-kind) layer stacks")
+
+    def tp_partition_rules(self, params_shapes=None):
+        if params_shapes is None:
+            return None
+        raise NotImplementedError("hybrid layer stacks have no tensor- or expert-parallel partition rules yet")
+
+    def keep_fp32_params(self, params_shapes=None):
+        return None
+
+    def init(self, rng, batch) -> Dict[str, Any]:
+        cfg = self.config
+        H, L, V = cfg.hidden_size, cfg.num_layers, cfg.vocab_size
+        NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        LH, LD, r, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_gate_rank, cfg.linear_conv_kernel
+        E, ER, I = cfg.num_experts, cfg.moe_router_experts, cfg.expert_intermediate_size
+        NP, period = cfg.num_periods, cfg.period
+        ns, nl, n = period.count("softmax"), period.count("linear"), len(period)
+        keys = iter(jax.random.split(rng, 40))
+        std, out_std = 0.02, 0.02 / np.sqrt(2 * L)
+
+        def dense(shape, s=std):
+            return jax.random.normal(next(keys), shape, jnp.float32) * s
+
+        periods: Dict[str, Any] = {}
+        if ns:
+            soft = {
+                "attn_norm_scale": jnp.ones((NP, ns, H)),
+                "wq": dense((NP, ns, H, NH * D)),
+                "wk": dense((NP, ns, H, NKV * D)),
+                "wv": dense((NP, ns, H, NKV * D)),
+                "wo": dense((NP, ns, NH * D, H), out_std),
+            }
+            if cfg.attn_output_gate:
+                soft["wg"] = dense((NP, ns, H, NH * D))
+            periods["softmax"] = soft
+        if nl:
+            C = LH * LD
+            # the decay's initial rate and bias as the family's modelling code draws
+            # them: a rate of 1..16 a head, a step of 1e-3..1e-1 a channel
+            dt = jnp.exp(jax.random.uniform(next(keys), (NP, nl, C), minval=np.log(1e-3), maxval=np.log(1e-1)))
+            periods["linear"] = {
+                "attn_norm_scale": jnp.ones((NP, nl, H)),
+                "wq": dense((NP, nl, H, C)),
+                "wk": dense((NP, nl, H, C)),
+                "wv": dense((NP, nl, H, C)),
+                "conv_q": dense((NP, nl, K, C), 0.5),
+                "conv_k": dense((NP, nl, K, C), 0.5),
+                "conv_v": dense((NP, nl, K, C), 0.5),
+                "wf_down": dense((NP, nl, H, r)),
+                "wf_up": dense((NP, nl, r, C)),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+                "A_log": jnp.log(jax.random.uniform(next(keys), (NP, nl, LH), minval=1.0, maxval=16.0)),
+                "wb": dense((NP, nl, H, LH)),
+                "wg_down": dense((NP, nl, H, r)),
+                "wg_up": dense((NP, nl, r, C)),
+                "o_norm_scale": jnp.ones((NP, nl, LD)),
+                "wo": dense((NP, nl, C, H), out_std),
+            }
+        moe = {
+            "mlp_norm_scale": jnp.ones((NP, n, H)),
+            "gate": {"wg": dense((NP, n, H, ER))},
+            "experts": {
+                "w_gate": dense((NP, n, E, H, I)),
+                "w_up": dense((NP, n, E, H, I)),
+                "w_out": dense((NP, n, E, I, H), out_std),
+            },
+        }
+        if cfg.moe_select_bias:
+            moe["gate"]["bias"] = dense((NP, n, ER))
+        if cfg.moe_shared_experts:
+            Is = I * cfg.moe_shared_experts
+            moe["shared"] = {"w_gate": dense((NP, n, H, Is)), "w_up": dense((NP, n, H, Is)), "w_out": dense((NP, n, Is, H), out_std)}
+        periods["moe"] = moe
+        params = {"embed": {"tokens": dense((V, H))}, "periods": periods, "final_norm_scale": jnp.ones((H,))}
+        if not cfg.tie_embeddings:
+            params["lm_head"] = dense((H, V))
+        return params
+
+    # --- the cache-free forward ------------------------------------------
+    def _softmax_mixer(self, p, h):
+        cfg = self.config
+        B, T, _ = h.shape
+        NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q = qmatmul(h, p["wq"]).reshape(B, T, NKV, NH // NKV, D)
+        k = qmatmul(h, p["wk"]).reshape(B, T, NKV, D)
+        v = qmatmul(h, p["wv"]).reshape(B, T, NKV, D)
+        scale = cfg.attn_softmax_scale if cfg.attn_softmax_scale is not None else D ** -0.5
+        scores = jnp.einsum("btkgd,bskd->bkgts", q, k).astype(jnp.float32) * scale
+        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores, -1e30)
+        attn = jnp.einsum("bkgts,bskd->btkgd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v)
+        return qmatmul(softmax_gate(p, h, attn.reshape(B, T, NH * D)), p["wo"])
+
+    def _linear_mixer(self, p, h):
+        from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked
+
+        cfg = self.config
+        B, T, _ = h.shape
+        NH, D, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_conv_kernel
+        qkv, log_a, beta = linear_inputs(cfg, p, h)
+        q, k, v = linear_qkv(cfg, short_conv(p, jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype), qkv))
+        o, _ = kda_chunked(q, k, v, log_a.reshape(B, T, NH, D), beta, jnp.zeros((B, NH, D, D), jnp.float32))
+        return linear_output(cfg, p, h, o)
+
+    def apply(self, params, batch, *, rngs=None, train: bool = False, pld_theta=None, ltd_idx=None):
+        from deepspeed_tpu.models.transformer import _split_batch, cross_entropy_loss
+
+        if train:
+            raise NotImplementedError("training a hybrid (linear-attention) model is not supported: apply is the eval forward")
+        cfg = self.config
+        tokens, labels = _split_batch(batch)
+        x = params["embed"]["tokens"].astype(self.dtype)[tokens]
+
+        def period_step(x, p):
+            at = {kind: 0 for kind in LAYER_KINDS}
+            for j, kind in enumerate(cfg.period):
+                mixer = jax.tree_util.tree_map(lambda a: a[at[kind]], p[kind])
+                at[kind] += 1
+                h = _norm(x, mixer["attn_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+                with jax.named_scope("attention" if kind == "softmax" else "linear_attention"):
+                    x = x + (self._softmax_mixer if kind == "softmax" else self._linear_mixer)(mixer, h).astype(x.dtype)
+                moe = jax.tree_util.tree_map(lambda a: a[j], p["moe"])
+                with jax.named_scope("mlp"):
+                    out, _ = moe_ffn(cfg, moe, _norm(x, moe["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps))
+                x = x + out.astype(x.dtype)
+            return x, None
+
+        x, _ = jax.lax.scan(period_step, x, params["periods"])
+        x = _norm(x, params["final_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+        head = params["embed"]["tokens"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = qmatmul(x, head.astype(x.dtype))
+        return logits if labels is None else cross_entropy_loss(logits, labels)
+
+
+def solar_open2_config(size: str = "250b", **overrides) -> HybridMoEConfig:
+    """Solar-Open2-250B (``upstage/Solar-Open2-250B`` ``config.json``,
+    ``model_type: solar_open2``): 48 layers, every fourth (0, 4, 8, ...) gated
+    NoPE GQA of 64 query heads over 8 KV heads of 128, the rest gated
+    delta-rule linear attention of 64 heads of 128 with a 4-tap short
+    convolution and ``allow_neg_eigval``; every layer 320 SwiGLU experts of
+    1,280, 8 a token by sigmoid scores with a selection bias, gates
+    normalised, one shared expert. ``250b`` is the published model whole;
+    ``tiny`` a toy of one chip's share (2 of 4 experts held... of 8 at the
+    router) for tests."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=512,
+                     max_seq_len=256, intermediate_size=32, linear_num_heads=8, linear_head_dim=16, linear_gate_rank=8,
+                     num_experts=4, moe_router_experts=8, moe_expert_share=(0, 2), moe_top_k=3),
+        "250b": dict(hidden_size=4096, num_layers=48, num_heads=64, num_kv_heads=8, head_dim=128, vocab_size=196608,
+                     max_seq_len=131072, intermediate_size=1280, linear_num_heads=64, linear_head_dim=128,
+                     linear_gate_rank=128, num_experts=320, moe_top_k=8),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="none", activation="swiglu", use_bias=False, tie_embeddings=False,
+        attn_output_gate=True, linear_conv_kernel=4, linear_allow_neg_eigval=True,
+        moe_layer_freq=1, moe_drop_tokens=False, moe_norm_topk_prob=True, moe_scoring="sigmoid",
+        moe_select_bias=True, moe_shared_experts=1, moe_routed_scaling=1.0,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        base["layer_types"] = ["softmax" if i % 4 == 0 else "linear" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)

@@ -34,19 +34,31 @@ import jax.numpy as jnp
 from deepspeed_tpu.moe.grouped_matmul import grouped_matmul
 
 
-def route(logits: jnp.ndarray, k: int, norm_topk_prob: Optional[bool], select_logits: Optional[jnp.ndarray] = None):
+def route(logits: jnp.ndarray, k: int, norm_topk_prob: Optional[bool], select_logits: Optional[jnp.ndarray] = None,
+          scoring: str = "softmax", select_bias: Optional[jnp.ndarray] = None):
     """``logits`` [S, E] float32 -> (gates of all experts [S, E], the chosen
     experts [S, k] int32, their gates [S, k]). ``norm_topk_prob`` None: as
     the capacity gates do (top-1 keeps the plain gate, k > 1 renormalises).
     ``select_logits`` (training noise) picks the experts; the gates always
-    come from the clean logits."""
+    come from the clean logits. ``scoring`` ``sigmoid``: each expert's gate
+    is its own sigmoid, not a share of a softmax. ``select_bias`` [E] is
+    added to the gates for the choice alone (the load-balancing bias of
+    sigmoid-routed models): the chosen gates are the unbiased ones."""
     E = logits.shape[-1]
     if not 1 <= k <= E:
         raise ValueError(f"top-k routing needs 1 <= k <= num_experts, got k={k} of {E}")
     if norm_topk_prob is None:
         norm_topk_prob = k > 1
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    _, experts = jax.lax.top_k(gates if select_logits is None else select_logits, k)
+    if scoring == "softmax":
+        gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    elif scoring == "sigmoid":
+        gates = jax.nn.sigmoid(logits.astype(jnp.float32))
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}; expected softmax|sigmoid")
+    select = gates if select_logits is None else select_logits
+    if select_bias is not None:
+        select = select + select_bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(select, k)
     chosen = jnp.take_along_axis(gates, experts, axis=-1)
     if norm_topk_prob:
         chosen = chosen / jnp.clip(jnp.sum(chosen, axis=-1, keepdims=True), min=jnp.finfo(jnp.float32).eps)
@@ -79,6 +91,9 @@ def routed_ffn(
     live: Optional[jnp.ndarray] = None,
     select_logits: Optional[jnp.ndarray] = None,
     group_offset=0,
+    scoring: str = "softmax",
+    select_bias: Optional[jnp.ndarray] = None,
+    held: Optional[Tuple[int, int]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``tokens`` [S, H] through their k experts of the stacked ``experts``
     (``moe/experts.py::init_expert_ffn``'s leaves ``[E, ...]``). ``logits``
@@ -88,6 +103,11 @@ def routed_ffn(
     ``leaf[group_offset + e]``, the offset being data, so that the kernel
     reads a layer's experts where they lie (sliced out of the stack first, a
     layer's 268 MB a matrix would be copied before each call).
+    ``held`` ``(first, n)``: this chip's share of a deployment's experts. The
+    router keeps its whole width E and its k a token, gates normalised over
+    all k; the stacks hold experts ``first .. first + n`` only, an assignment
+    to any other expert is dropped (it sorts behind every group and adds
+    nothing: its chip adds it), and ``counts`` is ``[n]``, of the held alone.
     Returns ``(out [S, H] in tokens' dtype, counts [E] int32, gates [S, E])``."""
     from deepspeed_tpu.moe.experts import _pointwise_activation
 
@@ -95,8 +115,11 @@ def routed_ffn(
     E = logits.shape[-1]
     dt = tokens.dtype
     with jax.named_scope("moe_route"):
-        gates, chosen, weights = route(logits, k, norm_topk_prob, select_logits)
+        gates, chosen, weights = route(logits, k, norm_topk_prob, select_logits, scoring, select_bias)
         flat = chosen.reshape(-1)
+        if held is not None:
+            first, E = held
+            flat = jnp.where((flat >= first) & (flat < first + E), flat - first, E)
         if live is not None:
             flat = jnp.where(jnp.repeat(live, k), flat, E)
         counts = jnp.sum(flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
@@ -119,7 +142,10 @@ def routed_ffn(
     with jax.named_scope("moe_route"):
         # back to token order; a dead assignment's row was never computed
         back = jnp.argsort(order).reshape(S, k)
-        routed = jnp.ones((S, k), bool) if live is None else jnp.broadcast_to(live[:, None], (S, k))
+        if held is not None:
+            routed = (flat < E).reshape(S, k)  # held, and of a live token
+        else:
+            routed = jnp.ones((S, k), bool) if live is None else jnp.broadcast_to(live[:, None], (S, k))
         per_choice = jnp.where(routed[..., None], out_rows[back], 0.0)
         out = jnp.sum(per_choice * weights[..., None], axis=1).astype(dt)
     return out, counts, gates

@@ -309,3 +309,58 @@ def test_olmoe_ragged_step_fits_and_names_its_kernels(v5e, monkeypatch, width):
     assert memory.temp_size_in_bytes < 0.5e9
     # the step's one result: 16 rows of width + 1, and the three rows of routing counts
     assert re.search(rf"s32\[{rows + decode.MOE_STAT_ROWS},{width + 1}\]", text)
+
+
+_SOLAR_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/solar-open2-250b-l4-ep8.json"
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the Solar-Open2 cell's shapes (one period:
+    a gated GQA layer of 64 query heads over 8, three delta-rule layers of 64
+    heads of 128, 40 held experts of 1,280 of a router over 320, 64 rows,
+    1,537 pages of 64, a state store of 65 slots): it compiles for a v5e, the
+    pages AND the state store stay aliased in to out, weights + pools +
+    temporaries fit the chip, and the recurrence of one-token rows is the
+    ``kda_decode`` kernel, which must not open with three ``s32`` operands
+    (the ragged attention kernel's signature for the accepted readers)."""
+    from deepspeed_tpu.inference import hybrid_decode
+    from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
+
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul",
+                   "deepspeed_tpu.ops.transformer.linear_attention"):
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = json.loads(_SOLAR_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = HybridMoEConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
+    pool = on_v5e((cfg.layers_of("softmax"), rows * maxp + 1, cfg.num_kv_heads, page, cfg.head_dim), BF16)
+    shapes = hybrid_decode.state_shapes(cfg, rows)
+    assert shapes.state == (3, 65, 64, 128, 128)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), pool, pool, on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv, BF16),
+        on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+    first_pool = len(jax.tree_util.tree_leaves(params)) + 1
+    assert {first_pool + i for i in range(4)} <= parse_input_output_aliases(text)
+    memory = compiled.memory_analysis()
+    print(f"solar w{width}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB")
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+)", text, flags=re.M))
+    kernels = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*? custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text, flags=re.M))
+    opens_with_three_s32 = [
+        name for name, operands in kernels.items()
+        if all(shape_of[o].startswith("s32[") for o in re.findall(r"%([\w.-]+)", operands)[:3])
+    ]
+    assert len(opens_with_three_s32) == 1, opens_with_three_s32  # the one softmax layer's ragged kernel
+    assert any(name.startswith("kda_decode") for name in kernels), sorted(kernels)
+    assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
