@@ -190,6 +190,44 @@ def test_engine_serve_and_compile_stats(model_and_params):
     assert stats["kv_decode_loop"]["compiles"] <= 1
 
 
+def test_no_serving_program_calls_the_flash_kernel(monkeypatch):
+    """The paged programs attend through ``ragged_paged_attention`` whatever
+    ``config.flash_attention`` says (the default: on): serving a dense model
+    never reaches the training kernel, so a change to it cannot move a serving
+    cell (ISSUE 33). The lowered text of both ``paged_ragged_*`` programs
+    names no ``flash_fwd``, and the kernel's entry is not called while they
+    are traced; the unpaged forward of the same engine does call it."""
+    import sys
+
+    import deepspeed_tpu.ops.transformer  # noqa: F401  (the package rebinds the module's name to the function)
+
+    module = sys.modules["deepspeed_tpu.ops.transformer.flash_attention"]
+    calls = []
+    real = module.flash_attention
+
+    def spy(*args, **kwargs):
+        calls.append(args[0].shape)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(module, "flash_attention", spy)
+    cfg = TransformerConfig(**{**CFG, "flash_attention": True})
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    assert not calls  # drawing parameters does not trace the forward
+    engine = ds.init_inference(model, dtype="fp32", paged_kv={"page_size": 8, "max_slots": 4, "prefill_chunk": 8})
+    engine.set_params(params)
+    engine._ds_config = cfg
+    engine.serve(_prompts(3, seed=11), max_new_tokens=4)
+    programs = [name for name in engine.compile_stats() if name.startswith("paged_ragged_")]
+    assert len(programs) == 2, programs  # the narrow and the mixed width
+    assert not calls
+    for name in programs:
+        text = engine.program_text(name)
+        assert text and "flash_fwd" not in text and "flash_bwd" not in text, name
+    engine(jnp.zeros((1, 16), jnp.int32))  # the control: the unpaged forward is the kernel's caller
+    assert calls
+
+
 def test_paged_matches_dense_gpt2_family():
     """Learned positions + tied embeddings + MHA (the gpt2 shape) through
     the paged path — per-row position gathers must stay exact."""

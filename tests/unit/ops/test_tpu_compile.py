@@ -110,6 +110,7 @@ _PAGES = ((2, 257, 4, 64, 64), BF16)
 _TABLE = ((8, 32), I32)
 _LENS = ((8,), I32)
 _TRAIN_QKV = [((8, 1024, 12, 64), BF16)] * 3
+_TRAIN_XL_QKV = [((8, 1024, 25, 64), BF16)] * 3  # a chip's share of gpt2_xl_zero3_dp4_train
 _SPARSE_QKV = [((1, 8, _SPARSE_T, 64), BF16)] * 3
 
 def _grouped(x, w, sizes):
@@ -119,6 +120,8 @@ def _grouped(x, w, sizes):
 CASES = {
     "flash_fwd_gpt2_125m": (_flash_fwd, _TRAIN_QKV),
     "flash_bwd_gpt2_125m": (_bwd(_flash_fwd), _TRAIN_QKV),
+    "flash_fwd_gpt2_xl": (_flash_fwd, _TRAIN_XL_QKV),
+    "flash_bwd_gpt2_xl": (_bwd(_flash_fwd), _TRAIN_XL_QKV),
     "ragged_w1_llama_1b": (
         _ragged,
         [((8, 1, 32, 64), BF16)] + [((8, 1, 4, 64), BF16)] * 2 + [_PAGES, _PAGES, _TABLE, _LENS, _LENS],
@@ -159,6 +162,32 @@ def test_kernel_compiles_for_v5e(v5e, name):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel is not in the program"
+
+
+def test_flash_backward_is_what_the_benchmark_reads(v5e):
+    """The compiled forward + backward of the 125M cell's attention: the row
+    statistics stay lane-dense (no ``f32[96,1024,128]`` anywhere: they were
+    stored and re-broadcast so until PR 33), and each of the three custom
+    calls has the operands and results by which the benchmark's readers tell
+    the kernels apart in a trace (``benchmark/kernels/flash_attention.py``)."""
+    from benchmark.kernels import flash_attention as read
+    from benchmark.trace_reduce import _LAYOUT
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in _TRAIN_QKV]
+    from jax._src.lib import xla_client
+
+    # an instruction as a trace event names it: with its operands' shapes
+    as_traced = xla_client._xla.HloPrintOptions.short_parsable()
+    as_traced.print_operand_shape = as_traced.print_percent = True
+    (module,) = jax.jit(_bwd(_flash_fwd)).lower(*args).compile().runtime_executable().hlo_modules()
+    text = module.to_string(as_traced)
+    assert "f32[96,1024,128]" not in text
+    assert "f32[96,1,1024]" in text
+    calls = [_LAYOUT.sub("", line) for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    found = {kind: [c for c in calls if re.search(pattern, c)] for kind, pattern in read.EVENTS.items()}
+    assert {kind: len(c) for kind, c in found.items()} == {"forward": 1, "backward_dq": 1, "backward_dkv": 1}, calls
+    for kind, name in (("forward", "flash_fwd"), ("backward_dq", "flash_bwd_dq"), ("backward_dkv", "flash_bwd_dkv")):
+        assert name in found[kind][0].split(" = ")[0], found[kind][0][:120]
 
 
 _MISTRAL_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mistral-7b-v0.3-l16.json"
