@@ -306,14 +306,16 @@ _decoder_cache: Dict[Tuple, Tuple] = {}
 
 
 def _refuse_state_layers(cfg, what: str) -> None:
-    """Keys and values are the only state ``what`` knows of. A model with
-    recurrent-state layers (``layer_types`` naming ``linear``) is refused
-    where it is built, with the missing piece named."""
+    """Every key and value of a row is the only state ``what`` knows of. A
+    model with recurrent-state or sliding-window layers (``layer_types``
+    naming ``linear`` or ``window``) is refused where it is built, with the
+    missing piece named."""
     if has_state_layers(cfg):
         raise NotImplementedError(
-            f"{what} does not support a model with recurrent-state (linear-attention) layers: it would need "
-            "a snapshot of each row's recurrent state and convolution tail beside its keys and values, "
-            "which only the paged server's per-slot state store keeps (serve through init_inference(...).serve())"
+            f"{what} does not support a model with recurrent-state (linear-attention) or sliding-window layers: "
+            "it would need a snapshot of each row's recurrent state and convolution tail, or a window layer's "
+            "masks, sinks and heads of their own, beside its keys and values, which only the paged server's "
+            "per-slot store keeps (serve through init_inference(...).serve())"
         )
 
 

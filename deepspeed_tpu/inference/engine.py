@@ -626,6 +626,16 @@ class InferenceEngine:
                         kind="kv_pool",
                         detail={k: v for k, v in rep.items() if k.startswith("state_")},
                     )
+                if "window_total_bytes" in rep:
+                    # its third: the sliding-window layers' page rings, the same
+                    # size a slot whatever the row's length
+                    ledger.add_persistent(
+                        "window_kv",
+                        per_chip_bytes=rep["window_total_bytes"],
+                        global_bytes=rep["window_total_bytes"],
+                        kind="kv_pool",
+                        detail={k: v for k, v in rep.items() if k.startswith("window_")},
+                    )
                 ledger.add_persistent(
                     "kv_page_tables",
                     per_chip_bytes=rep["host_table_bytes"],
@@ -705,10 +715,11 @@ class InferenceEngine:
             params = quantize_params_int8(params)
         prefix_cache = pcfg.prefix_cache
         if has_state_layers(self._ds_config) and "prefix_cache" not in pcfg.model_fields_set:
-            # the default is on; a model with recurrent-state layers cannot
-            # attach a cached prefix (no state snapshot at that position), so
-            # the default is off for it. Asked for by name, it is refused
-            log_dist("paged_kv.prefix_cache defaults to off for a model with recurrent-state layers", ranks=[0])
+            # the default is on; a model with recurrent-state or sliding-window
+            # layers cannot attach a cached prefix (no state snapshot at that
+            # position, no ring of the pages before it), so the default is off
+            # for it. Asked for by name, it is refused
+            log_dist("paged_kv.prefix_cache defaults to off for a model with recurrent-state or sliding-window layers", ranks=[0])
             prefix_cache = False
         server = PagedServer(
             self._ds_config,

@@ -1,35 +1,52 @@
 """The ragged serving step of a model whose layers are of more than one kind
 (``models/hybrid_moe.py``): softmax layers that keep keys and values in pages,
-linear-attention layers that keep one recurrent state and a convolution tail a
-row, every layer with its routed FFN.
+sliding-window layers that keep a row's newest pages in a ring, linear-attention
+layers that keep one recurrent state and a convolution tail a row; leading
+layers with a dense FFN, then every layer with its routed FFN.
 
 ``decode.build_ragged_step`` comes here, when the program is BUILT, for a
 config that names ``layer_types``; a uniform model never reaches this file
 and lowers to what it always did. The step is the same program in the
 scheduler's eyes (the same two widths, the same names, one dispatch and one
-fetch a step) with two more donated buffers and one more row array:
+fetch a step) with the per-slot store's buffers donated beside the pages
+(``kv_pool.StateStore``, one argument: a field that is ``None`` is no
+parameter of the program) and one more row array:
 
-    step(params, tokens [R, W], k_pages, v_pages, state, conv,
+    step(params, tokens [R, W], k_pages, v_pages, store,
          page_table [R, MAXP], lengths [R], q_lens [R], slots [R])
-      -> (out, k_pages, v_pages, state, conv)
+      -> (out, k_pages, v_pages, store)
 
-* ``k_pages / v_pages`` ``[softmax layers, NP, NKV, P, D]``: only the softmax
-  layers have pages;
-* ``state`` ``[linear layers, slots + 1, NH, Dk, Dv]`` float32 and ``conv``
-  ``[linear layers, slots + 1, K - 1, 3 NH D]``: row r's are at ``slots[r]``,
-  the last slot belongs to nobody and takes what dead rows write. A row whose
-  window starts at position 0 (``lengths[r] == 0``) starts from zero state
-  inside the program, so admission, preemption and re-admission need no
-  reset dispatch.
+* ``k_pages`` ``[softmax layers, NP, NKV, P, Dk]`` and ``v_pages`` ``[..., Dv]``:
+  only the softmax layers have pages under the page table; a key head wider
+  than a lane tile is stored at whole tiles (``kv_pool.key_lanes``: 192 at 256);
+* ``store.state`` ``[linear layers, slots + 1, NH, Dk, Dv]`` float32 and
+  ``store.conv`` ``[linear layers, slots + 1, K - 1, 3 NH D]``: row r's are at
+  ``slots[r]``, the last slot belongs to nobody and takes what dead rows
+  write. A row whose window starts at position 0 (``lengths[r] == 0``) starts
+  from zero state inside the program, so admission, preemption and
+  re-admission need no reset dispatch;
+* ``store.window_k / window_v`` ``[window layers, 1 + R * ring, NKV', P, ..]``:
+  the rings of the sliding-window layers, with their own KV-head count. Row r
+  owns pages ``1 + slots[r] * ring ..`` and position ``p`` lives in ring page
+  ``(p // P) % ring``; the table that says so is made here, from ``slots``,
+  and the kernel starts its walk at the window's first page. A row that starts
+  again at position 0 simply overwrites: what its ring held lies past its
+  length or outside its window, and is masked. ``R`` is the pool's
+  ``max_slots``.
 
-One ``lax.scan`` over PERIODS runs the layers; its body holds the period's
-layers in order and reaches each layer's weights, pages and state through an
-index, so the donated buffers are the ones returned. Token-wise work
+The leading dense layers, then one ``lax.scan`` over PERIODS run the layers;
+the scan's body holds the period's layers in order and reaches each layer's
+weights, pages and state through an index, so the donated buffers are the
+ones returned. Token-wise work
 (norms, projections, gates, the FFN) runs over the packed live tokens in
 tiles, as in ``decode._paged_layers``; a window of at most one tile is one
-"tile" of its whole slab, through the same code. Only the two mixers see rows:
+"tile" of its whole slab, through the same code. Only the mixers see rows:
 
-* softmax: ``ragged_paged_attention`` on the ``[R, W]`` window;
+* softmax and window: ``ragged_paged_attention`` on the ``[R, 1]`` window of
+  the narrow program; in the wide program the rows with one token through
+  the same width-1 call and the rows with a chunk ``CHUNK_ROWS`` a trip of a
+  loop whose count is data (``wide_attention``: the ``[R, W]`` window is
+  never laid out); the window layers' calls with ``window`` and their sinks;
 * linear: a row with ONE token (a decode row, in the narrow program or
   riding in a wide window) goes through ``kda_decode``, in place on the
   pool; a row with more (a prefill chunk) goes through the chunkwise form
@@ -48,9 +65,18 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.inference import decode
+from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes
 from deepspeed_tpu.models import hybrid_moe as hm
 from deepspeed_tpu.models.transformer import _norm
 from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked, kda_decode
+
+# Rows with a prefill chunk that one trip of a wide window's attention loop
+# takes (``wide_attention``). A steady mixed step has one such row, the first
+# steps of a full house as many as slots: a dead row of a trip costs the
+# kernel a few scalar reads and XLA its 128-token window, 1/16 of what the
+# whole 64-row window cost.
+CHUNK_ROWS = 4
+
 
 class StateShapes(NamedTuple):
     """The per-slot store of a config's state layers, for ``max_slots`` rows."""
@@ -64,6 +90,12 @@ def state_shapes(cfg, max_slots: int) -> StateShapes:
     return StateShapes((n, max_slots + 1, NH, D, D), (n, max_slots + 1, cfg.linear_conv_kernel - 1, 3 * NH * D))
 
 
+def window_shapes(cfg, max_slots: int, page_size: int, ring: int):
+    """The window layers' key and value pools: ``ring`` pages a slot behind the trash page."""
+    k = (cfg.layers_of("window"), 1 + max_slots * ring, cfg.window_num_kv_heads, page_size, key_lanes(cfg.head_dim))
+    return k, k[:-1] + (cfg.v_head_dim,)
+
+
 def _whole_slab(q_lens, B: int, T: int) -> decode._Packed:
     """The slab as its own packing: one tile, every slot where it is."""
     i = jnp.arange(B * T, dtype=jnp.int32)
@@ -71,13 +103,14 @@ def _whole_slab(q_lens, B: int, T: int) -> decode._Packed:
     return decode._Packed(B * T, jnp.int32(1), i, i.reshape(B, T), live)
 
 
-def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots, attn_impl):
-    """Embedding and layers. Returns ``(x [NP, H] packed, the four pools,
-    moe_counts [L, E], packed)``."""
+def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, lengths, q_lens, slots, attn_impl):
+    """Embedding and layers. Returns ``(x [NP, H] packed, k_pages, v_pages,
+    the store, moe_counts [routed layers, E], packed)``."""
     from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
 
     B, T = tokens.shape
     dtype = k_pages.dtype
+    state, conv, wk, wv = store
     tile = decode.token_tile(cfg)
     tiled = bool(tile) and B * T > tile
     packed = decode._pack_window(q_lens, B, T, tile) if tiled else _whole_slab(q_lens, B, T)
@@ -88,13 +121,16 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, state, conv, page_tabl
 
     kv_lens = jnp.where(q_lens > 0, lengths + q_lens, 0)
     x = params["embed"]["tokens"].astype(dtype)[jnp.take(tokens.reshape(-1), packed.slot, mode="clip")]
+    positions = None
+    if cfg.position == "rope":  # a packed token's absolute position
+        positions = jnp.take((lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]).reshape(-1), packed.slot, mode="clip")
 
     period, stacks = cfg.period, params["periods"]
-    ns, nl, n = period.count("softmax"), period.count("linear"), len(period)
+    nl, n = period.count("linear"), len(period)
     E = cfg.num_experts
     expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[3:]), stacks["moe"]["experts"])
     moe_stacks = {k: v for k, v in stacks["moe"].items() if k != "experts"}
-    NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    NH, D, Dv = cfg.num_heads, cfg.head_dim, cfg.v_head_dim
     LH, LD, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_conv_kernel
     C3 = 3 * LH * LD
     scale = decode._softmax_scale(cfg, D)
@@ -104,23 +140,31 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, state, conv, page_tabl
     one_token = q_lens == 1
     starts = packed.index[:, 0]  # a row's first packed token
     write_slot = jnp.where(q_lens > 0, slots, NS - 1)
-    if nl and T > 1:
-        # the rows with a chunk first: the chunkwise loop runs as many times as there are
+    if T > 1:
+        # the rows with a chunk first: the loops over them run as many times as there are
         chunk_rows = q_lens > 1
         n_chunk_rows = jnp.sum(chunk_rows, dtype=jnp.int32)
         order = jnp.argsort(~chunk_rows, stable=True).astype(jnp.int32)
+    if wk is not None:
+        # the rings as a page table: slot i of row r on page i % ring of the row's own
+        ring = (wk.shape[1] - 1) // B
+        own = 1 + slots[:, None] * ring + (jnp.arange(page_table.shape[1], dtype=jnp.int32) % ring)[None, :]
+        ring_table = jnp.where((q_lens > 0)[:, None], own, -1)
 
     def weights_at(tree, per, j, start):
-        """Layer ``j`` of period ``per`` out of its stacks, tied to the tile
-        (or the compiler hoists the slices out of the tile loop and copies them)."""
+        """Layer ``j`` of period ``per`` out of its stacks (``per`` None: a
+        leading layer's own leaves), tied to the tile (or the compiler hoists
+        the slices out of the tile loop and copies them)."""
         if tiled:
             tree, _ = jax.lax.optimization_barrier((tree, start))
+        if per is None:
+            return tree
         return jax.tree_util.tree_map(lambda a: jax.lax.dynamic_index_in_dim(a, per, keepdims=False)[j], tree)
 
     def put(buf, new, start):
         return jax.lax.dynamic_update_slice_in_dim(buf, new.astype(buf.dtype), start, axis=0)
 
-    def ffn(x_tile, per, j, start):
+    def ffn(x_tile, start, per, j):
         p = weights_at(moe_stacks, per, j, start)
         moe = functools.partial(
             hm.moe_ffn, live=packed.take(packed.live, start)[None], experts=expert_stacks, group_offset=(per * n + j) * E
@@ -128,34 +172,89 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, state, conv, page_tabl
         out, counts = decode._ffn_body(cfg, {"moe": p}, x_tile, p["mlp_norm_scale"], None, moe_ffn=moe)
         return x_tile + out, counts
 
-    def softmax_layer(x, kp, vp, per, js, j):
-        layer = per * ns + js
+    def dense_ffn(x_tile, start, p):
+        p = weights_at(p, None, None, start)
+        out, _ = decode._ffn_body(cfg, p, x_tile, p["mlp_norm_scale"], None)
+        return x_tile + out, jnp.zeros((E,), jnp.int32)
+
+    def wide_attention(attend, qkv, NKV, kp, vp, layer, table):
+        """A wide window's attention without the window's slab: the rows with
+        ONE token (decode rows riding beside a prefill chunk) through one
+        width-1 call, the rows with a chunk ``CHUNK_ROWS`` a trip of a loop
+        whose count is data, each trip a ``[CHUNK_ROWS, T]`` window. Laid out
+        as the whole ``[B, T]`` window, every layer's q is B T NH D lanes that
+        XLA gathers, transposes and concatenates for the kernel, and the
+        kernel gives each one-token row a whole query tile: 7 ms a layer of a
+        64 x 128 window with one chunk in it (PERF.md section 6, PR 34).
+        ``qkv`` packed ``[NPK, heads * d]``; returns the output packed
+        ``[NPK, NH * Dv]`` (dead slots: zeros) and the pools."""
+        heads = (NH, NKV, NKV)
+        first = tuple(jnp.take(a, starts, axis=0, mode="clip").reshape(B, 1, nh, -1) for a, nh in zip(qkv, heads))
+        o1, kp, vp = attend(*first, kp, vp, layer, table, jnp.where(one_token, kv_lens, 0), one_token.astype(jnp.int32))
+
+        def trip(i, carry):
+            attn, kp, vp = carry
+            at = i * CHUNK_ROWS + jnp.arange(CHUNK_ROWS, dtype=jnp.int32)
+            rows = order[jnp.minimum(at, B - 1)]
+            lens = jnp.where(at < n_chunk_rows, q_lens[rows], 0)  # past the last chunk row: a dead row
+            index = packed.index[rows]  # [CHUNK_ROWS, T]: a row's tokens lie together
+            window = tuple(jnp.take(a, index, axis=0, mode="clip").reshape(CHUNK_ROWS, T, nh, -1) for a, nh in zip(qkv, heads))
+            o, kp, vp = attend(*window, kp, vp, layer, table[rows], jnp.where(lens > 0, kv_lens[rows], 0), lens)
+            real = jnp.arange(T, dtype=jnp.int32)[None, :] < lens[:, None]
+            attn = attn.at[jnp.where(real, index, NPK).reshape(-1)].set(o.reshape(CHUNK_ROWS * T, NH * Dv), mode="drop")
+            return attn, kp, vp
+
+        attn, kp, vp = jax.lax.fori_loop(
+            0, (n_chunk_rows + CHUNK_ROWS - 1) // CHUNK_ROWS, trip, (jnp.zeros((NPK, NH * Dv), dtype), kp, vp)
+        )
+        return attn.at[jnp.where(one_token, starts, NPK)].set(o1.reshape(B, NH * Dv), mode="drop"), kp, vp
+
+    def attention_layer(kind, x, kp, vp, tree, per, jk, layer, ffn):
+        """A softmax or a window layer: ``tree`` its kind's stacks (its own
+        leaves where ``per`` is None), ``layer`` its entry in the kind's
+        pools, ``ffn(x_tile, start)`` what follows the mixer."""
+        NKV = cfg.kv_heads_of(kind)
+        rotary_or_scaled = cfg.position == "rope" or cfg.attn_value_scale != 1.0
 
         def before(start, qkv):
-            p = weights_at(stacks["softmax"], per, js, start)
+            p = weights_at(tree, per, jk, start)
             h = _norm(packed.take(x, start), p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-            new = (qmatmul(h, p["wq"]), qmatmul(h, p["wk"]), qmatmul(h, p["wv"]))
+            new = hm.attn_project(p, h)
             if tiled:
                 new = jax.lax.optimization_barrier(new)  # decode._paged_layers.project: keep the head split apart
+            if rotary_or_scaled:
+                at = None if positions is None else packed.take(positions, start)[None]
+                new = tuple(a.reshape(a.shape[1], -1) for a in hm.attn_heads(cfg, kind, *(a[None] for a in new), at))
             return tuple(put(buf, a, start) for buf, a in zip(qkv, new))
 
-        with jax.named_scope("attention"):
-            qkv = tiles(before, tuple(jnp.zeros((NPK, nh * D), dtype) for nh in (NH, NKV, NKV)))
-            attn, kp, vp = ragged_paged_attention(
-                *(packed.expand(a).reshape(B, T, nh, D) for a, nh in zip(qkv, (NH, NKV, NKV))),
-                kp, vp, layer, page_table, kv_lens, q_lens, scale=scale, impl=attn_impl,
-            )
-            attn = attn.reshape(B * T, NH * D)
+        with jax.named_scope(hm.SCOPES[kind]):
+            qkv = tiles(before, tuple(jnp.zeros((NPK, nh * d), dtype) for nh, d in ((NH, D), (NKV, D), (NKV, Dv))))
+            extras = {}
+            if kind == "window":
+                extras["window"] = cfg.window
+                if cfg.window_sinks:
+                    extras["sinks"] = weights_at({"sinks": tree["sinks"]}, per, jk, jnp.int32(0))["sinks"]
+            attend = functools.partial(ragged_paged_attention, scale=scale, impl=attn_impl, **extras)
+            table = ring_table if kind == "window" else page_table
+            if T == 1:
+                attn, kp, vp = attend(
+                    *(packed.expand(a).reshape(B, T, nh, -1) for a, nh in zip(qkv, (NH, NKV, NKV))),
+                    kp, vp, layer, table, kv_lens, q_lens,
+                )
+                attn = attn.reshape(B * T, NH * Dv)
+            else:
+                attn, kp, vp = wide_attention(attend, qkv, NKV, kp, vp, layer, table)
 
         def after(start, carry):
             x, counts = carry
             x_tile = packed.take(x, start)
-            with jax.named_scope("attention"):
-                p = weights_at(stacks["softmax"], per, js, start)
+            with jax.named_scope(hm.SCOPES[kind]):
+                p = weights_at(tree, per, jk, start)
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-                a = jnp.take(attn, packed.take(packed.slot, start), axis=0, mode="clip")
+                # the narrow program's is in slab order (which a whole slab's packing is), the wide one's packed
+                a = jnp.take(attn, packed.take(packed.slot, start), axis=0, mode="clip") if T == 1 else packed.take(attn, start)
                 x_tile = x_tile + qmatmul(hm.softmax_gate(p, h, a), p["wo"]).astype(x.dtype)
-            x_tile, tile_counts = ffn(x_tile[None], per, j, start)
+            x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
@@ -216,42 +315,64 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, state, conv, page_tabl
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
                 o_tile = jnp.take(o, packed.take(packed.slot, start), axis=0, mode="clip")
                 x_tile = x_tile + hm.linear_output(cfg, p, h, o_tile).astype(x.dtype)
-            x_tile, tile_counts = ffn(x_tile[None], per, j, start)
+            x_tile, tile_counts = ffn(x_tile[None], start, per, j)
             return put(x, x_tile[0], start), counts + tile_counts
 
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, st, cv, counts
 
+    # a kind's pools: the full layers' pages, the window layers' rings
+    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv)}
+    # the leading dense layers, each with its own weights and the first entries of its kind's pools
+    for i, kind in enumerate(cfg.layer_types[: cfg.leading_dense_layers]):
+        lead = params["leading"][i]
+        if kind == "linear":
+            raise NotImplementedError("a leading dense layer with a linear-attention mixer is not served")
+        x, *written, _ = attention_layer(
+            kind, x, *pools[kind], lead["mixer"], None, None, cfg.layer_types[:i].count(kind),
+            functools.partial(dense_ffn, p=lead["ffn"]),
+        )
+        pools[kind] = tuple(written)
+
     def period_step(carry, per):
-        x, kp, vp, st, cv = carry
-        at = {"softmax": 0, "linear": 0}
+        x, st, cv, pools = carry
+        pools = dict(pools)
+        at = {kind: 0 for kind in hm.LAYER_KINDS}
         counts = []
         for j, kind in enumerate(period):
-            if kind == "softmax":
-                x, kp, vp, c = softmax_layer(x, kp, vp, per, at[kind], j)
-            else:
+            if kind == "linear":
                 x, st, cv, c = linear_layer(x, st, cv, per, at[kind], j)
+            else:
+                layer = cfg.leading_of(kind) + per * period.count(kind) + at[kind]
+                x, *written, c = attention_layer(
+                    kind, x, *pools[kind], stacks[kind], per, at[kind], layer, functools.partial(ffn, per=per, j=j)
+                )
+                pools[kind] = tuple(written)
             at[kind] += 1
             counts.append(c)
-        return (x, kp, vp, st, cv), jnp.stack(counts)
+        return (x, st, cv, pools), jnp.stack(counts)
 
-    (x, kp, vp, st, cv), counts = jax.lax.scan(
-        period_step, (x, k_pages, v_pages, state, conv), jnp.arange(cfg.num_periods, dtype=jnp.int32)
+    (x, st, cv, pools), counts = jax.lax.scan(
+        period_step, (x, state, conv, pools), jnp.arange(cfg.num_periods, dtype=jnp.int32)
     )
-    return x, kp, vp, st, cv, counts.reshape(cfg.num_layers, E), packed
+    return x, *pools["softmax"], StateStore(st, cv, *pools["window"]), counts.reshape(cfg.num_moe_layers, E), packed
 
 
 def hybrid_forward(cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots,
-                   attn_impl: str = "auto"):
+                   attn_impl: str = "auto", window=None):
     """``decode._paged_forward`` for a hybrid model: the window's logits
-    ``[B, T, V]`` (a dead slot's are some live token's) and the four pools.
-    What the parity tests and ``benchmark/tools/solar_logits_check.py``
-    compare with the reference; the serving step takes its arg-max on the
-    packed tiles instead."""
-    x, kp, vp, st, cv, moe_counts, packed = _hybrid_layers(
-        cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots, attn_impl
+    ``[B, T, V]`` (a dead slot's are some live token's) and the pools:
+    ``(logits, k_pages, v_pages, state, conv, moe_counts)`` and, for a model
+    with sliding-window layers, whose rings ``window = (window_k, window_v)``
+    gives, the rings after them. What the parity tests and the benchmark's
+    logits tools compare with the reference; the serving step takes its
+    arg-max on the packed tiles instead."""
+    x, kp, vp, store, moe_counts, packed = _hybrid_layers(
+        cfg, params, tokens, k_pages, v_pages, StateStore(state, conv, *(window or ())), page_table, lengths, q_lens,
+        slots, attn_impl,
     )
-    return decode._final_logits(cfg, params, packed.expand(x)), kp, vp, st, cv, moe_counts
+    out = (decode._final_logits(cfg, params, packed.expand(x)), kp, vp, store.state, store.conv, moe_counts)
+    return out if window is None else out + ((store.window_k, store.window_v),)
 
 
 def build_hybrid_ragged_step(cfg, rows: int, width: int, page_size: int, attn_impl: str, telemetry, name: str, key):
@@ -260,9 +381,9 @@ def build_hybrid_ragged_step(cfg, rows: int, width: int, page_size: int, attn_im
     ``build_ragged_step``'s (with its MoE rows)."""
     W = int(width)
 
-    def _step(params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots):
-        x, kp, vp, st, cv, moe_counts, packed = _hybrid_layers(
-            cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots, attn_impl
+    def _step(params, tokens, k_pages, v_pages, store, page_table, lengths, q_lens, slots):
+        x, kp, vp, store, moe_counts, packed = _hybrid_layers(
+            cfg, params, tokens, k_pages, v_pages, store, page_table, lengths, q_lens, slots, attn_impl
         )
         if packed.slot.shape[0] == packed.tile:  # the whole slab: one head over it
             logits = decode._final_logits(cfg, params, x.reshape(rows, W, -1))
@@ -274,8 +395,8 @@ def build_hybrid_ragged_step(cfg, rows: int, width: int, page_size: int, attn_im
             accepted = decode._accepted_prefix(tokens, greedy, q_lens - 1)
             out = jnp.concatenate([accepted[:, None].astype(jnp.int32), greedy], axis=1)
             out = jnp.concatenate([out, decode._moe_stat_rows(moe_counts, W + 1)], axis=0)
-        return out, kp, vp, st, cv
+        return out, kp, vp, store
 
-    fn = decode._jit(_step, telemetry, name, donate_argnums=(2, 3, 4, 5))
+    fn = decode._jit(_step, telemetry, name, donate_argnums=(2, 3, 4))
     decode._paged_program_cache[key] = fn
     return fn

@@ -132,7 +132,7 @@ class PagedKVCache(NamedTuple):
     def bytes_per_token(self) -> int:
         """HBM bytes one cached token costs across all layers (K + V)."""
         L, _, NKV, _, D = self.k_pages.shape
-        return 2 * L * NKV * D * self.k_pages.dtype.itemsize
+        return L * NKV * (D + self.v_pages.shape[-1]) * self.k_pages.dtype.itemsize
 
     def hbm_bytes(self) -> int:
         return self.k_pages.nbytes + self.v_pages.nbytes
@@ -150,44 +150,81 @@ def init_paged_cache(
         dtype = _DTYPES[cfg.dtype]
     # a model with layers of more than one kind pages its softmax layers only
     layers = cfg.layers_of("softmax") if getattr(cfg, "layer_types", None) else cfg.num_layers
-    shape = (layers, num_pages, cfg.num_kv_heads, page_size, cfg.head_dim)
+    k_shape = (layers, num_pages, cfg.num_kv_heads, page_size, key_lanes(cfg.head_dim))
+    v_shape = k_shape[:-1] + (getattr(cfg, "v_head_dim", None) or cfg.head_dim,)
     if sharding is not None:
         # allocate DIRECTLY sharded: a full-size zeros + device_put would
         # transiently commit the whole pool to one chip — tp× the
         # steady-state per-chip footprint, an OOM at bring-up on exactly
         # the pools sized against aggregate mesh HBM
         zeros = jax.jit(
-            lambda: (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)),
+            lambda: (jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype)),
             out_shardings=(sharding, sharding),
         )
         k, v = zeros()
     else:
-        k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+        k, v = jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype)
     return PagedKVCache(k_pages=k, v_pages=v)
 
 
+def key_lanes(head_dim: int) -> int:
+    """A key head's width in a page: the head's own up to one lane tile, whole
+    lane tiles beyond (192 is stored at 256). A page that is no whole number
+    of 128-lane tiles is one the ragged kernel cannot fetch by DMA
+    (``ops/transformer/decode_attention.py``); the device's tiled layout pads
+    such a page to whole tiles in HBM anyway, so nothing is lost against the
+    naive pool. The pad lanes hold zeros and q's are zeros, so every product
+    is what it was."""
+    return head_dim if head_dim <= 128 else -(-head_dim // 128) * 128
+
+
+def window_ring_pages(window: int, page_size: int, prefill_chunk: int) -> int:
+    """Pages a slot's ring of a window layer holds: the pages a step writes
+    (a prefill chunk's, which start on page boundaries because chunks start
+    on the chunk grid: ``scheduler.py::_next_chunk_len``) and the pages that
+    hold the ``window - 1`` keys before them. A chunk that is no whole number
+    of pages may start inside a page, and its walk then spans one more."""
+    aligned = prefill_chunk % page_size == 0
+    return -(-prefill_chunk // page_size) + -(-(window - 1) // page_size) + (0 if aligned else 1)
+
+
 class StateStore(NamedTuple):
-    """The second kind of cache: what a model's recurrent-state layers keep of
-    a row, whatever its length. One entry a SLOT (the pool's slots are the
-    index; entry ``max_slots`` belongs to nobody and takes dead rows' writes),
-    donated into every serving program beside the pages and returned in
-    place. Nothing here is allocated or freed: a slot's entry is simply
-    restarted from zero by the program when a row's window begins at position
-    0 (``inference/hybrid_decode.py``)."""
+    """The second kind of cache: what a model's layers keep of a row whatever
+    its length, one entry a SLOT (the pool's slots are the index), donated
+    into every serving program beside the pages and returned in place.
+    Nothing here is allocated or freed.
+
+    * recurrent-state layers: a state and a convolution tail; entry
+      ``max_slots`` belongs to nobody and takes dead rows' writes. A slot's
+      entry is restarted from zero by the program when a row's window begins
+      at position 0 (``inference/hybrid_decode.py``).
+    * sliding-window layers: pools of their own shape in which slot ``s`` owns
+      pages ``1 + s * ring ..`` (page 0 is the trash page, as in the page pool)
+      as a RING: position ``p`` of its row lives in ring page ``(p // P) %
+      ring``, whatever the row's length, ``ring`` being
+      ``window_ring_pages``. What a page held a lap ago lies outside the
+      window or past the row's length, and is masked. ``None`` for a model
+      with no such layer."""
 
     state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32
     conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3 NH D]
+    window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk]
+    window_v: Optional[jax.Array] = None
+
+    def window_bytes(self) -> int:
+        return 0 if self.window_k is None else self.window_k.nbytes + self.window_v.nbytes
 
     def hbm_bytes(self) -> int:
-        return self.state.nbytes + self.conv.nbytes
+        return self.state.nbytes + self.conv.nbytes + self.window_bytes()
 
 
 def _refuse_with_state(states, what: str) -> None:
-    if states is not None and states.state.size:
+    if states is not None and (states.state.size or states.window_k is not None):
         raise NotImplementedError(
-            f"{what} is not supported for a model with recurrent-state layers: keys and values can be shared, "
-            "copied or rolled back a page at a time, the recurrent state of a row cannot without a snapshot of "
-            "it at that position, which the state store does not keep"
+            f"{what} is not supported for a model with recurrent-state or sliding-window layers: keys and values "
+            "of a full-attention layer can be shared, copied or rolled back a page at a time; the recurrent state "
+            "of a row cannot without a snapshot of it at that position, nor can a window layer's page ring, which "
+            "holds a row's newest positions only, and the per-slot store keeps neither"
         )
 
 
@@ -218,6 +255,7 @@ class PagePool:
         max_seq_len: Optional[int] = None,
         dtype=None,
         kv_sharding=None,
+        prefill_chunk: Optional[int] = None,
     ):
         if page_size < 1 or num_pages < 2:
             raise ValueError("need page_size >= 1 and num_pages >= 2 (page 0 is reserved)")
@@ -234,15 +272,22 @@ class PagePool:
         )
         # a model with layers of more than one kind: the per-slot store of its
         # recurrent-state layers, sized by max_slots (no such layer: empty arrays,
-        # the hybrid step's arguments all the same)
+        # the hybrid step's arguments all the same), and the page rings of its
+        # sliding-window layers, sized by max_slots, the window and the chunk
         self.states: Optional[StateStore] = None
+        self.window_ring = 0
         if getattr(cfg, "layer_types", None):
-            from deepspeed_tpu.inference.hybrid_decode import state_shapes
+            from deepspeed_tpu.inference.hybrid_decode import state_shapes, window_shapes
 
             shapes = state_shapes(cfg, self.max_slots)
-            self.states = StateStore(
-                jnp.zeros(shapes.state, jnp.float32), jnp.zeros(shapes.conv, self.cache.k_pages.dtype)
-            )
+            kv_dtype = self.cache.k_pages.dtype
+            rings = ()
+            if cfg.layers_of("window"):
+                if not prefill_chunk:
+                    raise ValueError("a model with sliding-window layers needs prefill_chunk to size its page rings")
+                self.window_ring = window_ring_pages(cfg.window, self.page_size, int(prefill_chunk))
+                rings = tuple(jnp.zeros(shape, kv_dtype) for shape in window_shapes(cfg, self.max_slots, self.page_size, self.window_ring))
+            self.states = StateStore(jnp.zeros(shapes.state, jnp.float32), jnp.zeros(shapes.conv, kv_dtype), *rings)
         # LIFO free list keeps hot pages hot; page 0 stays out of circulation
         self._free = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._free_slots = list(range(max_slots - 1, -1, -1))
@@ -310,11 +355,21 @@ class PagePool:
                 per_device[shard.device] = per_device.get(shard.device, 0) + shard.data.nbytes
         state = {}
         if self.states is not None:
+            in_use = self.max_slots - len(self._free_slots)
             state = {
-                "state_total_bytes": self.states.hbm_bytes(),
+                "state_total_bytes": self.states.hbm_bytes() - self.states.window_bytes(),
                 "state_slots": self.max_slots,
-                "state_slots_in_use": self.max_slots - len(self._free_slots),
+                "state_slots_in_use": in_use,
             }
+            if self.states.window_k is not None:
+                # the same for a row of any length: ring pages a slot, not pages a token
+                state.update(
+                    window_total_bytes=self.states.window_bytes(),
+                    window_bytes_per_slot=self.states.window_bytes() // (1 + self.max_slots * self.window_ring) * self.window_ring,
+                    window_ring_pages=self.window_ring,
+                    window_slots=self.max_slots,
+                    window_slots_in_use=in_use,
+                )
         return {
             **state,
             "kv_total_bytes": self.cache.hbm_bytes(),
@@ -342,9 +397,9 @@ class PagePool:
         buffers aliased in place). The one sanctioned external write."""
         self.cache = PagedKVCache(k_pages=new_k, v_pages=new_v)
 
-    def set_states(self, state: jax.Array, conv: jax.Array) -> None:
+    def set_states(self, states: StateStore) -> None:
         """Same, for the state store's buffers."""
-        self.states = StateStore(state, conv)
+        self.states = states
 
     # --- page acquisition / release -------------------------------------
     def _acquire_page(self) -> Optional[int]:

@@ -256,12 +256,13 @@ class PagedServer:
         # an MoE model's ragged step appends its routing counts to the
         # step's one result (decode.py:_moe_stat_rows); a dense model's
         # result, stats keys and spans are as they were
-        self._moe_slots = cfg.num_layers * cfg.num_experts if is_moe else 0
+        moe_layers = getattr(cfg, "num_moe_layers", cfg.num_layers)  # fewer behind leading dense layers
+        self._moe_slots = moe_layers * cfg.num_experts if is_moe else 0
         # a chip that holds a share of its router's experts (models/hybrid_moe.py)
         # counts the held assignments in the program; every live token routes
         # moe_top_k a layer, so all routed assignments are counted here
         self._moe_routed_per_token = (
-            cfg.num_layers * cfg.moe_top_k if is_moe and getattr(cfg, "moe_router_experts", None) else 0
+            moe_layers * cfg.moe_top_k if is_moe and getattr(cfg, "moe_router_experts", None) else 0
         )
         if tp is not None:
             if tp.degree > 1:
@@ -306,8 +307,9 @@ class PagedServer:
         # single-step fallback, so a (possibly stateful) Drafter is asked
         # at most once per scheduler step
         self._predrafts: Optional[Dict[int, np.ndarray]] = None
-        # recurrent-state layers: a row's state exists at its newest position
-        # only, so whatever re-enters a sequence part-way is refused here
+        # recurrent-state and sliding-window layers: a row's state, or its page
+        # ring, exists at its newest positions only, so whatever re-enters a
+        # sequence part-way is refused here
         refused = {
             "paged_kv.prefix_cache (and copy-on-write forks of shared pages)": self.prefix_cache,
             "spec_decode (verify rows roll their rejected tail back)": drafter is not None or bool(_spec_knob(spec_decode, "enable", False)),
@@ -351,6 +353,7 @@ class PagedServer:
             cfg, num_pages, page_size, max_slots,
             max_seq_len=max_seq, dtype=dtype,
             kv_sharding=None if tp is None else tp.kv_sharding,
+            prefill_chunk=prefill_chunk,
         )
         self._queue: deque[Request] = deque()
         self._active: List[Request] = []  # admission order (oldest first)
@@ -424,6 +427,8 @@ class PagedServer:
                 self.stats["moe_routed_assignments"] = 0  # held or not; moe_assignments: the held
         if self.pool.states is not None:
             self._g_state_slots = self.metrics.gauge("serve.state_slots_in_use")
+            if self.pool.states.window_k is not None:
+                self._g_window_slots = self.metrics.gauge("serve.window_slots_in_use")
 
     # --- request intake -------------------------------------------------
     def _tenant(self, name: str) -> Dict:
@@ -932,7 +937,10 @@ class PagedServer:
                 slots = np.full(R, self.pool.max_slots, np.int32)
                 slots[: len(rows)] = [r.slot for r in rows]
                 self._g_state_slots.set(len(rows))
-                pack_span.set(state_slots=len(rows), state_bytes=states.hbm_bytes())
+                pack_span.set(state_slots=len(rows), state_bytes=states.hbm_bytes() - states.window_bytes())
+                if states.window_k is not None:
+                    self._g_window_slots.set(len(rows))
+                    pack_span.set(window_slots=len(rows), window_bytes=states.window_bytes())
             if mixed:
                 self.stats["mixed_steps"] += 1
                 self.stats["mixed_live_tokens"] += live_tokens
@@ -945,11 +953,11 @@ class PagedServer:
                 telemetry=self.telemetry, tp=self.tp,
             )
             if states is not None:
-                out, new_k, new_v, new_state, new_conv = step_fn(
+                out, new_k, new_v, states = step_fn(
                     self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
-                    states.state, states.conv, page_table, lengths, q_lens, slots,
+                    states, page_table, lengths, q_lens, slots,
                 )
-                self.pool.set_states(new_state, new_conv)
+                self.pool.set_states(states)
             else:
                 out, new_k, new_v = step_fn(
                     self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,

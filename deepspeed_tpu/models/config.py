@@ -98,9 +98,12 @@ class TransformerConfig:
 
 
 def has_state_layers(cfg) -> bool:
-    """Whether a model keeps recurrent state beside its keys and values
-    (``models/hybrid_moe.py``: a ``layer_types`` that names ``linear``)."""
-    return "linear" in (getattr(cfg, "layer_types", None) or ())
+    """Whether a model keeps, beside the keys and values of its full-attention
+    layers, something of a row that exists at the row's newest positions only
+    (``models/hybrid_moe.py``: a ``layer_types`` that names ``linear``, whose
+    layers keep a recurrent state, or ``window``, whose layers keep a ring of
+    the newest pages)."""
+    return bool({"linear", "window"} & set(getattr(cfg, "layer_types", None) or ()))
 
 
 def gpt2_config(size: str = "125m", **overrides) -> TransformerConfig:

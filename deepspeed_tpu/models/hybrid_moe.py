@@ -1,25 +1,38 @@
-"""A decoder whose layers are of more than one kind: softmax attention in
-some, gated delta-rule linear attention (``ops/transformer/linear_attention.py``)
-in the others, every layer with a routed FFN that has a shared expert and may
-hold only this chip's share of the experts its router chooses from.
+"""A decoder whose layers are of more than one kind: softmax attention over
+every earlier key in some, over a sliding window in others, gated delta-rule
+linear attention (``ops/transformer/linear_attention.py``) in others; a
+routed FFN that may have a shared expert and may hold only this chip's share
+of the experts its router chooses from, behind ``leading_dense_layers`` layers
+whose FFN is dense.
 
 ``HybridMoEConfig.layer_types`` says what each layer is (``softmax`` /
-``linear``); the list repeats with a period (one softmax layer and three
-linear ones, say). Parameters are stacked by KIND inside a period and by
-period in front::
+``window`` / ``linear``); after the leading dense layers the list repeats
+with a period (one softmax layer and three linear ones, say, or five window
+layers and a softmax one). Parameters are stacked by KIND inside a period and
+by period in front; a leading layer has its own::
 
+    params["leading"][i]          {"mixer": its kind's leaves, "ffn": a dense FFN's}
     params["periods"]["softmax"]  leaves [periods, softmax layers a period, ...]
+    params["periods"]["window"]   leaves [periods, window layers a period, ...]
     params["periods"]["linear"]   leaves [periods, linear layers a period, ...]
     params["periods"]["moe"]      leaves [periods, layers a period, ...]
 
-so one ``lax.scan`` over periods runs the model, its body holding the
-period's layers in order. The functions below are the layer's mathematics,
-shared by ``HybridMoETransformerLM.apply`` (a whole sequence, no cache: what
-the parity tests use; training this family is not supported) and by the paged
-serving step (``inference/hybrid_decode.py``).
+so the leading layers and then one ``lax.scan`` over periods run the model,
+the scan's body holding the period's layers in order. The functions below are
+the layer's mathematics, shared by ``HybridMoETransformerLM.apply`` (a whole
+sequence, no cache: what the parity tests use; training this family is not
+supported) and by the paged serving step (``inference/hybrid_decode.py``).
 
-The softmax layer: ``q k v = h Wq, h Wk, h Wv``, no positional term at all
-(``position="none"``), causal softmax over ``num_kv_heads`` grouped heads,
+The softmax and the window layer (``attn_project``, ``attn_heads``): ``q k v = h Wq, h Wk, h Wv``
+as ``num_heads`` query heads and the kind's KV heads (``num_kv_heads``,
+``window_num_kv_heads``) of ``head_dim``, values of ``v_head_dim``;
+``position="rope"`` rotates the leading ``rope_dim`` features of q and k
+(rotate-half, at the token's absolute position, theta ``rope_theta`` /
+``window_rope_theta``), ``"none"`` has no positional term at all; ``v`` times
+``attn_value_scale``; causal softmax over grouped heads, in a window layer
+over the newest ``window`` keys only (itself included) and, with
+``window_sinks``, with one learned scalar a head as one more column of the
+softmax that is then dropped, so that a row's weights sum to less than one;
 ``o = (attn * sigmoid(h Wg)) Wo`` (``attn_output_gate``). The linear layer:
 ``q~ k~ v~ = h Wq, h Wk, h Wv``, each through a depthwise causal convolution
 of ``linear_conv_kernel`` taps and SiLU; per head ``q = l2norm(q~) / sqrt(Dk)``,
@@ -47,14 +60,23 @@ from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.models.moe_transformer import MoETransformerConfig, MoETransformerLM
 from deepspeed_tpu.models.transformer import _norm
 
-LAYER_KINDS = ("softmax", "linear")
+LAYER_KINDS = ("softmax", "linear", "window")
+# the named scope around a kind's mixer, which the benchmark's readers find device time by
+SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention"}
 
 
 @dataclasses.dataclass
 class HybridMoEConfig(MoETransformerConfig):
     # what each layer is; None: every layer ``softmax``
     layer_types: Optional[Sequence[str]] = None
+    leading_dense_layers: int = 0  # layers in front whose FFN is dense (``intermediate_size``), not routed
     attn_output_gate: bool = False  # softmax layers: attn * sigmoid(h Wg) before Wo
+    v_head_dim: int = 0  # a value head's width; 0: head_dim
+    attn_value_scale: float = 1.0  # v times this, before P v
+    window: int = 0  # window layers: query i sees keys j with i - window < j <= i
+    window_num_kv_heads: int = 0  # 0: num_kv_heads
+    window_rope_theta: float = 0.0  # 0: rope_theta
+    window_sinks: bool = False  # window layers: a learned scalar a head, one more column of the softmax
     linear_num_heads: int = 0  # 0: num_heads
     linear_head_dim: int = 0  # 0: head_dim (keys and values alike)
     linear_conv_kernel: int = 4
@@ -73,6 +95,13 @@ class HybridMoEConfig(MoETransformerConfig):
         self.layer_types = tuple(self.layer_types or ("softmax",) * self.num_layers)
         if len(self.layer_types) != self.num_layers or set(self.layer_types) - set(LAYER_KINDS):
             raise ValueError(f"layer_types must name {self.num_layers} layers of {LAYER_KINDS}, got {self.layer_types}")
+        self.v_head_dim = self.v_head_dim or self.head_dim
+        self.window_num_kv_heads = self.window_num_kv_heads or self.num_kv_heads
+        self.window_rope_theta = self.window_rope_theta or self.rope_theta
+        if "window" in self.layer_types and self.window < 1:
+            raise ValueError("a window layer needs window >= 1")
+        if not 0 <= self.leading_dense_layers < self.num_layers:
+            raise ValueError(f"leading_dense_layers={self.leading_dense_layers} of {self.num_layers} layers")
         self.linear_num_heads = self.linear_num_heads or self.num_heads
         self.linear_head_dim = self.linear_head_dim or self.head_dim
         self.linear_gate_rank = self.linear_gate_rank or self.linear_head_dim
@@ -86,26 +115,37 @@ class HybridMoEConfig(MoETransformerConfig):
                 f"{self.moe_router_experts // of}, not num_experts={self.num_experts}"
             )
         if self.moe_layer_freq != 1 or self.moe_drop_tokens or self.activation != "swiglu":
-            raise ValueError("a hybrid model routes every layer droplessly through SwiGLU experts: "
-                             "moe_layer_freq=1, moe_drop_tokens=False, activation='swiglu'")
-        if self.position != "none" or self.use_bias or self.norm != "rmsnorm":
-            raise ValueError("a hybrid model is pre-norm RMSNorm without biases and without a positional term (position='none')")
+            raise ValueError("a hybrid model routes every layer behind its leading dense ones droplessly through "
+                             "SwiGLU experts: moe_layer_freq=1, moe_drop_tokens=False, activation='swiglu'")
+        if self.position not in ("none", "rope") or self.use_bias or self.norm != "rmsnorm":
+            raise ValueError("a hybrid model is pre-norm RMSNorm without biases, with rotary positions or none (position='rope'|'none')")
 
     @property
     def period(self) -> Tuple[str, ...]:
-        """The shortest prefix of ``layer_types`` that the list repeats."""
-        L = self.num_layers
-        for n in range(1, L + 1):
-            if L % n == 0 and all(self.layer_types[i] == self.layer_types[i % n] for i in range(L)):
-                return tuple(self.layer_types[:n])
+        """The shortest prefix that the layers behind the leading dense ones repeat."""
+        body = self.layer_types[self.leading_dense_layers :]
+        for n in range(1, len(body) + 1):
+            if len(body) % n == 0 and all(body[i] == body[i % n] for i in range(len(body))):
+                return tuple(body[:n])
         raise AssertionError
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // len(self.period)
+        return (self.num_layers - self.leading_dense_layers) // len(self.period)
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.leading_dense_layers
 
     def layers_of(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)
+
+    def leading_of(self, kind: str) -> int:
+        """Leading dense layers of ``kind``: they have the first entries of the kind's cache."""
+        return sum(t == kind for t in self.layer_types[: self.leading_dense_layers])
+
+    def kv_heads_of(self, kind: str) -> int:
+        return self.window_num_kv_heads if kind == "window" else self.num_kv_heads
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -114,6 +154,28 @@ class HybridMoEConfig(MoETransformerConfig):
 
 
 # --- the layers' mathematics -------------------------------------------------------
+
+
+def attn_project(p, h):
+    """A softmax or window layer's projections of the normed ``h`` [..., H],
+    heads side by side: ``h Wq, h Wk, h Wv``."""
+    return qmatmul(h, p["wq"]), qmatmul(h, p["wk"]), qmatmul(h, p["wv"])
+
+
+def attn_heads(cfg: HybridMoEConfig, kind: str, q, k, v, positions):
+    """``attn_project``'s three as heads, ``q`` [B, T, NH, D], ``k`` [B, T,
+    NKV, D], ``v`` [B, T, NKV, Dv], at ``positions`` [B, T]: the leading
+    ``rope_dim`` features of q and k rotated with the kind's theta, v scaled."""
+    from deepspeed_tpu.models.transformer import _rope
+
+    NH, NKV, D, Dv = cfg.num_heads, cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
+    q, k, v = (a.reshape(a.shape[:-1] + shape) for a, shape in zip((q, k, v), ((NH, D), (NKV, D), (NKV, Dv))))
+    if cfg.position == "rope":
+        theta = cfg.window_rope_theta if kind == "window" else cfg.rope_theta
+        q, k = _rope(q, positions, theta, cfg.rope_dim), _rope(k, positions, theta, cfg.rope_dim)
+    if cfg.attn_value_scale != 1.0:
+        v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
+    return q, k, v
 
 
 def softmax_gate(p, h, attn):
@@ -207,52 +269,57 @@ class HybridMoETransformerLM(MoETransformerLM):
     def init(self, rng, batch) -> Dict[str, Any]:
         cfg = self.config
         H, L, V = cfg.hidden_size, cfg.num_layers, cfg.vocab_size
-        NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        NH, D, Dv = cfg.num_heads, cfg.head_dim, cfg.v_head_dim
         LH, LD, r, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_gate_rank, cfg.linear_conv_kernel
         E, ER, I = cfg.num_experts, cfg.moe_router_experts, cfg.expert_intermediate_size
         NP, period = cfg.num_periods, cfg.period
-        ns, nl, n = period.count("softmax"), period.count("linear"), len(period)
-        keys = iter(jax.random.split(rng, 40))
+        n = len(period)
+        keys = iter(jax.random.split(rng, 40 + 24 * cfg.leading_dense_layers))
         std, out_std = 0.02, 0.02 / np.sqrt(2 * L)
 
         def dense(shape, s=std):
             return jax.random.normal(next(keys), shape, jnp.float32) * s
 
-        periods: Dict[str, Any] = {}
-        if ns:
-            soft = {
-                "attn_norm_scale": jnp.ones((NP, ns, H)),
-                "wq": dense((NP, ns, H, NH * D)),
-                "wk": dense((NP, ns, H, NKV * D)),
-                "wv": dense((NP, ns, H, NKV * D)),
-                "wo": dense((NP, ns, NH * D, H), out_std),
+        def mixer(kind, *lead):
+            """The leaves of one kind of mixer, each behind the axes ``lead``."""
+            if kind == "linear":
+                C = LH * LD
+                # the decay's initial rate and bias as the family's modelling code draws
+                # them: a rate of 1..16 a head, a step of 1e-3..1e-1 a channel
+                dt = jnp.exp(jax.random.uniform(next(keys), lead + (C,), minval=np.log(1e-3), maxval=np.log(1e-1)))
+                return {
+                    "attn_norm_scale": jnp.ones(lead + (H,)),
+                    "wq": dense(lead + (H, C)),
+                    "wk": dense(lead + (H, C)),
+                    "wv": dense(lead + (H, C)),
+                    "conv_q": dense(lead + (K, C), 0.5),
+                    "conv_k": dense(lead + (K, C), 0.5),
+                    "conv_v": dense(lead + (K, C), 0.5),
+                    "wf_down": dense(lead + (H, r)),
+                    "wf_up": dense(lead + (r, C)),
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+                    "A_log": jnp.log(jax.random.uniform(next(keys), lead + (LH,), minval=1.0, maxval=16.0)),
+                    "wb": dense(lead + (H, LH)),
+                    "wg_down": dense(lead + (H, r)),
+                    "wg_up": dense(lead + (r, C)),
+                    "o_norm_scale": jnp.ones(lead + (LD,)),
+                    "wo": dense(lead + (C, H), out_std),
+                }
+            NKV = cfg.kv_heads_of(kind)
+            attn = {
+                "attn_norm_scale": jnp.ones(lead + (H,)),
+                "wq": dense(lead + (H, NH * D)),
+                "wk": dense(lead + (H, NKV * D)),
+                "wv": dense(lead + (H, NKV * Dv)),
+                "wo": dense(lead + (NH * Dv, H), out_std),
             }
             if cfg.attn_output_gate:
-                soft["wg"] = dense((NP, ns, H, NH * D))
-            periods["softmax"] = soft
-        if nl:
-            C = LH * LD
-            # the decay's initial rate and bias as the family's modelling code draws
-            # them: a rate of 1..16 a head, a step of 1e-3..1e-1 a channel
-            dt = jnp.exp(jax.random.uniform(next(keys), (NP, nl, C), minval=np.log(1e-3), maxval=np.log(1e-1)))
-            periods["linear"] = {
-                "attn_norm_scale": jnp.ones((NP, nl, H)),
-                "wq": dense((NP, nl, H, C)),
-                "wk": dense((NP, nl, H, C)),
-                "wv": dense((NP, nl, H, C)),
-                "conv_q": dense((NP, nl, K, C), 0.5),
-                "conv_k": dense((NP, nl, K, C), 0.5),
-                "conv_v": dense((NP, nl, K, C), 0.5),
-                "wf_down": dense((NP, nl, H, r)),
-                "wf_up": dense((NP, nl, r, C)),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
-                "A_log": jnp.log(jax.random.uniform(next(keys), (NP, nl, LH), minval=1.0, maxval=16.0)),
-                "wb": dense((NP, nl, H, LH)),
-                "wg_down": dense((NP, nl, H, r)),
-                "wg_up": dense((NP, nl, r, C)),
-                "o_norm_scale": jnp.ones((NP, nl, LD)),
-                "wo": dense((NP, nl, C, H), out_std),
-            }
+                attn["wg"] = dense(lead + (H, NH * Dv))
+            if kind == "window" and cfg.window_sinks:
+                attn["sinks"] = dense(lead + (NH,))
+            return attn
+
+        periods: Dict[str, Any] = {kind: mixer(kind, NP, period.count(kind)) for kind in LAYER_KINDS if kind in period}
         moe = {
             "mlp_norm_scale": jnp.ones((NP, n, H)),
             "gate": {"wg": dense((NP, n, H, ER))},
@@ -269,23 +336,41 @@ class HybridMoETransformerLM(MoETransformerLM):
             moe["shared"] = {"w_gate": dense((NP, n, H, Is)), "w_up": dense((NP, n, H, Is)), "w_out": dense((NP, n, Is, H), out_std)}
         periods["moe"] = moe
         params = {"embed": {"tokens": dense((V, H))}, "periods": periods, "final_norm_scale": jnp.ones((H,))}
+        if cfg.leading_dense_layers:
+            Id = cfg.intermediate_size
+            params["leading"] = [
+                {
+                    "mixer": mixer(kind),
+                    "ffn": {"mlp_norm_scale": jnp.ones((H,)), "w_gate": dense((H, Id)), "w_up": dense((H, Id)),
+                            "w_out": dense((Id, H), out_std)},
+                }
+                for kind in cfg.layer_types[: cfg.leading_dense_layers]
+            ]
         if not cfg.tie_embeddings:
             params["lm_head"] = dense((H, V))
         return params
 
     # --- the cache-free forward ------------------------------------------
-    def _softmax_mixer(self, p, h):
+    def _attention_mixer(self, kind, p, h):
         cfg = self.config
         B, T, _ = h.shape
-        NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        q = qmatmul(h, p["wq"]).reshape(B, T, NKV, NH // NKV, D)
-        k = qmatmul(h, p["wk"]).reshape(B, T, NKV, D)
-        v = qmatmul(h, p["wv"]).reshape(B, T, NKV, D)
+        NH, NKV, D, Dv = cfg.num_heads, cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
+        pos = jnp.arange(T, dtype=jnp.int32)
+        q, k, v = attn_heads(cfg, kind, *attn_project(p, h), jnp.broadcast_to(pos, (B, T)))
+        q = q.reshape(B, T, NKV, NH // NKV, D)
         scale = cfg.attn_softmax_scale if cfg.attn_softmax_scale is not None else D ** -0.5
         scores = jnp.einsum("btkgd,bskd->bkgts", q, k).astype(jnp.float32) * scale
-        scores = jnp.where(jnp.tril(jnp.ones((T, T), bool)), scores, -1e30)
-        attn = jnp.einsum("bkgts,bskd->btkgd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v)
-        return qmatmul(softmax_gate(p, h, attn.reshape(B, T, NH * D)), p["wo"])
+        seen = pos[:, None] >= pos[None, :]
+        if kind == "window":
+            seen &= pos[:, None] - pos[None, :] < cfg.window
+        scores = jnp.where(seen, scores, -1e30)
+        if "sinks" in p:  # one more column a head, dropped after the softmax
+            sink = jnp.broadcast_to(p["sinks"].astype(jnp.float32).reshape(1, NKV, NH // NKV, 1, 1), scores.shape[:-1] + (1,))
+            probs = jax.nn.softmax(jnp.concatenate([scores, sink], axis=-1), axis=-1)[..., :-1]
+        else:
+            probs = jax.nn.softmax(scores, axis=-1)
+        attn = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
+        return qmatmul(softmax_gate(p, h, attn.reshape(B, T, NH * Dv)), p["wo"])
 
     def _linear_mixer(self, p, h):
         from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked
@@ -300,6 +385,7 @@ class HybridMoETransformerLM(MoETransformerLM):
 
     def apply(self, params, batch, *, rngs=None, train: bool = False, pld_theta=None, ltd_idx=None):
         from deepspeed_tpu.models.transformer import _split_batch, cross_entropy_loss
+        from deepspeed_tpu.moe.experts import apply_dense_ffn
 
         if train:
             raise NotImplementedError("training a hybrid (linear-attention) model is not supported: apply is the eval forward")
@@ -307,14 +393,23 @@ class HybridMoETransformerLM(MoETransformerLM):
         tokens, labels = _split_batch(batch)
         x = params["embed"]["tokens"].astype(self.dtype)[tokens]
 
+        def mix(x, kind, mixer):
+            h = _norm(x, mixer["attn_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+            with jax.named_scope(SCOPES[kind]):
+                out = self._linear_mixer(mixer, h) if kind == "linear" else self._attention_mixer(kind, mixer, h)
+            return x + out.astype(x.dtype)
+
+        for kind, p in zip(cfg.layer_types, params.get("leading", ())):
+            x = mix(x, kind, p["mixer"])
+            with jax.named_scope("mlp"):
+                h = _norm(x, p["ffn"]["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+                x = x + apply_dense_ffn(p["ffn"], h, cfg.activation).astype(x.dtype)
+
         def period_step(x, p):
             at = {kind: 0 for kind in LAYER_KINDS}
             for j, kind in enumerate(cfg.period):
-                mixer = jax.tree_util.tree_map(lambda a: a[at[kind]], p[kind])
+                x = mix(x, kind, jax.tree_util.tree_map(lambda a: a[at[kind]], p[kind]))
                 at[kind] += 1
-                h = _norm(x, mixer["attn_norm_scale"], None, "rmsnorm", cfg.norm_eps)
-                with jax.named_scope("attention" if kind == "softmax" else "linear_attention"):
-                    x = x + (self._softmax_mixer if kind == "softmax" else self._linear_mixer)(mixer, h).astype(x.dtype)
                 moe = jax.tree_util.tree_map(lambda a: a[j], p["moe"])
                 with jax.named_scope("mlp"):
                     out, _ = moe_ffn(cfg, moe, _norm(x, moe["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps))
@@ -356,4 +451,39 @@ def solar_open2_config(size: str = "250b", **overrides) -> HybridMoEConfig:
     base.update(overrides)
     if "layer_types" not in base:
         base["layer_types"] = ["softmax" if i % 4 == 0 else "linear" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)
+
+
+def mimo_v2_config(size: str = "v2.5", **overrides) -> HybridMoEConfig:
+    """MiMo-V2.5's language model (``XiaomiMiMo/MiMo-V2.5`` ``config.json``,
+    ``model_type: mimo_v2``): 48 layers, layers 0, 5, 11, 17, ... full causal
+    GQA of 64 query heads over 4 KV heads, the others over a sliding window of
+    128 with 8 KV heads and a learned sink a head; keys of 192 (the leading 64
+    rotated, theta 1e7 full / 1e4 window), values of 128 scaled by 0.707;
+    layer 0 a dense SwiGLU FFN of 16,384, layers 1-47 256 SwiGLU experts of
+    2,048, 8 a token by sigmoid scores with a selection bias, gates
+    normalised, no shared expert. The vision and audio towers and the three
+    multi-token-prediction layers are not part of this model. ``v2.5`` is the
+    published model whole; ``tiny`` a toy of one chip's share (4 of 16 experts
+    held) with one leading dense layer and one period for tests."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=7, num_heads=8, num_kv_heads=2, window_num_kv_heads=4, head_dim=24,
+                     v_head_dim=16, rope_dim=8, window=8, vocab_size=512, max_seq_len=256, intermediate_size=96,
+                     expert_intermediate_size=32, num_experts=4, moe_router_experts=16, moe_expert_share=(0, 4),
+                     moe_top_k=3, layer_types=["softmax"] + ["window"] * 5 + ["softmax"]),
+        "v2.5": dict(hidden_size=4096, num_layers=48, num_heads=64, num_kv_heads=4, window_num_kv_heads=8, head_dim=192,
+                     v_head_dim=128, rope_dim=64, window=128, vocab_size=152576, max_seq_len=1048576,
+                     intermediate_size=16384, expert_intermediate_size=2048, num_experts=256, moe_top_k=8),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="rope", rope_theta=1e7, window_rope_theta=1e4, activation="swiglu",
+        use_bias=False, tie_embeddings=False, attn_value_scale=0.707, window_sinks=True, leading_dense_layers=1,
+        moe_layer_freq=1, moe_drop_tokens=False, moe_norm_topk_prob=True, moe_scoring="sigmoid",
+        moe_select_bias=True, moe_shared_experts=0, moe_routed_scaling=1.0,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        # hybrid_layer_pattern: full at 0, 5 and then every sixth
+        base["layer_types"] = ["softmax" if i == 0 or i % 6 == 5 else "window" for i in range(base["num_layers"])]
     return HybridMoEConfig(**base)

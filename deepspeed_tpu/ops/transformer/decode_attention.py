@@ -300,9 +300,7 @@ def _ragged_tiles(NKV, Hg, W, P, D, maxp, itemsize, pages_per_buffer=None):
     return C, CK, TQ, HB
 
 
-def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool, v_pool,
-                   kbuf, vbuf, m_s, l_s, acc_s, fetch_sem, write_sem, slot_s,
-                   *, scale, P, C, CK, TQ, HB, Hg, W):
+def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, *refs, scale, P, C, CK, TQ, HB, Hg, W, window=None, sinks=False):
     """Grid step ``g`` attends row ``g - 1`` and starts the fetch of row
     ``g``'s first pages (step 0 only fetches). The row's live pages, all kv
     heads of a page at a time, come by DMA into one half of ``kbuf`` / ``vbuf``
@@ -311,27 +309,48 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool
     written in place (the aliased inputs ``_k_in`` / ``_v_in`` are the same
     memory).
 
+    ``window`` (static): a query sees the newest ``window`` keys only, and the
+    walk starts at the page of the first key the row's first query sees, not
+    at page 0. ``sinks`` (static): one more input, ``[NKV, TQ, 128]`` float32,
+    a query row's head's sink in every lane: the running maximum and the
+    denominator start from it (one more column of the softmax, value nothing)
+    and not from ``-inf`` and 0. A value head may be narrower than a key head
+    (``o_ref``'s width): the window's new values ride in ``x_ref``'s leading
+    lanes. All three branch in Python, when the kernel is built: with the
+    defaults the body traces to the jaxpr it always did.
+
     The scalar arithmetic is written in ``lax`` primitives: a ``jnp`` function
     or an operator on a traced value is a nested ``jit`` trace, five times the
     price, and this body is traced for every serving program of every process
     (``PERF.md`` section 6, PR 26: what no compilation cache holds)."""
     add, sub, mul, div, lt, gt = lax.add, lax.sub, lax.mul, lax.div, lax.lt, lax.gt
+    if sinks:
+        sink_ref, *refs = refs
+    _k_in, _v_in, o_ref, k_pool, v_pool, kbuf, vbuf, m_s, l_s, acc_s, fetch_sem, write_sem, slot_s = refs
     g = pl.program_id(0)
     R = pl.num_programs(0) - 1
-    NKV, rows, D = o_ref.shape
+    NKV, rows, Dv = o_ref.shape
     TK = CK * P
     pools = ((k_pool, kbuf), (v_pool, vbuf))  # a pool and the double buffer its pages come into
 
-    def pages_of(row, there):  # what the kernel walks of a row: nothing of a dead one
+    def pages_of(row, there):  # where the walk of a row ends: nowhere for a dead one
         walked = lax.bitwise_and(there, gt(qlen_ref[row], 0))
         return lax.select(walked, div(add(len_ref[row], P - 1), P), 0)
+
+    def first_page(row):  # where it starts: the page of the first key the row's first query sees
+        return div(lax.max(sub(sub(len_ref[row], qlen_ref[row]), window - 1), 0), P)
 
     r, nxt = lax.max(sub(g, 1), 0), lax.min(g, R - 1)
     kv_len = len_ref[r]
     start = sub(kv_len, qlen_ref[r])  # the row's write base
     n_pages = pages_of(r, gt(g, 0))
-    n_buf = div(add(n_pages, C - 1), C)
+    page0 = 0 if window is None else first_page(r)
+    n_buf = div(add(n_pages if window is None else lax.max(sub(n_pages, page0), 0), C - 1), C)
     next_pages = pages_of(nxt, lt(g, R))
+    next_first = 0
+    if window is not None:
+        next_first = first_page(nxt)
+        next_pages = sub(next_pages, next_first)  # how many, from there
     n_live = div(add(mul(qlen_ref[r], Hg), TQ - 1), TQ)  # query tiles that hold a real token
 
     def page_rows(c):
@@ -367,13 +386,13 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool
 
     def half(b, _):
         slot = lax.bitwise_and(add(slot0, b), 1)
-        first = mul(b, C)  # the half's first table slot
+        first = mul(b, C) if window is None else add(page0, mul(b, C))  # the half's first table slot
         base = mul(first, P)  # and its first key's position
         # the next half's pages, the row's own or else the next row's first, are
         # asked for before this half's are waited for: two halves in flight
         own = lt(add(b, 1), n_buf)
         fetch(
-            lax.select(own, r, nxt), lax.select(own, add(first, C), 0), sub(1, slot),
+            lax.select(own, r, nxt), lax.select(own, add(first, C), next_first), sub(1, slot),
             lax.min(lax.select(own, sub(n_pages, add(first, C)), next_pages), C),
         )
         fetch(r, first, slot, lax.min(sub(n_pages, first), C), wait=True)
@@ -398,6 +417,8 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool
             hit = (pos[:, :1] >= start) & (pos[:, :1] < kv_len)
             for n, buf in enumerate((kbuf, vbuf)):
                 new = x_ref[:, W * (Hg + n) : W * (Hg + n + 1), :].astype(buf.dtype)  # [NKV, W, D]
+                if new.shape[-1] != buf.shape[-1]:  # values narrower than keys: their leading lanes
+                    new = new[..., : buf.shape[-1]]
                 if W > 1:
                     # one product term a row at most, so exact in the pool's dtype
                     new = lax.dot_general(
@@ -433,6 +454,8 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool
                     )  # [HB, TQ, TK]
                     kv_pos = add(lax.broadcasted_iota(jnp.int32, (TQ, TK), 1), add(base, key0))
                     live = lax.bitwise_and(lax.le(kv_pos, q_pos), lt(kv_pos, kv_len))
+                    if window is not None:
+                        live = lax.bitwise_and(live, lt(sub(q_pos, kv_pos), window))
                     s = jnp.where(live, mul(s, scale), NEG_INF)
                     m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
                     corr = jnp.exp(m - m_new)
@@ -448,8 +471,9 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool
                 m, l, acc = lax.fori_loop(
                     0, lax.clamp(0, div(add(seen, TK - 1), TK), C // CK), key_tile,
                     (
-                        jnp.where(first_half, NEG_INF, m_s[heads, tile, :1]),
-                        jnp.where(first_half, 0.0, l_s[heads, tile, :1]),
+                        # a tile holds whole groups, so its rows' heads are every tile's
+                        jnp.where(first_half, sink_ref[heads, :, :1] if sinks else NEG_INF, m_s[heads, tile, :1]),
+                        jnp.where(first_half, 1.0 if sinks else 0.0, l_s[heads, tile, :1]),
                         jnp.where(first_half, 0.0, acc_s[heads, tile, :]),
                     ),
                 )
@@ -478,17 +502,30 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, _k_in, _v_in, o_ref, k_pool
 
 
 def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dtype, interpret,
-                          pages_per_buffer=None):
-    """``_ragged_kernel`` over ``R + 1`` steps, the pools left where they are."""
+                          pages_per_buffer=None, window=None, sinks=None):
+    """``_ragged_kernel`` over ``R + 1`` steps, the pools left where they are.
+    ``sinks`` [NKV * Hg] float32 or None."""
     R, NKV, _, D = x.shape
-    P, maxp = pools[0].shape[2], pages.shape[1]
+    P, maxp, Dv = pools[0].shape[2], pages.shape[1], pools[1].shape[3]
     itemsize = jnp.dtype(pools[0].dtype).itemsize
+    if window is not None and pages_per_buffer is None:
+        # a row's walk is the window's pages and the step's own: no half needs more
+        maxp = min(maxp, -(-(window - 1) // P) + -(-W // P) + 1)
     C, CK, TQ, HB = _ragged_tiles(NKV, Hg, W, P, D, maxp, itemsize, pages_per_buffer)
     kernel = functools.partial(
         _ragged_kernel, scale=scale, P=P, C=C, CK=CK, TQ=TQ, HB=HB, Hg=Hg, W=W
     )
     half = (2, NKV, C * P, D)
     stats = (NKV, W * Hg, 128)
+    operands, extra_specs = [x], []
+    if window is not None or sinks is not None:
+        kernel = functools.partial(kernel, window=window, sinks=sinks is not None)
+    if sinks is not None:
+        if TQ % Hg:
+            raise ValueError(f"sinks need query tiles of whole groups: {TQ} rows a tile, {Hg} heads a group")
+        by_row = jnp.tile(sinks.astype(jnp.float32).reshape(NKV, 1, Hg), (1, TQ // Hg, 1)).reshape(NKV, TQ, 1)
+        operands.append(jnp.broadcast_to(by_row, (NKV, TQ, 128)))
+        extra_specs.append(pl.BlockSpec((NKV, TQ, 128), lambda g, pt, ln, ql: (0, 0, 0)))
     params = {}
     if not interpret:
         held = (
@@ -509,14 +546,14 @@ def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dty
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(R + 1,),
-        in_specs=[pl.BlockSpec((None, NKV, W * (Hg + 2), D), row_block), pool, pool],
-        out_specs=[pl.BlockSpec((None, NKV, W * Hg, D), row_block), pool, pool],
+        in_specs=[pl.BlockSpec((None, NKV, W * (Hg + 2), D), row_block), *extra_specs, pool, pool],
+        out_specs=[pl.BlockSpec((None, NKV, W * Hg, Dv), row_block), pool, pool],
         scratch_shapes=[
             pltpu.VMEM(half, pools[0].dtype),
-            pltpu.VMEM(half, pools[1].dtype),
+            pltpu.VMEM(half[:3] + (Dv,), pools[1].dtype),
             pltpu.VMEM(stats, jnp.float32),
             pltpu.VMEM(stats, jnp.float32),
-            pltpu.VMEM((NKV, W * Hg, D), jnp.float32),
+            pltpu.VMEM((NKV, W * Hg, Dv), jnp.float32),
             pltpu.SemaphoreType.DMA((2, 2)),  # fetches: a half, keys or values
             pltpu.SemaphoreType.DMA((2,)),  # write-backs: keys or values
             pltpu.SMEM((1,), jnp.int32),
@@ -525,14 +562,14 @@ def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dty
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, NKV, W * Hg, D), out_dtype)]
+        out_shape=[jax.ShapeDtypeStruct((R, NKV, W * Hg, Dv), out_dtype)]
         + [jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in pools],
-        # operands count from the scalars: the pools are the 5th and 6th
-        input_output_aliases={4: 1, 5: 2},
+        # operands count from the scalars: the pools are the last two inputs
+        input_output_aliases={3 + len(operands): 1, 4 + len(operands): 2},
         interpret=interpret,
         name="ragged_paged_attention",
         **params,
-    )(pages, lens, qlens, x, *pools)
+    )(pages, lens, qlens, *operands, *pools)
 
 
 def ragged_paged_attention(
@@ -548,6 +585,8 @@ def ragged_paged_attention(
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
     pages_per_buffer: Optional[int] = None,
+    window: Optional[int] = None,
+    sinks: Optional[jnp.ndarray] = None,
 ):
     """One ragged kernel for mixed prefill-chunk / decode / verify rows that
     writes the step's keys and values into the pool and attends over it.
@@ -595,22 +634,40 @@ def ragged_paged_attention(
     lanes, and Mosaic refuses a DMA of a page of it, whole or in part ("Slice
     shape along dimension 3 must be aligned to tiling (128), but is 64").
 
-    Returns ``(out [R, W, NH, D], k_pages, v_pages)``. Window slots past
+    A value head may have another width than a key head (``v_new`` and
+    ``v_pages`` end in ``Dv``, the result too), and the key pool may be wider
+    than ``q`` and ``k_new`` (a head of 192 stored at 256 lanes, so that a page
+    is whole lane tiles): they are padded with zeros here, which leaves every
+    product what it was. ``window`` (static): a query sees the newest
+    ``window`` keys only, itself included, and a row's walk starts at the
+    page of the first key its first query sees; the page table then may be a
+    ring (table slot i on page ``i % n``) as long as no walk spans more than
+    ``n`` pages. ``sinks`` [NH]: a scalar a head that enters the softmax as
+    one more column with no value (a row's weights sum to less than one).
+    These are static choices made when the kernel is built; neither works
+    through the ``_ragged_by_grid`` fallback.
+
+    Returns ``(out [R, W, NH, Dv], k_pages, v_pages)``. Window slots past
     ``q_lens[r]`` are not written and produce garbage rows the caller ignores
     (finite: masked softmax over the live prefix, or zeros); rows with
     ``q_lens[r] == 0`` return exact zeros. ``pages_per_buffer`` overrides the
     size of a half (tests, ``tools/ragged_kernel_bench.py``)."""
-    R, W, NH, D = q.shape
-    L, NP, NKV, P, Dk = k_pages.shape
-    assert Dk == D and v_pages.shape == k_pages.shape and v_pages.dtype == k_pages.dtype
-    assert k_new.shape == v_new.shape == (R, W, NKV, D)
+    R, W, NH, Dq = q.shape
+    L, NP, NKV, P, D = k_pages.shape
+    Dv = v_pages.shape[-1]
+    assert Dq <= D and v_pages.shape == k_pages.shape[:-1] + (Dv,) and v_pages.dtype == k_pages.dtype
+    assert k_new.shape == (R, W, NKV, Dq) and v_new.shape == (R, W, NKV, Dv) and Dv <= D
     if NH % NKV:
         raise ValueError(f"query heads {NH} not a multiple of kv heads {NKV}")
     maxp = page_table.shape[1]
-    scale_f = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
+    scale_f = float(scale) if scale is not None else 1.0 / float(np.sqrt(Dq))
     if interpret is None:
         interpret = not on_tpu()
     Hg = NH // NKV
+    plain = Dq == D == Dv and window is None and sinks is None
+    if not plain:  # everything in the one operand at the key pool's width
+        lanes = lambda a: jnp.pad(a, ((0, 0),) * 3 + ((0, D - a.shape[-1]),))
+        q, k_new, v_new = lanes(q), lanes(k_new), lanes(v_new)
     # one operand a row: the queries W-major (slot w of group head h at
     # sublane w*Hg + h), then the window's keys, then its values — rounded
     # to the pool's dtype first, in a dtype that holds both exactly
@@ -622,19 +679,25 @@ def ragged_paged_attention(
     )
     lens = jnp.broadcast_to(jnp.asarray(kv_lens, jnp.int32), (R,))
     qlens = jnp.broadcast_to(jnp.asarray(q_lens, jnp.int32), (R,))
-    pools = [k_pages.reshape(L * NP, NKV, P, D), v_pages.reshape(L * NP, NKV, P, D)]
+    pools = [k_pages.reshape(L * NP, NKV, P, D), v_pages.reshape(L * NP, NKV, P, Dv)]
     shared = dict(scale=scale_f, Hg=Hg, W=W, out_dtype=q.dtype, interpret=interpret)
-    if D % 128 == 0:  # a page of all kv heads is a slab a DMA can address
+    if D % 128 == 0 and Dv % 128 == 0:  # a page of all kv heads is a slab a DMA can address
+        if not plain:
+            shared.update(window=window, sinks=sinks)
         o, new_k, new_v = _ragged_by_live_pages(
             x, _pages_in_stack(layer, page_table, NP), lens, qlens, pools,
             pages_per_buffer=pages_per_buffer, **shared,
+        )
+    elif not plain:
+        raise NotImplementedError(
+            f"a window, sinks or a value width of its own need pages of whole lane tiles: keys {D}, values {Dv}"
         )
     else:
         trash_last = jnp.pad(page_table, ((0, 0), (0, 1)), constant_values=-1)
         o, new_k, new_v = _ragged_by_grid(
             x, _pages_in_stack(layer, trash_last, NP), lens, qlens, pools, **shared
         )
-    o = o.reshape(R, NKV, W, Hg, D).transpose(0, 2, 1, 3, 4).reshape(R, W, NH, D)
+    o = o.reshape(R, NKV, W, Hg, Dv).transpose(0, 2, 1, 3, 4).reshape(R, W, NH, Dv)
     return o, new_k.reshape(k_pages.shape), new_v.reshape(v_pages.shape)
 
 

@@ -111,6 +111,8 @@ def ragged_paged_attention(
     q_lens: jnp.ndarray,  # [R] real tokens in the window (0 = dead row)
     scale: Optional[float] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
+    sinks: Optional[jnp.ndarray] = None,
 ):
     """Unified mixed-row write-and-attend for the one-program ragged serving
     step (arXiv 2604.15464): row r's ``q_lens[r]`` new keys and values go
@@ -122,19 +124,29 @@ def ragged_paged_attention(
     changes the program. ``impl``: ``auto`` picks the fused Pallas kernel on
     TPU and XLA's scatter + gather elsewhere; ``pallas`` / ``xla`` force one
     (``pallas`` off-TPU runs in interpret mode — tests only). Both leave the
-    same bytes in every page but the trash page. Returns
-    ``(out [R, W, NH, D], k_pages, v_pages)``: rows with ``kv_lens == 0``
+    same bytes in every page but the trash page. ``window`` (static: a query
+    sees the newest ``window`` keys only, itself included), ``sinks`` ([NH]:
+    one more softmax column a head, with no value), a value head narrower
+    than a key head and a key pool wider than ``q`` (zero lanes) are the
+    kernel's (``decode_attention.ragged_paged_attention``). Returns
+    ``(out [R, W, NH, Dv], k_pages, v_pages)``: rows with ``kv_lens == 0``
     are exact zeros; window slots past ``q_lens`` are garbage the caller
     ignores."""
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
+    extras = {} if window is None and sinks is None else dict(window=window, sinks=sinks)
     if impl == "pallas":
         return _pallas_ragged_paged(
-            q, k_new, v_new, k_pages, v_pages, layer, page_table, kv_lens, q_lens, scale=scale
+            q, k_new, v_new, k_pages, v_pages, layer, page_table, kv_lens, q_lens, scale=scale, **extras
         )
     if impl != "xla":
         raise ValueError(f"unknown ragged attention impl {impl!r}; expected auto|pallas|xla")
     R, W = q.shape[:2]
+    if scale is None:
+        scale = _scale_or_default(None, q.shape[-1])
+    if k_pages.shape[-1] > q.shape[-1]:  # a key head stored wider than it is: zero lanes
+        lanes = ((0, 0),) * 3 + ((0, k_pages.shape[-1] - q.shape[-1]),)
+        q, k_new = jnp.pad(q, lanes), jnp.pad(k_new, lanes)
     lens = jnp.asarray(kv_lens, jnp.int32)
     qlens = jnp.asarray(q_lens, jnp.int32)
     # absolute query positions: the row's write base (kv_len - q_len) plus
@@ -146,7 +158,7 @@ def ragged_paged_attention(
     k_pages = scatter_pages(k_pages, layer, k_new, page_table, q_positions, valid)
     v_pages = scatter_pages(v_pages, layer, v_new, page_table, q_positions, valid)
     out = paged_prefill_attention(
-        q, k_pages, v_pages, layer, page_table, q_positions, lens, scale=scale
+        q, k_pages, v_pages, layer, page_table, q_positions, lens, scale=scale, **extras
     )
     return out, k_pages, v_pages
 
@@ -160,6 +172,8 @@ def paged_prefill_attention(
     q_positions: jnp.ndarray,  # [B, T] absolute positions of the chunk tokens
     kv_lens: jnp.ndarray,  # [B] live kv bound (incl. the slab)
     scale: Optional[float] = None,
+    window: Optional[int] = None,
+    sinks: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Causal slab attention over each sequence's own pages: query at
     absolute position p sees kv positions <= p (the slab's k/v have already
@@ -186,8 +200,14 @@ def paged_prefill_attention(
     lens = jnp.asarray(kv_lens, jnp.int32)
     mask = q_positions[:, None, None, :, None] >= kv_pos[None, None, None, None, :]
     mask = mask & (kv_pos[None, None, None, None, :] < lens[:, None, None, None, None])
+    if window is not None:
+        mask = mask & (q_positions[:, None, None, :, None] - kv_pos[None, None, None, None, :] < window)
     scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    if sinks is not None:  # one more column a head, dropped after the softmax
+        column = jnp.broadcast_to(sinks.astype(jnp.float32).reshape(1, NKV, G, 1, 1), scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([scores, column], axis=-1), axis=-1)[..., :-1].astype(v.dtype)
+    else:
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
-    out = out.reshape(B, T, NH, D)
+    out = out.reshape(B, T, NH, v.shape[-1])
     return jnp.where((lens > 0)[:, None, None, None], out, 0)

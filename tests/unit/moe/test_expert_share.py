@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, moe_ffn, solar_open2_config
+from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, mimo_v2_config, moe_ffn, solar_open2_config
 from deepspeed_tpu.moe.routed_ffn import route, routed_ffn
 
 S, H, I, E, K = 48, 32, 24, 16, 4
@@ -116,8 +116,57 @@ def test_eight_shares_plus_the_shared_expert_once_are_the_uncut_model_layer():
     assert float(jnp.abs(brute - whole).max()) < TOL
 
 
+def test_sixteen_shares_without_a_shared_expert_are_the_uncut_model_layer():
+    """The first share with nothing that every chip computes alike: sixteen
+    configs that differ in ``moe_expert_share`` alone, each given its slice of
+    the uncut layer's expert stacks (2 of 32 experts, 8 a token); the sixteen
+    outputs add up to the uncut layer with nothing to count once, a token
+    whose 8 choices all lie elsewhere gets exact zeros from a share, and the
+    plain reference's loop over a share's held experts gives that share's part."""
+    from benchmark.files import load_module
+
+    kw = dict(num_experts=32, moe_router_experts=32, moe_expert_share=(0, 1), moe_top_k=8, dtype="float32")
+    whole_cfg = mimo_v2_config("tiny", **kw)
+    assert whole_cfg.moe_shared_experts == 0
+    lm = HybridMoETransformerLM(whole_cfg)
+    p = jax.tree_util.tree_map(lambda a: a[0, 2], lm.init(jax.random.PRNGKey(3), None)["periods"]["moe"])
+    assert "shared" not in p
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, whole_cfg.hidden_size))
+    whole, counts = moe_ffn(whole_cfg, p, h)
+    assert int(counts.sum()) == 2 * 24 * 8
+    tokens = h.reshape(-1, whole_cfg.hidden_size)
+    weights = _weights(tokens @ p["gate"]["wg"], p["gate"]["bias"], k=8)
+    assert float(jnp.abs(_brute(p["experts"], tokens, weights).reshape(h.shape) - whole).max()) < TOL
+    total, untouched = 0, 0
+    for index in range(16):
+        cfg = dataclasses.replace(whole_cfg, num_experts=2, moe_expert_share=(index, 16))
+        mine = {**p, "experts": jax.tree_util.tree_map(lambda a: a[index * 2 : index * 2 + 2], p["experts"])}
+        out, held = moe_ffn(cfg, mine, h)
+        assert held.shape == (2,) and np.array_equal(held, counts[index * 2 : index * 2 + 2])
+        part = _brute(mine["experts"], tokens, weights[:, index * 2 : index * 2 + 2]).reshape(h.shape)
+        assert float(jnp.abs(out - part).max()) < TOL
+        nothing_here = np.asarray(weights[:, index * 2 : index * 2 + 2].sum(-1) == 0).reshape(h.shape[:2])
+        assert np.all(np.asarray(out)[nothing_here] == 0.0)
+        untouched += int(nothing_here.sum())
+        total = total + out
+    assert untouched > 0
+    assert float(jnp.abs(total - whole).max()) < TOL
+    # the reference's routed FFN for one share: its router over the whole width, its loop over the held
+    ref = load_module("reference", "mimo_v2_decoder")
+    x = jnp.zeros_like(tokens)
+    gate = {"mlp_norm_scale": jnp.ones((whole_cfg.hidden_size,)), "gate": p["gate"]}
+    hn, w = ref._router(tokens, gate, arch_key=(("experts_per_token", 8), ("norm_eps", 1e-5), ("routed_scaling", 1.0)))
+    mine = jax.tree_util.tree_map(lambda a: a[6:8], p["experts"])
+    out = x
+    for e in range(2):
+        out = ref._add_expert(out, hn, w[..., 6 + e], mine["w_gate"][e], mine["w_up"][e], mine["w_out"][e])
+    norm = tokens * jax.lax.rsqrt(jnp.mean(tokens * tokens, -1, keepdims=True) + 1e-5)
+    share3, _ = moe_ffn(dataclasses.replace(whole_cfg, num_experts=2, moe_expert_share=(3, 16)), {**p, "experts": mine}, norm.reshape(h.shape))
+    assert float(jnp.abs(out.reshape(h.shape) - share3).max()) < TOL
+
+
 def test_a_share_that_is_not_a_share_is_refused():
     with pytest.raises(ValueError, match="holds"):
         solar_open2_config("tiny", num_experts=3, moe_router_experts=8, moe_expert_share=(0, 2))
     with pytest.raises(ValueError, match="layer_types"):
-        solar_open2_config("tiny", layer_types=["softmax", "window", "linear", "linear"])
+        solar_open2_config("tiny", layer_types=["softmax", "latent", "linear", "linear"])

@@ -354,6 +354,7 @@ def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, mon
     ``kda_decode`` kernel, which must not open with three ``s32`` operands
     (the ragged attention kernel's signature for the accepted readers)."""
     from deepspeed_tpu.inference import hybrid_decode
+    from deepspeed_tpu.inference.kv_pool import StateStore
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
 
     for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul",
@@ -374,8 +375,9 @@ def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, mon
     shapes = hybrid_decode.state_shapes(cfg, rows)
     assert shapes.state == (3, 65, 64, 128, 128)
     step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv, BF16))
     compiled = step.lower(
-        params, on_v5e((rows, width), I32), pool, pool, on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv, BF16),
+        params, on_v5e((rows, width), I32), pool, pool, store,
         on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
     ).compile()
     text = compiled.as_text()
@@ -390,6 +392,73 @@ def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, mon
         name for name, operands in kernels.items()
         if all(shape_of[o].startswith("s32[") for o in re.findall(r"%([\w.-]+)", operands)[:3])
     ]
-    assert len(opens_with_three_s32) == 1, opens_with_three_s32  # the one softmax layer's ragged kernel
+    # the one softmax layer's ragged kernel: a wide window calls it at width 1 for its one-token rows and at
+    # the window's width, a few rows a trip, for its chunk rows (hybrid_decode.wide_attention)
+    assert len(opens_with_three_s32) == (1 if width == 1 else 2), opens_with_three_s32
     assert any(name.startswith("kda_decode") for name in kernels), sorted(kernels)
+    assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
+
+
+_MIMO_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mimo-v2.5-l7-ep16.json"
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_mimo_v2_ragged_step_fits_and_every_attention_layer_walks_live_pages(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the MiMo-V2.5 cell's shapes (a leading dense
+    layer and one period: two full layers of 64 query heads over 4 KV heads,
+    five window layers over 8 with sinks, keys of 192 stored at 256 lanes,
+    values of 128, 16 held experts of 2,048 of a router over 256, 64 rows,
+    4,097 pages of 64, rings of 4 pages a slot): it compiles for a v5e, the
+    pages AND the rings stay aliased in to out, weights + pools + temporaries
+    fit the chip, and all seven attention calls are the live-pages kernel (three
+    ``s32`` operands in front: table, lengths, q lengths), none the grid
+    fallback, which a 192-wide page would take."""
+    from deepspeed_tpu.inference import hybrid_decode
+    from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
+    from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
+    from deepspeed_tpu.ops.transformer import decode_attention
+
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul"):
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    monkeypatch.setattr(decode_attention, "_ragged_by_grid", None)  # reaching it would raise
+    conf = json.loads(_MIMO_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = HybridMoEConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    ring = window_ring_pages(cfg.window, page, paged["prefill_chunk"])
+    assert ring == 4 and key_lanes(cfg.head_dim) == 256
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
+    k_pool = on_v5e((2, rows * maxp + 1, 4, page, 256), BF16)
+    v_pool = on_v5e((2, rows * maxp + 1, 4, page, 128), BF16)
+    shapes = hybrid_decode.state_shapes(cfg, rows)
+    wk, wv = hybrid_decode.window_shapes(cfg, rows, page, ring)
+    assert wk == (5, 257, 8, 64, 256) and wv == (5, 257, 8, 64, 128)
+    store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv, BF16), on_v5e(wk, BF16), on_v5e(wv, BF16))
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), k_pool, v_pool, store,
+        on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+    first_pool = len(jax.tree_util.tree_leaves(params)) + 1
+    aliased = parse_input_output_aliases(text)
+    assert {first_pool, first_pool + 1, first_pool + 4, first_pool + 5} <= aliased, aliased  # pages and rings (state, conv: empty)
+    memory = compiled.memory_analysis()
+    print(f"mimo w{width}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB")
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+)", text, flags=re.M))
+    kernels = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*? custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text, flags=re.M))
+    opens_with_three_s32 = [
+        name for name, operands in kernels.items()
+        if all(shape_of[o].startswith("s32[") for o in re.findall(r"%([\w.-]+)", operands)[:3])
+    ]
+    assert len(opens_with_three_s32) == (7 if width == 1 else 14), opens_with_three_s32  # a wide window: two calls a layer
+    # the 64 x 128 window is never laid out: no operand or result of its size (64 x 128 x 64 heads x 256 lanes)
+    assert not re.search(r"bf16\[64,128,(12288|16384)\]|bf16\[64,[48],128,(8|16),256\]", text)
     assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
