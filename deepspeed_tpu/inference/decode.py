@@ -727,6 +727,49 @@ def _accepted_prefix(tokens, greedy, n_drafts):
     return jnp.sum(jnp.cumprod(matches.astype(jnp.int32), axis=1), axis=1)
 
 
+_token_feed_cache: Dict[Any, Tuple[Any, Any]] = {}
+
+
+def build_token_feed(tp=None) -> Tuple[Any, Any]:
+    """``(next_tokens, feed_tokens)``: the two small programs that carry a
+    row's next decode token from one ragged step's result to the next
+    step's window without the host reading it, so that the scheduler can
+    enqueue step n+1 before it fetches step n (``scheduler.py``).
+
+    * ``next_tokens(out, cols [R]) -> [R]``: ``out[r, cols[r]]`` of the
+      token rows (an MoE model's ``MOE_STAT_ROWS`` lie past them), enqueued
+      behind the step that computes ``out`` with ``cols = q_lens``: the
+      greedy token after each row's last live position.
+    * ``feed_tokens(tokens [R, W], nxt [R], src [R]) -> [R, W]``: the host's
+      window with ``tokens[i, 0]`` replaced by ``nxt[src[i]]`` wherever
+      ``src[i] >= 0``, enqueued before the step that takes the window.
+
+    The step programs' text and operands stay what they were. Each of the
+    two compiles once a shape of ONE operand (``out``'s width, the window's
+    width), so whatever runs both step programs has compiled all four.
+    Under ``tp`` the window is placed replicated on the mesh whatever fed
+    it, so the step program sees one signature from its first call on."""
+    key = None if tp is None else tp.cache_key()
+    pair = _token_feed_cache.get(key)
+    if pair is not None:
+        return pair
+
+    def next_tokens(out, cols):
+        return out[jnp.arange(cols.shape[0]), cols]
+
+    def feed_tokens(tokens, nxt, src):
+        fed = jnp.where(src >= 0, nxt[jnp.maximum(src, 0)], tokens[:, 0])
+        return tokens.at[:, 0].set(fed)
+
+    placed = {}
+    if tp is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        placed = {"out_shardings": NamedSharding(tp.mesh, PartitionSpec())}
+    pair = _token_feed_cache[key] = (jax.jit(next_tokens, **placed), jax.jit(feed_tokens, **placed))
+    return pair
+
+
 DENSE_TOKEN_TILE = 512
 MOE_ROWS_PER_EXPERT = 128
 

@@ -358,8 +358,9 @@ class FleetRouter:
         self.stats["drains"] += 1
         moved = 0
         with self.tracer.span("fleet.drain", replica=name):
-            self._collect_results()
             inner = h.inner
+            inner.settle()  # what finishes in the step in flight is a result, not a move
+            self._collect_results()
             uids = [r.uid for r in list(inner._queue)] + [
                 r.uid for r in list(inner._active)
             ]
@@ -513,6 +514,11 @@ class FleetRouter:
             chaos.point("fleet.replica_kill", replica=h.name)
             with self.tracer.span("fleet.replica_step", replica=h.name):
                 h.server.step()
+                if h.role == "prefill":
+                    # the hand-off below reads the first token's value: a
+                    # prefill replica settles the step it just dispatched, so
+                    # it never enqueues a decode step behind a prompt's last chunk
+                    h.inner.settle()
             h.failures = 0
         except chaos.ChaosKilled:
             # the replica is the failure domain: a kill unwinding out of

@@ -51,7 +51,7 @@ sequence's pages.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import List, NamedTuple, Optional, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -626,6 +626,26 @@ class PagePool:
         if first < len(self._chain_keys[slot]):
             del self._chain_keys[slot][first:]
         return True
+
+    def can_write(self, slots: Sequence[int], n_tokens: Sequence[int]) -> bool:
+        """Whether ``prepare_write(slot, seq_lens[slot] + n)`` would succeed
+        for every ``(slot, n)`` in turn with the pages reclaimable now, from
+        counts alone and with nothing changed: each slot's page growth plus a
+        copy-on-write page for every shared page in its written span. Never
+        True where a ``prepare_write`` would fail (a copy may hand its source
+        back, which is not counted): the scheduler asks before it packs a
+        step behind one still in flight, where nobody can be preempted."""
+        idx = np.asarray(slots, np.int32)
+        cur = self.seq_lens[idx]
+        new = cur + np.asarray(n_tokens, np.int32)
+        if (new > self.max_seq_len).any():
+            return False
+        P = self.page_size
+        pages = int(np.maximum(-(-new // P) - self._owned[idx], 0).sum())
+        if self.stats["prefix_hit_pages"]:  # only an attached prefix is ever shared
+            for slot, first, last in zip(idx, cur // P, np.minimum((new - 1) // P, self._owned[idx] - 1)):
+                pages += int((self._refcount[self.page_table[slot, first : last + 1]] > 1).sum())
+        return pages <= self.free_pages()
 
     def advance(self, slot: int, n_tokens: int) -> None:
         """Record ``n_tokens`` newly written to ``slot`` (pages must already

@@ -50,6 +50,36 @@ vLLM style):
   window instead of once per token (dispatches/token → 1/horizon). Any
   scheduling event breaks back to the single-step path — streams stay
   byte-identical, and ``window_break_reasons`` names every break;
+* **the host works while the device does**: a call of ``step()`` admits,
+  enqueues the step the call before it packed (n+1; packed again first if
+  someone was just admitted, so a newcomer never waits a step for having
+  come between two calls), then settles the step before that one (n, whose
+  result that call waited for), packs the step after (n+2) behind the one
+  now running, and ends with the wait for the device (``serve.fetch``). So
+  what the host does for a step but the enqueue itself runs while the
+  device is busy, the device is idle between two calls (whoever stops a
+  profiler or reads a clock there cuts no execution), and the tokens a call
+  emits are those of the step dispatched one call earlier. Everything the
+  host can know from counts is committed when a step is dispatched (a decode
+  row writes one position and a prefill row its chunk: ``pool.advance``,
+  ``consumed``, the prompt's prefix pages; which rows still have budget; the
+  width); everything a token's VALUE decides waits for the settle (the
+  stream, EOS, the journal, the policy's stamps, ``_finish``). A row's next
+  decode token never visits the host on its way: ``decode.build_token_feed``
+  gathers it on the device from the unsettled result, and the operands the
+  host makes are sent ahead at the pack. An EOS is therefore seen one step
+  late: the row already rides in the next step, whose result for it is
+  discarded (``overshoot_rows``) and whose settle releases its slot; the
+  stream is what it always was. Whenever the host needs values before it can
+  pack — a drafter or ``multi_step`` windows armed, a reservation that would
+  have to preempt, an entry point that reads or moves a request
+  (``extract_request``, ``restore_request``, ``recover``,
+  ``finalize_migration``, ``compact_journal``, ``settle``) — the unsettled
+  step is settled first and what was packed behind it dropped
+  (``drain_reasons``), and the call goes on as a synchronous server's
+  would. No knob: the depth is 1 or 0 by what the server sees in its own
+  state. ``has_work()`` counts an unsettled step and the last call of a run
+  only settles, so ``run()`` / ``serve()`` return settled streams;
 * admission order and preemption victims are delegated to a
   ``SchedulingPolicy`` (default: FIFO admission, youngest-first
   preemption — the original behavior). ``inference/traffic.py`` layers
@@ -67,6 +97,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from deepspeed_tpu.inference.decode import (
@@ -74,6 +105,7 @@ from deepspeed_tpu.inference.decode import (
     _refuse_state_layers,
     build_ragged_multistep,
     build_ragged_step,
+    build_token_feed,
     multistep_program_name,
     ragged_program_name,
     token_tiles,
@@ -196,6 +228,39 @@ class Request:
 
     def output(self) -> np.ndarray:
         return self.context().copy()
+
+
+@dataclass
+class _Packed:
+    """One ragged step packed and not yet enqueued: its rows and, already on
+    their way to the device, the operands the host makes."""
+
+    rows: List[Request]
+    chunk_len: Dict[int, int]  # uid -> chunk length, the prefill rows
+    q_lens: np.ndarray
+    width: int
+    program: str
+    live_tokens: int
+    token_tiles: int
+    step_fn: object  # the width's step program, looked up while the device is busy
+    operands: tuple  # device: the token window, then page table, lengths, q_lens (and the state slots)
+
+
+@dataclass
+class _Dispatched:
+    """One ragged step the device has been handed and the host has not yet
+    settled: what its settle needs."""
+
+    rows: List[Request]
+    out: object  # device [R (+ MOE_STAT_ROWS), W + 1], the step's one result
+    next_tokens: object  # device [R]: the greedy token after each row's last live position
+    chunk_len: Dict[int, int]
+    q_lens: np.ndarray
+    # uid -> row of every row whose next input token this step computes and
+    # nothing else (a plain decode row, a prompt's last chunk): the rows the
+    # next step can take before the settle
+    feeds: Dict[int, int]
+    waited: bool = False  # the device has finished it: a call that ran ahead ended with the wait
 
 
 class PagedServer:
@@ -357,6 +422,12 @@ class PagedServer:
         )
         self._queue: deque[Request] = deque()
         self._active: List[Request] = []  # admission order (oldest first)
+        # the dispatched step whose result has not been settled (at most
+        # one), and the step packed behind it for the next call to enqueue
+        self._in_flight: Optional[_Dispatched] = None
+        self._packed: Optional[_Packed] = None
+        self._next_tokens, self._feed_tokens = build_token_feed(tp)
+        self._no_tokens = np.zeros(max_slots, np.int32)  # what the feed takes with nothing in flight
         self._results: Dict[int, np.ndarray] = {}
         self._next_uid = 0
         # per-tenant serving observability (created lazily per tenant name):
@@ -386,6 +457,13 @@ class PagedServer:
             # spec_rounds count the dispatches that carried plain-decode /
             # drafted rows (a mixed dispatch can count as both)
             "ragged_steps": 0,
+            # ragged steps dispatched while the one before was still in
+            # flight; a row-step run for a row whose EOS the step before
+            # had produced (its result discarded); and why an in-flight step
+            # was settled before the next could be packed, by reason
+            "run_ahead_steps": 0,
+            "overshoot_rows": 0,
+            "drain_reasons": {},
             # multi-step windows: one fused horizon-round dispatch each;
             # `dispatches` counts EVERY serving dispatch (windows and
             # ragged steps) and `emitted_tokens` every generated token, so
@@ -529,6 +607,7 @@ class PagedServer:
         stats); inbound moves count under ``stats['migrated_in']``. The
         default is the fresh-process form: stamps restart with the clock
         and the counters are this server's to claim."""
+        self._drain("recover")
         recovered = 0
         for uid in sorted(states):
             st = states[uid]
@@ -600,6 +679,7 @@ class PagedServer:
         so no crash instant leaves the request claimed by neither
         journal. Returns None when the uid is not live here (already
         finished or never admitted)."""
+        self._drain("extract_request")
         req = next((r for r in self._active if r.uid == uid), None)
         if req is not None:
             self.pool.free_slot(req.slot)
@@ -638,6 +718,7 @@ class PagedServer:
         target: re-queue the state on THIS server (stamps preserved — the
         clock never changed) and undo the extraction's migration
         accounting, since nothing actually moved."""
+        self._drain("restore_request")
         self.recover({state.uid: state}, 0, migrated_in=True)
         self.stats["migrated_out"] -= 1
         self.stats["migrated_in"] -= 1
@@ -686,6 +767,7 @@ class PagedServer:
         ≤1 segment) instead of N full-state rewrites — and a single
         rebalancing move off a busy replica costs one record + sync, not
         a rewrite of every resident request."""
+        self._drain("finalize_migration")
         if self.journal is None:
             return
         self.journal.append_migrate(uid)
@@ -704,6 +786,7 @@ class PagedServer:
         ``finalize_migration`` triggers it when migrated-out garbage
         outweighs live state, so journal growth stays bounded. Returns
         the number of segments retired."""
+        self._drain("compact_journal")
         if self.journal is None:
             return 0
         self._migrated_since_compact = 0
@@ -731,7 +814,27 @@ class PagedServer:
         return self.journal.retire_older_segments()
 
     def has_work(self) -> bool:
-        return bool(self._queue or self._active)
+        """Something queued, running, or dispatched and not yet settled."""
+        return bool(self._queue or self._active or self._in_flight is not None)
+
+    def settle(self) -> None:
+        """Fetch and settle the step in flight, if any: afterwards every
+        token the device has been asked for is in its stream. For a caller
+        that reads requests between steps (the fleet router before a
+        hand-off or a drain); ``run()`` and ``serve()`` end settled anyway."""
+        self._drain("settle")
+
+    def _drain(self, reason: str) -> None:
+        """Settle the in-flight step now because the host needs its values
+        before it can go on; ``reason`` is counted in ``drain_reasons``."""
+        step, self._in_flight = self._in_flight, None
+        self._packed = None  # packed from counts the settle may overturn; nothing of it was committed
+        if step is None:
+            return
+        reasons = self.stats["drain_reasons"]
+        reasons[reason] = reasons.get(reason, 0) + 1
+        with self.tracer.span("serve.emit", drain=reason):
+            self._settle_ragged_rows(step)
 
     def result(self, uid: int) -> Optional[np.ndarray]:
         return self._results.get(uid)
@@ -743,11 +846,18 @@ class PagedServer:
 
     # --- one scheduler iteration ---------------------------------------
     def step(self) -> None:
-        """Admit what fits, then run the round's device work: ONE dispatch
-        covering every active row's next tokens (prefill chunks, pending
-        decodes, and drafted verifies together) — or, with ``multi_step``
-        armed and the running set stable, ONE fused window of ``horizon``
-        plain-decode rounds."""
+        """One scheduler round: ONE dispatch covering every active row's next
+        tokens (prefill chunks, decodes, and drafted verifies together).
+        The call admits what fits, enqueues the step the call before it
+        packed (packed again first if someone was just admitted), settles
+        the step before that one behind the enqueue, packs the next step
+        while the device runs, and ends with the wait for the device — so
+        the tokens a call emits are those of the step dispatched one call
+        earlier, and the device is idle between two calls. A first call, a
+        drained server and one with a drafter or windows armed pack and
+        enqueue in the same call. With ``multi_step`` armed and the running
+        set stable: ONE fused window of ``horizon`` plain-decode rounds,
+        settled in line."""
         waiting, running = len(self._queue), len(self._active)
         pages_in_use = self.pool.used_pages()
         self._g_waiting.set(waiting)
@@ -757,14 +867,49 @@ class PagedServer:
             "serve.step", waiting=waiting, running=running,
             pages_in_use=pages_in_use, pages_total=self.pool.num_pages - 1,
         ):
+            packed, self._packed = self._packed, None
+            if packed is None:
+                self._drain("idle")  # nothing was packed behind it: the call settles first
             with self.tracer.span("serve.admit") as admit_span:
                 admitted = self.stats["admitted"]
                 self._admit()
-                admit_span.set(admitted=self.stats["admitted"] - admitted)
-            if not (self.ms_enable and self._ragged_window()):
-                self._ragged_step(drafts=self._take_predrafts())
-            # the round's device work and emissions happened; the chaos
-            # point models dying BEFORE the journal flush — the un-synced
+                admitted = self.stats["admitted"] - admitted
+                admit_span.set(admitted=admitted)
+            if packed is not None:
+                if admitted:
+                    # whoever came since the step was packed rides in it all
+                    # the same: it is packed again, with them (the one pack
+                    # the device waits for; a request's wait for its first
+                    # chunk is a synchronous server's)
+                    packed = self._pack()
+                # the step goes to the device first, and the one before it is
+                # settled behind that enqueue
+                if packed is not None:
+                    self._dispatch(packed)
+            if self._in_flight is None and not (self.ms_enable and self._ragged_window()):
+                # nothing was packed ahead (a first step, a drained or a
+                # synchronous server): pack and enqueue in this call
+                packed = self._pack(self._take_predrafts())
+                if packed is not None:
+                    self._dispatch(packed)
+            if self._in_flight is not None:
+                # drafts are proposed from settled contexts and a window
+                # probes settled rows: with either armed the step is settled
+                # in the call that dispatched it, and the server is the
+                # synchronous one. Otherwise the next step is packed while
+                # the device runs this one, and the call ends when the
+                # device does: nothing executes between two calls
+                if self.drafter is not None:
+                    self._drain("draft")
+                elif self.ms_enable:
+                    self._drain("window")
+                else:
+                    self._packed = self._pack()
+                    if self._in_flight is not None:
+                        self._wait_ragged_rows(self._in_flight)
+            # the round's dispatch and the emissions of the step before it
+            # happened; the chaos point models dying BEFORE the journal flush
+            # (and with a step in flight, which dies unseen) — the un-synced
             # tokens are re-derived identically on recovery (greedy
             # re-prefill). A ChaosKilled unwinds through the open spans
             # (the flight recorder saw them as open at dump time).
@@ -864,10 +1009,31 @@ class PagedServer:
         drafts, self._predrafts = self._predrafts, None
         return drafts
 
-    def _ragged_step(self, drafts: Optional[Dict[int, np.ndarray]] = None) -> None:
-        """ONE dispatch for the whole round: every active row contributes
-        its next tokens — a prefill chunk, the pending decode token, or the
-        pending token plus host-side drafts — packed into a single
+    def _rows_to_pack(self) -> List[Request]:
+        """The running rows the next step carries: all of them, but for a row
+        whose budget ends with the token still in flight (it has nothing
+        left to ask for, and finishes when that step settles)."""
+        feeds = self._in_flight.feeds if self._in_flight is not None else ()
+        return [
+            r for r in self._active
+            if not (r.uid in feeds and len(r.generated) + 1 >= r.max_new_tokens)
+        ]
+
+    def _fits_without_preemption(self, rows: List[Request]) -> bool:
+        """Whether the pool can host the next step's writes as it stands. It
+        is asked only behind a step in flight, where a row decodes one token
+        or prefills its next chunk."""
+        feeds = self._in_flight.feeds
+        grow = [
+            1 if r.uid in feeds or r.pending is not None else self._next_chunk_len(r, r.context().size)
+            for r in rows
+        ]
+        return self.pool.can_write([r.slot for r in rows], grow)
+
+    def _pack(self, drafts: Optional[Dict[int, np.ndarray]] = None) -> Optional[_Packed]:
+        """Pack ONE dispatch for the whole round: every active row
+        contributes its next tokens — a prefill chunk, the pending decode
+        token, or the pending token plus host-side drafts — in a single
         ``[max_slots, W]`` window whose per-row ``(kv_len, q_len)`` metadata
         ride in as arrays. A chunk row no longer steals a step from
         decoders (they share the dispatch), spec-K varies freely per row,
@@ -876,10 +1042,24 @@ class PagedServer:
         width is the window the attention kernel sees; what the wide
         program computes besides follows the step's live tokens, in token
         tiles (``decode.token_tiles``: ``serve.pack``'s ``live_tokens`` and
-        ``token_tiles``, summed over mixed steps in ``stats``)."""
-        rows = [r for r in self._active if not r.done]
+        ``token_tiles``, summed over mixed steps in ``stats``).
+
+        Behind a step in flight the pack reads counts only: a row that
+        decoded (or finished its prompt) there takes its token from that
+        step's result on the device (``decode.build_token_feed``). The
+        operands the host makes are sent to the device here, while it is
+        busy, so that the enqueue (``_dispatch``) has nothing left to move.
+        Nothing is committed: a drain may drop what this returns."""
+        rows = self._rows_to_pack()
+        if self._in_flight is not None and rows and not self._fits_without_preemption(rows):
+            # settle first, then preempt on settled state as a synchronous
+            # server does: a victim never has a token in flight
+            self._drain("preempt")
+            rows = self._rows_to_pack()
         if not rows:
-            return
+            return None
+        prev = self._in_flight
+        feeds = prev.feeds if prev is not None else {}
         with self.tracer.span("serve.pack") as pack_span:
             if drafts is None:
                 drafts = {}
@@ -890,7 +1070,9 @@ class PagedServer:
             chunk_len: Dict[int, int] = {}
             need: Dict[int, int] = {}
             for r in rows:
-                if r.pending is None:
+                if r.uid in feeds:
+                    need[r.uid] = 1  # its token is on the device; nothing is drafted from it
+                elif r.pending is None:
                     chunk_len[r.uid] = self._next_chunk_len(r, r.context().size)
                     need[r.uid] = chunk_len[r.uid]
                 else:
@@ -900,8 +1082,8 @@ class PagedServer:
                     need[r.uid] = d.size + 1
             rows = self._reserve_for_growth(rows, need)
             if not rows:
-                return
-            mixed = any(r.pending is None for r in rows)
+                return None
+            mixed = any(r.uid in chunk_len for r in rows)
             W = self._ragged_w_mixed if mixed else self._ragged_w_decode
             # pad to the single fixed row budget — never re-bucketed; lengths
             # == consumed for prefill rows, so one write base serves every mode
@@ -909,8 +1091,14 @@ class PagedServer:
             page_table, lengths = self._dispatch_rows(rows, R)
             tokens = np.zeros((R, W), np.int32)
             q_lens = np.zeros(R, np.int32)
+            # rows whose first token is row src[i]'s of the step in flight
+            src = np.full(R, -1, np.int32)
             for i, r in enumerate(rows):
-                if r.pending is None:
+                fed = feeds.get(r.uid)
+                if fed is not None:
+                    src[i] = fed
+                    q_lens[i] = 1
+                elif r.uid in chunk_len:
                     real = chunk_len[r.uid]
                     tokens[i, :real] = r.context()[r.consumed : r.consumed + real]
                     q_lens[i] = real
@@ -931,52 +1119,116 @@ class PagedServer:
                 rows=len(rows), width=W, program=program, kv_pages=kv_pages, table_pages=page_table.size,
                 live_tokens=live_tokens, token_tiles=tiles,
             )
+            host_made = [page_table, lengths, q_lens]
             states = self.pool.states
             if states is not None:
                 # the rows' entries of the state store; dead rows go to the spare one
                 slots = np.full(R, self.pool.max_slots, np.int32)
                 slots[: len(rows)] = [r.slot for r in rows]
+                host_made.append(slots)
                 self._g_state_slots.set(len(rows))
                 pack_span.set(state_slots=len(rows), state_bytes=states.hbm_bytes() - states.window_bytes())
                 if states.window_k is not None:
                     self._g_window_slots.set(len(rows))
                     pack_span.set(window_slots=len(rows), window_bytes=states.window_bytes())
-            if mixed:
-                self.stats["mixed_steps"] += 1
-                self.stats["mixed_live_tokens"] += live_tokens
-                self.stats["mixed_token_tiles"] += tiles
-        # dispatch = build + ENQUEUE only (jit returns futures; the fetch
-        # below is where device time surfaces)
-        with self.tracer.span("serve.dispatch", rows=len(rows), width=W, program=program):
+            # the window with the in-flight rows' tokens laid in on the device
+            # (queued behind the step that computes them), and the rest
+            window = self._feed_tokens(tokens, prev.next_tokens if prev is not None else self._no_tokens, src)
+            operands = (window, *jax.device_put(host_made))
             step_fn = build_ragged_step(
                 self.cfg, R, W, self.pool.page_size, attn_impl=self.attn_impl,
                 telemetry=self.telemetry, tp=self.tp,
             )
+        return _Packed(rows, chunk_len, q_lens, W, program, live_tokens, tiles, step_fn, operands)
+
+    def _dispatch(self, packed: _Packed) -> None:
+        """Enqueue a packed step (jit returns futures; the fetch is where
+        device time surfaces) and the gather of the tokens the step after it
+        may take, commit what its counts decide, and only then settle the
+        step before it, whose result the call before waited for."""
+        prev = self._in_flight
+        rows, W = packed.rows, packed.width
+        with self.tracer.span("serve.dispatch", rows=len(rows), width=W, program=packed.program, ahead=int(prev is not None)):
+            step_fn = packed.step_fn
+            window, page_table, lengths, q_lens, *slots = packed.operands
+            states = self.pool.states
             if states is not None:
                 out, new_k, new_v, states = step_fn(
-                    self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
-                    states, page_table, lengths, q_lens, slots,
+                    self.params, window, self.pool.cache.k_pages, self.pool.cache.v_pages,
+                    states, page_table, lengths, q_lens, *slots,
                 )
                 self.pool.set_states(states)
             else:
                 out, new_k, new_v = step_fn(
-                    self.params, tokens, self.pool.cache.k_pages, self.pool.cache.v_pages,
+                    self.params, window, self.pool.cache.k_pages, self.pool.cache.v_pages,
                     page_table, lengths, q_lens,
                 )
             self.pool.set_cache(new_k, new_v)
+            self._in_flight = _Dispatched(
+                rows, out, self._next_tokens(out, q_lens), packed.chunk_len, packed.q_lens,
+                feeds=self._commit_dispatched(rows, packed.chunk_len, packed.q_lens),
+            )
         self.stats["ragged_steps"] += 1
         self.stats["dispatches"] += 1
-        with self.tracer.span("serve.emit"):
-            self._settle_ragged_rows(rows, out, chunk_len, q_lens)
+        if packed.chunk_len:
+            self.stats["mixed_steps"] += 1
+            self.stats["mixed_live_tokens"] += packed.live_tokens
+            self.stats["mixed_token_tiles"] += packed.token_tiles
+        if prev is not None:
+            self.stats["run_ahead_steps"] += 1
+            with self.tracer.span("serve.emit"):
+                self._settle_ragged_rows(prev)
 
-    def _settle_ragged_rows(self, rows, out, chunk_len, q_lens) -> None:
-        """Post-dispatch accounting for one ragged step: the budgeted host
-        fetch, then per-row advance/emit/publish."""
-        # the step's single host fetch: [R, W+1] = accepted counts + the
-        # greedy token after each position. ``serve.fetch`` is the wait for
-        # the device, ``serve.settle`` the host's own work after it
-        with self.tracer.span("serve.fetch"):
-            out = np.asarray(out)  # lint: allow(DS-R005)
+    def _commit_dispatched(self, rows, chunk_len, q_lens) -> Dict[int, int]:
+        """What a dispatch settles by counts alone, so that the next step can
+        be packed before this one's result is read: every row's written
+        positions, a prefill row's progress and the prefix pages its chunk
+        filled (their tokens are the prompt's). Returns the step's ``feeds``:
+        the rows that will hold exactly one new token, whose value only the
+        device knows yet."""
+        feeds: Dict[int, int] = {}
+        had_decode = had_spec = False
+        for i, r in enumerate(rows):
+            n = int(q_lens[i])
+            # a verify row's rejected tail rolls back at its settle
+            self.pool.advance(r.slot, n)
+            if r.uid in chunk_len:
+                ctx = r.context()
+                r.consumed += n
+                self.stats["prefill_chunks"] += 1
+                if self.prefix_cache:
+                    self.pool.register_prefix(r.slot, ctx, r.consumed)
+                if r.consumed == ctx.size:
+                    feeds[r.uid] = i  # the first generated token
+            elif n == 1:
+                had_decode = True
+                feeds[r.uid] = i
+            else:
+                had_spec = True
+        self.stats["decode_steps"] += had_decode
+        self.stats["spec_rounds"] += had_spec
+        return feeds
+
+    def _wait_ragged_rows(self, step: _Dispatched) -> None:
+        """``serve.fetch``: the wait for the device. A call that runs ahead
+        ends with it, so that the device is idle between two calls and the
+        settle, the admissions and the next pack all ran while it was busy.
+        It waits for the step, not for its result's way to the host: that
+        round trip (~0.3 ms on a v5e) is left to the settle, which the next
+        call makes behind its own enqueue."""
+        if not step.waited:
+            with self.tracer.span("serve.fetch"):
+                step.out.block_until_ready()
+            step.waited = True
+
+    def _settle_ragged_rows(self, step: _Dispatched) -> None:
+        """A dispatched step's settle: the wait for the device, if the call
+        that dispatched it has not made it, the step's single host fetch
+        ([R, W+1] = accepted counts + the greedy token after each position),
+        then the per-row emit/publish that the tokens' values decide
+        (``serve.settle``)."""
+        self._wait_ragged_rows(step)
+        out = np.asarray(step.out)  # lint: allow(DS-R005)
         with self.tracer.span("serve.settle") as settle_span:
             emitted = self.stats["emitted_tokens"]
             if self._moe_slots:
@@ -988,41 +1240,33 @@ class PagedServer:
                 self._g_moe_hit.set(hit / self._moe_slots)
                 settle_span.set(moe_assignments=assignments, moe_experts_hit=hit, moe_max_expert_load=max_load)
                 if self._moe_routed_per_token:
-                    routed = int(q_lens.sum()) * self._moe_routed_per_token
+                    routed = int(step.q_lens.sum()) * self._moe_routed_per_token
                     self.stats["moe_routed_assignments"] += routed
                     settle_span.set(moe_routed_assignments=routed)
-            self._settle_fetched_rows(rows, out, chunk_len, q_lens)
+            self._settle_fetched_rows(step, out)
             settle_span.set(tokens=self.stats["emitted_tokens"] - emitted)
 
-    def _settle_fetched_rows(self, rows, out, chunk_len, q_lens) -> None:
-        had_decode = had_spec = False
-        for i, r in enumerate(rows):
-            if r.pending is None:
-                real = chunk_len[r.uid]
-                ctx = r.context()
-                self.pool.advance(r.slot, real)
-                r.consumed += real
-                self.stats["prefill_chunks"] += 1
-                if self.prefix_cache:
-                    self.pool.register_prefix(r.slot, ctx, r.consumed)
-                if r.consumed == ctx.size:
-                    # the first generated token: greedy after the chunk's
-                    # last real position
-                    self._emit(r, int(out[i, real]))
+    def _settle_fetched_rows(self, step: _Dispatched, out) -> None:
+        for i, r in enumerate(step.rows):
+            if r.done:
+                # its EOS came out of the step before this one, which had
+                # been packed by then: the row-step ran for nothing, and the
+                # slot it wrote is released now that it has settled
+                self.stats["overshoot_rows"] += 1
+                self.pool.free_slot(r.slot)
+                r.slot = None
                 continue
-            d = int(q_lens[i]) - 1
-            if d:
-                had_spec = True
-            else:
-                had_decode = True
+            if r.uid in step.chunk_len:
+                if r.uid in step.feeds:
+                    # the context's last chunk, and the first generated
+                    # token: greedy after the chunk's last real position
+                    self._emit(r, int(out[i, step.chunk_len[r.uid]]))
+                continue
+            d = int(step.q_lens[i]) - 1
             # acc is bounded by the drafted count in-program; all d+1
-            # written positions advance first, then the rejected tail rolls
-            # back — net advance is the accepted prefix + bonus token
+            # written positions advanced at the dispatch, the rejected tail
+            # rolls back here — net advance is the accepted prefix + bonus token
             self._settle_spec_row(r, d, int(out[i, 0]), out[i])
-        if had_decode:
-            self.stats["decode_steps"] += 1
-        if had_spec:
-            self.stats["spec_rounds"] += 1
 
     # --- the multi-step window (one dispatch = N decode rounds) ----------
     def _window_break(self, reason: str) -> None:
@@ -1190,6 +1434,8 @@ class PagedServer:
                     for r in running[: idx + 1]:
                         self.pool.trim_reservation(r.slot)
                     return None
+                if self._in_flight is not None:
+                    raise RuntimeError("a preemption behind a step in flight: its victim may hold an unsettled token")
                 candidates = [r for r in self._active if r is not req]
                 if not candidates:
                     # unreachable while submit() validates total size, kept
@@ -1222,12 +1468,11 @@ class PagedServer:
         return page_table, lengths
 
     def _settle_spec_row(self, req: Request, d: int, acc: int, out_row) -> None:
-        """Post-dispatch accounting for one decode/verify row — advance all
-        ``d + 1`` written positions, roll the rejected tail's pages back,
+        """A decode/verify row's settle — its ``d + 1`` written positions
+        advanced at the dispatch: roll the rejected tail's pages back,
         update the speculation stats, emit the accepted prefix + bonus/
         correction token (stopping at EOS / budget), and republish the
         prefix."""
-        self.pool.advance(req.slot, d + 1)
         self.pool.rollback(req.slot, d - acc)
         self.stats["spec_drafted"] += d
         self.stats["spec_accepted"] += acc
@@ -1290,8 +1535,11 @@ class PagedServer:
     def _finish(self, req: Request) -> None:
         req.done = True
         req.t_finish = self.clock()
-        self.pool.free_slot(req.slot)
-        req.slot = None
+        if self._in_flight is None or req.uid not in self._in_flight.feeds:
+            self.pool.free_slot(req.slot)
+            req.slot = None
+        # else an EOS seen a step late: the row rides in the step in flight,
+        # which still writes the slot's pages; its settle releases the slot
         self._active.remove(req)
         self._results[req.uid] = req.output()
         self.stats["finished"] += 1
@@ -1353,6 +1601,10 @@ class PagedServer:
         s = dict(self.stats)
         s["spec_accept_hist"] = list(self.stats["spec_accept_hist"])
         s["window_break_reasons"] = dict(self.stats["window_break_reasons"])
+        s["drain_reasons"] = dict(self.stats["drain_reasons"])
+        # how often a step was enqueued behind one still in flight: near 1 in
+        # steady serving, 0 for a server that has to be synchronous
+        s["run_ahead_share"] = s["run_ahead_steps"] / s["ragged_steps"] if s["ragged_steps"] else 0.0
         drafted, rounds = s["spec_drafted"], s["spec_rounds"]
         s["spec_accept_rate"] = s["spec_accepted"] / drafted if drafted else 0.0
         s["spec_mean_accepted_per_round"] = (

@@ -238,6 +238,9 @@ class MultiTenantServer:
     def has_work(self) -> bool:
         return self.server.has_work()
 
+    def settle(self) -> None:
+        self.server.settle()
+
     def result(self, uid: int):
         return self.server.result(uid)
 

@@ -936,3 +936,53 @@ def test_moe_package_lints_clean_under_routing_scope():
     of host syncs and raw clocks by construction."""
     findings = lint_paths([os.path.join(REPO, "deepspeed_tpu", "moe")])
     assert [f for f in findings if f.rule in ("DS-R005", "DS-R009")] == []
+
+
+def test_the_scheduler_has_one_sanctioned_fetch_a_dispatch_and_none_before_it():
+    """``inference/scheduler.py`` as it stands: exactly one device-to-host
+    read a dispatch site (the ragged step's and the window's), each carrying
+    the pragma; and where a step is enqueued behind the one in flight
+    (``_pack``'s span, ``_dispatch``'s span) nothing reads the device or
+    settles a step: the settle of the step before follows the enqueue, and
+    the wait for the device is the last thing ``step()`` does."""
+    import ast
+    import re
+
+    path = os.path.join(REPO, "deepspeed_tpu", "inference", "scheduler.py")
+    src = open(path).read()
+    rel = "deepspeed_tpu/inference/scheduler.py"
+    assert [f for f in lint_source(src, path=rel) if f.rule == "DS-R005"] == []
+    bare = [f for f in lint_source(re.sub(r"# lint: allow\(DS-R005\).*", "", src), path=rel) if f.rule == "DS-R005"]
+    # the hybrid and the uniform call of the one step, and the window's
+    assert src.count("= step_fn(") + src.count("= window_fn(") == 3
+    assert sorted(re.search(r"PagedServer\.(\w+)", f.message).group(1) for f in bare) == ["_settle_ragged_rows", "_settle_window_rows"]
+
+    server = next(n for n in ast.walk(ast.parse(src)) if isinstance(n, ast.ClassDef) and n.name == "PagedServer")
+    methods = {n.name: n for n in server.body if isinstance(n, ast.FunctionDef)}
+
+    def span_of(node):
+        for item in getattr(node, "items", []):
+            call = item.context_expr
+            if isinstance(call, ast.Call) and call.args and isinstance(call.args[0], ast.Constant):
+                return call.args[0].value
+        return None
+
+    def spans(fn):
+        return {span_of(n): n for n in ast.walk(fn) if isinstance(n, ast.With) and span_of(n)}
+
+    def calls(node):
+        return [(n.func.attr if isinstance(n.func, ast.Attribute) else getattr(n.func, "id", ""), n.lineno) for n in ast.walk(node) if isinstance(n, ast.Call)]
+
+    reads = {"asarray", "array", "device_get", "item", "block_until_ready", "_drain", "_settle_ragged_rows", "_wait_ragged_rows", "settle"}
+    pack = spans(methods["_pack"])["serve.pack"]
+    assert calls(pack) and not [name for name, _ in calls(pack) if name in reads]
+    # the one drain a pack may need (a reservation that would preempt) comes before its span opens
+    assert [line for name, line in calls(methods["_pack"]) if name == "_drain"] and all(
+        line < pack.lineno for name, line in calls(methods["_pack"]) if name in reads
+    )
+    dispatch, emit = spans(methods["_dispatch"])["serve.dispatch"], spans(methods["_dispatch"])["serve.emit"]
+    assert calls(dispatch) and not [name for name, _ in calls(dispatch) if name in reads]
+    assert dispatch.end_lineno < emit.lineno and "_settle_ragged_rows" in [name for name, _ in calls(emit)]
+    # step(): admit (and pack again only for a newcomer), enqueue, ..., pack the next step, wait last
+    order = [name for name, _ in sorted(calls(methods["step"]), key=lambda c: c[1]) if name in ("_dispatch", "_admit", "_pack", "_wait_ragged_rows")]
+    assert order[:3] == ["_admit", "_pack", "_dispatch"] and order[-2:] == ["_pack", "_wait_ragged_rows"]

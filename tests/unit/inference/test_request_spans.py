@@ -143,18 +143,24 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
     first = steps[0]
     assert first[3] == {"waiting": 3, "running": 0, "pages_in_use": 0, "pages_total": server.pool.num_pages - 1}
     assert waiting_then == 3.0 and m.snapshot()["gauges"]["serve.running"] > 0
-    (admit,), (pack,), (dispatch,), (emit,) = (inside(first, n) for n in ("serve.admit", "serve.pack", "serve.dispatch", "serve.emit"))
+    (admit,), (pack, pack_next), (dispatch,) = (inside(first, n) for n in ("serve.admit", "serve.pack", "serve.dispatch"))
     assert admit[3] == {"admitted": 3}
     program = f"paged_ragged_r{server.pool.max_slots}_w8"
-    assert dispatch[3] == {"rows": 3, "width": 8, "program": program}
+    assert dispatch[3] == {"rows": 3, "width": 8, "program": program, "ahead": 0}
+    del dispatch[3]["ahead"]
     # three first chunks of at most a page each, of a table of max_slots x max_pages_per_slot slots
     table_pages = server.pool.max_slots * server.pool.max_pages_per_slot
     # and what the program computes: the chunks' tokens, in no token tile (a window of one tile at most)
     live_tokens = sum(min(p.size, 8) for p in _prompts(3, seed=12))
     assert pack[3] == {**dispatch[3], "kv_pages": 3, "table_pages": table_pages, "live_tokens": live_tokens, "token_tiles": 0}
-    assert admit[2] <= pack[1] and pack[2] <= dispatch[1] and dispatch[2] <= emit[1]
-    (fetch,), (settle,) = inside(emit, "serve.fetch"), inside(emit, "serve.settle")
-    assert fetch[2] <= settle[1]
+    assert admit[2] <= pack[1] and pack[2] <= dispatch[1]
+    # the first call packs the next step while the device runs this one, waits for the device and leaves the
+    # settle to the call after it, which does it behind the enqueue of its own step
+    (fetch,) = inside(first, "serve.fetch")
+    assert inside(first, "serve.emit") == [] and dispatch[2] <= pack_next[1] and pack_next[2] <= fetch[1]
+    (dispatch2,), (emit,), (fetch2,) = (inside(steps[1], n) for n in ("serve.dispatch", "serve.emit", "serve.fetch"))
+    assert dispatch2[3]["ahead"] == 1 and dispatch2[2] <= emit[1] and emit[2] <= fetch2[1]
+    (settle,) = inside(emit, "serve.settle")
     emitted = sum(e[3]["tokens"] for e in events if e[0] == "serve.settle")
     assert emitted == 9 == sum(len(server.take_result(u)) for u in uids) - sum(p.size for p in _prompts(3, seed=12))
     # a later step sees the running set and its pages

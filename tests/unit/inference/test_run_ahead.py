@@ -1,0 +1,484 @@
+"""The host works while the device does (``scheduler.py``): a call of
+``step()`` enqueues the step the call before it packed (its decode tokens
+gathered on the device from the unsettled result), settles the step before
+that one behind the enqueue, packs the next and ends with the wait for the
+device.
+
+The oracle is the same server drained every step: ``settle()`` after each
+``step()`` (a draining entry point, not a flag) settles the step just
+dispatched and drops what was packed behind it, so the next one is packed
+from settled state as a synchronous server packs it. On the CPU in float32
+every stream must be byte-identical between the two under every feature, and
+the counters must say which of the two a run was.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference import decode
+from deepspeed_tpu.inference.journal import JournaledRequest, RequestJournal
+from deepspeed_tpu.inference.scheduler import PagedServer
+from deepspeed_tpu.inference.spec_decode import Drafter
+from deepspeed_tpu.models import TransformerLM
+from deepspeed_tpu.models.config import TransformerConfig
+from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, mimo_v2_config, solar_open2_config
+from deepspeed_tpu.models.moe_transformer import MoETransformerLM, olmoe_config
+from deepspeed_tpu.profiling.tracer import Tracer
+from deepspeed_tpu.utils import chaos
+
+CFG = dict(
+    vocab_size=128, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=96,
+    norm="rmsnorm", position="rope", activation="swiglu", use_bias=False, tie_embeddings=False,
+    flash_attention=False, dtype="float32",
+)
+
+
+@pytest.fixture(scope="module")
+def dense():
+    cfg = TransformerConfig(**CFG)
+    params = TransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return cfg, params
+
+
+@pytest.fixture(autouse=True)
+def _disarm_chaos():
+    yield
+    chaos.uninstall()
+
+
+def _prompts(n, seed=0, lo=3, hi=20, vocab=128):
+    rs = np.random.RandomState(seed)
+    return [rs.randint(0, vocab, (int(rs.randint(lo, hi)),)).astype(np.int32) for _ in range(n)]
+
+
+def _generate(cfg, params, prompt, n, eos=None):
+    out = np.asarray(decode.generate(cfg, params, prompt[None], n, eos_token_id=eos))[0]
+    if eos is not None:  # generate pads a finished row with its EOS; a stream ends at it
+        made = out[prompt.size :]
+        hit = np.flatnonzero(made == eos)
+        if hit.size:
+            out = out[: prompt.size + hit[0] + 1]
+    return out
+
+
+def _server(cfg, params, **kw):
+    kw = {"page_size": 8, "max_slots": 4, "prefill_chunk": 8, "attn_impl": "xla", "dtype": jnp.float32, **kw}
+    return PagedServer(cfg, params, **kw)
+
+
+def _run(server, drained: bool):
+    """``run()``, or the same loop with the step in flight settled after
+    every call: the synchronous server."""
+    while server.has_work():
+        server.step()
+        if drained:
+            server.settle()
+    return server._results
+
+
+def _both(cfg, params, requests, **kw):
+    """The requests through a server that runs ahead and through one drained
+    every step; returns (ahead server, drained server, streams, streams)."""
+    servers, streams = [], []
+    for drained in (False, True):
+        server = _server(cfg, params, **kw)
+        uids = [server.submit(p, max_new_tokens=n, eos_token_id=eos) for p, n, eos in requests]
+        results = _run(server, drained)
+        servers.append(server)
+        streams.append([results[u] for u in uids])
+    return servers[0], servers[1], streams[0], streams[1]
+
+
+def _assert_same(ahead_streams, drained_streams):
+    assert len(ahead_streams) == len(drained_streams)
+    for a, b in zip(ahead_streams, drained_streams):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def _drained_to_zero(server):
+    """Nothing left behind: no step in flight, every slot and page back."""
+    assert server._in_flight is None and not server.has_work()
+    assert server.pool.used_pages() == 0 and len(server.pool._free_slots) == server.pool.max_slots
+    server.pool.integrity_check()
+
+
+# --- the dense model, feature by feature ---------------------------------------
+def _eos_requests(cfg, params, prompts, budgets):
+    """Each request's EOS is a token its own greedy stream produces part-way
+    (first occurrence at a different depth a request), so every one ends early."""
+    out = []
+    for i, (p, n) in enumerate(zip(prompts, budgets)):
+        made = _generate(cfg, params, p, n)[p.size :]
+        out.append((p, n, int(made[min(2 + 3 * i, n - 2)])))
+    return out
+
+
+SCENARIOS = {
+    # one chunk each, all admitted at once: decode alone after the first step
+    "plain_decode": dict(prompts=dict(n=4, seed=1, lo=3, hi=8), budgets=[12, 9, 15, 11]),
+    # prompts of several chunks and more requests than slots: chunks ride with decode rows, admissions mid-stream
+    "chunked_prefill_rides_with_decode": dict(prompts=dict(n=7, seed=2, lo=6, hi=40), budgets=[10, 14, 6, 9, 12, 7, 11]),
+    # budgets that end at the first token, the second, and later: a row whose budget ends is not packed again
+    "budget_end": dict(prompts=dict(n=6, seed=3, lo=3, hi=20), budgets=[1, 2, 3, 1, 8, 2]),
+    "eos_mid_run": dict(prompts=dict(n=5, seed=4, lo=3, hi=20), budgets=[16, 18, 20, 17, 19], eos=True),
+    "prefix_cache": dict(prompts=dict(n=6, seed=5, lo=4, hi=12), budgets=[9, 6, 11, 8, 7, 10], shared_prefix=24, kw=dict(prefix_cache=True)),
+    "prefix_cache_eos": dict(prompts=dict(n=5, seed=6, lo=4, hi=12), budgets=[14, 16, 15, 17, 13], shared_prefix=16, eos=True, kw=dict(prefix_cache=True)),
+}
+
+
+@pytest.mark.parametrize("name", list(SCENARIOS))
+def test_streams_are_those_of_a_server_drained_every_step(dense, name):
+    cfg, params = dense
+    sc = SCENARIOS[name]
+    prompts = _prompts(**sc["prompts"])
+    if sc.get("shared_prefix"):
+        system = np.random.RandomState(99).randint(0, 128, sc["shared_prefix"]).astype(np.int32)
+        prompts = [np.concatenate([system, p]) for p in prompts]
+    budgets = sc["budgets"]
+    requests = _eos_requests(cfg, params, prompts, budgets) if sc.get("eos") else [(p, n, None) for p, n in zip(prompts, budgets)]
+    ahead, drained, got, want = _both(cfg, params, requests, **sc.get("kw", {}))
+    _assert_same(got, want)
+    for (p, n, eos), stream in zip(requests, got):
+        assert np.array_equal(stream, _generate(cfg, params, p, n, eos))
+    # which of the two each was
+    assert ahead.stats["run_ahead_steps"] >= ahead.stats["ragged_steps"] - 2 > 0
+    assert set(ahead.stats["drain_reasons"]) <= {"idle"}
+    assert drained.stats["run_ahead_steps"] == 0 and drained.stats["drain_reasons"] == {"settle": drained.stats["ragged_steps"]}
+    assert drained.stats["overshoot_rows"] == 0
+    if sc.get("eos"):
+        # an EOS is seen a step late: the row rode in one more step, whose result for it was discarded
+        assert ahead.stats["overshoot_rows"] >= 1
+        assert all(stream[-1] == eos and (stream[p.size : -1] != eos).all() for (p, _, eos), stream in zip(requests, got))
+    else:
+        assert ahead.stats["overshoot_rows"] == 0
+    assert ahead.stats["emitted_tokens"] == drained.stats["emitted_tokens"] == sum(s.size - p.size for (p, _, _), s in zip(requests, got))
+    assert ahead.stats["prefill_chunks"] == drained.stats["prefill_chunks"]
+    for server in (ahead, drained):
+        _drained_to_zero(server)
+
+
+@pytest.mark.parametrize("num_pages", [9, 11])
+def test_a_pool_that_has_to_preempt_settles_first(dense, num_pages):
+    """A reservation that would have to preempt is made on settled state: the
+    in-flight step is fetched first (``drain_reasons['preempt']``), the victim
+    holds no unsettled token, and the streams are ``generate``'s."""
+    cfg, params = dense
+    prompts = _prompts(5, seed=7, lo=10, hi=22)
+    budgets = [20, 24, 18, 22, 16]
+    requests = [(p, n, None) for p, n in zip(prompts, budgets)]
+    ahead, drained, got, want = _both(cfg, params, requests, num_pages=num_pages)
+    _assert_same(got, want)
+    for (p, n, _), stream in zip(requests, got):
+        assert np.array_equal(stream, _generate(cfg, params, p, n))
+    assert ahead.stats["preempted"] > 0 and ahead.stats["drain_reasons"]["preempt"] > 0
+    assert ahead.stats["run_ahead_steps"] > 0
+    _drained_to_zero(ahead)
+
+
+class _SilentDrafter(Drafter):
+    """Armed, and proposes nothing: the server has to be the synchronous one."""
+
+    def propose(self, uid, context, k):
+        return np.zeros(0, np.int32)
+
+
+class _FutureDrafter(Drafter):
+    """Proposes the request's own greedy future, the last of every proposal wrong."""
+
+    def __init__(self, futures):
+        self.futures = futures
+
+    def propose(self, uid, context, k):
+        cont = self.futures[uid][context.size : context.size + k].copy()
+        if cont.size:
+            cont[-1] = (cont[-1] + 1) % 128
+        return cont.astype(np.int32)
+
+
+@pytest.mark.parametrize("armed", ["silent_drafter", "future_drafter", "ngram_config", "multi_step"])
+def test_an_armed_drafter_or_window_makes_the_server_synchronous(dense, armed):
+    """Drafts are proposed from settled contexts and a window probes settled
+    rows: the step is settled in the call that dispatched it, nothing ever
+    runs ahead, and the streams are the plain server's."""
+    cfg, params = dense
+    prompts = _prompts(5, seed=8, lo=4, hi=18)
+    budgets = [14, 17, 9, 13, 12]
+    futures = {i: _generate(cfg, params, p, n) for i, (p, n) in enumerate(zip(prompts, budgets))}
+    kw = {
+        "silent_drafter": dict(drafter=_SilentDrafter()),
+        "future_drafter": dict(drafter=_FutureDrafter(futures)),
+        "ngram_config": dict(spec_decode={"enable": True, "max_draft": 3}),
+        "multi_step": dict(multi_step={"enable": True, "horizon": 4}),
+    }[armed]
+    requests = [(p, n, None) for p, n in zip(prompts, budgets)]
+    ahead, drained, got, want = _both(cfg, params, requests, **kw)
+    _assert_same(got, want)
+    for i, stream in enumerate(got):
+        assert np.array_equal(stream, futures[i])
+    reason = "window" if armed == "multi_step" else "draft"
+    assert ahead.stats["run_ahead_steps"] == 0 and ahead.stats["overshoot_rows"] == 0
+    assert ahead.stats["drain_reasons"] == {reason: ahead.stats["ragged_steps"]}
+    assert ahead.serve_stats()["run_ahead_share"] == 0.0
+    if armed == "future_drafter":
+        assert ahead.stats["spec_accepted"] > 0
+    if armed == "multi_step":
+        assert ahead.stats["window_steps"] > 0
+    _drained_to_zero(ahead)
+
+
+# --- the models with layers of more than one kind, and the routed one ----------
+def _hybrid(kind):
+    if kind == "solar":
+        cfg = solar_open2_config("tiny", num_layers=8, dtype="float32")
+    elif kind == "mimo":
+        cfg = mimo_v2_config("tiny", dtype="float32")
+    else:
+        cfg = olmoe_config("tiny", dtype="float32", flash_attention=False, remat=False)
+        return cfg, MoETransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return cfg, HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None)
+
+
+@pytest.mark.parametrize("kind", ["solar", "mimo", "olmoe"])
+def test_the_state_store_the_window_rings_and_the_routing_counts_follow(kind):
+    """The state store and the window rings are threaded through the steps
+    as the pools are, and an MoE model's routing counts ride past the token
+    rows of the result the gather reads: the streams and the counts are the
+    drained server's, EOS overshoot included (a stray write into a slot's
+    state or ring is harmless to its next owner: the program restarts a row
+    that begins at position 0)."""
+    cfg, params = _hybrid(kind)
+    rs = np.random.RandomState(11)
+    prompts = [rs.randint(0, 512, n).astype(np.int32) for n in (37, 5, 20, 16, 50, 3, 9)]
+    budgets = [12, 20, 9, 15, 7, 30, 11]
+    kw = dict(page_size=8, max_slots=4, prefill_chunk=16, max_seq_len=96)
+    plain = _server(cfg, params, **kw)
+    uids = [plain.submit(p, max_new_tokens=n) for p, n in zip(prompts, budgets)]
+    futures = [_run(plain, drained=True)[u] for u in uids]
+    # every other request ends at a token of its own stream
+    requests = [(p, n, int(f[p.size + 3]) if i % 2 else None) for i, (p, n, f) in enumerate(zip(prompts, budgets, futures))]
+    ahead, drained, got, want = _both(cfg, params, requests, **kw)
+    _assert_same(got, want)
+    assert ahead.stats["overshoot_rows"] >= 1 and ahead.stats["run_ahead_steps"] > 0.8 * ahead.stats["ragged_steps"]
+    # a discarded row-step still routed: the overshoot rows are the only difference
+    extra = ahead.stats["overshoot_rows"] * getattr(cfg, "num_moe_layers", cfg.num_layers) * cfg.moe_top_k
+    if "moe_routed_assignments" in ahead.stats:
+        assert ahead.stats["moe_routed_assignments"] == drained.stats["moe_routed_assignments"] + extra
+    else:
+        assert ahead.stats["moe_assignments"] == drained.stats["moe_assignments"] + extra
+    _drained_to_zero(ahead)
+
+
+# --- what a step in flight means for the caller -------------------------------
+def test_an_eos_row_is_released_once_and_only_after_the_step_that_writes_it(dense):
+    cfg, params = dense
+    prompt = _prompts(1, seed=12, lo=6, hi=7)[0]
+    future = _generate(cfg, params, prompt, 12)
+    eos = int(future[prompt.size + 4])
+    first = prompt.size + int(np.flatnonzero(future[prompt.size :] == eos)[0])
+    server = _server(cfg, params)
+    freed = []
+    free_slot = server.pool.free_slot
+    server.pool.free_slot = lambda slot: (freed.append(slot), free_slot(slot))[1]
+    uid = server.submit(prompt, max_new_tokens=12, eos_token_id=eos)
+    while server.result(uid) is None:
+        server.step()
+    # the stream is whole the moment its EOS settles: the EOS included, nothing after it
+    assert np.array_equal(server.result(uid), future[: first + 1])
+    # ... while the step packed before the EOS was seen still writes the row's pages
+    step = server._in_flight
+    assert step is not None and [r.uid for r in step.rows] == [uid]
+    slot = step.rows[0].slot
+    assert freed == [] and slot not in server.pool._free_slots and server.pool._owned[slot] > 0
+    assert server.has_work() and not server._active and not server._queue  # a step in flight is work
+    assert server.stats["overshoot_rows"] == 0 and server.stats["finished"] == 1
+    server.step()  # nothing to pack: the call only settles, and discards the row's result
+    assert freed == [slot] and server.stats["overshoot_rows"] == 1
+    assert server.stats["drain_reasons"] == {"idle": 1}
+    assert np.array_equal(server.take_result(uid), future[: first + 1])
+    assert server.stats["emitted_tokens"] == first + 1 - prompt.size
+    _drained_to_zero(server)
+    server.step()  # and an idle server's step is a no-op
+    assert freed == [slot] and server.stats["drain_reasons"] == {"idle": 1}
+
+
+def test_a_call_returns_the_tokens_of_the_step_dispatched_one_call_earlier(dense):
+    cfg, params = dense
+    server = _server(cfg, params)
+    uid = server.submit(_prompts(1, seed=13, lo=5, hi=8)[0], max_new_tokens=3)
+    emitted = []
+    for _ in range(4):
+        server.step()
+        emitted.append(server.stats["emitted_tokens"])
+    # chunk | decode (token on the device) + first token | decode + second | settle only: third
+    assert emitted == [0, 1, 2, 3]
+    assert server.stats["ragged_steps"] == 3 and server.stats["run_ahead_steps"] == 2
+    assert server.stats["prefill_chunks"] == 1 and server.stats["decode_steps"] == 2
+    assert server.take_result(uid).size == server.stats["emitted_tokens"] + _prompts(1, seed=13, lo=5, hi=8)[0].size
+    assert not server.has_work()
+
+
+def test_a_request_that_comes_between_two_calls_rides_in_the_next_step(dense):
+    """The step packed before it came is packed again with it: its wait for
+    its first chunk is a synchronous server's, and nothing is drained."""
+    cfg, params = dense
+    server = _server(cfg, params)
+    first, second = _prompts(2, seed=20, lo=5, hi=8)
+    a = server.submit(first, max_new_tokens=20)
+    for _ in range(4):
+        server.step()
+    assert [r.uid for r in server._packed.rows] == [a]  # packed while the device ran, without the newcomer
+    b = server.submit(second, max_new_tokens=6)
+    chunks, steps = server.stats["prefill_chunks"], server.stats["ragged_steps"]
+    server.step()
+    assert server.stats["admitted"] == 2 and server.stats["prefill_chunks"] == chunks + 1
+    assert server.stats["ragged_steps"] == steps + 1 and [r.uid for r in server._in_flight.rows] == [a, b]
+    assert server.stats["drain_reasons"] == {} and server.stats["run_ahead_steps"] == server.stats["ragged_steps"] - 1
+    results = server.run()
+    assert np.array_equal(results[a], _generate(cfg, params, first, 20))
+    assert np.array_equal(results[b], _generate(cfg, params, second, 6))
+    _drained_to_zero(server)
+
+
+def test_run_ahead_share_of_a_steady_run(dense):
+    cfg, params = dense
+    server = _server(cfg, params)
+    prompts = _prompts(4, seed=14, lo=4, hi=8)
+    outs = server.serve(prompts, max_new_tokens=60)
+    stats = server.serve_stats()
+    assert stats["run_ahead_share"] > 0.9 and stats["run_ahead_share"] == stats["run_ahead_steps"] / stats["ragged_steps"]
+    assert stats["drain_reasons"] == {"idle": 1}  # the run's end
+    for p, o in zip(prompts, outs):
+        assert np.array_equal(o, _generate(cfg, params, p, 60))
+
+
+def test_extract_and_restore_with_a_step_in_flight(dense):
+    """The entry points that read or move a request settle first: what they
+    hand over holds every token the device was asked for."""
+    cfg, params = dense
+    prompts = _prompts(3, seed=15, lo=5, hi=14)
+    source, target = _server(cfg, params), _server(cfg, params)
+    uids = [source.submit(p, max_new_tokens=14) for p in prompts]
+    for _ in range(5):
+        source.step()
+    assert source._in_flight is not None
+    before = source.stats["emitted_tokens"]
+    state = source.extract_request(uids[1])
+    assert source._in_flight is None and source.stats["drain_reasons"] == {"extract_request": 1}
+    assert source.stats["emitted_tokens"] > before and len(state.generated) >= 3
+    assert np.array_equal(state.generated, _generate(cfg, params, prompts[1], 14)[prompts[1].size :][: len(state.generated)])
+    moved = source.extract_request(uids[2])
+    source.restore_request(moved)  # nowhere to go: back on the source
+    assert source.stats["migrated_out"] == 1 and source.stats["migrated_in"] == 0
+    target.recover({state.uid: state}, migrated_in=True)
+    source.run(), target.run()
+    for u, p in zip(uids, prompts):
+        got = target.result(u) if u == uids[1] else source.result(u)
+        assert np.array_equal(got, _generate(cfg, params, p, 14))
+    _drained_to_zero(source), _drained_to_zero(target)
+
+
+def test_recover_lands_on_a_server_with_a_step_in_flight(dense):
+    cfg, params = dense
+    prompts = _prompts(3, seed=16, lo=5, hi=14)
+    server = _server(cfg, params)
+    uids = [server.submit(p, max_new_tokens=10) for p in prompts[:2]]
+    for _ in range(4):
+        server.step()
+    assert server._in_flight is not None
+    future = _generate(cfg, params, prompts[2], 10)
+    state = JournaledRequest(uid=7, prompt=prompts[2], max_new_tokens=10, eos_token_id=None, tenant="default",
+                             generated=[int(t) for t in future[prompts[2].size :][:4]])
+    assert server.recover({7: state}, next_uid=8, migrated_in=True) == 1
+    assert server._in_flight is None and server.stats["drain_reasons"] == {"recover": 1}
+    results = server.run()
+    for u, p in zip(uids + [7], prompts):
+        assert np.array_equal(results[u], _generate(cfg, params, p, 10))
+
+
+@pytest.mark.parametrize("kill_step", [1, 2, 4, 7])
+def test_a_journal_replays_after_a_kill_with_a_step_in_flight(dense, tmp_path, kill_step):
+    """``serve.mid_step`` fires after a call's settle and before its journal
+    flush, with the step the call dispatched still in flight: that step dies
+    unseen, the unsynced tokens are re-derived, and every stream is whole."""
+    cfg, params = dense
+    prompts = _prompts(4, seed=17, lo=5, hi=20)
+    budgets = [9, 12, 7, 10]
+    server = _server(cfg, params, journal=RequestJournal(str(tmp_path)))
+    uids = [server.submit(p, max_new_tokens=n) for p, n in zip(prompts, budgets)]
+    chaos.install(chaos.ChaosSchedule([chaos.ChaosRule("serve.mid_step", hit=kill_step)]))
+    with pytest.raises(chaos.ChaosKilled):
+        server.run()
+    chaos.uninstall()
+    assert server._in_flight is not None
+    states, next_uid = RequestJournal.replay(str(tmp_path))
+    # the journal holds what was flushed: the tokens of the calls before the kill
+    assert sum(len(st.generated) for st in states.values()) <= server.stats["emitted_tokens"]
+    restarted = _server(cfg, params, journal=RequestJournal(str(tmp_path)))
+    assert restarted.recover(states, next_uid) == len(prompts)
+    results = restarted.run()
+    for u, p, n in zip(uids, prompts, budgets):
+        assert np.array_equal(results[u], _generate(cfg, params, p, n))
+    _drained_to_zero(restarted)
+
+
+def test_a_compaction_and_a_finalized_migration_settle_first(dense, tmp_path):
+    cfg, params = dense
+    prompts = _prompts(3, seed=18, lo=5, hi=14)
+    server = _server(cfg, params, journal=RequestJournal(str(tmp_path)))
+    uids = [server.submit(p, max_new_tokens=12) for p in prompts]
+    for _ in range(4):
+        server.step()
+    server.compact_journal()
+    assert server.stats["drain_reasons"] == {"compact_journal": 1}
+    states, _ = RequestJournal.replay(str(tmp_path))
+    # the rewritten segment holds every token the device had been asked for
+    assert sum(len(st.generated) for st in states.values()) == server.stats["emitted_tokens"]
+    server.step()
+    state = server.extract_request(uids[0])
+    server.step()
+    server.finalize_migration(uids[0])
+    assert server.stats["drain_reasons"] == {"compact_journal": 1, "extract_request": 1, "finalize_migration": 1}
+    results = server.run()
+    for u, p in zip(uids[1:], prompts[1:]):
+        assert np.array_equal(results[u], _generate(cfg, params, p, 12))
+    assert len(state.generated) >= 3
+
+
+# --- the order that is the mechanism ------------------------------------------
+def test_inside_one_step_the_dispatch_closes_before_the_fetch_opens(dense):
+    """From the tracer's ring buffer: a call enqueues the step packed by the
+    call before (``serve.dispatch``, ``ahead=1``), settles the step before
+    that one, admits, packs the next step and only then waits for the device
+    (``serve.fetch``); the first call packs its own step first and the last
+    one only settles."""
+    cfg, params = dense
+    tracer = Tracer()
+    server = _server(cfg, params, tracer=tracer)
+    server.serve(_prompts(3, seed=19, lo=4, hi=30), max_new_tokens=10)
+    spans = [r for r in tracer.spans() if r["ph"] == "X"]
+    steps = [r for r in spans if r["name"] == "serve.step"]
+
+    def inside(outer, name):
+        return [r for r in spans if r["name"] == name and outer["t0"] <= r["t0"] and r["t1"] <= outer["t1"]]
+
+    assert len(steps) >= 12
+    for k, step in enumerate(steps):
+        dispatch, fetch, settle, packs = (inside(step, "serve." + n) for n in ("dispatch", "fetch", "settle", "pack"))
+        if k == len(steps) - 1:  # the run's end: nothing packed, nothing to wait for
+            assert (len(dispatch), len(fetch), len(settle), len(packs)) == (0, 0, 1, 0)
+            continue
+        (dispatch,), (fetch,) = dispatch, fetch
+        assert dispatch["attrs"]["ahead"] == int(k > 0)
+        assert dispatch["t1"] <= fetch["t0"]  # the enqueue of step n+1 is over before anything waits for the device
+        if k < len(steps) - 2:  # the next step, packed while the device runs (the last step has none behind it)
+            assert packs and packs[-1]["t0"] >= dispatch["t1"] and packs[-1]["t1"] <= fetch["t0"]
+        if k == 0:
+            assert len(packs) == 2 and packs[0]["t1"] <= dispatch["t0"] and settle == []
+        else:
+            (settle,) = settle  # of step n, behind the enqueue of step n+1 and before the wait for it
+            assert dispatch["t1"] <= settle["t0"] and settle["t1"] <= fetch["t0"]
+    assert server.stats["run_ahead_steps"] == len(steps) - 2 == server.stats["ragged_steps"] - 1
