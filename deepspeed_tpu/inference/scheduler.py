@@ -79,7 +79,12 @@ vLLM style):
   (``drain_reasons``), and the call goes on as a synchronous server's
   would. No knob: the depth is 1 or 0 by what the server sees in its own
   state. ``has_work()`` counts an unsettled step and the last call of a run
-  only settles, so ``run()`` / ``serve()`` return settled streams;
+  only settles, so ``run()`` / ``serve()`` return settled streams. A step's
+  life thus spans three calls, and every span of it (``serve.pack``,
+  ``serve.dispatch`` > ``serve.enqueue``, ``serve.fetch``, ``serve.emit`` >
+  ``serve.settle``) carries the step's ``seq``; the histogram
+  ``serve.turnaround_ms`` times the host between one step's wait and the
+  next step's enqueue, the part of its work the device waits for;
 * admission order and preemption victims are delegated to a
   ``SchedulingPolicy`` (default: FIFO admission, youngest-first
   preemption — the original behavior). ``inference/traffic.py`` layers
@@ -120,6 +125,13 @@ from deepspeed_tpu.profiling.tracer import (
     percentile_summary,
 )
 from deepspeed_tpu.utils import chaos
+
+
+# bounds of ``serve.turnaround_ms``: tenths of a millisecond where a healthy
+# host sits, coarser above
+_TURNAROUND_BUCKETS_MS = (
+    0.02, 0.05, 0.1, 0.15, 0.2, 0.3, 0.4, 0.5, 0.75, 1.0, 1.5, 2.0, 3.0, 5.0, 10.0, 25.0, 100.0,
+)
 
 
 def _spec_knob(spec, name, default):
@@ -235,6 +247,7 @@ class _Packed:
     """One ragged step packed and not yet enqueued: its rows and, already on
     their way to the device, the operands the host makes."""
 
+    seq: int  # the step's number on every span of its life: ``stats["dispatches"]`` at the pack
     rows: List[Request]
     chunk_len: Dict[int, int]  # uid -> chunk length, the prefill rows
     q_lens: np.ndarray
@@ -251,6 +264,7 @@ class _Dispatched:
     """One ragged step the device has been handed and the host has not yet
     settled: what its settle needs."""
 
+    seq: int  # as its ``_Packed``'s
     rows: List[Request]
     out: object  # device [R (+ MOE_STAT_ROWS), W + 1], the step's one result
     next_tokens: object  # device [R]: the greedy token after each row's last live position
@@ -260,7 +274,9 @@ class _Dispatched:
     # nothing else (a plain decode row, a prompt's last chunk): the rows the
     # next step can take before the settle
     feeds: Dict[int, int]
-    waited: bool = False  # the device has finished it: a call that ran ahead ended with the wait
+    # ``tracer.clock()`` when the wait for it returned (the device has finished it: a call
+    # that ran ahead ended with that wait); None until then
+    waited_at: Optional[float] = None
 
 
 class PagedServer:
@@ -335,11 +351,15 @@ class PagedServer:
             params = tp.shard_params(cfg, params)
         self.params = params
         # unified tracing (profiling/tracer.py): per-step phase spans
-        # (admit / pack / dispatch / emit > fetch, settle / journal_sync,
-        # each also an event of the profiler's trace) and per-request
-        # lifecycle spans (submit → admit → first_token → finish, with
-        # tenant / prefix-hit / spec-accept attributes). Host-side only —
-        # the step's device work stays one enqueue + one budgeted fetch.
+        # (admit / pack / dispatch > enqueue / emit > fetch, settle /
+        # journal_sync, each also an event of the profiler's trace) and
+        # per-request lifecycle spans (submit → admit → first_token →
+        # finish, with tenant / prefix-hit / spec-accept attributes).
+        # Host-side only — the step's device work stays one enqueue + one
+        # budgeted fetch. Every span of a step's life carries the step's
+        # ``seq``: ``stats["dispatches"]`` as it stands at the pack, which
+        # is what it still is at the enqueue (a step packed again gets the
+        # same number).
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         # the scheduler's state at the top of each step: the same three
@@ -348,6 +368,11 @@ class PagedServer:
         self._g_waiting = self.metrics.gauge("serve.waiting")
         self._g_running = self.metrics.gauge("serve.running")
         self._g_pages = self.metrics.gauge("serve.kv_pages_in_use")
+        # host milliseconds from the return of a step's wait to the next
+        # step's jitted call, where no drain lies between: a lower bound of
+        # the device's idle time between the two executions that needs no
+        # profiler ("my host holds the chip back" against "the model is slow")
+        self._turnaround = self.metrics.histogram("serve.turnaround_ms", _TURNAROUND_BUCKETS_MS)
         self.prefill_chunk = int(prefill_chunk)
         self.attn_impl = attn_impl
         self.telemetry = telemetry
@@ -833,7 +858,7 @@ class PagedServer:
             return
         reasons = self.stats["drain_reasons"]
         reasons[reason] = reasons.get(reason, 0) + 1
-        with self.tracer.span("serve.emit", drain=reason):
+        with self.tracer.span("serve.emit", seq=step.seq, drain=reason):
             self._settle_ragged_rows(step)
 
     def result(self, uid: int) -> Optional[np.ndarray]:
@@ -863,10 +888,11 @@ class PagedServer:
         self._g_waiting.set(waiting)
         self._g_running.set(running)
         self._g_pages.set(pages_in_use)
+        seq = self.stats["dispatches"]  # of the step this call enqueues, if it enqueues one
         with self.tracer.span(
             "serve.step", waiting=waiting, running=running,
             pages_in_use=pages_in_use, pages_total=self.pool.num_pages - 1,
-        ):
+        ) as step_span:
             packed, self._packed = self._packed, None
             if packed is None:
                 self._drain("idle")  # nothing was packed behind it: the call settles first
@@ -907,6 +933,8 @@ class PagedServer:
                     self._packed = self._pack()
                     if self._in_flight is not None:
                         self._wait_ragged_rows(self._in_flight)
+            if self.stats["dispatches"] > seq:
+                step_span.set(seq_enqueued=seq)
             # the round's dispatch and the emissions of the step before it
             # happened; the chaos point models dying BEFORE the journal flush
             # (and with a step in flight, which dies unseen) — the un-synced
@@ -1060,7 +1088,8 @@ class PagedServer:
             return None
         prev = self._in_flight
         feeds = prev.feeds if prev is not None else {}
-        with self.tracer.span("serve.pack") as pack_span:
+        seq = self.stats["dispatches"]
+        with self.tracer.span("serve.pack", seq=seq) as pack_span:
             if drafts is None:
                 drafts = {}
                 if self.drafter is not None:
@@ -1127,10 +1156,8 @@ class PagedServer:
                 slots[: len(rows)] = [r.slot for r in rows]
                 host_made.append(slots)
                 self._g_state_slots.set(len(rows))
-                pack_span.set(state_slots=len(rows), state_bytes=states.hbm_bytes() - states.window_bytes())
                 if states.window_k is not None:
                     self._g_window_slots.set(len(rows))
-                    pack_span.set(window_slots=len(rows), window_bytes=states.window_bytes())
             # the window with the in-flight rows' tokens laid in on the device
             # (queued behind the step that computes them), and the rest
             window = self._feed_tokens(tokens, prev.next_tokens if prev is not None else self._no_tokens, src)
@@ -1139,7 +1166,7 @@ class PagedServer:
                 self.cfg, R, W, self.pool.page_size, attn_impl=self.attn_impl,
                 telemetry=self.telemetry, tp=self.tp,
             )
-        return _Packed(rows, chunk_len, q_lens, W, program, live_tokens, tiles, step_fn, operands)
+        return _Packed(seq, rows, chunk_len, q_lens, W, program, live_tokens, tiles, step_fn, operands)
 
     def _dispatch(self, packed: _Packed) -> None:
         """Enqueue a packed step (jit returns futures; the fetch is where
@@ -1147,25 +1174,26 @@ class PagedServer:
         may take, commit what its counts decide, and only then settle the
         step before it, whose result the call before waited for."""
         prev = self._in_flight
-        rows, W = packed.rows, packed.width
-        with self.tracer.span("serve.dispatch", rows=len(rows), width=W, program=packed.program, ahead=int(prev is not None)):
+        seq, rows, W = packed.seq, packed.rows, packed.width
+        with self.tracer.span("serve.dispatch", seq=seq, rows=len(rows), width=W, program=packed.program, ahead=int(prev is not None)):
             step_fn = packed.step_fn
             window, page_table, lengths, q_lens, *slots = packed.operands
-            states = self.pool.states
-            if states is not None:
-                out, new_k, new_v, states = step_fn(
+            states = () if self.pool.states is None else (self.pool.states,)  # with their slots, or neither
+            # the jitted call alone: what the host spends inside jax and the
+            # runtime before the device can start. The rest of the dispatch
+            # is this file's Python, behind the enqueue
+            with self.tracer.span("serve.enqueue", seq=seq, program=packed.program):
+                if prev is not None and prev.waited_at is not None:  # a drained step is gone: nothing is observed across a drain
+                    self._turnaround.observe((self.tracer.clock() - prev.waited_at) * 1e3)
+                out, new_k, new_v, *new_states = step_fn(
                     self.params, window, self.pool.cache.k_pages, self.pool.cache.v_pages,
-                    states, page_table, lengths, q_lens, *slots,
+                    *states, page_table, lengths, q_lens, *slots,
                 )
-                self.pool.set_states(states)
-            else:
-                out, new_k, new_v = step_fn(
-                    self.params, window, self.pool.cache.k_pages, self.pool.cache.v_pages,
-                    page_table, lengths, q_lens,
-                )
+            if new_states:
+                self.pool.set_states(*new_states)
             self.pool.set_cache(new_k, new_v)
             self._in_flight = _Dispatched(
-                rows, out, self._next_tokens(out, q_lens), packed.chunk_len, packed.q_lens,
+                seq, rows, out, self._next_tokens(out, q_lens), packed.chunk_len, packed.q_lens,
                 feeds=self._commit_dispatched(rows, packed.chunk_len, packed.q_lens),
             )
         self.stats["ragged_steps"] += 1
@@ -1176,7 +1204,7 @@ class PagedServer:
             self.stats["mixed_token_tiles"] += packed.token_tiles
         if prev is not None:
             self.stats["run_ahead_steps"] += 1
-            with self.tracer.span("serve.emit"):
+            with self.tracer.span("serve.emit", seq=prev.seq):
                 self._settle_ragged_rows(prev)
 
     def _commit_dispatched(self, rows, chunk_len, q_lens) -> Dict[int, int]:
@@ -1216,10 +1244,10 @@ class PagedServer:
         It waits for the step, not for its result's way to the host: that
         round trip (~0.3 ms on a v5e) is left to the settle, which the next
         call makes behind its own enqueue."""
-        if not step.waited:
-            with self.tracer.span("serve.fetch"):
+        if step.waited_at is None:
+            with self.tracer.span("serve.fetch", seq=step.seq):
                 step.out.block_until_ready()
-            step.waited = True
+                step.waited_at = self.tracer.clock()
 
     def _settle_ragged_rows(self, step: _Dispatched) -> None:
         """A dispatched step's settle: the wait for the device, if the call
@@ -1229,7 +1257,7 @@ class PagedServer:
         (``serve.settle``)."""
         self._wait_ragged_rows(step)
         out = np.asarray(step.out)  # lint: allow(DS-R005)
-        with self.tracer.span("serve.settle") as settle_span:
+        with self.tracer.span("serve.settle", seq=step.seq) as settle_span:
             emitted = self.stats["emitted_tokens"]
             if self._moe_slots:
                 out, moe = out[:-MOE_STAT_ROWS], out[-MOE_STAT_ROWS:, 0]
@@ -1326,8 +1354,9 @@ class PagedServer:
         # the window dispatches: drop the (all-empty) stash — a later
         # step's fallback must ask the drafter fresh, not read this one
         self._predrafts = None
-        with self.tracer.span("serve.window", rows=len(rows), horizon=H):
-            with self.tracer.span("serve.pack") as pack_span:
+        seq = self.stats["dispatches"]
+        with self.tracer.span("serve.window"):
+            with self.tracer.span("serve.pack", seq=seq) as pack_span:
                 R = self.pool.max_slots
                 page_table, lengths = self._dispatch_rows(rows, R)
                 tokens = np.zeros(R, np.int32)
@@ -1341,24 +1370,24 @@ class PagedServer:
                         eos_ids[i] = r.eos_token_id
                     budgets[i] = r.max_new_tokens - len(r.generated)  # >= 1
                 program = multistep_program_name(R, 1, H, self.tp)
-                pack_span.set(rows=len(rows), horizon=H, program=program)
-            with self.tracer.span("serve.dispatch", rows=len(rows), width=1,
-                                  horizon=H, program=program):
+                pack_span.set(rows=len(rows), program=program)
+            with self.tracer.span("serve.dispatch", seq=seq, rows=len(rows), width=1, program=program):
                 window_fn = build_ragged_multistep(
                     self.cfg, R, 1, H, self.pool.page_size,
                     attn_impl=self.attn_impl, telemetry=self.telemetry,
                     tp=self.tp,
                 )
-                out, new_k, new_v = window_fn(
-                    self.params, tokens, self.pool.cache.k_pages,
-                    self.pool.cache.v_pages, page_table, lengths, live,
-                    eos_ids, budgets,
-                )
+                with self.tracer.span("serve.enqueue", seq=seq, program=program):
+                    out, new_k, new_v = window_fn(
+                        self.params, tokens, self.pool.cache.k_pages,
+                        self.pool.cache.v_pages, page_table, lengths, live,
+                        eos_ids, budgets,
+                    )
                 self.pool.set_cache(new_k, new_v)
             self.stats["window_steps"] += 1
             self.stats["dispatches"] += 1
-            with self.tracer.span("serve.emit"):
-                self._settle_window_rows(rows, out, H)
+            with self.tracer.span("serve.emit", seq=seq):
+                self._settle_window_rows(rows, out, H, seq)
             # crash INSIDE the window's host phase: every emitted token of
             # the window sits in the journal buffer, none acked — recovery
             # replays from the last synced token and the greedy re-prefill
@@ -1366,16 +1395,16 @@ class PagedServer:
             chaos.point("serve.mid_window")
         return True
 
-    def _settle_window_rows(self, rows, out, horizon: int) -> None:
+    def _settle_window_rows(self, rows, out, horizon: int, seq: int) -> None:
         """Post-dispatch accounting for one window: the single budgeted
         host fetch (``[R, 1+N]`` = per-row emitted count + tokens), then
         per-row advance/emit/publish, amortized over up to N tokens per
         row. Rows that froze before the horizon name the window's break
         reason (EOS vs budget); surplus reserved pages go back to the
         pool so a parked reservation never starves the next admission."""
-        with self.tracer.span("serve.fetch"):
+        with self.tracer.span("serve.fetch", seq=seq):
             out = np.asarray(out)  # lint: allow(DS-R005) — the window's one fetch
-        with self.tracer.span("serve.settle") as settle_span:
+        with self.tracer.span("serve.settle", seq=seq) as settle_span:
             emitted = self.stats["emitted_tokens"]
             self._settle_fetched_window(rows, out, horizon)
             settle_span.set(tokens=self.stats["emitted_tokens"] - emitted)
@@ -1605,6 +1634,9 @@ class PagedServer:
         # how often a step was enqueued behind one still in flight: near 1 in
         # steady serving, 0 for a server that has to be synchronous
         s["run_ahead_share"] = s["run_ahead_steps"] / s["ragged_steps"] if s["ragged_steps"] else 0.0
+        # the host's median milliseconds between a step's wait and the next
+        # step's enqueue (``serve.turnaround_ms``); 0.0 before two steps ran back to back
+        s["turnaround_ms_p50"] = self._turnaround.percentile(50)
         drafted, rounds = s["spec_drafted"], s["spec_rounds"]
         s["spec_accept_rate"] = s["spec_accepted"] / drafted if drafted else 0.0
         s["spec_mean_accepted_per_round"] = (

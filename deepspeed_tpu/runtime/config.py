@@ -237,9 +237,11 @@ class AnalysisConfig(DeepSpeedConfigModel):
 class TracingConfig(DeepSpeedConfigModel):
     """Unified tracing/metrics plane (``profiling/tracer.py``; ISSUE 10).
 
-    ``enabled`` (default ON — the tracer is host-side only, adds zero
-    device transfers and zero compiled programs, and measures under 2%
-    of a bench step) records step-phase spans and engine metrics into a
+    ``enabled`` (default ON — the tracer is host-side only and adds zero
+    device transfers and zero compiled programs; on a v5e the serving
+    cells complete 0.4% fewer tokens/s with it on than off: 0.1-0.8% over
+    six alternated pairs, eight spans a step of 13-16 ms, PERF.md section
+    6, PR 36) records step-phase spans and engine metrics into a
     ``max_spans``-deep ring buffer, readable via ``engine.observability()``
     and exportable as a Perfetto/Chrome trace. That export is on the host's
     ``perf_counter`` and holds no device operation; while a

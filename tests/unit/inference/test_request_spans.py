@@ -118,6 +118,7 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
     server = _server(cfg, params, tracer=tr, metrics=m)
     server.serve(_prompts(1, seed=11), max_new_tokens=2)  # compile outside the session
     warm_steps = tr.phase_summary()["serve.step"]["count"]
+    seq = server.stats["dispatches"]  # the number of the first step the session sees
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
@@ -141,12 +142,12 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
         return [e for e in events if e[0] == name and outer[1] <= e[1] and e[2] <= outer[2]]
 
     first = steps[0]
-    assert first[3] == {"waiting": 3, "running": 0, "pages_in_use": 0, "pages_total": server.pool.num_pages - 1}
+    assert first[3] == {"waiting": 3, "running": 0, "pages_in_use": 0, "pages_total": server.pool.num_pages - 1, "seq_enqueued": seq}
     assert waiting_then == 3.0 and m.snapshot()["gauges"]["serve.running"] > 0
     (admit,), (pack, pack_next), (dispatch,) = (inside(first, n) for n in ("serve.admit", "serve.pack", "serve.dispatch"))
     assert admit[3] == {"admitted": 3}
     program = f"paged_ragged_r{server.pool.max_slots}_w8"
-    assert dispatch[3] == {"rows": 3, "width": 8, "program": program, "ahead": 0}
+    assert dispatch[3] == {"seq": seq, "rows": 3, "width": 8, "program": program, "ahead": 0}
     del dispatch[3]["ahead"]
     # three first chunks of at most a page each, of a table of max_slots x max_pages_per_slot slots
     table_pages = server.pool.max_slots * server.pool.max_pages_per_slot
@@ -158,9 +159,15 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
     # settle to the call after it, which does it behind the enqueue of its own step
     (fetch,) = inside(first, "serve.fetch")
     assert inside(first, "serve.emit") == [] and dispatch[2] <= pack_next[1] and pack_next[2] <= fetch[1]
+    # the jitted call alone is a span of its own inside the dispatch, and the step's number rides on all of them:
+    # the step packed in this call is enqueued in the next, and settled in the one after
+    (enqueue,) = inside(dispatch, "serve.enqueue")
+    assert enqueue[3] == {"seq": seq, "program": program} and fetch[3] == {"seq": seq} and pack_next[3]["seq"] == seq + 1
     (dispatch2,), (emit,), (fetch2,) = (inside(steps[1], n) for n in ("serve.dispatch", "serve.emit", "serve.fetch"))
     assert dispatch2[3]["ahead"] == 1 and dispatch2[2] <= emit[1] and emit[2] <= fetch2[1]
+    assert (dispatch2[3]["seq"], emit[3], fetch2[3], steps[1][3]["seq_enqueued"]) == (seq + 1, {"seq": seq}, {"seq": seq + 1}, seq + 1)
     (settle,) = inside(emit, "serve.settle")
+    assert settle[3]["seq"] == seq
     emitted = sum(e[3]["tokens"] for e in events if e[0] == "serve.settle")
     assert emitted == 9 == sum(len(server.take_result(u)) for u in uids) - sum(p.size for p in _prompts(3, seed=12))
     # a later step sees the running set and its pages

@@ -481,4 +481,37 @@ def test_inside_one_step_the_dispatch_closes_before_the_fetch_opens(dense):
         else:
             (settle,) = settle  # of step n, behind the enqueue of step n+1 and before the wait for it
             assert dispatch["t1"] <= settle["t0"] and settle["t1"] <= fetch["t0"]
+            assert settle["attrs"]["seq"] == k - 1
+        # the step's number ties what three calls do for it: call k enqueues and waits for step k (packed by call
+        # k - 1, but for the first), settles step k - 1 and packs step k + 1; the jitted call is a span of its own
+        (enqueue,) = inside(dispatch, "serve.enqueue")
+        assert dispatch["attrs"]["seq"] == enqueue["attrs"]["seq"] == fetch["attrs"]["seq"] == step["attrs"]["seq_enqueued"] == k
+        assert [p["attrs"]["seq"] for p in packs] == ([0, 1] if k == 0 else [k + 1] if packs else [])
+    assert "seq_enqueued" not in steps[-1]["attrs"]
     assert server.stats["run_ahead_steps"] == len(steps) - 2 == server.stats["ragged_steps"] - 1
+
+
+@pytest.mark.parametrize("name", ["chunked_prefill_rides_with_decode", "eos_mid_run"])
+def test_streams_with_the_tracer_on_are_those_with_it_off(dense, name):
+    """The spans, their ``seq`` and the turnaround stamps are host-side
+    bookkeeping: the served tokens and the counters are the same with a live
+    tracer as with the disabled one every other test of this file runs on."""
+    cfg, params = dense
+    sc = SCENARIOS[name]
+    prompts, budgets = _prompts(**sc["prompts"]), sc["budgets"]
+    requests = _eos_requests(cfg, params, prompts, budgets) if sc.get("eos") else [(p, n, None) for p, n in zip(prompts, budgets)]
+    runs = []
+    for tracer in (None, Tracer(max_spans=1 << 14)):
+        server = _server(cfg, params, tracer=tracer)
+        uids = [server.submit(p, max_new_tokens=n, eos_token_id=eos) for p, n, eos in requests]
+        results = _run(server, drained=False)
+        runs.append((server, [results[u] for u in uids]))
+    (off, want), (on, got) = runs
+    _assert_same(got, want)
+    for (p, n, eos), stream in zip(requests, got):
+        assert np.array_equal(stream, _generate(cfg, params, p, n, eos))
+    counted = ("ragged_steps", "run_ahead_steps", "overshoot_rows", "emitted_tokens", "prefill_chunks", "drain_reasons")
+    assert {k: on.stats[k] for k in counted} == {k: off.stats[k] for k in counted}
+    assert off.tracer.spans() == [] and len([r for r in on.tracer.spans() if r["name"] == "serve.enqueue"]) == on.stats["ragged_steps"]
+    # the histogram is the registry's and counts the same steps either way
+    assert on.metrics.histogram("serve.turnaround_ms").snapshot()["count"] == off.metrics.histogram("serve.turnaround_ms").snapshot()["count"] == on.stats["run_ahead_steps"]
