@@ -19,13 +19,19 @@ Pallas's interpreter, for tests off a TPU):
   ``tm`` rows meeting one group that owns rows of it, so a group's weights
   are read once for every row tile it touches (once, when ``M <= tm``), and
   never for a group without rows. At most ``M / tm + G - 1`` visits exist;
-  the grid has that many, the dead ones (past the visits the sizes make)
-  keep the last live visit's blocks, so they fetch nothing, and skip their
-  body. Blocks are as large as the weight budget allows (the whole
-  ``[K, N]`` matrix of an expert when it is at most 4 MiB): a grid step
-  costs ~0.35 us whatever it moves (PERF.md, PR 24), so a 4 MiB block keeps
-  that under a tenth of its 5 us of HBM time. All visit metadata rides in
-  ONE packed ``s32`` scalar-prefetch operand.
+  the grid has that many, and the dead ones (past the visits the sizes
+  make) skip their body and fetch nothing: Pallas copies a block only when
+  its index differs from the step before, so a dead visit repeats the last
+  live visit's group and row tile AND, where K is tiled, holds the last K
+  block that visit left in VMEM instead of walking K again (until PR 37 it
+  walked, and so re-read its expert's whole matrix: PERF.md). A row tile's
+  ``[tm, K]`` rows are one block, indexed by the tile alone and sliced by
+  the K step in the body, so they are read once while the tile's visits
+  pass, not once a K step of each. Weight blocks are as large as the budget
+  allows (the whole ``[K, N]`` matrix of an expert when it is at most
+  4 MiB): a grid step costs ~0.35 us whatever it moves (PERF.md, PR 24), so
+  a 4 MiB block keeps that under a tenth of its 5 us of HBM time. All visit
+  metadata rides in ONE packed ``s32`` scalar-prefetch operand.
 
 The backward pass of either is ``ragged_dot``'s own (``custom_vjp``): the
 kernel is a forward kernel.
@@ -84,7 +90,30 @@ def _visits(group_sizes, group_offset, tiles_m: int, tm: int):
     return jnp.concatenate([group + group_offset, tile, starts[group], ends[group], live_visits[None]]).astype(jnp.int32)
 
 
-def _kernel(meta, x_ref, w_ref, o_ref, acc_ref, *, V: int, tm: int, k_tiles: int):
+# The grid's index maps, of a step ``(n, v, kk)`` and the packed metadata: what
+# they return is ALL that decides what a step fetches (a block is copied when
+# its index differs from the step before), which
+# tests/unit/moe/test_grouped_matmul_fetches.py counts on the CPU.
+
+
+def _x_index(n, v, kk, meta, *, V: int, k_tiles: int):
+    """The visit's row tile, whole in K: the same block while the tile's visits pass."""
+    return meta[V + v], 0
+
+
+def _w_index(n, v, kk, meta, *, V: int, k_tiles: int):
+    """The visit's matrix and the step's K block; a dead visit stays on the
+    last K block, which the last live step left in VMEM."""
+    if k_tiles > 1:
+        kk = jnp.where(v < meta[4 * V], kk, k_tiles - 1)
+    return meta[v], kk, n
+
+
+def _o_index(n, v, kk, meta, *, V: int, k_tiles: int):
+    return meta[V + v], n
+
+
+def _kernel(meta, x_ref, w_ref, o_ref, acc_ref, *, V: int, tm: int, tk: int, k_tiles: int):
     v, kk = pl.program_id(1), pl.program_id(2)
     tile = meta[V + v]
 
@@ -94,7 +123,8 @@ def _kernel(meta, x_ref, w_ref, o_ref, acc_ref, *, V: int, tm: int, k_tiles: int
         def _():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        acc_ref[...] += jnp.dot(x_ref[...], w_ref[0], preferred_element_type=jnp.float32)
+        x = x_ref[...] if k_tiles == 1 else x_ref[:, pl.ds(pl.multiple_of(kk * tk, tk), tk)]
+        acc_ref[...] += jnp.dot(x, w_ref[0], preferred_element_type=jnp.float32)
 
         @pl.when(kk == k_tiles - 1)
         def _():
@@ -124,18 +154,19 @@ def _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret: bool)
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=32 << 20,
         )
+    at = dict(V=V, k_tiles=k_tiles)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(N // tn, V, k_tiles),
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda n, v, kk, meta: (meta[V + v], kk)),
-            pl.BlockSpec((1, tk, tn), lambda n, v, kk, meta: (meta[v], kk, n)),
+            pl.BlockSpec((tm, K), functools.partial(_x_index, **at)),
+            pl.BlockSpec((1, tk, tn), functools.partial(_w_index, **at)),
         ],
-        out_specs=pl.BlockSpec((tm, tn), lambda n, v, kk, meta: (meta[V + v], n)),
+        out_specs=pl.BlockSpec((tm, tn), functools.partial(_o_index, **at)),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, V=V, tm=tm, k_tiles=k_tiles),
+        functools.partial(_kernel, V=V, tm=tm, tk=tk, k_tiles=k_tiles),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, N), out_dtype),
         interpret=interpret,

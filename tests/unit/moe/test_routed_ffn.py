@@ -95,6 +95,11 @@ def by_hand(x, w, sizes, offset=0):
         (256, 128, 128, [0, 256, 0, 0], 4, 0),  # one group owns everything
         (256, 128, 128, [0, 0, 0, 0], 4, 0),  # no assignment at all
         (128, 4096, 256, [60, 8, 60], 9, 3),  # K in two tiles; the groups are matrices 3..5 of a longer stack
+        # K in two tiles AND dead visits (where a dead visit re-read an expert's matrix until PR 37): every row in
+        # tile 0 of four, empty groups between and behind the live ones, matrices 5..16 of a longer stack
+        (512, 4096, 256, [9, 0, 17, 0, 0, 30, 1, 0, 25, 0, 0, 0], 20, 5),
+        # the same over several row tiles: groups that cross a tile's edge, three row tiles of eight without a row
+        (1024, 4096, 384, [100, 0, 60, 200, 0, 0, 150, 3, 0, 0], 14, 4),
     ],
 )
 def test_grouped_matmul_against_a_loop(impl, m, k, n, sizes, stack, offset):
@@ -103,6 +108,23 @@ def test_grouped_matmul_against_a_loop(impl, m, k, n, sizes, stack, offset):
     want, live = by_hand(x, w, sizes, offset)
     got = np.asarray(grouped_matmul(jnp.asarray(x), jnp.asarray(w), jnp.asarray(sizes, jnp.int32), group_offset=offset, impl=impl))
     np.testing.assert_allclose(got[:live], want[:live], atol=2e-4 * np.sqrt(k / 64), rtol=1e-5)  # rows past the groups are undefined
+
+
+def test_grouped_matmul_shapes_bench_rehearses():
+    """``tools/grouped_matmul_shapes_bench.py --rehearse``: the tool's control flow, tiny, on the CPU: a line a window."""
+    import json
+    import pathlib
+    import subprocess
+    import sys
+
+    tool = pathlib.Path(__file__).parents[3] / "tools" / "grouped_matmul_shapes_bench.py"
+    done = subprocess.run([sys.executable, str(tool), "--rehearse"], capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    lines = [json.loads(line) for line in done.stdout.splitlines() if line.startswith("{")]
+    assert [(line["shape"], line["window"], line["device"]) for line in lines] == [("tiny", "narrow", "cpu"), ("tiny", "mixed", "cpu")]
+    for line in lines:
+        assert 0 < line["experts_hit"] <= line["live"] < line["rows"] and line["least_us_a_layer"] > 0
+        assert all(line[impl][key] > 0 for impl in ("pallas", "xla") for key in ("up_us", "down_us", "layer_us", "layer_roofline_pct"))
 
 
 def test_the_kernels_gradient_is_ragged_dots():
