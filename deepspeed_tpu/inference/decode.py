@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.compression.int8 import qmatmul
-from deepspeed_tpu.models.config import TransformerConfig, has_state_layers
+from deepspeed_tpu.models.config import TransformerConfig, has_latent_layers, has_state_layers
 from deepspeed_tpu.models.transformer import _norm, _rope
 
 NEG_INF_F = -1e30  # additive mask for dead beams (finite: keeps fp math NaN-free)
@@ -307,15 +307,21 @@ _decoder_cache: Dict[Tuple, Tuple] = {}
 
 def _refuse_state_layers(cfg, what: str) -> None:
     """Every key and value of a row is the only state ``what`` knows of. A
-    model with recurrent-state or sliding-window layers (``layer_types``
-    naming ``linear`` or ``window``) is refused where it is built, with the
-    missing piece named."""
+    model with recurrent-state, sliding-window or latent-attention layers
+    (``layer_types`` naming ``linear``, ``window`` or ``latent``) is refused
+    where it is built, with the missing piece named."""
     if has_state_layers(cfg):
         raise NotImplementedError(
             f"{what} does not support a model with recurrent-state (linear-attention) or sliding-window layers: "
             "it would need a snapshot of each row's recurrent state and convolution tail, or a window layer's "
             "masks, sinks and heads of their own, beside its keys and values, which only the paged server's "
             "per-slot store keeps (serve through init_inference(...).serve())"
+        )
+    if has_latent_layers(cfg):
+        raise NotImplementedError(
+            f"{what} does not support a model with latent-attention layers: a row's latents live in the paged "
+            "server's latent pages (kv_pool.StateStore.latent, one entry a token and no value array), which this "
+            "path neither allocates, copies, rolls back nor reads (serve through init_inference(...).serve())"
         )
 
 

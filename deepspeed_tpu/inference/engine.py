@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig, DtypeEnum
-from deepspeed_tpu.models.config import has_state_layers
+from deepspeed_tpu.models.config import has_latent_layers, has_state_layers
 from deepspeed_tpu.models.transformer import TransformerLM
 from deepspeed_tpu.parallel.mesh import get_topology
 from deepspeed_tpu.profiling.compile_telemetry import CompileTelemetry
@@ -636,6 +636,16 @@ class InferenceEngine:
                         kind="kv_pool",
                         detail={k: v for k, v in rep.items() if k.startswith("window_")},
                     )
+                if "latent_total_bytes" in rep:
+                    # the latent-attention layers' pages: one entry a token a layer under
+                    # the pool's page ids, no value array (kv_pool.StateStore.latent)
+                    ledger.add_persistent(
+                        "latent_kv",
+                        per_chip_bytes=rep["latent_total_bytes"],
+                        global_bytes=rep["latent_total_bytes"],
+                        kind="kv_pool",
+                        detail={k: v for k, v in rep.items() if k.startswith("latent_")},
+                    )
                 ledger.add_persistent(
                     "kv_page_tables",
                     per_chip_bytes=rep["host_table_bytes"],
@@ -714,12 +724,14 @@ class InferenceEngine:
 
             params = quantize_params_int8(params)
         prefix_cache = pcfg.prefix_cache
-        if has_state_layers(self._ds_config) and "prefix_cache" not in pcfg.model_fields_set:
+        unshareable = has_state_layers(self._ds_config) or has_latent_layers(self._ds_config)
+        if unshareable and "prefix_cache" not in pcfg.model_fields_set:
             # the default is on; a model with recurrent-state or sliding-window
             # layers cannot attach a cached prefix (no state snapshot at that
-            # position, no ring of the pages before it), so the default is off
-            # for it. Asked for by name, it is refused
-            log_dist("paged_kv.prefix_cache defaults to off for a model with recurrent-state or sliding-window layers", ranks=[0])
+            # position, no ring of the pages before it), and the pool's
+            # copy-on-write does not copy a latent layer's pages yet, so the
+            # default is off for them. Asked for by name, it is refused
+            log_dist("paged_kv.prefix_cache defaults to off for a model with recurrent-state, sliding-window or latent-attention layers", ranks=[0])
             prefix_cache = False
         server = PagedServer(
             self._ds_config,

@@ -1,7 +1,8 @@
 """The ragged serving step of a model whose layers are of more than one kind
 (``models/hybrid_moe.py``): softmax layers that keep keys and values in pages,
 sliding-window layers that keep a row's newest pages in a ring, linear-attention
-layers that keep one recurrent state and a convolution tail a row; leading
+layers that keep one recurrent state and a convolution tail a row, latent-attention
+layers that keep one low-rank entry a token in pages of their own; leading
 layers with a dense FFN, then every layer with its routed FFN.
 
 ``decode.build_ragged_step`` comes here, when the program is BUILT, for a
@@ -32,7 +33,13 @@ parameter of the program) and one more row array:
   and the kernel starts its walk at the window's first page. A row that starts
   again at position 0 simply overwrites: what its ring held lies past its
   length or outside its window, and is masked. ``R`` is the pool's
-  ``max_slots``.
+  ``max_slots``;
+* ``store.latent`` ``[latent layers, NP, P, lanes]``: the latent layers' pages,
+  under the SAME page table and page ids as ``k_pages`` (one allocation of a
+  page id holds a token's entry in every paged layer of either kind). A token
+  is stored once, ``[c_kv ; k_rope]`` (``kv_lora_rank + qk_rope_head_dim``
+  numbers, at whole lane tiles: 576 at 640), with no value array: the value is
+  the entry's leading ``kv_lora_rank`` lanes.
 
 The leading dense layers, then one ``lax.scan`` over PERIODS run the layers;
 the scan's body holds the period's layers in order and reaches each layer's
@@ -47,6 +54,11 @@ tiles, as in ``decode._paged_layers``; a window of at most one tile is one
   the same width-1 call and the rows with a chunk ``CHUNK_ROWS`` a trip of a
   loop whose count is data (``wide_attention``: the ``[R, W]`` window is
   never laid out); the window layers' calls with ``window`` and their sinks;
+* latent: ``latent_paged_attention`` (``ops/transformer/latent_attention.py``)
+  in the absorbed form, for decode rows and prefill chunks alike, split by
+  width as the softmax layers' calls are: the query is ``[q_nope Wk_b^T ;
+  q_rope]`` against the stored entry, the output ``(P c_kv) Wv_b`` and then
+  ``Wo``; the kernel reads a page once and writes the step's entries in place;
 * linear: a row with ONE token (a decode row, in the narrow program or
   riding in a wide window) goes through ``kda_decode``, in place on the
   pool; a row with more (a prefill chunk) goes through the chunkwise form
@@ -110,7 +122,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
 
     B, T = tokens.shape
     dtype = k_pages.dtype
-    state, conv, wk, wv = store
+    state, conv, wk, wv, latent = store
     tile = decode.token_tile(cfg)
     tiled = bool(tile) and B * T > tile
     packed = decode._pack_window(q_lens, B, T, tile) if tiled else _whole_slab(q_lens, B, T)
@@ -177,7 +189,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         out, _ = decode._ffn_body(cfg, p, x_tile, p["mlp_norm_scale"], None)
         return x_tile + out, jnp.zeros((E,), jnp.int32)
 
-    def wide_attention(attend, qkv, NKV, kp, vp, layer, table):
+    def wide_attention(attend, operands, shapes, pools, layer, table, width):
         """A wide window's attention without the window's slab: the rows with
         ONE token (decode rows riding beside a prefill chunk) through one
         width-1 call, the rows with a chunk ``CHUNK_ROWS`` a trip of a loop
@@ -186,28 +198,29 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         XLA gathers, transposes and concatenates for the kernel, and the
         kernel gives each one-token row a whole query tile: 7 ms a layer of a
         64 x 128 window with one chunk in it (PERF.md section 6, PR 34).
-        ``qkv`` packed ``[NPK, heads * d]``; returns the output packed
-        ``[NPK, NH * Dv]`` (dead slots: zeros) and the pools."""
-        heads = (NH, NKV, NKV)
-        first = tuple(jnp.take(a, starts, axis=0, mode="clip").reshape(B, 1, nh, -1) for a, nh in zip(qkv, heads))
-        o1, kp, vp = attend(*first, kp, vp, layer, table, jnp.where(one_token, kv_lens, 0), one_token.astype(jnp.int32))
+        ``operands`` packed ``[NPK, ...]``, each a token's ``shapes`` entry in
+        a window; ``attend(*windows, *pools, layer, table, kv_lens, q_lens)``
+        gives ``(out, *pools)``. Returns the output packed ``[NPK, width]``
+        (dead slots: zeros) and the pools."""
+        first = tuple(jnp.take(a, starts, axis=0, mode="clip").reshape((B, 1) + shape) for a, shape in zip(operands, shapes))
+        o1, *pools = attend(*first, *pools, layer, table, jnp.where(one_token, kv_lens, 0), one_token.astype(jnp.int32))
 
         def trip(i, carry):
-            attn, kp, vp = carry
+            attn, *pools = carry
             at = i * CHUNK_ROWS + jnp.arange(CHUNK_ROWS, dtype=jnp.int32)
             rows = order[jnp.minimum(at, B - 1)]
             lens = jnp.where(at < n_chunk_rows, q_lens[rows], 0)  # past the last chunk row: a dead row
             index = packed.index[rows]  # [CHUNK_ROWS, T]: a row's tokens lie together
-            window = tuple(jnp.take(a, index, axis=0, mode="clip").reshape(CHUNK_ROWS, T, nh, -1) for a, nh in zip(qkv, heads))
-            o, kp, vp = attend(*window, kp, vp, layer, table[rows], jnp.where(lens > 0, kv_lens[rows], 0), lens)
+            window = tuple(jnp.take(a, index, axis=0, mode="clip").reshape((CHUNK_ROWS, T) + shape) for a, shape in zip(operands, shapes))
+            o, *pools = attend(*window, *pools, layer, table[rows], jnp.where(lens > 0, kv_lens[rows], 0), lens)
             real = jnp.arange(T, dtype=jnp.int32)[None, :] < lens[:, None]
-            attn = attn.at[jnp.where(real, index, NPK).reshape(-1)].set(o.reshape(CHUNK_ROWS * T, NH * Dv), mode="drop")
-            return attn, kp, vp
+            attn = attn.at[jnp.where(real, index, NPK).reshape(-1)].set(o.reshape(CHUNK_ROWS * T, width), mode="drop")
+            return (attn, *pools)
 
-        attn, kp, vp = jax.lax.fori_loop(
-            0, (n_chunk_rows + CHUNK_ROWS - 1) // CHUNK_ROWS, trip, (jnp.zeros((NPK, NH * Dv), dtype), kp, vp)
+        attn, *pools = jax.lax.fori_loop(
+            0, (n_chunk_rows + CHUNK_ROWS - 1) // CHUNK_ROWS, trip, (jnp.zeros((NPK, width), dtype), *pools)
         )
-        return attn.at[jnp.where(one_token, starts, NPK)].set(o1.reshape(B, NH * Dv), mode="drop"), kp, vp
+        return (attn.at[jnp.where(one_token, starts, NPK)].set(o1.reshape(B, width), mode="drop"), *pools)
 
     def attention_layer(kind, x, kp, vp, tree, per, jk, layer, ffn):
         """A softmax or a window layer: ``tree`` its kind's stacks (its own
@@ -243,7 +256,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
                 )
                 attn = attn.reshape(B * T, NH * Dv)
             else:
-                attn, kp, vp = wide_attention(attend, qkv, NKV, kp, vp, layer, table)
+                attn, kp, vp = wide_attention(attend, qkv, ((NH, D), (NKV, D), (NKV, Dv)), (kp, vp), layer, table, NH * Dv)
 
         def after(start, carry):
             x, counts = carry
@@ -259,6 +272,53 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
 
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, kp, vp, counts
+
+    def latent_layer(x, pages, tree, per, jk, layer, ffn):
+        """A latent layer in the absorbed form: ``tree``, ``per``, ``jk``,
+        ``layer`` and ``ffn`` as ``attention_layer``'s; ``pages`` the latent
+        layers' pool. Returns ``(x, pages, counts)``."""
+        from deepspeed_tpu.ops.transformer.latent_attention import latent_paged_attention
+
+        C, Dl = cfg.kv_lora_rank, cfg.latent_width
+        # the per-head matrices, sliced out of their stacks ONCE a layer, outside the tile loop: inside it the
+        # compiler wants them token-minor for the head-wise products and, tied to the tile as the other leaves
+        # are, transposes the whole stack of every layer on every layer's entry (0.65 ms a layer: PERF.md, PR 41)
+        per_head = ("wq_b", "wk_b", "wv_b")
+        heads = weights_at({k: tree[k] for k in per_head}, per, jk, jnp.int32(0))
+        tree = {k: v for k, v in tree.items() if k not in per_head}
+
+        def before(start, bufs):
+            p = {**weights_at(tree, per, jk, start), **heads}
+            h = _norm(packed.take(x, start), p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
+            q_nope, q_rope, entry = hm.latent_project(cfg, p, h[None], packed.take(positions, start)[None])
+            q = hm.latent_absorb(cfg, p, q_nope[0], q_rope[0])  # [tile, NH, Dl]: against the stored entry
+            return put(bufs[0], q.reshape(q.shape[0], NH * Dl), start), put(bufs[1], entry[0], start)
+
+        with jax.named_scope(hm.SCOPES["latent"]):
+            q, entries = tiles(before, (jnp.zeros((NPK, NH * Dl), dtype), jnp.zeros((NPK, Dl), dtype)))
+            attend = functools.partial(latent_paged_attention, value_lanes=C, scale=scale, impl=attn_impl)
+            if T == 1:
+                o, pages = attend(
+                    packed.expand(q).reshape(B, T, NH, Dl), packed.expand(entries).reshape(B, T, Dl),
+                    pages, layer, page_table, kv_lens, q_lens,
+                )
+                o = o.reshape(B * T, NH * C)
+            else:
+                o, pages = wide_attention(attend, (q, entries), ((NH, Dl), (Dl,)), (pages,), layer, page_table, NH * C)
+
+        def after(start, carry):
+            x, counts = carry
+            x_tile = packed.take(x, start)
+            with jax.named_scope(hm.SCOPES["latent"]):
+                p = {**weights_at(tree, per, jk, start), **heads}
+                # the narrow program's is in slab order (which a whole slab's packing is), the wide one's packed
+                a = jnp.take(o, packed.take(packed.slot, start), axis=0, mode="clip") if T == 1 else packed.take(o, start)
+                x_tile = x_tile + hm.latent_output(cfg, p, a.reshape(a.shape[0], NH, C)).astype(x.dtype)
+            x_tile, tile_counts = ffn(x_tile[None], start)
+            return put(x, x_tile[0], start), counts + tile_counts
+
+        x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
+        return x, pages, counts
 
     def linear_layer(x, st, cv, per, jl, j):
         layer = per * nl + jl
@@ -321,15 +381,17 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, st, cv, counts
 
-    # a kind's pools: the full layers' pages, the window layers' rings
-    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv)}
+    # a kind's pools: the full layers' pages, the window layers' rings, the latent layers' pages
+    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,)}
+    mixers = {"softmax": functools.partial(attention_layer, "softmax"), "window": functools.partial(attention_layer, "window"),
+              "latent": latent_layer}
     # the leading dense layers, each with its own weights and the first entries of its kind's pools
     for i, kind in enumerate(cfg.layer_types[: cfg.leading_dense_layers]):
         lead = params["leading"][i]
         if kind == "linear":
             raise NotImplementedError("a leading dense layer with a linear-attention mixer is not served")
-        x, *written, _ = attention_layer(
-            kind, x, *pools[kind], lead["mixer"], None, None, cfg.layer_types[:i].count(kind),
+        x, *written, _ = mixers[kind](
+            x, *pools[kind], lead["mixer"], None, None, cfg.layer_types[:i].count(kind),
             functools.partial(dense_ffn, p=lead["ffn"]),
         )
         pools[kind] = tuple(written)
@@ -344,8 +406,8 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
                 x, st, cv, c = linear_layer(x, st, cv, per, at[kind], j)
             else:
                 layer = cfg.leading_of(kind) + per * period.count(kind) + at[kind]
-                x, *written, c = attention_layer(
-                    kind, x, *pools[kind], stacks[kind], per, at[kind], layer, functools.partial(ffn, per=per, j=j)
+                x, *written, c = mixers[kind](
+                    x, *pools[kind], stacks[kind], per, at[kind], layer, functools.partial(ffn, per=per, j=j)
                 )
                 pools[kind] = tuple(written)
             at[kind] += 1
@@ -355,24 +417,27 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
     (x, st, cv, pools), counts = jax.lax.scan(
         period_step, (x, state, conv, pools), jnp.arange(cfg.num_periods, dtype=jnp.int32)
     )
-    return x, *pools["softmax"], StateStore(st, cv, *pools["window"]), counts.reshape(cfg.num_moe_layers, E), packed
+    return x, *pools["softmax"], StateStore(st, cv, *pools["window"], *pools["latent"]), counts.reshape(cfg.num_moe_layers, E), packed
 
 
 def hybrid_forward(cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots,
-                   attn_impl: str = "auto", window=None):
+                   attn_impl: str = "auto", window=None, latent=None):
     """``decode._paged_forward`` for a hybrid model: the window's logits
     ``[B, T, V]`` (a dead slot's are some live token's) and the pools:
     ``(logits, k_pages, v_pages, state, conv, moe_counts)`` and, for a model
     with sliding-window layers, whose rings ``window = (window_k, window_v)``
-    gives, the rings after them. What the parity tests and the benchmark's
+    gives, the rings after them; for a model with latent layers, whose pages
+    ``latent`` gives, those pages last. What the parity tests and the benchmark's
     logits tools compare with the reference; the serving step takes its
     arg-max on the packed tiles instead."""
     x, kp, vp, store, moe_counts, packed = _hybrid_layers(
-        cfg, params, tokens, k_pages, v_pages, StateStore(state, conv, *(window or ())), page_table, lengths, q_lens,
-        slots, attn_impl,
+        cfg, params, tokens, k_pages, v_pages, StateStore(state, conv, *(window or (None, None)), latent), page_table,
+        lengths, q_lens, slots, attn_impl,
     )
     out = (decode._final_logits(cfg, params, packed.expand(x)), kp, vp, store.state, store.conv, moe_counts)
-    return out if window is None else out + ((store.window_k, store.window_v),)
+    if window is not None:
+        out += ((store.window_k, store.window_v),)
+    return out if latent is None else out + (store.latent,)
 
 
 def build_hybrid_ragged_step(cfg, rows: int, width: int, page_size: int, attn_impl: str, telemetry, name: str, key):

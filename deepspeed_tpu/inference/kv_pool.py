@@ -148,7 +148,8 @@ def init_paged_cache(
     so per-chip KV HBM is ``hbm_bytes() / tp``."""
     if dtype is None:
         dtype = _DTYPES[cfg.dtype]
-    # a model with layers of more than one kind pages its softmax layers only
+    # a model with layers of more than one kind keeps the K and V of its softmax layers
+    # only here (none, zero-sized arrays, where every paged layer is a latent one)
     layers = cfg.layers_of("softmax") if getattr(cfg, "layer_types", None) else cfg.num_layers
     k_shape = (layers, num_pages, cfg.num_kv_heads, page_size, key_lanes(cfg.head_dim))
     v_shape = k_shape[:-1] + (getattr(cfg, "v_head_dim", None) or cfg.head_dim,)
@@ -204,21 +205,41 @@ class StateStore(NamedTuple):
       ring``, whatever the row's length, ``ring`` being
       ``window_ring_pages``. What a page held a lap ago lies outside the
       window or past the row's length, and is masked. ``None`` for a model
-      with no such layer."""
+      with no such layer.
+
+    And, beside those, the one array here that IS under the page table:
+
+    * latent-attention layers: ``latent`` holds ``[c_kv ; k_rope]`` of a
+      token ONCE (no value array: the value is the entry's leading
+      ``kv_lora_rank`` lanes), in pages with the page ids of ``PagedKVCache``'s
+      (the pool's free list, tables and trash page 0 serve both), an entry at
+      ``key_lanes`` of its width. It rides here because the serving step
+      takes and returns this store in place; ``None`` for a model with no
+      such layer."""
 
     state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32
     conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3 NH D]
     window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk]
     window_v: Optional[jax.Array] = None
+    latent: Optional[jax.Array] = None  # [latent layers, num_pages, P, lanes]
 
     def window_bytes(self) -> int:
         return 0 if self.window_k is None else self.window_k.nbytes + self.window_v.nbytes
 
+    def latent_bytes(self) -> int:
+        return 0 if self.latent is None else self.latent.nbytes
+
     def hbm_bytes(self) -> int:
-        return self.state.nbytes + self.conv.nbytes + self.window_bytes()
+        return self.state.nbytes + self.conv.nbytes + self.window_bytes() + self.latent_bytes()
 
 
 def _refuse_with_state(states, what: str) -> None:
+    if states is not None and states.latent is not None:
+        raise NotImplementedError(
+            f"{what} is not supported for a model with latent-attention layers: the pool copies, shares and rolls "
+            "back pages of the K and V arrays (kv_pool._copy_page, the prefix index), and a latent layer's pages are a "
+            "third array (StateStore.latent) that none of them knows yet"
+        )
     if states is not None and (states.state.size or states.window_k is not None):
         raise NotImplementedError(
             f"{what} is not supported for a model with recurrent-state or sliding-window layers: keys and values "
@@ -281,13 +302,17 @@ class PagePool:
 
             shapes = state_shapes(cfg, self.max_slots)
             kv_dtype = self.cache.k_pages.dtype
-            rings = ()
+            rings = (None, None)
             if cfg.layers_of("window"):
                 if not prefill_chunk:
                     raise ValueError("a model with sliding-window layers needs prefill_chunk to size its page rings")
                 self.window_ring = window_ring_pages(cfg.window, self.page_size, int(prefill_chunk))
                 rings = tuple(jnp.zeros(shape, kv_dtype) for shape in window_shapes(cfg, self.max_slots, self.page_size, self.window_ring))
-            self.states = StateStore(jnp.zeros(shapes.state, jnp.float32), jnp.zeros(shapes.conv, kv_dtype), *rings)
+            latent = None
+            if cfg.layers_of("latent"):
+                # one entry a token under the pool's own page ids: no value array
+                latent = jnp.zeros((cfg.layers_of("latent"), num_pages, self.page_size, key_lanes(cfg.latent_width)), kv_dtype)
+            self.states = StateStore(jnp.zeros(shapes.state, jnp.float32), jnp.zeros(shapes.conv, kv_dtype), *rings, latent)
         # LIFO free list keeps hot pages hot; page 0 stays out of circulation
         self._free = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._free_slots = list(range(max_slots - 1, -1, -1))
@@ -337,9 +362,15 @@ class PagePool:
     def live_tokens(self) -> int:
         return int(self.seq_lens.sum())
 
+    @property
+    def latent_bytes_per_token(self) -> int:
+        """HBM bytes one cached token costs across the latent layers: one entry a layer, no value."""
+        latent = None if self.states is None else self.states.latent
+        return 0 if latent is None else latent.shape[0] * latent.shape[-1] * latent.dtype.itemsize
+
     def live_hbm_bytes(self) -> int:
         """HBM actually pinned by live sequences (page-granular)."""
-        return self.used_pages() * self.page_size * self.cache.bytes_per_token
+        return self.used_pages() * self.page_size * (self.cache.bytes_per_token + self.latent_bytes_per_token)
 
     def memory_report(self) -> dict:
         """Residency accounting for the analysis HBM ledger, read from the
@@ -357,7 +388,7 @@ class PagePool:
         if self.states is not None:
             in_use = self.max_slots - len(self._free_slots)
             state = {
-                "state_total_bytes": self.states.hbm_bytes() - self.states.window_bytes(),
+                "state_total_bytes": self.states.state.nbytes + self.states.conv.nbytes,
                 "state_slots": self.max_slots,
                 "state_slots_in_use": in_use,
             }
@@ -369,6 +400,15 @@ class PagePool:
                     window_ring_pages=self.window_ring,
                     window_slots=self.max_slots,
                     window_slots_in_use=in_use,
+                )
+            if self.states.latent is not None:
+                latent = self.states.latent
+                state.update(
+                    latent_total_bytes=latent.nbytes,
+                    latent_bytes_per_token=self.latent_bytes_per_token,
+                    latent_layers=latent.shape[0],
+                    latent_lanes=latent.shape[-1],  # an entry's stored width (its own at whole lane tiles)
+                    latent_live_bytes=self.used_pages() * self.page_size * self.latent_bytes_per_token,
                 )
         return {
             **state,
@@ -809,6 +849,8 @@ class PagePool:
             k_pages=self.cache.k_pages[:, gather],
             v_pages=self.cache.v_pages[:, gather],
         )
+        if self.states is not None and self.states.latent is not None:
+            self.states = self.states._replace(latent=self.states.latent[:, gather])
         for s in range(self.max_slots):
             for i in range(int(self._owned[s])):
                 self.page_table[s, i] = remap[int(self.page_table[s, i])]

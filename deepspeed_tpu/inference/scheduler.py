@@ -1158,6 +1158,9 @@ class PagedServer:
                 self._g_state_slots.set(len(rows))
                 if states.window_k is not None:
                     self._g_window_slots.set(len(rows))
+                if states.latent is not None:
+                    # live tokens under latent pages after this step: what each latent layer's kernel reads
+                    pack_span.set(latent_tokens=int((lengths + q_lens)[q_lens > 0].sum()))
             # the window with the in-flight rows' tokens laid in on the device
             # (queued behind the step that computes them), and the rest
             window = self._feed_tokens(tokens, prev.next_tokens if prev is not None else self._no_tokens, src)

@@ -106,6 +106,13 @@ def has_state_layers(cfg) -> bool:
     return bool({"linear", "window"} & set(getattr(cfg, "layer_types", None) or ()))
 
 
+def has_latent_layers(cfg) -> bool:
+    """Whether some layer keeps one latent entry a token in place of keys and
+    values a head (``layer_types`` naming ``latent``): pages of a third array,
+    which what copies, shares or rolls back K and V pages does not know."""
+    return "latent" in (getattr(cfg, "layer_types", None) or ())
+
+
 def gpt2_config(size: str = "125m", **overrides) -> TransformerConfig:
     presets = {
         "tiny": dict(hidden_size=256, num_layers=4, num_heads=8, vocab_size=1024, max_seq_len=512),

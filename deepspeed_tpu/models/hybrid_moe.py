@@ -1,12 +1,13 @@
 """A decoder whose layers are of more than one kind: softmax attention over
 every earlier key in some, over a sliding window in others, gated delta-rule
-linear attention (``ops/transformer/linear_attention.py``) in others; a
-routed FFN that may have a shared expert and may hold only this chip's share
+linear attention (``ops/transformer/linear_attention.py``) in others, latent
+attention (one low-rank latent a token in place of keys and values a head) in
+others; a routed FFN that may have a shared expert and may hold only this chip's share
 of the experts its router chooses from, behind ``leading_dense_layers`` layers
 whose FFN is dense.
 
 ``HybridMoEConfig.layer_types`` says what each layer is (``softmax`` /
-``window`` / ``linear``); after the leading dense layers the list repeats
+``window`` / ``linear`` / ``latent``); after the leading dense layers the list repeats
 with a period (one softmax layer and three linear ones, say, or five window
 layers and a softmax one). Parameters are stacked by KIND inside a period and
 by period in front; a leading layer has its own::
@@ -15,6 +16,7 @@ by period in front; a leading layer has its own::
     params["periods"]["softmax"]  leaves [periods, softmax layers a period, ...]
     params["periods"]["window"]   leaves [periods, window layers a period, ...]
     params["periods"]["linear"]   leaves [periods, linear layers a period, ...]
+    params["periods"]["latent"]   leaves [periods, latent layers a period, ...]
     params["periods"]["moe"]      leaves [periods, layers a period, ...]
 
 so the leading layers and then one ``lax.scan`` over periods run the model,
@@ -39,7 +41,18 @@ of ``linear_conv_kernel`` taps and SiLU; per head ``q = l2norm(q~) / sqrt(Dk)``,
 ``k = l2norm(k~)``; decay ``a = exp(-exp(A_log) softplus(Wf_up (Wf_down h) +
 dt_bias))`` a key channel; ``b = 2 sigmoid(h w_b)`` a head (``1 x`` without
 ``linear_allow_neg_eigval``); the delta rule; output
-``(RMSNorm_head(o) * sigmoid(Wg_up (Wg_down h))) Wo``. The FFN: ``moe_scoring``
+``(RMSNorm_head(o) * sigmoid(Wg_up (Wg_down h))) Wo``. The latent layer
+(``latent_project``, ``latent_absorb``, ``latent_output``): ``c_q = RMSNorm(h
+Wq_a)`` of ``q_lora_rank``, a query head ``[q_nope ; q_rope] = c_q Wq_b`` of
+``qk_nope_head_dim + qk_rope_head_dim`` (= ``head_dim``); ``[c ; r] = h Wkv_a``,
+``c_kv = RMSNorm(c)`` of ``kv_lora_rank`` (the norm over those alone), ``k_rope
+= RoPE(r)`` of ``qk_rope_head_dim``, ONE for all heads; a head's key is
+``[c_kv Wk_b,h ; k_rope]``, its value ``c_kv Wv_b,h`` of ``v_head_dim``
+(``Wk_b`` and ``Wv_b`` are the published ``kv_b_proj``'s two parts, stored
+apart); rotate-half on the rope parts at the token's absolute position, scale
+``head_dim^-0.5``. ``apply`` computes that (the expanded form); the server keeps
+``[c_kv ; k_rope]`` a token and computes the same numbers absorbed: ``q~ =
+q_nope Wk_b,h^T`` against ``c_kv``, ``o = (P c_kv) Wv_b,h``. The FFN: ``moe_scoring``
 over ``moe_router_experts`` outputs, the ``moe_top_k`` largest of score +
 selection bias, gates normalised over the chosen (``moe_norm_topk_prob``) and
 scaled by ``moe_routed_scaling``; of those, the experts this chip holds
@@ -60,9 +73,9 @@ from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.models.moe_transformer import MoETransformerConfig, MoETransformerLM
 from deepspeed_tpu.models.transformer import _norm
 
-LAYER_KINDS = ("softmax", "linear", "window")
+LAYER_KINDS = ("softmax", "linear", "window", "latent")
 # the named scope around a kind's mixer, which the benchmark's readers find device time by
-SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention"}
+SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention", "latent": "latent_attention"}
 
 
 @dataclasses.dataclass
@@ -82,6 +95,11 @@ class HybridMoEConfig(MoETransformerConfig):
     linear_conv_kernel: int = 4
     linear_gate_rank: int = 0  # the decay's and the output gate's low rank; 0: linear_head_dim
     linear_allow_neg_eigval: bool = True
+    # latent layers: the two low ranks and a query/key head's two parts (head_dim is their sum)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
     # the router
     moe_scoring: str = "softmax"  # softmax | sigmoid
     moe_select_bias: bool = False  # a learned bias added to the scores for the choice alone
@@ -102,6 +120,13 @@ class HybridMoEConfig(MoETransformerConfig):
             raise ValueError("a window layer needs window >= 1")
         if not 0 <= self.leading_dense_layers < self.num_layers:
             raise ValueError(f"leading_dense_layers={self.leading_dense_layers} of {self.num_layers} layers")
+        if "latent" in self.layer_types:
+            sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim)
+            if min(sizes) < 1 or self.qk_nope_head_dim + self.qk_rope_head_dim != self.head_dim or self.position != "rope":
+                raise ValueError(
+                    "a latent layer needs q_lora_rank, kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, "
+                    f"head_dim = their last two's sum and position='rope': got {sizes}, head_dim={self.head_dim}, position={self.position!r}"
+                )
         self.linear_num_heads = self.linear_num_heads or self.num_heads
         self.linear_head_dim = self.linear_head_dim or self.head_dim
         self.linear_gate_rank = self.linear_gate_rank or self.linear_head_dim
@@ -146,6 +171,11 @@ class HybridMoEConfig(MoETransformerConfig):
 
     def kv_heads_of(self, kind: str) -> int:
         return self.window_num_kv_heads if kind == "window" else self.num_kv_heads
+
+    @property
+    def latent_width(self) -> int:
+        """What a latent layer keeps of a token: ``[c_kv ; k_rope]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -222,6 +252,39 @@ def linear_output(cfg: HybridMoEConfig, p, h, o):
     gate = jax.nn.sigmoid(qmatmul(qmatmul(h, p["wg_down"]), p["wg_up"]).astype(jnp.float32))
     o = _norm(o.astype(jnp.float32), p["o_norm_scale"], None, "rmsnorm", cfg.norm_eps)
     return qmatmul((o.reshape(gate.shape) * gate).astype(h.dtype), p["wo"])
+
+
+def latent_project(cfg: HybridMoEConfig, p, h, positions):
+    """A latent layer's projections of the normed ``h`` [B, T, H] at
+    ``positions`` [B, T]: ``q_nope`` [B, T, NH, nope], ``q_rope`` [B, T, NH,
+    rope] rotated, and what the layer keeps of each token, ``[c_kv ; k_rope]``
+    [B, T, kv_lora_rank + rope]: the normed latent and the one rotated key part
+    all heads share."""
+    from deepspeed_tpu.models.transformer import _rope
+
+    NH, nope, rope, C = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    c_q = _norm(qmatmul(h, p["wq_a"]), p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+    q = qmatmul(c_q, p["wq_b"]).reshape(h.shape[:-1] + (NH, nope + rope))
+    kv = qmatmul(h, p["wkv_a"])
+    c_kv = _norm(kv[..., :C], p["kv_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+    k_rope = _rope(kv[..., None, C:], positions, cfg.rope_theta)[..., 0, :]
+    return q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta), jnp.concatenate([c_kv, k_rope], axis=-1)
+
+
+def latent_absorb(cfg: HybridMoEConfig, p, q_nope, q_rope):
+    """The query against the stored latent: ``[q_nope Wk_b,h^T ; q_rope]``
+    [..., NH, kv_lora_rank + rope], so that its product with ``[c_kv ; k_rope]``
+    is the head's score."""
+    wk_b = p["wk_b"].reshape(cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim)
+    return jnp.concatenate([jnp.einsum("...hd,chd->...hc", q_nope, wk_b.astype(q_nope.dtype)), q_rope], axis=-1)
+
+
+def latent_output(cfg: HybridMoEConfig, p, o):
+    """``o`` [..., NH, kv_lora_rank], a head's weights over the latents: its
+    values' sum ``o Wv_b,h``, then the output projection. [..., H]."""
+    wv_b = p["wv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim)
+    attn = jnp.einsum("...hc,chv->...hv", o, wv_b.astype(o.dtype))
+    return qmatmul(attn.reshape(o.shape[:-2] + (cfg.num_heads * cfg.v_head_dim,)), p["wo"])
 
 
 def moe_ffn(cfg: HybridMoEConfig, p, h, live=None, experts=None, group_offset=0):
@@ -305,6 +368,19 @@ class HybridMoETransformerLM(MoETransformerLM):
                     "o_norm_scale": jnp.ones(lead + (LD,)),
                     "wo": dense(lead + (C, H), out_std),
                 }
+            if kind == "latent":
+                Cq, C, nope, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+                return {
+                    "attn_norm_scale": jnp.ones(lead + (H,)),
+                    "wq_a": dense(lead + (H, Cq)),
+                    "q_norm_scale": jnp.ones(lead + (Cq,)),
+                    "wq_b": dense(lead + (Cq, NH * (nope + rope))),
+                    "wkv_a": dense(lead + (H, C + rope)),
+                    "kv_norm_scale": jnp.ones(lead + (C,)),
+                    "wk_b": dense(lead + (C, NH * nope)),
+                    "wv_b": dense(lead + (C, NH * Dv)),
+                    "wo": dense(lead + (NH * Dv, H), out_std),
+                }
             NKV = cfg.kv_heads_of(kind)
             attn = {
                 "attn_norm_scale": jnp.ones(lead + (H,)),
@@ -372,6 +448,22 @@ class HybridMoETransformerLM(MoETransformerLM):
         attn = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
         return qmatmul(softmax_gate(p, h, attn.reshape(B, T, NH * Dv)), p["wo"])
 
+    def _latent_mixer(self, p, h):
+        """The published (expanded) form: every head's keys and values made from the latents."""
+        cfg = self.config
+        B, T, _ = h.shape
+        NH, C = cfg.num_heads, cfg.kv_lora_rank
+        pos = jnp.arange(T, dtype=jnp.int32)
+        q_nope, q_rope, latent = latent_project(cfg, p, h, jnp.broadcast_to(pos, (B, T)))
+        c_kv, k_rope = latent[..., :C], latent[..., C:]
+        k_nope = qmatmul(c_kv, p["wk_b"]).reshape(B, T, NH, cfg.qk_nope_head_dim)
+        v = qmatmul(c_kv, p["wv_b"]).reshape(B, T, NH, cfg.v_head_dim)
+        scale = cfg.attn_softmax_scale if cfg.attn_softmax_scale is not None else cfg.head_dim ** -0.5
+        scores = (jnp.einsum("bthd,bshd->bhts", q_nope, k_nope) + jnp.einsum("bthd,bsd->bhts", q_rope, k_rope)).astype(jnp.float32)
+        scores = jnp.where(pos[:, None] >= pos[None, :], scores * scale, -1e30)
+        attn = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v)
+        return qmatmul(attn.reshape(B, T, NH * cfg.v_head_dim), p["wo"])
+
     def _linear_mixer(self, p, h):
         from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked
 
@@ -396,7 +488,12 @@ class HybridMoETransformerLM(MoETransformerLM):
         def mix(x, kind, mixer):
             h = _norm(x, mixer["attn_norm_scale"], None, "rmsnorm", cfg.norm_eps)
             with jax.named_scope(SCOPES[kind]):
-                out = self._linear_mixer(mixer, h) if kind == "linear" else self._attention_mixer(kind, mixer, h)
+                if kind == "linear":
+                    out = self._linear_mixer(mixer, h)
+                elif kind == "latent":
+                    out = self._latent_mixer(mixer, h)
+                else:
+                    out = self._attention_mixer(kind, mixer, h)
             return x + out.astype(x.dtype)
 
         for kind, p in zip(cfg.layer_types, params.get("leading", ())):
@@ -486,4 +583,35 @@ def mimo_v2_config(size: str = "v2.5", **overrides) -> HybridMoEConfig:
     if "layer_types" not in base:
         # hybrid_layer_pattern: full at 0, 5 and then every sixth
         base["layer_types"] = ["softmax" if i == 0 or i % 6 == 5 else "window" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)
+
+
+def glm4_moe_lite_config(size: str = "4.7-flash", **overrides) -> HybridMoEConfig:
+    """GLM-4.7-Flash (``zai-org/GLM-4.7-Flash`` ``config.json``, ``model_type:
+    glm4_moe_lite``): 47 layers of latent attention, 20 heads with a query and
+    key of 192 unrotated + 64 rotated features (theta 1e6) and a value of 256
+    over a latent of 512 (queries through a low rank of 768); layer 0 a dense
+    SwiGLU FFN of 10,240, layers 1-46 64 SwiGLU experts of 1,536, 4 a token by
+    sigmoid scores with a selection bias, gates normalised and times 1.8, one
+    shared expert. The multi-token-prediction layer is not part of this model.
+    ``4.7-flash`` is the published model whole; ``tiny`` a toy of one chip's
+    share (4 of 16 experts held) with one leading dense layer for tests."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=4, num_heads=4, head_dim=24, v_head_dim=16, q_lora_rank=48,
+                     kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, vocab_size=512, max_seq_len=256,
+                     intermediate_size=96, expert_intermediate_size=32, num_experts=4, moe_router_experts=16,
+                     moe_expert_share=(0, 4), moe_top_k=3),
+        "4.7-flash": dict(hidden_size=2048, num_layers=47, num_heads=20, head_dim=256, v_head_dim=256, q_lora_rank=768,
+                          kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, vocab_size=154880,
+                          max_seq_len=202752, intermediate_size=10240, expert_intermediate_size=1536, num_experts=64,
+                          moe_top_k=4),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="rope", rope_theta=1e6, activation="swiglu", use_bias=False,
+        tie_embeddings=False, leading_dense_layers=1, moe_layer_freq=1, moe_drop_tokens=False, moe_norm_topk_prob=True,
+        moe_scoring="sigmoid", moe_select_bias=True, moe_shared_experts=1, moe_routed_scaling=1.8,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    base.setdefault("layer_types", ["latent"] * base["num_layers"])
     return HybridMoEConfig(**base)

@@ -1,0 +1,158 @@
+"""The ragged serving programs of the benchmark's configurations, lowered for
+a described v5e (nothing compiles, nothing runs), and each attention kernel's
+custom call held to what the benchmark's readers expect of it.
+
+``benchmark/kernels/ragged_paged_attention.py::EVENTS`` tells the ragged
+kernel by its signature, three ``s32`` scalar-prefetch operands (page table,
+kv lengths, q lengths) in front of the row operand and the two page pools,
+and ``trace_reduce.checked_kernel_events`` raises unless every whole execution
+holds exactly ``num_layers`` such calls: the ``serve.`` / ``chat.`` / ``moe.``
+``ragged_attn_*`` readers run that check in every traced run of the Mistral
+and OLMoE cells. ``windowed_paged_attention.py`` finds the same kernel by its
+``name=`` inside a scope. A PR that gives that kernel another operand, or adds
+a second custom call of the same signature to a model that had none, makes
+those cells' traced runs exit 1 (PR 38). So, for every configuration the
+benchmark holds:
+
+* every custom call that opens with three ``s32`` operands is named
+  ``ragged_paged_attention`` and has the operand list the kernel had (the row
+  operand, the sinks of a model that has them, two pools of one type), or is
+  the latent kernel in a model with latent layers, and in no other;
+* no other custom call opens with more than one ``s32`` operand;
+* the calls a step: one a layer through the layer scan of a uniform model
+  (``calls_per_step``), one a layer kind a period and leading layer in a
+  model of several kinds, twice that in its wide program.
+"""
+
+import importlib
+import json
+import pathlib
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from benchmark import files
+from benchmark.kernels import ragged_paged_attention, windowed_paged_attention
+from deepspeed_tpu.inference import decode, hybrid_decode
+from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
+
+SPEC = json.loads((pathlib.Path(files.ROOT) / "BENCHMARK.json").read_text())
+CONFIGS = [c["name"] for c in SPEC["configs"]]
+ACCEPTED = ["gpt2-125m", "gpt2-xl", "mistral-7b-v0.3-l16", "olmoe-1b-7b-0125-l12", "solar-open2-250b-l4-ep8", "mimo-v2.5-l7-ep16"]
+BF16, I32 = jnp.bfloat16, jnp.int32
+# a training configuration has no serving geometry of its own: the Mistral cells'
+DEFAULT_PAGED = {"page_size": 64, "max_slots": 16, "prefill_chunk": 128, "max_seq_len": 1024}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _lowered_step(v5e, monkeypatch, name, width):
+    """(config, the StableHLO text of its ragged step at its own sizes)."""
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.latent_attention",
+                   "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.ops.transformer.linear_attention"):
+        importlib.import_module(module)
+        if hasattr(sys.modules[module], "on_tpu"):  # NOT via attribute access: ops/transformer rebinds names
+            monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = files.load_json(files.ROOT, next(c["file"] for c in SPEC["configs"] if c["name"] == name))
+    model, _ = files.build_model(conf)
+    cfg = model.config
+    paged = conf["engine"].get("init_inference", {}).get("paged_kv", DEFAULT_PAGED)
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = min(paged["max_seq_len"], cfg.max_seq_len) // page
+    pages = rows * maxp + 1
+
+    def on(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32)))
+    params = jax.tree_util.tree_map(lambda a: on(a.shape), params)
+    extra = ()
+    layers = cfg.num_layers
+    if getattr(cfg, "layer_types", None):
+        layers = cfg.layers_of("softmax")
+        shapes = hybrid_decode.state_shapes(cfg, rows)
+        rings = (None, None)
+        if cfg.layers_of("window"):
+            ring = window_ring_pages(cfg.window, page, paged["prefill_chunk"])
+            rings = tuple(on(s) for s in hybrid_decode.window_shapes(cfg, rows, page, ring))
+        latent = on((cfg.layers_of("latent"), pages, page, key_lanes(cfg.latent_width))) if cfg.layers_of("latent") else None
+        extra = (StateStore(on(shapes.state, jnp.float32), on(shapes.conv), *rings, latent),)
+    k_pool = on((layers, pages, cfg.num_kv_heads, page, key_lanes(cfg.head_dim)))
+    v_pool = on(k_pool.shape[:-1] + (getattr(cfg, "v_head_dim", None) or cfg.head_dim,))
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    rows_i32 = (on((rows,), I32),) * (3 if extra else 2)
+    text = step.lower(params, on((rows, width), I32), k_pool, v_pool, *extra, on((rows, maxp), I32), *rows_i32).as_text()
+    decode._paged_program_cache.clear()  # programs lowered for a described chip are nobody else's
+    return cfg, text
+
+
+_CALL = re.compile(r'stablehlo\.custom_call @tpu_custom_call\(([^)]*)\).*?kernel_name = "([\w.-]+)".*?: \(([^)]*)\) ->')
+
+
+def _kernel_calls(text):
+    """(kernel name, operand element types) of every Mosaic custom call in the program text."""
+    calls = []
+    for line in text.splitlines():
+        if "@tpu_custom_call" not in line:
+            continue
+        m = _CALL.search(line)
+        assert m, line[:300]
+        types = [t.strip().rsplit("x", 1)[-1].rstrip(">") for t in re.findall(r"tensor<[^>]*>", m.group(3))]
+        calls.append((m.group(2), types))
+    return calls
+
+
+def _expected_ragged_calls(cfg, width):
+    if not getattr(cfg, "layer_types", None):
+        return 1  # the layer scan's body: num_layers a step (ragged_paged_attention.calls_per_step)
+    kinds = list(cfg.layer_types[: cfg.leading_dense_layers]) + list(cfg.period)
+    return sum(k in ("softmax", "window") for k in kinds) * (1 if width == 1 else 2)
+
+
+@pytest.mark.parametrize("width", [1, 128])
+@pytest.mark.parametrize("name", CONFIGS)
+def test_attention_kernel_calls_are_what_the_benchmarks_readers_expect(v5e, monkeypatch, name, width):
+    cfg, text = _lowered_step(v5e, monkeypatch, name, width)
+    calls = _kernel_calls(text)
+    assert calls, "no Mosaic kernel in the program"
+    latent_layers = getattr(cfg, "layer_types", None) and cfg.layers_of("latent")
+    ragged = [types for kernel, types in calls if kernel == windowed_paged_attention.KERNEL]
+    for kernel, types in calls:
+        leading = next(i for i, t in enumerate(types + ["-"]) if t != "i32")
+        if kernel == windowed_paged_attention.KERNEL:
+            # what EVENTS matches: three s32 in front; then the row operand, a model's sinks, the two pools
+            sinks = ["f32"] if getattr(cfg, "window_sinks", False) and len(types) == 7 else []
+            assert types == ["i32"] * 3 + ["bf16"] + sinks + ["bf16", "bf16"], (kernel, types)
+        elif kernel == "latent_paged_attention":
+            assert latent_layers and name not in ACCEPTED, f"{name} has no latent layer and must not call {kernel}"
+            assert types == ["i32"] * 3 + ["bf16", "bf16"], types  # the row operand and ONE pool
+        else:
+            assert leading <= 1, f"{kernel} opens with {leading} s32 operands: the ragged kernel's readers would count it"
+    assert len(ragged) == _expected_ragged_calls(cfg, width), (len(ragged), [k for k, _ in calls])
+    if not getattr(cfg, "layer_types", None):
+        assert ragged_paged_attention.calls_per_step(cfg.num_layers) == {"ragged": cfg.num_layers * len(ragged)}
+    if latent_layers:
+        kinds = list(cfg.layer_types[: cfg.leading_dense_layers]) + list(cfg.period)
+        assert sum(k == "latent_paged_attention" for k, _ in calls) == kinds.count("latent") * (1 if width == 1 else 2)
+
+
+def test_the_guard_knows_every_configuration():
+    """A configuration a later PR adds is lowered and held above too; the six
+    accepted before the latent kind are named, and none of them has one."""
+    assert set(ACCEPTED) <= set(CONFIGS)
+    for name in ACCEPTED:
+        conf = files.load_json(files.ROOT, next(c["file"] for c in SPEC["configs"] if c["name"] == name))
+        assert "latent" not in (conf["model"]["kwargs"].get("layer_types") or ())

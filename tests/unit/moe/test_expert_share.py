@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, mimo_v2_config, moe_ffn, solar_open2_config
+from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, glm4_moe_lite_config, mimo_v2_config, moe_ffn, solar_open2_config
 from deepspeed_tpu.moe.routed_ffn import route, routed_ffn
 
 S, H, I, E, K = 48, 32, 24, 16, 4
@@ -165,8 +165,54 @@ def test_sixteen_shares_without_a_shared_expert_are_the_uncut_model_layer():
     assert float(jnp.abs(out.reshape(h.shape) - share3).max()) < TOL
 
 
+def test_eight_shares_of_a_scaled_router_plus_the_shared_expert_once_are_the_uncut_model_layer():
+    """The latent model's FFN: top-4 of 64 by sigmoid scores with a selection
+    bias, weights normalised over the four and times 1.8, one shared expert.
+    Eight configs that differ in ``moe_expert_share`` alone, each given its 8
+    of the uncut layer's 64 experts: the routed parts (each share's output
+    minus the shared expert's) plus the shared expert counted ONCE are the
+    uncut layer, which is 1.8 times the brute-force weighted sum plus the
+    shared expert; and the plain reference's router and loop over a share's
+    held experts give that share's part, the factor inside the weights."""
+    from benchmark.files import load_module
+    from deepspeed_tpu.moe.experts import apply_dense_ffn
+
+    whole_cfg = glm4_moe_lite_config("tiny", num_experts=64, moe_router_experts=64, moe_expert_share=(0, 1), moe_top_k=4, dtype="float32")
+    assert (whole_cfg.moe_routed_scaling, whole_cfg.moe_shared_experts, whole_cfg.moe_scoring) == (1.8, 1, "sigmoid")
+    lm = HybridMoETransformerLM(whole_cfg)
+    p = jax.tree_util.tree_map(lambda a: a[1, 0], lm.init(jax.random.PRNGKey(3), None)["periods"]["moe"])
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, whole_cfg.hidden_size))
+    whole, counts = moe_ffn(whole_cfg, p, h)
+    assert int(counts.sum()) == 2 * 24 * 4
+    shared = apply_dense_ffn(p["shared"], h, "swiglu")
+    tokens = h.reshape(-1, whole_cfg.hidden_size)
+    weights = 1.8 * _weights(tokens @ p["gate"]["wg"], p["gate"]["bias"])
+    assert float(jnp.abs(_brute(p["experts"], tokens, weights).reshape(h.shape) + shared - whole).max()) < TOL
+    # without the factor the layer is another one, by far more than the tolerance
+    assert float(jnp.abs(_brute(p["experts"], tokens, weights / 1.8).reshape(h.shape) + shared - whole).max()) > 100 * TOL
+    total = shared
+    for index in range(8):
+        cfg = dataclasses.replace(whole_cfg, num_experts=8, moe_expert_share=(index, 8))
+        mine = {**p, "experts": jax.tree_util.tree_map(lambda a: a[index * 8 : index * 8 + 8], p["experts"])}
+        out, held = moe_ffn(cfg, mine, h)
+        assert held.shape == (8,) and np.array_equal(held, counts[index * 8 : index * 8 + 8])
+        part = _brute(mine["experts"], tokens, weights[:, index * 8 : index * 8 + 8]).reshape(h.shape)
+        assert float(jnp.abs(out - shared - part).max()) < TOL
+        total = total + (out - shared)
+    assert float(jnp.abs(total - whole).max()) < TOL
+    # the reference's routed FFN for share 5: its router over the whole width, its loop over the 8 held
+    ref = load_module("reference", "glm4_moe_lite_decoder")
+    gate = {"mlp_norm_scale": jnp.ones((whole_cfg.hidden_size,)), "gate": p["gate"], "shared": p["shared"]}
+    hn, w, out = ref._router(tokens, gate, arch_key=(("experts_per_token", 4), ("norm_eps", 1e-5), ("routed_scaling", 1.8)))
+    mine = jax.tree_util.tree_map(lambda a: a[40:48], p["experts"])
+    for e in range(8):
+        out = ref._add_expert(out, hn, w[..., 40 + e], mine["w_gate"][e], mine["w_up"][e], mine["w_out"][e])
+    share5, _ = moe_ffn(dataclasses.replace(whole_cfg, num_experts=8, moe_expert_share=(5, 8)), {**p, "experts": mine}, hn.reshape(h.shape))
+    assert float(jnp.abs(out.reshape(h.shape) - share5).max()) < TOL
+
+
 def test_a_share_that_is_not_a_share_is_refused():
     with pytest.raises(ValueError, match="holds"):
         solar_open2_config("tiny", num_experts=3, moe_router_experts=8, moe_expert_share=(0, 2))
     with pytest.raises(ValueError, match="layer_types"):
-        solar_open2_config("tiny", layer_types=["softmax", "latent", "linear", "linear"])
+        solar_open2_config("tiny", layer_types=["softmax", "mamba", "linear", "linear"])  # a kind that does not exist

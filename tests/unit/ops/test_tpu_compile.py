@@ -87,6 +87,12 @@ def _ragged(q, k_new, v_new, k_pages, v_pages, table, kv_lens, q_lens):
     )
 
 
+def _latent(q, new, pages, table, kv_lens, q_lens):
+    from deepspeed_tpu.ops.transformer.latent_attention import latent_paged_attention
+
+    return latent_paged_attention(q, new, pages, 3, table, kv_lens, q_lens, value_lanes=512, scale=1 / 16, impl="pallas", interpret=False)
+
+
 def _dense_decode(q, k_cache, v_cache, kv_lens):
     return decode_attention(q, k_cache, v_cache, kv_lens, interpret=False)
 
@@ -143,6 +149,16 @@ CASES = {
         }.items()
         for width in (1, 128)
     },
+    **{
+        # GLM-4.7-Flash's latent kernel at the published shapes: 20 heads over one entry of 576 (512 of them the
+        # value) in pages of 64 x 640 lanes, 16 layers of 4,097 pages; 64 decode rows, and the wide window's 4 chunk rows
+        f"latent_w{width}_glm47": (
+            _latent,
+            [((rows, width, 20, 576), BF16), ((rows, width, 576), BF16), ((16, 4097, 64, 640), BF16), ((rows, 64), I32)]
+            + [((rows,), I32)] * 2,
+        )
+        for rows, width in ((64, 1), (4, 128))
+    },
     "dense_decode_llama_1b": (
         _dense_decode,
         [((8, 32, 64), BF16), ((8, 2048, 4, 64), BF16), ((8, 2048, 4, 64), BF16), _LENS],
@@ -164,9 +180,17 @@ CASES = {
 def test_kernel_compiles_for_v5e(v5e, name):
     fn, shapes = CASES[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jax.jit(fn, donate_argnums=(2,) if fn is _latent else ()).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "the Pallas kernel is not in the program"
+    if fn is _latent:
+        # donated, the pool goes in and comes out in one buffer (a bitcast to the stack of pages and back): no other
+        # instruction makes an array of its size, and the one custom call reads the page ONCE (one pool operand)
+        assert 2 in parse_input_output_aliases(text)
+        made = [line for line in text.splitlines() if re.search(r"= bf16\[(16,4097|65552),64,640\]", line)]
+        assert made and all(re.search(r" (parameter|bitcast|get-tuple-element)\(", line) for line in made), made
+        (call,) = re.findall(r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text)
+        assert len(call.split(",")) == 5, call  # table, lengths, q lengths, the row operand, the pool
     if fn is _grouped:
         # ONE s32 operand in front (the packed visits): the benchmark's readers tell the ragged attention
         # kernel by its three, and jax.lax.ragged_dot's own lowering opens with five
@@ -474,3 +498,63 @@ def test_mimo_v2_ragged_step_fits_and_every_attention_layer_walks_live_pages(v5e
     # the 64 x 128 window is never laid out: no operand or result of its size (64 x 128 x 64 heads x 256 lanes)
     assert not re.search(r"bf16\[64,128,(12288|16384)\]|bf16\[64,[48],128,(8|16),256\]", text)
     assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
+
+
+_GLM_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/glm-4.7-flash-l16-ep8.json"
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_glm47_flash_ragged_step_fits_and_keeps_one_latent_pool_in_place(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the GLM-4.7-Flash cell's shapes (a leading
+    dense layer and 15 routed ones, every one a latent layer of 20 heads over
+    an entry of 576 stored at 640 lanes, 8 held experts of 1,536 of a router
+    over 64 and a shared one, 64 rows, 4,097 pages of 64): it compiles for a
+    v5e, the ONE latent pool stays aliased in to out, weights + pool +
+    temporaries fit the chip, the latent kernel is called once a layer of the
+    program text (twice in the wide program: the one-token rows, the chunk
+    rows) and the accepted ragged kernel not at all, and the 64 x 128 window
+    is never laid out."""
+    from deepspeed_tpu.inference import hybrid_decode
+    from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes
+    from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
+
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.latent_attention",
+                   "deepspeed_tpu.moe.grouped_matmul"):
+        __import__(module)
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = json.loads(_GLM_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = HybridMoEConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    assert key_lanes(cfg.latent_width) == 640 and cfg.layers_of("latent") == 16
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
+    no_kv = on_v5e((0, rows * maxp + 1, 20, page, 256), BF16)  # no softmax layer: no K, no V
+    shapes = hybrid_decode.state_shapes(cfg, rows)
+    latent = on_v5e((16, rows * maxp + 1, page, 640), BF16)
+    store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv, BF16), None, None, latent)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), no_kv, no_kv, store,
+        on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+    first_pool = len(jax.tree_util.tree_leaves(params)) + 1
+    assert first_pool + 4 in parse_input_output_aliases(text)  # k, v, state, conv (all empty), then the latent pool
+    memory = compiled.memory_analysis()
+    print(f"glm w{width}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB")
+    assert 8.8e9 < memory.argument_size_in_bytes < 9.0e9  # 3.53 GB of weights, 5.37 GB of latent pages
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*? custom-call\(", text, flags=re.M)
+    latent_calls = [name for name in kernels if name.startswith("latent_paged_attention")]
+    assert len(latent_calls) == (2 if width == 1 else 4), kernels  # the leading layer's and the scan body's
+    assert not any(name.startswith("ragged_paged_attention") for name in kernels), kernels
+    assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
+    # no copy of the pool, and the 64 x 128 window of 20 heads x 640 lanes is never laid out
+    assert not re.search(r"= bf16\[16,4097,64,640\]\S* (copy|fusion)\(", text)
+    assert not re.search(r"bf16\[64,128,(12800|20,640)\]|bf16\[64,2560,640\]", text)

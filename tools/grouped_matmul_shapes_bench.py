@@ -1,4 +1,4 @@
-"""The grouped expert matmul alone, on the chip, at the three MoE serving
+"""The grouped expert matmul alone, on the chip, at the four MoE serving
 cells' shapes (``deepspeed_tpu/moe/grouped_matmul.py``; the benchmark's own
 ``benchmark/tools/grouped_matmul_bench.py`` holds OLMoE's shapes only): a
 layer's gate, up and down calls over the cell's expert stack, for a narrow
@@ -13,7 +13,7 @@ share of the least time the chip could take
 (``benchmark/kernels/grouped_expert_matmul.py::min_seconds``). It is how the
 kernel's blocks were chosen (PERF.md, PR 37).
 
-    chiprun -- python3 tools/grouped_matmul_shapes_bench.py [--shape solar,mimo,olmoe]
+    chiprun -- python3 tools/grouped_matmul_shapes_bench.py [--shape solar,mimo,olmoe,glm]
     python3 tools/grouped_matmul_shapes_bench.py --rehearse   # tiny, on the CPU: the control flow only
 """
 
@@ -54,6 +54,10 @@ SHAPES = {
     "mimo": Shape(4096, 2048, 16, 6, (512, 33, 14), (4096, 99, None)),
     # every expert held: 16 rows x 8, 87.9% of 64 hit; a 1,024-token tile with ~143 live tokens
     "olmoe": Shape(2048, 1024, 64, 4, (128, 128, 56), (8192, 1144, None)),
+    # GLM-4.7-Flash (PR 41): K 2,048 in one block, N 1,536 (gate and up are two calls; fused they would be 3,072);
+    # 64 rows x top-4 = 256 assignments, 12.5% of them held, on all 8 held experts (98% by the binomial);
+    # a 512-token tile's 2,048 assignment rows with ~190 live tokens
+    "glm": Shape(2048, 1536, 8, 15, (256, 32, 8), (2048, 95, None)),
 }
 TINY = {"tiny": Shape(256, 128, 5, 2, (16, 6, 3), (256, 40, None))}
 
@@ -132,7 +136,7 @@ def bench(shape: Shape, matmul, rows: int, sizes, calls: int, repeats: int):
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--shape", default="solar,mimo,olmoe")
+    ap.add_argument("--shape", default="solar,mimo,olmoe,glm")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     if args.rehearse:
