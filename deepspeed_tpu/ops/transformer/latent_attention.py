@@ -10,15 +10,35 @@ entries are merged into the pages that receive them and written back once.
 
 ``latent_paged_attention`` is the entry: the Pallas kernel on a TPU, XLA's
 scatter + gather elsewhere, as ``paged_attention.ragged_paged_attention`` is
-for keys and values a head. The kernel shares that kernel's walk
+for keys and values a head. The kernel walks as that one does
 (``decode_attention._ragged_kernel``: a grid step a row, the row's live pages
-fetched by the kernel's own DMAs into one half of a double buffer while the
-other half is attended, a row's first pages fetched by the step before its
-own, rolled loops over halves, query tiles and key tiles) and its tile rule
-(``_ragged_tiles``, with one kv head of ``Hg = NH`` grouped queries), in a
-body of its own: the accepted kernel's file, and so the programs of every
-model without a latent layer, are untouched by it. The ``pallas_call`` is
-named ``latent_paged_attention``: a profiler trace finds the kernel by it.
+fetched a half at a time by the kernel's own DMAs while an earlier half is
+attended, a row's first pages fetched before its own step, a rolled loop over
+halves) in a body of its own: the accepted kernel's file, and so the programs
+of every model without a latent layer, are untouched by it. The halves lie in
+a ring, and the fetches run ahead of the walk through it, over the ends of
+rows. What a half's trip hands the MXU is chosen by the shape, when the kernel
+is built (``_latent_tiles``):
+
+* wide (``W * Hg >= 128``: prefill chunks; query tiles of 128 rows fill the
+  MXU): that kernel's rolled loops over query tiles and key tiles under its
+  tile rule (``_ragged_tiles``, one kv head of ``Hg = NH`` grouped queries),
+  a ring of two halves;
+* narrow (``W * Hg < 128``: decode rows, 20 query rows a key): the work is
+  taking the ENTRIES in, the DMA's and then the MXU's, and every key tile
+  costs a chain of latencies (scores, maximum, exponentials, ``p . v``) that
+  20 rows do not fill, whatever the tile's size. So a trip is ONE key tile,
+  the smallest of ``_NARROW_SIZES`` static sizes that holds the half's live
+  pages, straight-line code, the running statistics through scratch once a
+  half; and the halves are long and three in the ring, so that the DMA queue
+  never runs dry while a tile is attended.
+
+The form is a property of the program, not of the step: every latent layer of
+a serving step ``paged_ragged_r<rows>_w1`` (decode) runs the narrow form, of
+``_w128`` (a prefill chunk of 128 x 20 query rows) the wide one, and a verify
+window of up to 6 tokens x 20 heads the narrow one again. The ``pallas_call``
+is named ``latent_paged_attention`` in both: a profiler trace finds the
+kernel by it.
 """
 
 from __future__ import annotations
@@ -33,76 +53,120 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.accelerator import on_tpu
-from deepspeed_tpu.ops.transformer.decode_attention import NEG_INF, _pages_in_stack, _ragged_tiles
+from deepspeed_tpu.ops.transformer.decode_attention import NEG_INF, _TILE_ROWS, _pages_in_stack, _ragged_tiles
+
+# The narrow form: the keys a half holds, how many sizes of key tile it is attended in, and the halves in the ring.
+# By ``tools/ragged_kernel_bench.py --models glm47 --set ...`` on a v5e (PR 43; ``decode64_long``: 64 decode rows of
+# 1,661 tokens in the mean, 153.0 us by their bytes; us a call): as here 210.6-211.7, where the walk's DMAs alone take
+# ~210 and the parent's double buffer of 384 keys took 328.7. Halves of 384 / 768 / 1,024 / 2,048 keys: 296.8 / 235.2 /
+# 217.5 / 211.6 (a key tile costs ~0.27 us of chained latencies whatever its size, so few and long ones). One / three
+# sizes: 220.1 / 209.3 (a third size is one more traced body a call site: +6% on the cell's ``setup_s`` in PR 42's
+# runs). A ring of two / four: 241.1 (the DMA queue runs dry every trip) / 211.8.
+_NARROW_HALF_KEYS = 1536
+_NARROW_SIZES = 2
+_NARROW_RING = 3
+
+
+def _latent_tiles(Hg, W, P, D, maxp, itemsize, pages_per_buffer=None):
+    """``(C, CK, TQ, N)``: pages a half holds, pages a key tile spans, query
+    rows a score tile holds, halves in the ring. Wide (``W * Hg`` a whole
+    query tile at least): ``_ragged_tiles``' values for one kv head of ``Hg``
+    grouped queries, and two halves. Narrow: one query tile, long halves in
+    a ring of ``_NARROW_RING``, and ``CK`` what the sizes of a trip's ONE key
+    tile step by (``CK``, ``2 CK`` ... ``C`` pages)."""
+    C, CK, TQ, _ = _ragged_tiles(1, Hg, W, P, D, maxp, itemsize, pages_per_buffer)
+    if W * Hg >= _TILE_ROWS:
+        return C, CK, TQ, 2
+    if pages_per_buffer is None:
+        pages_per_buffer = max(1, min(_NARROW_HALF_KEYS // P, maxp))
+    CK = -(-pages_per_buffer // _NARROW_SIZES)
+    return pages_per_buffer // CK * CK, CK, TQ, _NARROW_RING
 
 
 def _latent_kernel(pt_ref, len_ref, qlen_ref, x_ref, _pool_in, o_ref, pool, buf, m_s, l_s, acc_s,
-                   fetch_sem, write_sem, slot_s, *, scale, P, C, CK, TQ, Hg, W):
-    """Grid step ``g`` attends row ``g - 1`` and starts the fetch of row
-    ``g``'s first pages (step 0 only fetches). ``x_ref`` ``[W * (Hg + 1),
-    lanes]``: the row's queries W-major (slot w of head h at row ``w * Hg +
-    h``), then its ``W`` new entries. ``buf`` ``[2, C * P, lanes]`` holds a
+                   fetch_sem, write_sem, ring_s, *, scale, P, C, CK, TQ, Hg, W):
+    """Grid step ``g`` attends row ``g - 1`` (step 0 only starts the ring's
+    first fetches). ``x_ref`` ``[W * (Hg + 1), lanes]``: the row's queries
+    W-major (slot w of head h at row ``w * Hg + h``), then its ``W`` new
+    entries. ``buf`` ``[N, C * P, lanes]`` is the ring of halves, each a
     half's pages: scores are taken against all its lanes, values are its
-    leading ``o_ref.shape[-1]``. ``pool`` is the whole pool as ``layers * NP``
-    pages, read and written in place (``_pool_in`` is the same memory).
-    Scalar arithmetic in ``lax`` primitives, as in ``_ragged_kernel``."""
+    leading ``o_ref.shape[-1]``. ``ring_s``: the place of the next half to
+    attend, and the row, table slot and place of the next to fetch. ``pool``
+    is the whole pool as ``layers * NP`` pages, read and written in place
+    (``_pool_in`` is the same memory). ``W * Hg < _TILE_ROWS`` is the narrow
+    form. Scalar arithmetic in ``lax`` primitives, as in ``_ragged_kernel``."""
     add, sub, mul, div, lt, gt = lax.add, lax.sub, lax.mul, lax.div, lax.lt, lax.gt
     g = pl.program_id(0)
     R = pl.num_programs(0) - 1
     rows, Dv = o_ref.shape
+    N = buf.shape[0]  # halves in the ring
     TK = CK * P
 
-    def pages_of(row, there):  # where the walk of a row ends: nowhere for a dead one
-        walked = lax.bitwise_and(there, gt(qlen_ref[row], 0))
-        return lax.select(walked, div(add(len_ref[row], P - 1), P), 0)
+    def pages_of(row):  # where the walk of a row ends: nowhere for a dead one
+        return lax.select(gt(qlen_ref[row], 0), div(add(len_ref[row], P - 1), P), 0)
 
-    r, nxt = lax.max(sub(g, 1), 0), lax.min(g, R - 1)
+    r = lax.max(sub(g, 1), 0)
     kv_len = len_ref[r]
     start = sub(kv_len, qlen_ref[r])  # the row's write base
-    n_pages = pages_of(r, gt(g, 0))
+    n_pages = lax.select(gt(g, 0), pages_of(r), 0)
     n_buf = div(add(n_pages, C - 1), C)
-    next_pages = pages_of(nxt, lt(g, R))
     n_live = div(add(mul(qlen_ref[r], Hg), TQ - 1), TQ)  # query tiles that hold a real token
 
     def page_rows(c):
         return pl.ds(pl.multiple_of(mul(c, P), P), P)
 
-    def fetch(row, first, slot, count, wait=False):
-        """The copies of ``count`` pages, from table slot ``first`` of ``row``
-        on, into half ``slot``: started, or waited for."""
+    def fetch(row, first, slot, wait=False):
+        """The copies of a half's pages, from table slot ``first`` of ``row``
+        on, into place ``slot`` of the ring: started, or waited for."""
 
         def page(c, _):
             copy = pltpu.make_async_copy(pool.at[pt_ref[row, add(first, c)]], buf.at[slot, page_rows(c), :], fetch_sem.at[slot])
             copy.wait() if wait else copy.start()
             return _
 
-        lax.fori_loop(0, count, page, None)
+        lax.fori_loop(0, lax.min(sub(pages_of(row), first), C), page, None)
+
+    def after(slot):
+        return lax.select(lax.eq(slot, N - 1), 0, add(slot, 1))
+
+    def fetch_next():
+        """Start the fetch of the first half in walk order (the rows in order,
+        a row's halves in order, none for a dead row) that none was started
+        for, into the ring's next place; past the last row, nothing."""
+        row, first = lax.while_loop(
+            lambda at: lax.bitwise_and(lt(at[0], R), lax.ge(at[1], pages_of(lax.min(at[0], R - 1)))),
+            lambda at: (add(at[0], 1), 0),
+            (ring_s[1], ring_s[2]),
+        )
+
+        @pl.when(lt(row, R))
+        def _start():
+            fetch(row, first, ring_s[3])
+            ring_s[3] = after(ring_s[3])
+
+        ring_s[1], ring_s[2] = row, add(first, C)
 
     @pl.when(g == 0)
     def _first_step():
         # what a half holds past a row's live pages is masked, and so must be finite
         buf[...] = jnp.zeros_like(buf)
-        slot_s[0] = 0
+        for i in range(4):
+            ring_s[i] = 0
+        # step 0 attends nothing: the ring's first halves are on their way while row 0's queries come
+        lax.fori_loop(0, N - 1, lambda _, none: fetch_next(), None)
 
     @pl.when(lt(mul(n_live, TQ), rows))
     def _dead_slots():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    slot0 = slot_s[0]
-    n_halves = lax.max(n_buf, 1)  # a step that attends nothing still fetches for the next
-
-    def half(b, _):
-        slot = lax.bitwise_and(add(slot0, b), 1)
+    def half(b, slot):
         first = mul(b, C)  # the half's first table slot
         base = mul(first, P)  # and its first key's position
-        # the next half's pages, the row's own or else the next row's first, are
-        # asked for before this half's are waited for: two halves in flight
-        own = lt(add(b, 1), n_buf)
-        fetch(
-            lax.select(own, r, nxt), lax.select(own, add(first, C), 0), sub(1, slot),
-            lax.min(lax.select(own, sub(n_pages, add(first, C)), next_pages), C),
-        )
-        fetch(r, first, slot, lax.min(sub(n_pages, first), C), wait=True)
+        # the place the half before this one was attended from is free: ``N - 1``
+        # halves, the row's own and then the next rows', are on their way while
+        # this one is attended
+        fetch_next()
+        fetch(r, first, slot, wait=True)
 
         # pages of this half that receive the row's new positions ``start ..
         # kv_len - 1``: merged here with the window's entries (one-hot, exact),
@@ -133,74 +197,96 @@ def _latent_kernel(pt_ref, len_ref, qlen_ref, x_ref, _pool_in, o_ref, pool, buf,
 
         lax.fori_loop(c_lo, c_hi, merge, None)
 
-        def query_tile(t, _):
-            row0 = mul(t, TQ)
-            tile = pl.ds(0 if TQ == rows else pl.multiple_of(row0, TQ), TQ)
-            q = x_ref[tile, :]  # [TQ, lanes]
-            q_pos = add(div(add(lax.broadcasted_iota(jnp.int32, (TQ, TK), 0), row0), Hg), start)
-            # keys the tile's last query sees, counted from the half's first
-            seen = sub(lax.min(kv_len, add(add(div(add(row0, TQ - 1), Hg), 1), start)), base)
+        def key_tile(q, q_pos, key0, carry):
+            """The running ``(m, l, acc)`` of the query rows ``q`` (at
+            positions ``q_pos`` ``[TQ, TK]``) after the half's ``TK`` keys
+            from ``key0`` on."""
+            m, l, acc = carry
+            keys = pl.ds(key0, q_pos.shape[1])
+            s = lax.dot_general(q, buf[slot, keys, :], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)  # [TQ, TK]
+            kv_pos = add(lax.broadcasted_iota(jnp.int32, q_pos.shape, 1), add(base, key0))
+            live = lax.bitwise_and(lax.le(kv_pos, q_pos), lt(kv_pos, kv_len))
+            s = jnp.where(live, mul(s, scale), NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = corr * l + jnp.sum(p, axis=1, keepdims=True)
+            v = buf[slot, keys, :Dv]  # the entries' leading lanes: the same bytes, not fetched again
+            acc = acc * corr + lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            return m_new, l, acc
 
-            def key_tile(kt, carry):
-                m, l, acc = carry
-                key0 = mul(kt, TK)
-                keys = pl.ds(pl.multiple_of(key0, TK), TK)
-                s = lax.dot_general(q, buf[slot, keys, :], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)  # [TQ, TK]
-                kv_pos = add(lax.broadcasted_iota(jnp.int32, (TQ, TK), 1), add(base, key0))
-                live = lax.bitwise_and(lax.le(kv_pos, q_pos), lt(kv_pos, kv_len))
-                s = jnp.where(live, mul(s, scale), NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-                corr = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)
-                l = corr * l + jnp.sum(p, axis=1, keepdims=True)
-                v = buf[slot, keys, :Dv]  # the entries' leading lanes: the same bytes, not fetched again
-                acc = acc * corr + lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-                return m_new, l, acc
-
+        def attend(tile, walk):
+            """The half's keys as ``walk`` takes them, from the statistics the
+            halves before it left the query rows ``tile`` to the ones it leaves."""
             first_half = b == 0
-            m, l, acc = lax.fori_loop(
-                0, lax.clamp(0, div(add(seen, TK - 1), TK), C // CK), key_tile,
-                (
-                    jnp.where(first_half, NEG_INF, m_s[tile, :1]),
-                    jnp.where(first_half, 0.0, l_s[tile, :1]),
-                    jnp.where(first_half, 0.0, acc_s[tile, :]),
-                ),
-            )
-            m_s[tile, :] = jnp.broadcast_to(m, (TQ, 128))
-            l_s[tile, :] = jnp.broadcast_to(l, (TQ, 128))
+            m, l, acc = walk((
+                jnp.where(first_half, NEG_INF, m_s[tile, :1]),
+                jnp.where(first_half, 0.0, l_s[tile, :1]),
+                jnp.where(first_half, 0.0, acc_s[tile, :]),
+            ))
+            m_s[tile, :] = jnp.broadcast_to(m, (tile.size, 128))
+            l_s[tile, :] = jnp.broadcast_to(l, (tile.size, 128))
             acc_s[tile, :] = acc
 
             @pl.when(b == n_buf - 1)
             def _finish():
                 o_ref[tile, :] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
 
+        def q_positions(row0, keys):
+            return add(div(add(lax.broadcasted_iota(jnp.int32, (TQ, keys), 0), row0), Hg), start)
+
+        def query_tile(t, _):
+            row0 = mul(t, TQ)
+            tile = pl.ds(0 if TQ == rows else pl.multiple_of(row0, TQ), TQ)
+            q = x_ref[tile, :]  # [TQ, lanes]
+            q_pos = q_positions(row0, TK)
+            # keys the tile's last query sees, counted from the half's first
+            seen = sub(lax.min(kv_len, add(add(div(add(row0, TQ - 1), Hg), 1), start)), base)
+            attend(tile, lambda carry: lax.fori_loop(
+                0, lax.clamp(0, div(add(seen, TK - 1), TK), C // CK),
+                lambda kt, carry: key_tile(q, q_pos, pl.multiple_of(mul(kt, TK), TK), carry), carry,
+            ))
             return _
 
-        if TQ == rows:  # one tile, whatever its height: a static slice
-            pl.when(gt(n_pages, 0))(lambda: query_tile(0, None))
+        def whole_tile(pages):
+            """The narrow form's trip: the half's leading ``pages`` as ONE key
+            tile, whatever the causal mask leaves of it."""
+            tile = pl.ds(0, rows)
+            attend(tile, functools.partial(key_tile, x_ref[tile, :], q_positions(0, pages * P), 0))
+
+        if rows < _TILE_ROWS:
+            # narrow: the smallest of the key tiles' sizes (in pages) that holds the half's live pages
+            sizes = tuple(range(CK, C + 1, CK))
+            live = lax.min(sub(n_pages, first), C)
+            for below, pages in zip((0,) + sizes, sizes):
+                pl.when(lax.bitwise_and(gt(live, below), lax.le(live, pages)))(functools.partial(whole_tile, pages))
+        elif TQ == rows:  # one tile, whatever its height: a static slice
+            query_tile(0, None)
         else:
-            lax.fori_loop(0, lax.select(gt(n_pages, 0), n_live, 0), query_tile, None)
+            lax.fori_loop(0, n_live, query_tile, None)
         # the written pages are on their way since the merge: the half is the
         # next fetch's only once they have left
         lax.fori_loop(c_lo, c_hi, lambda c, _: write_back(c, wait=True), None)
-        return _
+        return after(slot)
 
-    lax.fori_loop(0, n_halves, half, None)
-    slot_s[0] = lax.bitwise_and(add(slot0, n_halves), 1)  # where the next step finds its first pages
+    ring_s[0] = lax.fori_loop(0, n_buf, half, ring_s[0])  # where the next row finds its first half
 
 
-def _latent_by_live_pages(x, pages, lens, qlens, pool, *, scale, Hg, W, Dv, out_dtype, interpret, pages_per_buffer=None):
-    """``_latent_kernel`` over ``R + 1`` steps, the pool left where it is."""
-    R, _, D = x.shape
-    P, maxp = pool.shape[1], pages.shape[1]
-    itemsize = jnp.dtype(pool.dtype).itemsize
-    C, CK, TQ, _ = _ragged_tiles(1, Hg, W, P, D, maxp, itemsize, pages_per_buffer)
+@functools.lru_cache(maxsize=64)
+def _latent_call(tiles, R, Hg, W, D, Dv, pool_shape, pool_dtype, x_dtype, out_dtype, scale, interpret):
+    """The ``pallas_call`` of ``_latent_kernel`` over ``R + 1`` steps, built
+    ONCE a shape: a serving process calls the entry at several sites of its
+    programs (the leading layer's and the scanned period's, in the narrow
+    and in the wide program), and the call's own ``jit`` traces the kernel's
+    body anew for every callable it is handed, though not for one it knows."""
+    C, CK, TQ, N = tiles
+    P = pool_shape[1]
     kernel = functools.partial(_latent_kernel, scale=scale, P=P, C=C, CK=CK, TQ=TQ, Hg=Hg, W=W)
     params = {}
     if not interpret:
         held = (
-            2 * C * P * D * itemsize  # the double buffer
-            + 2 * W * ((Hg + 1) * D + Hg * Dv) * x.dtype.itemsize  # x and o, twice
+            N * C * P * D * pool_dtype.itemsize  # the ring
+            + 2 * W * ((Hg + 1) * D + Hg * Dv) * x_dtype.itemsize  # x and o, twice
             + 4 * W * Hg * (2 * 128 + Dv)  # m, l, acc
         )
         params["compiler_params"] = pltpu.CompilerParams(
@@ -220,25 +306,33 @@ def _latent_by_live_pages(x, pages, lens, qlens, pool, *, scale, Hg, W, Dv, out_
         in_specs=[pl.BlockSpec((None, W * (Hg + 1), D), row_block), whole],
         out_specs=[pl.BlockSpec((None, W * Hg, Dv), row_block), whole],
         scratch_shapes=[
-            pltpu.VMEM((2, C * P, D), pool.dtype),
+            pltpu.VMEM((N, C * P, D), pool_dtype),
             pltpu.VMEM(stats, jnp.float32),
             pltpu.VMEM(stats, jnp.float32),
             pltpu.VMEM((W * Hg, Dv), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),  # fetches: a half
+            pltpu.SemaphoreType.DMA((N,)),  # fetches: a half
             pltpu.SemaphoreType.DMA((1,)),  # write-backs
-            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SMEM((4,), jnp.int32),  # the ring: the consumer's place; the producer's row, table slot and place
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, W * Hg, Dv), out_dtype), jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((R, W * Hg, Dv), out_dtype), jax.ShapeDtypeStruct(pool_shape, pool_dtype)],
         # operands count from the scalars: the pool is the 5th
         input_output_aliases={4: 1},
         interpret=interpret,
         name="latent_paged_attention",
         **params,
-    )(pages, lens, qlens, x, pool)
+    )
+
+
+def _latent_by_live_pages(x, pages, lens, qlens, pool, *, scale, Hg, W, Dv, out_dtype, interpret, pages_per_buffer=None):
+    """``_latent_kernel`` over ``R + 1`` steps, the pool left where it is."""
+    R, _, D = x.shape
+    tiles = _latent_tiles(Hg, W, pool.shape[1], D, pages.shape[1], pool.dtype.itemsize, pages_per_buffer)
+    call = _latent_call(tiles, R, Hg, W, D, Dv, pool.shape, pool.dtype, x.dtype, jnp.dtype(out_dtype), scale, interpret)
+    return call(pages, lens, qlens, x, pool)
 
 
 def latent_paged_attention(

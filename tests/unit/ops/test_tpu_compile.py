@@ -191,6 +191,13 @@ def test_kernel_compiles_for_v5e(v5e, name):
         assert made and all(re.search(r" (parameter|bitcast|get-tuple-element)\(", line) for line in made), made
         (call,) = re.findall(r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text)
         assert len(call.split(",")) == 5, call  # table, lengths, q lengths, the row operand, the pool
+        if name == "latent_w1_glm47":
+            # the narrow form: what the kernel may hold (its vmem_limit_bytes less the compiler's 24 MiB) is the ring
+            # of three halves of 24 pages, and the row's queries, output and statistics on top
+            (kernel,) = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+            (limit,) = re.findall(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', kernel)
+            ring = 3 * 24 * 64 * 640 * 2
+            assert ring < int(limit) - (24 << 20) < ring + (1 << 18), limit
     if fn is _grouped:
         # ONE s32 operand in front (the packed visits): the benchmark's readers tell the ragged attention
         # kernel by its three, and jax.lax.ragged_dot's own lowering opens with five

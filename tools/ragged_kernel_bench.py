@@ -14,19 +14,34 @@ whatever the compilation cache holds), and whether outputs and pools agree
 with XLA's scatter + gather. It is how the tile sizes at the head of the
 kernel were chosen.
 
+The model ``glm47`` is the latent kernel's (``ops/transformer/latent_attention.py::
+latent_paged_attention``: 20 heads over one entry of 576 a token in pages of
+640 lanes, GLM-4.7-Flash's 16 layers of 4,097 pages) under the mix
+
+* ``decode64_long``: 64 decode rows holding 600-4,096 tokens, ~1,650 in the
+  mean (what ``long_decode`` leaves in ``glm47_flash_long_decode``),
+
+and ``decode64_short`` (the same rows at a quarter of those contexts: the
+decode-heavy cells' lengths), against
+``benchmark/kernels/latent_paged_attention.py::min_seconds``. It is how the
+narrow form's half, key tiles and ring were chosen: each ``--set`` runs the
+mixes once more with other values of that file's constants, and ``--root``
+measures another checkout's kernel (the parent's) beside them.
+
     chiprun -- python3 tools/ragged_kernel_bench.py [--models mistral7b,olmoe] [--mixes decode16,chat4,mixed]
-    python3 tools/ragged_kernel_bench.py --rehearse      # tiny, on the CPU: the control flow only
+    chiprun -- python3 tools/ragged_kernel_bench.py --models glm47 [--root DIR] [--mixes decode64_long] [--set _NARROW_HALF_KEYS=768,_NARROW_RING=2 ...]
+    python3 tools/ragged_kernel_bench.py --rehearse [--models glm47]      # tiny, on the CPU: the control flow only
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
 
 CALLS = 100
 # (query heads, kv heads, head size, layers, rows, pages a row, page, wide window)
@@ -36,6 +51,9 @@ MODELS = {
     "mistral7b_tp4": (8, 2, 128, 16, 8, 38, 64, 128),
 }
 TINY = {"tiny": (4, 2, 128, 2, 4, 6, 8, 8)}
+# (query heads, value lanes, rotated lanes, lanes a page stores, layers, rows, pages a row, page)
+LATENT = {"glm47": (20, 512, 64, 640, 16, 64, 64, 64)}
+LATENT_TINY = {"glm47": (20, 128, 32, 256, 2, 6, 12, 8)}
 
 
 def mixes(rng, rows, maxp, page, wide):
@@ -56,15 +74,116 @@ def mixes(rng, rows, maxp, page, wide):
     }
 
 
+def timed(program, operands, carried, rehearse):
+    """The least seconds of five runs of ``program`` and the operands as the last run left them (``carried``:
+    the donated ones' places among them, which the program's later outputs fill again)."""
+    best = float("inf")
+    for _ in range(1 if rehearse else 5):
+        t = time.perf_counter()
+        acc, *pools = program(*operands)
+        acc.block_until_ready()
+        best = min(best, time.perf_counter() - t)
+        for at, pool in zip(carried, pools):
+            operands[at] = pool
+    return best, operands
+
+
+def latent_bench(args, model, dims, calls, peak):
+    """The latent model's mixes through its kernel, each once with the file's constants and once a ``--set``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.kernels import latent_paged_attention as k
+    from deepspeed_tpu.ops.transformer import latent_attention as module
+
+    NH, Dv, rope, D, L, R, maxp, P = dims
+    NP, longest = R * maxp + 1, maxp * P
+    rng = np.random.default_rng(0)
+    key = jax.random.PRNGKey(0)
+    layer = jax.random.normal(key, (1, NP, P, D), jnp.bfloat16)
+    table = jnp.asarray(1 + rng.permutation(R * maxp).reshape(R, maxp), jnp.int32)  # scattered, as a pool ages
+    q = jax.random.normal(jax.random.fold_in(key, 1), (R, 1, NH, Dv + rope), jnp.bfloat16)
+    new = jax.random.normal(jax.random.fold_in(key, 2), (R, 1, Dv + rope), jnp.bfloat16)
+    q_lens = jnp.ones((R,), jnp.int32)
+    attend = functools.partial(
+        module.latent_paged_attention, value_lanes=Dv, scale=(Dv + rope) ** -0.5, interpret=args.rehearse or None,
+        pages_per_buffer=args.pages_per_buffer,
+    )
+
+    # contexts as the cell's window finds them, the same for every seed: none shorter than a seventh of the longest,
+    # a long tail up to it (the quantiles of 0.146 + 0.854 Beta(1, 2.33)), in a shuffled order
+    long = rng.permutation(longest * (0.146 + 0.854 * (1 - (1 - np.arange(R) / max(1, R - 1)) ** (1 / 2.33))))
+    tried = [{}] + [dict((name, int(value)) for name, value in (pair.split("=") for pair in text.split(","))) for text in args.set]
+    pool = jnp.concatenate([layer] * L)
+    for mix, kv in {"decode64_long": long.astype(np.int64), "decode64_short": (long / 4).astype(np.int64)}.items():
+        if mix not in args.mixes.split(","):
+            continue
+        kv_lens = jnp.asarray(kv, jnp.int32)
+        # what XLA's scatter + gather makes of one call, on ONE layer's pages (the whole pool twice does not fit the chip)
+        want_o, want_pool = jax.jit(functools.partial(attend, impl="xla"))(q, new, layer, 0, table, kv_lens, q_lens)
+        floor, bound = k.min_seconds([(1, int(n)) for n in kv], NH, Dv, rope, peak)
+        for constants in tried:
+
+            def many(q, new, pool, table, kv_lens, q_lens):  # a function of its own a trial: jit keeps no trace of another's constants
+                # CALLS layers back to back in one program, the pool carried as the layer loop carries it
+                def body(i, carry):
+                    acc, pool = carry
+                    o, pool = attend(q, new, pool, i % L, table, kv_lens, q_lens, impl="pallas")
+                    return acc + o.astype(jnp.float32), pool
+
+                return jax.lax.fori_loop(0, calls, body, (jnp.zeros(q.shape[:3] + (Dv,), jnp.float32), pool))
+
+            defaults = {name: getattr(module, name) for name in constants}
+            for name, value in constants.items():
+                setattr(module, name, value)
+            try:
+                form = "the parent's double buffer"  # a checkout from before the ring (--root) has no forms
+                if hasattr(module, "_latent_tiles"):
+                    C, CK, _, N = module._latent_tiles(NH, 1, P, D, maxp, 2, args.pages_per_buffer)
+                    form = f"{'narrow' if NH < module._TILE_ROWS else 'wide'}: {N} halves of {C * P} keys, key tiles of {'/'.join(str(n * P) for n in range(CK, C + 1, CK))}"
+                operands = [q, new, pool, table, kv_lens, q_lens]
+                t0 = time.perf_counter()
+                traced = jax.jit(many, donate_argnums=(2,)).trace(*operands)
+                t1 = time.perf_counter()
+                lowered = traced.lower()
+                t2 = time.perf_counter()
+                program = lowered.compile()
+                t3 = time.perf_counter()
+                got_o, got_pool = jax.jit(functools.partial(attend, impl="pallas"))(q, new, layer, 0, table, kv_lens, q_lens)
+            finally:
+                for name, value in defaults.items():
+                    setattr(module, name, value)
+            gap = float(jnp.max(jnp.abs(got_o.astype(jnp.float32) - want_o.astype(jnp.float32))))
+            same_pool = bool(jnp.array_equal(got_pool[:, 1:], want_pool[:, 1:]))
+            del got_pool
+            best, (_, _, pool, *_) = timed(program, operands, (2,), args.rehearse)
+            print(
+                f"{model:14s} {mix} W=1 rows {R} pages {sum(-(-n // P) for n in kv):4d}/{R * maxp} mean context {kv.mean():.0f} "
+                f"{' '.join(f'{n}={v}' for n, v in constants.items()) or 'as the file has it'}: "
+                f"{best / calls * 1e6:8.1f} us a call, floor {floor * 1e6:6.1f} us ({bound}), "
+                f"{100 * floor / (best / calls):5.1f}% | {form} | trace {t1 - t0:.3f} s lower {t2 - t1:.3f} s compile {t3 - t2:.2f} s | "
+                f"max |o - xla| {gap:.4f} pool {'same' if same_pool else 'DIFFERS'}",
+                flush=True,
+            )
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--models", default="mistral7b,olmoe")
-    ap.add_argument("--mixes", default="decode16,chat4,mixed")
+    ap.add_argument("--mixes", default="decode16,chat4,mixed,decode64_long,decode64_short")
+    ap.add_argument("--root", default=ROOT, help="the checkout whose deepspeed_tpu is measured")
     ap.add_argument("--pages-per-buffer", type=int, default=None)
+    ap.add_argument(
+        "--set", action="append", default=[], metavar="NAME=INT,...",
+        help="glm47: constants of the latent kernel's file to try in place of the file's, e.g. "
+        "_NARROW_HALF_KEYS=768,_NARROW_RING=2; may be given more than once",
+    )
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path.insert(0, os.path.abspath(args.root))
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -75,8 +194,12 @@ def main() -> None:
     from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention as front
 
     calls = 2 if args.rehearse else CALLS
-    models = TINY if args.rehearse else {name: MODELS[name] for name in args.models.split(",")}
+    names = args.models.split(",")
+    latent = [name for name in names if name in LATENT]
+    models = TINY if args.rehearse and not latent else {name: MODELS[name] for name in names if name not in LATENT}
     peak = files.load_json(files.HERE, "peaks.json")["TPU v5 lite" if args.rehearse else jax.devices()[0].device_kind]
+    for model in latent:
+        latent_bench(args, model, (LATENT_TINY if args.rehearse else LATENT)[model], calls, peak)
     for model, (NH, NKV, D, L, R, maxp, P, wide) in models.items():
         NP = R * maxp + 1
         rng = np.random.default_rng(0)
@@ -124,18 +247,13 @@ def main() -> None:
             gap = float(jnp.max(jnp.abs(jnp.where(live, got_o.astype(jnp.float32) - want_o.astype(jnp.float32), 0))))
             same_pools = all(bool(jnp.array_equal(g[:, 1:], w[:, 1:])) for g, w in ((got_k, want_k), (got_v, want_v)))
             del want_k, want_v, got_k, got_v
-            best = float("inf")
-            for _ in range(1 if args.rehearse else 5):
-                t = time.perf_counter()
-                acc, *pools = program(q, k_new, v_new, *pools, table, kv_lens, q_lens)
-                jax.block_until_ready(acc)
-                best = min(best, time.perf_counter() - t)
+            best, (_, _, _, *pools, _, _, _) = timed(program, [q, k_new, v_new, *pools, table, kv_lens, q_lens], (3, 4), args.rehearse)
             floor, bound = k.min_seconds(rows, NH, NKV, D, peak)
             pages = sum(-(-kv // P) for n, kv in rows if n)
             print(
                 f"{model:14s} {mix:9s} W={W:<4d} live rows {sum(1 for n, _ in rows if n):2d} pages {pages:4d}/{R * maxp}: "
                 f"{best / calls * 1e6:8.1f} us a call, floor {floor * 1e6:6.1f} us ({bound}), "
-                f"{100 * floor / (best / calls):5.1f}% | trace {t1 - t0:.3f} s lower {t2 - t1:.3f} s compile {t3 - t2:.2f} s | "
+                f"{100 * floor / (best / calls):5.1f}% | {form} | trace {t1 - t0:.3f} s lower {t2 - t1:.3f} s compile {t3 - t2:.2f} s | "
                 f"max |o - xla| {gap:.4f} pools {'same' if same_pools else 'DIFFER'}",
                 flush=True,
             )
