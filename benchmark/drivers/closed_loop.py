@@ -4,7 +4,8 @@ receives less load, so the end-to-end metric is the tokens completed per
 second, never a tail.
 
 Requests come in rounds of ``clients``, every round the same stratified
-multiset of lengths in a seeded order. The window is
+multiset of lengths in an order that is the same for every seed (the seed
+draws the token ids and the weights: ``loadgen.request_stream``). The window is
 ``--seconds`` from the first submissions; tokens stamped inside it count.
 With ``--trace 1`` the loop goes on for a settle second and
 ``trace_seconds`` more, and that last stretch is traced. Then the run ends:

@@ -18,10 +18,18 @@ kernel or a metric by adding files and entries, and edits none.
     benchmark/peaks.json                 the chip's peaks, by device_kind
 
 A per-layer metric names the one end-to-end metric it should move, so a
-reader that several families of cells report has one metric per family,
-named ``<family>.<reader>`` (``train.device_idle_share``,
-``chat.device_idle_share``): the file is the reader's, the family is free
-text. A name without a dot is its own reader.
+reader has one entry for each end-to-end metric its cells report, named
+``<prefix>.<reader>``: ``train.`` moves ``train_tokens_per_s_per_chip``,
+``chat.`` moves ``itl_p50_ms``, ``serve.`` moves ``serve_tokens_per_s``, and
+``step.`` is ``serve.`` for the four readers of ``step_seq.py`` (PR 36's
+names, kept). Under a prefix a reader has ONE entry, whatever the model, and
+the cells that report it are the entry's ``workloads`` (since PR 44; until
+then every model's cell had entries of its own, ``moe.`` / ``solar.`` /
+``mimo.`` / ``glm.``, and 128 entries held 67 such pairs). A later PR's new
+reader gets one entry under the prefix of the metric it moves, and its cell
+joins the ``workloads`` of the readers it shares. The file is the reader's,
+the harness drops the prefix (``reader_of``) and picks a cell's entries by
+their lists (``metrics_of``). A name without a dot is its own reader.
 """
 
 from __future__ import annotations
@@ -53,7 +61,7 @@ def load_module(kind: str, name: str):
 
 
 def reader_of(metric: str) -> str:
-    """The reader file of a per-layer metric: ``<family>.<reader>`` or ``<reader>``."""
+    """The reader file of a per-layer metric: ``<prefix>.<reader>`` or ``<reader>``."""
     return metric.split(".", 1)[-1]
 
 

@@ -7,8 +7,10 @@ The idea (a seeded trace of heavy-tailed arrivals) is that of
 ``deepspeed_tpu/utils/loadgen.py``; three things differ, on purpose:
 
 * lengths are **stratified**: request *i* of *N* takes the ``(i + 0.5) / N``
-  quantile of the stated distribution and the seed only permutes the
-  pairing and the order, so every seed offers the same multiset of work;
+  quantile of the stated distribution, so every seed offers the same multiset
+  of work. In an open loop the seed permutes the pairing and the order; in a
+  closed loop both are a rule of the round's number and the seed draws the
+  token ids alone (``request_stream`` says why);
 * an open loop has a fixed number of arrivals, ``round(rate * horizon)``,
   whose seeded gaps are scaled to span the horizon exactly (a Poisson
   process conditioned on its count), so every seed offers the same load;
@@ -72,24 +74,31 @@ def arrival_offsets(arrival: Dict, rate_rps: float, horizon_s: float, rng: np.ra
 
 def request_stream(mix: Dict, block: int, vocab_size: int, seed: int):
     """An endless supply for a closed loop: block after block of ``block``
-    requests, each block holding the same stratified multiset of lengths in
-    its own seeded order, so every round of callers is offered the same
-    work whatever the seed."""
+    requests, each block holding the same stratified multiset of lengths.
+    A block's order, and its pairing of prompt with output length, follow
+    from the block's number alone, the same for every seed: in a closed loop
+    the order decides how many requests, prefill chunks and long contexts
+    fall inside the window, so an order drawn from the seed let the seed
+    move the work (Solar's cell: 843-854 prefill chunks a window by the seed
+    before, 838-846 by where the window ends since; PERF.md section 2,
+    PR 44). The seed draws the token ids."""
     k = 0
     while True:
-        for r in make_requests(mix, block, vocab_size, seed, salt=k):
+        for r in make_requests(mix, block, vocab_size, seed, salt=k, seeded_order=False):
             r.index += k * block
             yield r
         k += 1
 
 
-def make_requests(mix: Dict, n: int, vocab_size: int, seed: int, salt: int = 0) -> List[TrafficRequest]:
-    """``n`` requests of the mix, in a seeded order. Prompt and output
-    lengths are stratified and paired by a seeded permutation; token ids are
-    uniform, so no two prompts share a prefix."""
+def make_requests(mix: Dict, n: int, vocab_size: int, seed: int, salt: int = 0, seeded_order: bool = True) -> List[TrafficRequest]:
+    """``n`` requests of the mix. Prompt and output lengths are stratified,
+    then paired and ordered by a permutation: the seed's, or with
+    ``seeded_order`` False one that ``salt`` alone decides. Token ids are
+    uniform and always the seed's, so no two prompts share a prefix."""
     rng = np.random.default_rng([seed, 0x7AFF1C, salt])
-    prompt_lens = rng.permutation(stratified_lengths(mix["prompt_len"], n))
-    out_lens = rng.permutation(stratified_lengths(mix["output_len"], n))
+    order = rng if seeded_order else np.random.default_rng([0x7AFF1C, salt])
+    prompt_lens = order.permutation(stratified_lengths(mix["prompt_len"], n))
+    out_lens = order.permutation(stratified_lengths(mix["output_len"], n))
     return [
         TrafficRequest(i, rng.integers(0, vocab_size, int(prompt_lens[i]), dtype=np.int32), int(out_lens[i]))
         for i in range(n)

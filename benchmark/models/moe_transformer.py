@@ -5,7 +5,7 @@ through it.
 
 ``build`` returns the model and its ``shape`` under the keys every family
 gives (``dense_transformer.py``), plus the expert layer's own: what the
-``moe.`` readers need to count weights and operations.
+experts' readers need to count weights and operations.
 
 Seeded weights stand in for a trained checkpoint, and a trained router is
 peaked where the training initialisation (``wg`` of standard deviation 0.02)

@@ -19,6 +19,19 @@ settle the host could not run ahead of). So:
   ran the program the span names and that no step is missing between two
   pairs. A trace that fails the check is a changed program or a broken
   reader, and raises.
+* a host that stood still is neither (PR 44). The sandbox stalls for 8 to
+  ~110 ms at a time, a few times a window, and a stall inside the jitted call
+  puts an execution's start further than ``PAIR_REACH_S`` behind its
+  ``serve.enqueue``. Such a step is not paired by time; where the paired
+  numbers skip, the unpaired enqueues and the unpaired whole executions
+  between the two paired neighbours are counted, and where they are equally
+  many they are paired by order (``Step.by_order``), under the same check of
+  the program. Only where the counts differ is a step's execution missing.
+  At the slice's two ends there is no neighbour to count from, so the reach
+  stands there and a late execution stays unpaired, as one whose enqueue lies
+  outside the slice does. No median reads ``Step.execution``: the stalled
+  step's long gap is among ``exec_gaps`` and its turnaround among
+  ``turnarounds``, as a real gap should be, one sample each under a median.
 
 A trace without ``serve.enqueue`` spans (a program before PR 36, a training
 cell) has nothing of this: ``steps`` returns None and every reader says None.
@@ -47,13 +60,20 @@ class Step:
     fetch: Optional[Span]  # the wait for this step, where it lies whole inside the slice
     drained: bool  # settled by a drain: what follows it is no turnaround
     ahead: bool  # enqueued with the step before it unsettled (``serve.dispatch``'s ``ahead``; a window has none)
-    execution: Optional[Event] = None  # the whole execution paired with it by time
+    execution: Optional[Event] = None  # the whole execution paired with it
+    by_order: bool = False  # paired by order between two pairs by time: the execution started beyond the reach (a host stall)
 
 
 def _consecutive(seqs: Sequence[int], what: str) -> None:
     for a, b in zip(seqs, seqs[1:]):
         if b != a + 1:
-            raise ValueError(f"{what}: seq {a} is followed by {b}; a step's spans or its execution are missing from the slice's middle")
+            raise ValueError(f"{what}: seq {a} is followed by {b}; a step's spans are missing from the slice's middle")
+
+
+def _check_program(span: Span, m: Event) -> None:
+    module = op_scopes.module_of(m.name)[0]
+    if module != "jit_" + span.attrs["program"]:
+        raise ValueError(f"seq {span.attrs['seq']}: serve.enqueue names {span.attrs['program']!r} and the device ran {module!r} {1e3 * (m.start - span.start):+.3f} ms from it")
 
 
 def executions(trace, programs) -> List[Event]:
@@ -63,11 +83,12 @@ def executions(trace, programs) -> List[Event]:
 
 
 def steps(trace, spans: Sequence[Span]) -> Optional[List[Step]]:
-    """The slice's steps by ``seq``, each with its enqueue, its wait and,
-    paired by time, its execution; None where the trace holds no
-    ``serve.enqueue``. Raises where the numbers skip, where two executions
-    fall to one enqueue, or where the device ran another program than the
-    span names."""
+    """The slice's steps by ``seq``, each with its enqueue, its wait and its
+    execution, paired by time or, between two such pairs, by order; None where
+    the trace holds no ``serve.enqueue``. Raises where the spans' numbers
+    skip, where two executions fall to one enqueue, where the device ran
+    another program than the span names, or where fewer or more executions
+    than enqueues lie unpaired between two pairs."""
     enqueues = sorted((s for s in spans if s.name == ENQUEUE), key=lambda s: s.start)
     if not enqueues:
         return None
@@ -76,24 +97,36 @@ def steps(trace, spans: Sequence[Span]) -> Optional[List[Step]]:
     drained = {s.attrs["seq"] for s in spans if s.name == EMIT and "drain" in s.attrs}
     ahead = {s.attrs["seq"] for s in spans if s.name == DISPATCH and s.attrs.get("ahead") == 1}
     starts = [s.start for s in enqueues]
-    paired: Dict[int, Event] = {}
-    for m in executions(trace, {s.attrs["program"] for s in enqueues}):
+    runs = executions(trace, {s.attrs["program"] for s in enqueues})
+    paired: Dict[int, int] = {}  # seq -> index into ``runs``
+    for k, m in enumerate(runs):
         i = bisect.bisect_left(starts, m.start)
         near = min((j for j in (i - 1, i) if 0 <= j < len(starts)), key=lambda j: abs(starts[j] - m.start))
         if abs(starts[near] - m.start) > PAIR_REACH_S:
-            continue  # its enqueue lies outside the slice
+            continue  # its enqueue lies outside the slice, or the host stood still between the two
         span = enqueues[near]
-        seq, module = span.attrs["seq"], op_scopes.module_of(m.name)[0]
-        if module != "jit_" + span.attrs["program"]:
-            raise ValueError(f"seq {seq}: serve.enqueue names {span.attrs['program']!r} and the device ran {module!r} {1e3 * (m.start - span.start):+.3f} ms from it")
+        seq = span.attrs["seq"]
+        _check_program(span, m)
         if seq in paired:
-            raise ValueError(f"seq {seq}: two executions of {module!r} fall to one serve.enqueue")
-        paired[seq] = m
-    _consecutive(sorted(paired), "executions paired with serve.enqueue spans")
+            raise ValueError(f"seq {seq}: two executions of {op_scopes.module_of(m.name)[0]!r} fall to one serve.enqueue")
+        paired[seq] = k
+    by_seq = {s.attrs["seq"]: s for s in enqueues}
+    by_order = set()
+    by_time = sorted(paired)
+    for a, b in zip(by_time, by_time[1:]):
+        between = range(paired[a] + 1, paired[b])  # the unpaired executions between the two pairs; no pair by time lies among them
+        if len(between) != b - a - 1:
+            raise ValueError(
+                f"executions paired with serve.enqueue spans: seq {a} is followed by {b}, and between the two lie {b - a - 1} "
+                f"serve.enqueue span(s) and {len(between)} whole execution(s): a step's execution is missing from the slice's middle")
+        for seq, k in zip(range(a + 1, b), between):
+            _check_program(by_seq[seq], runs[k])
+            paired[seq] = k
+            by_order.add(seq)
     found = []
     for s in enqueues:
         seq = s.attrs["seq"]
-        found.append(Step(seq, s.attrs["program"], s, fetch.get(seq), seq in drained, seq in ahead, paired.get(seq)))
+        found.append(Step(seq, s.attrs["program"], s, fetch.get(seq), seq in drained, seq in ahead, runs[paired[seq]] if seq in paired else None, seq in by_order))
     return found
 
 

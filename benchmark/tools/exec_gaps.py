@@ -9,7 +9,10 @@ device's line, the host's turnaround before its enqueue (``drain`` where a
 drain settled the step before, ``sync`` where nothing was in flight), the
 enqueue call, the wait for it, and the self times of its pack and its settle,
 each marked ``in`` where the span lies inside an execution (the host worked
-while the device did) and ``out`` where it does not. Then the medians, which
+while the device did) and ``out`` where it does not. The first line says how
+many steps were paired with their execution by order and not by time (0 in a
+quiet slice; a host stall inside the jitted call otherwise, named with its
+``seq`` and how late the execution started). Then the medians, which
 are what the four readers of ``benchmark/step_seq.py`` report, the gap's
 decomposition (gap = turnaround + enqueue call + the rest: the runtime's
 launch after the enqueue and the completion's way back to the host, which
@@ -67,7 +70,10 @@ def main() -> int:
         return {s.attrs.get("seq"): (t, any(lo <= s.start and s.end <= hi for lo, hi in busy)) for s, t in zip(named, program_spans.self_seconds(spans, name))}
 
     pack_of, settle_of = own("serve.pack"), own("serve.settle")
-    print(f"slice {trace.window_s:.3f} s, {len(found)} steps enqueued, {len(runs)} whole executions, clock_shift {1e3 * trace.clock_shift:+.3f} ms")
+    by_order = [st for st in found if st.by_order]
+    print(f"slice {trace.window_s:.3f} s, {len(found)} steps enqueued, {len(runs)} whole executions, clock_shift {1e3 * trace.clock_shift:+.3f} ms; "
+          f"{len(by_order)} steps paired by order (an execution more than {1e3 * step_seq.PAIR_REACH_S:.0f} ms from its enqueue: a host stall)"
+          + "".join(f"; seq {st.seq} {1e3 * (st.execution.start - st.enqueue.start):+.1f} ms" for st in by_order[:8]))
     print("    seq program                       device     gap  turnar. enqueue    wait   pack        settle")
     first = max(0, (len(found) - args.steps) // 2)
     packs, settles = [], []

@@ -135,6 +135,16 @@ def test_every_metric_has_a_reader_and_every_cell_its_metrics():
         assert any(cell in cells_of(m) for m in SPEC["per_layer"]), cell
 
 
+def test_one_entry_a_reader_and_moved_metric_and_no_more_than_the_contract_holds():
+    # a prefix says which end-to-end metric the entry moves (``train.``, ``chat.``, ``serve.``; ``step.`` for the four
+    # readers of ``step_seq.py``): under it a reader has ONE entry, and the cells that report it are its ``workloads``
+    pairs = [(reader_of(m["name"]), m["moves"]) for m in SPEC["per_layer"]]
+    assert len(pairs) == len(set(pairs)), sorted(p for p in set(pairs) if pairs.count(p) > 1)
+    assert 1 <= len(SPEC["per_layer"]) <= 128  # the contract's cap: what refused PR 41's first draft (138)
+    for m in SPEC["per_layer"]:
+        assert cells_of(m) == sorted(set(cells_of(m)), key=CELLS.index), m["name"]  # each cell once, in the cells' order
+
+
 def test_a_metric_family_shares_its_reader_and_layers_are_those_of_perf_md():
     assert reader_of("chat.device_idle_share") == reader_of("train.device_idle_share") == reader_of("device_idle_share") == "device_idle_share"
     with open(os.path.join(ROOT, "PERF.md")) as f:

@@ -1,7 +1,7 @@
 """What PR 41 added to the benchmark for GLM-4.7-Flash: the configuration file
 against the catalog row's published keys, its cuts and floors and its memory
 arithmetic, the reference's independence, the latent kernel's operations and
-bytes by hand, the three new ``glm.`` readers on a synthetic trace (device
+bytes by hand, the three new readers on a synthetic trace (device
 events with the name stacks the program's scope and kernel name give them) and
 where scope or model is absent, the traffic file against the engine's
 ``max_seq_len``, and the cell's and the logits tool's rehearsals. Entries are
@@ -18,6 +18,7 @@ import pytest
 from benchmark import files, op_scopes, program_spans
 from benchmark import trace_reduce as tr
 from benchmark.kernels import latent_paged_attention as lpa
+from tests.benchmark.spec_lookup import readers_of
 
 HERE = os.path.dirname(__file__)
 ROOT = os.path.abspath(os.path.join(HERE, "..", ".."))
@@ -25,11 +26,13 @@ NAME, CELL_NAME = "glm-4.7-flash-l16-ep8", "glm47_flash_long_decode"
 PEAK = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 CELL = {"name": "a_serving_cell", "config": {"engine": {"kind": "serve"}}, "peak": PEAK}
 NEW_READERS = ["latent_attn_time_share", "latent_attn_roofline", "latent_proj_time_share"]
-# the readers of the ``mimo.`` family and of PR 36 that apply, less ten: ``per_layer`` holds at most 128 entries and the
-# accepted benchmark has 113, so the family is the three new readers and twelve of the 22 ISSUE 41 lists (PERF.md, section 7)
+# the readers MiMo's cell lists and PR 36's four, as far as they apply: all 22 that ISSUE 41 listed (ten of them were out
+# from PR 41 to PR 44, while every family had entries of its own and ``per_layer`` stood at its cap of 128)
 SHARED_READERS = [
     "device_idle_share", "decode_step_device_ms", "mixed_step_device_ms", "step_host_share", "kv_pages_in_use_share", "compiles_in_window",
     "expert_ffn_time_share", "expert_ffn_roofline", "moe_route_time_share", "held_assignments_share", "held_experts_hit_share", "exec_gap_ms",
+    "step_admit_ms", "step_pack_ms", "step_dispatch_ms", "step_settle_ms", "rows_per_step", "mixed_step_token_fill", "max_expert_load",
+    "host_turnaround_ms", "enqueue_call_ms", "run_ahead_share",
 ]
 # config.json of zai-org/GLM-4.7-Flash as the model-configs catalog holds it
 PUBLISHED = {
@@ -128,17 +131,14 @@ def test_the_traffic_fills_the_engines_max_seq_len_and_the_cell_is_in_its_lists(
     r_paged, r_mix = rehearse["config_file"]["engine"]["init_inference"]["paged_kv"], rehearse["traffic_file"]
     assert r_paged["max_seq_len"] == r_mix["prompt_len"]["max"] + r_mix["output_len"]["max"] == 96
     assert CELL_NAME in next(m for m in spec["end_to_end"] if m["name"] == "serve_tokens_per_s")["workloads"]
-    family = {m["name"]: m for m in spec["per_layer"] if m["name"].startswith("glm.")}
-    assert {"glm." + r for r in NEW_READERS + SHARED_READERS} == set(family)
-    assert len(spec["per_layer"]) <= 128  # the contract's cap: what refused this PR's first draft (138)
-    for m in family.values():
-        assert m["workloads"] == [CELL_NAME] and m["moves"] == "serve_tokens_per_s"
-        assert os.path.exists(os.path.join(ROOT, "benchmark", "layer_metrics", files.reader_of(m["name"]) + ".py"))
-    assert family["glm.latent_attn_roofline"]["unit"] == "%" and family["glm.latent_attn_roofline"]["layer"] == "kernels"
+    family = readers_of(spec, CELL_NAME)
+    assert set(NEW_READERS + SHARED_READERS) == set(family)
+    for r, m in family.items():
+        assert m["moves"] == "serve_tokens_per_s"
+        assert os.path.exists(os.path.join(ROOT, "benchmark", "layer_metrics", r + ".py"))
+    assert family["latent_attn_roofline"]["unit"] == "%" and family["latent_attn_roofline"]["layer"] == "kernels"
     # what reckons num_layers calls of one head layout, or every layer as routed, is not asked of this cell
-    for m in spec["per_layer"]:
-        if files.reader_of(m["name"]) in ("ragged_attn_time_share", "ragged_attn_roofline", "ragged_kernel_call_us", "experts_hit_share"):
-            assert CELL_NAME not in m.get("workloads", [CELL_NAME]), m["name"]
+    assert not set(family) & {"ragged_attn_time_share", "ragged_attn_roofline", "ragged_kernel_call_us", "experts_hit_share"}
 
 
 def test_the_adapter_builds_the_programs_model_and_scales_one_leaf():
@@ -274,7 +274,7 @@ def test_the_cell_rehearses_correct_with_a_trace_and_a_large_seed():
     assert done.returncode == 0, done.stderr[-2000:]
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] == "passed" and last["correct"] is True and last["failed"] == 0
-    assert "glm.compiles_in_window" in last["metric_names"]
+    assert "serve.compiles_in_window" in last["metric_names"]
 
 
 def test_the_logits_tool_rehearses_and_every_control_is_refused():

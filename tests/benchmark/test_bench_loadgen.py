@@ -76,6 +76,36 @@ def test_closed_loop_rounds_repeat_the_multiset():
     assert 512 <= min(r.max_new_tokens for r in first) and max(r.max_new_tokens for r in first) <= 1024
 
 
+@pytest.mark.parametrize("traffic", ["decode_heavy", "long_decode"])
+def test_closed_loop_order_is_the_same_for_every_seed(traffic):
+    """In a closed loop the order of the lengths decides what falls inside
+    the window, so it is a rule of the round and not the seed's: two seeds
+    offer the same lengths request for request, and other token ids."""
+    m = mix(traffic)
+    a, b = (loadgen.request_stream(m, 64, 32768, seed=s) for s in (2244070101, 7))
+    a, b = [next(a) for _ in range(192)], [next(b) for _ in range(192)]
+    sizes = lambda reqs: [(r.prompt.size, r.max_new_tokens) for r in reqs]  # noqa: E731
+    assert sizes(a) == sizes(b)
+    assert all(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
+    # a round's order is its own, and the pairing of prompt with output length too
+    assert sizes(a[:64]) != sizes(a[64:128]) != sizes(a[128:])
+    assert np.argsort([r.prompt.size for r in a[:64]]).tolist() != np.argsort([r.max_new_tokens for r in a[:64]]).tolist()
+    # the same seed, the same requests byte for byte
+    again = loadgen.request_stream(m, 64, 32768, seed=7)
+    assert all(next(again).prompt.tobytes() == y.prompt.tobytes() for y in b)
+
+
+def test_an_open_loop_keeps_its_seeded_order():
+    """``make_requests`` as the open loop calls it draws order and pairing
+    from the seed, as before PR 44's change to the closed loop's supply."""
+    m = mix("chat_steady")
+    a, b = loadgen.make_requests(m, 40, 32768, seed=1), loadgen.make_requests(m, 40, 32768, seed=2)
+    assert lengths(a) == lengths(b)
+    assert [r.prompt.size for r in a] != [r.prompt.size for r in b]
+    fixed_a, fixed_b = (loadgen.make_requests(m, 40, 32768, seed=s, seeded_order=False) for s in (1, 2))
+    assert [r.prompt.size for r in fixed_a] == [r.prompt.size for r in fixed_b] != [r.prompt.size for r in a]
+
+
 def test_stratified_lengths_are_the_quantiles_clipped_to_the_stated_range():
     # lognormal: the middle request takes the median, the tails are clipped, the order rises
     dist = {"dist": "lognormal", "median": 256, "sigma": 0.9, "min": 32, "max": 2048}

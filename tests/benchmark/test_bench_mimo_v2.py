@@ -1,7 +1,7 @@
 """What PR 34 added to the benchmark for MiMo-V2.5: the configuration file
 against the catalog row's published keys, the reference's independence, the
 windowed kernel's operations and bytes on hand-worked cases, the five new
-``mimo.`` readers on a synthetic trace (device events with the name stacks the
+readers (``mimo.`` entries until PR 44, ``serve.`` since) on a synthetic trace (device events with the name stacks the
 program's scopes give them, ``serve.settle`` spans with the program's
 attributes) and where their scope is absent, the traffic file against the
 engine's ``max_seq_len``, and the cell's rehearsal. Pins neither a count of
@@ -18,6 +18,7 @@ import pytest
 from benchmark import files, op_scopes, program_spans
 from benchmark import trace_reduce as tr
 from benchmark.kernels import windowed_paged_attention as wpa
+from tests.benchmark.spec_lookup import readers_of
 
 HERE = os.path.dirname(__file__)
 ROOT = os.path.abspath(os.path.join(HERE, "..", ".."))
@@ -94,13 +95,10 @@ def test_the_traffic_fills_the_engines_max_seq_len_and_the_cell_is_in_its_lists(
     r_paged, r_mix = rehearse["config_file"]["engine"]["init_inference"]["paged_kv"], rehearse["traffic_file"]
     assert r_paged["max_seq_len"] == r_mix["prompt_len"]["max"] + r_mix["output_len"]["max"] == 96
     assert CELL_NAME in next(m for m in spec["end_to_end"] if m["name"] == "serve_tokens_per_s")["workloads"]
-    family = {m["name"]: m for m in spec["per_layer"] if m["name"].startswith("mimo.")}
-    assert {"mimo." + r for r in NEW_READERS} <= set(family)
-    for m in family.values():
-        assert m["workloads"] == [CELL_NAME] and m["moves"] == "serve_tokens_per_s"
+    family = readers_of(spec, CELL_NAME)
+    assert set(NEW_READERS) <= set(family) and all(m["moves"] == "serve_tokens_per_s" for m in family.values())
     # what reckons one head layout for every layer, or every layer as routed, is not asked of this cell
-    for r in ("ragged_attn_time_share", "ragged_attn_roofline", "ragged_kernel_call_us", "softmax_attn_time_share", "experts_hit_share"):
-        assert "mimo." + r not in family
+    assert not set(family) & {"ragged_attn_time_share", "ragged_attn_roofline", "ragged_kernel_call_us", "softmax_attn_time_share", "experts_hit_share"}
 
 
 def test_the_adapter_builds_the_programs_model_and_says_both_head_layouts():
@@ -239,7 +237,7 @@ def test_the_cell_rehearses_correct_with_a_trace_and_a_large_seed():
     assert done.returncode == 0, done.stderr[-2000:]
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] == "passed" and last["correct"] is True and last["failed"] == 0
-    assert "mimo.compiles_in_window" in last["metric_names"]
+    assert "serve.compiles_in_window" in last["metric_names"]
 
 
 def test_the_logits_tool_rehearses():

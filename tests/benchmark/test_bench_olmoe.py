@@ -1,7 +1,7 @@
 """What PR 25 added to the benchmark for OLMoE: the configuration file against
 the catalog's published keys, the reference's independence, the grouped expert
-matmul's operations and bytes on hand-worked cases, the five ``moe.`` readers
-on a small trace recorded on a v5e chip from the program itself
+matmul's operations and bytes on hand-worked cases, the five new readers
+(``moe.`` entries until PR 44, ``serve.`` since) on a small trace recorded on a v5e chip from the program itself
 (``benchmark/tools/record_moe_trace.py``: a two-layer, eight-expert paged
 server's five steps) and on a dense model's trace, where they have to find
 nothing, and the cell's rehearsal."""
@@ -18,6 +18,7 @@ import pytest
 from benchmark import files, op_scopes, program_spans
 from benchmark import trace_reduce as tr
 from benchmark.kernels import grouped_expert_matmul as gem
+from tests.benchmark.spec_lookup import readers_of
 
 HERE = os.path.dirname(__file__)
 ROOT = os.path.abspath(os.path.join(HERE, "..", ".."))
@@ -81,14 +82,14 @@ def test_the_cell_and_its_metric_family():
     spec = load("BENCHMARK.json")
     cell = next(w for w in spec["workloads"] if w["name"] == "olmoe_decode_heavy")
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("olmoe-1b-7b-0125-l12", "decode_heavy", 1)
-    assert len(spec["workloads"]) == 5 and sum(w["chips"] == 4 for w in spec["workloads"]) == 1
+    assert len(spec["workloads"]) >= 5 and sum(w["chips"] == 4 for w in spec["workloads"]) == 1
     tokens = next(m for m in spec["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert tokens["workloads"] == ["mistral7b_decode_heavy", "olmoe_decode_heavy"] and tokens["bound"] == 0.016
-    family = [m for m in spec["per_layer"] if m["name"].startswith("moe.")]
-    assert len(family) == 20 and all(m["workloads"] == ["olmoe_decode_heavy"] and m["moves"] == "serve_tokens_per_s" for m in family)
-    assert {files.reader_of(m["name"]) for m in family} >= set(READERS)
-    # new entries stand at the end of their lists
-    assert spec["workloads"][-1] is cell and spec["per_layer"][-20:] == family and spec["configs"][-1]["name"] == cell["config"]
+    assert {"mistral7b_decode_heavy", "olmoe_decode_heavy"} <= set(tokens["workloads"]) and 0.01 <= tokens["bound"] <= 0.1  # its value is the benchmark's to refit (PERF.md section 2)
+    family = readers_of(spec, "olmoe_decode_heavy")
+    assert len(family) >= 20 and all(m["moves"] == "serve_tokens_per_s" for m in family.values())
+    assert set(family) >= set(READERS)
+    # neither a position nor a count of the benchmark as a whole is pinned: later cells and entries come after these
+    assert any(c["name"] == cell["config"] for c in spec["configs"])
 
 
 def test_the_adapter_builds_the_programs_model_and_says_its_expert_shape():

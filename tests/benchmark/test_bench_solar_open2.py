@@ -1,7 +1,7 @@
 """What PR 31 added to the benchmark for Solar-Open2-250B: the configuration
 file against the catalog row's published keys, the reference's independence,
-the recurrence's operations and bytes on hand-worked cases, the four ``solar.``
-readers on a small trace recorded on a v5e chip from the program itself
+the recurrence's operations and bytes on hand-worked cases, the four new
+readers (``solar.`` entries until PR 44, ``serve.`` since) on a small trace recorded on a v5e chip from the program itself
 (``benchmark/tools/record_solar_trace.py``: a one-period toy of the model's
 shape, a paged server's steps) and on a dense and an MoE model's traces,
 where they have to find nothing, and the cell's rehearsal."""
@@ -18,6 +18,7 @@ import pytest
 from benchmark import files, op_scopes, program_spans
 from benchmark import trace_reduce as tr
 from benchmark.kernels import kda_recurrence as kda
+from tests.benchmark.spec_lookup import readers_of
 
 HERE = os.path.dirname(__file__)
 ROOT = os.path.abspath(os.path.join(HERE, "..", ".."))
@@ -98,11 +99,10 @@ def test_the_cell_and_its_metric_family():
     cell = next(w for w in spec["workloads"] if w["name"] == "solar_open2_decode_heavy")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (NAME, "decode_heavy", 1)
     tokens = next(m for m in spec["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert "solar_open2_decode_heavy" in tokens["workloads"] and tokens["bound"] == 0.016
-    family = [m for m in spec["per_layer"] if m["name"].startswith("solar.")]
-    assert len(family) == 21 and all(m["workloads"] == ["solar_open2_decode_heavy"] and m["moves"] == "serve_tokens_per_s" for m in family)
-    got = {files.reader_of(m["name"]) for m in family}
-    assert got >= set(READERS) and not got & {"ragged_attn_time_share", "ragged_attn_roofline", "ragged_kernel_call_us"}
+    assert "solar_open2_decode_heavy" in tokens["workloads"] and 0.01 <= tokens["bound"] <= 0.1  # its value is the benchmark's to refit (PERF.md section 2)
+    family = readers_of(spec, "solar_open2_decode_heavy")
+    assert len(family) >= 21 and all(m["moves"] == "serve_tokens_per_s" for m in family.values())
+    assert set(family) >= set(READERS) and not set(family) & {"ragged_attn_time_share", "ragged_attn_roofline", "ragged_kernel_call_us"}
     # no position and no count of the benchmark as a whole is pinned here: the next PR's cell comes after this one
     assert any(c["name"] == NAME for c in spec["configs"])
 
