@@ -54,6 +54,10 @@ tiles, as in ``decode._paged_layers``; a window of at most one tile is one
   the same width-1 call and the rows with a chunk ``CHUNK_ROWS`` a trip of a
   loop whose count is data (``wide_attention``: the ``[R, W]`` window is
   never laid out); the window layers' calls with ``window`` and their sinks;
+  each kind with its own query heads (``cfg.heads_of``), so the kernel's
+  group is the kind's; an output gate (``hm.output_gate``: a feature's, or one
+  scalar a head under the ``head_gate`` scope) from the same normed tile as q,
+  between the kernel's output and ``Wo``, for decode rows and chunk rows alike;
 * latent: ``latent_paged_attention`` (``ops/transformer/latent_attention.py``)
   in the absorbed form, for decode rows and prefill chunks alike, split by
   width as the softmax layers' calls are: the query is ``[q_nope Wk_b^T ;
@@ -142,7 +146,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
     E = cfg.num_experts
     expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[3:]), stacks["moe"]["experts"])
     moe_stacks = {k: v for k, v in stacks["moe"].items() if k != "experts"}
-    NH, D, Dv = cfg.num_heads, cfg.head_dim, cfg.v_head_dim
+    NH, D, Dv = cfg.num_heads, cfg.head_dim, cfg.v_head_dim  # NH: a latent layer's heads; a softmax or window layer takes its kind's
     LH, LD, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_conv_kernel
     C3 = 3 * LH * LD
     scale = decode._softmax_scale(cfg, D)
@@ -226,7 +230,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         """A softmax or a window layer: ``tree`` its kind's stacks (its own
         leaves where ``per`` is None), ``layer`` its entry in the kind's
         pools, ``ffn(x_tile, start)`` what follows the mixer."""
-        NKV = cfg.kv_heads_of(kind)
+        NH, NKV = cfg.heads_of(kind), cfg.kv_heads_of(kind)
         rotary_or_scaled = cfg.position == "rope" or cfg.attn_value_scale != 1.0
 
         def before(start, qkv):
@@ -266,7 +270,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
                 # the narrow program's is in slab order (which a whole slab's packing is), the wide one's packed
                 a = jnp.take(attn, packed.take(packed.slot, start), axis=0, mode="clip") if T == 1 else packed.take(attn, start)
-                x_tile = x_tile + qmatmul(hm.softmax_gate(p, h, a), p["wo"]).astype(x.dtype)
+                x_tile = x_tile + qmatmul(hm.output_gate(p, h, a), p["wo"]).astype(x.dtype)
             x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 

@@ -297,16 +297,20 @@ class PagePool:
         # sliding-window layers, sized by max_slots, the window and the chunk
         self.states: Optional[StateStore] = None
         self.window_ring = 0
+        self.window_keys = 0  # keys a window layer's query sees
+        self.query_heads: dict = {}  # a layer kind's query heads, for the memory report
         if getattr(cfg, "layer_types", None):
             from deepspeed_tpu.inference.hybrid_decode import state_shapes, window_shapes
 
             shapes = state_shapes(cfg, self.max_slots)
             kv_dtype = self.cache.k_pages.dtype
+            self.query_heads = {kind: cfg.heads_of(kind) for kind in ("softmax", "window")}
             rings = (None, None)
             if cfg.layers_of("window"):
                 if not prefill_chunk:
                     raise ValueError("a model with sliding-window layers needs prefill_chunk to size its page rings")
                 self.window_ring = window_ring_pages(cfg.window, self.page_size, int(prefill_chunk))
+                self.window_keys = cfg.window
                 rings = tuple(jnp.zeros(shape, kv_dtype) for shape in window_shapes(cfg, self.max_slots, self.page_size, self.window_ring))
             latent = None
             if cfg.layers_of("latent"):
@@ -388,6 +392,8 @@ class PagePool:
         if self.states is not None:
             in_use = self.max_slots - len(self._free_slots)
             state = {
+                # the paged (full or latent) layers' query heads; a window layer's are with its ring's entries
+                "paged_query_heads": self.query_heads["softmax"],
                 "state_total_bytes": self.states.state.nbytes + self.states.conv.nbytes,
                 "state_slots": self.max_slots,
                 "state_slots_in_use": in_use,
@@ -398,6 +404,10 @@ class PagePool:
                     window_total_bytes=self.states.window_bytes(),
                     window_bytes_per_slot=self.states.window_bytes() // (1 + self.max_slots * self.window_ring) * self.window_ring,
                     window_ring_pages=self.window_ring,
+                    window_keys=self.window_keys,
+                    window_layers=self.states.window_k.shape[0],
+                    window_query_heads=self.query_heads["window"],
+                    window_kv_heads=self.states.window_k.shape[2],
                     window_slots=self.max_slots,
                     window_slots_in_use=in_use,
                 )

@@ -26,16 +26,20 @@ sequence, no cache: what the parity tests use; training this family is not
 supported) and by the paged serving step (``inference/hybrid_decode.py``).
 
 The softmax and the window layer (``attn_project``, ``attn_heads``): ``q k v = h Wq, h Wk, h Wv``
-as ``num_heads`` query heads and the kind's KV heads (``num_kv_heads``,
-``window_num_kv_heads``) of ``head_dim``, values of ``v_head_dim``;
-``position="rope"`` rotates the leading ``rope_dim`` features of q and k
-(rotate-half, at the token's absolute position, theta ``rope_theta`` /
-``window_rope_theta``), ``"none"`` has no positional term at all; ``v`` times
-``attn_value_scale``; causal softmax over grouped heads, in a window layer
-over the newest ``window`` keys only (itself included) and, with
-``window_sinks``, with one learned scalar a head as one more column of the
-softmax that is then dropped, so that a row's weights sum to less than one;
-``o = (attn * sigmoid(h Wg)) Wo`` (``attn_output_gate``). The linear layer:
+as the kind's query heads (``num_heads``, ``window_num_heads``: ``heads_of``)
+and the kind's KV heads (``num_kv_heads``, ``window_num_kv_heads``) of
+``head_dim``, values of ``v_head_dim``; ``position="rope"`` rotates the
+leading ``rope_dim`` / ``window_rope_dim`` features of q and k (rotate-half, at
+the token's absolute position, theta ``rope_theta`` / ``window_rope_theta``;
+in a softmax layer with ``rope_yarn_factor`` the YaRN frequencies, cos and sin
+times the attention factor: ``rope_frequencies``), ``"none"`` has no
+positional term at all; ``v`` times ``attn_value_scale``; causal softmax over
+grouped heads, in a window layer over the newest ``window`` keys only (itself
+included) and, with ``window_sinks``, with one learned scalar a head as one
+more column of the softmax that is then dropped, so that a row's weights sum
+to less than one; ``o = (attn * gate) Wo`` (``output_gate``), the gate
+``sigmoid(h Wg)`` a feature in a softmax layer (``attn_output_gate``) or one
+scalar a head in both kinds (``attn_head_gate``). The linear layer:
 ``q~ k~ v~ = h Wq, h Wk, h Wv``, each through a depthwise causal convolution
 of ``linear_conv_kernel`` taps and SiLU; per head ``q = l2norm(q~) / sqrt(Dk)``,
 ``k = l2norm(k~)``; decay ``a = exp(-exp(A_log) softplus(Wf_up (Wf_down h) +
@@ -83,12 +87,21 @@ class HybridMoEConfig(MoETransformerConfig):
     # what each layer is; None: every layer ``softmax``
     layer_types: Optional[Sequence[str]] = None
     leading_dense_layers: int = 0  # layers in front whose FFN is dense (``intermediate_size``), not routed
-    attn_output_gate: bool = False  # softmax layers: attn * sigmoid(h Wg) before Wo
+    attn_output_gate: bool = False  # softmax layers: attn * sigmoid(h Wg) before Wo, a gate a feature
+    attn_head_gate: bool = False  # softmax and window layers: one sigmoid scalar a head on its output, before Wo
     v_head_dim: int = 0  # a value head's width; 0: head_dim
     attn_value_scale: float = 1.0  # v times this, before P v
     window: int = 0  # window layers: query i sees keys j with i - window < j <= i
+    window_num_heads: int = 0  # a window layer's query heads; 0: num_heads
     window_num_kv_heads: int = 0  # 0: num_kv_heads
     window_rope_theta: float = 0.0  # 0: rope_theta
+    window_rope_dim: int = 0  # a window layer's rotated width; 0: rope_dim
+    # softmax layers: YaRN frequency scaling by its published numbers (factor 0: none)
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_positions: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_attention_factor: float = 0.0  # cos and sin times this; 0: 0.1 ln(factor) + 1
     window_sinks: bool = False  # window layers: a learned scalar a head, one more column of the softmax
     linear_num_heads: int = 0  # 0: num_heads
     linear_head_dim: int = 0  # 0: head_dim (keys and values alike)
@@ -114,8 +127,16 @@ class HybridMoEConfig(MoETransformerConfig):
         if len(self.layer_types) != self.num_layers or set(self.layer_types) - set(LAYER_KINDS):
             raise ValueError(f"layer_types must name {self.num_layers} layers of {LAYER_KINDS}, got {self.layer_types}")
         self.v_head_dim = self.v_head_dim or self.head_dim
+        self.window_num_heads = self.window_num_heads or self.num_heads
         self.window_num_kv_heads = self.window_num_kv_heads or self.num_kv_heads
         self.window_rope_theta = self.window_rope_theta or self.rope_theta
+        self.window_rope_dim = self.window_rope_dim or self.rope_dim
+        if self.window_num_heads % self.window_num_kv_heads:
+            raise ValueError(f"a window layer's {self.window_num_heads} query heads are no multiple of its {self.window_num_kv_heads} KV heads")
+        if self.rope_yarn_factor and (self.position != "rope" or self.rope_yarn_factor <= 1 or self.rope_yarn_original_positions < 1):
+            raise ValueError("rope_yarn_factor needs position='rope', a factor above 1 and rope_yarn_original_positions")
+        if self.attn_output_gate and self.attn_head_gate:
+            raise ValueError("attn_output_gate (a gate a feature) and attn_head_gate (a gate a head) are two forms of one gate: name one")
         if "window" in self.layer_types and self.window < 1:
             raise ValueError("a window layer needs window >= 1")
         if not 0 <= self.leading_dense_layers < self.num_layers:
@@ -169,8 +190,39 @@ class HybridMoEConfig(MoETransformerConfig):
         """Leading dense layers of ``kind``: they have the first entries of the kind's cache."""
         return sum(t == kind for t in self.layer_types[: self.leading_dense_layers])
 
+    def heads_of(self, kind: str) -> int:
+        """Query heads of a softmax, window or latent layer."""
+        return self.window_num_heads if kind == "window" else self.num_heads
+
     def kv_heads_of(self, kind: str) -> int:
         return self.window_num_kv_heads if kind == "window" else self.num_kv_heads
+
+    def rope_dim_of(self, kind: str) -> int:
+        """The leading features of a q or k head that a softmax or window layer rotates."""
+        return (self.window_rope_dim if kind == "window" else self.rope_dim) or self.head_dim
+
+    def rope_frequencies(self, kind: str):
+        """``(inverse frequencies [rotated width / 2] float32, what cos and sin
+        are multiplied by)`` of a layer whose frequencies are scaled: a softmax
+        layer under ``rope_yarn_factor``. None where they are the plain
+        ``theta^(-n / half)``. YaRN by its published numbers (arXiv:2309.00071
+        as the family's modelling code computes it): pair ``n`` of the rotated
+        width ``d`` keeps its frequency below the pair that turns ``beta_fast``
+        times in the original positions, has it divided by the factor above the
+        pair that turns ``beta_slow`` times, and a linear ramp between (the
+        ramp's ends floor and ceil of ``d ln(L / (2 pi beta)) / (2 ln theta)``).
+        Made with NumPy from the config alone: constants of whatever program
+        uses them, outside any layer loop."""
+        if kind != "softmax" or not self.rope_yarn_factor:
+            return None
+        d, theta, L = self.rope_dim_of(kind), float(self.rope_theta), self.rope_yarn_original_positions
+        turns_at = lambda beta: d * np.log(L / (2 * np.pi * beta)) / (2 * np.log(theta))
+        low = max(int(np.floor(turns_at(self.rope_yarn_beta_fast))), 0)
+        high = min(int(np.ceil(turns_at(self.rope_yarn_beta_slow))), d - 1)
+        plain = theta ** (-np.arange(d // 2, dtype=np.float64) / (d // 2))
+        kept = 1.0 - np.clip((np.arange(d // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+        factor = self.rope_yarn_attention_factor or 0.1 * np.log(self.rope_yarn_factor) + 1.0
+        return (plain / self.rope_yarn_factor * (1.0 - kept) + plain * kept).astype(np.float32), float(factor)
 
     @property
     def latent_width(self) -> int:
@@ -194,23 +246,47 @@ def attn_project(p, h):
 
 def attn_heads(cfg: HybridMoEConfig, kind: str, q, k, v, positions):
     """``attn_project``'s three as heads, ``q`` [B, T, NH, D], ``k`` [B, T,
-    NKV, D], ``v`` [B, T, NKV, Dv], at ``positions`` [B, T]: the leading
-    ``rope_dim`` features of q and k rotated with the kind's theta, v scaled."""
+    NKV, D], ``v`` [B, T, NKV, Dv], at ``positions`` [B, T]: the kind's leading
+    features of q and k rotated with the kind's frequencies, v scaled."""
     from deepspeed_tpu.models.transformer import _rope
 
-    NH, NKV, D, Dv = cfg.num_heads, cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
+    NH, NKV, D, Dv = cfg.heads_of(kind), cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
     q, k, v = (a.reshape(a.shape[:-1] + shape) for a, shape in zip((q, k, v), ((NH, D), (NKV, D), (NKV, Dv))))
     if cfg.position == "rope":
-        theta = cfg.window_rope_theta if kind == "window" else cfg.rope_theta
-        q, k = _rope(q, positions, theta, cfg.rope_dim), _rope(k, positions, theta, cfg.rope_dim)
+        scaled = cfg.rope_frequencies(kind)
+        if scaled is not None:
+            q, k = (_rope_scaled(a, positions, *scaled) for a in (q, k))
+        else:
+            theta = cfg.window_rope_theta if kind == "window" else cfg.rope_theta
+            q, k = (_rope(a, positions, theta, cfg.rope_dim_of(kind)) for a in (q, k))
     if cfg.attn_value_scale != 1.0:
         v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
     return q, k, v
 
 
-def softmax_gate(p, h, attn):
-    """``attn`` [..., NH * D] times the element-wise output gate, where the layer has one."""
-    return attn * jax.nn.sigmoid(qmatmul(h, p["wg"])).astype(attn.dtype) if "wg" in p else attn
+def _rope_scaled(x, positions, inv_freq, factor):
+    """``x`` [B, T, N, D] at ``positions`` [B, T]: the leading ``2 len(inv_freq)``
+    features rotated (rotate-half) at the given frequencies, cos and sin times
+    ``factor``; the tail passes through."""
+    half = inv_freq.shape[0]
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)  # [B, T, half]
+    cos, sin = (jnp.cos(angles) * factor)[:, :, None, :], (jnp.sin(angles) * factor)[:, :, None, :]
+    x1, x2 = x[..., :half], x[..., half : 2 * half]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., 2 * half :].astype(jnp.float32)], axis=-1).astype(x.dtype)
+
+
+def output_gate(p, h, attn):
+    """``attn`` [..., NH * Dv] times the layer's output gate, from the same
+    normed ``h`` as its queries: ``wg`` [H, NH * Dv] a gate a feature, ``wg_head``
+    [H, NH] one scalar a head; a layer with neither gives ``attn`` back."""
+    if "wg" in p:
+        return attn * jax.nn.sigmoid(qmatmul(h, p["wg"])).astype(attn.dtype)
+    if "wg_head" in p:
+        with jax.named_scope("head_gate"):
+            gate = jax.nn.sigmoid(qmatmul(h, p["wg_head"]).astype(jnp.float32)).astype(attn.dtype)  # [..., NH]
+            heads = attn.reshape(attn.shape[:-1] + (gate.shape[-1], -1))
+            return (heads * gate[..., None]).reshape(attn.shape)
+    return attn
 
 
 def linear_inputs(cfg: HybridMoEConfig, p, h):
@@ -381,18 +457,20 @@ class HybridMoETransformerLM(MoETransformerLM):
                     "wv_b": dense(lead + (C, NH * Dv)),
                     "wo": dense(lead + (NH * Dv, H), out_std),
                 }
-            NKV = cfg.kv_heads_of(kind)
+            NQ, NKV = cfg.heads_of(kind), cfg.kv_heads_of(kind)
             attn = {
                 "attn_norm_scale": jnp.ones(lead + (H,)),
-                "wq": dense(lead + (H, NH * D)),
+                "wq": dense(lead + (H, NQ * D)),
                 "wk": dense(lead + (H, NKV * D)),
                 "wv": dense(lead + (H, NKV * Dv)),
-                "wo": dense(lead + (NH * Dv, H), out_std),
+                "wo": dense(lead + (NQ * Dv, H), out_std),
             }
             if cfg.attn_output_gate:
-                attn["wg"] = dense(lead + (H, NH * Dv))
+                attn["wg"] = dense(lead + (H, NQ * Dv))
+            if cfg.attn_head_gate:
+                attn["wg_head"] = dense(lead + (H, NQ))
             if kind == "window" and cfg.window_sinks:
-                attn["sinks"] = dense(lead + (NH,))
+                attn["sinks"] = dense(lead + (NQ,))
             return attn
 
         periods: Dict[str, Any] = {kind: mixer(kind, NP, period.count(kind)) for kind in LAYER_KINDS if kind in period}
@@ -430,7 +508,7 @@ class HybridMoETransformerLM(MoETransformerLM):
     def _attention_mixer(self, kind, p, h):
         cfg = self.config
         B, T, _ = h.shape
-        NH, NKV, D, Dv = cfg.num_heads, cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
+        NH, NKV, D, Dv = cfg.heads_of(kind), cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
         pos = jnp.arange(T, dtype=jnp.int32)
         q, k, v = attn_heads(cfg, kind, *attn_project(p, h), jnp.broadcast_to(pos, (B, T)))
         q = q.reshape(B, T, NKV, NH // NKV, D)
@@ -446,7 +524,7 @@ class HybridMoETransformerLM(MoETransformerLM):
         else:
             probs = jax.nn.softmax(scores, axis=-1)
         attn = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
-        return qmatmul(softmax_gate(p, h, attn.reshape(B, T, NH * Dv)), p["wo"])
+        return qmatmul(output_gate(p, h, attn.reshape(B, T, NH * Dv)), p["wo"])
 
     def _latent_mixer(self, p, h):
         """The published (expanded) form: every head's keys and values made from the latents."""
@@ -614,4 +692,43 @@ def glm4_moe_lite_config(size: str = "4.7-flash", **overrides) -> HybridMoEConfi
     base.update(presets[size])
     base.update(overrides)
     base.setdefault("layer_types", ["latent"] * base["num_layers"])
+    return HybridMoEConfig(**base)
+
+
+def laguna_config(size: str = "s-2.1", **overrides) -> HybridMoEConfig:
+    """Laguna-S-2.1 (``poolside/Laguna-S-2.1`` ``config.json``, ``model_type:
+    laguna``): 48 layers, layers 0, 4, 8, ... full causal GQA of 48 query heads,
+    the others 72 query heads over a sliding window of 512, both over 8 KV
+    heads of 128; a full layer rotates the leading 64 features with YaRN
+    frequencies (theta 5e5, factor 128 over 8,192 positions, cos and sin times
+    1.4852), a window layer all 128 with plain ones (theta 1e4); one sigmoid
+    gate a head on every layer's attention output; layer 0 a dense SwiGLU FFN
+    of 12,288, layers 1-47 256 SwiGLU experts of 1,024, 10 a token by softmax
+    scores, gates normalised and times 2.5, one shared expert. ``s-2.1`` is the
+    published model whole; ``tiny`` a toy of one chip's share (4 of 16 experts
+    held) with one leading dense layer and two periods for tests: groups of 6
+    and 9 query heads a KV head as published, and a YaRN ramp that its short
+    contexts reach."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=9, num_heads=12, window_num_heads=18, num_kv_heads=2, head_dim=16,
+                     rope_dim=8, window_rope_dim=16, rope_theta=100.0, rope_yarn_factor=8.0, rope_yarn_original_positions=32,
+                     rope_yarn_beta_fast=4.0, rope_yarn_beta_slow=0.5, window=8, vocab_size=512, max_seq_len=256,
+                     intermediate_size=96, expert_intermediate_size=32, num_experts=4, moe_router_experts=16,
+                     moe_expert_share=(0, 4), moe_top_k=3),
+        "s-2.1": dict(hidden_size=3072, num_layers=48, num_heads=48, window_num_heads=72, num_kv_heads=8, head_dim=128,
+                      rope_dim=64, window_rope_dim=128, rope_theta=5e5, rope_yarn_factor=128.0,
+                      rope_yarn_original_positions=8192, rope_yarn_beta_fast=32.0, rope_yarn_beta_slow=1.0,
+                      rope_yarn_attention_factor=1.4852030263919618, window=512, vocab_size=100352, max_seq_len=1048576,
+                      intermediate_size=12288, expert_intermediate_size=1024, num_experts=256, moe_top_k=10),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-6, position="rope", window_rope_theta=1e4, activation="swiglu", use_bias=False,
+        tie_embeddings=False, attn_head_gate=True, leading_dense_layers=1, moe_layer_freq=1, moe_drop_tokens=False,
+        moe_norm_topk_prob=True, moe_scoring="softmax", moe_select_bias=False, moe_shared_experts=1, moe_routed_scaling=2.5,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        # layer_types: full_attention at 0 and then every fourth
+        base["layer_types"] = ["softmax" if i % 4 == 0 else "window" for i in range(base["num_layers"])]
     return HybridMoEConfig(**base)

@@ -42,7 +42,9 @@ from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_p
 
 SPEC = json.loads((pathlib.Path(files.ROOT) / "BENCHMARK.json").read_text())
 CONFIGS = [c["name"] for c in SPEC["configs"]]
-ACCEPTED = ["gpt2-125m", "gpt2-xl", "mistral-7b-v0.3-l16", "olmoe-1b-7b-0125-l12", "solar-open2-250b-l4-ep8", "mimo-v2.5-l7-ep16"]
+ACCEPTED = ["gpt2-125m", "gpt2-xl", "mistral-7b-v0.3-l16", "olmoe-1b-7b-0125-l12", "solar-open2-250b-l4-ep8", "mimo-v2.5-l7-ep16",
+            "glm-4.7-flash-l16-ep8"]
+LATENT = ["glm-4.7-flash-l16-ep8"]  # of the accepted, the ones with latent layers: no other of them may call the latent kernel
 BF16, I32 = jnp.bfloat16, jnp.int32
 # a training configuration has no serving geometry of its own: the Mistral cells'
 DEFAULT_PAGED = {"page_size": 64, "max_slots": 16, "prefill_chunk": 128, "max_seq_len": 1024}
@@ -137,7 +139,7 @@ def test_attention_kernel_calls_are_what_the_benchmarks_readers_expect(v5e, monk
             sinks = ["f32"] if getattr(cfg, "window_sinks", False) and len(types) == 7 else []
             assert types == ["i32"] * 3 + ["bf16"] + sinks + ["bf16", "bf16"], (kernel, types)
         elif kernel == "latent_paged_attention":
-            assert latent_layers and name not in ACCEPTED, f"{name} has no latent layer and must not call {kernel}"
+            assert latent_layers and (name in LATENT or name not in ACCEPTED), f"{name} has no latent layer and must not call {kernel}"
             assert types == ["i32"] * 3 + ["bf16", "bf16"], types  # the row operand and ONE pool
         else:
             assert leading <= 1, f"{kernel} opens with {leading} s32 operands: the ragged kernel's readers would count it"
@@ -150,9 +152,10 @@ def test_attention_kernel_calls_are_what_the_benchmarks_readers_expect(v5e, monk
 
 
 def test_the_guard_knows_every_configuration():
-    """A configuration a later PR adds is lowered and held above too; the six
-    accepted before the latent kind are named, and none of them has one."""
-    assert set(ACCEPTED) <= set(CONFIGS)
+    """A configuration a later PR adds is lowered and held above too (PR 45's
+    ``laguna-s-2.1-l9-ep16`` came in through ``CONFIGS``, two cases); the seven
+    accepted before it are named, and of them the one with latent layers."""
+    assert set(LATENT) <= set(ACCEPTED) <= set(CONFIGS) and len(CONFIGS) > len(ACCEPTED)
     for name in ACCEPTED:
         conf = files.load_json(files.ROOT, next(c["file"] for c in SPEC["configs"] if c["name"] == name))
-        assert "latent" not in (conf["model"]["kwargs"].get("layer_types") or ())
+        assert ("latent" in (conf["model"]["kwargs"].get("layer_types") or ())) == (name in LATENT)

@@ -11,7 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, glm4_moe_lite_config, mimo_v2_config, moe_ffn, solar_open2_config
+from deepspeed_tpu.models.hybrid_moe import (
+    HybridMoETransformerLM, glm4_moe_lite_config, laguna_config, mimo_v2_config, moe_ffn, solar_open2_config,
+)
 from deepspeed_tpu.moe.routed_ffn import route, routed_ffn
 
 S, H, I, E, K = 48, 32, 24, 16, 4
@@ -216,3 +218,58 @@ def test_a_share_that_is_not_a_share_is_refused():
         solar_open2_config("tiny", num_experts=3, moe_router_experts=8, moe_expert_share=(0, 2))
     with pytest.raises(ValueError, match="layer_types"):
         solar_open2_config("tiny", layer_types=["softmax", "mamba", "linear", "linear"])  # a kind that does not exist
+
+
+def test_sixteen_shares_of_a_softmax_router_plus_the_shared_expert_once_are_the_uncut_model_layer():
+    """Laguna's FFN: top-5 of 32 by SOFTMAX scores over the router's whole
+    width (the tiny size's equivalent of top-10 of 256), no selection bias,
+    weights normalised over the five and times 2.5, one shared expert. Sixteen
+    configs that differ in ``moe_expert_share`` alone, each given its 2 of the
+    uncut layer's 32 experts: the routed parts (each share's output minus the
+    shared expert's) plus the shared expert counted ONCE are the uncut layer,
+    which is the brute-force sum with weights ``2.5 p_e / sum of the chosen p``
+    plus the shared expert; and the plain reference's router and loop over a
+    share's held experts give that share's part."""
+    from benchmark.files import load_module
+    from deepspeed_tpu.moe.experts import apply_dense_ffn
+
+    whole_cfg = laguna_config("tiny", num_experts=32, moe_router_experts=32, moe_expert_share=(0, 1), moe_top_k=5, dtype="float32")
+    assert (whole_cfg.moe_routed_scaling, whole_cfg.moe_shared_experts, whole_cfg.moe_scoring, whole_cfg.moe_select_bias) == (2.5, 1, "softmax", False)
+    lm = HybridMoETransformerLM(whole_cfg)
+    p = jax.tree_util.tree_map(lambda a: a[1, 2], jax.jit(lambda key: lm.init(key, None)["periods"]["moe"])(jax.random.PRNGKey(3)))
+    assert "bias" not in p["gate"]
+    p = {**p, "gate": {"wg": p["gate"]["wg"] * 6.0}}  # router logits of std ~1 (0.02 x 6 x sqrt(64)), as at the published width
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, whole_cfg.hidden_size))
+    whole, counts = moe_ffn(whole_cfg, p, h)
+    assert int(counts.sum()) == 2 * 24 * 5
+    shared = apply_dense_ffn(p["shared"], h, "swiglu")
+    tokens = h.reshape(-1, whole_cfg.hidden_size)
+    probs = jax.nn.softmax(tokens @ p["gate"]["wg"], axis=-1)
+    top, chosen = jax.lax.top_k(probs, 5)
+    weights = jnp.sum(jax.nn.one_hot(chosen, 32) * (2.5 * top / top.sum(-1, keepdims=True))[..., None], axis=-2)
+    assert float(weights.sum(-1).min()) == pytest.approx(2.5, rel=1e-5) and float(top.std()) > 0.01
+    assert float(jnp.abs(_brute(p["experts"], tokens, weights).reshape(h.shape) + shared - whole).max()) < TOL
+    # sigmoid scores, or weights that are not renormalised, are another layer by far more than the tolerance
+    other = _brute(p["experts"], tokens, jnp.sum(jax.nn.one_hot(chosen, 32) * (2.5 * top)[..., None], axis=-2)).reshape(h.shape)
+    assert float(jnp.abs(other + shared - whole).max()) > 0.2 * float(jnp.abs(whole).max()) > 100 * TOL
+    total = shared
+    for index in range(16):
+        cfg = dataclasses.replace(whole_cfg, num_experts=2, moe_expert_share=(index, 16))
+        mine = {**p, "experts": jax.tree_util.tree_map(lambda a: a[index * 2 : index * 2 + 2], p["experts"])}
+        out, held = moe_ffn(cfg, mine, h)
+        assert held.shape == (2,) and np.array_equal(held, counts[index * 2 : index * 2 + 2])
+        part = _brute(mine["experts"], tokens, weights[:, index * 2 : index * 2 + 2]).reshape(h.shape)
+        assert float(jnp.abs(out - shared - part).max()) < TOL
+        total = total + (out - shared)
+    assert float(jnp.abs(total - whole).max()) < TOL
+    # the reference's routed FFN for one share: its router over the whole width (the factor inside the weights,
+    # the shared expert beside them), its loop over the held
+    ref = load_module("reference", "laguna_decoder")
+    gate = {"mlp_norm_scale": jnp.ones((whole_cfg.hidden_size,)), "gate": p["gate"], "shared": p["shared"]}
+    hn, w, out = ref._router(tokens, gate, arch_key=(("experts_per_token", 5), ("norm_eps", 1e-6), ("routed_scaling", 2.5)))
+    mine = jax.tree_util.tree_map(lambda a: a[6:8], p["experts"])
+    for e in range(2):
+        out = ref._add_expert(out, hn, w[..., 6 + e], mine["w_gate"][e], mine["w_up"][e], mine["w_out"][e])
+    norm = tokens * jax.lax.rsqrt(jnp.mean(tokens * tokens, -1, keepdims=True) + 1e-6)
+    share3, _ = moe_ffn(dataclasses.replace(whole_cfg, num_experts=2, moe_expert_share=(3, 16)), {**p, "experts": mine}, norm.reshape(h.shape))
+    assert float(jnp.abs(out.reshape(h.shape) - share3).max()) < TOL

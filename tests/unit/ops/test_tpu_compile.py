@@ -207,6 +207,43 @@ def test_kernel_compiles_for_v5e(v5e, name):
         assert kinds == ["s32", "bf16", "bf16"], kinds
 
 
+# Laguna-S-2.1's two kinds of attention layer at the published shapes: 8 KV heads of 128 under 48 query heads (groups
+# of 6, every key, three layers of 4,097 pages) and under 72 (groups of 9, a window of 512 keys on six layers' rings of
+# 10 pages a slot); the narrow program's 64 rows of one token and a wide window's trip of 4 rows of 128
+LAGUNA_KERNEL = {
+    f"{kind}_w{width}": (heads, window, layers, pages, rows, width)
+    for kind, (heads, window, layers, pages) in {"full": (48, None, 3, 4097), "window": (72, 512, 6, 641)}.items()
+    for rows, width in ((64, 1), (4, 128))
+}
+
+
+@pytest.mark.parametrize("name", sorted(LAGUNA_KERNEL))
+def test_ragged_kernel_compiles_at_groups_of_six_and_nine_with_its_pools_in_place(v5e, name):
+    """No accepted model's group is anything but a power of two; the kernel
+    finds a query row's window slot by ``row // group`` on a vector and cuts
+    128 x 9 rows into tiles of 128 that end inside a group. Mosaic takes both:
+    the call compiles for a v5e, it is the live-pages kernel (three ``s32``
+    operands in front), and the donated pools come out in the buffers they went in."""
+    heads, window, layers, pages, rows, width = LAGUNA_KERNEL[name]
+
+    def call(q, k_new, v_new, k_pages, v_pages, table, kv_lens, q_lens):
+        return ragged_paged_attention(q, k_new, v_new, k_pages, v_pages, 1, table, kv_lens, q_lens, interpret=False, window=window)
+
+    shapes = (
+        [((rows, width, heads, 128), BF16)] + [((rows, width, 8, 128), BF16)] * 2 + [((layers, pages, 8, 64, 128), BF16)] * 2
+        + [((rows, 64), I32)] + [((rows,), I32)] * 2
+    )
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
+    text = jax.jit(call, donate_argnums=(3, 4)).lower(*args).compile().as_text()
+    assert {3, 4} <= parse_input_output_aliases(text)
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+)", text, flags=re.M))
+    (operands,) = re.findall(r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text)
+    kinds = [shape_of[o].split("[")[0] for o in re.findall(r"%([\w.-]+)", operands)]
+    assert kinds == ["s32"] * 3 + ["bf16"] * 3, kinds  # table, lengths, q lengths; the row operand, the two pools
+    made = [line for line in text.splitlines() if re.search(rf"= bf16\[({layers},{pages}|{layers * pages}),8,64,128\]", line)]
+    assert made and all(re.search(r" (parameter|bitcast|get-tuple-element|custom-call)\(", line) for line in made), made
+
+
 def test_flash_backward_is_what_the_benchmark_reads(v5e):
     """The compiled forward + backward of the 125M cell's attention: the row
     statistics stay lane-dense (no ``f32[96,1024,128]`` anywhere: they were

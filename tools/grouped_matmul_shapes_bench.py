@@ -1,4 +1,4 @@
-"""The grouped expert matmul alone, on the chip, at the four MoE serving
+"""The grouped expert matmul alone, on the chip, at the five MoE serving
 cells' shapes (``deepspeed_tpu/moe/grouped_matmul.py``; the benchmark's own
 ``benchmark/tools/grouped_matmul_bench.py`` holds OLMoE's shapes only): a
 layer's gate, up and down calls over the cell's expert stack, for a narrow
@@ -13,7 +13,7 @@ share of the least time the chip could take
 (``benchmark/kernels/grouped_expert_matmul.py::min_seconds``). It is how the
 kernel's blocks were chosen (PERF.md, PR 37).
 
-    chiprun -- python3 tools/grouped_matmul_shapes_bench.py [--shape solar,mimo,olmoe,glm]
+    chiprun -- python3 tools/grouped_matmul_shapes_bench.py [--shape solar,mimo,olmoe,glm,laguna,laguna_x16]
     python3 tools/grouped_matmul_shapes_bench.py --rehearse   # tiny, on the CPU: the control flow only
 """
 
@@ -58,6 +58,13 @@ SHAPES = {
     # 64 rows x top-4 = 256 assignments, 12.5% of them held, on all 8 held experts (98% by the binomial);
     # a 512-token tile's 2,048 assignment rows with ~190 live tokens
     "glm": Shape(2048, 1536, 8, 15, (256, 32, 8), (2048, 95, None)),
+    # Laguna-S-2.1 (PR 45): K 3,072, N 1,024 (gate and up are two calls; fused they would be 2,048), 16 held of 256;
+    # 64 rows x top-10 = 640 assignments, 6.25% of them held (40), on ~15 of the 16 held experts (92% by the binomial);
+    # a 512-token tile's 5,120 assignment rows with ~190 live tokens
+    "laguna": Shape(3072, 1024, 16, 8, (640, 40, 15), (5120, 119, None)),
+    # the same layer under the deployment's load: each of the sixteen chips receives the tokens of all, 640 held
+    # assignments a narrow step (40 an expert: still under the row tile of 128, so an expert's time is its bytes)
+    "laguna_x16": Shape(3072, 1024, 16, 8, (10240, 640, 16), (5120, 1900, None)),
 }
 TINY = {"tiny": Shape(256, 128, 5, 2, (16, 6, 3), (256, 40, None))}
 
@@ -136,7 +143,7 @@ def bench(shape: Shape, matmul, rows: int, sizes, calls: int, repeats: int):
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--shape", default="solar,mimo,olmoe,glm")
+    ap.add_argument("--shape", default="solar,mimo,olmoe,glm,laguna,laguna_x16")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     if args.rehearse:

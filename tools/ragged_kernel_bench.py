@@ -6,13 +6,21 @@ layer loop does, for three mixes of rows
 * ``decode16``: 16 decode rows holding 200-1,500 tokens (the decode-heavy cells),
 * ``chat4``: 2-4 live rows of 16 (the chat cell),
 * ``mixed``: a prefill chunk of the wide window among decode rows,
+* ``decode_long``: every row a decode row holding 600-4,096 tokens, ~1,650 in
+  the mean (what ``long_decode`` leaves in a 64-row cell),
+* ``chunk1``: one prefill chunk of the wide window and dead rows beside it
+  (a trip of ``hybrid_decode.wide_attention``: 4 rows of 128),
 
 and prints microseconds a call against the least the chip could take
 (``benchmark/kernels/ragged_paged_attention.py::min_seconds``), the seconds
 the program took to trace and to lower (what every process pays at set-up,
 whatever the compilation cache holds), and whether outputs and pools agree
 with XLA's scatter + gather. It is how the tile sizes at the head of the
-kernel were chosen.
+kernel were chosen. The ``laguna_*`` models are Laguna-S-2.1's two kinds of
+layer, groups of 6 (48 query heads over 8 KV heads, every key) and of 9 (72
+over 8, a window of 512 keys on a ring of 10 pages a row), at 64 decode rows
+and at a wide trip's 4 rows; a model with a window is measured against
+``benchmark/kernels/windowed_paged_attention.py::min_seconds``.
 
 The model ``glm47`` is the latent kernel's (``ops/transformer/latent_attention.py::
 latent_paged_attention``: 20 heads over one entry of 576 a token in pages of
@@ -44,13 +52,17 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CALLS = 100
-# (query heads, kv heads, head size, layers, rows, pages a row, page, wide window)
+# (query heads, kv heads, head size, layers, rows, pages a row, page, wide window, keys a query sees or None: all)
 MODELS = {
-    "mistral7b": (32, 8, 128, 16, 16, 38, 64, 128),
-    "olmoe": (16, 16, 128, 12, 16, 24, 64, 128),
-    "mistral7b_tp4": (8, 2, 128, 16, 8, 38, 64, 128),
+    "mistral7b": (32, 8, 128, 16, 16, 38, 64, 128, None),
+    "olmoe": (16, 16, 128, 12, 16, 24, 64, 128, None),
+    "mistral7b_tp4": (8, 2, 128, 16, 8, 38, 64, 128, None),
+    "laguna_full": (48, 8, 128, 3, 64, 64, 64, 128, None),
+    "laguna_window": (72, 8, 128, 6, 64, 64, 64, 128, 512),
+    "laguna_full_chunk": (48, 8, 128, 3, 4, 64, 64, 128, None),
+    "laguna_window_chunk": (72, 8, 128, 6, 4, 64, 64, 128, 512),
 }
-TINY = {"tiny": (4, 2, 128, 2, 4, 6, 8, 8)}
+TINY = {"tiny": (4, 2, 128, 2, 4, 6, 8, 8, None), "tiny_window": (18, 2, 128, 2, 4, 6, 8, 8, 8)}
 # (query heads, value lanes, rotated lanes, lanes a page stores, layers, rows, pages a row, page)
 LATENT = {"glm47": (20, 512, 64, 640, 16, 64, 64, 64)}
 LATENT_TINY = {"glm47": (20, 128, 32, 256, 2, 6, 12, 8)}
@@ -58,6 +70,8 @@ LATENT_TINY = {"glm47": (20, 128, 32, 256, 2, 6, 12, 8)}
 
 def mixes(rng, rows, maxp, page, wide):
     """``{mix: (window width, [(new tokens, keys after the step)] a row)}``."""
+    import numpy as np
+
     longest = maxp * page
 
     def decode(n):
@@ -67,10 +81,14 @@ def mixes(rng, rows, maxp, page, wide):
     few = [(0, 0)] * rows  # three live rows among dead ones
     for row, live in zip((1, rows // 2, rows - 2), decode(3)):
         few[row] = live
+    # contexts as a long_decode cell's window finds them: none shorter than a seventh of the longest, a long tail up to it
+    long = rng.permutation(longest * (0.146 + 0.854 * (1 - (1 - np.arange(rows) / max(1, rows - 1)) ** (1 / 2.33))))
     return {
         "decode16": (1, decode(rows)),
         "chat4": (1, few),
         "mixed": (wide, [chunk] + decode(rows - 3) + [(0, 0)] * 2),
+        "decode_long": (1, [(1, max(1, int(kv))) for kv in long]),
+        "chunk1": (wide, [chunk] + [(0, 0)] * (rows - 1)),
     }
 
 
@@ -171,7 +189,7 @@ def latent_bench(args, model, dims, calls, peak):
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--models", default="mistral7b,olmoe")
-    ap.add_argument("--mixes", default="decode16,chat4,mixed,decode64_long,decode64_short")
+    ap.add_argument("--mixes", default="decode16,chat4,mixed,decode64_long,decode64_short,decode_long,chunk1")
     ap.add_argument("--root", default=ROOT, help="the checkout whose deepspeed_tpu is measured")
     ap.add_argument("--pages-per-buffer", type=int, default=None)
     ap.add_argument(
@@ -190,6 +208,8 @@ def main() -> None:
 
     from benchmark import files
     from benchmark.kernels import ragged_paged_attention as k
+    from benchmark.kernels import windowed_paged_attention as kw
+    from deepspeed_tpu.ops.transformer import decode_attention
     from deepspeed_tpu.ops.transformer.decode_attention import ragged_paged_attention
     from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention as front
 
@@ -200,12 +220,17 @@ def main() -> None:
     peak = files.load_json(files.HERE, "peaks.json")["TPU v5 lite" if args.rehearse else jax.devices()[0].device_kind]
     for model in latent:
         latent_bench(args, model, (LATENT_TINY if args.rehearse else LATENT)[model], calls, peak)
-    for model, (NH, NKV, D, L, R, maxp, P, wide) in models.items():
-        NP = R * maxp + 1
+    for model, (NH, NKV, D, L, R, maxp, P, wide, window) in models.items():
+        # a row's pages: its own all the way, or with a window a ring of those a chunk writes and the window's before it
+        held = maxp if window is None else -(-wide // P) + -(-(window - 1) // P)
+        NP = R * held + 1
         rng = np.random.default_rng(0)
         key = jax.random.PRNGKey(0)
         pools = [jax.random.normal(jax.random.fold_in(key, i), (L, NP, NKV, P, D), jnp.bfloat16) for i in (1, 2)]
-        table = jnp.asarray(1 + rng.permutation(R * maxp).reshape(R, maxp), jnp.int32)  # scattered, as a pool ages
+        if window is None:
+            table = jnp.asarray(1 + rng.permutation(R * maxp).reshape(R, maxp), jnp.int32)  # scattered, as a pool ages
+        else:
+            table = jnp.asarray(1 + np.arange(R)[:, None] * held + np.arange(maxp)[None, :] % held, jnp.int32)
         for mix, (W, rows) in mixes(rng, R, maxp, P, wide).items():
             if mix not in args.mixes.split(","):
                 continue
@@ -222,7 +247,7 @@ def main() -> None:
                     acc, kp, vp = carry
                     o, kp, vp = ragged_paged_attention(
                         q, k_new, v_new, kp, vp, i % L, table, kv_lens, q_lens,
-                        interpret=args.rehearse, pages_per_buffer=args.pages_per_buffer,
+                        interpret=args.rehearse, pages_per_buffer=args.pages_per_buffer, window=window,
                     )
                     return acc + o.astype(jnp.float32), kp, vp
 
@@ -237,21 +262,24 @@ def main() -> None:
             program = lowered.compile()
             t3 = time.perf_counter()
             # what XLA's write + gather makes of one call, before the pools are donated
-            want_o, want_k, want_v = jax.jit(front, static_argnames=("impl",))(
-                q, k_new, v_new, *pools, 0, table, kv_lens, q_lens, impl="xla"
+            want_o, want_k, want_v = jax.jit(front, static_argnames=("impl", "window"))(
+                q, k_new, v_new, *pools, 0, table, kv_lens, q_lens, impl="xla", window=window
             )
             got_o, got_k, got_v = jax.jit(
-                lambda *a: ragged_paged_attention(*a, interpret=args.rehearse, pages_per_buffer=args.pages_per_buffer)
+                lambda *a: ragged_paged_attention(*a, interpret=args.rehearse, pages_per_buffer=args.pages_per_buffer, window=window)
             )(q, k_new, v_new, *pools, 0, table, kv_lens, q_lens)
             live = (jnp.arange(W)[None, :] < q_lens[:, None])[:, :, None, None]
             gap = float(jnp.max(jnp.abs(jnp.where(live, got_o.astype(jnp.float32) - want_o.astype(jnp.float32), 0))))
             same_pools = all(bool(jnp.array_equal(g[:, 1:], w[:, 1:])) for g, w in ((got_k, want_k), (got_v, want_v)))
             del want_k, want_v, got_k, got_v
             best, (_, _, _, *pools, _, _, _) = timed(program, [q, k_new, v_new, *pools, table, kv_lens, q_lens], (3, 4), args.rehearse)
-            floor, bound = k.min_seconds(rows, NH, NKV, D, peak)
-            pages = sum(-(-kv // P) for n, kv in rows if n)
+            floor, bound = k.min_seconds(rows, NH, NKV, D, peak) if window is None else kw.min_seconds(rows, NH, NKV, D, D, peak, window)
+            pages = sum(-(-kw.keys_read(n, kv, window) // P) for n, kv in rows if n)
+            walked = maxp if window is None else min(maxp, -(-(window - 1) // P) + -(-W // P) + 1)  # as the kernel's wrapper reckons it
+            C, CK, TQ, HB = decode_attention._ragged_tiles(NKV, NH // NKV, W, P, D, walked, 2, args.pages_per_buffer)
+            form = f"group {NH // NKV}{'' if window is None else f' window {window}'}: halves of {C} pages, key tiles of {CK * P}, query tiles of {TQ} rows, {HB} kv heads a tile"
             print(
-                f"{model:14s} {mix:9s} W={W:<4d} live rows {sum(1 for n, _ in rows if n):2d} pages {pages:4d}/{R * maxp}: "
+                f"{model:19s} {mix:11s} W={W:<4d} live rows {sum(1 for n, _ in rows if n):2d} pages {pages:4d}/{R * held}: "
                 f"{best / calls * 1e6:8.1f} us a call, floor {floor * 1e6:6.1f} us ({bound}), "
                 f"{100 * floor / (best / calls):5.1f}% | {form} | trace {t1 - t0:.3f} s lower {t2 - t1:.3f} s compile {t3 - t2:.2f} s | "
                 f"max |o - xla| {gap:.4f} pools {'same' if same_pools else 'DIFFER'}",
