@@ -914,7 +914,9 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
     The per-layer weights are indexed out of their stacks INSIDE the tile
     bodies (the layer scan carries an index, not slices): a slice made in
     the scan's body would be copied to be handed to the inner loop, a layer's
-    weights read and written once more a layer.
+    weights read and written once more a layer. In both forms a barrier
+    stands between the projections' matmuls and the head split
+    (``project``), so each matmul reads its matrix where the stack lies.
     Returns ``(x, new_k, new_v, moe_counts, packed)``: ``x`` is the slab
     ``[B, T, H]`` and ``packed`` None, or ``x`` is the packed ``[NP, H]`` and
     ``packed`` says where its rows belong."""
@@ -931,13 +933,12 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
             x = x + params["embed"]["pos"].astype(dtype)[positions]
         return x
 
-    def project(p, x, positions, keep_apart=False):
-        q, k_new, v_new = _project_qkv(cfg, p, x)
-        if keep_apart:
-            # or the compiler folds the head split into the matmul, wants the
-            # weights transposed for it, and copies their whole STACK to that
-            # layout once a layer on its way into the tile loop
-            q, k_new, v_new = jax.lax.optimization_barrier((q, k_new, v_new))
+    def project(p, x, positions):
+        # the head split kept apart from the matmul, or the compiler folds it
+        # in, wants the weights with the contracted dimension minor for it,
+        # and copies them to that layout: a layer's once a layer in the slab
+        # program, their whole STACK on the way into the tile loop
+        q, k_new, v_new = jax.lax.optimization_barrier(_project_qkv(cfg, p, x))
         q, k_new, v_new = _split_heads(cfg, q, k_new, v_new)
         if cfg.position == "rope":
             q = _rope(q, positions, cfg.rope_theta, cfg.rope_dim)
@@ -990,7 +991,7 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
 
         def before(start, qkv):
             q, k_new, v_new = project(
-                weights(start), packed.take(x, start)[None], packed.take(positions, start)[None], keep_apart=True
+                weights(start), packed.take(x, start)[None], packed.take(positions, start)[None]
             )
             return tuple(
                 jax.lax.dynamic_update_slice_in_dim(buf, new[0].astype(dtype).reshape(packed.tile, -1), start, axis=0)

@@ -43,8 +43,11 @@ parameter of the program) and one more row array:
 
 The leading dense layers, then one ``lax.scan`` over PERIODS run the layers;
 the scan's body holds the period's layers in order and reaches each layer's
-weights, pages and state through an index, so the donated buffers are the
-ones returned. Token-wise work
+pages and state through an index, so the donated buffers are the ones
+returned, and each layer's weights by one slice a leaf of the stacks the
+parameters arrived in (``layer_of``), with the head split kept behind a
+barrier, so a projection reads its matrix where it lies: no period's slice
+and no other layout of it is written out. Token-wise work
 (norms, projections, gates, the FFN) runs over the packed live tokens in
 tiles, as in ``decode._paged_layers``; a window of at most one tile is one
 "tile" of its whole slab, through the same code. Only the mixers see rows:
@@ -112,6 +115,19 @@ def window_shapes(cfg, max_slots: int, page_size: int, ring: int):
     return k, k[:-1] + (cfg.v_head_dim,)
 
 
+def layer_of(tree, per, j: int):
+    """Layer ``j`` of period ``per`` (a traced index) of a kind's stacks
+    ``[periods, count, ...]``: ONE slice a leaf, straight out of the stack
+    the parameters arrived in, which fuses into the matmul that reads it. A
+    period's slice with a layer's behind it (``a[per][j]``) is shared by the
+    period's ``count`` layers, fuses into none of them and is written out
+    whole: three window layers' ``wq`` and ``wo``, 170 MB each, every trip of
+    Laguna's scan, five ``[12288, 4096]`` in MiMo (PERF.md section 6, PR 46)."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_slice(a, (per, j) + (0,) * (a.ndim - 2), (1, 1) + a.shape[2:]).reshape(a.shape[2:]), tree
+    )
+
+
 def _whole_slab(q_lens, B: int, T: int) -> decode._Packed:
     """The slab as its own packing: one tile, every slot where it is."""
     i = jnp.arange(B * T, dtype=jnp.int32)
@@ -168,14 +184,13 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         ring_table = jnp.where((q_lens > 0)[:, None], own, -1)
 
     def weights_at(tree, per, j, start):
-        """Layer ``j`` of period ``per`` out of its stacks (``per`` None: a
-        leading layer's own leaves), tied to the tile (or the compiler hoists
-        the slices out of the tile loop and copies them)."""
+        """Layer ``j`` of period ``per`` out of its stacks, one slice a leaf
+        (``layer_of``; ``per`` None: a leading layer's own leaves), tied to
+        the tile (or the compiler hoists the slices out of the tile loop and
+        copies them)."""
         if tiled:
             tree, _ = jax.lax.optimization_barrier((tree, start))
-        if per is None:
-            return tree
-        return jax.tree_util.tree_map(lambda a: jax.lax.dynamic_index_in_dim(a, per, keepdims=False)[j], tree)
+        return tree if per is None else layer_of(tree, per, j)
 
     def put(buf, new, start):
         return jax.lax.dynamic_update_slice_in_dim(buf, new.astype(buf.dtype), start, axis=0)
@@ -236,9 +251,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         def before(start, qkv):
             p = weights_at(tree, per, jk, start)
             h = _norm(packed.take(x, start), p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-            new = hm.attn_project(p, h)
-            if tiled:
-                new = jax.lax.optimization_barrier(new)  # decode._paged_layers.project: keep the head split apart
+            new = jax.lax.optimization_barrier(hm.attn_project(p, h))  # decode._paged_layers.project: keep the head split apart
             if rotary_or_scaled:
                 at = None if positions is None else packed.take(positions, start)[None]
                 new = tuple(a.reshape(a.shape[1], -1) for a in hm.attn_heads(cfg, kind, *(a[None] for a in new), at))

@@ -178,6 +178,28 @@ def test_a_leading_dense_layer_and_two_periods_are_the_unrolled_stack(toy):
     assert np.abs(np.asarray(jax.jit(lm.apply)(params, tokens)) - np.asarray(REFERENCE.logits(section, params, tokens))).max() < F32_TOL
 
 
+@pytest.mark.parametrize("kind", ["softmax", "window", "moe"])
+def test_a_layers_weights_are_one_slice_of_the_stack_at_its_period_and_place(toy, kind):
+    """``hybrid_decode.layer_of`` (what the served scan's body reaches a
+    layer's weights by: ``per`` a traced index, ``j`` the layer's place among
+    its kind in the period) against ``stacks[kind][leaf][per, j]``, every leaf
+    at every ``(per, j)`` of two periods of three window layers, one full
+    layer and four routed FFNs."""
+    cfg, _, params, _, _ = toy
+    stacks = {k: v for k, v in params["periods"][kind].items() if k != "experts"}
+    count = len(cfg.period) if kind == "moe" else cfg.period.count(kind)
+    assert (cfg.num_periods, count) == (2, {"softmax": 1, "window": 3, "moe": 4}[kind])
+    for leaf in jax.tree_util.tree_leaves(stacks):
+        assert leaf.shape[:2] == (2, count)
+    for j in range(count):
+        at = jax.jit(lambda per: hybrid_decode.layer_of(stacks, per, j))
+        for per in range(cfg.num_periods):
+            got, want = at(jnp.int32(per)), jax.tree_util.tree_map(lambda a: a[per, j], stacks)
+            assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+            for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+                assert g.shape == w.shape and np.array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_each_kinds_rotary_term_and_head_count_is_its_own():
     """``attn_heads`` on one token's projections at position 40: what a config
     with one piece wrong gives differs from the right one's in the kind it

@@ -272,6 +272,66 @@ def test_flash_backward_is_what_the_benchmark_reads(v5e):
 
 _MISTRAL_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mistral-7b-v0.3-l16.json"
 _PLUMBING = {"parameter", "tuple", "get-tuple-element", "while", "bitcast"}  # no bytes move
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.$-]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.$-]+) = (.+?) ([a-z][a-z0-9-]*)\((.*)$")
+
+
+def _executed(text):
+    """``(name, opcode, result as written)`` of every instruction of a
+    compiled program's text that runs as itself: the entry's and the loop
+    bodies'. What stands inside a fusion is part of it and writes nothing out.
+    (``analysis.hlo.parse_computations`` does not read a tiled layout,
+    ``{1,0:T(8,128)(2,1)}``, and drops such a line: most of a TPU program.)"""
+    computations, body = {}, None
+    for line in text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header:
+            body = computations.setdefault(header.group(1), [])
+        elif body is not None and (m := _INSTRUCTION.match(line)):
+            body.append(m.groups())
+    fused = {
+        callee for body in computations.values() for _, _, opcode, rest in body if opcode == "fusion"
+        for callee in re.findall(r"calls=%([\w.$-]+)", rest)
+    }
+    return [
+        (name, opcode, result) for computation, body in computations.items() if computation not in fused
+        for name, result, opcode, _ in body
+    ]
+
+
+def _mixer_matrices_written_out(text, matrices, but=()):
+    """The instructions that WRITE a mixer's matrix: a result whose two minor
+    dimensions are one of ``matrices`` (a layer's, a period's ``[1, count,
+    ...]`` or the stack's, in any layout), outside parameters, plumbing,
+    kernels and XLA's own asynchronous prefetches into fast memory
+    (``slice-start`` / ``copy-start`` and their ``-done``, which keep the
+    layout). A projection reads its matrix where the parameters lie, by a
+    slice fused into the matmul; a materialised period slice or a copy to the
+    layout a folded head split asks for shows here (PERF.md, PR 46: 170 MB
+    three times a trip of Laguna's scan, 101 MB seven times a step in MiMo, a
+    layer's wq / wk / wv once a layer in Mistral). ``but``: whole shapes that
+    are something else in this program (its packed activations)."""
+    found = {}
+    for name, opcode, result in _executed(text):
+        if opcode in _PLUMBING or opcode == "custom-call" or opcode.endswith(("-start", "-done")):
+            continue
+        for dims in re.findall(r"bf16\[([\d,]+)\]", result):
+            shape = tuple(int(d) for d in dims.split(","))
+            if shape[-2:] in matrices and shape not in but:
+                found[name] = f"{opcode} {result}"
+    return found
+
+
+def _attention_matrices(cfg, kinds=(None,)):
+    """``[H, NH D]``, ``[H, NKV D]``, ``[H, NKV Dv]`` and ``[NH Dv, H]`` of a
+    config's attention layers, a uniform model's or each of ``kinds``'s, and
+    each the other way round (a re-laid matrix may be written as either)."""
+    H, D, Dv = cfg.hidden_size, cfg.head_dim, getattr(cfg, "v_head_dim", None) or cfg.head_dim
+    out = set()
+    for kind in kinds:
+        NH, NKV = (cfg.num_heads, cfg.num_kv_heads) if kind is None else (cfg.heads_of(kind), cfg.kv_heads_of(kind))
+        out |= {(H, NH * D), (H, NKV * D), (H, NKV * Dv), (NH * Dv, H)}
+    return out | {(b, a) for a, b in out}
 
 
 def _compiled_mistral_step(v5e, monkeypatch, width):
@@ -339,6 +399,8 @@ def test_ragged_step_keeps_the_pool_in_one_buffer(v5e, monkeypatch, width):
     assert not strangers, f"pool-shaped results outside the kernel: {strangers}"
     pool_bytes = int(np.prod(pool.shape)) * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+    # nor is a layer's wq, wk, wv or wo written out on its way to its matmul (once a layer in the narrow program, before PR 46)
+    assert not _mixer_matrices_written_out(text, _attention_matrices(cfg))
 
 
 def test_mixed_step_computes_token_tiles_not_the_slab(v5e, monkeypatch):
@@ -542,6 +604,13 @@ def test_mimo_v2_ragged_step_fits_and_every_attention_layer_walks_live_pages(v5e
     # the 64 x 128 window is never laid out: no operand or result of its size (64 x 128 x 64 heads x 256 lanes)
     assert not re.search(r"bf16\[64,128,(12288|16384)\]|bf16\[64,[48],128,(8|16),256\]", text)
     assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
+    # every projection reads its matrix where the parameters lie: no period's slice (five window layers' [12288, 4096],
+    # 101 MB seven times a narrow step before PR 46) and no copy to another layout is written out
+    # (the wide program's packed tokens, 64 x 128 of 4096, happen to be as many as wo's rows, and a tile of them as wv's columns)
+    activations = {(rows * width, cfg.hidden_size), (decode.token_tile(cfg), cfg.hidden_size)}
+    assert not _mixer_matrices_written_out(text, _attention_matrices(cfg, ("softmax", "window")), but=activations)
+    if width == 1:
+        assert memory.temp_size_in_bytes < 0.05e9  # 0.411 GB before
 
 
 _GLM_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/glm-4.7-flash-l16-ep8.json"
@@ -602,3 +671,54 @@ def test_glm47_flash_ragged_step_fits_and_keeps_one_latent_pool_in_place(v5e, mo
     # no copy of the pool, and the 64 x 128 window of 20 heads x 640 lanes is never laid out
     assert not re.search(r"= bf16\[16,4097,64,640\]\S* (copy|fusion)\(", text)
     assert not re.search(r"bf16\[64,128,(12800|20,640)\]|bf16\[64,2560,640\]", text)
+
+
+_LAGUNA_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/laguna-s-2.1-l9-ep16.json"
+
+
+def test_laguna_narrow_step_reads_its_mixers_matrices_where_they_lie(v5e, monkeypatch):
+    """``build_ragged_step`` at the Laguna-S-2.1 cell's shapes, the narrow
+    program (a leading dense layer and TWO periods of a full layer of 48
+    query heads and three window layers of 72 over 8 KV heads, 64 rows, 4,097
+    pages of 64, rings of 10 pages a slot): the scan over periods reaches a
+    layer's weights by ONE slice a leaf (``hybrid_decode.layer_of``) and
+    keeps the head split behind a barrier, so no period's slice of ``wq`` /
+    ``wo`` (``[1, 3, 3072, 9216]``, 170 MB) and no copy of one to another
+    layout is written out, and the program's temporaries are the
+    activations' (0.346 GB before PR 46: a fifth of the cell's device time)."""
+    from deepspeed_tpu.inference import hybrid_decode
+    from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
+    from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
+
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul"):
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = json.loads(_LAGUNA_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = HybridMoEConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    ring = window_ring_pages(cfg.window, page, paged["prefill_chunk"])
+    assert (cfg.num_periods, cfg.period.count("window"), cfg.heads_of("window"), ring) == (2, 3, 72, 10)
+
+    def on_v5e(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape), params)
+    k_pool = on_v5e((cfg.layers_of("softmax"), rows * maxp + 1, cfg.num_kv_heads, page, key_lanes(cfg.head_dim)))
+    shapes = hybrid_decode.state_shapes(cfg, rows)
+    rings = (on_v5e(shape) for shape in hybrid_decode.window_shapes(cfg, rows, page, ring))
+    store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv), *rings)
+    step = decode.build_ragged_step(cfg, rows, 1, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, 1), I32), k_pool, k_pool, store,
+        on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+    matrices = _attention_matrices(cfg, ("softmax", "window"))
+    assert (3072, 9216) in matrices and (9216, 3072) in matrices
+    assert any(opcode == "while" for _, opcode, _ in _executed(text))  # the scan over periods is a loop: its body is read
+    assert not _mixer_matrices_written_out(text, matrices)
+    memory = compiled.memory_analysis()
+    print(f"laguna w1: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert memory.temp_size_in_bytes < 0.05e9
