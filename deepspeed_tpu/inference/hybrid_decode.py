@@ -128,6 +128,33 @@ def layer_of(tree, per, j: int):
     )
 
 
+def unfilled(shape, dtype) -> jax.Array:
+    """A layer's token buffer ``[packed tokens, lanes]`` as the allocator hands
+    it over: nothing fills it (on the TPU; XLA's CPU lowering writes zeros).
+    The layer's tile loop writes the live tiles whole, and a chunk row's
+    outputs go to the row's own slots; EVERY OTHER ROW HOLDS WHATEVER WAS
+    THERE, NaN and Inf included, so a read of such a buffer is a slice of a
+    live tile or a gather behind a select on the token being real
+    (``_hybrid_layers``: ``real_rows``, ``rows_output``), never a product
+    with a mask. Zeros cost a wide window 64 x 128 slots x 10,240-24,576
+    lanes a layer for the ~200 tokens it holds: 7 ms of GLM-4.7-Flash's
+    35.7 ms mixed step (PERF.md section 6, PR 47)."""
+    return _token_major(jax.lax.empty(shape, dtype))
+
+
+def _token_major(buf):
+    """``buf`` with a token's lanes together in memory, the layout its
+    gathers of rows read. A zero fill happened to pin it; left to the
+    compiler an allocation takes the layout of whatever tile is written
+    into it, lanes-major for GLM's q and for Laguna's gated output, and the
+    whole ``[8192, lanes]`` buffer is then COPIED to this one a layer, which
+    costs more than the fill did (the ``_w128`` texts compiled for a v5e:
+    ``tests/unit/ops/test_tpu_compile.py::_slab_sized_fills``)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(buf, Layout(major_to_minor=tuple(range(buf.ndim))))
+
+
 def _whole_slab(q_lens, B: int, T: int) -> decode._Packed:
     """The slab as its own packing: one tile, every slot where it is."""
     i = jnp.arange(B * T, dtype=jnp.int32)
@@ -137,7 +164,22 @@ def _whole_slab(q_lens, B: int, T: int) -> decode._Packed:
 
 def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, lengths, q_lens, slots, attn_impl):
     """Embedding and layers. Returns ``(x [NP, H] packed, k_pages, v_pages,
-    the store, moe_counts [routed layers, E], packed)``."""
+    the store, moe_counts [routed layers, E], packed)``.
+
+    A layer's token buffers (q, k, v or the latent entry, the linear layers'
+    inputs, the chunk rows' outputs) are ``[NPK, lanes]`` with ``NPK`` the
+    window's slots in whole tiles, 8,192 for 64 x 128, since a step MAY
+    carry 64 chunks; a steady mixed step holds ~200 tokens. They are
+    ``unfilled``. What a row of one may hold: a live tile's rows, the dead
+    ones among them too, hold what the tile loop computed (finite); a row
+    past the live tiles, and in the output buffer every row but a chunk
+    row's real tokens, holds whatever the memory held. Who reads them:
+    the tile loops, a live tile at a time; ``real_rows``, for the kernels'
+    operands, and ``rows_output``, for the tile that follows the mixer,
+    both behind selects; the convolution tail, behind its own. Nothing else
+    may: not a reduction over a buffer, not a product with a mask. The
+    narrow program (``T == 1``) writes every row of its 64 and reads them
+    as it always did."""
     from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
 
     B, T = tokens.shape
@@ -193,7 +235,37 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         return tree if per is None else layer_of(tree, per, j)
 
     def put(buf, new, start):
-        return jax.lax.dynamic_update_slice_in_dim(buf, new.astype(buf.dtype), start, axis=0)
+        return _token_major(jax.lax.dynamic_update_slice_in_dim(buf, new.astype(buf.dtype), start, axis=0))
+
+    def real_rows(buf, at, real):
+        """Packed tokens ``at`` of a layer's buffer for a kernel: a token that
+        is not ``real`` (a dead row's, a slot past a chunk's tokens: any row
+        of the buffer, written or not) is zeros, by a select."""
+        return jnp.where(real.reshape(real.shape + (1,) * (buf.ndim - 1)), jnp.take(buf, at, axis=0, mode="clip"), 0)
+
+    def put_chunk(buf, new, start, real):
+        """A chunk row's outputs ``new`` ``[T, lanes]`` at its tokens' place in
+        a packed buffer: they lie together from ``start`` on (at most
+        ``NPK - T``: the rows before it hold at most ``T`` each); the slots
+        past its ``real`` tokens are the next rows' and keep what they hold.
+        One slice read and written where a scatter goes row by row (0.28 ms
+        for a trip's 512 rows of 10,240 lanes: PERF.md section 6, PR 47)."""
+        old = jax.lax.dynamic_slice_in_dim(buf, start, T, axis=0)
+        return put(buf, jnp.where(real[:, None], new.astype(buf.dtype), old), start)
+
+    def slab_rows(out, start):
+        """A tile's mixer outputs in the narrow program, whose ``out`` is in slab order (which a whole slab's packing is)."""
+        return jnp.take(out, packed.take(packed.slot, start), axis=0, mode="clip")
+
+    def rows_output(chunks, ones, start):
+        """A tile's mixer outputs in a wide window: a chunk row's token's out
+        of ``chunks`` (packed, written by ``put_chunk``), a one-token row's
+        out of ``ones`` ``[B, lanes]``, zeros for a dead slot: a gather of a
+        tile's rows where the 64 rows of ``ones`` used to be scattered into
+        the slab-sized buffer, a layer."""
+        row = packed.take(packed.slot, start) // T
+        out = jnp.where(chunk_rows[row][:, None], packed.take(chunks, start), jnp.take(ones, row, axis=0))
+        return jnp.where(packed.take(packed.live, start)[:, None], out, 0)
 
     def ffn(x_tile, start, per, j):
         p = weights_at(moe_stacks, per, j, start)
@@ -217,11 +289,14 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         XLA gathers, transposes and concatenates for the kernel, and the
         kernel gives each one-token row a whole query tile: 7 ms a layer of a
         64 x 128 window with one chunk in it (PERF.md section 6, PR 34).
-        ``operands`` packed ``[NPK, ...]``, each a token's ``shapes`` entry in
-        a window; ``attend(*windows, *pools, layer, table, kv_lens, q_lens)``
-        gives ``(out, *pools)``. Returns the output packed ``[NPK, width]``
-        (dead slots: zeros) and the pools."""
-        first = tuple(jnp.take(a, starts, axis=0, mode="clip").reshape((B, 1) + shape) for a, shape in zip(operands, shapes))
+        ``operands`` packed ``[NPK, ...]`` (``unfilled`` past the live tiles:
+        read through ``real_rows``), each a token's ``shapes`` entry in a
+        window; ``attend(*windows, *pools, layer, table, kv_lens, q_lens)``
+        gives ``(out, *pools)``. Returns the pools behind ``out(start)``, a
+        tile's outputs ``[tile, width]`` (``rows_output`` of the chunk rows'
+        packed ``[NPK, width]``, ``unfilled`` but for their real tokens, and
+        the one-token rows' ``[B, width]``)."""
+        first = tuple(real_rows(a, starts, one_token).reshape((B, 1) + shape) for a, shape in zip(operands, shapes))
         o1, *pools = attend(*first, *pools, layer, table, jnp.where(one_token, kv_lens, 0), one_token.astype(jnp.int32))
 
         def trip(i, carry):
@@ -230,16 +305,18 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             rows = order[jnp.minimum(at, B - 1)]
             lens = jnp.where(at < n_chunk_rows, q_lens[rows], 0)  # past the last chunk row: a dead row
             index = packed.index[rows]  # [CHUNK_ROWS, T]: a row's tokens lie together
-            window = tuple(jnp.take(a, index, axis=0, mode="clip").reshape((CHUNK_ROWS, T) + shape) for a, shape in zip(operands, shapes))
-            o, *pools = attend(*window, *pools, layer, table[rows], jnp.where(lens > 0, kv_lens[rows], 0), lens)
             real = jnp.arange(T, dtype=jnp.int32)[None, :] < lens[:, None]
-            attn = attn.at[jnp.where(real, index, NPK).reshape(-1)].set(o.reshape(CHUNK_ROWS * T, width), mode="drop")
+            window = tuple(real_rows(a, index, real).reshape((CHUNK_ROWS, T) + shape) for a, shape in zip(operands, shapes))
+            o, *pools = attend(*window, *pools, layer, table[rows], jnp.where(lens > 0, kv_lens[rows], 0), lens)
+            o = o.reshape(CHUNK_ROWS, T, width)
+            for c in range(CHUNK_ROWS):
+                attn = put_chunk(attn, o[c], index[c, 0], real[c])
             return (attn, *pools)
 
         attn, *pools = jax.lax.fori_loop(
-            0, (n_chunk_rows + CHUNK_ROWS - 1) // CHUNK_ROWS, trip, (jnp.zeros((NPK, width), dtype), *pools)
+            0, (n_chunk_rows + CHUNK_ROWS - 1) // CHUNK_ROWS, trip, (unfilled((NPK, width), dtype), *pools)
         )
-        return (attn.at[jnp.where(one_token, starts, NPK)].set(o1.reshape(B, width), mode="drop"), *pools)
+        return (functools.partial(rows_output, attn, o1.reshape(B, width)), *pools)
 
     def attention_layer(kind, x, kp, vp, tree, per, jk, layer, ffn):
         """A softmax or a window layer: ``tree`` its kind's stacks (its own
@@ -258,7 +335,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             return tuple(put(buf, a, start) for buf, a in zip(qkv, new))
 
         with jax.named_scope(hm.SCOPES[kind]):
-            qkv = tiles(before, tuple(jnp.zeros((NPK, nh * d), dtype) for nh, d in ((NH, D), (NKV, D), (NKV, Dv))))
+            qkv = tiles(before, tuple(unfilled((NPK, nh * d), dtype) for nh, d in ((NH, D), (NKV, D), (NKV, Dv))))
             extras = {}
             if kind == "window":
                 extras["window"] = cfg.window
@@ -271,7 +348,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
                     *(packed.expand(a).reshape(B, T, nh, -1) for a, nh in zip(qkv, (NH, NKV, NKV))),
                     kp, vp, layer, table, kv_lens, q_lens,
                 )
-                attn = attn.reshape(B * T, NH * Dv)
+                attn = functools.partial(slab_rows, attn.reshape(B * T, NH * Dv))
             else:
                 attn, kp, vp = wide_attention(attend, qkv, ((NH, D), (NKV, D), (NKV, Dv)), (kp, vp), layer, table, NH * Dv)
 
@@ -281,9 +358,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             with jax.named_scope(hm.SCOPES[kind]):
                 p = weights_at(tree, per, jk, start)
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-                # the narrow program's is in slab order (which a whole slab's packing is), the wide one's packed
-                a = jnp.take(attn, packed.take(packed.slot, start), axis=0, mode="clip") if T == 1 else packed.take(attn, start)
-                x_tile = x_tile + qmatmul(hm.output_gate(p, h, a), p["wo"]).astype(x.dtype)
+                x_tile = x_tile + qmatmul(hm.output_gate(p, h, attn(start)), p["wo"]).astype(x.dtype)
             x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 
@@ -312,14 +387,14 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             return put(bufs[0], q.reshape(q.shape[0], NH * Dl), start), put(bufs[1], entry[0], start)
 
         with jax.named_scope(hm.SCOPES["latent"]):
-            q, entries = tiles(before, (jnp.zeros((NPK, NH * Dl), dtype), jnp.zeros((NPK, Dl), dtype)))
+            q, entries = tiles(before, (unfilled((NPK, NH * Dl), dtype), unfilled((NPK, Dl), dtype)))
             attend = functools.partial(latent_paged_attention, value_lanes=C, scale=scale, impl=attn_impl)
             if T == 1:
                 o, pages = attend(
                     packed.expand(q).reshape(B, T, NH, Dl), packed.expand(entries).reshape(B, T, Dl),
                     pages, layer, page_table, kv_lens, q_lens,
                 )
-                o = o.reshape(B * T, NH * C)
+                o = functools.partial(slab_rows, o.reshape(B * T, NH * C))
             else:
                 o, pages = wide_attention(attend, (q, entries), ((NH, Dl), (Dl,)), (pages,), layer, page_table, NH * C)
 
@@ -328,9 +403,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             x_tile = packed.take(x, start)
             with jax.named_scope(hm.SCOPES["latent"]):
                 p = {**weights_at(tree, per, jk, start), **heads}
-                # the narrow program's is in slab order (which a whole slab's packing is), the wide one's packed
-                a = jnp.take(o, packed.take(packed.slot, start), axis=0, mode="clip") if T == 1 else packed.take(o, start)
-                x_tile = x_tile + hm.latent_output(cfg, p, a.reshape(a.shape[0], NH, C)).astype(x.dtype)
+                x_tile = x_tile + hm.latent_output(cfg, p, o(start).reshape(-1, NH, C)).astype(x.dtype)
             x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 
@@ -347,42 +420,42 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
 
         with jax.named_scope("linear_attention"):
             qkv, log_a, beta = tiles(before, (
-                jnp.zeros((NPK, C3), dtype), jnp.zeros((NPK, LH * LD), jnp.float32), jnp.zeros((NPK, LH), jnp.float32),
+                unfilled((NPK, C3), dtype), unfilled((NPK, LH * LD), jnp.float32), unfilled((NPK, LH), jnp.float32),
             ))
             cp = weights_at({k: v for k, v in stacks["linear"].items() if k.startswith("conv_")}, per, jl, jnp.int32(0))
             tails = jnp.where(fresh[:, None, None], 0, cv[layer, slots])  # [B, K - 1, 3C]
             # the rows with one token: in place on the pool
+            # (the narrow program wrote every row: it reads them as it always did, and compiles to the text it always did)
+            qkv1, log_a1, beta1 = (a[starts] if T == 1 else real_rows(a, starts, one_token) for a in (qkv, log_a, beta))
             with jax.named_scope("kda_recurrence"):
-                q1, k1, v1 = hm.linear_qkv(cfg, hm.short_conv(cp, tails, qkv[starts][:, None])[:, 0])
-                o, st = kda_decode(
-                    q1, k1, v1, log_a[starts].reshape(B, LH, LD), beta[starts], st, layer, slots, one_token, fresh
-                )
-            o = o.astype(dtype).reshape(B, 1, LH * LD)
-            if T > 1:
-                o = jnp.zeros((B, T, LH * LD), dtype).at[:, :1].set(o)
+                q1, k1, v1 = hm.linear_qkv(cfg, hm.short_conv(cp, tails, qkv1[:, None])[:, 0])
+                o, st = kda_decode(q1, k1, v1, log_a1.reshape(B, LH, LD), beta1, st, layer, slots, one_token, fresh)
+            o = o.astype(dtype)  # [B, LH, LD]
+            if T == 1:
+                o = functools.partial(slab_rows, o)
+            else:
 
                 def chunk_row(i, carry):
-                    st, o = carry
+                    st, chunks = carry
                     r = order[i]
                     idx = packed.index[r]  # [T]
-                    valid = (jnp.arange(T, dtype=jnp.int32) < q_lens[r])[:, None]
-                    q, k, v = hm.linear_qkv(cfg, hm.short_conv(cp, tails[r][None], qkv[idx][None]))
-                    la = jnp.where(valid, log_a[idx], 0.0).reshape(1, T, LH, LD)
-                    b = jnp.where(valid, beta[idx], 0.0)[None]
+                    valid = jnp.arange(T, dtype=jnp.int32) < q_lens[r]
+                    q, k, v = hm.linear_qkv(cfg, hm.short_conv(cp, tails[r][None], real_rows(qkv, idx, valid)[None]))
+                    la = real_rows(log_a, idx, valid).reshape(1, T, LH, LD)
+                    b = real_rows(beta, idx, valid)[None]
                     S0 = jnp.where(fresh[r], 0.0, st[layer, slots[r]].astype(jnp.float32))[None]
                     with jax.named_scope("kda_recurrence"):
                         o_row, S = kda_chunked(q, k, v, la, b, S0)
                     st = jax.lax.dynamic_update_slice(st, S[None].astype(st.dtype), (layer, slots[r], 0, 0, 0))
-                    o = jax.lax.dynamic_update_slice(o, o_row.astype(dtype).reshape(1, T, LH * LD), (r, 0, 0))
-                    return st, o
+                    return st, put_chunk(chunks, o_row.reshape(T, LH * LD), idx[0], valid)
 
-                st, o = jax.lax.fori_loop(0, n_chunk_rows, chunk_row, (st, o))
+                st, chunks = jax.lax.fori_loop(0, n_chunk_rows, chunk_row, (st, unfilled((NPK, LH * LD), dtype)))
+                o = functools.partial(rows_output, chunks, o.reshape(B, LH * LD))
             # the convolution's tail after the window: the last K - 1 of (old tail, the row's tokens)
             at = q_lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]  # [B, K - 1], in that sequence
             from_window = jnp.take(qkv, jnp.clip(starts[:, None] + at - (K - 1), 0, NPK - 1), axis=0)
             from_tail = jnp.take_along_axis(tails, jnp.minimum(at, K - 2)[..., None], axis=1)
             cv = cv.at[layer, write_slot].set(jnp.where((at >= K - 1)[..., None], from_window, from_tail))
-            o = o.reshape(B * T, LH, LD)
 
         def after(start, carry):
             x, counts = carry
@@ -390,8 +463,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             with jax.named_scope("linear_attention"):
                 p = weights_at(stacks["linear"], per, jl, start)
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-                o_tile = jnp.take(o, packed.take(packed.slot, start), axis=0, mode="clip")
-                x_tile = x_tile + hm.linear_output(cfg, p, h, o_tile).astype(x.dtype)
+                x_tile = x_tile + hm.linear_output(cfg, p, h, o(start).reshape(-1, LH, LD)).astype(x.dtype)
             x_tile, tile_counts = ffn(x_tile[None], start, per, j)
             return put(x, x_tile[0], start), counts + tile_counts
 

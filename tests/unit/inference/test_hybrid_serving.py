@@ -39,6 +39,9 @@ def _model(dtype="float32", **kw):
     return cfg, lm, params, section
 
 
+_FORWARDS = {}  # (id of the config, token tile) -> (the config, kept alive for its id; its jitted forward)
+
+
 class Driver:
     """Rows stepped by hand through ``hybrid_forward``: what the scheduler
     does, with the logits kept."""
@@ -52,7 +55,10 @@ class Driver:
         self.pools = [jnp.zeros(pages, dtype), jnp.zeros(pages, dtype), jnp.zeros(shapes.state, jnp.float32), jnp.zeros(shapes.conv, dtype)]
         self.table = np.stack([1 + s * maxp + np.arange(maxp) for s in range(SLOTS)]).astype(np.int32)
         self.lengths = np.zeros(SLOTS, np.int32)
-        self.forward = jax.jit(lambda p, *a: hybrid_decode.hybrid_forward(cfg, p, *a, attn_impl="xla"))
+        key = (id(cfg), decode.token_tile(cfg))  # drivers of one model share its two compiled programs
+        if key not in _FORWARDS:
+            _FORWARDS[key] = (cfg, jax.jit(lambda p, *a: hybrid_decode.hybrid_forward(cfg, p, *a, attn_impl="xla")))
+        self.forward = _FORWARDS[key][1]
 
     def step(self, windows, width):
         """``windows``: {slot: tokens}; the rows are laid out in a shuffled
@@ -291,3 +297,83 @@ def test_a_hybrid_config_without_linear_layers_serves_with_an_empty_state_store(
     for i, (p, o) in enumerate(zip(prompts, outs)):
         assert [int(lg[i, p.size - 1 + j].argmax()) for j in range(6)] == [int(t) for t in o[p.size :]]
     assert eng._paged_server.pool.rollback(0, 0) == 0
+
+
+# --- what a wide window's token buffers hold past the live tiles ----------------
+def _one_kind(kind):
+    """The smallest toy with a routed layer of ``kind``."""
+    from deepspeed_tpu.models.hybrid_moe import glm4_moe_lite_config, mimo_v2_config
+
+    if kind in ("softmax", "linear"):  # Solar-Open2's: a gated softmax layer, a delta-rule layer
+        return solar_open2_config("tiny", num_layers=1, layer_types=[kind], dtype="float32")
+    if kind == "window":  # MiMo's, with sinks, behind a leading dense layer of full attention
+        return mimo_v2_config("tiny", num_layers=2, layer_types=["softmax", "window"], dtype="float32")
+    return glm4_moe_lite_config("tiny", num_layers=2, dtype="float32")  # a leading dense layer, a routed one
+
+
+# (tokens in the row's window, tokens it holds already) of each row
+WINDOWS = {
+    # 13 live tokens of the 16-token tile, the chunk row last: the tile's dead tail counts as that row's
+    "a_dead_tail_in_the_live_tile": ((1, 9), (0, 0), (1, 3), (11, 16)),
+    # the one tile full: the dead row's first packed token and the chunk's last two window slots lie PAST the live tiles
+    "a_full_tile": ((1, 9), (14, 16), (1, 3), (0, 0)),
+    # two chunk rows over two tiles, the second tile partly dead
+    "two_chunks_two_tiles": ((16, 0), (1, 20), (7, 32), (0, 0)),
+}
+
+
+@pytest.mark.parametrize("kind", ["softmax", "window", "linear", "latent"])
+def test_what_lies_past_the_live_tiles_reaches_nothing(kind, monkeypatch):
+    """A wide window's per-layer token buffers are not filled
+    (``hybrid_decode.unfilled``: on the chip whatever the allocator hands
+    over; XLA's CPU lowering writes zeros). With NaN in every row nothing
+    writes, the step's greedy tokens, routing counts and EVERY pool, trash
+    page and spare slot included, are bit for bit those of zero-filled
+    buffers, and all finite: each read of such a buffer is a live tile's slice
+    or stands behind a select on the token being real. One chunk row, one-token
+    rows and a dead row; a dead tail inside the live tile, a full tile, two."""
+    from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
+
+    cfg = _one_kind(kind)
+    params = HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None)
+    monkeypatch.setattr(decode, "DENSE_TOKEN_TILE", 16)
+    assert decode.token_tile(cfg) == 16 < SLOTS * CHUNK and kind in cfg.period
+    maxp = MAXLEN // PAGE
+    n_pages = SLOTS * maxp + 1
+    rng = np.random.default_rng(7)
+
+    def pool(shape):  # what earlier steps left: anything finite
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    pages = (cfg.layers_of("softmax"), n_pages, cfg.num_kv_heads, PAGE)
+    shapes = hybrid_decode.state_shapes(cfg, SLOTS)
+    rings, latent = (None, None), None
+    if kind == "window":
+        ring = window_ring_pages(cfg.window, PAGE, CHUNK)
+        rings = tuple(pool(shape) for shape in hybrid_decode.window_shapes(cfg, SLOTS, PAGE, ring))
+    if kind == "latent":
+        latent = pool((cfg.layers_of("latent"), n_pages, PAGE, key_lanes(cfg.latent_width)))
+    pools = (pool(pages + (key_lanes(cfg.head_dim),)), pool(pages + (cfg.v_head_dim,)),
+             StateStore(pool(shapes.state), pool(shapes.conv), *rings, latent))
+    own_pages = np.stack([1 + s * maxp + np.arange(maxp) for s in range(SLOTS)]).astype(np.int32)
+
+    def program(fill):
+        monkeypatch.setattr(decode, "_paged_program_cache", {})
+        monkeypatch.setattr(hybrid_decode, "unfilled", lambda shape, dtype: jnp.full(shape, fill, dtype))
+        return decode.build_ragged_step(cfg, SLOTS, CHUNK, PAGE, attn_impl="xla")
+
+    zeros, garbage = program(0), program(np.nan)
+    for name, windows in WINDOWS.items():
+        q_lens, lengths = (np.asarray(a, np.int32) for a in zip(*windows))
+        tokens = rng.integers(0, 512, (SLOTS, CHUNK)).astype(np.int32)
+        slots = np.where(q_lens > 0, (np.arange(SLOTS) + 1) % SLOTS, SLOTS).astype(np.int32)  # row and slot differ
+        table = np.where((q_lens > 0)[:, None], own_pages[slots % SLOTS], -1).astype(np.int32)
+        # the step donates its pools: each run takes copies
+        want, got = (
+            jax.tree_util.tree_leaves(step(params, tokens, *jax.tree_util.tree_map(jnp.copy, pools), table, lengths, q_lens, slots))
+            for step in (zeros, garbage)
+        )
+        assert np.asarray(want[0])[SLOTS, 0] > 0, name  # the routing counts ride in the result: live assignments
+        for a, b in zip(want, got):
+            assert np.isfinite(np.asarray(b, np.float32)).all(), name
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name

@@ -46,6 +46,9 @@ def _model(dtype="float32", **kw):
     return cfg, lm, params, section
 
 
+_FORWARDS = {}  # (id of the config, token tile) -> (the config, kept alive for its id; its jitted forward)
+
+
 class Driver:
     """Rows stepped by hand through ``hybrid_forward``: what the scheduler
     does, with the logits kept."""
@@ -60,7 +63,10 @@ class Driver:
         self.rings = (pool.states.window_k, pool.states.window_v)
         self.table = np.stack([1 + s * maxp + np.arange(maxp) for s in range(SLOTS)]).astype(np.int32)
         self.lengths = np.zeros(SLOTS, np.int32)
-        self.forward = jax.jit(lambda p, *a, window: hybrid_decode.hybrid_forward(cfg, p, *a, attn_impl="xla", window=window))
+        key = (id(cfg), decode.token_tile(cfg))  # drivers of one model share its two compiled programs
+        if key not in _FORWARDS:
+            _FORWARDS[key] = (cfg, jax.jit(lambda p, *a, window: hybrid_decode.hybrid_forward(cfg, p, *a, attn_impl="xla", window=window)))
+        self.forward = _FORWARDS[key][1]
 
     def step(self, windows, width):
         """``windows``: {slot: tokens}; the rows are laid out in a shuffled

@@ -334,6 +334,36 @@ def _attention_matrices(cfg, kinds=(None,)):
     return out | {(b, a) for a, b in out}
 
 
+def _slab_sized_fills(text, slots, lanes=512):
+    """The instructions of a wide program that cost what its ``slots`` (rows
+    x width: 64 x 128) cost and not what its live tokens do: a ``broadcast``
+    or a ``copy`` that runs as itself and writes ``[slots, >= lanes]`` whole
+    (a layer's token buffer zero-filled, or moved to another layout: 134-403
+    MB a layer), and a ``scatter`` into one, fused or not, which goes row by
+    row (PERF.md section 6, PR 47: three such ops a latent layer were 10.4%
+    of GLM-4.7-Flash's cell). ``hybrid_decode.unfilled`` buffers are
+    ``custom-call``s that allocate and write nothing; a tile or a chunk row
+    goes in by ``dynamic-update-slice``, in place."""
+    slab = re.compile(rf"(?:bf16|f32)\[{slots},(\d+)\]")
+    wide = lambda result: any(int(n) >= lanes for n in slab.findall(result))
+    found = {name: f"{opcode} {result}" for name, opcode, result in _executed(text) if opcode in ("broadcast", "copy") and wide(result)}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) == "scatter" and wide(m.group(2)):
+            found[m.group(1)] = f"scatter {m.group(2)}"
+    return found
+
+
+# (kernel calls, temporaries in bytes) of the hybrid configurations' NARROW programs at PR 46: PR 47 rewrote the
+# wide window's buffers in lines the two programs share, and the narrow text kept every instruction (compared
+# with names, metadata and the kernels' payloads taken out). A PR that means to change a narrow program says so here.
+NARROW_PROGRAMS = {"solar": (16, 224_022_016), "mimo": (25, 6_880_256), "glm": (5, 2_549_248), "laguna": (17, 5_070_848)}
+
+
+def _narrow_program(text, memory):
+    return text.count('custom_call_target="tpu_custom_call"'), memory.temp_size_in_bytes
+
+
 def _compiled_mistral_step(v5e, monkeypatch, width):
     """``build_ragged_step`` at the Mistral cells' shapes (16 rows, 609 pages
     of 64), compiled for the described chip: (config, the cell's ``paged_kv``,
@@ -539,6 +569,13 @@ def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, mon
     assert len(opens_with_three_s32) == (1 if width == 1 else 2), opens_with_three_s32
     assert any(name.startswith("kda_decode") for name in kernels), sorted(kernels)
     assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
+    if width == 1:
+        assert _narrow_program(text, memory) == NARROW_PROGRAMS["solar"]
+    else:
+        # nothing fills, copies or scatters into a 64 x 128-slot token buffer (a linear layer's qkv [8192, 24576], its
+        # log_a f32 [8192, 8192] and its [64, 128, 8192] output, the full layer's q and out [8192, 8192], before)
+        assert not _slab_sized_fills(text, rows * width)
+        assert memory.temp_size_in_bytes < 1.70e9  # 1.672 GB before: the chunk rows' kda_chunked, not the buffers
 
 
 _MIMO_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mimo-v2.5-l7-ep16.json"
@@ -611,6 +648,10 @@ def test_mimo_v2_ragged_step_fits_and_every_attention_layer_walks_live_pages(v5e
     assert not _mixer_matrices_written_out(text, _attention_matrices(cfg, ("softmax", "window")), but=activations)
     if width == 1:
         assert memory.temp_size_in_bytes < 0.05e9  # 0.411 GB before
+        assert _narrow_program(text, memory) == NARROW_PROGRAMS["mimo"]
+    else:
+        assert not _slab_sized_fills(text, rows * width)  # 7 x q [8192, 12288], 7 x out [8192, 8192] and 14 scatters into one, before
+        assert memory.temp_size_in_bytes < 0.63e9  # 0.647 GB before
 
 
 _GLM_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/glm-4.7-flash-l16-ep8.json"
@@ -671,21 +712,31 @@ def test_glm47_flash_ragged_step_fits_and_keeps_one_latent_pool_in_place(v5e, mo
     # no copy of the pool, and the 64 x 128 window of 20 heads x 640 lanes is never laid out
     assert not re.search(r"= bf16\[16,4097,64,640\]\S* (copy|fusion)\(", text)
     assert not re.search(r"bf16\[64,128,(12800|20,640)\]|bf16\[64,2560,640\]", text)
+    if width == 1:
+        assert _narrow_program(text, memory) == NARROW_PROGRAMS["glm"]
+    else:
+        # q [8192, 11520] and out [8192, 10240] zero-filled a layer and the chunk rows scattered into out: 10.4% of the cell, before
+        assert not _slab_sized_fills(text, rows * width)
+        assert memory.temp_size_in_bytes < 0.665e9  # 0.669 GB before
 
 
 _LAGUNA_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/laguna-s-2.1-l9-ep16.json"
 
 
-def test_laguna_narrow_step_reads_its_mixers_matrices_where_they_lie(v5e, monkeypatch):
-    """``build_ragged_step`` at the Laguna-S-2.1 cell's shapes, the narrow
-    program (a leading dense layer and TWO periods of a full layer of 48
+@pytest.mark.parametrize("width", [1, 128])
+def test_laguna_step_reads_its_mixers_matrices_where_they_lie(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the Laguna-S-2.1 cell's shapes, both
+    programs (a leading dense layer and TWO periods of a full layer of 48
     query heads and three window layers of 72 over 8 KV heads, 64 rows, 4,097
     pages of 64, rings of 10 pages a slot): the scan over periods reaches a
     layer's weights by ONE slice a leaf (``hybrid_decode.layer_of``) and
     keeps the head split behind a barrier, so no period's slice of ``wq`` /
     ``wo`` (``[1, 3, 3072, 9216]``, 170 MB) and no copy of one to another
     layout is written out, and the program's temporaries are the
-    activations' (0.346 GB before PR 46: a fifth of the cell's device time)."""
+    activations' (0.346 GB before PR 46: a fifth of the cell's device time).
+    The wide program's are its 64 x 128-slot token buffers (q and out
+    ``[8192, 9216]`` / ``[8192, 6144]``, k and v ``[8192, 1024]``), which
+    nothing fills, copies or scatters into (PR 47)."""
     from deepspeed_tpu.inference import hybrid_decode
     from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
@@ -709,16 +760,23 @@ def test_laguna_narrow_step_reads_its_mixers_matrices_where_they_lie(v5e, monkey
     shapes = hybrid_decode.state_shapes(cfg, rows)
     rings = (on_v5e(shape) for shape in hybrid_decode.window_shapes(cfg, rows, page, ring))
     store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv), *rings)
-    step = decode.build_ragged_step(cfg, rows, 1, page, attn_impl="pallas")
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
     compiled = step.lower(
-        params, on_v5e((rows, 1), I32), k_pool, k_pool, store,
+        params, on_v5e((rows, width), I32), k_pool, k_pool, store,
         on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
     ).compile()
     text = compiled.as_text()
     matrices = _attention_matrices(cfg, ("softmax", "window"))
     assert (3072, 9216) in matrices and (9216, 3072) in matrices
     assert any(opcode == "while" for _, opcode, _ in _executed(text))  # the scan over periods is a loop: its body is read
-    assert not _mixer_matrices_written_out(text, matrices)
+    # (the wide program's packed tokens, 64 x 128 of 3072 or a tile of them, are no matrix)
+    activations = {(rows * width, cfg.hidden_size), (decode.token_tile(cfg), cfg.hidden_size)}
+    assert not _mixer_matrices_written_out(text, matrices, but=activations)
     memory = compiled.memory_analysis()
-    print(f"laguna w1: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
-    assert memory.temp_size_in_bytes < 0.05e9
+    print(f"laguna w{width}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    if width == 1:
+        assert memory.temp_size_in_bytes < 0.05e9
+        assert _narrow_program(text, memory) == NARROW_PROGRAMS["laguna"]
+    else:
+        assert not _slab_sized_fills(text, rows * width)
+        assert memory.temp_size_in_bytes < 0.60e9  # 0.592 GB before: the same buffers, allocated and not filled
