@@ -204,11 +204,9 @@ _NP_CASTS = ("np.asarray", "np.array", "numpy.asarray", "numpy.array", "onp.asar
 
 # DS-R009 scope: step-loop methods of engine/server/scheduler classes —
 # the code that runs between (or around) every hot dispatch — plus the
-# input-pipeline Loader classes (ISSUE 14: a prefetching loader's __next__
-# runs once per microbatch on the same critical path, and the multi-step
-# window family — formation, per-step commit, deferred loss drain, lr
-# pre-evaluation — runs between every window dispatch). The tracer /
-# timer / sync modules OWN the clocks and are exempt by path.
+# input-pipeline Loader classes (a loader's __next__ runs once per
+# microbatch on the same critical path). The tracer / timer / sync
+# modules OWN the clocks and are exempt by path.
 _R009_EXEMPT_PATH = re.compile(r"(utils/timer\.py|utils/sync\.py|profiling/)")
 _R009_CLASS = re.compile(r"(Engine|Server|Scheduler|Loader|Streamer)$")
 _R009_FN = re.compile(
@@ -216,9 +214,7 @@ _R009_FN = re.compile(
     r"|take_offload_step|take_streamed_offload_step|generate"
     r"|(plain_)?(decode|prefill|verify|spec|ragged)"
     r"_(step|round)|admit|emit|run|serve|settle_spec_row|reserve_for_growth"
-    r"|finish_step_bookkeeping|try_train_window|commit_window_step"
-    r"|drain_pending|window_lrs|window_loader|__next__|pull|fill"
-    r"|h2d_bucket|d2h_bucket|gather_device_state|scatter_device_state"
+    r"|finish_step_bookkeeping|__next__|h2d_bucket|d2h_bucket"
     r"|materialize_writes|drain_writes|discard_staged|take_staged|land)$"
 )
 # call names that read a raw clock or drain the dispatch queue

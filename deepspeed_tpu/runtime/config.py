@@ -128,44 +128,6 @@ def split_data_axis(mc: "MeshConfig", group_size: int, n_devices: int, feature: 
     mc.data_outer = data_total // data_inner
 
 
-class MultiStepTrainConfig(DeepSpeedConfigModel):
-    """N-step fused training windows (``compile.multi_step``; ISSUE 14).
-
-    ``enable`` arms the training-side twin of the serving multi-step
-    windows (``paged_kv.multi_step``): when ``train_batch(data_iter=...)``
-    sits at an optimizer-step boundary with ``horizon`` steps of data
-    available and no schedule event (checkpoint interval, monitor flush,
-    flops-profiler step) inside the window, the engine dispatches ONE
-    jitted program that ``lax.scan``s ``horizon`` FULL optimizer steps —
-    stacked ``[N, gas, ...]`` microbatches, per-step lr values evaluated
-    ahead on the host and riding in as an array, fp16 dynamic loss-scale
-    state carried through the scan so overflow-skip/rescale stays
-    in-program — amortizing every per-step host cost (dispatch RTT, data
-    fetch, h2d, loss fetch) to 1/N. Windows are bit-identical to N
-    sequential ``train_batch`` calls by construction; any step a window
-    cannot cover falls back to the single-step fused path and
-    ``engine.window_stats()['window_break_reasons']`` says why.
-    ``prefetch`` stages the next window's batches (sharded ``device_put``
-    enqueued ahead) while the current window computes — the
-    double-buffered input pipeline (``runtime/dataloader.py``
-    ``PrefetchingLoader``; exact-resume data cursors are preserved).
-    With ``gradient_accumulation_steps > 1`` the window scans the fused
-    grad-accum step body, so ``compile.fuse_grad_accum`` must be on."""
-
-    enable: bool = False
-    horizon: int = 8
-    prefetch: bool = True
-
-    @model_validator(mode="after")
-    def _check(self):
-        if self.enable and self.horizon < 2:
-            raise ValueError(
-                "compile.multi_step.horizon must be >= 2 when enabled "
-                "(1 is the single-step fused path)"
-            )
-        return self
-
-
 class CompileConfig(DeepSpeedConfigModel):
     """TPU-native compile controls.
 
@@ -174,13 +136,10 @@ class CompileConfig(DeepSpeedConfigModel):
     fwd+bwd+accumulate, followed by the optimizer update — so the host
     dispatches once per optimizer step instead of gas+1 times (engaged
     through ``train_batch``; the per-microbatch forward/backward/step
-    protocol keeps the unfused programs). ``multi_step`` goes one level
-    further and fuses N whole optimizer steps into one dispatch (see
-    :class:`MultiStepTrainConfig`).
+    protocol keeps the unfused programs).
     """
 
     fuse_grad_accum: bool = False
-    multi_step: MultiStepTrainConfig = Field(default_factory=MultiStepTrainConfig)
 
 
 class AnalysisConfig(DeepSpeedConfigModel):

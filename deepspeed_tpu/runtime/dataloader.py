@@ -9,9 +9,7 @@ global batch when running multi-host (each host loads its addressable slice).
 
 from __future__ import annotations
 
-import copy
 import math
-from collections import deque
 from typing import Any, Callable, Iterable, Optional
 
 import numpy as np
@@ -79,117 +77,6 @@ class RepeatingLoader:
             except StopIteration:
                 self.data_iter = iter(self.loader)
                 next(self.data_iter)
-
-
-class PrefetchingLoader:
-    """Double-buffered input pipeline (ISSUE 14).
-
-    Wraps a batch source (an iterator, or an iterable like
-    :class:`DeepSpeedDataLoader`) and keeps up to ``depth`` batches pulled
-    ahead, applying ``place_fn`` — typically the engine's sharded
-    ``device_put`` (``engine._place_batch``) — at PULL time. The host→device
-    transfer of batch i+1 is therefore enqueued while step/window i is still
-    computing on device, taking ``train.data_fetch`` + ``train.h2d`` off the
-    step's critical path.
-
-    Exact-resume contract (PR-8 mid-epoch resume must keep holding):
-    pulling ahead advances the underlying loader's cursor past batches that
-    have NOT been trained yet, so ``state_dict()`` here reports the cursor
-    of the first *undelivered* batch — a snapshot of ``state_source``
-    (default: the wrapped source, when it exposes ``state_dict``) taken
-    immediately before each pull. A checkpoint cut mid-prefetch thus
-    replays the buffered-but-untrained batches on resume instead of
-    skipping them. ``load_state_dict`` drops the stale buffer, restores the
-    source cursor, and re-iterates the source — it therefore requires a
-    RE-ITERABLE source (wrap the loader itself); over a bare iterator it
-    raises, because a running generator cannot rewind (rebuild the wrapper
-    after restoring the loader's cursor instead, as the engine does).
-    """
-
-    def __init__(self, source, place_fn: Optional[Callable] = None, depth: int = 1, state_source=None):
-        self._source = source
-        self._iter = iter(source)
-        self.place_fn = place_fn
-        self.depth = max(int(depth), 0)
-        if state_source is None and hasattr(source, "state_dict"):
-            state_source = source
-        self._state_source = state_source
-        self._buf: deque = deque()  # (placed_batch, cursor_snapshot_before_pull)
-        self._exhausted = False
-
-    def _snap(self):
-        if self._state_source is None:
-            return None
-        return copy.deepcopy(self._state_source.state_dict())
-
-    def _pull(self) -> bool:
-        """Stage one more batch (snapshot cursor, fetch, place). False once
-        the source is exhausted — StopIteration is latched so a generator
-        source is never advanced past its end twice."""
-        if self._exhausted:
-            return False
-        snap = self._snap()
-        try:
-            batch = next(self._iter)
-        except StopIteration:
-            self._exhausted = True
-            return False
-        if self.place_fn is not None:
-            batch = self.place_fn(batch)
-        self._buf.append((batch, snap))
-        return True
-
-    def fill(self, n: Optional[int] = None) -> int:
-        """Pull until ``n`` (default: ``depth``) batches are buffered or the
-        source runs dry; returns the buffered count. The window former uses
-        this to ask 'does a full window of data exist?' without consuming."""
-        target = self.depth if n is None else int(n)
-        while len(self._buf) < target and self._pull():
-            pass
-        return len(self._buf)
-
-    def buffered(self) -> int:
-        return len(self._buf)
-
-    def __iter__(self):
-        return self
-
-    def __next__(self):
-        if not self._buf and not self._pull():
-            raise StopIteration
-        batch, _ = self._buf.popleft()
-        # top back up: this is the double buffer — the NEXT batch's
-        # device_put is enqueued now, while the consumer's current
-        # step/window still owns the device
-        self.fill(self.depth)
-        return batch
-
-    def state_dict(self) -> Optional[dict]:
-        """Cursor of the first undelivered batch (see class docstring)."""
-        if self._buf:
-            snap = self._buf[0][1]
-            return copy.deepcopy(snap) if snap is not None else None
-        return self._snap()
-
-    def load_state_dict(self, sd) -> None:
-        if iter(self._source) is self._source:
-            # a running iterator/generator cannot rewind: "restoring" it
-            # would silently skip every staged-but-untrained batch — the
-            # exact sample loss this class exists to prevent. Only a
-            # RE-ITERABLE source (the loader itself) can resume in place;
-            # iterator-wrapped pipelines rebuild the wrapper after
-            # restoring the loader's own cursor (what the engine does).
-            raise ValueError(
-                "PrefetchingLoader.load_state_dict requires a re-iterable "
-                "source (wrap the loader, not iter(loader)): a bare "
-                "iterator cannot rewind to the restored cursor; restore "
-                "the loader's cursor and rebuild the wrapper instead"
-            )
-        self._buf.clear()
-        self._exhausted = False
-        if self._state_source is not None and sd is not None:
-            self._state_source.load_state_dict(sd)
-        self._iter = iter(self._source)
 
 
 class DeepSpeedDataLoader:

@@ -16,15 +16,11 @@ sharded jax.Arrays, and three jitted programs implement the hot loop:
   overflow skip costs a ``where``, not a host sync.
 * ``_eval_fwd``  — forward only.
 
-Three fused flavors collapse host work into ONE dispatch: at gas=1 the
-optimizer update fuses into the forward program (``_jit_fused_step``); with
-``compile.fuse_grad_accum`` on, gas>1 steps run as a ``lax.scan`` over
+Two fused flavors collapse host work into ONE dispatch: at gas=1 the
+optimizer update fuses into the forward program (``_jit_fused_step``); and
+with ``compile.fuse_grad_accum`` on, gas>1 steps run as a ``lax.scan`` over
 stacked microbatches plus the update (``_jit_fused_accum_step``, engaged
-through ``train_batch``); and with ``compile.multi_step`` armed, N whole
-optimizer steps fuse into one program (``_jit_fused_window_step`` — the
-state tuple threads the scan carry, per-step lr values ride in as an array,
-and the per-step losses drain asynchronously one window deferred), so every
-per-step host cost amortizes to 1/N. All step-flavor
+through ``train_batch``). All step-flavor
 programs donate the full state tuple (params, master, opt_state, grad_acc,
 scale_state) so XLA updates state in place instead of double-buffering it,
 and every program is wrapped in compile telemetry
@@ -39,7 +35,6 @@ from __future__ import annotations
 
 import os
 import time
-from collections import deque
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -92,15 +87,11 @@ from deepspeed_tpu.utils.timer import (
 
 MEMORY_OPT_ALLREDUCE_SIZE = 500_000_000  # parity: engine.py:105
 
-# sentinel: a multi-step window could not form this step (the caller falls
-# back to the bit-identical single-step path)
-_NO_WINDOW = object()
-
 
 def _enqueue_host_copies(leaves) -> None:
-    """Start device→host copies on every array that supports it (the async
-    half of the deferred loss drain): a later ``device_get`` completes an
-    in-flight copy instead of starting a blocking one."""
+    """Start device→host copies on every array that supports it: a later
+    ``device_get`` completes an in-flight copy instead of starting a
+    blocking one."""
     for leaf in leaves:
         copy_async = getattr(leaf, "copy_to_host_async", None)
         if copy_async is not None:
@@ -432,29 +423,6 @@ class DeepSpeedEngine:
         self._jit_step = None
         self._batch_spec_fn = None
 
-        # multi-step training windows (compile.multi_step; ISSUE 14):
-        # N full optimizer steps per dispatch, with the per-step results
-        # stashed device-side and committed one train_batch call at a time
-        self._jit_fused_window_step = None
-        self._window_armed = False
-        self._window_horizon = 0
-        self._window_stash: deque = deque()  # computed-but-uncommitted steps
-        self._pending_drains: deque = deque()  # deferred per-window loss drains
-        self._drained_log: deque = deque(maxlen=4096)
-        self._drained_dropped = 0  # entries the bounded log evicted unread
-        self._window_metrics = {
-            "window_steps": 0,
-            "windowed_opt_steps": 0,
-            "window_break_reasons": {
-                "checkpoint": 0,  # a checkpoint-interval boundary inside the horizon
-                "monitor": 0,  # a monitor flush inside the horizon
-                "data": 0,  # dataloader exhausted before a full window
-                "profiler": 0,  # the flops-profiler step wants the unfused path
-            },
-        }
-        self._active_prefetcher = None  # PrefetchingLoader for the live data_iter
-        self._prefetch_key = None
-
         # compile telemetry: every jitted program is instrumented so
         # trace/compile/dispatch counts (and retrace regressions) are
         # observable via compile_stats()
@@ -466,19 +434,6 @@ class DeepSpeedEngine:
         acfg = self._config.analysis_config
         if acfg.verify != "off":
             self._telemetry.on_compile = self._verify_program_static
-
-        # multi-step window validation + observability: structural conflicts
-        # fail at construction (an armed knob that silently never windows is
-        # worse than an error), and window_stats rides the merged report
-        self._validate_multi_step()
-        self._obs_hub.add_source("train_window", self.window_stats)
-        if self._config.compile_config.multi_step.enable and self._obs_hub.flight_recorder is not None:
-            # postmortems must name the window config (a crash dump showing a
-            # train.window span is only readable next to the armed horizon)
-            self._obs_hub.flight_recorder.context["train.multi_step"] = {
-                "enable": True,
-                "horizon": int(self._config.compile_config.multi_step.horizon),
-            }
 
         self.training_dataloader = self.deepspeed_io(training_data) if training_data is not None else None
 
@@ -528,20 +483,6 @@ class DeepSpeedEngine:
             self.tput_timer.batch_size = train_batch_size
             return
         self._check_resize_allowed()
-        if (
-            self._config.compile_config.multi_step.enable
-            and new_gas > 1
-            and not self._config.compile_config.fuse_grad_accum
-        ):
-            # same contract _validate_multi_step enforces at construction:
-            # a resize must not silently disarm the windows (the rebuild
-            # would set _window_armed False and never count a break)
-            raise ValueError(
-                f"set_train_batch_size: gradient_accumulation_steps={new_gas} "
-                "with compile.multi_step enabled requires "
-                "compile.fuse_grad_accum (the window scans the fused "
-                "grad-accum body)"
-            )
         if self._is_pipe_engine:
             # the pipeline folds all microbatches into one compiled schedule
             # sized at construction — a live resize cannot reshape it
@@ -570,11 +511,6 @@ class DeepSpeedEngine:
     def _check_resize_allowed(self) -> None:
         if self._in_forward or self._pending_commit is not None:
             raise RuntimeError("cannot resize the batch mid-step: finish backward()+step() first")
-        if self._window_stash:
-            raise RuntimeError(
-                "cannot resize the batch mid-window: the multi-step window's "
-                "remaining train_batch calls must commit first"
-            )
         if self.micro_steps % self.gradient_accumulation_steps() != 0:
             raise RuntimeError(
                 "cannot resize the batch inside an accumulation window: "
@@ -699,13 +635,6 @@ class DeepSpeedEngine:
                 "eval() called with a pending fused step: with "
                 "gradient_accumulation_steps=1 forward() already applied the "
                 "optimizer update; call step() before switching to eval"
-            )
-        if not mode and self._window_stash:
-            raise RuntimeError(
-                "eval() called with a multi-step window mid-flight: the "
-                "fused window already advanced the model state but "
-                f"{len(self._window_stash)} step(s) are uncommitted; finish "
-                "the window's train_batch calls before switching to eval"
             )
         if not mode and self._training_mode:
             # a half-open throughput window would count eval wall-clock
@@ -846,17 +775,10 @@ class DeepSpeedEngine:
                     clock=self.tracer.clock,
                 )
                 self._streamed_offload = True
-                # the window program (compile.multi_step) still needs the
-                # device-side opt shardings to rebuild/donate gathered state
-                opt_specs = self.optimizer.state_specs(self._master_specs)
-                self._opt_shardings = jax.tree_util.tree_map(
-                    lambda s: NamedSharding(self.mesh, s),
-                    opt_specs,
-                    is_leaf=lambda x: isinstance(x, PartitionSpec),
-                )
                 # free the device-side master: the host copy is authoritative
                 self._master = None
                 self._opt_state = None
+                self._opt_shardings = None
             else:
                 # legacy ZeRO-Offload: fp32 master + moments leave the chip —
                 # host DRAM (device=cpu) or local SSD (device=nvme) via the
@@ -1071,7 +993,6 @@ class DeepSpeedEngine:
         "_jit_step",
         "_jit_fused_step",
         "_jit_fused_accum_step",
-        "_jit_fused_window_step",
         "_jit_debug_grad",
         "_jit_grad_stats",
         "_jit_zero_grads",
@@ -1324,11 +1245,9 @@ class DeepSpeedEngine:
         def full_step_core(params, master, opt_state, scale_state, lr, rng, data, model_kwargs):
             """ONE complete optimizer step: fwd+bwd (a scan over gas
             microbatches when gas>1), unscale, update. Shared by
-            ``fused_step`` (gas=1), ``fused_accum_step`` (gas>1) AND the
-            multi-step window body — the window's bit-identity guarantee
-            (a window == N sequential train_batch calls) rests on all
-            three running EXACTLY this math with EXACTLY this rng split
-            schedule, so it lives in one place. ``data`` is the single
+            ``fused_step`` (gas=1) and ``fused_accum_step`` (gas>1), so the
+            step math and its rng split schedule live in one place.
+            ``data`` is the single
             microbatch at gas=1, the stacked ``[gas, ...]`` microbatches
             otherwise. Returns the new state plus the step's loss, grad
             norm, overflow flag, and pre-update scale."""
@@ -1514,118 +1433,6 @@ class DeepSpeedEngine:
                 )
         else:
             self._jit_fused_accum_step = None
-
-        # multi-step training windows (compile.multi_step; ISSUE 14): ONE
-        # jitted program running `horizon` FULL optimizer steps as a
-        # lax.scan whose carry IS the donated state tuple — params, master,
-        # opt_state AND the fp16 loss-scale state all thread through the
-        # carry, so overflow-skip/rescale stays in-program and the donation
-        # pass verifies the aliasing end to end. Each scanned step
-        # replicates the sequential fused program's math exactly, including
-        # its rng split schedule (gas=1 mirrors fused_step, gas>1 mirrors
-        # fused_accum_step), so a window is bit-identical to N sequential
-        # train_batch calls. Per-step lr values ride in as an array indexed
-        # by an in-carry scheduler cursor that advances only on
-        # non-overflow steps — exactly when the host lr scheduler would
-        # have stepped. Host-relevant per-step results (loss, grad norm,
-        # overflow) return as N scalars each so the host can
-        # copy_to_host_async them and drain one window deferred; slicing a
-        # device array post-hoc would dispatch tiny gather programs the
-        # compile-telemetry gates forbid.
-        mscfg = self._config.compile_config.multi_step
-        # streamed host offload composes with windows: the window program
-        # runs the on-device fused step body over GATHERED master/moments
-        # (see _try_train_window), so the offload-disabled fused flags don't
-        # gate it — the same construction conditions do, minus offload.
-        _streamed_window_ok = self._streamed_offload and not qgz and (
-            gas == 1
-            or (
-                bool(self._config.compile_config.fuse_grad_accum)
-                and self.random_ltd_scheduler is None
-            )
-        )
-        self._window_armed = bool(
-            mscfg.enable
-            and (
-                (self._fused_step_enabled if gas == 1 else self._fused_accum_enabled)
-                or _streamed_window_ok
-            )
-        )
-        self._window_horizon = int(mscfg.horizon) if self._window_armed else 0
-        if self._window_armed:
-            H = int(mscfg.horizon)
-
-            def fused_window_step(params_or_none, master, opt_state, scale_state, lrs, rng, stacked):
-                params = master if params_or_none is None else params_or_none
-                if gas > 1:
-                    # [H*gas, B, ...] -> [H, gas, B, ...]: both leading dims
-                    # are unsharded (the batch dim carries the DP split), so
-                    # the reshape is resharding-free
-                    stacked = jax.tree_util.tree_map(
-                        lambda x: x.reshape((H, gas) + x.shape[1:]), stacked
-                    )
-
-                def one_step(carry, mb):
-                    params, master, opt, sstate, rng, sched = carry
-                    lr = jnp.take(lrs, sched)
-                    rng_in = rng
-                    # the SAME step body the sequential fused programs run
-                    # (full_step_core), so window == N sequential steps by
-                    # construction; model_kwargs is None — windows exclude
-                    # the per-step-kwarg features at construction
-                    (params, master, opt, sstate, rng, loss, grad_norm, overflow, pre_scale) = (
-                        full_step_core(params, master, opt, sstate, lr, rng, mb, None)
-                    )
-                    # the host lr scheduler does not advance on an
-                    # overflow-skipped step; neither does the lr cursor
-                    sched = jnp.where(overflow, sched, sched + 1)
-                    return (params, master, opt, sstate, rng, sched), (
-                        loss, grad_norm, overflow, pre_scale, rng_in,
-                    )
-
-                carry0 = (params, master, opt_state, scale_state, rng, jnp.int32(0))
-                carry, ys = jax.lax.scan(one_step, carry0, stacked)
-                new_params, new_master, new_opt, new_scale_state, rng, _ = carry
-                losses, norms, ovfs, pre_scales, rngs_in = ys
-                per_step = tuple((losses[k], norms[k], ovfs[k]) for k in range(H))
-                return (
-                    new_params, new_master, new_opt, new_scale_state, rng,
-                    per_step, pre_scales[H - 1], rngs_in[H - 1],
-                )
-
-            window_name = f"fused_window_step_n{H}"
-            if mixed:
-                self._jit_fused_window_step = self._telemetry.instrument(
-                    window_name,
-                    fused_window_step,
-                    donate_argnums=(0, 1, 2, 3),
-                    out_shardings=(
-                        self._param_shardings,
-                        self._master_shardings,
-                        self._opt_shardings,
-                        None, None, None, None, None,
-                    ),
-                    **step_jit_extra,
-                )
-            else:
-
-                def fp32_fused_window_step(master, opt_state, scale_state, lrs, rng, stacked):
-                    out = fused_window_step(None, master, opt_state, scale_state, lrs, rng, stacked)
-                    return out[1], out[2], out[3], out[4], out[5], out[6], out[7]
-
-                self._jit_fused_window_step = self._telemetry.instrument(
-                    window_name,
-                    fp32_fused_window_step,
-                    donate_argnums=(0, 1, 2),
-                    out_shardings=(
-                        self._master_shardings,
-                        self._opt_shardings,
-                        None, None, None, None, None,
-                    ),
-                    **step_jit_extra,
-                )
-        else:
-            self._jit_fused_window_step = None
 
         if self._streamed_offload:
             # ZeRO-Infinity streamed path: the update math stays ON DEVICE —
@@ -1854,12 +1661,6 @@ class DeepSpeedEngine:
         return self.forward(batch)
 
     def forward(self, batch):
-        if self._window_stash:
-            raise RuntimeError(
-                "forward() called with a multi-step window mid-flight: "
-                f"{len(self._window_stash)} computed step(s) are uncommitted; "
-                "drive them through train_batch(data_iter) first"
-            )
         if not self._initialized:
             self.init_params(batch)
         self.timers(FORWARD_GLOBAL_TIMER).start()
@@ -2054,14 +1855,16 @@ class DeepSpeedEngine:
     def step(self, lr_kwargs=None):  # noqa: ARG002
         self.timers(STEP_GLOBAL_TIMER).start()
         boundary = self.is_gradient_accumulation_boundary()
+        # counted BEFORE the commit, as the fused step does: the interval
+        # auto-save and the monitor feed run in the commit's bookkeeping
+        # tail and must record this microbatch (a checkpoint one micro-step
+        # short resumes with the accumulation boundary shifted by one)
+        self.micro_steps += 1
+        self.global_samples += self.train_micro_batch_size_per_gpu() * self.data_parallel_world_size()
         if boundary:
-            # counted BEFORE the commit so the monitor feed (which runs in
-            # the commit's bookkeeping tail) reports this step inclusively
             self.metrics.counter("train.steps").inc()
             with self.tracer.span("train.step_commit"):
                 self._take_model_step()
-        self.micro_steps += 1
-        self.global_samples += self.train_micro_batch_size_per_gpu() * self.data_parallel_world_size()
         self.timers(STEP_GLOBAL_TIMER).stop(sync=False)
         self.tput_timer.stop(global_step=boundary)
 
@@ -2082,101 +1885,6 @@ class DeepSpeedEngine:
             f"replicated over {mc.data_outer} groups",
             ranks=[0],
         )
-
-    def _validate_multi_step(self) -> None:
-        """Reject configs a multi-step training window cannot honor
-        bit-identically (ISSUE 14). Each of these features injects per-step
-        host decisions between optimizer steps — exactly what the fused
-        window removes — so arming both is a contradiction, not a fallback:
-
-        * ``fuse_grad_accum`` off at gas>1: the window scans the fused
-          grad-accum step body; its sequential fallback steps must run the
-          same program family or the mixed run stops being bit-exact.
-        * curriculum learning (per-step sequence-shape schedule), PLD and
-          random-LTD (per-step traced kwargs / host-sampled index shapes),
-          MoQ (host re-quantizes params between steps).
-        * qgZ / offloaded optimizer or params (their step paths are
-          unfused by construction).
-        * an lr scheduler without ``state_dict``/``load_state_dict``: the
-          window pre-evaluates the schedule by snapshot→replay→restore.
-        """
-        ms = self._config.compile_config.multi_step
-        if not ms.enable:
-            return
-        if jax.process_count() > 1:
-            # the window former stages PRE-PLACED batches and stacks them
-            # device-side; a multi-process global batch cannot be re-stacked
-            # across hosts (same constraint fuse_grad_accum documents for
-            # pre-placed inputs) — reject up front with the right name
-            # instead of dying inside _place_stacked_batch mid-training
-            raise NotImplementedError(
-                "compile.multi_step currently requires a single-process run "
-                "(the window former stacks device-placed microbatches, which "
-                "multi-process global arrays do not support); disable "
-                "multi_step on multi-host launches"
-            )
-        if self.gradient_accumulation_steps() > 1 and not self._config.compile_config.fuse_grad_accum:
-            raise ValueError(
-                "compile.multi_step with gradient_accumulation_steps > 1 "
-                "requires compile.fuse_grad_accum (the window scans the "
-                "fused grad-accum step body)"
-            )
-        cl_cfg = self._config.curriculum_learning_config
-        if cl_cfg and cl_cfg.get("enabled", False):
-            raise ValueError(
-                "compile.multi_step is incompatible with curriculum_learning "
-                "(the per-step seqlen schedule changes batch shapes inside "
-                "the window)"
-            )
-        if self.progressive_layer_drop is not None:
-            raise ValueError(
-                "compile.multi_step is incompatible with "
-                "progressive_layer_drop (theta is a per-step host kwarg)"
-            )
-        if self.random_ltd_scheduler is not None:
-            raise ValueError(
-                "compile.multi_step is incompatible with random_ltd "
-                "(per-step host-sampled index shapes retrace the program)"
-            )
-        if self.quantizer is not None:
-            raise ValueError(
-                "compile.multi_step is incompatible with MoQ (the host "
-                "re-quantizes the compute store between optimizer steps)"
-            )
-        zcfg = self._config.zero_config
-        if zcfg.zero_quantized_gradients:
-            raise ValueError(
-                "compile.multi_step is incompatible with "
-                "zero_quantized_gradients (the qgZ grad path is unfused)"
-            )
-        off = zcfg.offload_optimizer
-        streamed_opt_offload = (
-            off is not None
-            and self._offload_requested(off)
-            and off.pipeline
-            and str(off.device.value) == "cpu"
-        )
-        if self._offload_requested(zcfg.offload_param):
-            raise ValueError(
-                "compile.multi_step is incompatible with offload_param "
-                "(the layer stream owns the per-microbatch update loop)"
-            )
-        if self._offload_requested(off) and not streamed_opt_offload:
-            raise ValueError(
-                "compile.multi_step is incompatible with the LEGACY host-Adam "
-                "offload (the host owns that update loop); the streamed "
-                "ZeRO-Infinity path (offload_optimizer.device=cpu with "
-                "pipeline_read/pipeline_write) composes with windows"
-            )
-        if self.lr_scheduler is not None and not (
-            hasattr(self.lr_scheduler, "state_dict")
-            and hasattr(self.lr_scheduler, "load_state_dict")
-        ):
-            raise ValueError(
-                "compile.multi_step requires an lr scheduler with "
-                "state_dict/load_state_dict (the window pre-evaluates the "
-                "schedule via snapshot -> replay -> restore)"
-            )
 
     def _validate_zeropp_config(self) -> None:
         """Consume the ZeRO++ keys (reference zero/config.py:260-272) or
@@ -2670,56 +2378,17 @@ class DeepSpeedEngine:
     def train_batch(self, data_iter=None, batch=None):
         """Convenience: run a full GAS cycle — gas × fwd/bwd + step, or,
         with ``compile.fuse_grad_accum`` on, ONE fused jitted program for
-        the whole optimizer step. With ``compile.multi_step`` armed and an
-        iterator supplied, the engine additionally forms N-step fused
-        WINDOWS: one call dispatches ``horizon`` full optimizer steps in a
-        single program, and the following N-1 calls commit the remaining
-        (already computed) steps without touching the device — the
-        training loop's step count and per-step losses are unchanged,
-        bit-identical to the unwindowed run. Armed calls return the step's
-        loss as a 0-d device array (``float()`` it to force a fetch); the
-        host-side values flow through the deferred loss drain
-        (``drained_losses()``) instead of a blocking per-step
-        ``device_get``.
+        the whole optimizer step. ``data_iter`` is pulled ``gas`` times.
 
         ``batch``, when given, is the FULL-step batch — its leading dim is
         sliced into ``gas`` microbatches (matching the pipeline engine's
         contract so the same caller works at any mesh.pipe)."""
         gas = self.gradient_accumulation_steps()
-        if self._window_stash:
-            if batch is not None:
-                raise RuntimeError(
-                    "train_batch(batch=...) called with a multi-step window "
-                    "mid-flight: the window's remaining steps already consumed "
-                    "their data; keep driving with train_batch(data_iter)"
-                )
-            return self._commit_window_step()
-        if (
-            self._window_armed
-            and data_iter is not None
-            and batch is None
-            and self._training_mode
-            and self._initialized
-            and not self._in_forward
-            and self._pending_commit is None
-            and self._param_stream is None
-        ):
-            out = self._try_train_window(data_iter)
-            if out is not _NO_WINDOW:
-                return out
         if batch is not None:
             micro = self._split_step_batch(batch, gas)
         else:
-            # an armed engine keeps pulling through its prefetching wrapper
-            # even on sequential-fallback steps, so window-pulled batches
-            # are never dropped and the staged h2d stays warm
-            src = (
-                self._window_loader(data_iter)
-                if (self._window_armed and self._training_mode and data_iter is not None)
-                else data_iter
-            )
             with self.tracer.span("train.data_fetch", gas=gas):
-                micro = [next(src) for _ in range(gas)]
+                micro = [next(data_iter) for _ in range(gas)]
         if not self._initialized:
             self.init_params(micro[0])
         if (
@@ -2871,348 +2540,6 @@ class DeepSpeedEngine:
             for g in range(gas)
         ]
 
-    # ------------------------------------------------------------------
-    # multi-step training windows (compile.multi_step; ISSUE 14)
-    # ------------------------------------------------------------------
-    def _window_loader(self, data_iter):
-        """The engine's double-buffered input pipeline: wrap the live
-        ``data_iter`` in a :class:`PrefetchingLoader` (cached by iterator
-        identity) whose ``place_fn`` is the engine's sharded ``device_put``
-        — batch i+1's h2d is enqueued while step/window i computes. The
-        wrapper snapshots ``training_dataloader``'s cursor before each
-        ahead-pull, so checkpoints cut mid-prefetch keep the PR-8
-        mid-epoch exact-resume contract (see ``_data_cursor_state``)."""
-        from deepspeed_tpu.runtime.dataloader import PrefetchingLoader
-
-        if self._active_prefetcher is not None and self._prefetch_key == id(data_iter):
-            return self._active_prefetcher
-        if (
-            self._active_prefetcher is not None
-            and data_iter is not self._active_prefetcher
-            and self._active_prefetcher.buffered()
-        ):
-            # switching iterators strands the old wrapper's staged batches:
-            # they were pulled from the PREVIOUS stream and cannot be fed
-            # into the new one. Say so — silently skipping samples is the
-            # failure mode the cursor machinery exists to prevent.
-            logger.warning(
-                f"multi_step prefetcher: a new data iterator replaces one "
-                f"with {self._active_prefetcher.buffered()} staged-but-"
-                "untrained batch(es); those samples are dropped. Drive "
-                "epochs through one continuous iterator (e.g. a "
-                "RepeatingLoader) or set compile.multi_step.prefetch=false"
-            )
-        if isinstance(data_iter, PrefetchingLoader):
-            self._active_prefetcher = data_iter
-            self._prefetch_key = id(data_iter)
-            return data_iter
-        gas = self.gradient_accumulation_steps()
-        depth = (
-            gas * self._window_horizon
-            if self._config.compile_config.multi_step.prefetch
-            else 0
-        )
-        state_source = (
-            self.training_dataloader
-            if self.training_dataloader is not None
-            and hasattr(self.training_dataloader, "state_dict")
-            else None
-        )
-        self._active_prefetcher = PrefetchingLoader(
-            data_iter, place_fn=self._place_batch, depth=depth,
-            state_source=state_source,
-        )
-        self._prefetch_key = id(data_iter)
-        return self._active_prefetcher
-
-    def _window_break(self, reason: str):
-        self._window_metrics["window_break_reasons"][reason] += 1
-        return _NO_WINDOW
-
-    def _window_lrs(self, n: int):
-        """The next ``n`` lr values the host schedule would produce, WITHOUT
-        advancing it: snapshot -> replay -> restore (load_state_dict
-        re-applies the restored lr to the param groups — the PR-8 resume
-        contract this replay leans on). The window program indexes this
-        array with its in-carry cursor, so overflow-skipped steps re-use
-        their lr exactly like the sequential path (which skips the host
-        ``lr_scheduler.step()`` on overflow)."""
-        import copy as _copy
-
-        lr0 = float(self.optimizer.param_groups[0]["lr"])
-        if self.lr_scheduler is None or n == 1:
-            return [lr0] * n
-        sd = _copy.deepcopy(self.lr_scheduler.state_dict())
-        group_lrs = [g["lr"] for g in self.optimizer.param_groups]
-        lrs = [lr0]
-        try:
-            for _ in range(n - 1):
-                self.lr_scheduler.step()
-                lrs.append(float(self.optimizer.param_groups[0]["lr"]))
-        finally:
-            self.lr_scheduler.load_state_dict(sd)
-            # load_state_dict re-applies the lr only for a scheduler that
-            # has stepped (last_batch_iteration >= 0); on a NEVER-stepped
-            # one the replay above would otherwise leak its last warmup
-            # value into the live param groups — and an all-overflow first
-            # window (the normal fp16 scale-settling phase) has no
-            # scheduler.step() to self-correct it before the next window
-            # reads lr0
-            for group, lr in zip(self.optimizer.param_groups, group_lrs):
-                group["lr"] = lr
-        return lrs
-
-    def _try_train_window(self, data_iter):
-        """Form and dispatch ONE fused N-step window, or return the
-        ``_NO_WINDOW`` sentinel (counting why in ``window_break_reasons``)
-        so the caller falls back to the bit-identical single-step path.
-        Windows only form when the whole horizon fits before the next
-        host-visible schedule event — checkpoint-interval boundary,
-        monitor flush, flops-profiler step — and a full horizon of data
-        exists; they therefore never straddle a checkpoint interval (the
-        crash contract ``train.mid_window`` chaos kills exercise)."""
-        gas = self.gradient_accumulation_steps()
-        H = self._window_horizon
-        if self.micro_steps % gas != 0:
-            return _NO_WINDOW  # mid-accumulation window: sequential owns it
-        ccfg = self._config.checkpoint_config
-        if ccfg.save_dir and ccfg.interval_steps > 0:
-            to_boundary = ccfg.interval_steps - (self.global_steps % ccfg.interval_steps)
-            if to_boundary < H:
-                return self._window_break("checkpoint")
-        if self.monitor is not None:
-            interval = (
-                self._config.monitor_config.interval_steps
-                or self._config.steps_per_print
-            )
-            to_flush = interval - (self.global_steps % interval)
-            if to_flush < H:
-                return self._window_break("monitor")
-        if self.flops_profiler is not None:
-            p = self._config.flops_profiler_config.profile_step
-            if self.global_steps <= p < self.global_steps + H:
-                return self._window_break("profiler")
-        loader = self._window_loader(data_iter)
-        if loader.fill(gas * H) < gas * H:
-            return self._window_break("data")
-        with self.tracer.span("train.window", steps=H, gas=gas):
-            with self.tracer.span("train.data_fetch", gas=gas * H):
-                micro = [next(loader) for _ in range(gas * H)]
-            with self.tracer.span("train.h2d"):
-                stacked = self._place_stacked_batch(micro)
-            lrs = np.asarray(self._window_lrs(H), np.float32)
-            if self._streamed_offload:
-                # gather the full host-resident master/moments device-ward
-                # (bucketed H2D through the same stream helpers) so the
-                # window program scans the IDENTICAL fused step body; the
-                # updated state scatters back D2H after the window commits
-                ho = self._host_offload
-                with self.tracer.span("train.offload_h2d", window=True):
-                    g_masters, g_ms, g_vs = ho.gather_device_state()
-                if self.mixed_precision:
-                    self._master = ho.unflatten(g_masters)
-                else:
-                    self._master = self._params
-                self._opt_state = AdamState(
-                    step=jax.device_put(
-                        jnp.int32(ho.step_count), self._opt_shardings.step
-                    ),
-                    exp_avg=ho.unflatten(g_ms),
-                    exp_avg_sq=ho.unflatten(g_vs),
-                )
-            window_name = f"fused_window_step_n{H}"
-            with self.tracer.span("train.dispatch", program=window_name, step=self.global_steps):
-                if self.mixed_precision:
-                    (
-                        self._params,
-                        self._master,
-                        self._opt_state,
-                        self._scale_state,
-                        self._rng,
-                        per_step,
-                        last_scale,
-                        last_rng_in,
-                    ) = self._jit_fused_window_step(
-                        self._params, self._master, self._opt_state,
-                        self._scale_state, lrs, self._rng, stacked,
-                    )
-                else:
-                    (
-                        self._master,
-                        self._opt_state,
-                        self._scale_state,
-                        self._rng,
-                        per_step,
-                        last_scale,
-                        last_rng_in,
-                    ) = self._jit_fused_window_step(
-                        self._master, self._opt_state, self._scale_state,
-                        lrs, self._rng, stacked,
-                    )
-                    self._params = self._master
-        # async loss drain: enqueue the host copies NOW; the blocking read
-        # happens one window deferred (bf16/fp32) or at window end (fp16,
-        # whose host bookkeeping needs the overflow verdicts)
-        for step_out in per_step:
-            _enqueue_host_copies(step_out)
-        # a fallback window may have lazily allocated the accumulator; the
-        # fused window neither reads nor zeroes it (same as the fused-accum
-        # path) — drop it rather than hand get_last_grads a stale tree
-        self._grad_acc = None
-        # debug-grad stash: the LAST step's entering rng and pre-update
-        # scale came back as program outputs, so get_last_grads replays the
-        # exact key/scale schedule the window consumed
-        self._last_batch = micro[-1]
-        self._last_fwd_rng = last_rng_in
-        self._last_model_kwargs = {}
-        self._last_fwd_scale = last_scale
-        self._window_metrics["window_steps"] += 1
-        self._window_metrics["windowed_opt_steps"] += H
-        self.metrics.counter("train.window_steps").inc()
-        chaos.point("train.mid_window")
-        base_step = self.global_steps
-        recs = []
-        if self._config.fp16_enabled:
-            # fp16's per-step bookkeeping (skip counters, lr-schedule
-            # advancement, the next window's lr pre-evaluation) is a
-            # function of the overflow verdicts — drain this window now.
-            # Still ONE batched fetch per N steps, vs one per step before.
-            with self.tracer.span("train.loss_drain", steps=H):
-                host_vals = jax.device_get(per_step)
-            for k, ((loss, norm, ovf), (h_loss, h_norm, h_ovf)) in enumerate(
-                zip(per_step, host_vals)
-            ):
-                recs.append({"loss": loss, "norm": norm, "ovf": bool(h_ovf)})
-                self._append_drained({
-                    "step": base_step + k + 1,
-                    "loss": float(h_loss),
-                    "grad_norm": float(h_norm),
-                    "overflow": bool(h_ovf),
-                })
-        else:
-            for loss, norm, _ovf in per_step:
-                recs.append({"loss": loss, "norm": norm, "ovf": None})
-            self._pending_drains.append({"base_step": base_step, "vals": per_step})
-            # one-window-deferred: everything up to window i-1 is surely
-            # materialized by now (its compute finished while window i was
-            # being formed), so this read does not block the pipeline
-            self._drain_pending(keep=1)
-        if self._streamed_offload:
-            # scatter the window's updated master/moments back to the host
-            # buffers; overflow-skipped steps never advanced opt.step inside
-            # the window (the where-revert restores it), so the host step
-            # counter advances by the taken steps only
-            ho = self._host_offload
-            taken = (
-                H - sum(1 for r in recs if r["ovf"])
-                if self._config.fp16_enabled
-                else H
-            )
-            with self.tracer.span("train.offload_d2h", window=True):
-                ho.scatter_device_state(
-                    jax.tree_util.tree_leaves(self._master),
-                    jax.tree_util.tree_leaves(self._opt_state.exp_avg),
-                    jax.tree_util.tree_leaves(self._opt_state.exp_avg_sq),
-                    taken,
-                )
-            # the host copies are authoritative again; drop the device set
-            self._master = None
-            self._opt_state = None
-        self._window_stash.extend(recs)
-        return self._commit_window_step()
-
-    def _commit_window_step(self):
-        """Commit ONE already-computed window step to the host bookkeeping:
-        counters, lr schedule, fp16 skip accounting, interval auto-save and
-        monitor flush (both of which, by the formation clamp, can only fire
-        at the LAST step of a window — when the counters have caught up
-        with the device state)."""
-        rec = self._window_stash.popleft()
-        gas = self.gradient_accumulation_steps()
-        self.tput_timer.start()
-        self._last_loss = rec["loss"]
-        self._last_grad_norm = rec["norm"]
-        self.micro_steps += gas
-        self.global_samples += (
-            self.train_micro_batch_size_per_gpu() * self.data_parallel_world_size() * gas
-        )
-        self.metrics.counter("train.steps").inc()
-        with self.tracer.span("train.step_commit"):
-            self._finish_step_bookkeeping(rec["ovf"])
-        self.tput_timer.stop(global_step=True)
-        return rec["loss"]
-
-    def _drain_pending(self, keep: int = 0) -> None:
-        while len(self._pending_drains) > keep:
-            pend = self._pending_drains.popleft()
-            with self.tracer.span("train.loss_drain", steps=len(pend["vals"])):
-                host = jax.device_get(pend["vals"])
-            for k, (h_loss, h_norm, h_ovf) in enumerate(host):
-                self._append_drained({
-                    "step": pend["base_step"] + k + 1,
-                    "loss": float(h_loss),
-                    "grad_norm": float(h_norm),
-                    "overflow": bool(h_ovf),
-                })
-
-    def _append_drained(self, entry: Dict[str, Any]) -> None:
-        """Append to the bounded drained-loss log, counting evictions so
-        ``drained_losses()`` can say when it is NOT the whole curve."""
-        if len(self._drained_log) == self._drained_log.maxlen:
-            self._drained_dropped += 1
-        self._drained_log.append(entry)
-
-    def flush_loss_drain(self) -> None:
-        """Force the deferred loss drain: after this, ``drained_losses()``
-        covers every committed window step. Call at end of training (or
-        before reading the full loss curve)."""
-        self._drain_pending(keep=0)
-
-    def drained_losses(self):
-        """Host-side per-step results delivered by the (deferred) window
-        loss drain: a list of ``{step, loss, grad_norm, overflow}`` dicts
-        in step order. Values are bit-identical to what per-step
-        ``device_get`` calls would have returned — only their delivery is
-        deferred. The log is BOUNDED (4096 entries): read it incrementally
-        on long runs — ``window_stats()["drained_dropped"]`` counts
-        entries the bound evicted unread, so a truncated curve is never
-        mistaken for a complete one. ``load_checkpoint`` resets the log to
-        the resumed timeline (the replayed steps re-drain); flush and read
-        before loading if the pre-load curve matters."""
-        return list(self._drained_log)
-
-    def window_stats(self) -> Dict[str, Any]:
-        """Multi-step training window telemetry, mirroring the serving
-        side's ``serve_stats()`` window block: window counts, why windows
-        broke, and ``dispatches_per_opt_step`` — total train-program
-        dispatches (from compile telemetry) over optimizer steps, the
-        number the windows exist to drive to 1/N."""
-        stats = self._telemetry.stats()
-        step_programs = {"fwd_bwd", "step", "fused_step", "fused_accum_step",
-                         "grad_stats", "offload_stats", "zero_grads"}
-        dispatches = sum(
-            rec["dispatches"]
-            for name, rec in stats.items()
-            if name in step_programs
-            or name.startswith("fused_window_step")
-            or name.startswith("offload_bucket_update")
-        )
-        return {
-            "multi_step_enabled": self._window_armed,
-            "window_horizon": self._window_horizon,
-            "window_steps": self._window_metrics["window_steps"],
-            "windowed_opt_steps": self._window_metrics["windowed_opt_steps"],
-            "opt_steps": self.global_steps,
-            "window_break_reasons": dict(self._window_metrics["window_break_reasons"]),
-            "dispatches": dispatches,
-            "dispatches_per_opt_step": (
-                dispatches / self.global_steps if self.global_steps else 0.0
-            ),
-            "pending_loss_drains": len(self._pending_drains),
-            "stashed_steps": len(self._window_stash),
-            "drained_dropped": self._drained_dropped,
-        }
-
     def offload_stream_stats(self) -> Optional[Dict[str, Any]]:
         """Cumulative H2D/D2H stream accounting for the streamed host
         offload path (``HostOffloadStreamer.stream_stats()``): wall time
@@ -3224,25 +2551,6 @@ class DeepSpeedEngine:
         if not self._streamed_offload or self._host_offload is None:
             return None
         return self._host_offload.stream_stats()
-
-    def _data_cursor_state(self):
-        """The data cursor a checkpoint should carry. When the prefetching
-        wrapper has pulled ahead of training, the TRUE cursor is the one
-        before the first undelivered batch (the wrapper's snapshot), not
-        the loader's over-advanced one — otherwise a resumed run would skip
-        the staged-but-untrained batches."""
-        pl = self._active_prefetcher
-        if (
-            pl is not None
-            and self.training_dataloader is not None
-            and getattr(pl, "_state_source", None) is self.training_dataloader
-        ):
-            return pl.state_dict()
-        if self.training_dataloader is not None and hasattr(
-            self.training_dataloader, "state_dict"
-        ):
-            return self.training_dataloader.state_dict()
-        return None
 
     # ------------------------------------------------------------------
     # checkpointing (reference: engine.py:2961 save / :2638 load)
@@ -3270,14 +2578,6 @@ class DeepSpeedEngine:
                 "save_checkpoint() called with a pending fused step: forward() "
                 "already applied the optimizer update but step() has not adopted "
                 "it (counters/lr would be inconsistent); call step() first"
-            )
-        if self._window_stash:
-            raise RuntimeError(
-                "save_checkpoint() called mid-window: the fused multi-step "
-                "program already advanced the model state but "
-                f"{len(self._window_stash)} step(s) are uncommitted "
-                "(counters/lr would be inconsistent); finish the window's "
-                "train_batch calls first"
             )
         if tag is None:
             tag = f"global_step{self.global_steps}"
@@ -3319,10 +2619,12 @@ class DeepSpeedEngine:
             # split, the data-sampler cursor, and the mesh topology (a
             # load into a different mesh fails loudly, not via reshape)
             "rng": np.asarray(jax.device_get(self._rng)),
-            # via _data_cursor_state: when the prefetching wrapper has
-            # staged batches ahead, the cursor of the first UNDELIVERED
-            # batch is saved, not the loader's over-advanced one
-            "data_cursor": self._data_cursor_state(),
+            "data_cursor": (
+                self.training_dataloader.state_dict()
+                if self.training_dataloader is not None
+                and hasattr(self.training_dataloader, "state_dict")
+                else None
+            ),
             "mesh": dict(zip(self.mesh.axis_names, map(int, self.mesh.devices.shape))),
             "ds_config": self._config._param_dict,
             "ds_version": _version(),
@@ -3377,7 +2679,12 @@ class DeepSpeedEngine:
             self._ckpt_metrics["total_stall_ms"] += stall_ms
         else:
             self.checkpoint_engine.save(state, path)
-            # the save was collective (all ranks, one shared staging dir);
+            # the save was collective (all ranks, one shared staging dir):
+            # every rank's metadata and sentinel must be down before the
+            # directory is renamed from under them (a slower rank's write
+            # into the vanished staging dir raises, and its peers then wait
+            # at the barrier below for a rank that never comes)
+            dist.barrier(name="save_checkpoint_staged")
             # the commit rename is rank 0's alone — and it happens BEFORE
             # the latest marker, which may only ever name a fully
             # committed checkpoint
@@ -3452,13 +2759,6 @@ class DeepSpeedEngine:
         ``load_module_strict`` (default) every module leaf is validated
         against the live state first — a shape/dtype/mesh mismatch raises
         one clear ``CheckpointLoadError`` naming the offending leaf."""
-        if self._window_stash:
-            raise RuntimeError(
-                "load_checkpoint() called mid-window: "
-                f"{len(self._window_stash)} computed step(s) are uncommitted; "
-                "finish the window's train_batch calls (or rebuild the "
-                "engine) before loading"
-            )
         self.wait_pending_checkpoint()
         t_load = time.perf_counter()
         state = None
@@ -3631,19 +2931,6 @@ class DeepSpeedEngine:
             and hasattr(self.training_dataloader, "load_state_dict")
         ):
             self.training_dataloader.load_state_dict(cursor)
-        # a live prefetching wrapper holds batches pulled under the OLD
-        # cursor; drop it (the next train_batch re-wraps the caller's
-        # post-resume iterator). The pending drains and the drained-loss
-        # log belong to the ABANDONED timeline — the resumed run replays
-        # (and re-drains) every step past the checkpoint, so keeping them
-        # would duplicate or contradict step numbers. Callers wanting the
-        # pre-load curve call flush_loss_drain() + drained_losses() BEFORE
-        # loading.
-        self._active_prefetcher = None
-        self._prefetch_key = None
-        self._pending_drains.clear()
-        self._drained_log.clear()
-        self._drained_dropped = 0
 
     def _validate_checkpoint_state(self, state: Dict, path: str) -> None:
         """Fail fast, with names: a checkpoint whose mesh topology or module

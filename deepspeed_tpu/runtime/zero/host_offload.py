@@ -259,40 +259,6 @@ class HostOffloadStreamer:
         host state is already authoritative — nothing to write back)."""
         self._staged.clear()
 
-    # -- window (compile.multi_step) composition ------------------------
-    def gather_device_state(self):
-        """Stream EVERY bucket device-ward for a fused multi-step window:
-        the window program wants the whole master/opt tree on device. Goes
-        through the sanctioned h2d helper bucket by bucket."""
-        for bi in range(self.num_buckets):
-            self.h2d_bucket(bi)
-        masters: List[Any] = [None] * len(self._master)
-        ms: List[Any] = [None] * len(self._master)
-        vs: List[Any] = [None] * len(self._master)
-        for bi in range(self.num_buckets):
-            staged_m, staged_ea, staged_eas = self.take_staged(bi)
-            for k, i in enumerate(self._buckets[bi]):
-                if staged_m is not None:
-                    masters[i] = staged_m[k]
-                ms[i] = staged_ea[k]
-                vs[i] = staged_eas[k]
-        return (masters if self.mixed_precision else None), ms, vs
-
-    def scatter_device_state(self, master_leaves, m_leaves, v_leaves, steps_taken: int) -> None:
-        """Stream the window's updated master/moments back host-ward, bucket
-        by bucket through the sanctioned d2h helper; the newest bucket's
-        copies stay in flight (depth-2 steady state)."""
-        for bi in range(self.num_buckets):
-            idx = self._buckets[bi]
-            self.d2h_bucket(
-                bi,
-                [master_leaves[i] for i in idx],
-                [m_leaves[i] for i in idx],
-                [v_leaves[i] for i in idx],
-            )
-            self.materialize_writes(keep=1)
-        self.step_count += int(steps_taken)
-
     # -- declared transfer schedule (the overlap pass verifies this) ----
     def stream_schedule(self) -> Dict[str, Any]:
         """The stream's declared accounting: every per-step transfer, its

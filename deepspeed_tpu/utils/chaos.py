@@ -21,13 +21,6 @@ Injection points (the canonical set — sites call ``chaos.point(NAME, ...)``):
   not yet updated
 * ``serve.mid_step``       — inside the serving scheduler step, after the
   device dispatch/emits but before the journal flush
-* ``train.mid_window``     — inside a multi-step TRAINING window
-  (``compile.multi_step``): the fused N-step program was dispatched and
-  the engine adopted the donated state, but the window's per-step losses
-  have not been drained and none of its steps committed to the counters —
-  a kill here must resume bit-identically from the last committed
-  checkpoint (windows never straddle a checkpoint interval, so that
-  checkpoint sits at or before the window's first step)
 * ``train.mid_step``       — a single optimizer step: the step program was
   dispatched and the engine adopted the donated state, but none of the
   host bookkeeping (counters, lr schedule, interval checkpoint) committed;
@@ -98,8 +91,6 @@ POINTS = (
     "serve.mid_step",
     "serve.mid_window",  # inside a multi-step window's host phase: the whole
     # window's tokens are buffered in the journal, none yet acked
-    "train.mid_window",  # training window dispatched + state adopted, loss
-    # drain not yet run and no step of the window committed to the counters
     "train.mid_step",  # a single optimizer step: the step program dispatched
     # and the donated state adopted, but the counters / lr schedule / interval
     # checkpoint not yet committed — resume must replay from the last

@@ -973,84 +973,6 @@ def test_green_multistep_window_program_and_compile_gate():
         assert passes["donation"]["ok"]
 
 
-def test_green_multistep_training_program(eight_devices):
-    """THE acceptance gate for multi-step TRAINING windows (ISSUE 14): a
-    windowed ZeRO-3 gas=2 run compiles exactly ONE window program for the
-    armed horizon, never retraces after its first window, reconciles
-    telemetry dispatches with the engine's window stats (steady-state
-    dispatches/opt-step ≤ 1/N), and the window program verifies clean
-    under donation (the FULL state tuple — params, master, opt_state,
-    loss-scale state — aliases through the lax.scan carry, zero
-    double-buffered bytes), host_transfer (0 in-program transfers: the
-    deferred loss drain is the one sanctioned fetch per window), dtype-
-    promotion, and overlap passes."""
-    import deepspeed_tpu as ds
-    import deepspeed_tpu.parallel.mesh as mesh_mod
-    from tests.unit.simple_model import SimpleModel
-
-    mesh_mod.reset_topology()
-    H = 4
-    engine, *_ = ds.initialize(
-        model=SimpleModel(),
-        config={
-            "train_micro_batch_size_per_gpu": 1,
-            "gradient_accumulation_steps": 2,
-            "optimizer": {"type": "adam", "params": {"lr": 1e-2}},
-            "zero_optimization": {"stage": 3},
-            "bf16": {"enabled": True},
-            "gradient_clipping": 1.0,
-            "compile": {
-                "fuse_grad_accum": True,
-                "multi_step": {"enable": True, "horizon": H},
-            },
-        },
-    )
-    rs = np.random.RandomState(0)
-
-    def batches(n):
-        return iter(
-            [(rs.randn(8, 16).astype(np.float32), rs.randn(8, 16).astype(np.float32))
-             for _ in range(2 * n)]
-        )
-
-    steps = 1 + 3 * H  # sequential init step + exactly 3 full windows
-    it = batches(steps)
-    compiles_after_window = []
-    for s in range(steps):
-        engine.train_batch(data_iter=it)
-        compiles_after_window.append(
-            sum(r["compiles"] for r in engine.compile_stats().values())
-        )
-    engine.flush_loss_drain()
-    stats = engine.compile_stats()
-    window_programs = [n for n in stats if n.startswith("fused_window_step")]
-    assert window_programs == [f"fused_window_step_n{H}"], stats.keys()
-    wrec = stats[window_programs[0]]
-    assert wrec["compiles"] == 1 and wrec["traces"] == 1, wrec
-    # no retrace after the first window (step 2 compiled it; every later
-    # step added nothing)
-    assert compiles_after_window[-1] == compiles_after_window[1], compiles_after_window
-    ws = engine.window_stats()
-    assert ws["window_steps"] == 3 and wrec["dispatches"] == 3
-    assert ws["windowed_opt_steps"] == 3 * H
-    # steady state: the windowed segment is exactly 1/H dispatches per step
-    assert wrec["dispatches"] / ws["windowed_opt_steps"] == 1.0 / H
-    assert ws["dispatches_per_opt_step"] <= 1.0 / H + 1.0 / ws["opt_steps"]
-    # analysis green sweep on the window program: donation aliased through
-    # the scan carry, 0 in-program host transfers, no silent upcasts, and
-    # the overlap pass happy
-    rep = engine.analysis_report()
-    t = rep["totals"]
-    assert t["analysis_failures"] == 0 and t["violations"] == 0, rep
-    assert t["donation_verified"] is True
-    wpasses = rep["programs"][window_programs[0]]["passes"]
-    for pname in ("donation", "host_transfer", "dtype_promotion", "overlap"):
-        assert wpasses[pname]["ok"], (pname, wpasses[pname])
-    don = wpasses["donation"]["summary"]
-    assert don["unhonored"] == 0 and don["double_buffered_bytes"] == 0, don
-    assert don["declared_donations"] >= 4  # params+master+opt+scale leaves
-
-
 def test_green_infinity_offload_program(eight_devices):
     """THE acceptance gate for streamed ZeRO-Infinity host offload
     (ISSUE 16): with pipeline_read AND pipeline_write on, the engine's

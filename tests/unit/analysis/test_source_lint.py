@@ -490,31 +490,18 @@ def test_r009_quiet_outside_scope():
     ]
 
 
-def test_r009_window_and_prefetch_methods_in_scope():
-    """ISSUE 14 extension: the multi-step window family (formation,
-    per-step commit, deferred drain, lr pre-evaluation) and the
-    input-pipeline Loader methods run on the same step critical path —
-    a raw clock there is the same fork of the timeline. Red."""
+def test_r009_loader_next_in_scope():
+    """An input-pipeline Loader's ``__next__`` runs once per microbatch on
+    the step's critical path — a raw clock there is the same fork of the
+    timeline as one in an engine step method. Red."""
     findings = _rules("""
         import time
-        class FooEngine:
-            def _try_train_window(self, it):
-                t0 = time.perf_counter()
-            def _commit_window_step(self):
-                return time.time()
-            def _drain_pending(self, keep=0):
-                time.monotonic()
-            def _window_lrs(self, n):
-                return time.perf_counter()
-        class PrefetchingLoader:
+        class FooLoader:
             def __next__(self):
                 t = time.perf_counter()
-            def _pull(self):
                 return time.time()
-            def fill(self, n=None):
-                device_sync()
     """)
-    assert findings.count("DS-R009") == 7
+    assert findings.count("DS-R009") == 2
 
 
 def test_r009_loader_quiet_outside_hot_methods():
@@ -522,7 +509,7 @@ def test_r009_loader_quiet_outside_hot_methods():
     and the REAL dataloader module lints clean under the extended scope."""
     assert "DS-R009" not in _rules("""
         import time
-        class PrefetchingLoader:
+        class RepeatingLoader:
             def state_dict(self):
                 return {"t": time.time()}  # not a hot-path method
         class DataLoader:
@@ -570,7 +557,7 @@ def test_r009_streamer_stream_family_in_scope():
                 return time.time()
             def materialize_writes(self, keep=0):
                 time.monotonic()
-            def gather_device_state(self):
+            def drain_writes(self):
                 device_sync()
         class FooEngine:
             def _take_streamed_offload_step(self, lr):

@@ -7,6 +7,7 @@ import os
 import socket
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -58,10 +59,17 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _run(mode, mismatch, tmp_path):
+_RANK_LIMIT_S = 60
+
+
+def _spawn_ranks(worker, tmp_path, marker, **env_extra):
+    """Two ranks of ``worker`` with one deadline for both: a rank that has
+    not ended by then is killed (and its peer with it) and the failure names
+    it, beside every rank's output. Each rank must exit 0 having printed
+    ``RANK<n> <marker>``."""
     port = _free_port()
-    procs = []
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    procs = []
     for rank in range(2):
         env = dict(os.environ)
         env.update(
@@ -71,29 +79,47 @@ def _run(mode, mismatch, tmp_path):
             WORLD_SIZE="2",
             JAX_PLATFORMS="cpu",
             XLA_FLAGS="--xla_force_host_platform_device_count=1",
-            TAG_MODE=mode,
-            TAG_MISMATCH="1" if mismatch else "0",
-            TAG_CKPT_DIR=str(tmp_path / f"ck_{mode}"),
+            **env_extra,
         )
-        procs.append(
-            subprocess.Popen(
-                [sys.executable, "-c", _WORKER],
-                env=env,
-                stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT,
-                text=True,
-                cwd=repo,
+        # output to a file: a pipe nobody reads while the peer is waited for
+        # fills, and the rank blocks in its own logging
+        with open(tmp_path / f"rank{rank}.log", "w") as log:
+            procs.append(
+                subprocess.Popen(
+                    [sys.executable, "-c", worker],
+                    env=env, stdout=log, stderr=subprocess.STDOUT, cwd=repo,
+                )
             )
-        )
-    outs = []
-    for p in procs:
+    deadline = time.monotonic() + _RANK_LIMIT_S
+    lost = []
+    for rank, p in enumerate(procs):
         try:
-            out, _ = p.communicate(timeout=240)
+            p.wait(timeout=max(0.0, deadline - time.monotonic()))
         except subprocess.TimeoutExpired:
+            lost.append(rank)
+    for p in procs:
+        if p.poll() is None:
             p.kill()
-            out, _ = p.communicate()
-        outs.append(out)
-    return procs, outs
+            p.wait()
+    outs = [(tmp_path / f"rank{rank}.log").read_text() for rank in range(2)]
+    assert not lost, f"rank(s) {lost} still running after {_RANK_LIMIT_S} s:\n" + "\n".join(
+        f"--- rank {rank} (rc {p.returncode}) ---\n{out[-2500:]}"
+        for rank, (p, out) in enumerate(zip(procs, outs))
+    )
+    for rank, (p, out) in enumerate(zip(procs, outs)):
+        assert p.returncode == 0, f"rank {rank}:\n{out[-2500:]}"
+        assert f"RANK{rank} {marker}" in out, out[-2500:]
+
+
+def _run(mode, mismatch, marker, tmp_path):
+    _spawn_ranks(
+        _WORKER,
+        tmp_path,
+        marker,
+        TAG_MODE=mode,
+        TAG_MISMATCH="1" if mismatch else "0",
+        TAG_CKPT_DIR=str(tmp_path / f"ck_{mode}"),
+    )
 
 
 _ROUNDTRIP_WORKER = r"""
@@ -131,54 +157,17 @@ print(f"RANK{rank} ROUNDTRIP", flush=True)
 def test_cross_process_zero2_checkpoint_roundtrip(tmp_path):
     """Two real processes: ZeRO-2 save -> load -> continue training (the
     multi-process global-array load path)."""
-    port = _free_port()
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
-    procs = []
-    for rank in range(2):
-        env = dict(os.environ)
-        env.update(
-            MASTER_ADDR="127.0.0.1",
-            MASTER_PORT=str(port),
-            RANK=str(rank),
-            WORLD_SIZE="2",
-            JAX_PLATFORMS="cpu",
-            XLA_FLAGS="--xla_force_host_platform_device_count=1",
-            TAG_CKPT_DIR=str(tmp_path / "ck_rt"),
-        )
-        procs.append(
-            subprocess.Popen(
-                [sys.executable, "-c", _ROUNDTRIP_WORKER],
-                env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True, cwd=repo,
-            )
-        )
-    for rank, p in enumerate(procs):
-        try:
-            out, _ = p.communicate(timeout=240)
-        except subprocess.TimeoutExpired:
-            p.kill()
-            out, _ = p.communicate()
-        assert p.returncode == 0, f"rank {rank}:\n{out[-2500:]}"
-        assert f"RANK{rank} ROUNDTRIP" in out
+    _spawn_ranks(_ROUNDTRIP_WORKER, tmp_path, "ROUNDTRIP", TAG_CKPT_DIR=str(tmp_path / "ck_rt"))
 
 
 @pytest.mark.parametrize("mode", ["Warn", "Ignore"])
 def test_matching_tags_save(mode, tmp_path):
-    procs, outs = _run(mode, mismatch=False, tmp_path=tmp_path)
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank}:\n{out}"
-        assert f"RANK{rank} SAVED" in out
+    _run(mode, mismatch=False, marker="SAVED", tmp_path=tmp_path)
 
 
 def test_mismatched_tags_fail_mode_raises(tmp_path):
-    procs, outs = _run("Fail", mismatch=True, tmp_path=tmp_path)
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank}:\n{out}"
-        assert f"RANK{rank} REJECTED" in out, out
+    _run("Fail", mismatch=True, marker="REJECTED", tmp_path=tmp_path)
 
 
 def test_mismatched_tags_warn_mode_saves(tmp_path):
-    procs, outs = _run("Warn", mismatch=True, tmp_path=tmp_path)
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank}:\n{out}"
-        assert f"RANK{rank} SAVED" in out, out
+    _run("Warn", mismatch=True, marker="SAVED", tmp_path=tmp_path)

@@ -7,10 +7,6 @@ The bit-identity contract pinned here:
 * sequential streamed training bit-matches the on-device path — losses,
   master tree, moments, fp16 scale trajectory — across
   zero{1,3} x {fp32,bf16,fp16} x gas{1,2};
-* under ``compile.multi_step`` the window program is the SAME trace on
-  both engines, so a fully-windowed run (params pre-initialized — the
-  lazy-init step would otherwise run as a sequential step) is bitwise
-  end to end, overflow-in-window included;
 * a checkpoint roundtrip and a ``train.mid_offload_stream`` chaos kill
   both resume bit-identically — torn host buffers are never trusted,
   they are rebuilt from the last committed checkpoint.
@@ -30,7 +26,13 @@ import deepspeed_tpu as ds
 import deepspeed_tpu.parallel.mesh as mesh_mod
 from deepspeed_tpu.runtime.zero.host_offload import split_offload_buckets
 from deepspeed_tpu.utils import chaos
-from tests.unit.simple_model import SimpleModel, master_snapshot, step_batch, train_steps_batch
+from tests.unit.simple_model import (
+    SimpleModel,
+    assert_same_master,
+    master_snapshot,
+    step_batch,
+    train_steps_batch,
+)
 
 # 300-element buckets split SimpleModel's 512 params into 2 buckets, so
 # every test exercises real bucket boundaries and the double-buffer depth
@@ -43,7 +45,7 @@ STREAM = {
 }
 
 
-def _cfg(offload, gas=1, stage=1, prec="bf16", multi_step=False, horizon=2, **over):
+def _cfg(offload, gas=1, stage=1, prec="bf16", **over):
     base = {
         "train_micro_batch_size_per_gpu": 1,
         "gradient_accumulation_steps": gas,
@@ -55,12 +57,8 @@ def _cfg(offload, gas=1, stage=1, prec="bf16", multi_step=False, horizon=2, **ov
         base["bf16"] = {"enabled": True}
     elif prec == "fp16":
         base["fp16"] = {"enabled": True, "initial_scale_power": 4, "hysteresis": 1}
-    if gas > 1 and (offload or multi_step):
+    if gas > 1 and offload:
         base["compile"] = {"fuse_grad_accum": True}
-    if multi_step:
-        base.setdefault("compile", {})["multi_step"] = {
-            "enable": True, "horizon": horizon,
-        }
     if offload:
         base["zero_optimization"]["offload_optimizer"] = dict(STREAM)
     base.update(over)
@@ -91,11 +89,6 @@ def _batches(gas, steps, seed=0, bad_step=None):
 def _drive(engine, data, steps):
     it = iter(list(data))
     return [float(engine.train_batch(data_iter=it)) for _ in range(steps)]
-
-
-def _assert_same_master(a, b):
-    for k in a:
-        np.testing.assert_array_equal(a[k], b[k])
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +133,7 @@ def test_streamed_bit_identical_to_on_device(eight_devices, stage, prec, gas):
     assert off._streamed_offload, "streamed engine not selected"
     assert off._host_offload.num_buckets >= 2  # real bucket boundaries
     np.testing.assert_array_equal(np.asarray(off_losses), np.asarray(ref_losses))
-    _assert_same_master(master_snapshot(off), ref_master)
+    assert_same_master(master_snapshot(off), ref_master)
 
 
 def test_fp16_overflow_reverts_bitwise_and_tracks_scale(eight_devices):
@@ -159,63 +152,7 @@ def test_fp16_overflow_reverts_bitwise_and_tracks_scale(eight_devices):
         engine.train_batch(batch=(xbad, y))
         assert engine.skipped_steps == 1, f"offload={offload}"
         assert engine.loss_scale == 8.0, f"offload={offload}"
-        _assert_same_master(master_snapshot(engine), before)
-
-
-# ---------------------------------------------------------------------------
-# bit-identity under multi_step windows
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("prec", ["bf16", "fp16"])
-@pytest.mark.parametrize("gas", [1, 2])
-def test_windowed_run_bit_identical(eight_devices, prec, gas):
-    """Fully-windowed streamed run vs fully-windowed on-device run: the
-    window program is the identical trace on both engines (the streamed
-    arm gathers master/moments device-ward, runs the SAME window, streams
-    the result back), so losses, master, skipped steps and the loss-scale
-    trajectory are bitwise. Params are pre-initialized so the lazy-init
-    step doesn't fall back to a sequential (different-program) step; the
-    fp16 arm puts an overflow INSIDE a window."""
-    steps, horizon = 6, 2
-    bad = 2 if prec == "fp16" else None
-    data = _batches(gas, steps, bad_step=bad)
-    runs = {}
-    for offload in (False, True):
-        engine = _engine(offload, gas=gas, prec=prec, multi_step=True, horizon=horizon)
-        engine.init_params(data[0])
-        losses = _drive(engine, data, steps)
-        ws = engine.window_stats()
-        assert ws["window_steps"] == steps // horizon, (offload, ws)
-        runs[offload] = (
-            losses, master_snapshot(engine), engine.skipped_steps, engine.loss_scale,
-        )
-    ref_losses, ref_master, ref_skip, ref_scale = runs[False]
-    off_losses, off_master, off_skip, off_scale = runs[True]
-    assert off_losses == ref_losses
-    assert (off_skip, off_scale) == (ref_skip, ref_scale)
-    _assert_same_master(off_master, ref_master)
-
-
-def test_window_gather_scatter_roundtrip_lossless(eight_devices):
-    """gather_device_state -> scatter_device_state with zero steps taken
-    must leave the host buffers bit-identical: the window path's framing
-    adds nothing to the state."""
-    engine = _engine(True)
-    batch = step_batch(batch_size=8, seed=0)
-    train_steps_batch(engine, batch, 1)
-    ho = engine._host_offload
-    ho.drain_writes()
-    before = (
-        [m.copy() for m in ho._master],
-        [m.copy() for m in ho._exp_avg],
-        [m.copy() for m in ho._exp_avg_sq],
-    )
-    masters, ms, vs = ho.gather_device_state()
-    ho.scatter_device_state(masters, ms, vs, steps_taken=0)
-    ho.drain_writes()
-    for got, want in zip((ho._master, ho._exp_avg, ho._exp_avg_sq), before):
-        for g, w in zip(got, want):
-            np.testing.assert_array_equal(g, w)
-    assert ho.step_count == 1
+        assert_same_master(master_snapshot(engine), before)
 
 
 # ---------------------------------------------------------------------------
@@ -283,7 +220,7 @@ def test_checkpoint_roundtrip_bit_identical(eight_devices, tmp_path):
     assert path is not None
     out = _drive(resumed, data[2:], steps - 2)
     assert out == ref_losses[2:]
-    _assert_same_master(master_snapshot(resumed), ref_master)
+    assert_same_master(master_snapshot(resumed), ref_master)
 
 
 def test_state_dict_is_host_resident_numpy(eight_devices):
@@ -378,7 +315,7 @@ def test_mid_stream_chaos_kill_resumes_bit_identical(eight_devices, tmp_path):
     it2 = iter(list(data[start:]))
     out = [float(resumed.train_batch(data_iter=it2)) for _ in range(steps - start)]
     assert out == ref_losses[start:]
-    _assert_same_master(master_snapshot(resumed), ref_master)
+    assert_same_master(master_snapshot(resumed), ref_master)
 
 
 # ---------------------------------------------------------------------------
@@ -432,21 +369,3 @@ def test_config_red_streamed_partial_ratio(eight_devices):
         "stage": 1, "offload_optimizer": over}})
     with pytest.raises(ValueError, match="ratio"):
         engine.train_batch(batch=step_batch(batch_size=8, seed=0))
-
-
-def test_red_multistep_rejects_legacy_offload_and_offload_param(eight_devices):
-    # legacy (non-pipelined) host offload cannot window: the message must
-    # point at the streamed path
-    legacy = dict(STREAM)
-    legacy["pipeline_read"] = legacy["pipeline_write"] = False
-    cfg = _cfg(False, multi_step=True)
-    cfg["zero_optimization"]["offload_optimizer"] = legacy
-    mesh_mod.reset_topology()
-    with pytest.raises(ValueError, match="pipeline"):
-        ds.initialize(model=SimpleModel(), config=cfg)
-
-    cfg = _cfg(False, multi_step=True, stage=3)
-    cfg["zero_optimization"]["offload_param"] = {"device": "cpu"}
-    mesh_mod.reset_topology()
-    with pytest.raises(ValueError, match="offload_param"):
-        ds.initialize(model=SimpleModel(), config=cfg)

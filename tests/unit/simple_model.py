@@ -116,3 +116,10 @@ def master_snapshot(engine):
     return jax.tree_util.tree_map(
         lambda x: np.asarray(jax.device_get(x)), engine.get_master_params()
     )
+
+
+def assert_same_master(a, b):
+    """Two ``master_snapshot`` trees hold the same leaves, bit for bit."""
+    assert set(a) == set(b)
+    for k in a:
+        np.testing.assert_array_equal(a[k], b[k])

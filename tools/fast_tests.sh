@@ -80,19 +80,6 @@
 # mesh, int8 weight roundtrip ≤ max|w_ch|/254 + logits-allclose bound,
 # EQuARX quantized all-reduce allclose + wire-bytes = fp/4 accounting,
 # DS-R005/DS-R007 TP-path lint extensions.
-# +multi-step TRAINING windows 2026-08-04 (test_multistep_training.py +
-# test_passes.py::test_green_multistep_training_program on the lint.sh
-# analysis suite + DS-R009 window/Loader lint extension): N-optimizer-
-# steps-per-dispatch fused windows — window vs sequential BIT-identical
-# losses/master-trees/loss-scale across zero{1,3} × {bf16, fp16-forced-
-# overflow} × gas{1,2} × horizon{2,4}, checkpoint/monitor/data/profiler
-# break accounting (windows never straddle a checkpoint interval),
-# train.mid_window chaos kill → auto_resume bit-identical, prefetching-
-# loader cursor exact-resume roundtrips, steady-state dispatches/opt-step
-# ≤ 1/N via compile telemetry + 3-wave retrace guard, deferred-loss-drain
-# value identity, mid-window protocol guards, window-program green sweep
-# (full state tuple donated THROUGH the lax.scan carry, 0 in-program host
-# transfers).
 # +ZeRO-Infinity streamed host offload 2026-08-07 (test_host_offload.py
 # rides the tests/unit/runtime/zero dir below; test_passes.py::
 # test_green_infinity_offload_program rides the lint.sh analysis suite;
@@ -100,8 +87,7 @@
 # test_source_lint.py): fp32 master + Adam moments live in pinned host
 # buffers and stream per-bucket through a depth-2 double-buffered async
 # pipeline — streamed vs on-device BIT-identical losses/master across
-# zero{1,3} × {fp32,bf16,fp16-forced-overflow} × gas{1,2}, fully-windowed
-# multi_step bit-identity (same window trace both engines), declared
+# zero{1,3} × {fp32,bf16,fp16-forced-overflow} × gas{1,2}, declared
 # stream schedule == measured bytes + 0 exposed ms with both pipeline
 # knobs on / red overlap verdict with pipeline_write off, host-resident
 # checkpoint snapshot roundtrip + streamed/legacy format guards,
@@ -137,7 +123,7 @@ sh tools/lint.sh || exit 1
 exec python -m pytest -q \
   tests/unit/runtime/test_engine.py \
   tests/unit/runtime/test_fused_grad_accum.py \
-  tests/unit/runtime/test_multistep_training.py \
+  tests/unit/runtime/test_train_batch_loop.py \
   tests/unit/runtime/test_compile_telemetry.py \
   tests/unit/runtime/test_config.py \
   tests/unit/runtime/test_lr_schedules.py \
