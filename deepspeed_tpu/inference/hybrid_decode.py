@@ -2,8 +2,10 @@
 (``models/hybrid_moe.py``): softmax layers that keep keys and values in pages,
 sliding-window layers that keep a row's newest pages in a ring, linear-attention
 layers that keep one recurrent state and a convolution tail a row, latent-attention
-layers that keep one low-rank entry a token in pages of their own; leading
-layers with a dense FFN, then every layer with its routed FFN.
+layers that keep one low-rank entry a token in pages of their own (a latent
+layer with or without a low-rank query, with rotary or none); leading layers
+with a dense FFN, a leading layer of any kind, then every layer with its
+routed FFN.
 
 ``decode.build_ragged_step`` comes here, when the program is BUILT, for a
 config that names ``layer_types``; a uniform model never reaches this file
@@ -23,7 +25,9 @@ parameter of the program) and one more row array:
 * ``store.state`` ``[linear layers, slots + 1, NH, Dk, Dv]`` float32 and
   ``store.conv`` ``[linear layers, slots + 1, K - 1, 3 NH D]``: row r's are at
   ``slots[r]``, the last slot belongs to nobody and takes what dead rows
-  write. A row whose window starts at position 0 (``lengths[r] == 0``) starts
+  write; a leading dense layer that is a linear one has the kind's first
+  entries, the scanned layers the entries behind them. A row whose window
+  starts at position 0 (``lengths[r] == 0``) starts
   from zero state inside the program, so admission, preemption and
   re-admission need no reset dispatch;
 * ``store.window_k / window_v`` ``[window layers, 1 + R * ring, NKV', P, ..]``:
@@ -200,7 +204,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         positions = jnp.take((lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]).reshape(-1), packed.slot, mode="clip")
 
     period, stacks = cfg.period, params["periods"]
-    nl, n = period.count("linear"), len(period)
+    n = len(period)
     E = cfg.num_experts
     expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[3:]), stacks["moe"]["experts"])
     moe_stacks = {k: v for k, v in stacks["moe"].items() if k != "experts"}
@@ -375,14 +379,15 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         # the per-head matrices, sliced out of their stacks ONCE a layer, outside the tile loop: inside it the
         # compiler wants them token-minor for the head-wise products and, tied to the tile as the other leaves
         # are, transposes the whole stack of every layer on every layer's entry (0.65 ms a layer: PERF.md, PR 41)
-        per_head = ("wq_b", "wk_b", "wv_b")
+        per_head = tuple(k for k in ("wq_b", "wk_b", "wv_b") if k in tree)  # a query with no low rank has no ``wq_b``
         heads = weights_at({k: tree[k] for k in per_head}, per, jk, jnp.int32(0))
         tree = {k: v for k, v in tree.items() if k not in per_head}
 
         def before(start, bufs):
             p = {**weights_at(tree, per, jk, start), **heads}
             h = _norm(packed.take(x, start), p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-            q_nope, q_rope, entry = hm.latent_project(cfg, p, h[None], packed.take(positions, start)[None])
+            at = None if positions is None else packed.take(positions, start)[None]
+            q_nope, q_rope, entry = hm.latent_project(cfg, p, h[None], at)
             q = hm.latent_absorb(cfg, p, q_nope[0], q_rope[0])  # [tile, NH, Dl]: against the stored entry
             return put(bufs[0], q.reshape(q.shape[0], NH * Dl), start), put(bufs[1], entry[0], start)
 
@@ -410,11 +415,14 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, pages, counts
 
-    def linear_layer(x, st, cv, per, jl, j):
-        layer = per * nl + jl
+    def linear_layer(x, st, cv, tree, per, jl, layer, ffn):
+        """A linear layer: ``tree``, ``per``, ``jl``, ``layer`` (its entry of
+        the state store) and ``ffn`` as ``attention_layer``'s; a row with one
+        token through ``kda_decode`` in place, a chunk row through
+        ``kda_chunked``. Returns ``(x, state, conv, counts)``."""
 
         def before(start, bufs):
-            p = weights_at(stacks["linear"], per, jl, start)
+            p = weights_at(tree, per, jl, start)
             h = _norm(packed.take(x, start), p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
             return tuple(put(buf, a, start) for buf, a in zip(bufs, hm.linear_inputs(cfg, p, h)))
 
@@ -422,7 +430,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             qkv, log_a, beta = tiles(before, (
                 unfilled((NPK, C3), dtype), unfilled((NPK, LH * LD), jnp.float32), unfilled((NPK, LH), jnp.float32),
             ))
-            cp = weights_at({k: v for k, v in stacks["linear"].items() if k.startswith("conv_")}, per, jl, jnp.int32(0))
+            cp = weights_at({k: v for k, v in tree.items() if k.startswith("conv_")}, per, jl, jnp.int32(0))
             tails = jnp.where(fresh[:, None, None], 0, cv[layer, slots])  # [B, K - 1, 3C]
             # the rows with one token: in place on the pool
             # (the narrow program wrote every row: it reads them as it always did, and compiles to the text it always did)
@@ -461,24 +469,23 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             x, counts = carry
             x_tile = packed.take(x, start)
             with jax.named_scope("linear_attention"):
-                p = weights_at(stacks["linear"], per, jl, start)
+                p = weights_at(tree, per, jl, start)
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
                 x_tile = x_tile + hm.linear_output(cfg, p, h, o(start).reshape(-1, LH, LD)).astype(x.dtype)
-            x_tile, tile_counts = ffn(x_tile[None], start, per, j)
+            x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, st, cv, counts
 
-    # a kind's pools: the full layers' pages, the window layers' rings, the latent layers' pages
-    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,)}
+    # a kind's pools: the full layers' pages, the window layers' rings, the latent layers' pages, the linear layers'
+    # states and convolution tails
+    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,), "linear": (state, conv)}
     mixers = {"softmax": functools.partial(attention_layer, "softmax"), "window": functools.partial(attention_layer, "window"),
-              "latent": latent_layer}
+              "latent": latent_layer, "linear": linear_layer}
     # the leading dense layers, each with its own weights and the first entries of its kind's pools
     for i, kind in enumerate(cfg.layer_types[: cfg.leading_dense_layers]):
         lead = params["leading"][i]
-        if kind == "linear":
-            raise NotImplementedError("a leading dense layer with a linear-attention mixer is not served")
         x, *written, _ = mixers[kind](
             x, *pools[kind], lead["mixer"], None, None, cfg.layer_types[:i].count(kind),
             functools.partial(dense_ffn, p=lead["ffn"]),
@@ -486,27 +493,23 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         pools[kind] = tuple(written)
 
     def period_step(carry, per):
-        x, st, cv, pools = carry
+        x, pools = carry
         pools = dict(pools)
         at = {kind: 0 for kind in hm.LAYER_KINDS}
         counts = []
         for j, kind in enumerate(period):
-            if kind == "linear":
-                x, st, cv, c = linear_layer(x, st, cv, per, at[kind], j)
-            else:
-                layer = cfg.leading_of(kind) + per * period.count(kind) + at[kind]
-                x, *written, c = mixers[kind](
-                    x, *pools[kind], stacks[kind], per, at[kind], layer, functools.partial(ffn, per=per, j=j)
-                )
-                pools[kind] = tuple(written)
+            layer = cfg.leading_of(kind) + per * period.count(kind) + at[kind]
+            x, *written, c = mixers[kind](
+                x, *pools[kind], stacks[kind], per, at[kind], layer, functools.partial(ffn, per=per, j=j)
+            )
+            pools[kind] = tuple(written)
             at[kind] += 1
             counts.append(c)
-        return (x, st, cv, pools), jnp.stack(counts)
+        return (x, pools), jnp.stack(counts)
 
-    (x, st, cv, pools), counts = jax.lax.scan(
-        period_step, (x, state, conv, pools), jnp.arange(cfg.num_periods, dtype=jnp.int32)
-    )
-    return x, *pools["softmax"], StateStore(st, cv, *pools["window"], *pools["latent"]), counts.reshape(cfg.num_moe_layers, E), packed
+    (x, pools), counts = jax.lax.scan(period_step, (x, pools), jnp.arange(cfg.num_periods, dtype=jnp.int32))
+    store = StateStore(*pools["linear"], *pools["window"], *pools["latent"])
+    return x, *pools["softmax"], store, counts.reshape(cfg.num_moe_layers, E), packed
 
 
 def hybrid_forward(cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots,

@@ -215,7 +215,13 @@ class StateStore(NamedTuple):
       (the pool's free list, tables and trash page 0 serve both), an entry at
       ``key_lanes`` of its width. It rides here because the serving step
       takes and returns this store in place; ``None`` for a model with no
-      such layer."""
+      such layer.
+
+    A model may have state layers AND latent layers and no layer with keys
+    and values a head: the pool's K and V arrays are then empty, a page id
+    holds a token's entry in every latent layer while every state layer holds
+    a slot's state, and ``PagePool.cache_bytes`` says which of the two the
+    live rows' bytes are in."""
 
     state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32
     conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3 NH D]
@@ -372,6 +378,25 @@ class PagePool:
         latent = None if self.states is None else self.states.latent
         return 0 if latent is None else latent.shape[0] * latent.shape[-1] * latent.dtype.itemsize
 
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """HBM bytes a slot's recurrent states and convolution tails take over the state layers, whatever its row's length."""
+        if self.states is None:
+            return 0
+        return (self.states.state.nbytes + self.states.conv.nbytes) // (self.max_slots + 1)
+
+    def cache_bytes(self) -> dict:
+        """Which of the two caches the live rows' bytes are in: the slots in
+        use times a slot's state, and the pages in use times a page's latent
+        entries. What ``serve.step`` carries; ``{}`` for a model with neither
+        (a uniform one: its pages are ``live_hbm_bytes``)."""
+        if self.states is None:
+            return {}
+        return {
+            "state_bytes_in_use": (self.max_slots - len(self._free_slots)) * self.state_bytes_per_slot,
+            "latent_bytes_in_use": self.used_pages() * self.page_size * self.latent_bytes_per_token,
+        }
+
     def live_hbm_bytes(self) -> int:
         """HBM actually pinned by live sequences (page-granular)."""
         return self.used_pages() * self.page_size * (self.cache.bytes_per_token + self.latent_bytes_per_token)
@@ -391,10 +416,13 @@ class PagePool:
         state = {}
         if self.states is not None:
             in_use = self.max_slots - len(self._free_slots)
+            cache = self.cache_bytes()
             state = {
                 # the paged (full or latent) layers' query heads; a window layer's are with its ring's entries
                 "paged_query_heads": self.query_heads["softmax"],
                 "state_total_bytes": self.states.state.nbytes + self.states.conv.nbytes,
+                "state_bytes_per_slot": self.state_bytes_per_slot,
+                "state_bytes_in_use": cache["state_bytes_in_use"],
                 "state_slots": self.max_slots,
                 "state_slots_in_use": in_use,
             }
@@ -418,7 +446,7 @@ class PagePool:
                     latent_bytes_per_token=self.latent_bytes_per_token,
                     latent_layers=latent.shape[0],
                     latent_lanes=latent.shape[-1],  # an entry's stored width (its own at whole lane tiles)
-                    latent_live_bytes=self.used_pages() * self.page_size * self.latent_bytes_per_token,
+                    latent_live_bytes=cache["latent_bytes_in_use"],
                 )
         return {
             **state,

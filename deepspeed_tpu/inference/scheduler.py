@@ -437,7 +437,11 @@ class PagedServer:
         if num_pages <= 0:
             # worst-case sizing: every slot at max length, plus the trash
             # page — no preemption can ever trigger. Shrink num_pages to
-            # oversubscribe HBM and trade it for preemptions.
+            # oversubscribe HBM and trade it for preemptions. (A page id
+            # holds a token's entry in every paged layer, softmax or
+            # latent; the state layers' store is sized by max_slots alone,
+            # so a state + latent model's pool is pages x latent bytes a
+            # token + slots x state bytes a slot: PagePool.memory_report.)
             num_pages = max_slots * (-(-max_seq // page_size)) + 1
         self.pool = PagePool(
             cfg, num_pages, page_size, max_slots,
@@ -892,6 +896,7 @@ class PagedServer:
         with self.tracer.span(
             "serve.step", waiting=waiting, running=running,
             pages_in_use=pages_in_use, pages_total=self.pool.num_pages - 1,
+            **self.pool.cache_bytes(),  # a model with state or latent layers: which cache the live rows' bytes are in
         ) as step_span:
             packed, self._packed = self._packed, None
             if packed is None:

@@ -4,7 +4,7 @@ linear attention (``ops/transformer/linear_attention.py``) in others, latent
 attention (one low-rank latent a token in place of keys and values a head) in
 others; a routed FFN that may have a shared expert and may hold only this chip's share
 of the experts its router chooses from, behind ``leading_dense_layers`` layers
-whose FFN is dense.
+whose FFN is dense: a leading layer of any kind.
 
 ``HybridMoEConfig.layer_types`` says what each layer is (``softmax`` /
 ``window`` / ``linear`` / ``latent``); after the leading dense layers the list repeats
@@ -46,15 +46,19 @@ of ``linear_conv_kernel`` taps and SiLU; per head ``q = l2norm(q~) / sqrt(Dk)``,
 dt_bias))`` a key channel; ``b = 2 sigmoid(h w_b)`` a head (``1 x`` without
 ``linear_allow_neg_eigval``); the delta rule; output
 ``(RMSNorm_head(o) * sigmoid(Wg_up (Wg_down h))) Wo``. The latent layer
-(``latent_project``, ``latent_absorb``, ``latent_output``): ``c_q = RMSNorm(h
-Wq_a)`` of ``q_lora_rank``, a query head ``[q_nope ; q_rope] = c_q Wq_b`` of
-``qk_nope_head_dim + qk_rope_head_dim`` (= ``head_dim``); ``[c ; r] = h Wkv_a``,
-``c_kv = RMSNorm(c)`` of ``kv_lora_rank`` (the norm over those alone), ``k_rope
-= RoPE(r)`` of ``qk_rope_head_dim``, ONE for all heads; a head's key is
+(``latent_project``, ``latent_absorb``, ``latent_output``), with or without a
+low-rank query, with rotary or none: ``c_q = RMSNorm(h Wq_a)`` of
+``q_lora_rank``, a query head ``[q_nope ; q_rope] = c_q Wq_b`` of
+``qk_nope_head_dim + qk_rope_head_dim`` (= ``head_dim``), or, with
+``q_lora_rank`` 0, ``h Wq`` with no low rank and no query norm; ``[c ; r] = h
+Wkv_a``, ``c_kv = RMSNorm(c)`` of ``kv_lora_rank`` (the norm over those alone),
+``k_rope = RoPE(r)`` of ``qk_rope_head_dim``, ONE for all heads; a head's key is
 ``[c_kv Wk_b,h ; k_rope]``, its value ``c_kv Wv_b,h`` of ``v_head_dim``
 (``Wk_b`` and ``Wv_b`` are the published ``kv_b_proj``'s two parts, stored
-apart); rotate-half on the rope parts at the token's absolute position, scale
-``head_dim^-0.5``. ``apply`` computes that (the expanded form); the server keeps
+apart); rotate-half on the rope parts at the token's absolute position, or,
+with ``position="none"``, ``q_rope`` and ``k_rope = r`` as projected (the
+shared features are kept, nothing is rotated); scale ``head_dim^-0.5``.
+``apply`` computes that (the expanded form); the server keeps
 ``[c_kv ; k_rope]`` a token and computes the same numbers absorbed: ``q~ =
 q_nope Wk_b,h^T`` against ``c_kv``, ``o = (P c_kv) Wv_b,h``. The FFN: ``moe_scoring``
 over ``moe_router_experts`` outputs, the ``moe_top_k`` largest of score +
@@ -108,7 +112,8 @@ class HybridMoEConfig(MoETransformerConfig):
     linear_conv_kernel: int = 4
     linear_gate_rank: int = 0  # the decay's and the output gate's low rank; 0: linear_head_dim
     linear_allow_neg_eigval: bool = True
-    # latent layers: the two low ranks and a query/key head's two parts (head_dim is their sum)
+    # latent layers: the two low ranks (q_lora_rank 0: the query has none, one ``wq`` and no query norm) and a
+    # query/key head's two parts (head_dim is their sum; ``position="none"`` leaves the second unrotated)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -142,11 +147,11 @@ class HybridMoEConfig(MoETransformerConfig):
         if not 0 <= self.leading_dense_layers < self.num_layers:
             raise ValueError(f"leading_dense_layers={self.leading_dense_layers} of {self.num_layers} layers")
         if "latent" in self.layer_types:
-            sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim)
-            if min(sizes) < 1 or self.qk_nope_head_dim + self.qk_rope_head_dim != self.head_dim or self.position != "rope":
+            sizes = (self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim)
+            if min(sizes) < 1 or self.q_lora_rank < 0 or self.qk_nope_head_dim + self.qk_rope_head_dim != self.head_dim:
                 raise ValueError(
-                    "a latent layer needs q_lora_rank, kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, "
-                    f"head_dim = their last two's sum and position='rope': got {sizes}, head_dim={self.head_dim}, position={self.position!r}"
+                    "a latent layer needs kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, head_dim = the last two's sum "
+                    f"and q_lora_rank >= 0 (0: no low rank): got {sizes}, q_lora_rank={self.q_lora_rank}, head_dim={self.head_dim}"
                 )
         self.linear_num_heads = self.linear_num_heads or self.num_heads
         self.linear_head_dim = self.linear_head_dim or self.head_dim
@@ -332,17 +337,26 @@ def linear_output(cfg: HybridMoEConfig, p, h, o):
 
 def latent_project(cfg: HybridMoEConfig, p, h, positions):
     """A latent layer's projections of the normed ``h`` [B, T, H] at
-    ``positions`` [B, T]: ``q_nope`` [B, T, NH, nope], ``q_rope`` [B, T, NH,
-    rope] rotated, and what the layer keeps of each token, ``[c_kv ; k_rope]``
-    [B, T, kv_lora_rank + rope]: the normed latent and the one rotated key part
-    all heads share."""
+    ``positions`` [B, T] (``position="none"``: unused, may be None): ``q_nope``
+    [B, T, NH, nope], ``q_rope`` [B, T, NH, rope], and what the layer keeps of
+    each token, ``[c_kv ; k_rope]`` [B, T, kv_lora_rank + rope]: the normed
+    latent and the one key part all heads share; ``q_rope`` and ``k_rope``
+    rotated under ``position="rope"``, as projected under ``"none"``. The query
+    through its low rank and norm, or, with ``q_lora_rank`` 0, ``h Wq``."""
     from deepspeed_tpu.models.transformer import _rope
 
     NH, nope, rope, C = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    c_q = _norm(qmatmul(h, p["wq_a"]), p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps)
-    q = qmatmul(c_q, p["wq_b"]).reshape(h.shape[:-1] + (NH, nope + rope))
+    if cfg.q_lora_rank:
+        q = qmatmul(_norm(qmatmul(h, p["wq_a"]), p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps), p["wq_b"])
+    else:
+        # the head split kept apart from the matmul (as ``decode._paged_layers.project`` keeps a softmax layer's): folded into
+        # it, the compiler wants ``wq`` head-major and copies the layer's 28 MB to that layout every step
+        q = jax.lax.optimization_barrier(qmatmul(h, p["wq"]))
+    q = q.reshape(h.shape[:-1] + (NH, nope + rope))
     kv = qmatmul(h, p["wkv_a"])
     c_kv = _norm(kv[..., :C], p["kv_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+    if cfg.position != "rope":  # nothing is rotated: the shared features as projected
+        return q[..., :nope], q[..., nope:], jnp.concatenate([c_kv, kv[..., C:]], axis=-1)
     k_rope = _rope(kv[..., None, C:], positions, cfg.rope_theta)[..., 0, :]
     return q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta), jnp.concatenate([c_kv, k_rope], axis=-1)
 
@@ -446,11 +460,14 @@ class HybridMoETransformerLM(MoETransformerLM):
                 }
             if kind == "latent":
                 Cq, C, nope, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+                if Cq:
+                    query = {"wq_a": dense(lead + (H, Cq)), "q_norm_scale": jnp.ones(lead + (Cq,)),
+                             "wq_b": dense(lead + (Cq, NH * (nope + rope)))}
+                else:  # no low rank: one matrix, no query norm
+                    query = {"wq": dense(lead + (H, NH * (nope + rope)))}
                 return {
                     "attn_norm_scale": jnp.ones(lead + (H,)),
-                    "wq_a": dense(lead + (H, Cq)),
-                    "q_norm_scale": jnp.ones(lead + (Cq,)),
-                    "wq_b": dense(lead + (Cq, NH * (nope + rope))),
+                    **query,
                     "wkv_a": dense(lead + (H, C + rope)),
                     "kv_norm_scale": jnp.ones(lead + (C,)),
                     "wk_b": dense(lead + (C, NH * nope)),
@@ -731,4 +748,44 @@ def laguna_config(size: str = "s-2.1", **overrides) -> HybridMoEConfig:
     if "layer_types" not in base:
         # layer_types: full_attention at 0 and then every fourth
         base["layer_types"] = ["softmax" if i % 4 == 0 else "window" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)
+
+
+def kimi_linear_config(size: str = "48b-a3b", **overrides) -> HybridMoEConfig:
+    """Kimi-Linear-48B-A3B (``moonshotai/Kimi-Linear-48B-A3B-Instruct``
+    ``config.json``, ``model_type: kimi_linear``): 27 layers, published layers
+    4, 8, ..., 24 and 27 (counted from 1) latent attention of 32 heads with a
+    query and key of 128 + 64 features, NONE rotated (``mla_use_nope``), a value
+    of 128 over a latent of 512 and a query with no low rank (``q_lora_rank``
+    null); the other 20 gated delta-rule linear attention of 32 heads of 128
+    with a 4-tap short convolution, ``b = sigmoid`` (no ``allow_neg_eigval``);
+    layer 1 a dense SwiGLU FFN of 9,216 under a LINEAR mixer, layers 2-27 256
+    SwiGLU experts of 1,024, 8 a token by sigmoid scores with a selection bias,
+    gates normalised and times 2.446, one shared expert. ``48b-a3b`` is the
+    published model whole, which no whole number of periods makes (26 layers
+    behind the leading one, the last two a part of a period): ``period`` is
+    then the 26 layers themselves, one trip of the scan; a benchmark
+    configuration holds 1 + 4 n layers. ``tiny`` a toy of one chip's share (4
+    of 16 experts held) with the leading layer and two periods for tests."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=9, num_heads=4, num_kv_heads=4, head_dim=24, v_head_dim=16,
+                     kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, linear_num_heads=4, linear_head_dim=16,
+                     linear_gate_rank=8, vocab_size=512, max_seq_len=256, intermediate_size=96,
+                     expert_intermediate_size=32, num_experts=4, moe_router_experts=16, moe_expert_share=(0, 4), moe_top_k=3),
+        "48b-a3b": dict(hidden_size=2304, num_layers=27, num_heads=32, num_kv_heads=32, head_dim=192, v_head_dim=128,
+                        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, linear_num_heads=32,
+                        linear_head_dim=128, linear_gate_rank=128, vocab_size=163840, max_seq_len=1048576,
+                        intermediate_size=9216, expert_intermediate_size=1024, num_experts=256, moe_top_k=8),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="none", activation="swiglu", use_bias=False, tie_embeddings=False,
+        q_lora_rank=0, linear_conv_kernel=4, linear_allow_neg_eigval=False, leading_dense_layers=1, moe_layer_freq=1,
+        moe_drop_tokens=False, moe_norm_topk_prob=True, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_shared_experts=1, moe_routed_scaling=2.446,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        full_attn_layers = (4, 8, 12, 16, 20, 24, 27)  # counted from 1, as published; the others are kda_layers
+        base["layer_types"] = ["latent" if i + 1 in full_attn_layers else "linear" for i in range(base["num_layers"])]
     return HybridMoEConfig(**base)

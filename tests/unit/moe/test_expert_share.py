@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models.hybrid_moe import (
-    HybridMoETransformerLM, glm4_moe_lite_config, laguna_config, mimo_v2_config, moe_ffn, solar_open2_config,
+    HybridMoETransformerLM, glm4_moe_lite_config, kimi_linear_config, laguna_config, mimo_v2_config, moe_ffn, solar_open2_config,
 )
 from deepspeed_tpu.moe.routed_ffn import route, routed_ffn
 
@@ -273,3 +273,55 @@ def test_sixteen_shares_of_a_softmax_router_plus_the_shared_expert_once_are_the_
     norm = tokens * jax.lax.rsqrt(jnp.mean(tokens * tokens, -1, keepdims=True) + 1e-6)
     share3, _ = moe_ffn(dataclasses.replace(whole_cfg, num_experts=2, moe_expert_share=(3, 16)), {**p, "experts": mine}, norm.reshape(h.shape))
     assert float(jnp.abs(out.reshape(h.shape) - share3).max()) < TOL
+
+
+@pytest.mark.parametrize("share", [0, 3, 7])
+def test_eight_shares_of_a_router_over_256_plus_the_shared_expert_once_are_the_uncut_model_layer(share):
+    """Kimi-Linear's FFN at its router's PUBLISHED width: top-8 of 256 by
+    sigmoid scores with a selection bias, weights normalised over the eight and
+    times 2.446, one shared expert. Eight configs that differ in
+    ``moe_expert_share`` alone, each given its 32 of the uncut layer's 256
+    experts: the routed parts (each share's output minus the shared expert's)
+    plus the shared expert counted ONCE are the uncut layer, which is 2.446
+    times the brute-force weighted sum plus the shared expert; and the plain
+    reference's router and loop over ``share``'s 32 held experts give that
+    share's part, the factor inside the weights."""
+    from benchmark.files import load_module
+    from deepspeed_tpu.moe.experts import apply_dense_ffn
+
+    whole_cfg = kimi_linear_config("tiny", num_experts=256, moe_router_experts=256, moe_expert_share=(0, 1), moe_top_k=8, dtype="float32")
+    assert (whole_cfg.moe_routed_scaling, whole_cfg.moe_shared_experts, whole_cfg.moe_scoring, whole_cfg.moe_select_bias) == (2.446, 1, "sigmoid", True)
+    lm = HybridMoETransformerLM(whole_cfg)
+    p = jax.tree_util.tree_map(lambda a: a[1, 2], jax.jit(lambda key: lm.init(key, None)["periods"]["moe"])(jax.random.PRNGKey(3)))
+    p = {**p, "gate": {"wg": p["gate"]["wg"] * 6.0, "bias": p["gate"]["bias"]}}  # router logits of std ~1, as at the published width
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, whole_cfg.hidden_size))
+    whole, counts = moe_ffn(whole_cfg, p, h)
+    assert counts.shape == (256,) and int(counts.sum()) == 2 * 24 * 8
+    shared = apply_dense_ffn(p["shared"], h, "swiglu")
+    tokens = h.reshape(-1, whole_cfg.hidden_size)
+    weights = 2.446 * _weights(tokens @ p["gate"]["wg"], p["gate"]["bias"], k=8)
+    assert float(jnp.abs(_brute(p["experts"], tokens, weights).reshape(h.shape) + shared - whole).max()) < TOL
+    # without the factor the layer is another one, by far more than the tolerance
+    assert float(jnp.abs(_brute(p["experts"], tokens, weights / 2.446).reshape(h.shape) + shared - whole).max()) > 100 * TOL
+    total = shared
+    for index in range(8):
+        cfg = dataclasses.replace(whole_cfg, num_experts=32, moe_expert_share=(index, 8))
+        mine = {**p, "experts": jax.tree_util.tree_map(lambda a: a[index * 32 : index * 32 + 32], p["experts"])}
+        out, held = moe_ffn(cfg, mine, h)
+        assert held.shape == (32,) and np.array_equal(held, counts[index * 32 : index * 32 + 32])
+        total = total + (out - shared)
+        if index == share:
+            mine_out = out
+    assert float(jnp.abs(total - whole).max()) < TOL
+    # the reference's routed FFN for this share: its router over the whole width, its loop over the 32 held
+    ref = load_module("reference", "kimi_linear_decoder")
+    gate = {"mlp_norm_scale": jnp.ones((whole_cfg.hidden_size,)), "gate": p["gate"], "shared": p["shared"]}
+    hn, w, out = ref._router(tokens, gate, arch_key=(("experts_per_token", 8), ("norm_eps", 1e-5), ("routed_scaling", 2.446)))
+    first = share * 32
+    for e in range(32):
+        out = ref._add_expert(out, hn, w[..., first + e], *(p["experts"][name][first + e] for name in ("w_gate", "w_up", "w_out")))
+    held_cfg = dataclasses.replace(whole_cfg, num_experts=32, moe_expert_share=(share, 8))
+    mine = {**p, "experts": jax.tree_util.tree_map(lambda a: a[first : first + 32], p["experts"])}
+    normed, _ = moe_ffn(held_cfg, mine, hn.reshape(h.shape))
+    assert float(jnp.abs(out.reshape(h.shape) - normed).max()) < TOL
+    assert float(jnp.abs(mine_out - shared).max()) > 100 * TOL  # the share's routed part is no rounding

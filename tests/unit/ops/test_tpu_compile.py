@@ -780,3 +780,83 @@ def test_laguna_step_reads_its_mixers_matrices_where_they_lie(v5e, monkeypatch, 
     else:
         assert not _slab_sized_fills(text, rows * width)
         assert memory.temp_size_in_bytes < 0.60e9  # 0.592 GB before: the same buffers, allocated and not filled
+
+
+_KIMI_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/kimi-linear-48b-a3b-l13-ep8.json"
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_kimi_linear_step_fits_with_state_and_latent_pages_in_place(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the Kimi-Linear cell's shapes, both programs
+    (a leading dense layer that is a KDA one and three periods ``[KDA, KDA,
+    MLA, KDA]``: 10 delta-rule layers of 32 heads of 128 on a state store of 65
+    slots, 3 latent layers of 32 heads over an unrotated entry of 576 stored
+    at 640 lanes on 4,097 pages of 64, a query with no low rank, 32 held
+    experts of 1,024 of a router over 256 and a shared one, 64 rows): it
+    compiles for a v5e; the state, the convolution tails and the ONE latent
+    pool stay aliased in to out; ``kda_decode`` runs at 32 heads in the
+    leading layer and in the scan's body, the latent kernel once a latent
+    layer of the text (twice in the wide program) and the accepted ragged
+    kernel not at all; weights + pools are the configuration file's 9.3 GB and
+    the temporaries fit beside them; no slice of a mixer's matrix is written
+    out and nothing fills a 64 x 128-slot buffer."""
+    from deepspeed_tpu.inference import hybrid_decode
+    from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes
+    from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
+
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.latent_attention",
+                   "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.ops.transformer.linear_attention"):
+        __import__(module)
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = json.loads(_KIMI_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = HybridMoEConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    assert (cfg.num_periods, cfg.period, cfg.leading_of("linear"), cfg.q_lora_rank, cfg.position) == (3, ("linear", "linear", "latent", "linear"), 1, 0, "none")
+
+    def on_v5e(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape), params)
+    no_kv = on_v5e((0, rows * maxp + 1, cfg.num_kv_heads, page, key_lanes(cfg.head_dim)))  # no softmax layer: no K, no V
+    shapes = hybrid_decode.state_shapes(cfg, rows)
+    assert shapes.state == (10, 65, 32, 128, 128) and shapes.conv == (10, 65, 3, 3 * 32 * 128)
+    latent = on_v5e((3, rows * maxp + 1, page, key_lanes(cfg.latent_width)))
+    store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv), None, None, latent)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), no_kv, no_kv, store,
+        on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+    first_pool = len(jax.tree_util.tree_leaves(params)) + 1
+    assert {first_pool + 2, first_pool + 3, first_pool + 4} <= parse_input_output_aliases(text)  # k, v (empty), then state, conv, latent
+    memory = compiled.memory_analysis()
+    print(f"kimi w{width}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert 9.25e9 < memory.argument_size_in_bytes < 9.40e9  # 6.90 GB of weights, 1.41 GB of state and tails, 1.01 GB of latent pages
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*? custom-call\(", text, flags=re.M)
+    assert sum(name.startswith("kda_decode") for name in kernels) == 4, kernels  # the leading layer's and the body's three
+    assert sum(name.startswith("latent_paged_attention") for name in kernels) == (1 if width == 1 else 2), kernels
+    assert not any(name.startswith("ragged_paged_attention") for name in kernels), kernels
+    assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
+    # the state's operand is the whole store at 32 heads: [layers, slots, 32, 128, 128] float32, never copied (a chunk
+    # row's new state goes in by a dynamic-update-slice fusion, in place)
+    assert re.search(r"f32\[10,65,32,128,128\]\S* custom-call\(", text) and not re.search(r"= f32\[10,65,32,128,128\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[3,4097,64,640\]\S* (copy|fusion)\(", text)
+    H = cfg.hidden_size
+    wq = {(H, 32 * 192), (32 * 192, H)}  # a latent layer's query matrix, no low rank in front of it: 28 MB
+    if width == 1:
+        # no layer's slice of a mixer's matrix is written out, in either layout (a KDA layer's Wq Wk Wv Wo and a latent
+        # layer's Wo are [2304, 4096] or its transpose). Without the barrier behind ``h Wq`` (hm.latent_project) the
+        # compiler folds the head split into the matmul, writes the layer's Wq out and copies it head-major: 0.138 GB
+        assert not _mixer_matrices_written_out(text, wq | {(H, 4096), (4096, H)})
+        assert memory.temp_size_in_bytes < 0.10e9  # 0.091 GB
+    else:
+        # (the wide program stages each linear layer's and each latent layer's Wo [4096, 2304] in fast memory by a fusion
+        # inside its tile loops, as Solar-Open2's does: PERF.md section 7)
+        assert not _mixer_matrices_written_out(text, wq)
+        assert not _slab_sized_fills(text, rows * width)
+        assert memory.temp_size_in_bytes < 1.45e9  # 1.388 GB: the chunk rows' kda_chunked and the 64 x 128-slot buffers, unfilled
