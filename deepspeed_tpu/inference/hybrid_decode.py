@@ -23,7 +23,8 @@ parameter of the program) and one more row array:
   only the softmax layers have pages under the page table; a key head wider
   than a lane tile is stored at whole tiles (``kv_pool.key_lanes``: 192 at 256);
 * ``store.state`` ``[linear layers, slots + 1, NH, Dk, Dv]`` float32 and
-  ``store.conv`` ``[linear layers, slots + 1, K - 1, 3 NH D]``: row r's are at
+  ``store.conv`` ``[linear layers, slots + 1, K - 1, 3, NH, D]`` (a head's
+  channels on the lanes, as ``kda_decode`` reads them): row r's are at
   ``slots[r]``, the last slot belongs to nobody and takes what dead rows
   write; a leading dense layer that is a linear one has the kind's first
   entries, the scanned layers the entries behind them. A row whose window
@@ -71,11 +72,15 @@ tiles, as in ``decode._paged_layers``; a window of at most one tile is one
   q_rope]`` against the stored entry, the output ``(P c_kv) Wv_b`` and then
   ``Wo``; the kernel reads a page once and writes the step's entries in place;
 * linear: a row with ONE token (a decode row, in the narrow program or
-  riding in a wide window) goes through ``kda_decode``, in place on the
-  pool; a row with more (a prefill chunk) goes through the chunkwise form
+  riding in a wide window) goes through ``kda_decode`` from its projections
+  on: the kernel reads the row's pre-convolution ``q~ k~ v~``, log decay and
+  ``b`` as the tile loop wrote them and the row's tail and state where they
+  lie in the pools, and writes both back in place, so the narrow program
+  gathers no tail, builds no operand and scatters nothing; a row with more (a
+  prefill chunk) goes through ``hm.short_conv`` and the chunkwise form
   (``kda_chunked``), one row a trip of a loop whose count is data: one read
-  and one write of a row's state a layer. (Four rows a trip, of which a steady
-  mixed step fills one, read 41 ms a mixed step for 35: PERF.md, PR 31.)
+  and one write of a row's state and tail a layer. (Four rows a trip, of which
+  a steady mixed step fills one, read 41 ms a mixed step for 35: PERF.md, PR 31.)
 """
 
 from __future__ import annotations
@@ -105,12 +110,12 @@ class StateShapes(NamedTuple):
     """The per-slot store of a config's state layers, for ``max_slots`` rows."""
 
     state: tuple  # [linear layers, slots + 1, NH, Dk, Dv], float32
-    conv: tuple  # [linear layers, slots + 1, K - 1, 3 NH D], the activations' type
+    conv: tuple  # [linear layers, slots + 1, K - 1, 3, NH, D], the activations' type
 
 
 def state_shapes(cfg, max_slots: int) -> StateShapes:
     n, NH, D = cfg.layers_of("linear"), cfg.linear_num_heads, cfg.linear_head_dim
-    return StateShapes((n, max_slots + 1, NH, D, D), (n, max_slots + 1, cfg.linear_conv_kernel - 1, 3 * NH * D))
+    return StateShapes((n, max_slots + 1, NH, D, D), (n, max_slots + 1, cfg.linear_conv_kernel - 1, 3, NH, D))
 
 
 def window_shapes(cfg, max_slots: int, page_size: int, ring: int):
@@ -212,12 +217,10 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
     LH, LD, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_conv_kernel
     C3 = 3 * LH * LD
     scale = decode._softmax_scale(cfg, D)
-    NS = state.shape[1]
 
     fresh = lengths == 0
     one_token = q_lens == 1
     starts = packed.index[:, 0]  # a row's first packed token
-    write_slot = jnp.where(q_lens > 0, slots, NS - 1)
     if T > 1:
         # the rows with a chunk first: the loops over them run as many times as there are
         chunk_rows = q_lens > 1
@@ -431,39 +434,43 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
                 unfilled((NPK, C3), dtype), unfilled((NPK, LH * LD), jnp.float32), unfilled((NPK, LH), jnp.float32),
             ))
             cp = weights_at({k: v for k, v in tree.items() if k.startswith("conv_")}, per, jl, jnp.int32(0))
-            tails = jnp.where(fresh[:, None, None], 0, cv[layer, slots])  # [B, K - 1, 3C]
-            # the rows with one token: in place on the pool
-            # (the narrow program wrote every row: it reads them as it always did, and compiles to the text it always did)
+            taps = jnp.stack([cp["conv_q"], cp["conv_k"], cp["conv_v"]], axis=1).reshape(K, 3, LH, LD)
+            # the rows with one token: from the projections to the recurrence's output in one kernel, in place on both
+            # pools (the narrow program wrote every row of its buffers and reads them as they lie)
             qkv1, log_a1, beta1 = (a[starts] if T == 1 else real_rows(a, starts, one_token) for a in (qkv, log_a, beta))
             with jax.named_scope("kda_recurrence"):
-                q1, k1, v1 = hm.linear_qkv(cfg, hm.short_conv(cp, tails, qkv1[:, None])[:, 0])
-                o, st = kda_decode(q1, k1, v1, log_a1.reshape(B, LH, LD), beta1, st, layer, slots, one_token, fresh)
+                o, st, cv = kda_decode(
+                    qkv1.reshape(B, 3, LH, LD), log_a1.reshape(B, LH, LD), beta1, taps, st, cv, layer, slots, one_token, fresh
+                )
             o = o.astype(dtype)  # [B, LH, LD]
             if T == 1:
                 o = functools.partial(slab_rows, o)
             else:
 
                 def chunk_row(i, carry):
-                    st, chunks = carry
+                    st, cv, chunks = carry
                     r = order[i]
                     idx = packed.index[r]  # [T]
                     valid = jnp.arange(T, dtype=jnp.int32) < q_lens[r]
-                    q, k, v = hm.linear_qkv(cfg, hm.short_conv(cp, tails[r][None], real_rows(qkv, idx, valid)[None]))
+                    own = (layer, slots[r]) + (0,) * (cv.ndim - 2)
+                    tail = jnp.where(fresh[r], 0, jax.lax.dynamic_slice(cv, own, (1, 1) + cv.shape[2:]).reshape(1, K - 1, C3))
+                    row_qkv = real_rows(qkv, idx, valid)  # [T, 3C]
+                    q, k, v = hm.linear_qkv(cfg, hm.short_conv(cp, tail, row_qkv[None]))
                     la = real_rows(log_a, idx, valid).reshape(1, T, LH, LD)
                     b = real_rows(beta, idx, valid)[None]
                     S0 = jnp.where(fresh[r], 0.0, st[layer, slots[r]].astype(jnp.float32))[None]
                     with jax.named_scope("kda_recurrence"):
                         o_row, S = kda_chunked(q, k, v, la, b, S0)
                     st = jax.lax.dynamic_update_slice(st, S[None].astype(st.dtype), (layer, slots[r], 0, 0, 0))
-                    return st, put_chunk(chunks, o_row.reshape(T, LH * LD), idx[0], valid)
+                    # the convolution's tail after the chunk: the last K - 1 of (old tail, the row's tokens)
+                    at = q_lens[r] + jnp.arange(K - 1, dtype=jnp.int32)  # in that sequence
+                    from_row = jnp.take(row_qkv, jnp.clip(at - (K - 1), 0, T - 1), axis=0)
+                    last = jnp.where((at >= K - 1)[:, None], from_row, jnp.take(tail[0], jnp.minimum(at, K - 2), axis=0))
+                    cv = jax.lax.dynamic_update_slice(cv, last.reshape((1, 1) + cv.shape[2:]).astype(cv.dtype), own)
+                    return st, cv, put_chunk(chunks, o_row.reshape(T, LH * LD), idx[0], valid)
 
-                st, chunks = jax.lax.fori_loop(0, n_chunk_rows, chunk_row, (st, unfilled((NPK, LH * LD), dtype)))
+                st, cv, chunks = jax.lax.fori_loop(0, n_chunk_rows, chunk_row, (st, cv, unfilled((NPK, LH * LD), dtype)))
                 o = functools.partial(rows_output, chunks, o.reshape(B, LH * LD))
-            # the convolution's tail after the window: the last K - 1 of (old tail, the row's tokens)
-            at = q_lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]  # [B, K - 1], in that sequence
-            from_window = jnp.take(qkv, jnp.clip(starts[:, None] + at - (K - 1), 0, NPK - 1), axis=0)
-            from_tail = jnp.take_along_axis(tails, jnp.minimum(at, K - 2)[..., None], axis=1)
-            cv = cv.at[layer, write_slot].set(jnp.where((at >= K - 1)[..., None], from_window, from_tail))
 
         def after(start, carry):
             x, counts = carry

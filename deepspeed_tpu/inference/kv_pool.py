@@ -224,7 +224,7 @@ class StateStore(NamedTuple):
     live rows' bytes are in."""
 
     state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32
-    conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3 NH D]
+    conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3, NH, D]
     window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk]
     window_v: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None  # [latent layers, num_pages, P, lanes]

@@ -29,12 +29,22 @@ Three forms of the same mathematics:
   ``exp(g_t - g_s) exp(g_s - g_i)``, both factors at most one, so the
   off-diagonal blocks stay matrix products. A dead position (``log_a`` 0,
   ``beta`` 0) leaves the state as it is.
-* ``kda_decode``: one token a row, in place on the state pool
-  ``[L, slots + 1, H, Dk, Dv]``: a Pallas kernel on the TPU (one read and one
-  write of a live row's state; the pool is aliased in to out), a gather and a
-  scatter elsewhere. Row r's state is ``pool[layer, slots[r]]``; a row with
-  ``fresh[r]`` starts from zero, whatever the pool holds; a dead row is sent
-  to the pool's last slot, which no request owns.
+* ``kda_decode``: one token a row, everything between a linear layer's
+  projections and its output gate, in place on the state pool
+  ``[L, slots + 1, H, Dk, Dv]`` and on the pool of the short convolution's
+  tails ``[L, slots + 1, K - 1, 3, H, D]``: a Pallas kernel on the TPU (both
+  pools aliased in to out), a gather and a scatter elsewhere. The kernel
+  takes the row as the projections leave it, the 128 channels of a head on
+  the lanes: the pre-convolution ``q~ k~ v~`` ``[R, 3, H, D]``, the log decay
+  ``[R, H, D]``, ``b`` among the scalars. A grid step is one row's
+  ``HEAD_BLOCK`` heads: the taps and SiLU on (tail, token), the l2 norms,
+  ``exp``, the tail shifted and written back, one transposition of the
+  block's ``[3 HEAD_BLOCK, D]`` tile of decay, k and q (the state has the
+  key channel on its sublanes, so a head's three vectors are columns), then
+  one read and one write of each head's state. Row r's state and tail are
+  at ``slots[r]``; a row with ``fresh[r]`` starts from zero state and a zero
+  tail, whatever the pools hold; a dead row is sent to the pools' last slot,
+  which no request owns.
 """
 
 from __future__ import annotations
@@ -51,7 +61,7 @@ from deepspeed_tpu.accelerator import on_tpu
 
 CHUNK = 64
 SUB = 16
-HEAD_BLOCK = 8  # heads a grid step of the decode kernel: 512 KB of state in, 512 KB out
+HEAD_BLOCK = 16  # heads a grid step of the decode kernel: 1 MB of state in, 1 MB out, a tile of a bfloat16 operand's sublanes
 _HIGHEST = jax.lax.Precision.HIGHEST  # the recurrence is float32 throughout
 
 
@@ -134,77 +144,118 @@ def kda_chunked(q, k, v, log_a, beta, state, chunk: int = CHUNK):
     return o[:, :T], state
 
 
-# --- one token a row, in place on the pool -------------------------------------
+# --- one token a row, in place on the pools ------------------------------------
 
 
-def _decode_kernel(meta, kq_ref, v_ref, s_ref, o_ref, s_out, *, rows: int):
-    r = pl.program_id(0)
+def decode_qkv(w, taps):
+    """What a one-token row's ``q k v`` are made of its pre-convolution token
+    and tail: ``taps`` the ``K`` inputs, oldest first, each ``[..., 3, h, D]``,
+    ``w`` ``[K, 3, h, D]``. The depthwise convolution and SiLU, the l2 norm of
+    q and k over ``D`` and q's ``D ** -0.5``, all float32, term for term what
+    ``models/hybrid_moe.py`` (``short_conv``, ``linear_qkv``) computes of a window."""
+    y = jax.nn.silu(sum(w[j].astype(jnp.float32) * x.astype(jnp.float32) for j, x in enumerate(taps)))
+    q, k, v = (y[..., i, :, :] for i in range(3))
+    unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+    return unit(q) * (q.shape[-1] ** -0.5), unit(k), v
+
+
+def _decode_kernel(meta, x_ref, la_ref, w_ref, s_ref, t_ref, o_ref, s_out, t_out, cols, *, rows: int, heads: int):
+    g, r = pl.program_id(0), pl.program_id(1)
+    HB, K1 = HEAD_BLOCK, t_ref.shape[2]
     fresh = meta[1 + rows + r] != 0
-    for j in range(HEAD_BLOCK):
-        a, k, kb, q = (kq_ref[0, 0, i][:, j : j + 1] for i in range(4))  # [Dk, 1] each
+    token = x_ref[0]  # [3, HB, D]
+    taps = [jnp.where(fresh, 0, t_ref[0, 0, j]) for j in range(K1)] + [token]
+    q, k, v = decode_qkv(w_ref[...], taps)  # [HB, D] each, a head a sublane
+    for j in range(K1):  # the tail, shifted by the token
+        t_out[0, 0, j] = taps[j + 1].astype(t_out.dtype)
+    # the key-indexed vectors as columns, for a head's state [Dk, Dv] has the key channel on its sublanes: one
+    # transposition of the block's [3 HB, Dk] tile, on the matrix unit, exact at HIGHEST (I X^T)
+    Dk = q.shape[-1]
+    x = jnp.concatenate([jnp.exp(la_ref[0]), k, q], axis=0)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (Dk, Dk), 0) == jax.lax.broadcasted_iota(jnp.int32, (Dk, Dk), 1)).astype(jnp.float32)
+    cols[...] = jax.lax.dot_general(eye, x, (((1,), (1,)), ((), ())), precision=_HIGHEST, preferred_element_type=jnp.float32)
+    for j in range(HB):
+        a, kc, qc = (cols[:, i * HB + j : i * HB + j + 1] for i in range(3))  # [Dk, 1] each
+        # b rides among the scalars as its bits, and is a float again once it lies along a row's lanes
+        beta = jax.lax.bitcast_convert_type(jnp.full((1, Dk), meta[1 + 2 * rows + r * heads + g * HB + j]), jnp.float32)
         S = jnp.where(fresh, 0.0, s_ref[0, 0, j].astype(jnp.float32)) * a
-        w = v_ref[0, j : j + 1, :] - jnp.sum(S * k, axis=0, keepdims=True)
-        S = S + kb * w
-        o_ref[0, j : j + 1, :] = jnp.sum(S * q, axis=0, keepdims=True)
+        u = beta * (v[j : j + 1, :] - jnp.sum(S * kc, axis=0, keepdims=True))
+        S = S + kc * u
+        o_ref[0, j : j + 1, :] = jnp.sum(S * qc, axis=0, keepdims=True)
         s_out[0, 0, j] = S.astype(s_out.dtype)
 
 
-def _decode_pallas(q, k, v, a, beta, pool, meta, interpret: bool):
-    R, H, Dk = q.shape
-    Dv = v.shape[-1]
-    G = H // HEAD_BLOCK
-    # the four key-indexed vectors with the channel on sublanes and the head on
-    # lanes, a head block apart: the kernel slices a head's column and
-    # broadcasts it along the state's value axis
-    kq = jnp.stack([a, k, k * beta[..., None], q], axis=1)  # [R, 4, H, Dk]
-    kq = kq.reshape(R, 4, G, HEAD_BLOCK, Dk).transpose(0, 2, 1, 4, 3)  # [R, G, 4, Dk, HB]
+def _decode_pallas(qkv, log_a, w, pool, tails, meta, interpret: bool):
+    R, _, H, D = qkv.shape
+    K = w.shape[0]
+    HB, G = HEAD_BLOCK, H // HEAD_BLOCK
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"))
+    state = pl.BlockSpec((1, 1, HB, D, D), lambda g, r, m: (m[0], m[1 + r], g, 0, 0))
+    tail = pl.BlockSpec((1, 1, K - 1, 3, HB, D), lambda g, r, m: (m[0], m[1 + r], 0, 0, g, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(R, G),
+        grid=(G, R),  # a head block's rows together: its taps are fetched once
         in_specs=[
-            pl.BlockSpec((1, 1, 4, Dk, HEAD_BLOCK), lambda r, g, m: (r, g, 0, 0, 0)),
-            pl.BlockSpec((1, HEAD_BLOCK, Dv), lambda r, g, m: (r, g, 0)),
-            pl.BlockSpec((1, 1, HEAD_BLOCK, Dk, Dv), lambda r, g, m: (m[0], m[1 + r], g, 0, 0)),
+            pl.BlockSpec((1, 3, HB, D), lambda g, r, m: (r, 0, g, 0)),
+            pl.BlockSpec((1, HB, D), lambda g, r, m: (r, g, 0)),
+            pl.BlockSpec((K, 3, HB, D), lambda g, r, m: (0, 0, g, 0)),
+            state,
+            tail,
         ],
-        out_specs=[
-            pl.BlockSpec((1, HEAD_BLOCK, Dv), lambda r, g, m: (r, g, 0)),
-            pl.BlockSpec((1, 1, HEAD_BLOCK, Dk, Dv), lambda r, g, m: (m[0], m[1 + r], g, 0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, HB, D), lambda g, r, m: (r, g, 0)), state, tail],
+        scratch_shapes=[pltpu.VMEM((D, 3 * HB), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_decode_kernel, rows=R),
+        functools.partial(_decode_kernel, rows=R, heads=H),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, H, Dv), jnp.float32), jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        input_output_aliases={3: 1},  # operands count from the scalars: the pool is the 4th
+        out_shape=[jax.ShapeDtypeStruct((R, H, D), jnp.float32), jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
+        input_output_aliases={4: 1, 5: 2},  # operands count from the scalars: the pools are the 5th and 6th
+        # what a call touches of its operands: the rows' states and tails, not the pools. (It also decides where the
+        # compiler keeps the tail pool: with no estimate Kimi's narrow program moves all 48 MB of it into fast memory
+        # ahead of a scan trip's kernels and back behind them, for the 6 MB they touch. Pinning the pool to HBM by the
+        # result's memory space ends every such copy, and aborts the compiler in a program that returns the pools
+        # undonated, as the logits tools' does: PERF.md section 6, PR 50.)
+        cost_estimate=pl.CostEstimate(
+            flops=7 * R * H * D * D, transcendentals=5 * R * H * D,
+            bytes_accessed=2 * R * H * D * (D * pool.dtype.itemsize + (K - 1) * 3 * tails.dtype.itemsize) + R * H * D * 16,
+        ),
         interpret=interpret,
         name="kda_decode",
         **params,
-    )(meta, kq, v, pool)
+    )(meta, qkv, log_a, w, pool, tails)
 
 
-def kda_decode(q, k, v, log_a, beta, pool, layer, slots, live, fresh, impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
-    """One token a row against ``pool`` [L, NS, H, Dk, Dv] float32, in place:
-    ``q k log_a`` [R, H, Dk], ``v`` [R, H, Dv], ``beta`` [R, H], float32;
-    ``slots`` [R] int32 the rows' states, ``live`` [R] bool, ``fresh`` [R] bool
-    (a row that starts from zero state). A row that is not live leaves every
-    request's state alone: it works on the last slot, ``NS - 1``. ``impl``:
-    ``auto`` (the kernel on a TPU, XLA elsewhere), ``pallas``,
-    ``pallas_interpret``, ``xla``. Returns (o [R, H, Dv] float32, the pool)."""
+def kda_decode(qkv, log_a, beta, conv_w, pool, tails, layer, slots, live, fresh, impl: str = "auto") -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One token a row, from the projections to the recurrence's output, in
+    place on the state pool ``pool`` [L, NS, H, D, D] float32 and the tail
+    pool ``tails`` [L, NS, K - 1, 3, H, D]: ``qkv`` [R, 3, H, D] the row's
+    pre-convolution ``q~ k~ v~``, ``log_a`` [R, H, D] and ``beta`` [R, H]
+    float32, ``conv_w`` [K, 3, H, D] the taps; ``slots`` [R] int32 the rows'
+    places in both pools, ``live`` [R] bool, ``fresh`` [R] bool (a row that
+    starts from zero state AND a zero tail). A row that is not live leaves
+    every request's state and tail alone: it works on the last slot,
+    ``NS - 1``. ``impl``: ``auto`` (the kernel on a TPU, XLA elsewhere),
+    ``pallas``, ``pallas_interpret``, ``xla``. Returns (o [R, H, D] float32,
+    the state pool, the tail pool)."""
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
     NS = pool.shape[1]
     slots = jnp.where(live, jnp.asarray(slots, jnp.int32), NS - 1)
     fresh = fresh | ~live  # the spare slot never accumulates
     if impl in ("pallas", "pallas_interpret"):
-        if q.shape[1] % HEAD_BLOCK:
-            raise ValueError(f"kda_decode needs a multiple of {HEAD_BLOCK} heads, got {q.shape[1]}")
-        meta = jnp.concatenate([jnp.asarray(layer, jnp.int32).reshape(1), slots, fresh.astype(jnp.int32)])
-        return tuple(_decode_pallas(q, k, v, jnp.exp(log_a), beta, pool, meta, interpret=impl == "pallas_interpret"))
+        if qkv.shape[2] % HEAD_BLOCK:
+            raise ValueError(f"kda_decode needs a multiple of {HEAD_BLOCK} heads, got {qkv.shape[2]}")
+        bits = jax.lax.bitcast_convert_type(beta.astype(jnp.float32), jnp.int32).reshape(-1)
+        meta = jnp.concatenate([jnp.asarray(layer, jnp.int32).reshape(1), slots, fresh.astype(jnp.int32), bits])
+        return tuple(_decode_pallas(qkv, log_a, conv_w, pool, tails, meta, interpret=impl == "pallas_interpret"))
     if impl != "xla":
         raise ValueError(f"unknown kda_decode impl {impl!r}; expected auto|pallas|pallas_interpret|xla")
-    S = jnp.where(fresh[:, None, None, None], 0.0, pool[layer, slots].astype(jnp.float32))
-    o, S = kda_step(S, q, k, v, log_a, beta)
-    return o, pool.at[layer, slots].set(S.astype(pool.dtype))
+    zeroed = lambda a: jnp.where(fresh.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a)
+    tail = zeroed(tails[layer, slots])  # [R, K - 1, 3, H, D]
+    taps = [tail[:, j] for j in range(tail.shape[1])] + [qkv]
+    o, S = kda_step(zeroed(pool[layer, slots]).astype(jnp.float32), *decode_qkv(conv_w, taps), log_a, beta)
+    shifted = jnp.stack([a.astype(tails.dtype) for a in taps[1:]], axis=1)
+    return o, pool.at[layer, slots].set(S.astype(pool.dtype)), tails.at[layer, slots].set(shifted)

@@ -283,12 +283,12 @@ def test_bf16_serving_keeps_float32_state():
 def test_the_kernels_serve_what_the_xla_forms_serve(monkeypatch):
     """Both Pallas kernels (interpreted) inside the step, at a size whose
     latent entries are whole lane tiles (128 + 32 shared in pages of 256
-    lanes) and whose linear heads fill a head block of 8: the logits of the
+    lanes) and whose linear heads fill a head block of 16: the logits of the
     XLA forms."""
     from deepspeed_tpu.ops.transformer.linear_attention import kda_decode
 
     cfg, _, params, _ = _model(num_layers=3, layer_types=["linear", "latent", "linear"], num_heads=2, num_kv_heads=2, kv_lora_rank=128, qk_nope_head_dim=32, qk_rope_head_dim=32, head_dim=64,
-                               linear_num_heads=8)
+                               linear_num_heads=16)
     seqs = _sequences(4, lens=(21, 9))
     b = Driver(cfg, params).run(seqs, decode_from={0: 18, 1: 0})
     monkeypatch.setattr(hybrid_decode, "kda_decode", functools.partial(kda_decode, impl="pallas_interpret"))

@@ -15,6 +15,7 @@ The last test compiles the whole ragged serving step at the shapes of the
 benchmark's Mistral cells and reads what the compiler made of the KV pool.
 """
 
+import functools
 import json
 import os
 import pathlib
@@ -357,11 +358,42 @@ def _slab_sized_fills(text, slots, lanes=512):
 # (kernel calls, temporaries in bytes) of the hybrid configurations' NARROW programs at PR 46: PR 47 rewrote the
 # wide window's buffers in lines the two programs share, and the narrow text kept every instruction (compared
 # with names, metadata and the kernels' payloads taken out). A PR that means to change a narrow program says so here.
-NARROW_PROGRAMS = {"solar": (16, 224_022_016), "mimo": (25, 6_880_256), "glm": (5, 2_549_248), "laguna": (17, 5_070_848)}
+# PR 50 meant to change Solar's: ``kda_decode`` takes a linear layer's rows from the projections on, so the 134 MB
+# ``f32[64,8,4,128,8]`` operand stack, the gathered tails and the convolution's float32 copies are gone from the
+# temporaries (224,022,016 before), with the same 16 kernel calls. The three others trace no line of a linear layer.
+NARROW_PROGRAMS = {"solar": (16, 5_343_232), "mimo": (25, 6_880_256), "glm": (5, 2_549_248), "laguna": (17, 5_070_848)}
 
 
 def _narrow_program(text, memory):
     return text.count('custom_call_target="tpu_custom_call"'), memory.temp_size_in_bytes
+
+
+_KDA_CALL = re.compile(r"%kda_decode[\w.-]* = \((.*?)\) custom-call\(.*? operand_layout_constraints=\{(.*?)\}, output_to_operand_aliasing=\{(.*?)\}, frontend")
+
+
+def _assert_kda_decode_takes_rows_as_they_lie(text, tail_pool: str):
+    """Every ``kda_decode`` call of a compiled step: no operand and no result
+    with fewer than 128 numbers on its minor axis (the parent's kernel took
+    decay, k, k b and q as ``f32[rows, groups, 4, 128, 8]``, 8 heads on the
+    lanes: 16 times its numbers in HBM), no such array anywhere in the text,
+    and the convolution tails' pool ``tail_pool`` an operand AND a result,
+    aliased like the state and in the layout it arrived in, so the gather
+    and the scatter of the tails are gone. Returns the calls."""
+    calls = _KDA_CALL.findall(text)
+    shape = re.compile(r"\w+\[([\d,]*)\]")
+    for results, operands, aliasing in calls:
+        for dims in shape.findall(results) + shape.findall(operands):
+            assert int(dims.split(",")[-1]) >= 128, (dims, operands)
+        assert tail_pool in operands and tail_pool in results, (tail_pool, operands)
+        assert aliasing == "{1}: (4, {}), {2}: (5, {})", aliasing  # the state pool and the tail pool, in to out
+    assert not re.search(r"f32\[[\d,]*128,8\]", text)
+    # no copy of the tail pool to another layout (the parent's narrow text: ``copy bf16[3,65,3,24576]`` at the entry and
+    # at the exit, 28.8 MB each, around the gathers and scatters). Whether the compiler keeps a whole pool in fast
+    # memory through a scan (``copy-start`` / ``slice-start`` into ``S(1)``) is its memory-space assignment's choice,
+    # program by program, and no layout: PERF.md section 6, PR 50
+    moved = re.findall(rf"= {re.escape(tail_pool)}\S* copy\(", text)
+    assert not moved, moved
+    return calls
 
 
 def _compiled_mistral_step(v5e, monkeypatch, width):
@@ -512,6 +544,26 @@ def test_olmoe_ragged_step_fits_and_names_its_kernels(v5e, monkeypatch, width):
     assert re.search(rf"s32\[{rows + decode.MOE_STAT_ROWS},{width + 1}\]", text)
 
 
+@pytest.mark.parametrize("donated", [True, False], ids=["pools_donated", "pools_returned_undonated"])
+def test_kda_decode_alone_compiles_whoever_owns_its_pools(v5e, donated):
+    """``kda_decode`` at Kimi's shapes as a program's only operation, its two
+    pools donated (the serving step's way) and not (the logits tools' way,
+    whose ``hybrid_forward`` returns the pools it was lent). The tail pool
+    pinned to HBM through the result's memory space compiles inside the
+    serving step and nowhere else: XLA refuses the first of these programs
+    and aborts the process in the second."""
+    from deepspeed_tpu.ops.transformer.linear_attention import kda_decode
+
+    R, H, D, K, L = 64, 32, 128, 4, 10
+    on = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    program = jax.jit(functools.partial(kda_decode, impl="pallas"), donate_argnums=(4, 5) if donated else ())
+    compiled = program.lower(
+        on((R, 3, H, D)), on((R, H, D), jnp.float32), on((R, H), jnp.float32), on((K, 3, H, D)),
+        on((L, R + 1, H, D, D), jnp.float32), on((L, R + 1, K - 1, 3, H, D)), on((), I32), on((R,), I32), on((R,), bool), on((R,), bool),
+    ).compile()
+    assert len(_KDA_CALL.findall(compiled.as_text())) == 1  # (a pool that is not donated is copied first: the caller's choice)
+
+
 _SOLAR_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/solar-open2-250b-l4-ep8.json"
 
 
@@ -524,7 +576,9 @@ def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, mon
     pages AND the state store stay aliased in to out, weights + pools +
     temporaries fit the chip, and the recurrence of one-token rows is the
     ``kda_decode`` kernel, which must not open with three ``s32`` operands
-    (the ragged attention kernel's signature for the accepted readers)."""
+    (the ragged attention kernel's signature for the accepted readers), takes
+    its rows with 128 channels on the lanes and has the tail pool aliased in
+    to out beside the state."""
     from deepspeed_tpu.inference import hybrid_decode
     from deepspeed_tpu.inference.kv_pool import StateStore
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
@@ -569,13 +623,15 @@ def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, mon
     assert len(opens_with_three_s32) == (1 if width == 1 else 2), opens_with_three_s32
     assert any(name.startswith("kda_decode") for name in kernels), sorted(kernels)
     assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
+    assert len(_assert_kda_decode_takes_rows_as_they_lie(text, "bf16[3,65,3,3,64,128]")) == 3
     if width == 1:
         assert _narrow_program(text, memory) == NARROW_PROGRAMS["solar"]
+        assert memory.temp_size_in_bytes < 0.10e9
     else:
         # nothing fills, copies or scatters into a 64 x 128-slot token buffer (a linear layer's qkv [8192, 24576], its
         # log_a f32 [8192, 8192] and its [64, 128, 8192] output, the full layer's q and out [8192, 8192], before)
         assert not _slab_sized_fills(text, rows * width)
-        assert memory.temp_size_in_bytes < 1.70e9  # 1.672 GB before: the chunk rows' kda_chunked, not the buffers
+        assert memory.temp_size_in_bytes < 1.15e9  # 1.108 GB (1.672 before the kernel took the rows' convolution): the chunk rows' kda_chunked
 
 
 _MIMO_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mimo-v2.5-l7-ep16.json"
@@ -795,7 +851,8 @@ def test_kimi_linear_step_fits_with_state_and_latent_pages_in_place(v5e, monkeyp
     experts of 1,024 of a router over 256 and a shared one, 64 rows): it
     compiles for a v5e; the state, the convolution tails and the ONE latent
     pool stay aliased in to out; ``kda_decode`` runs at 32 heads in the
-    leading layer and in the scan's body, the latent kernel once a latent
+    leading layer and in the scan's body, on rows as the projections leave
+    them and in place on both of its pools, the latent kernel once a latent
     layer of the text (twice in the wide program) and the accepted ragged
     kernel not at all; weights + pools are the configuration file's 9.3 GB and
     the temporaries fit beside them; no slice of a mixer's matrix is written
@@ -822,7 +879,7 @@ def test_kimi_linear_step_fits_with_state_and_latent_pages_in_place(v5e, monkeyp
     params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape), params)
     no_kv = on_v5e((0, rows * maxp + 1, cfg.num_kv_heads, page, key_lanes(cfg.head_dim)))  # no softmax layer: no K, no V
     shapes = hybrid_decode.state_shapes(cfg, rows)
-    assert shapes.state == (10, 65, 32, 128, 128) and shapes.conv == (10, 65, 3, 3 * 32 * 128)
+    assert shapes.state == (10, 65, 32, 128, 128) and shapes.conv == (10, 65, 3, 3, 32, 128)
     latent = on_v5e((3, rows * maxp + 1, page, key_lanes(cfg.latent_width)))
     store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv), None, None, latent)
     step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
@@ -839,12 +896,13 @@ def test_kimi_linear_step_fits_with_state_and_latent_pages_in_place(v5e, monkeyp
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
     kernels = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*? custom-call\(", text, flags=re.M)
     assert sum(name.startswith("kda_decode") for name in kernels) == 4, kernels  # the leading layer's and the body's three
+    assert len(_assert_kda_decode_takes_rows_as_they_lie(text, "bf16[10,65,3,3,32,128]")) == 4
     assert sum(name.startswith("latent_paged_attention") for name in kernels) == (1 if width == 1 else 2), kernels
     assert not any(name.startswith("ragged_paged_attention") for name in kernels), kernels
     assert sum(name.startswith("moe_grouped_matmul") for name in kernels) >= 3
     # the state's operand is the whole store at 32 heads: [layers, slots, 32, 128, 128] float32, never copied (a chunk
     # row's new state goes in by a dynamic-update-slice fusion, in place)
-    assert re.search(r"f32\[10,65,32,128,128\]\S* custom-call\(", text) and not re.search(r"= f32\[10,65,32,128,128\]\S* copy\(", text)
+    assert not re.search(r"= f32\[10,65,32,128,128\]\S* copy\(", text)
     assert not re.search(r"= bf16\[3,4097,64,640\]\S* (copy|fusion)\(", text)
     H = cfg.hidden_size
     wq = {(H, 32 * 192), (32 * 192, H)}  # a latent layer's query matrix, no low rank in front of it: 28 MB
@@ -853,10 +911,10 @@ def test_kimi_linear_step_fits_with_state_and_latent_pages_in_place(v5e, monkeyp
         # layer's Wo are [2304, 4096] or its transpose). Without the barrier behind ``h Wq`` (hm.latent_project) the
         # compiler folds the head split into the matmul, writes the layer's Wq out and copies it head-major: 0.138 GB
         assert not _mixer_matrices_written_out(text, wq | {(H, 4096), (4096, H)})
-        assert memory.temp_size_in_bytes < 0.10e9  # 0.091 GB
+        assert memory.temp_size_in_bytes < 0.03e9  # 0.0052 GB (0.091 with the kernel's operand stack and the gathered tails)
     else:
         # (the wide program stages each linear layer's and each latent layer's Wo [4096, 2304] in fast memory by a fusion
         # inside its tile loops, as Solar-Open2's does: PERF.md section 7)
         assert not _mixer_matrices_written_out(text, wq)
         assert not _slab_sized_fills(text, rows * width)
-        assert memory.temp_size_in_bytes < 1.45e9  # 1.388 GB: the chunk rows' kda_chunked and the 64 x 128-slot buffers, unfilled
+        assert memory.temp_size_in_bytes < 0.95e9  # 0.902 GB (1.388 before): the chunk rows' kda_chunked and the 64 x 128-slot buffers, unfilled
