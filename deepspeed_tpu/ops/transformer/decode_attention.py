@@ -300,8 +300,98 @@ def _ragged_tiles(NKV, Hg, W, P, D, maxp, itemsize, pages_per_buffer=None):
     return C, CK, TQ, HB
 
 
+# The block form (read on a v5e at the two window cells' shapes with
+# ``tools/ragged_kernel_bench.py --rows-per-step ... --set _RING_SLOTS=...``;
+# ``PERF.md`` section 6, PR 51): rows a grid step attends, slots of a row's
+# walk the ring has (the rows whose fetches are in flight and two more), and
+# the bytes of keys and values the ring may hold.
+_BLOCK_ROWS = 8
+_RING_SLOTS = 4
+_RING_BYTES = 32 << 20
+
+
+def _slab(P, itemsize):
+    """Rows of a page a DMA moves at least: the pool's sublane tile (16 rows
+    of bfloat16), the whole page where that does not divide it."""
+    rows = 32 // itemsize
+    return P if P % rows else rows
+
+
+def _ragged_block(NKV, Hg, W, P, D, Dv, CK, itemsize, window, rows_per_step=None):
+    """``(RB, PR)``: rows a grid step attends and pages a row's slot holds
+    (whole key tiles of ``CK`` pages). One row a step, the walk in halves, where
+    nothing bounds a walk (no window), where the rows are wide (a prefill
+    chunk's tiles hide their fetches themselves) or where the walks of
+    ``_RING_SLOTS`` rows do not fit ``_RING_BYTES``; else ``_BLOCK_ROWS``."""
+    if window is None or W * Hg >= _TILE_ROWS:
+        return 1, 0
+    # the keys a row's queries see between them, window + W - 1, begin anywhere in their first page
+    PR = -(-(-(-(window + W - 2) // P) + 1) // CK) * CK
+    if rows_per_step is None:
+        rows_per_step = _BLOCK_ROWS if _RING_SLOTS * NKV * PR * P * (D + Dv) * itemsize <= _RING_BYTES else 1
+    return max(1, rows_per_step), PR
+
+
+def _merge_new(new_of, bufs, at, run, rows, W, base, start, kv_len):
+    """The ``rows`` rows at ``at()`` of the keys' and the values' buffer (run
+    ``run`` of as many, counted from position ``base``) take the row's new
+    keys and values (``new_of(0)``, ``new_of(1)``: ``[NKV, W, D]`` of the
+    row's operand) at the positions ``start .. kv_len - 1`` they land on: a
+    one-hot product, exact; every other row is left what it was. Both forms of
+    the kernel below merge through this one body (``new_of`` and ``at`` are
+    called where the one-row form always read them: its jaxpr is pinned)."""
+    pos = lax.add(lax.broadcasted_iota(jnp.int32, (rows, W), 0), lax.add(base, lax.mul(run, rows)))
+    w = lax.broadcasted_iota(jnp.int32, (rows, W), 1)
+    sel = lax.eq(pos, lax.add(w, start))  # [rows, W] one-hot: window slot w lands on row p
+    hit = (pos[:, :1] >= start) & (pos[:, :1] < kv_len)
+    for n, buf in enumerate(bufs):
+        new = new_of(n).astype(buf.dtype)  # [NKV, W, D]
+        if new.shape[-1] != buf.shape[-1]:  # values narrower than keys: their leading lanes
+            new = new[..., : buf.shape[-1]]
+        if W > 1:
+            # one product term a row at most, so exact in the pool's dtype
+            new = lax.dot_general(
+                jnp.broadcast_to(sel.astype(new.dtype), (new.shape[0], rows, W)), new,
+                (((2,), (1,)), ((0,), (0,))),
+                precision=lax.Precision.HIGHEST if new.dtype == jnp.float32 else None,
+                preferred_element_type=jnp.float32,
+            ).astype(buf.dtype)
+        buf[at()] = jnp.where(hit, new, buf[at()])
+
+
+def _key_tile(q, kbuf, vbuf, at, carry, q_pos, base, key0, kv_len, scale, window):
+    """One key tile of a query tile's online softmax: ``q`` [HB, TQ, D] against
+    the keys and values at ``at`` of the two buffers (the tile's first key at
+    position ``base + key0``), under the causal, length and window masks;
+    ``carry`` is the float32 ``(m, l, acc)`` so far. Both forms of the kernel
+    below attend through this one body, so a row's result does not depend on
+    which of them walked it."""
+    m, l, acc = carry
+    at = (*at, slice(None))
+    s = lax.dot_general(
+        q, kbuf[at], (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )  # [HB, TQ, TK]
+    kv_pos = lax.add(lax.broadcasted_iota(jnp.int32, q_pos.shape, 1), lax.add(base, key0))
+    live = lax.bitwise_and(lax.le(kv_pos, q_pos), lax.lt(kv_pos, kv_len))
+    if window is not None:
+        live = lax.bitwise_and(live, lax.lt(lax.sub(q_pos, kv_pos), window))
+    s = jnp.where(live, lax.mul(s, scale), NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+    corr = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    l = corr * l + jnp.sum(p, axis=2, keepdims=True)
+    acc = acc * corr + lax.dot_general(
+        p, vbuf[at].astype(jnp.float32),
+        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32,
+    )
+    return m_new, l, acc
+
+
 def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, *refs, scale, P, C, CK, TQ, HB, Hg, W, window=None, sinks=False):
-    """Grid step ``g`` attends row ``g - 1`` and starts the fetch of row
+    """One row a grid step (``_ragged_block_kernel`` below is the form for
+    narrow rows whose walk a window bounds: a block of rows a step). Grid step
+    ``g`` attends row ``g - 1`` and starts the fetch of row
     ``g``'s first pages (step 0 only fetches). The row's live pages, all kv
     heads of a page at a time, come by DMA into one half of ``kbuf`` / ``vbuf``
     (``[2, NKV, C * P, D]``: a kv head's keys lie together) while the other
@@ -411,23 +501,10 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, *refs, scale, P, C, CK, TQ,
                 copy.wait() if wait else copy.start()
 
         def merge(c, _):
-            pos = add(lax.broadcasted_iota(jnp.int32, (P, W), 0), add(base, mul(c, P)))
-            w = lax.broadcasted_iota(jnp.int32, (P, W), 1)
-            sel = lax.eq(pos, add(w, start))  # [P, W] one-hot: window slot w lands on page row p
-            hit = (pos[:, :1] >= start) & (pos[:, :1] < kv_len)
-            for n, buf in enumerate((kbuf, vbuf)):
-                new = x_ref[:, W * (Hg + n) : W * (Hg + n + 1), :].astype(buf.dtype)  # [NKV, W, D]
-                if new.shape[-1] != buf.shape[-1]:  # values narrower than keys: their leading lanes
-                    new = new[..., : buf.shape[-1]]
-                if W > 1:
-                    # one product term a row at most, so exact in the pool's dtype
-                    new = lax.dot_general(
-                        jnp.broadcast_to(sel.astype(new.dtype), (NKV, P, W)), new,
-                        (((2,), (1,)), ((0,), (0,))),
-                        precision=lax.Precision.HIGHEST if new.dtype == jnp.float32 else None,
-                        preferred_element_type=jnp.float32,
-                    ).astype(buf.dtype)
-                buf[slot, :, page_rows(c), :] = jnp.where(hit, new, buf[slot, :, page_rows(c), :])
+            _merge_new(
+                lambda n: x_ref[:, W * (Hg + n) : W * (Hg + n + 1), :], (kbuf, vbuf),
+                lambda: (slot, slice(None), page_rows(c), slice(None)), c, P, W, base, start, kv_len,
+            )
             write_back(c)
             return _
 
@@ -445,27 +522,9 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, *refs, scale, P, C, CK, TQ,
                 seen = sub(lax.min(kv_len, add(add(div(add(row0, TQ - 1), Hg), 1), start)), base)
 
                 def key_tile(kt, carry):
-                    m, l, acc = carry
                     key0 = mul(kt, TK)
                     keys = pl.ds(pl.multiple_of(key0, TK), TK)
-                    s = lax.dot_general(
-                        q, kbuf[slot, heads, keys, :], (((2,), (2,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32,
-                    )  # [HB, TQ, TK]
-                    kv_pos = add(lax.broadcasted_iota(jnp.int32, (TQ, TK), 1), add(base, key0))
-                    live = lax.bitwise_and(lax.le(kv_pos, q_pos), lt(kv_pos, kv_len))
-                    if window is not None:
-                        live = lax.bitwise_and(live, lt(sub(q_pos, kv_pos), window))
-                    s = jnp.where(live, mul(s, scale), NEG_INF)
-                    m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
-                    corr = jnp.exp(m - m_new)
-                    p = jnp.exp(s - m_new)
-                    l = corr * l + jnp.sum(p, axis=2, keepdims=True)
-                    acc = acc * corr + lax.dot_general(
-                        p, vbuf[slot, heads, keys, :].astype(jnp.float32),
-                        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32,
-                    )
-                    return m_new, l, acc
+                    return _key_tile(q, kbuf, vbuf, (slot, heads, keys), carry, q_pos, base, key0, kv_len, scale, window)
 
                 first_half = b == 0
                 m, l, acc = lax.fori_loop(
@@ -501,10 +560,187 @@ def _ragged_kernel(pt_ref, len_ref, qlen_ref, x_ref, *refs, scale, P, C, CK, TQ,
     slot_s[0] = lax.bitwise_and(add(slot0, n_halves), 1)  # where the next step finds its first pages
 
 
+def _ragged_block_kernel(pt_ref, len_ref, qlen_ref, x_ref, *refs, scale, P, RB, NS, PR, CK, HB, SL, Hg, W, window, sinks=False):
+    """``_ragged_kernel`` where the window bounds a row's walk to ``PR`` pages
+    and the rows are narrow (one query tile): a grid step attends a BLOCK of
+    ``RB`` rows, ``x_ref`` and ``o_ref`` hold a block, and a row's whole walk
+    lies in a slot of its own, one of a ring of ``NS`` in ``kbuf`` / ``vbuf``
+    (``[NS, NKV, PR * P, D]``, from the row's first page on). Before row ``r``
+    is attended the fetch of row ``r + NS - 2`` is started, into the slot row
+    ``r - 2`` was attended from, whatever blocks the two are in: ``NS - 2``
+    rows' copies are in flight all through the call, where ``_ragged_kernel``
+    has one half's, and a row pays neither a grid step nor the wait for its
+    own write-back.
+
+    Of a walk's first and last page only the ``SL``-row slabs (the pool's
+    sublane tile: what a DMA can address) that hold a key the row sees are
+    fetched; the pages between come whole. The row's new keys and values are
+    merged where they land, a slab at a time, and only those slabs are written
+    back, from the row's slot: waited for two rows later, before the slot is
+    fetched into again (and at the end of the last step).
+
+    The score tile is ``_key_tile``, the key tiles of ``CK`` pages start at the
+    row's first page and the float32 statistics are carried in registers, so a
+    row's output is bit for bit what ``_ragged_kernel`` gives it: what a slot
+    holds beside the fetched slabs is masked, as a half's dead pages are."""
+    add, sub, mul, div, lt, gt = lax.add, lax.sub, lax.mul, lax.div, lax.lt, lax.gt
+    if sinks:
+        sink_ref, *refs = refs
+    _k_in, _v_in, o_ref, k_pool, v_pool, kbuf, vbuf, fetch_sem, write_sem = refs
+    g = pl.program_id(0)
+    R = len_ref.shape[0]  # whole blocks: dead rows fill the last
+    _, NKV, TQ, Dv = o_ref.shape
+    TK, SPP, AHEAD = CK * P, P // SL, NS - 2
+    pools = ((k_pool, kbuf), (v_pool, vbuf))  # a pool and the ring its pages come into
+
+    def live(row):
+        return gt(qlen_ref[row], 0)
+
+    def walk(row):
+        """``(kv_len, start, first, fetched, written)`` of a live row: its
+        write base, the table slot of the first key its first query sees, and
+        two ranges of slabs of its slot, counted from that page's first: those
+        that hold a key the row sees and those that hold a position it writes,
+        ``start .. kv_len - 1``."""
+        kv_len = len_ref[row]
+        start = sub(kv_len, qlen_ref[row])
+        seen = lax.max(sub(start, window - 1), 0)  # the first key the row's first query sees
+        first = div(seen, P)
+        end = lax.min(add(div(sub(sub(kv_len, 1), mul(first, P)), SL), 1), PR * SPP)
+        at = lambda pos: div(sub(pos, mul(first, P)), SL)
+        return kv_len, start, first, (at(seen), end), (at(start), end)
+
+    def copies(row, first, slabs, wait=False, to_pool=False):
+        """The copies of the slabs ``slabs`` of the row's slot, from their pages
+        or (``to_pool``) to them, started or waited for: those from the pool slab
+        by slab up to the first page boundary and from the last on, the pages
+        between whole."""
+        slot = lax.rem(row, NS)
+        lo, hi = slabs
+        sem = write_sem if to_pool else fetch_sem
+
+        def copy(c, rows_of_page, rows_of_slot):
+            for i, (pool, buf) in enumerate(pools):
+                there, here = pool.at[pt_ref[row, add(first, c)], :, rows_of_page, :], buf.at[slot, :, rows_of_slot, :]
+                dma = pltpu.make_async_copy(*((here, there) if to_pool else (there, here)), sem.at[slot, i])
+                dma.wait() if wait else dma.start()
+
+        def slab(sl, _):
+            c = div(sl, SPP)
+            copy(c, pl.ds(pl.multiple_of(mul(sub(sl, mul(c, SPP)), SL), SL), SL), pl.ds(pl.multiple_of(mul(sl, SL), SL), SL))
+            return _
+
+        def page(c, _):
+            copy(c, slice(None), pl.ds(pl.multiple_of(mul(c, P), P), P))
+            return _
+
+        if to_pool or SPP == 1:
+            lax.fori_loop(lo, hi, slab, None)
+            return
+        whole_from = lax.min(mul(div(add(lo, SPP - 1), SPP), SPP), hi)
+        whole_to = lax.max(mul(div(hi, SPP), SPP), whole_from)
+        lax.fori_loop(lo, whole_from, slab, None)
+        lax.fori_loop(div(whole_from, SPP), div(whole_to, SPP), page, None)
+        lax.fori_loop(whole_to, hi, slab, None)
+
+    def fetch(row):
+        _, _, first, fetched, _ = walk(row)
+        copies(row, first, fetched)
+
+    def written_slabs_have_left(row):
+        _, _, first, _, written = walk(row)
+        copies(row, first, written, wait=True, to_pool=True)
+
+    def each(lo, hi, do):  # ``do(row)`` for the live rows of ``lo .. hi - 1``
+        lax.fori_loop(lo, hi, lambda row, _: pl.when(live(row))(functools.partial(do, row)), None)
+
+    @pl.when(g == 0)
+    def _first_step():
+        # what a slot holds beside a row's fetched slabs is masked, and so must be finite
+        def clear(slot, _):
+            vbuf[slot] = jnp.zeros(vbuf.shape[1:], vbuf.dtype)
+            return _
+
+        lax.fori_loop(0, NS, clear, None)
+        each(0, min(AHEAD, R), fetch)
+
+    def tiles(slot, j, kv_len, start, base):
+        """The row's queries against its slot, a block of kv heads at a time."""
+        # keys the row's last query sees, counted from the slot's first
+        seen = sub(lax.min(kv_len, add(start, (TQ - 1) // Hg + 1)), base)
+        q_pos = add(div(lax.broadcasted_iota(jnp.int32, (TQ, TK), 0), Hg), start)
+
+        def head_block(hb, _):
+            heads = pl.ds(pl.multiple_of(mul(hb, HB), HB), HB)
+            q = x_ref[j, heads, :TQ, :]  # [HB, TQ, D]: slot w of group head h at row w*Hg + h
+
+            def key_tile(kt, carry):
+                key0 = mul(kt, TK)
+                keys = pl.ds(pl.multiple_of(key0, TK), TK)
+                return _key_tile(q, kbuf, vbuf, (slot, heads, keys), carry, q_pos, base, key0, kv_len, scale, window)
+
+            carry = lax.fori_loop(
+                0, lax.clamp(0, div(add(seen, TK - 1), TK), PR // CK), key_tile,
+                (
+                    # the one tile holds whole groups: its rows' heads are the sinks' block's
+                    sink_ref[heads, :, :1] if sinks else jnp.full((HB, TQ, 1), NEG_INF, jnp.float32),
+                    jnp.full((HB, TQ, 1), 1.0 if sinks else 0.0, jnp.float32),
+                    jnp.zeros((HB, TQ, Dv), jnp.float32),
+                ),
+            )
+            l, acc = carry[1:]
+            o_ref[j, heads] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
+            return _
+
+        lax.fori_loop(0, NKV // HB, head_block, None)
+
+    def attend(j, _):
+        row = add(mul(g, RB), j)
+        slot = lax.rem(row, NS)
+        # row - 2's slot is the one fetched into next: its written slabs have had a row's arithmetic to leave
+        before, ahead = lax.max(sub(row, 2), 0), lax.min(add(row, AHEAD), R - 1)
+        pl.when(lax.bitwise_and(gt(row, 1), live(before)))(functools.partial(written_slabs_have_left, before))
+        pl.when(lax.bitwise_and(lt(add(row, AHEAD), R), live(ahead)))(functools.partial(fetch, ahead))
+
+        @pl.when(live(row))
+        def _live_row():
+            kv_len, start, first, fetched, written = walk(row)
+            base = mul(first, P)  # the position of the slot's first key
+            copies(row, first, fetched, wait=True)
+
+            # the slabs that receive the row's new positions: merged here with the
+            # window's rows (one-hot, exact), attended from here, written back from here
+            def merge(sl, _):
+                rows = pl.ds(pl.multiple_of(mul(sl, SL), SL), SL)
+                _merge_new(
+                    lambda n: x_ref[j, :, W * (Hg + n) : W * (Hg + n + 1), :], (kbuf, vbuf),
+                    lambda: (slot, slice(None), rows, slice(None)), sl, SL, W, base, start, kv_len,
+                )
+                return _
+
+            lax.fori_loop(*written, merge, None)
+            copies(row, first, written, to_pool=True)
+            tiles(slot, j, kv_len, start, base)
+
+        @pl.when(lax.eq(qlen_ref[row], 0))
+        def _dead_row():
+            o_ref[j] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+        return _
+
+    lax.fori_loop(0, RB, attend, None)
+
+    @pl.when(lax.eq(g, pl.num_programs(0) - 1))
+    def _last_step():
+        each(max(R - 2, 0), R, written_slabs_have_left)
+
+
 def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dtype, interpret,
-                          pages_per_buffer=None, window=None, sinks=None):
-    """``_ragged_kernel`` over ``R + 1`` steps, the pools left where they are.
-    ``sinks`` [NKV * Hg] float32 or None."""
+                          pages_per_buffer=None, rows_per_step=None, window=None, sinks=None):
+    """``_ragged_kernel`` over ``R + 1`` steps or, where ``_ragged_block`` finds
+    room for several rows' walks, ``_ragged_block_kernel`` over
+    ``ceil(R / RB)``; the pools left where they are. ``sinks`` [NKV * Hg]
+    float32 or None."""
     R, NKV, _, D = x.shape
     P, maxp, Dv = pools[0].shape[2], pages.shape[1], pools[1].shape[3]
     itemsize = jnp.dtype(pools[0].dtype).itemsize
@@ -512,14 +748,44 @@ def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dty
         # a row's walk is the window's pages and the step's own: no half needs more
         maxp = min(maxp, -(-(window - 1) // P) + -(-W // P) + 1)
     C, CK, TQ, HB = _ragged_tiles(NKV, Hg, W, P, D, maxp, itemsize, pages_per_buffer)
-    kernel = functools.partial(
-        _ragged_kernel, scale=scale, P=P, C=C, CK=CK, TQ=TQ, HB=HB, Hg=Hg, W=W
-    )
-    half = (2, NKV, C * P, D)
-    stats = (NKV, W * Hg, 128)
+    RB, PR = 1, 0
+    if pages_per_buffer is None or rows_per_step is not None:
+        RB, PR = _ragged_block(NKV, Hg, W, P, D, Dv, CK, itemsize, window, rows_per_step)
+    if RB > 1:
+        kernel = functools.partial(
+            _ragged_block_kernel, scale=scale, P=P, RB=RB, NS=_RING_SLOTS, PR=PR, CK=CK, HB=HB, SL=_slab(P, itemsize),
+            Hg=Hg, W=W, window=window, sinks=sinks is not None,
+        )
+        NB = -(-R // RB)
+        if NB * RB != R:  # dead rows fill the last block
+            x = jnp.pad(x, ((0, NB * RB - R), (0, 0), (0, 0), (0, 0)))
+            pages, lens, qlens = (jnp.pad(a, ((0, NB * RB - R),) + ((0, 0),) * (a.ndim - 1)) for a in (pages, lens, qlens))
+        steps, row_dims = NB, (RB, NKV)
+        buffers = [(_RING_SLOTS, NKV, PR * P, D), (_RING_SLOTS, NKV, PR * P, Dv)]
+        scratch = [
+            pltpu.SemaphoreType.DMA((_RING_SLOTS, 2)),  # fetches: a row's slot, keys or values
+            pltpu.SemaphoreType.DMA((_RING_SLOTS, 2)),  # write-backs: likewise
+        ]
+        held_stats = 0
+    else:
+        kernel = functools.partial(
+            _ragged_kernel, scale=scale, P=P, C=C, CK=CK, TQ=TQ, HB=HB, Hg=Hg, W=W
+        )
+        if window is not None or sinks is not None:
+            kernel = functools.partial(kernel, window=window, sinks=sinks is not None)
+        steps, row_dims = R + 1, (None, NKV)
+        buffers = [(2, NKV, C * P, D), (2, NKV, C * P, Dv)]
+        stats = (NKV, W * Hg, 128)
+        scratch = [
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM((NKV, W * Hg, Dv), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),  # fetches: a half, keys or values
+            pltpu.SemaphoreType.DMA((2,)),  # write-backs: keys or values
+            pltpu.SMEM((1,), jnp.int32),
+        ]
+        held_stats = 4 * NKV * W * Hg * (2 * 128 + D)  # m, l, acc
     operands, extra_specs = [x], []
-    if window is not None or sinks is not None:
-        kernel = functools.partial(kernel, window=window, sinks=sinks is not None)
     if sinks is not None:
         if TQ % Hg:
             raise ValueError(f"sinks need query tiles of whole groups: {TQ} rows a tile, {Hg} heads a group")
@@ -529,9 +795,9 @@ def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dty
     params = {}
     if not interpret:
         held = (
-            2 * 2 * NKV * C * P * D * itemsize  # the two double buffers
-            + 2 * NKV * W * (2 * Hg + 2) * D * x.dtype.itemsize  # x and o, twice
-            + 4 * NKV * W * Hg * (2 * 128 + D)  # m, l, acc
+            2 * int(np.prod(buffers[0])) * itemsize  # the keys' buffer and the values'
+            + 2 * RB * NKV * W * (2 * Hg + 2) * D * x.dtype.itemsize  # x and o, twice
+            + held_stats
         )
         params["compiler_params"] = pltpu.CompilerParams(
             # a row's first pages are fetched by the step before its own
@@ -539,30 +805,21 @@ def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dty
             vmem_limit_bytes=held + (24 << 20),
         )
 
-    def row_block(g, pt, ln, ql):  # step g attends row g - 1; step 0 only fetches
-        return (lax.max(g - 1, 0), 0, 0, 0)
+    def row_block(g, pt, ln, ql):  # step g attends row g - 1 (step 0 only fetches), or block g
+        return (g if RB > 1 else lax.max(g - 1, 0), 0, 0, 0)
 
     pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(R + 1,),
-        in_specs=[pl.BlockSpec((None, NKV, W * (Hg + 2), D), row_block), *extra_specs, pool, pool],
-        out_specs=[pl.BlockSpec((None, NKV, W * Hg, Dv), row_block), pool, pool],
-        scratch_shapes=[
-            pltpu.VMEM(half, pools[0].dtype),
-            pltpu.VMEM(half[:3] + (Dv,), pools[1].dtype),
-            pltpu.VMEM(stats, jnp.float32),
-            pltpu.VMEM(stats, jnp.float32),
-            pltpu.VMEM((NKV, W * Hg, Dv), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),  # fetches: a half, keys or values
-            pltpu.SemaphoreType.DMA((2,)),  # write-backs: keys or values
-            pltpu.SMEM((1,), jnp.int32),
-        ],
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((*row_dims, W * (Hg + 2), D), row_block), *extra_specs, pool, pool],
+        out_specs=[pl.BlockSpec((*row_dims, W * Hg, Dv), row_block), pool, pool],
+        scratch_shapes=[pltpu.VMEM(buffers[0], pools[0].dtype), pltpu.VMEM(buffers[1], pools[1].dtype), *scratch],
     )
-    return pl.pallas_call(
+    o, *new_pools = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, NKV, W * Hg, Dv), out_dtype)]
+        out_shape=[jax.ShapeDtypeStruct((x.shape[0], NKV, W * Hg, Dv), out_dtype)]
         + [jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in pools],
         # operands count from the scalars: the pools are the last two inputs
         input_output_aliases={3 + len(operands): 1, 4 + len(operands): 2},
@@ -570,6 +827,7 @@ def _ragged_by_live_pages(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dty
         name="ragged_paged_attention",
         **params,
     )(pages, lens, qlens, *operands, *pools)
+    return (o[:R], *new_pools)
 
 
 def ragged_paged_attention(
@@ -587,6 +845,7 @@ def ragged_paged_attention(
     pages_per_buffer: Optional[int] = None,
     window: Optional[int] = None,
     sinks: Optional[jnp.ndarray] = None,
+    rows_per_step: Optional[int] = None,
 ):
     """One ragged kernel for mixed prefill-chunk / decode / verify rows that
     writes the step's keys and values into the pool and attends over it.
@@ -603,7 +862,10 @@ def ragged_paged_attention(
 
     The work follows the row's live pages, not the page table's width: a
     grid step is a row, and its body walks ``ceil(kv_len / P)`` pages only,
-    so a dead row and the dead tail of a table cost a few scalar reads. The
+    so a dead row and the dead tail of a table cost a few scalar reads (where
+    a window bounds the walk of narrow rows, decode or verify, to a few
+    pages, a grid step is a BLOCK of rows and a row's pages are fetched
+    two rows ahead of it: ``_ragged_block``, ``_ragged_block_kernel``). The
     pools are the whole ``[L, NP, NKV, P, D]`` stacks, left where they are
     (``pl.ANY``), aliased in → out and seen as ``L * NP`` pages (a view), with
     ``layer`` folded into the page table: the kernel is the only operation
@@ -651,7 +913,8 @@ def ragged_paged_attention(
     ``q_lens[r]`` are not written and produce garbage rows the caller ignores
     (finite: masked softmax over the live prefix, or zeros); rows with
     ``q_lens[r] == 0`` return exact zeros. ``pages_per_buffer`` overrides the
-    size of a half (tests, ``tools/ragged_kernel_bench.py``)."""
+    size of a half and ``rows_per_step`` the rows of a block, 1 for the form
+    of one row a grid step (tests, ``tools/ragged_kernel_bench.py``)."""
     R, W, NH, Dq = q.shape
     L, NP, NKV, P, D = k_pages.shape
     Dv = v_pages.shape[-1]
@@ -686,7 +949,7 @@ def ragged_paged_attention(
             shared.update(window=window, sinks=sinks)
         o, new_k, new_v = _ragged_by_live_pages(
             x, _pages_in_stack(layer, page_table, NP), lens, qlens, pools,
-            pages_per_buffer=pages_per_buffer, **shared,
+            pages_per_buffer=pages_per_buffer, rows_per_step=rows_per_step, **shared,
         )
     elif not plain:
         raise NotImplementedError(

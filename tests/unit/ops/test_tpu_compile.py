@@ -245,6 +245,75 @@ def test_ragged_kernel_compiles_at_groups_of_six_and_nine_with_its_pools_in_plac
     assert made and all(re.search(r" (parameter|bitcast|get-tuple-element|custom-call)\(", line) for line in made), made
 
 
+# The two window cells' narrow programs: 64 rows of one token under Laguna-S-2.1's window layers (72 query heads over
+# 8 KV heads of 128, 512 keys on rings of 10 pages) and MiMo-V2.5's (64 over 8, keys of 192 in pages of 256 lanes,
+# values of 128, a sink a head, 128 keys on rings of 4 pages)
+WINDOW_DECODE = {
+    "laguna": dict(heads=72, dq=128, lanes=128, window=512, layers=6, pages=641, sinks=False),
+    "mimo": dict(heads=64, dq=192, lanes=256, window=128, layers=5, pages=257, sinks=True),
+}
+
+
+def _window_decode(name, window, rows=64, width=1):
+    """The call and its abstract arguments (no sharding yet)."""
+    c = WINDOW_DECODE[name]
+
+    def call(q, k_new, v_new, k_pages, v_pages, table, kv_lens, q_lens, sinks):
+        return ragged_paged_attention(
+            q, k_new, v_new, k_pages, v_pages, 1, table, kv_lens, q_lens, interpret=False, window=window,
+            sinks=sinks if c["sinks"] and window else None,
+        )
+
+    shapes = (
+        [((rows, width, c["heads"], c["dq"]), BF16), ((rows, width, 8, c["dq"]), BF16), ((rows, width, 8, 128), BF16)]
+        + [((c["layers"], c["pages"], 8, 64, c["lanes"]), BF16), ((c["layers"], c["pages"], 8, 64, 128), BF16)]
+        + [((rows, 64), I32)] + [((rows,), I32)] * 2 + [((c["heads"],), jnp.float32)]
+    )
+    return call, shapes
+
+
+def _kernel_grid_and_equations(call, shapes):
+    from deepspeed_tpu.analysis import iter_eqns
+
+    jaxpr = jax.make_jaxpr(call)(*[jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in shapes])
+    (grid,) = [eqn.params["grid_mapping"].grid for eqn in iter_eqns(jaxpr) if eqn.primitive.name == "pallas_call"]
+    return grid, sum(1 for _ in iter_eqns(jaxpr))
+
+
+@pytest.mark.parametrize("name", sorted(WINDOW_DECODE))
+def test_window_decode_rows_go_a_block_a_grid_step(v5e, name):
+    """A window layer's 64 one-token rows are attended ``RB`` a grid step
+    (``_ragged_block``: the grid is ``ceil(64 / RB)`` steps, not 65: no step
+    that only fetches), and Mosaic takes the block form for a v5e at both
+    cells' shapes: a ring of rows' walks in VMEM, slabs of 16 rows fetched and
+    written back at a dynamic offset of a page, the pools in place."""
+    from deepspeed_tpu.ops.transformer import decode_attention
+
+    c = WINDOW_DECODE[name]
+    call, shapes = _window_decode(name, c["window"])
+    _, CK, _, _ = decode_attention._ragged_tiles(8, c["heads"] // 8, 1, 64, c["lanes"], -(-(c["window"] - 1) // 64) + 2, 2)
+    RB, _ = decode_attention._ragged_block(8, c["heads"] // 8, 1, 64, c["lanes"], 128, CK, 2, c["window"])
+    assert RB > 1
+    grid, _ = _kernel_grid_and_equations(call, shapes)
+    assert grid == (-(-64 // RB),)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes]
+    text = jax.jit(call, donate_argnums=(3, 4)).lower(*args).compile().as_text()
+    assert {3, 4} <= parse_input_output_aliases(text)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("rows,width,equations", [(64, 1, 266), (4, 128, 278)])
+def test_a_row_a_grid_step_where_no_window_bounds_the_walk(rows, width, equations):
+    """Every full layer, and a window layer's prefill chunks: one row a grid
+    step, and with no window the body every other model traces, equation for
+    equation what it was before the block form existed (PR 50's counts)."""
+    call, shapes = _window_decode("laguna", None, rows, width)
+    assert _kernel_grid_and_equations(call, shapes) == ((rows + 1,), equations)
+    if width > 1:
+        call, shapes = _window_decode("laguna", 512, rows, width)
+        assert _kernel_grid_and_equations(call, shapes)[0] == (rows + 1,)
+
+
 def test_flash_backward_is_what_the_benchmark_reads(v5e):
     """The compiled forward + backward of the 125M cell's attention: the row
     statistics stay lane-dense (no ``f32[96,1024,128]`` anywhere: they were
@@ -361,7 +430,9 @@ def _slab_sized_fills(text, slots, lanes=512):
 # PR 50 meant to change Solar's: ``kda_decode`` takes a linear layer's rows from the projections on, so the 134 MB
 # ``f32[64,8,4,128,8]`` operand stack, the gathered tails and the convolution's float32 copies are gone from the
 # temporaries (224,022,016 before), with the same 16 kernel calls. The three others trace no line of a linear layer.
-NARROW_PROGRAMS = {"solar": (16, 5_343_232), "mimo": (25, 6_880_256), "glm": (5, 2_549_248), "laguna": (17, 5_070_848)}
+# PR 51 meant to change MiMo's and Laguna's: their window layers' one-token rows go a block of 8 a grid step of the
+# ragged kernel (``_ragged_block_kernel``), the same 25 and 17 kernel calls (6,880,256 and 5,070,848 bytes before).
+NARROW_PROGRAMS = {"solar": (16, 5_343_232), "mimo": (25, 6_279_680), "glm": (5, 2_549_248), "laguna": (17, 4_812_800)}
 
 
 def _narrow_program(text, memory):

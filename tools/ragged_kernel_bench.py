@@ -21,6 +21,14 @@ layer, groups of 6 (48 query heads over 8 KV heads, every key) and of 9 (72
 over 8, a window of 512 keys on a ring of 10 pages a row), at 64 decode rows
 and at a wide trip's 4 rows; a model with a window is measured against
 ``benchmark/kernels/windowed_paged_attention.py::min_seconds``.
+``mimo_window`` is MiMo-V2.5's window layer (64 query heads over 8 KV heads,
+keys of 192 in pages of 256 lanes, values of 128, a sink a head, a window of
+128 keys on a ring of 4 pages a row). ``--rows-per-step 1,2,4,8,16`` runs a
+window model's narrow mixes once a value with that many rows a grid step
+(``window_rows_per_grid_step`` in the printout is what the kernel was built
+with; 1 is the form that walks one row a step in halves), and each ``--set
+_RING_SLOTS=N`` once more with that constant of the kernel's file: how
+``_BLOCK_ROWS`` and ``_RING_SLOTS`` there were chosen.
 
 The model ``glm47`` is the latent kernel's (``ops/transformer/latent_attention.py::
 latent_paged_attention``: 20 heads over one entry of 576 a token in pages of
@@ -37,6 +45,7 @@ mixes once more with other values of that file's constants, and ``--root``
 measures another checkout's kernel (the parent's) beside them.
 
     chiprun -- python3 tools/ragged_kernel_bench.py [--models mistral7b,olmoe] [--mixes decode16,chat4,mixed]
+    chiprun -- python3 tools/ragged_kernel_bench.py --models laguna_window,mimo_window --mixes decode_long --rows-per-step 1,2,4,8,16
     chiprun -- python3 tools/ragged_kernel_bench.py --models glm47 [--root DIR] [--mixes decode64_long] [--set _NARROW_HALF_KEYS=768,_NARROW_RING=2 ...]
     python3 tools/ragged_kernel_bench.py --rehearse [--models glm47]      # tiny, on the CPU: the control flow only
 """
@@ -61,8 +70,11 @@ MODELS = {
     "laguna_window": (72, 8, 128, 6, 64, 64, 64, 128, 512),
     "laguna_full_chunk": (48, 8, 128, 3, 4, 64, 64, 128, None),
     "laguna_window_chunk": (72, 8, 128, 6, 4, 64, 64, 128, 512),
+    "mimo_window": (64, 8, 256, 5, 64, 64, 64, 128, 128),
 }
-TINY = {"tiny": (4, 2, 128, 2, 4, 6, 8, 8, None), "tiny_window": (18, 2, 128, 2, 4, 6, 8, 8, 8)}
+TINY = {"tiny": (4, 2, 128, 2, 4, 6, 8, 8, None), "tiny_window": (18, 2, 128, 2, 4, 6, 8, 8, 8), "tiny_sinks": (8, 2, 256, 2, 4, 6, 8, 8, 8)}
+# (lanes of a key the mathematics needs, lanes of a value) where they are not the page's, and a sink a head
+WIDTHS = {"mimo_window": (192, 128), "tiny_sinks": (192, 128)}
 # (query heads, value lanes, rotated lanes, lanes a page stores, layers, rows, pages a row, page)
 LATENT = {"glm47": (20, 512, 64, 640, 16, 64, 64, 64)}
 LATENT_TINY = {"glm47": (20, 128, 32, 256, 2, 6, 12, 8)}
@@ -193,9 +205,14 @@ def main() -> None:
     ap.add_argument("--root", default=ROOT, help="the checkout whose deepspeed_tpu is measured")
     ap.add_argument("--pages-per-buffer", type=int, default=None)
     ap.add_argument(
+        "--rows-per-step", default="", metavar="INT,...",
+        help="rows a grid step of the ragged kernel attends, each value a run of its own (a window model's narrow mixes take "
+        "them; elsewhere the kernel keeps one row a step)",
+    )
+    ap.add_argument(
         "--set", action="append", default=[], metavar="NAME=INT,...",
-        help="glm47: constants of the latent kernel's file to try in place of the file's, e.g. "
-        "_NARROW_HALF_KEYS=768,_NARROW_RING=2; may be given more than once",
+        help="constants of the kernel's file to try in place of the file's, a run each time it is given: glm47 "
+        "_NARROW_HALF_KEYS=768,_NARROW_RING=2 (beside the file's own), the ragged models _RING_SLOTS=6 (in its place)",
     )
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
@@ -220,13 +237,18 @@ def main() -> None:
     peak = files.load_json(files.HERE, "peaks.json")["TPU v5 lite" if args.rehearse else jax.devices()[0].device_kind]
     for model in latent:
         latent_bench(args, model, (LATENT_TINY if args.rehearse else LATENT)[model], calls, peak)
+    swept = [int(n) for n in args.rows_per_step.split(",")] if args.rows_per_step else [None]
+    tried = [dict((name, int(value)) for name, value in (pair.split("=") for pair in text.split(","))) for text in args.set] or [{}]
+    defaults = {name: getattr(decode_attention, name) for constants in tried for name in constants}
     for model, (NH, NKV, D, L, R, maxp, P, wide, window) in models.items():
+        dk, dv = WIDTHS.get(model, (D, D))
+        sinks = jnp.linspace(-1.0, 2.0, NH, dtype=jnp.float32) if model in WIDTHS else None
         # a row's pages: its own all the way, or with a window a ring of those a chunk writes and the window's before it
         held = maxp if window is None else -(-wide // P) + -(-(window - 1) // P)
         NP = R * held + 1
         rng = np.random.default_rng(0)
         key = jax.random.PRNGKey(0)
-        pools = [jax.random.normal(jax.random.fold_in(key, i), (L, NP, NKV, P, D), jnp.bfloat16) for i in (1, 2)]
+        pools = [jax.random.normal(jax.random.fold_in(key, i), (L, NP, NKV, P, lanes), jnp.bfloat16) for i, lanes in ((1, D), (2, dv))]
         if window is None:
             table = jnp.asarray(1 + rng.permutation(R * maxp).reshape(R, maxp), jnp.int32)  # scattered, as a pool ages
         else:
@@ -237,54 +259,66 @@ def main() -> None:
             q_lens = jnp.asarray([n for n, _ in rows], jnp.int32)
             kv_lens = jnp.asarray([kv for _, kv in rows], jnp.int32)
             q, k_new, v_new = (
-                jax.random.normal(jax.random.fold_in(key, 3 + i), (R, W, heads, D), jnp.bfloat16)
-                for i, heads in enumerate((NH, NKV, NKV))
+                jax.random.normal(jax.random.fold_in(key, 3 + i), (R, W, heads, lanes), jnp.bfloat16)
+                for i, (heads, lanes) in enumerate(((NH, dk), (NKV, dk), (NKV, dv)))
             )
-
-            def many(q, k_new, v_new, k_pages, v_pages, table, kv_lens, q_lens):
-                # CALLS layers back to back in one program, the pools carried as the layer loop carries them
-                def body(i, carry):
-                    acc, kp, vp = carry
-                    o, kp, vp = ragged_paged_attention(
-                        q, k_new, v_new, kp, vp, i % L, table, kv_lens, q_lens,
-                        interpret=args.rehearse, pages_per_buffer=args.pages_per_buffer, window=window,
-                    )
-                    return acc + o.astype(jnp.float32), kp, vp
-
-                return jax.lax.fori_loop(0, calls, body, (jnp.zeros(q.shape, jnp.float32), k_pages, v_pages))
-
-            operands = (q, k_new, v_new, *pools, table, kv_lens, q_lens)
-            t0 = time.perf_counter()
-            traced = jax.jit(many, donate_argnums=(3, 4)).trace(*operands)
-            t1 = time.perf_counter()
-            lowered = traced.lower()
-            t2 = time.perf_counter()
-            program = lowered.compile()
-            t3 = time.perf_counter()
-            # what XLA's write + gather makes of one call, before the pools are donated
-            want_o, want_k, want_v = jax.jit(front, static_argnames=("impl", "window"))(
-                q, k_new, v_new, *pools, 0, table, kv_lens, q_lens, impl="xla", window=window
-            )
-            got_o, got_k, got_v = jax.jit(
-                lambda *a: ragged_paged_attention(*a, interpret=args.rehearse, pages_per_buffer=args.pages_per_buffer, window=window)
-            )(q, k_new, v_new, *pools, 0, table, kv_lens, q_lens)
-            live = (jnp.arange(W)[None, :] < q_lens[:, None])[:, :, None, None]
-            gap = float(jnp.max(jnp.abs(jnp.where(live, got_o.astype(jnp.float32) - want_o.astype(jnp.float32), 0))))
-            same_pools = all(bool(jnp.array_equal(g[:, 1:], w[:, 1:])) for g, w in ((got_k, want_k), (got_v, want_v)))
-            del want_k, want_v, got_k, got_v
-            best, (_, _, _, *pools, _, _, _) = timed(program, [q, k_new, v_new, *pools, table, kv_lens, q_lens], (3, 4), args.rehearse)
-            floor, bound = k.min_seconds(rows, NH, NKV, D, peak) if window is None else kw.min_seconds(rows, NH, NKV, D, D, peak, window)
+            floor, bound = k.min_seconds(rows, NH, NKV, D, peak) if window is None else kw.min_seconds(rows, NH, NKV, dk, dv, peak, window)
             pages = sum(-(-kw.keys_read(n, kv, window) // P) for n, kv in rows if n)
             walked = maxp if window is None else min(maxp, -(-(window - 1) // P) + -(-W // P) + 1)  # as the kernel's wrapper reckons it
             C, CK, TQ, HB = decode_attention._ragged_tiles(NKV, NH // NKV, W, P, D, walked, 2, args.pages_per_buffer)
-            form = f"group {NH // NKV}{'' if window is None else f' window {window}'}: halves of {C} pages, key tiles of {CK * P}, query tiles of {TQ} rows, {HB} kv heads a tile"
-            print(
-                f"{model:19s} {mix:11s} W={W:<4d} live rows {sum(1 for n, _ in rows if n):2d} pages {pages:4d}/{R * held}: "
-                f"{best / calls * 1e6:8.1f} us a call, floor {floor * 1e6:6.1f} us ({bound}), "
-                f"{100 * floor / (best / calls):5.1f}% | {form} | trace {t1 - t0:.3f} s lower {t2 - t1:.3f} s compile {t3 - t2:.2f} s | "
-                f"max |o - xla| {gap:.4f} pools {'same' if same_pools else 'DIFFER'}",
-                flush=True,
-            )
+            for constants, rows_per_step in [(constants, n) for constants in tried for n in swept]:
+                # a checkout from before the block form (--root) has neither the argument nor the rule
+                block = {} if rows_per_step is None else dict(rows_per_step=rows_per_step)
+                attend = functools.partial(
+                    ragged_paged_attention, interpret=args.rehearse, pages_per_buffer=args.pages_per_buffer, window=window, sinks=sinks, **block
+                )
+                for name, value in constants.items():
+                    setattr(decode_attention, name, value)
+                RB, PR = 1, 0
+                if hasattr(decode_attention, "_ragged_block") and (args.pages_per_buffer is None or rows_per_step):
+                    RB, PR = decode_attention._ragged_block(NKV, NH // NKV, W, P, D, dv, CK, 2, window, rows_per_step)
+                form = f"group {NH // NKV}{'' if window is None else f' window {window}'}: "
+                form += f"halves of {C} pages" if RB == 1 else f"window_rows_per_grid_step {RB}, a ring of {decode_attention._RING_SLOTS} slots of {PR} pages"
+                form += f", key tiles of {CK * P}, query tiles of {TQ} rows, {HB} kv heads a tile"
+
+                def many(q, k_new, v_new, k_pages, v_pages, table, kv_lens, q_lens):
+                    # CALLS layers back to back in one program, the pools carried as the layer loop carries them
+                    def body(i, carry):
+                        acc, kp, vp = carry
+                        o, kp, vp = attend(q, k_new, v_new, kp, vp, i % L, table, kv_lens, q_lens)
+                        return acc + o.astype(jnp.float32), kp, vp
+
+                    return jax.lax.fori_loop(0, calls, body, (jnp.zeros(q.shape[:3] + (dv,), jnp.float32), k_pages, v_pages))
+
+                try:
+                    operands = (q, k_new, v_new, *pools, table, kv_lens, q_lens)
+                    t0 = time.perf_counter()
+                    traced = jax.jit(many, donate_argnums=(3, 4)).trace(*operands)
+                    t1 = time.perf_counter()
+                    lowered = traced.lower()
+                    t2 = time.perf_counter()
+                    program = lowered.compile()
+                    t3 = time.perf_counter()
+                    got_o, got_k, got_v = jax.jit(attend)(q, k_new, v_new, *pools, 0, table, kv_lens, q_lens)
+                finally:
+                    for name, value in defaults.items():
+                        setattr(decode_attention, name, value)
+                # what XLA's write + gather makes of one call, before the pools are donated
+                want_o, want_k, want_v = jax.jit(front, static_argnames=("impl", "window"))(
+                    q, k_new, v_new, *pools, 0, table, kv_lens, q_lens, impl="xla", window=window, sinks=sinks
+                )
+                live = (jnp.arange(W)[None, :] < q_lens[:, None])[:, :, None, None]
+                gap = float(jnp.max(jnp.abs(jnp.where(live, got_o.astype(jnp.float32) - want_o.astype(jnp.float32), 0))))
+                same_pools = all(bool(jnp.array_equal(g[:, 1:], w[:, 1:])) for g, w in ((got_k, want_k), (got_v, want_v)))
+                del want_k, want_v, got_k, got_v
+                best, (_, _, _, *pools, _, _, _) = timed(program, [q, k_new, v_new, *pools, table, kv_lens, q_lens], (3, 4), args.rehearse)
+                print(
+                    f"{model:19s} {mix:11s} W={W:<4d} live rows {sum(1 for n, _ in rows if n):2d} pages {pages:4d}/{R * held}: "
+                    f"{best / calls * 1e6:8.1f} us a call, floor {floor * 1e6:6.1f} us ({bound}), "
+                    f"{100 * floor / (best / calls):5.1f}% | {form} | trace {t1 - t0:.3f} s lower {t2 - t1:.3f} s compile {t3 - t2:.2f} s | "
+                    f"max |o - xla| {gap:.4f} pools {'same' if same_pools else 'DIFFER'}",
+                    flush=True,
+                )
 
 
 if __name__ == "__main__":
