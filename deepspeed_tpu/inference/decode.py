@@ -281,6 +281,9 @@ def _final_logits(cfg, params, x):
             logits = qmatmul(x, params["lm_head"])
             if cfg.lm_head_bias:
                 logits = logits + params["lm_head_bias"].astype(logits.dtype)
+        scaling = getattr(cfg, "logits_scaling", 1.0)  # models/hybrid_moe.py: the logits DIVIDED by it; 1.0 traces nothing
+        if scaling != 1.0:
+            logits = logits * jnp.asarray(1.0 / scaling, logits.dtype)
     return logits
 
 
@@ -308,11 +311,11 @@ _decoder_cache: Dict[Tuple, Tuple] = {}
 def _refuse_state_layers(cfg, what: str) -> None:
     """Every key and value of a row is the only state ``what`` knows of. A
     model with recurrent-state, sliding-window or latent-attention layers
-    (``layer_types`` naming ``linear``, ``window`` or ``latent``) is refused
+    (``layer_types`` naming ``linear``, ``ssm``, ``window`` or ``latent``) is refused
     where it is built, with the missing piece named."""
     if has_state_layers(cfg):
         raise NotImplementedError(
-            f"{what} does not support a model with recurrent-state (linear-attention) or sliding-window layers: "
+            f"{what} does not support a model with recurrent-state (linear-attention or state-space) or sliding-window layers: "
             "it would need a snapshot of each row's recurrent state and convolution tail, or a window layer's "
             "masks, sinks and heads of their own, beside its keys and values, which only the paged server's "
             "per-slot store keeps (serve through init_inference(...).serve())"

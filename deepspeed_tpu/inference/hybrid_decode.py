@@ -1,11 +1,12 @@
 """The ragged serving step of a model whose layers are of more than one kind
 (``models/hybrid_moe.py``): softmax layers that keep keys and values in pages,
 sliding-window layers that keep a row's newest pages in a ring, linear-attention
-layers that keep one recurrent state and a convolution tail a row, latent-attention
+or state-space layers that keep one recurrent state and a convolution tail a row, latent-attention
 layers that keep one low-rank entry a token in pages of their own (a latent
 layer with or without a low-rank query, with rotary or none); leading layers
 with a dense FFN, a leading layer of any kind, then every layer with its
-routed FFN.
+routed FFN, or (``num_experts`` 0) every layer with a dense FFN out of the
+period's stacks.
 
 ``decode.build_ragged_step`` comes here, when the program is BUILT, for a
 config that names ``layer_types``; a uniform model never reaches this file
@@ -30,7 +31,12 @@ parameter of the program) and one more row array:
   entries, the scanned layers the entries behind them. A row whose window
   starts at position 0 (``lengths[r] == 0``) starts
   from zero state inside the program, so admission, preemption and
-  re-admission need no reset dispatch;
+  re-admission need no reset dispatch. A model with state-space layers keeps
+  THEIR state and tail in the same two fields, at the kind's shapes
+  (``state_shapes``): ``[ssm layers, slots + 1, NH, P, N]`` float32 and ``[ssm
+  layers, slots + 1, K - 1, tail_rows(C), 128]``, the ``C = NH P + 2 N``
+  convolved channels a lane tile a row in whole sublane tiles, as
+  ``ssd_decode`` reads them;
 * ``store.window_k / window_v`` ``[window layers, 1 + R * ring, NKV', P, ..]``:
   the rings of the sliding-window layers, with their own KV-head count. Row r
   owns pages ``1 + slots[r] * ring ..`` and position ``p`` lives in ring page
@@ -81,6 +87,18 @@ tiles, as in ``decode._paged_layers``; a window of at most one tile is one
   (``kda_chunked``), one row a trip of a loop whose count is data: one read
   and one write of a row's state and tail a layer. (Four rows a trip, of which
   a steady mixed step fills one, read 41 ms a mixed step for 35: PERF.md, PR 31.)
+* ssm: the same split. A row with ONE token goes through ``ssd_decode`` from
+  the input projection's ``[x ; B ; C]`` and ``dt`` on (the convolution with
+  its bias, the tail, the recurrence and its read-out in one kernel, in place
+  on both pools); a row with a chunk through ``hm.ssm_conv`` and
+  ``ssd_chunked`` from its carried state, one row a trip. The gate ``z`` waits
+  in a token buffer for the tile loop behind the mixer, which gates, norms
+  and projects (``hm.ssm_output``).
+
+The config's scalar multipliers (``embedding_multiplier``,
+``residual_multiplier`` on both branches of every layer, ``logits_scaling`` in
+``decode._final_logits``) are read when the program is built: at 1.0 no
+multiply is traced and the program's text is what it was.
 """
 
 from __future__ import annotations
@@ -97,6 +115,7 @@ from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes
 from deepspeed_tpu.models import hybrid_moe as hm
 from deepspeed_tpu.models.transformer import _norm
 from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked, kda_decode
+from deepspeed_tpu.ops.transformer.state_space import LANES, ssd_chunked, ssd_decode, tail_rows
 
 # Rows with a prefill chunk that one trip of a wide window's attention loop
 # takes (``wide_attention``). A steady mixed step has one such row, the first
@@ -107,13 +126,18 @@ CHUNK_ROWS = 4
 
 
 class StateShapes(NamedTuple):
-    """The per-slot store of a config's state layers, for ``max_slots`` rows."""
+    """The per-slot store of a config's state layers, for ``max_slots`` rows:
+    the shapes are the kind's (``cfg.state_kind``; a config names one at most)."""
 
-    state: tuple  # [linear layers, slots + 1, NH, Dk, Dv], float32
-    conv: tuple  # [linear layers, slots + 1, K - 1, 3, NH, D], the activations' type
+    state: tuple  # linear: [layers, slots + 1, NH, Dk, Dv]; ssm: [layers, slots + 1, NH, P, N]; float32
+    conv: tuple  # linear: [layers, slots + 1, K - 1, 3, NH, D]; ssm: [layers, slots + 1, K - 1, tail_rows(C), 128]; the activations' type
 
 
 def state_shapes(cfg, max_slots: int) -> StateShapes:
+    if cfg.state_kind == "ssm":
+        n, K = cfg.layers_of("ssm"), cfg.ssm_conv_kernel
+        return StateShapes((n, max_slots + 1, cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                           (n, max_slots + 1, K - 1, tail_rows(cfg.ssm_conv_channels), LANES))
     n, NH, D = cfg.layers_of("linear"), cfg.linear_num_heads, cfg.linear_head_dim
     return StateShapes((n, max_slots + 1, NH, D, D), (n, max_slots + 1, cfg.linear_conv_kernel - 1, 3, NH, D))
 
@@ -203,7 +227,8 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         return packed.tiles(body, init) if tiled else body(jnp.int32(0), init)
 
     kv_lens = jnp.where(q_lens > 0, lengths + q_lens, 0)
-    x = params["embed"]["tokens"].astype(dtype)[jnp.take(tokens.reshape(-1), packed.slot, mode="clip")]
+    x = hm.scaled(params["embed"]["tokens"].astype(dtype)[jnp.take(tokens.reshape(-1), packed.slot, mode="clip")], cfg.embedding_multiplier)
+    branch = functools.partial(hm.scaled, by=cfg.residual_multiplier)  # a layer's branch before it is added: at 1.0 itself
     positions = None
     if cfg.position == "rope":  # a packed token's absolute position
         positions = jnp.take((lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]).reshape(-1), packed.slot, mode="clip")
@@ -211,8 +236,9 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
     period, stacks = cfg.period, params["periods"]
     n = len(period)
     E = cfg.num_experts
-    expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[3:]), stacks["moe"]["experts"])
-    moe_stacks = {k: v for k, v in stacks["moe"].items() if k != "experts"}
+    if E:
+        expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[3:]), stacks["moe"]["experts"])
+        moe_stacks = {k: v for k, v in stacks["moe"].items() if k != "experts"}
     NH, D, Dv = cfg.num_heads, cfg.head_dim, cfg.v_head_dim  # NH: a latent layer's heads; a softmax or window layer takes its kind's
     LH, LD, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_conv_kernel
     C3 = 3 * LH * LD
@@ -274,18 +300,28 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         out = jnp.where(chunk_rows[row][:, None], packed.take(chunks, start), jnp.take(ones, row, axis=0))
         return jnp.where(packed.take(packed.live, start)[:, None], out, 0)
 
+    def tail_after_chunk(tail, row, n, taps):
+        """A convolution's tail after a chunk row: the last ``taps - 1`` of
+        (the old ``tail`` ``[taps - 1, C]``, the row's ``n`` real tokens of
+        ``row`` ``[T, C]``)."""
+        at = n + jnp.arange(taps - 1, dtype=jnp.int32)  # in that sequence
+        from_row = jnp.take(row, jnp.clip(at - (taps - 1), 0, T - 1), axis=0)
+        return jnp.where((at >= taps - 1)[:, None], from_row, jnp.take(tail, jnp.minimum(at, taps - 2), axis=0))
+
     def ffn(x_tile, start, per, j):
+        if not E:  # no expert anywhere: the layer's dense FFN out of the period's stacks
+            return dense_ffn(x_tile, start, stacks["ffn"], per, j)
         p = weights_at(moe_stacks, per, j, start)
         moe = functools.partial(
             hm.moe_ffn, live=packed.take(packed.live, start)[None], experts=expert_stacks, group_offset=(per * n + j) * E
         )
         out, counts = decode._ffn_body(cfg, {"moe": p}, x_tile, p["mlp_norm_scale"], None, moe_ffn=moe)
-        return x_tile + out, counts
+        return x_tile + branch(out), counts
 
-    def dense_ffn(x_tile, start, p):
-        p = weights_at(p, None, None, start)
+    def dense_ffn(x_tile, start, p, per=None, j=None):
+        p = weights_at(p, per, j, start)
         out, _ = decode._ffn_body(cfg, p, x_tile, p["mlp_norm_scale"], None)
-        return x_tile + out, jnp.zeros((E,), jnp.int32)
+        return x_tile + branch(out), jnp.zeros((E,), jnp.int32)
 
     def wide_attention(attend, operands, shapes, pools, layer, table, width):
         """A wide window's attention without the window's slab: the rows with
@@ -365,7 +401,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             with jax.named_scope(hm.SCOPES[kind]):
                 p = weights_at(tree, per, jk, start)
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-                x_tile = x_tile + qmatmul(hm.output_gate(p, h, attn(start)), p["wo"]).astype(x.dtype)
+                x_tile = x_tile + branch(qmatmul(hm.output_gate(p, h, attn(start)), p["wo"]).astype(x.dtype))
             x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 
@@ -411,7 +447,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             x_tile = packed.take(x, start)
             with jax.named_scope(hm.SCOPES["latent"]):
                 p = {**weights_at(tree, per, jk, start), **heads}
-                x_tile = x_tile + hm.latent_output(cfg, p, o(start).reshape(-1, NH, C)).astype(x.dtype)
+                x_tile = x_tile + branch(hm.latent_output(cfg, p, o(start).reshape(-1, NH, C)).astype(x.dtype))
             x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 
@@ -462,10 +498,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
                     with jax.named_scope("kda_recurrence"):
                         o_row, S = kda_chunked(q, k, v, la, b, S0)
                     st = jax.lax.dynamic_update_slice(st, S[None].astype(st.dtype), (layer, slots[r], 0, 0, 0))
-                    # the convolution's tail after the chunk: the last K - 1 of (old tail, the row's tokens)
-                    at = q_lens[r] + jnp.arange(K - 1, dtype=jnp.int32)  # in that sequence
-                    from_row = jnp.take(row_qkv, jnp.clip(at - (K - 1), 0, T - 1), axis=0)
-                    last = jnp.where((at >= K - 1)[:, None], from_row, jnp.take(tail[0], jnp.minimum(at, K - 2), axis=0))
+                    last = tail_after_chunk(tail[0], row_qkv, q_lens[r], K)
                     cv = jax.lax.dynamic_update_slice(cv, last.reshape((1, 1) + cv.shape[2:]).astype(cv.dtype), own)
                     return st, cv, put_chunk(chunks, o_row.reshape(T, LH * LD), idx[0], valid)
 
@@ -478,18 +511,80 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             with jax.named_scope("linear_attention"):
                 p = weights_at(tree, per, jl, start)
                 h = _norm(x_tile, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
-                x_tile = x_tile + hm.linear_output(cfg, p, h, o(start).reshape(-1, LH, LD)).astype(x.dtype)
+                x_tile = x_tile + branch(hm.linear_output(cfg, p, h, o(start).reshape(-1, LH, LD)).astype(x.dtype))
             x_tile, tile_counts = ffn(x_tile[None], start)
             return put(x, x_tile[0], start), counts + tile_counts
 
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, st, cv, counts
 
-    # a kind's pools: the full layers' pages, the window layers' rings, the latent layers' pages, the linear layers'
-    # states and convolution tails
-    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,), "linear": (state, conv)}
+    def ssm_layer(x, st, cv, tree, per, js, layer, ffn):
+        """A state-space layer: ``tree``, ``per``, ``js``, ``layer`` (its entry
+        of the state store) and ``ffn`` as ``attention_layer``'s; a row with
+        one token through ``ssd_decode`` in place, a chunk row through
+        ``ssd_chunked``. Returns ``(x, state, conv, counts)``."""
+        SH, SK, inner, SC = cfg.ssm_num_heads, cfg.ssm_conv_kernel, cfg.ssm_inner, cfg.ssm_conv_channels
+
+        def before(start, bufs):
+            p = weights_at(tree, per, js, start)
+            h = _norm(packed.take(x, start), p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
+            return tuple(put(buf, a, start) for buf, a in zip(bufs, hm.ssm_inputs(cfg, p, h)))
+
+        with jax.named_scope(hm.SCOPES["ssm"]):
+            z, xbc, dt = tiles(before, (unfilled((NPK, inner), dtype), unfilled((NPK, SC), dtype), unfilled((NPK, SH), jnp.float32)))
+            # the recurrence's own leaves, sliced out of their stacks once a layer
+            rp = weights_at({k: tree[k] for k in ("conv_w", "conv_b", "A_log", "D")}, per, js, jnp.int32(0))
+            A, D = -jnp.exp(rp["A_log"].astype(jnp.float32)), rp["D"].astype(jnp.float32)
+            # the rows with one token: from the projection to the recurrence's output in one kernel, in place on both pools
+            xbc1, dt1 = (a[starts] if T == 1 else real_rows(a, starts, one_token) for a in (xbc, dt))
+            with jax.named_scope("ssd_recurrence"):
+                y, st, cv = ssd_decode(xbc1, dt1, rp["conv_w"], rp["conv_b"], A, D, st, cv, layer, slots, one_token, fresh)
+            y = y.astype(dtype)  # [B, inner]
+            if T == 1:
+                y = functools.partial(slab_rows, y)
+            else:
+
+                def chunk_row(i, carry):
+                    st, cv, chunks = carry
+                    r = order[i]
+                    idx = packed.index[r]  # [T]
+                    valid = jnp.arange(T, dtype=jnp.int32) < q_lens[r]
+                    own = (layer, slots[r]) + (0,) * (cv.ndim - 2)
+                    held = jax.lax.dynamic_slice(cv, own, (1, 1) + cv.shape[2:])[0, 0, :, : SC // LANES]  # the rows past the channels are zeros
+                    tail = jnp.where(fresh[r], 0, held.reshape(1, SK - 1, SC))
+                    row_xbc = real_rows(xbc, idx, valid)  # [T, C]
+                    with jax.named_scope("ssd_recurrence"):
+                        xs, Bm, Cm = hm.ssm_split(cfg, hm.ssm_conv(rp, tail, row_xbc[None]))
+                        S0 = jnp.where(fresh[r], 0.0, st[layer, slots[r]].astype(jnp.float32))[None]
+                        # a slot past the row's tokens is a dead position: dt 0 leaves the state as it is
+                        y_row, S = ssd_chunked(xs, Bm, Cm, real_rows(dt, idx, valid)[None], A, D, S0)
+                    st = jax.lax.dynamic_update_slice(st, S[None].astype(st.dtype), (layer, slots[r], 0, 0, 0))
+                    last = tail_after_chunk(tail[0], row_xbc, q_lens[r], SK).reshape(SK - 1, SC // LANES, LANES)
+                    last = jnp.pad(last, ((0, 0), (0, cv.shape[3] - SC // LANES), (0, 0)))  # zeros in the rows past the channels
+                    cv = jax.lax.dynamic_update_slice(cv, last[None, None].astype(cv.dtype), own)
+                    return st, cv, put_chunk(chunks, y_row.reshape(T, inner), idx[0], valid)
+
+                st, cv, chunks = jax.lax.fori_loop(0, n_chunk_rows, chunk_row, (st, cv, unfilled((NPK, inner), dtype)))
+                y = functools.partial(rows_output, chunks, y)
+
+        def after(start, carry):
+            x, counts = carry
+            x_tile = packed.take(x, start)
+            with jax.named_scope(hm.SCOPES["ssm"]):
+                p = weights_at(tree, per, js, start)
+                x_tile = x_tile + branch(hm.ssm_output(cfg, p, packed.take(z, start), y(start)).astype(x.dtype))
+            x_tile, tile_counts = ffn(x_tile[None], start)
+            return put(x, x_tile[0], start), counts + tile_counts
+
+        x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
+        return x, st, cv, counts
+
+    # a kind's pools: the full layers' pages, the window layers' rings, the latent layers' pages, the linear or the
+    # state-space layers' states and convolution tails
+    state_kind = cfg.state_kind or "linear"  # the kind whose states and tails the store's two fields hold
+    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,), state_kind: (state, conv)}
     mixers = {"softmax": functools.partial(attention_layer, "softmax"), "window": functools.partial(attention_layer, "window"),
-              "latent": latent_layer, "linear": linear_layer}
+              "latent": latent_layer, "linear": linear_layer, "ssm": ssm_layer}
     # the leading dense layers, each with its own weights and the first entries of its kind's pools
     for i, kind in enumerate(cfg.layer_types[: cfg.leading_dense_layers]):
         lead = params["leading"][i]
@@ -515,7 +610,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         return (x, pools), jnp.stack(counts)
 
     (x, pools), counts = jax.lax.scan(period_step, (x, pools), jnp.arange(cfg.num_periods, dtype=jnp.int32))
-    store = StateStore(*pools["linear"], *pools["window"], *pools["latent"])
+    store = StateStore(*pools[state_kind], *pools["window"], *pools["latent"])
     return x, *pools["softmax"], store, counts.reshape(cfg.num_moe_layers, E), packed
 
 
@@ -558,7 +653,8 @@ def build_hybrid_ragged_step(cfg, rows: int, width: int, page_size: int, attn_im
         with jax.named_scope("head_sample"):
             accepted = decode._accepted_prefix(tokens, greedy, q_lens - 1)
             out = jnp.concatenate([accepted[:, None].astype(jnp.int32), greedy], axis=1)
-            out = jnp.concatenate([out, decode._moe_stat_rows(moe_counts, W + 1)], axis=0)
+            if cfg.num_experts:  # a model with no expert has no routing counts: its result is the rows alone
+                out = jnp.concatenate([out, decode._moe_stat_rows(moe_counts, W + 1)], axis=0)
         return out, kp, vp, store
 
     fn = decode._jit(_step, telemetry, name, donate_argnums=(2, 3, 4))

@@ -195,7 +195,12 @@ class StateStore(NamedTuple):
     into every serving program beside the pages and returned in place.
     Nothing here is allocated or freed.
 
-    * recurrent-state layers: a state and a convolution tail; entry
+    * recurrent-state layers (delta-rule linear attention OR Mamba-2
+      state-space layers: a model names one kind at most): a state and a
+      convolution tail, whose shapes are the KIND's
+      (``hybrid_decode.state_shapes``: a linear layer's square state a head
+      and three convolved streams, a state-space layer's ``[heads, head_dim,
+      state]`` and one stream of ``[x ; B ; C]`` a lane tile a row); entry
       ``max_slots`` belongs to nobody and takes dead rows' writes. A slot's
       entry is restarted from zero by the program when a row's window begins
       at position 0 (``inference/hybrid_decode.py``).
@@ -223,8 +228,8 @@ class StateStore(NamedTuple):
     a slot's state, and ``PagePool.cache_bytes`` says which of the two the
     live rows' bytes are in."""
 
-    state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32
-    conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3, NH, D]
+    state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32 (ssm: [.., NH, P, N])
+    conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3, NH, D] (ssm: [.., K - 1, tail_rows(C), 128])
     window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk]
     window_v: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None  # [latent layers, num_pages, P, lanes]
@@ -305,10 +310,12 @@ class PagePool:
         self.window_ring = 0
         self.window_keys = 0  # keys a window layer's query sees
         self.query_heads: dict = {}  # a layer kind's query heads, for the memory report
+        self.state_kind: Optional[str] = None  # the kind whose layers' states the store holds: linear | ssm
         if getattr(cfg, "layer_types", None):
             from deepspeed_tpu.inference.hybrid_decode import state_shapes, window_shapes
 
             shapes = state_shapes(cfg, self.max_slots)
+            self.state_kind = cfg.state_kind
             kv_dtype = self.cache.k_pages.dtype
             self.query_heads = {kind: cfg.heads_of(kind) for kind in ("softmax", "window")}
             rings = (None, None)
@@ -420,6 +427,9 @@ class PagePool:
             state = {
                 # the paged (full or latent) layers' query heads; a window layer's are with its ring's entries
                 "paged_query_heads": self.query_heads["softmax"],
+                "state_kind": self.state_kind,  # None: no recurrent-state layer (empty arrays)
+                "state_shape": list(self.states.state.shape[2:]),  # a slot's state in one layer, float32
+                "state_layers": self.states.state.shape[0],
                 "state_total_bytes": self.states.state.nbytes + self.states.conv.nbytes,
                 "state_bytes_per_slot": self.state_bytes_per_slot,
                 "state_bytes_in_use": cache["state_bytes_in_use"],

@@ -100,10 +100,10 @@ class TransformerConfig:
 def has_state_layers(cfg) -> bool:
     """Whether a model keeps, beside the keys and values of its full-attention
     layers, something of a row that exists at the row's newest positions only
-    (``models/hybrid_moe.py``: a ``layer_types`` that names ``linear``, whose
-    layers keep a recurrent state, or ``window``, whose layers keep a ring of
-    the newest pages)."""
-    return bool({"linear", "window"} & set(getattr(cfg, "layer_types", None) or ()))
+    (``models/hybrid_moe.py``: a ``layer_types`` that names ``linear`` or
+    ``ssm``, whose layers keep a recurrent state, or ``window``, whose layers
+    keep a ring of the newest pages)."""
+    return bool({"linear", "ssm", "window"} & set(getattr(cfg, "layer_types", None) or ()))
 
 
 def has_latent_layers(cfg) -> bool:

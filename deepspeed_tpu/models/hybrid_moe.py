@@ -2,12 +2,14 @@
 every earlier key in some, over a sliding window in others, gated delta-rule
 linear attention (``ops/transformer/linear_attention.py``) in others, latent
 attention (one low-rank latent a token in place of keys and values a head) in
+others, a Mamba-2 state-space mixer (``ops/transformer/state_space.py``) in
 others; a routed FFN that may have a shared expert and may hold only this chip's share
 of the experts its router chooses from, behind ``leading_dense_layers`` layers
-whose FFN is dense: a leading layer of any kind.
+whose FFN is dense: a leading layer of any kind; or, with ``num_experts`` 0, a
+dense FFN in EVERY layer, stacked by period like the mixers.
 
 ``HybridMoEConfig.layer_types`` says what each layer is (``softmax`` /
-``window`` / ``linear`` / ``latent``); after the leading dense layers the list repeats
+``window`` / ``linear`` / ``latent`` / ``ssm``); after the leading dense layers the list repeats
 with a period (one softmax layer and three linear ones, say, or five window
 layers and a softmax one). Parameters are stacked by KIND inside a period and
 by period in front; a leading layer has its own::
@@ -17,7 +19,9 @@ by period in front; a leading layer has its own::
     params["periods"]["window"]   leaves [periods, window layers a period, ...]
     params["periods"]["linear"]   leaves [periods, linear layers a period, ...]
     params["periods"]["latent"]   leaves [periods, latent layers a period, ...]
+    params["periods"]["ssm"]      leaves [periods, state-space layers a period, ...]
     params["periods"]["moe"]      leaves [periods, layers a period, ...]
+    params["periods"]["ffn"]      in place of "moe" where ``num_experts`` is 0: a dense FFN a layer
 
 so the leading layers and then one ``lax.scan`` over periods run the model,
 the scan's body holding the period's layers in order. The functions below are
@@ -60,7 +64,21 @@ with ``position="none"``, ``q_rope`` and ``k_rope = r`` as projected (the
 shared features are kept, nothing is rotated); scale ``head_dim^-0.5``.
 ``apply`` computes that (the expanded form); the server keeps
 ``[c_kv ; k_rope]`` a token and computes the same numbers absorbed: ``q~ =
-q_nope Wk_b,h^T`` against ``c_kv``, ``o = (P c_kv) Wv_b,h``. The FFN: ``moe_scoring``
+q_nope Wk_b,h^T`` against ``c_kv``, ``o = (P c_kv) Wv_b,h``. The state-space
+layer (``ssm_inputs``, ``ssm_conv``, ``ssm_split``, ``ssm_output``; Mamba-2 with
+ONE group): ``[z ; xBC ; dt] = h W_in`` (``d_inner = ssm_num_heads x
+ssm_head_dim``, ``d_inner + 2 ssm_state``, ``ssm_num_heads``; the published
+``in_proj``'s three parts are three leaves, ``w_z``, ``w_xbc``, ``w_dt``); ``xBC`` through
+one depthwise causal convolution of ``ssm_conv_kernel`` taps WITH a bias and
+SiLU, then split into ``x`` a head, ``B`` and ``C`` of ``ssm_state`` (shared by
+all heads); ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a head; the
+state ``S`` ``[heads, head_dim, ssm_state]`` float32, ``S_t = exp(dt_t A) S_{t-1}
++ dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``; output ``RMSNorm(y * silu(z))
+W_out``, the gate BEFORE the norm, the norm over all ``d_inner`` features. The
+scalar multipliers (each 1.0 puts nothing into a program): the embedding times
+``embedding_multiplier``, BOTH branches of every layer times
+``residual_multiplier`` before they are added, the logits divided by
+``logits_scaling``; a softmax layer's scale is ``attn_softmax_scale``. The FFN: ``moe_scoring``
 over ``moe_router_experts`` outputs, the ``moe_top_k`` largest of score +
 selection bias, gates normalised over the chosen (``moe_norm_topk_prob``) and
 scaled by ``moe_routed_scaling``; of those, the experts this chip holds
@@ -71,6 +89,7 @@ the shared expert, once.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -81,9 +100,11 @@ from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.models.moe_transformer import MoETransformerConfig, MoETransformerLM
 from deepspeed_tpu.models.transformer import _norm
 
-LAYER_KINDS = ("softmax", "linear", "window", "latent")
+LAYER_KINDS = ("softmax", "linear", "window", "latent", "ssm")
 # the named scope around a kind's mixer, which the benchmark's readers find device time by
-SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention", "latent": "latent_attention"}
+SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention", "latent": "latent_attention",
+          "ssm": "ssm_mixer"}
+STATE_KINDS = ("linear", "ssm")  # the kinds whose layers keep a recurrent state and a convolution tail a slot
 
 
 @dataclasses.dataclass
@@ -125,6 +146,17 @@ class HybridMoEConfig(MoETransformerConfig):
     moe_expert_share: Tuple[int, int] = (0, 1)  # (index, of): which share of the router's experts is held
     moe_shared_experts: int = 0  # shared experts, run as one FFN of that many expert widths
     moe_routed_scaling: float = 1.0
+    # state-space (Mamba-2) layers: heads of ``ssm_head_dim`` over a state of ``ssm_state`` a feature, B and C in
+    # ``ssm_groups`` groups (1: shared by all heads), one convolution over [x ; B ; C]
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    # the scalar multipliers: 1.0 is no multiply anywhere (decided in Python when a program is built)
+    embedding_multiplier: float = 1.0  # the embedding times this
+    residual_multiplier: float = 1.0  # each branch of a layer times this, before it is added
+    logits_scaling: float = 1.0  # the logits DIVIDED by this
 
     def __post_init__(self):
         super().__post_init__()
@@ -156,10 +188,32 @@ class HybridMoEConfig(MoETransformerConfig):
         self.linear_num_heads = self.linear_num_heads or self.num_heads
         self.linear_head_dim = self.linear_head_dim or self.head_dim
         self.linear_gate_rank = self.linear_gate_rank or self.linear_head_dim
+        if "ssm" in self.layer_types:
+            if min(self.ssm_num_heads, self.ssm_head_dim, self.ssm_state) < 1 or self.ssm_conv_kernel < 2:
+                raise ValueError("a state-space layer needs ssm_num_heads, ssm_head_dim, ssm_state and ssm_conv_kernel >= 2")
+            if self.ssm_groups != 1:
+                raise NotImplementedError(
+                    f"ssm_groups={self.ssm_groups}: a state-space layer's B and C are shared by all its heads here (one "
+                    "group, which the decode kernel reads once a row); no published model served here asks for more"
+                )
+            if self.ssm_conv_channels % 128:
+                raise ValueError(
+                    f"a state-space layer's convolved channels d_inner + 2 ssm_state = {self.ssm_conv_channels} must be "
+                    "whole lane tiles of 128: the per-slot store keeps a row's tail a lane tile a row"
+                )
+            if "linear" in self.layer_types:
+                raise NotImplementedError(
+                    "a model with delta-rule linear layers AND state-space layers: the per-slot store holds ONE kind of "
+                    "recurrent state (kv_pool.StateStore: one state array, one tail array, their shapes the kind's); "
+                    "no published model asks for both"
+                )
         self.moe_expert_share = tuple(self.moe_expert_share)
         index, of = self.moe_expert_share
         if self.moe_router_experts is None:
             self.moe_router_experts = self.num_experts * of
+        if self.num_experts == 0 and (self.leading_dense_layers or self.moe_shared_experts):
+            raise ValueError("num_experts=0 makes EVERY layer's FFN a dense one of intermediate_size, stacked by period: "
+                             "it has no leading dense layers apart and no shared expert")
         if self.num_experts * of != self.moe_router_experts or not 0 <= index < of:
             raise ValueError(
                 f"share {index} of {of} of a router over {self.moe_router_experts} experts holds "
@@ -186,7 +240,23 @@ class HybridMoEConfig(MoETransformerConfig):
 
     @property
     def num_moe_layers(self) -> int:
-        return self.num_layers - self.leading_dense_layers
+        """Layers with a routed FFN: those behind the leading dense ones; none where ``num_experts`` is 0."""
+        return self.num_layers - self.leading_dense_layers if self.num_experts else 0
+
+    @property
+    def ssm_inner(self) -> int:
+        """A state-space layer's inner width: its heads side by side."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """What a state-space layer convolves of a token: ``[x ; B ; C]``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def state_kind(self) -> Optional[str]:
+        """The kind whose layers keep a recurrent state and a convolution tail a slot (``STATE_KINDS``), or None."""
+        return next((kind for kind in STATE_KINDS if kind in self.layer_types), None)
 
     def layers_of(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)
@@ -335,6 +405,49 @@ def linear_output(cfg: HybridMoEConfig, p, h, o):
     return qmatmul((o.reshape(gate.shape) * gate).astype(h.dtype), p["wo"])
 
 
+def ssm_inputs(cfg: HybridMoEConfig, p, h):
+    """What a state-space layer computes of one token before its convolution,
+    from the normed ``h`` [..., H]: the gate ``z`` [..., d_inner] and the
+    pre-convolution ``[x ; B ; C]`` [..., d_inner + 2 N] in h's type, and
+    ``dt = softplus(dt + dt_bias)`` [..., NH] in float32 (no clamp: the config
+    names none)."""
+    dt = jax.nn.softplus(qmatmul(h, p["w_dt"]).astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    return qmatmul(h, p["w_z"]), qmatmul(h, p["w_xbc"]), dt
+
+
+def ssm_conv(p, tails, xbc):
+    """The depthwise causal convolution WITH its bias, and SiLU: ``xbc`` [B, T,
+    C] after the ``K - 1`` inputs that came before it (``tails`` [B, K - 1,
+    C]). Float32 [B, T, C]: the decode kernel's own arithmetic
+    (``state_space.decode_conv``) over the window's ``K`` shifted views."""
+    from deepspeed_tpu.ops.transformer.state_space import decode_conv
+
+    T = xbc.shape[1]
+    ext = jnp.concatenate([tails, xbc], axis=1)
+    return decode_conv(p["conv_w"], p["conv_b"], [ext[:, j : j + T] for j in range(p["conv_w"].shape[0])])
+
+
+def ssm_split(cfg: HybridMoEConfig, y):
+    """The convolved ``y`` [..., d_inner + 2 N], split: ``x`` [..., NH, P],
+    ``B`` and ``C`` [..., N] (one group: shared by all heads)."""
+    inner, N = cfg.ssm_inner, cfg.ssm_state
+    x = y[..., :inner].reshape(y.shape[:-1] + (cfg.ssm_num_heads, cfg.ssm_head_dim))
+    return x, y[..., inner : inner + N], y[..., inner + N :]
+
+
+def ssm_output(cfg: HybridMoEConfig, p, z, y):
+    """``y`` [..., d_inner] of the recurrence and the gate ``z``: the gate
+    BEFORE the norm, the RMSNorm over all ``d_inner`` features (one group),
+    the output projection. [..., H]."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return qmatmul(_norm(gated, p["o_norm_scale"], None, "rmsnorm", cfg.norm_eps).astype(z.dtype), p["wo"])
+
+
+def scaled(x, by: float):
+    """``x`` times a config's scalar multiplier; at 1.0 ``x`` itself, no multiply in any program."""
+    return x if by == 1.0 else x * jnp.asarray(by, x.dtype)
+
+
 def latent_project(cfg: HybridMoEConfig, p, h, positions):
     """A latent layer's projections of the normed ``h`` [B, T, H] at
     ``positions`` [B, T] (``position="none"``: unused, may be None): ``q_nope``
@@ -458,6 +571,25 @@ class HybridMoETransformerLM(MoETransformerLM):
                     "o_norm_scale": jnp.ones(lead + (LD,)),
                     "wo": dense(lead + (C, H), out_std),
                 }
+            if kind == "ssm":
+                SH, inner, SC, SK = cfg.ssm_num_heads, cfg.ssm_inner, cfg.ssm_conv_channels, cfg.ssm_conv_kernel
+                # the family's modelling code: a rate of 1..16 a head, a step (after softplus) of 1e-3..1e-1, D ones
+                dt = jnp.exp(jax.random.uniform(next(keys), lead + (SH,), minval=np.log(1e-3), maxval=np.log(1e-1)))
+                return {
+                    "attn_norm_scale": jnp.ones(lead + (H,)),
+                    # the published in_proj [H, z ; x B C ; dt], its three parts apart (a projection reads its matrix
+                    # where it lies: of one matrix of 8,512 columns, no whole lane tiles, each layer's slice is written out)
+                    "w_z": dense(lead + (H, inner)),
+                    "w_xbc": dense(lead + (H, SC)),
+                    "w_dt": dense(lead + (H, SH)),
+                    "conv_w": dense(lead + (SK, SC), 0.5),
+                    "conv_b": dense(lead + (SC,)),
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+                    "A_log": jnp.log(jax.random.uniform(next(keys), lead + (SH,), minval=1.0, maxval=16.0)),
+                    "D": jnp.ones(lead + (SH,)),
+                    "o_norm_scale": jnp.ones(lead + (inner,)),
+                    "wo": dense(lead + (inner, H), out_std),
+                }
             if kind == "latent":
                 Cq, C, nope, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
                 if Cq:
@@ -491,32 +623,30 @@ class HybridMoETransformerLM(MoETransformerLM):
             return attn
 
         periods: Dict[str, Any] = {kind: mixer(kind, NP, period.count(kind)) for kind in LAYER_KINDS if kind in period}
-        moe = {
-            "mlp_norm_scale": jnp.ones((NP, n, H)),
-            "gate": {"wg": dense((NP, n, H, ER))},
-            "experts": {
-                "w_gate": dense((NP, n, E, H, I)),
-                "w_up": dense((NP, n, E, H, I)),
-                "w_out": dense((NP, n, E, I, H), out_std),
-            },
-        }
-        if cfg.moe_select_bias:
-            moe["gate"]["bias"] = dense((NP, n, ER))
-        if cfg.moe_shared_experts:
-            Is = I * cfg.moe_shared_experts
-            moe["shared"] = {"w_gate": dense((NP, n, H, Is)), "w_up": dense((NP, n, H, Is)), "w_out": dense((NP, n, Is, H), out_std)}
-        periods["moe"] = moe
+        Id = cfg.intermediate_size
+        dense_ffn = lambda *lead: {"mlp_norm_scale": jnp.ones(lead + (H,)), "w_gate": dense(lead + (H, Id)),
+                                   "w_up": dense(lead + (H, Id)), "w_out": dense(lead + (Id, H), out_std)}
+        if E:
+            moe = {
+                "mlp_norm_scale": jnp.ones((NP, n, H)),
+                "gate": {"wg": dense((NP, n, H, ER))},
+                "experts": {
+                    "w_gate": dense((NP, n, E, H, I)),
+                    "w_up": dense((NP, n, E, H, I)),
+                    "w_out": dense((NP, n, E, I, H), out_std),
+                },
+            }
+            if cfg.moe_select_bias:
+                moe["gate"]["bias"] = dense((NP, n, ER))
+            if cfg.moe_shared_experts:
+                Is = I * cfg.moe_shared_experts
+                moe["shared"] = {"w_gate": dense((NP, n, H, Is)), "w_up": dense((NP, n, H, Is)), "w_out": dense((NP, n, Is, H), out_std)}
+            periods["moe"] = moe
+        else:  # no expert anywhere: a dense FFN a layer, stacked as the mixers are
+            periods["ffn"] = dense_ffn(NP, n)
         params = {"embed": {"tokens": dense((V, H))}, "periods": periods, "final_norm_scale": jnp.ones((H,))}
         if cfg.leading_dense_layers:
-            Id = cfg.intermediate_size
-            params["leading"] = [
-                {
-                    "mixer": mixer(kind),
-                    "ffn": {"mlp_norm_scale": jnp.ones((H,)), "w_gate": dense((H, Id)), "w_up": dense((H, Id)),
-                            "w_out": dense((Id, H), out_std)},
-                }
-                for kind in cfg.layer_types[: cfg.leading_dense_layers]
-            ]
+            params["leading"] = [{"mixer": mixer(kind), "ffn": dense_ffn()} for kind in cfg.layer_types[: cfg.leading_dense_layers]]
         if not cfg.tie_embeddings:
             params["lm_head"] = dense((H, V))
         return params
@@ -570,15 +700,27 @@ class HybridMoETransformerLM(MoETransformerLM):
         o, _ = kda_chunked(q, k, v, log_a.reshape(B, T, NH, D), beta, jnp.zeros((B, NH, D, D), jnp.float32))
         return linear_output(cfg, p, h, o)
 
+    def _ssm_mixer(self, p, h):
+        from deepspeed_tpu.ops.transformer.state_space import ssd_chunked
+
+        cfg = self.config
+        B, T, _ = h.shape
+        z, xbc, dt = ssm_inputs(cfg, p, h)
+        x, Bm, Cm = ssm_split(cfg, ssm_conv(p, jnp.zeros((B, cfg.ssm_conv_kernel - 1, xbc.shape[-1]), xbc.dtype), xbc))
+        state = jnp.zeros((B, cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+        y, _ = ssd_chunked(x, Bm, Cm, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), p["D"].astype(jnp.float32), state)
+        return ssm_output(cfg, p, z, y.reshape(B, T, cfg.ssm_inner))
+
     def apply(self, params, batch, *, rngs=None, train: bool = False, pld_theta=None, ltd_idx=None):
         from deepspeed_tpu.models.transformer import _split_batch, cross_entropy_loss
         from deepspeed_tpu.moe.experts import apply_dense_ffn
 
         if train:
-            raise NotImplementedError("training a hybrid (linear-attention) model is not supported: apply is the eval forward")
+            raise NotImplementedError("training a hybrid (linear-attention or state-space) model is not supported: apply is the eval forward")
         cfg = self.config
         tokens, labels = _split_batch(batch)
-        x = params["embed"]["tokens"].astype(self.dtype)[tokens]
+        x = scaled(params["embed"]["tokens"].astype(self.dtype)[tokens], cfg.embedding_multiplier)
+        branch = functools.partial(scaled, by=cfg.residual_multiplier)
 
         def mix(x, kind, mixer):
             h = _norm(x, mixer["attn_norm_scale"], None, "rmsnorm", cfg.norm_eps)
@@ -587,31 +729,38 @@ class HybridMoETransformerLM(MoETransformerLM):
                     out = self._linear_mixer(mixer, h)
                 elif kind == "latent":
                     out = self._latent_mixer(mixer, h)
+                elif kind == "ssm":
+                    out = self._ssm_mixer(mixer, h)
                 else:
                     out = self._attention_mixer(kind, mixer, h)
-            return x + out.astype(x.dtype)
+            return x + branch(out.astype(x.dtype))
+
+        def dense(x, p):
+            with jax.named_scope("mlp"):
+                h = _norm(x, p["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+                return x + branch(apply_dense_ffn(p, h, cfg.activation).astype(x.dtype))
 
         for kind, p in zip(cfg.layer_types, params.get("leading", ())):
-            x = mix(x, kind, p["mixer"])
-            with jax.named_scope("mlp"):
-                h = _norm(x, p["ffn"]["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps)
-                x = x + apply_dense_ffn(p["ffn"], h, cfg.activation).astype(x.dtype)
+            x = dense(mix(x, kind, p["mixer"]), p["ffn"])
 
         def period_step(x, p):
             at = {kind: 0 for kind in LAYER_KINDS}
             for j, kind in enumerate(cfg.period):
                 x = mix(x, kind, jax.tree_util.tree_map(lambda a: a[at[kind]], p[kind]))
                 at[kind] += 1
+                if "ffn" in p:  # num_experts 0: every layer's FFN is dense
+                    x = dense(x, jax.tree_util.tree_map(lambda a: a[j], p["ffn"]))
+                    continue
                 moe = jax.tree_util.tree_map(lambda a: a[j], p["moe"])
                 with jax.named_scope("mlp"):
                     out, _ = moe_ffn(cfg, moe, _norm(x, moe["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps))
-                x = x + out.astype(x.dtype)
+                x = x + branch(out.astype(x.dtype))
             return x, None
 
         x, _ = jax.lax.scan(period_step, x, params["periods"])
         x = _norm(x, params["final_norm_scale"], None, "rmsnorm", cfg.norm_eps)
         head = params["embed"]["tokens"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = qmatmul(x, head.astype(x.dtype))
+        logits = scaled(qmatmul(x, head.astype(x.dtype)), 1.0 / cfg.logits_scaling)
         return logits if labels is None else cross_entropy_loss(logits, labels)
 
 
@@ -788,4 +937,37 @@ def kimi_linear_config(size: str = "48b-a3b", **overrides) -> HybridMoEConfig:
     if "layer_types" not in base:
         full_attn_layers = (4, 8, 12, 16, 20, 24, 27)  # counted from 1, as published; the others are kda_layers
         base["layer_types"] = ["latent" if i + 1 in full_attn_layers else "linear" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)
+
+
+def granite_hybrid_config(size: str = "4.0-h-micro", **overrides) -> HybridMoEConfig:
+    """granite-4.0-h-micro (``ibm-granite/granite-4.0-h-micro`` ``config.json``,
+    ``model_type: granitemoehybrid``): 40 layers, layers 5, 15, 25 and 35 causal
+    GQA of 32 query heads over 8 KV heads of 64 with NO positional term and a
+    softmax scale of 1/64 (``attention_multiplier``), the other 36 Mamba-2
+    mixers of 64 heads of 64 over a state of 128 (one group, a 4-tap
+    convolution with a bias); a dense SwiGLU FFN of 8,192 in EVERY layer
+    (``num_local_experts`` 0: ``num_experts`` 0 here); the embedding times 12,
+    both branches of every layer times 0.22, the logits divided by 8, the
+    embedding tied to the head. ``4.0-h-micro`` is the published model whole;
+    ``tiny`` a toy of two periods ``[ssm, ssm, softmax]`` for tests, its
+    state-space layer at the kernel's own tiles (heads of 64, a state of 128)."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=6, num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=512, max_seq_len=256,
+                     intermediate_size=96, ssm_num_heads=2, ssm_head_dim=64, ssm_state=128, attn_softmax_scale=0.0625,
+                     layer_types=["ssm", "ssm", "softmax"] * 2),
+        "4.0-h-micro": dict(hidden_size=2048, num_layers=40, num_heads=32, num_kv_heads=8, head_dim=64, vocab_size=100352,
+                            max_seq_len=131072, intermediate_size=8192, ssm_num_heads=64, ssm_head_dim=64, ssm_state=128,
+                            attn_softmax_scale=0.015625),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="none", activation="swiglu", use_bias=False, tie_embeddings=True,
+        ssm_groups=1, ssm_conv_kernel=4, embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0,
+        num_experts=0, moe_top_k=0, moe_layer_freq=1, moe_drop_tokens=False,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        # layer_types: attention at 5 and then every tenth, mamba elsewhere
+        base["layer_types"] = ["softmax" if i % 10 == 5 else "ssm" for i in range(base["num_layers"])]
     return HybridMoEConfig(**base)

@@ -49,6 +49,7 @@ from deepspeed_tpu.models import TransformerLM
 from deepspeed_tpu.models.config import TransformerConfig
 from deepspeed_tpu.utils import chaos
 from deepspeed_tpu.utils.loadgen import TenantLoad, VirtualClock, make_trace, replay
+from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file  # noqa: F401 (the two fixtures are taken by their import)
 
 CFG = dict(
     vocab_size=128,

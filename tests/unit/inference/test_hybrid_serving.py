@@ -25,6 +25,7 @@ from benchmark.files import load_module
 from deepspeed_tpu.inference import decode, hybrid_decode
 from deepspeed_tpu.inference.scheduler import PagedServer
 from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, solar_open2_config
+from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file, apply_logits, seeded  # noqa: F401 (the two fixtures are taken by their import)
 
 REFERENCE = load_module("reference", "solar_open2_decoder")
 PAGE, SLOTS, CHUNK, MAXLEN = 8, 4, 16, 96
@@ -34,7 +35,7 @@ F32_TOL = 5e-5
 def _model(dtype="float32", **kw):
     cfg = solar_open2_config("tiny", num_layers=8, dtype=dtype, **kw)
     lm = HybridMoETransformerLM(cfg)
-    params = lm.init(jax.random.PRNGKey(0), None)
+    params = seeded(lm)
     section = {"kwargs": {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}}
     return cfg, lm, params, section
 
@@ -110,8 +111,9 @@ def toy():
 
 
 def _reference_logits(section, params, seqs):
-    T = max(len(v) for v in seqs.values())
-    tokens = np.zeros((len(seqs), T), np.int32)
+    """At MAXLEN whatever the sequences' lengths (padded behind: the model is causal and routes token by token, so
+    what follows a position does not move its logits): the reference compiles for ONE shape a file, not one a test."""
+    tokens = np.zeros((len(seqs), MAXLEN), np.int32)
     for i, seq in enumerate(seqs.values()):
         tokens[i, : len(seq)] = seq
     lg = np.asarray(REFERENCE.logits(section, params, tokens))
@@ -220,13 +222,9 @@ def test_the_engine_serves_it_with_two_programs_and_preemption_changes_nothing(t
     report = eng.memory_report(enforce=False)
     assert any(b["name"] == "recurrent_state" and b["per_chip_bytes"] == pool.states.hbm_bytes() for b in report["entries"])
     # every served token is the reference's arg-max at its position (float32, no near-tie at this size)
-    T = max(o.size for o in outs)
-    tokens = np.zeros((len(outs), T), np.int32)
-    for i, o in enumerate(outs):
-        tokens[i, : o.size] = o
-    lg = np.asarray(REFERENCE.logits(section, params, tokens))
+    lg = _reference_logits(section, params, dict(enumerate(outs)))
     for i, (p, o) in enumerate(zip(prompts, outs)):
-        gap = lg[i, p.size - 1 : o.size - 1].max(-1) - np.take_along_axis(lg[i, p.size - 1 : o.size - 1], o[p.size :, None], -1)[:, 0]
+        gap = lg[i][p.size - 1 : o.size - 1].max(-1) - np.take_along_axis(lg[i][p.size - 1 : o.size - 1], o[p.size :, None], -1)[:, 0]
         assert gap.max() < F32_TOL, i
     tight = _server(lm, params, num_pages=14)
     squeezed = tight.serve(prompts, max_new_tokens=budgets)
@@ -284,7 +282,7 @@ def test_a_hybrid_config_without_linear_layers_serves_with_an_empty_state_store(
     and nothing refused for a state that does not exist."""
     cfg = solar_open2_config("tiny", num_layers=2, layer_types=["softmax", "softmax"], dtype="float32")
     lm = HybridMoETransformerLM(cfg)
-    params = lm.init(jax.random.PRNGKey(0), None)
+    params = seeded(lm)
     eng = _server(lm, params)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 512, n).astype(np.int32) for n in (20, 5)]
@@ -293,7 +291,7 @@ def test_a_hybrid_config_without_linear_layers_serves_with_an_empty_state_store(
     tokens = np.zeros((2, 32), np.int32)
     for i, o in enumerate(outs):
         tokens[i, : o.size] = o
-    lg = np.asarray(lm.apply(params, tokens))
+    lg = apply_logits(lm, params, tokens)
     for i, (p, o) in enumerate(zip(prompts, outs)):
         assert [int(lg[i, p.size - 1 + j].argmax()) for j in range(6)] == [int(t) for t in o[p.size :]]
     assert eng._paged_server.pool.rollback(0, 0) == 0
@@ -335,7 +333,7 @@ def test_what_lies_past_the_live_tiles_reaches_nothing(kind, monkeypatch):
     from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
 
     cfg = _one_kind(kind)
-    params = HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None)
+    params = seeded(HybridMoETransformerLM(cfg))
     monkeypatch.setattr(decode, "DENSE_TOKEN_TILE", 16)
     assert decode.token_tile(cfg) == 16 < SLOTS * CHUNK and kind in cfg.period
     maxp = MAXLEN // PAGE

@@ -35,6 +35,7 @@ from deepspeed_tpu.inference import decode, hybrid_decode
 from deepspeed_tpu.inference.kv_pool import PagePool, key_lanes
 from deepspeed_tpu.inference.scheduler import PagedServer
 from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, kimi_linear_config
+from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file, apply_logits, seeded  # noqa: F401 (the two fixtures are taken by their import)
 
 REFERENCE = load_module("reference", "kimi_linear_decoder")
 PAGE, SLOTS, CHUNK, MAXLEN = 8, 4, 16, 96
@@ -44,7 +45,7 @@ F32_TOL = 5e-5
 def _model(dtype="float32", **kw):
     cfg = kimi_linear_config("tiny", dtype=dtype, **kw)
     lm = HybridMoETransformerLM(cfg)
-    params = lm.init(jax.random.PRNGKey(0), None)
+    params = seeded(lm)
     # trained-like scores: init's 0.02 gives a nearly flat softmax, in which a wrong rotary or a dropped part hides
     params["periods"]["latent"]["wq"] = params["periods"]["latent"]["wq"] * 40.0
     section = {"kwargs": {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}}
@@ -186,7 +187,7 @@ def test_apply_is_the_reference(toy):
     tokens = _sequences(7, lens=(50,))[0][None]
     assert cfg.layer_types == ("linear",) + ("linear", "linear", "latent", "linear") * 2
     assert cfg.leading_dense_layers == 1 and cfg.num_periods == 2 and cfg.q_lora_rank == 0 and cfg.position == "none"
-    assert np.abs(np.asarray(lm.apply(params, tokens))[0] - _reference(section, params, tokens[0])).max() < F32_TOL
+    assert np.abs(apply_logits(lm, params, tokens)[0] - _reference(section, params, tokens[0])).max() < F32_TOL
 
 
 WRONG = ["rotary_on_q_r_and_k_r", "shared_features_dropped", "b_doubled", "decay_a_head", "no_scaling_factor", "no_shared_expert"]
@@ -227,7 +228,7 @@ def test_a_wrong_block_is_far_outside_the_tolerance(toy, wrong, monkeypatch):
     elif wrong == "no_shared_expert":
         params = jax.tree_util.tree_map(lambda a: a, params)
         del params["periods"]["moe"]["shared"]
-    assert np.abs(np.asarray(lm.apply(params, tokens)) - want).max() > 100 * F32_TOL
+    assert np.abs(apply_logits(lm, params, tokens) - want).max() > 100 * F32_TOL
 
 
 @pytest.mark.parametrize("tiled", [False, True], ids=["slab", "token_tiles"])
@@ -460,7 +461,7 @@ def test_a_leading_layer_of_any_kind_is_served(kind):
         cfg = solar_open2_config("tiny", num_layers=3, layer_types=["linear", "softmax", "linear"], leading_dense_layers=1, dtype="float32")
     assert cfg.leading_of(kind) == 1 and cfg.layers_of(kind) == 2
     lm = HybridMoETransformerLM(cfg)
-    params = lm.init(jax.random.PRNGKey(0), None)
+    params = seeded(lm)
     eng = _server(lm, params)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 512, n).astype(np.int32) for n in (20, 5)]
@@ -468,7 +469,7 @@ def test_a_leading_layer_of_any_kind_is_served(kind):
     tokens = np.zeros((2, 32), np.int32)
     for i, o in enumerate(outs):
         tokens[i, : o.size] = o
-    lg = np.asarray(lm.apply(params, tokens))
+    lg = apply_logits(lm, params, tokens)
     for i, (p, o) in enumerate(zip(prompts, outs)):
         at = lg[i, p.size - 1 : o.size - 1]
         assert (at.max(-1) - np.take_along_axis(at, o[p.size :, None], -1)[:, 0]).max() < F32_TOL, i

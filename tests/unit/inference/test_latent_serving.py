@@ -30,6 +30,7 @@ from deepspeed_tpu.inference import decode, hybrid_decode
 from deepspeed_tpu.inference.kv_pool import PagePool, key_lanes
 from deepspeed_tpu.inference.scheduler import PagedServer
 from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, glm4_moe_lite_config
+from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file, apply_logits, seeded  # noqa: F401 (the two fixtures are taken by their import)
 
 REFERENCE = load_module("reference", "glm4_moe_lite_decoder")
 PAGE, SLOTS, CHUNK, MAXLEN = 8, 4, 16, 96
@@ -39,7 +40,7 @@ F32_TOL = 5e-5
 def _model(dtype="float32", **kw):
     cfg = glm4_moe_lite_config("tiny", dtype=dtype, **kw)
     lm = HybridMoETransformerLM(cfg)
-    params = lm.init(jax.random.PRNGKey(0), None)
+    params = seeded(lm)
     # trained-like scores: init's 0.02 gives a nearly flat softmax, in which a wrong rotary or scale hides
     for tree in [params["periods"]["latent"]] + [p["mixer"] for p in params["leading"]]:
         tree["wq_b"] = tree["wq_b"] * 40.0
@@ -149,7 +150,7 @@ def test_apply_is_the_published_expanded_form(toy):
     cfg, lm, params, section = toy
     tokens = _sequences(7, lens=(50,))[0][None]
     assert cfg.period == ("latent",) and cfg.num_periods == 3 and cfg.num_moe_layers == 3
-    assert np.abs(np.asarray(lm.apply(params, tokens))[0] - _reference(section, params, tokens[0])).max() < F32_TOL
+    assert np.abs(apply_logits(lm, params, tokens)[0] - _reference(section, params, tokens[0])).max() < F32_TOL
 
 
 @pytest.mark.parametrize("wrong", ["no_rotary_on_q", "no_rotary_on_k", "norm_over_all_of_kv_a", "scale_of_the_nope_part", "no_scaling_factor", "no_shared_expert"])
@@ -183,7 +184,7 @@ def test_a_wrong_block_is_far_outside_the_tolerance(toy, wrong, monkeypatch):
     elif wrong == "no_shared_expert":
         params = jax.tree_util.tree_map(lambda a: a, params)
         del params["periods"]["moe"]["shared"]
-    assert np.abs(np.asarray(lm.apply(params, tokens)) - want).max() > 100 * F32_TOL
+    assert np.abs(apply_logits(lm, params, tokens) - want).max() > 100 * F32_TOL
 
 
 @pytest.mark.parametrize("tiled", [False, True], ids=["slab", "token_tiles"])
@@ -212,7 +213,7 @@ def test_the_absorbed_program_is_the_expanded_apply(toy):
     for s, seq in seqs.items():
         padded = np.zeros((1, 48), np.int32)  # one length for both rows (causal: what follows moves nothing)
         padded[0, : seq.size] = seq
-        assert np.abs(got[s] - np.asarray(lm.apply(params, padded))[0, : seq.size]).max() < F32_TOL, s
+        assert np.abs(got[s] - apply_logits(lm, params, padded)[0, : seq.size]).max() < F32_TOL, s
 
 
 def test_bf16_serving():

@@ -29,6 +29,7 @@ from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, mimo_v2_conf
 from deepspeed_tpu.models.moe_transformer import MoETransformerLM, olmoe_config
 from deepspeed_tpu.profiling.tracer import Tracer
 from deepspeed_tpu.utils import chaos
+from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file  # noqa: F401 (the two fixtures are taken by their import)
 
 CFG = dict(
     vocab_size=128, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=96,

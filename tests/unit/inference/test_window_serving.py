@@ -29,6 +29,7 @@ from deepspeed_tpu.inference import decode, hybrid_decode
 from deepspeed_tpu.inference.kv_pool import PagePool, key_lanes, window_ring_pages
 from deepspeed_tpu.inference.scheduler import PagedServer
 from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, mimo_v2_config
+from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file, apply_logits, seeded  # noqa: F401 (the two fixtures are taken by their import)
 
 REFERENCE = load_module("reference", "mimo_v2_decoder")
 PAGE, SLOTS, CHUNK, MAXLEN = 8, 4, 16, 96
@@ -39,7 +40,7 @@ F32_TOL = 5e-5
 def _model(dtype="float32", **kw):
     cfg = mimo_v2_config("tiny", dtype=dtype, **kw)
     lm = HybridMoETransformerLM(cfg)
-    params = lm.init(jax.random.PRNGKey(0), None)
+    params = seeded(lm)
     # trained-like sinks: of the size of a score, so that the column carries weight
     params["periods"]["window"]["sinks"] = jax.random.normal(jax.random.PRNGKey(1), params["periods"]["window"]["sinks"].shape)
     section = {"kwargs": {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}}
@@ -117,8 +118,17 @@ def toy():
     return _model()
 
 
+def _reference(section, params, seq):
+    """The reference's logits [len, V] of one sequence, computed at MAXLEN (padded behind: the model is causal and
+    routes token by token, so what follows a position does not move its logits): the reference compiles for ONE shape
+    a model, not one a sequence (ten shapes in this file before)."""
+    padded = np.zeros((1, MAXLEN), np.int32)
+    padded[0, : seq.size] = seq
+    return np.asarray(REFERENCE.logits(section, params, padded))[0, : seq.size]
+
+
 def _reference_logits(section, params, seqs):
-    return {s: np.asarray(REFERENCE.logits(section, params, seq[None]))[0] for s, seq in seqs.items()}
+    return {s: _reference(section, params, seq) for s, seq in seqs.items()}
 
 
 def test_a_leading_dense_layer_and_the_period_scan_are_the_unrolled_stack(toy):
@@ -128,11 +138,11 @@ def test_a_leading_dense_layer_and_the_period_scan_are_the_unrolled_stack(toy):
     cfg, lm, params, section = toy
     tokens = _sequences(7, lens=(50,))[0][None]
     assert cfg.period == ("window",) * 5 + ("softmax",) and cfg.num_periods == 1 and cfg.num_moe_layers == 6
-    assert np.abs(np.asarray(lm.apply(params, tokens)) - np.asarray(REFERENCE.logits(section, params, tokens))).max() < F32_TOL
+    assert np.abs(apply_logits(lm, params, tokens) - np.asarray(REFERENCE.logits(section, params, tokens))).max() < F32_TOL
     # and two periods behind the leading layer
     cfg2, lm2, params2, section2 = _model(num_layers=13, layer_types=["softmax"] + (["window"] * 5 + ["softmax"]) * 2)
     assert cfg2.num_periods == 2
-    assert np.abs(np.asarray(lm2.apply(params2, tokens)) - np.asarray(REFERENCE.logits(section2, params2, tokens))).max() < F32_TOL
+    assert np.abs(apply_logits(lm2, params2, tokens) - np.asarray(REFERENCE.logits(section2, params2, tokens))).max() < F32_TOL
 
 
 @pytest.mark.parametrize("tiled", [False, True], ids=["slab", "token_tiles"])
@@ -230,7 +240,7 @@ def test_the_engine_serves_it_with_two_programs_and_preemption_changes_nothing(t
     assert before is None or next(b for b in before["entries"] if b["name"] == "window_kv")["per_chip_bytes"] == ring["per_chip_bytes"]
     # every served token is the reference's arg-max at its position (float32, no near-tie at this size)
     for i, (p, o) in enumerate(zip(prompts, outs)):
-        lg = np.asarray(REFERENCE.logits(section, params, o[None]))[0]
+        lg = _reference(section, params, o)
         gap = lg[p.size - 1 : o.size - 1].max(-1) - np.take_along_axis(lg[p.size - 1 : o.size - 1], o[p.size :, None], -1)[:, 0]
         assert gap.max() < F32_TOL, i
     tight = _server(lm, params, num_pages=14)

@@ -989,3 +989,82 @@ def test_kimi_linear_step_fits_with_state_and_latent_pages_in_place(v5e, monkeyp
         assert not _mixer_matrices_written_out(text, wq)
         assert not _slab_sized_fills(text, rows * width)
         assert memory.temp_size_in_bytes < 0.95e9  # 0.902 GB (1.388 before): the chunk rows' kda_chunked and the 64 x 128-slot buffers, unfilled
+
+
+@pytest.mark.parametrize("donated", [True, False], ids=["pools_donated", "pools_returned_undonated"])
+def test_ssd_decode_alone_compiles_whoever_owns_its_pools(v5e, donated):
+    """``ssd_decode`` at granite-4.0-h-micro's shapes (64 rows, 64 heads of 64
+    over a state of 128, 4,352 convolved channels, 36 layers' pools of 65
+    slots) as a program's only operation, its two pools donated (the serving
+    step's way) and not (the logits tool's way): one kernel call, a whole
+    row's 2 MB of state a grid step."""
+    from deepspeed_tpu.ops.transformer.state_space import ssd_decode
+
+    R, NH, P, N, K, L = 64, 64, 64, 128, 4, 36
+    C = NH * P + 2 * N
+    on = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    program = jax.jit(functools.partial(ssd_decode, impl="pallas"), donate_argnums=(6, 7) if donated else ())
+    compiled = program.lower(
+        on((R, C)), on((R, NH), jnp.float32), on((K, C)), on((C,)), on((NH,), jnp.float32), on((NH,), jnp.float32),
+        on((L, R + 1, NH, P, N), jnp.float32), on((L, R + 1, K - 1, 48, 128)), on((), I32), on((R,), I32), on((R,), bool), on((R,), bool),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ssd_decode[\w.-]* = .*? custom-call\(", text)) == 1
+    if donated:
+        assert {6, 7} <= parse_input_output_aliases(text)
+
+
+_GRANITE_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/granite-4.0-h-micro.json"
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_granite_hybrid_step_fits_whole_with_its_state_in_place(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the granite-4.0-h-micro cell's shapes, both
+    programs (ALL 40 layers: four periods of nine Mamba-2 layers around one
+    attention layer, 36 states of 64 x 64 x 128 float32 a row on a store of 65
+    slots, heads of 64 on 1,537 pages of 64, a dense FFN of 8,192 out of the
+    period's stacks, the tied table of 100,352 rows whole, 64 rows): it
+    compiles for a v5e; K, V, the state and the convolution tails stay
+    aliased in to out; ``ssd_decode`` runs nine times in the scan's body, the
+    ragged kernel once (twice in the wide program); no routing rows ride on
+    the step's result; weights + pools + temporaries fit a chip."""
+    from deepspeed_tpu.inference import hybrid_decode
+    from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes
+    from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
+
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.state_space"):
+        __import__(module)
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = json.loads(_GRANITE_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = HybridMoEConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    assert (cfg.num_periods, cfg.period.count("ssm"), cfg.period.count("softmax"), cfg.num_experts, cfg.state_kind) == (4, 9, 1, 0, "ssm")
+
+    def on_v5e(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape), params)
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) == 3_191_396_096  # 6.38 GB in bfloat16
+    kv = on_v5e((4, rows * maxp + 1, cfg.num_kv_heads, page, key_lanes(cfg.head_dim)))
+    shapes = hybrid_decode.state_shapes(cfg, rows)
+    assert shapes.state == (36, 65, 64, 64, 128) and shapes.conv == (36, 65, 3, 48, 128)
+    store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv), None, None, None)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), kv, kv, store,
+        on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32), on_v5e((rows,), I32),
+    ).compile()
+    text = compiled.as_text()
+    first_pool = len(jax.tree_util.tree_leaves(params)) + 1
+    assert {first_pool, first_pool + 1, first_pool + 2, first_pool + 3} <= parse_input_output_aliases(text)  # k, v, state, conv
+    memory = compiled.memory_analysis()
+    print(f"granite w{width}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = .*? custom-call\(", text, flags=re.M)
+    assert sum(name.startswith("ssd_decode") for name in kernels) == 9, kernels  # a period's nine, in the scan's body
+    assert sum(name.startswith("ragged_paged_attention") for name in kernels) == (1 if width == 1 else 2), kernels
+    assert not re.search(r"= f32\[36,65,64,64,128\]\S* copy\(", text)  # the state store is never copied
+    assert re.search(rf"s32\[{rows},{width + 1}\]", text) and not re.search(rf"s32\[{rows + decode.MOE_STAT_ROWS},{width + 1}\]", text)
