@@ -341,10 +341,13 @@ def generate_reference(engine, prompts, budgets):
 def attention_parity(sz, seed: int) -> dict:
     """``ragged_paged_attention`` Pallas vs XLA on seeded inputs at the
     server's two window shapes: the outputs of live rows and slots, and the
-    bytes both leave in every page but the trash page (layer 1 of two). Once
-    with the server's heads and once with the same widths cut into heads of
-    128: a head of whole lanes takes the kernel that walks a row's live
-    pages, a narrower one the grid over the page table."""
+    bytes both leave in every page but the trash page (layer 1 of two). With
+    the server's heads on the server's pool (heads narrower than a lane tile
+    share one: ``kv_pool.heads_per_group``), with the same heads one to a page,
+    and with the same widths cut into heads of 128: pages of whole lanes take
+    the kernel that walks a row's live pages, narrower ones the grid over the
+    page table."""
+    from deepspeed_tpu.inference.kv_pool import heads_per_group, page_shapes
     from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
 
     cfg, paged = sz.serve_model, sz.paged
@@ -355,11 +358,13 @@ def attention_parity(sz, seed: int) -> dict:
     table = jnp.asarray(1 + rs.permutation(rows * maxp).reshape(rows, maxp), jnp.int32)
     worst = {}
     whole_lanes = max(1, 128 // cfg.head_dim)  # heads that make one head of 128
-    layouts = {(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)}
+    # (query heads, kv heads, head width, kv heads a page)
+    layouts = {(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, f)
+               for f in (1, heads_per_group(cfg.head_dim, cfg.head_dim, cfg.num_kv_heads))}
     if cfg.num_kv_heads % whole_lanes == 0:
-        layouts.add((cfg.num_heads // whole_lanes, cfg.num_kv_heads // whole_lanes, cfg.head_dim * whole_lanes))
-    for (heads, kv_heads, head_dim), width in itertools.product(sorted(layouts), (1, paged["prefill_chunk"])):
-        shape = (2, n_pages, kv_heads, page, head_dim)
+        layouts.add((cfg.num_heads // whole_lanes, cfg.num_kv_heads // whole_lanes, cfg.head_dim * whole_lanes, 1))
+    for (heads, kv_heads, head_dim, f), width in itertools.product(sorted(layouts), (1, paged["prefill_chunk"])):
+        shape, _ = page_shapes(2, n_pages, kv_heads, page, head_dim, head_dim, f)
         k_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
         v_pages = jnp.asarray(rs.randn(*shape), jnp.bfloat16)
         q = jnp.asarray(rs.randn(rows, width, heads, head_dim), jnp.bfloat16)
@@ -386,7 +391,7 @@ def attention_parity(sz, seed: int) -> dict:
         a, b = (got[impl][0].astype(np.float32)[live] for impl in ("pallas", "xla"))
         assert np.isfinite(a).all() and np.isfinite(b).all()
         np.testing.assert_allclose(a, b, atol=BF16_ATTN_TOL, rtol=BF16_ATTN_TOL)
-        worst[f"d{head_dim}_w{width}"] = float(np.abs(a - b).max())
+        worst[f"d{head_dim}x{f}_w{width}"] = float(np.abs(a - b).max())
     return worst
 
 

@@ -22,7 +22,12 @@ parameter of the program) and one more row array:
 
 * ``k_pages`` ``[softmax layers, NP, NKV, P, Dk]`` and ``v_pages`` ``[..., Dv]``:
   only the softmax layers have pages under the page table; a key head wider
-  than a lane tile is stored at whole tiles (``kv_pool.key_lanes``: 192 at 256);
+  than a lane tile is stored at whole tiles (``kv_pool.key_lanes``: 192 at 256),
+  heads narrower than one ``f`` to a page, ``[.., NKV / f, P, f Dk]``
+  (``kv_pool.heads_per_group``: granite's 8 of 64 as 4 groups of 128; the
+  window layers' rings likewise). The mixers below hand ``ragged_paged_attention``
+  q, k and v at the TRUE head count and width whatever the pool's, and the
+  entry sees the grouping from the shapes;
 * ``store.state`` ``[linear layers, slots + 1, NH, Dk, Dv]`` float32 and
   ``store.conv`` ``[linear layers, slots + 1, K - 1, 3, NH, D]`` (a head's
   channels on the lanes, as ``kda_decode`` reads them): row r's are at
@@ -111,7 +116,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.inference import decode
-from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes
+from deepspeed_tpu.inference.kv_pool import StateStore, heads_per_group, page_shapes
 from deepspeed_tpu.models import hybrid_moe as hm
 from deepspeed_tpu.models.transformer import _norm
 from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked, kda_decode
@@ -144,8 +149,9 @@ def state_shapes(cfg, max_slots: int) -> StateShapes:
 
 def window_shapes(cfg, max_slots: int, page_size: int, ring: int):
     """The window layers' key and value pools: ``ring`` pages a slot behind the trash page."""
-    k = (cfg.layers_of("window"), 1 + max_slots * ring, cfg.window_num_kv_heads, page_size, key_lanes(cfg.head_dim))
-    return k, k[:-1] + (cfg.v_head_dim,)
+    NKV = cfg.window_num_kv_heads
+    f = heads_per_group(cfg.head_dim, cfg.v_head_dim, NKV)
+    return page_shapes(cfg.layers_of("window"), 1 + max_slots * ring, NKV, page_size, cfg.head_dim, cfg.v_head_dim, f)
 
 
 def layer_of(tree, per, j: int):

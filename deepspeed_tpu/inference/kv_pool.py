@@ -3,8 +3,10 @@
 The dense decode workspace (``inference/decode.py:init_cache``) allocates
 ``[L, B, max_len, NKV, D]`` per batch — HBM scales with ``batch × max_len``
 whether or not those tokens exist. Here the cache is a shared pool of
-fixed-size pages ``[L, num_pages, NKV, page_size, D]`` plus a per-sequence
-page table: HBM holds ``live_tokens × bytes_per_token`` rounded up to page
+fixed-size pages ``[L, num_pages, NKV, page_size, D]`` (heads narrower than a
+lane tile ``f`` to a group, ``[L, num_pages, NKV / f, page_size, f * D]``:
+``heads_per_group``) plus a per-sequence page table: HBM holds
+``live_tokens × bytes_per_token`` rounded up to page
 granularity, and any free page can serve any sequence (the vLLM block-table
 layout; the reference approximates it with contiguous per-sequence
 workspaces — ``allocate_workspace`` in
@@ -114,11 +116,16 @@ class PagedKVCache(NamedTuple):
 
     Layout ``[L, num_pages, NKV, page_size, D]``: the layer axis scans, and
     each layer slice is exactly the ``[NP, NKV, P, D]`` pool the paged
-    attention kernels take.
+    attention kernels take. Where ``heads_per_group`` is ``f > 1`` the layout is
+    ``[L, num_pages, NKV / f, page_size, f * D]``: KV head ``j`` lives in group
+    ``j // f`` at lanes ``(j % f) * D ..``, which the attention entry sees from
+    the shapes (``ops/transformer/paged_attention.py``); a page's bytes, the
+    axis a mesh shards and everything that moves whole pages are what they were.
     """
 
     k_pages: jax.Array
     v_pages: jax.Array
+    heads_per_group: int = 1
 
     @property
     def num_pages(self) -> int:
@@ -151,8 +158,11 @@ def init_paged_cache(
     # a model with layers of more than one kind keeps the K and V of its softmax layers
     # only here (none, zero-sized arrays, where every paged layer is a latent one)
     layers = cfg.layers_of("softmax") if getattr(cfg, "layer_types", None) else cfg.num_layers
-    k_shape = (layers, num_pages, cfg.num_kv_heads, page_size, key_lanes(cfg.head_dim))
-    v_shape = k_shape[:-1] + (getattr(cfg, "v_head_dim", None) or cfg.head_dim,)
+    v_head_dim = getattr(cfg, "v_head_dim", None) or cfg.head_dim
+    # the heads a shard holds decide: a group never spans two chips
+    tp = 1 if sharding is None else sharding.mesh.shape[sharding.spec[2]]
+    f = heads_per_group(cfg.head_dim, v_head_dim, cfg.num_kv_heads // tp)
+    k_shape, v_shape = page_shapes(layers, num_pages, cfg.num_kv_heads, page_size, cfg.head_dim, v_head_dim, f)
     if sharding is not None:
         # allocate DIRECTLY sharded: a full-size zeros + device_put would
         # transiently commit the whole pool to one chip — tp× the
@@ -165,18 +175,41 @@ def init_paged_cache(
         k, v = zeros()
     else:
         k, v = jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype)
-    return PagedKVCache(k_pages=k, v_pages=v)
+    return PagedKVCache(k_pages=k, v_pages=v, heads_per_group=f)
 
 
 def key_lanes(head_dim: int) -> int:
     """A key head's width in a page: the head's own up to one lane tile, whole
     lane tiles beyond (192 is stored at 256). A page that is no whole number
     of 128-lane tiles is one the ragged kernel cannot fetch by DMA
-    (``ops/transformer/decode_attention.py``); the device's tiled layout pads
-    such a page to whole tiles in HBM anyway, so nothing is lost against the
-    naive pool. The pad lanes hold zeros and q's are zeros, so every product
-    is what it was."""
+    (``ops/transformer/decode_attention.py``). The pad lanes hold zeros and
+    q's are zeros, so every product is what it was. A head NARROWER than a
+    lane tile is not padded (that would double the pool): it shares the tile
+    with its neighbours (``heads_per_group``)."""
     return head_dim if head_dim <= 128 else -(-head_dim // 128) * 128
+
+
+def heads_per_group(head_dim: int, v_head_dim: int, kv_heads: int) -> int:
+    """KV heads that lie side by side on the lanes of one page: ``f = 128 //
+    head_dim`` where keys and values are equally wide, narrower than a lane
+    tile and divide it, and the ``kv_heads`` a shard holds are whole groups of
+    ``f``; 1 otherwise (today's pool, and the grid kernel of
+    ``decode_attention._ragged_by_grid``). A page ``[P, 64]`` is half a lane
+    tile: the device does NOT pad it, it gives such a pool the layout with the
+    page index on the lanes, and a program that hands the pool to a kernel
+    transposes it whole at entry and back at exit (both of granite's, 1.61 GB
+    a step: ``PERF.md`` section 6, PR 53). Two heads of 64 to a page make it
+    ``[P, 128]``, whole tiles, row-major by itself, at the same bytes."""
+    if head_dim != v_head_dim or head_dim >= 128 or 128 % head_dim:
+        return 1
+    f = 128 // head_dim
+    return f if kv_heads % f == 0 else 1
+
+
+def page_shapes(layers: int, num_pages: int, kv_heads: int, page_size: int, head_dim: int, v_head_dim: int, f: int):
+    """The key and the value pool of ``kv_heads`` heads, ``f`` to a group."""
+    k_shape = (layers, num_pages, kv_heads // f, page_size, f * key_lanes(head_dim))
+    return k_shape, k_shape[:-1] + (f * v_head_dim,)
 
 
 def window_ring_pages(window: int, page_size: int, prefill_chunk: int) -> int:
@@ -230,7 +263,7 @@ class StateStore(NamedTuple):
 
     state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32 (ssm: [.., NH, P, N])
     conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3, NH, D] (ssm: [.., K - 1, tail_rows(C), 128])
-    window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk]
+    window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk] (narrow heads: NKV / f, P, f Dk)
     window_v: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None  # [latent layers, num_pages, P, lanes]
 
@@ -309,6 +342,7 @@ class PagePool:
         self.states: Optional[StateStore] = None
         self.window_ring = 0
         self.window_keys = 0  # keys a window layer's query sees
+        self.window_kv_heads = 0  # a window layer's KV heads (its ring's axis 2 holds them in groups)
         self.query_heads: dict = {}  # a layer kind's query heads, for the memory report
         self.state_kind: Optional[str] = None  # the kind whose layers' states the store holds: linear | ssm
         if getattr(cfg, "layer_types", None):
@@ -324,6 +358,7 @@ class PagePool:
                     raise ValueError("a model with sliding-window layers needs prefill_chunk to size its page rings")
                 self.window_ring = window_ring_pages(cfg.window, self.page_size, int(prefill_chunk))
                 self.window_keys = cfg.window
+                self.window_kv_heads = cfg.kv_heads_of("window")
                 rings = tuple(jnp.zeros(shape, kv_dtype) for shape in window_shapes(cfg, self.max_slots, self.page_size, self.window_ring))
             latent = None
             if cfg.layers_of("latent"):
@@ -445,7 +480,7 @@ class PagePool:
                     window_keys=self.window_keys,
                     window_layers=self.states.window_k.shape[0],
                     window_query_heads=self.query_heads["window"],
-                    window_kv_heads=self.states.window_k.shape[2],
+                    window_kv_heads=self.window_kv_heads,
                     window_slots=self.max_slots,
                     window_slots_in_use=in_use,
                 )
@@ -483,7 +518,7 @@ class PagePool:
     def set_cache(self, new_k: jax.Array, new_v: jax.Array) -> None:
         """Install the page arrays a serving program returned (the donated
         buffers aliased in place). The one sanctioned external write."""
-        self.cache = PagedKVCache(k_pages=new_k, v_pages=new_v)
+        self.cache = self.cache._replace(k_pages=new_k, v_pages=new_v)
 
     def set_states(self, states: StateStore) -> None:
         """Same, for the state store's buffers."""
@@ -697,7 +732,7 @@ class PagePool:
                 self.cache.k_pages, self.cache.v_pages,
                 jnp.int32(src), jnp.int32(dst),
             )
-            self.cache = PagedKVCache(k_pages=new_k, v_pages=new_v)
+            self.cache = self.cache._replace(k_pages=new_k, v_pages=new_v)
             self.page_table[slot, i] = dst
             self._refcount[dst] = 1
             self._refcount[src] -= 1
@@ -893,7 +928,7 @@ class PagePool:
         if moves == 0:
             return 0
         gather = jnp.asarray(perm)
-        self.cache = PagedKVCache(
+        self.cache = self.cache._replace(
             k_pages=self.cache.k_pages[:, gather],
             v_pages=self.cache.v_pages[:, gather],
         )

@@ -208,7 +208,12 @@ def _ragged_grid_kernel(pt_ref, len_ref, qlen_ref, x_ref, k_ref, v_ref, o_ref, k
 def _ragged_by_grid(x, pages, lens, qlens, pools, *, scale, Hg, W, out_dtype, interpret):
     """``_ragged_grid_kernel`` over ``R x NKV x MAXP`` steps; ``pages`` has the
     trash page once more in a last column, for the out-blocks of grid steps
-    that write nothing."""
+    that write nothing. The path of heads that are no whole lanes and do not
+    pack to whole lanes either (``kv_pool.heads_per_group`` is 1: an odd head
+    count a shard, a width such as 24 that does not divide 128): a grid step
+    for every table slot of every KV head of every row, read or not, and the
+    pool copied to row-major around the call. Everything else, granite's and
+    GPT-2's heads of 64 included, takes ``_ragged_by_live_pages``."""
     R, NKV, _, D = x.shape
     P = pools[0].shape[2]
     maxp = pages.shape[1] - 1
@@ -890,11 +895,19 @@ def ragged_paged_attention(
     (a bfloat16 product is exact there); the softmax statistics and the
     accumulator are float32, and so is ``p`` in ``p · v``.
 
-    A head that is no whole lanes (``D % 128 != 0``) keeps the kernel this
-    one replaced, a grid of ``R x NKV x MAXP`` steps with out-blocks on the
-    pools (``_ragged_by_grid``): such a pool's last dimension is padded to 128
-    lanes, and Mosaic refuses a DMA of a page of it, whole or in part ("Slice
-    shape along dimension 3 must be aligned to tiling (128), but is 64").
+    A head narrower than a lane tile reaches this function at whole lanes:
+    the serving pool holds ``128 // D`` such KV heads side by side on a page's
+    lanes and the entry (``paged_attention.ragged_paged_attention``) widens
+    q, k and v to match, so what arrives here is an attention at heads of
+    128. What cannot pack (an odd head count a shard, a width that does not
+    divide 128, a caller's pool of its own shape) comes with ``D % 128 != 0``
+    and keeps the kernel this one replaced, a grid of ``R x NKV x MAXP`` steps
+    with out-blocks on the pools (``_ragged_by_grid``): Mosaic refuses a DMA of
+    a page of such a pool, whole or in part ("Slice shape along dimension 3
+    must be aligned to tiling (128), but is 64"), and outside the kernel the
+    device keeps a pool of half-tile pages with the page index on its lanes,
+    so a program that calls this on one transposes the pool whole before the
+    call and back after it. No serving cell of the benchmark comes this way.
 
     A value head may have another width than a key head (``v_new`` and
     ``v_pages`` end in ``Dv``, the result too), and the key pool may be wider

@@ -3,7 +3,12 @@
 The serving layer (``inference/kv_pool.py`` + ``inference/scheduler.py``)
 stores every sequence's KV cache as fixed-size pages in one shared pool
 ``[L, num_pages, NKV, page_size, D]`` for all layers, addressed through
-per-sequence page tables. This module is the single attention entry point
+per-sequence page tables; heads narrower than a lane tile lie ``f = 128 // D``
+to a page, ``[L, num_pages, NKV / f, page_size, f D]`` (``kv_pool.heads_per_group``:
+KV head ``j`` in group ``j // f`` at lanes ``(j % f) D ..``), so that a page is
+whole lane tiles: ``ragged_paged_attention`` sees it from the shapes and
+hands its implementations an ordinary attention at heads of ``f D``
+(``_share_lanes``). This module is the single attention entry point
 for that layout. Every entry takes the whole stack and a ``layer`` index and
 reaches the layer's pages through it (``pool[layer, ids]``, the kernels'
 index maps): the serving step carries the stack through its layer loop, and
@@ -99,11 +104,36 @@ def scatter_pages(pages, layer, vals, page_table, positions, valid):
     return pages.at[layer, pid, :, off, :].set(vals.astype(pages.dtype))
 
 
+def _share_lanes(q, k_new, v_new, f: int):
+    """A window's operands for a pool that holds ``f`` KV heads a group:
+    ``k_new`` and ``v_new`` ``[R, W, NKV, D]`` as ``[R, W, NKV / f, f D]`` (a
+    reshape: a group's heads are neighbours), and ``q`` ``[R, W, NH, D]`` as
+    ``[R, W, NH, f D]``, a head's own lanes where its KV head's lie in the
+    group and zeros elsewhere. Query heads keep their order and a group's
+    ``f Hg`` are contiguous, so this is GQA at ``NKV / f`` heads of ``f D``. A
+    zero lane adds an exact zero to a float32 sum and a softmax row is one
+    query head's, so scores and weights are the head's own; the lanes of
+    ``p v`` that are its neighbours' values are dropped (``_own_lanes``)."""
+    R, W, NH, D = q.shape
+    NKV = k_new.shape[2]
+    own = jnp.eye(f, dtype=bool).reshape(f, 1, f, 1)  # [head in group, ., lanes of head, .]
+    q = jnp.where(own, q.reshape(R, W, NKV // f, f, NH // NKV, 1, D), 0).reshape(R, W, NH, f * D)
+    return q, k_new.reshape(R, W, NKV // f, -1), v_new.reshape(R, W, NKV // f, -1)
+
+
+def _own_lanes(out, f: int, groups: int):
+    """``out`` ``[R, W, NH, f D]`` of a shared-lane attention -> ``[R, W, NH, D]``:
+    each head's own lanes."""
+    R, W, NH, lanes = out.shape
+    out = out.reshape(R, W, groups, f, NH // (groups * f), f, lanes // f)
+    return jnp.stack([out[:, :, :, j, :, j] for j in range(f)], axis=3).reshape(R, W, NH, lanes // f)
+
+
 def ragged_paged_attention(
     q: jnp.ndarray,  # [R, W, NH, D] — per-row padded token windows
     k_new: jnp.ndarray,  # [R, W, NKV, D] — the windows' keys, not yet in the pool
     v_new: jnp.ndarray,
-    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D]
+    k_pages: jnp.ndarray,  # [L, NP, NKV, P, D], or [L, NP, NKV / f, P, f D]
     v_pages: jnp.ndarray,
     layer,  # int32 scalar
     page_table: jnp.ndarray,  # [R, MAXP] int32
@@ -128,22 +158,38 @@ def ragged_paged_attention(
     sees the newest ``window`` keys only, itself included), ``sinks`` ([NH]:
     one more softmax column a head, with no value), a value head narrower
     than a key head and a key pool wider than ``q`` (zero lanes) are the
-    kernel's (``decode_attention.ragged_paged_attention``). Returns
+    kernel's (``decode_attention.ragged_paged_attention``). A pool with
+    ``NKV / f`` heads of ``f D`` where ``k_new`` has ``NKV`` of ``D`` holds ``f``
+    heads a group (``kv_pool.heads_per_group``): both implementations then
+    see the group as one KV head of ``f D`` lanes, each query head with zeros
+    on its neighbours' lanes (``_share_lanes``), and ``scale`` is the true
+    head's. Returns
     ``(out [R, W, NH, Dv], k_pages, v_pages)``: rows with ``kv_lens == 0``
     are exact zeros; window slots past ``q_lens`` are garbage the caller
     ignores."""
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
+    f = k_new.shape[2] // k_pages.shape[2]
+    if f > 1:  # heads that share a page's lanes
+        if not (k_pages.shape[-1] == v_pages.shape[-1] == f * q.shape[-1] and k_new.shape[2] == f * k_pages.shape[2]):
+            raise ValueError(
+                f"{k_new.shape[2]} kv heads of {q.shape[-1]} on pools {k_pages.shape[2:]} / {v_pages.shape[2:]}: "
+                "neither a head a page nor whole groups of heads that share its lanes (kv_pool.heads_per_group)"
+            )
+        scale = _scale_or_default(scale, q.shape[-1])
+        q, k_new, v_new = _share_lanes(q, k_new, v_new, f)
     extras = {} if window is None and sinks is None else dict(window=window, sinks=sinks)
-    if impl == "pallas":
-        return _pallas_ragged_paged(
-            q, k_new, v_new, k_pages, v_pages, layer, page_table, kv_lens, q_lens, scale=scale, **extras
-        )
-    if impl != "xla":
+    if impl not in ("pallas", "xla"):
         raise ValueError(f"unknown ragged attention impl {impl!r}; expected auto|pallas|xla")
-    R, W = q.shape[:2]
-    if scale is None:
-        scale = _scale_or_default(None, q.shape[-1])
+    attend = _pallas_ragged_paged if impl == "pallas" else _xla_ragged_paged
+    out, k_pages, v_pages = attend(q, k_new, v_new, k_pages, v_pages, layer, page_table, kv_lens, q_lens, scale=scale, **extras)
+    return (_own_lanes(out, f, k_pages.shape[2]) if f > 1 else out), k_pages, v_pages
+
+
+def _xla_ragged_paged(q, k_new, v_new, k_pages, v_pages, layer, page_table, kv_lens, q_lens, scale=None, **extras):
+    """``ragged_paged_attention`` as XLA's scatter and gather."""
+    W = q.shape[1]
+    scale = _scale_or_default(scale, q.shape[-1])  # of the head's own width, before any zero lanes
     if k_pages.shape[-1] > q.shape[-1]:  # a key head stored wider than it is: zero lanes
         lanes = ((0, 0),) * 3 + ((0, k_pages.shape[-1] - q.shape[-1]),)
         q, k_new = jnp.pad(q, lanes), jnp.pad(k_new, lanes)

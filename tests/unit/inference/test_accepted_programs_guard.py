@@ -38,7 +38,7 @@ from jax.sharding import SingleDeviceSharding
 from benchmark import files
 from benchmark.kernels import ragged_paged_attention, windowed_paged_attention
 from deepspeed_tpu.inference import decode, hybrid_decode
-from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
+from deepspeed_tpu.inference.kv_pool import StateStore, heads_per_group, key_lanes, page_shapes, window_ring_pages
 
 SPEC = json.loads((pathlib.Path(files.ROOT) / "BENCHMARK.json").read_text())
 CONFIGS = [c["name"] for c in SPEC["configs"]]
@@ -93,8 +93,13 @@ def _lowered_step(v5e, monkeypatch, name, width):
             rings = tuple(on(s) for s in hybrid_decode.window_shapes(cfg, rows, page, ring))
         latent = on((cfg.layers_of("latent"), pages, page, key_lanes(cfg.latent_width))) if cfg.layers_of("latent") else None
         extra = (StateStore(on(shapes.state, jnp.float32), on(shapes.conv), *rings, latent),)
-    k_pool = on((layers, pages, cfg.num_kv_heads, page, key_lanes(cfg.head_dim)))
-    v_pool = on(k_pool.shape[:-1] + (getattr(cfg, "v_head_dim", None) or cfg.head_dim,))
+    # the pool's own shapes: heads narrower than a lane tile share one (granite's 8 of 64, gpt2-125m's 12: two a page)
+    v_head_dim = getattr(cfg, "v_head_dim", None) or cfg.head_dim
+    f = heads_per_group(cfg.head_dim, v_head_dim, cfg.num_kv_heads)
+    assert f == {"granite-4.0-h-micro": 2, "gpt2-125m": 2}.get(name, 1)
+    if f > 1:  # such a pool goes to the kernel that walks live pages: reaching the grid fallback would raise
+        monkeypatch.setattr(sys.modules["deepspeed_tpu.ops.transformer.decode_attention"], "_ragged_by_grid", None)
+    k_pool, v_pool = (on(s) for s in page_shapes(layers, pages, cfg.num_kv_heads, page, cfg.head_dim, v_head_dim, f))
     step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
     rows_i32 = (on((rows,), I32),) * (3 if extra else 2)
     text = step.lower(params, on((rows, width), I32), k_pool, v_pool, *extra, on((rows, maxp), I32), *rows_i32).as_text()

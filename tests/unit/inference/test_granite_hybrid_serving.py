@@ -227,6 +227,22 @@ def test_served_logits_match_the_reference(toy, tiled, monkeypatch):
         assert np.abs(got[s] - want).max() < F32_TOL, s
 
 
+def test_two_heads_of_64_a_page_serve_the_reference():
+    """The published heads of 64 on the toy: the pool holds its two KV heads
+    side by side on a page's 128 lanes (``kv_pool.heads_per_group``), the step
+    hands the attention entry q, k and v at the true heads, and every
+    position's logits, chunk rows and decode rows, are the reference's."""
+    cfg, _, params, section = toy_model(head_dim=64, attn_softmax_scale=0.015625)
+    seqs = sequences(2, lens=(45, 7, 30))
+    driver = Driver(cfg, params)
+    pages = SLOTS * (MAXLEN // PAGE) + 1
+    assert driver.pools[0].shape == driver.pools[1].shape == (2, pages, 1, PAGE, 128)
+    got = driver.run(seqs, decode_from={0: 36, 1: 3, 2: 30})
+    assert driver.pools[0].shape == (2, pages, 1, PAGE, 128) and float(jnp.abs(driver.pools[0][:, 1:]).max()) > 0
+    for s, seq in seqs.items():
+        assert np.abs(got[s] - reference_logits(section, params, seq)).max() < F32_TOL, s
+
+
 def test_the_store_holds_the_references_final_states(toy):
     """After a row's tokens the state store's entries are the reference's
     final states in LAYER order (period by period), at the row's slot, and

@@ -19,7 +19,7 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.inference import decode, hybrid_decode
-from deepspeed_tpu.inference.kv_pool import PagePool
+from deepspeed_tpu.inference.kv_pool import PagePool, heads_per_group, page_shapes
 from deepspeed_tpu.inference.scheduler import PagedServer
 from deepspeed_tpu.models import hybrid_moe as hm
 from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, granite_hybrid_config, solar_open2_config
@@ -68,6 +68,26 @@ def test_the_published_store_is_the_issues():
     shapes = hybrid_decode.state_shapes(cfg, 64)
     assert shapes.state == (36, 65, 64, 64, 128) and shapes.conv == (36, 65, 3, 48, 128)
     assert int(np.prod(shapes.state[2:])) * 4 == 2_097_152  # a row's state in one layer
+    # the four attention layers' pages: 8 KV heads of 64, two a 128-lane page, the bytes of one a page
+    f = heads_per_group(cfg.head_dim, cfg.v_head_dim, cfg.num_kv_heads)
+    assert f == 2 and page_shapes(4, 1537, cfg.num_kv_heads, 64, cfg.head_dim, cfg.v_head_dim, f) == ((4, 1537, 4, 64, 128),) * 2
+    assert heads_per_group(64, 64, 3) == heads_per_group(24, 24, 4) == heads_per_group(64, 128, 8) == heads_per_group(128, 128, 8) == 1
+    assert (heads_per_group(32, 32, 4), heads_per_group(32, 32, 2), heads_per_group(16, 16, 8)) == (4, 1, 8)
+
+
+def test_the_pool_holds_two_heads_of_64_a_page():
+    """A pool of the published head width: ``heads_per_group`` 2, K and V
+    ``[layers, pages, NKV / 2, P, 128]``, a token's bytes what they were, and
+    whatever installs new arrays (``set_cache``) keeps the grouping."""
+    cfg = granite_hybrid_config("tiny", dtype="float32", head_dim=64)
+    pool = PagePool(cfg, 9, PAGE, SLOTS, max_seq_len=MAXLEN, dtype=jnp.float32)
+    assert pool.cache.heads_per_group == 2
+    assert pool.cache.k_pages.shape == pool.cache.v_pages.shape == (2, 9, 1, PAGE, 128)
+    assert pool.cache.bytes_per_token == 2 * 2 * cfg.num_kv_heads * 64 * 4
+    pool.set_cache(pool.cache.k_pages + 1, pool.cache.v_pages)
+    assert pool.cache.heads_per_group == 2 and pool.cache.page_size == PAGE
+    narrow = PagePool(granite_hybrid_config("tiny", dtype="float32"), 9, PAGE, SLOTS, max_seq_len=MAXLEN, dtype=jnp.float32)
+    assert narrow.cache.heads_per_group == 1 and narrow.cache.k_pages.shape == (2, 9, 2, PAGE, 16)  # 2 heads of 16: no whole lane tile
 
 
 @pytest.mark.parametrize("what", ["both_kinds", "two_groups", "ragged_channels", "no_sizes", "experts_none_but_a_leading_layer", "experts_none_but_a_shared_one"])

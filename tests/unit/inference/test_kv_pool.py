@@ -338,6 +338,61 @@ def test_cow_on_divergence_preserves_shared_reader():
     assert [p for p, _ in pool.match_prefix(prompt)] == orig
 
 
+def test_cow_on_a_pool_of_shared_lanes_copies_every_head_of_the_page():
+    """The tiny model's 8 KV heads of 32 lie four to a 128-lane page
+    (``heads_per_group``). A page is still one index of axis 1, so the
+    copy-on-write duplicate carries all of them: read back THROUGH the
+    attention entry at the true heads, the writer's row attends exactly what
+    the reader's does, and the grouping survives every reinstall of the
+    arrays (the copy, ``set_cache``, ``defrag``)."""
+    from deepspeed_tpu.ops.transformer.paged_attention import ragged_paged_attention
+
+    pool = _pool(num_pages=12, page_size=4, max_slots=3, max_seq_len=32)
+    cfg = llama_config("tiny", num_layers=2, max_seq_len=32)
+    assert pool.cache.heads_per_group == 4 and pool.cache.k_pages.shape == (2, 12, 2, 4, 128)
+    prompt = np.arange(9, dtype=np.int32)
+    s1 = pool.alloc_slot(10, prefix_tokens=prompt)
+    rs = np.random.RandomState(0)
+    shape = (9, cfg.num_kv_heads, cfg.head_dim)
+    k_new, v_new = jnp.asarray(rs.randn(*shape), jnp.float32), jnp.asarray(rs.randn(*shape), jnp.float32)
+    q = jnp.asarray(rs.randn(1, 1, cfg.num_heads, cfg.head_dim), jnp.float32)
+
+    def attend(slot, kv_len, window_k, window_v):
+        """The entry on the pool's arrays, layer 1: writes ``window_*`` behind ``kv_len - n`` and attends."""
+        n = window_k.shape[0]
+        out, k, v = ragged_paged_attention(
+            jnp.broadcast_to(q, (1, n) + q.shape[2:]), window_k[None], window_v[None], pool.cache.k_pages, pool.cache.v_pages, 1,
+            jnp.asarray(pool.page_table[slot][None]), jnp.asarray([kv_len], jnp.int32), jnp.asarray([n], jnp.int32), impl="xla",
+        )
+        pool.set_cache(k, v)
+        return np.asarray(out[0, n - 1])
+
+    assert pool.prepare_write(s1, 9)
+    attend(s1, 9, k_new, v_new)  # the prefill: 9 tokens' keys and values, every head in its own lanes
+    pool.advance(s1, 9)
+    pool.register_prefix(s1, prompt)
+    s2 = pool.alloc_slot(10, prefix_tokens=prompt)  # attaches the two full pages
+    orig = [int(p) for p in pool.page_table[s2][:2]]
+    pool.rollback(s1, 3)
+    assert pool.prepare_write(s1, 8) and pool.stats["cow_copies"] == 1 and pool.cache.heads_per_group == 4
+    copy = int(pool.page_table[s1, 1])
+    assert copy != orig[1] and int(pool.page_table[s2, 1]) == orig[1]
+    for pages in (pool.cache.k_pages, pool.cache.v_pages):
+        np.testing.assert_array_equal(np.asarray(pages[:, copy]), np.asarray(pages[:, orig[1]]))
+    assert float(jnp.abs(pool.cache.k_pages[1, copy]).min(axis=0).min(axis=0).max()) > 0  # every head's lanes of every group hold keys
+    # the writer writes positions 6, 7 again: its query then attends the 8 keys of the prompt, each head its own
+    got = attend(s1, 8, k_new[6:8], v_new[6:8])
+    group = cfg.num_heads // cfg.num_kv_heads
+    for h in range(cfg.num_heads):
+        k, v = np.asarray(k_new[:8, h // group]), np.asarray(v_new[:8, h // group])
+        scores = k @ np.asarray(q[0, 0, h]) / np.sqrt(cfg.head_dim)
+        p = np.exp(scores - scores.max())
+        np.testing.assert_allclose(got[h], (p / p.sum()) @ v, rtol=1e-5, atol=1e-5, err_msg=f"head {h}")
+    pool.integrity_check()
+    pool.defrag()
+    assert pool.cache.heads_per_group == 4
+
+
 def test_write_barrier_invalidates_exclusive_indexed_page():
     """Re-writing an indexed page you own exclusively must drop it from
     the index (an indexed page's content is immutable) — no copy needed."""

@@ -8,6 +8,8 @@ never by materializing an NH-wide cache copy.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -326,13 +328,18 @@ def test_fused_ragged_kernel_matches_xla_scatter_then_gather(case, heads, D, W, 
     kv_lens = jnp.asarray([before + n for before, n, _ in rows], jnp.int32)
     pt = jnp.asarray([table for _, _, table in rows], jnp.int32)
 
+    @jax.jit  # one program each: op by op, either form compiles its dozens of ops alone, seconds a case
     def fused(k_pages, v_pages):
         return kernel(
             q, k_new, v_new, k_pages, v_pages, layer, pt, kv_lens, q_lens,
             interpret=True, pages_per_buffer=pages_per_buffer,
         )
 
-    out_x, k_x, v_x = ragged_paged_attention(q, k_new, v_new, k0, v0, layer, pt, kv_lens, q_lens, impl="xla")
+    @jax.jit
+    def scatter_then_gather(k_pages, v_pages):
+        return ragged_paged_attention(q, k_new, v_new, k_pages, v_pages, layer, pt, kv_lens, q_lens, impl="xla")
+
+    out_x, k_x, v_x = scatter_then_gather(k0, v0)
     out_p, k_p, v_p = fused(k0, v0)
     for got, scattered, before in ((k_p, k_x, k0), (v_p, v_x, v0)):
         np.testing.assert_array_equal(np.asarray(got[:, 1:]), np.asarray(scattered[:, 1:]))
@@ -356,6 +363,120 @@ def test_fused_ragged_kernel_matches_xla_scatter_then_gather(case, heads, D, W, 
         )
         if n == 0 and (D % 128 == 0 or int(kv_lens[r]) == 0):  # the grid kernel: a row without keys
             assert (np.asarray(out_p)[r] == 0).all()
+
+
+# --- heads narrower than a lane tile, ``f`` to a page ------------------------
+def _shared(pool, f):
+    """An unpacked pool ``[L, NP, NKV, P, D]`` as the pool that holds ``f`` heads
+    a group, ``[L, NP, NKV / f, P, f D]``: head ``j`` in group ``j // f`` at lanes
+    ``(j % f) D ..``."""
+    L, NP, NKV, P, D = pool.shape
+    return pool.reshape(L, NP, NKV // f, f, P, D).transpose(0, 1, 2, 4, 3, 5).reshape(L, NP, NKV // f, P, f * D)
+
+
+def _unshared(pool, f):
+    L, NP, G, P, lanes = pool.shape
+    return pool.reshape(L, NP, G, P, f, lanes // f).transpose(0, 1, 2, 4, 3, 5).reshape(L, NP, G * f, P, lanes // f)
+
+
+def _narrow_head_window(D, NKV, Hg, W, seed=11):
+    """A window of every row kind at heads of ``D``: a decode row, a dead row, a
+    row that writes across a page boundary and one whose window starts an
+    empty row (at ``W = 16`` a chunk that spans two pages). Returns the
+    entry's arguments with UNPACKED pools."""
+    rs = np.random.RandomState(seed)
+    rows = [(10, 1), (0, 0), (_P - 1, min(W, 4)), (0, W)]
+    maxp, L, R = 3, 2, 4
+    arr = lambda *shape: jnp.asarray(rs.randn(*shape).astype(np.float32))
+    pools = arr(L, 1 + R * maxp, NKV, _P, D), arr(L, 1 + R * maxp, NKV, _P, D)
+    window = arr(R, W, NKV * Hg, D), arr(R, W, NKV, D), arr(R, W, NKV, D)
+    table = jnp.asarray(1 + rs.permutation(R * maxp).reshape(R, maxp), jnp.int32)
+    q_lens = jnp.asarray([n for _, n in rows], jnp.int32)
+    kv_lens = jnp.asarray([before + n for before, n in rows], jnp.int32)
+    return window, pools, (1, table, kv_lens, q_lens)
+
+
+@functools.lru_cache(maxsize=None)
+def _entry(impl, window=None, with_sinks=False):
+    """The entry as one compiled program (called op by op it compiles each of
+    its dozens of ops alone, seconds a case)."""
+
+    def call(q, k_new, v_new, k_pages, v_pages, layer, table, kv_lens, q_lens, sinks):
+        extras = dict(window=window, sinks=sinks) if window or with_sinks else {}
+        return ragged_paged_attention(q, k_new, v_new, k_pages, v_pages, layer, table, kv_lens, q_lens, impl=impl, **extras)
+
+    return jax.jit(call)
+
+
+@functools.lru_cache(maxsize=None)
+def _unpacked_reference(D, NKV, Hg, W, window=None, with_sinks=False):
+    """(the window, the pools before, the rest of the entry's arguments, what XLA's form gives on the unpacked pools)."""
+    operands, pools, rest = _narrow_head_window(D, NKV, Hg, W)
+    rest += (jnp.linspace(-1.0, 1.0, NKV * Hg) if with_sinks else None,)
+    return operands, pools, rest, _entry("xla", window, with_sinks)(*operands, *pools, *rest)
+
+
+def _assert_shared_lanes_agree(D, NKV, Hg, W, impl, **extras):
+    """The packed pool through ``impl`` against the unpacked pool through XLA:
+    live slots' outputs, a dead row's zeros, and, unpacked, every page but the
+    trash page byte for byte."""
+    f = 128 // D
+    operands, pools, rest, (want, *want_pools) = _unpacked_reference(D, NKV, Hg, W, **extras)
+    got, *got_pools = _entry(impl, **extras)(*operands, *(_shared(p, f) for p in pools), *rest)
+    assert got.shape == want.shape
+    for a, b, before in zip(got_pools, want_pools, pools):
+        assert a.shape == _shared(before, f).shape
+        np.testing.assert_array_equal(np.asarray(_unshared(a, f))[:, 1:], np.asarray(b)[:, 1:])
+        assert (np.asarray(b)[1, 1:] != np.asarray(before)[1, 1:]).any()  # something was written
+    for r, n in enumerate(np.asarray(rest[3])):
+        np.testing.assert_allclose(np.asarray(got)[r, :n], np.asarray(want)[r, :n], rtol=2e-5, atol=2e-5, err_msg=f"row {r}")
+    assert not np.asarray(got)[1].any()  # the dead row
+
+
+_GRANITE, _MHA_64, _FOUR_A_TILE = (64, 8, 4), (64, 2, 1), (32, 4, 2)  # (head_dim, kv heads, query heads a kv head)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize(
+    "layout, W",
+    # decode, verify (K = 3 drafts), a chunk of two pages at granite's heads; the two others a width each
+    [(_GRANITE, W) for W in _WIDTHS] + [(_MHA_64, 16), (_FOUR_A_TILE, 1), (_FOUR_A_TILE, 4)],
+)
+def test_heads_that_share_a_lane_tile_are_the_unpacked_attention(monkeypatch, layout, W, impl):
+    """``f = 128 // D`` KV heads side by side on a page's lanes (the pool of
+    ``kv_pool.heads_per_group``): the entry widens q, k and v to heads of 128,
+    BOTH implementations attend as if the group were one KV head, and what
+    comes back is the unpacked pool's attention, with the unpacked pool's
+    bytes in every page. The Pallas form is the kernel that walks live pages
+    (interpreted): the grid kernel, which pages of 64 lanes took, is out of reach."""
+    from deepspeed_tpu.ops.transformer import decode_attention
+
+    monkeypatch.setattr(decode_attention, "_ragged_by_grid", None)  # reaching it would raise
+    _assert_shared_lanes_agree(*layout, W, impl)
+
+
+@pytest.mark.parametrize("extras", [{"window": 5}, {"with_sinks": True}], ids=["window", "sinks"])
+def test_shared_lanes_bring_windows_and_sinks_to_heads_of_64(extras):
+    """A window and sinks at heads of 64, which the grid kernel refuses: query
+    heads keep their order through the widening, so ``sinks [NH]`` is passed
+    as it is."""
+    _assert_shared_lanes_agree(*_GRANITE, 4, "pallas", **extras)
+
+
+@pytest.mark.parametrize("D, NKV", [(64, 3), (24, 4)], ids=["three_heads_of_64", "heads_of_24"])
+def test_heads_that_do_not_pack_keep_the_unpacked_pool(D, NKV):
+    """An odd head count a shard, a width that does not divide 128: one head a
+    group, today's pool, and the two implementations still agree on it."""
+    from deepspeed_tpu.inference.kv_pool import heads_per_group, page_shapes
+
+    assert heads_per_group(D, D, NKV) == 1
+    assert page_shapes(2, 13, NKV, _P, D, D, 1) == ((2, 13, NKV, _P, D),) * 2
+    operands, pools, rest, (want, *want_pools) = _unpacked_reference(D, NKV, 2, 4)
+    got, *got_pools = _entry("pallas")(*operands, *pools, *rest)
+    for a, b in zip(got_pools, want_pools):
+        np.testing.assert_array_equal(np.asarray(a)[:, 1:], np.asarray(b)[:, 1:])
+    for r, n in enumerate(np.asarray(rest[3])):
+        np.testing.assert_allclose(np.asarray(got)[r, :n], np.asarray(want)[r, :n], rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("W", [1, 128])

@@ -1027,14 +1027,21 @@ def test_granite_hybrid_step_fits_whole_with_its_state_in_place(v5e, monkeypatch
     compiles for a v5e; K, V, the state and the convolution tails stay
     aliased in to out; ``ssd_decode`` runs nine times in the scan's body, the
     ragged kernel once (twice in the wide program); no routing rows ride on
-    the step's result; weights + pools + temporaries fit a chip."""
+    the step's result; weights + pools + temporaries fit a chip. Two KV heads
+    of 64 share a page's 128 lanes (``kv_pool.heads_per_group``), so the call
+    is the kernel that walks a row's live pages (the grid fallback, which
+    pages of 64 lanes took, is out of reach), and the pools keep the default
+    layout: the program holds no copy of either (both, in and out, 1.61 GB of
+    temporaries a step, before)."""
     from deepspeed_tpu.inference import hybrid_decode
-    from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes
+    from deepspeed_tpu.inference.kv_pool import StateStore, heads_per_group, page_shapes
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
+    from deepspeed_tpu.ops.transformer import decode_attention
 
     for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.state_space"):
         __import__(module)
         monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    monkeypatch.setattr(decode_attention, "_ragged_by_grid", None)  # reaching it would raise
     conf = json.loads(_GRANITE_CELL.read_text())
     paged = conf["engine"]["init_inference"]["paged_kv"]
     cfg = HybridMoEConfig(**conf["model"]["kwargs"])
@@ -1048,7 +1055,10 @@ def test_granite_hybrid_step_fits_whole_with_its_state_in_place(v5e, monkeypatch
     params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
     params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape), params)
     assert sum(a.size for a in jax.tree_util.tree_leaves(params)) == 3_191_396_096  # 6.38 GB in bfloat16
-    kv = on_v5e((4, rows * maxp + 1, cfg.num_kv_heads, page, key_lanes(cfg.head_dim)))
+    f = heads_per_group(cfg.head_dim, cfg.v_head_dim, cfg.num_kv_heads)
+    k_shape, v_shape = page_shapes(4, rows * maxp + 1, cfg.num_kv_heads, page, cfg.head_dim, cfg.v_head_dim, f)
+    assert f == 2 and k_shape == v_shape == (4, 1537, 4, 64, 128)
+    kv = on_v5e(k_shape)
     shapes = hybrid_decode.state_shapes(cfg, rows)
     assert shapes.state == (36, 65, 64, 64, 128) and shapes.conv == (36, 65, 3, 48, 128)
     store = StateStore(on_v5e(shapes.state, jnp.float32), on_v5e(shapes.conv), None, None, None)
@@ -1067,4 +1077,6 @@ def test_granite_hybrid_step_fits_whole_with_its_state_in_place(v5e, monkeypatch
     assert sum(name.startswith("ssd_decode") for name in kernels) == 9, kernels  # a period's nine, in the scan's body
     assert sum(name.startswith("ragged_paged_attention") for name in kernels) == (1 if width == 1 else 2), kernels
     assert not re.search(r"= f32\[36,65,64,64,128\]\S* copy\(", text)  # the state store is never copied
+    assert not re.search(r"= bf16\[4,1537,\S* copy\(", text)  # nor are the pages
+    assert memory.temp_size_in_bytes < 0.5e9  # 0.007 / 0.172 GB (1.618 / 1.680 with the pools' four copies, before)
     assert re.search(rf"s32\[{rows},{width + 1}\]", text) and not re.search(rf"s32\[{rows + decode.MOE_STAT_ROWS},{width + 1}\]", text)
