@@ -82,7 +82,11 @@ vLLM style):
   only settles, so ``run()`` / ``serve()`` return settled streams. A step's
   life thus spans three calls, and every span of it (``serve.pack``,
   ``serve.dispatch`` > ``serve.enqueue``, ``serve.fetch``, ``serve.emit`` >
-  ``serve.settle``) carries the step's ``seq``; the histogram
+  ``serve.settle``) carries the step's ``seq``; ``serve.pack`` also holds
+  the step's own record, what it sends to the kernel (``mixed``,
+  ``kv_tokens`` and ``row_lens``, the live rows' ``(q_len, kv_len)``: a
+  step packed again keeps its ``seq``, so the last pack before the
+  enqueue is the step the device ran); the histogram
   ``serve.turnaround_ms`` times the host between one step's wait and the
   next step's enqueue, the part of its work the device waits for;
 * admission order and preemption victims are delegated to a
@@ -141,6 +145,18 @@ def _spec_knob(spec, name, default):
     if isinstance(spec, dict):
         return spec.get(name, default)
     return getattr(spec, name, default)
+
+
+def _row_lens(q_lens: np.ndarray, kv_lens: np.ndarray) -> str:
+    """A step's live rows as ``serve.pack`` records them (``row_lens``): one
+    word a row in pack order, ``kv`` for a row of one query token and
+    ``q:kv`` for any other, ``kv`` counting the row's keys once the step's
+    own are written (what the attention kernel reads). A string, so that the
+    flight recorder's JSON and the profiler's event stats hold it as it went
+    in; a lone decode row keeps its ``1:``, because the profiler's stats give
+    a string of digits alone back as a number."""
+    text = " ".join(str(kv) if q == 1 else f"{q}:{kv}" for q, kv in zip(q_lens.tolist(), kv_lens.tolist()))
+    return "1:" + text if text.isdigit() else text
 
 
 def compiled_serving_programs(compile_stats: Dict) -> int:
@@ -1142,16 +1158,22 @@ class PagedServer:
                     tokens[i, 1 : 1 + d.size] = d
                     q_lens[i] = 1 + d.size
             program = ragged_program_name(R, W, self.tp)
-            # pages the ragged kernel walks a layer (the live rows' own) of the
-            # table slots a grid over the whole table would visit
-            kv_pages = int((-(-(lengths + q_lens)[q_lens > 0] // self.pool.page_size)).sum())
+            # the live rows as the kernel gets them: the step's own record,
+            # under its ``seq`` (a step packed again keeps its number: the
+            # last pack before the enqueue is the one the device ran)
+            live = q_lens > 0
+            live_q = q_lens[live]
+            live_kv = lengths[live] + live_q
+            # pages the ragged kernel walks a layer (the live rows' own)
+            kv_pages = int((-(-live_kv // self.pool.page_size)).sum())
             # what the program computes: its live tokens, in that many token
             # tiles (0: a window of one tile at most, computed whole)
-            live_tokens = int(q_lens.sum())
+            live_tokens = int(live_q.sum())
             tiles = token_tiles(self.cfg, R, W, live_tokens)
             pack_span.set(
-                rows=len(rows), width=W, program=program, kv_pages=kv_pages, table_pages=page_table.size,
-                live_tokens=live_tokens, token_tiles=tiles,
+                rows=len(rows), width=W, program=program, mixed=int(mixed), kv_pages=kv_pages,
+                kv_tokens=int(live_kv.sum()), live_tokens=live_tokens, token_tiles=tiles,
+                row_lens=_row_lens(live_q, live_kv),
             )
             host_made = [page_table, lengths, q_lens]
             states = self.pool.states
@@ -1163,9 +1185,6 @@ class PagedServer:
                 self._g_state_slots.set(len(rows))
                 if states.window_k is not None:
                     self._g_window_slots.set(len(rows))
-                if states.latent is not None:
-                    # live tokens under latent pages after this step: what each latent layer's kernel reads
-                    pack_span.set(latent_tokens=int((lengths + q_lens)[q_lens > 0].sum()))
             # the window with the in-flight rows' tokens laid in on the device
             # (queued behind the step that computes them), and the rest
             window = self._feed_tokens(tokens, prev.next_tokens if prev is not None else self._no_tokens, src)
@@ -1378,7 +1397,12 @@ class PagedServer:
                         eos_ids[i] = r.eos_token_id
                     budgets[i] = r.max_new_tokens - len(r.generated)  # >= 1
                 program = multistep_program_name(R, 1, H, self.tp)
-                pack_span.set(rows=len(rows), program=program)
+                # the window's first round, as a step's pack records it
+                live_kv = lengths[: len(rows)] + 1
+                pack_span.set(
+                    rows=len(rows), program=program, mixed=0, kv_tokens=int(live_kv.sum()),
+                    row_lens=_row_lens(live[: len(rows)], live_kv),
+                )
             with self.tracer.span("serve.dispatch", seq=seq, rows=len(rows), width=1, program=program):
                 window_fn = build_ragged_multistep(
                     self.cfg, R, 1, H, self.pool.page_size,

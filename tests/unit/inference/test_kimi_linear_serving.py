@@ -383,7 +383,7 @@ def test_the_step_span_says_which_cache_the_bytes_are_in(toy):
     assert {s["state_bytes_in_use"] for s in steps} == {0, a_slot, 2 * a_slot}  # the short row leaves, the other runs on
     assert max(s["latent_bytes_in_use"] for s in steps) == 3 * a_page  # 11 + 8 tokens: three pages of 8
     packs = [s["attrs"] for s in tracer.spans() if s["name"] == "serve.pack"]
-    assert packs[0]["latent_tokens"] == 14  # both prompts in the first wide window
+    assert packs[0]["kv_tokens"] == 14 and packs[0]["row_lens"] == "11:11 3:3"  # both prompts in the first wide window
     assert srv.pool.cache_bytes() == {"state_bytes_in_use": 0, "latent_bytes_in_use": 0}
 
 

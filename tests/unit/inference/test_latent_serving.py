@@ -310,7 +310,9 @@ def test_the_pack_span_counts_the_tokens_under_latent_pages(toy):
     while srv.has_work():
         srv.step()
     packs = [s for s in tracer.spans() if s["name"] == "serve.pack"]
-    assert [s["attrs"]["latent_tokens"] for s in packs][:3] == [11, 12, 13]
+    assert [s["attrs"]["kv_tokens"] for s in packs][:3] == [11, 12, 13]
+    # a lone decode row keeps its ``1:``: digits alone come back from the profiler's stats as a number
+    assert [s["attrs"]["row_lens"] for s in packs][:3] == ["11:11", "1:12", "1:13"] and [s["attrs"]["mixed"] for s in packs][:3] == [1, 0, 0]
     assert srv.pool.live_hbm_bytes() == 0 and srv.pool.latent_bytes_per_token == 4 * 40 * 4
 
 

@@ -149,11 +149,15 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
     program = f"paged_ragged_r{server.pool.max_slots}_w8"
     assert dispatch[3] == {"seq": seq, "rows": 3, "width": 8, "program": program, "ahead": 0}
     del dispatch[3]["ahead"]
-    # three first chunks of at most a page each, of a table of max_slots x max_pages_per_slot slots
-    table_pages = server.pool.max_slots * server.pool.max_pages_per_slot
+    # three first chunks of at most a page each: a mixed step, its rows as the kernel gets them (``q:kv``, a first
+    # chunk's keys are its own tokens) in one string that comes back from the event's stats as it went in
+    chunks = [min(p.size, 8) for p in _prompts(3, seed=12)]
+    row_lens = " ".join(f"{n}:{n}" for n in chunks)
     # and what the program computes: the chunks' tokens, in no token tile (a window of one tile at most)
-    live_tokens = sum(min(p.size, 8) for p in _prompts(3, seed=12))
-    assert pack[3] == {**dispatch[3], "kv_pages": 3, "table_pages": table_pages, "live_tokens": live_tokens, "token_tiles": 0}
+    live_tokens = sum(chunks)
+    assert pack[3] == {**dispatch[3], "mixed": 1, "kv_pages": 3, "kv_tokens": live_tokens, "live_tokens": live_tokens, "token_tiles": 0, "row_lens": row_lens}
+    # the table the step sends is a constant of the pool, and no attribute of a step
+    assert server.pool.page_table.shape == (server.pool.max_slots, server.pool.max_pages_per_slot)
     assert admit[2] <= pack[1] and pack[2] <= dispatch[1]
     # the first call packs the next step while the device runs this one, waits for the device and leaves the
     # settle to the call after it, which does it behind the enqueue of its own step
@@ -174,6 +178,18 @@ def test_step_spans_reach_the_profiler_trace_with_their_attributes(model_and_par
     assert steps[-1][3]["running"] >= 1 and steps[-1][3]["pages_in_use"] >= 1
     admits = [r for r in tr.spans() if r["ph"] == "n" and r["name"] == "admit" and r.get("id") in uids]
     assert len(admits) == 3 and all(r["attrs"]["queue_wait_ms"] >= 0.0 for r in admits)
+    # a lone decode row's ``row_lens`` keeps its ``1:``: the event's stats give a string of digits alone back as a number,
+    # and a record has to come back as it went in
+    jax.profiler.start_trace(str(tmp_path / "lone"), profiler_options=options)
+    try:
+        server.serve(_prompts(1, seed=13), max_new_tokens=3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = (tmp_path / "lone").glob("plugins/profile/*/*.xplane.pb")
+    lone = [dict(ev.stats) for plane in ProfileData.from_file(str(path)).planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events if ev.name == "serve.pack"]
+    assert len(lone) >= 3 and all(a["rows"] == 1 for a in lone) and sum(a["row_lens"].startswith("1:") for a in lone) >= 2
+    assert [a["row_lens"] for a in lone] == [r["attrs"]["row_lens"] for r in tr.spans() if r["name"] == "serve.pack"][-len(lone):]
 
 
 def test_preemption_leaves_preempt_instant_and_readmission(model_and_params):

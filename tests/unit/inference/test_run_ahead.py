@@ -14,6 +14,8 @@ the counters must say which of the two a run was.
 
 from __future__ import annotations
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -516,3 +518,117 @@ def test_streams_with_the_tracer_on_are_those_with_it_off(dense, name):
     assert off.tracer.spans() == [] and len([r for r in on.tracer.spans() if r["name"] == "serve.enqueue"]) == on.stats["ragged_steps"]
     # the histogram is the registry's and counts the same steps either way
     assert on.metrics.histogram("serve.turnaround_ms").snapshot()["count"] == off.metrics.histogram("serve.turnaround_ms").snapshot()["count"] == on.stats["run_ahead_steps"]
+
+
+# --- the step's own record -----------------------------------------------------
+def _decode_row_lens(text):
+    """``serve.pack``'s ``row_lens`` back to (q_len, kv_len) pairs: a word a row, ``kv`` alone where q is 1."""
+    return [tuple(int(x) for x in w.split(":")) if ":" in w else (1, int(w)) for w in text.split()]
+
+
+@pytest.fixture(scope="module")
+def recorded(dense):
+    """One traced run that holds a prompt of three chunks beside a short one,
+    a newcomer admitted behind a step in flight (the step packed before it
+    came is packed again under its ``seq``) and one drain (``settle()``
+    drops what was packed behind the step in flight: that ``seq`` is packed
+    again too), with every ``_Packed`` that reached ``_dispatch`` kept as the
+    device got it; and a second, of a server with windows armed."""
+    cfg, params = dense
+    tracer = Tracer(max_spans=1 << 14)
+    server = _server(cfg, params, tracer=tracer)
+    dispatched = {}
+    dispatch = server._dispatch
+
+    def spy(packed):
+        n = len(packed.rows)
+        q = np.asarray(packed.q_lens)[:n]
+        dispatched[packed.seq] = (packed.width, q.tolist(), (np.asarray(packed.operands[2])[:n] + q).tolist())
+        return dispatch(packed)
+
+    server._dispatch = spy
+    long, short, newcomer = (np.arange(n, dtype=np.int32) % 128 for n in (20, 5, 6))
+    server.submit(long, max_new_tokens=12)
+    server.submit(short, max_new_tokens=14)
+    for _ in range(4):
+        server.step()
+    repacked = [server._packed.seq]  # packed while the device ran, without the newcomer
+    server.submit(newcomer, max_new_tokens=6)
+    server.step()
+    server.step()
+    repacked.append(server._packed.seq)
+    server.settle()  # the one drain: what was packed behind the step in flight is dropped
+    server.run()
+    assert server.stats["drain_reasons"] == {"settle": 1, "idle": 1} and server.stats["mixed_steps"] >= 4
+    windowed_tracer = Tracer(max_spans=1 << 14)
+    windowed = _server(cfg, params, tracer=windowed_tracer, multi_step={"enable": True, "horizon": 4})
+    windowed.serve([short, newcomer], max_new_tokens=[13, 9])
+    assert windowed.stats["window_steps"] >= 1
+    return server, tracer.spans(), dispatched, repacked, windowed_tracer.spans()
+
+
+def _last_pack_before_each_enqueue(spans):
+    """seq -> the attributes of the last ``serve.pack{seq}`` that ended before ``serve.enqueue{seq}`` began."""
+    out = {}
+    for enq in (r for r in spans if r["name"] == "serve.enqueue"):
+        seq = enq["attrs"]["seq"]
+        before = [r for r in spans if r["name"] == "serve.pack" and r["attrs"].get("seq") == seq and r["t1"] <= enq["t0"]]
+        assert before, f"serve.enqueue seq {seq} has no serve.pack before it"
+        out[seq] = max(before, key=lambda r: r["t1"])["attrs"]
+    return out
+
+
+STEP_RECORD = ["every_enqueue_has_its_pack", "row_lens_are_the_dispatched_rows", "mixed_is_the_width", "kv_tokens_is_their_sum",
+               "a_repacked_seq_takes_its_last_pack", "json_dumps_as_the_flight_recorder", "a_windows_pack"]
+
+
+@pytest.mark.parametrize("case", STEP_RECORD)
+def test_a_step_carries_its_own_record_under_its_seq(recorded, case):
+    """``serve.pack`` holds what the kernel got: ``row_lens`` decode to the
+    ``q_lens`` / ``lengths + q_lens`` of the ``_Packed`` that was dispatched,
+    ``mixed`` says its width and ``kv_tokens`` their sum, under the ``seq``
+    of the ``serve.enqueue`` that follows; a ``seq`` packed twice (a newcomer,
+    a drain) is read from its LAST pack before the enqueue."""
+    server, spans, dispatched, repacked, window_spans = recorded
+    record = _last_pack_before_each_enqueue(spans)
+    if case == "every_enqueue_has_its_pack":
+        assert sorted(record) == sorted(dispatched) == list(range(server.stats["ragged_steps"]))
+        assert all({"mixed", "kv_tokens", "row_lens", "kv_pages", "live_tokens", "token_tiles"} <= set(a) for a in record.values())
+        assert not any({"table_pages", "latent_tokens"} & set(a) for a in record.values())
+    elif case == "row_lens_are_the_dispatched_rows":
+        for seq, (_, q, kv) in dispatched.items():
+            assert _decode_row_lens(record[seq]["row_lens"]) == list(zip(q, kv)), seq
+            assert record[seq]["rows"] == len(q) and record[seq]["live_tokens"] == sum(q)
+        # the long prompt's three chunks (8, 8, 4 of 20) and a decode row written ``kv`` alone
+        assert [_decode_row_lens(record[s]["row_lens"])[0] for s in (0, 1, 2)] == [(8, 8), (8, 16), (4, 20)]
+        assert record[3]["row_lens"] == "21 8"  # the short prompt: 5, then a token a step
+    elif case == "mixed_is_the_width":
+        for seq, (width, q, _) in dispatched.items():
+            assert record[seq]["mixed"] == int(width == server._ragged_w_mixed) == int(record[seq]["width"] != 1), seq
+        assert {a["mixed"] for a in record.values()} == {0, 1}
+        assert sum(a["mixed"] for a in record.values()) == server.stats["mixed_steps"]
+    elif case == "kv_tokens_is_their_sum":
+        for seq, (_, _, kv) in dispatched.items():
+            assert record[seq]["kv_tokens"] == sum(kv), seq
+            assert record[seq]["kv_pages"] == sum(-(-n // server.pool.page_size) for n in kv)
+    elif case == "a_repacked_seq_takes_its_last_pack":
+        for seq in repacked:
+            packs = [r["attrs"] for r in spans if r["name"] == "serve.pack" and r["attrs"].get("seq") == seq]
+            assert len(packs) == 2 and record[seq] is packs[1] and _decode_row_lens(packs[1]["row_lens"]) == list(zip(*dispatched[seq][1:]))
+        # the newcomer's first chunk rides in the pack that was enqueued, not in the one made before it came
+        first, last = ([r["attrs"] for r in spans if r["name"] == "serve.pack" and r["attrs"].get("seq") == repacked[0]])
+        assert (first["rows"], first["mixed"]) == (2, 0) and (last["rows"], last["mixed"]) == (3, 1) and last["row_lens"].endswith(" 6:6")
+        # the pack a drain dropped was made from counts the settle did not overturn: the same rows, packed again
+        first, last = ([r["attrs"] for r in spans if r["name"] == "serve.pack" and r["attrs"].get("seq") == repacked[1]])
+        assert first == last and first is not last
+    elif case == "json_dumps_as_the_flight_recorder":
+        for r in spans + window_spans:
+            if r["name"] == "serve.pack":
+                assert json.loads(json.dumps(r["attrs"])) == r["attrs"] and isinstance(r["attrs"]["row_lens"], str)
+    else:
+        window = _last_pack_before_each_enqueue(window_spans)
+        windows = [a for a in window.values() if a["program"].startswith("paged_multistep")]
+        assert windows and all(a["mixed"] == 0 and "width" not in a for a in windows)
+        for a in windows:  # every row decodes one token in the window's first round
+            rows = _decode_row_lens(a["row_lens"])
+            assert len(rows) == a["rows"] and all(q == 1 for q, _ in rows) and a["kv_tokens"] == sum(kv for _, kv in rows)

@@ -135,6 +135,9 @@ def test_serving_chaos_kill_dumps_mid_step(tmp_path, model_and_params):
     # the two completed scheduler rounds are on the timeline
     names = [s["name"] for s in obj["spans"]]
     assert names.count("serve.step") == 2
+    # and each step's own record came through the dump's JSON as it went in: a prompt of 8 is one chunk, then decode rows
+    packs = [s["attrs"] for s in obj["spans"] if s["name"] == "serve.pack"]
+    assert [(a["seq"], a["mixed"], a["kv_tokens"], a["row_lens"]) for a in packs][:3] == [(0, 1, 8, "8:8"), (1, 0, 9, "1:9"), (2, 0, 10, "1:10")]
 
 
 # ---------------------------------------------------------------------------
