@@ -737,21 +737,32 @@ class TransformerLM(DSModule):
         gather is exact and the rng split order matches the plain scan body,
         so every depth produces bit-identical outputs — only the schedule
         changes. Tail iterations re-gather the last layer into
-        never-consumed buffers (index clamp); their cotangents are zero, so
-        gradients are untouched."""
+        never-consumed buffers (index clamp).
+
+        The stack is scanned (``xs``) for the layer's USE: iteration *i*
+        gets its own ZeRO-cut slice, the layer's cotangent is transposed
+        onto it, and the backward scan hands it out as ``ys`` — one layer's
+        gradient written in place an iteration. The prologue and the
+        lookahead index ``frozen``, a ``stop_gradient`` view the body
+        closes over: nothing flows back into it, so the backward carries no
+        accumulator of the stack's whole shape (which it would pass over
+        once a layer to add one layer's gradient)."""
         cfg = self.config
         L = cfg.num_layers
         depth = max(0, min(int(plan.depth), L))
 
-        def pbody(carry, i):
+        frozen = jax.lax.stop_gradient(layers)
+
+        def pbody(carry, scanned):
             x, rng, bufs = carry
+            mine, i = scanned
             if depth:
-                cur = plan.use_buffered(layers, bufs[0], i)
+                cur = plan.use_buffered(mine, bufs[0])
                 bufs = bufs[1:] + (
-                    plan.gather_layer(layers, jnp.minimum(i + depth, L - 1)),
+                    plan.gather_layer(frozen, jnp.minimum(i + depth, L - 1)),
                 )
             else:
-                cur = plan.gather_layer(layers, i)
+                cur = mine  # the pin below IS the use-point gather
             cur = plan.reduce_grads(plan.pin_gathered(cur))
             y, rng, aux = self._scan_layer_step(x, cur, positions, rng, train)
             return (y, rng, bufs), aux
@@ -760,9 +771,9 @@ class TransformerLM(DSModule):
             policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
             pbody = jax.checkpoint(pbody, policy=policy, prevent_cse=False)
 
-        bufs = tuple(plan.gather_layer(layers, min(j, L - 1)) for j in range(depth))
+        bufs = tuple(plan.gather_layer(frozen, min(j, L - 1)) for j in range(depth))
         (x, _, _), aux_per_layer = jax.lax.scan(
-            pbody, (x, base_rng, bufs), jnp.arange(L, dtype=jnp.int32)
+            pbody, (x, base_rng, bufs), (layers, jnp.arange(L, dtype=jnp.int32))
         )
         return x, jnp.sum(aux_per_layer)
 

@@ -21,7 +21,17 @@ software pipeline:
   ``stage3_prefetch_bucket_size``). The gather is a
   ``with_sharding_constraint`` from the ZeRO-sharded per-layer spec to the
   spec with the ZeRO axes stripped — exact, so the pipelined step is
-  bit-identical to the unpipelined one.
+  bit-identical to the unpipelined one. The stacked tree is SCANNED
+  (``xs``): each iteration is handed its own ZeRO-cut slice, the layer's
+  cotangent is transposed onto that slice (:meth:`OverlapPlan.use_buffered`)
+  and so leaves the backward scan as its output (``ys``), one layer
+  written in place an iteration. Only the lookahead indexes a stack the
+  body closes over, and that one is a ``stop_gradient`` view: a
+  closed-over stack WITH a gradient gets a whole-stack accumulator in the
+  backward carry (``acc += update_slice(zeros, g, i)``, a pass over all L
+  layers to add one — 16 ``select_add`` fusions a layer in GPT-2 XL's
+  step, PR 55), and even an instantiated zero sent to the lookahead's index
+  would keep that accumulator alive.
 * **Bucketed gradient reduce-scatter** (stage >= 2) — an identity
   ``custom_vjp`` around the per-layer params whose backward pins each
   layer's cotangent to its scattered layout *inside* the backward scan,
@@ -135,65 +145,62 @@ class OverlapPlan:
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def gather_layer(self, stacked: Any, i) -> Any:
-        """Slice layer ``i`` from the stacked [L, ...] tree and constrain it
+        """Slice layer ``i`` from a stacked [L, ...] tree and constrain it
         to the gathered (ZeRO-axes-stripped) sharding — the all-gather the
-        pipeline issues ahead of use. ``i`` may be a python int (prologue)
-        or a traced scan index."""
-        flat, treedef = jax.tree_util.tree_flatten(stacked)
-        out = []
-        for leaf, info in zip(flat, self.leaves):
-            t = jax.lax.dynamic_index_in_dim(leaf, i, axis=0, keepdims=False)
-            out.append(
-                jax.lax.with_sharding_constraint(
-                    t, NamedSharding(self.mesh, info.gather_spec)
-                )
+        pipeline issues AHEAD of use, for the prologue (``i`` a python int)
+        and the lookahead (a traced scan index). ``stacked`` must carry no
+        gradient (the scan hands it a ``stop_gradient`` view): the transpose
+        of an index into a stack the scan closes over is a whole-stack
+        accumulator in the backward carry, ``acc += update_slice(zeros, g,
+        i)`` once a layer — a pass over every [L, ...] leaf to add one
+        layer's gradient. The layer's gradient leaves through
+        :meth:`use_buffered` instead."""
+        return self.pin_gathered(
+            jax.tree_util.tree_map(
+                lambda leaf: jax.lax.dynamic_index_in_dim(
+                    leaf, i, axis=0, keepdims=False
+                ),
+                stacked,
             )
-        return jax.tree_util.tree_unflatten(treedef, out)
+        )
 
-    def use_buffered(self, stacked: Any, buf: Any, i) -> Any:
+    def use_buffered(self, mine: Any, buf: Any) -> Any:
         """Consume a prefetched per-layer buffer with USE-POINT autodiff.
 
-        Forward: the double-buffered carry value (the gather issued
-        ``depth`` layers ago — the schedule the pipeline exists for).
-        Backward: ``jax.linear_transpose`` of :meth:`gather_layer` at this
-        layer's own index — the exact transpose the depth-0 use-point
-        gather gets from autodiff, scattering the cotangent straight into
-        the stacked tree. Without this, the buffer's cotangent travels
-        back through ``depth`` backward-scan carries and the partitioner
-        re-derives the cross-device grad reduction around the carry's
-        layout — measured on the 8-device mesh as last-ulp grad drift vs
-        depth 0 (all-reduce vs reduce-scatter summation order). Routing
-        the cotangent through the same ops as depth 0 makes depth-k
-        bit-identical BY CONSTRUCTION; the carried buffers get zero
-        cotangent, so their backward path folds away. Sound because the
-        pipeline invariant holds bit-wise: buf IS gather_layer(stacked, i)
-        — both pure data movement of the same shards."""
+        ``mine`` is this iteration's own ZeRO-cut slice of the stack, handed
+        in by the scan as ``xs``; ``buf`` the double-buffered carry value
+        (the gather issued ``depth`` layers ago — the schedule the pipeline
+        exists for). Forward: ``buf``. Backward: ``jax.linear_transpose`` of
+        the gather (:meth:`pin_gathered`, the constraint alone) onto
+        ``mine`` — the exact transpose the depth-0 use-point gather gets
+        from autodiff — and nothing to ``buf``. So the layer's cotangent
+        leaves the backward scan as that iteration's ``ys`` slice, written
+        once in place, at every depth. Without this, the buffer's cotangent
+        travels back through ``depth`` backward-scan carries and the
+        partitioner re-derives the cross-device grad reduction around the
+        carry's layout — measured on the 8-device mesh as last-ulp grad
+        drift vs depth 0 (all-reduce vs reduce-scatter summation order).
+        Routing the cotangent through the same ops as depth 0 makes depth-k
+        bit-identical BY CONSTRUCTION. Sound because the pipeline invariant
+        holds bit-wise: buf IS pin_gathered(mine) — both pure data movement
+        of the same shards."""
         avals = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), stacked
-        )
-        bavals = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), buf
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), mine
         )
 
         @jax.custom_vjp
-        def _use(stacked, buf, i):
+        def _use(mine, buf):
             return buf
 
-        def _fwd(stacked, buf, i):
-            return buf, i
+        def _fwd(mine, buf):
+            return buf, None
 
-        def _bwd(idx, g):
-            (d_stacked,) = jax.linear_transpose(
-                lambda s: self.gather_layer(s, idx), avals
-            )(g)
-            d_buf = jax.tree_util.tree_map(
-                lambda a: jnp.zeros(a.shape, a.dtype), bavals
-            )
-            d_idx = np.zeros(np.shape(idx), jax.dtypes.float0)
-            return (d_stacked, d_buf, d_idx)
+        def _bwd(_, g):
+            (d_mine,) = jax.linear_transpose(self.pin_gathered, avals)(g)
+            return (d_mine, None)
 
         _use.defvjp(_fwd, _bwd)
-        return _use(stacked, buf, i)
+        return _use(mine, buf)
 
     # --- bucketed in-scan gradient reduction ---------------------------
     def reduce_grads(self, per_layer: Any) -> Any:
@@ -206,11 +213,16 @@ class OverlapPlan:
         gathered-over-ZeRO layout (ONE collective per bucket; without it
         XLA emits one per leaf, or defers the whole reduction to the tail).
         The SCATTERED stage-2/3 layout then lands at the engine's grad
-        shardings — a free local slice once the sum exists. Pinning the
-        scattered layout here instead would fight the transpose
-        accumulator's carry sharding: the partitioner keeps that carry
-        gathered and answers with a gather-back per layer (measured on the
-        8-device mesh), turning the optimization into extra wire traffic."""
+        shardings — a free local slice once the sum exists. In the
+        pipelined scan the reduced cotangent leaves the loop as the
+        backward scan's ``ys``, and the partitioner gives that stack the
+        engine's cut layout: GPT-2 XL's step compiled for a described
+        ``v5e:2x2`` (PR 55) all-reduces the bucket in the loop body,
+        slices, and writes each chip's quarter of a layer in place
+        (``dynamic-update-slice`` into ``[48,400,1600]``; ``w_in`` and
+        ``w_out`` cut are ``[48,1600,1600]``). It is all-reduce + slice,
+        not a reduce-scatter: pinning the scattered layout here is
+        untried since the stack left the carry."""
         if not self.reduce_enabled:
             return per_layer
 

@@ -11,8 +11,11 @@ heads over 4 kv heads, D64, page 64, 8 slots, windows 1 and 128); the ragged
 kernel also at the benchmark's serving cells' (heads of 128: the kernel that
 walks a row's live pages).
 
-The last test compiles the whole ragged serving step at the shapes of the
-benchmark's Mistral cells and reads what the compiler made of the KV pool.
+The tests after the kernels compile whole ragged serving steps at the shapes
+of the benchmark's cells (Mistral's first) and read what the compiler made of
+the pools. The file's last test compiles training's pipelined ZeRO-3 layer scan
+at GPT-2 XL's widths for the four described chips and reads where a layer's
+gradient is written.
 """
 
 import functools
@@ -50,8 +53,8 @@ I32 = jnp.int32
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """One described v5e device. A compile for it is written to the
+def v5e_2x2():
+    """A described ``v5e:2x2``. A compile for it is written to the
     persistent cache but can never be read back without a chip, so the
     cache is off for this module (the guide's advice)."""
     from jax.experimental import topologies
@@ -63,9 +66,15 @@ def v5e():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    """One described v5e device."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _flash_fwd(q, k, v):
@@ -346,10 +355,9 @@ _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.$-]+) \(.*\{$")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.$-]+) = (.+?) ([a-z][a-z0-9-]*)\((.*)$")
 
 
-def _executed(text):
-    """``(name, opcode, result as written)`` of every instruction of a
-    compiled program's text that runs as itself: the entry's and the loop
-    bodies'. What stands inside a fusion is part of it and writes nothing out.
+def _computations(text):
+    """``{computation: [(is ROOT, name, result as written, opcode, the rest of the line)]}``
+    of a compiled program's text.
     (``analysis.hlo.parse_computations`` does not read a tiled layout,
     ``{1,0:T(8,128)(2,1)}``, and drops such a line: most of a TPU program.)"""
     computations, body = {}, None
@@ -358,14 +366,22 @@ def _executed(text):
         if header:
             body = computations.setdefault(header.group(1), [])
         elif body is not None and (m := _INSTRUCTION.match(line)):
-            body.append(m.groups())
+            body.append((line.lstrip().startswith("ROOT "), *m.groups()))
+    return computations
+
+
+def _executed(text):
+    """``(name, opcode, result as written)`` of every instruction of a
+    compiled program's text that runs as itself: the entry's and the loop
+    bodies'. What stands inside a fusion is part of it and writes nothing out."""
+    computations = _computations(text)
     fused = {
-        callee for body in computations.values() for _, _, opcode, rest in body if opcode == "fusion"
+        callee for body in computations.values() for _, _, _, opcode, rest in body if opcode == "fusion"
         for callee in re.findall(r"calls=%([\w.$-]+)", rest)
     }
     return [
         (name, opcode, result) for computation, body in computations.items() if computation not in fused
-        for name, result, opcode, _ in body
+        for _, name, result, opcode, _ in body
     ]
 
 
@@ -1080,3 +1096,108 @@ def test_granite_hybrid_step_fits_whole_with_its_state_in_place(v5e, monkeypatch
     assert not re.search(r"= bf16\[4,1537,\S* copy\(", text)  # nor are the pages
     assert memory.temp_size_in_bytes < 0.5e9  # 0.007 / 0.172 GB (1.618 / 1.680 with the pools' four copies, before)
     assert re.search(rf"s32\[{rows},{width + 1}\]", text) and not re.search(rf"s32\[{rows + decode.MOE_STAT_ROWS},{width + 1}\]", text)
+
+
+_XL_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/gpt2-xl.json"
+
+
+def _loop_bodies(text):
+    """``{while body: [(name, opcode, result as written)]}`` of a compiled
+    program's text, a fusion's opcode written ``fusion:<its root's opcode>``."""
+    computations = _computations(text)
+    root_of = {c: next((opcode for root, _, _, opcode, _ in body if root), None) for c, body in computations.items()}
+    loops = {
+        callee for body in computations.values() for _, _, _, opcode, rest in body if opcode == "while"
+        for callee in re.findall(r"body=%([\w.$-]+)", rest)
+    }
+    return {
+        loop: [
+            (name, opcode if opcode != "fusion" else "fusion:" + str(root_of[re.search(r"calls=%([\w.$-]+)", rest).group(1)]), result)
+            for _, name, result, opcode, rest in computations[loop]
+        ]
+        for loop in loops
+    }
+
+
+def test_zero3_layer_pipeline_writes_a_layers_gradient_where_it_lies(v5e_2x2, monkeypatch):
+    """The pipelined ZeRO-3 forward and backward of ``TransformerLM`` at
+    GPT-2 XL's widths (48 layers, H 1,600, I 6,400, 25 heads of 64, remat,
+    flash attention under ``shard_map``; a short sequence and a small table:
+    neither is in the layer scan's parameters) for the four chips of a
+    described ``v5e:2x2``, the ``gpt2_xl_zero3_dp4_train`` cell's plan (stage
+    3 and nothing else: one layer of lookahead, the in-loop reduction).
+    Inside a loop body nothing but a ``dynamic-update-slice`` (a fusion rooted
+    in one, for the matrices) has a result of a stacked leaf's shape, whole or
+    cut four ways: the forward stacks what the backward needs and the backward
+    writes each layer's gradient, one slice a layer, in place. A stack the
+    scan closes over gets a whole-stack accumulator in the backward carry
+    instead: a zero ``broadcast`` of every stack, an update of one layer of it
+    and a ``select_add`` fusion over all 48 layers, once a layer (until PR 55:
+    16 of them, 2 x 53.8 ms of a 1,275 ms step for ``w_in`` and ``w_out``
+    alone). The collectives are not this mechanism's: as many all-gathers and
+    all-reduces as before it, and no reduce-scatter."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.parallel.mesh import MeshConfig, initialize_topology
+    from deepspeed_tpu.runtime.zero.config import DeepSpeedZeroConfig
+    from deepspeed_tpu.runtime.zero.overlap import build_overlap_plan, overlap_scope
+    from deepspeed_tpu.runtime.zero.partition import ZeroPartitioner
+
+    module = "deepspeed_tpu.ops.transformer.flash_attention"
+    __import__(module)
+    monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    conf = json.loads(_XL_CELL.read_text())
+    chips, seq = conf["engine"]["ds_config"]["mesh"]["data"], 128
+    cfg = TransformerConfig(**{**conf["model"]["kwargs"], "vocab_size": 1024, "max_seq_len": seq})
+    L, H, I = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
+    assert (L, H, I, cfg.num_heads, cfg.remat, cfg.flash_attention, chips) == (48, 1600, 6400, 25, True, True, 4)
+    topo = initialize_topology(MeshConfig(data=chips), devices=v5e_2x2.devices[:chips])  # conftest resets it
+    model = TransformerLM(cfg)
+    tokens = jnp.zeros((chips, seq), I32)
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), {"input_ids": tokens, "labels": tokens}))
+    zero = DeepSpeedZeroConfig(**conf["engine"]["ds_config"]["zero_optimization"])
+    partitioner = ZeroPartitioner(zero, topo, model.tp_partition_rules(shapes))
+    param_specs, grad_specs = partitioner.param_specs(shapes), partitioner.grad_accum_specs(shapes)
+    plan = build_overlap_plan(zero, topo, shapes["layers"], param_specs["layers"], grad_specs["layers"], L)
+    assert (plan.prefetch_enabled, plan.depth, plan.reduce_enabled) == (True, 1, True)
+
+    def on_mesh(spec):
+        return NamedSharding(topo.mesh, spec)
+
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        lambda a, spec: jax.ShapeDtypeStruct(a.shape, BF16, sharding=on_mesh(spec)), shapes, param_specs, is_leaf=is_spec
+    )
+    batch = jax.ShapeDtypeStruct(tokens.shape, I32, sharding=on_mesh(P("data", None)))
+
+    def loss(params, batch):
+        with overlap_scope(plan):
+            return model.apply(params, batch, rngs={"dropout": jax.random.PRNGKey(0)}, train=True)
+
+    compiled = jax.jit(
+        jax.grad(loss), out_shardings=jax.tree_util.tree_map(on_mesh, grad_specs, is_leaf=is_spec)
+    ).lower(params, {"input_ids": batch, "labels": batch}).compile({"xla_tpu_enable_latency_hiding_scheduler": "true"})
+    text = compiled.as_text()
+
+    stacks = {a.shape for a in jax.tree_util.tree_leaves(shapes["layers"])}
+    assert {(L, H, I), (L, I, H), (L, H, H), (L, H)} <= stacks
+    cut = {s[:d] + (s[d] // chips,) + s[d + 1:] for s in stacks for d in range(1, len(s)) if s[d] % chips == 0}
+    of_a_stack = re.compile("|".join(rf"^\w+\[{','.join(map(str, s))}\]" for s in stacks | cut))
+    moves = {"dynamic-update-slice", "fusion:dynamic-update-slice"}
+    bodies = _loop_bodies(text)
+    written = {
+        loop: [(name, opcode, result.split("{")[0]) for name, opcode, result in body
+               if of_a_stack.match(result) and opcode not in _PLUMBING]
+        for loop, body in bodies.items()
+    }
+    # the vectors' stacks (150 KB) may be fetched ahead into fast memory: a copy of no account
+    strangers = [
+        w for ws in written.values() for w in ws
+        if w[1] not in moves and not (w[1] in ("copy-start", "copy-done") and w[2].count(",") == 1)
+    ]
+    assert not strangers, strangers
+    # the layer loops are there and write the matrices: forward (six gathered, for the backward) and backward (six gradients)
+    assert sorted(sum(opcode == "fusion:dynamic-update-slice" for _, opcode, _ in ws) for ws in written.values() if ws) == [6, 6], written
+    opcodes = [opcode for _, opcode, _ in _executed(text)]
+    collectives = {k: sum(o in (k, k + "-start") for o in opcodes) for k in ("all-gather", "all-reduce", "reduce-scatter")}
+    assert collectives == {"all-gather": 13, "all-reduce": 7, "reduce-scatter": 0}, collectives

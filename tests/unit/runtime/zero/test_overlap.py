@@ -210,6 +210,45 @@ def test_explicit_gather_matches_raw_scan_allclose(eight_devices):
 
 
 # ---------------------------------------------------------------------------
+# where the stacked gradient leaves the backward scan
+# ---------------------------------------------------------------------------
+def _scan_eqns(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            out.append(eqn)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _scan_eqns(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("prefetch_layers", [0, 1, 2])
+def test_backward_scan_writes_each_layer_grad_as_ys(prefetch_layers, remat, eight_devices):
+    """The stack is scanned (``xs``) for the layer's use, so its cotangent
+    leaves the backward scan as ``ys`` — one slice written in place a
+    layer. A stack the body closes over gets a carry-held accumulator of its
+    whole shape instead, ``acc += update_slice(zeros, g, i)`` every
+    iteration: a pass over all L layers to add one (the ``select_add``
+    fusions over ``[48,1600,1600]`` that headed GPT-2 XL's step). Read from
+    the jaxpr: no compiler in the loop."""
+    e = _engine({"prefetch_layers": prefetch_layers}, num_layers=6, remat=remat)
+    batch = jax.tree_util.tree_map(jnp.asarray, _batches(1, 1)[0][0])
+    e.init_params(batch)
+    assert e._overlap_plan.prefetch_enabled and e._overlap_plan.depth == prefetch_layers
+    stacked = sorted(tuple(l.shape) for l in jax.tree_util.tree_leaves(e._params["layers"]))
+    jaxpr = jax.make_jaxpr(jax.grad(e._loss_of))(e._params, batch, jax.random.PRNGKey(0))
+    (bwd,) = [s for s in _scan_eqns(jaxpr.jaxpr, []) if s.params["reverse"]]
+    n_carry = bwd.params["num_carry"]
+    carries = [tuple(v.aval.shape) for v in bwd.outvars[:n_carry]]
+    ys = [tuple(v.aval.shape) for v in bwd.outvars[n_carry:]]
+    assert not [c for c in carries if c in stacked], carries
+    assert sorted(y for y in ys if y in stacked) == stacked, ys
+
+
+# ---------------------------------------------------------------------------
 # plan gating and the in-flight byte budget
 # ---------------------------------------------------------------------------
 def test_plan_gating(eight_devices):
