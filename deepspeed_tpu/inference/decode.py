@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.compression.int8 import qmatmul
-from deepspeed_tpu.models.config import TransformerConfig, has_latent_layers, has_state_layers
+from deepspeed_tpu.models.config import TransformerConfig, cache_layers, has_latent_layers, has_state_layers
 from deepspeed_tpu.models.transformer import _norm, _rope
 
 NEG_INF_F = -1e30  # additive mask for dead beams (finite: keeps fp math NaN-free)
@@ -36,7 +36,7 @@ NEG_INF_F = -1e30  # additive mask for dead beams (finite: keeps fp math NaN-fre
 class KVCache(NamedTuple):
     """Preallocated decode workspace (reference allocate_workspace)."""
 
-    k: jax.Array  # [L, B, max_len, NKV, D]
+    k: jax.Array  # [L, B, max_len, NKV, D], L the model's cache layers (models/config.py::cache_layers)
     v: jax.Array  # [L, B, max_len, NKV, D]
 
     @property
@@ -50,7 +50,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None) -> 
         dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}[
             cfg.dtype
         ]
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cache_layers(cfg), batch, max_len, cfg.num_kv_heads, cfg.head_dim)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
@@ -125,7 +125,8 @@ def _moe_ffn(cfg, p, h, live=None, experts=None, group_offset=0):
 
 
 def _ffn_body(cfg: TransformerConfig, p, x, norm_scale, norm_bias, tp=None, moe_ffn=_moe_ffn):
-    """norm → ffn, NO residual — callers place the residual per architecture.
+    """norm → ffn (→ the sandwich's second norm, ``post_sublayer_norm``), NO
+    residual — callers place the residual per architecture.
     ``moe_ffn`` is what an MoE layer runs (``_moe_ffn``, which a caller may
     have bound to its live tokens and expert stacks).
     Returns (out, an MoE layer's per-expert assignment counts or None)."""
@@ -140,7 +141,10 @@ def _ffn_body(cfg: TransformerConfig, p, x, norm_scale, norm_bias, tp=None, moe_
                     "placement is the 'expert' mesh axis, not a TP weight split"
                 )
             return moe_ffn(cfg, p["moe"], h)
-        return apply_dense_ffn(p, h, cfg.activation, tp=tp), None
+        out = apply_dense_ffn(p, h, cfg.activation, tp=tp)
+        if getattr(cfg, "post_sublayer_norm", False):
+            out = _norm(out, p["mlp_post_norm_scale"], p.get("mlp_post_norm_bias"), cfg.norm, cfg.norm_eps)
+        return out, None
 
 
 def _layer_mlp(cfg: TransformerConfig, p, x, tp=None, moe_ffn=_moe_ffn):
@@ -172,6 +176,8 @@ def _post_attention(cfg, p, x, attn, tp=None, moe_ffn=_moe_ffn):
         attn = attn.astype(x.dtype)
         if cfg.use_bias:
             attn = attn + p["bo"].astype(x.dtype)
+        if getattr(cfg, "post_sublayer_norm", False):
+            attn = _norm(attn, p["attn_post_norm_scale"], p.get("attn_post_norm_bias"), cfg.norm, cfg.norm_eps)
     if cfg.parallel_residual:
         # GPT-J/NeoX: mlp branch reads x (shared ln_1 or its own norm),
         # not the attn-updated residual
@@ -259,11 +265,47 @@ def _forward_with_cache(cfg, params, tokens, cache: KVCache, start_pos):
         x, _ = _post_attention(cfg, p, x, attn)
         return x, (k_cache_l, v_cache_l)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache.k, cache.v)
-    )
+    loops = getattr(cfg, "num_loops", 1)
+    if loops == 1:
+        x, (new_k, new_v) = jax.lax.scan(
+            layer_step, x, (params["layers"], cache.k, cache.v)
+        )
+    else:
+        # a looped stack: pass t runs the same weights over cache layers
+        # t * L .. (t + 1) * L - 1, the final norm between passes
+        L = cfg.num_layers
+        ks, vs = [], []
+        for t in range(loops):
+            x, (k_t, v_t) = jax.lax.scan(
+                layer_step, x, (params["layers"], cache.k[t * L : (t + 1) * L], cache.v[t * L : (t + 1) * L])
+            )
+            ks.append(k_t)
+            vs.append(v_t)
+            if t < loops - 1:
+                x = _pass_norm(cfg, params, x)
+        new_k, new_v = jnp.concatenate(ks), jnp.concatenate(vs)
 
     return _final_logits(cfg, params, x)[:, -1, :], KVCache(k=new_k, v=new_v)
+
+
+def _looped_passes(cfg, params, carry, one_pass):
+    """``cfg.num_loops`` passes of a looped stack inside one program:
+    ``one_pass(t, carry) -> carry`` (a layer scan; ``carry[0]`` the hidden
+    state, the whole pools behind it) under the ``loop_pass`` scope, the final
+    norm between two passes."""
+    for t in range(cfg.num_loops):
+        with jax.named_scope("loop_pass"):
+            carry = one_pass(t, carry)
+        if t < cfg.num_loops - 1:
+            carry = (_pass_norm(cfg, params, carry[0]),) + tuple(carry[1:])
+    return carry
+
+
+def _pass_norm(cfg, params, x):
+    """The final norm between two passes of a looped stack: its output is the
+    next pass's input (the last pass's is ``_final_logits``' own)."""
+    with jax.named_scope("pass_norm"):
+        return _norm(x, params["final_norm_scale"], params.get("final_norm_bias"), cfg.norm, cfg.norm_eps)
 
 
 def _final_logits(cfg, params, x):
@@ -920,6 +962,16 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
     weights read and written once more a layer. In both forms a barrier
     stands between the projections' matmuls and the head split
     (``project``), so each matmul reads its matrix where the stack lies.
+    A looped model (``num_loops > 1``) runs the layer scan once a PASS over
+    the same weight stacks, inside the one program (scope ``loop_pass`` around
+    a pass's layers, ``pass_norm`` around the final norm between two passes):
+    the weights' index is the scan's own ``l``, the pools' index
+    ``t * num_layers + l`` runs on through the pools' ``L = num_loops *
+    num_layers`` cache layers (the slab form's carried counter; the packed
+    form's offset a pass), so each pass writes and attends to its own keys and
+    values and the whole pools ride through every pass's scan, never a pass's
+    slice of them. The branch is Python's, taken when the step is built: a
+    model with one pass traces the one scan it always did.
     Returns ``(x, new_k, new_v, moe_counts, packed)``: ``x`` is the slab
     ``[B, T, H]`` and ``packed`` None, or ``x`` is the packed ``[NP, H]`` and
     ``packed`` says where its rows belong."""
@@ -929,6 +981,7 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
     dtype = k_pages.dtype
     scale = _softmax_scale(cfg, cfg.head_dim)
     tile = token_tile(cfg)
+    loops = getattr(cfg, "num_loops", 1)  # a Python branch where the step is built: one pass traces what it always did
 
     def embed(tokens, positions):
         x = params["embed"]["tokens"].astype(dtype)[tokens]
@@ -973,10 +1026,14 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
             )
             return (x, kp, vp, layer + 1), moe_counts
 
-        (x, new_k, new_v, _), moe_counts = jax.lax.scan(
-            layer_step, (x, k_pages, v_pages, jnp.int32(0)), layers
-        )
-        return x, new_k, new_v, moe_counts, None
+        carry = (x, k_pages, v_pages, jnp.int32(0))
+        if loops == 1:
+            (x, new_k, new_v, _), moe_counts = jax.lax.scan(layer_step, carry, layers)
+            return x, new_k, new_v, moe_counts, None
+        # a looped stack: the scan again over the SAME stacks a pass, the
+        # carried index running on through the pools' loops x L cache layers
+        x, new_k, new_v, _ = _looped_passes(cfg, params, carry, lambda t, carry: jax.lax.scan(layer_step, carry, layers)[0])
+        return x, new_k, new_v, None, None
 
     packed = _pack_window(ragged_q_lens, B, T, tile)
     positions = jnp.take(positions_b.reshape(-1), packed.slot, mode="clip")
@@ -984,8 +1041,10 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
     layers, expert_stacks = _split_expert_stacks(cfg, params["layers"])
     NH, NKV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    def layer_step(carry, layer):
+    def layer_step(carry, layer, first_cache_layer=0):
         x, kp, vp = carry
+        # pass t's layer l of a looped stack owns cache layer t * L + l
+        cache_layer = layer + first_cache_layer if first_cache_layer else layer
 
         def weights(start):
             # tied to the tile, or the compiler hoists the slices out of the tile loop
@@ -1004,7 +1063,7 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
         with jax.named_scope("attention"):
             qkv = packed.tiles(before, tuple(jnp.zeros((x.shape[0], n * D), dtype) for n in (NH, NKV, NKV)))
             attn, kp, vp = attend(
-                *(packed.expand(a).reshape(B, T, n, D) for a, n in zip(qkv, (NH, NKV, NKV))), kp, vp, layer
+                *(packed.expand(a).reshape(B, T, n, D) for a, n in zip(qkv, (NH, NKV, NKV))), kp, vp, cache_layer
             )
             attn = attn.reshape(B * T, NH, D)
 
@@ -1022,10 +1081,15 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
         x, moe_counts = packed.tiles(after, (x, jnp.zeros((cfg.num_experts,), jnp.int32) if "moe" in layers else None))
         return (x, kp, vp), moe_counts
 
-    (x, new_k, new_v), moe_counts = jax.lax.scan(
-        layer_step, (x, k_pages, v_pages), jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    carry, steps = (x, k_pages, v_pages), jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    if loops == 1:
+        (x, new_k, new_v), moe_counts = jax.lax.scan(layer_step, carry, steps)
+        return x, new_k, new_v, moe_counts, packed
+    x, new_k, new_v = _looped_passes(
+        cfg, params, carry,
+        lambda t, carry: jax.lax.scan(functools.partial(layer_step, first_cache_layer=t * cfg.num_layers), carry, steps)[0],
     )
-    return x, new_k, new_v, moe_counts, packed
+    return x, new_k, new_v, None, packed
 
 
 def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_b,
@@ -1045,10 +1109,13 @@ def _paged_forward(cfg, params, tokens, k_pages, v_pages, page_table, positions_
     projections all-reduce through the context, and the returned logits may
     be the local vocab slice.
 
-    The stacked pools ``[L, NP, NKV, P, D]`` ride in the layer scan's CARRY
-    beside the layer index, and every write and read reaches its layer
-    through that index: the pools are never sliced into per-layer ``xs`` nor
-    restacked from ``ys``, so the donated buffers are the ones returned. On
+    The stacked pools ``[L, NP, NKV, P, D]`` (``L`` the model's CACHE layers,
+    ``models/config.py::cache_layers``: as many as layers of weights but in a
+    looped model, which keeps ``num_loops`` of them for every layer of
+    weights) ride in the layer scan's CARRY beside the layer index, and every
+    write and read reaches its layer through that index: the pools are never
+    sliced into per-layer ``xs`` nor restacked from ``ys``, nor sliced a
+    pass, so the donated buffers are the ones returned. On
     the Pallas path the fused kernel is the only operation applied to them
     (aliased in → out), which also leaves their layout to nobody but the
     kernel.

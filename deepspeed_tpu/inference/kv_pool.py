@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.config import TransformerConfig
+from deepspeed_tpu.models.config import TransformerConfig, cache_layers
 
 TRASH_PAGE = 0
 
@@ -114,9 +114,13 @@ def _copy_page_fn(k_pages, sharding=None):
 class PagedKVCache(NamedTuple):
     """Device page pool, one stacked array per K and V.
 
-    Layout ``[L, num_pages, NKV, page_size, D]``: the layer axis scans, and
-    each layer slice is exactly the ``[NP, NKV, P, D]`` pool the paged
-    attention kernels take. Where ``heads_per_group`` is ``f > 1`` the layout is
+    Layout ``[L, num_pages, NKV, page_size, D]``, ``L`` the model's CACHE
+    layers (``models/config.py::cache_layers``: a layer of weights owns one in
+    every pass of a looped stack, ``num_loops * num_layers``, pass ``t``'s
+    layer ``l`` at ``t * num_layers + l``; every configuration with one pass
+    has as many as layers of weights): the serving step reaches a layer
+    through its index, and each layer slice is exactly the ``[NP, NKV, P, D]``
+    pool the paged attention kernels take. Where ``heads_per_group`` is ``f > 1`` the layout is
     ``[L, num_pages, NKV / f, page_size, f * D]``: KV head ``j`` lives in group
     ``j // f`` at lanes ``(j % f) * D ..``, which the attention entry sees from
     the shapes (``ops/transformer/paged_attention.py``); a page's bytes, the
@@ -149,15 +153,16 @@ def init_paged_cache(
     cfg: TransformerConfig, num_pages: int, page_size: int, dtype=None,
     sharding=None,
 ) -> PagedKVCache:
-    """Allocate the device page pools. ``sharding`` (tensor-parallel
-    serving) places them kv-head-sharded across the mesh — the page
-    CONTENTS shard on axis 2 while the host-side tables stay replicated,
-    so per-chip KV HBM is ``hbm_bytes() / tp``."""
+    """Allocate the device page pools ``[L, num_pages, NKV, page_size, D]``,
+    ``L = cache_layers(cfg)``: a looped model's ``num_loops * num_layers``, a
+    multi-kind model's softmax layers (none, zero-sized arrays, where every
+    paged layer is a latent one), ``num_layers`` otherwise. ``sharding``
+    (tensor-parallel serving) places them kv-head-sharded across the mesh —
+    the page CONTENTS shard on axis 2 while the host-side tables stay
+    replicated, so per-chip KV HBM is ``hbm_bytes() / tp``."""
     if dtype is None:
         dtype = _DTYPES[cfg.dtype]
-    # a model with layers of more than one kind keeps the K and V of its softmax layers
-    # only here (none, zero-sized arrays, where every paged layer is a latent one)
-    layers = cfg.layers_of("softmax") if getattr(cfg, "layer_types", None) else cfg.num_layers
+    layers = cache_layers(cfg)
     v_head_dim = getattr(cfg, "v_head_dim", None) or cfg.head_dim
     # the heads a shard holds decide: a group never spans two chips
     tp = 1 if sharding is None else sharding.mesh.shape[sharding.spec[2]]
@@ -326,6 +331,7 @@ class PagePool:
             raise ValueError("need page_size >= 1 and num_pages >= 2 (page 0 is reserved)")
         self.page_size = int(page_size)
         self.max_slots = int(max_slots)
+        self.weight_layers = int(cfg.num_layers)  # the memory report says them apart from the cache layers
         self.max_seq_len = int(max_seq_len or cfg.max_seq_len)
         self.max_pages_per_slot = -(-self.max_seq_len // self.page_size)
         # tensor-parallel serving: the page contents shard over the kv-head
@@ -496,6 +502,10 @@ class PagePool:
         return {
             **state,
             "kv_total_bytes": self.cache.hbm_bytes(),
+            # a layer of weights owns a cache layer in every pass of a looped stack
+            "cache_layers": self.cache.k_pages.shape[0],
+            "weight_layers": self.weight_layers,
+            "kv_bytes_per_token": self.cache.bytes_per_token,
             "kv_bytes_per_chip": max(per_device.values()),
             "kv_devices": len(per_device),
             "live_kv_bytes": self.live_hbm_bytes(),

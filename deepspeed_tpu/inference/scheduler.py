@@ -1689,6 +1689,10 @@ class PagedServer:
         # tensor-parallel serving: the sharding degree this server runs at
         # (1 = single-chip) and whether the row-parallel all-reduces are
         # EQuARX-quantized — fleet observability keys on these
+        # the model as served: passes over the layer stack a token (1 but for a
+        # looped model) and the layers of K and V cache a token keeps
+        s["loop_passes"] = getattr(self.cfg, "num_loops", 1)
+        s["cache_layers"] = self.pool.cache.k_pages.shape[0]
         s["tp_degree"] = self.tp.degree if self.tp is not None else 1
         s["tp_quantized_allreduce"] = (
             bool(self.tp.quantized_allreduce) if self.tp is not None else False

@@ -52,6 +52,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.compression.int8 import QuantizedTensor, qmatmul, slice_out_channels
+from deepspeed_tpu.models.config import refuse_looped
 from deepspeed_tpu.parallel.mesh import mesh_fingerprint
 
 # serving-layout classification (models/transformer.py param names; the
@@ -166,6 +167,11 @@ class TPServing:
 
     # --- config & weights ------------------------------------------------
     def validate_cfg(self, cfg) -> None:
+        refuse_looped(
+            cfg, "tensor-parallel serving",
+            "no mesh run has compared a step whose head-sharded pools hold num_loops x num_layers cache layers "
+            "with the one-chip step, and partition_specs names no rule for the sandwich norms' scales or the exit gate",
+        )
         if getattr(cfg, "qk_norm", None) is not None:
             raise NotImplementedError(
                 "tensor-parallel serving does not support qk_norm: the norm runs over the "

@@ -45,6 +45,23 @@ class TransformerConfig:
     # q projection [NH*D] and the whole k projection [NKV*D], before the split
     # into heads and RoPE (the OLMoE family), so the cached k is the normed one
     qk_norm: Optional[str] = None
+    # a looped (universal-transformer) stack: the SAME ``num_layers`` layers of
+    # weights run ``num_loops`` times a token, the final norm after every pass
+    # (its output is the next pass's input), each pass attending to the keys
+    # and values that this pass itself made: ``cache_layers`` below counts
+    # ``num_loops * num_layers`` layers of cache over ``num_layers`` of weights
+    num_loops: int = 1
+    # sandwich norms: a second norm a sublayer, on the sublayer's OUTPUT before
+    # the residual add (leaves ``attn_post_norm_scale`` / ``mlp_post_norm_scale``)
+    post_sublayer_norm: bool = False
+    # a looped model's exit gate: ``sigmoid(h w + b)`` of every pass's normed
+    # output gives the distribution over passes a token could leave after
+    # (``TransformerLM.apply(..., exit_distribution=True)``). A token leaves
+    # at the first pass whose cumulated share reaches ``early_exit_threshold``:
+    # at 1.0, the only value served, that is the last pass for every token and
+    # the gate moves no logit
+    exit_gate: bool = False
+    early_exit_threshold: float = 1.0
     dtype: str = "bfloat16"  # computation dtype for activations
 
     # sparse embedding gradients (reference engine.py:2398: DP-reduce the
@@ -80,6 +97,23 @@ class TransformerConfig:
                 f"unknown sequence_parallel_mode {self.sequence_parallel_mode!r}; "
                 "expected 'ulysses' or 'ring'"
             )
+        if self.num_loops < 1:
+            raise ValueError(f"num_loops counts the passes over the layer stack: at least 1, got {self.num_loops}")
+        if self.early_exit_threshold < 1.0:
+            raise NotImplementedError(
+                f"early_exit_threshold={self.early_exit_threshold} < 1 lets rows of one step leave the loop after "
+                "different passes: the scheduler's step has one q_len a row and no count of passes a row, "
+                "and a row that left early has no keys in the later passes' cache layers for its successors "
+                "to attend to; only 1.0 (every token runs every pass) is served"
+            )
+        if (self.num_loops > 1 or self.post_sublayer_norm) and (not self.prenorm or self.parallel_residual):
+            raise ValueError(
+                "num_loops > 1 and post_sublayer_norm describe a pre-norm sequential layer (the final norm runs "
+                "between passes; the second norm sits on a sublayer's output before its residual add): "
+                "prenorm=True and parallel_residual=False"
+            )
+        if self.exit_gate and self.num_loops < 2:
+            raise ValueError("exit_gate is a looped model's (num_loops > 1): with one pass there is nothing to leave")
         if self.shared_parallel_norm and not self.parallel_residual:
             raise ValueError("shared_parallel_norm requires parallel_residual=True")
         if self.parallel_residual and not self.prenorm:
@@ -95,6 +129,28 @@ class TransformerConfig:
                 "LM head contributes a dense gradient to the same table, so "
                 "there is nothing sparse to reduce"
             )
+
+
+def cache_layers(cfg) -> int:
+    """Layers of K and V cache a token keeps: the ONE place that counts them,
+    read by the page pool (``kv_pool.init_paged_cache``, and through its
+    shapes ``bytes_per_token`` / ``memory_report()``), the dense workspace
+    (``decode.init_cache``) and the benchmark's adapters. A uniform model
+    keeps a layer of cache for every layer of weights in every pass over the
+    stack (``num_loops``: pass ``t``'s layer ``l`` owns cache layer
+    ``t * num_layers + l``); a model with layers of more than one kind keeps
+    keys and values a head in its softmax layers only."""
+    if getattr(cfg, "layer_types", None):
+        return cfg.layers_of("softmax")
+    return cfg.num_layers * getattr(cfg, "num_loops", 1)
+
+
+def refuse_looped(cfg, what: str, missing: str) -> None:
+    """``what`` runs the layer stack once a token: a looped model
+    (``num_loops > 1``) is refused where ``what`` is built, with the missing
+    piece named, and never silently run for one pass."""
+    if getattr(cfg, "num_loops", 1) > 1:
+        raise NotImplementedError(f"{what} does not support a looped model (num_loops={cfg.num_loops}): {missing}")
 
 
 def has_state_layers(cfg) -> bool:

@@ -54,6 +54,12 @@ class MoETransformerConfig(TransformerConfig):
 
     def __post_init__(self):
         super().__post_init__()
+        if self.num_loops > 1 or self.post_sublayer_norm:
+            raise NotImplementedError(
+                "num_loops > 1 and post_sublayer_norm are the dense TransformerLM's: a routed model's serving step "
+                "hands back routing counts a layer of WEIGHTS ([num_layers, num_experts], decode.py::_moe_stat_rows) "
+                "and its expert stacks are reached through a layer offset; neither knows of a pass"
+            )
         if self.expert_intermediate_size is None:
             self.expert_intermediate_size = self.intermediate_size
         if self.moe_top_k > 2 and self.moe_drop_tokens:

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.config import TransformerConfig
+from deepspeed_tpu.models.config import TransformerConfig, refuse_looped
 from deepspeed_tpu.runtime.module import DSModule
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
@@ -200,6 +200,11 @@ class TransformerLM(DSModule):
             layer["b_out"] = jnp.zeros((L, H))
             if cfg.activation not in ("swiglu", "geglu"):
                 layer["b_in"] = jnp.zeros((L, I))
+        if cfg.post_sublayer_norm:
+            for name in ("attn_post_norm", "mlp_post_norm"):
+                layer[name + "_scale"] = jnp.ones((L, H))
+                if cfg.norm == "layernorm":
+                    layer[name + "_bias"] = jnp.zeros((L, H))
         params["layers"] = layer
 
         if cfg.prenorm:  # post-LN nets end inside the last layer's norm
@@ -210,6 +215,8 @@ class TransformerLM(DSModule):
             params["lm_head"] = dense(next(k), (H, cfg.vocab_size))
             if cfg.lm_head_bias:
                 params["lm_head_bias"] = jnp.zeros((cfg.vocab_size,))
+        if cfg.exit_gate:
+            params["exit_gate"] = {"w": dense(next(k), (H,)), "b": jnp.zeros(())}
         return params
 
     # --- TP sharding rules ----------------------------------------------
@@ -442,6 +449,8 @@ class TransformerLM(DSModule):
             if train and cfg.hidden_dropout > 0 and r_hid is not None:
                 keep = jax.random.bernoulli(r_hid, 1 - cfg.hidden_dropout, attn.shape)
                 attn = attn * keep / (1 - cfg.hidden_dropout)
+            if cfg.post_sublayer_norm:
+                attn = _norm(attn, p["attn_post_norm_scale"], p.get("attn_post_norm_bias"), cfg.norm, cfg.norm_eps)
         if cfg.parallel_residual:
             # GPT-J/NeoX: both branches read x — attn already consumed
             # norm1(x) as h; the mlp branch reads the SAME h (GPT-J shared
@@ -462,6 +471,8 @@ class TransformerLM(DSModule):
                 x = _norm(x + attn, p["attn_norm_scale"], p.get("attn_norm_bias"), cfg.norm, cfg.norm_eps)
                 h = x
             out, aux = self._mlp(p, h, r_mlp, train)
+            if cfg.post_sublayer_norm:
+                out = _norm(out, p["mlp_post_norm_scale"], p.get("mlp_post_norm_bias"), cfg.norm, cfg.norm_eps)
             if cfg.prenorm:
                 return x + out, aux
             return _norm(x + out, p["mlp_norm_scale"], p.get("mlp_norm_bias"), cfg.norm, cfg.norm_eps), aux
@@ -529,8 +540,10 @@ class TransformerLM(DSModule):
         table = params["embed"]["tokens"].astype(self.dtype)
         return sparse_embedding_lookup(table, tokens, data_axes)
 
-    def _forward(self, params, tokens, rngs, train, pld_theta=None, ltd_idx=None):
+    def _forward(self, params, tokens, rngs, train, pld_theta=None, ltd_idx=None, exit_distribution=False):
         cfg = self.config
+        if exit_distribution and not cfg.exit_gate:
+            raise ValueError("exit_distribution needs a model with an exit gate (exit_gate=True, num_loops > 1)")
         tokens = jnp.asarray(tokens)
         B, T = tokens.shape
         # the step's regions are named scopes (``embed``, ``layers`` with
@@ -567,6 +580,11 @@ class TransformerLM(DSModule):
         if pld_active and ltd_active:
             raise ValueError(
                 "progressive_layer_drop and random-LTD cannot be combined"
+            )
+        if pld_active or ltd_active:
+            refuse_looped(
+                cfg, "progressive layer drop / random-LTD",
+                "their schedules count a layer of weights once a token (a keep probability by depth, the first and last layer always full)",
             )
         if ltd_active:
             n_ltd = int(ltd_idx.shape[0])
@@ -651,8 +669,17 @@ class TransformerLM(DSModule):
             ltd_body = jax.checkpoint(ltd_body, policy=policy, prevent_cse=False)
 
         aux_total = jnp.zeros((), jnp.float32)
+        exit_p = None
         with jax.named_scope("layers"):
-            if ltd_active:
+            if cfg.num_loops > 1:
+                if overlap_plan is not None and overlap_plan.prefetch_enabled:
+                    refuse_looped(
+                        cfg, "the ZeRO-3 layer pipeline (_pipelined_layer_scan)",
+                        "its prologue and lookahead gather layers 0..depth-1 once and clamp at the last layer, "
+                        "so a second pass would start on the first pass's tail buffers",
+                    )
+                x, aux_total, exit_p = self._looped_layers(params, x, base_rng, body, exit_distribution)
+            elif ltd_active:
                 # layer 0 full → LTD layers 1..1+n_ltd on subsets → rest full
                 def run_full(x, rng, aux_total, lo, hi):
                     if hi <= lo:
@@ -712,7 +739,40 @@ class TransformerLM(DSModule):
                 logits = x @ params["lm_head"].astype(self.dtype)
                 if cfg.lm_head_bias:
                     logits = logits + params["lm_head_bias"].astype(logits.dtype)
+        if exit_distribution:
+            return logits, aux_total, exit_p
         return logits, aux_total
+
+    def _looped_layers(self, params, x, rng, body, exit_distribution):
+        """``num_loops`` passes of the SAME layers (scope ``loop_pass``), the
+        final norm between passes (``pass_norm``; the last pass's is the
+        head's own). With ``exit_distribution`` the gate reads every normed
+        pass output but the last: ``p_t = lambda_t * prod_{s<t}(1 -
+        lambda_s)``, the last pass the remainder, ``[B, T, num_loops]``
+        float32. Nothing of the gate is traced otherwise."""
+        cfg = self.config
+        aux_total = jnp.zeros((), jnp.float32)
+        stay = jnp.ones(x.shape[:2], jnp.float32)  # the share of a token still in the loop
+        shares = []
+        for t in range(cfg.num_loops):
+            with jax.named_scope("loop_pass"):
+                if cfg.scan_layers:
+                    (x, rng), aux = jax.lax.scan(body, (x, rng), params["layers"])
+                    aux_total = aux_total + jnp.sum(aux)
+                else:
+                    for i in range(cfg.num_layers):
+                        (x, rng), aux = body((x, rng), self._layer_params(params, i))
+                        aux_total = aux_total + aux
+            if t == cfg.num_loops - 1:
+                break
+            with jax.named_scope("pass_norm"):
+                x = _norm(x, params["final_norm_scale"], params.get("final_norm_bias"), cfg.norm, cfg.norm_eps)
+            if exit_distribution:
+                gate = params["exit_gate"]
+                lam = jax.nn.sigmoid(x.astype(jnp.float32) @ gate["w"].astype(jnp.float32) + gate["b"].astype(jnp.float32))
+                shares.append(lam * stay)
+                stay = stay * (1.0 - lam)
+        return x, aux_total, jnp.stack(shares + [stay], axis=-1) if exit_distribution else None
 
     def _scan_layer_step(self, x, per_layer, positions, rng, train):
         """One non-PLD scanned layer iteration: rng split, layer, activation
@@ -792,6 +852,10 @@ class TransformerLM(DSModule):
         unstacked per-layer tree. MoE aux losses are not routed through this
         path (``MoETransformerLM.stream_fns`` raises)."""
         cfg = self.config
+        refuse_looped(
+            cfg, "layer streaming (stream_fns: ZeRO-Infinity param offload, the flops profiler's walk)",
+            "embed -> each layer once -> head is its whole contract; it has no pass to repeat nor a norm between passes",
+        )
 
         def embed_fwd(resident, tokens):
             tokens = jnp.asarray(tokens)
@@ -837,8 +901,15 @@ class TransformerLM(DSModule):
 
         return embed_fwd, layer_fwd, head_loss
 
-    def apply(self, params, batch, *, rngs=None, train: bool = True, pld_theta=None, ltd_idx=None):
+    def apply(self, params, batch, *, rngs=None, train: bool = True, pld_theta=None, ltd_idx=None,
+              exit_distribution: bool = False):
+        """``exit_distribution`` (a looped model with an exit gate, tokens
+        alone): returns ``(logits, p)``, ``p`` ``[B, T, num_loops]`` the
+        share of each token that would leave after each pass."""
         tokens, labels = _split_batch(batch)
+        if exit_distribution:
+            logits, _, exit_p = self._forward(params, tokens, rngs, train, exit_distribution=True)
+            return logits, exit_p
         logits, aux = self._forward(
             params, tokens, rngs, train, pld_theta=pld_theta, ltd_idx=ltd_idx
         )

@@ -20,8 +20,10 @@ benchmark holds:
   the latent kernel in a model with latent layers, and in no other;
 * no other custom call opens with more than one ``s32`` operand;
 * the calls a step: one a layer through the layer scan of a uniform model
-  (``calls_per_step``), one a layer kind a period and leading layer in a
-  model of several kinds, twice that in its wide program.
+  (``calls_per_step``; a looped model's stack is scanned once a pass: one
+  call in each pass's scan, ``cache_layers`` a step), one a layer kind a
+  period and leading layer in a model of several kinds, twice that in its
+  wide program.
 """
 
 import importlib
@@ -39,6 +41,7 @@ from benchmark import files
 from benchmark.kernels import ragged_paged_attention, windowed_paged_attention
 from deepspeed_tpu.inference import decode, hybrid_decode
 from deepspeed_tpu.inference.kv_pool import StateStore, heads_per_group, key_lanes, page_shapes, window_ring_pages
+from deepspeed_tpu.models.config import cache_layers
 
 SPEC = json.loads((pathlib.Path(files.ROOT) / "BENCHMARK.json").read_text())
 CONFIGS = [c["name"] for c in SPEC["configs"]]
@@ -83,9 +86,8 @@ def _lowered_step(v5e, monkeypatch, name, width):
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32)))
     params = jax.tree_util.tree_map(lambda a: on(a.shape), params)
     extra = ()
-    layers = cfg.num_layers
+    layers = cache_layers(cfg)  # a looped model's num_loops x num_layers, a multi-kind model's softmax layers
     if getattr(cfg, "layer_types", None):
-        layers = cfg.layers_of("softmax")
         shapes = hybrid_decode.state_shapes(cfg, rows)
         rings = (None, None)
         if cfg.layers_of("window"):
@@ -125,7 +127,10 @@ def _kernel_calls(text):
 
 def _expected_ragged_calls(cfg, width):
     if not getattr(cfg, "layer_types", None):
-        return 1  # the layer scan's body: num_layers a step (ragged_paged_attention.calls_per_step)
+        # the layer scan's body: num_layers a step (ragged_paged_attention.calls_per_step). A looped model scans its stack a
+        # pass: the narrow program's passes are ONE traced body scanned num_loops times (the carried index runs on), the wide
+        # program's have a body each (a pass's first cache layer is the body's own constant)
+        return 1 if width == 1 else cfg.num_loops
     kinds = list(cfg.layer_types[: cfg.leading_dense_layers]) + list(cfg.period)
     return sum(k in ("softmax", "window") for k in kinds) * (1 if width == 1 else 2)
 
@@ -151,7 +156,7 @@ def test_attention_kernel_calls_are_what_the_benchmarks_readers_expect(v5e, monk
             assert leading <= 1, f"{kernel} opens with {leading} s32 operands: the ragged kernel's readers would count it"
     assert len(ragged) == _expected_ragged_calls(cfg, width), (len(ragged), [k for k, _ in calls])
     if not getattr(cfg, "layer_types", None):
-        assert ragged_paged_attention.calls_per_step(cfg.num_layers) == {"ragged": cfg.num_layers * len(ragged)}
+        assert ragged_paged_attention.calls_per_step(cache_layers(cfg)) == {"ragged": cfg.num_layers * cfg.num_loops}
     if latent_layers:
         kinds = list(cfg.layer_types[: cfg.leading_dense_layers]) + list(cfg.period)
         assert sum(k == "latent_paged_attention" for k, _ in calls) == kinds.count("latent") * (1 if width == 1 else 2)

@@ -1098,6 +1098,69 @@ def test_granite_hybrid_step_fits_whole_with_its_state_in_place(v5e, monkeypatch
     assert re.search(rf"s32\[{rows},{width + 1}\]", text) and not re.search(rf"s32\[{rows + decode.MOE_STAT_ROWS},{width + 1}\]", text)
 
 
+_OURO_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/ouro-2.6b.json"
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_ouro_looped_step_fits_whole_with_192_cache_layers_in_place(v5e, monkeypatch, width):
+    """``build_ragged_step`` at the Ouro cell's shapes (8 rows, 73 pages of 64,
+    the published heads of 128, all 48 layers run four times): the pools are
+    ``[192, 73, 16, 64, 128]`` (``cache_layers``), donated and aliased to the
+    outputs; nothing but parameters, plumbing and the ragged kernel has a
+    result of a pool's shape, of a PASS's 48 layers of it or of one layer's
+    pages (a slice handed to a pass, a carry a loop does not alias, would be
+    3.7 GB or 0.9 GB a step); no layer's matrix and no weight stack is
+    written out on the way to its matmul, in any of the four passes; the
+    program holds one kernel call a pass (48 executions each: 192 a step);
+    and weights + pools + the program's temporaries fit the chip."""
+    from deepspeed_tpu.models.config import cache_layers
+
+    monkeypatch.setattr(sys.modules["deepspeed_tpu.ops.transformer.decode_attention"], "on_tpu", lambda: True)
+    conf = json.loads(_OURO_CELL.read_text())
+    paged = conf["engine"]["init_inference"]["paged_kv"]
+    cfg = TransformerConfig(**conf["model"]["kwargs"])
+    rows, page = paged["max_slots"], paged["page_size"]
+    maxp = paged["max_seq_len"] // page
+    n_pages = rows * maxp + 1
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32)))
+    params = jax.tree_util.tree_map(lambda a: on_v5e(a.shape, BF16), params)
+    pool = on_v5e((cache_layers(cfg), n_pages, cfg.num_kv_heads, page, cfg.head_dim), BF16)
+    assert pool.shape == (192, 73, 16, 64, 128)
+    step = decode.build_ragged_step(cfg, rows, width, page, attn_impl="pallas")
+    compiled = step.lower(
+        params, on_v5e((rows, width), I32), pool, pool, on_v5e((rows, maxp), I32), on_v5e((rows,), I32), on_v5e((rows,), I32)
+    ).compile()
+    decode._paged_program_cache.clear()
+    text = compiled.as_text()
+
+    # after the params and the tokens; the exit gate's two leaves are no operand: the timed path holds no gate arithmetic
+    assert "exit_gate" in params
+    first_pool = len(jax.tree_util.tree_leaves(params)) - 2 + 1
+    assert {first_pool, first_pool + 1} <= parse_input_output_aliases(text)
+    pages_of = re.compile(rf"\[([\d,]+),{cfg.num_kv_heads},{page},{cfg.head_dim}\]")
+    strangers = {
+        name: f"{opcode} {result}" for name, opcode, result in _executed(text)
+        if opcode not in _PLUMBING and not (opcode == "custom-call" and name.startswith("ragged_paged_attention"))
+        and any(np.prod([int(d) for d in dims.split(",")]) >= n_pages for dims in pages_of.findall(result))
+    }
+    assert not strangers, f"pool-shaped results outside the kernel: {strangers}"
+    kernels = [name for name, opcode, _ in _executed(text) if opcode == "custom-call" and name.startswith("ragged_paged_attention")]
+    assert len(kernels) == cfg.num_loops  # one in each pass's scan body
+    I = cfg.intermediate_size
+    matrices = _attention_matrices(cfg) | {(cfg.hidden_size, I), (I, cfg.hidden_size)}
+    assert not _mixer_matrices_written_out(text, matrices)
+    memory = compiled.memory_analysis()
+    weights = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params)) * 2
+    pools = 2 * int(np.prod(pool.shape)) * 2
+    assert (round(weights / 1e9, 2), round(pools / 1e9, 2)) == (5.34, 7.35)
+    assert memory.temp_size_in_bytes < 0.6e9 and weights + pools + memory.temp_size_in_bytes < 15.75 * 2**30
+    print(f"ouro w{width}: temp {memory.temp_size_in_bytes:,} B, resident {weights + pools:,} B")
+
+
 _XL_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/gpt2-xl.json"
 
 
