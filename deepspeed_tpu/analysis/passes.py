@@ -663,7 +663,7 @@ def overlap_pass(art: ProgramArtifact, config: Optional[Dict[str, Any]] = None) 
     * sync collectives (the CPU mesh, unscheduled backends) are **hidden**
       when the computation contains real compute with no dependency path to
       or from the collective — independent work the scheduler is free to
-      overlap (the feasibility the pipelined gather/bucketed reduce create).
+      overlap (the feasibility the pipelined gather/in-loop reduction create).
 
     ``overlap_verified`` means no collective inside a while-loop body (the
     scanned layer stack / microbatch loop — the hot path the pipeline owns)

@@ -595,11 +595,11 @@ class TransformerLM(DSModule):
                 )
 
         # comm-overlap plan (runtime/zero/overlap.py): set by the engine
-        # around its training-loss traces. reduce_grads pins each layer's
-        # cotangent to its scattered layout inside the backward scan
-        # (bucketed reduce-scatter); the prefetch pipeline below restructures
-        # the whole scan. Both are value-preserving, so every path stays
-        # bit-identical to the unpipelined program.
+        # around its training-loss traces. reduce_grads forces each layer's
+        # gradient reduction inside the backward scan, every leaf where it
+        # lies; the prefetch pipeline below restructures the whole scan.
+        # Both are value-preserving, so every path stays bit-identical to
+        # the unpipelined program.
         from deepspeed_tpu.runtime.zero.overlap import active_plan
 
         overlap_plan = active_plan()

@@ -20,63 +20,15 @@ for bandwidth; tests bound the error against the exact collective.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 
 from deepspeed_tpu.ops.quantizer import dequantize, quantize
-
-
-def row_coalesced_layout(
-    shapes: Sequence[Sequence[int]], world: int
-) -> List[Tuple[int, int]]:
-    """Column layout of the ``[world, C]`` coalesced buffer: for each input
-    (whose dim 0 is the world-divisible shard dim), ``(col_offset, width)``.
-    Row k of the buffer holds every input's k-th shard chunk back-to-back,
-    so a single dim-0 collective on the buffer lands each input directly in
-    its own per-leaf scattered layout — no inter-device reshard afterwards.
-    Shared by the overlap plan's bucketed grad reduce-scatter
-    (``runtime/zero/overlap.py``) and the coalesced collectives below."""
-    layout = []
-    off = 0
-    for shape in shapes:
-        n = int(np.prod(shape)) if len(shape) else 1
-        width = -(-n // world)  # ceil: non-divisible inputs pad to a full chunk
-        layout.append((off, width))
-        off += width
-    return layout
-
-
-def pack_row_coalesced(tensors: Sequence[jnp.ndarray], world: int) -> jnp.ndarray:
-    """Concatenate tensors (shard dim leading) into one ``[world, C]``
-    buffer per :func:`row_coalesced_layout`. Pure data movement."""
-    cols = []
-    for t in tensors:
-        flat = t.reshape(-1)
-        pad = (-flat.shape[0]) % world
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        cols.append(flat.reshape(world, -1))
-    return jnp.concatenate(cols, axis=1)
-
-
-def unpack_row_coalesced(
-    buf: jnp.ndarray, shapes: Sequence[Sequence[int]], world: int
-) -> List[jnp.ndarray]:
-    """Inverse of :func:`pack_row_coalesced`: split the ``[world, C]``
-    buffer back into tensors of ``shapes`` (shard dim leading)."""
-    layout = row_coalesced_layout(shapes, world)
-    out = []
-    for shape, (off, width) in zip(shapes, layout):
-        n = int(np.prod(shape)) if len(shape) else 1
-        flat = buf[:, off : off + width].reshape(-1)[:n]
-        out.append(flat.reshape(tuple(shape)))
-    return out
 
 
 def reduce_scatter_coalesced(

@@ -1093,9 +1093,9 @@ class DeepSpeedEngine:
         # comm-overlap plan (runtime/zero/overlap.py): activated trace-time
         # around every training loss, so the scanned layer stack pipelines
         # its stage-3 param gathers (layer i+1's all-gather issued during
-        # layer i's compute) and reduce-scatters each layer's grads in
-        # reduce_bucket_size buckets inside the backward scan instead of one
-        # tail barrier. Value-preserving by construction — the parity suite
+        # layer i's compute) and reduces each layer's grads inside the
+        # backward scan, every leaf where it lies, instead of one tail
+        # barrier. Value-preserving by construction — the parity suite
         # holds it bit-identical. qwZ/qgZ own their gather/reduce wire
         # formats and stay unpipelined.
         self._overlap_plan = self._build_overlap_plan(qwz=qwz, qgz=qgz)
@@ -1628,17 +1628,20 @@ class DeepSpeedEngine:
             # skipped layers / token-subset segments) — the prefetch
             # pipeline does not run there. Disable it VISIBLY rather than
             # letting prefetch_enabled=True report a pipeline that never
-            # engaged; the bucketed in-scan grad reduction still applies.
+            # engaged; the in-scan grad reduction still applies.
             log_dist(
                 "zero.prefetch_layers is a no-op under progressive_layer_drop/"
                 "random_ltd (the layer loop is theirs); pipelined gather "
-                "disabled, bucketed grad reduce-scatter stays on",
+                "disabled, the in-scan grad reduction stays on",
                 ranks=[0],
             )
             plan.prefetch_enabled = False
             plan.depth = 0
             if not plan.reduce_enabled and not plan.a2a_enabled:
                 plan = None
+        if plan is not None and plan.reduce_enabled:
+            # what the in-loop reduction does with a layer's leaves, said once
+            self.tracer.event("zero.grad_reduce_plan", **plan.reduction_record())
         return plan
 
     def _overlap_compiler_options(self) -> Optional[Dict[str, Any]]:

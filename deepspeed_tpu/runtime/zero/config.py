@@ -36,6 +36,9 @@ class DeepSpeedZeroConfig(DeepSpeedConfigModel):
     stage: ZeroStageEnum = ZeroStageEnum.disabled
     contiguous_gradients: bool = True
     reduce_scatter: bool = True
+    # accepted as the reference's configs carry it; read by nothing since PR
+    # 58: the in-loop reduction (runtime/zero/overlap.py) takes every leaf
+    # alone and the compiler combines the small ones' all-reduces itself
     reduce_bucket_size: int = Field(pp_int(int(5e8)), ge=0)
     allgather_partitions: bool = True
     allgather_bucket_size: int = Field(pp_int(int(5e8)), ge=0)

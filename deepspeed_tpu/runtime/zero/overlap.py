@@ -32,15 +32,20 @@ software pipeline:
   layers to add one — 16 ``select_add`` fusions a layer in GPT-2 XL's
   step, PR 55), and even an instantiated zero sent to the lookahead's index
   would keep that accumulator alive.
-* **Bucketed gradient reduce-scatter** (stage >= 2) — an identity
-  ``custom_vjp`` around the per-layer params whose backward pins each
-  layer's cotangent to its scattered layout *inside* the backward scan,
-  coalescing leaves into ``reduce_bucket_size``-element buckets through
-  the ``[world, chunk]`` row layout of
-  ``runtime/comm/coalesced_collectives.py`` — one reduce-scatter per
-  bucket per layer, issued as backward produces it, instead of one tail
-  barrier over the whole stacked gradient. The packing is pure data
-  movement (transpose + pad + concat), so values are unchanged.
+* **In-loop gradient reduction** (stage >= 2) — an identity
+  ``custom_vjp`` around the per-layer params whose backward forces each
+  layer's cross-batch sum *inside* the backward scan, as backward produces
+  it, instead of one tail barrier over the whole stacked gradient. Every
+  leaf whose only sharding is ZeRO's is reduced ALONE, where it lies: one
+  sharding constraint on the leaf in its own shape. The TPU compiler turns
+  a matrix's into its fused reduce-scatter with the slice to the engine's
+  cut layout behind it, and combines the small leaves' all-reduces (the
+  norms' and biases' vectors, all latency) into one by itself, so the plan
+  builds no bucket: packing a layer's leaves into one ``[world, chunk]``
+  buffer saved no collective (the compiler reduced the pack's pieces, not
+  the pack) and cost a relayout of every element (173 ms of GPT-2 XL's
+  1,078 ms step until PR 58). :meth:`OverlapPlan.reduction_record` says
+  which leaves the plan reduces.
 
 Both transforms are value-preserving by construction; the parity suite
 (tests/unit/runtime/zero/test_overlap.py) enforces bit-identity against
@@ -51,19 +56,14 @@ behind.
 
 from __future__ import annotations
 
+import math
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from deepspeed_tpu.runtime.comm.coalesced_collectives import (
-    pack_row_coalesced,
-    unpack_row_coalesced,
-)
 
 _is_spec = lambda x: isinstance(x, P)  # noqa: E731
 
@@ -91,9 +91,9 @@ class _LeafInfo:
 
     shape: Tuple[int, ...]  # per-layer (unstacked) shape
     gather_spec: P  # per-layer spec with ZeRO axes stripped (the gather target)
-    grad_spec: P  # per-layer grad spec (the scattered reduce target)
-    scatter_dim: int  # dim of grad_spec carrying the ZeRO axes; -1 if none
-    coalescable: bool  # ZeRO axes are the ONLY sharding → row-layout packable
+    # the ZeRO axes are this leaf's ONLY sharding: gathered over ZeRO it is
+    # replicated, so the constraint to gather_spec is its whole reduction
+    zero_only: bool
 
 
 @dataclass
@@ -102,11 +102,9 @@ class OverlapPlan:
 
     mesh: Any
     zero_axes: Tuple[str, ...]
-    zero_world: int
     depth: int  # layers gathered AHEAD of use; 0 = explicit use-point gather
     prefetch_enabled: bool
     reduce_enabled: bool
-    reduce_bucket_elems: int
     leaves: List[_LeafInfo] = field(default_factory=list)
     treedef: Any = None
     # --- a2a stage (expert-parallel MoE dispatch/combine) --------------
@@ -202,27 +200,37 @@ class OverlapPlan:
         _use.defvjp(_fwd, _bwd)
         return _use(mine, buf)
 
-    # --- bucketed in-scan gradient reduction ---------------------------
+    # --- in-scan gradient reduction ------------------------------------
     def reduce_grads(self, per_layer: Any) -> Any:
         """Identity on the per-layer param tree whose backward issues this
-        layer's gradient reduction right where the layer's backward runs —
-        inside the scan, coalesced into ``reduce_bucket_size``-element
-        buckets — instead of one monolithic tail barrier.
+        layer's gradient reduction right where the layer's backward runs,
+        inside the scan, instead of one monolithic tail barrier.
 
         The in-loop constraint materializes the cross-batch sum in the
-        gathered-over-ZeRO layout (ONE collective per bucket; without it
-        XLA emits one per leaf, or defers the whole reduction to the tail).
-        The SCATTERED stage-2/3 layout then lands at the engine's grad
-        shardings — a free local slice once the sum exists. In the
-        pipelined scan the reduced cotangent leaves the loop as the
-        backward scan's ``ys``, and the partitioner gives that stack the
-        engine's cut layout: GPT-2 XL's step compiled for a described
-        ``v5e:2x2`` (PR 55) all-reduces the bucket in the loop body,
-        slices, and writes each chip's quarter of a layer in place
-        (``dynamic-update-slice`` into ``[48,400,1600]``; ``w_in`` and
-        ``w_out`` cut are ``[48,1600,1600]``). It is all-reduce + slice,
-        not a reduce-scatter: pinning the scattered layout here is
-        untried since the stack left the carry."""
+        gathered-over-ZeRO layout; without it XLA defers the whole reduction
+        to the tail. The SCATTERED stage-2/3 layout then lands at the
+        engine's grad shardings: in the pipelined scan the reduced cotangent
+        leaves the loop as the backward scan's ``ys``, and the partitioner
+        gives that stack the engine's cut layout, so what follows the sum
+        is a slice to a chip's quarter. The TPU compiler fuses the two:
+        GPT-2 XL's step compiled for a described ``v5e:2x2`` (PR 58) holds,
+        in the backward loop body, five matrices as its fused
+        reduce-scatter (``%all-reduce-scatter.*`` on the whole
+        ``[6400,1600]`` / ``[1600,1600]`` operand, padded by a tile row: a
+        chip gets ``[1632,1600]`` / ``[416,1600]`` and a halo exchange, a
+        ``collective-permute`` of 96 / 48 rows, turns that into the
+        engine's 1,600 / 400), and ``w_in`` ``[1600,6400]``, cut by
+        columns, in the one all-reduce the compiler combines eight of the
+        ten vectors' into (two biases share another), sliced after it.
+        Three other forms were measured on the chip by the refused PR 57's
+        builder and dropped (PERF.md section 6, PR 58). The leaf's scattered
+        grad spec in the constraint: the same compiled text (the gather's
+        transpose in :meth:`use_buffered` re-imposes the gathered layout).
+        ``w_in`` in a rows-leading view, a sixth reduce-scatter: its
+        weight-gradient matmul loses more than the collective saves (883.3
+        against 880.4 ms a step). The ten vectors in one ``[world, chunk]``
+        bucket: the same two all-reduces and thirteen more reshapes (9,305.3
+        against 9,308.3 tokens/s/chip with every leaf alone)."""
         if not self.reduce_enabled:
             return per_layer
 
@@ -235,84 +243,38 @@ class OverlapPlan:
 
         def _bwd(_, g):
             with jax.named_scope("grad_reduce"):
-                return (self._coalesce_cotangent(g),)
+                return (self._reduce_cotangent(g),)
 
         _reduce_boundary.defvjp(_fwd, _bwd)
         return _reduce_boundary(per_layer)
 
-    def _coalesce_cotangent(self, g: Any) -> Any:
-        """Coalesce one layer's cotangent tree into element-capped buckets
-        via the shared ``[world, chunk]`` row layout and force each
-        bucket's reduction with a single gathered-layout constraint. Pure
-        data movement around one collective per bucket — values untouched.
-        Leaves with TP-mixed sharding stay un-coalesced (their layout is
-        not row-packable with the pure-ZeRO leaves)."""
+    def _reduce_cotangent(self, g: Any) -> Any:
+        """Force the reduction of one layer's cotangent tree: one
+        gathered-layout constraint on every ``zero_only`` leaf, in its own
+        shape. A leaf with TP-mixed sharding is left to the partitioner."""
         flat, treedef = jax.tree_util.tree_flatten(g)
-
-        # group coalescable leaves by dtype (a packed buffer is one dtype),
-        # then split each group into element-capped buckets, preserving
-        # tree order so the bucket layout is deterministic across traces
-        groups: dict = {}
-        for idx, (leaf, info) in enumerate(zip(flat, self.leaves)):
-            if leaf is None:  # symbolic zero cotangent: nothing to reduce
-                continue
-            if info.coalescable:
-                groups.setdefault(str(leaf.dtype), []).append(idx)
-
-        out = list(flat)
-        for idxs in groups.values():
-            for bucket in _split_buckets(
-                idxs, [self.leaves[i] for i in idxs], self.reduce_bucket_elems
-            ):
-                infos = [self.leaves[i] for i in bucket]
-                if len(bucket) == 1:
-                    i, info = bucket[0], infos[0]
-                    out[i] = jax.lax.with_sharding_constraint(
-                        flat[i], NamedSharding(self.mesh, info.gather_spec)
-                    )
-                    continue
-                moved = [
-                    jnp.moveaxis(flat[i], info.scatter_dim, 0)
-                    for i, info in zip(bucket, infos)
-                ]
-                buf = pack_row_coalesced(moved, self.zero_world)
-                # ONE reduction for the whole bucket (coalescable leaves are
-                # pure-ZeRO sharded, so gathered-over-ZeRO == replicated)
-                buf = jax.lax.with_sharding_constraint(
-                    buf, NamedSharding(self.mesh, P(None, None))
-                )
-                parts = unpack_row_coalesced(
-                    buf, [m.shape for m in moved], self.zero_world
-                )
-                for i, info, part in zip(bucket, infos, parts):
-                    out[i] = jnp.moveaxis(part, 0, info.scatter_dim)
+        out = [
+            jax.lax.with_sharding_constraint(t, NamedSharding(self.mesh, info.gather_spec))
+            if info.zero_only
+            else t
+            for t, info in zip(flat, self.leaves)
+        ]
         return jax.tree_util.tree_unflatten(treedef, out)
+
+    def reduction_record(self) -> Dict[str, Any]:
+        """Which of a layer's leaves :meth:`reduce_grads` reduces in the
+        loop, each alone, and which it leaves to the partitioner: the engine
+        emits it once, where it builds the plan (GPT-2 XL's: 16 alone)."""
+        alone = [math.prod(info.shape) for info in self.leaves if info.zero_only]
+        return {
+            "leaves_alone": len(alone),
+            "leaves_left_to_partitioner": len(self.leaves) - len(alone),
+            "alone_elems": alone,
+        }
 
 
 def _entry_axes_nonempty(spec: P) -> bool:
     return any(_entry_axes(e) for e in spec)
-
-
-def _split_buckets(
-    idxs: List[int], infos: List[_LeafInfo], cap_elems: int
-) -> List[List[int]]:
-    """Greedy size-targeted grouping (reference ``reduce_bucket_size``
-    semantics: element count per collective). Every bucket holds >= 1 leaf;
-    an oversized single leaf rides alone."""
-    buckets: List[List[int]] = []
-    cur: List[int] = []
-    cur_elems = 0
-    cap = max(int(cap_elems), 1)
-    for i, info in zip(idxs, infos):
-        n = int(np.prod(info.shape)) if info.shape else 1
-        if cur and cur_elems + n > cap:
-            buckets.append(cur)
-            cur, cur_elems = [], 0
-        cur.append(i)
-        cur_elems += n
-    if cur:
-        buckets.append(cur)
-    return buckets
 
 
 def build_overlap_plan(
@@ -381,16 +343,13 @@ def build_overlap_plan(
         g_entries = list(gspec) + [None] * (len(shape) - len(list(gspec)))
         # per-layer view: drop the scanned L dim (entry 0)
         gather_spec = P(*[_strip_axes(e, drop) for e in p_entries[1:]])
-        grad_spec = P(*g_entries[1:])
-        scatter_dim = -1
-        coalescable = False
-        for d, e in enumerate(g_entries[1:]):
+        zero_only = False
+        for e in g_entries[1:]:
             axes = _entry_axes(e)
             if set(axes) & drop:
-                scatter_dim = d
-                # packable iff the ZeRO axes are this leaf's ONLY effective
-                # sharding — a TP-stacked dim or a second sharded dim would
-                # need its own buffer layout, so it reduces un-coalesced
+                # the ZeRO axes are this leaf's ONLY effective sharding: a
+                # TP-stacked dim or a second sharded dim stays sharded in the
+                # gathered layout, and that leaf is left to the partitioner
                 others = [
                     a
                     for ee in g_entries[1:]
@@ -398,7 +357,7 @@ def build_overlap_plan(
                     if a not in drop and a not in trivial
                 ]
                 effective = tuple(a for a in axes if a not in trivial)
-                coalescable = effective == tuple(zero_axes) and not others
+                zero_only = effective == tuple(zero_axes) and not others
                 break
         # a leaf whose ZeRO sharding landed on the scanned L dim itself
         # yields an already-replicated per-layer slice — nothing to gather
@@ -410,9 +369,7 @@ def build_overlap_plan(
             _LeafInfo(
                 shape=per_shape,
                 gather_spec=gather_spec,
-                grad_spec=grad_spec,
-                scatter_dim=scatter_dim,
-                coalescable=coalescable,
+                zero_only=zero_only,
             )
         )
 
@@ -434,11 +391,9 @@ def build_overlap_plan(
     return OverlapPlan(
         mesh=topo.mesh,
         zero_axes=zero_axes,
-        zero_world=zero_world,
         depth=depth,
         prefetch_enabled=prefetch,
         reduce_enabled=reduce_,
-        reduce_bucket_elems=int(zero_config.reduce_bucket_size) or 1,
         leaves=leaves,
         treedef=treedef,
         a2a_axis="expert" if a2a else None,

@@ -1,7 +1,7 @@
 """Comm/compute overlap for ZeRO training (``runtime/zero/overlap.py``).
 
 The contract under test (ISSUE 5): the pipelined parameter gather and the
-bucketed in-scan gradient reduce-scatter are SCHEDULE transforms — they
+in-scan gradient reduction are SCHEDULE transforms — they
 move where collectives are issued, never what is computed. So:
 
 * parity is ``assert_array_equal`` (bit-identity), not allclose —
@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu as ds
 import deepspeed_tpu.parallel.mesh as mesh_mod
@@ -151,8 +152,8 @@ def test_overlap_parity_bit_identical(stage, gas, precision, eight_devices):
 
 
 def test_depth2_and_reduce_off_still_bit_identical(eight_devices):
-    """Pipeline depth is schedule-only at every depth, and the bucketed
-    reduce-scatter transform is value-preserving on its own."""
+    """Pipeline depth is schedule-only at every depth, and the in-loop
+    reduction is value-preserving on its own."""
     batches = _batches(1, STEPS)
     ref = _engine({"prefetch_layers": 0})
     lref = _train(ref, batches)
@@ -183,7 +184,7 @@ def test_remat_parity_bit_identical(eight_devices):
 def test_pld_disables_prefetch_visibly(eight_devices):
     """PLD owns the layer loop (cond-skipped layers) — the prefetch
     pipeline does not run there. The plan must SAY so (prefetch_enabled
-    False) instead of reporting a pipeline that never engaged; the bucketed
+    False) instead of reporting a pipeline that never engaged; the in-scan
     grad reduction still applies."""
     plan = _plan(_engine(
         {"prefetch_layers": 1},
@@ -207,6 +208,131 @@ def test_explicit_gather_matches_raw_scan_allclose(eight_devices):
     assert eraw._overlap_plan is None
     lraw = _train(eraw, batches)
     np.testing.assert_allclose(np.asarray(l0), np.asarray(lraw), rtol=2e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the in-loop reduction: every leaf alone, where it lies
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_in_loop_reduction_parity_bit_identical_at_every_depth(precision, remat, eight_devices):
+    """With every leaf reduced alone, where it lies, depth 1 and depth 2
+    still equal depth 0 bit for bit, with and without remat: every depth
+    takes the same reduction, only the gather's place moves."""
+    batches = _batches(1, STEPS)
+    e0 = _engine({"prefetch_layers": 0}, precision=precision, remat=remat)
+    l0 = _train(e0, batches)
+    record = e0._overlap_plan.reduction_record()
+    assert record["leaves_alone"] >= 6 and record["leaves_left_to_partitioner"] == 0, record
+    for depth in (1, 2):
+        e = _engine({"prefetch_layers": depth}, precision=precision, remat=remat)
+        l = _train(e, batches)
+        assert e._overlap_plan.depth == depth and e._overlap_plan.reduction_record() == record
+        for a, b in zip(l0, l):
+            np.testing.assert_array_equal(a, b)
+        _assert_states_identical(e0, e)
+
+
+@pytest.mark.parametrize("stage", [2, 3])
+def test_in_loop_reduction_matches_reduce_off(stage, eight_devices):
+    """Where a leaf's sum is forced (alone in the loop, or not at all in
+    the loop: ``reduce_scatter: False``) moves no value: the same partials
+    are summed, and on this mesh in the same order. Stage 2 takes the plain
+    scan body (nothing to gather), stage 3 the pipelined one."""
+    batches = _batches(1, STEPS)
+    on = _engine({"stage": stage, "prefetch_layers": 1})
+    lon = _train(on, batches)
+    assert on._overlap_plan.reduce_enabled and on._overlap_plan.prefetch_enabled == (stage == 3)
+    off = _engine({"stage": stage, "prefetch_layers": 1, "reduce_scatter": False})
+    loff = _train(off, batches)
+    assert off._overlap_plan is None or not off._overlap_plan.reduce_enabled
+    for a, b in zip(lon, loff):
+        np.testing.assert_array_equal(a, b)
+    _assert_states_identical(on, off)
+
+
+_XL_LAYER = {  # GPT-2 XL's sixteen leaves a layer, in tree order: H 1,600, I 6,400
+    "attn_norm_bias": (1600,), "attn_norm_scale": (1600,), "b_in": (6400,), "b_out": (1600,),
+    "bk": (1600,), "bo": (1600,), "bq": (1600,), "bv": (1600,),
+    "mlp_norm_bias": (1600,), "mlp_norm_scale": (1600,),
+    "w_in": (1600, 6400), "w_out": (6400, 1600),
+    "wk": (1600, 1600), "wo": (1600, 1600), "wq": (1600, 1600), "wv": (1600, 1600),
+}
+
+
+def _stack_plan(shapes, param_specs, mesh_cfg, layers=2):
+    """A stage-3 plan for a stack of ``shapes`` a layer whose leaves are cut
+    as ``param_specs`` says (the leading entry is the layer dim's)."""
+    from deepspeed_tpu.parallel.mesh import MeshConfig, initialize_topology
+    from deepspeed_tpu.runtime.zero.config import DeepSpeedZeroConfig
+    from deepspeed_tpu.runtime.zero.overlap import build_overlap_plan
+
+    mesh_mod.reset_topology()
+    topo = initialize_topology(MeshConfig(**mesh_cfg))
+    stacked = {k: jax.ShapeDtypeStruct((layers,) + s, jnp.bfloat16) for k, s in shapes.items()}
+    zero = DeepSpeedZeroConfig(stage=3, overlap_comm=True)
+    return build_overlap_plan(zero, topo, stacked, param_specs, param_specs, layers)
+
+
+def test_reduction_record_of_gpt2_xl_layer(eight_devices):
+    """GPT-2 XL's sixteen leaves a layer are all cut by ZeRO and nothing
+    else, so the plan reduces all sixteen alone, in their own shapes: the
+    six matrices (2.56 M and 10.24 M elements) and the ten vectors."""
+    plan = _stack_plan(_XL_LAYER, {k: P(None, "data") for k in _XL_LAYER}, {"data": 8})
+    assert plan.reduce_enabled and all(info.zero_only for info in plan.leaves)
+    record = plan.reduction_record()
+    assert sorted(record) == ["alone_elems", "leaves_alone", "leaves_left_to_partitioner"]
+    assert (record["leaves_alone"], record["leaves_left_to_partitioner"]) == (16, 0)
+    assert record["alone_elems"] == 2 * [1600] + [6400] + 7 * [1600] + 2 * [10_240_000] + 4 * [2_560_000]
+
+
+def test_tp_mixed_leaf_is_left_to_the_partitioner(eight_devices):
+    """A leaf with a second real sharding (a TP-cut dim beside ZeRO's) is
+    not the plan's to reduce: its gathered layout is still sharded, so the
+    constraint would not be its whole reduction. A size-1 ``model`` axis
+    shards nothing and does not count."""
+    shapes = {"norm": (128,), "w_col": (128, 256), "w_row": (256, 128)}
+    specs = {"norm": P(None, "data"), "w_col": P(None, "data", "model"), "w_row": P(None, "model", "data")}
+    plan = _stack_plan(shapes, specs, {"data": 4, "model": 2})
+    assert [info.zero_only for info in plan.leaves] == [True, False, False]
+    assert plan.reduction_record() == {"leaves_alone": 1, "leaves_left_to_partitioner": 2, "alone_elems": [128]}
+    plan = _stack_plan(shapes, specs, {"data": 8, "model": 1})
+    assert plan.reduction_record()["leaves_alone"] == 3
+
+
+@pytest.mark.parametrize("mesh_cfg, w_col", [({"data": 8}, None), ({"data": 4, "model": 2}, "model")])
+def test_reduce_grads_backward_is_one_constraint_a_leaf(mesh_cfg, w_col, eight_devices):
+    """What the backward of ``reduce_grads`` holds: one sharding constraint
+    for every ``zero_only`` leaf, on the leaf in its own shape, and nothing
+    else: no transpose, reshape, pad or concatenate (the ``[world, chunk]``
+    bucket, until PR 58). A TP-mixed leaf gets none."""
+    shapes = {"norm": (128,), "w_col": (128, 256), "w_row": (256, 128)}
+    specs = {"norm": P(None, "data"), "w_col": P(None, "data", w_col), "w_row": P(None, None, "data")}
+    plan = _stack_plan(shapes, specs, mesh_cfg)
+    alone = [k for k, info in zip(sorted(shapes), plan.leaves) if info.zero_only]
+    assert alone == [k for k in sorted(shapes) if not (k == "w_col" and w_col)]
+
+    def loss(tree):
+        return sum(jnp.sum(v.astype(jnp.float32) ** 2) for v in plan.reduce_grads(tree).values())
+
+    with plan.mesh:
+        jaxpr = jax.make_jaxpr(jax.grad(loss))({k: jnp.ones(s, jnp.bfloat16) for k, s in shapes.items()})
+    eqns = jaxpr.jaxpr.eqns
+    names = [e.primitive.name for e in eqns]
+    constrained = sorted(e.invars[0].aval.shape for e in eqns if e.primitive.name == "sharding_constraint")
+    assert constrained == sorted(shapes[k] for k in alone), names
+    assert not {"transpose", "reshape", "pad", "concatenate", "custom_vjp_call"} & set(names), names
+
+
+def test_engine_emits_the_reduction_record_once(eight_devices):
+    """The engine says which leaves the plan reduces, once, where it builds
+    it: an instant event of its tracer carrying the record."""
+    e = _engine({"prefetch_layers": 1})
+    _train(e, _batches(1, STEPS))
+    events = [s for s in e.tracer.spans() if s["name"] == "zero.grad_reduce_plan"]
+    assert len(events) == 1 and events[0]["ph"] == "i"
+    assert events[0]["attrs"] == e._overlap_plan.reduction_record()
+    assert events[0]["attrs"]["leaves_alone"] >= 6 and events[0]["attrs"]["leaves_left_to_partitioner"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -269,28 +395,6 @@ def test_prefetch_bucket_size_caps_depth(eight_devices):
     layer of lookahead is the floor while prefetch is on)."""
     assert _plan(_engine({"prefetch_layers": 2, "stage3_prefetch_bucket_size": int(5e7)})).depth == 2
     assert _plan(_engine({"prefetch_layers": 2, "stage3_prefetch_bucket_size": 1})).depth == 1
-
-
-def test_row_coalesced_roundtrip():
-    """The [world, C] bucket layout is pure data movement: pack→unpack is
-    exact, including the padded not-world-divisible leaf."""
-    from deepspeed_tpu.runtime.comm.coalesced_collectives import (
-        pack_row_coalesced,
-        row_coalesced_layout,
-        unpack_row_coalesced,
-    )
-
-    world = 8
-    shapes = [(16, 3), (8, 2), (5,)]
-    tensors = [
-        jnp.arange(int(np.prod(s)), dtype=jnp.float32).reshape(s) for s in shapes
-    ]
-    buf = pack_row_coalesced(tensors, world)
-    layout = row_coalesced_layout(shapes, world)
-    assert buf.shape == (world, sum(w for _, w in layout))
-    out = unpack_row_coalesced(buf, shapes, world)
-    for t, o in zip(tensors, out):
-        np.testing.assert_array_equal(np.asarray(t), np.asarray(o))
 
 
 # ---------------------------------------------------------------------------
