@@ -6,7 +6,12 @@ layers that keep one low-rank entry a token in pages of their own (a latent
 layer with or without a low-rank query, with rotary or none); leading layers
 with a dense FFN, a leading layer of any kind, then every layer with its
 routed FFN, or (``num_experts`` 0) every layer with a dense FFN out of the
-period's stacks.
+period's stacks; or, where ``layer_types`` names FFN blocks, blocks of ONE
+sublayer each: a mixer block is norm, mixer, add and nothing else, an FFN
+block norm, router, held experts and shared expert, add (``ffn_block``), each
+with its own index into what its kind keeps: a state-space block counts the
+state store's entries, a softmax block the pages' layers, an FFN block the
+expert stacks and the routing counts.
 
 ``decode.build_ragged_step`` comes here, when the program is BUILT, for a
 config that names ``layer_types``; a uniform model never reaches this file
@@ -39,9 +44,9 @@ parameter of the program) and one more row array:
   re-admission need no reset dispatch. A model with state-space layers keeps
   THEIR state and tail in the same two fields, at the kind's shapes
   (``state_shapes``): ``[ssm layers, slots + 1, NH, P, N]`` float32 and ``[ssm
-  layers, slots + 1, K - 1, tail_rows(C), 128]``, the ``C = NH P + 2 N``
-  convolved channels a lane tile a row in whole sublane tiles, as
-  ``ssd_decode`` reads them;
+  layers, slots + 1, K - 1, tail_rows(C), 128]``, the ``C = NH P + 2 G N``
+  convolved channels (``G`` groups of ``B`` and ``C``) a lane tile a row in
+  whole sublane tiles, as ``ssd_decode`` reads them;
 * ``store.window_k / window_v`` ``[window layers, 1 + R * ring, NKV', P, ..]``:
   the rings of the sliding-window layers, with their own KV-head count. Row r
   owns pages ``1 + slots[r] * ring ..`` and position ``p`` lives in ring page
@@ -98,7 +103,8 @@ tiles, as in ``decode._paged_layers``; a window of at most one tile is one
   on both pools); a row with a chunk through ``hm.ssm_conv`` and
   ``ssd_chunked`` from its carried state, one row a trip. The gate ``z`` waits
   in a token buffer for the tile loop behind the mixer, which gates, norms
-  and projects (``hm.ssm_output``).
+  (over each of the layer's ``ssm_groups`` groups apart) and projects
+  (``hm.ssm_output``).
 
 The config's scalar multipliers (``embedding_multiplier``,
 ``residual_multiplier`` on both branches of every layer, ``logits_scaling`` in
@@ -240,7 +246,8 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         positions = jnp.take((lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]).reshape(-1), packed.slot, mode="clip")
 
     period, stacks = cfg.period, params["periods"]
-    n = len(period)
+    n = cfg.ffns_per_period  # what the FFN stacks hold of a period: one a layer, or the period's FFN blocks
+    single = cfg.single_sublayer  # a block is a mixer OR the FFN alone (``layer_types`` names FFN blocks)
     E = cfg.num_experts
     if E:
         expert_stacks = jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[3:]), stacks["moe"]["experts"])
@@ -328,6 +335,21 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         p = weights_at(p, per, j, start)
         out, _ = decode._ffn_body(cfg, p, x_tile, p["mlp_norm_scale"], None)
         return x_tile + branch(out), jnp.zeros((E,), jnp.int32)
+
+    def nothing_behind(x_tile, start):
+        """What follows the mixer of a block that is a mixer alone."""
+        return x_tile, jnp.zeros((E,), jnp.int32)
+
+    def ffn_block(x, per, j):
+        """A block that is the FFN alone, the period's ``j``-th: norm, router, held experts and shared expert (or the
+        dense FFN), one add, a tile at a time. Returns ``(x, counts)``."""
+
+        def body(start, carry):
+            x, counts = carry
+            x_tile, tile_counts = ffn(packed.take(x, start)[None], start, per, j)
+            return put(x, x_tile[0], start), counts + tile_counts
+
+        return tiles(body, (x, jnp.zeros((E,), jnp.int32)))
 
     def wide_attention(attend, operands, shapes, pools, layer, table, width):
         """A wide window's attention without the window's slab: the rows with
@@ -603,16 +625,21 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
     def period_step(carry, per):
         x, pools = carry
         pools = dict(pools)
-        at = {kind: 0 for kind in hm.LAYER_KINDS}
+        at = {kind: 0 for kind in hm.LAYER_KINDS + (hm.FFN_BLOCK,)}
         counts = []
         for j, kind in enumerate(period):
-            layer = cfg.leading_of(kind) + per * period.count(kind) + at[kind]
-            x, *written, c = mixers[kind](
-                x, *pools[kind], stacks[kind], per, at[kind], layer, functools.partial(ffn, per=per, j=j)
-            )
-            pools[kind] = tuple(written)
+            if kind == hm.FFN_BLOCK:
+                x, c = ffn_block(x, per, at[kind])
+            else:
+                layer = cfg.leading_of(kind) + per * period.count(kind) + at[kind]
+                x, *written, c = mixers[kind](
+                    x, *pools[kind], stacks[kind], per, at[kind], layer,
+                    nothing_behind if single else functools.partial(ffn, per=per, j=j),
+                )
+                pools[kind] = tuple(written)
             at[kind] += 1
-            counts.append(c)
+            if kind == hm.FFN_BLOCK or not single:  # the blocks with an FFN: a mixer alone routes nothing
+                counts.append(c)
         return (x, pools), jnp.stack(counts)
 
     (x, pools), counts = jax.lax.scan(period_step, (x, pools), jnp.arange(cfg.num_periods, dtype=jnp.int32))

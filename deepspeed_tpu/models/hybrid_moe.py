@@ -6,12 +6,18 @@ others, a Mamba-2 state-space mixer (``ops/transformer/state_space.py``) in
 others; a routed FFN that may have a shared expert and may hold only this chip's share
 of the experts its router chooses from, behind ``leading_dense_layers`` layers
 whose FFN is dense: a leading layer of any kind; or, with ``num_experts`` 0, a
-dense FFN in EVERY layer, stacked by period like the mixers.
+dense FFN in EVERY layer, stacked by period like the mixers. A layer is a mixer
+AND an FFN, unless the list names FFN blocks (``ffn``): a block is then ONE
+sublayer, ``x + f(RMSNorm(x))`` with ``f`` a mixer alone or the FFN alone
+(Nemotron-H), and nothing stands behind a mixer. The experts are SwiGLU ones of
+three matrices or, with a pointwise ``activation`` (``relu2``), of two.
 
 ``HybridMoEConfig.layer_types`` says what each layer is (``softmax`` /
-``window`` / ``linear`` / ``latent`` / ``ssm``); after the leading dense layers the list repeats
+``window`` / ``linear`` / ``latent`` / ``ssm``, and ``ffn`` for a block that is the FFN
+alone); after the leading dense layers the list repeats
 with a period (one softmax layer and three linear ones, say, or five window
-layers and a softmax one). Parameters are stacked by KIND inside a period and
+layers and a softmax one; a list that repeats nothing is one period of all its layers).
+Parameters are stacked by KIND inside a period and
 by period in front; a leading layer has its own::
 
     params["leading"][i]          {"mixer": its kind's leaves, "ffn": a dense FFN's}
@@ -20,8 +26,8 @@ by period in front; a leading layer has its own::
     params["periods"]["linear"]   leaves [periods, linear layers a period, ...]
     params["periods"]["latent"]   leaves [periods, latent layers a period, ...]
     params["periods"]["ssm"]      leaves [periods, state-space layers a period, ...]
-    params["periods"]["moe"]      leaves [periods, layers a period, ...]
-    params["periods"]["ffn"]      in place of "moe" where ``num_experts`` is 0: a dense FFN a layer
+    params["periods"]["moe"]      leaves [periods, layers a period, ...]; [periods, FFN blocks a period, ...] where the list names them
+    params["periods"]["ffn"]      in place of "moe" where ``num_experts`` is 0: a dense FFN a layer (or an FFN block)
 
 so the leading layers and then one ``lax.scan`` over periods run the model,
 the scan's body holding the period's layers in order. The functions below are
@@ -66,15 +72,16 @@ shared features are kept, nothing is rotated); scale ``head_dim^-0.5``.
 ``[c_kv ; k_rope]`` a token and computes the same numbers absorbed: ``q~ =
 q_nope Wk_b,h^T`` against ``c_kv``, ``o = (P c_kv) Wv_b,h``. The state-space
 layer (``ssm_inputs``, ``ssm_conv``, ``ssm_split``, ``ssm_output``; Mamba-2 with
-ONE group): ``[z ; xBC ; dt] = h W_in`` (``d_inner = ssm_num_heads x
-ssm_head_dim``, ``d_inner + 2 ssm_state``, ``ssm_num_heads``; the published
+``ssm_groups`` groups of ``B`` and ``C``, ``G``): ``[z ; xBC ; dt] = h W_in`` (``d_inner = ssm_num_heads x
+ssm_head_dim``, ``d_inner + 2 G ssm_state``, ``ssm_num_heads``; the published
 ``in_proj``'s three parts are three leaves, ``w_z``, ``w_xbc``, ``w_dt``); ``xBC`` through
 one depthwise causal convolution of ``ssm_conv_kernel`` taps WITH a bias and
-SiLU, then split into ``x`` a head, ``B`` and ``C`` of ``ssm_state`` (shared by
-all heads); ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a head; the
+SiLU, then split into ``x`` a head, ``B`` and ``C`` of ``ssm_state`` a group (one group: shared by
+all heads; more: head n reads group ``n // (heads / G)``); ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a head; the
 state ``S`` ``[heads, head_dim, ssm_state]`` float32, ``S_t = exp(dt_t A) S_{t-1}
 + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``; output ``RMSNorm(y * silu(z))
-W_out``, the gate BEFORE the norm, the norm over all ``d_inner`` features. The
+W_out``, the gate BEFORE the norm, the norm over each of the ``G`` groups of
+``d_inner / G`` features apart (one group: over all of them). The
 scalar multipliers (each 1.0 puts nothing into a program): the embedding times
 ``embedding_multiplier``, BOTH branches of every layer times
 ``residual_multiplier`` before they are added, the logits divided by
@@ -83,7 +90,11 @@ over ``moe_router_experts`` outputs, the ``moe_top_k`` largest of score +
 selection bias, gates normalised over the chosen (``moe_norm_topk_prob``) and
 scaled by ``moe_routed_scaling``; of those, the experts this chip holds
 (``moe_expert_share = (index, of)``: experts ``index * num_experts ..``), plus
-the shared expert, once.
+the shared expert, once. An expert is ``(silu(h Wg) * (h Wu)) Wd`` or, with a
+pointwise activation, ``act(h W_in) W_out`` (``relu2``: the rectified input
+squared; ``moe/experts.py`` owns the activations); a routed two-matrix
+expert's ``W_in`` is kept by its output rows (``w_in_t`` ``[.., E, I, H]``,
+``init`` says why).
 """
 
 from __future__ import annotations
@@ -100,7 +111,8 @@ from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.models.moe_transformer import MoETransformerConfig, MoETransformerLM
 from deepspeed_tpu.models.transformer import _norm
 
-LAYER_KINDS = ("softmax", "linear", "window", "latent", "ssm")
+LAYER_KINDS = ("softmax", "linear", "window", "latent", "ssm")  # the mixers
+FFN_BLOCK = "ffn"  # in ``layer_types``: a block that is the FFN alone; a list that names one has nothing behind its mixers
 # the named scope around a kind's mixer, which the benchmark's readers find device time by
 SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention", "latent": "latent_attention",
           "ssm": "ssm_mixer"}
@@ -109,7 +121,8 @@ STATE_KINDS = ("linear", "ssm")  # the kinds whose layers keep a recurrent state
 
 @dataclasses.dataclass
 class HybridMoEConfig(MoETransformerConfig):
-    # what each layer is; None: every layer ``softmax``
+    # what each layer is (a mixer of ``LAYER_KINDS``, or ``ffn``: a block that is the FFN alone, which makes every
+    # mixer a block alone too); None: every layer ``softmax``
     layer_types: Optional[Sequence[str]] = None
     leading_dense_layers: int = 0  # layers in front whose FFN is dense (``intermediate_size``), not routed
     attn_output_gate: bool = False  # softmax layers: attn * sigmoid(h Wg) before Wo, a gate a feature
@@ -147,7 +160,8 @@ class HybridMoEConfig(MoETransformerConfig):
     moe_shared_experts: int = 0  # shared experts, run as one FFN of that many expert widths
     moe_routed_scaling: float = 1.0
     # state-space (Mamba-2) layers: heads of ``ssm_head_dim`` over a state of ``ssm_state`` a feature, B and C in
-    # ``ssm_groups`` groups (1: shared by all heads), one convolution over [x ; B ; C]
+    # ``ssm_groups`` groups (1: shared by all heads; G: head n reads group n // (heads / G), and the gated norm
+    # runs over each group's features apart), one convolution over [x ; B ; C]
     ssm_num_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
@@ -159,10 +173,18 @@ class HybridMoEConfig(MoETransformerConfig):
     logits_scaling: float = 1.0  # the logits DIVIDED by this
 
     def __post_init__(self):
+        dense_width_named = self.intermediate_size is not None
         super().__post_init__()
         self.layer_types = tuple(self.layer_types or ("softmax",) * self.num_layers)
-        if len(self.layer_types) != self.num_layers or set(self.layer_types) - set(LAYER_KINDS):
-            raise ValueError(f"layer_types must name {self.num_layers} layers of {LAYER_KINDS}, got {self.layer_types}")
+        if len(self.layer_types) != self.num_layers or set(self.layer_types) - set(LAYER_KINDS) - {FFN_BLOCK}:
+            raise ValueError(f"layer_types must name {self.num_layers} layers of {LAYER_KINDS + (FFN_BLOCK,)}, got {self.layer_types}")
+        if self.single_sublayer:
+            if set(self.layer_types) == {FFN_BLOCK}:
+                raise ValueError("a model of FFN blocks only mixes no token with another: layer_types must name a mixer")
+            if self.leading_dense_layers:
+                raise ValueError("a list that names FFN blocks says where every FFN is: it has no leading_dense_layers (a mixer AND a dense FFN)")
+            if not self.num_experts and not dense_width_named:
+                raise ValueError("an FFN block of a model with num_experts=0 is a dense FFN and needs its width: intermediate_size")
         self.v_head_dim = self.v_head_dim or self.head_dim
         self.window_num_heads = self.window_num_heads or self.num_heads
         self.window_num_kv_heads = self.window_num_kv_heads or self.num_kv_heads
@@ -191,14 +213,14 @@ class HybridMoEConfig(MoETransformerConfig):
         if "ssm" in self.layer_types:
             if min(self.ssm_num_heads, self.ssm_head_dim, self.ssm_state) < 1 or self.ssm_conv_kernel < 2:
                 raise ValueError("a state-space layer needs ssm_num_heads, ssm_head_dim, ssm_state and ssm_conv_kernel >= 2")
-            if self.ssm_groups != 1:
-                raise NotImplementedError(
-                    f"ssm_groups={self.ssm_groups}: a state-space layer's B and C are shared by all its heads here (one "
-                    "group, which the decode kernel reads once a row); no published model served here asks for more"
+            if self.ssm_groups < 1 or self.ssm_num_heads % self.ssm_groups:
+                raise ValueError(
+                    f"ssm_groups={self.ssm_groups}: a state-space layer's {self.ssm_num_heads} heads read B and C in whole "
+                    "groups (head n reads group n // (ssm_num_heads / ssm_groups))"
                 )
             if self.ssm_conv_channels % 128:
                 raise ValueError(
-                    f"a state-space layer's convolved channels d_inner + 2 ssm_state = {self.ssm_conv_channels} must be "
+                    f"a state-space layer's convolved channels d_inner + 2 ssm_groups ssm_state = {self.ssm_conv_channels} must be "
                     "whole lane tiles of 128: the per-slot store keeps a row's tail a lane tile a row"
                 )
             if "linear" in self.layer_types:
@@ -219,9 +241,12 @@ class HybridMoEConfig(MoETransformerConfig):
                 f"share {index} of {of} of a router over {self.moe_router_experts} experts holds "
                 f"{self.moe_router_experts // of}, not num_experts={self.num_experts}"
             )
-        if self.moe_layer_freq != 1 or self.moe_drop_tokens or self.activation != "swiglu":
-            raise ValueError("a hybrid model routes every layer behind its leading dense ones droplessly through "
-                             "SwiGLU experts: moe_layer_freq=1, moe_drop_tokens=False, activation='swiglu'")
+        from deepspeed_tpu.moe.experts import POINTWISE_ACTIVATIONS
+
+        if self.moe_layer_freq != 1 or self.moe_drop_tokens or self.activation not in ("swiglu",) + POINTWISE_ACTIVATIONS:
+            raise ValueError("a hybrid model routes every FFN behind its leading dense ones droplessly, through SwiGLU experts of "
+                             f"three matrices or experts of two with one of {POINTWISE_ACTIVATIONS} between them: "
+                             "moe_layer_freq=1, moe_drop_tokens=False, activation='swiglu' or one of those")
         if self.position not in ("none", "rope") or self.use_bias or self.norm != "rmsnorm":
             raise ValueError("a hybrid model is pre-norm RMSNorm without biases, with rotary positions or none (position='rope'|'none')")
 
@@ -239,9 +264,22 @@ class HybridMoEConfig(MoETransformerConfig):
         return (self.num_layers - self.leading_dense_layers) // len(self.period)
 
     @property
+    def single_sublayer(self) -> bool:
+        """Whether a block is ONE sublayer, a mixer or the FFN alone: ``layer_types`` names FFN blocks."""
+        return FFN_BLOCK in self.layer_types
+
+    @property
+    def ffns_per_period(self) -> int:
+        """The FFNs of a period, which its FFN stacks hold: one a layer, or the period's FFN blocks."""
+        return self.period.count(FFN_BLOCK) if self.single_sublayer else len(self.period)
+
+    @property
     def num_moe_layers(self) -> int:
-        """Layers with a routed FFN: those behind the leading dense ones; none where ``num_experts`` is 0."""
-        return self.num_layers - self.leading_dense_layers if self.num_experts else 0
+        """Layers with a routed FFN: those behind the leading dense ones, or the FFN blocks of a list that names
+        them; none where ``num_experts`` is 0."""
+        if not self.num_experts:
+            return 0
+        return self.layers_of(FFN_BLOCK) if self.single_sublayer else self.num_layers - self.leading_dense_layers
 
     @property
     def ssm_inner(self) -> int:
@@ -408,7 +446,7 @@ def linear_output(cfg: HybridMoEConfig, p, h, o):
 def ssm_inputs(cfg: HybridMoEConfig, p, h):
     """What a state-space layer computes of one token before its convolution,
     from the normed ``h`` [..., H]: the gate ``z`` [..., d_inner] and the
-    pre-convolution ``[x ; B ; C]`` [..., d_inner + 2 N] in h's type, and
+    pre-convolution ``[x ; B ; C]`` [..., d_inner + 2 G N] in h's type, and
     ``dt = softplus(dt + dt_bias)`` [..., NH] in float32 (no clamp: the config
     names none)."""
     dt = jax.nn.softplus(qmatmul(h, p["w_dt"]).astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
@@ -428,18 +466,26 @@ def ssm_conv(p, tails, xbc):
 
 
 def ssm_split(cfg: HybridMoEConfig, y):
-    """The convolved ``y`` [..., d_inner + 2 N], split: ``x`` [..., NH, P],
-    ``B`` and ``C`` [..., N] (one group: shared by all heads)."""
-    inner, N = cfg.ssm_inner, cfg.ssm_state
+    """The convolved ``y`` [..., d_inner + 2 G N], split: ``x`` [..., NH, P],
+    ``B`` and ``C`` [..., N] (one group: shared by all heads) or, with
+    ``ssm_groups`` above one, [..., G, N] (head n reads group ``n // (NH / G)``)."""
+    inner, N, G = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_groups
     x = y[..., :inner].reshape(y.shape[:-1] + (cfg.ssm_num_heads, cfg.ssm_head_dim))
-    return x, y[..., inner : inner + N], y[..., inner + N :]
+    if G == 1:
+        return x, y[..., inner : inner + N], y[..., inner + N :]
+    return x, y[..., inner : inner + G * N].reshape(y.shape[:-1] + (G, N)), y[..., inner + G * N :].reshape(y.shape[:-1] + (G, N))
 
 
 def ssm_output(cfg: HybridMoEConfig, p, z, y):
     """``y`` [..., d_inner] of the recurrence and the gate ``z``: the gate
-    BEFORE the norm, the RMSNorm over all ``d_inner`` features (one group),
-    the output projection. [..., H]."""
+    BEFORE the norm, the RMSNorm over all ``d_inner`` features (one group) or
+    over each of the ``ssm_groups`` groups of ``d_inner / G`` apart (one learned
+    scale [d_inner] either way), the output projection. [..., H]."""
     gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    if cfg.ssm_groups > 1:
+        by_group = (cfg.ssm_groups, cfg.ssm_inner // cfg.ssm_groups)
+        normed = _norm(gated.reshape(gated.shape[:-1] + by_group), p["o_norm_scale"].reshape(by_group), None, "rmsnorm", cfg.norm_eps)
+        return qmatmul(normed.reshape(gated.shape).astype(z.dtype), p["wo"])
     return qmatmul(_norm(gated, p["o_norm_scale"], None, "rmsnorm", cfg.norm_eps).astype(z.dtype), p["wo"])
 
 
@@ -539,7 +585,8 @@ class HybridMoETransformerLM(MoETransformerLM):
         LH, LD, r, K = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_gate_rank, cfg.linear_conv_kernel
         E, ER, I = cfg.num_experts, cfg.moe_router_experts, cfg.expert_intermediate_size
         NP, period = cfg.num_periods, cfg.period
-        n = len(period)
+        n = cfg.ffns_per_period
+        gated = cfg.activation == "swiglu"  # three matrices an FFN; a pointwise activation has two, ``w_in`` and ``w_out``
         keys = iter(jax.random.split(rng, 40 + 24 * cfg.leading_dense_layers))
         std, out_std = 0.02, 0.02 / np.sqrt(2 * L)
 
@@ -624,23 +671,33 @@ class HybridMoETransformerLM(MoETransformerLM):
 
         periods: Dict[str, Any] = {kind: mixer(kind, NP, period.count(kind)) for kind in LAYER_KINDS if kind in period}
         Id = cfg.intermediate_size
-        dense_ffn = lambda *lead: {"mlp_norm_scale": jnp.ones(lead + (H,)), "w_gate": dense(lead + (H, Id)),
-                                   "w_up": dense(lead + (H, Id)), "w_out": dense(lead + (Id, H), out_std)}
+
+        def ffn(width, *lead, routed=False):
+            """One FFN's matrices behind the axes ``lead``: the input side at
+            ``lead + (H, width)``; a ROUTED expert of two matrices keeps its
+            input matrix by its output rows, ``w_in_t`` ``lead + (width, H)``
+            (the published ``up_proj``'s own layout): a width of no whole
+            lane tiles (1,856) on the minor axis of a stack is an array the
+            device keeps H-minor, and the grouped matmul would be handed a
+            transposed copy of all of it, every call
+            (``grouped_matmul``'s ``transposed``)."""
+            if gated:
+                into = {"w_gate": dense(lead + (H, width)), "w_up": dense(lead + (H, width))}
+            else:
+                into = {"w_in_t": dense(lead + (width, H))} if routed else {"w_in": dense(lead + (H, width))}
+            return {**into, "w_out": dense(lead + (width, H), out_std)}
+
+        dense_ffn = lambda *lead: {"mlp_norm_scale": jnp.ones(lead + (H,)), **ffn(Id, *lead)}
         if E:
             moe = {
                 "mlp_norm_scale": jnp.ones((NP, n, H)),
                 "gate": {"wg": dense((NP, n, H, ER))},
-                "experts": {
-                    "w_gate": dense((NP, n, E, H, I)),
-                    "w_up": dense((NP, n, E, H, I)),
-                    "w_out": dense((NP, n, E, I, H), out_std),
-                },
+                "experts": ffn(I, NP, n, E, routed=True),
             }
             if cfg.moe_select_bias:
                 moe["gate"]["bias"] = dense((NP, n, ER))
             if cfg.moe_shared_experts:
-                Is = I * cfg.moe_shared_experts
-                moe["shared"] = {"w_gate": dense((NP, n, H, Is)), "w_up": dense((NP, n, H, Is)), "w_out": dense((NP, n, Is, H), out_std)}
+                moe["shared"] = ffn(I * cfg.moe_shared_experts, NP, n)
             periods["moe"] = moe
         else:  # no expert anywhere: a dense FFN a layer, stacked as the mixers are
             periods["ffn"] = dense_ffn(NP, n)
@@ -743,18 +800,25 @@ class HybridMoETransformerLM(MoETransformerLM):
         for kind, p in zip(cfg.layer_types, params.get("leading", ())):
             x = dense(mix(x, kind, p["mixer"]), p["ffn"])
 
+        def feed_forward(x, p, j):
+            """The period's ``j``-th FFN: dense out of ``p["ffn"]`` (``num_experts`` 0), else routed."""
+            if "ffn" in p:
+                return dense(x, jax.tree_util.tree_map(lambda a: a[j], p["ffn"]))
+            moe = jax.tree_util.tree_map(lambda a: a[j], p["moe"])
+            with jax.named_scope("mlp"):
+                out, _ = moe_ffn(cfg, moe, _norm(x, moe["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps))
+            return x + branch(out.astype(x.dtype))
+
         def period_step(x, p):
-            at = {kind: 0 for kind in LAYER_KINDS}
+            at = {kind: 0 for kind in LAYER_KINDS + (FFN_BLOCK,)}
             for j, kind in enumerate(cfg.period):
-                x = mix(x, kind, jax.tree_util.tree_map(lambda a: a[at[kind]], p[kind]))
+                if kind == FFN_BLOCK:  # a block that is the FFN alone
+                    x = feed_forward(x, p, at[kind])
+                else:
+                    x = mix(x, kind, jax.tree_util.tree_map(lambda a: a[at[kind]], p[kind]))
+                    if not cfg.single_sublayer:  # a layer is a mixer AND an FFN
+                        x = feed_forward(x, p, j)
                 at[kind] += 1
-                if "ffn" in p:  # num_experts 0: every layer's FFN is dense
-                    x = dense(x, jax.tree_util.tree_map(lambda a: a[j], p["ffn"]))
-                    continue
-                moe = jax.tree_util.tree_map(lambda a: a[j], p["moe"])
-                with jax.named_scope("mlp"):
-                    out, _ = moe_ffn(cfg, moe, _norm(x, moe["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps))
-                x = x + branch(out.astype(x.dtype))
             return x, None
 
         x, _ = jax.lax.scan(period_step, x, params["periods"])
@@ -970,4 +1034,48 @@ def granite_hybrid_config(size: str = "4.0-h-micro", **overrides) -> HybridMoECo
     if "layer_types" not in base:
         # layer_types: attention at 5 and then every tenth, mamba elsewhere
         base["layer_types"] = ["softmax" if i % 10 == 5 else "ssm" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)
+
+
+def layer_types_of_pattern(pattern: str):
+    """A ``hybrid_override_pattern`` as ``layer_types``: ``M`` a Mamba-2 mixer alone, ``*`` an attention mixer alone, ``E`` the expert FFN alone."""
+    return [{"M": "ssm", "*": "softmax", "E": FFN_BLOCK}[letter] for letter in pattern]
+
+
+def nemotron_h_config(size: str = "3-nano-30b-a3b", **overrides) -> HybridMoEConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (``nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16``
+    ``config.json``, ``model_type: nemotron_h``): 52 blocks of ONE sublayer
+    each, ``hybrid_override_pattern`` ``MEMEM*EMEMEM*...``: 23 ``M`` Mamba-2
+    mixers of 64 heads of 64 over a state of 128 with EIGHT groups of ``B``
+    and ``C`` (a 4-tap convolution with a bias over 6,144 channels, the gated
+    norm over each group of 512 features apart), 6 ``*`` causal GQA of 32
+    query heads over 2 KV heads of 128 with no positional term, 23 ``E``
+    expert FFNs: 128 experts of TWO matrices with ``relu2`` between them
+    (2,688 -> 1,856 -> 2,688), 6 a token by sigmoid scores with a selection
+    bias, gates normalised and times 2.5, one shared expert of 3,712 (two
+    expert widths). ``layer_types`` names the blocks ``ssm`` / ``softmax`` /
+    ``ffn`` (``layer_types_of_pattern``). ``3-nano-30b-a3b`` is the published
+    model whole, whose list repeats nothing: ``period`` is then the 52 blocks
+    themselves, one trip of the scan; ``tiny`` a toy of one chip's share (4 of
+    8 experts held) of the leading 16 blocks ``MEMEM*EMEMEM*EME`` for tests,
+    its state-space layer at the kernel's own tiles with two groups of two
+    heads."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=16, num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=512, max_seq_len=256,
+                     intermediate_size=32, expert_intermediate_size=32, num_experts=4, moe_router_experts=8,
+                     moe_expert_share=(0, 2), moe_top_k=3, ssm_num_heads=4, ssm_head_dim=64, ssm_state=128, ssm_groups=2),
+        "3-nano-30b-a3b": dict(hidden_size=2688, num_layers=52, num_heads=32, num_kv_heads=2, head_dim=128, vocab_size=131072,
+                               max_seq_len=262144, intermediate_size=1856, expert_intermediate_size=1856, num_experts=128,
+                               moe_top_k=6, ssm_num_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="none", activation="relu2", use_bias=False, tie_embeddings=False,
+        ssm_conv_kernel=4, moe_layer_freq=1, moe_drop_tokens=False, moe_norm_topk_prob=True, moe_scoring="sigmoid",
+        moe_select_bias=True, moe_shared_experts=2, moe_routed_scaling=2.5,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        published = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+        base["layer_types"] = layer_types_of_pattern(published[: base["num_layers"]])
     return HybridMoEConfig(**base)

@@ -45,14 +45,20 @@ def init_dense_ffn(
     return params
 
 
+# an FFN of two matrices, ``act(x w_in) w_out``: the one place that owns the pointwise activations
+_POINTWISE = {
+    "gelu": jax.nn.gelu,
+    "relu": jax.nn.relu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),  # Nemotron-H's ``relu2``: the rectified input, squared
+    "quick_gelu": lambda x: x * jax.nn.sigmoid(1.702 * x),  # CLIP
+}
+POINTWISE_ACTIVATIONS = tuple(_POINTWISE)
+
+
 def _pointwise_activation(x: jnp.ndarray, activation: str) -> jnp.ndarray:
-    if activation == "gelu":
-        return jax.nn.gelu(x)
-    if activation == "relu":
-        return jax.nn.relu(x)
-    if activation == "quick_gelu":  # CLIP: x * sigmoid(1.702 x)
-        return x * jax.nn.sigmoid(1.702 * x)
-    raise ValueError(f"unknown pointwise activation {activation!r}")
+    if activation not in _POINTWISE:
+        raise ValueError(f"unknown pointwise activation {activation!r}")
+    return _POINTWISE[activation](x)
 
 
 def apply_dense_ffn(params: Dict[str, Any], x: jnp.ndarray, activation: str = "gelu",

@@ -101,19 +101,19 @@ def _x_index(n, v, kk, meta, *, V: int, k_tiles: int):
     return meta[V + v], 0
 
 
-def _w_index(n, v, kk, meta, *, V: int, k_tiles: int):
+def _w_index(n, v, kk, meta, *, V: int, k_tiles: int, transposed: bool = False):
     """The visit's matrix and the step's K block; a dead visit stays on the
     last K block, which the last live step left in VMEM."""
     if k_tiles > 1:
         kk = jnp.where(v < meta[4 * V], kk, k_tiles - 1)
-    return meta[v], kk, n
+    return (meta[v], n, kk) if transposed else (meta[v], kk, n)
 
 
 def _o_index(n, v, kk, meta, *, V: int, k_tiles: int):
     return meta[V + v], n
 
 
-def _kernel(meta, x_ref, w_ref, o_ref, acc_ref, *, V: int, tm: int, tk: int, k_tiles: int):
+def _kernel(meta, x_ref, w_ref, o_ref, acc_ref, *, V: int, tm: int, tk: int, k_tiles: int, transposed: bool = False):
     v, kk = pl.program_id(1), pl.program_id(2)
     tile = meta[V + v]
 
@@ -124,7 +124,10 @@ def _kernel(meta, x_ref, w_ref, o_ref, acc_ref, *, V: int, tm: int, tk: int, k_t
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         x = x_ref[...] if k_tiles == 1 else x_ref[:, pl.ds(pl.multiple_of(kk * tk, tk), tk)]
-        acc_ref[...] += jnp.dot(x, w_ref[0], preferred_element_type=jnp.float32)
+        if transposed:  # the block is [tn, tk]: x w^T, the contraction over both operands' lanes
+            acc_ref[...] += jax.lax.dot_general(x, w_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        else:
+            acc_ref[...] += jnp.dot(x, w_ref[0], preferred_element_type=jnp.float32)
 
         @pl.when(kk == k_tiles - 1)
         def _():
@@ -137,9 +140,9 @@ def _kernel(meta, x_ref, w_ref, o_ref, acc_ref, *, V: int, tm: int, tk: int, k_t
             o_ref[...] = jnp.where(mine, acc_ref[...], kept).astype(o_ref.dtype)
 
 
-def _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret: bool):
+def _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret: bool, transposed: bool = False):
     M, K = x.shape
-    N = w.shape[-1]
+    N = w.shape[-2] if transposed else w.shape[-1]
     G = group_sizes.shape[0]
     tm, tk, tn = _tiles(M, K, N, w.dtype.itemsize)
     rows = -(-M // tm) * tm
@@ -160,13 +163,13 @@ def _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret: bool)
         grid=(N // tn, V, k_tiles),
         in_specs=[
             pl.BlockSpec((tm, K), functools.partial(_x_index, **at)),
-            pl.BlockSpec((1, tk, tn), functools.partial(_w_index, **at)),
+            pl.BlockSpec((1, tn, tk) if transposed else (1, tk, tn), functools.partial(_w_index, transposed=transposed, **at)),
         ],
         out_specs=pl.BlockSpec((tm, tn), functools.partial(_o_index, **at)),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, V=V, tm=tm, tk=tk, k_tiles=k_tiles),
+        functools.partial(_kernel, V=V, tm=tm, tk=tk, k_tiles=k_tiles, transposed=transposed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, N), out_dtype),
         interpret=interpret,
@@ -176,21 +179,25 @@ def _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret: bool)
     return out[:M]
 
 
-def _xla_forward(x, w, group_sizes, group_offset, out_dtype):
+def _xla_forward(x, w, group_sizes, group_offset, out_dtype, transposed: bool = False):
     w = jax.lax.dynamic_slice_in_dim(w, group_offset, group_sizes.shape[0], axis=0)
+    if transposed:
+        w = jnp.swapaxes(w, 1, 2)
     return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32), preferred_element_type=out_dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _pallas_matmul(x, w, group_sizes, group_offset, out_dtype, interpret):
-    return _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _pallas_matmul(x, w, group_sizes, group_offset, out_dtype, interpret, transposed):
+    return _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret, transposed)
 
 
-def _pallas_fwd(x, w, group_sizes, group_offset, out_dtype, interpret):
-    return _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret), (x, w, group_sizes, group_offset)
+def _pallas_fwd(x, w, group_sizes, group_offset, out_dtype, interpret, transposed):
+    return _pallas_forward(x, w, group_sizes, group_offset, out_dtype, interpret, transposed), (x, w, group_sizes, group_offset)
 
 
-def _pallas_bwd(out_dtype, interpret, saved, g):
+def _pallas_bwd(out_dtype, interpret, transposed, saved, g):
+    if transposed:  # the serving path's form: no model that trains keeps a stack by its output rows
+        raise NotImplementedError("grouped_matmul(transposed=True) has no gradient through the kernel; impl='xla' differentiates")
     x, w, group_sizes, group_offset = saved
     _, vjp = jax.vjp(lambda x_, w_: _xla_forward(x_, w_, group_sizes, group_offset, out_dtype), x, w)
     return (*vjp(g), None, None)
@@ -199,20 +206,26 @@ def _pallas_bwd(out_dtype, interpret, saved, g):
 _pallas_matmul.defvjp(_pallas_fwd, _pallas_bwd)
 
 
-def grouped_matmul(x, w, group_sizes, *, group_offset=0, out_dtype=None, impl: str = "auto"):
+def grouped_matmul(x, w, group_sizes, *, group_offset=0, out_dtype=None, impl: str = "auto", transposed: bool = False):
     """See the module docstring. ``w`` may hold more matrices than there are
     groups (``[L * G, K, N]``: every layer's experts, seen as one stack):
     group g then uses ``w[group_offset + g]``, the offset being data, so the
     kernel reads a layer's experts where they lie and nothing slices the
     stack first. ``x`` and ``w`` are multiplied in ``w``'s dtype and
-    accumulated in float32; ``out_dtype`` defaults to ``x``'s."""
+    accumulated in float32; ``out_dtype`` defaults to ``x``'s.
+    ``transposed``: ``w`` is ``[G, N, K]``, a matrix stored by its OUTPUT
+    rows, and the product is ``x w[g]^T``: for an ``N`` that is no whole
+    number of lane tiles (1,856), whose ``[K, N]`` array the device keeps
+    K-minor, so that the kernel's operand would be a transposed copy of the
+    whole stack, every call (PERF.md section 6, PR 59). Forward only through
+    the kernel: its gradient raises (``impl="xla"`` differentiates)."""
     out_dtype = jnp.dtype(out_dtype or x.dtype)
     x = x.astype(w.dtype)
     group_offset = jnp.asarray(group_offset, jnp.int32)
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
     if impl == "xla":
-        return _xla_forward(x, w, group_sizes, group_offset, out_dtype)
+        return _xla_forward(x, w, group_sizes, group_offset, out_dtype, transposed)
     if impl not in ("pallas", "pallas_interpret"):
         raise ValueError(f"grouped_matmul impl must be auto, pallas, pallas_interpret or xla, got {impl!r}")
-    return _pallas_matmul(x, w, group_sizes, group_offset, out_dtype, impl == "pallas_interpret")
+    return _pallas_matmul(x, w, group_sizes, group_offset, out_dtype, impl == "pallas_interpret", transposed)

@@ -65,13 +65,18 @@ def route(logits: jnp.ndarray, k: int, norm_topk_prob: Optional[bool], select_lo
     return gates, experts.astype(jnp.int32), chosen
 
 
-def _expert_matmul(rows, w, sizes, row_expert, group_offset, out_dtype):
+def _expert_matmul(rows, w, sizes, row_expert, group_offset, out_dtype, transposed: bool = False):
     """``grouped_matmul`` of sorted ``rows`` with the experts
     ``w[group_offset : group_offset + E]``. A stack may be int8
     (``compression/int8.py``: codes and per-output-channel scales
-    ``[G, 1, N]``, applied to each row by its expert's)."""
+    ``[G, 1, N]``, applied to each row by its expert's). ``transposed``: the
+    stack is ``[G, N, K]`` (``grouped_matmul``)."""
     from deepspeed_tpu.compression.int8 import QuantizedTensor
 
+    if transposed:
+        if isinstance(w, QuantizedTensor):
+            raise NotImplementedError("an int8 expert stack stored by its output rows (w_in_t)")
+        return grouped_matmul(rows, w.astype(rows.dtype), sizes, group_offset=group_offset, out_dtype=out_dtype, transposed=True)
     if isinstance(w, QuantizedTensor):
         # the codes are converted before the kernel, so only this call's experts are
         codes = jax.lax.dynamic_slice_in_dim(w.q, group_offset, sizes.shape[0], axis=0)
@@ -132,7 +137,10 @@ def routed_ffn(
             up = _expert_matmul(rows, experts["w_up"], counts, row_expert, group_offset, dt)
             inner = (jax.nn.silu(gate) if activation == "swiglu" else jax.nn.gelu(gate)) * up
         else:
-            inner = _expert_matmul(rows, experts["w_in"], counts, row_expert, group_offset, dt)
+            if "w_in_t" in experts:  # the input matrix stored by its output rows, [E, I, H] (``grouped_matmul``'s ``transposed``)
+                inner = _expert_matmul(rows, experts["w_in_t"], counts, row_expert, group_offset, dt, transposed=True)
+            else:
+                inner = _expert_matmul(rows, experts["w_in"], counts, row_expert, group_offset, dt)
             if "b_in" in experts:
                 inner = inner + experts["b_in"].astype(dt)[row_expert]
             inner = _pointwise_activation(inner, activation)

@@ -22,7 +22,7 @@ from deepspeed_tpu.inference import decode, hybrid_decode
 from deepspeed_tpu.inference.kv_pool import PagePool, heads_per_group, page_shapes
 from deepspeed_tpu.inference.scheduler import PagedServer
 from deepspeed_tpu.models import hybrid_moe as hm
-from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, granite_hybrid_config, solar_open2_config
+from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, granite_hybrid_config, nemotron_h_config, solar_open2_config
 from tests.unit.inference.test_granite_hybrid_serving import CHUNK, F32_TOL, MAXLEN, PAGE, SLOTS, reference_logits, toy_model
 from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file  # noqa: F401 (the two fixtures are taken by their import)
 
@@ -90,15 +90,22 @@ def test_the_pool_holds_two_heads_of_64_a_page():
     assert narrow.cache.heads_per_group == 1 and narrow.cache.k_pages.shape == (2, 9, 2, PAGE, 16)  # 2 heads of 16: no whole lane tile
 
 
-@pytest.mark.parametrize("what", ["both_kinds", "two_groups", "ragged_channels", "no_sizes", "experts_none_but_a_leading_layer", "experts_none_but_a_shared_one"])
+@pytest.mark.parametrize("what", ["both_kinds", "two_groups", "ragged_channels", "no_sizes", "experts_none_but_a_leading_layer", "experts_none_but_a_shared_one",
+                                  "an_ffn_block_with_no_width", "ffn_blocks_only", "ffn_blocks_behind_a_leading_layer", "an_unknown_activation"])
 def test_what_the_config_refuses_it_names(what):
     make = {
         "both_kinds": (NotImplementedError, "ONE kind of", lambda: granite_hybrid_config("tiny", layer_types=["ssm", "linear", "softmax"] * 2)),
-        "two_groups": (NotImplementedError, "ssm_groups=2", lambda: granite_hybrid_config("tiny", ssm_groups=2)),
+        # two heads in two groups are served since PR 59; heads that are no whole number of groups are still refused
+        "two_groups": (ValueError, "ssm_groups=2", lambda: granite_hybrid_config("tiny", ssm_num_heads=3, ssm_groups=2)),
         "ragged_channels": (ValueError, "whole lane tiles", lambda: granite_hybrid_config("tiny", ssm_head_dim=48)),
         "no_sizes": (ValueError, "needs ssm_num_heads", lambda: granite_hybrid_config("tiny", ssm_state=0)),
         "experts_none_but_a_leading_layer": (ValueError, "num_experts=0", lambda: granite_hybrid_config("tiny", leading_dense_layers=1)),
         "experts_none_but_a_shared_one": (ValueError, "num_experts=0", lambda: granite_hybrid_config("tiny", moe_shared_experts=1)),
+        # what a list that names FFN blocks (a block is ONE sublayer) may not say
+        "an_ffn_block_with_no_width": (ValueError, "needs its width", lambda: granite_hybrid_config("tiny", intermediate_size=None, layer_types=["ssm", "ffn", "softmax"] * 2)),
+        "ffn_blocks_only": (ValueError, "FFN blocks only", lambda: granite_hybrid_config("tiny", layer_types=["ffn"] * 6)),
+        "ffn_blocks_behind_a_leading_layer": (ValueError, "no leading_dense_layers", lambda: nemotron_h_config("tiny", leading_dense_layers=1)),
+        "an_unknown_activation": (ValueError, "relu2", lambda: nemotron_h_config("tiny", activation="geglu")),
     }
     error, match, build = make[what]
     with pytest.raises(error, match=match):
