@@ -957,6 +957,28 @@ def _flash_on_mesh(q, k, v, scale):
     )(q, k, v)
 
 
+def flash_operand_layout(cfg, topo) -> Optional[Dict[str, Any]]:
+    """What training's attention asks of the flash kernels, from the model's
+    config and the mesh alone: the heads a chip holds (``_flash_on_mesh``
+    splits them over ``model`` where that divides them), their width, and the
+    kernels' answer (``ops/transformer/flash_attention.py::operand_layout``).
+    None where training does not enter them. The engine records it once at
+    build time (``flash.operand_layout``), so a fall back to the transposing
+    entry shows in a run's events and not only in a slower step."""
+    if not (getattr(cfg, "flash_attention", False) and cfg.position != "alibi" and cfg.causal and cfg.attn_dropout == 0):
+        return None
+    from deepspeed_tpu.ops.transformer.flash_attention import operand_layout
+
+    sp = topo.axis_size("sequence") if cfg.sequence_parallel else 1
+    if sp > 1 and cfg.sequence_parallel_mode == "ring":
+        return None  # the ring has an attention of its own
+    heads = cfg.num_heads // sp  # Ulysses scatters the heads over the sequence axis
+    tp = topo.axis_size("model")
+    if heads % tp == 0:
+        heads //= tp
+    return {"heads_on_a_chip": heads, "head_dim": cfg.head_dim, **operand_layout(heads, cfg.head_dim)._asdict()}
+
+
 def _expand_gqa(q, k, v):
     """Repeat kv heads up to q's head count — ONLY for consumers whose
     contract requires equal head counts (the fused flash kernel, the

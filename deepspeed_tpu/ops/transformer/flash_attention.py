@@ -5,19 +5,49 @@ TPU-native replacement for the reference's fused attention kernels
 and ``csrc/transformer/inference/csrc/softmax.cu`` softmax_context): one
 fused kernel that never materializes the [T, T] score matrix in HBM.
 
-Layout: q/k/v as [BN, T, D] (batch*heads flattened into the leading dim).
+Layout: q, k, v and o as ``[B, T, N * D]``, the layout the model's
+projections write and read (``h @ wq`` leaves it, ``attn @ wo`` takes it; the
+``[B, T, N, D]`` the entry takes is a free reshape of it), so no copy stands
+between a projection and a kernel, forward or backward, and the residuals
+are saved as they came (until PR 60 the kernels were entered head-major,
+``[B * N, T, D]``: twelve copies a layer around the three calls, 35 ms of
+GPT-2 XL's 880 ms step). The unit is a **tile** of a batch row's lanes
+(``operand_layout``): a head where a head is whole lane tiles (D 128, 256),
+the heads of one lane tile where they divide it (two heads of 64); a grid
+step takes up to ``_MAX_HEADS`` heads of them side by side (``_heads_a_step``)
+and a head's operands are its tile of the step's blocks, a slice at a whole
+number of lane tiles. The products stay whole-tile, with no lane shuffle: a
+contraction over the tile's lanes against an operand whose other heads'
+lanes are *selected* to zero (``_head_lanes``, on the outer side, once an
+outer block) is one head's ``q k^T``, at the cost the 64-lane product has on
+a unit 128 deep; a product that contracts over rows (``p v``, ``ds k``,
+``p^T do``, ``ds^T q``) gives the tile's lanes whole, of which the head's are
+kept when the result is stored (``_store_head``). Static 64-lane slices of
+the refs are the other form (Mosaic takes them): at GPT-2 XL's shape the
+kernels read 3% slower with them than with the selects, and their body is
+traced once a head of the tile (``PERF.md`` section 6, PR 60). Where the
+heads do not fill the last step (GPT-2 XL: 25 heads of 64 are 12.5 tiles)
+the walk over heads stops at the last real one and the walked side's
+operands have the lanes past it selected to zero: what lies there is
+unspecified, and both sides of a contraction must be numbers. A head width
+that neither divides a lane tile nor is divided by one (80, 96) is
+transposed to ``[B * N, T, D]`` at the door and runs the same kernels with
+its ``D`` lanes as the tile.
+
 Online-softmax forward; the log-sum-exp is saved as a residual and the
 backward pass recomputes probabilities blockwise (standard FlashAttention-2
 scheme: one kernel for dq accumulating over kv blocks, one for dk/dv
 accumulating over q blocks).
 
-**What one grid step holds** follows from ``T``, ``D``, the dtype and a VMEM
-budget (``_plan``): where a head's whole sequence fits, a step holds several
-heads' q, k, v whole and the body walks heads, the blocks of the *outer* side
-(q for the forward and dq, kv for dkv) and, for each, the blocks of the
-*walked* side it needs: the causal bound of the outer block, so nothing above
-the diagonal costs a grid step or a fetch, and k/v come into VMEM once a
-head. The walk has three forms, one body:
+**What one grid step holds** follows from ``T``, the heads' lanes, the dtype
+and a VMEM budget (``_heads_a_step``, ``_plan``): where the whole sequence of
+several tiles fits, a step holds their q, k, v whole and the body walks the
+step's heads (a rolled loop, one body for them all: the lowering does not
+grow with them), the blocks of the *outer* side (q for the forward and dq, kv
+for dkv) and, for each, the blocks of the *walked* side it needs: the causal
+bound of the outer block, so nothing above the diagonal costs a grid step or
+a fetch, and k/v come into VMEM once a step. The walk has three forms, one
+body:
 
 * *unrolled*, where a head has ``_UNROLL_PAIRS`` pairs of blocks at most (both
   training cells: T 1,024 is 2 x 2 blocks of 512): the loops over outer and
@@ -53,16 +83,20 @@ float32 through ``preferred_element_type``; a float32 cast would force
 their product.
 
 **Row statistics** (the forward's log-sum-exp, the backward's ``delta``) are
-``f32[BN, 1, T]``: lane-dense, 4 bytes a row, written once by the forward and
+``f32[B, N, 1, T]``: lane-dense, 4 bytes a row, written once by the forward and
 read as they are by both backward kernels (they were ``[BN, T, 128]`` with
 128 equal lanes, broadcast again by XLA before the backward: 0.4 GB a layer
-call at GPT-2 125M for 0.4 MB of information). The forward and dq hold a
+call at GPT-2 125M for 0.4 MB of information). ``delta`` is made outside the
+three kernels, by a small fourth (``flash_bwd_delta``: the matrix unit sums a
+head's lanes of ``do * o`` and leaves the sums along lanes); left to XLA the
+reduction takes ``do`` and the saved ``o`` with ``T`` minor, and the kernels
+get a copy of each. The forward and dq hold a
 block's statistics as a column (one a score row) and turn it to and from the
 stored row with one small transpose an outer block; dkv computes its blocks
 transposed (``k q^T``), so a ``[1, blk_q]`` statistic broadcasts along
 sublanes as stored and ``p^T do`` and ``ds^T q`` need no transposed operand.
 
-The three ``pallas_call``s are named (``flash_fwd``, ``flash_bwd_dq``,
+The three attention ``pallas_call``s are named (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``): the name is the custom call's instruction name in the
 compiled HLO and a component of its op name stack, which is how a profiler
 trace finds each kernel (``benchmark/op_scopes.py``); the benchmark's other
@@ -92,7 +126,11 @@ _LANES = 128
 # faster unrolled at T 1,024 and twice the lowering; 256 x 512 and 512 x 256 are slower)
 _BLOCK_Q = 512
 _BLOCK_K = 512
-_MAX_HEADS = 8  # heads a grid step holds at most (1 to 8 time the same)
+# heads a grid step holds at most, lane tile beside lane tile, and the bytes of operand blocks (double-buffered) such a
+# step may take: a grid step costs ~0.3 us whatever it holds, and past ~16 MB the backward's blocks crowd out their own
+# intermediates (dkv at 25 MB a step reads 40% slower at D 128 and at D 64 alike; tools/flash_kernel_bench.py, PR 60)
+_MAX_HEADS = 8
+_STEP_BYTES = 16 << 20
 # a head's walk is unrolled where it has this many pairs of blocks at most: T 2,048 at blocks of 512
 _UNROLL_PAIRS = 16
 # bytes a step's operand blocks (double-buffered by the pipeline) and scratch
@@ -104,12 +142,34 @@ _VMEM_INTERMEDIATES = 24 << 20
 _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 
 
+class OperandLayout(NamedTuple):
+    """How the kernels address q, k, v, o (``operand_layout``)."""
+
+    path: str  # "lanes": [B, T, N * D] as the projections leave it; "head_major": [B * N, T, D], transposed at the door
+    heads_per_lane_tile: int  # heads that share a tile of lanes (a grid step holds several tiles)
+    edge_tile: bool  # the operand's last tile holds fewer heads than that
+
+
+def operand_layout(heads: int, head_dim: int) -> OperandLayout:
+    """The layout ``flash_attention`` takes for ``heads`` heads (those a chip
+    holds) of ``head_dim``: a pure function of the shape, which the entry
+    itself calls and the engine records at build time (``flash.operand_layout``).
+    A head of whole lane tiles is a tile of its own; heads that divide a
+    lane tile share one, as many as fit; any other width (80, 96, ...) keeps
+    the head-major entry, whose tile is a head's full ``D``."""
+    if head_dim % _LANES == 0:
+        return OperandLayout("lanes", 1, False)
+    if _LANES % head_dim == 0:
+        per_tile = min(_LANES // head_dim, heads)
+        return OperandLayout("lanes", per_tile, heads % per_tile != 0)
+    return OperandLayout("head_major", 1, False)
+
+
 class _Plan(NamedTuple):
-    """What a grid step holds: ``heads`` heads, ``outer`` rows of the outer
+    """What a grid step holds of its heads: ``outer`` rows of the outer
     side and ``walked`` rows of the walked side; ``streams`` where the walked
     side takes more than one grid step."""
 
-    heads: int
     outer: int
     walked: int
     streams: bool
@@ -125,31 +185,62 @@ class _How(NamedTuple):
     interpret: bool
     vmem_budget: int
     unroll_pairs: int
+    head_dim: int
 
 
-def _largest_divisor(n: int, limit: int) -> int:
-    """The largest divisor of ``n`` that is at most ``limit``."""
-    return next(d for d in range(min(n, limit), 0, -1) if n % d == 0)
+class _Heads(NamedTuple):
+    """The heads a grid step holds: ``tiles`` tiles side by side, each
+    ``per_tile`` heads of ``dim`` lanes (a lane tile of two heads of 64, or one
+    head of whole lane tiles); ``total`` heads in the operand where its last
+    tile holds fewer than ``per_tile`` (lanes that are not there), else None:
+    every step is full."""
+
+    per_tile: int
+    dim: int
+    tiles: int
+    total: int | None
+
+    @property
+    def lanes(self) -> int:  # of a tile
+        return self.per_tile * self.dim
+
+    @property
+    def a_step(self) -> int:
+        return self.tiles * self.per_tile
 
 
-def _plan(BN, T, D, itemsize, blk_outer, blk_walked, tensors_outer, tensors_walked, vmem_budget) -> _Plan:
-    """A head's whole sequence a step where ``vmem_budget`` holds it (then as
-    many heads as fit), else one outer block and the largest chunk of walked
-    blocks that fits."""
-    row = -(-D // _LANES) * _LANES * itemsize  # a row of a [T, D] block in VMEM: whole lanes
-    stats = 2 * 8 * 4  # two [1, T] float32 statistics at most, a sublane tile high
+def _heads_a_step(lanes: int, head_dim: int, fits) -> tuple[_Heads, int]:
+    """The heads a grid step holds of an operand of ``lanes`` lanes
+    (``N * D``), and the steps that takes: the most tiles that divide the
+    operand's (every step is full: 6 tiles go 3 a step, GPT-2 XL's 13 one a
+    step, which its cell's step reads 0.3% faster than four and a short last
+    step), hold ``_MAX_HEADS`` heads at most and ``fits(lanes of the step)``."""
+    N = lanes // head_dim
+    _, per_tile, ragged = operand_layout(N, head_dim)
+    tile = per_tile * head_dim
+    n_tiles = -(-N // per_tile)
+    most = min(max(1, _MAX_HEADS // per_tile), n_tiles) if tile % _LANES == 0 else 1  # else the tile is the operand's full width
+    tiles = next(t for t in range(most, 0, -1) if n_tiles % t == 0 and (t == 1 or fits(t * tile)))
+    return _Heads(per_tile, head_dim, tiles, N if ragged else None), n_tiles // tiles
 
-    def head_bytes(outer, walked):
-        state = 0 if walked == T else outer * (2 * _LANES + _LANES) * 4  # m, l, accumulators
+
+def _plan(T, W, heads, itemsize, blk_outer, blk_walked, tensors_outer, tensors_walked, vmem_budget) -> _Plan:
+    """The whole sequence of a block of ``W`` lanes (``heads`` heads) a step
+    where ``vmem_budget`` holds it, else one outer block and the largest chunk
+    of walked blocks that fits."""
+    row = -(-W // _LANES) * _LANES * itemsize  # a row of a [T, W] block in VMEM: whole lanes
+    stats = heads * 2 * 8 * 4  # two [1, T] float32 statistics a head at most, a sublane tile high
+
+    def step_bytes(outer, walked):
+        state = 0 if walked == T else heads * outer * (2 * _LANES + row // itemsize) * 4  # m, l, accumulators
         return 2 * (tensors_outer * outer + tensors_walked * walked) * row + 2 * stats * max(outer, walked) + state
 
     outer, walked = T, T
-    if head_bytes(T, T) > vmem_budget:
+    if step_bytes(T, T) > vmem_budget:
         outer = blk_outer
-        fits = [c for c in range(blk_walked, T + 1, blk_walked) if T % c == 0 and head_bytes(outer, c) <= vmem_budget]
+        fits = [c for c in range(blk_walked, T + 1, blk_walked) if T % c == 0 and step_bytes(outer, c) <= vmem_budget]
         walked = max(fits, default=blk_walked)
-    heads = _largest_divisor(BN, min(_MAX_HEADS, max(1, vmem_budget // head_bytes(outer, walked))))
-    return _Plan(heads, outer, walked, walked != T)
+    return _Plan(outer, walked, walked != T)
 
 
 def _span(i, blk):
@@ -226,25 +317,82 @@ def _leave(carry, state, g, last, finish):
     pl.when(last)(finish)
 
 
+def _heads_here(heads: _Heads):
+    """Heads in this grid step: fewer in the operand's last step where the
+    heads do not fill it (GPT-2 XL's 25 heads of 64 are 12.5 lane tiles), so
+    the walk over heads never enters a head that is not there."""
+    if heads.total is None:
+        return heads.a_step
+    return lax.min(heads.a_step, lax.sub(heads.total, lax.mul(pl.program_id(1), heads.a_step)))
+
+
+def _head_in_step(g, n_here, heads: _Heads):
+    """Of head ``g`` of a grid step: its tile's lanes in the step's blocks (a
+    slice at a whole number of tiles), its place ``h`` in the tile, and the
+    lanes of its tile that hold heads (None where every tile is full)."""
+    if heads.tiles == 1:
+        lanes, h = slice(None), g
+    else:
+        tile = lax.div(g, heads.per_tile)
+        lanes, h = pl.ds(pl.multiple_of(lax.mul(tile, heads.lanes), heads.lanes), heads.lanes), lax.rem(g, heads.per_tile)
+    return lanes, h, lax.mul(lax.sub(n_here, lax.sub(g, h)), heads.dim) if heads.total is not None else None
+
+
+def _lanes_of(x, lo, hi, other=None):
+    """``x`` on lanes ``lo .. hi``, ``other`` (0) on the rest: a select, never
+    a product by a 0/1 mask (what lies beyond an operand's last lane is
+    unspecified, and 0 x NaN is NaN)."""
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lax.bitwise_and(lax.ge(lane, lo), lax.lt(lane, hi)), x, jnp.zeros_like(x) if other is None else other)
+
+
+def _head_lanes(x, h, heads: _Heads):
+    """Head ``h``'s lanes of a tile, the other heads' zeroed: a contraction
+    over all the tile's lanes against it is head ``h``'s alone, at the cost
+    the contraction over ``dim`` lanes has on a unit 128 deep. It is taken on
+    the outer side's operands, once an outer block."""
+    if heads.per_tile == 1:
+        return x
+    return _lanes_of(x, lax.mul(h, heads.dim), lax.mul(lax.add(h, 1), heads.dim))
+
+
+def _real_lanes(x, real):
+    """A walked block with the lanes beyond the operand's last head zeroed
+    (they meet the outer side's zeros in a contraction, and must be numbers);
+    the block as it is where every tile is full (``real`` None)."""
+    return x if real is None else _lanes_of(x, 0, real)
+
+
+def _store_head(ref, rows, lanes, x, h, heads: _Heads):
+    """Head ``h``'s lanes of ``x`` into its tile of ``ref[0, rows]``, the
+    tile's other heads left as they are."""
+    if heads.per_tile > 1:
+        x = _lanes_of(x, lax.mul(h, heads.dim), lax.mul(lax.add(h, 1), heads.dim), other=ref[0, rows, lanes])
+    ref[0, rows, lanes] = x
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal, blk_q, blk_k, unrolled):
-    G, Lq, D = q_ref.shape
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal, blk_q, blk_k, unrolled, heads):
+    Lq, W = q_ref.shape[1], heads.lanes
     nkb = k_ref.shape[1] // blk_k
-    row_base = lax.mul(pl.program_id(1), Lq)  # this step's first query
-    col_base = lax.mul(pl.program_id(2), k_ref.shape[1])  # and first key
-    first, last = pl.program_id(2) == 0, pl.program_id(2) == pl.num_programs(2) - 1
+    row_base = lax.mul(pl.program_id(2), Lq)  # this step's first query
+    col_base = lax.mul(pl.program_id(3), k_ref.shape[1])  # and first key
+    first, last = pl.program_id(3) == 0, pl.program_id(3) == pl.num_programs(3) - 1
+    n_here = _heads_here(heads)
 
     def head(g, _):
+        lanes, h, real = _head_in_step(g, n_here, heads)
+
         def q_block(qb, _):
             rows = _span(qb, blk_q)
             row0 = lax.add(row_base, lax.mul(qb, blk_q))
-            q = _scaled(q_ref[g, rows, :], scale)
+            q = _head_lanes(_scaled(q_ref[0, rows, lanes], scale), h, heads)
             carry = (
                 jnp.full((blk_q, 1), NEG_INF, jnp.float32),
                 jnp.zeros((blk_q, 1), jnp.float32),
-                jnp.zeros((blk_q, D), jnp.float32),
+                jnp.zeros((blk_q, W), jnp.float32),  # head h's lanes are its output; the others are not read
             )
             carry = _resume(carry, state, g, first)
 
@@ -252,8 +400,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal, blk_
                 def kv_block(kb, carry):
                     m, l, acc = carry
                     cols = _span(kb, blk_k)
-                    v = v_ref[g, cols, :]
-                    s = lax.dot_general(q, k_ref[g, cols, :], _NT, preferred_element_type=jnp.float32)
+                    v = v_ref[0, cols, lanes]
+                    s = lax.dot_general(q, _real_lanes(k_ref[0, cols, lanes], real), _NT, preferred_element_type=jnp.float32)
                     if masked:
                         col0 = lax.add(col_base, lax.mul(kb, blk_k))
                         s = jnp.where(_below_diagonal(row0, col0, s.shape, 0), s, NEG_INF)
@@ -272,48 +420,51 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal, blk_
 
             def finish():
                 safe_l = jnp.where(l == 0, 1.0, l)
-                o_ref[g, rows, :] = (acc / safe_l).astype(o_ref.dtype)
-                lse_ref[g, :, rows] = _as_row(m + jnp.log(safe_l))
+                _store_head(o_ref, rows, lanes, (acc / safe_l).astype(o_ref.dtype), h, heads)
+                lse_ref[0, g, :, rows] = _as_row(m + jnp.log(safe_l))
 
             _leave((m, l, acc), state, g, last, finish)
             return _
 
         return lax.fori_loop(0, Lq // blk_q, q_block, _, unroll=unrolled)
 
-    lax.fori_loop(0, G, head, None)
+    lax.fori_loop(0, n_here, head, None)
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *state, scale, causal, blk_q, blk_k, unrolled):
-    G, Lq, D = q_ref.shape
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *state, scale, causal, blk_q, blk_k, unrolled, heads):
+    Lq, W = q_ref.shape[1], heads.lanes
     nkb = k_ref.shape[1] // blk_k
-    row_base = lax.mul(pl.program_id(1), Lq)
-    col_base = lax.mul(pl.program_id(2), k_ref.shape[1])
-    first, last = pl.program_id(2) == 0, pl.program_id(2) == pl.num_programs(2) - 1
+    row_base = lax.mul(pl.program_id(2), Lq)
+    col_base = lax.mul(pl.program_id(3), k_ref.shape[1])
+    first, last = pl.program_id(3) == 0, pl.program_id(3) == pl.num_programs(3) - 1
+    n_here = _heads_here(heads)
 
     def head(g, _):
+        lanes, h, real = _head_in_step(g, n_here, heads)
+
         def q_block(qb, _):
             rows = _span(qb, blk_q)
             row0 = lax.add(row_base, lax.mul(qb, blk_q))
-            q = _scaled(q_ref[g, rows, :], scale)
-            do = do_ref[g, rows, :]
-            lse = _as_col(lse_ref[g, :, rows])
-            delta = _as_col(delta_ref[g, :, rows])
-            carry = _resume((jnp.zeros((blk_q, D), jnp.float32),), state, g, first)
+            q = _head_lanes(_scaled(q_ref[0, rows, lanes], scale), h, heads)
+            do = _head_lanes(do_ref[0, rows, lanes], h, heads)
+            lse = _as_col(lse_ref[0, g, :, rows])
+            delta = _as_col(delta_ref[0, g, :, rows])
+            carry = _resume((jnp.zeros((blk_q, W), jnp.float32),), state, g, first)
 
             def step(masked):
                 def kv_block(kb, carry):
                     (dq,) = carry
                     cols = _span(kb, blk_k)
-                    k = k_ref[g, cols, :]
+                    k = _real_lanes(k_ref[0, cols, lanes], real)
                     s = lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
                     if masked:
                         col0 = lax.add(col_base, lax.mul(kb, blk_k))
                         s = jnp.where(_below_diagonal(row0, col0, s.shape, 0), s, NEG_INF)
                     p = jnp.exp(s - lse)
-                    dp = lax.dot_general(do, v_ref[g, cols, :], _NT, preferred_element_type=jnp.float32)
+                    dp = lax.dot_general(do, _real_lanes(v_ref[0, cols, lanes], real), _NT, preferred_element_type=jnp.float32)
                     ds = (p * (dp - delta)).astype(k.dtype)
                     return (dq + lax.dot(ds, k, preferred_element_type=jnp.float32),)
 
@@ -323,46 +474,49 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *state, 
             (dq,) = _walk(nkb, 0, n_full, n_any, False, step, carry, unrolled)
 
             def finish():
-                dq_ref[g, rows, :] = (dq * scale).astype(dq_ref.dtype)
+                _store_head(dq_ref, rows, lanes, (dq * scale).astype(dq_ref.dtype), h, heads)
 
             _leave((dq,), state, g, last, finish)
             return _
 
         return lax.fori_loop(0, Lq // blk_q, q_block, _, unroll=unrolled)
 
-    lax.fori_loop(0, G, head, None)
+    lax.fori_loop(0, n_here, head, None)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *state, scale, causal, blk_q, blk_k, unrolled):
-    G, Lk, D = k_ref.shape
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *state, scale, causal, blk_q, blk_k, unrolled, heads):
+    Lk, W = k_ref.shape[1], heads.lanes
     nqb = q_ref.shape[1] // blk_q
-    col_base = lax.mul(pl.program_id(1), Lk)
-    row_base = lax.mul(pl.program_id(2), q_ref.shape[1])
-    first, last = pl.program_id(2) == 0, pl.program_id(2) == pl.num_programs(2) - 1
+    col_base = lax.mul(pl.program_id(2), Lk)
+    row_base = lax.mul(pl.program_id(3), q_ref.shape[1])
+    first, last = pl.program_id(3) == 0, pl.program_id(3) == pl.num_programs(3) - 1
+    n_here = _heads_here(heads)
 
     def head(g, _):
+        lanes, h, real = _head_in_step(g, n_here, heads)
+
         def kv_block(kb, _):
             cols = _span(kb, blk_k)
             col0 = lax.add(col_base, lax.mul(kb, blk_k))
-            k = _scaled(k_ref[g, cols, :], scale)
-            v = v_ref[g, cols, :]
-            carry = _resume((jnp.zeros((blk_k, D), jnp.float32), jnp.zeros((blk_k, D), jnp.float32)), state, g, first)
+            k = _head_lanes(_scaled(k_ref[0, cols, lanes], scale), h, heads)
+            v = _head_lanes(v_ref[0, cols, lanes], h, heads)
+            carry = _resume((jnp.zeros((blk_k, W), jnp.float32), jnp.zeros((blk_k, W), jnp.float32)), state, g, first)
 
             def step(masked):
                 def q_block(qb, carry):
                     dk, dv = carry
                     rows = _span(qb, blk_q)
-                    q = q_ref[g, rows, :]
-                    do = do_ref[g, rows, :]
+                    q = _real_lanes(q_ref[0, rows, lanes], real)
+                    do = _real_lanes(do_ref[0, rows, lanes], real)
                     # the block transposed, [blk_k, blk_q]: a query's statistic is a lane's
                     s = lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
                     if masked:
                         row0 = lax.add(row_base, lax.mul(qb, blk_q))
                         s = jnp.where(_below_diagonal(row0, col0, s.shape, 1), s, NEG_INF)
-                    p = jnp.exp(s - lse_ref[g, :, rows])
+                    p = jnp.exp(s - lse_ref[0, g, :, rows])
                     dv = dv + lax.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
                     dp = lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
-                    ds = (p * (dp - delta_ref[g, :, rows])).astype(q.dtype)
+                    ds = (p * (dp - delta_ref[0, g, :, rows])).astype(q.dtype)
                     return dk + lax.dot(ds, q, preferred_element_type=jnp.float32), dv
 
                 return q_block
@@ -372,75 +526,87 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
             dk, dv = _walk(nqb, n_none, n_masked, nqb, True, step, carry, unrolled)
 
             def finish():
-                dk_ref[g, cols, :] = (dk * scale).astype(dk_ref.dtype)
-                dv_ref[g, cols, :] = dv.astype(dv_ref.dtype)
+                _store_head(dk_ref, cols, lanes, (dk * scale).astype(dk_ref.dtype), h, heads)
+                _store_head(dv_ref, cols, lanes, dv.astype(dv_ref.dtype), h, heads)
 
             _leave((dk, dv), state, g, last, finish)
             return _
 
         return lax.fori_loop(0, Lk // blk_k, kv_block, _, unroll=unrolled)
 
-    lax.fori_loop(0, G, head, None)
+    lax.fori_loop(0, n_here, head, None)
 
 
-def _call(kernel, name, operands, stats, out_dtypes, q_outer, state_cols, how: _How):
-    """One of the three kernels over ``[BN // heads, outer steps, walked
-    steps]``. ``operands`` are ``("q" | "k", [BN, T, D] array)``: the side whose
-    rows the operand follows; ``stats`` are the ``[BN, 1, T]`` row statistics
-    it reads (it writes one where it reads none: the forward); the results
-    follow the outer side, the queries' where ``q_outer``; ``state_cols``
-    are the widths of what a streaming walk carries from step to step."""
-    scale, causal, blk_q, blk_k, interpret, vmem_budget, unroll_pairs = how
-    BN, T, D = operands[0][1].shape
+def _call(kernel, name, operands, stats, out_dtypes, q_outer, state_wide, how: _How):
+    """One of the three kernels over ``[B, steps of heads, outer steps,
+    walked steps]``. ``operands`` are ``("q" | "k", [B, T, N * D] array)``: the
+    side whose rows the operand follows; ``stats`` are the ``[B, N, 1, T]`` row
+    statistics it reads (it writes one where it reads none: the forward); the
+    results follow the outer side, the queries' where ``q_outer``;
+    ``state_wide`` says of each value a streaming walk carries from step to
+    step whether it is a tile's lanes wide or one column."""
+    scale, causal, blk_q, blk_k, interpret, vmem_budget, unroll_pairs, D = how
+    B, T, ND = operands[0][1].shape
     outer_side = "q" if q_outer else "k"
     blk_outer, blk_walked = (blk_q, blk_k) if q_outer else (blk_k, blk_q)
     held_outer = sum(side == outer_side for side, _ in operands) + len(out_dtypes)  # tensors whose outer rows a step holds
-    G, Lo, Lw, streams = _plan(
-        BN, T, D, operands[0][1].dtype.itemsize, blk_outer, blk_walked, held_outer, len(operands) + len(out_dtypes) - held_outer, vmem_budget
+
+    def plan(step_lanes):
+        return _plan(
+            T, step_lanes, max(1, step_lanes // D), operands[0][1].dtype.itemsize, blk_outer, blk_walked,
+            held_outer, len(operands) + len(out_dtypes) - held_outer, vmem_budget,
+        )
+
+    itemsize = operands[0][1].dtype.itemsize
+    heads, steps = _heads_a_step(
+        ND, D, lambda step_lanes: not plan(step_lanes).streams and 2 * (len(operands) + len(out_dtypes)) * T * step_lanes * itemsize <= _STEP_BYTES
     )
+    W = heads.tiles * heads.lanes  # a step's lanes
+    Lo, Lw, streams = plan(W)
 
-    def outer_map(b, i, j):
-        return (b, i, 0)
+    def outer_map(b, t, i, j):
+        return (b, i, t)
 
-    def walked_map(b, i, j):
+    def walked_map(b, t, i, j):
         if causal and streams:
             # a chunk the outer block sees nothing of is not fetched: the map stays on the nearest it needs
             if q_outer:
                 j = lax.min(j, lax.div(lax.add(lax.mul(i, Lo), Lo - 1), Lw))
             else:
                 j = lax.max(j, lax.div(lax.mul(i, Lo), Lw))
-        return (b, j, 0)
+        return (b, j, t)
 
-    def stat_map(b, i, j):
-        return (b, 0, (outer_map if q_outer else walked_map)(b, i, j)[1])
+    def stat_map(b, t, i, j):
+        return (b, t, 0, (outer_map if q_outer else walked_map)(b, t, i, j)[1])
 
-    stat_spec = pl.BlockSpec((G, 1, Lo if q_outer else Lw), stat_map)
+    stat_spec = pl.BlockSpec((1, heads.a_step, 1, Lo if q_outer else Lw), stat_map)
     in_specs = [
-        pl.BlockSpec((G, Lo, D), outer_map) if side == outer_side else pl.BlockSpec((G, Lw, D), walked_map)
+        pl.BlockSpec((1, Lo, W), outer_map) if side == outer_side else pl.BlockSpec((1, Lw, W), walked_map)
         for side, _ in operands
     ] + [stat_spec] * len(stats)
-    out_specs = [pl.BlockSpec((G, Lo, D), outer_map) for _ in out_dtypes]
-    out_shape = [jax.ShapeDtypeStruct((BN, T, D), dtype) for dtype in out_dtypes]
+    out_specs = [pl.BlockSpec((1, Lo, W), outer_map) for _ in out_dtypes]
+    out_shape = [jax.ShapeDtypeStruct((B, T, ND), dtype) for dtype in out_dtypes]
     if not stats:
         out_specs.append(stat_spec)
-        out_shape.append(jax.ShapeDtypeStruct((BN, 1, T), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((B, ND // D, 1, T), jnp.float32))
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_budget + _VMEM_INTERMEDIATES,
         )
     return pl.pallas_call(
         functools.partial(
             kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-            # only a whole head a step has constant block indices to unroll over
+            # only a whole sequence a step has constant block indices to unroll over
             unrolled=Lo == Lw == T and (T // blk_outer) * (T // blk_walked) <= unroll_pairs,
+            heads=heads,
         ),
-        grid=(BN // G, T // Lo, T // Lw),
+        grid=(B, steps, T // Lo, T // Lw),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((G, Lo, cols), jnp.float32) for cols in state_cols] if streams else [],
+        scratch_shapes=[pltpu.VMEM((heads.a_step, Lo, heads.lanes if wide else 1), jnp.float32) for wide in state_wide] if streams else [],
         interpret=interpret,
         name=name,
         **params,
@@ -448,17 +614,59 @@ def _call(kernel, name, operands, stats, out_dtypes, q_outer, state_cols, how: _
 
 
 def _flash_fwd(q, k, v, how):
-    D = q.shape[-1]
-    return _call(_fwd_kernel, "flash_fwd", [("q", q), ("k", k), ("k", v)], [], [q.dtype], True, (1, 1, D), how)
+    return _call(_fwd_kernel, "flash_fwd", [("q", q), ("k", k), ("k", v)], [], [q.dtype], True, (False, False, True), how)
+
+
+def _delta_kernel(do_ref, o_ref, delta_ref, *, heads):
+    """``delta[g, t] = sum over head g's lanes of do[t] * o[t]``, float32. The
+    matrix unit sums (an indicator row a head against the product, contracted
+    over the lanes), which leaves a head's sums along lanes: the row statistic
+    the two backward kernels read, with no transpose."""
+    prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    if heads.total is not None:
+        prod = _lanes_of(prod, 0, lax.mul(_heads_here(heads), heads.dim))
+    shape = (-(-heads.a_step // 8) * 8, prod.shape[1])  # whole sublane tiles of indicator rows
+    head = lax.broadcasted_iota(jnp.int32, shape, 0)
+    mine = _lanes_of(jnp.ones(shape, jnp.float32), lax.mul(head, heads.dim), lax.mul(lax.add(head, 1), heads.dim))
+    sums = lax.dot_general(mine, prod, _NT, precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+    for g in range(heads.a_step):
+        delta_ref[0, g] = sums[g : g + 1, :]
+
+
+def _flash_delta(do, o, how: _How):
+    """The backward's second row statistic, ``[B, N, 1, T]`` as ``lse`` is. A
+    kernel of its own and not a reduction of XLA's: that one wants the summed
+    lanes off the minor dimension, takes ``do`` and the saved ``o`` in a
+    layout with ``T`` minor for it, and the backward kernels then get a copy
+    of each (compiled for a v5e: two of the copies this layout exists to avoid)."""
+    B, T, ND = do.shape
+
+    def rows_of(step_lanes):  # the whole sequence where two operands' two buffers of it and the float32 product fit
+        held = T * -(-step_lanes // _LANES) * _LANES * (2 * 2 * do.dtype.itemsize + 2 * 4)
+        return T if held <= how.vmem_budget // 2 else how.blk_q
+
+    heads, steps = _heads_a_step(ND, how.head_dim, lambda step_lanes: rows_of(step_lanes) == T)
+    W = heads.tiles * heads.lanes
+    rows = rows_of(W)
+    operand = pl.BlockSpec((1, rows, W), lambda b, t, i: (b, i, t))
+    return pl.pallas_call(
+        functools.partial(_delta_kernel, heads=heads),
+        grid=(B, steps, T // rows),
+        in_specs=[operand, operand],
+        out_specs=pl.BlockSpec((1, heads.a_step, 1, rows), lambda b, t, i: (b, t, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((B, ND // how.head_dim, 1, T), jnp.float32),
+        interpret=how.interpret,
+        name="flash_bwd_delta",
+        **({} if how.interpret else {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=how.vmem_budget + _VMEM_INTERMEDIATES)}),
+    )(do, o)
 
 
 def _flash_bwd(how, res, do):
     q, k, v, o, lse = res
-    D = q.shape[-1]
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, None, :]  # [BN, 1, T], as lse is
+    delta = _flash_delta(do, o, how)
     operands = [("q", q), ("k", k), ("k", v), ("q", do)]
-    (dq,) = _call(_dq_kernel, "flash_bwd_dq", operands, [lse, delta], [q.dtype], True, (D,), how)
-    dk, dv = _call(_dkv_kernel, "flash_bwd_dkv", operands, [lse, delta], [k.dtype, v.dtype], False, (D, D), how)
+    (dq,) = _call(_dq_kernel, "flash_bwd_dq", operands, [lse, delta], [q.dtype], True, (True,), how)
+    dk, dv = _call(_dkv_kernel, "flash_bwd_dkv", operands, [lse, delta], [k.dtype, v.dtype], False, (True, True), how)
     return dq, dk, dv
 
 
@@ -501,6 +709,14 @@ def flash_attention(
 ):
     """Fused attention over [B, T, N, D] (heads-last layout like the model).
 
+    The kernels read q, k, v and write o where the model's projections leave
+    and take them: ``[B, T, N * D]``, of which ``[B, T, N, D]`` is a free
+    reshape, tiles of lanes a grid step (``operand_layout``: two heads of 64 a
+    tile, a head of 128), so no head-major copy stands around the calls, forward or
+    backward, and the residuals are saved as they came. Only a head width
+    that neither divides a lane tile nor is divided by one is transposed to
+    ``[B * N, T, D]`` at the door.
+
     GQA inputs (fewer kv heads) must be pre-expanded by the caller. The
     sequence is padded up to the block size; padded kv columns sit above the
     causal diagonal of every real row, and padded q rows are sliced off on
@@ -532,11 +748,14 @@ def _flash_attention(q, k, v, causal, scale, block_q, block_k, interpret, vmem_b
     if pad:
         q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v))
 
-    def to_bn(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * N, padded_T, D)
-
-    o = _flash_core(to_bn(q), to_bn(k), to_bn(v), _How(float(scale), causal, blk_q, blk_k, interpret, vmem_budget, unroll_pairs))
-    o = o.reshape(B, N, padded_T, D).transpose(0, 2, 1, 3)
+    if operand_layout(N, D).path == "lanes":
+        enter = lambda x: x.reshape(B, padded_T, N * D)
+        leave = lambda o: o.reshape(B, padded_T, N, D)
+    else:  # every head an operand row of its own: [B * N, T, 1 * D]
+        enter = lambda x: x.transpose(0, 2, 1, 3).reshape(B * N, padded_T, D)
+        leave = lambda o: o.reshape(B, N, padded_T, D).transpose(0, 2, 1, 3)
+    how = _How(float(scale), causal, blk_q, blk_k, interpret, vmem_budget, unroll_pairs, D)
+    o = leave(_flash_core(enter(q), enter(k), enter(v), how))
     if pad:
         o = o[:, :T]
     return o

@@ -1099,6 +1099,7 @@ class DeepSpeedEngine:
         # holds it bit-identical. qwZ/qgZ own their gather/reduce wire
         # formats and stay unpipelined.
         self._overlap_plan = self._build_overlap_plan(qwz=qwz, qgz=qgz)
+        self._record_flash_operand_layout()
         if self._overlap_plan is not None:
             from deepspeed_tpu.runtime.zero.overlap import overlap_scope
 
@@ -1588,6 +1589,16 @@ class DeepSpeedEngine:
                 ),
                 **step_jit_extra,
             )
+
+    def _record_flash_operand_layout(self) -> None:
+        """Which layout the flash kernels take q, k, v and give o in for this
+        model on this mesh, said once where the step is built (the ops have
+        no tracer): nothing in the step."""
+        from deepspeed_tpu.models.transformer import flash_operand_layout
+
+        record = flash_operand_layout(getattr(self.module, "config", None), self.topology)
+        if record is not None:
+            self.tracer.event("flash.operand_layout", **record)
 
     def _build_overlap_plan(self, qwz: bool, qgz: bool):
         """Comm-overlap plan for the scanned layer stack, or None.

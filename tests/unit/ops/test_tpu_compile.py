@@ -138,6 +138,8 @@ CASES = {
     "flash_bwd_gpt2_125m": (_bwd(_flash_fwd), _TRAIN_QKV),
     "flash_fwd_gpt2_xl": (_flash_fwd, _TRAIN_XL_QKV),
     "flash_bwd_gpt2_xl": (_bwd(_flash_fwd), _TRAIN_XL_QKV),
+    # heads of 128 at T 4,096 (tools/flash_kernel_bench.py's last shape): what a grid step holds is cut to the VMEM budget
+    "flash_bwd_d128_t4096": (_bwd(_flash_fwd), [((1, 4096, 32, 128), BF16)] * 3),
     "ragged_w1_llama_1b": (
         _ragged,
         [((8, 1, 32, 64), BF16)] + [((8, 1, 4, 64), BF16)] * 2 + [_PAGES, _PAGES, _TABLE, _LENS, _LENS],
@@ -340,13 +342,51 @@ def test_flash_backward_is_what_the_benchmark_reads(v5e):
     as_traced.print_operand_shape = as_traced.print_percent = True
     (module,) = jax.jit(_bwd(_flash_fwd)).lower(*args).compile().runtime_executable().hlo_modules()
     text = module.to_string(as_traced)
-    assert "f32[96,1024,128]" not in text
-    assert "f32[96,1,1024]" in text
+    assert "f32[96,1024,128]" not in text and "f32[8,12,1024,128]" not in text
+    assert "f32[8,12,1,1024]" in text
     calls = [_LAYOUT.sub("", line) for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
     found = {kind: [c for c in calls if re.search(pattern, c)] for kind, pattern in read.EVENTS.items()}
     assert {kind: len(c) for kind, c in found.items()} == {"forward": 1, "backward_dq": 1, "backward_dkv": 1}, calls
     for kind, name in (("forward", "flash_fwd"), ("backward_dq", "flash_bwd_dq"), ("backward_dkv", "flash_bwd_dkv")):
         assert name in found[kind][0].split(" = ")[0], found[kind][0][:120]
+
+
+@pytest.mark.parametrize("heads, remat", [(12, False), (25, True)], ids=["gpt2_125m", "gpt2_xl_a_chip"])
+def test_no_copy_of_q_k_v_or_o_stands_beside_a_flash_call(v5e, heads, remat):
+    """A scan of two layers, forward + backward, of what stands around
+    training's attention (the three projections, the flash kernels, ``wo``,
+    the residual) at both cells' shapes a chip (8 x 1,024 tokens, 12 and 25
+    heads of 64; XL's layer rematerialised as its cell's is): the kernels take
+    q, k, v and ``do`` and give o, dq, dk, dv as ``[B, T, N * D]``, where the
+    matrix products leave and take them, so the compiled program holds no
+    ``copy`` or ``transpose`` of a tensor of that size in any shape or layout.
+    Until PR 60 the kernels were entered head-major and this program held 12
+    of them a layer at either shape (``bf16[8,12,1024,64]``, ``[8,1024,25,64]``,
+    ``[8,25,1024,64]``; 9 and 14 in the cells' own compiled steps, 35.2 ms of
+    GPT-2 XL's 880 ms). The backward's ``delta`` is a kernel for the same
+    reason: a reduction of XLA's over a head's lanes takes ``do`` and the
+    saved ``o`` with ``T`` minor, and each is then copied for the kernels."""
+    B, T, D, L = 8, 1024, 64, 2
+    H = heads * D
+
+    def layer(x, w):
+        q, k, v = ((x @ w[i]).reshape(B, T, heads, D) for i in range(3))
+        return x + _flash_fwd(q, k, v).reshape(B, T, H) @ w[3], None
+
+    def loss(x, ws):
+        return jax.lax.scan(jax.checkpoint(layer) if remat else layer, x, ws)[0].astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((B, T, H), BF16, sharding=v5e)
+    ws = jax.ShapeDtypeStruct((L, 4, H, H), BF16, sharding=v5e)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, ws).compile().as_text()
+    for kernel, calls in {"flash_fwd": 2 if remat else 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}.items():
+        assert len(re.findall(rf"%{kernel}[\w.]* = .* custom-call\(", text)) == calls, kernel
+    moved = [
+        m.group(0)
+        for m in re.finditer(r"%[\w.$-]+ = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
+        if np.prod([int(d) for d in m.group(1).split(",")]) == B * T * H
+    ]
+    assert not moved, moved
 
 
 _MISTRAL_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/mistral-7b-v0.3-l16.json"

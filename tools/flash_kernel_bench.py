@@ -1,28 +1,31 @@
 """The three flash-attention kernels alone, on the chip, at the training
 cells' shapes (``deepspeed_tpu/ops/transformer/flash_attention.py``): forward
 and backward of ``CALLS`` causal calls in one program, as a layer scan makes
-them, for
+them, entered as the model enters them (q, k, v ``[B, T, N * D]`` as a
+projection writes them, reshaped for nothing to ``[B, T, N, D]`` at the
+door), for
 
-* ``96x1024x64``: GPT-2 125M, 8 sequences x 12 heads (``gpt2_125m_zero1_train``),
-* ``200x1024x64``: GPT-2 XL, 8 x 25 a chip (``gpt2_xl_zero3_dp4_train``),
-* ``64x1024x128``, ``32x4096x128``: heads of 128 (Llama / Mistral families;
+* ``8x12x1024x64``: GPT-2 125M, 8 sequences x 12 heads (``gpt2_125m_zero1_train``),
+* ``8x25x1024x64``: GPT-2 XL, 8 x 25 a chip (``gpt2_xl_zero3_dp4_train``),
+* ``1x64x1024x128``, ``1x32x4096x128``: heads of 128 (Llama / Mistral families;
   no cell trains one, so this is their only guard),
 
 and prints, from the profiler's trace of that program, the microseconds a call
 of ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv`` against the least
 the chip could take (``benchmark/kernels/flash_attention.py::min_seconds``),
-the milliseconds the whole forward + backward takes on the host's clock (the
-layout transposes around the kernels included), and the seconds the program
-took to trace and to lower (what every process pays at set-up, whatever the
-compilation cache holds). It is how the constants at the head of the kernels'
-file were chosen: each ``--set`` runs the shapes once more with other values
-of them.
+the three summed beside the milliseconds the whole forward + backward takes
+on the host's clock and what the device spends of the difference in ``copy``
+and ``transpose`` instructions (the head-major layout's price, where a
+checkout pays one), and the seconds the program took to trace and to lower
+(what every process pays at set-up, whatever the compilation cache holds). It
+is how the constants at the head of the kernels' file were chosen: each
+``--set`` runs the shapes once more with other values of them.
 
 Run it on the parent's copy and on the change's side by side (``--root``: the
 checkout whose ``deepspeed_tpu`` is imported; only the public
 ``flash_attention`` is called, so any checkout runs).
 
-    chiprun -- python3 tools/flash_kernel_bench.py [--root DIR] [--shapes 96x1024x64,...] [--set _BLOCK_Q=256,_BLOCK_K=256 --set _MAX_HEADS=1 ...]
+    chiprun -- python3 tools/flash_kernel_bench.py [--root DIR] [--shapes 8x12x1024x64,...] [--set _BLOCK_Q=256,_BLOCK_K=256 --set _MAX_HEADS=2 ...]
     python3 tools/flash_kernel_bench.py --rehearse      # tiny, on the CPU: the control flow only
 """
 
@@ -31,34 +34,41 @@ from __future__ import annotations
 import argparse
 import collections
 import os
+import re
 import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CALLS = 20
-SHAPES = "96x1024x64,200x1024x64,64x1024x128,32x4096x128"
+SHAPES = "8x12x1024x64,8x25x1024x64,1x64x1024x128,1x32x4096x128"
 # the program's names -> the kinds of benchmark/kernels/flash_attention.py; the longest name first, a name is matched once
 KERNELS = {"flash_bwd_dkv": "backward_dkv", "flash_bwd_dq": "backward_dq", "flash_fwd": "forward"}
 
 
 def kernel_us(trace_dir: str, calls: int):
-    """{kernel: (microseconds a call, events a call)} from the newest trace under ``trace_dir``."""
+    """``({kernel: (microseconds a call, events a call)}, microseconds a call
+    in copy and transpose instructions, their events a call)`` from the newest
+    trace under ``trace_dir``."""
     from mixed_step_bench import device_ops  # the sibling tool's reading of a trace's device ops
 
     us, events = collections.Counter(), collections.Counter()
     for ms, n, text in device_ops(trace_dir, calls, top=None):
-        kernel = next((k for k in KERNELS if k in text.split(" = ")[0]), None)
+        name, _, rest = text.partition(" = ")
+        kernel = next((k for k in KERNELS if k in name), None)
         if kernel and "custom-call" in text:
             us[kernel] += ms * 1e3
             events[kernel] += n
-    return {k: (us[k], events[k]) for k in KERNELS}
+        elif re.match(r"\S+ (copy|transpose)\(", rest):
+            us["moved"] += ms * 1e3
+            events["moved"] += n
+    return {k: (us[k], events[k]) for k in KERNELS}, us["moved"], events["moved"]
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--root", default=ROOT, help="the checkout whose deepspeed_tpu is measured")
-    ap.add_argument("--shapes", default=SHAPES, help="BNxTxD, comma-separated")
+    ap.add_argument("--shapes", default=SHAPES, help="BxNxTxD, comma-separated")
     ap.add_argument(
         "--set", action="append", default=[], metavar="NAME=INT,...",
         help="constants of the kernels' file to try in place of the file's, e.g. _BLOCK_Q=256,_BLOCK_K=256; may be given more than once",
@@ -77,15 +87,15 @@ def main() -> None:
 
     module = sys.modules[flash_attention.__module__]  # the package rebinds the module's name to the function
     calls = 2 if args.rehearse else CALLS
-    shapes = [(4, 256, 64)] if args.rehearse else [tuple(int(n) for n in s.split("x")) for s in args.shapes.split(",")]
+    shapes = [(2, 3, 256, 64)] if args.rehearse else [tuple(int(n) for n in s.split("x")) for s in args.shapes.split(",")]
     peak = files.load_json(files.HERE, "peaks.json")["TPU v5 lite" if args.rehearse else jax.devices()[0].device_kind]
     tries = [{name: int(value) for name, value in (pair.split("=") for pair in t.split(","))} for t in args.set]
     the_files = {name: getattr(module, name) for t in tries for name in t}  # AttributeError: a checkout without the constant
     print(f"root {os.path.abspath(args.root)} on {jax.devices()[0].device_kind}", flush=True)
-    for BN, T, D in shapes:
-        # [B, T, N, D] as the model calls it: one sequence, BN heads
+    for B, N, T, D in shapes:
+        # [B, T, N * D] as a projection leaves it
         q, k, v, do = (
-            jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(0), i), (1, T, BN, D), jnp.bfloat16) for i in range(4)
+            jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(0), i), (B, T, N * D), jnp.bfloat16) for i in range(4)
         )
         for tried in tries or [{}]:
             for name, value in {**the_files, **tried}.items():
@@ -98,8 +108,11 @@ def main() -> None:
                 # CALLS layers back to back in one program: forward, then the backward of a cotangent. Each
                 # call's operands come from the call before (a small step along the gradient), or the
                 # compiler lifts the kernels out of the loop.
+                def attention(*qkv):
+                    return flash_attention(*(x.reshape(B, T, N, D) for x in qkv), causal=True, **limits).reshape(B, T, N * D)
+
                 def body(i, qkv):
-                    o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True, **limits), *qkv)
+                    o, vjp = jax.vjp(attention, *qkv)
                     return tuple(x - (1e-3 * g.astype(jnp.float32) + 1e-3 * o.astype(jnp.float32)).astype(x.dtype) for x, g in zip(qkv, vjp(do)))
 
                 return jax.lax.fori_loop(0, calls, body, (q, k, v))
@@ -118,7 +131,7 @@ def main() -> None:
                 jax.block_until_ready(program(q, k, v, do))
                 best = min(best, time.perf_counter() - t)
             line = (
-                f"{BN}x{T}x{D} {used}: forward + backward {best / calls * 1e3:7.3f} ms a call | "
+                f"{B}x{N}x{T}x{D} {used}: forward + backward {best / calls * 1e3:7.3f} ms a call | "
                 f"trace {t1 - t0:.3f} s lower {t2 - t1:.3f} s compile {t3 - t2:.2f} s"
             )
             if not args.rehearse:  # the CPU backend writes no device plane
@@ -126,9 +139,14 @@ def main() -> None:
                 jax.profiler.start_trace(trace_dir)
                 jax.block_until_ready(program(q, k, v, do))
                 jax.profiler.stop_trace()
-                for kernel, (us, n) in kernel_us(trace_dir, calls).items():
-                    floor, bound = roofline.min_seconds(KERNELS[kernel], BN, T, D, peak)
+                kernels, moved_us, moved_n = kernel_us(trace_dir, calls)
+                for kernel, (us, n) in kernels.items():
+                    floor, bound = roofline.min_seconds(KERNELS[kernel], B * N, T, D, peak)
                     line += f"\n    {kernel:14s} x{n:3.1f} {us:8.1f} us a call, floor {floor * 1e6:6.1f} us ({bound}), {100 * floor * 1e6 / us:5.1f}%"
+                line += (
+                    f"\n    the three kernels {sum(us for us, _ in kernels.values()) / 1e3:7.3f} ms of the call's {best / calls * 1e3:7.3f} ms; "
+                    f"copy + transpose x{moved_n:3.1f} {moved_us / 1e3:7.3f} ms"
+                )
             print(line, flush=True)
 
 
