@@ -47,10 +47,9 @@ Rules (each one traces back to a real incident in PERF.md / PR history):
 * **DS-R009 raw-clock-in-step-loop** — a raw ``time.time()`` /
   ``time.perf_counter()`` / ``time.monotonic()`` call, or a ``device_sync``
   (full async-dispatch drain), inside a step-loop method of an
-  ``*Engine`` / ``*Server`` / ``*Scheduler`` / ``*Loader`` class (the
-  multi-step window family and the prefetching input pipeline run on the
-  same critical path), or a routing method of a ``*Gate`` / ``*MoE`` /
-  ``*MoELayer`` class (the expert dispatch path runs inside every traced
+  ``*Engine`` / ``*Server`` / ``*Scheduler`` / ``*Loader`` class (an input
+  pipeline runs on the same critical path), or a routing method of a
+  ``*Gate`` / ``*MoE`` / ``*MoELayer`` class (the expert dispatch path runs inside every traced
   step — a clock there stalls the a2a overlap): ad-hoc timing forks a
   second, invisible timeline next to the unified tracer (ISSUE 10), and a
   stray ``device_sync`` serializes host and device on every step (the
@@ -177,16 +176,19 @@ _SCAN_COLLECTIVES = {"all_gather", "psum"}
 # class qualifies only when it BOTH matches the name pattern and defines a
 # serving-specific round method, so host-only training-side schedulers
 # (curriculum / random-LTD / compression `step()`s) stay out of scope.
-# The ragged/window/TP family is in scope too (ISSUE 13): the sharded
+# The ragged/TP family is in scope too (ISSUE 13): the sharded
 # serving path runs the SAME one-fetch-per-dispatch budget, and a host
 # transfer hidden in a tp/ragged step method costs every chip in the mesh.
+# Beside the round methods by their generic names, ``_HOT_FN`` names the
+# methods of ``inference/scheduler.py:PagedServer`` that lie between two
+# dispatches (the pack, the enqueue, the wait, the settles):
+# ``test_source_lint.py`` holds them against the class as it stands.
 _HOT_CLASS = re.compile(r"(Server|Scheduler)$")
-_SERVING_FN = re.compile(
-    r"^_?((plain_)?(decode|prefill|verify|spec|ragged|tp)_(step|round|window)|serve)$"
-)
+_ROUND_FN = r"(decode|prefill|verify|spec|ragged|tp)_(step|round)"
+_SERVING_FN = re.compile(rf"^_?({_ROUND_FN}|serve)$")
 _HOT_FN = re.compile(
-    r"^_?((plain_)?(decode|prefill|verify|spec|ragged|tp)_(step|round|window)"
-    r"|settle_(ragged|window)_rows|settle_spec_row|step|run|serve)$"
+    rf"^_?({_ROUND_FN}|pack|dispatch|wait_ragged_rows|settle_(ragged|fetched)_rows"
+    r"|settle_spec_row|step|run|serve)$"
 )
 
 # DS-R005/DS-R009 MoE routing scope (ISSUE 20): the gate/dispatch methods
@@ -212,7 +214,7 @@ _R009_CLASS = re.compile(r"(Engine|Server|Scheduler|Loader|Streamer)$")
 _R009_FN = re.compile(
     r"^_?(forward|backward|step|train_batch|fused_train_batch|take_model_step"
     r"|take_offload_step|take_streamed_offload_step|generate"
-    r"|(plain_)?(decode|prefill|verify|spec|ragged)"
+    r"|(decode|prefill|verify|spec|ragged)"
     r"_(step|round)|admit|emit|run|serve|settle_spec_row|reserve_for_growth"
     r"|finish_step_bookkeeping|__next__|h2d_bucket|d2h_bucket"
     r"|materialize_writes|drain_writes|discard_staged|take_staged|land)$"

@@ -62,37 +62,6 @@ class CheckpointConfig(DeepSpeedConfigModel):
     base_dir: Optional[str] = None
 
 
-class MultiStepConfig(DeepSpeedConfigModel):
-    """Multi-step in-program serving windows (``decode.py:
-    build_ragged_multistep`` / ``scheduler.py:_ragged_window``).
-
-    With ``enable``, a scheduler step whose running set is stable — no
-    pending admissions, no prefill chunks, no drafts, no preemption
-    pressure — dispatches ONE fused ``lax.scan`` program of up to
-    ``horizon`` plain-decode rounds: per-row EOS/length stopping masks
-    freeze finished rows in-program, the page table rides in pre-reserved
-    for the whole window's KV growth, and the host dispatch gap, packing,
-    emit, and journal sync are paid once per window — steady-state
-    dispatches/token → ``1/horizon``. Any scheduling event falls back to
-    the single-step ragged path (``serve_stats()['window_break_reasons']``
-    counts why), so greedy streams stay byte-identical to single-step —
-    and to dense — serving. One horizon is armed at a time,
-    adding at most ONE compiled serving program (≤ 4 total with the
-    narrow + mixed ragged widths)."""
-
-    enable: bool = False
-    horizon: int = 8  # decode rounds fused into one dispatch (>= 2)
-
-    @model_validator(mode="after")
-    def _check_horizon(self):
-        if self.enable and self.horizon < 2:
-            raise ValueError(
-                f"paged_kv.multi_step.horizon must be >= 2 (1 is the "
-                f"single-step path), got {self.horizon}"
-            )
-        return self
-
-
 class ShardedServingConfig(DeepSpeedConfigModel):
     """Multi-chip tensor-parallel serving knobs (``inference/tp.py``).
 
@@ -155,11 +124,6 @@ class PagedKVConfig(DeepSpeedConfigModel):
     with decoders instead of stealing whole steps, and spec-K varies freely
     per request.
 
-    ``multi_step`` (see :class:`MultiStepConfig`) arms fused windows of N
-    plain-decode rounds per dispatch on top of that step — the host
-    dispatch gap amortizes to 1/N in steady state, streams stay
-    byte-identical, and any scheduling event falls back to single-step.
-
     ``prefix_cache`` turns on page-level prefix sharing: full KV pages are
     indexed by a content chain hash, requests attach the longest cached
     prefix of their context by reference (refcounted, copy-on-write on
@@ -176,9 +140,6 @@ class PagedKVConfig(DeepSpeedConfigModel):
     prefill_chunk: int = 32  # prompt tokens per interleaved prefill dispatch
     attn_impl: str = "auto"  # auto | pallas | xla (decode attention backend)
     prefix_cache: bool = True  # page-level prefix sharing (hash-of-block + CoW)
-    # multi-step windows: N decode rounds fused into one dispatch when the
-    # running set is stable
-    multi_step: MultiStepConfig = Field(default_factory=MultiStepConfig)
     # multi-chip tensor-parallel serving: sharded weights +
     # kv-head-sharded pages + quantized comms knobs
     sharded: ShardedServingConfig = Field(default_factory=ShardedServingConfig)

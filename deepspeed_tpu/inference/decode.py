@@ -709,20 +709,8 @@ def beam_generate(
 # ONE `build_ragged_step` program per step handles mixed prefill-chunk,
 # decode, and verify rows together, driven by per-row (kv_len, q_len)
 # metadata arrays — total compiled serving programs ≤ 2 (a narrow
-# decode/verify width plus the mixed width covering prefill chunks).
-# Multi-step windows (`build_ragged_multistep`, armed via
-# `paged_kv.multi_step`) add at most ONE more program per horizon: a
-# lax.scan of N plain-decode rounds dispatched when the running set is
-# stable, amortizing the host gap to 1/N. The count is never bounded by
-# traffic.
-
-
-def _program_name(kind: str, rows: int, width: int) -> str:
-    """Serving-program name ``paged_<kind>_r<rows>_w<width>`` (``kind``:
-    ``ragged`` or ``multistep``), so compile telemetry counts serving
-    programs consistently — the ragged ≤2-compile gate and the benchmark's
-    compile counters both count ``paged_*`` entries."""
-    return f"paged_{kind}_r{int(rows)}_w{int(width)}"
+# decode/verify width plus the mixed width covering prefill chunks),
+# whatever the traffic.
 
 
 # one cache for every compiled serving program, keyed by the unified
@@ -741,13 +729,9 @@ def ragged_program_name(rows: int, width: int, tp=None) -> str:
     """The ``compile_stats()`` key of ``build_ragged_step(cfg, rows, width,
     ..., tp=tp)`` and, with ``jit_`` in front, its XLA module's name: what
     the scheduler's ``serve.pack`` / ``serve.dispatch`` spans carry as
-    ``program``."""
-    return _program_name("ragged", rows, width) + _tp_suffix(tp)
-
-
-def multistep_program_name(rows: int, width: int, horizon: int, tp=None) -> str:
-    """Same, for ``build_ragged_multistep``."""
-    return f"{_program_name('multistep', rows, width)}_n{int(horizon)}" + _tp_suffix(tp)
+    ``program``. The ragged ≤2-compile gate and the benchmark's compile
+    counters both count ``paged_*`` entries."""
+    return f"paged_ragged_r{int(rows)}_w{int(width)}" + _tp_suffix(tp)
 
 
 def _tp_suffix(tp) -> str:
@@ -936,7 +920,7 @@ def _paged_layers(cfg, params, tokens, k_pages, v_pages, page_table, positions_b
                   *, prefill_kv_lens, ragged_q_lens, tp=None):
     """Embedding and layers of the ragged step (``_paged_forward`` has the
     contract). A window of at most one token tile (``token_tile``; a test of
-    shapes: the width-1 program, a verify width, every multi-step window) is
+    shapes: the width-1 program, a verify width) is
     computed as the ``[B, T]`` slab it is. A wider one is computed over its
     LIVE tokens only, inside the one program:
 
@@ -1155,99 +1139,6 @@ def _packed_greedy(cfg, params, x, packed: _Packed, tp=None):
     return packed.expand(packed.tiles(head, jnp.zeros((x.shape[0],), jnp.int32)))
 
 
-def build_ragged_multistep(cfg, rows: int, width: int, horizon: int, page_size: int,
-                           attn_impl: str = "auto", telemetry=None, tp=None):
-    """N plain-decode rounds in ONE dispatch: a ``lax.scan`` of ``horizon``
-    iterations of the ragged step body, so the host dispatch gap, packing,
-    and journal syncs are paid once per WINDOW instead of once per token.
-
-    ``multistep(params, tokens [R], k_pages, v_pages, page_table [R, MAXP],
-    lengths [R], live [R], eos_ids [R], budgets [R])
-    -> (out [R, 1+N], k_pages, v_pages)``.
-
-    Row r starts from its pending token ``tokens[r]`` at live kv length
-    ``lengths[r]`` (``live[r] == 0`` marks dead padding rows). Each round
-    writes the carried token at the row's next position, attends through
-    the SAME ragged paged-attention entry the single-step program uses
-    (per-row ``(kv_len, q_len)`` metadata with ``q_len ∈ {0, 1}``), takes
-    the greedy argmax in-program, and advances the carry. Stopping is pure
-    in-program data: a row FREEZES — its ``q_len`` drops to 0, so further
-    writes reach no live page and its length stops — the round it
-    emits its ``eos_ids[r]`` token (−1 = no EOS) or its ``budgets[r]``-th
-    window token. A frozen row is indistinguishable from a dead padding
-    row to every other row, which is what makes the window byte-identical
-    to ``horizon`` sequential single-step dispatches.
-
-    In-window KV growth needs no host resync: positions index the page
-    table (``position // page_size``), and the scheduler pre-reserves the
-    ``ceil(N / page_size) + 1`` pages a row can touch before dispatching
-    (``_reserve_for_growth``), so the table rides in already covering the
-    whole window.
-
-    ``out[:, 0]`` is the per-row emitted count n (≤ N); ``out[:, 1 : 1+n]``
-    the emitted tokens — everything packed into ONE array so the window's
-    single host fetch stays a single transfer. Pages are donated; the
-    table rides in per window (rebuilt host-side, nothing to alias back).
-
-    Compiled once per (rows, horizon): the scheduler arms one horizon, so
-    the serving program set stays ≤ narrow + mixed + one window program.
-    ``width`` is reserved for drafted windows and must be 1 today (plain
-    decode — the window mode only engages when drafting is idle).
-    """
-    if cfg.position == "alibi":
-        raise NotImplementedError("paged serving does not support alibi attention biases")
-    _refuse_state_layers(cfg, "a multi-step window (paged_kv.multi_step)")
-    if width != 1:
-        raise ValueError(f"multi-step windows run plain decode only (width 1), got {width}")
-    if rows < 1 or horizon < 2:
-        raise ValueError(
-            f"multi-step window needs rows >= 1 and horizon >= 2, got "
-            f"{rows} rows x horizon {horizon}"
-        )
-    name = multistep_program_name(rows, width, horizon, tp)
-    key = _paged_program_key(name, cfg, page_size, attn_impl, telemetry, tp)
-    fn = _paged_program_cache.get(key)
-    if fn is not None:
-        return fn
-    N = int(horizon)
-    run_cfg = cfg if tp is None else tp.local_cfg(cfg)
-
-    def _window(params, tokens, k_pages, v_pages, page_table, lengths, live,
-                eos_ids, budgets):
-        def round_fn(carry, _):
-            tok, kp, vp, lens, alive, emitted = carry
-            q_lens = alive.astype(jnp.int32)  # [R]: 1 live, 0 frozen/dead
-            kv_lens = jnp.where(alive, lens + 1, 0)
-            logits, kp, vp, _ = _paged_forward(
-                run_cfg, params, tok[:, None], kp, vp, page_table, lens[:, None],
-                None, attn_impl, prefill_kv_lens=kv_lens, ragged_q_lens=q_lens, tp=tp,
-            )
-            nxt = _argmax(logits[:, -1, :], tp)
-            out_tok = jnp.where(alive, nxt, -1)
-            emitted = emitted + q_lens
-            lens = lens + q_lens
-            # freeze AFTER emitting the EOS / budget-hitting token — the
-            # scheduler's _emit includes that token, matching sequential
-            # decode's output contract
-            alive = alive & (nxt != eos_ids) & (emitted < budgets)
-            tok = jnp.where(alive, nxt, tok)
-            return (tok, kp, vp, lens, alive, emitted), out_tok
-
-        alive0 = live > 0
-        emitted0 = jnp.zeros_like(lengths)
-        (tok, kp, vp, lens, alive, emitted), toks = jax.lax.scan(
-            round_fn, (tokens, k_pages, v_pages, lengths, alive0, emitted0),
-            None, length=N,
-        )
-        packed = jnp.concatenate([emitted[:, None], toks.T], axis=1)  # [R, 1+N]
-        return packed, kp, vp
-
-    body = _window if tp is None else tp.shard_program(_window, n_args=9)
-    fn = _jit(body, telemetry, name, donate_argnums=(2, 3))
-    _paged_program_cache[key] = fn
-    return fn
-
-
 MOE_STAT_ROWS = 3
 
 
@@ -1359,7 +1250,7 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
                 packed = jnp.concatenate([packed, _moe_stat_rows(moe_counts, W + 1)], axis=0)
         return packed, new_k, new_v
 
-    body = _step if tp is None else tp.shard_program(_step, n_args=7)
+    body = _step if tp is None else tp.shard_program(_step)
     fn = _jit(body, telemetry, name, donate_argnums=(2, 3))
     _paged_program_cache[key] = fn
     return fn

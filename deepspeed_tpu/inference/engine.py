@@ -521,13 +521,11 @@ class InferenceEngine:
         """Per-program compile telemetry snapshot — the inference-side
         counterpart of the training engine's ``compile_stats()``: for each
         jitted program (``forward``, ``kv_prefill`` / ``kv_decode_loop`` /
-        ``kv_beam_loop``, ``full_fwd_gen_step``, and the serving programs —
-        ``paged_ragged_r<rows>_w<width>`` and, with windows armed,
-        ``paged_multistep_r<rows>_w<width>_n<horizon>``) the trace, compile,
-        and dispatch counters. The serving contract: ≤ 2 compiled
-        ``paged_*`` programs for a whole mixed serve (one more per window
-        horizon) and exactly one ``paged_ragged_*`` dispatch per
-        single-step scheduler step."""
+        ``kv_beam_loop``, ``full_fwd_gen_step``, and the serving programs
+        ``paged_ragged_r<rows>_w<width>``) the trace, compile, and dispatch
+        counters. The serving contract: ≤ 2 compiled ``paged_*`` programs
+        for a whole mixed serve and exactly one ``paged_ragged_*`` dispatch
+        per scheduler step."""
         return self._telemetry.stats()
 
     def program_text(self, name: str) -> str:
@@ -542,8 +540,8 @@ class InferenceEngine:
         host-transfer, and collective-schedule pass results per program,
         retrace-cause diffs, and aggregate totals (``donation_verified``,
         static collective bytes). The serving invariants become checkable
-        properties: every ``paged_ragged_*`` / ``paged_multistep_*`` program
-        must alias its donated page buffers and contain no host callback."""
+        properties: every ``paged_ragged_*`` program must alias its donated
+        page buffers and contain no host callback."""
         from deepspeed_tpu.analysis import engine_analysis_report
 
         return engine_analysis_report(
@@ -746,7 +744,6 @@ class InferenceEngine:
             telemetry=self._telemetry,
             spec_decode=self._config.spec_decode,
             prefix_cache=prefix_cache,
-            multi_step=pcfg.multi_step,
             journal=journal,
             tracer=self.tracer,
             metrics=self.metrics,
@@ -754,16 +751,6 @@ class InferenceEngine:
         )
         if recovered_states:
             server.recover(recovered_states, next_uid)
-        if self._obs_hub.flight_recorder is not None:
-            # postmortems must name the window config: a crash dump that
-            # shows a serve.window span is only readable next to the armed
-            # horizon (flight-recorder payloads carry this context block).
-            # Written unconditionally so a server REBUILT with windows
-            # disabled overwrites a stale armed-horizon claim
-            self._obs_hub.flight_recorder.context["serve.multi_step"] = {
-                "enable": bool(pcfg.multi_step.enable),
-                "horizon": int(pcfg.multi_step.horizon),
-            }
         tcfg = self._config.traffic
         if tcfg.enabled:
             # multi-tenant SLA layer (inference/traffic.py): weighted-deficit
@@ -781,9 +768,7 @@ class InferenceEngine:
         requests are admitted/evicted every step, prompts prefill in chunks
         riding the SAME dispatch as in-flight decoders, and each step is
         ONE dispatch of the unified ragged program
-        (``inference/scheduler.py``) — or, with ``paged_kv.multi_step``
-        armed and the running set stable, ONE fused window of up to ``horizon`` decode rounds (host dispatch gap
-        amortized to 1/N, still byte-identical). With
+        (``inference/scheduler.py``). With
         ``inference.spec_decode.enable`` host-side n-gram drafts verify
         inside the same per-step dispatch (per-request spec-K), token-exact
         under greedy. Accepts a list of 1-D
@@ -800,9 +785,8 @@ class InferenceEngine:
     def serve_stats(self):
         """Observability of the live paged server: scheduler counters
         (admitted, preempted, finished, prefill_chunks, decode_steps,
-        spec_rounds), the multi-step window block (``window_steps``,
-        ``window_horizon``, ``dispatches_per_token``,
-        ``window_break_reasons``), speculation quality (``spec_accept_rate``,
+        spec_rounds, ``dispatches_per_token``), speculation quality
+        (``spec_accept_rate``,
         ``spec_mean_accepted_per_round``, the ``spec_accept_hist`` draft-hit
         histogram), pool occupancy/utilization, prefix-cache counters
         (``prefix`` — hit rate, CoW copies, cached pages), latency SLOs

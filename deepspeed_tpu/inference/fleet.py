@@ -1003,10 +1003,9 @@ class FleetRouter:
                 continue  # unresponsive dead replica: history unavailable
         merged: Dict = {}
         skip = {
-            "ttft_ms", "tpot_ms", "tenants", "prefix", "window_break_reasons",
+            "ttft_ms", "tpot_ms", "tenants", "prefix",
             "spec_accept_hist", "dispatches_per_token", "spec_accept_rate",
             "spec_mean_accepted_per_round", "pool_utilization",
-            "window_horizon",
         }
         for rep in per.values():
             for k, v in rep.items():

@@ -824,17 +824,6 @@ class PagePool:
         del self._chain_keys[slot][min(len(self._chain_keys[slot]), keep):]
         return freed
 
-    def trim_reservation(self, slot: int) -> int:
-        """Release pages reserved past the slot's LIVE length. A multi-step
-        serving window pre-reserves the ``ceil(N / page_size) + 1`` pages a
-        row could touch (``prepare_write`` to ``len + N``) before its one
-        dispatch; rows that freeze early (EOS / budget) or a window that
-        falls back pre-dispatch hand the unused tail straight back here so
-        reservations never starve admissions. Refcount semantics are
-        ``rollback``'s (a zero-token rollback: only surplus pages move).
-        Returns how many pages were released."""
-        return self.rollback(slot, 0)
-
     def free_slot(self, slot: int) -> int:
         """Release the slot and drop its page references (pages whose last
         reference this was go back to the pool — or to the cached LRU when

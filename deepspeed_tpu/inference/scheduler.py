@@ -39,17 +39,6 @@ vLLM style):
   step — enforced by the serving tests via the engine's compile
   telemetry. Prefix sharing adds zero dispatches and zero programs:
   attach/register are host-side table and hash work;
-* with **multi-step windows** armed (``inference.paged_kv.multi_step``)
-  a step whose running set is STABLE — nothing queued, nothing
-  prefilling, no drafts, no preemption pressure — dispatches ONE fused
-  program of up to ``horizon`` plain-decode rounds
-  (``decode.py:build_ragged_multistep``): per-row EOS/budget stopping
-  masks freeze finished rows in-program (trash-page writes), the page
-  table rides in pre-reserved for the whole window's growth, and the
-  host pays its dispatch gap, packing, emit, and journal sync once per
-  window instead of once per token (dispatches/token → 1/horizon). Any
-  scheduling event breaks back to the single-step path — streams stay
-  byte-identical, and ``window_break_reasons`` names every break;
 * **the host works while the device does**: a call of ``step()`` admits,
   enqueues the step the call before it packed (n+1; packed again first if
   someone was just admitted, so a newcomer never waits a step for having
@@ -71,8 +60,8 @@ vLLM style):
   late: the row already rides in the next step, whose result for it is
   discarded (``overshoot_rows``) and whose settle releases its slot; the
   stream is what it always was. Whenever the host needs values before it can
-  pack — a drafter or ``multi_step`` windows armed, a reservation that would
-  have to preempt, an entry point that reads or moves a request
+  pack — a drafter armed, a reservation that would have to preempt, an
+  entry point that reads or moves a request
   (``extract_request``, ``restore_request``, ``recover``,
   ``finalize_migration``, ``compact_journal``, ``settle``) — the unsettled
   step is settled first and what was packed behind it dropped
@@ -112,10 +101,8 @@ import numpy as np
 from deepspeed_tpu.inference.decode import (
     MOE_STAT_ROWS,
     _refuse_state_layers,
-    build_ragged_multistep,
     build_ragged_step,
     build_token_feed,
-    multistep_program_name,
     ragged_program_name,
     token_tiles,
 )
@@ -162,9 +149,8 @@ def _row_lens(q_lens: np.ndarray, kv_lens: np.ndarray) -> str:
 def compiled_serving_programs(compile_stats: Dict) -> int:
     """Count the serving programs a telemetry snapshot saw compile: every
     ``paged_*`` entry (the ``paged_<kind>_r<rows>_w<width>`` naming of the
-    ragged and multistep builders) with at least one cold dispatch. The
-    compile-budget gate asserts this ≤ 2 for a full mixed serve — ≤ 4 with
-    a multi-step window horizon armed."""
+    ragged builder) with at least one cold dispatch. The compile-budget
+    gate asserts this ≤ 2 for a full mixed serve."""
     return sum(
         1
         for name, rec in compile_stats.items()
@@ -296,7 +282,7 @@ class _Dispatched:
 
 
 class PagedServer:
-    """Owns the page pool and the admit → ragged-step (or window) loop."""
+    """Owns the page pool and the admit → ragged-step loop."""
 
     def __init__(
         self,
@@ -315,7 +301,6 @@ class PagedServer:
         prefix_cache: bool = False,
         policy: Optional[SchedulingPolicy] = None,
         clock=None,
-        multi_step=None,
         journal: Optional[RequestJournal] = None,
         tracer=None,
         metrics: Optional[MetricsRegistry] = None,
@@ -393,33 +378,12 @@ class PagedServer:
         self.attn_impl = attn_impl
         self.telemetry = telemetry
         self.prefix_cache = bool(prefix_cache)
-        # multi-step windows (inference.paged_kv.multi_step): when the
-        # running set is STABLE — nothing queued, nothing prefilling, no
-        # drafts, no preemption pressure — a step dispatches ONE fused
-        # program of `horizon` plain-decode rounds (decode.py:
-        # build_ragged_multistep), paying the host dispatch gap, packing,
-        # and journal sync once per window instead of once per token. Any
-        # scheduling event falls back to the single-step ragged path, so
-        # prefix cache, CoW, SLA tenancy, spec decode, and the journal
-        # ride unchanged and streams stay byte-identical.
-        self.ms_enable = bool(_spec_knob(multi_step, "enable", False))
-        self.ms_horizon = int(_spec_knob(multi_step, "horizon", 8))
-        if self.ms_enable and self.ms_horizon < 2:
-            raise ValueError(
-                f"multi_step.horizon must be >= 2 (1 is the single-step "
-                f"path), got {self.ms_horizon}"
-            )
-        # drafts handed from a failed window-eligibility probe to the
-        # single-step fallback, so a (possibly stateful) Drafter is asked
-        # at most once per scheduler step
-        self._predrafts: Optional[Dict[int, np.ndarray]] = None
         # recurrent-state and sliding-window layers: a row's state, or its page
         # ring, exists at its newest positions only, so whatever re-enters a
         # sequence part-way is refused here
         refused = {
             "paged_kv.prefix_cache (and copy-on-write forks of shared pages)": self.prefix_cache,
             "spec_decode (verify rows roll their rejected tail back)": drafter is not None or bool(_spec_knob(spec_decode, "enable", False)),
-            "paged_kv.multi_step windows": self.ms_enable,
         }
         for what, asked in refused.items():
             if asked:
@@ -509,23 +473,10 @@ class PagedServer:
             "run_ahead_steps": 0,
             "overshoot_rows": 0,
             "drain_reasons": {},
-            # multi-step windows: one fused horizon-round dispatch each;
-            # `dispatches` counts EVERY serving dispatch (windows and
-            # ragged steps) and `emitted_tokens` every generated token, so
-            # dispatches_per_token is derivable
-            "window_steps": 0,
+            # `dispatches` counts every serving dispatch and `emitted_tokens`
+            # every generated token, so dispatches_per_token is derivable
             "dispatches": 0,
             "emitted_tokens": 0,
-            # why a window could not form (admission pending, a row mid
-            # prefill, drafts proposed, page-pool reservation pressure) or
-            # ended before its horizon (EOS / token budget) — the
-            # steady-state postmortem counters. "pool" and "budget" need
-            # OPPOSITE remediations (grow the pool vs lower the horizon),
-            # so they are never folded together
-            "window_break_reasons": {
-                "admission": 0, "prefill": 0, "draft": 0, "eos": 0,
-                "budget": 0, "pool": 0,
-            },
             # the mixed-width dispatches, the live tokens they carried and the
             # token tiles the program ran for them (decode.token_tiles)
             "mixed_steps": 0,
@@ -540,10 +491,9 @@ class PagedServer:
             "spec_accept_hist": [0] * (self.max_draft + 1),
         }
         if self._moe_slots:
-            # single steps only (windows carry none): live (token, expert)
-            # assignments over all layers; experts hit (>= 1 live token),
-            # summed over layers; the largest load any one expert of any
-            # layer took in one step
+            # live (token, expert) assignments over all layers; experts hit
+            # (>= 1 live token), summed over layers; the largest load any one
+            # expert of any layer took in one step
             self.stats.update(moe_assignments=0, moe_experts_hit=0, moe_max_expert_load=0)
             self._g_moe_hit = self.metrics.gauge("serve.moe_experts_hit_share")
             if self._moe_routed_per_token:
@@ -899,10 +849,8 @@ class PagedServer:
         while the device runs, and ends with the wait for the device — so
         the tokens a call emits are those of the step dispatched one call
         earlier, and the device is idle between two calls. A first call, a
-        drained server and one with a drafter or windows armed pack and
-        enqueue in the same call. With ``multi_step`` armed and the running
-        set stable: ONE fused window of ``horizon`` plain-decode rounds,
-        settled in line."""
+        drained server and one with a drafter armed pack and enqueue in the
+        same call."""
         waiting, running = len(self._queue), len(self._active)
         pages_in_use = self.pool.used_pages()
         self._g_waiting.set(waiting)
@@ -933,23 +881,20 @@ class PagedServer:
                 # settled behind that enqueue
                 if packed is not None:
                     self._dispatch(packed)
-            if self._in_flight is None and not (self.ms_enable and self._ragged_window()):
+            if self._in_flight is None:
                 # nothing was packed ahead (a first step, a drained or a
                 # synchronous server): pack and enqueue in this call
-                packed = self._pack(self._take_predrafts())
+                packed = self._pack()
                 if packed is not None:
                     self._dispatch(packed)
             if self._in_flight is not None:
-                # drafts are proposed from settled contexts and a window
-                # probes settled rows: with either armed the step is settled
-                # in the call that dispatched it, and the server is the
-                # synchronous one. Otherwise the next step is packed while
-                # the device runs this one, and the call ends when the
-                # device does: nothing executes between two calls
+                # drafts are proposed from settled contexts: with a drafter
+                # armed the step is settled in the call that dispatched it,
+                # and the server is the synchronous one. Otherwise the next
+                # step is packed while the device runs this one, and the call
+                # ends when the device does: nothing executes between two calls
                 if self.drafter is not None:
                     self._drain("draft")
-                elif self.ms_enable:
-                    self._drain("window")
                 else:
                     self._packed = self._pack()
                     if self._in_flight is not None:
@@ -1052,12 +997,6 @@ class PagedServer:
         return real
 
     # --- the ragged one-program step -------------------------------------
-    def _take_predrafts(self) -> Optional[Dict[int, np.ndarray]]:
-        """Drafts a failed window probe already proposed this step (the
-        Drafter is asked at most once per step — it may be stateful)."""
-        drafts, self._predrafts = self._predrafts, None
-        return drafts
-
     def _rows_to_pack(self) -> List[Request]:
         """The running rows the next step carries: all of them, but for a row
         whose budget ends with the token still in flight (it has nothing
@@ -1079,7 +1018,7 @@ class PagedServer:
         ]
         return self.pool.can_write([r.slot for r in rows], grow)
 
-    def _pack(self, drafts: Optional[Dict[int, np.ndarray]] = None) -> Optional[_Packed]:
+    def _pack(self) -> Optional[_Packed]:
         """Pack ONE dispatch for the whole round: every active row
         contributes its next tokens — a prefill chunk, the pending decode
         token, or the pending token plus host-side drafts — in a single
@@ -1111,12 +1050,9 @@ class PagedServer:
         feeds = prev.feeds if prev is not None else {}
         seq = self.stats["dispatches"]
         with self.tracer.span("serve.pack", seq=seq) as pack_span:
-            if drafts is None:
-                drafts = {}
-                if self.drafter is not None:
-                    drafts = self._propose_drafts(
-                        [r for r in rows if r.pending is not None]
-                    )
+            drafts: Dict[int, np.ndarray] = {}
+            if self.drafter is not None:
+                drafts = self._propose_drafts([r for r in rows if r.pending is not None])
             chunk_len: Dict[int, int] = {}
             need: Dict[int, int] = {}
             for r in rows:
@@ -1323,167 +1259,14 @@ class PagedServer:
             # rolls back here — net advance is the accepted prefix + bonus token
             self._settle_spec_row(r, d, int(out[i, 0]), out[i])
 
-    # --- the multi-step window (one dispatch = N decode rounds) ----------
-    def _window_break(self, reason: str) -> None:
-        self.stats["window_break_reasons"][reason] += 1
-
-    def _ragged_window(self) -> bool:
-        """Try to serve this step as ONE fused window of ``ms_horizon``
-        plain-decode rounds (``decode.py:build_ragged_multistep``). The
-        window forms only when the running set is STABLE — no pending
-        admissions, no row mid-prefill, no drafts proposed, every row's
-        remaining budget worth amortizing, and the whole window's pages
-        reservable WITHOUT preemption; any scheduling event records its
-        break reason and returns False, and the caller falls back to the
-        single-step ragged path (byte-identical streams either way — the
-        window program freezes rows in-program exactly where sequential
-        steps would retire them). Per-row EOS ids and token budgets ride
-        in as arrays, so the fused program never overruns a stream."""
-        rows = [r for r in self._active if not r.done]
-        if not rows:
-            return False
-        if self._queue:
-            # an admission is waiting: a window would starve its TTFT for
-            # up to N rounds — serve single-step until the queue drains
-            self._window_break("admission")
-            return False
-        if any(r.pending is None for r in rows):
-            self._window_break("prefill")
-            return False
-        H = self.ms_horizon
-        if max(r.max_new_tokens - len(r.generated) for r in rows) < H:
-            # every row would freeze before the horizon: the single-step
-            # tail is strictly cheaper than a mostly-frozen window
-            self._window_break("budget")
-            return False
-        if self.drafter is not None:
-            # stash the proposals whichever way the probe resolves: the
-            # single-step fallback consumes them instead of re-asking a
-            # (possibly stateful) Drafter twice in one step
-            self._predrafts = drafts = self._propose_drafts(rows)
-            if any(d.size for d in drafts.values()):
-                # speculation outruns a plain-decode window
-                self._window_break("draft")
-                return False
-        # pre-reserve the whole window's growth — ceil(N/page_size)+1
-        # pages per row worst case — WITHOUT preempting: pool pressure is
-        # a scheduling event, and the single-step path owns preemption.
-        # Per row the reservation is min(H, remaining budget): the
-        # in-program budget freeze bounds the row's writes to its budget,
-        # so a near-finished row never demands pages (or max_seq_len
-        # room) it cannot write — submit() guarantees len + budget fits
-        need = {
-            r.uid: min(H, r.max_new_tokens - len(r.generated)) for r in rows
-        }
-        if self._reserve_for_growth(rows, need, preempt=False) is None:
-            self._window_break("pool")
-            return False
-        # the window dispatches: drop the (all-empty) stash — a later
-        # step's fallback must ask the drafter fresh, not read this one
-        self._predrafts = None
-        seq = self.stats["dispatches"]
-        with self.tracer.span("serve.window"):
-            with self.tracer.span("serve.pack", seq=seq) as pack_span:
-                R = self.pool.max_slots
-                page_table, lengths = self._dispatch_rows(rows, R)
-                tokens = np.zeros(R, np.int32)
-                live = np.zeros(R, np.int32)
-                eos_ids = np.full(R, -1, np.int32)
-                budgets = np.zeros(R, np.int32)
-                for i, r in enumerate(rows):
-                    tokens[i] = r.pending
-                    live[i] = 1
-                    if r.eos_token_id is not None:
-                        eos_ids[i] = r.eos_token_id
-                    budgets[i] = r.max_new_tokens - len(r.generated)  # >= 1
-                program = multistep_program_name(R, 1, H, self.tp)
-                # the window's first round, as a step's pack records it
-                live_kv = lengths[: len(rows)] + 1
-                pack_span.set(
-                    rows=len(rows), program=program, mixed=0, kv_tokens=int(live_kv.sum()),
-                    row_lens=_row_lens(live[: len(rows)], live_kv),
-                )
-            with self.tracer.span("serve.dispatch", seq=seq, rows=len(rows), width=1, program=program):
-                window_fn = build_ragged_multistep(
-                    self.cfg, R, 1, H, self.pool.page_size,
-                    attn_impl=self.attn_impl, telemetry=self.telemetry,
-                    tp=self.tp,
-                )
-                with self.tracer.span("serve.enqueue", seq=seq, program=program):
-                    out, new_k, new_v = window_fn(
-                        self.params, tokens, self.pool.cache.k_pages,
-                        self.pool.cache.v_pages, page_table, lengths, live,
-                        eos_ids, budgets,
-                    )
-                self.pool.set_cache(new_k, new_v)
-            self.stats["window_steps"] += 1
-            self.stats["dispatches"] += 1
-            with self.tracer.span("serve.emit", seq=seq):
-                self._settle_window_rows(rows, out, H, seq)
-            # crash INSIDE the window's host phase: every emitted token of
-            # the window sits in the journal buffer, none acked — recovery
-            # replays from the last synced token and the greedy re-prefill
-            # re-derives the window's tokens byte-identically
-            chaos.point("serve.mid_window")
-        return True
-
-    def _settle_window_rows(self, rows, out, horizon: int, seq: int) -> None:
-        """Post-dispatch accounting for one window: the single budgeted
-        host fetch (``[R, 1+N]`` = per-row emitted count + tokens), then
-        per-row advance/emit/publish, amortized over up to N tokens per
-        row. Rows that froze before the horizon name the window's break
-        reason (EOS vs budget); surplus reserved pages go back to the
-        pool so a parked reservation never starves the next admission."""
-        with self.tracer.span("serve.fetch", seq=seq):
-            out = np.asarray(out)  # lint: allow(DS-R005) — the window's one fetch
-        with self.tracer.span("serve.settle", seq=seq) as settle_span:
-            emitted = self.stats["emitted_tokens"]
-            self._settle_fetched_window(rows, out, horizon)
-            settle_span.set(tokens=self.stats["emitted_tokens"] - emitted)
-
-    def _settle_fetched_window(self, rows, out, horizon: int) -> None:
-        eos_broke = budget_broke = False
-        for i, r in enumerate(rows):
-            n = int(out[i, 0])
-            self.pool.advance(r.slot, n)
-            for tok in out[i, 1 : 1 + n]:
-                self._emit(r, int(tok))
-            if r.done and n < horizon:
-                if (
-                    r.eos_token_id is not None
-                    and r.generated
-                    and r.generated[-1] == r.eos_token_id
-                ):
-                    eos_broke = True
-                else:
-                    budget_broke = True
-            if not r.done:
-                if self.prefix_cache:
-                    self.pool.register_prefix(
-                        r.slot, r.context(), int(self.pool.seq_lens[r.slot])
-                    )
-                self.pool.trim_reservation(r.slot)
-        if eos_broke:
-            self._window_break("eos")
-        if budget_broke:
-            self._window_break("budget")
-
-    def _reserve_for_growth(self, running: List[Request], need: Dict[int, int],
-                            preempt: bool = True) -> Optional[List[Request]]:
+    def _reserve_for_growth(self, running: List[Request], need: Dict[int, int]) -> List[Request]:
         """Make every running row writable for its next ``need[uid]`` tokens
         (default 1) — page growth plus the pool's copy-on-write barrier for
         any shared prefix page in the written span — preempting the
         policy's victim (default: youngest active request) when the pool is
         dry; vLLM's recompute preemption: the victim's greedy continuation
         is re-derived exactly on re-admission. Mutates and returns
-        ``running`` (preempted rows leave the round).
-
-        ``preempt=False`` is the multi-step window's reservation mode (a
-        whole horizon's pages per row, up front): preemption pressure is a
-        scheduling event that should BREAK the window, not evict anyone —
-        on the first row the pool cannot host, every reservation this call
-        already made is handed back (``trim_reservation``) and None is
-        returned so the caller falls back to the single-step path."""
+        ``running`` (preempted rows leave the round)."""
         idx = 0
         while idx < len(running):
             req = running[idx]
@@ -1491,10 +1274,6 @@ class PagedServer:
             while not self.pool.prepare_write(
                 req.slot, int(self.pool.seq_lens[req.slot]) + grow
             ):
-                if not preempt:
-                    for r in running[: idx + 1]:
-                        self.pool.trim_reservation(r.slot)
-                    return None
                 if self._in_flight is not None:
                     raise RuntimeError("a preemption behind a step in flight: its victim may hold an unsettled token")
                 candidates = [r for r in self._active if r is not req]
@@ -1648,11 +1427,8 @@ class PagedServer:
 
     def serve_stats(self) -> Dict:
         """Scheduler counters (incl. ``ragged_steps`` — one per unified
-        dispatch — and the multi-step window block:
-        ``window_steps`` fused dispatches, ``window_horizon``,
-        ``dispatches_per_token`` over every serving dispatch and emitted
-        token, and ``window_break_reasons`` naming why windows could not
-        form or ended early) plus derived speculation observability
+        dispatch — and ``dispatches_per_token`` over every serving dispatch
+        and emitted token) plus derived speculation observability
         (acceptance rate, mean accepted drafts per round, draft-hit
         histogram), pool occupancy/utilization, prefix-cache counters
         (hit rate, CoW copies, cached pages), and latency SLOs — aggregate
@@ -1661,7 +1437,6 @@ class PagedServer:
         payload ``InferenceEngine.serve_stats()`` surfaces."""
         s = dict(self.stats)
         s["spec_accept_hist"] = list(self.stats["spec_accept_hist"])
-        s["window_break_reasons"] = dict(self.stats["window_break_reasons"])
         s["drain_reasons"] = dict(self.stats["drain_reasons"])
         # how often a step was enqueued behind one still in flight: near 1 in
         # steady serving, 0 for a server that has to be synchronous
@@ -1674,10 +1449,9 @@ class PagedServer:
         s["spec_mean_accepted_per_round"] = (
             s["spec_accepted"] / rounds if rounds else 0.0
         )
-        # dispatch amortization (multi-step windows): every serving
-        # dispatch over every emitted token — steady-state windows drive
-        # this toward 1/horizon; 0.0 before anything has been emitted
-        s["window_horizon"] = self.ms_horizon if self.ms_enable else 0
+        # every serving dispatch over every emitted token (the rows a step
+        # carries and the drafts it accepts both lower it); 0.0 before
+        # anything has been emitted
         s["dispatches_per_token"] = (
             s["dispatches"] / s["emitted_tokens"] if s["emitted_tokens"] else 0.0
         )

@@ -2,9 +2,8 @@
 serving on the mesh.
 
 One :class:`TPServing` object carries everything the serving program
-builders (``inference/decode.py:build_ragged_step`` /
-``build_ragged_multistep``) need to run the SAME ragged step body across a
-``model``-axis mesh under ``shard_map``:
+builder (``inference/decode.py:build_ragged_step``) needs to run the
+ragged step body across a ``model``-axis mesh under ``shard_map``:
 
 * **weight sharding** — the reference AutoTP / ``SpecLayout`` fsdp×tp
   pattern specialised to the serving layout (``module_inject/auto_tp.py``
@@ -345,18 +344,17 @@ class TPServing:
         cand = jnp.where(vals == best, idxs, jnp.iinfo(jnp.int32).max)
         return jnp.min(cand, axis=0).astype(jnp.int32)
 
-    def shard_program(self, f, n_args: int):
-        """Wrap a serving step body for the mesh: params take the recorded
+    def shard_program(self, f):
+        """Wrap the serving step body for the mesh: params take the recorded
         spec tree, the two page pools shard on the kv-head axis, and every
-        host-built array (tokens, page tables, lengths, q_lens, window
-        masks) replicates. Outputs are the packed host fetch (replicated —
-        every chip resolves the same tokens) plus the sharded pools, so
-        the donated pages alias shard-for-shard."""
+        host-built array (tokens, page tables, lengths, q_lens) replicates.
+        Outputs are the packed host fetch (replicated — every chip resolves
+        the same tokens) plus the sharded pools, so the donated pages alias
+        shard-for-shard."""
         if self.param_specs is None:
             raise RuntimeError("TPServing.shard_params must run before building programs")
-        in_specs = (self.param_specs, P(), self.kv_spec, self.kv_spec) + (P(),) * (
-            n_args - 4
-        )
+        # params, tokens, k_pages, v_pages, page_table, lengths, q_lens
+        in_specs = (self.param_specs, P(), self.kv_spec, self.kv_spec, P(), P(), P())
         return shard_map(
             f,
             mesh=self.mesh,

@@ -29,14 +29,6 @@ Greedy output streams are byte-identical to single-tenant sharing-off
 serving for the same request set: scheduling order changes WHEN a request
 runs, never WHAT it generates (the recompute-preemption and prefix-cache
 exactness contracts of the underlying server).
-
-Multi-step windows (``paged_kv.multi_step``) ride underneath unchanged:
-the base server only fuses N decode rounds into one dispatch when NOTHING
-is queued and nothing is prefilling, so a tenant's pending admission
-always breaks the window first (its TTFT is never parked behind a fused
-window) and ``on_emit`` deficit accounting still sees every token — the
-SLA policy is indifferent to whether tokens arrived one dispatch or N
-dispatches at a time.
 """
 
 from __future__ import annotations
@@ -314,9 +306,7 @@ class MultiTenantServer:
 
     # --- observability ---------------------------------------------------
     def serve_stats(self) -> Dict:
-        """The base server's stats (incl. the multi-step window block —
-        ``window_steps`` / ``dispatches_per_token`` /
-        ``window_break_reasons``) with per-tenant SLA/budget breakdowns:
+        """The base server's stats with per-tenant SLA/budget breakdowns:
         ``budget_share`` (weight over all configured weights),
         ``goodput_share`` (fraction of served tokens), ``rejected``, and
         TTFT/TPOT SLA attainment (fraction of finished requests meeting

@@ -687,10 +687,6 @@ class FlightRecorder:
         self.last_spans = int(last_spans)
         self._installed: List[Callable[[], None]] = []
         self._prev_handlers: Dict[int, Any] = {}
-        # free-form armed-config block carried into every dump payload —
-        # e.g. the serving layer records the multi-step window horizon so
-        # a postmortem showing serve.window spans names its configuration
-        self.context: Dict[str, Any] = {}
         self.dumps = 0
 
     # --- triggers --------------------------------------------------------
@@ -768,7 +764,6 @@ class FlightRecorder:
             "point": point,
             "pid": os.getpid(),
             "wall_time": time.time(),
-            "context": dict(self.context),
             "dropped_spans": self.tracer.dropped(),
             "open_spans": self.tracer.open_spans(),
             "spans": self.tracer.spans(last=self.last_spans),

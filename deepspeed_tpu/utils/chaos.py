@@ -89,8 +89,6 @@ POINTS = (
     "ckpt.mid_array_write",
     "ckpt.post_commit",
     "serve.mid_step",
-    "serve.mid_window",  # inside a multi-step window's host phase: the whole
-    # window's tokens are buffered in the journal, none yet acked
     "train.mid_step",  # a single optimizer step: the step program dispatched
     # and the donated state adopted, but the counters / lr schedule / interval
     # checkpoint not yet committed — resume must replay from the last

@@ -289,10 +289,10 @@ def test_r007_quiet_inside_pool_and_on_reads():
 
 def test_r005_tp_ragged_step_host_transfer_flagged():
     """ISSUE 13 red test: the tensor-parallel scheduler path — ragged
-    steps, fused windows, and their settle methods — is inside the
+    steps, their enqueue and their settle methods — is inside the
     one-fetch-per-dispatch budget too. A host transfer smuggled into a
-    ``_tp_step`` / ``_ragged_step`` / ``_ragged_window`` /
-    ``_settle_window_rows`` costs a synchronous RTT on EVERY chip of the
+    ``_tp_step`` / ``_ragged_step`` / ``_dispatch`` /
+    ``_settle_fetched_rows`` costs a synchronous RTT on EVERY chip of the
     serving mesh, so DS-R005 must see those methods."""
     rules = _rules("""
         import numpy as np, jax
@@ -301,16 +301,16 @@ def test_r005_tp_ragged_step_host_transfer_flagged():
                 toks = np.asarray(self.pending)      # fetch per dispatch
             def _tp_step(self):
                 lens = jax.device_get(self.lengths)  # ditto, tp spelling
-            def _ragged_window(self):
+            def _dispatch(self, packed):
                 n = self.emitted.item()
-            def _settle_window_rows(self, rows, out):
+            def _settle_fetched_rows(self, step, out):
                 out = np.asarray(out)
     """)
     assert rules.count("DS-R005") == 4
 
 
 def test_r005_tp_settle_pragma_budget_still_honored():
-    """The sanctioned single packed fetch of a window stays pragma-able —
+    """The sanctioned single packed fetch of a step stays pragma-able —
     the rule polices UNBUDGETED transfers, not the contract fetch."""
     findings = lint_source(textwrap.dedent("""
         import numpy as np
@@ -926,9 +926,8 @@ def test_moe_package_lints_clean_under_routing_scope():
 
 
 def test_the_scheduler_has_one_sanctioned_fetch_a_dispatch_and_none_before_it():
-    """``inference/scheduler.py`` as it stands: exactly one device-to-host
-    read a dispatch site (the ragged step's and the window's), each carrying
-    the pragma; and where a step is enqueued behind the one in flight
+    """``inference/scheduler.py`` as it stands: one dispatch site and exactly
+    one device-to-host read for it, carrying the pragma; and where a step is enqueued behind the one in flight
     (``_pack``'s span, ``_dispatch``'s span) nothing reads the device or
     settles a step: the settle of the step before follows the enqueue, and
     the wait for the device is the last thing ``step()`` does."""
@@ -940,9 +939,9 @@ def test_the_scheduler_has_one_sanctioned_fetch_a_dispatch_and_none_before_it():
     rel = "deepspeed_tpu/inference/scheduler.py"
     assert [f for f in lint_source(src, path=rel) if f.rule == "DS-R005"] == []
     bare = [f for f in lint_source(re.sub(r"# lint: allow\(DS-R005\).*", "", src), path=rel) if f.rule == "DS-R005"]
-    # the one step's call (with the states and their slots, or with neither) and the window's
-    assert src.count("= step_fn(") + src.count("= window_fn(") == 2
-    assert sorted(re.search(r"PagedServer\.(\w+)", f.message).group(1) for f in bare) == ["_settle_ragged_rows", "_settle_window_rows"]
+    # the one step's call (with the states and their slots, or with neither)
+    assert src.count("= step_fn(") == 1 and src.count("_fn(") == 1
+    assert [re.search(r"PagedServer\.(\w+)", f.message).group(1) for f in bare] == ["_settle_ragged_rows"]
 
     server = next(n for n in ast.walk(ast.parse(src)) if isinstance(n, ast.ClassDef) and n.name == "PagedServer")
     methods = {n.name: n for n in server.body if isinstance(n, ast.FunctionDef)}
@@ -971,11 +970,86 @@ def test_the_scheduler_has_one_sanctioned_fetch_a_dispatch_and_none_before_it():
     assert calls(dispatch) and not [name for name, _ in calls(dispatch) if name in reads]
     assert dispatch.end_lineno < emit.lineno and "_settle_ragged_rows" in [name for name, _ in calls(emit)]
     # the jitted call alone is ``serve.enqueue``, inside the dispatch; what follows it there is this file's Python
-    for method, fn in (("_dispatch", "step_fn"), ("_ragged_window", "window_fn")):
-        enqueue = spans(methods[method])["serve.enqueue"]
-        assert fn in [name for name, _ in calls(enqueue)] and "set_cache" not in [name for name, _ in calls(enqueue)]
-        outer = spans(methods[method])["serve.dispatch"]
-        assert outer.lineno < enqueue.lineno and enqueue.end_lineno < outer.end_lineno
+    enqueue = spans(methods["_dispatch"])["serve.enqueue"]
+    assert "step_fn" in [name for name, _ in calls(enqueue)] and "set_cache" not in [name for name, _ in calls(enqueue)]
+    assert dispatch.lineno < enqueue.lineno and enqueue.end_lineno < dispatch.end_lineno
+    assert [m for m, fn in methods.items() if "serve.enqueue" in spans(fn)] == ["_dispatch"]
     # step(): admit (and pack again only for a newcomer), enqueue, ..., pack the next step, wait last
     order = [name for name, _ in sorted(calls(methods["step"]), key=lambda c: c[1]) if name in ("_dispatch", "_admit", "_pack", "_wait_ragged_rows")]
     assert order[:3] == ["_admit", "_pack", "_dispatch"] and order[-2:] == ["_pack", "_wait_ragged_rows"]
+
+
+# --- the hand-kept method patterns, held against the scheduler as it stands ----
+def _language(pattern):
+    """Every string a finite pattern (literals, groups, alternatives, ``?``) matches."""
+    from re import _parser as sre
+
+    def expand(items):
+        outs = [""]
+        for op, arg in items:
+            if op is sre.LITERAL:
+                tails = [chr(arg)]
+            elif op is sre.SUBPATTERN:
+                tails = expand(arg[3])
+            elif op is sre.BRANCH:
+                tails = [t for alt in arg[1] for t in expand(alt)]
+            elif op is sre.MAX_REPEAT and arg[:2] == (0, 1):
+                tails = [""] + expand(arg[2])
+            else:
+                assert op is sre.AT, op  # ^ and $
+                continue
+            outs = [o + t for o in outs for t in tails]
+        return outs
+
+    return set(expand(sre.parse(pattern)))
+
+
+def _paged_server_methods():
+    import ast
+
+    src = open(os.path.join(REPO, "deepspeed_tpu", "inference", "scheduler.py")).read()
+    server = next(n for n in ast.walk(ast.parse(src)) if isinstance(n, ast.ClassDef) and n.name == "PagedServer")
+    return src.splitlines(), {n.name: n for n in server.body if isinstance(n, ast.FunctionDef)}
+
+
+def test_every_alternative_of_the_serving_patterns_names_a_method_the_scheduler_has():
+    """``_SERVING_FN`` / ``_HOT_FN`` are kept by hand. Beside the round
+    methods by their generic names (``_ROUND_FN``: any ``*Server`` /
+    ``*Scheduler`` class, and this file's fixtures) every name they spell is
+    a method ``PagedServer`` has today, so one that goes takes its
+    alternative with it (the window's went with PR 61)."""
+    import re
+
+    from deepspeed_tpu.analysis import source_lint as sl
+
+    _, methods = _paged_server_methods()
+    generic = re.compile(rf"^_?{sl._ROUND_FN}$")
+    for pattern in (sl._SERVING_FN, sl._HOT_FN):
+        names = {n.lstrip("_") for n in _language(pattern.pattern) if not generic.match(n)}
+        assert names and all(pattern.match(n) and pattern.match("_" + n) for n in names)
+        assert {n for n in names if n not in methods and "_" + n not in methods} == set(), pattern.pattern
+    assert not [m for m in methods if generic.match(m)]  # the scheduler has no method by a generic name
+    assert "window" not in sl._HOT_FN.pattern + sl._SERVING_FN.pattern + sl._R009_FN.pattern and "plain_" not in sl._R009_FN.pattern
+    # and the class qualifies as a serving loop at all
+    assert sl._HOT_CLASS.search("PagedServer") and any(sl._SERVING_FN.match(m) for m in methods)
+
+
+def test_every_scheduler_method_that_enqueues_a_program_or_reads_its_result_is_in_scope():
+    """The other direction: a ``PagedServer`` method that calls a jitted
+    program (the step's, the token feed's two), waits for the device or
+    holds the sanctioned fetch is one DS-R005 looks at."""
+    import ast
+
+    from deepspeed_tpu.analysis.source_lint import _HOT_FN
+
+    lines, methods = _paged_server_methods()
+    jitted = {"step_fn", "_feed_tokens", "_next_tokens"}
+    touching = set()
+    for name, fn in methods.items():
+        called = {n.func.attr if isinstance(n.func, ast.Attribute) else getattr(n.func, "id", "") for n in ast.walk(fn) if isinstance(n, ast.Call)}
+        if called & (jitted | {"block_until_ready", "device_get"}) or any("allow(DS-R005)" in ln for ln in lines[fn.lineno - 1 : fn.end_lineno]):
+            touching.add(name)
+    assert {"_pack", "_dispatch", "_wait_ragged_rows", "_settle_ragged_rows"} <= touching
+    assert [m for m in sorted(touching) if not _HOT_FN.match(m)] == []
+    # what calls those is in scope too: the settle of the rows, and the loop itself
+    assert all(_HOT_FN.match(m) for m in ("_settle_fetched_rows", "_settle_spec_row", "step", "run", "serve"))

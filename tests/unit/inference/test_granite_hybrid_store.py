@@ -176,7 +176,7 @@ def test_the_step_span_keeps_the_state_bytes_true_for_the_kind(toy):
     assert srv.pool.cache_bytes()["state_bytes_in_use"] == 0
 
 
-FEATURES = ["prefix_cache", "forks", "spec_decode", "multi_step", "generate", "beam_generate", "multistep_program", "rollback", "attach_prefix", "train", "tensor_parallel"]
+FEATURES = ["prefix_cache", "forks", "spec_decode", "generate", "beam_generate", "rollback", "attach_prefix", "train", "tensor_parallel"]
 
 
 @pytest.mark.parametrize("feature", FEATURES)
@@ -192,10 +192,8 @@ def test_what_needs_a_state_snapshot_is_refused(toy, feature):
         "prefix_cache": lambda: PagedServer(cfg, params, prefix_cache=True, **kw),
         "forks": lambda: PagedServer(cfg, params, prefix_cache=True, **kw),
         "spec_decode": lambda: PagedServer(cfg, params, spec_decode={"enable": True}, **kw),
-        "multi_step": lambda: PagedServer(cfg, params, multi_step={"enable": True, "horizon": 4}, **kw),
         "generate": lambda: decode.generate(cfg, params, tokens, 4),
         "beam_generate": lambda: decode.beam_generate(cfg, params, tokens, 4, num_beams=2),
-        "multistep_program": lambda: decode.build_ragged_multistep(cfg, SLOTS, 1, 4, PAGE),
         "rollback": lambda: PagedServer(cfg, params, **kw).pool.rollback(0, 1),
         "attach_prefix": lambda: PagedServer(cfg, params, **kw).pool.alloc_slot(8, prefix_tokens=tokens[0]),
         "train": lambda: lm.apply(params, (tokens, tokens), train=True),

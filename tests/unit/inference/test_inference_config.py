@@ -48,17 +48,29 @@ class TestConfigModel:
     @pytest.mark.parametrize("through", ["config_model", "init_inference"])
     @pytest.mark.parametrize(
         "section, key, value",
-        [("paged_kv", "ragged", True), ("paged_kv", "slot_buckets", [1, 2, 4]), ("spec_decode", "spec_lens", [2, 4])],
+        [("paged_kv", "ragged", True), ("paged_kv", "slot_buckets", [1, 2, 4]), ("spec_decode", "spec_lens", [2, 4]),
+         ("paged_kv", "multi_step", {"enable": True, "horizon": 8}), ("paged_kv", "multi_step", {"enable": False})],
+        ids=["ragged", "slot_buckets", "spec_lens", "multi_step_on", "multi_step_off"],
     )
     def test_removed_serving_knobs_refused_by_name(self, section, key, value, through):
-        """The bucketed serving path's three options went with it: a config
-        that still sets one is refused by the key's name, not read past,
-        whatever the value, and before ``init_inference`` builds anything."""
+        """The bucketed serving path's three options went with it (PR 28) and
+        the multi-step window's with it (PR 61): a config that still sets one
+        is refused by the key's name, not read past, whatever the value, and
+        before ``init_inference`` builds anything."""
         with pytest.raises(ValidationError, match=rf"{section}\.{key}\b"):
             if through == "config_model":
                 DeepSpeedInferenceConfig(**{section: {key: value}})
             else:
                 ds.init_inference(object(), config={section: {key: value}})
+
+
+    def test_the_server_takes_no_multi_step_argument(self):
+        """The constructor's ``multi_step=`` went with the window: a caller
+        that still passes it is told so by name, before anything is built."""
+        from deepspeed_tpu.inference.scheduler import PagedServer
+
+        with pytest.raises(TypeError, match="multi_step"):
+            PagedServer(None, None, multi_step={"enable": True, "horizon": 4})
 
 
 class TestInitInference:

@@ -419,7 +419,7 @@ def test_defrag_moves_the_latent_pages_and_leaves_the_states(toy):
     pool.integrity_check()
 
 
-@pytest.mark.parametrize("feature", ["prefix_cache", "forks", "spec_decode", "multi_step", "generate", "beam_generate", "multistep_program", "rollback", "attach_prefix", "train", "tensor_parallel"])
+@pytest.mark.parametrize("feature", ["prefix_cache", "forks", "spec_decode", "generate", "beam_generate", "rollback", "attach_prefix", "train", "tensor_parallel"])
 def test_what_needs_a_state_snapshot_or_knows_only_k_and_v_pages_is_refused(toy, feature):
     """Each raises where it is built: a state + latent pool has neither a
     snapshot of a row's state at an earlier position nor K and V pages to
@@ -432,10 +432,8 @@ def test_what_needs_a_state_snapshot_or_knows_only_k_and_v_pages_is_refused(toy,
         "prefix_cache": lambda: PagedServer(cfg, params, prefix_cache=True, **kw),
         "forks": lambda: PagedServer(cfg, params, prefix_cache=True, **kw),
         "spec_decode": lambda: PagedServer(cfg, params, spec_decode={"enable": True}, **kw),
-        "multi_step": lambda: PagedServer(cfg, params, multi_step={"enable": True, "horizon": 4}, **kw),
         "generate": lambda: decode.generate(cfg, params, tokens, 4),
         "beam_generate": lambda: decode.beam_generate(cfg, params, tokens, 4, num_beams=2),
-        "multistep_program": lambda: decode.build_ragged_multistep(cfg, SLOTS, 1, 4, PAGE),
         "rollback": lambda: PagedServer(cfg, params, **kw).pool.rollback(0, 1),
         "attach_prefix": lambda: PagedServer(cfg, params, **kw).pool.alloc_slot(8, prefix_tokens=tokens[0]),
         "train": lambda: lm.apply(params, (tokens, tokens), train=True),

@@ -328,7 +328,7 @@ def test_the_engine_serves_it_with_two_programs_and_says_both_layouts(toy, monke
         assert gap.max() < F32_TOL
 
 
-@pytest.mark.parametrize("feature", ["prefix_cache", "spec_decode", "multi_step", "generate", "beam_generate", "tensor_parallel"])
+@pytest.mark.parametrize("feature", ["prefix_cache", "spec_decode", "generate", "beam_generate", "tensor_parallel"])
 def test_what_assumes_a_rows_pages_hold_its_whole_past_is_refused(toy, feature):
     """Each raises where it is built, naming what the ring does not keep (or,
     for tensor parallelism, the layer stack it has no rules for: 8 KV heads
@@ -339,7 +339,6 @@ def test_what_assumes_a_rows_pages_hold_its_whole_past_is_refused(toy, feature):
     calls = {
         "prefix_cache": lambda: PagedServer(cfg, params, prefix_cache=True, **kw),
         "spec_decode": lambda: PagedServer(cfg, params, spec_decode={"enable": True}, **kw),
-        "multi_step": lambda: PagedServer(cfg, params, multi_step={"enable": True, "horizon": 4}, **kw),
         "generate": lambda: decode.generate(cfg, params, tokens, 4),
         "beam_generate": lambda: decode.beam_generate(cfg, params, tokens, 4, num_beams=2),
         "tensor_parallel": lambda: decode.build_ragged_step(cfg, SLOTS, 1, PAGE, attn_impl="xla", tp=SimpleNamespace(degree=2, quantized_allreduce=False, quantized_weights=False, comm_chunks=2, cache_key=lambda: 2)),

@@ -191,11 +191,11 @@ def test_prefill_then_decode_through_every_pass_cache_is_the_full_forward(toy, m
 
 def test_paged_server_serves_the_references_argmax(toy):
     """Through ``PagedServer`` itself: more requests than slots (slots are freed and taken again), prompts longer and
-    shorter than a chunk, unequal budgets, the prefix cache and a multi-step window on. Every served token is the
+    shorter than a chunk, unequal budgets, the prefix cache on and a step in flight. Every served token is the
     reference's arg-max for its position, teacher-forced, to TOL (logits, not a comparison of tokens)."""
     cfg, lm, params, section = toy
     server = PagedServer(cfg, params, page_size=PAGE, max_slots=SLOTS, max_seq_len=MAXLEN, prefill_chunk=CHUNK, attn_impl="xla",
-                         dtype=jnp.float32, prefix_cache=True, multi_step={"enable": True, "horizon": 4})
+                         dtype=jnp.float32, prefix_cache=True)
     rng = np.random.default_rng(4)
     shared = rng.integers(0, 512, 24).astype(np.int32)  # three whole pages two prompts share: attached, then copied on write
     prompts = [np.concatenate([shared, rng.integers(0, 512, n).astype(np.int32)]) if i in (1, 4) else rng.integers(0, 512, n).astype(np.int32)
@@ -206,7 +206,7 @@ def test_paged_server_serves_the_references_argmax(toy):
         server.step()
     stats = server.serve_stats()
     assert (stats["loop_passes"], stats["cache_layers"]) == (4, 12)
-    assert stats["window_steps"] > 0 and server.pool.stats["prefix_hit_pages"] > 0, (stats["window_steps"], stats["window_break_reasons"], server.pool.stats)
+    assert stats["run_ahead_share"] > 0.8 and server.pool.stats["prefix_hit_pages"] > 0, (stats["run_ahead_share"], stats["drain_reasons"], server.pool.stats)
     for uid, p, n in zip(uids, prompts, budgets):
         out = np.asarray(server.take_result(uid))
         assert out.shape == (p.size + n,) and np.array_equal(out[: p.size], p)

@@ -27,11 +27,12 @@ from deepspeed_tpu.inference.scheduler import PagedServer
 from deepspeed_tpu.inference.spec_decode import Drafter
 from deepspeed_tpu.models import TransformerLM
 from deepspeed_tpu.models.config import TransformerConfig
-from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM, mimo_v2_config, solar_open2_config
+from deepspeed_tpu.models import hybrid_moe
+from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM
 from deepspeed_tpu.models.moe_transformer import MoETransformerLM, olmoe_config
 from deepspeed_tpu.profiling.tracer import Tracer
 from deepspeed_tpu.utils import chaos
-from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file  # noqa: F401 (the two fixtures are taken by their import)
+from tests.unit.inference.hybrid_toys import _clear_jax_caches, _compiled_programs_live_as_long_as_the_file, seeded  # noqa: F401 (the two fixtures are taken by their import)
 
 CFG = dict(
     vocab_size=128, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=96,
@@ -130,6 +131,10 @@ SCENARIOS = {
     "eos_mid_run": dict(prompts=dict(n=5, seed=4, lo=3, hi=20), budgets=[16, 18, 20, 17, 19], eos=True),
     "prefix_cache": dict(prompts=dict(n=6, seed=5, lo=4, hi=12), budgets=[9, 6, 11, 8, 7, 10], shared_prefix=24, kw=dict(prefix_cache=True)),
     "prefix_cache_eos": dict(prompts=dict(n=5, seed=6, lo=4, hi=12), budgets=[14, 16, 15, 17, 13], shared_prefix=16, eos=True, kw=dict(prefix_cache=True)),
+    # prompt + budget == max_seq_len: the step packed behind a row's last one must not carry the row (a position past the cap is refused)
+    "budget_ends_at_the_seq_cap": dict(prompts=dict(n=4, seed=21, lo=3, hi=14), budgets="to_the_cap", kw=dict(max_seq_len=40)),
+    # a row's last written position fills its last page, in a pool of exactly the rows' pages: one position more would have to preempt
+    "finish_on_a_page_edge": dict(prompts=dict(n=4, seed=22, lo=3, hi=14), budgets="to_a_page_edge"),
 }
 
 
@@ -142,11 +147,20 @@ def test_streams_are_those_of_a_server_drained_every_step(dense, name):
         system = np.random.RandomState(99).randint(0, 128, sc["shared_prefix"]).astype(np.int32)
         prompts = [np.concatenate([system, p]) for p in prompts]
     budgets = sc["budgets"]
+    kw = dict(sc.get("kw", {}))
+    if budgets == "to_the_cap":
+        budgets = [40 - p.size for p in prompts]
+    elif budgets == "to_a_page_edge":  # 8 m + 1 tokens in all: 8 m positions written, m whole pages (and the trash page)
+        pages = [-(-p.size // 8) + 1 + i % 2 for i, p in enumerate(prompts)]
+        budgets = [8 * m + 1 - p.size for m, p in zip(pages, prompts)]
+        kw["num_pages"] = sum(pages) + 1
     requests = _eos_requests(cfg, params, prompts, budgets) if sc.get("eos") else [(p, n, None) for p, n in zip(prompts, budgets)]
-    ahead, drained, got, want = _both(cfg, params, requests, **sc.get("kw", {}))
+    ahead, drained, got, want = _both(cfg, params, requests, **kw)
     _assert_same(got, want)
     for (p, n, eos), stream in zip(requests, got):
         assert np.array_equal(stream, _generate(cfg, params, p, n, eos))
+    if isinstance(sc["budgets"], str):
+        assert all(s.size == p.size + n for (p, n, _), s in zip(requests, got)) and ahead.stats["preempted"] == drained.stats["preempted"] == 0
     # which of the two each was
     assert ahead.stats["run_ahead_steps"] >= ahead.stats["ragged_steps"] - 2 > 0
     assert set(ahead.stats["drain_reasons"]) <= {"idle"}
@@ -202,11 +216,11 @@ class _FutureDrafter(Drafter):
         return cont.astype(np.int32)
 
 
-@pytest.mark.parametrize("armed", ["silent_drafter", "future_drafter", "ngram_config", "multi_step"])
-def test_an_armed_drafter_or_window_makes_the_server_synchronous(dense, armed):
-    """Drafts are proposed from settled contexts and a window probes settled
-    rows: the step is settled in the call that dispatched it, nothing ever
-    runs ahead, and the streams are the plain server's."""
+@pytest.mark.parametrize("armed", ["silent_drafter", "future_drafter", "ngram_config"])
+def test_an_armed_drafter_makes_the_server_synchronous(dense, armed):
+    """Drafts are proposed from settled contexts: the step is settled in the
+    call that dispatched it, nothing ever runs ahead, and the streams are the
+    plain server's."""
     cfg, params = dense
     prompts = _prompts(5, seed=8, lo=4, hi=18)
     budgets = [14, 17, 9, 13, 12]
@@ -215,47 +229,63 @@ def test_an_armed_drafter_or_window_makes_the_server_synchronous(dense, armed):
         "silent_drafter": dict(drafter=_SilentDrafter()),
         "future_drafter": dict(drafter=_FutureDrafter(futures)),
         "ngram_config": dict(spec_decode={"enable": True, "max_draft": 3}),
-        "multi_step": dict(multi_step={"enable": True, "horizon": 4}),
     }[armed]
     requests = [(p, n, None) for p, n in zip(prompts, budgets)]
     ahead, drained, got, want = _both(cfg, params, requests, **kw)
     _assert_same(got, want)
     for i, stream in enumerate(got):
         assert np.array_equal(stream, futures[i])
-    reason = "window" if armed == "multi_step" else "draft"
     assert ahead.stats["run_ahead_steps"] == 0 and ahead.stats["overshoot_rows"] == 0
-    assert ahead.stats["drain_reasons"] == {reason: ahead.stats["ragged_steps"]}
+    assert ahead.stats["drain_reasons"] == {"draft": ahead.stats["ragged_steps"]}
     assert ahead.serve_stats()["run_ahead_share"] == 0.0
     if armed == "future_drafter":
         assert ahead.stats["spec_accepted"] > 0
-    if armed == "multi_step":
-        assert ahead.stats["window_steps"] > 0
     _drained_to_zero(ahead)
 
 
 # --- the models with layers of more than one kind, and the routed one ----------
+LOOPED = dict(vocab_size=512, hidden_size=64, intermediate_size=96, num_layers=3, num_heads=4, head_dim=16, max_seq_len=256,
+              norm="rmsnorm", norm_eps=1e-6, position="rope", rope_theta=1e6, activation="swiglu", use_bias=False, tie_embeddings=False,
+              num_loops=4, post_sublayer_norm=True, exit_gate=True, dtype="float32", flash_attention=False)
+HYBRIDS = {"solar": "solar_open2_config", "mimo": "mimo_v2_config", "glm": "glm4_moe_lite_config", "laguna": "laguna_config",
+           "kimi": "kimi_linear_config", "granite": "granite_hybrid_config", "nemotron": "nemotron_h_config"}
+# init's 0.02 gives a nearly flat softmax and a recurrent state a hundredth of its input, in which a stale state,
+# ring or latent page would hide: trained-like scales on the scores and on the state-space layers' x, B and C
+TRAINED_LIKE = {"wq": 40.0, "wq_b": 40.0, "wk": 8.0, "w_xbc": 8.0}
+
+
+def _trained_like(tree):
+    if isinstance(tree, list):
+        return [_trained_like(t) for t in tree]
+    return {k: _trained_like(v) if isinstance(v, (dict, list)) else v * TRAINED_LIKE.get(k, 1.0) for k, v in tree.items()}
+
+
 def _hybrid(kind):
-    if kind == "solar":
-        cfg = solar_open2_config("tiny", num_layers=8, dtype="float32")
-    elif kind == "mimo":
-        cfg = mimo_v2_config("tiny", dtype="float32")
-    else:
+    if kind == "olmoe":
         cfg = olmoe_config("tiny", dtype="float32", flash_attention=False, remat=False)
         return cfg, MoETransformerLM(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    return cfg, HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None)
+    if kind == "ouro":  # the looped stack: a KV cache of its own for every pass
+        cfg = TransformerConfig(**LOOPED)
+        return cfg, seeded(TransformerLM(cfg))
+    cfg = getattr(hybrid_moe, HYBRIDS[kind])("tiny", dtype="float32", **({"num_layers": 8} if kind == "solar" else {}))
+    return cfg, _trained_like(seeded(HybridMoETransformerLM(cfg)))
 
 
-@pytest.mark.parametrize("kind", ["solar", "mimo", "olmoe"])
+@pytest.mark.parametrize("kind", ["solar", "mimo", "olmoe", "glm", "laguna", "kimi", "granite", "nemotron", "ouro"])
 def test_the_state_store_the_window_rings_and_the_routing_counts_follow(kind):
     """The state store and the window rings are threaded through the steps
     as the pools are, and an MoE model's routing counts ride past the token
     rows of the result the gather reads: the streams and the counts are the
     drained server's, EOS overshoot included (a stray write into a slot's
-    state or ring is harmless to its next owner: the program restarts a row
-    that begins at position 0)."""
+    state, ring, latent page or, in a looped stack, a pass's cache is
+    harmless to its next owner: the program restarts a row that begins at
+    position 0). The families whose every cell runs ahead: Solar (linear
+    state), MiMo (window rings), OLMoE, GLM (latent pages), Laguna (gated
+    window + full layers), Kimi (KDA state + latent), granite (SSD state, a
+    dense FFN), Nemotron (blocks of one sublayer), Ouro (four passes)."""
     cfg, params = _hybrid(kind)
     rs = np.random.RandomState(11)
-    prompts = [rs.randint(0, 512, n).astype(np.int32) for n in (37, 5, 20, 16, 50, 3, 9)]
+    prompts = [rs.randint(0, min(cfg.vocab_size, 512), n).astype(np.int32) for n in (37, 5, 20, 16, 50, 3, 9)]
     budgets = [12, 20, 9, 15, 7, 30, 11]
     kw = dict(page_size=8, max_slots=4, prefill_chunk=16, max_seq_len=96)
     plain = _server(cfg, params, **kw)
@@ -267,11 +297,15 @@ def test_the_state_store_the_window_rings_and_the_routing_counts_follow(kind):
     _assert_same(got, want)
     assert ahead.stats["overshoot_rows"] >= 1 and ahead.stats["run_ahead_steps"] > 0.8 * ahead.stats["ragged_steps"]
     # a discarded row-step still routed: the overshoot rows are the only difference
-    extra = ahead.stats["overshoot_rows"] * getattr(cfg, "num_moe_layers", cfg.num_layers) * cfg.moe_top_k
+    extra = ahead.stats["overshoot_rows"] * getattr(cfg, "num_moe_layers", cfg.num_layers) * getattr(cfg, "moe_top_k", 0)
     if "moe_routed_assignments" in ahead.stats:
         assert ahead.stats["moe_routed_assignments"] == drained.stats["moe_routed_assignments"] + extra
-    else:
+    elif "moe_assignments" in ahead.stats:
         assert ahead.stats["moe_assignments"] == drained.stats["moe_assignments"] + extra
+    else:
+        assert kind in ("granite", "ouro")  # no expert: nothing is counted
+    # a slot freed by an overshoot row was taken again: seven requests over four slots
+    assert ahead.stats["admitted"] == 7 > ahead.pool.max_slots
     _drained_to_zero(ahead)
 
 
@@ -533,7 +567,7 @@ def recorded(dense):
     came is packed again under its ``seq``) and one drain (``settle()``
     drops what was packed behind the step in flight: that ``seq`` is packed
     again too), with every ``_Packed`` that reached ``_dispatch`` kept as the
-    device got it; and a second, of a server with windows armed."""
+    device got it."""
     cfg, params = dense
     tracer = Tracer(max_spans=1 << 14)
     server = _server(cfg, params, tracer=tracer)
@@ -560,11 +594,7 @@ def recorded(dense):
     server.settle()  # the one drain: what was packed behind the step in flight is dropped
     server.run()
     assert server.stats["drain_reasons"] == {"settle": 1, "idle": 1} and server.stats["mixed_steps"] >= 4
-    windowed_tracer = Tracer(max_spans=1 << 14)
-    windowed = _server(cfg, params, tracer=windowed_tracer, multi_step={"enable": True, "horizon": 4})
-    windowed.serve([short, newcomer], max_new_tokens=[13, 9])
-    assert windowed.stats["window_steps"] >= 1
-    return server, tracer.spans(), dispatched, repacked, windowed_tracer.spans()
+    return server, tracer.spans(), dispatched, repacked
 
 
 def _last_pack_before_each_enqueue(spans):
@@ -579,7 +609,7 @@ def _last_pack_before_each_enqueue(spans):
 
 
 STEP_RECORD = ["every_enqueue_has_its_pack", "row_lens_are_the_dispatched_rows", "mixed_is_the_width", "kv_tokens_is_their_sum",
-               "a_repacked_seq_takes_its_last_pack", "json_dumps_as_the_flight_recorder", "a_windows_pack"]
+               "a_repacked_seq_takes_its_last_pack", "json_dumps_as_the_flight_recorder"]
 
 
 @pytest.mark.parametrize("case", STEP_RECORD)
@@ -589,7 +619,7 @@ def test_a_step_carries_its_own_record_under_its_seq(recorded, case):
     ``mixed`` says its width and ``kv_tokens`` their sum, under the ``seq``
     of the ``serve.enqueue`` that follows; a ``seq`` packed twice (a newcomer,
     a drain) is read from its LAST pack before the enqueue."""
-    server, spans, dispatched, repacked, window_spans = recorded
+    server, spans, dispatched, repacked = recorded
     record = _last_pack_before_each_enqueue(spans)
     if case == "every_enqueue_has_its_pack":
         assert sorted(record) == sorted(dispatched) == list(range(server.stats["ragged_steps"]))
@@ -621,14 +651,70 @@ def test_a_step_carries_its_own_record_under_its_seq(recorded, case):
         # the pack a drain dropped was made from counts the settle did not overturn: the same rows, packed again
         first, last = ([r["attrs"] for r in spans if r["name"] == "serve.pack" and r["attrs"].get("seq") == repacked[1]])
         assert first == last and first is not last
-    elif case == "json_dumps_as_the_flight_recorder":
-        for r in spans + window_spans:
+    else:
+        for r in spans:
             if r["name"] == "serve.pack":
                 assert json.loads(json.dumps(r["attrs"])) == r["attrs"] and isinstance(r["attrs"]["row_lens"], str)
-    else:
-        window = _last_pack_before_each_enqueue(window_spans)
-        windows = [a for a in window.values() if a["program"].startswith("paged_multistep")]
-        assert windows and all(a["mixed"] == 0 and "width" not in a for a in windows)
-        for a in windows:  # every row decodes one token in the window's first round
-            rows = _decode_row_lens(a["row_lens"])
-            assert len(rows) == a["rows"] and all(q == 1 for q, _ in rows) and a["kv_tokens"] == sum(kv for _, kv in rows)
+
+
+# --- what is left to count ------------------------------------------------------
+DRAIN_REASONS = {"idle", "draft", "preempt", "settle", "recover", "extract_request", "restore_request", "finalize_migration", "compact_journal"}
+
+
+def test_the_scheduler_names_nine_drain_reasons_and_a_run_counts_no_other(dense, tmp_path):
+    """``_drain``'s call sites, read from the source, name nine reasons; and a
+    run that takes a tight pool, an entry point between two calls and a
+    compaction counts reasons of those nine alone."""
+    import inspect
+    import re
+
+    assert set(re.findall(r'_drain\("(\w+)"\)', inspect.getsource(PagedServer))) == DRAIN_REASONS
+    cfg, params = dense
+    prompts = _prompts(5, seed=7, lo=10, hi=22)
+    seen = set()
+    for kw in (dict(num_pages=9), dict(drafter=_SilentDrafter()), dict(journal=RequestJournal(str(tmp_path)))):
+        server = _server(cfg, params, **kw)
+        uids = [server.submit(p, max_new_tokens=12) for p in prompts]
+        for _ in range(4):
+            server.step()
+        if server.journal is not None:
+            server.compact_journal()  # settles the step in flight first
+            server.step()
+        server.restore_request(server.extract_request(uids[0]))
+        server.settle()
+        results = server.run()
+        for u, p in zip(uids, prompts):
+            assert np.array_equal(results[u], _generate(cfg, params, p, 12))
+        seen |= set(server.stats["drain_reasons"])
+        _drained_to_zero(server)
+    assert {"idle", "draft", "preempt", "extract_request", "compact_journal"} <= seen <= DRAIN_REASONS
+
+
+def test_the_stats_hold_no_window_key_and_still_the_dispatches_a_token(dense):
+    """``serve_stats()`` of one server and of a fleet of two: every counter
+    is of the one path there is, and ``dispatches_per_token`` is still there:
+    a server's dispatches over its tokens, fewer where drafts are accepted,
+    and for a fleet the ratio of the sums."""
+    from deepspeed_tpu.inference.fleet import FleetRouter, ReplicaHandle
+
+    cfg, params = dense
+    prompts = _prompts(4, seed=23, lo=4, hi=12)
+    futures = {i: _generate(cfg, params, p, 12) for i, p in enumerate(prompts)}
+    plain, drafted = _server(cfg, params), _server(cfg, params, drafter=_FutureDrafter(futures))
+    for server in (plain, drafted):
+        for i, o in enumerate(server.serve(prompts, max_new_tokens=12)):
+            assert np.array_equal(o, futures[i])
+    servers = [_server(cfg, params) for _ in range(2)]
+    router = FleetRouter([ReplicaHandle(name=f"r{i}", server=s) for i, s in enumerate(servers)])
+    uids = [router.submit(p, max_new_tokens=12) for p in prompts]
+    results = router.run()
+    for i, u in enumerate(uids):
+        assert np.array_equal(results[u], futures[i])
+    merged = router.serve_stats()
+    for stats in (merged, plain.serve_stats(), drafted.serve_stats(), *merged["replicas"].values()):
+        assert not [k for k in stats if "window" in k], sorted(stats)
+        assert stats["dispatches"] == stats["ragged_steps"] > 0 and "dispatches_per_token" in stats
+    assert merged["emitted_tokens"] == drafted.stats["emitted_tokens"] == 48
+    assert drafted.serve_stats()["dispatches_per_token"] == drafted.stats["dispatches"] / 48 < plain.serve_stats()["dispatches_per_token"] == plain.stats["dispatches"] / 48
+    assert merged["dispatches"] == sum(s.stats["dispatches"] for s in servers) and all(s.stats["dispatches"] for s in servers)
+    assert merged["dispatches_per_token"] == merged["dispatches"] / 48

@@ -235,16 +235,6 @@ def test_narrow_and_verify_programs_hold_no_loop_but_the_layer_scan(request, mod
 
 
 @pytest.mark.parametrize("model", ["dense", "olmoe"])
-def test_multistep_program_holds_no_tile_loop(request, model):
-    cfg, params = request.getfixturevalue(model)
-    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    pool = jax.ShapeDtypeStruct((cfg.num_layers, ROWS * MAXP + 1, cfg.num_kv_heads, PAGE, cfg.head_dim), jnp.float32)
-    window = decode.build_ragged_multistep(cfg, ROWS, 1, 4, PAGE, attn_impl="xla")
-    jaxpr = window.trace(params, S(ROWS), pool, pool, S(ROWS, MAXP), S(ROWS), S(ROWS), S(ROWS), S(ROWS)).jaxpr.jaxpr
-    assert loops_of(jaxpr) == [("scan", 0), ("scan", 1)]  # the rounds, and in each the layers
-
-
-@pytest.mark.parametrize("model", ["dense", "olmoe"])
 def test_mixed_program_runs_its_tiles_in_loops_traced_once(request, model):
     """Two tile loops inside the layer scan and the head's after it, each a
     ``while`` whose trip count is data; as many equations at four times the

@@ -205,20 +205,38 @@ def test_tp_spec_decode_parity(model_and_params):
         np.testing.assert_array_equal(a, b)
 
 
-def test_tp_multistep_window_parity(model_and_params):
-    """Fused multi-step windows on the mesh: the scan-of-rounds program
-    shards like the single-step one (per-round all-reduces inside the
-    scan), windows form, and streams stay byte-identical."""
+@pytest.mark.parametrize("case", ["budgets_alone", "an_eos_a_request"])
+def test_tp_streams_with_a_step_in_flight_are_a_drained_servers(model_and_params, case):
+    """The sharded server runs ahead as the single-chip one does: a row's
+    next token is gathered on the mesh from the unsettled result
+    (``build_token_feed(tp)``: the window is placed replicated whatever fed
+    it), an EOS is seen a step late and its row-step discarded, and the
+    streams are those of the same server settled after every step and of the
+    single-chip one."""
     cfg, _, params = model_and_params
-    prompts = _prompts(3, seed=7, lo=4, hi=9)
-    ref = _server(cfg, params).serve(prompts, max_new_tokens=13)
-    srv = _server(
-        cfg, params, tp=_tp(2), multi_step={"enable": True, "horizon": 4},
-    )
-    outs = srv.serve(prompts, max_new_tokens=13)
-    assert srv.stats["window_steps"] >= 1, "no window formed"
-    for a, b in zip(outs, ref):
+    prompts = _prompts(5, seed=9, lo=4, hi=20)
+    budgets = [12, 9, 14, 10, 11]
+    ref = _server(cfg, params).serve(prompts, max_new_tokens=budgets)
+    eos = [int(f[p.size + 2 + i]) if case == "an_eos_a_request" else None for i, (p, f) in enumerate(zip(prompts, ref))]
+    runs = []
+    for drained in (False, True):
+        srv = _server(cfg, params, tp=_tp(2))
+        uids = [srv.submit(p, max_new_tokens=n, eos_token_id=e) for p, n, e in zip(prompts, budgets, eos)]
+        while srv.has_work():
+            srv.step()
+            if drained:
+                srv.settle()
+        runs.append((srv, [srv.take_result(u) for u in uids]))
+    (ahead, got), (sync, want) = runs
+    for a, b, f, p, e in zip(got, want, ref, prompts, eos):
         np.testing.assert_array_equal(a, b)
+        end = f.size if e is None else p.size + int(np.flatnonzero(f[p.size:] == e)[0]) + 1
+        np.testing.assert_array_equal(a, f[:end])
+    assert ahead.serve_stats()["run_ahead_share"] > 0.8 and set(ahead.stats["drain_reasons"]) <= {"idle"}
+    assert sync.stats["run_ahead_steps"] == 0 and sync.stats["overshoot_rows"] == 0
+    assert (ahead.stats["overshoot_rows"] >= 1) == (case == "an_eos_a_request")
+    for srv in (ahead, sync):
+        assert srv.pool.used_pages() == 0 and srv._in_flight is None
 
 
 # --- config / validation red tests ------------------------------------------

@@ -252,7 +252,7 @@ def test_the_engine_serves_it_with_two_programs_and_preemption_changes_nothing(t
     assert "serve.pack" in spans
 
 
-@pytest.mark.parametrize("feature", ["prefix_cache", "spec_decode", "multi_step", "generate", "beam_generate", "multistep_program", "rollback", "attach_prefix", "train", "tensor_parallel"])
+@pytest.mark.parametrize("feature", ["prefix_cache", "spec_decode", "generate", "beam_generate", "rollback", "attach_prefix", "train", "tensor_parallel"])
 def test_what_assumes_a_rows_pages_hold_its_whole_past_is_refused(toy, feature):
     """Each raises where it is built, naming what the ring does not keep."""
     cfg, lm, params, _ = toy
@@ -261,10 +261,8 @@ def test_what_assumes_a_rows_pages_hold_its_whole_past_is_refused(toy, feature):
     calls = {
         "prefix_cache": lambda: PagedServer(cfg, params, prefix_cache=True, **kw),
         "spec_decode": lambda: PagedServer(cfg, params, spec_decode={"enable": True}, **kw),
-        "multi_step": lambda: PagedServer(cfg, params, multi_step={"enable": True, "horizon": 4}, **kw),
         "generate": lambda: decode.generate(cfg, params, tokens, 4),
         "beam_generate": lambda: decode.beam_generate(cfg, params, tokens, 4, num_beams=2),
-        "multistep_program": lambda: decode.build_ragged_multistep(cfg, SLOTS, 1, 4, PAGE),
         "rollback": lambda: PagedServer(cfg, params, **kw).pool.rollback(0, 1),
         "attach_prefix": lambda: PagedServer(cfg, params, **kw).pool.alloc_slot(8, prefix_tokens=tokens[0]),
         "train": lambda: lm.apply(params, (tokens, tokens), train=True),

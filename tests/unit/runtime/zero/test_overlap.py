@@ -9,11 +9,13 @@ move where collectives are issued, never what is computed. So:
   (``prefetch_layers: 0``), across ZeRO-1/3 × gas ∈ {1, 2} × fp32/bf16;
 * the PR-1 invariants survive the restructuring: one fused dispatch per
   optimizer step, full state donation (checked via the analysis passes);
-* the ``overlap`` analysis pass verifies the compiled ZeRO-3 step has
-  real compute to hide every loop-body collective behind (green, with
-  nonzero hidden bytes — the acceptance criterion), refuses to verify the
-  unpipelined raw-scan program, and fails a deliberately serialized
-  schedule (red fixture: every dot depends on the loop's gather).
+* the ``overlap`` analysis pass finds the compiled ZeRO-3 step's
+  collectives, hides every loop-body parameter gather behind real compute
+  (nonzero hidden bytes), refuses to verify the unpipelined raw-scan
+  program, and fails a deliberately serialized schedule (red fixture:
+  every dot depends on the loop's gather). The in-loop gradient
+  reduction is one combined all-reduce on the CPU, which nothing can
+  hide: ``overlap_verified`` is not evidence on this backend.
 
 Runs comm-free on the 8-device virtual CPU mesh.
 """
@@ -424,22 +426,38 @@ def test_one_dispatch_and_donation_preserved(eight_devices):
 # the overlap analysis pass: green on the real program, red on serialized
 # ---------------------------------------------------------------------------
 def test_overlap_pass_green_on_pipelined_zero3_step(eight_devices):
-    """Acceptance: the compiled ZeRO-3 pipelined step program verifies —
-    every loop-body collective has independent real compute to hide behind,
-    with nonzero hidden collective bytes — and the raw (plan-less) scan
-    program does NOT, on the same model/mesh (red on a real program, not
-    just the fixture)."""
+    """What a CPU compile of the ZeRO-3 pipelined step establishes, and what
+    it cannot. The passes read the compiled text's collectives (their count
+    and bytes by kind), every parameter all-gather inside a loop body has
+    independent real compute to hide behind (the prefetch: the raw,
+    plan-less scan of the same model and mesh exposes three), hidden bytes
+    are nonzero, and a step is one dispatch. The in-loop gradient reduction
+    IS in the backward loop's body, but XLA's CPU pipeline combines PR 58's
+    per-leaf reductions into ONE variadic all-reduce over a layer's nine
+    gradient dots, so no dot of that body is independent of it: the CPU
+    schedule offers nothing to verify there, and ``overlap_verified`` is not
+    evidence on this backend (the ledger has the measured collective share
+    of the chip's schedule)."""
     e = _engine({"prefetch_layers": 1})
     _train(e, _batches(1, 1))
-    t = e.analysis_report(passes=["overlap"])["totals"]
-    assert t["overlap_verified"] is True, t
-    assert t["hidden_collective_bytes"] > 0, t
+    rep = e.analysis_report(passes=["overlap", "collectives"])
+    t, ov = rep["totals"], rep["programs"]["fused_step"]["passes"]["overlap"]["summary"]
+    assert t["analysis_failures"] == 0 and t["collective_count"] == ov["collectives"] >= 20, t
+    assert set(t["collectives"]) == {"all-gather", "all-reduce", "all-to-all"} and t["collective_bytes"] > 0, t
+    assert t["hidden_collective_bytes"] == ov["hidden_bytes"] > 0 and ov["loop_collectives"] >= 4, ov
+    layers = jax.tree_util.tree_leaves(e.get_master_params()["layers"])
+    layer_bytes = sum(leaf.size // leaf.shape[0] for leaf in layers) * 4
+    # one collective of the loops is exposed, and it is no gather: a layer's gradient, reduced where the backward scan makes it
+    assert [(x["op"], x["bytes"]) for x in ov["loop_exposed"]] == [("all-reduce", layer_bytes)], ov
+    assert e.compile_stats()["fused_step"]["dispatches"] == 1
 
     eraw = _engine({"overlap_comm": False})
     assert eraw._overlap_plan is None
     _train(eraw, _batches(1, 1))
-    traw = eraw.analysis_report(passes=["overlap"])["totals"]
-    assert traw["overlap_verified"] is False, traw
+    traw = eraw.analysis_report(passes=["overlap"])
+    raw = traw["programs"]["fused_step"]["passes"]["overlap"]["summary"]
+    assert traw["totals"]["overlap_verified"] is False, traw["totals"]
+    assert [x for x in raw["loop_exposed"] if x["op"] == "all-gather"], raw  # the use-point gathers the pipeline takes out of the way
 
 
 def test_overlap_pass_red_serialized_schedule(eight_devices):

@@ -53,14 +53,6 @@
 # transfer pass clean, <2% overhead bound), engine.observability() merged
 # reports + Perfetto export, monitor block + JSONL backend + hub feed,
 # DS-R009 lint.
-# +multi-step windows 2026-08-04 (test_multistep_serving.py + extended
-# test_journal_recovery.py + analysis window gate): N-decode-rounds-per-
-# dispatch fused windows — window vs single-step vs dense
-# byte-identical across EOS-in-window/window-edge/admission-break/
-# preemption/prefix-attach/spec-handoff, steady-state dispatches/token
-# ≤ 1/horizon via telemetry, ≤4-compiled-programs + retrace guards,
-# mid-window crash recovery + one-journal-sync-per-window, window-program
-# green sweep (donation through the lax.scan carry, 0 host transfers).
 # +serving fleet 2026-08-04 (test_fleet.py + fleet green gate + DS-R010
 # lint): replicated engines behind the FleetRouter — byte-identical
 # streams under replica kills at every fleet chaos point, live migration
@@ -142,7 +134,6 @@ exec python -m pytest -q \
   tests/unit/inference/test_kv_pool.py \
   tests/unit/inference/test_serving.py \
   tests/unit/inference/test_ragged_serving.py \
-  tests/unit/inference/test_multistep_serving.py \
   tests/unit/inference/test_spec_decode.py \
   tests/unit/inference/test_tp_serving.py \
   tests/unit/inference/test_traffic.py \
