@@ -343,14 +343,15 @@ class HloInstruction:
 
 
 _COMP_HEADER_RE = re.compile(r"^(ENTRY\s+)?%([\w.$-]+)\s*\(.*\)\s*->.*\{\s*$")
-# the shape group must swallow tuple shapes nested two levels deep:
-# variadic async combiner starts (TPU AllGatherCombiner et al.) have
-# ``((operands...), (results...))`` bundle shapes — a flat ``\([^)]*\)``
-# stops at the first inner ')' and silently drops the instruction, which
-# would let an exposed loop collective go unseen by the overlap pass
+# the shape is matched lazily, up to the first `` opcode(``: no shape holds a
+# space followed by a word and ``(``. A pattern of balanced parentheses
+# stops short twice over and silently drops the instruction, which would let
+# an exposed loop collective go unseen by the overlap pass: variadic async
+# combiner starts (TPU AllGatherCombiner et al.) have ``((operands...),
+# (results...))`` bundle shapes, and a TPU module writes a tiled layout into
+# every shape (``bf16[8,128]{1,0:T(8,128)(2,1)S(1)}``)
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([\w.$-]+)\s*=\s*"
-    r"((?:\((?:[^()]|\([^()]*\))*\))|[\w\[\]{},]+)\s+([\w-]+)\("
+    r"^\s*(?:ROOT\s+)?%([\w.$-]+)\s*=\s*(.+?)\s+([a-z][\w-]*)\("
 )
 _REF_RE = re.compile(r"%([\w.$-]+)")
 _ASYNC_SUFFIX_RE = re.compile(r"^(.*?)(-start|-done)$")
@@ -432,3 +433,237 @@ def find_host_ops(hlo_text: str) -> List[Dict[str, str]]:
         meta = _METADATA_OP_RE.search(line)
         found.append({"op": kind, "jax_op": meta.group(1) if meta else ""})
     return found
+
+
+# ---------------------------------------------------------------------------
+# the computation graph: who calls whom, where the matmuls are
+# ---------------------------------------------------------------------------
+REAL_COMPUTE_OPS = {"dot", "convolution"}
+
+_CALLEE_REF_RE = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation|"
+    r"branch_computations)=\{?%([\w.$-]+)"
+)
+_CALLEE_REF_LIST_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def callee_refs(attrs: str) -> Set[str]:
+    refs = set(_CALLEE_REF_RE.findall(attrs))
+    for m in _CALLEE_REF_LIST_RE.finditer(attrs):
+        refs.update(re.findall(r"%([\w.$-]+)", m.group(1)))
+    return refs
+
+
+def computation_callees(comps) -> Dict[str, Set[str]]:
+    """{computation: called-computation names} (fusion ``calls=``, while
+    bodies/conditions, conditional branches, ``to_apply=``) — the one
+    regex walk over every instruction's attrs, shared by transitive loop
+    membership and compute reachability so the two always agree."""
+    return {
+        cname: set().union(*[callee_refs(i.attrs) for i in instrs]) if instrs else set()
+        for cname, instrs in comps.items()
+    }
+
+
+def loop_computations(hlo_text: str, callees: Dict[str, Set[str]]) -> Set[str]:
+    """While bodies and everything they call. Loop membership is
+    TRANSITIVE: a computation called from a while body (a cond branch, a
+    to_apply/call target, a nested loop) executes once per iteration too —
+    a collective there is just as serialized as one directly in the body."""
+    loop_comps = set(while_body_computations(hlo_text))
+    frontier = list(loop_comps)
+    while frontier:
+        for ref in callees.get(frontier.pop(), ()):
+            if ref not in loop_comps:
+                loop_comps.add(ref)
+                frontier.append(ref)
+    return loop_comps
+
+
+def computations_with_compute(comps, callees: Dict[str, Set[str]]) -> Set[str]:
+    """Computation names that (transitively, through ``callees``) contain a
+    dot/convolution — the "real compute" a collective can hide behind.
+    Elementwise fusions don't count: a schedule is only overlapped if there
+    is MXU-shaped work to run during the DMA."""
+    has = {
+        cname
+        for cname, instrs in comps.items()
+        if any(i.op in REAL_COMPUTE_OPS for i in instrs)
+    }
+    changed = True
+    while changed:  # fixpoint: a computation calling a compute-bearing one counts too
+        changed = False
+        for cname, refs in callees.items():
+            if cname not in has and refs & has:
+                has.add(cname)
+                changed = True
+    return has
+
+
+def is_real_compute(instr: "HloInstruction", compute_comps: Set[str]) -> bool:
+    """dot/conv, or a fusion/conditional/while/call whose (transitive)
+    callee computations contain one — a cond-wrapped attention block or a
+    nested scan is schedulable work a collective can hide behind."""
+    if instr.op in REAL_COMPUTE_OPS:
+        return True
+    if instr.op in ("fusion", "conditional", "while", "call"):
+        return bool(callee_refs(instr.attrs) & compute_comps)
+    return False
+
+
+# ---------------------------------------------------------------------------
+# the forms the TPU compiler writes a collective in
+# ---------------------------------------------------------------------------
+_TILED_LAYOUT_RE = re.compile(r"\{[\d,]*:T\(")
+_CHAIN_ID_RE = re.compile(r'chain_id="(\d+)"')
+_FUSION_CALLS_RE = re.compile(r"calls=%([\w.$-]+)")
+
+
+def is_tpu_module(hlo_text: str) -> bool:
+    """A tiled layout (``{1,0:T(8,128)(2,1)}``) is written by the TPU
+    compiler alone: its modules are scheduled for one serial operations
+    line, where a synchronous collective runs with nothing beside it."""
+    return _TILED_LAYOUT_RE.search(hlo_text) is not None
+
+
+def collective_schedule(hlo_text: str) -> List[Dict[str, Any]]:
+    """Every collective the module EXECUTES (the entry's, the loop bodies',
+    a called computation's; what stands inside a fusion is read through the
+    fusion), one record each, in the form the compiler scheduled it:
+
+    * ``sync`` — ``all-gather(...)`` and its kin as plain instructions (one
+      with ``async_collective_name`` in its attributes was made asynchronous,
+      found nothing between its halves and was folded back);
+    * ``fused_sync`` — a ``fusion`` whose computation holds the collective
+      and no chain: the TPU's fused reduce-scatter, ``calls=%all-reduce-
+      scatter*`` (``op`` says ``all-reduce-scatter``), runs on the core like
+      any other fusion;
+    * ``start_done`` — a ``-start`` / ``-done`` pair;
+    * ``fusion_chain`` — the TPU's ``async_collective_fusion``: a fusion that
+      starts the collective (``AsyncCollectiveStart``), fusions that carry it
+      on beside a matmul of their own, one that ends it
+      (``AsyncCollectiveDone``), tied by the ``chain_id`` of the collective
+      each holds. A chain belongs to the computation that STARTS it: one
+      started before a loop may ride the loop's matmuls and end behind it.
+
+    ``compute_between`` (the asynchronous forms): a dot/convolution, alone or
+    in a fusion, is scheduled between the two halves and does not read the
+    start — for a chain, one of its fusions holds a matmul of its own.
+    ``independent_compute`` (the synchronous forms): the computation holds a
+    dot/convolution with no dependency path to or from the collective — work
+    a scheduler would be free to overlap, where there is one. ``bytes`` is the
+    collective's result, per device; ``quantized`` says a sub-byte / int8 /
+    f8 payload."""
+    comps, _entry = parse_computations(hlo_text)
+    callees = computation_callees(comps)
+    loops = loop_computations(hlo_text, callees)
+    compute_comps = computations_with_compute(comps, callees)
+    fused = {
+        callee
+        for instrs in comps.values()
+        for i in instrs
+        if i.op == "fusion"
+        for callee in _FUSION_CALLS_RE.findall(i.attrs)
+    }
+
+    def held_by(instr):
+        """(collective inside a fusion's computation, that computation's name)"""
+        for callee in _FUSION_CALLS_RE.findall(instr.attrs):
+            for inner in comps.get(callee, ()):
+                if inner.op in COLLECTIVE_OPS:
+                    return inner, callee
+        return None, None
+
+    out: List[Dict[str, Any]] = []
+    chains: Dict[str, Dict[str, Any]] = {}
+    for cname, instrs in comps.items():
+        if cname in fused:
+            continue
+        succ: Dict[str, List[str]] = {i.name: [] for i in instrs}
+        pred: Dict[str, List[str]] = {i.name: [] for i in instrs}
+        for i in instrs:
+            for o in i.operands:
+                if o in succ:
+                    succ[o].append(i.name)
+                    pred[i.name].append(o)
+        compute = [i for i in instrs if is_real_compute(i, compute_comps)]
+
+        def reach(name, edges):
+            seen, frontier = {name}, [name]
+            while frontier:
+                for nxt in edges.get(frontier.pop(), ()):
+                    if nxt not in seen:
+                        seen.add(nxt)
+                        frontier.append(nxt)
+            return seen
+
+        def between(start, done):
+            after = reach(start.name, succ)
+            return any(start.index < x.index < done.index and x.name not in after for x in compute)
+
+        def record(instr, held, op, form, **more):
+            start = held.suffix == "-start"
+            out.append(
+                {
+                    "computation": cname,
+                    "name": instr.name,
+                    "op": op,
+                    "form": form,
+                    "bytes": instruction_bytes(held),
+                    "in_loop": cname in loops,
+                    "quantized": any(_QUANT_DTYPE_RE.match(d) for d, _ in _payload_shapes(held.shape_str, start)),
+                    "folded_back": "async_collective_name" in instr.attrs,
+                    "compute_between": None,
+                    "independent_compute": None,
+                    **more,
+                }
+            )
+
+        def record_sync(instr, held, op, form):
+            related = reach(instr.name, succ) | reach(instr.name, pred)
+            record(instr, held, op, form, independent_compute=any(x.name not in related for x in compute))
+
+        for i in instrs:
+            if i.op in COLLECTIVE_OPS and i.suffix != "-done":
+                done = None
+                if i.suffix == "-start":
+                    done = next(
+                        (j for j in instrs if j.op == i.op and j.suffix == "-done" and i.name in j.operands), None
+                    )
+                if done is not None:
+                    record(i, i, i.op, "start_done", compute_between=between(i, done))
+                else:
+                    record_sync(i, i, i.op, "sync")
+            elif i.op == "fusion":
+                inner, callee = held_by(i)
+                if inner is None:
+                    continue
+                chain = _CHAIN_ID_RE.search(inner.attrs)
+                if chain is None:
+                    op = "all-reduce-scatter" if callee.startswith("all-reduce-scatter") else inner.op
+                    record_sync(i, inner, op, "fused_sync")
+                else:
+                    # a chain belongs to the computation that starts it; its other pieces may lie in a loop it rides through
+                    seen = chains.setdefault(chain.group(1), {"rides": False})
+                    seen["rides"] |= callee in compute_comps
+                    if any('"AsyncCollectiveStart"' in x.attrs for x in comps[callee]):
+                        record(i, inner, inner.op, "fusion_chain")
+                        seen["record"] = out[-1]
+    for chain in chains.values():
+        if "record" in chain:
+            chain["record"]["compute_between"] = chain["rides"]
+    return out
+
+
+def loop_schedule_summary(records: List[Dict[str, Any]]) -> Dict[str, int]:
+    """The loop bodies' collectives of :func:`collective_schedule`, counted:
+    how many there are, how many are asynchronous with a matmul between
+    their halves, and how many (with how many bytes) are left on the core."""
+    loop = [r for r in records if r["in_loop"]]
+    hidden = [r for r in loop if r["compute_between"]]
+    return {
+        "loop_collectives": len(loop),
+        "async_with_compute_between": len(hidden),
+        "sync_on_core": len(loop) - len(hidden),
+        "sync_bytes": sum(r["bytes"] for r in loop if not r["compute_between"]),
+    }

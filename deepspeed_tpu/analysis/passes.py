@@ -29,7 +29,6 @@ selection against one artifact.
 
 from __future__ import annotations
 
-import re
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -573,122 +572,37 @@ def collectives_pass(
 # ---------------------------------------------------------------------------
 # comm/compute overlap verifier
 # ---------------------------------------------------------------------------
-_REAL_COMPUTE_OPS = {"dot", "convolution"}
-
-
-_CALLEE_REF_RE = re.compile(
-    r"(?:calls|to_apply|body|condition|true_computation|false_computation|"
-    r"branch_computations)=\{?%([\w.$-]+)"
-)
-_CALLEE_REF_LIST_RE = re.compile(
-    r"branch_computations=\{([^}]*)\}"
-)
-
-
-def _callee_refs(attrs: str) -> set:
-    refs = set(_CALLEE_REF_RE.findall(attrs))
-    for m in _CALLEE_REF_LIST_RE.finditer(attrs):
-        refs.update(re.findall(r"%([\w.$-]+)", m.group(1)))
-    return refs
-
-
-def _computation_callees(comps) -> Dict[str, set]:
-    """{computation: called-computation names} (fusion ``calls=``, while
-    bodies/conditions, conditional branches, ``to_apply=``) — the one
-    regex walk over every instruction's attrs, shared by transitive loop
-    membership and compute reachability so the two always agree."""
-    return {
-        cname: set().union(*[_callee_refs(i.attrs) for i in instrs])
-        if instrs
-        else set()
-        for cname, instrs in comps.items()
-    }
-
-
-def _computations_with_compute(comps, callees: Dict[str, set]) -> set:
-    """Computation names that (transitively, through ``callees``) contain a
-    dot/convolution — the "real compute" a collective can hide behind.
-    Elementwise fusions don't count: a schedule is only overlapped if there
-    is MXU-shaped work to run during the DMA."""
-    direct = {
-        cname
-        for cname, instrs in comps.items()
-        if any(i.op in _REAL_COMPUTE_OPS for i in instrs)
-    }
-    # fixpoint: a computation calling a compute-bearing one counts too
-    changed = True
-    has = set(direct)
-    while changed:
-        changed = False
-        for cname, refs in callees.items():
-            if cname not in has and refs & has:
-                has.add(cname)
-                changed = True
-    return has
-
-
-def _is_real_compute(instr, compute_comps: set) -> bool:
-    """dot/conv, or a fusion/conditional/while/call whose (transitive)
-    callee computations contain one — a cond-wrapped attention block or a
-    nested scan is schedulable work a collective can hide behind."""
-    if instr.op in _REAL_COMPUTE_OPS:
-        return True
-    if instr.op in ("fusion", "conditional", "while", "call"):
-        return bool(_callee_refs(instr.attrs) & compute_comps)
-    return False
-
-
-def _reach(start_names, succ) -> set:
-    seen = set(start_names)
-    frontier = list(start_names)
-    while frontier:
-        n = frontier.pop()
-        for nxt in succ.get(n, ()):
-            if nxt not in seen:
-                seen.add(nxt)
-                frontier.append(nxt)
-    return seen
-
-
 def overlap_pass(art: ProgramArtifact, config: Optional[Dict[str, Any]] = None) -> PassResult:
     """Static comm/compute-overlap verifier over the compiled schedule.
 
-    For every collective in the optimized module (the order is the schedule:
-    post-optimization HLO is ``is_scheduled=true``):
+    For every collective the optimized module executes, in the form the
+    compiler scheduled it (:func:`analysis.hlo.collective_schedule`; the
+    order is the schedule: post-optimization HLO is ``is_scheduled=true``):
 
-    * async ``-start``/``-done`` pairs are **hidden** when real compute
-      (dot/conv, incl. inside fusions) sits between start and done in
-      schedule order without depending on the start — the latency-hiding
-      scheduler actually separated them;
-    * sync collectives (the CPU mesh, unscheduled backends) are **hidden**
-      when the computation contains real compute with no dependency path to
-      or from the collective — independent work the scheduler is free to
-      overlap (the feasibility the pipelined gather/in-loop reduction create).
+    * the asynchronous forms (a ``-start``/``-done`` pair; on the TPU an
+      ``async_collective_fusion`` chain) are **hidden** when real compute
+      (dot/conv, incl. inside fusions) sits between the two halves in
+      schedule order without depending on the start, or the chain's own
+      fusions hold a matmul — the latency-hiding scheduler actually
+      separated them;
+    * the synchronous forms (a plain collective; on the TPU also the fused
+      ``all-reduce-scatter``, a ``fusion`` by opcode) depend on who compiled
+      the module. The TPU's modules are scheduled for one serial operations
+      line, so a synchronous collective there runs with nothing beside it:
+      **exposed**, whatever else the computation holds. On the CPU mesh
+      (no schedule exists) they are **hidden** when the computation
+      contains real compute with no dependency path to or from the
+      collective — independent work a scheduler would be free to overlap
+      (the feasibility the pipelined gather/in-loop reduction create).
 
     ``overlap_verified`` means no collective inside a while-loop body (the
-    scanned layer stack / microbatch loop — the hot path the pipeline owns)
-    is exposed; entry-level tail collectives only count toward
-    ``exposed_bytes``. Exposed loop collectives are warn-severity findings
-    (error with ``require_overlap``)."""
+    scanned layer stack / microbatch loop — the hot path the pipeline owns;
+    membership is transitive) is exposed; entry-level tail collectives only
+    count toward ``exposed_bytes``. Exposed loop collectives are
+    warn-severity findings (error with ``require_overlap``)."""
     cfg = config or {}
     res = PassResult()
-    comps, _entry = hlo_parse.parse_computations(art.hlo_text)
-    bodies = hlo_parse.while_body_computations(art.hlo_text)
-    # loop membership is TRANSITIVE: a computation called from a while body
-    # (a cond branch, a to_apply/call target, a nested loop) executes once
-    # per iteration too — a collective there is just as serialized as one
-    # directly in the body, and missing it would false-green the verifier
-    callees = _computation_callees(comps)
-    loop_comps = set(bodies)
-    frontier = list(bodies)
-    while frontier:
-        c = frontier.pop()
-        for ref in callees.get(c, ()):
-            if ref not in loop_comps:
-                loop_comps.add(ref)
-                frontier.append(ref)
-    compute_comps = _computations_with_compute(comps, callees)
-
+    tpu = hlo_parse.is_tpu_module(art.hlo_text)
     n_hidden = n_exposed = hidden_bytes = exposed_bytes = async_pairs = 0
     loop_total = 0
     # quantized loop collectives (the EQuARX exchanges of a quantized TP
@@ -696,65 +610,28 @@ def overlap_pass(art: ProgramArtifact, config: Optional[Dict[str, Any]] = None) 
     # comm schedule was actually SEEN on the hot path, not just absent
     loop_quantized = loop_quantized_hidden = 0
     loop_exposed: List[Dict[str, Any]] = []
-    for cname, instrs in comps.items():
-        colls = [
-            i for i in instrs if i.op in hlo_parse.COLLECTIVE_OPS and i.suffix != "-done"
-        ]
-        if not colls:
-            continue
-        defmap = {i.name: i for i in instrs}
-        succ: Dict[str, List[str]] = {i.name: [] for i in instrs}
-        pred: Dict[str, List[str]] = {i.name: [] for i in instrs}
-        for i in instrs:
-            for o in i.operands:
-                if o in defmap:
-                    succ[o].append(i.name)
-                    pred[i.name].append(o)
-        compute = [i for i in instrs if _is_real_compute(i, compute_comps)]
-        in_loop = cname in loop_comps
-        for c in colls:
-            nbytes = hlo_parse.instruction_bytes(c)
-            done = None
-            if c.suffix == "-start":
-                for j in instrs:
-                    if j.op == c.op and j.suffix == "-done" and c.name in j.operands:
-                        done = j
-                        break
-            if done is not None:
-                async_pairs += 1
-                desc = _reach([c.name], succ)
-                hidden = any(
-                    c.index < x.index < done.index and x.name not in desc
-                    for x in compute
+    for c in hlo_parse.collective_schedule(art.hlo_text):
+        if c["compute_between"] is None:
+            hidden = c["independent_compute"] and not tpu
+        else:
+            async_pairs += 1
+            hidden = c["compute_between"]
+        if c["in_loop"]:
+            loop_total += 1
+            if c["quantized"]:
+                loop_quantized += 1
+                if hidden:
+                    loop_quantized_hidden += 1
+        if hidden:
+            n_hidden += 1
+            hidden_bytes += c["bytes"]
+        else:
+            n_exposed += 1
+            exposed_bytes += c["bytes"]
+            if c["in_loop"]:
+                loop_exposed.append(
+                    {"computation": c["computation"], "op": c["op"], "name": c["name"], "bytes": c["bytes"]}
                 )
-            else:
-                desc = _reach([c.name], succ)
-                anc = _reach([c.name], pred)
-                hidden = any(
-                    x.name not in desc and x.name not in anc for x in compute
-                )
-            quantized = any(
-                hlo_parse._QUANT_DTYPE_RE.match(dtype)
-                for dtype, _ in hlo_parse._payload_shapes(
-                    c.shape_str, c.suffix == "-start"
-                )
-            )
-            if in_loop:
-                loop_total += 1
-                if quantized:
-                    loop_quantized += 1
-                    if hidden:
-                        loop_quantized_hidden += 1
-            if hidden:
-                n_hidden += 1
-                hidden_bytes += nbytes
-            else:
-                n_exposed += 1
-                exposed_bytes += nbytes
-                if in_loop:
-                    loop_exposed.append(
-                        {"computation": cname, "op": c.op, "name": c.name, "bytes": nbytes}
-                    )
 
     verified = not loop_exposed
     res.summary = {

@@ -48,6 +48,21 @@ def _maybe_quantize_activation(x, site: str):
     return maybe_quantize(x, site)
 
 
+def _dense(x, p, key: str):
+    """``x @ p[key]``, one of a layer's matrices. Under an active comm-overlap
+    plan (``runtime/zero/overlap.py``, training's traces) the matmul goes
+    through the plan, which may own the weight gradient's cross-batch sum
+    (``OverlapPlan.matmul``); the value is the same. A quantized leaf
+    (``compression/int8.py``) keeps its fused dequantization."""
+    from deepspeed_tpu.compression.int8 import qmatmul
+    from deepspeed_tpu.runtime.zero.overlap import active_plan
+
+    plan = active_plan()
+    if plan is None or not hasattr(p[key], "astype"):
+        return qmatmul(x, p[key])
+    return plan.matmul(x, p[key].astype(x.dtype), key)
+
+
 def _norm(x, scale, bias, kind: str, eps: float):
     x32 = x.astype(jnp.float32)
     if kind == "rmsnorm":
@@ -401,7 +416,7 @@ class TransformerLM(DSModule):
         from deepspeed_tpu.moe.experts import apply_dense_ffn
 
         h = _maybe_quantize_activation(h, "layers/mlp_input")
-        return apply_dense_ffn(p, h, self.config.activation), jnp.zeros((), jnp.float32)
+        return apply_dense_ffn(p, h, self.config.activation, matmul=_dense), jnp.zeros((), jnp.float32)
 
     def _layer_params(self, params, i: int):
         """Per-layer param tree for the unrolled (non-scan) path; model
@@ -427,9 +442,7 @@ class TransformerLM(DSModule):
             else:
                 h = x
             h = _maybe_quantize_activation(h, "layers/attn_input")
-            q = h @ p["wq"].astype(h.dtype)
-            k = h @ p["wk"].astype(h.dtype)
-            v = h @ p["wv"].astype(h.dtype)
+            q, k, v = (_dense(h, p, key) for key in ("wq", "wk", "wv"))
             if cfg.qkv_bias:
                 q, k, v = q + p["bq"].astype(h.dtype), k + p["bk"].astype(h.dtype), v + p["bv"].astype(h.dtype)
             if cfg.qk_norm == "projection":
@@ -443,7 +456,7 @@ class TransformerLM(DSModule):
                 k = _rope(k, positions, cfg.rope_theta, cfg.rope_dim)
             rng, r_attn, r_hid, r_mlp = jax.random.split(rng, 4) if rng is not None else (None, None, None, None)
             attn = self._attention(q, k, v, positions, r_attn, train)
-            attn = attn.reshape(B, T, NH * D) @ p["wo"].astype(h.dtype)
+            attn = _dense(attn.reshape(B, T, NH * D), p, "wo")
             if cfg.use_bias:
                 attn = attn + p["bo"].astype(h.dtype)
             if train and cfg.hidden_dropout > 0 and r_hid is not None:
@@ -823,7 +836,7 @@ class TransformerLM(DSModule):
                 )
             else:
                 cur = mine  # the pin below IS the use-point gather
-            cur = plan.reduce_grads(plan.pin_gathered(cur))
+            cur = plan.at_use(cur)
             y, rng, aux = self._scan_layer_step(x, cur, positions, rng, train)
             return (y, rng, bufs), aux
 

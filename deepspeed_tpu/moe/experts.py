@@ -62,7 +62,7 @@ def _pointwise_activation(x: jnp.ndarray, activation: str) -> jnp.ndarray:
 
 
 def apply_dense_ffn(params: Dict[str, Any], x: jnp.ndarray, activation: str = "gelu",
-                    tp=None) -> jnp.ndarray:
+                    tp=None, matmul=None) -> jnp.ndarray:
     """[..., H] → [..., H] dense FFN; single source of activation semantics
     (shared by TransformerLM layers and the PR-MoE residual branch).
     ``qmatmul`` fuses int8-weight dequantization when the leaves are
@@ -70,23 +70,29 @@ def apply_dense_ffn(params: Dict[str, Any], x: jnp.ndarray, activation: str = "g
     (``tp``, a ``inference/tp.py:TPServing`` inside shard_map) the up/gate
     projections are column-parallel (weights arrive pre-sliced), the down
     projection is row-parallel through ``tp.row_matmul``'s all-reduce, and
-    the replicated output bias is added once, after the reduce."""
+    the replicated output bias is added once, after the reduce.
+    ``matmul(x, params, key)`` takes the place of ``qmatmul(x, params[key])``
+    where the caller has its own (training's layers: ``models/transformer.py::_dense``)."""
     from deepspeed_tpu.compression.int8 import qmatmul
+
+    if matmul is None:
+        def matmul(x, params, key):
+            return qmatmul(x, params[key])
 
     dt = x.dtype
     if activation in ("swiglu", "geglu"):
-        gate = qmatmul(x, params["w_gate"])
-        up = qmatmul(x, params["w_up"])
+        gate = matmul(x, params, "w_gate")
+        up = matmul(x, params, "w_up")
         act = jax.nn.silu(gate) if activation == "swiglu" else jax.nn.gelu(gate)
         inner = act * up
     else:
-        inner = qmatmul(x, params["w_in"])
+        inner = matmul(x, params, "w_in")
         if "b_in" in params:
             inner = inner + params["b_in"].astype(dt)
         inner = _pointwise_activation(inner, activation)
     out = (
         tp.row_matmul(inner, params["w_out"]) if tp is not None
-        else qmatmul(inner, params["w_out"])
+        else matmul(inner, params, "w_out")
     ).astype(dt)
     if "b_out" in params:
         out = out + params["b_out"].astype(dt)

@@ -264,6 +264,13 @@ class CompileTelemetry:
         interpreted one does not."""
         return self._fns[name].trace_abstract().lower().as_text()
 
+    def compiled_text(self, name: str) -> str:
+        """Optimized HLO text of a dispatched program as the backend
+        scheduled it, from the signature its latest cold dispatch recorded.
+        After that dispatch this is no second compile: the trace, the
+        lowering and the executable are the ones jit keeps for the call."""
+        return self._fns[name].trace_abstract().lower().compile().as_text()
+
     def program_stats(self, name: str) -> Optional[ProgramStats]:
         return self._programs.get(name)
 

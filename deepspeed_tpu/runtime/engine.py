@@ -431,9 +431,8 @@ class DeepSpeedEngine:
         # program right after its first compile (warn or raise) — the
         # donation/dtype/host-transfer/comms guarantees are checked where
         # they are created, not rediscovered in a bench regression
-        acfg = self._config.analysis_config
-        if acfg.verify != "off":
-            self._telemetry.on_compile = self._verify_program_static
+        self._telemetry.on_compile = self._on_program_compiled
+        self._collective_schedule_emitted = False
 
         self.training_dataloader = self.deepspeed_io(training_data) if training_data is not None else None
 
@@ -1656,17 +1655,16 @@ class DeepSpeedEngine:
         return plan
 
     def _overlap_compiler_options(self) -> Optional[Dict[str, Any]]:
-        """XLA latency-hiding-scheduler options for the step-flavor programs.
-
-        The pipeline/bucketing create the independent work; this scheduler
-        makes XLA interleave it with the collective DMAs. TPU-only: the CPU
-        mesh has no async collectives to schedule, and its compiler rejects
-        the ``xla_tpu_*`` option."""
+        """XLA options for the step-flavor programs: the latency-hiding
+        scheduler and what the overlap plan's collectives need from it
+        (``runtime/zero/overlap.py::step_compiler_options``). TPU-only: the
+        CPU mesh has no async collectives to schedule, and its compiler
+        rejects the ``xla_tpu_*`` options."""
         if not on_tpu():
             return None
-        if self._overlap_plan is None and not self._config.zero_config.overlap_comm:
-            return None
-        return {"xla_tpu_enable_latency_hiding_scheduler": "true"}
+        from deepspeed_tpu.runtime.zero.overlap import step_compiler_options
+
+        return step_compiler_options(self._overlap_plan, bool(self._config.zero_config.overlap_comm))
 
     # ------------------------------------------------------------------
     # train loop API (reference parity)
@@ -2287,6 +2285,39 @@ class DeepSpeedEngine:
         if self._streamed_offload and self._host_offload is not None:
             return {"offload_stream": self._host_offload.stream_schedule()}
         return None
+
+    def _on_program_compiled(self, name: str) -> None:
+        """After a program's first compile: the loops' collective schedule,
+        said once (``zero.collective_schedule``), and analysis.verify's
+        static passes where the config asks for them."""
+        if (
+            self.tracer.enabled
+            and self._overlap_plan is not None
+            and (self._overlap_plan.prefetch_enabled or self._overlap_plan.reduce_enabled)
+            and not self._collective_schedule_emitted
+            and name in ("fused_step", "fused_accum_step", "fwd_bwd")
+        ):
+            self._collective_schedule_emitted = True
+            self._emit_collective_schedule(name)
+        if self._config.analysis_config.verify != "off":
+            self._verify_program_static(name)
+
+    def _emit_collective_schedule(self, name: str) -> None:
+        """How the compiler scheduled the collectives of the step's loops
+        (the layer loops the overlap plan owns): the tracer's instant event
+        ``zero.collective_schedule`` {``loop_collectives``,
+        ``async_with_compute_between``, ``sync_on_core``, ``sync_bytes``},
+        read off the compiled text the dispatch just made (no second
+        compile). ``sync_on_core`` 0 is the plan's goal on the TPU; the CPU
+        mesh has no schedule and reads every collective synchronous."""
+        from deepspeed_tpu.analysis.hlo import collective_schedule, loop_schedule_summary
+
+        try:
+            summary = loop_schedule_summary(collective_schedule(self._telemetry.compiled_text(name)))
+        except Exception as e:  # telemetry must never fail a step
+            logger.warning(f"zero.collective_schedule: could not read {name}'s compiled text: {e}")
+            return
+        self.tracer.event("zero.collective_schedule", program=name, **summary)
 
     def _verify_program_static(self, name: str) -> None:
         """analysis.verify hook: passes over one freshly compiled program."""

@@ -18,6 +18,7 @@ at GPT-2 XL's widths for the four described chips and reads where a layer's
 gradient is written.
 """
 
+import collections
 import functools
 import json
 import os
@@ -1239,27 +1240,24 @@ def test_zero3_layer_pipeline_writes_a_layers_gradient_where_it_lies(v5e_2x2, mo
     16 of them, 2 x 53.8 ms of a 1,275 ms step for ``w_in`` and ``w_out``
     alone).
 
-    The in-loop reduction (PR 58) takes each of the sixteen leaves ALONE, in
-    its own shape: five matrices (``w_out``, ``wq``, ``wk``, ``wv``, ``wo``)
-    are this backend's fused reduce-scatter (a ``kCustom`` fusion calling
-    ``%all-reduce-scatter.*`` on the whole matrix, padded by less than a tile
-    row of 128: ``[6400,1600]`` -> ``[1632,1600]`` a chip, ``[1600,1600]`` ->
-    ``[416,1600]``), each followed by a halo exchange that turns 1,632 / 416
-    rows a chip into the engine's 1,600 / 400 (a ``slice`` of the last 96 / 48
-    rows, a ``collective-permute``, a ``concatenate``; ``[48,1600]`` is that
-    halo's shape, three times 16 rows, and by coincidence the vectors'
-    stack's), and ``w_in`` ``[1600,6400]`` rides the all-reduce the compiler
-    combines the vectors' into (eight of the ten; two biases share another).
-    Until PR 58 all sixteen leaves went through one ``[4, chunk]`` bucket,
-    which the compiler built in loops of its own inside the backward's
-    (``%wide.body*``: ``bf16[10240000]`` <-> ``bf16[1,4,2560000]`` a row a
-    trip, 173 ms of a 1,078 ms step) and then reduced piece by piece all the
-    same."""
+    Since PR 62 neither loop holds a collective of a matrix on the core.
+    The lookahead gathers each of the six matrices by direct sends, a chip's
+    shard to every other chip (``chips - 1`` ``collective-permute``s a
+    matrix, assembled by one ``concatenate`` in a branch a chip), and the
+    backward sums each matrix's gradient the same way, a chip's blocks of
+    its partial sum to the chips that keep them (``OverlapPlan.matmul``):
+    no ``all-gather`` in a loop, no fused ``all-reduce-scatter`` anywhere in
+    the loops, and the one ``all-reduce`` the compiler combines the ten
+    vectors' into carries no matrix. (Until PR 62: five matrices as fused
+    reduce-scatters with a halo exchange behind each, ``w_in`` in the
+    vectors' all-reduce, three of the six gathers synchronous; until PR 58
+    all sixteen leaves through one ``[4, chunk]`` bucket, which the compiler
+    built in loops of its own inside the backward's.)"""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from deepspeed_tpu.parallel.mesh import MeshConfig, initialize_topology
     from deepspeed_tpu.runtime.zero.config import DeepSpeedZeroConfig
-    from deepspeed_tpu.runtime.zero.overlap import build_overlap_plan, overlap_scope
+    from deepspeed_tpu.runtime.zero.overlap import build_overlap_plan, overlap_scope, step_compiler_options
     from deepspeed_tpu.runtime.zero.partition import ZeroPartitioner
 
     module = "deepspeed_tpu.ops.transformer.flash_attention"
@@ -1295,7 +1293,7 @@ def test_zero3_layer_pipeline_writes_a_layers_gradient_where_it_lies(v5e_2x2, mo
 
     compiled = jax.jit(
         jax.grad(loss), out_shardings=jax.tree_util.tree_map(on_mesh, grad_specs, is_leaf=is_spec)
-    ).lower(params, {"input_ids": batch, "labels": batch}).compile({"xla_tpu_enable_latency_hiding_scheduler": "true"})
+    ).lower(params, {"input_ids": batch, "labels": batch}).compile(step_compiler_options(plan, True))
     text = compiled.as_text()
 
     stacks = {a.shape for a in jax.tree_util.tree_leaves(shapes["layers"])}
@@ -1306,40 +1304,9 @@ def test_zero3_layer_pipeline_writes_a_layers_gradient_where_it_lies(v5e_2x2, mo
     bodies = _loop_bodies(text)
     # the forward's and the backward's layer loop and no other: the compiler builds no bucket in loops of its own
     assert len(bodies) == 2, sorted(bodies)
-    computations = _computations(text)
-    scattered = {  # the fused reduce-scatters, by the computation they call: {fusion: (operand's shape, result's shape)}
-        name: (re.search(r"\w+\[[\d,]+\]", computations[callee][0][2]).group(0), result.split("{")[0])
-        for body in computations.values() for _, name, result, opcode, rest in body if opcode == "fusion"
-        for callee in re.findall(r"calls=%(all-reduce-scatter[\w.$-]*)", rest)
-    }
-    # behind a reduce-scatter, its rows past the engine's cut go to the next chip: a slice of the pad of the chips - 1
-    # before it, a collective-permute of that slice. One such chain a reduce-scatter, of that shape, and no other
-    # op is told apart from the stacks' by what it reads
-    def shape_of(result):
-        dtype, dims = re.match(r"\(?(\w+)\[([\d,]*)\]", result).groups()
-        return dtype, tuple(map(int, dims.split(",")))
-
-    halo_of = {}
-    for name, (operand, result) in scattered.items():
-        (dtype, whole), (_, mine) = shape_of(operand), shape_of(result)
-        padded = [d for d in range(len(whole)) if mine[d] != whole[d]]
-        assert len(padded) == 1 and 0 <= mine[padded[0]] - whole[padded[0]] // chips < 128, (operand, result)
-        d, pad = padded[0], mine[padded[0]] - whole[padded[0]] // chips
-        halo_of[name] = (dtype, mine[:d] + ((chips - 1) * pad,) + mine[d + 1:])
-    halo = {}  # op -> the reduce-scatter it hangs behind
-    steps = {"slice": "fusion", "collective-permute-start": "slice", "collective-permute-done": "collective-permute-start"}
-    by_name = {name: opcode for body in computations.values() for _, name, _, opcode, _ in body}
-    for _ in steps:
-        for body in computations.values():
-            for _, name, result, opcode, rest in body:
-                read = re.match(r"%([\w.$-]+)\)", rest)
-                if opcode in steps and read and by_name.get(read.group(1)) == steps[opcode]:
-                    behind = read.group(1) if opcode == "slice" else halo.get(read.group(1))
-                    if behind in halo_of and shape_of(result) == halo_of[behind]:
-                        halo[name] = behind
     written = {
         loop: [(name, opcode, result.split("{")[0]) for name, opcode, result in body
-               if of_a_stack.match(result) and opcode not in _PLUMBING and name not in halo]
+               if of_a_stack.match(result) and opcode not in _PLUMBING]
         for loop, body in bodies.items()
     }
     # the vectors' stacks (150 KB) may be fetched ahead into fast memory: a copy of no account
@@ -1356,18 +1323,20 @@ def test_zero3_layer_pipeline_writes_a_layers_gradient_where_it_lies(v5e_2x2, mo
     flat = {n for h, w in matrices for n in (h * w, h * w // chips)}
     views = re.compile(r"^\w+\[(?:1,)?(?:%d,)?(?:%s)\]" % (chips, "|".join(map(str, sorted(flat)))))
     assert not [(name, result) for name, _, result in _executed(text) if views.match(result)]
-    backward = next(body for body in bodies.values() if any(name in scattered for name, _, _ in body))
-    in_backward = {name for name, _, _ in backward}
-    # five matrices are reduce-scattered whole, in their own shape (to a chip's quarter and a pad: checked above) ...
-    assert sorted(operand for k, (operand, _) in scattered.items() if k in in_backward) == sorted(
-        [f"bf16[{I},{H}]"] + 4 * [f"bf16[{H},{H}]"]
-    ), scattered
-    # ... each with its halo chain behind it and no other op excused
-    assert sorted(halo.values()) == sorted(3 * [k for k in scattered if k in in_backward]), halo
-    # ... and w_in rides an all-reduce the compiler combines the ten vectors' into, all eleven in their own shapes
-    reduced = [piece for _, opcode, result in backward if opcode == "all-reduce" for piece in re.findall(r"\w+\[[\d,]*\]", result)]
-    assert sorted(reduced) == sorted([f"bf16[{H},{I}]", f"bf16[{I}]"] + 9 * [f"bf16[{H}]"]), reduced
+    # neither loop moves a matrix on the core: no gather, no fused reduce-scatter, no matrix in an all-reduce;
+    # a chip's shard (forward) or block (backward) of every matrix goes to each other chip by a collective-permute
+    shard = {f"bf16[{h // chips},{w}]" for h, w in matrices} | {f"bf16[{h},{w // chips}]" for h, w in matrices}
+    for body in bodies.values():
+        opcodes = [opcode for _, opcode, _ in body]
+        assert "all-gather" not in opcodes and "fusion:all-reduce" not in opcodes and "reduce-scatter" not in opcodes
+        reduced = [piece for _, opcode, result in body if opcode == "all-reduce" for piece in re.findall(r"\w+\[[\d,]*\]", result)]
+        assert all(piece.count(",") == 0 for piece in reduced), reduced  # vectors alone
+        sent = [re.match(r"\((\w+\[[\d,]*\])", result).group(1) for _, opcode, result in body if opcode == "collective-permute-start"]
+        sent = [piece for piece in sent if "," in piece]  # a vector's are the compiler's own
+        assert len(sent) == 6 * (chips - 1) and set(sent) <= shard, sorted(collections.Counter(sent).items())
+    computations = _computations(text)
+    assert not [name for loop in bodies for _, name, _, _, rest in computations[loop] if "calls=%all-reduce-scatter" in rest]
     opcodes = [opcode for _, opcode, _ in _executed(text)]
     collectives = {k: sum(o in (k, k + "-start") for o in opcodes) for k in ("all-gather", "all-reduce", "reduce-scatter", "collective-permute")}
-    collectives["all-reduce-scatter"] = len(scattered)
-    assert collectives == {"all-gather": 13, "all-reduce": 7, "reduce-scatter": 0, "collective-permute": 5, "all-reduce-scatter": 6}, collectives
+    # outside the loops: the prologue's sends (18), the embedding's and the head's own
+    assert collectives["collective-permute"] >= 3 * 6 * (chips - 1) and collectives["reduce-scatter"] == 0, collectives

@@ -126,6 +126,31 @@ def _assert_states_identical(ea, eb):
         np.testing.assert_array_equal(va, vb, err_msg=f"master leaf {ka} diverged")
 
 
+def _schedule(engine, program):
+    from deepspeed_tpu.analysis.hlo import collective_schedule
+
+    return collective_schedule(engine._telemetry.compiled_text(program))
+
+
+def _assert_same_sum_in_another_order(ref, lref, e, l):
+    """Two engines that add the same per-chip partial gradients in a
+    different ORDER (since PR 62 the in-loop reduction adds a matrix's
+    itself, in float32, as its sends arrive: ``OverlapPlan._wgrad_by_sends``;
+    the partitioner's all-reduce adds them in its own). The first step's
+    loss is the same to the bit (no gradient is in it); the later ones agree
+    to float32's last place (4.243444 against 4.243445). Adam divides a
+    gradient by its own size, so where a gradient is all rounding the last
+    bit moves a whole update's worth (lr 1e-2): all but a thousandth of a
+    leaf's elements agree to 5e-6, and none is further off than a hundredth
+    of one update (seen: 10 of 98,304 elements, at most 3.2e-5)."""
+    np.testing.assert_array_equal(lref[0], l[0])
+    np.testing.assert_allclose(lref[1:], l[1:], rtol=1e-6)
+    for (ka, va), (kb, vb) in zip(_masters(ref), _masters(e)):
+        assert ka == kb
+        gap = np.abs(va - vb)
+        assert gap.max() <= 1e-4 and np.mean(gap > 5e-6) <= 1e-3, (ka, gap.max(), np.mean(gap > 5e-6))
+
+
 # ---------------------------------------------------------------------------
 # parity: pipelined vs use-point gather is bit-identical
 # ---------------------------------------------------------------------------
@@ -154,17 +179,20 @@ def test_overlap_parity_bit_identical(stage, gas, precision, eight_devices):
 
 
 def test_depth2_and_reduce_off_still_bit_identical(eight_devices):
-    """Pipeline depth is schedule-only at every depth, and the in-loop
-    reduction is value-preserving on its own."""
+    """Pipeline depth is schedule-only at every depth: bit-identical. The
+    in-loop reduction computes the same sum, in another order
+    (``_assert_same_sum_in_another_order``)."""
     batches = _batches(1, STEPS)
     ref = _engine({"prefetch_layers": 0})
     lref = _train(ref, batches)
-    for zover in ({"prefetch_layers": 2}, {"prefetch_layers": 1, "reduce_scatter": False}):
-        e = _engine(zover)
-        l = _train(e, batches)
-        for a, b in zip(lref, l):
-            np.testing.assert_array_equal(a, b)
-        _assert_states_identical(ref, e)
+    e = _engine({"prefetch_layers": 2})
+    for a, b in zip(lref, _train(e, batches)):
+        np.testing.assert_array_equal(a, b)
+    _assert_states_identical(ref, e)
+
+    e = _engine({"prefetch_layers": 1, "reduce_scatter": False})
+    assert not e._overlap_plan.reduce_enabled if e._overlap_plan is not None else True
+    _assert_same_sum_in_another_order(ref, lref, e, _train(e, batches))
 
 
 def test_remat_parity_bit_identical(eight_devices):
@@ -237,10 +265,11 @@ def test_in_loop_reduction_parity_bit_identical_at_every_depth(precision, remat,
 
 @pytest.mark.parametrize("stage", [2, 3])
 def test_in_loop_reduction_matches_reduce_off(stage, eight_devices):
-    """Where a leaf's sum is forced (alone in the loop, or not at all in
-    the loop: ``reduce_scatter: False``) moves no value: the same partials
-    are summed, and on this mesh in the same order. Stage 2 takes the plain
-    scan body (nothing to gather), stage 3 the pipelined one."""
+    """Where a leaf's sum is made (in the loop, a matrix's by the plan's own
+    sends; or not at all in the loop: ``reduce_scatter: False``) moves no
+    value but by the order of the additions: the same partials are summed.
+    Stage 2 takes the plain scan body (nothing to gather), stage 3 the
+    pipelined one."""
     batches = _batches(1, STEPS)
     on = _engine({"stage": stage, "prefetch_layers": 1})
     lon = _train(on, batches)
@@ -248,9 +277,7 @@ def test_in_loop_reduction_matches_reduce_off(stage, eight_devices):
     off = _engine({"stage": stage, "prefetch_layers": 1, "reduce_scatter": False})
     loff = _train(off, batches)
     assert off._overlap_plan is None or not off._overlap_plan.reduce_enabled
-    for a, b in zip(lon, loff):
-        np.testing.assert_array_equal(a, b)
-    _assert_states_identical(on, off)
+    _assert_same_sum_in_another_order(on, lon, off, loff)
 
 
 _XL_LAYER = {  # GPT-2 XL's sixteen leaves a layer, in tree order: H 1,600, I 6,400
@@ -335,6 +362,27 @@ def test_engine_emits_the_reduction_record_once(eight_devices):
     assert len(events) == 1 and events[0]["ph"] == "i"
     assert events[0]["attrs"] == e._overlap_plan.reduction_record()
     assert events[0]["attrs"]["leaves_alone"] >= 6 and events[0]["attrs"]["leaves_left_to_partitioner"] == 0
+
+
+def test_engine_says_how_the_loops_collectives_were_scheduled_once(eight_devices):
+    """After the step's first compile the engine reads the compiled text
+    (no second compile: the dispatch's own executable) and says, once, how
+    many collectives the loops hold and how many are left on the core. The
+    CPU mesh has no schedule: every one reads synchronous here."""
+    e = _engine({"prefetch_layers": 1})
+    _train(e, _batches(1, STEPS))
+    events = [s for s in e.tracer.spans() if s["name"] == "zero.collective_schedule"]
+    assert len(events) == 1 and events[0]["ph"] == "i"
+    said = events[0]["attrs"]
+    assert set(said) == {"program", "loop_collectives", "async_with_compute_between", "sync_on_core", "sync_bytes"}
+    assert said["program"] == "fused_step" and said["loop_collectives"] == said["sync_on_core"] > 0
+    assert said["async_with_compute_between"] == 0 and said["sync_bytes"] > 0
+    in_loop = [r for r in _schedule(e, "fused_step") if r["in_loop"]]
+    assert said["loop_collectives"] == len(in_loop) and said["sync_bytes"] == sum(r["bytes"] for r in in_loop)
+    assert e.compile_stats()["fused_step"]["compiles"] == 1
+    plain = _engine({"overlap_comm": False})  # no plan, nothing said
+    _train(plain, _batches(1, 1))
+    assert plain._overlap_plan is None and not [s for s in plain.tracer.spans() if s["name"] == "zero.collective_schedule"]
 
 
 # ---------------------------------------------------------------------------
@@ -428,27 +476,36 @@ def test_one_dispatch_and_donation_preserved(eight_devices):
 def test_overlap_pass_green_on_pipelined_zero3_step(eight_devices):
     """What a CPU compile of the ZeRO-3 pipelined step establishes, and what
     it cannot. The passes read the compiled text's collectives (their count
-    and bytes by kind), every parameter all-gather inside a loop body has
+    and bytes by kind), every parameter gather inside a loop body (since PR
+    62 the sends of the lookahead, ``world - 1`` a cut leaf) has
     independent real compute to hide behind (the prefetch: the raw,
     plan-less scan of the same model and mesh exposes three), hidden bytes
     are nonzero, and a step is one dispatch. The in-loop gradient reduction
     IS in the backward loop's body, but XLA's CPU pipeline combines PR 58's
     per-leaf reductions into ONE variadic all-reduce over a layer's nine
-    gradient dots, so no dot of that body is independent of it: the CPU
-    schedule offers nothing to verify there, and ``overlap_verified`` is not
-    evidence on this backend (the ledger has the measured collective share
-    of the chip's schedule)."""
+    gradient dots, so no dot of that body is independent of it (until PR 62
+    took the matrices out of it: their gradients are summed by sends, and
+    what is left is the vectors'): the CPU schedule offers nothing to verify
+    there, and ``overlap_verified`` is not evidence on this backend (the
+    ledger has the measured collective share of the chip's schedule)."""
     e = _engine({"prefetch_layers": 1})
     _train(e, _batches(1, 1))
     rep = e.analysis_report(passes=["overlap", "collectives"])
     t, ov = rep["totals"], rep["programs"]["fused_step"]["passes"]["overlap"]["summary"]
     assert t["analysis_failures"] == 0 and t["collective_count"] == ov["collectives"] >= 20, t
-    assert set(t["collectives"]) == {"all-gather", "all-reduce", "all-to-all"} and t["collective_bytes"] > 0, t
+    # since PR 62 the lookahead gathers a cut leaf by direct sends (collective-permutes): the all-gathers left are the
+    # once-a-step ones of the embedding and the head
+    assert set(t["collectives"]) == {"all-gather", "all-reduce", "all-to-all", "collective-permute"}, t
+    assert t["collective_bytes"] > 0, t
     assert t["hidden_collective_bytes"] == ov["hidden_bytes"] > 0 and ov["loop_collectives"] >= 4, ov
     layers = jax.tree_util.tree_leaves(e.get_master_params()["layers"])
     layer_bytes = sum(leaf.size // leaf.shape[0] for leaf in layers) * 4
-    # one collective of the loops is exposed, and it is no gather: a layer's gradient, reduced where the backward scan makes it
-    assert [(x["op"], x["bytes"]) for x in ov["loop_exposed"]] == [("all-reduce", layer_bytes)], ov
+    # no collective of the loops is exposed: since PR 62 a matrix's gradient is summed by the plan's sends, and the
+    # vectors' one combined all-reduce (a layer's bytes less its matrices') has the matrices' gradient dots beside it
+    assert ov["loop_exposed"] == [], ov
+    matrices = sum(leaf.size // leaf.shape[0] for leaf in layers if leaf.ndim == 3) * 4
+    assert any(r["op"] == "all-reduce" and r["bytes"] == layer_bytes - matrices and r["in_loop"]
+               for r in _schedule(e, "fused_step")), layer_bytes - matrices
     assert e.compile_stats()["fused_step"]["dispatches"] == 1
 
     eraw = _engine({"overlap_comm": False})
