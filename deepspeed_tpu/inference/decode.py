@@ -352,13 +352,13 @@ _decoder_cache: Dict[Tuple, Tuple] = {}
 
 def _refuse_state_layers(cfg, what: str) -> None:
     """Every key and value of a row is the only state ``what`` knows of. A
-    model with recurrent-state, sliding-window or latent-attention layers
-    (``layer_types`` naming ``linear``, ``ssm``, ``window`` or ``latent``) is refused
+    model with recurrent-state, short-convolution, sliding-window or latent-attention layers
+    (``layer_types`` naming ``linear``, ``ssm``, ``conv``, ``window`` or ``latent``) is refused
     where it is built, with the missing piece named."""
     if has_state_layers(cfg):
         raise NotImplementedError(
-            f"{what} does not support a model with recurrent-state (linear-attention or state-space) or sliding-window layers: "
-            "it would need a snapshot of each row's recurrent state and convolution tail, or a window layer's "
+            f"{what} does not support a model with recurrent-state (linear-attention or state-space), short-convolution or sliding-window layers: "
+            "it would need a snapshot of each row's recurrent state and convolution tail (a conv layer's: the tail alone), or a window layer's "
             "masks, sinks and heads of their own, beside its keys and values, which only the paged server's "
             "per-slot store keeps (serve through init_inference(...).serve())"
         )

@@ -1,7 +1,8 @@
 """The ragged serving step of a model whose layers are of more than one kind
 (``models/hybrid_moe.py``): softmax layers that keep keys and values in pages,
 sliding-window layers that keep a row's newest pages in a ring, linear-attention
-or state-space layers that keep one recurrent state and a convolution tail a row, latent-attention
+or state-space layers that keep one recurrent state and a convolution tail a row,
+gated short-convolution layers that keep a convolution tail a row and NOTHING else, latent-attention
 layers that keep one low-rank entry a token in pages of their own (a latent
 layer with or without a low-rank query, with rotary or none); leading layers
 with a dense FFN, a leading layer of any kind, then every layer with its
@@ -46,7 +47,12 @@ parameter of the program) and one more row array:
   (``state_shapes``): ``[ssm layers, slots + 1, NH, P, N]`` float32 and ``[ssm
   layers, slots + 1, K - 1, tail_rows(C), 128]``, the ``C = NH P + 2 G N``
   convolved channels (``G`` groups of ``B`` and ``C``) a lane tile a row in
-  whole sublane tiles, as ``ssd_decode`` reads them;
+  whole sublane tiles, as ``ssd_decode`` reads them. A model with ``conv``
+  layers keeps a tail ALONE: ``store.state`` is ``None`` (no parameter of the
+  program) and ``store.conv`` ``[conv layers, slots + 1, K - 1,
+  tail_rows(H), 128]`` holds a row's last ``K - 1`` gated products ``B * x~``,
+  restarted, handed over after a chunk (``tail_after_chunk``) and spared for
+  dead rows exactly as the other kinds' tails;
 * ``store.window_k / window_v`` ``[window layers, 1 + R * ring, NKV', P, ..]``:
   the rings of the sliding-window layers, with their own KV-head count. Row r
   owns pages ``1 + slots[r] * ring ..`` and position ``p`` lives in ring page
@@ -62,7 +68,10 @@ parameter of the program) and one more row array:
   numbers, at whole lane tiles: 576 at 640), with no value array: the value is
   the entry's leading ``kv_lora_rank`` lanes.
 
-The leading dense layers, then one ``lax.scan`` over PERIODS run the layers;
+The leading dense layers, then one ``lax.scan`` over the whole PERIODS, then the
+layers of a partial last period (``cfg.remainder``: each with leaves of its own
+under ``params["trailing"]``, its mixer's entries behind the scanned layers' and
+its own routed FFN behind it; none: nothing is traced) run the layers;
 the scan's body holds the period's layers in order and reaches each layer's
 pages and state through an index, so the donated buffers are the ones
 returned, and each layer's weights by one slice a leaf of the stacks the
@@ -106,6 +115,16 @@ tiles, as in ``decode._paged_layers``; a window of at most one tile is one
   (over each of the layer's ``ssm_groups`` groups apart) and projects
   (``hm.ssm_output``).
 
+* conv: no kernel. The tile loop writes ``u = B * x~`` and the gate ``C`` of
+  every live token; the rows with ONE token gather their tails, sum the ``K``
+  taps and scatter the shifted tails back, all rows at once; a row with a chunk
+  runs ``hm.gated_conv`` over ``[tail ; chunk]`` and leaves its last ``K - 1``
+  REAL products, one row a trip; the tile loop behind the mixer gates and
+  projects (``hm.conv_output``).
+* softmax and window under ``qk_norm="head"``: the tile loop norms q and k a
+  head before it rotates them (``hm.attn_heads``), so what is written to a
+  page is the normed, rotated key.
+
 The config's scalar multipliers (``embedding_multiplier``,
 ``residual_multiplier`` on both branches of every layer, ``logits_scaling`` in
 ``decode._final_logits``) are read when the program is built: at 1.0 no
@@ -115,7 +134,7 @@ multiply is traced and the program's text is what it was.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -138,13 +157,18 @@ CHUNK_ROWS = 4
 
 class StateShapes(NamedTuple):
     """The per-slot store of a config's state layers, for ``max_slots`` rows:
-    the shapes are the kind's (``cfg.state_kind``; a config names one at most)."""
+    the shapes are the kind's (``cfg.state_kind``; a config names one at most).
+    A kind may keep a tail ALONE (``conv``): its ``state`` is None, no array
+    anywhere and no parameter of any program."""
 
-    state: tuple  # linear: [layers, slots + 1, NH, Dk, Dv]; ssm: [layers, slots + 1, NH, P, N]; float32
-    conv: tuple  # linear: [layers, slots + 1, K - 1, 3, NH, D]; ssm: [layers, slots + 1, K - 1, tail_rows(C), 128]; the activations' type
+    state: Optional[tuple]  # linear: [layers, slots + 1, NH, Dk, Dv]; ssm: [layers, slots + 1, NH, P, N]; float32; conv: None
+    # linear: [layers, slots + 1, K - 1, 3, NH, D]; ssm and conv: [layers, slots + 1, K - 1, tail_rows(C), 128] (conv: C = H); the activations' type
+    conv: tuple
 
 
 def state_shapes(cfg, max_slots: int) -> StateShapes:
+    if cfg.state_kind == "conv":
+        return StateShapes(None, (cfg.layers_of("conv"), max_slots + 1, cfg.conv_kernel - 1, tail_rows(cfg.hidden_size), LANES))
     if cfg.state_kind == "ssm":
         n, K = cfg.layers_of("ssm"), cfg.ssm_conv_kernel
         return StateShapes((n, max_slots + 1, cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state),
@@ -321,12 +345,16 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         from_row = jnp.take(row, jnp.clip(at - (taps - 1), 0, T - 1), axis=0)
         return jnp.where((at >= taps - 1)[:, None], from_row, jnp.take(tail, jnp.minimum(at, taps - 2), axis=0))
 
-    def ffn(x_tile, start, per, j):
+    def ffn(x_tile, start, per, j, own=None):
+        """The period's ``j``-th FFN of period ``per`` out of the stacks, or ``own``, a trailing layer's own leaves."""
         if not E:  # no expert anywhere: the layer's dense FFN out of the period's stacks
-            return dense_ffn(x_tile, start, stacks["ffn"], per, j)
-        p = weights_at(moe_stacks, per, j, start)
+            return dense_ffn(x_tile, start, stacks["ffn"], per, j) if own is None else dense_ffn(x_tile, start, own["ffn"])
+        if own is None:
+            p, experts = weights_at(moe_stacks, per, j, start), expert_stacks
+        else:  # its experts are ONE layer's, not a slice of the stacks
+            p, experts = weights_at({k: v for k, v in own["moe"].items() if k != "experts"}, None, None, start), own["moe"]["experts"]
         moe = functools.partial(
-            hm.moe_ffn, live=packed.take(packed.live, start)[None], experts=expert_stacks, group_offset=(per * n + j) * E
+            hm.moe_ffn, live=packed.take(packed.live, start)[None], experts=experts, group_offset=0 if own else (per * n + j) * E
         )
         out, counts = decode._ffn_body(cfg, {"moe": p}, x_tile, p["mlp_norm_scale"], None, moe_ffn=moe)
         return x_tile + branch(out), counts
@@ -394,7 +422,8 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         leaves where ``per`` is None), ``layer`` its entry in the kind's
         pools, ``ffn(x_tile, start)`` what follows the mixer."""
         NH, NKV = cfg.heads_of(kind), cfg.kv_heads_of(kind)
-        rotary_or_scaled = cfg.position == "rope" or cfg.attn_value_scale != 1.0
+        # what ``attn_heads`` does to the projections: a norm a head, a rotation, a scale; none of them: it is not called
+        rotary_or_scaled = cfg.position == "rope" or cfg.attn_value_scale != 1.0 or cfg.qk_norm == "head"
 
         def before(start, qkv):
             p = weights_at(tree, per, jk, start)
@@ -402,7 +431,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
             new = jax.lax.optimization_barrier(hm.attn_project(p, h))  # decode._paged_layers.project: keep the head split apart
             if rotary_or_scaled:
                 at = None if positions is None else packed.take(positions, start)[None]
-                new = tuple(a.reshape(a.shape[1], -1) for a in hm.attn_heads(cfg, kind, *(a[None] for a in new), at))
+                new = tuple(a.reshape(a.shape[1], -1) for a in hm.attn_heads(cfg, kind, *(a[None] for a in new), at, p))
             return tuple(put(buf, a, start) for buf, a in zip(qkv, new))
 
         with jax.named_scope(hm.SCOPES[kind]):
@@ -607,12 +636,79 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, st, cv, counts
 
+    def conv_layer(x, cv, tree, per, jc, layer, ffn):
+        """A gated short-convolution layer: ``tree``, ``per``, ``jc``, ``layer``
+        (its entry of the tail store) and ``ffn`` as ``attention_layer``'s. All
+        it keeps of a row is the tail, the last ``K - 1`` gated products ``u =
+        B * x~``: a row with one token reads its tail, sums the ``K`` taps and
+        writes the tail shifted by its own ``u``, every such row at once; a
+        chunk row runs the same convolution over ``[tail ; chunk]`` and
+        leaves its last ``K - 1`` REAL products (``tail_after_chunk``), one
+        row a trip. Plain ``jnp`` under the scope: no kernel. Returns ``(x,
+        conv, counts)``."""
+        H, CK = cfg.hidden_size, cfg.conv_kernel
+        lane_rows = H // LANES  # the rows of the store's entry that hold channels; those past them are zeros
+
+        def before(start, bufs):
+            p = weights_at(tree, per, jc, start)
+            h = _norm(packed.take(x, start), p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
+            return tuple(put(buf, a, start) for buf, a in zip(bufs, hm.conv_inputs(p, h)))
+
+        def stored(tail):
+            """``tail`` [..., K - 1, H] as the store's entry [..., K - 1, tail_rows(H), 128]."""
+            rows = tail.reshape(tail.shape[:-1] + (lane_rows, LANES))
+            return jnp.pad(rows, ((0, 0),) * (rows.ndim - 2) + ((0, cv.shape[3] - lane_rows), (0, 0))).astype(cv.dtype)
+
+        with jax.named_scope(hm.SCOPES["conv"]):
+            u, gate = tiles(before, (unfilled((NPK, H), dtype), unfilled((NPK, H), dtype)))
+            taps = weights_at({"conv_w": tree["conv_w"]}, per, jc, jnp.int32(0))
+            # the rows with one token, all at once: their tails out of the store, the three-term sum, the tails shifted back
+            u1 = u[starts] if T == 1 else real_rows(u, starts, one_token)  # [B, H]
+            held = jnp.take(jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False), slots, axis=0)[:, :, :lane_rows]
+            tail = jnp.where(fresh[:, None, None], 0, held.reshape(B, CK - 1, H))
+            v = hm.gated_conv(taps, tail, u1[:, None])[:, 0].astype(dtype)  # [B, H]
+            # a row that is dead or has a chunk writes to the slot that is nobody's
+            cv = cv.at[layer, jnp.where(one_token, slots, cv.shape[1] - 1)].set(stored(hm.shifted_tail(tail, u1)))
+            if T == 1:
+                v = functools.partial(slab_rows, v)
+            else:
+
+                def chunk_row(i, carry):
+                    cv, chunks = carry
+                    r = order[i]
+                    idx = packed.index[r]  # [T]
+                    valid = jnp.arange(T, dtype=jnp.int32) < q_lens[r]
+                    own = (layer, slots[r]) + (0,) * (cv.ndim - 2)
+                    held = jax.lax.dynamic_slice(cv, own, (1, 1) + cv.shape[2:])[0, 0, :, :lane_rows]
+                    tail = jnp.where(fresh[r], 0, held.reshape(1, CK - 1, H))
+                    row_u = real_rows(u, idx, valid)  # [T, H]
+                    v_row = hm.gated_conv(taps, tail, row_u[None])[0]
+                    last = tail_after_chunk(tail[0], row_u, q_lens[r], CK)
+                    cv = jax.lax.dynamic_update_slice(cv, stored(last)[None, None], own)
+                    return cv, put_chunk(chunks, v_row, idx[0], valid)
+
+                cv, chunks = jax.lax.fori_loop(0, n_chunk_rows, chunk_row, (cv, unfilled((NPK, H), dtype)))
+                v = functools.partial(rows_output, chunks, v)
+
+        def after(start, carry):
+            x, counts = carry
+            x_tile = packed.take(x, start)
+            with jax.named_scope(hm.SCOPES["conv"]):
+                p = weights_at(tree, per, jc, start)
+                x_tile = x_tile + branch(hm.conv_output(p, packed.take(gate, start), v(start).astype(jnp.float32)).astype(x.dtype))
+            x_tile, tile_counts = ffn(x_tile[None], start)
+            return put(x, x_tile[0], start), counts + tile_counts
+
+        x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
+        return x, cv, counts
+
     # a kind's pools: the full layers' pages, the window layers' rings, the latent layers' pages, the linear or the
-    # state-space layers' states and convolution tails
+    # state-space layers' states and convolution tails, or the conv layers' tails alone
     state_kind = cfg.state_kind or "linear"  # the kind whose states and tails the store's two fields hold
-    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,), state_kind: (state, conv)}
+    pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,),
+             state_kind: (conv,) if state_kind == "conv" else (state, conv)}
     mixers = {"softmax": functools.partial(attention_layer, "softmax"), "window": functools.partial(attention_layer, "window"),
-              "latent": latent_layer, "linear": linear_layer, "ssm": ssm_layer}
+              "latent": latent_layer, "linear": linear_layer, "ssm": ssm_layer, "conv": conv_layer}
     # the leading dense layers, each with its own weights and the first entries of its kind's pools
     for i, kind in enumerate(cfg.layer_types[: cfg.leading_dense_layers]):
         lead = params["leading"][i]
@@ -643,8 +739,20 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         return (x, pools), jnp.stack(counts)
 
     (x, pools), counts = jax.lax.scan(period_step, (x, pools), jnp.arange(cfg.num_periods, dtype=jnp.int32))
-    store = StateStore(*pools[state_kind], *pools["window"], *pools["latent"])
-    return x, *pools["softmax"], store, counts.reshape(cfg.num_moe_layers, E), packed
+    counts = counts.reshape(max(cfg.num_moe_layers - len(cfg.remainder), 0), E)  # the scanned layers'
+    # the layers behind the last whole period (``cfg.remainder``), as the leading ones: each with its own weights, a mixer
+    # with the kind's entries behind the scanned layers' and the layer's own FFN behind it; none: nothing is traced
+    for i, kind in enumerate(cfg.remainder):
+        own = params["trailing"][i]
+        x, *written, c = mixers[kind](
+            x, *pools[kind], own["mixer"], None, None, cfg.trailing_of(kind) + cfg.remainder[:i].count(kind),
+            functools.partial(ffn, per=None, j=None, own=own),
+        )
+        pools[kind] = tuple(written)
+        counts = jnp.concatenate([counts, c[None]])
+    held = (None, *pools["conv"]) if state_kind == "conv" else pools[state_kind]
+    store = StateStore(*held, *pools["window"], *pools["latent"])
+    return x, *pools["softmax"], store, counts, packed
 
 
 def hybrid_forward(cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots,

@@ -242,6 +242,12 @@ class StateStore(NamedTuple):
       ``max_slots`` belongs to nobody and takes dead rows' writes. A slot's
       entry is restarted from zero by the program when a row's window begins
       at position 0 (``inference/hybrid_decode.py``).
+    * gated short-convolution layers (``conv``, in place of those two kinds):
+      a convolution tail ALONE, the last ``K - 1`` gated products of a row, a
+      lane tile a row as a state-space layer's; ``state`` is then ``None``
+      (no array, no parameter of any program) and what this store, the
+      pool's ``cache_bytes`` and its ``memory_report`` call a slot's "state"
+      is the tails.
     * sliding-window layers: pools of their own shape in which slot ``s`` owns
       pages ``1 + s * ring ..`` (page 0 is the trash page, as in the page pool)
       as a RING: position ``p`` of its row lives in ring page ``(p // P) %
@@ -266,8 +272,8 @@ class StateStore(NamedTuple):
     a slot's state, and ``PagePool.cache_bytes`` says which of the two the
     live rows' bytes are in."""
 
-    state: jax.Array  # [state layers, max_slots + 1, NH, Dk, Dv] float32 (ssm: [.., NH, P, N])
-    conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3, NH, D] (ssm: [.., K - 1, tail_rows(C), 128])
+    state: Optional[jax.Array]  # [state layers, max_slots + 1, NH, Dk, Dv] float32 (ssm: [.., NH, P, N]); None: the kind keeps a tail alone
+    conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3, NH, D] (ssm and conv: [.., K - 1, tail_rows(C), 128])
     window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk] (narrow heads: NKV / f, P, f Dk)
     window_v: Optional[jax.Array] = None
     latent: Optional[jax.Array] = None  # [latent layers, num_pages, P, lanes]
@@ -278,8 +284,12 @@ class StateStore(NamedTuple):
     def latent_bytes(self) -> int:
         return 0 if self.latent is None else self.latent.nbytes
 
+    def state_bytes(self) -> int:
+        """The per-slot entries' bytes: the states, where the kind keeps one, and the convolution tails."""
+        return (0 if self.state is None else self.state.nbytes) + self.conv.nbytes
+
     def hbm_bytes(self) -> int:
-        return self.state.nbytes + self.conv.nbytes + self.window_bytes() + self.latent_bytes()
+        return self.state_bytes() + self.window_bytes() + self.latent_bytes()
 
 
 def _refuse_with_state(states, what: str) -> None:
@@ -289,11 +299,12 @@ def _refuse_with_state(states, what: str) -> None:
             "back pages of the K and V arrays (kv_pool._copy_page, the prefix index), and a latent layer's pages are a "
             "third array (StateStore.latent) that none of them knows yet"
         )
-    if states is not None and (states.state.size or states.window_k is not None):
+    if states is not None and (states.conv.size or states.window_k is not None):  # every state kind keeps a tail
         raise NotImplementedError(
             f"{what} is not supported for a model with recurrent-state or sliding-window layers: keys and values "
             "of a full-attention layer can be shared, copied or rolled back a page at a time; the recurrent state "
-            "of a row cannot without a snapshot of it at that position, nor can a window layer's page ring, which "
+            "of a row (or a convolution's tail, where a layer keeps that alone) cannot without a snapshot of it at "
+            "that position, nor can a window layer's page ring, which "
             "holds a row's newest positions only, and the per-slot store keeps neither"
         )
 
@@ -350,7 +361,7 @@ class PagePool:
         self.window_keys = 0  # keys a window layer's query sees
         self.window_kv_heads = 0  # a window layer's KV heads (its ring's axis 2 holds them in groups)
         self.query_heads: dict = {}  # a layer kind's query heads, for the memory report
-        self.state_kind: Optional[str] = None  # the kind whose layers' states the store holds: linear | ssm
+        self.state_kind: Optional[str] = None  # the kind whose layers' states the store holds: linear | ssm | conv (tails alone)
         if getattr(cfg, "layer_types", None):
             from deepspeed_tpu.inference.hybrid_decode import state_shapes, window_shapes
 
@@ -370,7 +381,8 @@ class PagePool:
             if cfg.layers_of("latent"):
                 # one entry a token under the pool's own page ids: no value array
                 latent = jnp.zeros((cfg.layers_of("latent"), num_pages, self.page_size, key_lanes(cfg.latent_width)), kv_dtype)
-            self.states = StateStore(jnp.zeros(shapes.state, jnp.float32), jnp.zeros(shapes.conv, kv_dtype), *rings, latent)
+            state = None if shapes.state is None else jnp.zeros(shapes.state, jnp.float32)
+            self.states = StateStore(state, jnp.zeros(shapes.conv, kv_dtype), *rings, latent)
         # LIFO free list keeps hot pages hot; page 0 stays out of circulation
         self._free = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._free_slots = list(range(max_slots - 1, -1, -1))
@@ -428,14 +440,16 @@ class PagePool:
 
     @property
     def state_bytes_per_slot(self) -> int:
-        """HBM bytes a slot's recurrent states and convolution tails take over the state layers, whatever its row's length."""
+        """HBM bytes a slot's recurrent states and convolution tails (a conv layer's: the tails alone) take over the
+        state layers, whatever its row's length."""
         if self.states is None:
             return 0
-        return (self.states.state.nbytes + self.states.conv.nbytes) // (self.max_slots + 1)
+        return self.states.state_bytes() // (self.max_slots + 1)
 
     def cache_bytes(self) -> dict:
         """Which of the two caches the live rows' bytes are in: the slots in
-        use times a slot's state, and the pages in use times a page's latent
+        use times a slot's state (of a model whose state kind keeps a tail
+        alone: its tails), and the pages in use times a page's latent
         entries. What ``serve.step`` carries; ``{}`` for a model with neither
         (a uniform one: its pages are ``live_hbm_bytes``)."""
         if self.states is None:
@@ -469,9 +483,11 @@ class PagePool:
                 # the paged (full or latent) layers' query heads; a window layer's are with its ring's entries
                 "paged_query_heads": self.query_heads["softmax"],
                 "state_kind": self.state_kind,  # None: no recurrent-state layer (empty arrays)
-                "state_shape": list(self.states.state.shape[2:]),  # a slot's state in one layer, float32
-                "state_layers": self.states.state.shape[0],
-                "state_total_bytes": self.states.state.nbytes + self.states.conv.nbytes,
+                # a slot's state in one layer, float32; a kind that keeps a tail alone: none, and the bytes below are the tails'
+                "state_shape": [] if self.states.state is None else list(self.states.state.shape[2:]),
+                "tail_shape": list(self.states.conv.shape[2:]),  # a slot's convolution tail in one layer, the activations' type
+                "state_layers": self.states.conv.shape[0],
+                "state_total_bytes": self.states.state_bytes(),
                 "state_bytes_per_slot": self.state_bytes_per_slot,
                 "state_bytes_in_use": cache["state_bytes_in_use"],
                 "state_slots": self.max_slots,

@@ -43,7 +43,9 @@ class TransformerConfig:
     qkv_bias: Optional[bool] = None  # override for qkv projs
     # QK-norm. "projection": RMSNorm (``norm_eps``, learned scale) over the WHOLE
     # q projection [NH*D] and the whole k projection [NKV*D], before the split
-    # into heads and RoPE (the OLMoE family), so the cached k is the normed one
+    # into heads and RoPE (the OLMoE family), so the cached k is the normed one.
+    # "head" (the multi-kind family alone, ``models/hybrid_moe.py``): RMSNorm over
+    # each head's ``head_dim`` features of q and of k, one learned scale [D] each
     qk_norm: Optional[str] = None
     # a looped (universal-transformer) stack: the SAME ``num_layers`` layers of
     # weights run ``num_loops`` times a token, the final norm after every pass
@@ -90,8 +92,13 @@ class TransformerConfig:
                 self.intermediate_size = 4 * self.hidden_size
         if self.qkv_bias is None:
             self.qkv_bias = self.use_bias
-        if self.qk_norm not in (None, "projection"):
-            raise ValueError(f"unknown qk_norm {self.qk_norm!r}; expected None or 'projection'")
+        if self.qk_norm not in (None, "projection", "head"):
+            raise ValueError(f"unknown qk_norm {self.qk_norm!r}; expected None, 'projection' or 'head'")
+        if self.qk_norm == "head" and not hasattr(self, "layer_types"):
+            raise NotImplementedError(
+                "qk_norm='head' (a norm over each head's features) is the multi-kind family's (models/hybrid_moe.py: "
+                "HybridMoEConfig); a uniform model norms the whole projection: qk_norm='projection'"
+            )
         if self.sequence_parallel_mode not in ("ulysses", "ring"):
             raise ValueError(
                 f"unknown sequence_parallel_mode {self.sequence_parallel_mode!r}; "
@@ -157,9 +164,10 @@ def has_state_layers(cfg) -> bool:
     """Whether a model keeps, beside the keys and values of its full-attention
     layers, something of a row that exists at the row's newest positions only
     (``models/hybrid_moe.py``: a ``layer_types`` that names ``linear`` or
-    ``ssm``, whose layers keep a recurrent state, or ``window``, whose layers
-    keep a ring of the newest pages)."""
-    return bool({"linear", "ssm", "window"} & set(getattr(cfg, "layer_types", None) or ()))
+    ``ssm``, whose layers keep a recurrent state, ``conv``, whose layers keep
+    a convolution's tail, or ``window``, whose layers keep a ring of the
+    newest pages)."""
+    return bool({"linear", "ssm", "conv", "window"} & set(getattr(cfg, "layer_types", None) or ()))
 
 
 def has_latent_layers(cfg) -> bool:

@@ -3,7 +3,8 @@ every earlier key in some, over a sliding window in others, gated delta-rule
 linear attention (``ops/transformer/linear_attention.py``) in others, latent
 attention (one low-rank latent a token in place of keys and values a head) in
 others, a Mamba-2 state-space mixer (``ops/transformer/state_space.py``) in
-others; a routed FFN that may have a shared expert and may hold only this chip's share
+others, a gated short convolution (no attention, no recurrence: all a row
+carries is the convolution's tail) in others; a routed FFN that may have a shared expert and may hold only this chip's share
 of the experts its router chooses from, behind ``leading_dense_layers`` layers
 whose FFN is dense: a leading layer of any kind; or, with ``num_experts`` 0, a
 dense FFN in EVERY layer, stacked by period like the mixers. A layer is a mixer
@@ -13,12 +14,13 @@ sublayer, ``x + f(RMSNorm(x))`` with ``f`` a mixer alone or the FFN alone
 three matrices or, with a pointwise ``activation`` (``relu2``), of two.
 
 ``HybridMoEConfig.layer_types`` says what each layer is (``softmax`` /
-``window`` / ``linear`` / ``latent`` / ``ssm``, and ``ffn`` for a block that is the FFN
+``window`` / ``linear`` / ``latent`` / ``ssm`` / ``conv``, and ``ffn`` for a block that is the FFN
 alone); after the leading dense layers the list repeats
 with a period (one softmax layer and three linear ones, say, or five window
-layers and a softmax one; a list that repeats nothing is one period of all its layers).
-Parameters are stacked by KIND inside a period and
-by period in front; a leading layer has its own::
+layers and a softmax one; a list that repeats nothing is one period of all its layers),
+whole or, at least twice whole and then once more IN PART (``[a c c c] x 9 + [a
+c]``: nine periods and a ``remainder`` of two). Parameters are stacked by KIND inside a period and
+by period in front; a leading layer and a layer of the remainder have their own::
 
     params["leading"][i]          {"mixer": its kind's leaves, "ffn": a dense FFN's}
     params["periods"]["softmax"]  leaves [periods, softmax layers a period, ...]
@@ -26,11 +28,13 @@ by period in front; a leading layer has its own::
     params["periods"]["linear"]   leaves [periods, linear layers a period, ...]
     params["periods"]["latent"]   leaves [periods, latent layers a period, ...]
     params["periods"]["ssm"]      leaves [periods, state-space layers a period, ...]
+    params["periods"]["conv"]     leaves [periods, conv layers a period, ...]
     params["periods"]["moe"]      leaves [periods, layers a period, ...]; [periods, FFN blocks a period, ...] where the list names them
     params["periods"]["ffn"]      in place of "moe" where ``num_experts`` is 0: a dense FFN a layer (or an FFN block)
+    params["trailing"][i]         {"mixer": its kind's leaves, "moe": ONE routed FFN's (or "ffn": a dense one's)}; no remainder: no such key
 
-so the leading layers and then one ``lax.scan`` over periods run the model,
-the scan's body holding the period's layers in order. The functions below are
+so the leading layers, then one ``lax.scan`` over the whole periods, then the
+trailing layers run the model, the scan's body holding the period's layers in order. The functions below are
 the layer's mathematics, shared by ``HybridMoETransformerLM.apply`` (a whole
 sequence, no cache: what the parity tests use; training this family is not
 supported) and by the paged serving step (``inference/hybrid_decode.py``).
@@ -38,7 +42,9 @@ supported) and by the paged serving step (``inference/hybrid_decode.py``).
 The softmax and the window layer (``attn_project``, ``attn_heads``): ``q k v = h Wq, h Wk, h Wv``
 as the kind's query heads (``num_heads``, ``window_num_heads``: ``heads_of``)
 and the kind's KV heads (``num_kv_heads``, ``window_num_kv_heads``) of
-``head_dim``, values of ``v_head_dim``; ``position="rope"`` rotates the
+``head_dim``, values of ``v_head_dim``; ``qk_norm="head"`` norms q and k over each head's ``head_dim`` features
+(RMSNorm, one learned scale ``[head_dim]`` for q and one for k, BEFORE any
+rotation: a page holds normed, rotated keys); ``position="rope"`` rotates the
 leading ``rope_dim`` / ``window_rope_dim`` features of q and k (rotate-half, at
 the token's absolute position, theta ``rope_theta`` / ``window_rope_theta``;
 in a softmax layer with ``rope_yarn_factor`` the YaRN frequencies, cos and sin
@@ -81,7 +87,15 @@ all heads; more: head n reads group ``n // (heads / G)``); ``dt = softplus(dt + 
 state ``S`` ``[heads, head_dim, ssm_state]`` float32, ``S_t = exp(dt_t A) S_{t-1}
 + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``; output ``RMSNorm(y * silu(z))
 W_out``, the gate BEFORE the norm, the norm over each of the ``G`` groups of
-``d_inner / G`` features apart (one group: over all of them). The
+``d_inner / G`` features apart (one group: over all of them). The conv layer
+(``conv_inputs``, ``gated_conv``, ``shifted_tail``, ``conv_output``; the gated
+short convolution of the LFM2 family): ``[B ; C ; x~] = h W_in`` (three parts of
+``H``; the published ``in_proj`` is ONE leaf, ``w_in`` ``[H, 3 H]``: its parts are
+whole lane tiles), ``u = B * x~`` a channel, ``v_t = sum_j w_j u_{t - (K - 1) +
+j}``: one depthwise causal convolution of ``conv_kernel`` taps over the ``H``
+channels of ``u`` with NO bias and NO activation, zeros before the sequence;
+output ``(C * v) W_out``. No state: what a row carries is ``u`` of its last ``K -
+1`` tokens. The
 scalar multipliers (each 1.0 puts nothing into a program): the embedding times
 ``embedding_multiplier``, BOTH branches of every layer times
 ``residual_multiplier`` before they are added, the logits divided by
@@ -111,12 +125,14 @@ from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.models.moe_transformer import MoETransformerConfig, MoETransformerLM
 from deepspeed_tpu.models.transformer import _norm
 
-LAYER_KINDS = ("softmax", "linear", "window", "latent", "ssm")  # the mixers
+LAYER_KINDS = ("softmax", "linear", "window", "latent", "ssm", "conv")  # the mixers
 FFN_BLOCK = "ffn"  # in ``layer_types``: a block that is the FFN alone; a list that names one has nothing behind its mixers
 # the named scope around a kind's mixer, which the benchmark's readers find device time by
 SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention", "latent": "latent_attention",
-          "ssm": "ssm_mixer"}
-STATE_KINDS = ("linear", "ssm")  # the kinds whose layers keep a recurrent state and a convolution tail a slot
+          "ssm": "ssm_mixer", "conv": "conv_mixer"}
+# the kinds whose layers keep something a SLOT whatever the row's length: a recurrent state and a convolution tail, or
+# (``conv``) a convolution tail alone
+STATE_KINDS = ("linear", "ssm", "conv")
 
 
 @dataclasses.dataclass
@@ -167,6 +183,7 @@ class HybridMoEConfig(MoETransformerConfig):
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv_kernel: int = 4
+    conv_kernel: int = 3  # conv layers: the taps of the gated short convolution over ``hidden_size`` channels
     # the scalar multipliers: 1.0 is no multiply anywhere (decided in Python when a program is built)
     embedding_multiplier: float = 1.0  # the embedding times this
     residual_multiplier: float = 1.0  # each branch of a layer times this, before it is added
@@ -229,6 +246,22 @@ class HybridMoEConfig(MoETransformerConfig):
                     "recurrent state (kv_pool.StateStore: one state array, one tail array, their shapes the kind's); "
                     "no published model asks for both"
                 )
+        if "conv" in self.layer_types:
+            if self.conv_kernel < 2 or self.hidden_size % 128:
+                raise ValueError(
+                    f"a conv layer needs conv_kernel >= 2 and its convolved channels, hidden_size = {self.hidden_size}, in whole "
+                    "lane tiles of 128: the per-slot store keeps a row's tail a lane tile a row"
+                )
+            if self.state_kind != "conv":
+                raise NotImplementedError(
+                    f"a model with conv layers AND {self.state_kind} layers: the per-slot store holds ONE kind's "
+                    "convolution tails (kv_pool.StateStore: one tail array, its shape the kind's); no published model asks for both"
+                )
+        if self.qk_norm == "projection":
+            raise NotImplementedError(
+                "qk_norm='projection' (one norm over the whole q and k projections) is the uniform family's "
+                "(models/transformer.py); a softmax or window layer here norms q and k a head: qk_norm='head'"
+            )
         self.moe_expert_share = tuple(self.moe_expert_share)
         index, of = self.moe_expert_share
         if self.moe_router_experts is None:
@@ -252,16 +285,31 @@ class HybridMoEConfig(MoETransformerConfig):
 
     @property
     def period(self) -> Tuple[str, ...]:
-        """The shortest prefix that the layers behind the leading dense ones repeat."""
+        """The shortest prefix that the layers behind the leading dense ones
+        repeat: whole, or, where a layer is a mixer AND an FFN, at least
+        twice whole and then once more in part (``remainder``). A list that
+        names FFN blocks repeats its period whole or is one period of all its
+        blocks, as is a list whose second period would be the partial one."""
         body = self.layer_types[self.leading_dense_layers :]
         for n in range(1, len(body) + 1):
-            if len(body) % n == 0 and all(body[i] == body[i % n] for i in range(len(body))):
+            whole = len(body) % n == 0 or (len(body) // n >= 2 and not self.single_sublayer)
+            if whole and all(body[i] == body[i % n] for i in range(len(body))):
                 return tuple(body[:n])
         raise AssertionError
 
     @property
     def num_periods(self) -> int:
+        """The WHOLE periods: what the parameters' stacks hold and the layer scan runs."""
         return (self.num_layers - self.leading_dense_layers) // len(self.period)
+
+    @property
+    def remainder(self) -> Tuple[str, ...]:
+        """The layers behind the last whole period, a proper prefix of the
+        period (``[a c c c] x 9 + [a c]``: two), each a mixer and a routed FFN
+        with leaves of its own (``params["trailing"]``), run once behind the
+        scan as the leading layers are in front of it. Empty: no such leaves,
+        nothing in any program."""
+        return tuple(self.layer_types[self.leading_dense_layers + self.num_periods * len(self.period) :])
 
     @property
     def single_sublayer(self) -> bool:
@@ -293,7 +341,8 @@ class HybridMoEConfig(MoETransformerConfig):
 
     @property
     def state_kind(self) -> Optional[str]:
-        """The kind whose layers keep a recurrent state and a convolution tail a slot (``STATE_KINDS``), or None."""
+        """The kind whose layers keep a recurrent state and a convolution tail a slot, or (``conv``) a tail alone
+        (``STATE_KINDS``: a model names one at most), or None."""
         return next((kind for kind in STATE_KINDS if kind in self.layer_types), None)
 
     def layers_of(self, kind: str) -> int:
@@ -302,6 +351,10 @@ class HybridMoEConfig(MoETransformerConfig):
     def leading_of(self, kind: str) -> int:
         """Leading dense layers of ``kind``: they have the first entries of the kind's cache."""
         return sum(t == kind for t in self.layer_types[: self.leading_dense_layers])
+
+    def trailing_of(self, kind: str) -> int:
+        """The first entry of the kind's cache that a trailing layer (``remainder``) has: behind the leading layers' and the whole periods'."""
+        return self.leading_of(kind) + self.num_periods * self.period.count(kind)
 
     def heads_of(self, kind: str) -> int:
         """Query heads of a softmax, window or latent layer."""
@@ -357,14 +410,20 @@ def attn_project(p, h):
     return qmatmul(h, p["wq"]), qmatmul(h, p["wk"]), qmatmul(h, p["wv"])
 
 
-def attn_heads(cfg: HybridMoEConfig, kind: str, q, k, v, positions):
+def attn_heads(cfg: HybridMoEConfig, kind: str, q, k, v, positions, p=None):
     """``attn_project``'s three as heads, ``q`` [B, T, NH, D], ``k`` [B, T,
-    NKV, D], ``v`` [B, T, NKV, Dv], at ``positions`` [B, T]: the kind's leading
-    features of q and k rotated with the kind's frequencies, v scaled."""
+    NKV, D], ``v`` [B, T, NKV, Dv], at ``positions`` [B, T]: under
+    ``qk_norm="head"`` q and k normed over each head's ``D`` features (the
+    layer's ``p["q_norm_scale"]`` and ``p["k_norm_scale"]`` [D]) BEFORE the
+    rotation; the kind's leading features of q and k rotated with the kind's
+    frequencies, v scaled."""
     from deepspeed_tpu.models.transformer import _rope
 
     NH, NKV, D, Dv = cfg.heads_of(kind), cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
     q, k, v = (a.reshape(a.shape[:-1] + shape) for a, shape in zip((q, k, v), ((NH, D), (NKV, D), (NKV, Dv))))
+    if cfg.qk_norm == "head":
+        q = _norm(q, p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, p["k_norm_scale"], None, "rmsnorm", cfg.norm_eps)
     if cfg.position == "rope":
         scaled = cfg.rope_frequencies(kind)
         if scaled is not None:
@@ -489,6 +548,37 @@ def ssm_output(cfg: HybridMoEConfig, p, z, y):
     return qmatmul(_norm(gated, p["o_norm_scale"], None, "rmsnorm", cfg.norm_eps).astype(z.dtype), p["wo"])
 
 
+def conv_inputs(p, h):
+    """What a conv layer computes of one token before its convolution, from
+    the normed ``h`` [..., H]: ``[B ; C ; x~] = h W_in``; returns the gated
+    product ``u = B * x~`` (what the layer convolves and what its tail keeps)
+    and the output gate ``C``, both [..., H] in h's type."""
+    b, c, x = jnp.split(qmatmul(h, p["w_in"]), 3, axis=-1)
+    return b * x, c
+
+
+def gated_conv(p, tails, u):
+    """The depthwise causal convolution of a conv layer, with NO bias and NO
+    activation: ``u`` [B, T, C] after the ``K - 1`` products that came before
+    it (``tails`` [B, K - 1, C]), ``v_t = sum_j w_j u_{t - (K - 1) + j}``.
+    Float32 [B, T, C]."""
+    w = p["conv_w"].astype(jnp.float32)  # [K, C]
+    T = u.shape[1]
+    ext = jnp.concatenate([tails, u], axis=1).astype(jnp.float32)
+    return sum(w[j] * ext[:, j : j + T] for j in range(w.shape[0]))
+
+
+def shifted_tail(tail, u):
+    """A conv layer's tail ``[..., K - 1, C]`` after one more token's product ``u`` ``[..., C]``: the oldest entry gone, ``u`` the newest."""
+    return jnp.concatenate([tail[..., 1:, :], u[..., None, :]], axis=-2)
+
+
+def conv_output(p, c, v):
+    """``(C * v) W_out`` (the leaf ``wo``, as every mixer's output projection): ``c`` [..., H] the gate, ``v`` [..., H]
+    float32 the convolved product. [..., H]."""
+    return qmatmul((c.astype(jnp.float32) * v).astype(c.dtype), p["wo"])
+
+
 def scaled(x, by: float):
     """``x`` times a config's scalar multiplier; at 1.0 ``x`` itself, no multiply in any program."""
     return x if by == 1.0 else x * jnp.asarray(by, x.dtype)
@@ -587,7 +677,7 @@ class HybridMoETransformerLM(MoETransformerLM):
         NP, period = cfg.num_periods, cfg.period
         n = cfg.ffns_per_period
         gated = cfg.activation == "swiglu"  # three matrices an FFN; a pointwise activation has two, ``w_in`` and ``w_out``
-        keys = iter(jax.random.split(rng, 40 + 24 * cfg.leading_dense_layers))
+        keys = iter(jax.random.split(rng, 40 + 24 * (cfg.leading_dense_layers + len(cfg.remainder))))
         std, out_std = 0.02, 0.02 / np.sqrt(2 * L)
 
         def dense(shape, s=std):
@@ -637,6 +727,16 @@ class HybridMoETransformerLM(MoETransformerLM):
                     "o_norm_scale": jnp.ones(lead + (inner,)),
                     "wo": dense(lead + (inner, H), out_std),
                 }
+            if kind == "conv":
+                return {
+                    "attn_norm_scale": jnp.ones(lead + (H,)),
+                    # the published in_proj [H, B ; C ; x~] WHOLE: 3 H columns are whole lane tiles wherever H is, so the
+                    # three parts are slices of one product at tile boundaries, and a decode row's mixer is one read of
+                    # one matrix and one launch (granite's in_proj is in parts because ITS parts are no whole tiles)
+                    "w_in": dense(lead + (H, 3 * H)),
+                    "conv_w": dense(lead + (cfg.conv_kernel, H), 0.5),
+                    "wo": dense(lead + (H, H), out_std),
+                }
             if kind == "latent":
                 Cq, C, nope, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
                 if Cq:
@@ -661,6 +761,8 @@ class HybridMoETransformerLM(MoETransformerLM):
                 "wv": dense(lead + (H, NKV * Dv)),
                 "wo": dense(lead + (NQ * Dv, H), out_std),
             }
+            if cfg.qk_norm == "head":
+                attn.update(q_norm_scale=jnp.ones(lead + (D,)), k_norm_scale=jnp.ones(lead + (D,)))
             if cfg.attn_output_gate:
                 attn["wg"] = dense(lead + (H, NQ * Dv))
             if cfg.attn_head_gate:
@@ -688,22 +790,27 @@ class HybridMoETransformerLM(MoETransformerLM):
             return {**into, "w_out": dense(lead + (width, H), out_std)}
 
         dense_ffn = lambda *lead: {"mlp_norm_scale": jnp.ones(lead + (H,)), **ffn(Id, *lead)}
-        if E:
+
+        def routed_ffn(*lead):
             moe = {
-                "mlp_norm_scale": jnp.ones((NP, n, H)),
-                "gate": {"wg": dense((NP, n, H, ER))},
-                "experts": ffn(I, NP, n, E, routed=True),
+                "mlp_norm_scale": jnp.ones(lead + (H,)),
+                "gate": {"wg": dense(lead + (H, ER))},
+                "experts": ffn(I, *lead, E, routed=True),
             }
             if cfg.moe_select_bias:
-                moe["gate"]["bias"] = dense((NP, n, ER))
+                moe["gate"]["bias"] = dense(lead + (ER,))
             if cfg.moe_shared_experts:
-                moe["shared"] = ffn(I * cfg.moe_shared_experts, NP, n)
-            periods["moe"] = moe
-        else:  # no expert anywhere: a dense FFN a layer, stacked as the mixers are
-            periods["ffn"] = dense_ffn(NP, n)
+                moe["shared"] = ffn(I * cfg.moe_shared_experts, *lead)
+            return moe
+
+        # a layer's FFN under the key the period's stacks hold it by: routed, or (no expert anywhere) a dense one
+        feed_forward = (lambda *lead: {"moe": routed_ffn(*lead)}) if E else (lambda *lead: {"ffn": dense_ffn(*lead)})
+        periods.update(feed_forward(NP, n))
         params = {"embed": {"tokens": dense((V, H))}, "periods": periods, "final_norm_scale": jnp.ones((H,))}
         if cfg.leading_dense_layers:
             params["leading"] = [{"mixer": mixer(kind), "ffn": dense_ffn()} for kind in cfg.layer_types[: cfg.leading_dense_layers]]
+        if cfg.remainder:  # the layers behind the last whole period, as the leading ones: leaves of their own
+            params["trailing"] = [{"mixer": mixer(kind), **feed_forward()} for kind in cfg.remainder]
         if not cfg.tie_embeddings:
             params["lm_head"] = dense((H, V))
         return params
@@ -714,7 +821,7 @@ class HybridMoETransformerLM(MoETransformerLM):
         B, T, _ = h.shape
         NH, NKV, D, Dv = cfg.heads_of(kind), cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
         pos = jnp.arange(T, dtype=jnp.int32)
-        q, k, v = attn_heads(cfg, kind, *attn_project(p, h), jnp.broadcast_to(pos, (B, T)))
+        q, k, v = attn_heads(cfg, kind, *attn_project(p, h), jnp.broadcast_to(pos, (B, T)), p)
         q = q.reshape(B, T, NKV, NH // NKV, D)
         scale = cfg.attn_softmax_scale if cfg.attn_softmax_scale is not None else D ** -0.5
         scores = jnp.einsum("btkgd,bskd->bkgts", q, k).astype(jnp.float32) * scale
@@ -768,6 +875,10 @@ class HybridMoETransformerLM(MoETransformerLM):
         y, _ = ssd_chunked(x, Bm, Cm, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), p["D"].astype(jnp.float32), state)
         return ssm_output(cfg, p, z, y.reshape(B, T, cfg.ssm_inner))
 
+    def _conv_mixer(self, p, h):
+        u, c = conv_inputs(p, h)
+        return conv_output(p, c, gated_conv(p, jnp.zeros((h.shape[0], self.config.conv_kernel - 1, u.shape[-1]), u.dtype), u))
+
     def apply(self, params, batch, *, rngs=None, train: bool = False, pld_theta=None, ltd_idx=None):
         from deepspeed_tpu.models.transformer import _split_batch, cross_entropy_loss
         from deepspeed_tpu.moe.experts import apply_dense_ffn
@@ -788,6 +899,8 @@ class HybridMoETransformerLM(MoETransformerLM):
                     out = self._latent_mixer(mixer, h)
                 elif kind == "ssm":
                     out = self._ssm_mixer(mixer, h)
+                elif kind == "conv":
+                    out = self._conv_mixer(mixer, h)
                 else:
                     out = self._attention_mixer(kind, mixer, h)
             return x + branch(out.astype(x.dtype))
@@ -800,11 +913,13 @@ class HybridMoETransformerLM(MoETransformerLM):
         for kind, p in zip(cfg.layer_types, params.get("leading", ())):
             x = dense(mix(x, kind, p["mixer"]), p["ffn"])
 
-        def feed_forward(x, p, j):
-            """The period's ``j``-th FFN: dense out of ``p["ffn"]`` (``num_experts`` 0), else routed."""
+        def feed_forward(x, p, j=None):
+            """The period's ``j``-th FFN (None: ``p`` holds ONE layer's, a trailing layer's own): dense out of
+            ``p["ffn"]`` (``num_experts`` 0), else routed."""
+            own = (lambda tree: tree) if j is None else functools.partial(jax.tree_util.tree_map, lambda a: a[j])
             if "ffn" in p:
-                return dense(x, jax.tree_util.tree_map(lambda a: a[j], p["ffn"]))
-            moe = jax.tree_util.tree_map(lambda a: a[j], p["moe"])
+                return dense(x, own(p["ffn"]))
+            moe = own(p["moe"])
             with jax.named_scope("mlp"):
                 out, _ = moe_ffn(cfg, moe, _norm(x, moe["mlp_norm_scale"], None, "rmsnorm", cfg.norm_eps))
             return x + branch(out.astype(x.dtype))
@@ -822,6 +937,8 @@ class HybridMoETransformerLM(MoETransformerLM):
             return x, None
 
         x, _ = jax.lax.scan(period_step, x, params["periods"])
+        for kind, p in zip(cfg.remainder, params.get("trailing", ())):
+            x = feed_forward(mix(x, kind, p["mixer"]), p)
         x = _norm(x, params["final_norm_scale"], None, "rmsnorm", cfg.norm_eps)
         head = params["embed"]["tokens"].T if cfg.tie_embeddings else params["lm_head"]
         logits = scaled(qmatmul(x, head.astype(x.dtype)), 1.0 / cfg.logits_scaling)
@@ -1034,6 +1151,41 @@ def granite_hybrid_config(size: str = "4.0-h-micro", **overrides) -> HybridMoECo
     if "layer_types" not in base:
         # layer_types: attention at 5 and then every tenth, mamba elsewhere
         base["layer_types"] = ["softmax" if i % 10 == 5 else "ssm" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)
+
+
+def lfm2_moe_config(size: str = "24b-a2b", **overrides) -> HybridMoEConfig:
+    """LFM2-24B-A2B (``LiquidAI/LFM2-24B-A2B`` ``config.json``, ``model_type:
+    lfm2_moe``): 40 layers, layers 2, 6, 10, ..., 38 causal GQA of 32 query
+    heads over 8 KV heads of 64 with an RMSNorm over each head's features of q
+    and of k before a rotation of all 64 (theta 1e6), the other 30 gated short
+    convolutions (``conv_L_cache`` 3 taps over the 2,048 channels of ``B *
+    x~``, no bias, no activation: the only thing a row carries is the last two
+    products); layers 0 and 1 a dense SwiGLU FFN of 11,776, layers 2-39 64
+    SwiGLU experts of 1,536, 4 a token by sigmoid scores with a selection
+    bias, gates normalised, no shared expert; the embedding tied to the head.
+    Behind the two leading layers the list is ``[attn conv conv conv] x 9 +
+    [attn conv]``: nine scanned periods and a remainder of two. ``24b-a2b`` is
+    the published model whole; ``tiny`` a toy of one chip's share (2 of 16
+    experts held) with the two leading layers, two periods and the remainder
+    for tests."""
+    presets = {
+        "tiny": dict(hidden_size=128, num_layers=12, num_heads=4, num_kv_heads=2, head_dim=32, vocab_size=512, max_seq_len=256,
+                     intermediate_size=96, expert_intermediate_size=32, num_experts=2, moe_router_experts=16,
+                     moe_expert_share=(0, 8), moe_top_k=4),
+        "24b-a2b": dict(hidden_size=2048, num_layers=40, num_heads=32, num_kv_heads=8, head_dim=64, vocab_size=65536,
+                        max_seq_len=128000, intermediate_size=11776, expert_intermediate_size=1536, num_experts=64, moe_top_k=4),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="rope", rope_theta=1e6, activation="swiglu", use_bias=False,
+        tie_embeddings=True, qk_norm="head", conv_kernel=3, leading_dense_layers=2, moe_layer_freq=1, moe_drop_tokens=False,
+        moe_norm_topk_prob=True, moe_scoring="sigmoid", moe_select_bias=True, moe_shared_experts=0, moe_routed_scaling=1.0,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        # layer_types: full_attention at 2 and then every fourth, conv elsewhere
+        base["layer_types"] = ["softmax" if i % 4 == 2 else "conv" for i in range(base["num_layers"])]
     return HybridMoEConfig(**base)
 
 

@@ -1,4 +1,6 @@
-"""What the serving suites of toy models share (the hybrid models' five, and
+"""What the serving suites of toy models share (the hybrid models' six: the
+newest, ``test_lfm2_serving.py``, a toy whose state kind keeps a convolution
+tail and no state, ``lfm2_moe_config("tiny")``; and
 ``test_run_ahead.py`` and ``test_fleet.py``, whose every test builds servers of
 one toy model): a model's ``init`` and its ``apply`` as ONE jitted program each,
 and the pair of fixtures that keeps a file's compiled programs until the

@@ -24,8 +24,18 @@ benchmark holds:
   call in each pass's scan, ``cache_layers`` a step), one a layer kind a
   period and leading layer in a model of several kinds, twice that in its
   wide program.
+
+And since PR 63 (a sixth layer kind whose mixer is a convolution alone, a norm a
+head on q and k, a partial last period run behind the scan), for the hybrid
+configurations accepted before it: the parameter tree of each is what it was
+(a fingerprint of paths, shapes and types recorded from the parent commit:
+``data/accepted_hybrid_trees.json``), none names the new kind, the norm or a
+remainder, and with none of the three a toy of each family traces to the same
+text whether the step knows of them or not (``..._trace_nothing``).
 """
 
+import dataclasses
+import hashlib
 import importlib
 import json
 import pathlib
@@ -94,11 +104,12 @@ def _lowered_step(v5e, monkeypatch, name, width):
             ring = window_ring_pages(cfg.window, page, paged["prefill_chunk"])
             rings = tuple(on(s) for s in hybrid_decode.window_shapes(cfg, rows, page, ring))
         latent = on((cfg.layers_of("latent"), pages, page, key_lanes(cfg.latent_width))) if cfg.layers_of("latent") else None
-        extra = (StateStore(on(shapes.state, jnp.float32), on(shapes.conv), *rings, latent),)
+        state = None if shapes.state is None else on(shapes.state, jnp.float32)  # a kind that keeps a tail alone has no state array
+        extra = (StateStore(state, on(shapes.conv), *rings, latent),)
     # the pool's own shapes: heads narrower than a lane tile share one (granite's 8 of 64, gpt2-125m's 12: two a page)
     v_head_dim = getattr(cfg, "v_head_dim", None) or cfg.head_dim
     f = heads_per_group(cfg.head_dim, v_head_dim, cfg.num_kv_heads)
-    assert f == {"granite-4.0-h-micro": 2, "gpt2-125m": 2}.get(name, 1)
+    assert f == {"granite-4.0-h-micro": 2, "gpt2-125m": 2, "lfm2-24b-a2b-ep8": 2}.get(name, 1)
     if f > 1:  # such a pool goes to the kernel that walks live pages: reaching the grid fallback would raise
         monkeypatch.setattr(sys.modules["deepspeed_tpu.ops.transformer.decode_attention"], "_ragged_by_grid", None)
     k_pool, v_pool = (on(s) for s in page_shapes(layers, pages, cfg.num_kv_heads, page, cfg.head_dim, v_head_dim, f))
@@ -131,8 +142,12 @@ def _expected_ragged_calls(cfg, width):
         # pass: the narrow program's passes are ONE traced body scanned num_loops times (the carried index runs on), the wide
         # program's have a body each (a pass's first cache layer is the body's own constant)
         return 1 if width == 1 else cfg.num_loops
-    kinds = list(cfg.layer_types[: cfg.leading_dense_layers]) + list(cfg.period)
-    return sum(k in ("softmax", "window") for k in kinds) * (1 if width == 1 else 2)
+    return sum(k in ("softmax", "window") for k in _traced_layers(cfg)) * (1 if width == 1 else 2)
+
+
+def _traced_layers(cfg):
+    """The layers whose bodies a step's program holds: the leading ones, ONE period (the scan's body), the trailing ones."""
+    return list(cfg.layer_types[: cfg.leading_dense_layers]) + list(cfg.period) + list(cfg.remainder)
 
 
 @pytest.mark.parametrize("width", [1, 128])
@@ -158,8 +173,7 @@ def test_attention_kernel_calls_are_what_the_benchmarks_readers_expect(v5e, monk
     if not getattr(cfg, "layer_types", None):
         assert ragged_paged_attention.calls_per_step(cache_layers(cfg)) == {"ragged": cfg.num_layers * cfg.num_loops}
     if latent_layers:
-        kinds = list(cfg.layer_types[: cfg.leading_dense_layers]) + list(cfg.period)
-        assert sum(k == "latent_paged_attention" for k, _ in calls) == kinds.count("latent") * (1 if width == 1 else 2)
+        assert sum(k == "latent_paged_attention" for k, _ in calls) == _traced_layers(cfg).count("latent") * (1 if width == 1 else 2)
 
 
 def test_the_guard_knows_every_configuration():
@@ -170,3 +184,84 @@ def test_the_guard_knows_every_configuration():
     for name in ACCEPTED:
         conf = files.load_json(files.ROOT, next(c["file"] for c in SPEC["configs"] if c["name"] == name))
         assert ("latent" in (conf["model"]["kwargs"].get("layer_types") or ())) == (name in LATENT)
+
+
+# --- PR 63: the conv kind, the head norm and the partial last period leave the accepted hybrid configurations alone -------------
+
+TREES = json.loads((pathlib.Path(__file__).parent / "data" / "accepted_hybrid_trees.json").read_text())["trees"]
+
+
+@pytest.mark.parametrize("name", sorted(TREES))
+def test_an_accepted_hybrid_configurations_tree_is_what_it_was(name):
+    """Paths, shapes and types of the adapter's ``init``, as recorded from the commit before the conv kind: no
+    ``trailing`` leaves, no head norm's scales, no seventh stack; and the config names none of the three."""
+    conf = files.load_json(files.ROOT, next(c["file"] for c in SPEC["configs"] if c["name"] == name))
+    model, _ = files.build_model(conf)
+    cfg = model.config
+    assert cfg.remainder == () and cfg.qk_norm is None and "conv" not in cfg.layer_types
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), None))
+    assert "trailing" not in shapes and "conv" not in shapes["periods"]
+    tree = sorted((jax.tree_util.keystr(path), tuple(a.shape), str(a.dtype)) for path, a in jax.tree_util.tree_flatten_with_path(shapes)[0])
+    assert hashlib.sha256(repr(tree).encode()).hexdigest()[:16] == TREES[name]
+
+
+def _toy_step_text(cfg):
+    """The jaxpr of a toy's narrow step (``hybrid_forward``, four rows)."""
+    from deepspeed_tpu.inference.kv_pool import PagePool
+    from deepspeed_tpu.models.hybrid_moe import HybridMoETransformerLM
+
+    params = jax.eval_shape(lambda: HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    pool = PagePool(cfg, 9, 8, 4, max_seq_len=16, dtype=jnp.float32, prefill_chunk=8)
+    i32 = lambda *shape: jnp.zeros(shape, I32)
+    store = pool.states
+    forward = lambda p: hybrid_decode.hybrid_forward(
+        cfg, p, i32(4, 1), pool.cache.k_pages, pool.cache.v_pages, store.state, store.conv, i32(4, 2), i32(4), i32(4), i32(4), attn_impl="xla",
+        window=None if store.window_k is None else (store.window_k, store.window_v), latent=store.latent,
+    )
+    return str(jax.make_jaxpr(forward)(params))
+
+
+@pytest.mark.parametrize("family", ["solar_open2_config", "laguna_config", "granite_hybrid_config"])
+def test_with_no_conv_layer_no_head_norm_and_no_remainder_the_new_code_traces_nothing(family, monkeypatch):
+    """An accepted family's toy (delta-rule layers beside NoPE attention; rotary
+    and window layers with a head gate behind a leading layer; state-space
+    layers with a dense FFN) traces to the same text whether ``attn_heads``
+    knows of the head norm or is what it was before (below, verbatim), and
+    with every function of the conv kind and the trailing layers' FFN made to
+    raise; with the norm set, or one more layer that makes a remainder, the
+    text grows."""
+    from deepspeed_tpu.models import hybrid_moe as hm
+    from deepspeed_tpu.models.transformer import _rope
+
+    cfg = getattr(hm, family)("tiny", dtype="float32")
+    assert cfg.remainder == () and cfg.qk_norm is None and cfg.state_kind != "conv"
+    traced = _toy_step_text(cfg)
+
+    def attn_heads_before(cfg, kind, q, k, v, positions):
+        NH, NKV, D, Dv = cfg.heads_of(kind), cfg.kv_heads_of(kind), cfg.head_dim, cfg.v_head_dim
+        q, k, v = (a.reshape(a.shape[:-1] + shape) for a, shape in zip((q, k, v), ((NH, D), (NKV, D), (NKV, Dv))))
+        if cfg.position == "rope":
+            scaled = cfg.rope_frequencies(kind)
+            if scaled is not None:
+                q, k = (hm._rope_scaled(a, positions, *scaled) for a in (q, k))
+            else:
+                theta = cfg.window_rope_theta if kind == "window" else cfg.rope_theta
+                q, k = (_rope(a, positions, theta, cfg.rope_dim_of(kind)) for a in (q, k))
+        if cfg.attn_value_scale != 1.0:
+            v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
+        return q, k, v
+
+    def not_this_model(*args, **kwargs):
+        raise AssertionError("a function of the conv kind was traced for a model without a conv layer")
+
+    monkeypatch.setattr(hm, "attn_heads", lambda cfg, kind, q, k, v, positions, p=None: attn_heads_before(cfg, kind, q, k, v, positions))
+    for name in ("conv_inputs", "gated_conv", "shifted_tail", "conv_output"):
+        monkeypatch.setattr(hm, name, not_this_model)
+    assert _toy_step_text(cfg) == traced
+    monkeypatch.undo()
+    if "softmax" in cfg.layer_types and family != "granite_hybrid_config":
+        assert len(_toy_step_text(dataclasses.replace(cfg, qk_norm="head"))) > len(traced)
+    # at least two whole periods and one layer more: the scan's body is the same, and one trailing layer's is traced behind it
+    types = cfg.layer_types[: cfg.leading_dense_layers] + cfg.period * max(cfg.num_periods, 2) + cfg.period[:1]
+    longer = dataclasses.replace(cfg, num_layers=len(types), layer_types=types)
+    assert longer.remainder == cfg.period[:1] and longer.period == cfg.period and len(_toy_step_text(longer)) > len(traced)
