@@ -858,6 +858,18 @@ def token_tiles(cfg, rows: int, width: int, live_tokens: int) -> int:
     return -(-int(live_tokens) // tile)
 
 
+def routed_rows(cfg, rows: int, width: int) -> int:
+    """Tokens ONE call of a routed FFN takes in the ``rows x width`` ragged
+    program: a token tile where the window is tiled, else its whole slab; 0
+    for a model that routes nothing through ``moe/routed_ffn.py`` (no experts,
+    or capacity routing). The engine records the assignment plan's form at
+    these sizes where it builds the server (``moe.route_plan``)."""
+    if not (getattr(cfg, "num_experts", 0) and getattr(cfg, "moe_top_k", 0)) or cfg.moe_drop_tokens:
+        return 0
+    tile = token_tile(cfg)
+    return tile if tile and rows * width > tile else rows * width
+
+
 class _Packed(NamedTuple):
     """A ragged window's live tokens, row after row, at the front of a
     buffer of whole tiles."""

@@ -749,6 +749,7 @@ class InferenceEngine:
             metrics=self.metrics,
             tp=tp_ctx,
         )
+        self._record_route_plan(server)
         if recovered_states:
             server.recover(recovered_states, next_uid)
         tcfg = self._config.traffic
@@ -762,6 +763,21 @@ class InferenceEngine:
                 server, tenants=[t.model_dump() for t in tcfg.tenants]
             )
         return server
+
+    def _record_route_plan(self, server) -> None:
+        """Which form a routed layer's assignment plan takes in the server's
+        programs (``moe/route_plan.py::plan_path``, the question ``route_plan``
+        itself asks, at the tokens one call routes in the narrow and in the
+        mixed program), said once a shape where the server is built (the ops
+        have no tracer): nothing in a step. As the training engine says
+        ``flash.operand_layout``."""
+        from deepspeed_tpu.inference.decode import routed_rows
+        from deepspeed_tpu.moe.route_plan import plan_path
+
+        cfg = self._ds_config
+        width = getattr(cfg, "moe_router_experts", None) or getattr(cfg, "num_experts", 0)
+        for tokens in sorted({routed_rows(cfg, server.pool.max_slots, w) for w in (server._ragged_w_decode, server._ragged_w_mixed)} - {0}):
+            self.tracer.event("moe.route_plan", **plan_path(tokens, width, cfg.moe_top_k))
 
     def serve(self, prompts, max_new_tokens=32, eos_token_id=None):
         """Continuous-batching greedy generation over the paged KV pool:

@@ -1,4 +1,4 @@
-"""The dropless top-k routed FFN: tokens sorted by expert, experts
+"""The dropless top-k routed FFN: tokens in expert order, experts
 multiplied group by group.
 
 This is what ``moe_drop_tokens=False`` means, for training
@@ -11,10 +11,12 @@ work. Here every (token, expert) assignment is one row:
 1. ``route``: softmax over ALL experts in float32, the k largest, their
    gates renormalised to one only where the model says so
    (``norm_topk_prob``: Mixtral-style presets yes, OLMoE no);
-2. the ``S k`` assignments are sorted by expert (stable, so a token's rows
-   keep their order); an assignment of a dead token (``live`` false: a
-   padding slot of a serving window) sorts behind every expert and belongs
-   to no group, so it costs no expert work;
+2. the ``S k`` assignments are put in expert order (a token's rows keep
+   their order): on a TPU by COUNTING, steps 1 and 2 in one kernel call
+   (``moe/route_plan.py``, ``moe_route_plan``, up to a token tile), elsewhere
+   by two stable sorts; an assignment of
+   a dead token (``live`` false: a padding slot of a serving window) lies
+   behind every expert and belongs to no group, so it costs no expert work;
 3. gate/up, activation and down projection run as grouped matmuls over the
    ``[E, H, I]`` stacks with the group sizes as data
    (``moe/grouped_matmul.py``): a shifting routing mix compiles nothing;
@@ -32,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.moe.grouped_matmul import grouped_matmul
+from deepspeed_tpu.moe.route_plan import route_plan, scores, top_k_route
 
 
 def route(logits: jnp.ndarray, k: int, norm_topk_prob: Optional[bool], select_logits: Optional[jnp.ndarray] = None,
@@ -47,22 +50,17 @@ def route(logits: jnp.ndarray, k: int, norm_topk_prob: Optional[bool], select_lo
     E = logits.shape[-1]
     if not 1 <= k <= E:
         raise ValueError(f"top-k routing needs 1 <= k <= num_experts, got k={k} of {E}")
-    if norm_topk_prob is None:
-        norm_topk_prob = k > 1
-    if scoring == "softmax":
-        gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    elif scoring == "sigmoid":
-        gates = jax.nn.sigmoid(logits.astype(jnp.float32))
-    else:
-        raise ValueError(f"unknown router scoring {scoring!r}; expected softmax|sigmoid")
-    select = gates if select_logits is None else select_logits
-    if select_bias is not None:
-        select = select + select_bias.astype(jnp.float32)
-    _, experts = jax.lax.top_k(select, k)
-    chosen = jnp.take_along_axis(gates, experts, axis=-1)
-    if norm_topk_prob:
-        chosen = chosen / jnp.clip(jnp.sum(chosen, axis=-1, keepdims=True), min=jnp.finfo(jnp.float32).eps)
-    return gates, experts.astype(jnp.int32), chosen
+    return top_k_route(logits, k, k > 1 if norm_topk_prob is None else norm_topk_prob, select_logits, scoring, select_bias)
+
+
+def rows_at(x: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
+    """``x[index]`` for a plan's places (``index`` [...] in ``0 .. len(x)``,
+    by construction): nothing to wrap and nothing to clamp, which ``x[index]``
+    does in two fusions before each gather."""
+    return jax.lax.gather(
+        x, index[..., None], jax.lax.GatherDimensionNumbers(offset_dims=(index.ndim,), collapsed_slice_dims=(0,), start_index_map=(0,)),
+        slice_sizes=(1, x.shape[1]), mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
 
 
 def _expert_matmul(rows, w, sizes, row_expert, group_offset, out_dtype, transposed: bool = False):
@@ -116,21 +114,16 @@ def routed_ffn(
     Returns ``(out [S, H] in tokens' dtype, counts [E] int32, gates [S, E])``."""
     from deepspeed_tpu.moe.experts import _pointwise_activation
 
-    S, H = tokens.shape
-    E = logits.shape[-1]
     dt = tokens.dtype
     with jax.named_scope("moe_route"):
-        gates, chosen, weights = route(logits, k, norm_topk_prob, select_logits, scoring, select_bias)
-        flat = chosen.reshape(-1)
-        if held is not None:
-            first, E = held
-            flat = jnp.where((flat >= first) & (flat < first + E), flat - first, E)
-        if live is not None:
-            flat = jnp.where(jnp.repeat(live, k), flat, E)
-        counts = jnp.sum(flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
-        order = jnp.argsort(flat, stable=True)
-        row_expert = group_offset + jnp.minimum(flat[order], E - 1)
-        rows = tokens[order // k]
+        plan = route_plan(
+            logits, k=k, norm_topk_prob=norm_topk_prob, scoring=scoring, select_logits=select_logits,
+            select_bias=select_bias, live=live, held=held,
+        )
+        gates = scores(logits, scoring)  # for the caller's auxiliary loss; nothing in a program that drops them
+        counts = plan.counts
+        row_expert = group_offset + plan.row_expert
+        rows = rows_at(tokens, plan.src)
     with jax.named_scope("moe_experts"):
         if activation in ("swiglu", "geglu"):
             gate = _expert_matmul(rows, experts["w_gate"], counts, row_expert, group_offset, dt)
@@ -148,14 +141,11 @@ def routed_ffn(
         if "b_out" in experts:
             out_rows = out_rows + experts["b_out"].astype(jnp.float32)[row_expert]
     with jax.named_scope("moe_route"):
-        # back to token order; a dead assignment's row was never computed
-        back = jnp.argsort(order).reshape(S, k)
-        if held is not None:
-            routed = (flat < E).reshape(S, k)  # held, and of a live token
-        else:
-            routed = jnp.ones((S, k), bool) if live is None else jnp.broadcast_to(live[:, None], (S, k))
-        per_choice = jnp.where(routed[..., None], out_rows[back], 0.0)
-        out = jnp.sum(per_choice * weights[..., None], axis=1).astype(dt)
+        # back to token order; the row of a dead or not-held assignment lies behind every group and was never computed
+        per_choice = rows_at(out_rows, plan.dest)  # [k, S, H]
+        if held is not None or live is not None:
+            per_choice = jnp.where(plan.routed[..., None] != 0, per_choice, 0.0)
+        out = jnp.sum(per_choice * plan.weights[..., None], axis=0).astype(dt)
     return out, counts, gates
 
 

@@ -77,7 +77,7 @@ def v5e():
 def _lowered_step(v5e, monkeypatch, name, width):
     """(config, the StableHLO text of its ragged step at its own sizes)."""
     for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.latent_attention",
-                   "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.ops.transformer.linear_attention",
+                   "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.moe.route_plan", "deepspeed_tpu.ops.transformer.linear_attention",
                    "deepspeed_tpu.ops.transformer.state_space"):
         importlib.import_module(module)
         if hasattr(sys.modules[module], "on_tpu"):  # NOT via attribute access: ops/transformer rebinds names

@@ -200,6 +200,27 @@ def _server(lm, params, **kw):
     return eng
 
 
+@pytest.mark.parametrize("on_a_tpu, paths", [(True, ["kernel", "kernel"]), (False, ["sorted", "sorted"])], ids=["tpu", "cpu"])
+def test_the_engine_records_the_route_plans_form_where_it_builds_the_server(toy, monkeypatch, on_a_tpu, paths):
+    """``moe.route_plan``, once a shape: the narrow program routes its 64
+    rows in one call, the mixed one (64 x 16 slots, above a token tile) a
+    tile of 512 at a time; the path is ``plan_path``'s, the question
+    ``route_plan`` asks where the programs are traced. Nothing is compiled."""
+    from deepspeed_tpu.moe import route_plan
+
+    cfg, lm, params, _ = toy
+    monkeypatch.setattr(route_plan, "on_tpu", lambda: on_a_tpu)
+    eng = _server(lm, params, max_slots=64)
+    eng._build_paged_server()
+    events = [s["attrs"] for s in eng.tracer.spans() if s["name"] == "moe.route_plan"]
+    assert events == [
+        {"path": paths[0], "S": 64, "E": cfg.moe_router_experts, "k": cfg.moe_top_k, "blocks": int(on_a_tpu)},
+        {"path": paths[1], "S": 512, "E": cfg.moe_router_experts, "k": cfg.moe_top_k, "blocks": int(on_a_tpu)},
+    ]
+    assert decode.routed_rows(cfg, 64, 16) == decode.token_tile(cfg) == 512 and decode.routed_rows(cfg, 4, 16) == 64
+    assert eng.compile_stats() == {}
+
+
 def test_the_engine_serves_it_with_two_programs_and_preemption_changes_nothing(toy):
     """``init_inference`` -> ``serve``: two compiled programs, the held and
     all routed assignments counted, the state store in the memory report;

@@ -3,6 +3,8 @@
 at ``capacity = S``, the kernel (interpret mode) against ``ragged_dot``,
 dead tokens, gradients, int8 experts, and what must raise."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -189,3 +191,214 @@ class TestMoELayer:
         layer = MoE(H, num_experts=E, k=2, drop_tokens=False, intermediate_size=I)
         with pytest.raises(NotImplementedError, match="expert-parallel"):
             layer.apply(layer.init(jax.random.PRNGKey(0)), jnp.zeros((2, 8, H)), train=False)
+
+
+# --- the assignment plan (``moe/route_plan.py``) ---------------------------------
+
+# (S, E, k, held, live, scoring, bias, ties): the eight routed cells' narrow steps, the unit tests' own width, and slabs
+# of two token blocks (the second with a last block of 8 tokens)
+_PLAN_CASES = {
+    "lfm2_glm": (64, 64, 4, (0, 8), True, "sigmoid", True, False),
+    "laguna": (64, 256, 10, (16, 16), True, "softmax", False, False),
+    "mimo": (64, 256, 8, (240, 16), True, "sigmoid", True, False),
+    "solar": (64, 320, 8, (40, 40), True, "sigmoid", True, False),
+    "kimi": (64, 256, 8, (32, 32), False, "sigmoid", True, False),
+    "nemotron": (64, 128, 6, (64, 64), True, "sigmoid", True, False),
+    "olmoe": (16, 64, 8, None, True, "softmax", False, False),
+    "unit": (48, 8, 3, None, False, "softmax", False, False),
+    "unit_every_expert": (48, 8, 8, None, True, "softmax", False, False),
+    "tied_scores": (64, 64, 8, (8, 24), True, "softmax", False, True),
+    "tied_biased_scores": (16, 8, 3, None, False, "sigmoid", True, True),
+    "olmoe_token_tile": (1024, 64, 8, None, True, "softmax", False, False),
+    "ragged_last_block": (520, 16, 3, (4, 8), True, "sigmoid", True, True),
+}
+
+
+def _numpy_plan(logits, k, scoring, select_bias, live, held):
+    """The plan by numpy's stable sorts, on the float32 scores ``route`` takes
+    its choice from: chosen [S, k] (the largest first, the lowest index among
+    equals), dest [S, k], routed [S, k], counts [n], row_expert [S k], src [S k]."""
+    from deepspeed_tpu.moe.route_plan import scores
+
+    select = np.asarray(scores(logits, scoring))
+    if select_bias is not None:
+        select = select + np.asarray(select_bias, np.float32)
+    chosen = np.argsort(-select, axis=1, kind="stable")[:, :k]
+    n, buckets = logits.shape[1], chosen
+    if held is not None:
+        first, n = held
+        buckets = np.where((chosen >= first) & (chosen < first + n), chosen - first, n)
+    if live is not None:
+        buckets = np.where(np.asarray(live)[:, None], buckets, n)
+    flat = buckets.reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    dest = np.empty_like(order)
+    dest[order] = np.arange(order.size)
+    return chosen, dest.reshape(buckets.shape), buckets < n, np.bincount(flat, minlength=n + 1)[:n], np.minimum(flat[order], n - 1), order // k
+
+
+@pytest.mark.parametrize("impl", ["sorted", "pallas_interpret"])
+@pytest.mark.parametrize("case", _PLAN_CASES)
+def test_plan_equals_the_sorted_forms(case, impl):
+    """The chosen experts, each assignment's row, the counts, each sorted
+    row's expert and token, of the kernel and of the form that runs where it
+    does not: integer for integer what numpy's stable sorts give (tied
+    scores: the lowest index first, as ``lax.top_k``), and the kernel's
+    weights the sorted form's to float32 rounding."""
+    from deepspeed_tpu.moe.route_plan import route_plan
+
+    rows, width, k, held, with_live, scoring, with_bias, ties = _PLAN_CASES[case]
+    rng = np.random.default_rng(len(case))
+    logits = 2.0 * rng.standard_normal((rows, width))
+    if ties:
+        logits = np.round(logits)  # whole numbers: many a token's equal scores
+    logits = jnp.asarray(logits, jnp.float32)
+    static = dict(
+        k=k, norm_topk_prob=True, scoring=scoring, held=held,
+        select_bias=jnp.asarray(np.round(rng.standard_normal(width)) if ties else 0.1 * rng.standard_normal(width), jnp.float32) if with_bias else None,
+        live=jnp.asarray(rng.random(rows) < 0.7) if with_live else None,
+    )
+    got = route_plan(logits, impl=impl, **static)
+    chosen, dest, routed, counts, row_expert, src = _numpy_plan(logits, k, scoring, static["select_bias"], static["live"], held)
+    for name, want in (("chosen", chosen.T), ("dest", dest.T), ("routed", routed.T), ("counts", counts), ("row_expert", row_expert), ("src", src)):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)), want, err_msg=name)
+    np.testing.assert_allclose(np.asarray(got.weights), np.asarray(route_plan(logits, impl="sorted", **static).weights), rtol=2e-6, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(got.weights).sum(0), 1.0, rtol=1e-5)  # ``norm_topk_prob``
+    assert sorted(np.asarray(got.dest).reshape(-1)) == list(range(rows * k))  # a permutation: every row has one assignment
+
+
+@pytest.mark.parametrize("noise", ["RSample", None], ids=["noise_picks", "gates_pick"])
+def test_dropless_layers_gradient_is_the_sorted_forms(noise, monkeypatch):
+    """``jax.grad`` through ``MoE.apply`` (dropless; training's noise picking
+    the experts, or the gates themselves) with the kernel's plan is the
+    gradient with the sorted form's, the parent's: the plan's integers carry
+    nothing, the weights' gradient is the ``jnp`` formulas' at the chosen
+    experts."""
+    mesh_mod.reset_topology()
+    layer = MoE(H, num_experts=E, k=3, drop_tokens=False, intermediate_size=I, activation="swiglu", use_bias=False, noisy_gate_policy=noise)
+    params = layer.init(jax.random.PRNGKey(10))
+    x = jax.random.normal(jax.random.PRNGKey(11), (4, 12, H))
+
+    def grads(form):
+        monkeypatch.setattr(routed_ffn_module, "route_plan", lambda *a, impl="auto", **kw: plan(*a, impl=form, **kw))
+
+        def loss(p):
+            out, l_aux, _ = layer.apply(p, x, train=True, rng=jax.random.PRNGKey(12))
+            return jnp.sum(out**2) + 0.1 * l_aux
+
+        return jax.jit(jax.grad(loss))(params)
+
+    plan = routed_ffn_module.route_plan
+    got, want = grads("pallas_interpret"), grads("sorted")
+    assert float(jnp.abs(want["gate"]["wg"]).max()) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5 * float(jnp.abs(b).max()), rtol=1e-5)
+    mesh_mod.reset_topology()
+
+
+@pytest.mark.parametrize(
+    "on_a_tpu, tokens, want",
+    [(True, 64, ("kernel", 1)), (True, 1024, ("kernel", 2)), (True, 520, ("kernel", 2)), (True, 8, ("kernel", 1)),
+     (True, 2048, ("sorted", 0)), (True, 60, ("sorted", 0)), (False, 64, ("sorted", 0))],
+)
+def test_the_plans_path_follows_the_shape_and_the_backend(on_a_tpu, tokens, want, monkeypatch):
+    """``plan_path``: what ``auto`` takes, and what an engine records as ``moe.route_plan``."""
+    from deepspeed_tpu.moe import route_plan
+
+    monkeypatch.setattr(route_plan, "on_tpu", lambda: on_a_tpu)
+    assert route_plan.plan_path(tokens, 64, 4) == {"path": want[0], "S": tokens, "E": 64, "k": 4, "blocks": want[1]}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One chip of a described ``v5e:2x2`` (a compile, not a run); the
+    persistent compile cache is off around it (what is compiled for a
+    described chip can never be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize(
+    "rows, width, k, held, hidden, inner",
+    [(64, 64, 4, (0, 8), 2048, 1536), (64, 256, 10, (0, 16), 3072, 1024), (512, 64, 4, (0, 8), 2048, 1536)],
+    ids=["lfm2_narrow", "laguna_narrow", "lfm2_token_tile"],
+)
+def test_a_compiled_routed_layer_sorts_nothing(v5e, monkeypatch, rows, width, k, held, hidden, inner):
+    """``routed_ffn`` as a serving step calls it (``held``, ``live``, sigmoid
+    with a bias), compiled for a v5e at LFM2's and Laguna's narrow shapes and
+    at a token tile's: on a TPU the plan is the ``moe_route_plan`` kernel, and
+    the program holds no ``sort`` at all."""
+    import sys
+
+    for module in ("deepspeed_tpu.moe.route_plan", "deepspeed_tpu.moe.grouped_matmul"):
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(experts, tokens, logits, bias, live):
+        return routed_ffn_module.routed_ffn(
+            experts, tokens, logits, k=k, activation="swiglu", norm_topk_prob=True, live=live, scoring="sigmoid", select_bias=bias, held=held
+        )[:2]
+
+    experts = {"w_gate": on_v5e((held[1], hidden, inner), jnp.bfloat16), "w_up": on_v5e((held[1], hidden, inner), jnp.bfloat16), "w_out": on_v5e((held[1], inner, hidden), jnp.bfloat16)}
+    text = jax.jit(layer).lower(
+        experts, on_v5e((rows, hidden), jnp.bfloat16), on_v5e((rows, width), jnp.float32), on_v5e((width,), jnp.float32), on_v5e((rows,), bool)
+    ).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and "moe_route/moe_route_plan" in line]
+    assert len(kernels) == 1, kernels
+    assert not re.findall(r"^\s*(?:ROOT )?%[\w.-]+ = \S+ sort\(", text, flags=re.M), "a sort in a routed layer"
+
+
+@pytest.mark.parametrize(
+    "rows, width, k, held",
+    [(8, 64, 8, None), (8, 8, 2, None), (24, 64, 4, (0, 8)), (40, 320, 8, (40, 40)), (72, 256, 10, (16, 16)), (520, 64, 4, (0, 8)), (1000, 64, 8, None)],
+    ids=["8_olmoe", "8_of_8", "24_lfm2", "40_solar", "72_laguna", "520_lfm2", "1000_olmoe"],
+)
+def test_the_kernel_compiles_for_a_v5e_at_the_sizes_it_admits(v5e, rows, width, k, held):
+    """``kernel_fits`` admits any whole sublanes of tokens up to 1,024: the
+    fewest, sizes that are no multiple of 16 (one block of that many tokens)
+    and a ragged last block all pass Mosaic (their RESULTS are held to the
+    sorted form on the chip by ``tools/route_plan_bench.py``)."""
+    from deepspeed_tpu.moe.route_plan import kernel_fits, route_plan
+
+    assert kernel_fits(rows, width, k)
+
+    def plan(logits, bias, live):
+        return route_plan(logits, k=k, norm_topk_prob=True, scoring="sigmoid", select_bias=bias, live=live, held=held, impl="kernel")
+
+    text = jax.jit(plan).lower(
+        jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=v5e), jax.ShapeDtypeStruct((width,), jnp.float32, sharding=v5e),
+        jax.ShapeDtypeStruct((rows,), bool, sharding=v5e),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "moe_route_plan" in text
+
+
+def test_route_plan_bench_rehearses():
+    """``tools/route_plan_bench.py --rehearse``: the tool's control flow, tiny, on the CPU: the kernel held to the
+    sorted form at every shape first, then a line a window, two forms each."""
+    import json
+    import pathlib
+    import subprocess
+    import sys
+
+    tool = pathlib.Path(__file__).parents[3] / "tools" / "route_plan_bench.py"
+    done = subprocess.run([sys.executable, str(tool), "--rehearse"], capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    lines = [json.loads(line) for line in done.stdout.splitlines() if line.startswith("{")]
+    assert lines[0]["check"] == "kernel == sorted" and lines[0]["failed"] == [] and [shape[1] for shape in lines[0]["shapes"]] == [16, 64, 8, 24]
+    assert [(line["cell"], line["window"], line["device"]) for line in lines[1:]] == [("tiny", "narrow", "cpu"), ("tiny", "mixed", "cpu")]
+    for line in lines[1:]:  # times less the empty loop's: on the CPU, two calls, of any sign
+        assert all(isinstance(line[form][key], float) for form in ("overhead_us", "sorted", "kernel") for key in ("plan_us", "block_us"))

@@ -489,7 +489,10 @@ def _slab_sized_fills(text, slots, lanes=512):
 # temporaries (224,022,016 before), with the same 16 kernel calls. The three others trace no line of a linear layer.
 # PR 51 meant to change MiMo's and Laguna's: their window layers' one-token rows go a block of 8 a grid step of the
 # ragged kernel (``_ragged_block_kernel``), the same 25 and 17 kernel calls (6,880,256 and 5,070,848 bytes before).
-NARROW_PROGRAMS = {"solar": (16, 5_343_232), "mimo": (25, 6_279_680), "glm": (5, 2_549_248), "laguna": (17, 4_812_800)}
+# PR 64 meant to change all four: a routed layer's plan is the ``moe_route_plan`` kernel (``moe/route_plan.py``: one
+# more call a routed layer body: 16, 25, 5 and 17 before) and the sorts' pairs and the token-major copy of the
+# gathered rows are gone from the temporaries (5,343,232 / 6,279,680 / 2,549,248 / 4,812,800 before).
+NARROW_PROGRAMS = {"solar": (20, 4_667_904), "mimo": (31, 5_338_112), "glm": (6, 2_483_200), "laguna": (21, 4_328_960)}
 
 
 def _narrow_program(text, memory):
@@ -627,7 +630,7 @@ def test_olmoe_ragged_step_fits_and_names_its_kernels(v5e, monkeypatch, width):
     accepted readers tell it by that signature
     (``benchmark/kernels/ragged_paged_attention.py``), and ``jax.lax.ragged_dot``'s
     own lowering, which opens with five, would be counted as one."""
-    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul"):
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.moe.route_plan"):
         monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
     conf = json.loads(_OLMOE_CELL.read_text())
     paged = conf["engine"]["init_inference"]["paged_kv"]
@@ -711,7 +714,7 @@ def test_solar_open2_ragged_step_fits_and_keeps_its_four_pools_in_place(v5e, mon
     from deepspeed_tpu.inference.kv_pool import StateStore
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
 
-    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul",
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.moe.route_plan",
                    "deepspeed_tpu.ops.transformer.linear_attention"):
         monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
     conf = json.loads(_SOLAR_CELL.read_text())
@@ -781,7 +784,7 @@ def test_mimo_v2_ragged_step_fits_and_every_attention_layer_walks_live_pages(v5e
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
     from deepspeed_tpu.ops.transformer import decode_attention
 
-    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul"):
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.moe.route_plan"):
         monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
     monkeypatch.setattr(decode_attention, "_ragged_by_grid", None)  # reaching it would raise
     conf = json.loads(_MIMO_CELL.read_text())
@@ -857,7 +860,7 @@ def test_glm47_flash_ragged_step_fits_and_keeps_one_latent_pool_in_place(v5e, mo
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
 
     for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.latent_attention",
-                   "deepspeed_tpu.moe.grouped_matmul"):
+                   "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.moe.route_plan"):
         __import__(module)
         monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
     conf = json.loads(_GLM_CELL.read_text())
@@ -925,7 +928,7 @@ def test_laguna_step_reads_its_mixers_matrices_where_they_lie(v5e, monkeypatch, 
     from deepspeed_tpu.inference.kv_pool import StateStore, key_lanes, window_ring_pages
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
 
-    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul"):
+    for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.moe.route_plan"):
         monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
     conf = json.loads(_LAGUNA_CELL.read_text())
     paged = conf["engine"]["init_inference"]["paged_kv"]
@@ -990,7 +993,7 @@ def test_kimi_linear_step_fits_with_state_and_latent_pages_in_place(v5e, monkeyp
     from deepspeed_tpu.models.hybrid_moe import HybridMoEConfig, HybridMoETransformerLM
 
     for module in ("deepspeed_tpu.ops.transformer.decode_attention", "deepspeed_tpu.ops.transformer.latent_attention",
-                   "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.ops.transformer.linear_attention"):
+                   "deepspeed_tpu.moe.grouped_matmul", "deepspeed_tpu.moe.route_plan", "deepspeed_tpu.ops.transformer.linear_attention"):
         __import__(module)
         monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
     conf = json.loads(_KIMI_CELL.read_text())
