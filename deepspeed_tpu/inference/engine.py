@@ -766,11 +766,13 @@ class InferenceEngine:
 
     def _record_route_plan(self, server) -> None:
         """Which form a routed layer's assignment plan takes in the server's
-        programs (``moe/route_plan.py::plan_path``, the question ``route_plan``
-        itself asks, at the tokens one call routes in the narrow and in the
-        mixed program), said once a shape where the server is built (the ops
-        have no tracer): nothing in a step. As the training engine says
-        ``flash.operand_layout``."""
+        programs, and with it the layer's two ways between token order and
+        expert order (``combine``: ``live_rows`` | ``gather``,
+        ``moe/live_rows.py``): ``moe/route_plan.py::plan_path``, the question
+        ``routed_ffn`` itself asks, at the tokens one call routes in the
+        narrow and in the mixed program, said once a shape where the server
+        is built (the ops have no tracer): nothing in a step. As the training
+        engine says ``flash.operand_layout``."""
         from deepspeed_tpu.inference.decode import routed_rows
         from deepspeed_tpu.moe.route_plan import plan_path
 

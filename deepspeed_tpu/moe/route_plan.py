@@ -72,7 +72,11 @@ class RoutePlan(NamedTuple):
     its row in expert order; ``routed`` int32, 1 where that row belongs to a
     group (a held expert's, of a live token). ``counts`` [n] int32 (the held experts' alone, live tokens
     only); ``row_expert`` [S k] int32, each sorted row's expert (``n - 1``
-    behind the groups); ``src`` [S k] int32, each sorted row's token."""
+    behind the groups); ``src`` [S k] int32, each sorted row's token;
+    ``row_weight`` [S k] float32, the gate of the assignment that lies at
+    each sorted row (``row_weight[dest] == weights``): defined for the
+    ``sum(counts)`` rows of the groups, ANYTHING behind them (``sorted``: the
+    gate all the same; the kernel: whatever the memory held)."""
 
     weights: jnp.ndarray
     chosen: jnp.ndarray
@@ -81,6 +85,7 @@ class RoutePlan(NamedTuple):
     counts: jnp.ndarray
     row_expert: jnp.ndarray
     src: jnp.ndarray
+    row_weight: jnp.ndarray
 
 
 class _Spec(NamedTuple):
@@ -145,14 +150,15 @@ def buckets_of(chosen: jnp.ndarray, num_experts: int, held, live) -> Tuple[jnp.n
 
 def sorted_plan(buckets: jnp.ndarray, n: int):
     """``buckets`` [S, k] in ``0 .. n`` -> ``(dest [S, k], counts [n],
-    row_expert [S k], src [S k])`` by two stable sorts: the form ``routed_ffn``
-    had until PR 64, what the kernel is held to, and what runs where the
-    kernel does not (``plan_path``)."""
+    row_expert [S k], order [S k])`` by two stable sorts, ``order`` each sorted
+    row's assignment ``s k + j`` (its token is ``order // k``): the form
+    ``routed_ffn`` had until PR 64, what the kernel is held to, and what runs
+    where the kernel does not (``plan_path``)."""
     S, k = buckets.shape
     flat = buckets.reshape(-1)
     counts = jnp.sum(flat[:, None] == jnp.arange(n, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
     order = jnp.argsort(flat, stable=True)
-    return jnp.argsort(order).reshape(S, k).astype(jnp.int32), counts, jnp.minimum(flat[order], n - 1), (order // k).astype(jnp.int32)
+    return jnp.argsort(order).reshape(S, k).astype(jnp.int32), counts, jnp.minimum(flat[order], n - 1), order.astype(jnp.int32)
 
 
 # --- the kernel -----------------------------------------------------------------
@@ -166,8 +172,16 @@ def _digits(x):
 
 
 def _nt(a, b):
-    """``a b^T`` on the MXU with float32 sums."""
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    """``a b^T`` of two bfloat16 arrays on the MXU with float32 sums."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
+
+
+def bf16_dot(a, b):
+    """``a b`` of two bfloat16 arrays on the MXU with float32 sums: ONE pass,
+    whatever ``jax_default_matmul_precision`` says (under ``highest``, which
+    the float32 logits tools set, Mosaic refuses bfloat16 operands: "Bad lhs
+    type"); the operands here are exact in bfloat16 by construction."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
 
 
 def _geometry(spec: _Spec):
@@ -179,19 +193,25 @@ def _geometry(spec: _Spec):
     return TB, -(-spec.S // TB), -(-(n + 1) // 128) * 128, Rp, RB
 
 
+def bf16_parts(x):
+    """A float32 array as three bfloat16 arrays whose sum it is (eight bits
+    of its 24 each): a product of them with zeros and ones, summed in
+    float32, moves any float32 through the MXU exactly."""
+    hi = x.astype(jnp.bfloat16)
+    mid = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    lo = (x - hi.astype(jnp.float32) - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
 def _choice_major(x, rows: int, whole_numbers: bool):
     """``x`` [TB, 128] float32 with choice j in lane j -> ``[rows, TB]``: a
     product with rows of the identity, every sum of one term, exact for whole
-    numbers below 2**16 (two digits) and for any float32 (its three bfloat16
-    parts)."""
+    numbers below 2**16 (two digits) and for any float32 (``bf16_parts``)."""
     pick = (jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) == jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)).astype(jnp.bfloat16)
     if whole_numbers:
         hi, lo = _digits(x)
         return 256.0 * _nt(pick, hi) + _nt(pick, lo)
-    hi = x.astype(jnp.bfloat16)
-    mid = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32) - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    return _nt(pick, hi) + _nt(pick, mid) + _nt(pick, lo)
+    return sum(_nt(pick, part) for part in bf16_parts(x))
 
 
 def _kernel(*refs, spec: _Spec):
@@ -204,7 +224,7 @@ def _kernel(*refs, spec: _Spec):
     select_ref = refs.pop(0) if spec.has_select else None
     bias_ref = refs.pop(0) if spec.has_bias else None
     live_ref = refs.pop(0) if spec.has_live else None
-    weights_ref, chosen_ref, dest_ref, routed_ref, counts_ref, row_expert_ref, src_ref, buckets_s, totals_s, start_s, carry_s = refs
+    weights_ref, chosen_ref, dest_ref, routed_ref, counts_ref, row_expert_ref, src_ref, row_weight_ref, buckets_s, weights_s, totals_s, start_s, carry_s = refs
     f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
     phase, b = pl.program_id(0), pl.program_id(1)
     lane_c = jax.lax.broadcasted_iota(i32, (TB, 128), 1)  # a token's choice j lives in lane j
@@ -253,6 +273,7 @@ def _kernel(*refs, spec: _Spec):
         weights_ref[...] = _choice_major(weights, KP, False)[:k]
         chosen_ref[...] = _choice_major(chosen, KP, True)[:k].astype(i32)
         buckets_s[at, :] = buckets
+        weights_s[at, :] = weights
         block_totals = jnp.sum(bucket_counts(buckets), axis=0, keepdims=True)
         totals_s[...] = jnp.where(b == 0, block_totals, totals_s[...] + block_totals)
 
@@ -265,7 +286,7 @@ def _kernel(*refs, spec: _Spec):
             totals = totals_s[...]
             above = (jax.lax.broadcasted_iota(i32, (Lb, Lb), 0) < jax.lax.broadcasted_iota(i32, (Lb, Lb), 1)).astype(bf16)
             hi, lo = _digits(jnp.broadcast_to(totals, (16, Lb)))
-            start = (256.0 * jnp.dot(hi, above, preferred_element_type=f32) + jnp.dot(lo, above, preferred_element_type=f32))[:1]
+            start = (256.0 * bf16_dot(hi, above) + bf16_dot(lo, above))[:1]
             start_s[...] = start
             carry_s[...] = jnp.zeros((1, Lb), f32)
             counts_ref[...] = totals[:, :n].astype(i32)
@@ -280,7 +301,7 @@ def _kernel(*refs, spec: _Spec):
         counts = bucket_counts(buckets)
         below = (jax.lax.broadcasted_iota(i32, (TB, TB), 1) < jax.lax.broadcasted_iota(i32, (TB, TB), 0)).astype(bf16)
         # the bucket's first row + its rows of the blocks before + of this block's tokens before
-        place = start_s[...] + carry_s[...] + jnp.dot(below, counts.astype(bf16), preferred_element_type=f32)
+        place = start_s[...] + carry_s[...] + bf16_dot(below, counts.astype(bf16))
         dest, behind = jnp.zeros((TB, 128), f32), jnp.zeros((TB, 1), f32)
         for j in range(k):
             bucket = buckets[:, j : j + 1]
@@ -294,6 +315,12 @@ def _kernel(*refs, spec: _Spec):
         # a sorted row's token: how many tokens' rows of its bucket all lie before it
         past_hi, past_lo = _digits(place + counts)
         first_row, end_row = start_s[...], start_s[...] + totals_s[...]
+        # a token's gate in each bucket (a held expert is chosen at most once a token)
+        weights, gate = weights_s[at, :], jnp.zeros((TB, Lb), f32)
+        for j in range(k):
+            gate = gate + jnp.where(lane_b == buckets[:, j : j + 1], weights[:, j : j + 1], 0.0)
+        gate = bf16_parts(gate)
+        in_groups = jnp.sum(jnp.where(lane_row < n, totals_s[...], 0.0))  # the rows that exist: behind them no gate is looked up
         for r0 in range(0, Rp, RB):
             row = (r0 + jax.lax.broadcasted_iota(i32, (RB, Lb), 0)).astype(f32)
             of_bucket = ((first_row <= row) & (row < end_row)).astype(bf16)  # [RB, Lb], a one a row
@@ -301,6 +328,13 @@ def _kernel(*refs, spec: _Spec):
             row = (r0 + jax.lax.broadcasted_iota(i32, (1, RB), 1)).astype(f32)
             before = jnp.sum((past <= row).astype(f32), axis=0, keepdims=True).astype(i32)
             src_ref[:, r0 : r0 + RB] = jnp.where(b == 0, before, src_ref[:, r0 : r0 + RB] + before)
+
+            @pl.when(r0 < in_groups)
+            def _():
+                # the row's gate: that of the token whose one row in the row's bucket ends just behind it
+                in_bucket = sum(_nt(part, of_bucket) for part in gate)  # [TB, RB]: the token's gate in the row's bucket
+                mine = jnp.sum(jnp.where(past == row + 1.0, in_bucket, 0.0), axis=0, keepdims=True)
+                row_weight_ref[:, r0 : r0 + RB] = jnp.where(b == 0, mine, row_weight_ref[:, r0 : r0 + RB] + mine)
 
 
 @functools.lru_cache(maxsize=64)
@@ -350,6 +384,7 @@ def _plan_call(spec: _Spec):
             pl.BlockSpec((1, n), whole),
             pl.BlockSpec((1, Rp), whole),
             pl.BlockSpec((1, Rp), whole),
+            pl.BlockSpec((1, Rp), whole),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, S), jnp.float32),
@@ -359,9 +394,11 @@ def _plan_call(spec: _Spec):
             jax.ShapeDtypeStruct((1, n), jnp.int32),
             jax.ShapeDtypeStruct((1, Rp), jnp.int32),
             jax.ShapeDtypeStruct((1, Rp), jnp.int32),
+            jax.ShapeDtypeStruct((1, Rp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blocks * TB, 128), jnp.float32),  # every token's buckets, choice j in lane j
+            pltpu.VMEM((blocks * TB, 128), jnp.float32),  # and their gates
             pltpu.VMEM((1, Lb), jnp.float32),  # the buckets' totals
             pltpu.VMEM((1, Lb), jnp.float32),  # their first rows
             pltpu.VMEM((1, Lb), jnp.float32),  # their rows of the blocks passed
@@ -380,9 +417,9 @@ def _kernel_forward(logits, select_logits, select_bias, live, spec: _Spec) -> Ro
         operands.append(select_bias.astype(jnp.float32).reshape(1, spec.E))
     if spec.has_live:
         operands.append(live.astype(jnp.int32).reshape(spec.S, 1))
-    weights, chosen, dest, routed, counts, row_expert, src = _plan_call(spec)(*operands)
+    weights, chosen, dest, routed, counts, row_expert, src, row_weight = _plan_call(spec)(*operands)
     rows = spec.S * spec.k
-    return RoutePlan(weights, chosen, dest, routed, counts.reshape(-1), row_expert.reshape(-1)[:rows], src.reshape(-1)[:rows])
+    return RoutePlan(weights, chosen, dest, routed, counts.reshape(-1), row_expert.reshape(-1)[:rows], src.reshape(-1)[:rows], row_weight.reshape(-1)[:rows])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -392,13 +429,14 @@ def _kernel_plan(logits, select_logits, select_bias, live, spec: _Spec) -> Route
 
 def _kernel_plan_fwd(logits, select_logits, select_bias, live, spec):
     plan = _kernel_forward(logits, select_logits, select_bias, live, spec)
-    return plan, (logits, plan.chosen)
+    return plan, (logits, plan.chosen, plan.dest, plan.routed)
 
 
 def _kernel_plan_bwd(spec, saved, g):
-    logits, chosen = saved
+    logits, chosen, dest, routed = saved
     _, vjp = jax.vjp(lambda lg: chosen_gates(scores(lg, spec.scoring), chosen.T, spec.norm).T, logits)
-    return (*vjp(g.weights), None, None, None)
+    # a group's row holds its assignment's gate; what lies behind the groups is no function of the logits
+    return (*vjp(g.weights + jnp.where(routed != 0, g.row_weight[dest], 0.0)), None, None, None)
 
 
 _kernel_plan.defvjp(_kernel_plan_fwd, _kernel_plan_bwd)
@@ -417,13 +455,16 @@ def kernel_fits(S: int, E: int, k: int) -> bool:
 
 def plan_path(S: int, E: int, k: int) -> Dict[str, Any]:
     """What ``impl="auto"`` does with ``[S, E]`` logits and k choices here,
-    from the shape and the backend alone: ``path`` (``kernel`` | ``sorted``)
-    and the kernel's ``blocks`` of tokens (0 where it does not run). What
-    ``route_plan`` itself asks, and what an engine records of its programs'
-    shapes where it builds them (``moe.route_plan``: the ops have no tracer)."""
+    from the shape and the backend alone: ``path`` (``kernel`` | ``sorted``),
+    the kernel's ``blocks`` of tokens (0 where it does not run), and
+    ``combine``, the form of ``routed_ffn``'s two ways between token order
+    and expert order (``moe/live_rows.py``: ``live_rows`` beside the kernel,
+    ``gather`` beside the sorts). What ``routed_ffn`` asks, and what an
+    engine records of its programs' shapes where it builds them
+    (``moe.route_plan``: the ops have no tracer)."""
     if on_tpu() and kernel_fits(S, E, k):
-        return {"path": "kernel", "S": S, "E": E, "k": k, "blocks": -(-S // TOKEN_BLOCK)}
-    return {"path": "sorted", "S": S, "E": E, "k": k, "blocks": 0}
+        return {"path": "kernel", "S": S, "E": E, "k": k, "blocks": -(-S // TOKEN_BLOCK), "combine": "live_rows"}
+    return {"path": "sorted", "S": S, "E": E, "k": k, "blocks": 0, "combine": "gather"}
 
 
 def route_plan(
@@ -452,8 +493,8 @@ def route_plan(
     if impl == "sorted":
         _, chosen, weights = top_k_route(logits, k, norm, select_logits, scoring, select_bias)
         buckets, n = buckets_of(chosen, E, held, live)
-        dest, *rest = sorted_plan(buckets, n)
-        return RoutePlan(weights.T, chosen.T, dest.T, (buckets < n).T.astype(jnp.int32), *rest)
+        dest, counts, row_expert, order = sorted_plan(buckets, n)
+        return RoutePlan(weights.T, chosen.T, dest.T, (buckets < n).T.astype(jnp.int32), counts, row_expert, order // k, weights.reshape(-1)[order])
     if impl not in ("kernel", "pallas_interpret"):
         raise ValueError(f"route_plan impl must be auto, kernel, pallas_interpret or sorted, got {impl!r}")
     if not kernel_fits(S, E, k):

@@ -17,11 +17,17 @@ work. Here every (token, expert) assignment is one row:
    by two stable sorts; an assignment of
    a dead token (``live`` false: a padding slot of a serving window) lies
    behind every expert and belongs to no group, so it costs no expert work;
-3. gate/up, activation and down projection run as grouped matmuls over the
+3. the sorted rows are built from their tokens (``moe/live_rows.py``,
+   ``dispatch``): where the plan is the kernel's, only the ``sum(counts)``
+   rows of the groups (``moe_dispatch_rows``), elsewhere all ``S k`` by a
+   gather;
+4. gate/up, activation and down projection run as grouped matmuls over the
    ``[E, H, I]`` stacks with the group sizes as data
    (``moe/grouped_matmul.py``): a shifting routing mix compiles nothing;
-4. each row goes back to its token and the token's k rows are summed with
-   their gates in float32.
+5. each row goes back to its token and the token's rows are summed with
+   their gates in float32 (``live_rows.combine``): the rows of the groups
+   alone, added where their tokens lie (``moe_combine_rows``), or all
+   ``S k`` gathered, masked and summed in k slabs.
 
 Returns the output and the per-expert assignment counts (live tokens only).
 """
@@ -33,8 +39,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.moe import live_rows
 from deepspeed_tpu.moe.grouped_matmul import grouped_matmul
-from deepspeed_tpu.moe.route_plan import route_plan, scores, top_k_route
+from deepspeed_tpu.moe.route_plan import plan_path, route_plan, scores, top_k_route
 
 
 def route(logits: jnp.ndarray, k: int, norm_topk_prob: Optional[bool], select_logits: Optional[jnp.ndarray] = None,
@@ -51,16 +58,6 @@ def route(logits: jnp.ndarray, k: int, norm_topk_prob: Optional[bool], select_lo
     if not 1 <= k <= E:
         raise ValueError(f"top-k routing needs 1 <= k <= num_experts, got k={k} of {E}")
     return top_k_route(logits, k, k > 1 if norm_topk_prob is None else norm_topk_prob, select_logits, scoring, select_bias)
-
-
-def rows_at(x: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
-    """``x[index]`` for a plan's places (``index`` [...] in ``0 .. len(x)``,
-    by construction): nothing to wrap and nothing to clamp, which ``x[index]``
-    does in two fusions before each gather."""
-    return jax.lax.gather(
-        x, index[..., None], jax.lax.GatherDimensionNumbers(offset_dims=(index.ndim,), collapsed_slice_dims=(0,), start_index_map=(0,)),
-        slice_sizes=(1, x.shape[1]), mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
-    )
 
 
 def _expert_matmul(rows, w, sizes, row_expert, group_offset, out_dtype, transposed: bool = False):
@@ -115,15 +112,16 @@ def routed_ffn(
     from deepspeed_tpu.moe.experts import _pointwise_activation
 
     dt = tokens.dtype
+    how = plan_path(*logits.shape, k)
     with jax.named_scope("moe_route"):
         plan = route_plan(
             logits, k=k, norm_topk_prob=norm_topk_prob, scoring=scoring, select_logits=select_logits,
-            select_bias=select_bias, live=live, held=held,
+            select_bias=select_bias, live=live, held=held, impl=how["path"],
         )
         gates = scores(logits, scoring)  # for the caller's auxiliary loss; nothing in a program that drops them
         counts = plan.counts
         row_expert = group_offset + plan.row_expert
-        rows = rows_at(tokens, plan.src)
+        rows = live_rows.dispatch(tokens, plan, impl=how["combine"])
     with jax.named_scope("moe_experts"):
         if activation in ("swiglu", "geglu"):
             gate = _expert_matmul(rows, experts["w_gate"], counts, row_expert, group_offset, dt)
@@ -142,10 +140,7 @@ def routed_ffn(
             out_rows = out_rows + experts["b_out"].astype(jnp.float32)[row_expert]
     with jax.named_scope("moe_route"):
         # back to token order; the row of a dead or not-held assignment lies behind every group and was never computed
-        per_choice = rows_at(out_rows, plan.dest)  # [k, S, H]
-        if held is not None or live is not None:
-            per_choice = jnp.where(plan.routed[..., None] != 0, per_choice, 0.0)
-        out = jnp.sum(per_choice * plan.weights[..., None], axis=0).astype(dt)
+        out = live_rows.combine(out_rows, plan, dt, masked=held is not None or live is not None, impl=how["combine"])
     return out, counts, gates
 
 

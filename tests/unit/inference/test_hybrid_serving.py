@@ -213,9 +213,10 @@ def test_the_engine_records_the_route_plans_form_where_it_builds_the_server(toy,
     eng = _server(lm, params, max_slots=64)
     eng._build_paged_server()
     events = [s["attrs"] for s in eng.tracer.spans() if s["name"] == "moe.route_plan"]
+    combine = "live_rows" if on_a_tpu else "gather"  # the two ways between token order and expert order go with the plan
     assert events == [
-        {"path": paths[0], "S": 64, "E": cfg.moe_router_experts, "k": cfg.moe_top_k, "blocks": int(on_a_tpu)},
-        {"path": paths[1], "S": 512, "E": cfg.moe_router_experts, "k": cfg.moe_top_k, "blocks": int(on_a_tpu)},
+        {"path": paths[0], "S": 64, "E": cfg.moe_router_experts, "k": cfg.moe_top_k, "blocks": int(on_a_tpu), "combine": combine},
+        {"path": paths[1], "S": 512, "E": cfg.moe_router_experts, "k": cfg.moe_top_k, "blocks": int(on_a_tpu), "combine": combine},
     ]
     assert decode.routed_rows(cfg, 64, 16) == decode.token_tile(cfg) == 512 and decode.routed_rows(cfg, 4, 16) == 64
     assert eng.compile_stats() == {}

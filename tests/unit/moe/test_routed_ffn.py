@@ -264,6 +264,8 @@ def test_plan_equals_the_sorted_forms(case, impl):
         np.testing.assert_array_equal(np.asarray(getattr(got, name)), want, err_msg=name)
     np.testing.assert_allclose(np.asarray(got.weights), np.asarray(route_plan(logits, impl="sorted", **static).weights), rtol=2e-6, atol=1e-7)
     np.testing.assert_allclose(np.asarray(got.weights).sum(0), 1.0, rtol=1e-5)  # ``norm_topk_prob``
+    # a group's sorted row holds its assignment's gate: ``row_weight`` against ``weights`` through ``dest``, bit for bit
+    np.testing.assert_array_equal(np.asarray(got.row_weight)[dest.T[routed.T]], np.asarray(got.weights)[routed.T])
     assert sorted(np.asarray(got.dest).reshape(-1)) == list(range(rows * k))  # a permutation: every row has one assignment
 
 
@@ -306,7 +308,200 @@ def test_the_plans_path_follows_the_shape_and_the_backend(on_a_tpu, tokens, want
     from deepspeed_tpu.moe import route_plan
 
     monkeypatch.setattr(route_plan, "on_tpu", lambda: on_a_tpu)
-    assert route_plan.plan_path(tokens, 64, 4) == {"path": want[0], "S": tokens, "E": 64, "k": 4, "blocks": want[1]}
+    combine = {"kernel": "live_rows", "sorted": "gather"}[want[0]]  # the two ways go with the plan: one observable
+    assert route_plan.plan_path(tokens, 64, 4) == {"path": want[0], "S": tokens, "E": 64, "k": 4, "blocks": want[1], "combine": combine}
+
+
+# --- the two ways between token order and expert order (``moe/live_rows.py``) ----------
+
+# (S, E, k, held, live tokens (None: no ``live``), H, the tokens' type): the eight routed cells' narrow steps and token
+# tiles (a mixed step's tile: its first ~190 tokens live, OLMoE's 143 of 1,024), then the edges: no row at all, every row
+# (no ``held``, no ``live``), a tile whose last tokens are dead, blocks that end inside a group, odd sizes
+_WAYS_CASES = {
+    "lfm2_glm_narrow": (64, 64, 4, (0, 8), 64, 128, jnp.bfloat16),
+    "laguna_narrow": (64, 256, 10, (16, 16), 64, 128, jnp.bfloat16),
+    "mimo_narrow": (64, 256, 8, (240, 16), 64, 128, jnp.bfloat16),
+    "solar_narrow": (64, 320, 8, (40, 40), 64, 128, jnp.bfloat16),
+    "kimi_narrow": (64, 256, 8, (32, 32), 64, 128, jnp.bfloat16),
+    "nemotron_narrow": (64, 128, 6, (64, 64), 64, 128, jnp.bfloat16),
+    "olmoe_narrow": (16, 64, 8, None, 16, 128, jnp.bfloat16),
+    "lfm2_glm_tile": (512, 64, 4, (0, 8), 190, 128, jnp.bfloat16),
+    "laguna_tile": (512, 256, 10, (16, 16), 190, 128, jnp.bfloat16),
+    "mimo_tile": (512, 256, 8, (240, 16), 190, 128, jnp.bfloat16),
+    "solar_tile": (512, 320, 8, (40, 40), 190, 128, jnp.bfloat16),
+    "kimi_tile": (512, 256, 8, (32, 32), 190, 128, jnp.bfloat16),
+    "nemotron_tile": (512, 128, 6, (64, 64), 190, 128, jnp.bfloat16),
+    "olmoe_tile": (1024, 64, 8, None, 143, 128, jnp.bfloat16),
+    "no_row": (64, 64, 4, (0, 8), 0, 128, jnp.bfloat16),
+    "every_row": (48, 8, 3, None, None, 32, jnp.float32),
+    "every_row_of_whole_blocks": (64, 8, 4, None, None, 128, jnp.bfloat16),
+    "dead_last_tokens_float32": (72, 16, 3, (4, 8), 50, 384, jnp.float32),
+    "eight_tokens": (8, 64, 8, None, 5, 128, jnp.bfloat16),
+    "ragged_last_block": (520, 16, 3, (4, 8), 300, 128, jnp.bfloat16),
+}
+
+
+def _ways_inputs(case):
+    """(tokens [S, H], the plan, the experts' outputs' stand-in [S k, H] float32 with NaN behind the groups). The plan is
+    numpy's (``_numpy_plan``, what both forms of ``route_plan`` are held to above): nothing to compile a shape."""
+    from deepspeed_tpu.moe.route_plan import RoutePlan
+
+    rows, width, k, held, live_tokens, hidden, dtype = _WAYS_CASES[case]
+    rng = np.random.default_rng(len(case))
+    logits = jnp.asarray(2.0 * rng.standard_normal((rows, width)), jnp.float32)
+    live = None if live_tokens is None else np.arange(rows) < live_tokens
+    chosen, dest, routed, counts, row_expert, src = _numpy_plan(logits, k, "softmax", None, live, held)
+    weights = rng.random((rows, k)).astype(np.float32)
+    weights /= weights.sum(1, keepdims=True)
+    row_weight = np.zeros(rows * k, np.float32)
+    row_weight[dest.reshape(-1)] = weights.reshape(-1)
+    plan = RoutePlan(*(jnp.asarray(x) for x in (weights.T, chosen.T.astype(np.int32), dest.T.astype(np.int32), routed.T.astype(np.int32),
+                                                counts.astype(np.int32), row_expert.astype(np.int32), src.astype(np.int32), row_weight)))
+    tokens = jnp.asarray(rng.standard_normal((rows, hidden)), dtype)
+    out_rows = rng.standard_normal((rows * k, hidden)).astype(np.float32)
+    out_rows[int(counts.sum()) :] = np.nan
+    return tokens, plan, jnp.asarray(out_rows)
+
+
+@pytest.mark.parametrize("case", _WAYS_CASES)
+def test_live_rows_are_the_gathers(case):
+    """``moe_dispatch_rows`` and ``moe_combine_rows`` in Pallas's interpreter
+    against the two gathers and the sum they stand for: the rows of the
+    groups bit for bit (behind them lies anything: here NaN, which must reach
+    no token), the output in float32 to float32 rounding (a token's rows are
+    added in expert order, not in choice order) and in the tokens' type to
+    one step of it."""
+    from deepspeed_tpu.moe import live_rows
+
+    tokens, plan, out_rows = _ways_inputs(case)
+    rows, _, k, held, live_tokens, _, dtype = _WAYS_CASES[case]
+    total = int(jnp.sum(plan.counts))
+    assert total == (rows * k if held is None and live_tokens is None else total) and (total == 0) == (live_tokens == 0)
+    masked = held is not None or live_tokens is not None
+
+    typed = case in ("laguna_narrow", "olmoe_narrow", "no_row")  # the output in the tokens' type too: another call, so only here
+
+    def both(form, t, o, p):  # op by op: a call is its own compiled program, built once a shape
+        return (live_rows.dispatch(t, p, impl=form), live_rows.combine(o, p, jnp.float32, masked=masked, impl=form),
+                live_rows.combine(o, p, dtype, masked=masked, impl=form) if typed else None)
+
+    got_rows, got, got_typed = both("pallas_interpret", tokens, out_rows, plan)
+    want_rows, want, want_typed = both("gather", tokens, jnp.nan_to_num(out_rows), plan)
+    assert got_rows.shape == want_rows.shape == (rows * k, tokens.shape[1]) and got_rows.dtype == want_rows.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got_rows[:total], np.float32), np.asarray(want_rows[:total], np.float32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6, atol=1e-6)
+    if typed:
+        step = float(jnp.finfo(dtype).eps)
+        assert got_typed.dtype == want_typed.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got_typed, np.float32), np.asarray(want_typed, np.float32), rtol=step, atol=step)
+    if total == 0:
+        assert not np.asarray(got).any()
+
+
+def test_live_rows_walk_column_slabs(monkeypatch):
+    """A float32 accumulator of ``ACC_BYTES`` a grid step: at 512 x 4,096 the
+    columns go in four slabs; here 64 x 512 in four of 128, the same walk."""
+    from deepspeed_tpu.moe import live_rows
+
+    monkeypatch.setattr(live_rows, "ACC_BYTES", 64 * 128 * 4)
+    tokens, plan, out_rows = _ways_inputs("laguna_narrow")
+    tokens, out_rows = jnp.tile(tokens, (1, 4)), jnp.tile(out_rows, (1, 4))
+    spec = live_rows._Spec(64, 512, 640, 16, "bfloat16", True)
+    assert live_rows._geometry(spec) == (640, 128)
+    assert live_rows._geometry(live_rows._Spec(24, 96, 72, 16, "float32", True)) == (128, 96)  # an odd width is one slab
+    total = int(jnp.sum(plan.counts))
+    got = live_rows.dispatch(tokens, plan, impl="pallas_interpret")
+    np.testing.assert_array_equal(np.asarray(got[:total], np.float32), np.asarray(live_rows.dispatch(tokens, plan, impl="gather")[:total], np.float32))
+    np.testing.assert_allclose(
+        np.asarray(live_rows.combine(out_rows, plan, jnp.float32, masked=True, impl="pallas_interpret")),
+        np.asarray(live_rows.combine(jnp.nan_to_num(out_rows), plan, jnp.float32, masked=True, impl="gather")), rtol=2e-6, atol=1e-6,
+    )
+    monkeypatch.undo()
+    live_rows._dispatch_call.cache_clear(), live_rows._combine_call.cache_clear()  # built under the small budget
+
+
+def test_what_is_not_finite_stays_its_tokens():
+    """The rows move through the MXU as products with ones and zeros, and a
+    zero times an infinity is no zero: what is not finite is taken out before
+    the product and comes back as NaN in its own rows and its own token, as
+    a gather would leave it there and nowhere else."""
+    from deepspeed_tpu.moe import live_rows
+
+    tokens, plan, out_rows = _ways_inputs("every_row_of_whole_blocks")
+    total = int(jnp.sum(plan.counts))
+    src = np.asarray(plan.src)
+    poisoned = tokens.at[5, 7].set(jnp.inf).at[9, 0].set(jnp.nan)
+    got = np.asarray(live_rows.dispatch(poisoned, plan, impl="pallas_interpret"), np.float32)
+    clean = np.asarray(live_rows.dispatch(tokens, plan, impl="gather"), np.float32)
+    touched = np.isin(src, (5, 9))
+    np.testing.assert_array_equal(got[~touched], clean[~touched])
+    assert not np.isfinite(got[touched]).all(axis=1).any()
+    bad_row = int(np.flatnonzero(src == 20)[0])
+    back = np.asarray(live_rows.combine(out_rows.at[bad_row, 3].set(-jnp.inf), plan, jnp.float32, masked=False, impl="pallas_interpret"))
+    want = np.asarray(live_rows.combine(jnp.nan_to_num(out_rows), plan, jnp.float32, masked=False, impl="gather"))
+    assert total == src.size and not np.isfinite(back[20]).all()
+    np.testing.assert_allclose(np.delete(back, 20, axis=0), np.delete(want, 20, axis=0), rtol=2e-6, atol=1e-6)
+    with pytest.raises(ValueError, match="impl must be"):
+        live_rows.dispatch(tokens, plan, impl="auto")
+
+
+def _through(form, monkeypatch):
+    """``routed_ffn`` with the plan and the two ways in ``form`` (``pallas_interpret``: what a TPU takes, interpreted)."""
+    path = {"path": form, "combine": form} if form == "pallas_interpret" else {"path": "sorted", "combine": "gather"}
+    monkeypatch.setattr(routed_ffn_module, "plan_path", lambda *shape: path)
+
+
+@pytest.mark.parametrize(
+    "activation, use_bias, k, held, live_tokens",
+    [("swiglu", False, 3, (2, 4), 30), ("gelu", True, 2, None, None), ("gelu", True, 3, (0, 5), 40)],
+    ids=["held_share", "biased_every_row", "biased_held_dead_tail"],
+)
+def test_routed_ffn_through_the_live_rows_is_the_gathers(monkeypatch, activation, use_bias, k, held, live_tokens):
+    """The whole layer as a TPU runs it (the plan's kernel, ``moe_dispatch_rows``,
+    the experts, ``b_in`` / ``b_out`` by the rows' experts, ``moe_combine_rows``),
+    interpreted, against the sorted plan and the gathers: the output to
+    float32 rounding, ``counts`` and the gates equal."""
+    experts, tokens, logits = layer_inputs(6, activation, use_bias)
+    live = None if live_tokens is None else jnp.arange(S) < live_tokens
+    static = dict(k=k, activation=activation, norm_topk_prob=k > 1, held=held)
+    if held is not None:
+        experts = {name: leaf[held[0] : held[0] + held[1]] for name, leaf in experts.items()}
+    _through("pallas_interpret", monkeypatch)
+    got, got_counts, got_gates = routed_ffn(experts, tokens, logits, live, **static)
+    _through("sorted", monkeypatch)
+    want, want_counts, want_gates = routed_ffn(experts, tokens, logits, live, **static)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got_counts), np.asarray(want_counts))
+    np.testing.assert_array_equal(np.asarray(got_gates), np.asarray(want_gates))
+    assert held is not None or int(got_counts.sum()) == k * S
+
+
+@pytest.mark.parametrize("held, live_tokens, use_bias", [((2, 4), 30, False), (None, None, True)], ids=["held_share_dead_tail", "biased_every_row"])
+def test_routed_ffns_gradient_through_the_live_rows_is_the_gathers(monkeypatch, held, live_tokens, use_bias):
+    """``jax.grad`` of the layer w.r.t. the tokens, the router's logits and
+    every expert stack through the two calls' ``custom_vjp``s (and the
+    plan's, which hands ``row_weight``'s cotangent to the gates) against the
+    gradient through the gathers."""
+    activation = "gelu" if use_bias else "swiglu"
+    experts, tokens, logits = layer_inputs(7, activation, use_bias)
+    live = None if live_tokens is None else jnp.arange(S) < live_tokens
+    if held is not None:
+        experts = {name: leaf[held[0] : held[0] + held[1]] for name, leaf in experts.items()}
+    target = jax.random.normal(jax.random.PRNGKey(13), tokens.shape)
+
+    def grads(form):
+        _through(form, monkeypatch)
+
+        def loss(e, t, lg):
+            out, _, _ = routed_ffn_module.routed_ffn(e, t, lg, k=3, activation=activation, norm_topk_prob=True, live=live, held=held)
+            return jnp.sum(out * target) + 0.5 * jnp.sum(out**2)
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(experts, tokens, logits)
+
+    got, want = grads("pallas_interpret"), grads("sorted")
+    assert float(jnp.abs(want[2]).max()) > 0 and float(jnp.abs(want[1]).max()) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5 * max(float(jnp.abs(b).max()), 1e-6), rtol=1e-5)
 
 
 @pytest.fixture(scope="module")
@@ -388,7 +583,7 @@ def test_the_kernel_compiles_for_a_v5e_at_the_sizes_it_admits(v5e, rows, width, 
 
 def test_route_plan_bench_rehearses():
     """``tools/route_plan_bench.py --rehearse``: the tool's control flow, tiny, on the CPU: the kernel held to the
-    sorted form at every shape first, then a line a window, two forms each."""
+    sorted form and the two ways to the gathers at every shape first, then a line a window, three forms each."""
     import json
     import pathlib
     import subprocess
@@ -398,7 +593,9 @@ def test_route_plan_bench_rehearses():
     done = subprocess.run([sys.executable, str(tool), "--rehearse"], capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
     lines = [json.loads(line) for line in done.stdout.splitlines() if line.startswith("{")]
-    assert lines[0]["check"] == "kernel == sorted" and lines[0]["failed"] == [] and [shape[1] for shape in lines[0]["shapes"]] == [16, 64, 8, 24]
+    assert lines[0]["check"] == "kernel == sorted, live_rows == gather" and lines[0]["failed"] == []
+    assert [shape[1] for shape in lines[0]["shapes"]] == [16, 64, 8, 24]
     assert [(line["cell"], line["window"], line["device"]) for line in lines[1:]] == [("tiny", "narrow", "cpu"), ("tiny", "mixed", "cpu")]
     for line in lines[1:]:  # times less the empty loop's: on the CPU, two calls, of any sign
         assert all(isinstance(line[form][key], float) for form in ("overhead_us", "sorted", "kernel") for key in ("plan_us", "block_us"))
+        assert isinstance(line["live_rows"]["block_us"], float)  # the plan's kernel and the two calls that walk the groups' rows

@@ -492,7 +492,11 @@ def _slab_sized_fills(text, slots, lanes=512):
 # PR 64 meant to change all four: a routed layer's plan is the ``moe_route_plan`` kernel (``moe/route_plan.py``: one
 # more call a routed layer body: 16, 25, 5 and 17 before) and the sorts' pairs and the token-major copy of the
 # gathered rows are gone from the temporaries (5,343,232 / 6,279,680 / 2,549,248 / 4,812,800 before).
-NARROW_PROGRAMS = {"solar": (20, 4_667_904), "mimo": (31, 5_338_112), "glm": (6, 2_483_200), "laguna": (21, 4_328_960)}
+# PR 65 meant to change all four: the routed layers' two ways between token order and expert order are the
+# ``moe_dispatch_rows`` and ``moe_combine_rows`` calls (``moe/live_rows.py``: two more calls a routed layer body: 20, 31, 6
+# and 21 before) where two gathers, a mask and a sum of k slabs stood (4,667,904 / 5,338,112 / 2,483,200 / 4,328,960 bytes
+# of temporaries before; Laguna's grow from 4.3 to 11.3 MB, which the test beside it bounds at 50).
+NARROW_PROGRAMS = {"solar": (28, 3_812_352), "mimo": (43, 4_970_496), "glm": (8, 2_510_336), "laguna": (29, 11_251_200)}
 
 
 def _narrow_program(text, memory):
@@ -615,6 +619,102 @@ def test_mixed_step_computes_token_tiles_not_the_slab(v5e, monkeypatch):
     for slab in (f"[{rows},{width},{inner}]", f"[{rows * width},{inner}]"):
         assert slab not in text, f"an instruction of the whole slab's shape {slab}"
     assert compiled.memory_analysis().temp_size_in_bytes < cfg.hidden_size * inner * 2
+
+
+def _kernel_operands(text, kernel: str):
+    """The element types of a named Mosaic call's operands in a compiled program's text."""
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+)", text, flags=re.M))
+    (operands,) = re.findall(rf"%{kernel}[\w.-]* = .*? custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", text)
+    return [shape_of[o].split("[")[0] for o in re.findall(r"%([\w.-]+)", operands)]
+
+
+@pytest.mark.parametrize("rows", [64, 512], ids=["narrow", "token_tile"])
+def test_a_routed_layers_scope_is_three_calls_and_no_gather_of_every_row(v5e, monkeypatch, rows):
+    """``routed_ffn`` as Laguna's serving step calls it (256 experts, 10 a
+    token, 16 held, hidden 3,072; a narrow step's 64 rows and a mixed step's
+    tile of 512), compiled for a v5e: the ``moe_route`` scope holds the plan's
+    call, ``moe_dispatch_rows`` and ``moe_combine_rows`` (``moe/live_rows.py``)
+    and no gather at all, and nowhere in the program is there an array of the
+    gather form's shapes, ``f32[k, S, H]`` or a gathered ``[S k, H]``. Each new
+    call opens with ONE ``s32`` operand (the ragged attention kernel's readers
+    count a call that opens with three)."""
+    from deepspeed_tpu.moe import routed_ffn
+
+    for module in ("deepspeed_tpu.moe.route_plan", "deepspeed_tpu.moe.grouped_matmul"):
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+    width, k, held, hidden, inner = 256, 10, (0, 16), 3072, 1024
+
+    def on_v5e(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(experts, tokens, logits, live):
+        return routed_ffn.routed_ffn(experts, tokens, logits, k=k, activation="swiglu", norm_topk_prob=True, live=live, held=held)[:2]
+
+    experts = {"w_gate": on_v5e((16, hidden, inner), BF16), "w_up": on_v5e((16, hidden, inner), BF16), "w_out": on_v5e((16, inner, hidden), BF16)}
+    text = jax.jit(layer).lower(experts, on_v5e((rows, hidden), BF16), on_v5e((rows, width), jnp.float32), on_v5e((rows,), bool)).compile().as_text()
+    in_scope = [line for line in text.splitlines() if "/moe_route/" in line]
+    calls = re.findall(r"moe_route/(\w+)/pallas_call", "\n".join(line for line in in_scope if 'custom_call_target="tpu_custom_call"' in line))
+    assert calls == ["moe_route_plan", "moe_dispatch_rows", "moe_combine_rows"], calls
+    assert not [line for line in in_scope if re.search(r" gather\(", line)], "a gather in the moe_route scope"
+    assert f"f32[{k},{rows},{hidden}]" not in text
+    assert not re.findall(rf"= (?:bf16|f32)\[{rows * k},{hidden}\]\S* gather\(", text)
+    assert _kernel_operands(text, "moe_dispatch_rows") == ["s32", "bf16", "s32"]
+    assert _kernel_operands(text, "moe_combine_rows") == ["s32", "f32", "f32", "s32"]
+
+
+@pytest.mark.parametrize(
+    "rows, k, groups, hidden, dtype",
+    [(8, 8, 64, 2048, BF16), (24, 4, 8, 2048, BF16), (520, 4, 8, 2048, BF16), (1000, 8, 64, 2048, BF16), (512, 8, 16, 4096, BF16), (512, 4, 8, 2048, jnp.float32)],
+    ids=["8_olmoe", "24_lfm2", "520_lfm2", "1000_olmoe", "mimo_token_tile", "float32_token_tile"],
+)
+def test_the_live_rows_calls_compile_at_the_sizes_the_plan_admits(v5e, rows, k, groups, hidden, dtype):
+    """``moe_dispatch_rows`` and ``moe_combine_rows`` pass Mosaic wherever
+    ``moe_route_plan`` does: the fewest tokens, a size that fills no block of
+    128 rows, a ragged last token block, the widest token tile (512 x 4,096:
+    four column slabs) and float32 tokens (three bfloat16 parts), with no
+    ``vmem_limit_bytes`` asked for."""
+    from deepspeed_tpu.moe import live_rows
+    from deepspeed_tpu.moe.route_plan import RoutePlan, kernel_fits
+
+    assert kernel_fits(rows, 64, k)
+
+    def on_v5e(shape, kind):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=v5e)
+
+    per_choice, per_row = (k, rows), (rows * k,)
+    plan = RoutePlan(on_v5e(per_choice, jnp.float32), *(on_v5e(per_choice, I32),) * 3, on_v5e((groups,), I32), on_v5e(per_row, I32), on_v5e(per_row, I32), on_v5e(per_row, jnp.float32))
+
+    def both(tokens, out_rows, plan):
+        return live_rows.dispatch(tokens, plan, impl="live_rows"), live_rows.combine(out_rows, plan, dtype, masked=True, impl="live_rows")
+
+    text = jax.jit(both).lower(on_v5e((rows, hidden), dtype), on_v5e((rows * k, hidden), jnp.float32), plan).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(name for line in kernels for name in re.findall(r"/(moe_\w+_rows)/pallas_call", line)) == ["moe_combine_rows", "moe_dispatch_rows"]
+    assert not any("vmem_limit_bytes" in line or '"scoped_memory_configs":[{' in line for line in kernels)
+
+
+def test_a_routed_layer_compiles_under_a_highest_default_precision(v5e, monkeypatch):
+    """The routed models' ``--isolated`` logits tools run float32 with
+    ``jax_default_matmul_precision`` ``highest``; a kernel's product of two
+    bfloat16 arrays that leaves its precision to that default is refused by
+    Mosaic there ("Bad lhs type": ``laguna_logits_check.py --isolated`` on the
+    chip, PR 65, in the plan's kernel as PR 64 left it). The three calls of a
+    routed layer say ``DEFAULT`` themselves (``route_plan.bf16_dot``)."""
+    from deepspeed_tpu.moe import routed_ffn
+
+    for module in ("deepspeed_tpu.moe.route_plan", "deepspeed_tpu.moe.grouped_matmul"):
+        monkeypatch.setattr(sys.modules[module], "on_tpu", lambda: True)
+
+    def on_v5e(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(experts, tokens, logits, live):
+        return routed_ffn.routed_ffn(experts, tokens, logits, k=4, activation="swiglu", norm_topk_prob=True, live=live, held=(0, 8))[:2]
+
+    experts = {"w_gate": on_v5e((8, 256, 128)), "w_up": on_v5e((8, 256, 128)), "w_out": on_v5e((8, 128, 256))}
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(layer).lower(experts, on_v5e((64, 256)), on_v5e((64, 64)), on_v5e((64,), bool)).compile().as_text()
+    assert sorted(re.findall(r"moe_route/(\w+)/pallas_call", text))[:1] == ["moe_combine_rows"] and "moe_route_plan" in text and "moe_dispatch_rows" in text
 
 
 _OLMOE_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/olmoe-1b-7b-0125-l12.json"

@@ -1,19 +1,33 @@
 """The routing block alone, on the chip, at the eight routed serving cells'
-shapes (``deepspeed_tpu/moe/route_plan.py``, ``moe/routed_ffn.py``'s
-``moe_route`` scope): everything ``routed_ffn`` does before the experts (the
-scores, the top-k, the counts, each assignment's row, the gather of the
-sorted ``rows``) and after them (each row back to its token, the mask, the
-weighted sum), the experts left out (a sorted row's output is the row itself,
-in float32). Two forms side by side: ``sorted`` (two stable ``argsort``s and
-``lax.top_k``: what the scope held until PR 64, and ``auto``'s form where the
-kernel does not run) and ``kernel`` (the ``pallas_call``), for a narrow step's
-rows and for one token tile of a mixed step.
+shapes (``deepspeed_tpu/moe/route_plan.py``, ``moe/live_rows.py``,
+``moe/routed_ffn.py``'s ``moe_route`` scope): everything ``routed_ffn`` does
+before the experts (the scores, the top-k, the counts, each assignment's row,
+the sorted ``rows``) and after them (each row back to its token with its
+gate), the experts left out. Three forms side by side, for a narrow step's
+rows and for one token tile of a mixed step:
 
-First, once a shape and before any time is taken, the kernel's plan is held
-to the sorted form's ON THE CHIP, integer for integer (the weights to float32
-rounding): at every cell's two shapes and at ``EDGE_SHAPES``, the smallest,
-the odd and the ragged sizes ``kernel_fits`` admits. A difference ends the
-run with exit code 1.
+* ``sorted``: two stable ``argsort``s and ``lax.top_k``, the rows by a gather
+  of all ``S k``, back by a gather, a mask and a sum of k slabs: what the
+  scope held until PR 64, and ``plan_path``'s form where the kernel does not run;
+* ``kernel``: the plan by the ``moe_route_plan`` call, the same gathers
+  around it: PR 64's form, the parent of PR 65;
+* ``live_rows``: the plan's call and the two calls that walk the rows of the
+  groups alone (``moe_dispatch_rows``, ``moe_combine_rows``): what
+  ``plan_path`` takes on a TPU since PR 65.
+
+In the two gather forms a sorted row's output is the row itself in float32
+(the conversion rides in the gather's fusion). In ``live_rows`` the experts'
+outputs are an array that is there, as they are in a layer, with one block of
+this call's rows written into it: a conversion of all ``S k`` rows would be the
+stand-in's cost and not the block's.
+
+First, once a shape and before any time is taken, ON THE CHIP: the kernel's
+plan is held to the sorted form's, integer for integer (the weights to
+float32 rounding, ``row_weight`` over the rows of the groups), and
+``live_rows``' sorted rows and output to the gathers' (the rows of the groups
+bit for bit, the output in float32 to float32 rounding), at every cell's two
+shapes and at ``EDGE_SHAPES``, the smallest, the odd and the ragged sizes
+``kernel_fits`` admits. A difference ends the run with exit code 1.
 
 Then, a cell and a window, microseconds a call of the plan alone and of the
 whole block: ``CALLS`` calls back to back in one program that walks a stack
@@ -91,26 +105,30 @@ def plan_of(cell: Cell, impl: str):
     return fn
 
 
-def block(cell: Cell, impl: str, whole: bool):
-    """``(tokens [S, H], logits [S, E], bias [E], live [S]) -> [S, H]``: the
-    routing block in form ``impl``; ``whole`` False: the plan alone."""
+def block(cell: Cell, impl: str, whole: bool, combine: str = "gather"):
+    """``(tokens [S, H], logits [S, E], bias [E], live [S], stand_in [S k, H] float32) -> ([S, H], stand_in)``: the
+    routing block with the plan in form ``impl`` and the two ways in form ``combine``; ``whole`` False: the plan alone."""
+    import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.moe.routed_ffn import rows_at
+    from deepspeed_tpu.moe import live_rows
 
-    def fn(tokens, logits, bias, live):
+    def fn(tokens, logits, bias, live, stand_in):
         plan = plan_of(cell, impl)(logits, bias, live)
         if not whole:
-            return plan
-        out_rows = rows_at(tokens, plan.src).astype(jnp.float32)  # the experts' stand-in
-        per_choice = jnp.where(plan.routed[..., None] != 0, rows_at(out_rows, plan.dest), 0.0)
-        return jnp.sum(per_choice * plan.weights[..., None], axis=0).astype(tokens.dtype)
+            return plan, stand_in
+        rows = live_rows.dispatch(tokens, plan, impl=combine)
+        if combine == "gather":
+            out_rows = rows.astype(jnp.float32)  # the experts' stand-in
+        else:
+            out_rows = stand_in = jax.lax.dynamic_update_slice(stand_in, rows[: live_rows.ROW_BLOCK].astype(jnp.float32), (0, 0))
+        return live_rows.combine(out_rows, plan, tokens.dtype, masked=True, impl=combine), stand_in
 
     return fn
 
 
 def inputs(cell: Cell, rows: int, live_rows: int, seed: int):
-    """(tokens [S, H] bfloat16, logits [LAYERS, S, E], bias [E], live [S])."""
+    """(tokens [S, H] bfloat16, logits [LAYERS, S, E], bias [E], live [S], the experts' outputs' stand-in [S k, H] float32)."""
     import jax
     import jax.numpy as jnp
 
@@ -118,19 +136,34 @@ def inputs(cell: Cell, rows: int, live_rows: int, seed: int):
     tokens = jax.random.normal(key, (rows, cell.hidden), jnp.bfloat16)
     logits = 2.0 * jax.random.normal(jax.random.fold_in(key, 1), (LAYERS, rows, cell.experts), jnp.float32)
     bias = 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (cell.experts,), jnp.float32)
-    return tokens, logits, bias, jnp.arange(rows) < live_rows
+    return tokens, logits, bias, jnp.arange(rows) < live_rows, jax.random.normal(jax.random.fold_in(key, 3), (rows * cell.k, cell.hidden), jnp.float32)
 
 
-def differences(cell: Cell, rows: int, live_rows: int, kernel: str, seed: int) -> list:
-    """The names of what the kernel's plan and the sorted form's differ in, on this device: [] where they agree."""
+def differences(cell: Cell, rows: int, live_rows: int, kernel: str, ways: str, seed: int) -> list:
+    """The names of what the kernel's plan and the sorted form's differ in, and ``ways``' sorted rows and output and the
+    gathers', on this device: [] where they agree."""
     import jax
+    import jax.numpy as jnp
     import numpy as np
 
-    _, logits, bias, live = inputs(cell, rows, live_rows, seed)
+    from deepspeed_tpu.moe import live_rows as ways_of
+
+    tokens, logits, bias, live, out_rows = inputs(cell, rows, live_rows, seed)
     got, want = (jax.jit(plan_of(cell, impl))(logits[0], bias, live) for impl in (kernel, "sorted"))
     wrong = [name for name in ("chosen", "dest", "routed", "counts", "row_expert", "src") if not np.array_equal(getattr(got, name), getattr(want, name))]
-    if not np.allclose(got.weights, want.weights, rtol=2e-6, atol=1e-7):
-        wrong.append("weights")
+    total = int(np.sum(want.counts))
+    for name, exists in (("weights", slice(None)), ("row_weight", slice(0, total))):
+        if not np.allclose(np.asarray(getattr(got, name))[..., exists], np.asarray(getattr(want, name))[..., exists], rtol=2e-6, atol=1e-7):
+            wrong.append(name)
+
+    def both(form):
+        return jax.jit(lambda t, o, plan: (ways_of.dispatch(t, plan, impl=form), ways_of.combine(o, plan, jnp.float32, masked=True, impl=form)))(tokens, out_rows, got)
+
+    (rows_got, out_got), (rows_want, out_want) = both(ways), both("gather")
+    if not np.array_equal(np.asarray(rows_got[:total].astype(jnp.float32)), np.asarray(rows_want[:total].astype(jnp.float32))):
+        wrong.append("dispatch")
+    if not np.allclose(out_got, out_want, rtol=2e-6, atol=1e-6):
+        wrong.append("combine")
     return wrong
 
 
@@ -144,12 +177,16 @@ def _sum_of(out):
 
 
 def bench(fn, operands, calls: int, repeats: int) -> float:
-    """Seconds a call of ``fn(tokens, logits[i], bias, live)``, ``calls`` of them back to back in one program."""
+    """Seconds a call of ``fn(tokens, logits[i], bias, live, stand_in)``, ``calls`` of them back to back in one program."""
     import jax
     import jax.numpy as jnp
 
-    def many(tokens, logits, bias, live):
-        return jax.lax.fori_loop(0, calls, lambda i, acc: acc + _sum_of(fn(tokens, logits[i % LAYERS], bias, live)), jnp.float32(0))
+    def many(tokens, logits, bias, live, stand_in):
+        def call(i, carry):
+            out, stand_in = fn(tokens, logits[i % LAYERS], bias, live, carry[1])
+            return carry[0] + _sum_of(out), stand_in
+
+        return jax.lax.fori_loop(0, calls, call, (jnp.float32(0), stand_in))[0]
 
     program = jax.jit(many)
     program(*operands).block_until_ready()
@@ -167,13 +204,13 @@ def empty_body(cell: Cell, operands, whole: bool):
     call's logits added (so the loop cannot hoist it), or the tokens."""
     import jax
 
-    tokens, logits, bias, live = operands
+    tokens, logits, bias, live, _ = operands
     ready = jax.jit(plan_of(cell, "sorted"))(logits[0], bias, live)
 
-    def fn(tokens, logits, bias, live):
+    def fn(tokens, logits, bias, live, stand_in):
         if whole:
-            return tokens + logits[0, 0].astype(tokens.dtype)
-        return jax.tree_util.tree_map(lambda leaf: leaf + logits[0, 0].astype(leaf.dtype), ready)
+            return tokens + logits[0, 0].astype(tokens.dtype), stand_in
+        return jax.tree_util.tree_map(lambda leaf: leaf + logits[0, 0].astype(leaf.dtype), ready), stand_in
 
     return fn
 
@@ -197,7 +234,7 @@ def main(argv=None) -> None:
     routers = TINY if args.rehearse else CELLS
     cells = TINY if args.rehearse else {name: CELLS[name] for name in args.cell.split(",")}
     calls, repeats = (2, 1) if args.rehearse else (CALLS, args.repeats)
-    kernel = "pallas_interpret" if args.rehearse else "kernel"
+    kernel, ways = ("pallas_interpret", "pallas_interpret") if args.rehearse else ("kernel", "live_rows")
 
     shapes = [(name, cell, rows, live_rows) for name, cell in cells.items() for rows, live_rows in ((cell.narrow, cell.narrow), (cell.tile, cell.tile_live))]
     shapes += [(name, routers[name], rows, live_rows) for rows, live_rows, name in (TINY_EDGES if args.rehearse else EDGE_SHAPES)]
@@ -205,11 +242,11 @@ def main(argv=None) -> None:
     for name, cell, rows, live_rows in shapes:
         if not kernel_fits(rows, cell.experts, cell.k):
             continue
-        wrong = differences(cell, rows, live_rows, kernel, args.seed)
+        wrong = differences(cell, rows, live_rows, kernel, ways, args.seed)
         checked.append([name, rows, cell.experts, cell.k])
         if wrong:
             failed.append({"cell": name, "S": rows, "E": cell.experts, "k": cell.k, "differ": wrong})
-    print(json.dumps({"check": "kernel == sorted", "device": device, "shapes": checked, "failed": failed}), flush=True)
+    print(json.dumps({"check": "kernel == sorted, live_rows == gather", "device": device, "shapes": checked, "failed": failed}), flush=True)
     if failed:
         sys.exit(1)
 
@@ -219,12 +256,12 @@ def main(argv=None) -> None:
             overhead = {whole: bench(empty_body(cell, operands, whole), operands, calls, repeats) for whole in (False, True)}
             line = {"cell": name, "window": window, "device": device, "S": rows, "E": cell.experts, "k": cell.k, "live": live_rows,
                     "overhead_us": {key: round(1e6 * overhead[whole], 2) for key, whole in (("plan_us", False), ("block_us", True))}}
-            for impl in ("sorted", kernel):
-                if impl != "sorted" and not kernel_fits(rows, cell.experts, cell.k):
+            for form, impl, combine in (("sorted", "sorted", "gather"), ("kernel", kernel, "gather"), ("live_rows", kernel, ways)):
+                if form != "sorted" and not kernel_fits(rows, cell.experts, cell.k):
                     continue
-                line["sorted" if impl == "sorted" else "kernel"] = {
-                    key: round(1e6 * (bench(block(cell, impl, whole), operands, calls, repeats) - overhead[whole]), 2)
-                    for key, whole in (("plan_us", False), ("block_us", True))
+                line[form] = {
+                    key: round(1e6 * (bench(block(cell, impl, whole, combine), operands, calls, repeats) - overhead[whole]), 2)
+                    for key, whole in (("block_us", True),) + ((("plan_us", False),) if form != "live_rows" else ())  # the plan is ``kernel``'s
                 }
             print(json.dumps(line), flush=True)
 
