@@ -353,19 +353,20 @@ _decoder_cache: Dict[Tuple, Tuple] = {}
 def _refuse_state_layers(cfg, what: str) -> None:
     """Every key and value of a row is the only state ``what`` knows of. A
     model with recurrent-state, short-convolution, sliding-window or latent-attention layers
-    (``layer_types`` naming ``linear``, ``ssm``, ``conv``, ``window`` or ``latent``) is refused
+    (``layer_types`` naming ``linear``, ``ssm``, ``conv``, ``window``, ``window_latent``, ``latent`` or ``sparse_latent``) is refused
     where it is built, with the missing piece named."""
     if has_state_layers(cfg):
         raise NotImplementedError(
             f"{what} does not support a model with recurrent-state (linear-attention or state-space), short-convolution or sliding-window layers: "
             "it would need a snapshot of each row's recurrent state and convolution tail (a conv layer's: the tail alone), or a window layer's "
-            "masks, sinks and heads of their own, beside its keys and values, which only the paged server's "
+            "masks, sinks and heads of their own (a window_latent layer's: its ring of latents), beside its keys and values, which only the paged server's "
             "per-slot store keeps (serve through init_inference(...).serve())"
         )
     if has_latent_layers(cfg):
         raise NotImplementedError(
             f"{what} does not support a model with latent-attention layers: a row's latents live in the paged "
-            "server's latent pages (kv_pool.StateStore.latent, one entry a token and no value array), which this "
+            "server's latent pages (kv_pool.StateStore.latent, one entry a token and no value array; a sparse_latent layer's "
+            "indexer keys beside them, StateStore.index), which this "
             "path neither allocates, copies, rolls back nor reads (serve through init_inference(...).serve())"
         )
 
@@ -1233,7 +1234,10 @@ def build_ragged_step(cfg, rows: int, width: int, page_size: int,
         # buffers beside the pages. Chosen here, when the program is built: a
         # uniform model's step below is traced as it always was
         if tp is not None:
-            raise NotImplementedError("tensor-parallel serving of a hybrid (multi-kind) layer stack is not supported")
+            raise NotImplementedError(
+                f"tensor-parallel serving of a hybrid (multi-kind) layer stack is not supported: layer_types names {sorted(set(cfg.layer_types))}, "
+                "and nothing shards a kind's heads with its own pool (pages, rings, latents, an indexer's keys, a slot's state)"
+            )
         from deepspeed_tpu.inference.hybrid_decode import build_hybrid_ragged_step
 
         return build_hybrid_ragged_step(cfg, rows, W, page_size, attn_impl, telemetry, name, key)

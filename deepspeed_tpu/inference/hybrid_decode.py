@@ -141,7 +141,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.inference import decode
-from deepspeed_tpu.inference.kv_pool import StateStore, heads_per_group, page_shapes
+from deepspeed_tpu.inference.kv_pool import StateStore, heads_per_group, key_lanes, page_shapes
 from deepspeed_tpu.models import hybrid_moe as hm
 from deepspeed_tpu.models.transformer import _norm
 from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked, kda_decode
@@ -182,6 +182,23 @@ def window_shapes(cfg, max_slots: int, page_size: int, ring: int):
     NKV = cfg.window_num_kv_heads
     f = heads_per_group(cfg.head_dim, cfg.v_head_dim, NKV)
     return page_shapes(cfg.layers_of("window"), 1 + max_slots * ring, NKV, page_size, cfg.head_dim, cfg.v_head_dim, f)
+
+
+def paged_latent_shapes(cfg, num_pages: int, page_size: int):
+    """``(latent, index)``: the pages of the layers that keep one entry a token
+    under the page table (``cfg.paged_latent_kind``: an entry at whole lane
+    tiles, 576 at 640) and, of a model whose such layers are ``sparse_latent``
+    ones, the indexer's keys beside them; None for what the model has not."""
+    kind = cfg.paged_latent_kind
+    if kind is None:
+        return None, None
+    latent = (cfg.layers_of(kind), num_pages, page_size, key_lanes(cfg.latent_width))
+    return latent, latent[:3] + (cfg.index_head_dim,) if kind == "sparse_latent" else None
+
+
+def window_latent_shape(cfg, max_slots: int, page_size: int, ring: int):
+    """The window_latent layers' rings: ``ring`` pages a slot behind the trash page, an entry the kind's latent at whole lane tiles (1,088 at 1,152)."""
+    return (cfg.layers_of("window_latent"), 1 + max_slots * ring, page_size, key_lanes(cfg.latent_dims("window_latent").width))
 
 
 def layer_of(tree, per, j: int):
@@ -253,7 +270,7 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
 
     B, T = tokens.shape
     dtype = k_pages.dtype
-    state, conv, wk, wv, latent = store
+    state, conv, wk, wv, latent, index, latent_rings = store
     tile = decode.token_tile(cfg)
     tiled = bool(tile) and B * T > tile
     packed = decode._pack_window(q_lens, B, T, tile) if tiled else _whole_slab(q_lens, B, T)
@@ -289,6 +306,8 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         chunk_rows = q_lens > 1
         n_chunk_rows = jnp.sum(chunk_rows, dtype=jnp.int32)
         order = jnp.argsort(~chunk_rows, stable=True).astype(jnp.int32)
+    if latent_rings is not None:
+        ring = (latent_rings.shape[1] - 1) // B  # ring pages a slot (``ring_latent_attention`` finds a position's page itself)
     if wk is not None:
         # the rings as a page table: slot i of row r on page i % ring of the row's own
         ring = (wk.shape[1] - 1) // B
@@ -511,6 +530,78 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
         return x, pages, counts
 
+    def selective_latent_layer(kind, x, *args):
+        """A ``sparse_latent`` or a ``window_latent`` layer (``ops/transformer/
+        sparse_latent_attention.py``): ``tree``, ``per``, ``jk``, ``layer`` and
+        ``ffn`` as ``attention_layer``'s behind the kind's pools (the latent
+        pages and the indexer's keys, or the rings of latents). The WHOLE
+        mixer, from the norm to the output projection, runs a row group at a
+        time and not a token tile at a time: the rows with one token together,
+        then a row with a chunk a trip of a loop whose count is data. A buffer
+        of absorbed queries a packed token (128 heads x 576 numbers) would be
+        2.4 GB for a 32 x 512 window, so no buffer holds more of a token than
+        its mixer's output ``[NPK, H]``; a chunk's 512 tokens hide the stream
+        of the layer's weights they meet. Returns ``(x, *pools, counts)``."""
+        from deepspeed_tpu.ops.transformer.sparse_latent_attention import ring_latent_attention, sparse_latent_attention
+
+        *held, tree, per, jk, layer, ffn = args
+        d = cfg.latent_dims(kind)
+        softmax_scale = cfg.attn_softmax_scale if cfg.attn_softmax_scale is not None else d.scale
+        H = cfg.hidden_size
+
+        def mixer(p, x_rows, pos, held, table, row_slots, kv, ql):
+            """``x_rows`` [R, W, H] at ``pos`` [R, W]: the mixer's output [R, W, H] and the pools."""
+            h = _norm(x_rows, p["attn_norm_scale"], None, cfg.norm, cfg.norm_eps)
+            c_q = hm.latent_query_rank(cfg, p, h, kind)
+            q = hm.latent_queries(cfg, p, c_q, pos, kind)
+            q = hm.latent_absorb(cfg, p, q[..., : d.nope], q[..., d.nope :], kind)  # against the stored entry
+            entry = hm.latent_entry(cfg, p, h, pos, kind)
+            if kind == "sparse_latent":
+                with jax.named_scope("sparse_index"):
+                    qi, wi = hm.index_queries(cfg, p, c_q, h, pos)
+                    ki = hm.index_key(cfg, p, h, pos)
+                o, *held = sparse_latent_attention(
+                    q, qi, wi, entry, ki, *held, layer, table, kv, ql, topk=cfg.index_topk, value_lanes=d.kv_rank, scale=softmax_scale,
+                )
+            else:
+                o, *held = ring_latent_attention(
+                    q, entry, *held, layer, row_slots, kv, ql, window=cfg.window, ring=ring, value_lanes=d.kv_rank, scale=softmax_scale,
+                )
+            return hm.latent_output(cfg, p, o, kind, h=h).astype(dtype), held
+
+        with jax.named_scope(hm.SCOPES[kind]):
+            p = weights_at(tree, per, jk, jnp.int32(0))  # one slice a leaf, once a layer: no tile loop reads them
+            x1 = x[starts] if T == 1 else real_rows(x, starts, one_token)
+            o1, held = mixer(p, x1[:, None], lengths[:, None], held, page_table, slots, jnp.where(one_token, kv_lens, 0), one_token.astype(jnp.int32))
+            o1 = o1[:, 0]  # [B, H]
+            if T == 1:
+                out = functools.partial(slab_rows, o1)
+            else:
+
+                def chunk_row(i, carry):
+                    *held, chunks = carry
+                    r = order[i]
+                    idx = packed.index[r]  # [T]
+                    valid = jnp.arange(T, dtype=jnp.int32) < q_lens[r]
+                    o_row, held = mixer(
+                        p, real_rows(x, idx, valid)[None], (lengths[r] + jnp.arange(T, dtype=jnp.int32))[None], held,
+                        page_table[r][None], slots[r][None], kv_lens[r][None], q_lens[r][None],
+                    )
+                    return (*held, put_chunk(chunks, o_row[0], idx[0], valid))
+
+                *held, chunks = jax.lax.fori_loop(0, n_chunk_rows, chunk_row, (*held, unfilled((NPK, H), dtype)))
+                out = functools.partial(rows_output, chunks, o1)
+
+        def after(start, carry):
+            x, counts = carry
+            with jax.named_scope(hm.SCOPES[kind]):
+                x_tile = packed.take(x, start) + branch(out(start).astype(x.dtype))
+            x_tile, tile_counts = ffn(x_tile[None], start)
+            return put(x, x_tile[0], start), counts + tile_counts
+
+        x, counts = tiles(after, (x, jnp.zeros((E,), jnp.int32)))
+        return (x, *held, counts)
+
     def linear_layer(x, st, cv, tree, per, jl, layer, ffn):
         """A linear layer: ``tree``, ``per``, ``jl``, ``layer`` (its entry of
         the state store) and ``ffn`` as ``attention_layer``'s; a row with one
@@ -707,8 +798,14 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
     state_kind = cfg.state_kind or "linear"  # the kind whose states and tails the store's two fields hold
     pools = {"softmax": (k_pages, v_pages), "window": (wk, wv), "latent": (latent,),
              state_kind: (conv,) if state_kind == "conv" else (state, conv)}
+    if index is not None:  # the paged latent layers are sparse ones: the latents and the indexer's keys, written together
+        pools["sparse_latent"] = (latent, index)
+    if latent_rings is not None:
+        pools["window_latent"] = (latent_rings,)
     mixers = {"softmax": functools.partial(attention_layer, "softmax"), "window": functools.partial(attention_layer, "window"),
-              "latent": latent_layer, "linear": linear_layer, "ssm": ssm_layer, "conv": conv_layer}
+              "latent": latent_layer, "linear": linear_layer, "ssm": ssm_layer, "conv": conv_layer,
+              "sparse_latent": functools.partial(selective_latent_layer, "sparse_latent"),
+              "window_latent": functools.partial(selective_latent_layer, "window_latent")}
     # the leading dense layers, each with its own weights and the first entries of its kind's pools
     for i, kind in enumerate(cfg.layer_types[: cfg.leading_dense_layers]):
         lead = params["leading"][i]
@@ -751,28 +848,32 @@ def _hybrid_layers(cfg, params, tokens, k_pages, v_pages, store, page_table, len
         pools[kind] = tuple(written)
         counts = jnp.concatenate([counts, c[None]])
     held = (None, *pools["conv"]) if state_kind == "conv" else pools[state_kind]
-    store = StateStore(*held, *pools["window"], *pools["latent"])
+    paged = pools["sparse_latent"] if index is not None else (*pools["latent"], None)
+    store = StateStore(*held, *pools["window"], *paged, *pools.get("window_latent", (None,)))
     return x, *pools["softmax"], store, counts, packed
 
 
 def hybrid_forward(cfg, params, tokens, k_pages, v_pages, state, conv, page_table, lengths, q_lens, slots,
-                   attn_impl: str = "auto", window=None, latent=None):
+                   attn_impl: str = "auto", window=None, latent=None, index=None, latent_rings=None):
     """``decode._paged_forward`` for a hybrid model: the window's logits
     ``[B, T, V]`` (a dead slot's are some live token's) and the pools:
     ``(logits, k_pages, v_pages, state, conv, moe_counts)`` and, for a model
     with sliding-window layers, whose rings ``window = (window_k, window_v)``
     gives, the rings after them; for a model with latent layers, whose pages
-    ``latent`` gives, those pages last. What the parity tests and the benchmark's
+    ``latent`` gives, those pages last; behind them a sparse model's indexer
+    keys (``index``) and a model's rings of latents (``latent_rings``), each
+    where given. What the parity tests and the benchmark's
     logits tools compare with the reference; the serving step takes its
     arg-max on the packed tiles instead."""
     x, kp, vp, store, moe_counts, packed = _hybrid_layers(
-        cfg, params, tokens, k_pages, v_pages, StateStore(state, conv, *(window or (None, None)), latent), page_table,
+        cfg, params, tokens, k_pages, v_pages, StateStore(state, conv, *(window or (None, None)), latent, index, latent_rings), page_table,
         lengths, q_lens, slots, attn_impl,
     )
     out = (decode._final_logits(cfg, params, packed.expand(x)), kp, vp, store.state, store.conv, moe_counts)
     if window is not None:
         out += ((store.window_k, store.window_v),)
-    return out if latent is None else out + (store.latent,)
+    out += tuple(after for given, after in ((latent, store.latent), (index, store.index), (latent_rings, store.window_latent)) if given is not None)
+    return out
 
 
 def build_hybrid_ragged_step(cfg, rows: int, width: int, page_size: int, attn_impl: str, telemetry, name: str, key):

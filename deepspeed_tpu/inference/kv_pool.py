@@ -264,7 +264,14 @@ class StateStore(NamedTuple):
       (the pool's free list, tables and trash page 0 serve both), an entry at
       ``key_lanes`` of its width. It rides here because the serving step
       takes and returns this store in place; ``None`` for a model with no
-      such layer.
+      such layer. A ``sparse_latent`` layer's entries lie here too, and
+      ``index`` holds its indexer's key of the same token (``index_head_dim``
+      numbers) under the same page ids: the two are written together, and a
+      step reads a row's live indexer keys and then only the CHOSEN latents.
+    * ``window_latent`` layers: ``window_latent`` is a ring a slot like
+      ``window_k``'s, whose entries are latents ``[c_kv ; k_rope]`` of the
+      kind's own rank (no value array, no heads); a model has rings of one
+      of the two kinds.
 
     A model may have state layers AND latent layers and no layer with keys
     and values a head: the pool's K and V arrays are then empty, a page id
@@ -276,13 +283,16 @@ class StateStore(NamedTuple):
     conv: jax.Array  # [state layers, max_slots + 1, K - 1, 3, NH, D] (ssm and conv: [.., K - 1, tail_rows(C), 128])
     window_k: Optional[jax.Array] = None  # [window layers, 1 + max_slots * ring, NKV, P, Dk] (narrow heads: NKV / f, P, f Dk)
     window_v: Optional[jax.Array] = None
-    latent: Optional[jax.Array] = None  # [latent layers, num_pages, P, lanes]
+    latent: Optional[jax.Array] = None  # [latent (or sparse_latent) layers, num_pages, P, lanes]
+    index: Optional[jax.Array] = None  # [sparse_latent layers, num_pages, P, index_head_dim]: the indexer's keys, beside the latents they index
+    window_latent: Optional[jax.Array] = None  # [window_latent layers, 1 + max_slots * ring, P, lanes]: rings of latents
 
     def window_bytes(self) -> int:
-        return 0 if self.window_k is None else self.window_k.nbytes + self.window_v.nbytes
+        return sum(a.nbytes for a in (self.window_k, self.window_v, self.window_latent) if a is not None)
 
     def latent_bytes(self) -> int:
-        return 0 if self.latent is None else self.latent.nbytes
+        """The bytes under the page table beside K and V: the latents and, of a sparse layer, the indexer's keys."""
+        return sum(a.nbytes for a in (self.latent, self.index) if a is not None)
 
     def state_bytes(self) -> int:
         """The per-slot entries' bytes: the states, where the kind keeps one, and the convolution tails."""
@@ -297,15 +307,17 @@ def _refuse_with_state(states, what: str) -> None:
         raise NotImplementedError(
             f"{what} is not supported for a model with latent-attention layers: the pool copies, shares and rolls "
             "back pages of the K and V arrays (kv_pool._copy_page, the prefix index), and a latent layer's pages are a "
-            "third array (StateStore.latent) that none of them knows yet"
+            "third array (StateStore.latent; a sparse_latent layer's indexer keys a fourth, StateStore.index) that none "
+            "of them knows yet"
         )
-    if states is not None and (states.conv.size or states.window_k is not None):  # every state kind keeps a tail
+    rings = states is not None and (states.window_k is not None or states.window_latent is not None)
+    if states is not None and (states.conv.size or rings):  # every state kind keeps a tail
         raise NotImplementedError(
             f"{what} is not supported for a model with recurrent-state or sliding-window layers: keys and values "
             "of a full-attention layer can be shared, copied or rolled back a page at a time; the recurrent state "
             "of a row (or a convolution's tail, where a layer keeps that alone) cannot without a snapshot of it at "
-            "that position, nor can a window layer's page ring, which "
-            "holds a row's newest positions only, and the per-slot store keeps neither"
+            "that position, nor can a window layer's page ring (of keys and values a head, or of a window_latent "
+            "layer's latents), which holds a row's newest positions only, and the per-slot store keeps neither"
         )
 
 
@@ -363,26 +375,28 @@ class PagePool:
         self.query_heads: dict = {}  # a layer kind's query heads, for the memory report
         self.state_kind: Optional[str] = None  # the kind whose layers' states the store holds: linear | ssm | conv (tails alone)
         if getattr(cfg, "layer_types", None):
-            from deepspeed_tpu.inference.hybrid_decode import state_shapes, window_shapes
+            from deepspeed_tpu.inference.hybrid_decode import paged_latent_shapes, state_shapes, window_latent_shape, window_shapes
 
             shapes = state_shapes(cfg, self.max_slots)
             self.state_kind = cfg.state_kind
             kv_dtype = self.cache.k_pages.dtype
-            self.query_heads = {kind: cfg.heads_of(kind) for kind in ("softmax", "window")}
-            rings = (None, None)
-            if cfg.layers_of("window"):
+            ring_kind = "window_latent" if cfg.layers_of("window_latent") else "window"
+            self.query_heads = {"softmax": cfg.heads_of("softmax"), "window": cfg.heads_of(ring_kind)}
+            rings, latent_ring = (None, None), None
+            if cfg.layers_of(ring_kind):
                 if not prefill_chunk:
                     raise ValueError("a model with sliding-window layers needs prefill_chunk to size its page rings")
                 self.window_ring = window_ring_pages(cfg.window, self.page_size, int(prefill_chunk))
                 self.window_keys = cfg.window
-                self.window_kv_heads = cfg.kv_heads_of("window")
-                rings = tuple(jnp.zeros(shape, kv_dtype) for shape in window_shapes(cfg, self.max_slots, self.page_size, self.window_ring))
-            latent = None
-            if cfg.layers_of("latent"):
-                # one entry a token under the pool's own page ids: no value array
-                latent = jnp.zeros((cfg.layers_of("latent"), num_pages, self.page_size, key_lanes(cfg.latent_width)), kv_dtype)
+                if ring_kind == "window":
+                    self.window_kv_heads = cfg.kv_heads_of("window")
+                    rings = tuple(jnp.zeros(shape, kv_dtype) for shape in window_shapes(cfg, self.max_slots, self.page_size, self.window_ring))
+                else:  # rings of latents: no heads, no value array
+                    latent_ring = jnp.zeros(window_latent_shape(cfg, self.max_slots, self.page_size, self.window_ring), kv_dtype)
+            # one entry a token under the pool's own page ids, no value array; a sparse layer's indexer key beside it
+            latent, index = (None if shape is None else jnp.zeros(shape, kv_dtype) for shape in paged_latent_shapes(cfg, num_pages, self.page_size))
             state = None if shapes.state is None else jnp.zeros(shapes.state, jnp.float32)
-            self.states = StateStore(state, jnp.zeros(shapes.conv, kv_dtype), *rings, latent)
+            self.states = StateStore(state, jnp.zeros(shapes.conv, kv_dtype), *rings, latent, index, latent_ring)
         # LIFO free list keeps hot pages hot; page 0 stays out of circulation
         self._free = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._free_slots = list(range(max_slots - 1, -1, -1))
@@ -434,9 +448,11 @@ class PagePool:
 
     @property
     def latent_bytes_per_token(self) -> int:
-        """HBM bytes one cached token costs across the latent layers: one entry a layer, no value."""
-        latent = None if self.states is None else self.states.latent
-        return 0 if latent is None else latent.shape[0] * latent.shape[-1] * latent.dtype.itemsize
+        """HBM bytes one cached token costs across the latent layers: one entry a layer, no value; in a sparse layer
+        its indexer's key too."""
+        if self.states is None:
+            return 0
+        return sum(a.shape[0] * a.shape[-1] * a.dtype.itemsize for a in (self.states.latent, self.states.index) if a is not None)
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -493,28 +509,33 @@ class PagePool:
                 "state_slots": self.max_slots,
                 "state_slots_in_use": in_use,
             }
-            if self.states.window_k is not None:
+            ring = self.states.window_k if self.states.window_latent is None else self.states.window_latent
+            if ring is not None:
                 # the same for a row of any length: ring pages a slot, not pages a token
                 state.update(
                     window_total_bytes=self.states.window_bytes(),
                     window_bytes_per_slot=self.states.window_bytes() // (1 + self.max_slots * self.window_ring) * self.window_ring,
                     window_ring_pages=self.window_ring,
                     window_keys=self.window_keys,
-                    window_layers=self.states.window_k.shape[0],
+                    window_layers=ring.shape[0],
                     window_query_heads=self.query_heads["window"],
                     window_kv_heads=self.window_kv_heads,
                     window_slots=self.max_slots,
                     window_slots_in_use=in_use,
                 )
+                if self.states.window_latent is not None:
+                    state.update(window_latent_lanes=ring.shape[-1])  # a ring entry is a latent at whole lane tiles, no head
             if self.states.latent is not None:
                 latent = self.states.latent
                 state.update(
-                    latent_total_bytes=latent.nbytes,
+                    latent_total_bytes=self.states.latent_bytes(),
                     latent_bytes_per_token=self.latent_bytes_per_token,
                     latent_layers=latent.shape[0],
                     latent_lanes=latent.shape[-1],  # an entry's stored width (its own at whole lane tiles)
                     latent_live_bytes=cache["latent_bytes_in_use"],
                 )
+                if self.states.index is not None:  # a sparse layer's indexer keys, counted in the per-token and live bytes above
+                    state.update(index_total_bytes=self.states.index.nbytes, index_lanes=self.states.index.shape[-1])
         return {
             **state,
             "kv_total_bytes": self.cache.hbm_bytes(),
@@ -949,6 +970,8 @@ class PagePool:
         )
         if self.states is not None and self.states.latent is not None:
             self.states = self.states._replace(latent=self.states.latent[:, gather])
+            if self.states.index is not None:
+                self.states = self.states._replace(index=self.states.index[:, gather])
         for s in range(self.max_slots):
             for i in range(int(self._owned[s])):
                 self.page_table[s, i] = remap[int(self.page_table[s, i])]

@@ -500,7 +500,7 @@ class PagedServer:
                 self.stats["moe_routed_assignments"] = 0  # held or not; moe_assignments: the held
         if self.pool.states is not None:
             self._g_state_slots = self.metrics.gauge("serve.state_slots_in_use")
-            if self.pool.states.window_k is not None:
+            if self.pool.window_ring:
                 self._g_window_slots = self.metrics.gauge("serve.window_slots_in_use")
 
     # --- request intake -------------------------------------------------
@@ -1111,6 +1111,16 @@ class PagedServer:
                 kv_tokens=int(live_kv.sum()), live_tokens=live_tokens, token_tiles=tiles,
                 row_lens=_row_lens(live_q, live_kv),
             )
+            topk = getattr(self.cfg, "index_topk", 0)
+            if topk:
+                # a model whose full layers attend chosen keys: what its queries may see and what they attend of it (a
+                # query at position p has p + 1 live keys and attends min(p + 1, index_topk))
+                q, kv = live_q.astype(np.int64), live_kv.astype(np.int64)
+                below = np.clip(topk - (kv - q), 0, q)  # of a row's q queries, those that see at most index_topk keys: all of them
+                pack_span.set(
+                    keys_live=int((q * kv - q * (q - 1) // 2).sum()),
+                    keys_chosen=int((below * (kv - q) + below * (below + 1) // 2 + (q - below) * topk).sum()),
+                )
             host_made = [page_table, lengths, q_lens]
             states = self.pool.states
             if states is not None:
@@ -1119,7 +1129,7 @@ class PagedServer:
                 slots[: len(rows)] = [r.slot for r in rows]
                 host_made.append(slots)
                 self._g_state_slots.set(len(rows))
-                if states.window_k is not None:
+                if self.pool.window_ring:
                     self._g_window_slots.set(len(rows))
             # the window with the in-flight rows' tokens laid in on the device
             # (queued behind the step that computes them), and the rest

@@ -165,16 +165,18 @@ def has_state_layers(cfg) -> bool:
     layers, something of a row that exists at the row's newest positions only
     (``models/hybrid_moe.py``: a ``layer_types`` that names ``linear`` or
     ``ssm``, whose layers keep a recurrent state, ``conv``, whose layers keep
-    a convolution's tail, or ``window``, whose layers keep a ring of the
-    newest pages)."""
-    return bool({"linear", "ssm", "conv", "window"} & set(getattr(cfg, "layer_types", None) or ()))
+    a convolution's tail, or ``window`` / ``window_latent``, whose layers keep
+    a ring of the newest pages: keys and values a head, or latents)."""
+    return bool({"linear", "ssm", "conv", "window", "window_latent"} & set(getattr(cfg, "layer_types", None) or ()))
 
 
 def has_latent_layers(cfg) -> bool:
-    """Whether some layer keeps one latent entry a token in place of keys and
-    values a head (``layer_types`` naming ``latent``): pages of a third array,
-    which what copies, shares or rolls back K and V pages does not know."""
-    return "latent" in (getattr(cfg, "layer_types", None) or ())
+    """Whether some layer keeps one latent entry a token under the page table
+    in place of keys and values a head (``layer_types`` naming ``latent``, or
+    ``sparse_latent``, which keeps its indexer's key beside it): pages of a
+    third (and a fourth) array, which what copies, shares or rolls back K and V
+    pages does not know."""
+    return bool({"latent", "sparse_latent"} & set(getattr(cfg, "layer_types", None) or ()))
 
 
 def gpt2_config(size: str = "125m", **overrides) -> TransformerConfig:

@@ -115,7 +115,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -125,14 +125,42 @@ from deepspeed_tpu.compression.int8 import qmatmul
 from deepspeed_tpu.models.moe_transformer import MoETransformerConfig, MoETransformerLM
 from deepspeed_tpu.models.transformer import _norm
 
-LAYER_KINDS = ("softmax", "linear", "window", "latent", "ssm", "conv")  # the mixers
+LAYER_KINDS = ("softmax", "linear", "window", "latent", "ssm", "conv", "sparse_latent", "window_latent")  # the mixers
 FFN_BLOCK = "ffn"  # in ``layer_types``: a block that is the FFN alone; a list that names one has nothing behind its mixers
 # the named scope around a kind's mixer, which the benchmark's readers find device time by
 SCOPES = {"softmax": "attention", "linear": "linear_attention", "window": "window_attention", "latent": "latent_attention",
-          "ssm": "ssm_mixer", "conv": "conv_mixer"}
+          "ssm": "ssm_mixer", "conv": "conv_mixer", "sparse_latent": "sparse_latent_attention",
+          "window_latent": "window_latent_attention"}
+# the kinds that keep one low-rank entry a token: under the page table (``latent``; ``sparse_latent``, with an indexer's key
+# beside it) or in a ring a slot (``window_latent``)
+LATENT_KINDS = ("latent", "sparse_latent", "window_latent")
+WINDOW_KINDS = ("window", "window_latent")  # the kinds whose query sees the newest ``window`` keys, kept in a ring a slot
 # the kinds whose layers keep something a SLOT whatever the row's length: a recurrent state and a convolution tail, or
 # (``conv``) a convolution tail alone
 STATE_KINDS = ("linear", "ssm", "conv")
+
+
+class LatentDims(NamedTuple):
+    """What a latent layer of one kind is made of (``HybridMoEConfig.latent_dims``)."""
+
+    heads: int
+    q_rank: int  # 0: the query has no low rank
+    kv_rank: int  # an entry's value part, ``c_kv``
+    nope: int
+    rope: int  # the part all heads share, beside ``c_kv`` in an entry
+    v: int
+    theta: float
+    q_rescale: float  # the normed low ranks times these; 1.0: nothing
+    kv_rescale: float
+
+    @property
+    def width(self) -> int:
+        """What the layer keeps of a token: ``[c_kv ; k_rope]``."""
+        return self.kv_rank + self.rope
+
+    @property
+    def scale(self) -> float:
+        return float(self.nope + self.rope) ** -0.5
 
 
 @dataclasses.dataclass
@@ -142,10 +170,10 @@ class HybridMoEConfig(MoETransformerConfig):
     layer_types: Optional[Sequence[str]] = None
     leading_dense_layers: int = 0  # layers in front whose FFN is dense (``intermediate_size``), not routed
     attn_output_gate: bool = False  # softmax layers: attn * sigmoid(h Wg) before Wo, a gate a feature
-    attn_head_gate: bool = False  # softmax and window layers: one sigmoid scalar a head on its output, before Wo
+    attn_head_gate: bool = False  # softmax, window and every latent kind's layers: one sigmoid scalar a head on its output, before Wo
     v_head_dim: int = 0  # a value head's width; 0: head_dim
     attn_value_scale: float = 1.0  # v times this, before P v
-    window: int = 0  # window layers: query i sees keys j with i - window < j <= i
+    window: int = 0  # window and window_latent layers: query i sees keys j with i - window < j <= i
     window_num_heads: int = 0  # a window layer's query heads; 0: num_heads
     window_num_kv_heads: int = 0  # 0: num_kv_heads
     window_rope_theta: float = 0.0  # 0: rope_theta
@@ -168,6 +196,20 @@ class HybridMoEConfig(MoETransformerConfig):
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
+    # every latent kind: the normed low ranks times (hidden_size / rank)^0.5 (``apply_mla_qkv_lora_rescale``); off: times
+    # 1.0, which puts nothing into a program
+    latent_lora_rescale: bool = False
+    # window_latent layers: a latent layer's widths of their own (heads: ``window_num_heads``; theta: ``window_rope_theta``)
+    window_q_lora_rank: int = 0
+    window_kv_lora_rank: int = 0
+    window_qk_nope_head_dim: int = 0
+    window_qk_rope_head_dim: int = 0
+    window_v_head_dim: int = 0  # 0: v_head_dim
+    # sparse_latent layers: the indexer's heads and their width, and the keys a query attends (the highest-scoring
+    # ``index_topk`` causal ones; all of them while there are no more)
+    index_num_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # the router
     moe_scoring: str = "softmax"  # softmax | sigmoid
     moe_select_bias: bool = False  # a learned bias added to the scores for the choice alone
@@ -213,7 +255,7 @@ class HybridMoEConfig(MoETransformerConfig):
             raise ValueError("rope_yarn_factor needs position='rope', a factor above 1 and rope_yarn_original_positions")
         if self.attn_output_gate and self.attn_head_gate:
             raise ValueError("attn_output_gate (a gate a feature) and attn_head_gate (a gate a head) are two forms of one gate: name one")
-        if "window" in self.layer_types and self.window < 1:
+        if set(WINDOW_KINDS) & set(self.layer_types) and self.window < 1:
             raise ValueError("a window layer needs window >= 1")
         if not 0 <= self.leading_dense_layers < self.num_layers:
             raise ValueError(f"leading_dense_layers={self.leading_dense_layers} of {self.num_layers} layers")
@@ -223,6 +265,29 @@ class HybridMoEConfig(MoETransformerConfig):
                 raise ValueError(
                     "a latent layer needs kv_lora_rank, qk_nope_head_dim and qk_rope_head_dim, head_dim = the last two's sum "
                     f"and q_lora_rank >= 0 (0: no low rank): got {sizes}, q_lora_rank={self.q_lora_rank}, head_dim={self.head_dim}"
+                )
+        self.window_v_head_dim = self.window_v_head_dim or self.v_head_dim
+        if "sparse_latent" in self.layer_types:
+            sizes = (self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.q_lora_rank, self.index_num_heads, self.index_topk)
+            if min(sizes) < 1 or self.index_head_dim < self.qk_rope_head_dim or self.qk_nope_head_dim + self.qk_rope_head_dim != self.head_dim:
+                raise ValueError(
+                    "a sparse_latent layer needs a latent layer's widths with a low-rank query (the indexer's queries come from it), "
+                    f"index_num_heads, index_topk and index_head_dim >= qk_rope_head_dim (its rotated part): got {sizes}, "
+                    f"index_head_dim={self.index_head_dim}, head_dim={self.head_dim}"
+                )
+            if self.position != "rope" or "latent" in self.layer_types:
+                raise NotImplementedError(
+                    "a sparse_latent layer rotates its indexer (position='rope'), and a model's paged latent layers are of ONE kind "
+                    "(kv_pool.StateStore.latent holds the latent layers' pages or the sparse_latent layers', not both)"
+                )
+        if "window_latent" in self.layer_types:
+            sizes = (self.window_kv_lora_rank, self.window_qk_nope_head_dim, self.window_qk_rope_head_dim)
+            if min(sizes) < 1 or self.window_q_lora_rank < 0:
+                raise ValueError(f"a window_latent layer needs window_kv_lora_rank, window_qk_nope_head_dim and window_qk_rope_head_dim: got {sizes}")
+            if "window" in self.layer_types:
+                raise NotImplementedError(
+                    "a model with window layers AND window_latent layers: the per-slot store holds ONE kind of ring "
+                    "(kv_pool.StateStore: keys and values a head, or latents); no published model asks for both"
                 )
         self.linear_num_heads = self.linear_num_heads or self.num_heads
         self.linear_head_dim = self.linear_head_dim or self.head_dim
@@ -357,8 +422,19 @@ class HybridMoEConfig(MoETransformerConfig):
         return self.leading_of(kind) + self.num_periods * self.period.count(kind)
 
     def heads_of(self, kind: str) -> int:
-        """Query heads of a softmax, window or latent layer."""
-        return self.window_num_heads if kind == "window" else self.num_heads
+        """Query heads of a softmax, window or latent layer of any kind."""
+        return self.window_num_heads if kind in WINDOW_KINDS else self.num_heads
+
+    def latent_dims(self, kind: str = "latent") -> "LatentDims":
+        """A latent kind's widths: the model's (``latent``, ``sparse_latent``) or the window layers' own."""
+        rescale = lambda rank: (self.hidden_size / rank) ** 0.5 if self.latent_lora_rescale and rank else 1.0
+        if kind == "window_latent":
+            q, kv = self.window_q_lora_rank, self.window_kv_lora_rank
+            return LatentDims(self.window_num_heads, q, kv, self.window_qk_nope_head_dim, self.window_qk_rope_head_dim,
+                              self.window_v_head_dim, float(self.window_rope_theta), rescale(q), rescale(kv))
+        q, kv = self.q_lora_rank, self.kv_lora_rank
+        return LatentDims(self.num_heads, q, kv, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+                          float(self.rope_theta), rescale(q), rescale(kv))
 
     def kv_heads_of(self, kind: str) -> int:
         return self.window_num_kv_heads if kind == "window" else self.num_kv_heads
@@ -392,8 +468,13 @@ class HybridMoEConfig(MoETransformerConfig):
 
     @property
     def latent_width(self) -> int:
-        """What a latent layer keeps of a token: ``[c_kv ; k_rope]``."""
+        """What a latent (or sparse_latent) layer keeps of a token: ``[c_kv ; k_rope]``."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def paged_latent_kind(self) -> Optional[str]:
+        """The kind whose entries lie under the page table (``latent`` or ``sparse_latent``: a model names one at most), or None."""
+        return next((kind for kind in ("latent", "sparse_latent") if kind in self.layer_types), None)
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -584,46 +665,142 @@ def scaled(x, by: float):
     return x if by == 1.0 else x * jnp.asarray(by, x.dtype)
 
 
-def latent_project(cfg: HybridMoEConfig, p, h, positions):
+def latent_project(cfg: HybridMoEConfig, p, h, positions, kind: str = "latent"):
     """A latent layer's projections of the normed ``h`` [B, T, H] at
     ``positions`` [B, T] (``position="none"``: unused, may be None): ``q_nope``
     [B, T, NH, nope], ``q_rope`` [B, T, NH, rope], and what the layer keeps of
     each token, ``[c_kv ; k_rope]`` [B, T, kv_lora_rank + rope]: the normed
     latent and the one key part all heads share; ``q_rope`` and ``k_rope``
     rotated under ``position="rope"``, as projected under ``"none"``. The query
-    through its low rank and norm, or, with ``q_lora_rank`` 0, ``h Wq``."""
+    through its low rank and norm, or, with ``q_lora_rank`` 0, ``h Wq``. The
+    widths are the ``kind``'s (``cfg.latent_dims``); under
+    ``latent_lora_rescale`` both normed low ranks are scaled."""
     from deepspeed_tpu.models.transformer import _rope
 
-    NH, nope, rope, C = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    if cfg.q_lora_rank:
-        q = qmatmul(_norm(qmatmul(h, p["wq_a"]), p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps), p["wq_b"])
+    d = cfg.latent_dims(kind)
+    if d.q_rank:
+        q = qmatmul(latent_query_rank(cfg, p, h, kind), p["wq_b"])
     else:
         # the head split kept apart from the matmul (as ``decode._paged_layers.project`` keeps a softmax layer's): folded into
         # it, the compiler wants ``wq`` head-major and copies the layer's 28 MB to that layout every step
         q = jax.lax.optimization_barrier(qmatmul(h, p["wq"]))
-    q = q.reshape(h.shape[:-1] + (NH, nope + rope))
+    q = q.reshape(h.shape[:-1] + (d.heads, d.nope + d.rope))
+    # the entry spelled out here and not through ``latent_entry``: the accepted latent models' steps trace these operations in
+    # this order (``test_accepted_programs_guard.py``: a fingerprint a family)
     kv = qmatmul(h, p["wkv_a"])
-    c_kv = _norm(kv[..., :C], p["kv_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+    c_kv = scaled(_norm(kv[..., : d.kv_rank], p["kv_norm_scale"], None, "rmsnorm", cfg.norm_eps), d.kv_rescale)
     if cfg.position != "rope":  # nothing is rotated: the shared features as projected
-        return q[..., :nope], q[..., nope:], jnp.concatenate([c_kv, kv[..., C:]], axis=-1)
-    k_rope = _rope(kv[..., None, C:], positions, cfg.rope_theta)[..., 0, :]
-    return q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta), jnp.concatenate([c_kv, k_rope], axis=-1)
+        return q[..., : d.nope], q[..., d.nope :], jnp.concatenate([c_kv, kv[..., d.kv_rank :]], axis=-1)
+    k_rope = _rope(kv[..., None, d.kv_rank :], positions, d.theta)[..., 0, :]
+    return q[..., : d.nope], _rope(q[..., d.nope :], positions, d.theta), jnp.concatenate([c_kv, k_rope], axis=-1)
 
 
-def latent_absorb(cfg: HybridMoEConfig, p, q_nope, q_rope):
+def latent_query_rank(cfg: HybridMoEConfig, p, h, kind: str = "latent"):
+    """``c_q = s_q RMSNorm(h Wq_a)`` [..., q_lora_rank]: the query's low rank, which a sparse layer's indexer reads too."""
+    return scaled(_norm(qmatmul(h, p["wq_a"]), p["q_norm_scale"], None, "rmsnorm", cfg.norm_eps), cfg.latent_dims(kind).q_rescale)
+
+
+def latent_queries(cfg: HybridMoEConfig, p, c_q, positions, kind: str = "latent"):
+    """``c_q Wq_b`` as heads [..., NH, nope + rope], the rope part rotated at ``positions`` [B, T] under ``position="rope"``."""
+    from deepspeed_tpu.models.transformer import _rope
+
+    d = cfg.latent_dims(kind)
+    # the head split kept apart from the matmul (as ``latent_project`` keeps ``wq``'s): folded into it, the compiler wants the
+    # stack head-major and copied a window model's six ``wq_b`` whole, 201 MB, every step (0.6 ms of 21: PERF.md, PR 66)
+    q = jax.lax.optimization_barrier(qmatmul(c_q, p["wq_b"])).reshape(c_q.shape[:-1] + (d.heads, d.nope + d.rope))
+    if cfg.position != "rope":
+        return q
+    return jnp.concatenate([q[..., : d.nope], _rope(q[..., d.nope :], positions, d.theta)], axis=-1)
+
+
+def latent_entry(cfg: HybridMoEConfig, p, h, positions, kind: str = "latent"):
+    """What a latent layer keeps of a token: ``[s_kv RMSNorm(c) ; k_rope]`` [..., kv_lora_rank + rope] of ``[c ; r] = h
+    Wkv_a``: the norm over the latent alone; ``r`` rotated under ``position="rope"``, as projected under ``"none"``."""
+    from deepspeed_tpu.models.transformer import _rope
+
+    d = cfg.latent_dims(kind)
+    kv = qmatmul(h, p["wkv_a"])
+    c_kv = scaled(_norm(kv[..., : d.kv_rank], p["kv_norm_scale"], None, "rmsnorm", cfg.norm_eps), d.kv_rescale)
+    shared = kv[..., d.kv_rank :] if cfg.position != "rope" else _rope(kv[..., None, d.kv_rank :], positions, d.theta)[..., 0, :]
+    return jnp.concatenate([c_kv, shared], axis=-1)
+
+
+def latent_absorb(cfg: HybridMoEConfig, p, q_nope, q_rope, kind: str = "latent"):
     """The query against the stored latent: ``[q_nope Wk_b,h^T ; q_rope]``
     [..., NH, kv_lora_rank + rope], so that its product with ``[c_kv ; k_rope]``
     is the head's score."""
-    wk_b = p["wk_b"].reshape(cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim)
+    d = cfg.latent_dims(kind)
+    wk_b = p["wk_b"].reshape(d.kv_rank, d.heads, d.nope)
     return jnp.concatenate([jnp.einsum("...hd,chd->...hc", q_nope, wk_b.astype(q_nope.dtype)), q_rope], axis=-1)
 
 
-def latent_output(cfg: HybridMoEConfig, p, o):
+def latent_output(cfg: HybridMoEConfig, p, o, kind: str = "latent", h=None):
     """``o`` [..., NH, kv_lora_rank], a head's weights over the latents: its
-    values' sum ``o Wv_b,h``, then the output projection. [..., H]."""
-    wv_b = p["wv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim)
-    attn = jnp.einsum("...hc,chv->...hv", o, wv_b.astype(o.dtype))
-    return qmatmul(attn.reshape(o.shape[:-2] + (cfg.num_heads * cfg.v_head_dim,)), p["wo"])
+    values' sum ``o Wv_b,h``, the head gate of a layer that has one (from the
+    normed ``h`` [..., H] the queries came from), then the output projection.
+    [..., H]."""
+    d = cfg.latent_dims(kind)
+    wv_b = p["wv_b"].reshape(d.kv_rank, d.heads, d.v)
+    attn = jnp.einsum("...hc,chv->...hv", o, wv_b.astype(o.dtype)).reshape(o.shape[:-2] + (d.heads * d.v,))
+    return qmatmul(output_gate(p, h, attn) if "wg_head" in p else attn, p["wo"])
+
+
+def index_queries(cfg: HybridMoEConfig, p, c_q, h, positions):
+    """A sparse layer's indexer, the query's side: ``qI = c_q WI_qb`` [B, T,
+    IH, ID] from the query's low rank, its leading ``qk_rope_head_dim``
+    features rotated at ``positions`` [B, T] with the layer's theta, and the
+    weight a head ``w = (h WI_w) IH^-0.5 ID^-0.5`` [B, T, IH] float32."""
+    from deepspeed_tpu.models.transformer import _rope
+
+    IH, ID = cfg.index_num_heads, cfg.index_head_dim
+    q = _rope(qmatmul(c_q, p["wi_qb"]).reshape(c_q.shape[:-1] + (IH, ID)), positions, float(cfg.rope_theta), cfg.qk_rope_head_dim)
+    return q, qmatmul(h, p["wi_w"]).astype(jnp.float32) * float(IH ** -0.5 * ID ** -0.5)
+
+
+def index_key(cfg: HybridMoEConfig, p, h, positions):
+    """The key's side, what a sparse layer keeps of a token beside its latent:
+    ``kI = LayerNorm(h WI_k)`` [B, T, ID] (scale and bias, eps ``norm_eps``),
+    its leading ``qk_rope_head_dim`` features rotated."""
+    from deepspeed_tpu.models.transformer import _rope
+
+    k = _norm(qmatmul(h, p["wi_k"]), p["wi_k_norm_scale"], p["wi_k_norm_bias"], "layernorm", cfg.norm_eps)
+    return _rope(k[..., None, :], positions, float(cfg.rope_theta), cfg.qk_rope_head_dim)[..., 0, :]
+
+
+def index_scores(q, w, k):
+    """``I[t, s] = sum_j w[t, j] ReLU(qI[t, j] . kI[s])`` in float32: ``q``
+    [..., T, IH, ID], ``w`` [..., T, IH] float32, ``k`` [..., S, ID] -> [..., T, S]."""
+    dots = jnp.einsum("...tjd,...sd->...tjs", q, k.astype(q.dtype), preferred_element_type=jnp.float32)
+    # the heads' weighted sum as a float32 product and sum, not a matmul: a device's default matmul rounds float32
+    # operands to bfloat16, and a selection turns on the order of nearly equal scores
+    return jnp.sum(jax.nn.relu(dots) * w[..., None], axis=-2)
+
+
+def chosen_keys(scores, seen, k: int):
+    """The selection as a mask: of each query's keys that it may see (``seen``
+    [..., T, S] bool) the ``k`` of largest ``scores`` [..., T, S] float32, ties
+    to the lower position; all of them where there are ``k`` at most. EXACT,
+    and without a sort: a float32's bits, the sign bit flipped (a negative's
+    other bits too), order as unsigned integers as the floats do, so the
+    ``k``-th largest is built bit by bit from the top, 32 counts of the scores
+    at or above a candidate (a pass over the scores each; a sort of 512 x
+    16,384 scores is ~100 such passes), and a tie at it is resolved by
+    counting positions."""
+    S = scores.shape[-1]
+    if S <= k:
+        return seen
+    scores = jnp.where(seen & (scores != 0), scores, jnp.where(seen, 0.0, -jnp.inf))  # no negative zero: its bits order below zero's
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def bit(i, least):
+        candidate = least | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(jnp.sum(keys >= candidate, axis=-1, keepdims=True) >= k, candidate, least)
+
+    least = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))  # the k-th largest
+    above, tied = keys > least, keys == least
+    room = k - jnp.sum(above, axis=-1, keepdims=True)  # places left for the keys tied at it, lowest positions first
+    return seen & (above | (tied & (jnp.cumsum(tied, axis=-1) <= room)))
 
 
 def moe_ffn(cfg: HybridMoEConfig, p, h, live=None, experts=None, group_offset=0):
@@ -737,22 +914,30 @@ class HybridMoETransformerLM(MoETransformerLM):
                     "conv_w": dense(lead + (cfg.conv_kernel, H), 0.5),
                     "wo": dense(lead + (H, H), out_std),
                 }
-            if kind == "latent":
-                Cq, C, nope, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-                if Cq:
-                    query = {"wq_a": dense(lead + (H, Cq)), "q_norm_scale": jnp.ones(lead + (Cq,)),
-                             "wq_b": dense(lead + (Cq, NH * (nope + rope)))}
+            if kind in LATENT_KINDS:
+                d = cfg.latent_dims(kind)
+                if d.q_rank:
+                    query = {"wq_a": dense(lead + (H, d.q_rank)), "q_norm_scale": jnp.ones(lead + (d.q_rank,)),
+                             "wq_b": dense(lead + (d.q_rank, d.heads * (d.nope + d.rope)))}
                 else:  # no low rank: one matrix, no query norm
-                    query = {"wq": dense(lead + (H, NH * (nope + rope)))}
-                return {
+                    query = {"wq": dense(lead + (H, d.heads * (d.nope + d.rope)))}
+                latent = {
                     "attn_norm_scale": jnp.ones(lead + (H,)),
                     **query,
-                    "wkv_a": dense(lead + (H, C + rope)),
-                    "kv_norm_scale": jnp.ones(lead + (C,)),
-                    "wk_b": dense(lead + (C, NH * nope)),
-                    "wv_b": dense(lead + (C, NH * Dv)),
-                    "wo": dense(lead + (NH * Dv, H), out_std),
+                    "wkv_a": dense(lead + (H, d.width)),
+                    "kv_norm_scale": jnp.ones(lead + (d.kv_rank,)),
+                    "wk_b": dense(lead + (d.kv_rank, d.heads * d.nope)),
+                    "wv_b": dense(lead + (d.kv_rank, d.heads * d.v)),
+                    "wo": dense(lead + (d.heads * d.v, H), out_std),
                 }
+                if cfg.attn_head_gate:
+                    latent["wg_head"] = dense(lead + (H, d.heads))
+                if kind == "sparse_latent":  # the indexer: queries from the query's low rank, one key and a weight a head from h
+                    IH, ID = cfg.index_num_heads, cfg.index_head_dim
+                    latent.update(wi_qb=dense(lead + (d.q_rank, IH * ID)), wi_k=dense(lead + (H, ID)),
+                                  wi_k_norm_scale=jnp.ones(lead + (ID,)), wi_k_norm_bias=dense(lead + (ID,)),
+                                  wi_w=dense(lead + (H, IH)))
+                return latent
             NQ, NKV = cfg.heads_of(kind), cfg.kv_heads_of(kind)
             attn = {
                 "attn_norm_scale": jnp.ones(lead + (H,)),
@@ -837,21 +1022,33 @@ class HybridMoETransformerLM(MoETransformerLM):
         attn = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
         return qmatmul(output_gate(p, h, attn.reshape(B, T, NH * Dv)), p["wo"])
 
-    def _latent_mixer(self, p, h):
-        """The published (expanded) form: every head's keys and values made from the latents."""
+    def _latent_mixer(self, p, h, kind="latent"):
+        """The published (expanded) form: every head's keys and values made from
+        the latents; a ``window_latent`` layer's query sees the newest
+        ``window`` keys, a ``sparse_latent`` layer's the ``index_topk`` its
+        indexer scores highest (``chosen_keys``)."""
         cfg = self.config
         B, T, _ = h.shape
-        NH, C = cfg.num_heads, cfg.kv_lora_rank
+        d = cfg.latent_dims(kind)
+        NH, C = d.heads, d.kv_rank
         pos = jnp.arange(T, dtype=jnp.int32)
-        q_nope, q_rope, latent = latent_project(cfg, p, h, jnp.broadcast_to(pos, (B, T)))
+        positions = jnp.broadcast_to(pos, (B, T))
+        # the plain kind through the four-argument call it always was: tests and the logits tools stand in for it by that signature
+        q_nope, q_rope, latent = latent_project(cfg, p, h, positions, *(() if kind == "latent" else (kind,)))
         c_kv, k_rope = latent[..., :C], latent[..., C:]
-        k_nope = qmatmul(c_kv, p["wk_b"]).reshape(B, T, NH, cfg.qk_nope_head_dim)
-        v = qmatmul(c_kv, p["wv_b"]).reshape(B, T, NH, cfg.v_head_dim)
-        scale = cfg.attn_softmax_scale if cfg.attn_softmax_scale is not None else cfg.head_dim ** -0.5
+        k_nope = qmatmul(c_kv, p["wk_b"]).reshape(B, T, NH, d.nope)
+        v = qmatmul(c_kv, p["wv_b"]).reshape(B, T, NH, d.v)
+        scale = cfg.attn_softmax_scale if cfg.attn_softmax_scale is not None else d.scale
         scores = (jnp.einsum("bthd,bshd->bhts", q_nope, k_nope) + jnp.einsum("bthd,bsd->bhts", q_rope, k_rope)).astype(jnp.float32)
-        scores = jnp.where(pos[:, None] >= pos[None, :], scores * scale, -1e30)
-        attn = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v)
-        return qmatmul(attn.reshape(B, T, NH * cfg.v_head_dim), p["wo"])
+        seen = pos[:, None] >= pos[None, :]
+        if kind == "window_latent":
+            seen &= pos[:, None] - pos[None, :] < cfg.window
+        if kind == "sparse_latent":
+            qi, w = index_queries(cfg, p, latent_query_rank(cfg, p, h, kind), h, positions)
+            seen = chosen_keys(index_scores(qi, w, index_key(cfg, p, h, positions)), jnp.broadcast_to(seen, (B, T, T)), cfg.index_topk)[:, None]
+        scores = jnp.where(seen, scores * scale, -1e30)
+        attn = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v).reshape(B, T, NH * d.v)
+        return qmatmul(output_gate(p, h, attn) if "wg_head" in p else attn, p["wo"])
 
     def _linear_mixer(self, p, h):
         from deepspeed_tpu.ops.transformer.linear_attention import kda_chunked
@@ -895,8 +1092,8 @@ class HybridMoETransformerLM(MoETransformerLM):
             with jax.named_scope(SCOPES[kind]):
                 if kind == "linear":
                     out = self._linear_mixer(mixer, h)
-                elif kind == "latent":
-                    out = self._latent_mixer(mixer, h)
+                elif kind in LATENT_KINDS:
+                    out = self._latent_mixer(mixer, h, kind)
                 elif kind == "ssm":
                     out = self._ssm_mixer(mixer, h)
                 elif kind == "conv":
@@ -1186,6 +1383,55 @@ def lfm2_moe_config(size: str = "24b-a2b", **overrides) -> HybridMoEConfig:
     if "layer_types" not in base:
         # layer_types: full_attention at 2 and then every fourth, conv elsewhere
         base["layer_types"] = ["softmax" if i % 4 == 2 else "conv" for i in range(base["num_layers"])]
+    return HybridMoEConfig(**base)
+
+
+def dots3_note_config(size: str = "note-prev", **overrides) -> HybridMoEConfig:
+    """dots3-note-prev's language model (``dots-studio/dots3-note-prev``
+    ``config.json``, ``model_type: dots3_note``): 46 layers of latent
+    attention of TWO kinds. Layers 0, 1, 5, 9, ... (``full_attention``) 128
+    heads with a query and key of 128 unrotated + 64 rotated features (theta
+    8e7) and a value of 128 over a latent of 512, queries through a low rank
+    of 1,024, attending the ``index_topk`` 2,048 causal keys that an indexer
+    of 64 heads of 128 scores highest (``sparse_latent``); the others
+    (``sliding_attention``) 64 heads of 192 + 64 / 128 over a latent of 1,024
+    of their own, queries through 1,024, theta 5e4, over the newest 513 keys
+    (``window_latent``); both low ranks times (5120 / rank)^0.5 behind their
+    norms (``apply_mla_qkv_lora_rescale``), one sigmoid gate a head on both
+    kinds' output; layer 0 a dense SwiGLU FFN of 13,824, layers 1-45 256
+    SwiGLU experts of 1,536, 8 a token by sigmoid scores with a selection
+    bias, gates normalised, one shared expert. The vision and audio towers and
+    the multi-token-prediction layer are not part of this model.
+    ``note-prev`` is the published model whole (behind the leading layer the
+    list repeats ``[full window window window]``, eleven periods and a
+    remainder of one); ``tiny`` a toy of one chip's share (4 of 16 experts
+    held) with the leading layer and two periods for tests, whose
+    ``index_topk`` and window its short contexts pass."""
+    presets = {
+        "tiny": dict(hidden_size=64, num_layers=9, num_heads=4, num_kv_heads=4, head_dim=24, v_head_dim=16, q_lora_rank=48,
+                     kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, window_num_heads=2, window_num_kv_heads=2, window_q_lora_rank=40,
+                     window_kv_lora_rank=48, window_qk_nope_head_dim=24, window_qk_rope_head_dim=8, window_v_head_dim=16,
+                     window=9, index_num_heads=4, index_head_dim=16, index_topk=8, vocab_size=512, max_seq_len=256,
+                     intermediate_size=96, expert_intermediate_size=32, num_experts=4, moe_router_experts=16,
+                     moe_expert_share=(0, 4), moe_top_k=3),
+        "note-prev": dict(hidden_size=5120, num_layers=46, num_heads=128, num_kv_heads=128, head_dim=192, v_head_dim=128,
+                          q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, window_num_heads=64,
+                          window_num_kv_heads=64, window_q_lora_rank=1024, window_kv_lora_rank=1024, window_qk_nope_head_dim=192,
+                          window_qk_rope_head_dim=64, window_v_head_dim=128, window=513, index_num_heads=64, index_head_dim=128,
+                          index_topk=2048, vocab_size=152064, max_seq_len=524288, intermediate_size=13824,
+                          expert_intermediate_size=1536, num_experts=256, moe_top_k=8),
+    }
+    base = dict(
+        norm="rmsnorm", norm_eps=1e-5, position="rope", rope_theta=8e7, window_rope_theta=5e4, activation="swiglu",
+        use_bias=False, tie_embeddings=False, attn_head_gate=True, latent_lora_rescale=True, leading_dense_layers=1,
+        moe_layer_freq=1, moe_drop_tokens=False, moe_norm_topk_prob=True, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_shared_experts=1, moe_routed_scaling=1.0,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    if "layer_types" not in base:
+        # layer_types: full_attention at 0, at 1 and then every fourth
+        base["layer_types"] = ["sparse_latent" if i == 0 or i % 4 == 1 else "window_latent" for i in range(base["num_layers"])]
     return HybridMoEConfig(**base)
 
 

@@ -105,7 +105,12 @@ def _lowered_step(v5e, monkeypatch, name, width):
             rings = tuple(on(s) for s in hybrid_decode.window_shapes(cfg, rows, page, ring))
         latent = on((cfg.layers_of("latent"), pages, page, key_lanes(cfg.latent_width))) if cfg.layers_of("latent") else None
         state = None if shapes.state is None else on(shapes.state, jnp.float32)  # a kind that keeps a tail alone has no state array
-        extra = (StateStore(state, on(shapes.conv), *rings, latent),)
+        new = ()  # PR 66's two kinds: latents and indexer keys under the page table, rings of latents
+        if cfg.layers_of("sparse_latent") or cfg.layers_of("window_latent"):
+            latent, index = (None if s is None else on(s) for s in hybrid_decode.paged_latent_shapes(cfg, pages, page))
+            ring = window_ring_pages(cfg.window, page, paged["prefill_chunk"])
+            new = (index, on(hybrid_decode.window_latent_shape(cfg, rows, page, ring)) if cfg.layers_of("window_latent") else None)
+        extra = (StateStore(state, on(shapes.conv), *rings, latent, *new),)
     # the pool's own shapes: heads narrower than a lane tile share one (granite's 8 of 64, gpt2-125m's 12: two a page)
     v_head_dim = getattr(cfg, "v_head_dim", None) or cfg.head_dim
     f = heads_per_group(cfg.head_dim, v_head_dim, cfg.num_kv_heads)
@@ -265,3 +270,37 @@ def test_with_no_conv_layer_no_head_norm_and_no_remainder_the_new_code_traces_no
     types = cfg.layer_types[: cfg.leading_dense_layers] + cfg.period * max(cfg.num_periods, 2) + cfg.period[:1]
     longer = dataclasses.replace(cfg, num_layers=len(types), layer_types=types)
     assert longer.remainder == cfg.period[:1] and longer.period == cfg.period and len(_toy_step_text(longer)) > len(traced)
+
+
+# --- PR 66: two latent kinds, a rescale, a gate on latent layers, two more pools: every accepted family's toy steps are what they were ---
+
+TOY_STEPS = json.loads((pathlib.Path(__file__).parent / "data" / "accepted_toy_steps.json").read_text())["steps"]
+
+
+@pytest.mark.parametrize("family", sorted({name.split("/")[0] for name in TOY_STEPS}))
+def test_an_accepted_familys_toy_steps_trace_to_what_they_did(family, monkeypatch):
+    """The narrow and the wide step of the family's tiny preset trace to the
+    text they traced to on the commit before the ``sparse_latent`` and
+    ``window_latent`` kinds (a fingerprint a width, recorded there): the
+    generalised latent functions (``latent_dims``, a rescale of 1.0, a gate no
+    accepted latent layer has), the store's two new fields (None: no
+    parameter) and the new kinds' pools put nothing into, and take nothing out
+    of, a program that names neither kind."""
+    from deepspeed_tpu.inference.kv_pool import PagePool
+    from deepspeed_tpu.models import hybrid_moe as hm
+
+    monkeypatch.setattr(decode, "DENSE_TOKEN_TILE", 16)
+    cfg = getattr(hm, family)("tiny", dtype="float32")
+    assert not {"sparse_latent", "window_latent"} & set(cfg.layer_types)
+    params = jax.eval_shape(lambda: hm.HybridMoETransformerLM(cfg).init(jax.random.PRNGKey(0), None))
+    for width in (1, 16):
+        pool = PagePool(cfg, 9, 8, 4, max_seq_len=16, dtype=jnp.float32, prefill_chunk=8)
+        i32 = lambda *shape: jnp.zeros(shape, I32)
+        st = pool.states
+        assert st.index is None and st.window_latent is None
+        forward = lambda p: hybrid_decode.hybrid_forward(
+            cfg, p, i32(4, width), pool.cache.k_pages, pool.cache.v_pages, st.state, st.conv, i32(4, 2), i32(4), i32(4), i32(4), attn_impl="xla",
+            window=None if st.window_k is None else (st.window_k, st.window_v), latent=st.latent,
+        )
+        text = str(jax.make_jaxpr(forward)(params))
+        assert hashlib.sha256(text.encode()).hexdigest()[:12] == TOY_STEPS[f"{family}/w{width}"], (family, width)
