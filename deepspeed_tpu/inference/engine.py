@@ -750,6 +750,7 @@ class InferenceEngine:
             tp=tp_ctx,
         )
         self._record_route_plan(server)
+        self._record_sparse_attend_form(server)
         if recovered_states:
             server.recover(recovered_states, next_uid)
         tcfg = self._config.traffic
@@ -780,6 +781,24 @@ class InferenceEngine:
         width = getattr(cfg, "moe_router_experts", None) or getattr(cfg, "num_experts", 0)
         for tokens in sorted({routed_rows(cfg, server.pool.max_slots, w) for w in (server._ragged_w_decode, server._ragged_w_mixed)} - {0}):
             self.tracer.event("moe.route_plan", **plan_path(tokens, width, cfg.moe_top_k))
+
+    def _record_sparse_attend_form(self, server) -> None:
+        """Which form a sparse latent layer's decode rows attend their chosen
+        keys in (``walk``: the kernel over a row's live pages under the
+        selection's mask; ``gather``: XLA's gather of the chosen entries):
+        ``sparse_latent_attention.decode_form``, the question the layer itself
+        asks of its page table's width, said once a program (``window``: the
+        narrow one's and the mixed one's, whose one-token row group is the
+        same call) where the server is built: nothing in a step. As
+        ``_record_route_plan`` says ``moe.route_plan``."""
+        cfg = self._ds_config
+        if "sparse_latent" not in (getattr(cfg, "layer_types", None) or ()):
+            return
+        from deepspeed_tpu.ops.transformer.sparse_latent_attention import decode_form
+
+        form = decode_form(server.pool.max_pages_per_slot * server.pool.page_size, cfg.index_topk)
+        for window in sorted({server._ragged_w_decode, server._ragged_w_mixed}):
+            self.tracer.event("sparse_attend.form", window=window, **form)
 
     def serve(self, prompts, max_new_tokens=32, eos_token_id=None):
         """Continuous-batching greedy generation over the paged KV pool:

@@ -6,20 +6,33 @@ kinds). Both in the absorbed form of ``latent_attention.py``: a token is one
 entry ``[c_kv ; k_rope]``, the "key" the whole entry, the "value" its leading
 ``value_lanes``, shared by all query heads.
 
-Plain XLA, under named scopes the benchmark's readers find device time by
-(``SCOPES``): no kernel of this repo walks chosen entries or a ring of
-latents, and the accepted latent kernel's file, and so every program that
-calls it, is untouched. What the forms are built for:
+Under named scopes the benchmark's readers find device time by (``SCOPES``);
+the accepted latent kernel's file, and so every program that calls it, is
+untouched. What the forms are built for:
 
 * ``sparse_latent_attention``, one token a row (a decode row): the row's LIVE
   indexer keys are scored block by block of its page table (a loop whose
-  count is the longest live row's blocks, so dead pages are not read), the
-  exact top ``index_topk`` positions come from one stable sort (no
-  approximation; ties go to the lower position) that carries each position's
-  place in the pool as its payload, and ONLY the chosen entries
-  are gathered out of the latent pages, 1,280 B each: the unchosen latents do
-  not cross the bus. The softmax runs over the chosen entries in whatever
-  order the sort left them.
+  count is the longest live row's blocks, so dead pages are not read). What
+  follows takes one of two forms, chosen where the program is built from
+  what it can see (``decode_form``: the page table's positions against
+  ``index_topk``, the platform, the pool's lanes):
+
+  - ``walk`` (a TPU, a table of at most ``WALK_MAX_MULTIPLE x index_topk``
+    positions): the selection is a MASK of the row's keys
+    (``hybrid_moe.chosen_keys``: exact, ties to the lower position, 32 counts
+    and no sort), and ONE ``pallas_call`` (``sparse_latent_attention``)
+    walks the row's live latent pages where they lie, whole pages of 80 KB a
+    DMA into a ring of halves fetched ahead over the ends of rows, and
+    attends them under the mask with running softmax statistics: a row's 128
+    heads are one full query tile, so the unchosen keys' products ride beside
+    the walk's bytes, and a page crosses the bus once;
+  - ``gather`` (elsewhere, and the tests' reference): the exact top
+    ``index_topk`` positions come from one stable sort that carries each
+    position's place in the pool as its payload, and ONLY the chosen entries
+    are gathered out of the latent pages, 1,280 B each, ~15 ns an entry
+    whatever its width: what wins where the chosen are a hundredth of a
+    row's keys (the contexts the model is published for), and loses where
+    they are a quarter.
 * ``sparse_latent_attention``, a chunk of tokens a row (prefill): the
   selection is each QUERY TOKEN's. Scores ``[T, S]`` against the row's live
   indexer keys, the ``index_topk``-th largest a query built bit by bit from
@@ -41,12 +54,19 @@ same.
 
 from __future__ import annotations
 
+import functools
+from typing import Any, Dict
+
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.accelerator import on_tpu
 from deepspeed_tpu.models import hybrid_moe as hm
+from deepspeed_tpu.ops.transformer.decode_attention import NEG_INF, _pages_in_stack
 
-NEG_INF = -1e30
 # the named scopes inside the two mixers' own (``hybrid_moe.SCOPES``)
 SCOPES = {"index": "sparse_index_scores", "select": "sparse_select", "attend": "sparse_attend", "ring": "ring_latent_attend"}
 BLOCK_KEYS = 1024  # keys one trip of a walk takes (whole pages): a chunk's float32 scores are [heads, T, BLOCK_KEYS]
@@ -121,6 +141,210 @@ def _chosen_entries_attention(q, scores, latent, layer, page_table, kv_lens, top
         return (o / jnp.where(l == 0, 1.0, l)).astype(q.dtype)
 
 
+# The walk form: the keys a half of the ring holds, the keys one trip of the MXU takes (a key tile), the halves in the ring.
+# By ``tools/ragged_kernel_bench.py --models dots3_sparse --set ...`` on a v5e (PR 67; 32 rows of 128 heads, the longest at
+# 8,192 live keys and the others down to three quarters of it, 358.5 us by their bytes; us a call of the kernel alone and ns a
+# key walked): as here 564 / 2.47, where halves of 1,024 in tiles of 512 took 647 / 2.83 (a key tile is ONE chain, scores,
+# maximum, exponentials, ``p . v``, and the rolled loop's back edge drains the MXU: few and long ones), one tile of 2,048 a half
+# 572 / 2.50, halves of 4,096 551 / 2.41 (not worth 16 MB of a float32 ring), tiles of 256 3.5-3.6 ns a key; a ring of two
+# +10 us; four tiles of 512 in one straight line 576, two of 1,024 as one alone. The scores as ``entries . q^T`` (the keys
+# streamed through the MXU past a stationary q) and transposed back: 3.86 for 2.98, the transposes cost more than the weights'
+# loads. A whole half's 32 copies started and waited for in a straight line and not a rolled loop: 2.98 -> 2.83.
+_WALK_HALF_KEYS = 2048
+_WALK_TILE_KEYS = 1024
+_WALK_RING = 3
+# The walk form where the page table's positions (the longest row the server can hold) are at most this multiple of
+# ``index_topk``, the gather form above it: the walk pays a row's LIVE keys, the gather its chosen ones. Same tool, same
+# chip: the gather form 1,628 us a call at 2,130 chosen whatever the rows' lengths (399 its sort, then 18.0 ns an entry
+# gathered and attended; 14.8 at 1,065 chosen, 22.7 at 4,260), the walk 51 us of counts and 2.47 ns a key, so at
+# ``index_topk`` 2,048 they cross at 32 rows of ~19,300 live keys = 9.4 x ``index_topk`` (measured 16,384: 1,604 us before
+# the constants above were chosen, 1,350 by their rate, against 1,580).
+WALK_MAX_MULTIPLE = 9
+_M_FLOOR = -1e29  # the running maximum's floor, above ``NEG_INF``: what the mask took out gets exp(-9e29) = 0 whatever else a tile held
+
+
+def decode_form(table_positions: int, topk: int) -> Dict[str, Any]:
+    """The form a decode row's attention over its chosen keys takes in a
+    program whose page table spans ``table_positions`` keys a row, from the
+    shapes and the backend alone: ``form`` (``walk`` | ``gather``). What
+    ``sparse_latent_attention`` asks, and what an engine records of its
+    programs' shapes where it builds them (``sparse_attend.form``: the ops
+    have no tracer)."""
+    walk = on_tpu() and table_positions <= WALK_MAX_MULTIPLE * topk
+    return {"form": "walk" if walk else "gather", "table_positions": int(table_positions), "index_topk": int(topk)}
+
+
+def _walk_tiles(P: int, maxp: int):
+    """``(C, CK, N)``: pages a half of the ring holds (whole key tiles), pages a key tile spans, halves in the ring."""
+    CK = max(1, min(_WALK_TILE_KEYS // P, maxp))
+    return max(1, min(_WALK_HALF_KEYS // P, maxp) // CK) * CK, CK, _WALK_RING
+
+
+def _walk_kernel(pt_ref, len_ref, q_ref, mask_ref, pool, o_ref, buf, fetch_sem, ring_s, *, scale, P, C, CK):
+    """Grid step ``g`` attends row ``g - 1`` (step 0 only starts the ring's
+    first fetches), as ``latent_attention._latent_kernel`` walks: ``q_ref``
+    ``[NH, lanes]`` the row's heads, ONE query tile; ``mask_ref`` ``[key tiles,
+    CK * P]`` float32, 0 a chosen key and ``NEG_INF`` any other, a key tile a
+    sublane row; ``pool`` the whole pool as ``layers * NP`` pages, read where
+    it lies; ``buf`` ``[N, C * P, lanes]`` the ring of halves. Scores against
+    all of an entry's lanes, values its leading ``o_ref.shape[-1]``.
+    ``ring_s``: the place of the next half to attend, and the row, table slot
+    and place of the next to fetch. ``len_ref`` is 0 for a dead row, which
+    reads nothing. Scalar arithmetic in ``lax`` primitives, as there."""
+    add, sub, mul, div, lt = lax.add, lax.sub, lax.mul, lax.div, lax.lt
+    g = pl.program_id(0)
+    R = pl.num_programs(0) - 1
+    NH, Dv = o_ref.shape
+    N = buf.shape[0]
+    TK = CK * P
+
+    def pages_of(row):
+        return div(add(len_ref[row], P - 1), P)
+
+    r = lax.max(sub(g, 1), 0)
+    n_pages = lax.select(lax.gt(g, 0), pages_of(r), 0)
+    n_buf = div(add(n_pages, C - 1), C)
+
+    def fetch(row, first, slot, wait=False):
+        """The copies of a half's pages, from table slot ``first`` of ``row`` on, into place ``slot`` of the ring: started,
+        or waited for. A whole half's in a straight line (a rolled loop's trip costs as much as the copy it starts)."""
+
+        def page(c, _=None):
+            rows = pl.ds(c * P, P) if isinstance(c, int) else pl.ds(pl.multiple_of(mul(c, P), P), P)
+            copy = pltpu.make_async_copy(pool.at[pt_ref[row, add(first, c)]], buf.at[slot, rows, :], fetch_sem.at[slot])
+            copy.wait() if wait else copy.start()
+
+        live = lax.min(sub(pages_of(row), first), C)
+
+        @pl.when(lax.eq(live, C))
+        def _whole():
+            for c in range(C):
+                page(c)
+
+        @pl.when(lt(live, C))
+        def _part():
+            lax.fori_loop(0, live, page, None)
+
+    def after(slot):
+        return lax.select(lax.eq(slot, N - 1), 0, add(slot, 1))
+
+    def fetch_next():
+        """Start the fetch of the first half in walk order (the rows in order, a row's halves in order, none for a dead
+        row) that none was started for, into the ring's next place; past the last row, nothing."""
+        row, first = lax.while_loop(
+            lambda at: lax.bitwise_and(lt(at[0], R), lax.ge(at[1], pages_of(lax.min(at[0], R - 1)))),
+            lambda at: (add(at[0], 1), 0),
+            (ring_s[1], ring_s[2]),
+        )
+
+        @pl.when(lt(row, R))
+        def _start():
+            fetch(row, first, ring_s[3])
+            ring_s[3] = after(ring_s[3])
+
+        ring_s[1], ring_s[2] = row, add(first, C)
+
+    @pl.when(g == 0)
+    def _first_step():
+        buf[...] = jnp.zeros_like(buf)  # what a half holds past a row's live pages is masked, and so must be finite
+        for i in range(4):
+            ring_s[i] = 0
+        lax.fori_loop(0, N - 1, lambda _, none: fetch_next(), None)
+
+    def half(b, carry):
+        slot, *stats = carry
+        first = mul(b, C)
+        fetch_next()  # the place the half before this one was attended from is free
+        fetch(r, first, slot, wait=True)
+        q = q_ref[...]
+        # float32 operands take the precision the process asks for (``highest`` in the float32 logits runs); bfloat16
+        # ones name theirs, where Mosaic refuses ``highest`` ("Bad lhs type": ``route_plan.bf16_dot``)
+        dot = functools.partial(lax.dot_general, precision=None if q.dtype == jnp.float32 else lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
+
+        def key_tile(kt, stats):
+            m, l, acc = stats
+            keys = pl.ds(pl.multiple_of(mul(kt, TK), TK), TK)
+            s = dot(q, buf[slot, keys, :], (((1,), (1,)), ((), ())))  # [NH, TK]
+            s = add(mul(s, scale), mask_ref[pl.ds(add(mul(b, C // CK), kt), 1), :])  # the selection: NEG_INF what was not chosen
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = corr * l + jnp.sum(p, axis=1, keepdims=True)
+            v = buf[slot, keys, :Dv]  # the entries' leading lanes: the same bytes, not fetched again
+            acc = acc * corr + dot(p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+            return m_new, l, acc
+
+        tiles = div(add(lax.min(sub(n_pages, first), C), CK - 1), CK)  # the half's key tiles that hold a key of the row
+        return (after(slot), *lax.fori_loop(0, tiles, key_tile, tuple(stats)))
+
+    start = (ring_s[0], jnp.full((NH, 1), _M_FLOOR, jnp.float32), jnp.zeros((NH, 1), jnp.float32), jnp.zeros((NH, Dv), jnp.float32))
+    slot, _, l, acc = lax.fori_loop(0, n_buf, half, start)
+    ring_s[0] = slot  # where the next row finds its first half
+    o_ref[...] = (acc / jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)  # a dead row's zeros
+
+
+@functools.lru_cache(maxsize=16)
+def _walk_call(tiles, R, NH, D, Dv, n_tiles, pool_shape, pool_dtype, out_dtype, scale, interpret):
+    """The ``pallas_call`` of ``_walk_kernel`` over ``R + 1`` steps, built ONCE a shape (``latent_attention._latent_call``)."""
+    C, CK, N = tiles
+    P = pool_shape[1]
+    params = {}
+    if not interpret:
+        held = (
+            N * C * P * D * pool_dtype.itemsize  # the ring
+            + 2 * NH * (D + Dv) * pool_dtype.itemsize + 2 * 4 * n_tiles * CK * P  # q, o and the mask, twice
+            + 4 * NH * (2 * 128 + Dv + 3 * CK * P)  # m, l, acc; a key tile's scores
+        )
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),  # a row's first pages are fetched by the step before its own
+            vmem_limit_bytes=held + (24 << 20),
+        )
+
+    def row_block(g, pt, ln):  # step g attends row g - 1; step 0 only fetches
+        return (lax.max(g - 1, 0), 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(R + 1,),
+        in_specs=[pl.BlockSpec((None, NH, D), row_block), pl.BlockSpec((None, n_tiles, CK * P), row_block), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, NH, Dv), row_block),
+        scratch_shapes=[
+            pltpu.VMEM((N, C * P, D), pool_dtype),
+            pltpu.SemaphoreType.DMA((N,)),  # fetches: a half
+            pltpu.SMEM((4,), jnp.int32),  # the ring: the consumer's place; the producer's row, table slot and place
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_walk_kernel, scale=scale, P=P, C=C, CK=CK),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, NH, Dv), out_dtype),
+        interpret=interpret,
+        name="sparse_latent_attention",
+        **params,
+    )
+
+
+def _walk_chosen_pages(q, mask, latent, layer, page_table, kv_lens, value_lanes, scale, interpret=None):
+    """One token a row: ``q`` [R, NH, lanes] over the keys ``mask`` [R, S]
+    (bool; the first ``S`` positions of the row's table) names among the row's
+    ``kv_lens`` live ones, by the kernel: every live page of the row crosses
+    the bus once, whatever the mask keeps of it."""
+    L, NP, P, D = latent.shape
+    R, NH, _ = q.shape
+    maxp = page_table.shape[1]
+    tiles = _, CK, _ = _walk_tiles(P, maxp)
+    n_tiles = -(-maxp // CK)
+    keys = n_tiles * CK * P
+    mask = mask[:, :keys] if mask.shape[1] >= keys else jnp.pad(mask, ((0, 0), (0, keys - mask.shape[1])))
+    bias = jnp.where(mask, 0.0, NEG_INF).astype(jnp.float32).reshape(R, n_tiles, CK * P)
+    if interpret is None:
+        interpret = not on_tpu()
+    if not interpret and (D % 128 or value_lanes % 128):
+        raise NotImplementedError(f"the sparse latent kernel needs pages and values of whole lane tiles: {D} lanes, {value_lanes} of them the value")
+    call = _walk_call(tiles, R, NH, D, value_lanes, n_tiles, (L * NP, P, D), latent.dtype, jnp.dtype(q.dtype), float(scale), interpret)
+    return call(_pages_in_stack(layer, page_table, NP), kv_lens, q.astype(latent.dtype), bias, latent.reshape(L * NP, P, D))
+
+
 def _masked_walk_attention(q, mask, latent, layer, page_table, kv_lens, value_lanes, scale):
     """``q`` [R, T, NH, D] over the row's live pages block by block under
     ``mask`` [R, T, S] (the keys a query attends), running softmax statistics
@@ -173,10 +397,15 @@ def sparse_latent_attention(q, qi, wi, new, new_index, latent, index, layer, pag
     index = write_entries(index, layer, new_index, page_table, q_pos, valid)
     with jax.named_scope(SCOPES["index"]):
         scores = paged_index_scores(qi, wi, index, layer, page_table, kv_lens)
-    if T == 1:
+    at = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    if T == 1 and decode_form(page_table.shape[1] * latent.shape[2], topk)["form"] == "walk":
+        with jax.named_scope(SCOPES["select"]):
+            mask = hm.chosen_keys(scores[:, 0], at[None, :] < kv_lens[:, None], topk)
+        with jax.named_scope(SCOPES["attend"]):
+            out = _walk_chosen_pages(q[:, 0], mask, latent, layer, page_table, kv_lens, value_lanes, scale)[:, None]
+    elif T == 1:
         out = _chosen_entries_attention(q[:, 0], scores[:, 0], latent, layer, page_table, kv_lens, topk, value_lanes, scale)[:, None]
     else:
-        at = jnp.arange(scores.shape[-1], dtype=jnp.int32)
         with jax.named_scope(SCOPES["select"]):
             mask = hm.chosen_keys(scores, valid[..., None] & (at <= q_pos[..., None]), topk)
         with jax.named_scope(SCOPES["attend"]):

@@ -244,6 +244,29 @@ def test_the_engine_serves_it_with_two_programs_and_says_all_three_caches(toy, m
         assert gap.max() < F32_TOL
 
 
+@pytest.mark.parametrize(
+    "max_seq_len, on_a_tpu, form", [(40, True, "walk"), (72, True, "walk"), (80, True, "gather"), (40, False, "gather")], ids=["walk", "boundary", "gather", "cpu"]
+)
+def test_the_engine_says_the_decode_rows_form_where_it_builds_the_server(toy, monkeypatch, max_seq_len, on_a_tpu, form):
+    """``sparse_attend.form``, once a program (the narrow one's rows and the
+    mixed one's one-token row group are the same call): the walk while the
+    page table's positions are at most ``WALK_MAX_MULTIPLE x index_topk`` (8
+    here: 72 positions), the gather a page past it and off a TPU;
+    ``decode_form`` is the question the layer asks where the programs are
+    traced. Nothing is compiled."""
+    from deepspeed_tpu.ops.transformer import sparse_latent_attention as sla
+
+    cfg, lm, params, _, _ = toy
+    monkeypatch.setattr(sla, "on_tpu", lambda: on_a_tpu)
+    assert sla.WALK_MAX_MULTIPLE * cfg.index_topk == 72
+    eng = ds.init_inference(lm, dtype="fp32", paged_kv={"page_size": PAGE, "max_slots": SLOTS, "prefill_chunk": CHUNK, "max_seq_len": max_seq_len})
+    eng.set_params(params)
+    eng._build_paged_server()
+    events = [s["attrs"] for s in eng.tracer.spans() if s["name"] == "sparse_attend.form"]
+    assert events == [{"window": w, "form": form, "table_positions": max_seq_len, "index_topk": cfg.index_topk} for w in (1, CHUNK)]
+    assert eng.compile_stats() == {}
+
+
 @pytest.mark.parametrize("feature", ["prefix_cache", "spec_decode", "generate", "tensor_parallel"])
 def test_what_assumes_a_rows_pages_hold_its_whole_past_is_refused(toy, feature):
     """Each raises where it is built, naming what the rings and the third and fourth arrays do not allow (or, for tensor

@@ -6,6 +6,14 @@ decode row's newest pages, a chunk's whole ring, three laps in). Float32 on
 the CPU: the forms and the dense computation differ by the order of their sums
 (measured 2e-7); the limit is 2e-5, where one key more or fewer in a selection
 or a window differs by 1e-2 or more.
+
+The decode form's KERNEL (``_walk_chosen_pages``: a row's live pages walked
+under the selection's mask) runs in the Pallas interpreter against the gather
+form AND the dense masked softmax, a case a property, each in float32 and in
+bfloat16 (entries of unit variance: the forms differ by ``p``'s rounding and
+the order of their sums, measured 4e-3, limit 3e-2), halves of four pages in
+a ring of three so that a row's walk wraps the ring and runs over its end,
+key tiles of two pages.
 """
 
 import jax
@@ -18,6 +26,18 @@ from deepspeed_tpu.ops.transformer import sparse_latent_attention as sla
 
 TOL = 2e-5
 P, NH, D, C, IH, ID, TOPK = 8, 4, 24, 16, 3, 8, 12
+WALK_LANES, WALK_PAGES, WALK_POOL = 32, 12, 60  # a page's lanes, a row's table, the pool's pages
+# live keys a row (four rows a case) and what the case is there for
+WALK_CASES = {
+    "under_topk": (5, 11, 3, 9),  # every live key is chosen: the plain latent layer
+    "at_topk": (12, 12, 12, 12),
+    "past_topk": (37, 90, 13, 96),  # up to the whole table
+    "tie_at_the_kth": (40, 64, 23, 77),  # scores of few values: the lower position wins
+    "dead_rows_among_live": (37, 0, 0, 50),
+    "sentinel_pages": (20, 37, 9, 64),  # ids below 0 and past the pool behind a row's live pages
+    "page_wider_than_the_entry": (33, 70, 12, 41),  # 24 numbers an entry in pages of 32 lanes (576 in 640)
+    "last_page_partly_filled": (33, 41, 7, 95),
+}
 
 
 def _dense(q, entries, seen, scale):
@@ -103,3 +123,68 @@ def test_ring_attention_is_the_dense_window_three_laps_in(width, lens):
         assert np.abs(out[r] - np.asarray(_dense(jnp.asarray(q[r]), jnp.asarray(entries), jnp.asarray(seen), 0.3))).max() < TOL, r
         one_short = (at[None, :] <= pos[:, None]) & (at[None, :] > pos[:, None] - window + 1)
         assert np.abs(out[r] - np.asarray(_dense(jnp.asarray(q[r]), jnp.asarray(entries), jnp.asarray(one_short), 0.3))).max() > 1e-3
+
+
+@pytest.fixture()
+def short_halves(monkeypatch):
+    monkeypatch.setattr(sla, "_WALK_HALF_KEYS", 4 * P)
+    monkeypatch.setattr(sla, "_WALK_TILE_KEYS", 2 * P)
+
+
+# one trace a dtype for all the cases: their shapes are the same
+_gather_form = jax.jit(lambda q, s, *a: sla._chosen_entries_attention(q, s, *a, TOPK, C, 0.3))
+_selection = jax.jit(lambda scores, lens: hm.chosen_keys(scores, jnp.arange(scores.shape[1])[None, :] < lens[:, None], TOPK))
+_walk_form = jax.jit(lambda q, m, *a: sla._walk_chosen_pages(q, m, *a, C, 0.3, interpret=True))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_the_walk_kernel_is_the_gather_form_and_the_dense_masked_softmax(short_halves, case, dtype):
+    lens = np.asarray(WALK_CASES[case], np.int32)
+    rng = np.random.default_rng(sum(map(ord, case)))
+    R, S = lens.size, WALK_PAGES * P
+    width = D if case == "page_wider_than_the_entry" else WALK_LANES
+    pool = np.zeros((2, WALK_POOL, P, WALK_LANES), np.float32)
+    pool[..., :width] = rng.standard_normal((2, WALK_POOL, P, width))  # every page holds something: what is not the row's must not be read
+    q = np.zeros((R, NH, WALK_LANES), np.float32)
+    q[..., :width] = rng.standard_normal((R, NH, width))
+    own = 1 + rng.permutation(WALK_POOL - 1)[: R * WALK_PAGES].reshape(R, WALK_PAGES)  # a row's pages, not in walk order
+    table = np.where(np.arange(WALK_PAGES)[None, :] < -(-lens[:, None] // P), own, -1).astype(np.int32)
+    if case == "sentinel_pages":
+        table[:, -2:] = WALK_POOL + 7
+    scores = rng.standard_normal((R, S)).astype(np.float32)
+    if case == "tie_at_the_kth":
+        scores = np.round(scores)  # seven values or so: the k-th largest is shared
+    pool, q = jnp.asarray(pool, dtype), jnp.asarray(q, dtype)
+    args = (pool, jnp.int32(1), jnp.asarray(table), jnp.asarray(lens))
+    gathered = np.asarray(_gather_form(q, scores, *args), np.float32)
+    mask = _selection(scores, lens)
+    walked = np.asarray(_walk_form(q, mask, *args), np.float32)
+    tol = TOL if dtype == "float32" else 3e-2
+    held, queries = np.asarray(pool, np.float32), np.asarray(q, np.float32)  # as the dtype rounded them
+    mask = np.asarray(mask)
+    cut_ties = 0
+    for r, n in enumerate(lens):
+        if n == 0:
+            assert np.all(walked[r] == 0)
+            continue
+        # the selection by its definition: the first TOPK of a stable sort by falling score, so ties go to the lower position
+        chosen = np.zeros(n, bool)
+        chosen[np.argsort(-scores[r, :n], kind="stable")[:TOPK]] = True
+        assert np.array_equal(mask[r, :n], chosen) and not mask[r, n:].any()
+        tied = scores[r, :n] == np.sort(scores[r, :n])[-min(TOPK, n)]
+        cut_ties += tied.sum() > (chosen & tied).sum() > 0
+        entries = held[1, table[r, np.arange(n) // P], np.arange(n) % P]  # [n, lanes]
+        weights = np.exp(np.where(chosen, (queries[r] @ entries.T) * 0.3, -np.inf))  # the dense masked softmax, in numpy: [NH, n]
+        dense = (weights / weights.sum(-1, keepdims=True)) @ entries[:, :C]
+        assert np.abs(walked[r] - dense).max() < tol, (r, np.abs(walked[r] - dense).max())
+        assert np.abs(walked[r] - gathered[r]).max() < tol, (r, np.abs(walked[r] - gathered[r]).max())
+    assert cut_ties >= 2 or case != "tie_at_the_kth"  # rows whose k-th score is shared by keys the selection takes and keys it leaves
+
+
+@pytest.mark.parametrize("pages, on_a_tpu, form", [(8, True, "walk"), (15, True, "walk"), (16, True, "gather"), (8, False, "gather")], ids=["walk", "boundary", "gather", "cpu"])
+def test_the_decode_form_is_chosen_from_the_tables_width(monkeypatch, pages, on_a_tpu, form):
+    """``WALK_MAX_MULTIPLE x index_topk`` positions at most: the walk; a page more, and off a TPU: the gather."""
+    monkeypatch.setattr(sla, "on_tpu", lambda: on_a_tpu)
+    monkeypatch.setattr(sla, "WALK_MAX_MULTIPLE", 10)
+    assert sla.decode_form(pages * P, TOPK) == {"form": form, "table_positions": pages * P, "index_topk": TOPK}

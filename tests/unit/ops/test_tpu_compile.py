@@ -717,6 +717,69 @@ def test_a_routed_layer_compiles_under_a_highest_default_precision(v5e, monkeypa
     assert sorted(re.findall(r"moe_route/(\w+)/pallas_call", text))[:1] == ["moe_combine_rows"] and "moe_route_plan" in text and "moe_dispatch_rows" in text
 
 
+def _sparse_decode_layer(dtype, table_pages=256):
+    """``sparse_latent_attention``'s T = 1 call at the dots3 cell's shape (32 rows, 128 heads, entries of 576 in pages
+    of 640 lanes, a table of 256 pages of 64, ``index_topk`` 2,048, three layers of pages) and its abstract operands."""
+    from deepspeed_tpu.ops.transformer.sparse_latent_attention import sparse_latent_attention
+
+    R, NH, D, lanes, IH, ID, NP, P, MAXP = 32, 128, 576, 640, 64, 128, 2048, 64, table_pages
+
+    def layer(q, qi, wi, new, new_index, latent, index, table, kv_lens, q_lens):
+        return sparse_latent_attention(q, qi, wi, new, new_index, latent, index, jnp.int32(1), table, kv_lens, q_lens, topk=2048, value_lanes=512, scale=192**-0.5)
+
+    shapes = [
+        ((R, 1, NH, D), dtype), ((R, 1, IH, ID), dtype), ((R, 1, IH), jnp.float32), ((R, 1, D), dtype), ((R, 1, ID), dtype),
+        ((3, NP, P, lanes), dtype), ((3, NP, P, ID), dtype), ((R, MAXP), I32), ((R,), I32), ((R,), I32),
+    ]
+    return layer, shapes
+
+
+def test_a_sparse_layers_decode_row_walks_its_pages_in_one_call_and_gathers_no_chosen_entry(v5e, monkeypatch):
+    """The sparse layer's T = 1 call at the cell's shape, compiled for a v5e:
+    the table's 16,384 positions are 8 x ``index_topk``, so the form is the
+    walk; the ``sparse_attend`` scope holds ONE Mosaic call,
+    ``sparse_latent_attention``, and no gather; nowhere in the program is
+    there the gather form's ``[65536, 640]`` or ``[32, 2048, 640]`` buffer;
+    the selection's scope is there and holds no sort."""
+    from deepspeed_tpu.ops.transformer import sparse_latent_attention as sla
+
+    monkeypatch.setattr(sla, "on_tpu", lambda: True)
+    assert sla.decode_form(256 * 64, 2048)["form"] == "walk"
+    layer, shapes = _sparse_decode_layer(BF16)
+    text = jax.jit(layer).lower(*(jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes)).compile().as_text()
+    in_scope = [line for line in text.splitlines() if "/sparse_attend/" in line]
+    calls = [line for line in in_scope if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "sparse_attend/sparse_latent_attention/pallas_call" in calls[0], calls
+    assert not [line for line in in_scope if re.search(r" gather\(", line)], "a gather in the sparse_attend scope"
+    assert "[65536,640]" not in text and "[32,2048,640]" not in text
+    select = [line for line in text.splitlines() if "/sparse_select/" in line]
+    assert select and not [line for line in select if re.search(r" sort\(", line)]
+    assert [line for line in text.splitlines() if "/sparse_index_scores/" in line]
+
+
+@pytest.mark.parametrize("pages, form", [(256, "walk"), (384, "gather")], ids=["walk", "gather"])
+def test_a_sparse_layers_decode_row_compiles_under_a_highest_default_precision(v5e, monkeypatch, pages, form):
+    """The float32 ``--isolated`` logits run (``jax_default_matmul_precision``
+    ``highest``, a float32 pool): the kernel compiles there (its float32
+    products take the process's precision, its bfloat16 ones would name
+    ``DEFAULT``), so the form rule asks nothing of the pool's dtype; a table
+    past ``WALK_MAX_MULTIPLE x index_topk`` positions takes the gather form,
+    which holds no Mosaic call."""
+    from deepspeed_tpu.ops.transformer import sparse_latent_attention as sla
+
+    monkeypatch.setattr(sla, "on_tpu", lambda: True)
+    layer, shapes = _sparse_decode_layer(jnp.float32, pages)
+    assert sla.decode_form(pages * 64, 2048)["form"] == form
+    with jax.default_matmul_precision("highest"):
+        lowered = jax.jit(layer).lower(*(jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape, dtype in shapes))
+        if form == "gather":  # plain XLA, as the parent's: nothing of Mosaic's to compile (its sort alone compiles for half a minute)
+            assert "tpu_custom_call" not in lowered.as_text()
+            return
+        text = lowered.compile().as_text()
+    calls = [line for line in text.splitlines() if "/sparse_attend/" in line and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+
+
 _OLMOE_CELL = pathlib.Path(__file__).parents[3] / "benchmark/configs/olmoe-1b-7b-0125-l12.json"
 
 

@@ -44,7 +44,25 @@ narrow form's half, key tiles and ring were chosen: each ``--set`` runs the
 mixes once more with other values of that file's constants, and ``--root``
 measures another checkout's kernel (the parent's) beside them.
 
+The model ``dots3_sparse`` is a sparse latent layer's decode row over its
+chosen keys (``ops/transformer/sparse_latent_attention.py``: dots3-note-prev's
+128 heads over one entry of 576 in pages of 640 lanes, 32 rows, a table of 256
+pages, 3 layers) under the mix ``decode32``: the longest row at 4k, 8k and 16k
+live keys and the others down to three quarters of it, each with 26% of the
+longest row's keys chosen and with all of them, seeded scores. The
+two forms of the T = 1 attention, selection included, us a call: ``gather``
+(one stable sort with the pool address as payload, a gather of the chosen
+entries, the softmax over them) and ``walk`` (``hybrid_moe.chosen_keys``'s
+mask, the kernel over the row's live pages), each selection alone (``sort``,
+``counts``, and the same mask from one sort of the scores), the floor of the walk's bytes beside them, and the two rates the
+form rule's constant (``WALK_MAX_MULTIPLE``) is reckoned from: ns a key the
+kernel walks, ns an entry XLA gathers and attends. The walk form is held to
+the gather form ON THE CHIP at every shape before any timing; each ``--set``
+runs the walk once more with other values of ``_WALK_HALF_KEYS``,
+``_WALK_TILE_KEYS``, ``_WALK_RING``.
+
     chiprun -- python3 tools/ragged_kernel_bench.py [--models mistral7b,olmoe] [--mixes decode16,chat4,mixed]
+    chiprun -- python3 tools/ragged_kernel_bench.py --models dots3_sparse [--set _WALK_HALF_KEYS=2048,_WALK_RING=2 ...]
     chiprun -- python3 tools/ragged_kernel_bench.py --models laguna_window,mimo_window --mixes decode_long --rows-per-step 1,2,4,8,16
     chiprun -- python3 tools/ragged_kernel_bench.py --models glm47 [--root DIR] [--mixes decode64_long] [--set _NARROW_HALF_KEYS=768,_NARROW_RING=2 ...]
     python3 tools/ragged_kernel_bench.py --rehearse [--models glm47]      # tiny, on the CPU: the control flow only
@@ -78,6 +96,10 @@ WIDTHS = {"mimo_window": (192, 128), "tiny_sinks": (192, 128)}
 # (query heads, value lanes, rotated lanes, lanes a page stores, layers, rows, pages a row, page)
 LATENT = {"glm47": (20, 512, 64, 640, 16, 64, 64, 64)}
 LATENT_TINY = {"glm47": (20, 128, 32, 256, 2, 6, 12, 8)}
+# (query heads, value lanes, rotated lanes, lanes a page stores, layers, rows, pages a row, page, live keys a row, chosen shares)
+SPARSE = {"dots3_sparse": (128, 512, 64, 640, 3, 32, 256, 64, (4096, 8192, 16384), (0.26, 1.0))}
+SPARSE_TINY = {"dots3_sparse": (8, 128, 32, 256, 2, 4, 12, 8, (40, 96), (0.26, 1.0))}
+SPARSE_GAP = 0.03  # the two forms' outputs, bfloat16 entries of unit variance: they differ by the order of their sums and p's rounding
 
 
 def mixes(rng, rows, maxp, page, wide):
@@ -102,6 +124,11 @@ def mixes(rng, rows, maxp, page, wide):
         "decode_long": (1, [(1, max(1, int(kv))) for kv in long]),
         "chunk1": (wide, [chunk] + [(0, 0)] * (rows - 1)),
     }
+
+
+def tried_constants(args):
+    """Each ``--set NAME=INT,...`` as a dict of a kernel file's constants."""
+    return [dict((name, int(value)) for name, value in (pair.split("=") for pair in text.split(","))) for text in args.set]
 
 
 def timed(program, operands, carried, rehearse):
@@ -144,7 +171,7 @@ def latent_bench(args, model, dims, calls, peak):
     # contexts as the cell's window finds them, the same for every seed: none shorter than a seventh of the longest,
     # a long tail up to it (the quantiles of 0.146 + 0.854 Beta(1, 2.33)), in a shuffled order
     long = rng.permutation(longest * (0.146 + 0.854 * (1 - (1 - np.arange(R) / max(1, R - 1)) ** (1 / 2.33))))
-    tried = [{}] + [dict((name, int(value)) for name, value in (pair.split("=") for pair in text.split(","))) for text in args.set]
+    tried = [{}] + tried_constants(args)
     pool = jnp.concatenate([layer] * L)
     for mix, kv in {"decode64_long": long.astype(np.int64), "decode64_short": (long / 4).astype(np.int64)}.items():
         if mix not in args.mixes.split(","):
@@ -198,6 +225,103 @@ def latent_bench(args, model, dims, calls, peak):
             )
 
 
+def sparse_bench(args, model, dims, calls, peak):
+    """A sparse layer's decode rows over their chosen keys, both forms, at each length and chosen share."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.models import hybrid_moe as hm
+    from deepspeed_tpu.ops.transformer import sparse_latent_attention as module
+
+    NH, Dv, rope, D, L, R, maxp, P, lengths, shares = dims
+    lengths = [int(n) for n in args.live_keys.split(",")] if args.live_keys else lengths
+    shares = [float(x) for x in args.shares.split(",")] if args.shares else shares
+    NP, S = R * maxp + 1, maxp * P
+    rng = np.random.default_rng(0)
+    key = jax.random.PRNGKey(0)
+    pool = jax.random.normal(key, (L, NP, P, D), jnp.bfloat16)
+    table = jnp.asarray(1 + rng.permutation(R * maxp).reshape(R, maxp), jnp.int32)  # scattered, as a pool ages
+    q = jax.random.normal(jax.random.fold_in(key, 1), (R, NH, D), jnp.bfloat16)
+    scores = jax.random.normal(jax.random.fold_in(key, 2), (R, S), jnp.float32)  # seeded: the chosen lie evenly over a row's pages
+    scale = (Dv + rope) ** -0.5
+    at = jnp.arange(S, dtype=jnp.int32)
+    interpret = True if args.rehearse else None
+    tried = [{}] + tried_constants(args)
+
+    def gather(topk, q, scores, pool, layer, kv_lens):
+        return module._chosen_entries_attention(q, scores, pool, layer, table, kv_lens, topk, Dv, scale)
+
+    def walk(topk, q, scores, pool, layer, kv_lens):
+        mask = hm.chosen_keys(scores, at[None, :] < kv_lens[:, None], topk)
+        return module._walk_chosen_pages(q, mask, pool, layer, table, kv_lens, Dv, scale, interpret=interpret)
+
+    def sort(topk, q, scores, pool, layer, kv_lens):  # the gather form's selection alone: one stable sort, a payload beside the key
+        _, where = jax.lax.sort((jnp.where(at[None, :] < kv_lens[:, None], -scores, jnp.inf), jnp.broadcast_to(at, scores.shape)), dimension=1, is_stable=True, num_keys=1)
+        return where[:, :1, None].astype(jnp.float32)
+
+    def counts(topk, q, scores, pool, layer, kv_lens):  # the walk form's selection alone
+        return jnp.sum(hm.chosen_keys(scores, at[None, :] < kv_lens[:, None], topk), axis=1, dtype=jnp.float32)[:, None, None]
+
+    def sort_mask(topk, q, scores, pool, layer, kv_lens):  # the same mask from ONE sort of the scores alone: the k-th largest, then the ties by position
+        live = at[None, :] < kv_lens[:, None]
+        keyed = jnp.where(live, scores, -jnp.inf)
+        kth = jnp.sort(keyed, axis=1)[:, S - min(topk, S)][:, None]
+        above, tied = keyed > kth, keyed == kth
+        mask = live & (above | (tied & (jnp.cumsum(tied, axis=1) <= topk - jnp.sum(above, axis=1, keepdims=True))))
+        return jnp.sum(mask, axis=1, dtype=jnp.float32)[:, None, None]
+
+    def us_a_call(form, topk, kv_lens):
+        def many(q, scores, pool, kv_lens):  # ``calls`` layers back to back in one program; the scores move so that no selection is hoisted
+            def body(i, acc):
+                return acc + form(topk, q, scores + i.astype(jnp.float32) * 1e-9, pool, i % L, kv_lens).astype(jnp.float32)
+
+            return jax.lax.fori_loop(0, calls, body, jnp.zeros((R, NH, Dv), jnp.float32))
+
+        program = jax.jit(many).lower(q, scores, pool, kv_lens).compile()
+        best = float("inf")
+        for _ in range(1 if args.rehearse else 5):
+            t = time.perf_counter()
+            program(q, scores, pool, kv_lens).block_until_ready()
+            best = min(best, time.perf_counter() - t)
+        return best / calls * 1e6
+
+    for live in lengths:
+        # the longest row at ``live`` keys, the others down to three quarters of it in even steps: no two rows end in the same place of a half
+        kv_lens = jnp.asarray([live - (r * (live // 4)) // max(1, R - 1) - (r > 0) * (P // 2 - 1) for r in range(R)], jnp.int32)
+        walked = int(jnp.sum(kv_lens))
+        floor = sum(-(-int(n) // P) for n in kv_lens) * P * D * 2 / peak["hbm_bytes_per_s"] * 1e6
+        for share in shares:
+            topk = min(S, max(1, int(round(share * live))))
+            want = jax.jit(functools.partial(gather, topk))(q, scores, pool, 1, kv_lens).astype(jnp.float32)
+            times = {}
+            for constants in tried:
+                defaults = {name: getattr(module, name) for name in constants}
+                for name, value in constants.items():
+                    setattr(module, name, value)
+                try:
+                    C, CK, N = module._walk_tiles(P, maxp)
+                    got = jax.jit(functools.partial(walk, topk))(q, scores, pool, 1, kv_lens).astype(jnp.float32)
+                    gap = float(jnp.max(jnp.abs(got - want)))
+                    if not gap < SPARSE_GAP:  # held to the gather form before any timing
+                        sys.exit(f"ragged_kernel_bench: the walk form is not the gather form at {live} keys, {topk} chosen: max |walk - gather| {gap}")
+                    label = " ".join(f"{n}={v}" for n, v in constants.items()) or "walk"
+                    times[label] = (us_a_call(walk, topk, kv_lens), f"{N} halves of {C * P} keys, key tiles of {CK * P}", gap)
+                finally:
+                    for name, value in defaults.items():
+                        setattr(module, name, value)
+            t_gather, t_sort, t_counts, t_sort_mask = (us_a_call(form, topk, kv_lens) for form in (gather, sort, counts, sort_mask))
+            chosen = R * min(topk, live)
+            for label, (t_walk, form, gap) in times.items():
+                print(
+                    f"{model:14s} decode{R} live keys {live:5d} chosen {topk:5d} ({100 * share:3.0f}%) {label}: gather {t_gather:8.1f} us a call "
+                    f"(its sort {t_sort:7.1f}), walk {t_walk:8.1f} (its counts {t_counts:7.1f}; the mask by one sort {t_sort_mask:7.1f}), the walk's bytes {floor:7.1f} us ({100 * floor / (t_walk - t_counts):5.1f}% "
+                    f"of the kernel's time) | {1e3 * (t_walk - t_counts) / walked:6.2f} ns a key walked, {1e3 * (t_gather - t_sort) / chosen:6.2f} ns an entry gathered | "
+                    f"{form} | max |walk - gather| {gap:.4f}",
+                    flush=True,
+                )
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--models", default="mistral7b,olmoe")
@@ -212,8 +336,10 @@ def main() -> None:
     ap.add_argument(
         "--set", action="append", default=[], metavar="NAME=INT,...",
         help="constants of the kernel's file to try in place of the file's, a run each time it is given: glm47 "
-        "_NARROW_HALF_KEYS=768,_NARROW_RING=2 (beside the file's own), the ragged models _RING_SLOTS=6 (in its place)",
+        "_NARROW_HALF_KEYS=768,_NARROW_RING=2 and dots3_sparse _WALK_HALF_KEYS=2048 (beside the file's own), the ragged models _RING_SLOTS=6 (in its place)",
     )
+    ap.add_argument("--live-keys", default="", metavar="INT,...", help="dots3_sparse: the rows' live keys, in place of 4096,8192,16384")
+    ap.add_argument("--shares", default="", metavar="FLOAT,...", help="dots3_sparse: the chosen shares of a row's live keys, in place of 0.26,1.0")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
@@ -233,12 +359,17 @@ def main() -> None:
     calls = 2 if args.rehearse else CALLS
     names = args.models.split(",")
     latent = [name for name in names if name in LATENT]
-    models = TINY if args.rehearse and not latent else {name: MODELS[name] for name in names if name not in LATENT}
+    sparse = [name for name in names if name in SPARSE]
+    models = TINY if args.rehearse and not latent + sparse else {name: MODELS[name] for name in names if name not in {**LATENT, **SPARSE}}
     peak = files.load_json(files.HERE, "peaks.json")["TPU v5 lite" if args.rehearse else jax.devices()[0].device_kind]
     for model in latent:
         latent_bench(args, model, (LATENT_TINY if args.rehearse else LATENT)[model], calls, peak)
+    for model in sparse:
+        sparse_bench(args, model, (SPARSE_TINY if args.rehearse else SPARSE)[model], calls, peak)
+    if not models:  # a --set names constants of the latent or the sparse file alone
+        return
     swept = [int(n) for n in args.rows_per_step.split(",")] if args.rows_per_step else [None]
-    tried = [dict((name, int(value)) for name, value in (pair.split("=") for pair in text.split(","))) for text in args.set] or [{}]
+    tried = tried_constants(args) or [{}]
     defaults = {name: getattr(decode_attention, name) for constants in tried for name in constants}
     for model, (NH, NKV, D, L, R, maxp, P, wide, window) in models.items():
         dk, dv = WIDTHS.get(model, (D, D))
